@@ -24,9 +24,23 @@ def _cmd_serve_node(args) -> int:
     from aiohttp import web
 
     from helix_tpu.control.node_agent import NodeAgent
+    from helix_tpu.device.compile_cache import configure_compile_cache
     from helix_tpu.control.profile import ServingProfile
     from helix_tpu.serving.openai_api import OpenAIServer
 
+    import logging
+
+    from helix_tpu.serving.logbuf import install as install_logbuf
+
+    # the node's log: INFO to stderr and to the /logs ring, from before the
+    # profile is applied (the build says which device and which attention
+    # backend it resolved, and how long load and warm-up took)
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s %(levelname)s %(name)s: %(message)s",
+    )
+    install_logbuf()
+    configure_compile_cache()   # before the first compile
     tunnel_mode = getattr(args, "tunnel", False)
     if tunnel_mode and not args.control_plane:
         print(

@@ -65,6 +65,8 @@ class ProfileModel:
     # forwarded to ModelConfig.tiny — e.g. {num_experts: 4} builds a toy
     # MoE for ep-mesh dev profiles
     model_overrides: dict = dataclasses.field(default_factory=dict)
+    # PRNG seed of a random-init model's weights (no checkpoint)
+    seed: int = 0
     # multi-host lockstep serving over DCN (serving/multihost_serving):
     # {} = single host; {"role": "leader"} broadcasts this engine's step
     # plans; {"role": "follower", "leader_url": "http://host0:8000"}
@@ -114,6 +116,7 @@ class ProfileModel:
             engine=dict(d.get("engine", {})),
             context_length=d.get("context_length"),
             model_overrides=dict(d.get("model_overrides", {})),
+            seed=int(d.get("seed", 0)),
             multihost=mh,
             slo=dict(d.get("slo", {})),
         )
@@ -130,6 +133,7 @@ class ProfileModel:
             "engine": dict(self.engine),
             "context_length": self.context_length,
             "model_overrides": dict(self.model_overrides),
+            "seed": self.seed,
             "multihost": dict(self.multihost),
             "slo": dict(self.slo),
         }

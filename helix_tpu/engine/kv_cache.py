@@ -48,9 +48,11 @@ class CacheConfig:
     page_size: int = 16
     max_pages_per_seq: int = 128
     # Page-pool storage dtype.  "int8" stores K/V codes at 1 byte/elem
-    # plus per-(slot, kv-head) f32 scale pools — page bytes drop to
-    # (D + 4) / (2 * D) of bf16, so ``fit_hbm`` admits ~1.94x the pages
-    # at head_dim 128 (the decode-throughput lever: batch is page-bound).
+    # plus per-(slot, kv-head) f32 scale pools whose page rows are
+    # ``KVH*P`` wide, padded to the TPU's 128 lanes in HBM — page bytes
+    # drop to (D + 4) / (2 * D) of bf16 when ``KVH*P`` fills whole lane
+    # rows (Llama-3-8B at page 16: ~1.94x the pages) and to (D + 8) /
+    # (2 * D) when it fills half of one (Qwen2-7B: ~1.88x).
     dtype: str = "bfloat16"
 
     @property
@@ -70,8 +72,10 @@ class CacheConfig:
         )
         total = per_elem * model.head_dim * jnp.dtype(self.dtype).itemsize
         if self.quantized:
-            # f32 scale per (token slot, kv head), for K and V pools
-            total += per_elem * 4
+            # f32 scale per (token slot, kv head), for K and V pools, in
+            # page rows padded to whole 128-lane rows
+            row = -(-self.page_size * model.num_kv_heads // 128) * 128
+            total += 2 * model.num_layers * row * 4
         return total
 
     def total_bytes(self, model: ModelConfig) -> int:
@@ -89,8 +93,8 @@ class CacheConfig:
         """Size the page pool to an HBM budget (what's left after weights) —
         the accounting the reference does per-GPU with
         ``--gpu-memory-utilization`` on vLLM, done natively here.
-        ``dtype="int8"`` budgets codes + scale pools, admitting
-        ``2*D/(D+4)`` (~1.94x at head_dim 128) the bf16 pages."""
+        ``dtype="int8"`` budgets codes + lane-padded scale pools (see
+        ``page_bytes``)."""
         probe = cls(num_pages=1, page_size=page_size,
                     max_pages_per_seq=max_pages_per_seq, dtype=dtype)
         per_page = probe.page_bytes(model)
@@ -109,7 +113,8 @@ class PagedKVCache:
     """Device page pool (a pytree — passes through jit with donation).
 
     With an int8 pool the per-(slot, head) f32 scale pools ``k_scale`` /
-    ``v_scale`` (shape ``[L, N, P, KVH]``) ride along; they are ``None``
+    ``v_scale`` ride along as lane-dense page rows ``[L, N, KVH*P]``
+    (``ops.quant.pack_scale_pages``); they are ``None``
     for full-precision pools so the pytree structure itself encodes the
     storage mode (jit re-traces on the structural change, no static flag
     needed).
@@ -117,7 +122,7 @@ class PagedKVCache:
 
     k_pages: jax.Array  # [L, N, P, KVH, D]
     v_pages: jax.Array
-    k_scale: Optional[jax.Array] = None  # [L, N, P, KVH] f32 (int8 pools)
+    k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
 
     @classmethod
@@ -134,7 +139,11 @@ class PagedKVCache:
             model.num_kv_heads,
             model.head_dim,
         )
-        sshape = shape[:-1]
+        sshape = (
+            model.num_layers,
+            cache.num_pages,
+            model.num_kv_heads * cache.page_size,
+        )
         dtype = jnp.dtype(cache.dtype)
         if mesh is not None:
             from helix_tpu.parallel.sharding import logical_sharding
@@ -153,7 +162,7 @@ class PagedKVCache:
             v = zeros()
             if cache.quantized:
                 ssharding = logical_sharding(
-                    mesh, ("layers", "pages", None, "cache_heads")
+                    mesh, ("layers", "pages", "cache_heads")
                 )
                 szeros = jax.jit(
                     lambda: jnp.zeros(sshape, jnp.float32),
@@ -253,23 +262,26 @@ def write_kv(
     )
     if not cache.quantized:
         return PagedKVCache(k_pages=k_pages, v_pages=v_pages)
-    k_scale = (
-        cache.k_scale.reshape(Lp, P * ps, KVHp)
-        .at[:, flat_idx]
-        .set(k_sc.reshape(L, B * S, KVH), mode="drop",
-             unique_indices=False)
-        .reshape(Lp, P, ps, KVHp)
-    )
-    v_scale = (
-        cache.v_scale.reshape(Lp, P * ps, KVHp)
-        .at[:, flat_idx]
-        .set(v_sc.reshape(L, B * S, KVH), mode="drop",
-             unique_indices=False)
-        .reshape(Lp, P, ps, KVHp)
-    )
+    # scale pools are [L, N, KVH*ps] page rows, head-major in a page: a
+    # token's KVH scales sit ps lanes apart, so the scatter indexes
+    # (page, offset) on a [L, N, KVH, ps] view with the head axis a
+    # window — which also keeps it local when heads are sharded
+    pg = jnp.where(valid, pages, 0).reshape(-1)
+    off = jnp.where(valid, offsets, 0).reshape(-1)
+
+    def scatter_scales(pool, sc):
+        return (
+            pool.reshape(Lp, P, KVHp, ps)
+            .at[:, pg, :, off]
+            .set(sc.reshape(L, B * S, KVH).swapaxes(0, 1), mode="drop",
+                 unique_indices=False)
+            .reshape(Lp, P, KVHp * ps)
+        )
+
     return PagedKVCache(
         k_pages=k_pages, v_pages=v_pages,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=scatter_scales(cache.k_scale, k_sc),
+        v_scale=scatter_scales(cache.v_scale, v_sc),
     )
 
 
@@ -846,8 +858,13 @@ def gather_pages(cache: PagedKVCache, page_ids: list) -> list:
     idx = jnp.asarray(np.asarray(page_ids, np.int32))
     k = cache.k_pages[:, idx]
     v = cache.v_pages[:, idx]
-    ks = cache.k_scale[:, idx] if cache.k_scale is not None else None
-    vs = cache.v_scale[:, idx] if cache.v_scale is not None else None
+    ks = vs = None
+    if cache.k_scale is not None:
+        from helix_tpu.ops.quant import unpack_scale_pages
+
+        ps = cache.k_pages.shape[2]
+        ks = unpack_scale_pages(cache.k_scale[:, idx], ps)
+        vs = unpack_scale_pages(cache.v_scale[:, idx], ps)
     out = []
     for i in range(len(page_ids)):
         out.append(
@@ -911,8 +928,12 @@ def restore_pages(
 
     k_new = stack("k")
     v_new = stack("v")
-    k_sc = stack("k_scale") if quantized else None
-    v_sc = stack("v_scale") if quantized else None
+    k_sc = v_sc = None
+    if quantized:
+        from helix_tpu.ops.quant import pack_scale_pages
+
+        k_sc = pack_scale_pages(stack("k_scale"))
+        v_sc = pack_scale_pages(stack("v_scale"))
     fn = _build_page_restore_fn(bucket, quantized)
     carry = fn(cache.carry(), jnp.asarray(idx), k_new, v_new, k_sc, v_sc)
     return PagedKVCache.from_carry(carry)

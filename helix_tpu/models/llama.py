@@ -50,13 +50,48 @@ def _act(name: str):
     raise ValueError(f"unknown activation {name}")
 
 
+def _seeded_int8(key, shape, dtype, std, embed, shardings):
+    """int8 codes + scales of a seeded normal tensor, never materialised
+    in float: a stacked ``[L, ...]`` tensor is drawn and quantized one
+    layer at a time inside one jit (Qwen2-7B's bf16 ``w_gate`` alone is
+    3.8 GB, its f32 draw 7.6 GB, on a 16 GB chip)."""
+    from helix_tpu.ops.quant import quantize_embedding, quantize_tensor
+
+    quantize = quantize_embedding if embed else quantize_tensor
+
+    def draw(k, shp):
+        w = jax.random.normal(k, shp, jnp.float32) * std
+        return quantize(w.astype(dtype))
+
+    def build(k):
+        if len(shape) == 2:
+            return draw(k, shape)
+        return jax.lax.map(
+            lambda kk: draw(kk, shape[1:]), jax.random.split(k, shape[0])
+        )
+
+    # out_shardings is keyed like the output dict ({weight, scale});
+    # lax.map stacks each layer's [1, out] scale row into [L, 1, out],
+    # the shape quantize_tensor gives the stacked tensor
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
 def init_params(
-    cfg: ModelConfig, key: jax.Array, dtype=None
+    cfg: ModelConfig, key: jax.Array, dtype=None, *, int8: bool = False,
+    shardings=None,
 ) -> Params:
-    """Random-init a stacked-layer parameter tree (tests, training-from-init).
+    """Random-init a stacked-layer parameter tree (tests, training-from-init,
+    seeded serving without a checkpoint).
 
     Real checkpoints come from ``helix_tpu.models.loader`` which produces the
     same tree from HF safetensors.
+
+    ``int8=True`` returns the tree ``ops.quant.quantize_params`` would give
+    (same structure, dtypes and scale shapes; a different draw from the
+    same seed), built tensor by tensor so that no float copy of a matmul
+    weight ever exists on the device.  ``shardings`` is then an optional
+    tree of shardings matching that int8 tree (a mesh's
+    ``quantized_logical_axes``); each tensor is born sharded.
     """
     dtype = dtype or jnp.dtype(cfg.dtype)
     L, E, H, KVH, D, F, V = (
@@ -70,21 +105,28 @@ def init_params(
     )
     ks = jax.random.split(key, 8)
 
-    def norm(k, shape, scale=0.02):
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+    def weight(k, shape, *path, std=0.02):
+        """One matmul weight's leaf dict, at ``path`` in the tree."""
+        if not int8:
+            w = jax.random.normal(k, shape, jnp.float32) * std
+            return {"weight": w.astype(dtype)}
+        sh = shardings
+        for name in path if sh is not None else ():
+            sh = sh[name]
+        return _seeded_int8(k, shape, dtype, std, path == ("embed",), sh)
 
     params = {
-        "embed": {"weight": norm(ks[0], (V, E))},
+        "embed": weight(ks[0], (V, E), "embed"),
         "layers": {
             "attn_norm": {"weight": jnp.ones((L, E), dtype)},
             "mlp_norm": {"weight": jnp.ones((L, E), dtype)},
-            "wq": {"weight": norm(ks[1], (L, E, H * D))},
-            "wk": {"weight": norm(ks[2], (L, E, KVH * D))},
-            "wv": {"weight": norm(ks[3], (L, E, KVH * D))},
-            "wo": {"weight": norm(ks[4], (L, H * D, E))},
-            "w_gate": {"weight": norm(ks[5], (L, E, F))},
-            "w_up": {"weight": norm(ks[6], (L, E, F))},
-            "w_down": {"weight": norm(ks[7], (L, F, E))},
+            "wq": weight(ks[1], (L, E, H * D), "layers", "wq"),
+            "wk": weight(ks[2], (L, E, KVH * D), "layers", "wk"),
+            "wv": weight(ks[3], (L, E, KVH * D), "layers", "wv"),
+            "wo": weight(ks[4], (L, H * D, E), "layers", "wo"),
+            "w_gate": weight(ks[5], (L, E, F), "layers", "w_gate"),
+            "w_up": weight(ks[6], (L, E, F), "layers", "w_up"),
+            "w_down": weight(ks[7], (L, F, E), "layers", "w_down"),
         },
         "final_norm": {"weight": jnp.ones((E,), dtype)},
     }
@@ -95,11 +137,12 @@ def init_params(
         kk = jax.random.split(jax.random.fold_in(key, 7), 4)
         layers = params["layers"]
         del layers["w_gate"], layers["w_up"], layers["w_down"]
-        layers["router"] = {"weight": norm(kk[0], (L, E, X))}
+        layers["router"] = weight(kk[0], (L, E, X), "layers", "router")
+        ex = ("layers", "experts")
         layers["experts"] = {
-            "w_gate": {"weight": norm(kk[1], (L, X, E, F))},
-            "w_up": {"weight": norm(kk[2], (L, X, E, F))},
-            "w_down": {"weight": norm(kk[3], (L, X, F, E))},
+            "w_gate": weight(kk[1], (L, X, E, F), *ex, "w_gate"),
+            "w_up": weight(kk[2], (L, X, E, F), *ex, "w_up"),
+            "w_down": weight(kk[3], (L, X, F, E), *ex, "w_down"),
         }
     if cfg.attention_bias:
         for nm, width in (("wq", H * D), ("wk", KVH * D), ("wv", KVH * D)):
@@ -108,7 +151,12 @@ def init_params(
         params["layers"]["q_norm"] = {"weight": jnp.ones((L, D), dtype)}
         params["layers"]["k_norm"] = {"weight": jnp.ones((L, D), dtype)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = {"weight": norm(jax.random.fold_in(key, 99), (E, V))}
+        params["lm_head"] = weight(
+            jax.random.fold_in(key, 99), (E, V), "lm_head")
+    if int8 and shardings is not None:
+        # the matmul weights were born sharded; this places what is left
+        # (norms, biases) and is a no-op for the rest
+        params = jax.tree.map(jax.device_put, params, shardings)
     return params
 
 

@@ -74,11 +74,15 @@ def load_params(
     mesh=None,
     logical_axes=None,
     dtype=None,
+    quantize: bool = False,
 ):
     """Load checkpoint into the ``init_params`` tree layout.
 
     With ``mesh`` + ``logical_axes``, each stacked tensor is placed with its
     NamedSharding as it is built, so host->HBM transfer happens shard-wise.
+    ``quantize=True`` returns the int8 tree of ``ops.quant.quantize_params``,
+    each tensor quantized as it reaches the device so that the full-precision
+    model is never resident there (``quantize_params_streamed``).
     """
     import jax
     import jax.numpy as jnp
@@ -213,17 +217,23 @@ def load_params(
                 "weight": np.ascontiguousarray(params["embed"]["weight"].T)
             }
 
+    axes = None
     if mesh is not None:
         from jax.sharding import NamedSharding
 
         axes = logical_axes or param_logical_axes(cfg)
 
-        def place(x, ax):
-            spec = _prune_spec_for_mesh(mesh, spec_for(ax))
-            return jax.device_put(
-                jnp.asarray(x), NamedSharding(mesh, spec)
-            )
+    def place(x, ax):
+        if mesh is None:
+            return jnp.asarray(x)
+        spec = _prune_spec_for_mesh(mesh, spec_for(ax))
+        return jax.device_put(x, NamedSharding(mesh, spec))
 
+    if quantize:
+        from helix_tpu.ops.quant import quantize_params_streamed
+
+        params = quantize_params_streamed(params, place, axes)
+    elif mesh is not None:
         params = jax.tree.map(place, params, axes)
     else:
         params = jax.tree.map(jnp.asarray, params)

@@ -27,11 +27,15 @@ instead of one trace family per caller.
   scatter (the flat one-index scatter that keeps the pool's row-major
   layout — see ``engine/kv_cache.py``).
 - **Int8 pools**: history pages stream to VMEM as int8 (half the bf16 HBM
-  bytes) together with their ``[P, KVH]`` f32 scale rows, and
-  dequantization happens **in-register** right before the score dot — the
-  MXU still sees fp32 operands.  Fresh tokens are attended at full
-  precision (matching the pre-unification prefill/verify numerics); the
-  write path quantizes through the shared codec.
+  bytes).  Their f32 scales live in lane-dense page rows ``[L, N, KVH*P]``
+  (a ``KVH``-minor pool is padded 16-32x in HBM and Mosaic refuses to
+  slice it); the wrapper gathers each row's table of scale rows into a
+  ``[R, KVH, tokens]`` slab and the kernel streams one 128-lane window of
+  it per chunk.  Dequantization is on the score side — K's scale
+  multiplies the scores, V's the probabilities — so the MXU still sees
+  fp32 operands and no scale ever crosses from lanes to sublanes.  Fresh
+  tokens are attended at full precision; the write path quantizes
+  through the shared codec.
 - Scores for ALL heads of a q block come from ONE 128-aligned MXU dot:
   the block-diagonal q layout ``[8*H, KVH*D]`` (query head h occupies the
   column block of its kv head) against the chunk buffer viewed flat
@@ -60,13 +64,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
 
-# jax renamed these between versions; support both spellings
-_MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-_CompilerParams = (
-    getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-)
-
 BQ = 8  # query-block tokens: one f32 sublane tile; bounds ragged waste
+
+
+class UnsupportedKernelGeometry(ValueError):
+    """The ragged kernel has no TPU lowering for this head geometry."""
+
+
+def check_geometry(num_heads: int, num_kv_heads: int, head_dim: int,
+                   kv_itemsize: int = 2):
+    """Raise :class:`UnsupportedKernelGeometry` for a geometry Mosaic
+    refuses (per device: under a ``tp`` mesh pass the per-shard counts).
+
+    - The kernel views a chunk as ``[tokens, KVH*D]`` with each head a
+      whole number of 128-lane tiles; a head width such as Phi-3-mini's
+      96 needs a relayout the compiler does not have ("unsupported shape
+      cast").
+    - A page is DMA'd as ``[P, KVH, D]``, and the pool's ``(KVH, D)``
+      minor pair is tiled in 32-bit sublane packs: ``KVH`` kv heads of
+      ``kv_itemsize`` bytes must fill one (2 heads in bf16, 4 in int8), or
+      the pool is padded in HBM and the slice is refused ("must be aligned
+      to tiling").  Qwen2-7B at tp=4 (one kv head per chip) is such a
+      case.
+    """
+    why = None
+    if head_dim % 128:
+        why = "the head width must be a multiple of the 128 lanes"
+    elif num_heads % num_kv_heads:
+        why = "the kv heads must divide the query heads"
+    elif num_kv_heads * kv_itemsize < 4:
+        why = (
+            f"{num_kv_heads} kv head(s) of {kv_itemsize}-byte elements per "
+            "device do not fill a 32-bit sublane pack (use a wider KV "
+            "dtype or fewer head shards)"
+        )
+    if why:
+        raise UnsupportedKernelGeometry(
+            "ragged paged-attention kernel: no TPU lowering for "
+            f"{num_heads} query / {num_kv_heads} kv heads of width "
+            f"{head_dim} per device: {why}.  Serve this geometry with "
+            "attn_backend='reference' explicitly, or extend the kernel."
+        )
 
 
 def _ragged_kernel(
@@ -80,7 +118,7 @@ def _ragged_kernel(
     #   plain: qf, knf, vnf, k_hbm, v_hbm | o_hbm
     #          | qbuf, kbuf, vbuf, knbuf, vnbuf, obuf, sems, fsems, qsem,
     #            osem
-    #   quant: ... + ks_hbm, vs_hbm pools and ksbuf/vsbuf/ssems scratch
+    #   quant: ... + ks_hbm, vs_hbm row slabs and ksbuf/vsbuf/ssems scratch
     *refs,
     scale: float,
     page_size: int,
@@ -120,7 +158,24 @@ def _ragged_kernel(
         nchunks = jax.lax.div(npages + C - 1, C)
         max_chunks = (max_pages + C - 1) // C
 
+        def scale_copies(ci, slot):
+            # one [KVH, C*P] lane-aligned window of the row's gathered
+            # scale slab per chunk (see the wrapper)
+            return [
+                pltpu.make_async_copy(
+                    src.at[r, :, pl.ds(ci * (C * P), C * P)],
+                    dst.at[slot],
+                    ssems.at[slot, j],
+                )
+                for j, (src, dst) in enumerate(
+                    ((ks_hbm, ksbuf), (vs_hbm, vsbuf))
+                )
+            ]
+
         def start_chunk(ci, slot):
+            if quantized:
+                for cp in scale_copies(ci, slot):
+                    cp.start()
             for c in range(C):  # static unroll over pages in a chunk
                 @pl.when(ci * C + c < npages)
                 def _():
@@ -135,19 +190,11 @@ def _ragged_kernel(
                         vbuf.at[slot, c],
                         sems.at[slot, c, 1],
                     ).start()
-                    if quantized:
-                        pltpu.make_async_copy(
-                            ks_hbm.at[lyr, page],
-                            ksbuf.at[slot, c],
-                            ssems.at[slot, c, 0],
-                        ).start()
-                        pltpu.make_async_copy(
-                            vs_hbm.at[lyr, page],
-                            vsbuf.at[slot, c],
-                            ssems.at[slot, c, 1],
-                        ).start()
 
         def wait_chunk(ci, slot):
+            if quantized:
+                for cp in scale_copies(ci, slot):
+                    cp.wait()
             for c in range(C):
                 @pl.when(ci * C + c < npages)
                 def _():
@@ -162,17 +209,6 @@ def _ragged_kernel(
                         vbuf.at[slot, c],
                         sems.at[slot, c, 1],
                     ).wait()
-                    if quantized:
-                        pltpu.make_async_copy(
-                            ks_hbm.at[lyr, page],
-                            ksbuf.at[slot, c],
-                            ssems.at[slot, c, 0],
-                        ).wait()
-                        pltpu.make_async_copy(
-                            vs_hbm.at[lyr, page],
-                            vsbuf.at[slot, c],
-                            ssems.at[slot, c, 1],
-                        ).wait()
 
         @pl.when(nchunks > 0)
         def _():
@@ -206,6 +242,16 @@ def _ragged_kernel(
         tok_of_row = jax.lax.rem(r_iota, BQ * group) // group  # [RQ, 1]
         q_off_row = i * BQ + tok_of_row                         # [RQ, 1]
 
+        def head_rows(sc):
+            # [KVH, T] per-head scale rows -> [RQ, T] in q_bd row order
+            return jnp.concatenate(
+                [
+                    jnp.broadcast_to(sc[k:k + 1], (BQ * group, sc.shape[1]))
+                    for k in range(KVH)
+                ],
+                axis=0,
+            )
+
         # ---- history pages: online softmax over the ragged page walk --
         def body(ci, carry):
             m_prev, l_prev, acc_prev = carry   # [RQ,1],[RQ,1],[RQ,KVH*D]
@@ -216,22 +262,8 @@ def _ragged_kernel(
                 start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
 
             wait_chunk(ci, slot)
-            if quantized:
-                k_flat = (
-                    kbuf[slot].astype(jnp.float32)
-                    * ksbuf[slot][..., None]
-                ).reshape(C * P, KVH * D)
-                v_flat = (
-                    vbuf[slot].astype(jnp.float32)
-                    * vsbuf[slot][..., None]
-                ).reshape(C * P, KVH * D)
-            else:
-                k_flat = (
-                    kbuf[slot].reshape(C * P, KVH * D).astype(jnp.float32)
-                )
-                v_flat = (
-                    vbuf[slot].reshape(C * P, KVH * D).astype(jnp.float32)
-                )
+            k_flat = kbuf[slot].reshape(C * P, KVH * D).astype(jnp.float32)
+            v_flat = vbuf[slot].reshape(C * P, KVH * D).astype(jnp.float32)
             token0 = ci * C * P
             tok = token0 + jax.lax.broadcasted_iota(
                 jnp.int32, (1, C * P), 1
@@ -240,8 +272,8 @@ def _ragged_kernel(
             # un-DMA'd buffer regions (pages past this row's history)
             # hold garbage; the softmax weight there is exactly 0, but
             # 0 * NaN still poisons the PV accumulation — zero V
-            # explicitly (the int8 scale garbage folds into v_flat, so
-            # this one guard covers it too).
+            # explicitly (int8 codes are finite whatever the buffer
+            # holds, and their scales come from the wrapper's gather).
             v_flat = jnp.where(
                 jax.lax.broadcasted_iota(jnp.int32, (C * P, 1), 0)
                 < hist_r - token0,
@@ -251,12 +283,21 @@ def _ragged_kernel(
                 q_bd, k_flat, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale                           # [RQ, T]
+            if quantized:
+                # dequantize on the score side: q_bd row block k only
+                # ever meets kv head k, so K's per-(token, head) scale
+                # multiplies the scores and V's the probabilities —
+                # [RQ, T] lane-dense products instead of a lane->sublane
+                # relayout of the scales against [T, KVH*D]
+                s = s * head_rows(ksbuf[slot])
             s = jnp.where(in_range, s, DEFAULT_MASK_VALUE)
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * head_rows(vsbuf[slot])
             acc_new = acc_prev * alpha + jax.lax.dot_general(
                 p, v_flat, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -356,7 +397,7 @@ def ragged_paged_attention_tpu(
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
-    k_scale=None,  # [L, N, P, KVH] f32 — present iff the pool is int8
+    k_scale=None,  # [L, N, KVH*P] f32 — present iff the pool is int8
     v_scale=None,
     **tiered,      # span_lo/span_hi/cold_* — NOT supported in-kernel yet
 ):
@@ -380,7 +421,14 @@ def ragged_paged_attention_tpu(
     T, H, D = q.shape
     L, N, P, KVH, _ = k_pages.shape
     R, maxP = tables.shape
+    if not interpret:
+        check_geometry(H, KVH, D, k_pages.dtype.itemsize)
     group = H // KVH
+    # Mosaic tiles the (group, D) minor pair of the q/o blocks: a group
+    # that is neither a whole sublane tile nor a power-of-two fraction of
+    # one (Qwen2-7B: 28/4 = 7) is refused, so pad it with zero query heads
+    # and slice them off the output
+    G = group if group in (1, 2, 4) else -(-group // 8) * 8
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     C = max(1, 128 // P)
     C = min(C, maxP)
@@ -401,6 +449,8 @@ def ragged_paged_attention_tpu(
     NQ = Tpad // BQ
 
     qg = q.reshape(Tpad, KVH, group, D)
+    if G != group:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, G - group), (0, 0)))
     kernel = functools.partial(
         _ragged_kernel,
         scale=scale,
@@ -408,40 +458,49 @@ def ragged_paged_attention_tpu(
         pages_per_chunk=C,
         max_pages=maxP,
         kv_heads=KVH,
-        group=group,
+        group=G,
         quantized=quantized,
     )
-    any_spec = pl.BlockSpec(memory_space=_MemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     in_specs = [any_spec] * (7 if quantized else 5)
     out_spec = any_spec
     scratch = [
-        pltpu.VMEM((BQ, KVH, group, D), q.dtype),           # qbuf
+        pltpu.VMEM((BQ, KVH, G, D), q.dtype),               # qbuf
         pltpu.VMEM((2, C, P, KVH, D), k_pages.dtype),       # kbuf
         pltpu.VMEM((2, C, P, KVH, D), v_pages.dtype),       # vbuf
     ]
     if quantized:
         scratch += [
-            pltpu.VMEM((2, C, P, KVH), jnp.float32),        # ksbuf
-            pltpu.VMEM((2, C, P, KVH), jnp.float32),        # vsbuf
+            pltpu.VMEM((2, KVH, C * P), jnp.float32),       # ksbuf
+            pltpu.VMEM((2, KVH, C * P), jnp.float32),       # vsbuf
         ]
     scratch += [
         pltpu.VMEM((BQ, KVH, D), k_new.dtype),              # knbuf
         pltpu.VMEM((BQ, KVH, D), v_new.dtype),              # vnbuf
-        pltpu.VMEM((BQ, KVH, group, D), q.dtype),           # obuf
+        pltpu.VMEM((BQ, KVH, G, D), q.dtype),               # obuf
         pltpu.SemaphoreType.DMA((2, C, 2)),                 # sems
     ]
     if quantized:
-        scratch += [pltpu.SemaphoreType.DMA((2, C, 2))]     # ssems
+        scratch += [pltpu.SemaphoreType.DMA((2, 2))]        # ssems
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),                      # fsems
         pltpu.SemaphoreType.DMA(()),                        # qsem
         pltpu.SemaphoreType.DMA(()),                        # osem
     ]
-    inputs = (
-        (qg, k_new, v_new, k_pages, v_pages, k_scale, v_scale)
-        if quantized
-        else (qg, k_new, v_new, k_pages, v_pages)
-    )
+    inputs = (qg, k_new, v_new, k_pages, v_pages)
+    if quantized:
+        # The scale pools are lane-dense ``[L, N, KVH*P]`` (head-major in
+        # a page).  A page's 16 scales per head are far below the 128-lane
+        # DMA granule, so XLA gathers each row's table of page rows here
+        # and lays them out ``[R, KVH, tokens]``; the kernel then streams
+        # one aligned [KVH, C*P] window per chunk.
+        def row_scales(pool):
+            rows = pool[layer][tables].reshape(R, maxP, KVH, P)
+            rows = rows.transpose(0, 2, 1, 3).reshape(R, KVH, maxP * P)
+            tail = -maxP % C * P
+            return jnp.pad(rows, ((0, 0), (0, 0), (0, tail)))
+
+        inputs += (row_scales(k_scale), row_scales(v_scale))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(R, NQ),
@@ -452,9 +511,9 @@ def ragged_paged_attention_tpu(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tpad, KVH, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Tpad, KVH, G, D), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(
@@ -465,4 +524,4 @@ def ragged_paged_attention_tpu(
         jnp.asarray(layer, jnp.int32).reshape(1),
         *inputs,
     )
-    return out.reshape(Tpad, H, D)[:T]
+    return out[:T, :, :group].reshape(T, H, D)

@@ -52,6 +52,27 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype=jnp.float32):
     return out.astype(dtype)
 
 
+def pack_scale_pages(scale: jax.Array) -> jax.Array:
+    """Per-page scale rows ``[..., P, KVH]`` -> the pools' lane-dense
+    page rows ``[..., KVH*P]`` (head-major inside a page).
+
+    On the TPU an f32 array's minor axis is padded to 128 lanes, so a
+    pool with ``KVH`` (4 or 8) minor would cost 16-32x its nominal HBM
+    and the kernel could not slice it; ``KVH*P`` is 64-128 wide.  Pages
+    on the host and on the wire keep the ``[P, KVH]`` form."""
+    *lead, P, KVH = scale.shape
+    return jnp.swapaxes(scale, -1, -2).reshape(*lead, KVH * P)
+
+
+def unpack_scale_pages(rows: jax.Array, page_size: int) -> jax.Array:
+    """Inverse of :func:`pack_scale_pages`: ``[..., KVH*P]`` -> ``[..., P,
+    KVH]``."""
+    *lead, W = rows.shape
+    return jnp.swapaxes(
+        rows.reshape(*lead, W // page_size, page_size), -1, -2
+    )
+
+
 def quantize_tensor(w: jax.Array):
     """Symmetric int8, per-output-channel (last axis) scales.
 
@@ -71,40 +92,63 @@ def quantize_tensor(w: jax.Array):
     return {"weight": q, "scale": scale.astype(jnp.float32)}
 
 
+def quantize_embedding(w: jax.Array):
+    """Embedding table: int8 with per-row scales (lookup then rescale)."""
+    wf = w.astype(jnp.float32)
+    absmax = jnp.max(jnp.abs(wf), axis=-1, keepdims=True)
+    scale = jnp.maximum(absmax / 127.0, 1e-8)
+    q = jnp.clip(jnp.round(wf / scale), -127, 127).astype(jnp.int8)
+    return {"weight": q, "embed_scale": scale.astype(jnp.float32)}
+
+
+def _map_matmul_weights(tree, quantize, other, aux=None, path=()):
+    """Rebuild a model tree with ``quantize(v, aux, embed)`` (a dict of
+    leaves) replacing every matmul weight and ``other(v, aux)`` every
+    other leaf.  ``aux`` is an optional parallel tree (logical axes)."""
+    if not isinstance(tree, dict):
+        return other(tree, aux)
+    out = {}
+    for k, v in tree.items():
+        a = None if aux is None else aux[k]
+        if (
+            k == "weight"
+            and hasattr(v, "ndim")
+            and v.ndim >= 2
+            and not any("norm" in p for p in path)
+        ):
+            out.update(quantize(v, a, bool(path) and path[-1] == "embed"))
+        else:
+            out[k] = _map_matmul_weights(v, quantize, other, a, path + (k,))
+    return out
+
+
 def quantize_params(params: Any) -> Any:
     """Quantize every matmul weight in a model tree; embedding rows get
     per-row scales (lookup then rescale)."""
+    return _map_matmul_weights(
+        params,
+        lambda v, _, embed: (
+            quantize_embedding(v) if embed else quantize_tensor(v)
+        ),
+        lambda v, _: v,
+    )
 
-    def walk(tree, path=()):
-        if isinstance(tree, dict):
-            out = {}
-            for k, v in tree.items():
-                if (
-                    k == "weight"
-                    and hasattr(v, "ndim")
-                    and v.ndim >= 2
-                    and not any("norm" in p for p in path)
-                ):
-                    if path and path[-1] == "embed":
-                        # embedding: quantize per row (axis -1 reduce)
-                        wf = v.astype(jnp.float32)
-                        absmax = jnp.max(jnp.abs(wf), axis=-1, keepdims=True)
-                        scale = jnp.maximum(absmax / 127.0, 1e-8)
-                        q = jnp.clip(jnp.round(wf / scale), -127, 127).astype(
-                            jnp.int8
-                        )
-                        out["weight"] = q
-                        out["embed_scale"] = scale.astype(jnp.float32)
-                    else:
-                        qd = quantize_tensor(v)
-                        out["weight"] = qd["weight"]
-                        out["scale"] = qd["scale"]
-                else:
-                    out[k] = walk(v, path + (k,))
-            return out
-        return tree
 
-    return walk(params)
+def quantize_params_streamed(params: Any, place, aux: Any = None) -> Any:
+    """``quantize_params`` for a HOST tree on its way to the device:
+    ``place(leaf, aux_leaf)`` puts one leaf on the device(s), and each
+    matmul weight is quantized there by its own donated jit before the
+    next is placed.  The device then never holds more than one tensor in
+    its source dtype beside the int8 tree — what lets a 7-8B bf16
+    checkpoint load as int8 onto one 16 GB chip."""
+    q_embed = jax.jit(quantize_embedding, donate_argnums=0)
+    q_dense = jax.jit(quantize_tensor, donate_argnums=0)
+    return _map_matmul_weights(
+        params,
+        lambda v, a, embed: (q_embed if embed else q_dense)(place(v, a)),
+        place,
+        aux,
+    )
 
 
 def quantized_logical_axes(axes_tree: Any) -> Any:

@@ -66,12 +66,7 @@ def _merge_stats(m1, l1, a1, m2, l2, a2):
 
 def _ring_body(q, k, v, qpos, kpos, axis_name, scale, causal):
     """Runs inside shard_map: local shards + ppermute ring."""
-    # axis_size is missing on older jax; psum of a literal 1 constant-folds
-    # to the concrete axis size on every version
-    if hasattr(jax.lax, "axis_size"):
-        sp = jax.lax.axis_size(axis_name)
-    else:
-        sp = jax.lax.psum(1, axis_name)
+    sp = jax.lax.axis_size(axis_name)
     B, Sq, H, D = q.shape
 
     # derive the init carry from q so it carries the same varying-manual-axes
@@ -125,10 +120,7 @@ def ring_attention(
     history + itself): each device holds an Skv/sp KV shard and the ring
     rotates shards so every Q shard sees all of KV with O(Skv/sp) peak
     memory — the long-context serving path."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     B, Sq, H, D = q.shape
     Skv = k.shape[1]

@@ -1311,7 +1311,7 @@ class EngineLoop:
             scheduled, e,
         )
         if self._consec_failures == 1:
-            # transient faults (preemption, relay hiccup) clear on
+            # transient faults (preemption, a runtime hiccup) clear on
             # an immediate retry of the exact same state
             self.step_retries += 1
             return

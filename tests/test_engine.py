@@ -818,12 +818,13 @@ class TestInt8KVCache:
         cache = write_kv(
             cache, k_new, v_new, pages, offsets, jnp.ones((1, S), bool)
         )
-        from helix_tpu.ops.quant import dequantize_kv
+        from helix_tpu.ops.quant import dequantize_kv, unpack_scale_pages
+        k_scale = unpack_scale_pages(cache.k_scale, cc.page_size)
         for i in range(S):
             page = int(table[0, i // 4])
             got = dequantize_kv(
                 cache.k_pages[0, page, i % 4],
-                cache.k_scale[0, page, i % 4],
+                k_scale[0, page, i % 4],
             )
             # absmax/127 quantization: error <= scale/2 <= absmax/254
             bound = float(jnp.abs(k_new[0, 0, i]).max()) / 254 + 1e-6
@@ -888,7 +889,7 @@ class TestInt8KVCache:
         layout (a verify-width row + a decode row)."""
         from helix_tpu.ops.paged import ragged_paged_attention_reference
         from helix_tpu.ops.paged_kernel import ragged_paged_attention_tpu
-        from helix_tpu.ops.quant import quantize_kv
+        from helix_tpu.ops.quant import pack_scale_pages, quantize_kv
 
         KVH, H, D, P = 2, 4, 128, 4
         L, N = 3, 16
@@ -897,6 +898,7 @@ class TestInt8KVCache:
         v_f = k_f + 0.5
         k_pages, k_scale = quantize_kv(k_f)
         v_pages, v_scale = quantize_kv(v_f)
+        k_scale, v_scale = pack_scale_pages(k_scale), pack_scale_pages(v_scale)
         T = 4
         q = jax.random.normal(ks[0], (T, H, D), jnp.float32)
         k_new = jax.random.normal(ks[2], (T, KVH, D), jnp.float32)

@@ -479,17 +479,3 @@ class TestSatellites:
         assert r.status_code in (200, 501), r.text
         if r.status_code == 200:
             assert os.path.isdir(r.json()["log_dir"])
-
-    def test_bench_probe_skips_on_cpu_env(self, monkeypatch):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_probe_test",
-            os.path.join(os.path.dirname(__file__), "..", "bench.py"),
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        t0 = time.monotonic()
-        assert bench._device_healthy() is False
-        assert time.monotonic() - t0 < 1.0   # no probe subprocess at all

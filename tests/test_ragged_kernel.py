@@ -122,7 +122,7 @@ def _random_layout(rng_np, R, maxP, P, N):
 
 
 def _op_case(rng, *, int8: bool, seed: int):
-    from helix_tpu.ops.quant import quantize_kv
+    from helix_tpu.ops.quant import pack_scale_pages, quantize_kv
 
     L, N, P, KVH, D, H, maxP, R = 2, 24, 4, 2, 16, 4, 4, 5
     ks = jax.random.split(jax.random.fold_in(rng, seed), 4)
@@ -132,6 +132,7 @@ def _op_case(rng, *, int8: bool, seed: int):
     if int8:
         k_pages, k_scale = quantize_kv(k_f)
         v_pages, v_scale = quantize_kv(v_f)
+        k_scale, v_scale = pack_scale_pages(k_scale), pack_scale_pages(v_scale)
     else:
         k_pages, v_pages = k_f, v_f
     rng_np = np.random.default_rng(seed)

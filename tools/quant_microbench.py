@@ -15,7 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/root/.jax_bench_cache")
+from helix_tpu.device.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 
 def timeit(fn, *args, n=20):

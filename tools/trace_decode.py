@@ -13,8 +13,9 @@ sys.path.insert(0, ".")
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/root/.jax_bench_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from helix_tpu.device.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 TRACE_DIR = "/tmp/helix_trace"
 
