@@ -100,6 +100,7 @@ class Request:
     submit_time: float = dataclasses.field(default_factory=time.monotonic)
     admitted_time: Optional[float] = None   # slot claimed (queue wait ends)
     first_token_time: Optional[float] = None
+    first_emit_time: Optional[float] = None   # first token left the loop
     # end-to-end trace identity (obs.trace): minted at the OpenAI
     # endpoint, carried through dispatch into engine-level spans; empty
     # string = untraced (span recording is then a no-op)
@@ -807,12 +808,13 @@ def _tail_decode_step(params, cache, state: DecodeState, *, cfg, backend,
     )
     cache = write_kv(cache, kacc, vacc, pages, offsets,
                      (active > 0)[:, None])
-    penalised = apply_penalties(
-        logits[:, 0], state.token_counts,
-        state.sampling.presence, state.sampling.frequency,
-    )
-    carry_keys, step_keys = split_keys(state.keys)
-    token = sample(penalised, state.sampling, step_keys)
+    with jax.named_scope("sample"):
+        penalised = apply_penalties(
+            logits[:, 0], state.token_counts,
+            state.sampling.presence, state.sampling.frequency,
+        )
+        carry_keys, step_keys = split_keys(state.keys)
+        token = sample(penalised, state.sampling, step_keys)
     new_state = DecodeState(
         last_token=token,
         positions=state.positions + active,   # inactive slots stay parked
@@ -904,7 +906,6 @@ def _build_ragged_step_fn(
             "never the ragged prefill segment"
         )
 
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
     def step_fn(params, cache, state: DecodeState, pargs, drafts,
                 draft_len, n_extra, cold=None):
         B = state.last_token.shape[0]
@@ -930,209 +931,226 @@ def _build_ragged_step_fn(
 
         # ---- 1. prefill segment --------------------------------------
         if Cb > 0:
-            if use_adapters:
-                (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
-                 p_qlen, p_hist, p_tables, p_ends, p_sampling, p_keys,
-                 p_aids) = pargs
-            else:
-                (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
-                 p_qlen, p_hist, p_tables, p_ends, p_sampling,
-                 p_keys) = pargs
-                p_aids = None
-            kacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
-            vacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
-
-            def p_attn(q, k, v, carry_cache, pos):
-                (caches, kacc, vacc), lyr = carry_cache
-                if use_ring:
-                    out = _ring_chunk_attention(
-                        q, k, v, caches, lyr, p_pos, p_seg, p_hist,
-                        p_tables, mesh, page_size, ring_hist_pages,
-                    )
-                elif has_hist:
-                    out = _ragged_attn_call(
-                        q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
-                        p_tables, backend, cold=p_cold, mesh=mesh,
-                    )
+            with jax.named_scope("prefill"):
+                if use_adapters:
+                    (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
+                     p_qlen, p_hist, p_tables, p_ends, p_sampling, p_keys,
+                     p_aids) = pargs
                 else:
-                    # cold rows only: packed self-attention, no pool
-                    # reads — bit-compatible with the pre-unification
-                    # packed-prefill path
-                    out = full_attention(
-                        q, k, v,
-                        causal=True,
-                        q_positions=p_pos,
-                        kv_positions=p_pos,
-                        q_segment_ids=p_seg,
-                        kv_segment_ids=p_seg,
-                        backend=backend,
-                        mesh=mesh,
-                    )
-                return out, (caches, kacc.at[lyr].set(k),
-                             vacc.at[lyr].set(v))
+                    (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
+                     p_qlen, p_hist, p_tables, p_ends, p_sampling,
+                     p_keys) = pargs
+                    p_aids = None
+                kacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
+                vacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
 
-            res = forward(
-                params, cfg, p_tokens, p_pos,
-                attn_fn=p_attn,
-                carry_caches=(cache.carry(), kacc0, vacc0),
-                moe_token_mask=p_seg > 0,
-                return_moe_stats=is_moe,
-                adapter_ids=p_aids,
-            )
-            if is_moe:
-                logits_p, (pc, kacc, vacc), moe_stats = res
-                drops = moe_stats["dropped"]
-            else:
-                logits_p, (pc, kacc, vacc) = res
-            cache = write_kv(
-                PagedKVCache.from_carry(pc), kacc, vacc, p_pages,
-                p_offsets, p_seg > 0,
-            )
-            last = logits_p[0, p_ends]      # [R, V] — each row's last token
-            p_first = sample(last, p_sampling, p_keys)
+                def p_attn(q, k, v, carry_cache, pos):
+                    (caches, kacc, vacc), lyr = carry_cache
+                    if use_ring:
+                        out = _ring_chunk_attention(
+                            q, k, v, caches, lyr, p_pos, p_seg, p_hist,
+                            p_tables, mesh, page_size, ring_hist_pages,
+                        )
+                    elif has_hist:
+                        out = _ragged_attn_call(
+                            q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
+                            p_tables, backend, cold=p_cold, mesh=mesh,
+                        )
+                    else:
+                        # cold rows only: packed self-attention, no pool
+                        # reads — bit-compatible with the pre-unification
+                        # packed-prefill path
+                        out = full_attention(
+                            q, k, v,
+                            causal=True,
+                            q_positions=p_pos,
+                            kv_positions=p_pos,
+                            q_segment_ids=p_seg,
+                            kv_segment_ids=p_seg,
+                            backend=backend,
+                            mesh=mesh,
+                        )
+                    return out, (caches, kacc.at[lyr].set(k),
+                                 vacc.at[lyr].set(v))
+
+                res = forward(
+                    params, cfg, p_tokens, p_pos,
+                    attn_fn=p_attn,
+                    carry_caches=(cache.carry(), kacc0, vacc0),
+                    moe_token_mask=p_seg > 0,
+                    return_moe_stats=is_moe,
+                    adapter_ids=p_aids,
+                )
+                if is_moe:
+                    logits_p, (pc, kacc, vacc), moe_stats = res
+                    drops = moe_stats["dropped"]
+                else:
+                    logits_p, (pc, kacc, vacc) = res
+                cache = write_kv(
+                    PagedKVCache.from_carry(pc), kacc, vacc, p_pages,
+                    p_offsets, p_seg > 0,
+                )
+                last = logits_p[0, p_ends]   # [R, V]: each row's last token
+                with jax.named_scope("sample"):
+                    p_first = sample(last, p_sampling, p_keys)
         else:
             p_first = jnp.zeros((0,), jnp.int32)
 
         # ---- 2. state segment (decode / verify rows) -----------------
-        tokens_s = jnp.concatenate(
-            [state.last_token[:, None], drafts], axis=1
-        )                                                    # [B, W]
-        pos_s = state.positions[:, None] + jnp.arange(W)[None]
-        act = state.active > 0
-        live = (jnp.arange(W)[None] <= draft_len[:, None]) & act[:, None]
-        s_t0 = jnp.arange(B, dtype=jnp.int32) * W
-        # rows sitting this call out (draft_len -1: admission waves,
-        # standalone chunk steps) get q_len 0 so the kernel skips their
-        # page-pool sweep entirely — an admission wave must not cost a
-        # wasted decode step per active slot
-        s_qlen = jnp.where(act & (draft_len >= 0), W, 0).astype(jnp.int32)
-        s_hist = state.positions * state.active
-        kacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
-        vacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
-
-        def s_attn(q, k, v, carry_cache, pos):
-            (caches, kacc, vacc), lyr = carry_cache
-            out = _ragged_attn_call(
-                q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
-                state.page_tables, backend, cold=s_cold, mesh=mesh,
+        with jax.named_scope("state"):
+            tokens_s = jnp.concatenate(
+                [state.last_token[:, None], drafts], axis=1
+            )                                                    # [B, W]
+            pos_s = state.positions[:, None] + jnp.arange(W)[None]
+            act = state.active > 0
+            live = (
+                (jnp.arange(W)[None] <= draft_len[:, None]) & act[:, None]
             )
-            return out, (caches, kacc.at[lyr].set(k),
-                         vacc.at[lyr].set(v))
+            s_t0 = jnp.arange(B, dtype=jnp.int32) * W
+            # rows sitting this call out (draft_len -1: admission waves,
+            # standalone chunk steps) get q_len 0 so the kernel skips their
+            # page-pool sweep entirely — an admission wave must not cost a
+            # wasted decode step per active slot
+            s_qlen = jnp.where(
+                act & (draft_len >= 0), W, 0
+            ).astype(jnp.int32)
+            s_hist = state.positions * state.active
+            kacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
+            vacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
 
-        carry0 = (cache.carry(), kacc0s, vacc0s)
-        if is_mrope:
-            from helix_tpu.models.qwen2_vl import text_forward_mrope
+            def s_attn(q, k, v, carry_cache, pos):
+                (caches, kacc, vacc), lyr = carry_cache
+                out = _ragged_attn_call(
+                    q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
+                    state.page_tables, backend, cold=s_cold, mesh=mesh,
+                )
+                return out, (caches, kacc.at[lyr].set(k),
+                             vacc.at[lyr].set(v))
 
-            pos3 = jnp.broadcast_to(
-                (pos_s + state.mrope_delta[:, None])[None], (3, B, W)
+            carry0 = (cache.carry(), kacc0s, vacc0s)
+            if is_mrope:
+                from helix_tpu.models.qwen2_vl import text_forward_mrope
+
+                pos3 = jnp.broadcast_to(
+                    (pos_s + state.mrope_delta[:, None])[None], (3, B, W)
+                )
+                logits_s, (pc2, kaccs, vaccs) = text_forward_mrope(
+                    params, cfg, tokens_s, pos3,
+                    attn_fn=s_attn,
+                    carry_caches=carry0,
+                    mrope_sections=cfg.mrope_sections,
+                    seq_positions=pos_s,
+                )
+            else:
+                logits_s, (pc2, kaccs, vaccs) = forward(
+                    params, cfg, tokens_s, pos_s,
+                    attn_fn=s_attn,
+                    carry_caches=carry0,
+                    moe_token_mask=live,
+                    adapter_ids=(
+                        jnp.broadcast_to(
+                            state.adapter_slots[:, None], (B, W)
+                        )
+                        if use_adapters else None
+                    ),
+                )
+            cache = PagedKVCache.from_carry(pc2)
+            pages_s, offs_s = slot_to_page_offset(
+                pos_s, state.page_tables, page_size
             )
-            logits_s, (pc2, kaccs, vaccs) = text_forward_mrope(
-                params, cfg, tokens_s, pos3,
-                attn_fn=s_attn,
-                carry_caches=carry0,
-                mrope_sections=cfg.mrope_sections,
-                seq_positions=pos_s,
+            cache = write_kv(cache, kaccs, vaccs, pages_s, offs_s, live)
+
+            # position-by-position penalised sampling (cheap [B, V] ops):
+            # the histogram carries the drafted prefix forward so position
+            # j's penalties match plain decode having emitted j tokens.
+            # Splits are consumed ONLY at live positions — a plain step
+            # (draft_len 0) advances the key stream exactly once.
+            def samp_body(carry, j):
+                counts, keys = carry
+                pen = apply_penalties(
+                    logits_s[:, j], counts,
+                    state.sampling.presence, state.sampling.frequency,
+                )
+                carry_keys, step_keys = split_keys(keys)
+                tok = sample(pen, state.sampling, step_keys)
+                lj = live[:, j]
+                tok = jnp.where(lj, tok, 0)
+                keys = jnp.where(lj[:, None], carry_keys, keys)
+                counts = counts.at[jnp.arange(B), tok].add(
+                    lj.astype(counts.dtype)
+                )
+                return (counts, keys), tok
+
+            with jax.named_scope("sample"):
+                (counts, keys), sampled = jax.lax.scan(
+                    samp_body, (state.token_counts, state.keys),
+                    jnp.arange(W),
+                )
+            sampled = sampled.T                                  # [B, W]
+
+            # acceptance: longest prefix of draws agreeing with the drafts
+            if W > 1:
+                in_draft = jnp.arange(W - 1)[None, :] < draft_len[:, None]
+                agree = jnp.where(
+                    in_draft, sampled[:, : W - 1] == drafts, True
+                )
+                prefix = jnp.cumprod(agree.astype(jnp.int32), axis=1)
+                n_acc = jnp.sum(
+                    prefix * in_draft.astype(jnp.int32), axis=1
+                )
+            else:
+                n_acc = jnp.zeros((B,), jnp.int32)
+            emit = jnp.where(live[:, 0], n_acc + 1, 0)           # [B]
+
+            # roll back past the accepted length: positions/last_token/
+            # histogram come out exactly as ``emit`` plain decode steps
+            new_last = jnp.take_along_axis(
+                sampled, jnp.maximum(emit - 1, 0)[:, None], axis=1
+            )[:, 0]
+            discard = (jnp.arange(W)[None, :] >= emit[:, None]) & live
+            counts = counts.at[jnp.arange(B)[:, None], sampled].add(
+                -discard.astype(counts.dtype)
             )
-        else:
-            logits_s, (pc2, kaccs, vaccs) = forward(
-                params, cfg, tokens_s, pos_s,
-                attn_fn=s_attn,
-                carry_caches=carry0,
-                moe_token_mask=live,
-                adapter_ids=(
-                    jnp.broadcast_to(
-                        state.adapter_slots[:, None], (B, W)
-                    )
-                    if use_adapters else None
+            new_state = DecodeState(
+                last_token=jnp.where(
+                    emit > 0, new_last, state.last_token
                 ),
+                positions=state.positions + emit,
+                page_tables=state.page_tables,
+                active=state.active,
+                mrope_delta=state.mrope_delta,
+                keys=keys,
+                token_counts=counts,
+                adapter_slots=state.adapter_slots,
+                sampling=state.sampling,
             )
-        cache = PagedKVCache.from_carry(pc2)
-        pages_s, offs_s = slot_to_page_offset(
-            pos_s, state.page_tables, page_size
-        )
-        cache = write_kv(cache, kaccs, vaccs, pages_s, offs_s, live)
-
-        # position-by-position penalised sampling (cheap [B, V] ops):
-        # the histogram carries the drafted prefix forward so position
-        # j's penalties match plain decode having emitted j tokens.
-        # Splits are consumed ONLY at live positions — a plain step
-        # (draft_len 0) advances the key stream exactly once.
-        def samp_body(carry, j):
-            counts, keys = carry
-            pen = apply_penalties(
-                logits_s[:, j], counts,
-                state.sampling.presence, state.sampling.frequency,
-            )
-            carry_keys, step_keys = split_keys(keys)
-            tok = sample(pen, state.sampling, step_keys)
-            lj = live[:, j]
-            tok = jnp.where(lj, tok, 0)
-            keys = jnp.where(lj[:, None], carry_keys, keys)
-            counts = counts.at[jnp.arange(B), tok].add(
-                lj.astype(counts.dtype)
-            )
-            return (counts, keys), tok
-
-        (counts, keys), sampled = jax.lax.scan(
-            samp_body, (state.token_counts, state.keys), jnp.arange(W)
-        )
-        sampled = sampled.T                                  # [B, W]
-
-        # acceptance: longest prefix of draws agreeing with the drafts
-        if W > 1:
-            in_draft = jnp.arange(W - 1)[None, :] < draft_len[:, None]
-            agree = jnp.where(
-                in_draft, sampled[:, : W - 1] == drafts, True
-            )
-            prefix = jnp.cumprod(agree.astype(jnp.int32), axis=1)
-            n_acc = jnp.sum(prefix * in_draft.astype(jnp.int32), axis=1)
-        else:
-            n_acc = jnp.zeros((B,), jnp.int32)
-        emit = jnp.where(live[:, 0], n_acc + 1, 0)           # [B]
-
-        # roll back past the accepted length: positions/last_token/
-        # histogram come out exactly as ``emit`` plain decode steps
-        new_last = jnp.take_along_axis(
-            sampled, jnp.maximum(emit - 1, 0)[:, None], axis=1
-        )[:, 0]
-        discard = (jnp.arange(W)[None, :] >= emit[:, None]) & live
-        counts = counts.at[jnp.arange(B)[:, None], sampled].add(
-            -discard.astype(counts.dtype)
-        )
-        new_state = DecodeState(
-            last_token=jnp.where(emit > 0, new_last, state.last_token),
-            positions=state.positions + emit,
-            page_tables=state.page_tables,
-            active=state.active,
-            mrope_delta=state.mrope_delta,
-            keys=keys,
-            token_counts=counts,
-            adapter_slots=state.adapter_slots,
-            sampling=state.sampling,
-        )
 
         # ---- 3. fused plain-decode tail (dynamic length) -------------
         if n_tail_max > 0:
-            buf0 = jnp.zeros((n_tail_max, B), jnp.int32)
+            with jax.named_scope("tail"):
+                buf0 = jnp.zeros((n_tail_max, B), jnp.int32)
 
-            def tail_body(t, carry):
-                c, st, buf = carry
-                c, st, tok = _tail_decode_step(
-                    params, c, st, cfg=cfg, backend=backend,
-                    page_size=page_size, use_adapters=use_adapters,
-                    mesh=mesh,
+                def tail_body(t, carry):
+                    c, st, buf = carry
+                    c, st, tok = _tail_decode_step(
+                        params, c, st, cfg=cfg, backend=backend,
+                        page_size=page_size, use_adapters=use_adapters,
+                        mesh=mesh,
+                    )
+                    return _pin_default_layout(c), st, buf.at[t].set(tok)
+
+                cache, new_state, extra = jax.lax.fori_loop(
+                    0, n_extra, tail_body,
+                    (_pin_default_layout(cache), new_state, buf0),
                 )
-                return _pin_default_layout(c), st, buf.at[t].set(tok)
-
-            cache, new_state, extra = jax.lax.fori_loop(
-                0, n_extra, tail_body,
-                (_pin_default_layout(cache), new_state, buf0),
-            )
         else:
             extra = jnp.zeros((0, B), jnp.int32)
         return cache, new_state, p_first, sampled, emit, extra, drops
 
-    return step_fn
+    step_fn.__name__ = step_fn.__qualname__ = ragged_meta.step_program_name(
+        token_bucket, has_hist, prefill_rows, ring_hist_pages, cold_chunks
+    )
+    return jax.jit(step_fn, donate_argnums=(1, 2))
 
 
 
@@ -1298,8 +1316,6 @@ class Engine:
         self._key_nonce = 0
         self._step_counter = itertools.count()
         # metrics
-        import collections as _collections
-
         self.num_prefill_tokens = 0
         self.num_decode_tokens = 0
         # every token handed to a subscriber (decode + prefill first
@@ -1451,9 +1467,10 @@ class Engine:
         # prefill hot path never blocks on a drop-counter device_get
         self._moe_dropped = 0
         self._moe_drop_handles: list = []
-        self.recent_ttfts: "_collections.deque" = _collections.deque(
-            maxlen=200
-        )   # seconds; feeds /metrics p50/p95
+        # the step in progress, by named phase (obs.trace.phase): the
+        # engine loop clears it at the top of a pass and files it in the
+        # flight record; standalone step() callers never read it
+        self.step_phases = obs_trace.Phases()
 
     # ------------------------------------------------------------------
     # public API
@@ -1600,9 +1617,6 @@ class Engine:
         self.add_request(req)
         while self.has_work():
             self.step()
-        # the warmup token's latency is XLA compile time, not serving
-        # latency — keep it out of the TTFT percentiles
-        self.recent_ttfts.clear()
         self._sync_state()
         ps = self.cache_cfg.page_size
         maxP = self.cache_cfg.max_pages_per_seq
@@ -1626,7 +1640,7 @@ class Engine:
                 _host_key(0), SamplingParams(),
             )
             self._ragged_step(
-                plan=plan, draft_len=self._inert_rows, n_extra=0,
+                "warmup", plan=plan, draft_len=self._inert_rows, n_extra=0,
             )
 
         for rung in self._token_ladder:
@@ -1697,7 +1711,15 @@ class Engine:
             self._budget_left = self._plan_drive.budget
         elif self._plan_recorder is not None:
             self._plan_recorder.budget = self._budget_left
-        self._admit(emitted)
+        with obs_trace.phase("helix.loop.admit", into=self.step_phases):
+            self._admit(emitted)
+        with obs_trace.phase("helix.loop.dispatch", into=self.step_phases):
+            return emitted, self._dispatch_after_admit(emitted)
+
+    def _dispatch_after_admit(self, emitted) -> Optional[PendingStep]:
+        """What one step launches once admission is done: the long
+        prompt's next chunk alone or packed with the decode rows, a
+        speculative verify, or the fused decode window."""
         if self._chunking is not None and self._chunking["req"].finished:
             self._chunking = None    # aborted mid-prefill
         decode_ready = any(
@@ -1708,7 +1730,7 @@ class Engine:
             and decode_ready
             and self.cfg.enable_mixed_step
         ):
-            return emitted, self._mixed_dispatch()
+            return self._mixed_dispatch()
         if self._chunking is not None:
             self._chunk_dispatch()
         # re-check: a chunk that just completed activates its slot and
@@ -1723,12 +1745,12 @@ class Engine:
                 pend = self._spec_dispatch()
             if pend is None:
                 pend = self._decode_dispatch()
-            return emitted, pend
+            return pend
         # nothing decodable (admission-only step, or a chunk whose
         # request aborted between activation and decode): any deferred
         # first token must still land — conservative synchronous flush
         self._flush_pending_first(emitted)
-        return emitted, None
+        return None
 
     def step_complete(self, pend: PendingStep, emitted=None) -> list:
         """The RECONCILE phase: the step's one host fetch plus every
@@ -1737,13 +1759,20 @@ class Engine:
         fetch blocks only for the device time the host work did not
         already cover."""
         emitted = [] if emitted is None else emitted
-        if pend.kind == "decode":
-            self._decode_complete(pend, emitted)
-        elif pend.kind == "spec":
-            self._spec_complete(pend, emitted)
-        else:
-            self._mixed_complete(pend, emitted)
+        with obs_trace.phase("helix.loop.reconcile", into=self.step_phases):
+            if pend.kind == "decode":
+                self._decode_complete(pend, emitted)
+            elif pend.kind == "spec":
+                self._spec_complete(pend, emitted)
+            else:
+                self._mixed_complete(pend, emitted)
         return emitted
+
+    def _fetch(self, handles):
+        """A step's one ``jax.device_get``: the host blocks here until
+        the device has run the step."""
+        with obs_trace.phase("helix.loop.fetch", into=self.step_phases):
+            return jax.device_get(handles)
 
     def pipeline_ready(self) -> bool:
         """True when the NEXT dispatch can safely run against predicted
@@ -1858,7 +1887,7 @@ class Engine:
         if not pf:
             return
         for req, tok in pf:
-            self._finish_first_emit(req, int(np.asarray(tok)[0]), emitted)
+            self._finish_first_emit(req, int(self._fetch(tok)[0]), emitted)
         self._drain_moe_drops()   # the fetch above synced the device
 
     def _request_key(self, req: Request) -> np.ndarray:
@@ -2513,9 +2542,6 @@ class Engine:
                 continue
             first_token = self._prefill(req, table, slot=slot)
             req.first_token_time = time.monotonic()
-            self.recent_ttfts.append(
-                req.first_token_time - req.submit_time
-            )
             self._positions[slot] = plen
             self._mrope_delta[slot] = req.mrope_delta
             self._last_token[slot] = first_token
@@ -2614,7 +2640,8 @@ class Engine:
         admitted = 0
         for wave_plan, wave_batch in waves:
             first_tokens, _, _, _, drops = self._ragged_step(
-                plan=wave_plan, draft_len=self._inert_rows, n_extra=0,
+                "admit", plan=wave_plan, draft_len=self._inert_rows,
+                n_extra=0,
             )
             pending.append((wave_batch, first_tokens, drops))
             admitted += len(wave_batch)
@@ -2623,15 +2650,18 @@ class Engine:
     def _finish_packed_admissions(self, pending: list, emitted) -> None:
         """Fetch every admission wave's first tokens in ONE host round
         trip and complete the per-request bookkeeping."""
-        if len(pending) == 1:
-            batch0, tok0, _ = pending[0]
-            flat = np.asarray(tok0)[: len(batch0)]
-        else:
-            flat = np.asarray(
-                jnp.concatenate(
-                    [t[: len(b)] for b, t, _ in pending], axis=0
+        with obs_trace.phase(
+            "helix.loop.prefill_sync", into=self.step_phases
+        ):
+            if len(pending) == 1:
+                batch0, tok0, _ = pending[0]
+                flat = np.asarray(tok0)[: len(batch0)]
+            else:
+                flat = np.asarray(
+                    jnp.concatenate(
+                        [t[: len(b)] for b, t, _ in pending], axis=0
+                    )
                 )
-            )
         for _, _, drops in pending:
             self._note_moe_drops(drops)
         # the token fetch above synced the device: draining is free here
@@ -2644,7 +2674,6 @@ class Engine:
                 i += 1
                 slot = req.slot
                 req.first_token_time = now
-                self.recent_ttfts.append(now - req.submit_time)
                 self._positions[slot] = len(req.prompt_tokens)
                 self._mrope_delta[slot] = 0
                 self._last_token[slot] = first_token
@@ -2733,9 +2762,6 @@ class Engine:
         slot = st["slot"]
         self._chunking = None
         req.first_token_time = time.monotonic()
-        self.recent_ttfts.append(
-            req.first_token_time - req.submit_time
-        )
         self._positions[slot] = len(req.prompt_tokens)
         self._mrope_delta[slot] = req.mrope_delta
         self._slot_keys[slot] = _host_split(st["key"])[0]
@@ -2782,7 +2808,7 @@ class Engine:
         t0 = time.monotonic()
         plan, rem, end = self._chunk_plan(st)
         token, _, _, _, drops = self._ragged_step(
-            plan=plan, draft_len=self._inert_rows, n_extra=0,
+            "chunk", plan=plan, draft_len=self._inert_rows, n_extra=0,
         )
         self._note_moe_drops(drops)
         self.num_prefill_tokens += rem
@@ -2826,7 +2852,7 @@ class Engine:
         t0 = time.monotonic()
         plan, rem, end = self._chunk_plan(st)
         token, sampled, _, _, drops = self._ragged_step(
-            plan=plan, draft_len=self._zero_rows, n_extra=0,
+            "mixed", plan=plan, draft_len=self._zero_rows, n_extra=0,
         )
         self.num_mixed_steps += 1
         self.num_decode_device_steps += 1
@@ -2856,11 +2882,11 @@ class Engine:
             # chunk-final token folded into the step's ONE device_get
             # (previously its own np.asarray fetch — a second host
             # round trip on every long-prompt completion step)
-            fetched = jax.device_get((sampled, token) + firsts)
+            fetched = self._fetch((sampled, token) + firsts)
             next_np, tok_np = fetched[0], fetched[1]
             first_np = fetched[2:]
         else:
-            fetched = jax.device_get((sampled,) + firsts)
+            fetched = self._fetch((sampled,) + firsts)
             next_np, tok_np = fetched[0], None
             first_np = fetched[1:]
         if p.pending_first:
@@ -4134,7 +4160,7 @@ class Engine:
         ]
         n_extra = self._spec_extra_steps()
         _, sampled, emit, extra, _ = self._ragged_step(
-            drafts=drafts, draft_len=draft_len, n_extra=n_extra,
+            "spec", drafts=drafts, draft_len=draft_len, n_extra=n_extra,
         )
         self.num_spec_steps += 1
         # ONE device call for verify + the fused-window tail: with
@@ -4150,7 +4176,7 @@ class Engine:
     def _spec_complete(self, p: PendingStep, emitted) -> None:
         sampled, emit, extra = p.handles
         firsts = tuple(tok for _r, tok in p.pending_first)
-        fetched = jax.device_get((sampled, emit, extra) + firsts)
+        fetched = self._fetch((sampled, emit, extra) + firsts)
         sampled_np, emit_np, extra_np = fetched[0], fetched[1], fetched[2]
         if p.pending_first:
             for (req, _h), tok_np in zip(p.pending_first, fetched[3:]):
@@ -4208,7 +4234,7 @@ class Engine:
         # of each active row samples this step's token, and the fused
         # tail advances the remaining n-1 window steps in the same jit
         _, sampled, _, extra, _ = self._ragged_step(
-            draft_len=self._zero_rows, n_extra=n - 1,
+            "decode", draft_len=self._zero_rows, n_extra=n - 1,
         )
         self.num_decode_device_steps += n
         # Predicted-state advance: the DEVICE moves every dispatched row
@@ -4228,7 +4254,7 @@ class Engine:
     def _decode_complete(self, p: PendingStep, emitted) -> None:
         sampled, extra = p.handles
         firsts = tuple(tok for _r, tok in p.pending_first)
-        fetched = jax.device_get((sampled, extra) + firsts)
+        fetched = self._fetch((sampled, extra) + firsts)
         sampled_np, extra_np = fetched[0], fetched[1]
         if p.pending_first:
             # deferred chunk-final first tokens land in the SAME host
@@ -4275,14 +4301,15 @@ class Engine:
         module-level registry — the shape-zoo collapse, observable."""
         return ragged_meta.compiled_step_shapes(self._shape_key)
 
-    def _ragged_step(self, plan=None, drafts=None, draft_len=None,
-                     n_extra: int = 0):
+    def _ragged_step(self, kind: str, plan=None, drafts=None,
+                     draft_len=None, n_extra: int = 0):
         """Issue ONE unified device step: the optional prefill plan's
         ragged rows + the decode-state segment (+ a fused plain-decode
         tail of ``n_extra`` steps).  Every device-step caller routes
         here; the compiled entry point is keyed only on the prefill
         token-bucket (plus the has-history / row-capacity variants the
-        plan implies).  Returns ``(p_first, sampled, emit, extra,
+        plan implies).  ``kind`` names the caller on the launch's
+        profiler span.  Returns ``(p_first, sampled, emit, extra,
         drops)`` device handles."""
         if self._tiered:
             # tiered rows: demote pages behind the hot tail, then grow
@@ -4359,12 +4386,20 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
-        (self.cache, self._dstate, p_first, sampled, emit, extra,
-         drops) = fn(
-            self._graft_params(), self.cache, self._dstate, pargs,
-            jnp.asarray(drafts), jnp.asarray(draft_len),
-            jnp.int32(n_extra), cold_arg,
-        )
+        used = plan.used if rows else 0
+        with obs_trace.phase(
+            "helix.loop.launch", kind=kind, token_bucket=rung,
+            prefill_rows=rows, has_hist=has_hist,
+            live_rows=int(np.count_nonzero(np.asarray(draft_len) >= 0)),
+            n_extra=int(n_extra), prefill_tokens=used,
+            padding_tokens=rung - used,
+        ):
+            (self.cache, self._dstate, p_first, sampled, emit, extra,
+             drops) = fn(
+                self._graft_params(), self.cache, self._dstate, pargs,
+                jnp.asarray(drafts), jnp.asarray(draft_len),
+                jnp.int32(n_extra), cold_arg,
+            )
         return p_first, sampled, emit, extra, drops
 
     # ------------------------------------------------------------------

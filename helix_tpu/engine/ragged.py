@@ -87,6 +87,24 @@ def note_step_shape(model_key, shape: tuple) -> None:
         _SHAPES.setdefault(model_key, set()).add(shape)
 
 
+def step_program_name(token_bucket: int, has_hist: bool, prefill_rows: int,
+                      ring_hist_pages: int = 0, cold_chunks: int = 0) -> str:
+    """The unified step's function name for one compiled shape; a
+    profiler trace calls the program ``jit_<name>``.  Every name starts
+    with ``step_fn`` (what reads a trace matches ``^jit_step_fn``), and
+    ``step_fn_t0`` is the decode-only program: a trace tells a chunk
+    that continues a long prompt (``step_fn_t512_r1_h``) from a decode
+    window."""
+    name = f"step_fn_t{token_bucket}"
+    if token_bucket:
+        name += f"_r{prefill_rows}" + ("_h" if has_hist else "")
+    if ring_hist_pages:
+        name += f"_g{ring_hist_pages}"
+    if cold_chunks:
+        name += f"_c{cold_chunks}"
+    return name
+
+
 def compiled_step_shapes(model_key) -> int:
     with _SHAPES_LOCK:
         return len(_SHAPES.get(model_key, ()))

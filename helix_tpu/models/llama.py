@@ -231,22 +231,29 @@ def _layer(
     p = layer_params
 
     # --- attention ---
-    x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
-    q = _dense(x, p["wq"], adapter_ids).reshape(B, S, H, D)
-    k = _dense(x, p["wk"], adapter_ids).reshape(B, S, KVH, D)
-    v = _dense(x, p["wv"], adapter_ids).reshape(B, S, KVH, D)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
-        k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    res = attn_fn(q, k, v, layer_cache, positions)
+    # the named scopes are what a profiler trace calls these operations,
+    # whatever number the compiler gives their fusions
+    with jax.named_scope("attn.qkv"):
+        x = rms_norm(
+            h, p["attn_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset
+        )
+        q = _dense(x, p["wq"], adapter_ids).reshape(B, S, H, D)
+        k = _dense(x, p["wk"], adapter_ids).reshape(B, S, KVH, D)
+        v = _dense(x, p["wv"], adapter_ids).reshape(B, S, KVH, D)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+    with jax.named_scope("attn.kernel"):
+        res = attn_fn(q, k, v, layer_cache, positions)
     new_cache = None
     if isinstance(res, tuple):
         attn_out, new_cache = res
     else:
         attn_out = res
-    h = h + _dense(attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
+    with jax.named_scope("attn.out"):
+        h = h + _dense(attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
 
     # --- mlp ---
     x = rms_norm(h, p["mlp_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -269,9 +276,11 @@ def _layer(
         )
         h = h + moe_out
     else:
-        gate = _dense(x, p["w_gate"], adapter_ids)
-        up = _dense(x, p["w_up"], adapter_ids)
-        h = h + _dense(act(gate) * up, p["w_down"], adapter_ids)
+        with jax.named_scope("mlp.gate_up"):
+            gate = _dense(x, p["w_gate"], adapter_ids)
+            up = _dense(x, p["w_up"], adapter_ids)
+        with jax.named_scope("mlp.down"):
+            h = h + _dense(act(gate) * up, p["w_down"], adapter_ids)
     return h, (k, v), new_cache, moe_dropped
 
 
@@ -383,15 +392,19 @@ def forward(
         w_out = params["lm_head"]["weight"]
         out_scale = params["lm_head"].get("scale")
         out_scale = None if out_scale is None else out_scale.reshape(-1)
-    if w_out.dtype == jnp.int8:
-        w_out = w_out.astype(h.dtype)
-    logits = jax.lax.dot_general(
-        h, w_out, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if out_scale is not None:
-        logits = logits * out_scale[None, None, :]
-    if cfg.logits_soft_cap:
-        logits = cfg.logits_soft_cap * jnp.tanh(logits / cfg.logits_soft_cap)
+    with jax.named_scope("lm_head"):
+        if w_out.dtype == jnp.int8:
+            w_out = w_out.astype(h.dtype)
+        logits = jax.lax.dot_general(
+            h, w_out, (((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if out_scale is not None:
+            logits = logits * out_scale[None, None, :]
+        if cfg.logits_soft_cap:
+            logits = cfg.logits_soft_cap * jnp.tanh(
+                logits / cfg.logits_soft_cap
+            )
     if return_moe_stats:
         return logits, kv, {"dropped": moe_dropped}
     return logits, kv

@@ -235,6 +235,25 @@ class FlightRecorder:
         )
         return num / den if den > 0 else 0.0
 
+    def idle_share(self, recent: int = 256) -> float:
+        """Summed ``idle_gap_s`` of the last ``recent`` timed step
+        records over the time they span, from the first one's start
+        (``t_mono - wall_s``) to the last one's end.  Every gap ends
+        inside its own record and starts after the record before it, so
+        but for the first, which is cut at its record's start, the gaps
+        lie inside the span and do not overlap: the share cannot pass 1,
+        however sparse the steps."""
+        with self._lock:
+            recs = [r for r in list(self._ring)[-recent:] if "wall_s" in r]
+        if not recs:
+            return 0.0
+        start = recs[0]["t_mono"] - recs[0]["wall_s"]
+        span = recs[-1]["t_mono"] - start
+        idle = min(recs[0]["idle_gap_s"], recs[0]["wall_s"]) + sum(
+            r["idle_gap_s"] for r in recs[1:]
+        )
+        return idle / span if span > 0 else 0.0
+
     def snapshot(self, recent: int = 64) -> dict:
         with self._lock:
             return {

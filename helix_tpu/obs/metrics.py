@@ -449,10 +449,76 @@ class EngineLoopObs:
             "accounting) per step batch",
             buckets=FAST_BUCKETS,
         )
+        # engine-step phases (ISSUE 25): each is the span of the same
+        # name on the profiler's clock (obs.trace.phase) and is observed
+        # once an engine step, 0 where the phase did not run, so the
+        # phase means add up to the mean of helix_engine_step_seconds.
+        # host_build above times admit + prefill_sync + dispatch from
+        # outside; device_wait_s in the flight record, fetch + reconcile.
+        self.step_phases = {
+            "helix.loop.admit": Histogram(
+                "helix_step_admit_seconds",
+                "Admission per engine step: claim, page allocation, plan "
+                "packing and the prefill launch, less the first-token "
+                "fetch",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.prefill_sync": Histogram(
+                "helix_step_prefill_sync_seconds",
+                "Blocked on the admission wave's prefill program (its "
+                "first-token fetch) per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.dispatch": Histogram(
+                "helix_step_dispatch_seconds",
+                "Step dispatch per engine step: metadata build, upload "
+                "and the jitted call",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.fetch": Histogram(
+                "helix_step_fetch_seconds",
+                "Blocked on the device in the step's one device_get per "
+                "engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.reconcile": Histogram(
+                "helix_step_reconcile_seconds",
+                "Host effects after the fetch (emits, stop conditions, "
+                "slot frees, page adoption) per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+        }
+        # request stages (ISSUE 25), beside queue_wait: handler entry to
+        # first SSE chunk in five consecutive pieces, each also a span in
+        # the request's trace
+        self.http_pre_submit = Histogram(
+            "helix_http_pre_submit_seconds",
+            "Chat handler entry to loop.submit (parse, template, "
+            "tokenize)",
+            buckets=FAST_BUCKETS,
+        )
+        self.admit_to_first_token = Histogram(
+            "helix_admit_to_first_token_seconds",
+            "Slot admission to the engine holding the first token",
+        )
+        self.first_token_hold = Histogram(
+            "helix_first_token_hold_seconds",
+            "The engine holding the first token to its emission (the "
+            "decode window it travels through)",
+            buckets=FAST_BUCKETS,
+        )
+        self.http_first_write = Histogram(
+            "helix_http_first_write_seconds",
+            "First token's emission to the first SSE chunk written",
+            buckets=FAST_BUCKETS,
+        )
 
     def collect(self, c: Collector, labels: Optional[dict] = None) -> None:
         for m in (
             self.queue_wait, self.ttft, self.inter_token,
             self.step_seconds, self.host_build, self.emit_seconds,
+            *self.step_phases.values(),
+            self.http_pre_submit, self.admit_to_first_token,
+            self.first_token_hold, self.http_first_write,
         ):
             c.metric(m, labels)

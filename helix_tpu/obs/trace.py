@@ -563,6 +563,90 @@ class TraceFederation:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+# -- step phases and request stages on the profiler's clock (ISSUE 25) ----
+
+
+class Phases(dict):
+    """One engine step's phase seconds, ``{span name: self seconds}``.
+    ``open`` is the innermost phase still running, so a phase that closes
+    inside another is taken out of the outer one and the values add up to
+    the time the step spent inside any phase."""
+
+    __slots__ = ("open",)
+
+    def __init__(self):
+        super().__init__()
+        self.open = None
+
+
+_annotations = None
+
+
+class phase:
+    """``with phase(name, hist=None, into=None, **attrs)``: one named
+    span with three sinks.  (a) A ``jax.profiler.TraceAnnotation`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is among ``attrs``), which
+    records only while a profiler capture is active and is then on the
+    profiler's clock by construction.  (b) ``into[name]`` gains the
+    elapsed seconds less the phases nested inside this one (``into`` is
+    the current step's :class:`Phases`, which lands in the flight record
+    as ``phases``).  (c) ``hist`` observes the elapsed seconds, which
+    ``seconds`` holds once the block has ended.
+
+    JAX is imported on first use: the control plane imports ``obs`` and
+    never opens a phase."""
+
+    __slots__ = ("name", "hist", "into", "seconds", "_ann", "_t0",
+                 "_parent", "_child")
+
+    def __init__(self, name: str, hist=None, into: Optional[Phases] = None,
+                 **attrs):
+        global _annotations
+        if _annotations is None:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+            _annotations = (TraceAnnotation, StepTraceAnnotation)
+        self.name = name
+        self.hist = hist
+        self.into = into
+        self._ann = _annotations["step_num" in attrs](name, **attrs)
+        self._child = 0.0
+
+    def __enter__(self):
+        into = self.into
+        if into is not None:
+            self._parent = into.open
+            into.open = self
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        dt = self.seconds = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        into = self.into
+        if into is not None:
+            into[self.name] = into.get(self.name, 0.0) + dt - self._child
+            parent = into.open = self._parent
+            if parent is not None:
+                parent._child += dt
+        if self.hist is not None:
+            self.hist.observe(dt)
+        return False
+
+
+def clock_stamp() -> int:
+    """A ``helix.clock`` event in a running capture whose attribute is
+    ``time.monotonic_ns()`` at that instant: flight records and request
+    spans are on the monotonic clock, and the two stamps of a capture
+    (its start and stop) lay them onto the profiler's.  Returns the
+    stamp."""
+    now = time.monotonic_ns()
+    with phase("helix.clock", monotonic_ns=now):
+        pass
+    return now
+
+
 # -- metric minting (lint_metrics contract 13) ------------------------
 #
 # Every helix_trace_* / helix_cp_trace* series is minted HERE and only

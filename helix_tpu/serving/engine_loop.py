@@ -57,6 +57,11 @@ SHUTTING_DOWN = "shutting_down"
 KV_EXHAUSTED = "kv_exhausted"
 
 
+def _rounded(phases: dict) -> dict:
+    """A step's phase seconds as the flight record files them."""
+    return {name: round(sec, 6) for name, sec in phases.items()}
+
+
 @dataclasses.dataclass
 class TokenEvent:
     request_id: str
@@ -76,7 +81,9 @@ class _EmissionStage:
     full queue blocks the engine thread, so the pipeline never runs more
     than ``depth`` batches ahead of the slowest subscriber).  When not
     started (synchronous loop), ``push`` degrades to a direct call on
-    the caller's thread — exactly the pre-pipeline behaviour."""
+    the caller's thread — exactly the pre-pipeline behaviour — and the
+    caller times it (``EngineLoop._push_emit``); the worker times its own
+    deliveries.  Either way the time is the span ``helix.loop.emit``."""
 
     def __init__(self, sink: Callable, obs_hist, depth: int = 8):
         self._sink = sink
@@ -97,9 +104,7 @@ class _EmissionStage:
         if not emitted:
             return
         if not self.started:
-            t0 = time.monotonic()
             self._sink(emitted)
-            self._obs.observe(time.monotonic() - t0)
             return
         self._q.put(emitted)   # blocks when full: bounded backpressure
         self.batches += 1
@@ -129,12 +134,11 @@ class _EmissionStage:
             try:
                 if batch is None:
                     return
-                t0 = time.monotonic()
-                try:
-                    self._sink(batch)
-                except Exception:  # noqa: BLE001 — a subscriber bug must not kill emission
-                    log.exception("emission stage sink failed")
-                self._obs.observe(time.monotonic() - t0)
+                with obs_trace.phase("helix.loop.emit", hist=self._obs):
+                    try:
+                        self._sink(batch)
+                    except Exception:  # noqa: BLE001 — a subscriber bug must not kill emission
+                        log.exception("emission stage sink failed")
             finally:
                 self._q.task_done()
 
@@ -256,6 +260,7 @@ class EngineLoop:
         self._emit_stage = _EmissionStage(
             self._deliver, self.obs.emit_seconds
         )
+        self._phases = obs_trace.Phases()   # the step in progress
         # host-side device-busy watermark: the last completion's return
         # time.  A dispatch that happens with nothing in flight charges
         # the gap since this watermark as device idle (idle_gap_s).
@@ -832,14 +837,15 @@ class EngineLoop:
         }
 
     def device_idle_ratio(self) -> float:
-        """Fraction of recent serving wall time the device had NOTHING
-        dispatched (flight-window ``idle_gap_s`` / ``wall_s``) — the
-        async loop's headline gauge.  Host-side approximation: a gap is
-        charged from the previous completion's return to the next
-        dispatch whenever no step was in flight in between (pipelined
-        dispatches therefore charge zero), so it understates idle only
-        when a fetch returned after the device actually finished."""
-        return self.flight.window_ratio("idle_gap_s", ("wall_s",))
+        """A host-side estimate of the share of recent serving time the
+        device had NOTHING dispatched: the flight window's summed
+        ``idle_gap_s`` over the time its records span.  A gap is charged
+        from the previous completion's return to the next dispatch
+        whenever no step was in flight in between (pipelined dispatches
+        therefore charge zero), so it understates idle when a fetch
+        returned after the device actually finished.  The device's idle
+        share is read from a trace (``POST /admin/profiler``)."""
+        return self.flight.idle_share()
 
     def tokens_per_sec(self) -> float:
         """Goodput: generated tokens/s over the trailing rate window."""
@@ -999,8 +1005,15 @@ class EngineLoop:
         last = self._last_emit.get(rid)
         if rid not in self._first_emit:
             self._first_emit[rid] = now
+            # what the HTTP handler measures http.first_write from
+            req.first_emit_time = now
             admitted = req.admitted_time or now
+            # the engine has the token / the token is emitted: apart by
+            # the decode window the first token travels through
+            have = min(max(req.first_token_time or now, admitted), now)
             self.obs.queue_wait.observe(max(0.0, admitted - req.submit_time))
+            self.obs.admit_to_first_token.observe(have - admitted)
+            self.obs.first_token_hold.observe(now - have)
             self.obs.ttft.observe(max(0.0, now - req.submit_time))
             self.slo.note_first_token(
                 tenant,
@@ -1018,6 +1031,14 @@ class EngineLoop:
                     plane="engine", request_id=rid,
                     prompt_tokens=len(req.prompt_tokens),
                     cached_tokens=req.cached_tokens,
+                )
+                self._trace.record(
+                    req.trace_id, "admit_to_token", admitted, have,
+                    plane="engine", request_id=rid,
+                )
+                self._trace.record(
+                    req.trace_id, "first_token_hold", have, now,
+                    plane="engine", request_id=rid,
                 )
         elif last is not None:
             self.obs.inter_token.observe(max(0.0, now - last))
@@ -1295,7 +1316,7 @@ class EngineLoop:
         """The step-failure ladder (shared by the sync and async paths):
         record, retry once on the exact same state, then quarantine."""
         self._emit_stage.flush()
-        self.obs.step_seconds.observe(dt_step)
+        self._observe_step(dt_step, self._phases)
         self._flight_record(
             dt_step, flight_pre, generated=0, failed=str(e)
         )
@@ -1320,6 +1341,38 @@ class EngineLoop:
         traceback.print_exc()
         self._quarantine(e)
         self._consec_failures = 0
+
+    # -- step phases (obs.trace.phase) ---------------------------------------
+
+    def _new_phases(self) -> obs_trace.Phases:
+        """The phase seconds of the step that starts now.  The engine
+        owns the dict its own phases write to (a wrapper's attribute
+        passthrough reaches it); an engine without one leaves the loop's
+        phases alone in a dict of the loop's."""
+        ph = getattr(self.engine, "step_phases", None)
+        if ph is None:
+            ph = obs_trace.Phases()
+        ph.clear()
+        self._phases = ph
+        return ph
+
+    def _push_emit(self, emitted, ph: obs_trace.Phases) -> float:
+        """Hand one step's tokens to the emission stage, as the span
+        ``helix.loop.emit``; returns the seconds it took.  With the
+        synchronous loop that is the delivery itself and the histogram is
+        fed here; the async loop's worker thread feeds it per batch."""
+        hist = None if self._emit_stage.started else self.obs.emit_seconds
+        with obs_trace.phase("helix.loop.emit", hist=hist, into=ph) as span:
+            self._emit_stage.push(self._snapshot_events(emitted))
+        return span.seconds
+
+    def _observe_step(self, seconds: float, ph: obs_trace.Phases) -> None:
+        """One observation a step of the step histogram and of every
+        phase histogram (0 where the phase did not run), so the phase
+        means add up to the step's."""
+        self.obs.step_seconds.observe(seconds)
+        for name, hist in self.obs.step_phases.items():
+            hist.observe(ph.get(name, 0.0))
 
     # -- flight recorder (host-side counter deltas only) --------------------
 
@@ -1369,6 +1422,9 @@ class EngineLoop:
         rec = {
             "step": self.steps,
             "ts": time.time(),
+            # the same instant on the clock request spans and the
+            # profiler's helix.clock stamps are on
+            "t_mono": time.monotonic(),
             "duration": duration,
             "kind": kind,
             "slots_busy": sum(1 for s in eng.slots if s is not None),
@@ -1475,6 +1531,7 @@ class EngineLoop:
                 return True
             pend, inflight = inflight, None
             pre = self._flight_pre()
+            ph = self._new_phases()
             t0 = time.monotonic()
             try:
                 emitted = complete(pend)
@@ -1483,9 +1540,7 @@ class EngineLoop:
                 self._handle_step_failure(e, time.monotonic() - t0, pre)
                 return False
             dt_wait = time.monotonic() - t0
-            t_emit = time.monotonic()
-            self._emit_stage.push(self._snapshot_events(emitted))
-            dt_emit = time.monotonic() - t_emit
+            dt_emit = self._push_emit(emitted, ph)
             # the step was dispatched by an earlier pass that skipped
             # its record ("numbers land with its completion"): record
             # it here or the burst's last step vanishes from the flight
@@ -1499,6 +1554,7 @@ class EngineLoop:
                     "idle_gap_s": 0.0,
                     "wall_s": round(time.monotonic() - t0, 6),
                     "pipelined": 1,
+                    "phases": _rounded(ph),
                 },
             )
             self._emit_stage.flush()
@@ -1566,9 +1622,11 @@ class EngineLoop:
                     continue
                 if self.engine.has_work():
                     continue   # the reconcile freed/advanced work
-                self._wake.wait(timeout=0.05)
+                with obs_trace.phase("helix.loop.idle"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
+            sched_ph = obs_trace.Phases()
             if self._sched_active:
                 # scheduler pass (engine thread — the wait queue's
                 # owner): rewrite the queue into dispatch order (strict
@@ -1578,10 +1636,11 @@ class EngineLoop:
                 # queue and burn-rate reads (the sched.reorder contract)
                 # — and a non-empty queue forces the reconcile below
                 # before the dispatch acts on the new order anyway.
-                self.sched.reorder(self.engine.waiting)
-                self.engine.prefill_budget = self.sched.prefill_budget(
-                    self.slo
-                )
+                with obs_trace.phase("helix.sched.reorder", into=sched_ph):
+                    self.sched.reorder(self.engine.waiting)
+                    self.engine.prefill_budget = (
+                        self.sched.prefill_budget(self.slo)
+                    )
             # pipeline gate, decided BEFORE the dispatch: plain
             # fused-decode steady state only — anything else (admission
             # waves, chunked prefill, speculation, parked preemptions,
@@ -1595,118 +1654,122 @@ class EngineLoop:
             if inflight is not None and not can_pipe:
                 if not reconcile_or_fail():
                     continue
-            t_step = time.monotonic()
-            flight_pre = self._flight_pre()
-            overlapped = inflight is not None
-            try:
-                emitted, pend = self._dispatch_once()
-            except Exception as e:  # noqa: BLE001 — fail requests, not the loop
-                # the in-flight step is healthy already-dispatched work:
-                # reconcile it first so its tokens are not lost — and
-                # flight-record it (its fill pass skipped the record on
-                # the promise the completion would land it)
-                if inflight is not None:
-                    prev, inflight = inflight, None
-                    pre_prev = self._flight_pre()
-                    t0_prev = time.monotonic()
-                    try:
+            ph = self._new_phases()
+            ph.update(sched_ph)
+            with obs_trace.phase("helix.loop.step", step_num=self.steps):
+                t_step = time.monotonic()
+                flight_pre = self._flight_pre()
+                overlapped = inflight is not None
+                try:
+                    emitted, pend = self._dispatch_once()
+                except Exception as e:  # noqa: BLE001 — fail requests, not the loop
+                    # the in-flight step is healthy already-dispatched work:
+                    # reconcile it first so its tokens are not lost — and
+                    # flight-record it (its fill pass skipped the record on
+                    # the promise the completion would land it)
+                    if inflight is not None:
+                        prev, inflight = inflight, None
+                        pre_prev = self._flight_pre()
+                        t0_prev = time.monotonic()
+                        try:
+                            prev_emitted = complete(prev)
+                        except Exception:  # noqa: BLE001 — poisoned chain
+                            self.engine.discard_pending(prev)
+                        else:
+                            self._emit_stage.push(
+                                self._snapshot_events(prev_emitted)
+                            )
+                            dt_prev = time.monotonic() - t0_prev
+                            self._flight_record(
+                                dt_prev, pre_prev,
+                                generated=len(prev_emitted),
+                                timing={
+                                    "host_build_s": 0.0,
+                                    "device_wait_s": round(dt_prev, 6),
+                                    "emit_s": 0.0,
+                                    "idle_gap_s": 0.0,
+                                    "wall_s": round(dt_prev, 6),
+                                    "pipelined": 1,
+                                },
+                            )
+                    self._handle_step_failure(
+                        e, time.monotonic() - t_step, flight_pre
+                    )
+                    continue
+                t_build_end = time.monotonic()
+                dt_build = t_build_end - t_step
+                idle_gap = 0.0
+                if not overlapped and self._device_busy_until:
+                    # nothing was in flight while this step's metadata was
+                    # built: the device sat idle from the last completion's
+                    # return until this dispatch landed
+                    idle_gap = max(
+                        0.0, t_build_end - self._device_busy_until
+                    )
+                prev, inflight = inflight, None
+                dt_wait = 0.0
+                try:
+                    if prev is not None:
+                        # step N+1 is now queued on the device: fetch step
+                        # N's results — the block covers only the device
+                        # time the host build did not already overlap
+                        t_w = time.monotonic()
                         prev_emitted = complete(prev)
-                    except Exception:  # noqa: BLE001 — poisoned chain
-                        self.engine.discard_pending(prev)
-                    else:
-                        self._emit_stage.push(
-                            self._snapshot_events(prev_emitted)
-                        )
-                        dt_prev = time.monotonic() - t0_prev
-                        self._flight_record(
-                            dt_prev, pre_prev,
-                            generated=len(prev_emitted),
-                            timing={
-                                "host_build_s": 0.0,
-                                "device_wait_s": round(dt_prev, 6),
-                                "emit_s": 0.0,
-                                "idle_gap_s": 0.0,
-                                "wall_s": round(dt_prev, 6),
-                                "pipelined": 1,
-                            },
-                        )
-                self._handle_step_failure(
-                    e, time.monotonic() - t_step, flight_pre
+                        prev = None
+                        dt_wait += time.monotonic() - t_w
+                        emitted = prev_emitted + emitted
+                    if pend is not None and can_pipe and pend.kind == "decode":
+                        inflight, pend = pend, None
+                        self.pipelined_steps += 1
+                    elif pend is not None:
+                        t_w = time.monotonic()
+                        if hasattr(self.engine, "prefetch_cold"):
+                            # stage the NEXT step's cold-middle KV chunks
+                            # while the dispatched step still runs on the
+                            # device — the gathers queue behind the step on
+                            # the device stream, so this is free overlap
+                            self.engine.prefetch_cold()
+                        self.engine.step_complete(pend, emitted)
+                        pend = None
+                        dt_wait += time.monotonic() - t_w
+                        self._device_busy_until = time.monotonic()
+                except Exception as e:  # noqa: BLE001 — fail requests, not the loop
+                    for p in (prev, pend):
+                        if p is not None:
+                            self.engine.discard_pending(p)
+                    inflight = None
+                    self._handle_step_failure(
+                        e, time.monotonic() - t_step, flight_pre
+                    )
+                    continue
+                dt_step = time.monotonic() - t_step
+                self.obs.host_build.observe(dt_build)
+                self._consec_failures = 0
+                self._barren_rounds = 0
+                self.steps += 1
+                if inflight is not None and not emitted:
+                    # pipeline-fill pass: dispatched with nothing reconciled
+                    # yet — no flight record (a dispatch-only pass would read
+                    # as zero_progress to the watchdog); the step's numbers
+                    # land with its completion next pass
+                    self._observe_step(dt_step, ph)
+                    continue
+                dt_emit = self._push_emit(emitted, ph)
+                self._deliver_resume_failures()
+                wall = time.monotonic() - t_step
+                self._observe_step(wall, ph)
+                self._flight_record(
+                    dt_step, flight_pre, generated=len(emitted),
+                    timing={
+                        "host_build_s": round(dt_build, 6),
+                        "device_wait_s": round(dt_wait, 6),
+                        "emit_s": round(dt_emit, 6),
+                        "idle_gap_s": round(idle_gap, 6),
+                        "wall_s": round(wall, 6),
+                        "pipelined": 1 if overlapped else 0,
+                        "phases": _rounded(ph),
+                    },
                 )
-                continue
-            t_build_end = time.monotonic()
-            dt_build = t_build_end - t_step
-            idle_gap = 0.0
-            if not overlapped and self._device_busy_until:
-                # nothing was in flight while this step's metadata was
-                # built: the device sat idle from the last completion's
-                # return until this dispatch landed
-                idle_gap = max(
-                    0.0, t_build_end - self._device_busy_until
-                )
-            prev, inflight = inflight, None
-            dt_wait = 0.0
-            try:
-                if prev is not None:
-                    # step N+1 is now queued on the device: fetch step
-                    # N's results — the block covers only the device
-                    # time the host build did not already overlap
-                    t_w = time.monotonic()
-                    prev_emitted = complete(prev)
-                    prev = None
-                    dt_wait += time.monotonic() - t_w
-                    emitted = prev_emitted + emitted
-                if pend is not None and can_pipe and pend.kind == "decode":
-                    inflight, pend = pend, None
-                    self.pipelined_steps += 1
-                elif pend is not None:
-                    t_w = time.monotonic()
-                    if hasattr(self.engine, "prefetch_cold"):
-                        # stage the NEXT step's cold-middle KV chunks
-                        # while the dispatched step still runs on the
-                        # device — the gathers queue behind the step on
-                        # the device stream, so this is free overlap
-                        self.engine.prefetch_cold()
-                    self.engine.step_complete(pend, emitted)
-                    pend = None
-                    dt_wait += time.monotonic() - t_w
-                    self._device_busy_until = time.monotonic()
-            except Exception as e:  # noqa: BLE001 — fail requests, not the loop
-                for p in (prev, pend):
-                    if p is not None:
-                        self.engine.discard_pending(p)
-                inflight = None
-                self._handle_step_failure(
-                    e, time.monotonic() - t_step, flight_pre
-                )
-                continue
-            dt_step = time.monotonic() - t_step
-            self.obs.step_seconds.observe(dt_step)
-            self.obs.host_build.observe(dt_build)
-            self._consec_failures = 0
-            self._barren_rounds = 0
-            self.steps += 1
-            if inflight is not None and not emitted:
-                # pipeline-fill pass: dispatched with nothing reconciled
-                # yet — no flight record (a dispatch-only pass would read
-                # as zero_progress to the watchdog); the step's numbers
-                # land with its completion next pass
-                continue
-            t_emit = time.monotonic()
-            self._emit_stage.push(self._snapshot_events(emitted))
-            dt_emit = time.monotonic() - t_emit
-            self._deliver_resume_failures()
-            self._flight_record(
-                dt_step, flight_pre, generated=len(emitted),
-                timing={
-                    "host_build_s": round(dt_build, 6),
-                    "device_wait_s": round(dt_wait, 6),
-                    "emit_s": round(dt_emit, 6),
-                    "idle_gap_s": round(idle_gap, 6),
-                    "wall_s": round(time.monotonic() - t_step, 6),
-                    "pipelined": 1 if overlapped else 0,
-                },
-            )
         # a step still in flight at shutdown: reconcile so its tokens
         # reach subscribers before the terminal sweep
         if inflight is not None:

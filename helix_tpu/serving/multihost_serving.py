@@ -716,7 +716,7 @@ class PlanLeader:
     Duck-types the Engine surface EngineLoop uses (add_request / abort /
     step / step_dispatch / step_complete / pipeline_ready /
     discard_pending / has_work / validate_request / reap_stuck / slots /
-    waiting / recent_ttfts ...).  Unlike the old command-replay journal
+    waiting ...).  Unlike the old command-replay journal
     it does NOT disable anything: preemption, spec decode, adapters,
     WFQ, the async pipeline, filestore prefix hits, and drain-time
     snapshot export all run on the leader and replicate as plan data.

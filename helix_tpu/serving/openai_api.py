@@ -39,6 +39,7 @@ from helix_tpu.serving.sched import CLASS_HEADER, sanitize_class
 from helix_tpu.obs.trace import (
     TRACE_HEADER,
     adopt_trace_id,
+    clock_stamp,
     collect_trace_metrics,
     is_trace_id,
 )
@@ -415,9 +416,10 @@ class OpenAIServer:
             )
             # asynchronous pipelined loop (ISSUE 13): how often the loop
             # dispatched step N+1 while step N was still executing, and
-            # the flight-window fraction of serving time the device had
-            # nothing dispatched (the pipeline's headline gauge — the
-            # sync loop's build+emit shadow shows up here)
+            # a host-side estimate of the share of serving time the
+            # device had nothing dispatched (the sync loop's build+emit
+            # shadow shows up here); the device's idle share is read
+            # from a trace
             c.counter(
                 "helix_pipelined_steps_total",
                 getattr(m.loop, "pipelined_steps", 0), lbl,
@@ -426,6 +428,9 @@ class OpenAIServer:
                 c.gauge(
                     "helix_device_idle_ratio",
                     round(m.loop.device_idle_ratio(), 4), lbl,
+                    help="Flight-window idle gaps over the time the "
+                         "window spans: a host-side estimate; the "
+                         "device's idle share is read from a trace",
                 )
             # latency histograms (TTFT / queue wait / inter-token / step
             # duration) observed by the engine loop itself
@@ -483,27 +488,6 @@ class OpenAIServer:
                     "helix_prefix_cache_evicted_pages_total",
                     st.get("evicted_pages", 0), lbl,
                 )
-            ttfts = getattr(eng, "recent_ttfts", None)
-            if ttfts:
-                # rolling-window percentiles kept for dashboard
-                # continuity (the histogram is the durable surface).
-                # The engine thread appends concurrently; a mutation
-                # during iteration raises — retry on a fresh snapshot
-                s = []
-                for _ in range(3):
-                    try:
-                        s = sorted(ttfts)
-                        break
-                    except RuntimeError:
-                        continue
-                if s:
-                    c.gauge(
-                        "helix_ttft_p50_seconds", s[len(s) // 2], lbl
-                    )
-                    c.gauge(
-                        "helix_ttft_p95_seconds",
-                        s[min(len(s) - 1, int(len(s) * 0.95))], lbl,
-                    )
         mgr = self._residency_manager()
         if mgr is not None:
             st = mgr.stats()
@@ -1035,7 +1019,13 @@ class OpenAIServer:
         POST {"seconds": 2} starts a device+host trace and returns the
         directory to feed TensorBoard/XProf.  One capture at a time; the
         capture runs in an executor so serving traffic keeps flowing
-        while it records.
+        while it records.  The host side of the capture is the program's
+        own ``helix.*`` spans (``obs.trace.phase``); the Python tracer,
+        which slows the server it measures by a tenth, is off unless the
+        body says ``"python_tracer": true``.  The capture's first and
+        last event is a ``helix.clock`` stamp (``time.monotonic_ns()``),
+        returned as ``clock_ns``: it lays flight records and request
+        spans, which are on the monotonic clock, onto the trace.
 
         Trust model: captures are expensive (real serving-latency cost)
         and write to disk, so when ``HELIX_RUNNER_TOKEN`` is set the
@@ -1058,6 +1048,9 @@ class OpenAIServer:
             seconds = min(max(float(body.get("seconds", 2.0)), 0.01), 60.0)
         except (TypeError, ValueError):
             return _error(400, "'seconds' must be a number")
+        python_tracer = body.get("python_tracer", False)
+        if not isinstance(python_tracer, bool):
+            return _error(400, "'python_tracer' must be true or false")
         if not self._profiler_lock.acquire(blocking=False):
             return _error(
                 409, "a profiler capture is already running",
@@ -1075,12 +1068,17 @@ class OpenAIServer:
 
                 base = os.environ.get("HELIX_PROFILER_DIR") or None
                 d = tempfile.mkdtemp(prefix="helix-jax-profile-", dir=base)
-                jax.profiler.start_trace(d)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = int(python_tracer)
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(d, profiler_options=options)
                 try:
+                    stamps = [clock_stamp()]
                     time.sleep(seconds)
+                    stamps.append(clock_stamp())
                 finally:
                     jax.profiler.stop_trace()
-                return d
+                return d, stamps
             finally:
                 self._profiler_lock.release()
 
@@ -1090,12 +1088,15 @@ class OpenAIServer:
             self._profiler_lock.release()
             raise
         try:
-            d = await fut
+            d, stamps = await fut
         except asyncio.CancelledError:
             raise   # capture thread finishes + releases on its own
         except Exception as e:  # noqa: BLE001 — profiler not available
             return _error(501, f"jax profiler capture failed: {e}")
-        return web.json_response({"log_dir": d, "seconds": seconds})
+        return web.json_response({
+            "log_dir": d, "seconds": seconds,
+            "python_tracer": python_tracer, "clock_ns": stamps,
+        })
 
     def _residency_manager(self):
         """The ResidencyManager behind the registry, if hot-swap is on."""
@@ -1402,14 +1403,28 @@ class OpenAIServer:
             seed=body.get("seed"),
         )
 
+    def _request_stage(self, served, trace_id: str, name: str,
+                       hist: str, start: float, end: float) -> None:
+        """One HTTP-side stage of a request's way to its first token: a
+        span in the request's trace and an observation of the engine
+        loop's histogram ``hist`` (beside ``helix_queue_wait_seconds``,
+        so it carries the model's label)."""
+        histogram = getattr(getattr(served.loop, "obs", None), hist, None)
+        if histogram is not None:
+            histogram.observe(max(0.0, end - start))
+        self.traces.record(trace_id, name, start, end, plane="runner")
+
     async def _generate(self, served, prompt_ids, sampling, extra=None,
                         trace_id: str = "", tenant: str = ANON_TENANT,
-                        sched_class: str = ""):
+                        sched_class: str = "", stages=None):
         """Submit to the engine; yields (delta_text, token_id, finished,
         finish_reason).  ``extra`` carries multimodal Request fields;
         ``trace_id`` and ``tenant`` ride the Request into engine-level
         spans and the per-tenant accounting; ``sched_class`` is the
-        scheduler priority class ("" = profile default)."""
+        scheduler priority class ("" = profile default).  ``stages``
+        (``{"t_entry": handler entry}``) records ``http.pre_submit`` and
+        receives the Request under ``"req"``, whose ``first_emit_time``
+        the handler's ``http.first_write`` starts from."""
         loop = asyncio.get_running_loop()
         q: asyncio.Queue = asyncio.Queue()
 
@@ -1426,6 +1441,12 @@ class OpenAIServer:
             sched_class=sched_class,
             **(extra or {}),
         )
+        if stages is not None:
+            stages["req"] = req
+            self._request_stage(
+                served, trace_id, "http.pre_submit", "http_pre_submit",
+                stages["t_entry"], req.submit_time,
+            )
         served.loop.submit(req, on_event)
         detok = IncrementalDetokenizer(served.tokenizer)
         emitted_len = 0
@@ -1920,6 +1941,7 @@ class OpenAIServer:
         })
 
     async def chat_completions(self, request):
+        stages = {"t_entry": time.monotonic()}
         try:
             body = await request.json()
         except Exception:
@@ -2040,12 +2062,13 @@ class OpenAIServer:
             try:
               async for delta, tok, finished, reason in self._generate(
                 served, prompt_ids, sampling, extra, trace_id=tid,
-                tenant=tenant, sched_class=sclass,
+                tenant=tenant, sched_class=sclass, stages=stages,
               ):
                 if t_emit is None:
                     t_emit = time.monotonic()
                 ntokens += 1
                 chunk_delta = {}
+                first_write = first
                 if first:
                     chunk_delta["role"] = "assistant"
                     first = False
@@ -2067,6 +2090,11 @@ class OpenAIServer:
                         ],
                     }
                 )
+                if first_write and stages["req"].first_emit_time is not None:
+                    self._request_stage(
+                        served, tid, "http.first_write", "http_first_write",
+                        stages["req"].first_emit_time, time.monotonic(),
+                    )
                 if finished:
                     break
             except EngineRequestError as e:
@@ -2092,7 +2120,7 @@ class OpenAIServer:
         try:
           async for delta, tok, finished, reason in self._generate(
             served, prompt_ids, sampling, extra, trace_id=tid,
-            tenant=tenant, sched_class=sclass,
+            tenant=tenant, sched_class=sclass, stages=stages,
           ):
             if t_emit is None:
                 t_emit = time.monotonic()

@@ -1,0 +1,465 @@
+"""The measurement inside serve-node (ISSUE 25): engine-step phases,
+request stages and step-program kinds as named spans on the profiler's
+clock, each with the counter the benchmark reads.
+
+(a) every ``benchmark/metrics/*.json`` that reads a histogram finds its
+series in ``/metrics`` with only the ``model`` label; (b) every flight
+record carries ``phases`` that add up to its ``wall_s`` and the phase
+histograms count one observation a step; (c) the compiled step programs
+have distinct ``step_fn...`` names and their lowered text carries the
+named scopes; (d) a capture through ``POST /admin/profiler`` holds the
+``helix.loop.*`` spans and both ``helix.clock`` stamps, with the Python
+tracer off by default; (e) a streamed request's five stages are ordered
+and sum to no more than the client's time to first token; (f)
+``device_idle_ratio()`` cannot exceed 1.
+"""
+
+import asyncio
+import glob
+import json
+import os
+import re
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+import requests
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.lib import prom  # noqa: E402
+from helix_tpu.engine import ragged as ragged_meta  # noqa: E402
+from helix_tpu.engine.engine import (  # noqa: E402
+    Engine, EngineConfig, Request, _build_ragged_step_fn,
+)
+from helix_tpu.engine.sampling import SamplingParams  # noqa: E402
+from helix_tpu.models.common import ModelConfig  # noqa: E402
+from helix_tpu.models.llama import init_params  # noqa: E402
+from helix_tpu.serving.engine_loop import EngineLoop  # noqa: E402
+from helix_tpu.serving.openai_api import OpenAIServer  # noqa: E402
+from helix_tpu.serving.registry import ModelRegistry, ServedModel  # noqa: E402
+from helix_tpu.serving.tokenizer import ByteTokenizer  # noqa: E402
+
+PORT = 18471
+MODEL = "tiny-chat"
+LOOP_PHASES = (
+    "helix.loop.admit", "helix.loop.prefill_sync", "helix.loop.dispatch",
+    "helix.loop.fetch", "helix.loop.reconcile", "helix.loop.emit",
+)
+STAGES = ("http.pre_submit", "queue", "admit_to_token", "first_token_hold",
+          "http.first_write")
+SCOPES = ("prefill", "state", "tail", "sample", "attn.qkv", "attn.kernel",
+          "attn.out", "mlp.gate_up", "mlp.down", "lm_head")
+
+
+def histogram_specs():
+    out = []
+    for path in sorted(glob.glob(
+            os.path.join(ROOT, "benchmark", "metrics", "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        if spec["reduction"] == "histogram_mean_ms":
+            out.append(spec)
+    return out
+
+
+def tiny_engine(vocab_size=512, **extra):
+    cfg = ModelConfig.tiny(vocab_size=vocab_size, dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    kw = dict(
+        max_decode_batch=2, page_size=4, num_pages=256,
+        max_pages_per_seq=32, max_prefill_len=16,
+        attn_backend="reference", eos_token_ids=ByteTokenizer().eos_ids,
+    )
+    kw.update(extra)
+    return Engine(cfg, params, EngineConfig(**kw))
+
+
+def stream_chat(url, text, max_tokens=8):
+    """One streamed chat request; returns (trace id, client seconds to
+    the first data line, chunks)."""
+    t0 = time.monotonic()
+    r = requests.post(
+        f"{url}/v1/chat/completions",
+        json={"model": MODEL, "max_tokens": max_tokens, "temperature": 0,
+              "messages": [{"role": "user", "content": text}],
+              "stream": True},
+        stream=True, timeout=120,
+    )
+    assert r.status_code == 200, r.text
+    first, chunks = None, []
+    for line in r.iter_lines():
+        if not line:
+            continue
+        if first is None:
+            first = time.monotonic() - t0
+        payload = line[len(b"data: "):]
+        if payload == b"[DONE]":
+            break
+        chunks.append(json.loads(payload))
+    return r.headers["X-Helix-Trace-Id"], first, chunks
+
+
+@pytest.fixture(scope="module")
+def spine():
+    """A tiny model behind the real HTTP surface, after a few requests
+    (one prompt long enough to prefill in chunks)."""
+    loop = EngineLoop(tiny_engine(), "tiny").start()
+    registry = ModelRegistry()
+    registry.register(ServedModel(
+        name=MODEL, loop=loop, tokenizer=ByteTokenizer(),
+        context_length=128,
+    ))
+    srv = OpenAIServer(registry)   # the process's trace store, as the loop's
+    app = srv.build_app()
+    started = threading.Event()
+    holder = {}
+
+    def run():
+        from aiohttp import web
+
+        aloop = asyncio.new_event_loop()
+        asyncio.set_event_loop(aloop)
+        runner = web.AppRunner(app)
+        aloop.run_until_complete(runner.setup())
+        aloop.run_until_complete(
+            web.TCPSite(runner, "127.0.0.1", PORT).start())
+        holder["loop"] = aloop
+        started.set()
+        aloop.run_forever()
+
+    threading.Thread(target=run, daemon=True).start()
+    assert started.wait(10)
+    url = f"http://127.0.0.1:{PORT}"
+    # the first request compiles; the stages of the later ones are judged
+    stream_chat(url, "warm the shapes " * 3)
+    r = requests.post(
+        f"{url}/v1/chat/completions",
+        json={"model": MODEL, "max_tokens": 4, "temperature": 0,
+              "messages": [{"role": "user", "content": "hi"}]},
+        timeout=120,
+    )
+    assert r.status_code == 200, r.text
+    holder.update(url=url, srv=srv,
+                  streamed=[stream_chat(url, "hello there"),
+                            stream_chat(url, "warm the shapes " * 3)])
+    yield holder
+    holder["loop"].call_soon_threadsafe(holder["loop"].stop)
+    loop.stop(join=False)
+
+
+# ---- (a) the benchmark's histogram series ---------------------------------
+
+
+@pytest.mark.parametrize("spec", histogram_specs(), ids=lambda s: s["name"])
+def test_histogram_metric_finds_its_series(spine, spec):
+    text = requests.get(f"{spine['url']}/metrics", timeout=10).text
+    series = spec["series"]
+    parsed = prom.parse(text, MODEL)
+    assert parsed.get(series + "_count", 0) > 0, series
+    assert series + "_sum" in parsed
+    lines = [ln for ln in text.splitlines()
+             if re.match(rf"{series}_(count|sum)\b", ln)]
+    assert len(lines) == 2
+    for ln in lines:
+        assert re.match(rf'{series}_\w+{{model="{MODEL}"}} ', ln), ln
+    # the reduction itself, over a window that opens before any request
+    zero = {k: 0.0 for k in parsed}
+    assert prom.mean_of_histogram_ms(zero, parsed, series) >= 0.0
+
+
+# ---- (b) flight phases and one observation a step -------------------------
+
+
+@pytest.fixture(scope="module")
+def stepped():
+    """Some twenty steps of a tiny engine under the synchronous loop:
+    three requests, one with a prompt of several chunks."""
+    loop = EngineLoop(tiny_engine(), "phases").start()
+    done = []
+    try:
+        for i, n in enumerate((6, 40, 9)):
+            ev = threading.Event()
+            done.append(ev)
+
+            def on_event(e, ev=ev):
+                if e.finished:
+                    ev.set()
+
+            loop.submit(Request(
+                id=f"p{i}", prompt_tokens=list(range(4, 4 + n)),
+                sampling=SamplingParams(max_tokens=10, temperature=0.0),
+            ), on_event)
+        for ev in done:
+            assert ev.wait(120)
+    finally:
+        loop.stop(join=True)
+    return loop
+
+
+def test_every_flight_record_carries_phases_that_add_up(stepped):
+    recs = stepped.flight.snapshot(recent=512)["recent"]
+    assert len(recs) >= 15
+    for rec in recs:
+        ph = rec["phases"]
+        assert set(ph) <= set(LOOP_PHASES) | {"helix.sched.reorder"}
+        assert all(v >= 0.0 for v in ph.values())
+        inside = sum(v for k, v in ph.items() if k.startswith("helix.loop."))
+        assert abs(inside - rec["wall_s"]) <= max(
+            0.05 * rec["wall_s"], 1e-3), rec
+        assert "t_mono" in rec
+    kinds = {k for rec in recs for k in rec["phases"]}
+    assert set(LOOP_PHASES) <= kinds
+
+
+@pytest.mark.parametrize("name", LOOP_PHASES)
+def test_phase_histogram_counts_one_observation_a_step(stepped, name):
+    hists = dict(stepped.obs.step_phases,
+                 **{"helix.loop.emit": stepped.obs.emit_seconds})
+    assert stepped.steps >= 15
+    assert hists[name].count == stepped.steps
+    assert stepped.obs.step_seconds.count == stepped.steps
+
+
+def test_phase_means_add_up_to_the_step_mean(stepped):
+    hists = list(stepped.obs.step_phases.values()) + [
+        stepped.obs.emit_seconds]
+    total = sum(h.sum for h in hists)
+    step = stepped.obs.step_seconds.sum
+    assert abs(total - step) <= max(0.05 * step, 1e-3 * stepped.steps)
+
+
+# ---- (c) step programs and operations have stable names -------------------
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    # a model of its own: the shape registry is per model, and the other
+    # fixtures' traffic compiles shapes warmup() leaves out
+    eng = tiny_engine(vocab_size=384, decode_steps_per_sync=4)
+    eng.warmup()
+    return eng
+
+
+def test_compiled_step_programs_have_distinct_step_fn_names(warmed):
+    eng = warmed
+    shapes = ragged_meta.step_shape_set(eng._shape_key)
+    names = [ragged_meta.step_program_name(*s[1:]) for s in shapes]
+    assert len(set(names)) == len(names) == eng.compiled_step_shapes
+    assert all(n.startswith("step_fn") for n in names)
+    assert "step_fn_t0" in names
+    assert "step_fn_t16_r1_h" in names     # the continuing chunk's program
+
+
+def test_shape_gauge_reads_what_it_read(warmed):
+    eng = warmed
+    # the ladder's rungs with and without history at full row capacity,
+    # the two single-row chunk shapes, the decode-only program
+    assert eng.compiled_step_shapes == 2 * len(eng._token_ladder) + 3
+
+
+def decode_step_args(eng):
+    eng._sync_state()
+    return (eng._graft_params(), eng.cache, eng._dstate, (),
+            jnp.asarray(eng._zero_drafts), jnp.asarray(eng._zero_rows),
+            jnp.int32(1), None)
+
+
+def built(eng, rung, has_hist, rows):
+    return _build_ragged_step_fn(
+        eng.model_cfg, eng.cache_cfg.page_size, eng._backend, eng.mesh,
+        rung, has_hist, rows, eng._spec_width(), eng._n_tail_max, 0, 0,
+        0, 0,
+    )
+
+
+def test_jitted_step_carries_its_shapes_name(warmed):
+    eng = warmed
+    n = eng.compiled_step_shapes
+    assert built(eng, 0, False, 0).__name__ == "step_fn_t0"
+    assert built(eng, 16, True, 1).__name__ == "step_fn_t16_r1_h"
+    assert eng.compiled_step_shapes == n    # both were compiled by warmup
+
+
+@pytest.fixture(scope="module")
+def lowered_text(warmed):
+    eng = warmed
+    plan_rung = eng._token_ladder[-1]
+    B = eng.cfg.max_decode_batch
+    # a step with a prefill segment: take the arguments warmup() builds
+    from helix_tpu.engine.engine import _host_key
+    from helix_tpu.engine.ragged import PrefillPlan
+    from helix_tpu.engine.sampling import SamplingState
+    import numpy as np
+
+    ps, maxP = eng.cache_cfg.page_size, eng.cache_cfg.max_pages_per_seq
+    plan = PrefillPlan(ps, maxP, B)
+    plan.add(None, np.zeros((maxP,), np.int32), 0, plan_rung,
+             [0] * plan_rung, _host_key(0), SamplingParams())
+    a = plan.finalize_device(plan_rung)
+    sampling = SamplingState.from_params([SamplingParams()] * B)
+    pargs = (a["tokens"], a["pos"], a["seg"], a["pages"], a["offsets"],
+             a["t0"], a["qlen"], a["hist"], a["tables"], a["ends"],
+             sampling, a["keys"])
+    args = list(decode_step_args(eng))
+    args[3] = pargs
+    fn = built(eng, plan_rung, False, B)
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_lowered_step_carries_the_named_scope(lowered_text, scope):
+    assert "jit(step_fn_t16_r2)" in lowered_text
+    assert re.search(rf"[/\"]{re.escape(scope)}[/\"]", lowered_text), scope
+
+
+# ---- (d) a capture through POST /admin/profiler ---------------------------
+
+
+@pytest.fixture(scope="module")
+def capture(spine, tmp_path_factory):
+    """A 0.5 s capture of the live server while a request streams."""
+    seen = []
+    real = jax.profiler.start_trace
+
+    def spy(log_dir, *a, **kw):
+        seen.append(kw.get("profiler_options"))
+        return real(log_dir, *a, **kw)
+
+    os.environ["HELIX_PROFILER_DIR"] = str(tmp_path_factory.mktemp("prof"))
+    jax.profiler.start_trace = spy
+    try:
+        # traffic for as long as the capture runs, however long the
+        # profiler takes to start on a loaded machine
+        done = threading.Event()
+
+        def traffic():
+            while not done.is_set():
+                stream_chat(spine["url"], "during the capture", max_tokens=12)
+                time.sleep(0.06)    # a gap the loop spends in helix.loop.idle
+
+        t = threading.Thread(target=traffic)
+        t.start()
+        try:
+            r = requests.post(
+                f"{spine['url']}/admin/profiler", json={"seconds": 0.5},
+                timeout=300)
+        finally:
+            done.set()
+            t.join(120)
+    finally:
+        jax.profiler.start_trace = real
+        del os.environ["HELIX_PROFILER_DIR"]
+    assert r.status_code == 200, r.text
+    body = r.json()
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(body["log_dir"], "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("helix."):
+                    events.setdefault(ev.name, []).append(dict(ev.stats))
+    return body, seen, events
+
+
+@pytest.mark.parametrize("span", (
+    "helix.loop.step", "helix.loop.admit", "helix.loop.dispatch",
+    "helix.loop.launch", "helix.loop.fetch", "helix.loop.reconcile",
+    "helix.loop.emit", "helix.loop.idle",
+))
+def test_capture_holds_the_loops_spans(capture, span):
+    _, _, events = capture
+    assert events.get(span), sorted(events)
+
+
+def test_capture_launch_span_names_the_program(capture):
+    _, _, events = capture
+    launches = events["helix.loop.launch"]
+    assert {"kind", "token_bucket", "prefill_rows", "has_hist", "live_rows",
+            "n_extra", "prefill_tokens", "padding_tokens"} <= set(launches[0])
+    assert {ln["kind"] for ln in launches} <= {
+        "admit", "chunk", "mixed", "spec", "decode"}
+    assert any("step_num" in s for s in events["helix.loop.step"])
+
+
+def test_capture_is_stamped_with_the_monotonic_clock(capture):
+    body, _, events = capture
+    stamps = [s["monotonic_ns"] for s in events["helix.clock"]]
+    assert sorted(stamps) == body["clock_ns"] and len(stamps) == 2
+    assert 0.4e9 <= stamps[1] - stamps[0] <= 5e9
+    assert abs(time.monotonic_ns() - stamps[1]) < 600e9
+
+
+def test_capture_default_turns_the_python_tracer_off(capture):
+    body, seen, _ = capture
+    assert body["python_tracer"] is False
+    assert len(seen) == 1
+    assert seen[0].python_tracer_level == 0
+    assert seen[0].host_tracer_level > 0
+
+
+def test_python_tracer_field_must_be_a_boolean(spine):
+    r = requests.post(f"{spine['url']}/admin/profiler",
+                      json={"seconds": 0.01, "python_tracer": "yes"},
+                      timeout=30)
+    assert r.status_code == 400
+
+
+# ---- (e) request stages ---------------------------------------------------
+
+
+@pytest.mark.parametrize("which", (0, 1), ids=("short", "chunked"))
+def test_streamed_request_stages_are_ordered_and_inside_the_clients_ttft(
+        spine, which):
+    tid, client_ttft_s, chunks = spine["streamed"][which]
+    assert chunks and client_ttft_s is not None
+    spans = {}
+    for s in spine["srv"].traces.get(tid)["spans"]:
+        spans.setdefault(s["name"], s)
+    assert set(STAGES) <= set(spans), sorted(spans)
+    end = None
+    total_ms = 0.0
+    for name in STAGES:
+        s = spans[name]
+        assert s["duration_ms"] >= 0.0, name
+        if end is not None:
+            # each stage starts where the one before it ended
+            assert abs(s["start_unix"] - end) < 2e-3, name
+        end = s["start_unix"] + s["duration_ms"] / 1e3
+        total_ms += s["duration_ms"]
+    assert total_ms <= client_ttft_s * 1e3
+
+
+# ---- (f) the host-side idle estimate --------------------------------------
+
+
+def ring(records):
+    loop = EngineLoop(tiny_engine(), "idle")
+    for wall, gap, at in records:
+        loop.flight.record_step({
+            "duration": wall, "generated_tokens": 1, "wall_s": wall,
+            "idle_gap_s": gap, "t_mono": at, "ts": at,
+        })
+    return loop
+
+
+@pytest.mark.parametrize("records,expect", (
+    # sparse: a step every five seconds, each charged the gap before it
+    ([(0.01, 5.0, 100.0), (0.01, 4.99, 105.0), (0.01, 4.99, 110.0)],
+     (0.99, 1.0)),
+    # dense: back-to-back 100 ms steps with 40 ms gaps
+    ([(0.1, 0.04, 10.0 + 0.1 * i) for i in range(20)], (0.39, 0.41)),
+    # one record whose gap reaches back before it started
+    ([(0.02, 30.0, 50.0)], (0.0, 1.0)),
+    ([], (0.0, 0.0)),
+), ids=("sparse", "dense", "single", "empty"))
+def test_device_idle_ratio_cannot_exceed_one(records, expect):
+    ratio = ring(records).device_idle_ratio()
+    assert expect[0] <= ratio <= expect[1] <= 1.0
