@@ -58,16 +58,17 @@ ENV_REFERENCE: tuple = (
     ),
     EnvVar(
         "HELIX_ASYNC_LOOP",
-        "Asynchronous pipelined engine loop override for every engine "
-        "this node serves: truthy dispatches device step N+1 against "
-        "predicted post-step state while step N executes and emits "
-        "tokens through a bounded off-thread stage (greedy and seeded "
-        "temp>0 outputs stay bit-identical to the synchronous loop); "
-        "0/false forces the synchronous baseline even where a profile "
-        "sets engine.enable_async_loop. Watch helix_device_idle_ratio "
-        "and the helix_step_host_build_seconds / "
-        "helix_step_emit_seconds histograms for the effect. Unset: the "
-        "profile setting applies (default off).",
+        "Pipelined dispatch override for every engine this node "
+        "serves: truthy dispatches device step N+1 against predicted "
+        "post-step state while step N executes (greedy and seeded "
+        "temp>0 outputs stay bit-identical to the synchronous "
+        "dispatch); 0/false forces the synchronous dispatch even where "
+        "a profile sets engine.enable_async_loop. It governs the "
+        "dispatch only: every started engine loop delivers tokens on "
+        "its bounded off-thread emission stage either way. Watch "
+        "helix_device_idle_ratio, helix_pipelined_steps_total and the "
+        "helix_step_host_build_seconds histogram for the effect. "
+        "Unset: the profile setting applies (default off).",
         section="accelerator",
     ),
     EnvVar(

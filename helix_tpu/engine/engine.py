@@ -36,6 +36,7 @@ Execution model (all shapes static, everything jitted once per bucket):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import functools
@@ -204,8 +205,9 @@ class EngineConfig:
     # — the device advances every active row by the full window whether
     # or not the host later discards an overrun, so the prediction is
     # exact for everything but EOS, whose overrun tokens are discarded
-    # exactly like fused-window overruns always were) and emits step
-    # N-1's tokens through a bounded off-thread emission stage.  The
+    # exactly like fused-window overruns always were).  (Step N-1's
+    # tokens go out through the bounded off-thread emission stage in
+    # every started loop, with this knob on or off.)  The
     # pipeline engages only for plain fused-decode steps in steady state
     # (no admissions, no chunked prefill, no parked preemptions, state
     # clean) and degrades to the synchronous loop everywhere else —
@@ -1288,6 +1290,11 @@ class Engine:
         # prefill_budget caps NEW prefill-admission tokens per step
         # (None = unbudgeted — the historical behaviour)
         self.on_admit: Optional[Callable[[Request], None]] = None
+        # entered around every blocking read of the device (the step's
+        # fetch, the admission wave's first-token fetch): the loop wires
+        # its emission stage's gate here, so token delivery runs while
+        # the host is parked and not between a completion and a launch
+        self.device_wait = contextlib.nullcontext()
         self.victim_policy: Optional[Callable[[list], list]] = None
         self.prefill_budget: Optional[int] = None
         self._budget_left: Optional[int] = None
@@ -1771,7 +1778,8 @@ class Engine:
     def _fetch(self, handles):
         """A step's one ``jax.device_get``: the host blocks here until
         the device has run the step."""
-        with obs_trace.phase("helix.loop.fetch", into=self.step_phases):
+        with obs_trace.phase("helix.loop.fetch", into=self.step_phases), \
+                self.device_wait:
             return jax.device_get(handles)
 
     def pipeline_ready(self) -> bool:
@@ -2652,7 +2660,7 @@ class Engine:
         trip and complete the per-request bookkeeping."""
         with obs_trace.phase(
             "helix.loop.prefill_sync", into=self.step_phases
-        ):
+        ), self.device_wait:
             if len(pending) == 1:
                 batch0, tok0, _ = pending[0]
                 flat = np.asarray(tok0)[: len(batch0)]

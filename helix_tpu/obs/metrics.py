@@ -445,10 +445,35 @@ class EngineLoopObs:
         )
         self.emit_seconds = Histogram(
             "helix_step_emit_seconds",
-            "Token emission time (subscriber callbacks + per-tenant SLO "
-            "accounting) per step batch",
+            "The engine thread handing a step's tokens to the emission "
+            "stage (event snapshot + enqueue, and any block on a full "
+            "queue) per engine step; the delivery itself only in a loop "
+            "that was never started",
             buckets=FAST_BUCKETS,
         )
+        # the emission worker (ISSUE 26): what delivery costs now that
+        # it is off the engine thread, how long a batch sits before its
+        # delivery starts, and how often the slowest subscriber stalled
+        # the engine
+        self.emit_deliver = Histogram(
+            "helix_emit_deliver_seconds",
+            "Token delivery on the emission worker (subscriber "
+            "callbacks, latency histograms, per-tenant SLO accounting) "
+            "per step batch, less its waits for a parked engine thread",
+            buckets=FAST_BUCKETS,
+        )
+        self.emit_queue_wait = Histogram(
+            "helix_emit_queue_wait_seconds",
+            "A step batch's push to the start of its delivery on the "
+            "emission worker",
+            buckets=FAST_BUCKETS,
+        )
+        self.emit_backpressure = Counter(
+            "helix_emit_backpressure_total",
+            "Pushes that found the emission queue full and blocked the "
+            "engine thread",
+        )
+        self.emit_backpressure.inc(0)   # exported from the first scrape
         # engine-step phases (ISSUE 25): each is the span of the same
         # name on the profiler's clock (obs.trace.phase) and is observed
         # once an engine step, 0 where the phase did not run, so the
@@ -517,6 +542,7 @@ class EngineLoopObs:
         for m in (
             self.queue_wait, self.ttft, self.inter_token,
             self.step_seconds, self.host_build, self.emit_seconds,
+            self.emit_deliver, self.emit_queue_wait, self.emit_backpressure,
             *self.step_phases.values(),
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,

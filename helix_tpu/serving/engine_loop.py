@@ -71,27 +71,56 @@ class TokenEvent:
     error: Optional[str] = None
 
 
-class _EmissionStage:
-    """Bounded, ordered, off-critical-path token emission (ISSUE 13).
+class _Parked(threading.Event):
+    """Set while the engine thread is blocked (on the device, or on the
+    emission stage itself): ``with stage.parked:`` around the blocking
+    call.  Not re-entrant: the engine thread parks in one place at a
+    time."""
 
-    The async loop hands each step's emitted batch to this stage so SSE
+    def __enter__(self):
+        self.set()
+
+    def __exit__(self, *exc):
+        self.clear()
+        return False
+
+
+class _EmissionStage:
+    """Bounded, ordered token emission off the engine thread (ISSUE 13;
+    on in every started loop since ISSUE 26).
+
+    The loop hands each step's emitted batch to this stage so SSE
     subscriber callbacks and per-tenant SLO accounting never sit between
     a device completion and the next dispatch.  One worker thread keeps
     per-request event order; the bounded queue applies backpressure (a
-    full queue blocks the engine thread, so the pipeline never runs more
-    than ``depth`` batches ahead of the slowest subscriber).  When not
-    started (synchronous loop), ``push`` degrades to a direct call on
-    the caller's thread — exactly the pre-pipeline behaviour — and the
-    caller times it (``EngineLoop._push_emit``); the worker times its own
-    deliveries.  Either way the time is the span ``helix.loop.emit``."""
+    full queue blocks the engine thread, so the engine never runs more
+    than ``depth`` batches ahead of the slowest subscriber).
 
-    def __init__(self, sink: Callable, obs_hist, depth: int = 8):
+    The engine thread comes first on the GIL: the worker delivers only
+    while the engine thread is ``parked`` (blocked on the device, or
+    on this stage in a full ``push`` or a ``flush``, which every idle
+    wait and terminal event takes first), a slice of
+    ``SLICE`` events at a time, so its Python lands in the step's device
+    waits and not in the admission and dispatch that follow a
+    completion.  A slice waits for a park at most ``HOLD_MAX`` seconds:
+    an engine thread that never parks (a compile, a long export) delays
+    tokens by that much and no more.
+
+    When not started (a loop that was never started: unit tests,
+    quarantine bisection's ``_emit``), ``push`` is a direct call on the
+    caller's thread."""
+
+    SLICE = 16
+    HOLD_MAX = 0.05
+
+    def __init__(self, sink: Callable, obs: EngineLoopObs, depth: int = 8):
         self._sink = sink
-        self._obs = obs_hist
+        self._obs = obs
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._thread: Optional[threading.Thread] = None
         self.started = False
         self.batches = 0
+        self.parked = _Parked()
 
     def start(self, name: str = "emit") -> None:
         self._thread = threading.Thread(
@@ -106,7 +135,15 @@ class _EmissionStage:
         if not self.started:
             self._sink(emitted)
             return
-        self._q.put(emitted)   # blocks when full: bounded backpressure
+        item = (time.monotonic(), emitted)
+        try:
+            self._q.put_nowait(item)
+        except queue.Full:
+            # bounded backpressure: the slowest subscriber stalls the
+            # engine, and the stall is a park like any other
+            self._obs.emit_backpressure.inc()
+            with self.parked:
+                self._q.put(item)
         self.batches += 1
 
     def flush(self) -> None:
@@ -115,30 +152,43 @@ class _EmissionStage:
         event (evict/shed/drain/quarantine), so an error frame can never
         overtake that request's queued tokens."""
         if self.started:
-            self._q.join()
+            with self.parked:
+                self._q.join()
 
     def stop(self) -> None:
         if not self.started:
             return
         self._q.put(None)
         self.started = False
-        if self._thread is not None:
-            self._thread.join(timeout=10)
+        # what is queued is delivered: nothing is left to come first
+        with self.parked:
+            if self._thread is not None:
+                self._thread.join(timeout=10)
 
     def depth(self) -> int:
         return self._q.qsize()
 
     def _run(self) -> None:
         while True:
-            batch = self._q.get()
+            item = self._q.get()
             try:
-                if batch is None:
+                if item is None:
                     return
-                with obs_trace.phase("helix.loop.emit", hist=self._obs):
-                    try:
-                        self._sink(batch)
-                    except Exception:  # noqa: BLE001 — a subscriber bug must not kill emission
-                        log.exception("emission stage sink failed")
+                pushed_at, batch = item
+                spent = 0.0
+                for i in range(0, len(batch), self.SLICE):
+                    self.parked.wait(self.HOLD_MAX)
+                    if i == 0:
+                        self._obs.emit_queue_wait.observe(
+                            time.monotonic() - pushed_at
+                        )
+                    with obs_trace.phase("helix.emit.deliver") as span:
+                        try:
+                            self._sink(batch[i:i + self.SLICE])
+                        except Exception:  # noqa: BLE001 — a subscriber bug must not kill emission
+                            log.exception("emission stage sink failed")
+                    spent += span.seconds
+                self._obs.emit_deliver.observe(spent)
             finally:
                 self._q.task_done()
 
@@ -244,8 +294,9 @@ class EngineLoop:
         # adds the engine-side wait-queue count on demand
         self._pending_by_tenant: dict[str, int] = {}
         # asynchronous pipelined loop (ISSUE 13): dispatch step N+1
-        # against predicted post-step state while step N executes, and
-        # emit through the bounded off-thread stage.  Requires the
+        # against predicted post-step state while step N executes (the
+        # bounded off-thread emission stage below is on in every started
+        # loop, pipelined or not).  Requires the
         # dispatch/complete engine split.  Multihost leaders pipeline
         # too: plan N+1 publishes at dispatch, so the broadcast rides
         # the same overlap and followers apply it while device step N
@@ -257,9 +308,7 @@ class EngineLoop:
             and hasattr(engine, "step_dispatch")
         )
         self.pipelined_steps = 0    # steps dispatched while one was in flight
-        self._emit_stage = _EmissionStage(
-            self._deliver, self.obs.emit_seconds
-        )
+        self._emit_stage = _EmissionStage(self._deliver, self.obs)
         self._phases = obs_trace.Phases()   # the step in progress
         # host-side device-busy watermark: the last completion's return
         # time.  A dispatch that happens with nothing in flight charges
@@ -279,6 +328,7 @@ class EngineLoop:
         self._disagg_cb: dict = {}
         self.disagg_exports = 0       # prefill snapshots handed to a shipper
         engine.on_admit = self._note_admit
+        engine.device_wait = self._emit_stage.parked
         if self._sched_active:
             engine.victim_policy = self.sched.preempt_order
 
@@ -909,8 +959,7 @@ class EngineLoop:
         return {k: out[k] for k in SATURATION_KEYS}
 
     def start(self):
-        if self.async_enabled:
-            self._emit_stage.start(self.name)
+        self._emit_stage.start(self.name)
         self._thread = threading.Thread(
             target=self._run, name=f"helix-engine-{self.name}", daemon=True
         )
@@ -1063,10 +1112,10 @@ class EngineLoop:
         self._last_emit.pop(request_id, None)
 
     def _emit(self, emitted) -> None:
-        """Snapshot + deliver in one call (synchronous paths: direct
-        emission, quarantine bisection).  The async loop snapshots on
-        the engine thread at push time and delivers on the emission
-        worker."""
+        """Snapshot + deliver in one call (a loop that was never
+        started, and quarantine bisection, which flushes first).  A
+        started loop snapshots on the engine thread at push time and
+        delivers on the emission worker."""
         self._deliver(self._snapshot_events(emitted))
 
     def _snapshot_events(self, emitted) -> list:
@@ -1358,11 +1407,13 @@ class EngineLoop:
 
     def _push_emit(self, emitted, ph: obs_trace.Phases) -> float:
         """Hand one step's tokens to the emission stage, as the span
-        ``helix.loop.emit``; returns the seconds it took.  With the
-        synchronous loop that is the delivery itself and the histogram is
-        fed here; the async loop's worker thread feeds it per batch."""
-        hist = None if self._emit_stage.started else self.obs.emit_seconds
-        with obs_trace.phase("helix.loop.emit", hist=hist, into=ph) as span:
+        ``helix.loop.emit``; returns the seconds it took: the snapshot
+        and the enqueue (and any block on a full queue) in a started
+        loop, the delivery itself in one that was never started.  What
+        delivery costs on the worker is ``helix.emit.deliver``."""
+        with obs_trace.phase(
+            "helix.loop.emit", hist=self.obs.emit_seconds, into=ph
+        ) as span:
             self._emit_stage.push(self._snapshot_events(emitted))
         return span.seconds
 
@@ -1781,6 +1832,12 @@ class EngineLoop:
                 self.engine.discard_pending(inflight)
             inflight = None
         self._emit_stage.stop()
+        log.info(
+            "engine '%s' emission stage stopped: %d batch(es) delivered "
+            "off the engine thread, %d push(es) found the queue full",
+            self.name, self._emit_stage.batches,
+            self.obs.emit_backpressure.value,
+        )
         # terminal sweep: anything still in the inbox (raced a shutdown)
         # gets a clean error event instead of a 300s client hang
         while True:

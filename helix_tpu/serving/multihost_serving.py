@@ -812,6 +812,14 @@ class PlanLeader:
         self.engine.on_admit = value
 
     @property
+    def device_wait(self):
+        return self.engine.device_wait
+
+    @device_wait.setter
+    def device_wait(self, value):
+        self.engine.device_wait = value
+
+    @property
     def victim_policy(self):
         return self.engine.victim_policy
 
