@@ -53,7 +53,10 @@ ENV_REFERENCE: tuple = (
         "serves: >0 enables prompt-lookup drafting with that many draft "
         "tokens per slot per verify call, 0 forces speculation off even "
         "where a profile enables it. Unset: the profile's "
-        "enable_spec_decode/spec_tokens settings apply.",
+        "enable_spec_decode/spec_tokens settings apply. A latent-"
+        "attention model (DeepSeek-V2-Lite) is not served with "
+        "speculation: enabling it is refused at profile apply "
+        "(UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -227,7 +230,8 @@ ENV_REFERENCE: tuple = (
         "multi-host meshes the pool runs on every host (adapter ids "
         "ride the step plan and followers stage residency before the "
         "step), so publish adapters to the leader and followers as a "
-        "pair.",
+        "pair. A latent-attention model (DeepSeek-V2-Lite) refuses a "
+        "pool at profile apply (UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -303,7 +307,9 @@ ENV_REFERENCE: tuple = (
         "typed error, never wrong attention. Applies to every engine "
         "this node serves (operator-beats-profile); 0 forces fully-"
         "resident even where a profile enables tiering. Unset: the "
-        "profile's engine block (default 0 = off).",
+        "profile's engine block (default 0 = off). A latent-attention "
+        "model (DeepSeek-V2-Lite) refuses tiering at profile apply "
+        "(UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(

@@ -185,6 +185,10 @@ def _build_served_model(pm: ProfileModel, mesh=None) -> ServedModel:
         model_cfg = dataclasses.replace(model_cfg, name=pm.name)
     else:
         model_cfg = CATALOG.get(pm.name)
+        if isinstance(pm.model_overrides.get("rope_scaling"), dict):
+            # a profile writes it as a mapping; the config keeps it hashable
+            pm.model_overrides["rope_scaling"] = tuple(
+                sorted(pm.model_overrides["rope_scaling"].items()))
         if model_cfg is not None and pm.model_overrides:
             # overrides apply to catalog configs too (shrink a catalog
             # architecture for a dev mesh) — silently ignoring them

@@ -72,7 +72,10 @@ from helix_tpu.models.llama import forward
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.slo import ANON_TENANT
 from helix_tpu.ops.attention import attention as full_attention
-from helix_tpu.ops.paged import ragged_paged_attention
+from helix_tpu.ops.paged import (
+    mla_ragged_paged_attention,
+    ragged_paged_attention,
+)
 
 
 class FinishReason(str, enum.Enum):
@@ -680,6 +683,18 @@ def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
     ks = caches[2] if len(caches) == 4 else None
     vs = caches[3] if len(caches) == 4 else None
     Bq, Sq, H, D = q.shape
+    if k.ndim == 3:
+        # latent attention: k is the latent, v the rope key, no head axis;
+        # the layer has scaled q already.  A row holds at most Sq fresh
+        # tokens (a decode slot's width, or the whole prefill bucket).
+        out = mla_ragged_paged_attention(
+            q.reshape(Bq * Sq, H, D),
+            k.reshape(Bq * Sq, k.shape[-1]),
+            v.reshape(Bq * Sq, v.shape[-1]),
+            kp, vp, lyr, t0, q_len, hist, tables,
+            backend=backend, max_q_len=Sq,
+        )
+        return out.reshape(Bq, Sq, H, out.shape[-1])
     KVH = k.shape[-2]
     tkw = {}
     if cold is not None:
@@ -697,6 +712,45 @@ def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
         backend=backend, mesh=mesh, k_scale=ks, v_scale=vs, **tkw,
     )
     return out.reshape(Bq, Sq, H, D)
+
+
+class UnsupportedForModel(ValueError):
+    """An engine setting the served model's architecture cannot take
+    (raised when the engine is built, i.e. at profile apply)."""
+
+
+def _refuse_for_latent_attention(model_cfg, cfg, mesh) -> None:
+    """What a latent-attention (MLA) model is not served with: each is
+    refused here, by name, rather than run on a path that was never
+    written for a pool with no head axis."""
+    why = None
+    if mesh is not None and mesh.devices.size > 1:
+        why = (f"a mesh of {mesh.devices.size} devices (the latent pool "
+               "and its kernel are single-device: mesh {tp: 1})")
+    elif cfg.kv_cache_dtype == "int8":
+        why = "kv_cache_dtype int8 (the latent pool is bf16 or f32)"
+    elif cfg.adapter_pool_slots > 0:
+        why = "adapter_pool_slots > 0 (no LoRA targets on MLA projections)"
+    elif cfg.enable_spec_decode:
+        why = "enable_spec_decode (untested on the latent kernel)"
+    elif cfg.ctx_hot_pages > 0:
+        why = "ctx_hot_pages > 0 (tiered residency streams K/V chunks)"
+    if why:
+        raise UnsupportedForModel(
+            f"{model_cfg.name}: latent attention (MLA) is not served with "
+            + why
+        )
+
+
+def _fresh_kv_zeros(cfg: ModelConfig, B: int, S: int):
+    """The two ``[L, B, S, ...]`` accumulators a forward pass fills with
+    each layer's fresh cache entries (K and V, or latent and rope key)
+    for the one ``write_kv`` scatter after it."""
+    kdt = jnp.dtype(cfg.dtype)
+    return tuple(
+        jnp.zeros((cfg.num_layers, B, S) + shp, kdt)
+        for shp in cfg.kv_token_shapes()
+    )
 
 
 def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
@@ -739,16 +793,13 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     one-token row over its ragged paged history), nothing written:
     returns ``(logits [B, 1, V], (pool carry, fresh K, fresh V))``."""
     B = state.last_token.shape[0]
-    L, KVH, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    kdt = jnp.dtype(cfg.dtype)
     tokens = state.last_token[:, None]
     pos2d = state.positions[:, None]
     active = state.active
     t0 = jnp.arange(B, dtype=jnp.int32)
     q_len = (active > 0).astype(jnp.int32)
     hist = state.positions * active
-    kacc0 = jnp.zeros((L, B, 1, KVH, D), kdt)
-    vacc0 = jnp.zeros((L, B, 1, KVH, D), kdt)
+    kacc0, vacc0 = _fresh_kv_zeros(cfg, B, 1)
 
     def attn_fn(q, k, v, carry_cache, pos):
         (caches, kacc, vacc), lyr = carry_cache
@@ -911,8 +962,6 @@ def _build_ragged_step_fn(
     def step_fn(params, cache, state: DecodeState, pargs, drafts,
                 draft_len, n_extra, cold=None):
         B = state.last_token.shape[0]
-        L, KVH, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        kdt = jnp.dtype(cfg.dtype)
         drops = None
         # tiered KV residency (ISSUE 20): staged cold-middle chunks plus
         # the per-row demoted token spans — one slab shared by the
@@ -943,8 +992,7 @@ def _build_ragged_step_fn(
                      p_qlen, p_hist, p_tables, p_ends, p_sampling,
                      p_keys) = pargs
                     p_aids = None
-                kacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
-                vacc0 = jnp.zeros((L, 1, Cb, KVH, D), kdt)
+                kacc0, vacc0 = _fresh_kv_zeros(cfg, 1, Cb)
 
                 def p_attn(q, k, v, carry_cache, pos):
                     (caches, kacc, vacc), lyr = carry_cache
@@ -953,7 +1001,9 @@ def _build_ragged_step_fn(
                             q, k, v, caches, lyr, p_pos, p_seg, p_hist,
                             p_tables, mesh, page_size, ring_hist_pages,
                         )
-                    elif has_hist:
+                    elif has_hist or cfg.is_mla:
+                        # latent attention has one kernel: a cold row is
+                        # a row with no history
                         out = _ragged_attn_call(
                             q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
                             p_tables, backend, cold=p_cold, mesh=mesh,
@@ -985,7 +1035,7 @@ def _build_ragged_step_fn(
                 )
                 if is_moe:
                     logits_p, (pc, kacc, vacc), moe_stats = res
-                    drops = moe_stats["dropped"]
+                    drops = moe_stats["vector"]
                 else:
                     logits_p, (pc, kacc, vacc) = res
                 cache = write_kv(
@@ -1017,8 +1067,7 @@ def _build_ragged_step_fn(
                 act & (draft_len >= 0), W, 0
             ).astype(jnp.int32)
             s_hist = state.positions * state.active
-            kacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
-            vacc0s = jnp.zeros((L, B, W, KVH, D), kdt)
+            kacc0s, vacc0s = _fresh_kv_zeros(cfg, B, W)
 
             def s_attn(q, k, v, carry_cache, pos):
                 (caches, kacc, vacc), lyr = carry_cache
@@ -1044,11 +1093,12 @@ def _build_ragged_step_fn(
                     seq_positions=pos_s,
                 )
             else:
-                logits_s, (pc2, kaccs, vaccs) = forward(
+                res = forward(
                     params, cfg, tokens_s, pos_s,
                     attn_fn=s_attn,
                     carry_caches=carry0,
                     moe_token_mask=live,
+                    return_moe_stats=is_moe,
                     adapter_ids=(
                         jnp.broadcast_to(
                             state.adapter_slots[:, None], (B, W)
@@ -1056,6 +1106,19 @@ def _build_ragged_step_fn(
                         if use_adapters else None
                     ),
                 )
+                logits_s, (pc2, kaccs, vaccs) = res[:2]
+                if is_moe:
+                    # the step's routing load is the decode rows' when any
+                    # is live (what sets a decode step's bytes), else the
+                    # prefill segment's; drops and routed tokens add up
+                    sv = res[2]["vector"]
+                    if drops is None:
+                        drops = sv
+                    else:
+                        drops = jnp.concatenate([
+                            drops[:2] + sv[:2],
+                            jnp.where(sv[1] > 0, sv[2:], drops[2:]),
+                        ])
             cache = PagedKVCache.from_carry(pc2)
             pages_s, offs_s = slot_to_page_offset(
                 pos_s, state.page_tables, page_size
@@ -1188,6 +1251,8 @@ class Engine:
                 f"unsupported kv_cache_dtype {cfg.kv_cache_dtype!r} "
                 "(expected auto | bfloat16 | float32 | int8)"
             )
+        if model_cfg.is_mla:
+            _refuse_for_latent_attention(model_cfg, cfg, mesh)
         # resolved ONCE, here: on a TPU the Pallas kernels, on a CPU the
         # XLA references; nothing downstream re-decides or falls back
         from helix_tpu.ops.attention import head_shards, resolve_backend
@@ -1196,7 +1261,15 @@ class Engine:
         self.cache_cfg = cfg.cache_config(dtype=model_cfg.dtype)
         dev = (mesh.devices.flat[0] if mesh is not None
                else jax.devices()[0])
-        if self._backend == "pallas":
+        if self._backend == "pallas" and model_cfg.is_mla:
+            from helix_tpu.ops.mla_kernel import check_mla_geometry
+
+            check_mla_geometry(
+                model_cfg.num_heads, model_cfg.kv_lora_rank,
+                model_cfg.qk_rope_head_dim,
+                jnp.dtype(self.cache_cfg.dtype).itemsize,
+            )
+        elif self._backend == "pallas":
             from helix_tpu.ops.paged_kernel import check_geometry
 
             tp = head_shards(mesh)
@@ -1474,6 +1547,13 @@ class Engine:
         # prefill hot path never blocks on a drop-counter device_get
         self._moe_dropped = 0
         self._moe_drop_handles: list = []
+        # the routing load of MoE steps, from the same small array: routed
+        # (token, choice) assignments, and of the last step that routed
+        # any: the busiest expert's tokens over the mean, and the distinct
+        # experts touched (mean over the MoE layers)
+        self.moe_routed_tokens = 0
+        self.moe_expert_load_max_ratio = 0.0
+        self.moe_experts_touched = 0.0
         # the step in progress, by named phase (obs.trace.phase): the
         # engine loop clears it at the top of a pass and files it in the
         # flight record; standalone step() callers never read it
@@ -1780,7 +1860,9 @@ class Engine:
         the device has run the step."""
         with obs_trace.phase("helix.loop.fetch", into=self.step_phases), \
                 self.device_wait:
-            return jax.device_get(handles)
+            out = jax.device_get(handles)
+        self._drain_moe_drops()
+        return out
 
     def pipeline_ready(self) -> bool:
         """True when the NEXT dispatch can safely run against predicted
@@ -2573,12 +2655,15 @@ class Engine:
         ps = self.cache_cfg.page_size
         maxP = self.cache_cfg.max_pages_per_seq
         B = self.cfg.max_decode_batch
-        # MoE: one request per call — expert capacity is a shared field
-        # across the whole segment, so co-packed requests would perturb
-        # each other's routing (and the KV the prefix cache adopts).
-        # The admission loop still issues the calls in one wave with one
-        # batched token fetch.
-        max_pack = 1 if self.model_cfg.num_experts > 0 else B
+        # capacity-dispatch MoE: one request per call — expert capacity
+        # is a shared field across the whole segment, so co-packed
+        # requests would perturb each other's routing (and the KV the
+        # prefix cache adopts).  The admission loop still issues the
+        # calls in one wave with one batched token fetch.  A dropless
+        # expert layer has no shared field and packs like a dense model.
+        capacity_moe = (self.model_cfg.num_experts > 0
+                        and self.model_cfg.expert_capacity_factor > 0)
+        max_pack = 1 if capacity_moe else B
         sp_ring = _mesh_sp(self.mesh) > 1
         plan = PrefillPlan(ps, maxP, B)
         batch: list = []
@@ -2647,11 +2732,11 @@ class Engine:
         flush()
         admitted = 0
         for wave_plan, wave_batch in waves:
-            first_tokens, _, _, _, drops = self._ragged_step(
+            first_tokens, _, _, _ = self._ragged_step(
                 "admit", plan=wave_plan, draft_len=self._inert_rows,
                 n_extra=0,
             )
-            pending.append((wave_batch, first_tokens, drops))
+            pending.append((wave_batch, first_tokens))
             admitted += len(wave_batch)
         return admitted
 
@@ -2662,21 +2747,19 @@ class Engine:
             "helix.loop.prefill_sync", into=self.step_phases
         ), self.device_wait:
             if len(pending) == 1:
-                batch0, tok0, _ = pending[0]
+                batch0, tok0 = pending[0]
                 flat = np.asarray(tok0)[: len(batch0)]
             else:
                 flat = np.asarray(
                     jnp.concatenate(
-                        [t[: len(b)] for b, t, _ in pending], axis=0
+                        [t[: len(b)] for b, t in pending], axis=0
                     )
                 )
-        for _, _, drops in pending:
-            self._note_moe_drops(drops)
         # the token fetch above synced the device: draining is free here
         self._drain_moe_drops()
         now = time.monotonic()
         i = 0
-        for batch, _, _ in pending:
+        for batch, _ in pending:
             for req, _table in batch:
                 first_token = int(flat[i])
                 i += 1
@@ -2695,26 +2778,27 @@ class Engine:
                 )
                 self._emit(req, first_token, emitted)
 
-    def _note_moe_drops(self, drops) -> None:
-        """Queue a prefill call's MoE capacity-overflow count (device
-        scalar; None for dense models) WITHOUT fetching it — a blocking
-        device_get here would serialize every chunk dispatch.  The queue
-        drains on the ENGINE
-        thread at prefill-completion points, where the device work is
-        already host-synced."""
-        if drops is None:
-            return
-        self._moe_drop_handles.append(drops)
-
     def _drain_moe_drops(self) -> None:
-        """Fold queued drop counts into the host counter in one stacked
-        fetch.  Engine-thread only (prefill completion paths): the
-        /metrics scrape thread must never block on a device sync, so the
-        property below just reads the plain int."""
-        if not self._moe_drop_handles:
+        """Fold the queued MoE step stats (``[dropped, routed, load max
+        ratio, experts touched]`` a step, queued un-fetched by
+        ``_ragged_step``) into the host counters.  Only arrays the device
+        has already produced are read, so a step still in flight is never
+        waited for; called after each step's own fetch, which is when its
+        stats are ready.  Engine-thread only: the /metrics scrape thread
+        reads the plain numbers."""
+        ready = [h for h in self._moe_drop_handles if h.is_ready()]
+        if not ready:
             return
-        handles, self._moe_drop_handles = self._moe_drop_handles, []
-        n = int(np.asarray(jnp.stack(handles)).sum())
+        self._moe_drop_handles = [
+            h for h in self._moe_drop_handles if not h.is_ready()
+        ]
+        stats = np.asarray(jax.device_get(ready), np.float64)   # [n, 4]
+        self.moe_routed_tokens += int(stats[:, 1].sum())
+        routed = stats[stats[:, 1] > 0]
+        if len(routed):
+            self.moe_expert_load_max_ratio = float(routed[-1, 2])
+            self.moe_experts_touched = float(routed[-1, 3])
+        n = int(stats[:, 0].sum())
         if n <= 0:
             return
         self._moe_dropped += n
@@ -2815,10 +2899,9 @@ class Engine:
             return
         t0 = time.monotonic()
         plan, rem, end = self._chunk_plan(st)
-        token, _, _, _, drops = self._ragged_step(
+        token, _, _, _ = self._ragged_step(
             "chunk", plan=plan, draft_len=self._inert_rows, n_extra=0,
         )
-        self._note_moe_drops(drops)
         self.num_prefill_tokens += rem
         st["next"] = end
         if req.trace_id and self._should_trace_chunk(st, req, end):
@@ -2859,12 +2942,11 @@ class Engine:
         ]
         t0 = time.monotonic()
         plan, rem, end = self._chunk_plan(st)
-        token, sampled, _, _, drops = self._ragged_step(
+        token, sampled, _, _ = self._ragged_step(
             "mixed", plan=plan, draft_len=self._zero_rows, n_extra=0,
         )
         self.num_mixed_steps += 1
         self.num_decode_device_steps += 1
-        self._note_moe_drops(drops)
         self.num_prefill_tokens += rem
         st["next"] = end
         if req.trace_id and self._should_trace_chunk(st, req, end):
@@ -3597,10 +3679,20 @@ class Engine:
             "preempt_count": req.preempt_count,
             "page_size": self.cache_cfg.page_size,
             "num_layers": self.model_cfg.num_layers,
-            "kv_heads": self.model_cfg.num_kv_heads,
-            "head_dim": self.model_cfg.head_dim,
+            # a latent pool has no head axis: "kv_heads" 0 tells it from
+            # a K/V pool, "head_dim" is then the latent width
+            "kv_heads": self._snapshot_geometry()[0],
+            "head_dim": self._snapshot_geometry()[1],
             "kv_dtype": self.cache_cfg.dtype,
         }
+
+    def _snapshot_geometry(self) -> tuple:
+        """``(kv_heads, head_dim)`` as a snapshot states them: ``(0,
+        kv_lora_rank)`` for a latent pool, which no K/V pool can match."""
+        m = self.model_cfg
+        if m.is_mla:
+            return 0, m.kv_lora_rank
+        return m.num_kv_heads, m.head_dim
 
     def export_request(self, req_id: str) -> Optional[RequestSnapshot]:
         """Build a portable snapshot of one live request (engine thread).
@@ -3795,8 +3887,8 @@ class Engine:
         geometry = (
             ("page_size", snap.page_size, cc.page_size),
             ("num_layers", snap.num_layers, self.model_cfg.num_layers),
-            ("kv_heads", snap.kv_heads, self.model_cfg.num_kv_heads),
-            ("head_dim", snap.head_dim, self.model_cfg.head_dim),
+            ("kv_heads", snap.kv_heads, self._snapshot_geometry()[0]),
+            ("head_dim", snap.head_dim, self._snapshot_geometry()[1]),
             ("kv_dtype", snap.kv_dtype, cc.dtype),
         )
         for field, theirs, ours in geometry:
@@ -3832,10 +3924,7 @@ class Engine:
         from helix_tpu.engine.kv_cache import page_checksum
 
         quantized = cc.quantized
-        kshape = (
-            self.model_cfg.num_layers, cc.page_size,
-            self.model_cfg.num_kv_heads, self.model_cfg.head_dim,
-        )
+        kshape = cc.page_shapes(self.model_cfg)[0]
         entries = []
         for arrays, digest in zip(snap.pages, snap.page_checksums):
             entry = {
@@ -4167,7 +4256,7 @@ class Engine:
             if r is not None and self._slot_active(i)
         ]
         n_extra = self._spec_extra_steps()
-        _, sampled, emit, extra, _ = self._ragged_step(
+        _, sampled, emit, extra = self._ragged_step(
             "spec", drafts=drafts, draft_len=draft_len, n_extra=n_extra,
         )
         self.num_spec_steps += 1
@@ -4241,7 +4330,7 @@ class Engine:
         # plain decode IS the unified step with zero drafts: position 0
         # of each active row samples this step's token, and the fused
         # tail advances the remaining n-1 window steps in the same jit
-        _, sampled, _, extra, _ = self._ragged_step(
+        _, sampled, _, extra = self._ragged_step(
             "decode", draft_len=self._zero_rows, n_extra=n - 1,
         )
         self.num_decode_device_steps += n
@@ -4317,8 +4406,9 @@ class Engine:
         here; the compiled entry point is keyed only on the prefill
         token-bucket (plus the has-history / row-capacity variants the
         plan implies).  ``kind`` names the caller on the launch's
-        profiler span.  Returns ``(p_first, sampled, emit, extra,
-        drops)`` device handles."""
+        profiler span.  Returns ``(p_first, sampled, emit, extra)``
+        device handles; a MoE step's stats vector is queued for
+        ``_drain_moe_drops``."""
         if self._tiered:
             # tiered rows: demote pages behind the hot tail, then grow
             # tables to cover this step's writes — BEFORE the state sync
@@ -4401,6 +4491,8 @@ class Engine:
             live_rows=int(np.count_nonzero(np.asarray(draft_len) >= 0)),
             n_extra=int(n_extra), prefill_tokens=used,
             padding_tokens=rung - used,
+            **({"experts_touched": round(self.moe_experts_touched, 1)}
+               if self.model_cfg.num_experts else {}),
         ):
             (self.cache, self._dstate, p_first, sampled, emit, extra,
              drops) = fn(
@@ -4408,7 +4500,10 @@ class Engine:
                 jnp.asarray(drafts), jnp.asarray(draft_len),
                 jnp.int32(n_extra), cold_arg,
             )
-        return p_first, sampled, emit, extra, drops
+        if drops is not None:
+            # fetched when ready, after this step's own fetch
+            self._moe_drop_handles.append(drops)
+        return p_first, sampled, emit, extra
 
     # ------------------------------------------------------------------
     # completion

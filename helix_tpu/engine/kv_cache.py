@@ -63,7 +63,31 @@ class CacheConfig:
     def max_seq_len(self) -> int:
         return self.page_size * self.max_pages_per_seq
 
+    @staticmethod
+    def latent_widths(model: ModelConfig) -> tuple:
+        """Lane widths of a latent (MLA) pool's two arrays: the latent as
+        it is, the rope key padded to whole 128-lane tiles (what the pool
+        allocates and the kernel DMAs)."""
+        return model.kv_lora_rank, -(-model.qk_rope_head_dim // 128) * 128
+
+    def page_shapes(self, model: ModelConfig) -> tuple:
+        """Shapes of ONE page, all layers, in the pool's two arrays (what
+        ``gather_pages`` hands out and a snapshot carries): K and V
+        ``[L, P, KVH, D]``, or for latent attention the latent ``[L, P,
+        R]`` and the lane-padded rope key ``[L, P, 128]``."""
+        L, P = model.num_layers, self.page_size
+        if model.is_mla:
+            kw, vw = self.latent_widths(model)
+            return (L, P, kw), (L, P, vw)
+        kv = (L, P, model.num_kv_heads, model.head_dim)
+        return kv, kv
+
     def page_bytes(self, model: ModelConfig) -> int:
+        if model.is_mla:
+            # what is allocated, lane padding included
+            return sum(
+                int(np.prod(shp)) for shp in self.page_shapes(model)
+            ) * jnp.dtype(self.dtype).itemsize
         per_elem = (
             2
             * model.num_layers
@@ -120,8 +144,8 @@ class PagedKVCache:
     needed).
     """
 
-    k_pages: jax.Array  # [L, N, P, KVH, D]
-    v_pages: jax.Array
+    k_pages: jax.Array  # [L, N, P, KVH, D]; latent pools: c [L, N, P, R]
+    v_pages: jax.Array  # same shape; latent pools: rope key [L, N, P, 128]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
 
@@ -132,6 +156,8 @@ class PagedKVCache:
         cache: CacheConfig,
         mesh=None,
     ) -> "PagedKVCache":
+        if model.is_mla:
+            return cls._create_latent(model, cache, mesh)
         shape = (
             model.num_layers,
             cache.num_pages,
@@ -184,6 +210,33 @@ class PagedKVCache:
                 )
         return cls(k_pages=k, v_pages=v)
 
+    @classmethod
+    def _create_latent(cls, model, cache, mesh) -> "PagedKVCache":
+        """A latent pool: the two arrays keep their roles in every opaque
+        pair path (host pool, snapshots, checksums, filestore), with a
+        width each and no head axis."""
+        if cache.quantized:
+            raise ValueError(
+                "a latent (MLA) page pool has no int8 storage: set "
+                "kv_cache_dtype to auto, bfloat16 or float32"
+            )
+        if mesh is not None and mesh.devices.size > 1:
+            raise ValueError(
+                "a latent (MLA) page pool is held by one device: a "
+                f"mesh of {mesh.devices.size} devices is not supported"
+            )
+        dtype = jnp.dtype(cache.dtype)
+        pools = [
+            jnp.zeros((shp[0], cache.num_pages) + shp[1:], dtype)
+            for shp in cache.page_shapes(model)
+        ]
+        return cls(k_pages=pools[0], v_pages=pools[1])
+
+    @property
+    def latent(self) -> bool:
+        """No head axis: a latent (MLA) pool."""
+        return self.k_pages.ndim == 4
+
     @property
     def num_layers(self):
         return self.k_pages.shape[0]
@@ -228,6 +281,8 @@ def write_kv(
     Int8 pools quantize here (per-slot-per-head absmax scales) and scatter
     the f32 scale rows into the scale pools with the same fused index.
     """
+    if cache.latent:
+        return _write_latent(cache, k_new, v_new, pages, offsets, valid)
     L, B, S, KVH, D = k_new.shape
     Lp, P, ps, KVHp, Dp = cache.k_pages.shape
     # Scatter at ONE fused token index (page*page_size + offset) into a
@@ -282,6 +337,36 @@ def write_kv(
         k_pages=k_pages, v_pages=v_pages,
         k_scale=scatter_scales(cache.k_scale, k_sc),
         v_scale=scatter_scales(cache.v_scale, v_sc),
+    )
+
+
+def _write_latent(cache, c_new, r_new, pages, offsets, valid):
+    """``write_kv`` for a latent pool: ``c_new [L, B, S, R]``, ``r_new [L,
+    B, S, dr]`` (zero-padded to the pool's lane width).  One ROW scatter
+    over the pool viewed ``[L * N * ps, W]``, each (layer, token) its own
+    row index: a pool with no head axis has only the lane axis minor, and
+    a scatter that kept the layer axis as a window made layout assignment
+    move it next to the lanes and copy the whole pool (3.75 GB of
+    temporaries on a 16 GB chip, compiled for the described chip)."""
+    ps = cache.k_pages.shape[2]
+    tok = jnp.where(valid, pages * ps + offsets, 0).reshape(-1)     # [T]
+
+    def scatter(pool, new):
+        L, N, _, W = pool.shape
+        rows = (jnp.arange(L, dtype=tok.dtype)[:, None] * (N * ps)
+                + tok[None, :]).reshape(-1)                          # [L*T]
+        new = new.reshape(-1, new.shape[-1]).astype(pool.dtype)
+        new = jnp.pad(new, ((0, 0), (0, W - new.shape[-1])))
+        return (
+            pool.reshape(L * N * ps, W)
+            .at[rows]
+            .set(new, mode="drop", unique_indices=False)
+            .reshape(L, N, ps, W)
+        )
+
+    return PagedKVCache(
+        k_pages=scatter(cache.k_pages, c_new),
+        v_pages=scatter(cache.v_pages, r_new),
     )
 
 

@@ -36,22 +36,41 @@ def tree_bytes(tree) -> int:
     )
 
 
-def model_param_count(model_cfg) -> int:
-    """Architectural parameter count from a config (dense-path weights:
-    for MoE this is the ACTIVE-per-token shape, which is also the right
-    numerator for decode MFU — each generated token moves ~2 FLOPs per
-    active parameter through the MXU)."""
+def model_param_count(model_cfg, stored: bool = False) -> int:
+    """Architectural parameter count from a config.  By default the
+    ACTIVE-per-token shape (for MoE: the experts a token is routed to,
+    plus the shared ones), the right numerator for decode MFU: each
+    generated token moves ~2 FLOPs per active parameter through the MXU.
+    ``stored=True`` counts every expert: what the weights occupy."""
     c = model_cfg
-    embed = c.vocab_size * c.hidden_size
-    per_layer = (
-        c.hidden_size * c.num_heads * c.head_dim        # wq
-        + 2 * c.hidden_size * c.num_kv_heads * c.head_dim  # wk, wv
-        + c.num_heads * c.head_dim * c.hidden_size      # wo
-        + 3 * c.hidden_size * c.intermediate_size       # gate, up, down
-        + 2 * c.hidden_size                             # norms
+    E = c.hidden_size
+    embed = c.vocab_size * E
+    if c.is_mla:
+        attn = (
+            E * c.num_heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)
+            + E * (c.kv_lora_rank + c.qk_rope_head_dim)       # wkv_a
+            + c.kv_lora_rank * c.num_heads * (
+                c.qk_nope_head_dim + c.v_head_dim)            # wkv_b
+            + c.num_heads * c.v_head_dim * E                  # wo
+            + c.kv_lora_rank                                  # kv_norm
+        )
+    else:
+        attn = (
+            E * c.num_heads * c.head_dim                      # wq
+            + 2 * E * c.num_kv_heads * c.head_dim             # wk, wv
+            + c.num_heads * c.head_dim * E                    # wo
+        )
+    dense_ffn = 3 * E * c.intermediate_size                   # gate, up, down
+    n_moe = c.num_moe_layers if (c.is_mla or stored) else 0
+    experts = c.num_experts if stored else c.num_experts_per_tok
+    moe_ffn = (
+        3 * E * c.expert_width * (experts + c.num_shared_experts)
+        + E * c.num_experts                                   # router
     )
-    return embed * (1 if c.tie_word_embeddings else 2) + (
-        c.num_layers * per_layer + c.hidden_size
+    per_layer = attn + 2 * E                                  # + norms
+    return embed * (1 if c.tie_word_embeddings else 2) + E + (
+        c.num_layers * per_layer
+        + (c.num_layers - n_moe) * dense_ffn + n_moe * moe_ffn
     )
 
 
@@ -70,7 +89,7 @@ def estimate_model_bytes(
     from helix_tpu.engine.kv_cache import CacheConfig
 
     c = model_cfg
-    n_params = model_param_count(c)
+    n_params = model_param_count(c, stored=True)
     import jax.numpy as jnp
 
     itemsize = 1 if quantization == "int8" else jnp.dtype(c.dtype).itemsize

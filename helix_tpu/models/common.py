@@ -45,12 +45,50 @@ class ModelConfig:
     # per-expert token capacity = factor * tokens * k / num_experts
     # (GShard-style dispatch; overflow tokens fall back to the residual)
     expert_capacity_factor: float = 1.5
+    # 0 = dropless: tokens sorted by expert and one grouped product, at
+    # every shape (DeepSeek).  > 0 keeps the GShard capacity dispatch.
+    # --- DeepSeek-V2 family: fine-grained MoE with shared experts ---
+    moe_intermediate_size: int = 0      # routed expert width (0: the FFN's)
+    num_shared_experts: int = 0         # one shared MLP of this many widths
+    first_k_dense: int = 0              # leading layers with a dense FFN
+    # True: softmax over the top-k logits (Mixtral).  False: softmax over
+    # all experts, the top-k probabilities kept as they are (DeepSeek's
+    # norm_topk_prob false), times routed_scaling_factor.
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 1.0
+    # --- multi-head latent attention (MLA); 0 = plain multi-head ---
+    kv_lora_rank: int = 0               # compressed KV width, cached
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0           # shared rope key width, cached
+    v_head_dim: int = 0
     # --- non-architectural serving metadata ---
     name: str = "unnamed"
 
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.num_experts else 0
+
+    def kv_token_shapes(self) -> tuple:
+        """Per-token shapes of the two cached arrays, as the model hands
+        them to ``attn_fn``: K and V ``(kv_heads, head_dim)`` each, or for
+        latent attention the compressed latent ``(kv_lora_rank,)`` and
+        the shared rope key ``(qk_rope_head_dim,)``: no head axis, no V."""
+        if self.is_mla:
+            return (self.kv_lora_rank,), (self.qk_rope_head_dim,)
+        kv = (self.num_kv_heads, self.head_dim)
+        return kv, kv
 
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "unnamed") -> "ModelConfig":
@@ -68,14 +106,48 @@ class ModelConfig:
         rope_scaling = None
         if rs and mrope is None:
             rope_scaling = tuple(sorted(rs.items()))
+        family = {"num_experts": hf.get("num_local_experts", 0)}
+        if model_type == "deepseek_v2":
+            if hf.get("q_lora_rank"):
+                raise ValueError(
+                    "deepseek_v2 with a compressed query (q_lora_rank "
+                    f"{hf['q_lora_rank']}) is not supported: only the "
+                    "direct query projection of DeepSeek-V2-Lite is"
+                )
+            if (hf.get("topk_method", "greedy") != "greedy"
+                    or hf.get("scoring_func", "softmax") != "softmax"
+                    or hf.get("moe_layer_freq", 1) != 1):
+                raise ValueError(
+                    "deepseek_v2: only the greedy softmax router with an "
+                    "expert layer at every layer after the dense ones is "
+                    "supported"
+                )
+            family = dict(
+                num_experts=hf["n_routed_experts"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                num_shared_experts=hf.get("n_shared_experts") or 0,
+                first_k_dense=hf.get("first_k_dense_replace", 0),
+                moe_renormalize=bool(hf.get("norm_topk_prob", False)),
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                expert_capacity_factor=0.0,
+                kv_lora_rank=hf["kv_lora_rank"],
+                qk_nope_head_dim=hf["qk_nope_head_dim"],
+                qk_rope_head_dim=hf["qk_rope_head_dim"],
+                v_head_dim=hf["v_head_dim"],
+            )
         return cls(
+            **family,
             mrope_sections=mrope,
             vocab_size=hf["vocab_size"],
             hidden_size=hidden,
             num_layers=hf["num_hidden_layers"],
             num_heads=heads,
             num_kv_heads=hf.get("num_key_value_heads", heads),
-            head_dim=hf.get("head_dim") or hidden // heads,
+            head_dim=(
+                hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]
+                if "kv_lora_rank" in family
+                else hf.get("head_dim") or hidden // heads),
             intermediate_size=hf["intermediate_size"],
             rope_theta=hf.get("rope_theta", 10000.0),
             rope_scaling=rope_scaling,
@@ -87,7 +159,6 @@ class ModelConfig:
             mlp_bias=hf.get("mlp_bias", False),
             qk_norm=model_type == "qwen3",
             max_position_embeddings=hf.get("max_position_embeddings", 8192),
-            num_experts=hf.get("num_local_experts", 0),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
         )
@@ -170,7 +241,44 @@ MIXTRAL_8X7B = ModelConfig(
     name="mistralai/Mixtral-8x7B-Instruct-v0.1",
 )
 
+# DeepSeek-V2-Lite (https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/
+# blob/main/config.json): latent attention, one dense layer then 26 of
+# 64 routed + 2 shared experts, YaRN rope.  Served on one device with a
+# bf16/f32 latent cache; a tp/ep/sp mesh, an int8 cache, adapters and
+# tiered residency are refused at engine start (models/llama.py docstring).
+DEEPSEEK_V2_LITE = ModelConfig(
+    vocab_size=102400,
+    hidden_size=2048,
+    num_layers=27,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=192,
+    intermediate_size=10944,
+    rope_theta=10000.0,
+    rope_scaling=tuple(sorted({
+        "beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
+        "mscale_all_dim": 0.707, "original_max_position_embeddings": 4096,
+        "type": "yarn",
+    }.items())),
+    rms_norm_eps=1e-6,
+    max_position_embeddings=163840,
+    num_experts=64,
+    num_experts_per_tok=6,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=1408,
+    num_shared_experts=2,
+    first_k_dense=1,
+    moe_renormalize=False,
+    routed_scaling_factor=1.0,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    name="deepseek-ai/DeepSeek-V2-Lite",
+)
+
 CATALOG = {
     m.name: m
-    for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B)
+    for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
+              DEEPSEEK_V2_LITE)
 }

@@ -17,8 +17,10 @@ TPU-first code:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import zlib
 from typing import Any, Callable, Optional
 
 import jax
@@ -115,41 +117,101 @@ def init_params(
             sh = sh[name]
         return _seeded_int8(k, shape, dtype, std, path == ("embed",), sh)
 
+    # the tensors the dense and Mixtral families always had keep the keys
+    # they always had (a seed gives them the same weights as before); a
+    # tensor that came later draws from its path
+    kx = jax.random.split(jax.random.fold_in(key, 7), 4)
+    legacy = {
+        ("layers", "wq"): ks[1], ("layers", "wk"): ks[2],
+        ("layers", "wv"): ks[3], ("layers", "wo"): ks[4],
+        ("layers", "w_gate"): ks[5], ("layers", "w_up"): ks[6],
+        ("layers", "w_down"): ks[7], ("layers", "router"): kx[0],
+        ("layers", "experts", "w_gate"): kx[1],
+        ("layers", "experts", "w_up"): kx[2],
+        ("layers", "experts", "w_down"): kx[3],
+    }
+
+    def stack(n, moe, *at):
+        """One stack of ``n`` layers of one kind, at ``at`` in the tree."""
+
+        def w(shape, *name, std=0.02):
+            path = at + name
+            k = legacy.get(path)
+            if k is None:
+                k = jax.random.fold_in(
+                    jax.random.fold_in(key, 1000),
+                    zlib.crc32("/".join(path).encode()) & 0x7FFFFFFF)
+            return weight(k, (n,) + shape, *path, std=std)
+
+        lp = {
+            "attn_norm": {"weight": jnp.ones((n, E), dtype)},
+            "mlp_norm": {"weight": jnp.ones((n, E), dtype)},
+        }
+        if cfg.is_mla:
+            R, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+            lp["wq"] = w((E, H * (dn + dr)), "wq")
+            lp["wkv_a"] = w((E, R + dr), "wkv_a")
+            lp["kv_norm"] = {"weight": jnp.ones((n, R), dtype)}
+            lp["wkv_b"] = w((R, H * (dn + dv)), "wkv_b")
+            lp["wo"] = w((H * dv, E), "wo")
+        else:
+            lp["wq"] = w((E, H * D), "wq")
+            lp["wk"] = w((E, KVH * D), "wk")
+            lp["wv"] = w((E, KVH * D), "wv")
+            lp["wo"] = w((H * D, E), "wo")
+        if moe:
+            # router + expert-stacked SwiGLU replaces the dense FFN
+            # (models/moe.py); Mixtral's experts are as wide as the FFN
+            X, Fx = cfg.num_experts, cfg.expert_width
+            lp["router"] = w((E, X), "router")
+            lp["experts"] = {
+                "w_gate": w((X, E, Fx), "experts", "w_gate"),
+                "w_up": w((X, E, Fx), "experts", "w_up"),
+                "w_down": w((X, Fx, E), "experts", "w_down"),
+            }
+            if cfg.num_shared_experts:
+                Fs = cfg.num_shared_experts * Fx
+                lp["shared"] = {
+                    "w_gate": w((E, Fs), "shared", "w_gate"),
+                    "w_up": w((E, Fs), "shared", "w_up"),
+                    "w_down": w((Fs, E), "shared", "w_down"),
+                }
+        else:
+            lp["w_gate"] = w((E, F), "w_gate")
+            lp["w_up"] = w((E, F), "w_up")
+            lp["w_down"] = w((F, E), "w_down")
+        if cfg.attention_bias:
+            for nm, width in (("wq", H * D), ("wk", KVH * D),
+                              ("wv", KVH * D)):
+                lp[nm]["bias"] = jnp.zeros((n, width), dtype)
+        if cfg.qk_norm:
+            lp["q_norm"] = {"weight": jnp.ones((n, D), dtype)}
+            lp["k_norm"] = {"weight": jnp.ones((n, D), dtype)}
+        return lp
+
+    n_dense = cfg.first_k_dense if cfg.num_experts > 0 else 0
+    # A dropless expert model's embedding rows are drawn at unit RMS.  At
+    # 0.02 a token's own vector is lost under what the first random layer
+    # adds to the residual stream, every token's hidden state is one
+    # common vector, and every token picks the same six experts (on the
+    # chip: 47-49 of 64 experts touched, the busiest at 8.5x the mean, the
+    # count and with it the step's time following the seed).  No trained
+    # router does that.  At 1.0 the decode rows of a step touch every
+    # expert of every layer (PERF.md section 6, PR 28).  The head is
+    # untied, so the logits keep their scale.
+    dropless_moe = (cfg.num_experts > 0 and cfg.expert_capacity_factor <= 0
+                    and not cfg.tie_word_embeddings)
     params = {
-        "embed": weight(ks[0], (V, E), "embed"),
-        "layers": {
-            "attn_norm": {"weight": jnp.ones((L, E), dtype)},
-            "mlp_norm": {"weight": jnp.ones((L, E), dtype)},
-            "wq": weight(ks[1], (L, E, H * D), "layers", "wq"),
-            "wk": weight(ks[2], (L, E, KVH * D), "layers", "wk"),
-            "wv": weight(ks[3], (L, E, KVH * D), "layers", "wv"),
-            "wo": weight(ks[4], (L, H * D, E), "layers", "wo"),
-            "w_gate": weight(ks[5], (L, E, F), "layers", "w_gate"),
-            "w_up": weight(ks[6], (L, E, F), "layers", "w_up"),
-            "w_down": weight(ks[7], (L, F, E), "layers", "w_down"),
-        },
+        "embed": weight(ks[0], (V, E), "embed",
+                        std=1.0 if dropless_moe else 0.02),
+        "layers": stack(L - n_dense, cfg.num_experts > 0, "layers"),
         "final_norm": {"weight": jnp.ones((E,), dtype)},
     }
-    if cfg.num_experts > 0:
-        # Mixtral-family: router + expert-stacked SwiGLU replaces the
-        # dense FFN (models/moe.py)
-        X = cfg.num_experts
-        kk = jax.random.split(jax.random.fold_in(key, 7), 4)
-        layers = params["layers"]
-        del layers["w_gate"], layers["w_up"], layers["w_down"]
-        layers["router"] = weight(kk[0], (L, E, X), "layers", "router")
-        ex = ("layers", "experts")
-        layers["experts"] = {
-            "w_gate": weight(kk[1], (L, X, E, F), *ex, "w_gate"),
-            "w_up": weight(kk[2], (L, X, E, F), *ex, "w_up"),
-            "w_down": weight(kk[3], (L, X, F, E), *ex, "w_down"),
-        }
-    if cfg.attention_bias:
-        for nm, width in (("wq", H * D), ("wk", KVH * D), ("wv", KVH * D)):
-            params["layers"][nm]["bias"] = jnp.zeros((L, width), dtype)
-    if cfg.qk_norm:
-        params["layers"]["q_norm"] = {"weight": jnp.ones((L, D), dtype)}
-        params["layers"]["k_norm"] = {"weight": jnp.ones((L, D), dtype)}
+    if n_dense:
+        # the leading dense layers are a stack of their own: two kinds of
+        # layer cannot share one scan over stacked weights
+        params["dense_layers"] = stack(n_dense, False, "dense_layers")
     if not cfg.tie_word_embeddings:
         params["lm_head"] = weight(
             jax.random.fold_in(key, 99), (E, V), "lm_head")
@@ -168,40 +230,142 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
     and shards layer blocks across pipeline groups on ``mesh: {pp: N}``
     — a new stacked weight must use "layers" too or it silently
     replicates across the pipeline."""
-    lax_ = {
-        "attn_norm": {"weight": ("layers", None)},
-        "mlp_norm": {"weight": ("layers", None)},
-        "wq": {"weight": ("layers", "embed", "heads")},
-        "wk": {"weight": ("layers", "embed", "kv_heads")},
-        "wv": {"weight": ("layers", "embed", "kv_heads")},
-        "wo": {"weight": ("layers", "heads", "embed")},
-        "w_gate": {"weight": ("layers", "embed", "mlp")},
-        "w_up": {"weight": ("layers", "embed", "mlp")},
-        "w_down": {"weight": ("layers", "mlp", "embed")},
-    }
-    if cfg.num_experts > 0:
-        del lax_["w_gate"], lax_["w_up"], lax_["w_down"]
-        lax_["router"] = {"weight": ("layers", "embed", None)}
-        lax_["experts"] = {
-            "w_gate": {"weight": ("layers", "expert", "embed", "mlp")},
-            "w_up": {"weight": ("layers", "expert", "embed", "mlp")},
-            "w_down": {"weight": ("layers", "expert", "mlp", "embed")},
+    def stack(moe):
+        lax_ = {
+            "attn_norm": {"weight": ("layers", None)},
+            "mlp_norm": {"weight": ("layers", None)},
+            "wq": {"weight": ("layers", "embed", "heads")},
+            "wo": {"weight": ("layers", "heads", "embed")},
         }
-    if cfg.attention_bias:
-        lax_["wq"]["bias"] = ("layers", "heads")
-        lax_["wk"]["bias"] = ("layers", "kv_heads")
-        lax_["wv"]["bias"] = ("layers", "kv_heads")
-    if cfg.qk_norm:
-        lax_["q_norm"] = {"weight": ("layers", None)}
-        lax_["k_norm"] = {"weight": ("layers", None)}
+        if cfg.is_mla:
+            # the latent projection is shared by every head: replicated
+            lax_["wkv_a"] = {"weight": ("layers", "embed", None)}
+            lax_["kv_norm"] = {"weight": ("layers", None)}
+            lax_["wkv_b"] = {"weight": ("layers", None, "heads")}
+        else:
+            lax_["wk"] = {"weight": ("layers", "embed", "kv_heads")}
+            lax_["wv"] = {"weight": ("layers", "embed", "kv_heads")}
+        mlp = {
+            "w_gate": {"weight": ("layers", "embed", "mlp")},
+            "w_up": {"weight": ("layers", "embed", "mlp")},
+            "w_down": {"weight": ("layers", "mlp", "embed")},
+        }
+        if moe:
+            lax_["router"] = {"weight": ("layers", "embed", None)}
+            lax_["experts"] = {
+                "w_gate": {"weight": ("layers", "expert", "embed", "mlp")},
+                "w_up": {"weight": ("layers", "expert", "embed", "mlp")},
+                "w_down": {"weight": ("layers", "expert", "mlp", "embed")},
+            }
+            if cfg.num_shared_experts:
+                lax_["shared"] = mlp
+        else:
+            lax_.update(mlp)
+        if cfg.attention_bias:
+            lax_["wq"]["bias"] = ("layers", "heads")
+            lax_["wk"]["bias"] = ("layers", "kv_heads")
+            lax_["wv"]["bias"] = ("layers", "kv_heads")
+        if cfg.qk_norm:
+            lax_["q_norm"] = {"weight": ("layers", None)}
+            lax_["k_norm"] = {"weight": ("layers", None)}
+        return lax_
+
     axes = {
         "embed": {"weight": ("vocab", "embed")},
-        "layers": lax_,
+        "layers": stack(cfg.num_experts > 0),
         "final_norm": {"weight": (None,)},
     }
+    if cfg.num_experts > 0 and cfg.first_k_dense:
+        axes["dense_layers"] = stack(False)
     if not cfg.tie_word_embeddings:
         axes["lm_head"] = {"weight": ("embed", "vocab")}
     return axes
+
+
+def _swiglu(x, p, act, adapter_ids=None, scoped=False):
+    """``(act(x W_g) * (x W_u)) W_d`` over one dict of three weights."""
+    scope = jax.named_scope if scoped else (
+        lambda _: contextlib.nullcontext())
+    with scope("mlp.gate_up"):
+        gate = _dense(x, p["w_gate"], adapter_ids)
+        up = _dense(x, p["w_up"], adapter_ids)
+    with scope("mlp.down"):
+        return _dense(act(gate) * up, p["w_down"], adapter_ids)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5`` times YaRN's ``mscale ** 2``."""
+    from helix_tpu.ops.rope import yarn_attention_scales
+
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * (
+        yarn_attention_scales(cfg.rope_scaling)[1])
+
+
+def mla_absorbed_weights(wkv_b: dict, cfg: ModelConfig, dtype):
+    """``W_kvb [R, H * (dn + dv)]`` as the two absorbed factors
+    ``W_UK [R, H, dn]`` and ``W_UV [R, H, dv]`` in ``dtype`` (an int8
+    leaf is dequantised here: 2.1M values a layer)."""
+    w = wkv_b["weight"]
+    if "scale" in wkv_b:
+        w = w.astype(jnp.float32) * wkv_b["scale"].astype(jnp.float32)
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    w = w.astype(dtype).reshape(cfg.kv_lora_rank, cfg.num_heads, dn + dv)
+    return w[..., :dn], w[..., dn:]
+
+
+def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
+    """Multi-head latent attention (DeepSeek-V2) in the ABSORBED form, at
+    every shape: each head's no-rope query is carried into the latent
+    space (``q W_UK^T``), all heads attend ONE cached vector a token (the
+    normed latent ``c`` and the shared rope key), and the attended latent
+    leaves through ``W_UV``.  What ``attn_fn`` gets:
+
+    - ``q [B, S, H, R + dr]``: absorbed query | rope query, already times
+      the softmax scale (the op is called with scale 1);
+    - ``k = c [B, S, R]``, ``v = k_pe [B, S, dr]``: the two cached arrays
+      (no head axis, no V: the values are ``c`` itself);
+
+    and returns the attended latent ``[B, S, H, R]``.  Rope pairs are
+    rotated as published, ``(2i, 2i+1)``, and kept de-interleaved
+    (``ops.rope.apply_rope_interleaved``)."""
+    from helix_tpu.ops.rope import apply_rope_interleaved, yarn_attention_scales
+
+    B, S, E = h.shape
+    H, R = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rot = yarn_attention_scales(cfg.rope_scaling)[0]
+    w_uk, w_uv = mla_absorbed_weights(p["wkv_b"], cfg, h.dtype)
+    x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                 cfg.norm_offset)
+    with jax.named_scope("attn.q_proj"):
+        q = _dense(x, p["wq"]).astype(h.dtype).reshape(B, S, H, dn + dr)
+        q_pe = apply_rope_interleaved(q[..., dn:], positions, inv_freq, rot)
+        q_abs = jnp.einsum(
+            "bshd,rhd->bshr", q[..., :dn], w_uk,
+            preferred_element_type=jnp.float32,
+        )
+        q_lat = (
+            jnp.concatenate([q_abs, q_pe.astype(jnp.float32)], axis=-1)
+            * mla_softmax_scale(cfg)
+        ).astype(h.dtype)
+    with jax.named_scope("attn.kv_latent"):
+        ckv = _dense(x, p["wkv_a"]).astype(h.dtype)
+        c = rms_norm(ckv[..., :R], p["kv_norm"]["weight"], cfg.rms_norm_eps)
+        k_pe = apply_rope_interleaved(ckv[..., R:], positions, inv_freq, rot)
+    with jax.named_scope("attn.kernel"):
+        res = attn_fn(q_lat, c, k_pe, layer_cache, positions)
+    new_cache = None
+    if isinstance(res, tuple):
+        o_lat, new_cache = res
+    else:
+        o_lat = res
+    with jax.named_scope("attn.out"):
+        a = jnp.einsum(
+            "bshr,rhd->bshd", o_lat, w_uv,
+            preferred_element_type=jnp.float32,
+        ).astype(h.dtype)
+        h = h + _dense(a.reshape(B, S, H * dv), p["wo"])
+    return h, (c, k_pe), new_cache
 
 
 def _layer(
@@ -214,6 +378,7 @@ def _layer(
     attn_fn: AttnFn,
     moe_token_mask=None,
     adapter_ids=None,
+    stacked_experts=None,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -233,33 +398,39 @@ def _layer(
     # --- attention ---
     # the named scopes are what a profiler trace calls these operations,
     # whatever number the compiler gives their fusions
-    with jax.named_scope("attn.qkv"):
-        x = rms_norm(
-            h, p["attn_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset
-        )
-        q = _dense(x, p["wq"], adapter_ids).reshape(B, S, H, D)
-        k = _dense(x, p["wk"], adapter_ids).reshape(B, S, KVH, D)
-        v = _dense(x, p["wv"], adapter_ids).reshape(B, S, KVH, D)
-        if cfg.qk_norm:
-            q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
-            k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
-    with jax.named_scope("attn.kernel"):
-        res = attn_fn(q, k, v, layer_cache, positions)
-    new_cache = None
-    if isinstance(res, tuple):
-        attn_out, new_cache = res
+    if cfg.is_mla:
+        h, (k, v), new_cache = _mla_attention(
+            h, p, layer_cache, cfg, positions, inv_freq, attn_fn)
     else:
-        attn_out = res
-    with jax.named_scope("attn.out"):
-        h = h + _dense(attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
+        with jax.named_scope("attn.qkv"):
+            x = rms_norm(
+                h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                cfg.norm_offset,
+            )
+            q = _dense(x, p["wq"], adapter_ids).reshape(B, S, H, D)
+            k = _dense(x, p["wk"], adapter_ids).reshape(B, S, KVH, D)
+            v = _dense(x, p["wv"], adapter_ids).reshape(B, S, KVH, D)
+            if cfg.qk_norm:
+                q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
+                k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
+        with jax.named_scope("attn.kernel"):
+            res = attn_fn(q, k, v, layer_cache, positions)
+        new_cache = None
+        if isinstance(res, tuple):
+            attn_out, new_cache = res
+        else:
+            attn_out = res
+        with jax.named_scope("attn.out"):
+            h = h + _dense(
+                attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
 
-    # --- mlp ---
+    # --- mlp: the layer's kind is what its weights are ---
     x = rms_norm(h, p["mlp_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
     act = _act(cfg.hidden_act)
-    moe_dropped = jnp.int32(0)
-    if cfg.num_experts > 0:
+    moe_stats = None
+    if "router" in p:
         from helix_tpu.models.moe import moe_ffn
 
         router_w = p["router"]["weight"]
@@ -269,67 +440,91 @@ def _layer(
             router_w = router_w.astype(jnp.float32) * p["router"][
                 "scale"
             ].astype(jnp.float32)
-        moe_out, moe_dropped = moe_ffn(
-            x, router_w, p["experts"], cfg, act,
+        moe_out, moe_stats = moe_ffn(
+            x, router_w, p.get("experts"), cfg, act,
             token_mask=moe_token_mask,
-            return_dropped=True,
+            return_stats=True,
+            stacked_experts=stacked_experts,
         )
+        if "shared" in p:
+            with jax.named_scope("moe.shared"):
+                moe_out = moe_out + _swiglu(x, p["shared"], act)
         h = h + moe_out
     else:
-        with jax.named_scope("mlp.gate_up"):
-            gate = _dense(x, p["w_gate"], adapter_ids)
-            up = _dense(x, p["w_up"], adapter_ids)
-        with jax.named_scope("mlp.down"):
-            h = h + _dense(act(gate) * up, p["w_down"], adapter_ids)
-    return h, (k, v), new_cache, moe_dropped
+        h = h + _swiglu(x, p, act, adapter_ids, scoped=True)
+    if moe_stats is None:
+        moe_stats = jnp.zeros((4,), jnp.float32)
+    return h, (k, v), new_cache, moe_stats
 
 
 def scan_decoder_blocks(
-    h, layers_params, num_layers: int, block, layer_caches, carry_caches
+    h, layers_params, num_layers: int, block, layer_caches, carry_caches,
+    first_layer: int = 0, with_index: bool = False,
 ):
     """Shared cache-protocol dispatch for decoder towers (llama families +
     the Qwen2-VL mrope tower share this so the two protocols cannot
-    diverge).
+    diverge), over ONE stack of layers of one kind.
 
     ``block(h, layer_params, layer_cache) -> (h, (k, v), new_cache,
-    moe_dropped)``.
+    moe_stats)``.
 
     - xs mode (``layer_caches`` or no cache): the scan slices a per-layer
-      cache view; returns (h, kv, moe_dropped) with kv stacked [L, ...]
+      cache view; returns (h, kv, moe_stats) with kv stacked [L, ...]
       for the caller's scatter.
     - carry mode (``carry_caches``): the full cache pytree threads through
-      the scan carry and block's attn_fn receives ``(caches, layer_idx)``;
-      returns (h, final_caches, moe_dropped).
+      the scan carry and block's attn_fn receives ``(caches, layer_idx)``,
+      the index counted from ``first_layer`` (a model of two stacks runs
+      the pool's layer index through both); returns (h, final_caches,
+      moe_stats).
 
-    ``moe_dropped`` is the int32 total of MoE capacity-overflow drops
-    summed over all layers (0 for dense towers).
+    ``moe_stats`` is what each layer's block gave, stacked ``[L, ...]``
+    (``models.moe.expert_load_stats`` rows; zeros for dense layers).
+    ``with_index``: ``block`` also gets the layer's index IN THIS STACK as
+    a fourth argument (for weights it reads whole, not as a scan slice).
     """
+    idx = jnp.arange(num_layers, dtype=jnp.int32)
+
+    def call(h, layer_params, cache, i):
+        if with_index:
+            return block(h, layer_params, cache, i)
+        return block(h, layer_params, cache)
+
     if carry_caches is not None:
         def carry_body(carry, xs):
-            h, caches, drops = carry
-            layer_params, lyr = xs
-            h, _, caches, d = block(h, layer_params, (caches, lyr))
-            return (h, caches, drops + d), None
+            h, caches = carry
+            layer_params, i = xs
+            h, _, caches, d = call(
+                h, layer_params, (caches, first_layer + i), i)
+            return (h, caches), d
 
-        xs = (layers_params, jnp.arange(num_layers, dtype=jnp.int32))
-        (h, kv, dropped), _ = jax.lax.scan(
-            carry_body, (h, carry_caches, jnp.int32(0)), xs
-        )
+        xs = (layers_params, idx)
+        (h, kv), stats = jax.lax.scan(carry_body, (h, carry_caches), xs)
     else:
         def scan_body(h, xs):
-            layer_params, layer_cache = xs
-            h, kv, _, d = block(h, layer_params, layer_cache)
+            layer_params, layer_cache, i = xs
+            h, kv, _, d = call(h, layer_params, layer_cache, i)
             return h, (kv, d)
 
         if layer_caches is None:
             # lax.scan needs every xs leaf to have a leading L dim; "no
             # history" is a zero-length dummy the attn_fn never touches.
             layer_caches = jnp.zeros((num_layers, 0), jnp.int32)
-        h, (kv, drops) = jax.lax.scan(
-            scan_body, h, (layers_params, layer_caches)
+        h, (kv, stats) = jax.lax.scan(
+            scan_body, h, (layers_params, layer_caches, idx)
         )
-        dropped = jnp.sum(drops)
-    return h, kv, dropped
+    return h, kv, stats
+
+
+def layer_stacks(params: Params, cfg: ModelConfig) -> list:
+    """``[(stacked layer weights, layer count)]`` in layer order: the
+    leading dense layers, if the model has them, then the rest."""
+    stacks = []
+    n_dense = 0
+    if "dense_layers" in params:
+        n_dense = cfg.first_k_dense
+        stacks.append((params["dense_layers"], n_dense))
+    stacks.append((params["layers"], cfg.num_layers - n_dense))
+    return stacks
 
 
 def forward(
@@ -344,8 +539,9 @@ def forward(
     return_hidden: bool = False,
     moe_token_mask=None,  # [B, S] bool: MoE routing validity (padding /
                           # inactive decode slots never consume capacity)
-    return_moe_stats: bool = False,  # also return {"dropped": int32} —
-                          # MoE capacity-overflow drops summed over layers
+    return_moe_stats: bool = False,  # also return {"dropped": int32,
+                          # "vector": f32[4]}: drops summed over layers,
+                          # and the step's routing load
     adapter_ids=None,     # [B, S] i32: per-token multi-LoRA pool slot
                           # (0 = identity); None = no batched adapters
 ):
@@ -366,21 +562,48 @@ def forward(
     from helix_tpu.ops.quant import embed_lookup
 
     inv_freq = jnp.asarray(
-        rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+        rope_frequencies(
+            cfg.qk_rope_head_dim if cfg.is_mla else cfg.head_dim,
+            cfg.rope_theta, cfg.rope_scaling)
     )
     h = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
 
-    def block(h, layer_params, layer_cache):
-        return _layer(
-            h, layer_params, layer_cache, cfg, positions, inv_freq,
-            attn_fn, moe_token_mask=moe_token_mask,
-            adapter_ids=adapter_ids,
-        )
+    kvs, stats, first = [], [], 0
+    for stack, n in layer_stacks(params, cfg):
+        whole = None
+        if "experts" in stack and cfg.expert_capacity_factor <= 0:
+            # the grouped product is a Mosaic kernel: it reads its weights
+            # from a whole buffer, and a scan's per-layer slice of the
+            # stacked experts would be copied out for it (184 MB a
+            # projection a layer).  So the experts stay out of the scan's
+            # slices and the layer is picked by its groups (models/moe.py).
+            whole = stack["experts"]
+            stack = {k: v for k, v in stack.items() if k != "experts"}
 
-    h, kv, moe_dropped = scan_decoder_blocks(
-        h, params["layers"], cfg.num_layers, block, layer_caches,
-        carry_caches,
-    )
+        def block(h, layer_params, layer_cache, i, whole=whole):
+            return _layer(
+                h, layer_params, layer_cache, cfg, positions, inv_freq,
+                attn_fn, moe_token_mask=moe_token_mask,
+                adapter_ids=adapter_ids,
+                stacked_experts=None if whole is None else (whole, i),
+            )
+
+        h, kv, st = scan_decoder_blocks(
+            h, stack, n, block,
+            None if layer_caches is None else jax.tree.map(
+                lambda c: c[first:first + n], layer_caches),
+            carry_caches, first_layer=first, with_index=True,
+        )
+        if carry_caches is not None:
+            carry_caches = kv
+        kvs.append(kv)
+        stats.append(st)
+        first += n
+    if carry_caches is not None or len(kvs) == 1:
+        kv = kvs[-1]
+    else:
+        kv = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *kvs)
+    stats = jnp.concatenate(stats, axis=0)                   # [L, 4]
     h = rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
     if return_hidden:
         return h, kv
@@ -406,14 +629,36 @@ def forward(
                 logits / cfg.logits_soft_cap
             )
     if return_moe_stats:
-        return logits, kv, {"dropped": moe_dropped}
+        return logits, kv, {
+            "dropped": jnp.sum(stats[:, 0]).astype(jnp.int32),
+            # [dropped, routed, busiest expert over the mean (max over
+            # layers), distinct experts touched (mean over MoE layers)]
+            "vector": jnp.stack([
+                jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]),
+                jnp.max(stats[:, 2]),
+                jnp.sum(stats[:, 3]) / max(cfg.num_moe_layers, 1),
+            ]),
+        }
     return logits, kv
 
 
 def prefill_attn_fn(q, k, v, layer_cache, positions, *, segment_ids=None,
                     backend=None, soft_cap=None):
-    """Self-attention over the freshly computed K/V (no history)."""
+    """Self-attention over the freshly computed K/V (no history).  For a
+    latent-attention model ``k``/``v`` are the latent and the rope key
+    (``_mla_attention``) and the plain absorbed-form reference runs."""
     from helix_tpu.ops.attention import attention
+
+    if k.ndim == 3:
+        from helix_tpu.ops.paged import mla_attention_reference
+
+        seg = (jnp.ones_like(positions) if segment_ids is None
+               else segment_ids)
+        return jax.vmap(
+            lambda q1, c1, r1, p1, s1: mla_attention_reference(
+                q1, c1, r1, q_positions=p1, kv_positions=p1,
+                q_segment_ids=s1, kv_segment_ids=s1)
+        )(q, k, v, positions, seg)
 
     return attention(
         q, k, v,

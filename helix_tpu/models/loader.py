@@ -116,6 +116,9 @@ def load_params(
         return np.stack([fn(i) for i in range(L)])
 
     pfx = "model.layers.{}."
+    if cfg.is_mla:
+        params = _deepseek_v2_tree(cfg, shards, get, linear_t)
+        return cfg, _place_tree(params, cfg, mesh, logical_axes, quantize)
     fused_qkv = f"{pfx.format(0)}self_attn.qkv_proj.weight" in shards
     fused_mlp = f"{pfx.format(0)}mlp.gate_up_proj.weight" in shards
 
@@ -217,6 +220,18 @@ def load_params(
                 "weight": np.ascontiguousarray(params["embed"]["weight"].T)
             }
 
+    return cfg, _place_tree(params, cfg, mesh, logical_axes, quantize)
+
+
+def _place_tree(params, cfg, mesh, logical_axes, quantize):
+    """A host tree onto the device(s): sharded under a mesh, int8 tensor
+    by tensor with ``quantize``."""
+    import jax
+    import jax.numpy as jnp
+
+    from helix_tpu.models.llama import param_logical_axes
+    from helix_tpu.parallel.sharding import _prune_spec_for_mesh, spec_for
+
     axes = None
     if mesh is not None:
         from jax.sharding import NamedSharding
@@ -232,9 +247,70 @@ def load_params(
     if quantize:
         from helix_tpu.ops.quant import quantize_params_streamed
 
-        params = quantize_params_streamed(params, place, axes)
-    elif mesh is not None:
-        params = jax.tree.map(place, params, axes)
-    else:
-        params = jax.tree.map(jnp.asarray, params)
-    return cfg, params
+        return quantize_params_streamed(params, place, axes)
+    if mesh is not None:
+        return jax.tree.map(place, params, axes)
+    return jax.tree.map(jnp.asarray, params)
+
+
+def _deepseek_v2_tree(cfg, shards, get, linear_t):
+    """DeepSeek-V2's tensor names (``model_type: deepseek_v2``, direct query
+    projection) into the two-stack tree of ``init_params``: the leading
+    dense layers under ``dense_layers``, the expert layers under ``layers``.
+    Projections keep the published column order (rope pairs interleaved:
+    the program rotates them as published)."""
+    pfx = "model.layers.{}."
+    n_dense = cfg.first_k_dense
+
+    def stack_of(ids, moe):
+        def st(fn):
+            return np.stack([fn(i) for i in ids])
+
+        def lin(name):
+            return {"weight": st(lambda i: linear_t(pfx.format(i) + name))}
+
+        def mlp(at):
+            return {
+                "w_gate": lin(at + "gate_proj.weight"),
+                "w_up": lin(at + "up_proj.weight"),
+                "w_down": lin(at + "down_proj.weight"),
+            }
+
+        lp = {
+            "attn_norm": {"weight": st(
+                lambda i: get(pfx.format(i) + "input_layernorm.weight"))},
+            "mlp_norm": {"weight": st(lambda i: get(
+                pfx.format(i) + "post_attention_layernorm.weight"))},
+            "wq": lin("self_attn.q_proj.weight"),
+            "wkv_a": lin("self_attn.kv_a_proj_with_mqa.weight"),
+            "kv_norm": {"weight": st(lambda i: get(
+                pfx.format(i) + "self_attn.kv_a_layernorm.weight"))},
+            "wkv_b": lin("self_attn.kv_b_proj.weight"),
+            "wo": lin("self_attn.o_proj.weight"),
+        }
+        if not moe:
+            lp.update(mlp("mlp."))
+            return lp
+        lp["router"] = lin("mlp.gate.weight")
+        lp["experts"] = {
+            ours: {"weight": st(lambda i, t=theirs: np.stack([
+                linear_t(pfx.format(i) + f"mlp.experts.{e}.{t}.weight")
+                for e in range(cfg.num_experts)]))}
+            for ours, theirs in (("w_gate", "gate_proj"),
+                                 ("w_up", "up_proj"),
+                                 ("w_down", "down_proj"))
+        }
+        if cfg.num_shared_experts:
+            lp["shared"] = mlp("mlp.shared_experts.")
+        return lp
+
+    params = {
+        "embed": {"weight": get("model.embed_tokens.weight")},
+        "layers": stack_of(range(n_dense, cfg.num_layers), True),
+        "final_norm": {"weight": get("model.norm.weight")},
+    }
+    if n_dense:
+        params["dense_layers"] = stack_of(range(n_dense), False)
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = {"weight": linear_t("lm_head.weight")}
+    return params

@@ -1,22 +1,26 @@
-"""Mixture-of-experts FFN: GShard-style top-k dispatch on the MXU.
+"""Mixture-of-experts FFN: one router, two dispatches.
 
-The reference serves Mixtral through vLLM's fused CUDA MoE kernels
-(SURVEY.md §2.2 model families); the TPU-native formulation is the
-GShard/Switch dispatch algebra — everything is dense einsums over a
-``[experts, capacity]`` buffer, so XLA tiles it onto the MXU and, when
-the mesh carries an ``ep`` axis, shards the expert dimension and inserts
-the all-to-alls (the layout jax-ml's scaling guidance prescribes for
-MoE):
+- **Router** (``route``): per-token logits over the experts in fp32.
+  Mixtral: top-k of the logits, softmax over the k (``moe_renormalize``).
+  DeepSeek-V2: softmax over ALL experts, the top-k probabilities kept as
+  they are, times ``routed_scaling_factor``.
+- **Dropless grouped dispatch** (``expert_capacity_factor == 0``, what
+  DeepSeek configs get): the ``T * k`` (token, choice) assignments are
+  sorted by expert and each projection is ONE grouped matrix product over
+  the experts' stacked weights (``jax.lax.ragged_dot``: on a TPU a Mosaic
+  kernel that walks the groups, with int8 weights fed to it as stored).
+  Compute follows the tokens routed, at every shape: prefill, chunk,
+  decode, mixed.  Nothing is ever dropped.  Padding rows and idle slots
+  (``token_mask`` False) are sorted past the last group and multiply
+  nothing.
+- **Capacity dispatch** (``expert_capacity_factor > 0``, Mixtral): the
+  GShard/Switch algebra, dense einsums over an ``[experts, capacity]``
+  buffer, which an ``ep`` mesh axis shards with all-to-alls.  Each expert
+  processes at most ``C = factor * T * k / X`` tokens a call; overflow is
+  dropped from that expert and counted; decode (S == 1) runs with C = T.
 
-- router: per-token logits over experts, softmax, top-k;
-- capacity: each expert processes at most ``C = factor * T * k / X``
-  tokens per call — a STATIC shape, which is the whole point: ragged
-  per-expert batches don't exist under jit. Tokens that overflow an
-  expert's capacity are dropped from that expert (their combine weight
-  is zero) and ride the residual stream, the standard GShard fallback;
-- dispatch/combine: one-hot ``[T, X, C]`` masks move tokens into and out
-  of the expert buffers with two einsums; the expert FFNs themselves are
-  a single batched SwiGLU over stacked ``[X, E, F]`` weights.
+``moe_ffn`` returns the routed experts' sum only; a shared expert is a
+dense MLP the layer adds beside it (``models/llama.py``).
 """
 
 from __future__ import annotations
@@ -40,29 +44,117 @@ def _expert_dense(h_in, wp, spec):
     return out
 
 
+def route(xf, router_p, cfg):
+    """Router: ``xf [T, E]`` -> ``(weights [T, k] f32, experts [T, k])``."""
+    k = cfg.num_experts_per_tok
+    logits = jnp.dot(
+        xf.astype(jnp.float32), router_p.astype(jnp.float32)
+    )                                               # [T, X]
+    if cfg.moe_renormalize:
+        top_vals, top_idx = jax.lax.top_k(logits, k)
+        return jax.nn.softmax(top_vals, axis=-1), top_idx
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_idx = jax.lax.top_k(probs, k)
+    return top_w * cfg.routed_scaling_factor, top_idx
+
+
+def expert_load_stats(top_idx, valid, X, dropped=0):
+    """``[dropped, routed, busiest expert's tokens over the mean, distinct
+    experts touched]`` of one layer's routing, f32."""
+    load = jnp.sum(
+        jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
+        * valid[:, None, None].astype(jnp.float32), axis=(0, 1)
+    )                                               # [X]
+    routed = jnp.sum(load)
+    ratio = jnp.max(load) * X / jnp.maximum(routed, 1.0)
+    return jnp.stack([
+        jnp.asarray(dropped, jnp.float32), routed, ratio,
+        jnp.sum((load > 0).astype(jnp.float32)),
+    ])
+
+
+def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
+                     layer=None):
+    """Dropless: sort the assignments by expert, three grouped products,
+    unsort, weighted sum over each token's k choices.
+
+    ``layer`` (a traced index): ``experts_p`` is then a whole STACK of
+    layers' experts (``[n, X, ...]`` leaves) read as ``n * X`` groups of
+    which only this layer's X have rows: the kernel takes the stack's
+    buffer as it is, where a per-layer slice would be copied out for it."""
+    T, E = xf.shape
+    k = top_idx.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        # invalid tokens go to a sentinel past the last expert: they sort
+        # to the end, belong to no group and are multiplied by nothing
+        flat_e = jnp.where(valid[:, None], top_idx, X).reshape(-1)  # [T*k]
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        group_sizes = jnp.sum(
+            jax.nn.one_hot(flat_e, X, dtype=jnp.int32), axis=0
+        )                                                           # [X]
+        xs = xf[order // k]                                         # [T*k, E]
+        e_row = jnp.minimum(sorted_e, X - 1)
+
+    sizes = group_sizes
+    if layer is not None:
+        n_stack = experts_p["w_gate"]["weight"].shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_stack * X,), group_sizes.dtype), group_sizes,
+            (layer * X,))
+
+    def grouped(h, wp):
+        w = wp["weight"]
+        scale = wp.get("scale")
+        if layer is not None:
+            w = w.reshape((-1,) + w.shape[2:])        # [n * X, in, out]
+            scale = None if scale is None else scale[layer]
+        out = jax.lax.ragged_dot(
+            h, w, sizes,
+            preferred_element_type=jnp.float32,
+            # int8 weights meet the activations as stored (the kernel
+            # converts them in VMEM); there is no higher-precision product
+            # of that pair for a global matmul precision to ask for
+            precision=(jax.lax.Precision.DEFAULT
+                       if w.dtype == jnp.int8 else None),
+        )
+        if scale is not None:
+            # [X, 1, out] per-output-channel scales, one row per sorted
+            # assignment
+            out = out * scale[e_row, 0].astype(jnp.float32)
+        return out
+
+    with jax.named_scope("moe.experts"):
+        gate = grouped(xs, experts_p["w_gate"])
+        up = grouped(xs, experts_p["w_up"])
+        y = grouped((act(gate) * up).astype(xf.dtype), experts_p["w_down"])
+    with jax.named_scope("moe.combine"):
+        # rows past the last group hold whatever the kernel left there
+        y = jnp.where((sorted_e < X)[:, None], y, 0.0)
+        y = y[jnp.argsort(order)].reshape(T, k, E)
+        w = top_w * valid[:, None].astype(top_w.dtype)
+        return jnp.einsum("tk,tke->te", w, y)
+
+
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
-            return_dropped=False):
-    """x: [B, S, E] -> [B, S, E] (or ``(out, dropped)`` with
-    ``return_dropped``: the int32 count of (token, choice) routing
-    assignments this call dropped to capacity overflow — the tokens that
-    silently ride the residual stream instead of their expert.  Decode is
-    dropless (C = T), so only prefill shapes ever report > 0).
+            return_dropped=False, return_stats=False, stacked_experts=None):
+    """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
+    ``return_dropped`` also the int32 count of (token, choice) assignments
+    this call dropped to capacity overflow (always 0 on the dropless
+    path); with ``return_stats`` instead ``expert_load_stats``'s vector.
 
     router_p: [E, X] (dequantised); experts_p: {"w_gate"/"w_up":
-    {"weight": [X, E, F][, "scale"]}, "w_down": {...}} — int8
-    weight-only trees pass through unchanged.
+    {"weight": [X, E, F][, "scale"]}, "w_down": {...}}: int8 weight-only
+    trees pass through unchanged.
 
     token_mask [B, S] (optional): False tokens (padding, inactive decode
-    slots) are EXCLUDED from routing entirely — they consume no expert
-    capacity, so a request's outputs never depend on garbage riding the
-    same batch.
+    slots) are EXCLUDED from routing entirely, so a request's outputs
+    never depend on garbage riding the same batch.
 
-    Capacity: C = factor * T * k / X for prefill shapes; decode (S == 1)
-    runs DROPLESS (C = T) — the buffers are tiny at decode batch sizes
-    and per-token determinism matters more than the dispatch saving."""
+    ``stacked_experts = (experts of a whole layer stack, this layer's
+    index in it)`` instead of ``experts_p`` (dropless path only)."""
     B, S, E = x.shape
     X = cfg.num_experts
-    k = cfg.num_experts_per_tok
     T = B * S
     xf = x.reshape(T, E)
     valid = (
@@ -70,14 +162,34 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
         if token_mask is None
         else token_mask.reshape(T)
     )
+    with jax.named_scope("moe.router"):
+        top_w, top_idx = route(xf, router_p, cfg)
+    if cfg.expert_capacity_factor > 0:
+        out, dropped = _capacity_experts(
+            xf, top_w, top_idx, valid, experts_p, cfg, act, S
+        )
+    else:
+        if stacked_experts is not None:
+            experts_p, layer = stacked_experts
+        else:
+            layer = None
+        out = _grouped_experts(
+            xf, top_w, top_idx, valid, experts_p, X, act, layer)
+        dropped = jnp.int32(0)
+    out = out.reshape(B, S, E).astype(x.dtype)
+    if return_stats:
+        return out, expert_load_stats(top_idx, valid, X, dropped)
+    if return_dropped:
+        return out, dropped
+    return out
 
-    # --- router (fp32 for a stable softmax over few logits) ---
-    logits = jnp.dot(
-        xf.astype(jnp.float32), router_p.astype(jnp.float32)
-    )                                               # [T, X]
-    top_vals, top_idx = jax.lax.top_k(logits, k)    # [T, k]
-    top_w = jax.nn.softmax(top_vals, axis=-1)       # renormalised over k
 
+def _capacity_experts(xf, top_w, top_idx, valid, experts_p, cfg, act, S):
+    """GShard capacity dispatch.  C = factor * T * k / X for prefill
+    shapes; decode (S == 1) runs DROPLESS (C = T)."""
+    T, E = xf.shape
+    X = cfg.num_experts
+    k = cfg.num_experts_per_tok
     # --- capacity + position of each (token, choice) in its expert ---
     if S == 1:
         C = T                                        # dropless decode
@@ -109,7 +221,7 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     # expert x; combine carries the softmax weight on the same support
     dispatch = jnp.zeros((T, X, C), jnp.float32)
     combine = jnp.zeros((T, X, C), jnp.float32)
-    for j in range(k):      # k is 2: an unrolled static loop
+    for j in range(k):      # an unrolled static loop (Mixtral: 2)
         sel = (
             jax.nn.one_hot(top_idx[:, j], X, dtype=jnp.float32)[:, :, None]
             * jax.nn.one_hot(pos[:, j], C, dtype=jnp.float32)[:, None, :]
@@ -120,16 +232,12 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
 
     # --- expert buffers + batched SwiGLU over stacked weights ---
     expert_in = jnp.einsum(
-        "txc,te->xce", dispatch.astype(x.dtype), xf
+        "txc,te->xce", dispatch.astype(xf.dtype), xf
     )                                                       # [X, C, E]
     gate = _expert_dense(expert_in, experts_p["w_gate"], "xce,xef->xcf")
     up = _expert_dense(expert_in, experts_p["w_up"], "xce,xef->xcf")
     h = _expert_dense(
-        (act(gate) * up).astype(x.dtype), experts_p["w_down"],
+        (act(gate) * up).astype(xf.dtype), experts_p["w_down"],
         "xcf,xfe->xce",
     )                                                       # [X, C, E]
-    out = jnp.einsum("txc,xce->te", combine, h)
-    out = out.reshape(B, S, E).astype(x.dtype)
-    if return_dropped:
-        return out, dropped
-    return out
+    return jnp.einsum("txc,xce->te", combine, h), dropped

@@ -401,3 +401,101 @@ def ragged_paged_attention(
         cold_row=cold_row, cold_len=cold_len,
         cold_k_scale=cold_k_scale, cold_v_scale=cold_v_scale,
     )
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pools: one cached vector a token, shared by every head
+# ---------------------------------------------------------------------------
+
+
+def mla_attention_reference(q, c, k_pe, *, q_positions, kv_positions,
+                            q_segment_ids, kv_segment_ids, scale=1.0):
+    """Absorbed-form latent attention, plain softmax, in f32.
+
+    ``q [Tq, H, R + dr]`` (absorbed query | rope query), ``c [Tk, R]`` the
+    normed latents, ``k_pe [Tk, dr]`` the shared rope keys: every head
+    scores against the same ``[c | k_pe]`` and the values are ``c``.
+    Token t sees key s iff the segment ids agree (non-zero) and
+    ``kv_positions[s] <= q_positions[t]``.  Returns ``[Tq, H, R]``."""
+    R = c.shape[-1]
+    qf = q.astype(jnp.float32)
+    cf = c.astype(jnp.float32)
+    s = (
+        jnp.einsum("qhr,kr->hqk", qf[..., :R], cf)
+        + jnp.einsum("qhd,kd->hqk", qf[..., R:], k_pe.astype(jnp.float32))
+    ) * scale
+    mask = (q_positions[:, None] >= kv_positions[None, :]) & (
+        q_segment_ids[:, None] == kv_segment_ids[None, :]
+    ) & (kv_segment_ids[None, :] > 0)
+    s = jnp.where(mask[None], s, DEFAULT_MASK_VALUE)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask[None], jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("hqk,kr->qhr", p / jnp.where(l > 0, l, 1.0), cf)
+    return out.astype(q.dtype)
+
+
+def mla_ragged_paged_attention_reference(
+    q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist, tables, *,
+    scale: float = 1.0,
+):
+    """XLA gather oracle of ``mla_ragged_paged_attention``: gathers each
+    row's pages, masks beyond its history, one plain softmax."""
+    T = q.shape[0]
+    R, maxP = tables.shape
+    P = c_pages.shape[2]
+    dr = r_new.shape[-1]
+    Hs = maxP * P
+    row, q_off = _row_of_tokens(t0, q_len, T)
+    q_pos = jnp.where(row >= 0, hist[jnp.clip(row, 0)] + q_off, 0)
+    ch = c_pages[layer][tables].reshape(R * Hs, -1)
+    rh = r_pages[layer][tables].reshape(R * Hs, -1)[:, :dr]
+    hist_tok = jnp.arange(Hs)
+    seg_h = jnp.where(
+        hist_tok[None, :] < hist[:, None], jnp.arange(R)[:, None] + 1, 0
+    ).reshape(R * Hs)
+    pos_h = jnp.broadcast_to(hist_tok[None, :], (R, Hs)).reshape(R * Hs)
+    seg_fresh = jnp.where(row >= 0, row + 1, 0)
+    return mla_attention_reference(
+        q,
+        jnp.concatenate([ch.astype(q.dtype), c_new.astype(q.dtype)]),
+        jnp.concatenate([rh.astype(q.dtype), r_new.astype(q.dtype)]),
+        q_positions=q_pos,
+        kv_positions=jnp.concatenate([pos_h, q_pos]),
+        q_segment_ids=seg_fresh,
+        kv_segment_ids=jnp.concatenate([seg_h, seg_fresh]),
+        scale=scale,
+    )
+
+
+def mla_ragged_paged_attention(
+    q,            # [T, H, R + dr] absorbed | rope queries, flat over rows
+    c_new,        # [T, R] fresh normed latents (attended raw)
+    r_new,        # [T, dr] fresh rope keys
+    c_pages,      # [L, N, P, R] latent pool (``PagedKVCache.k_pages``)
+    r_pages,      # [L, N, P, >= dr] rope-key pool, lane-padded (``v_pages``)
+    layer, t0, q_len, hist, tables,
+    *,
+    scale: float = 1.0,
+    backend: Optional[str] = None,
+    max_q_len: Optional[int] = None,
+):
+    """Ragged paged attention over a LATENT pool, under the row contract of
+    ``ragged_paged_attention`` (decode, chunk with history, mixed, verify
+    and cold rows are metadata): H query heads against one cached
+    ``[c | k_pe]`` a token, the values a view of the keys (``c``).
+    Returns the attended latents ``[T, H, R]``.  ``max_q_len`` is a static
+    bound on a row's fresh tokens (1 for plain decode), which lets the
+    kernel size its query blocks.  The Pallas kernel on a TPU, the gather
+    oracle on a CPU or for ``backend="reference"``."""
+    if resolve_backend(backend) == "pallas":
+        from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
+
+        return mla_ragged_paged_attention_tpu(
+            q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist,
+            tables, scale=scale, max_q_len=max_q_len,
+        )
+    return mla_ragged_paged_attention_reference(
+        q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist, tables,
+        scale=scale,
+    )

@@ -806,6 +806,7 @@ class EngineLoop:
             "prefill_padding_ratio": self.padding_ratio(),
             "mixed_steps": getattr(eng, "num_mixed_steps", 0),
             "moe_dropped_tokens": getattr(eng, "moe_dropped_tokens", 0),
+            "moe_routed_tokens": getattr(eng, "moe_routed_tokens", 0),
             "spec_steps": getattr(eng, "num_spec_steps", 0),
             "spec_drafted_tokens": getattr(
                 eng, "num_spec_drafted_tokens", 0
@@ -1483,6 +1484,12 @@ class EngineLoop:
             "queue_depth": self.queue_depth(),
             "kv_pages_used": getattr(eng, "kv_pages_used", 0),
             "kv_pages_free": eng.allocator.free_pages,
+            # of the last MoE step the host has read (0 for dense models):
+            # the busiest expert's tokens over the mean, and the distinct
+            # experts a step touched (mean over the MoE layers)
+            "moe_expert_load_max_ratio": getattr(
+                eng, "moe_expert_load_max_ratio", 0.0),
+            "moe_experts_touched": getattr(eng, "moe_experts_touched", 0.0),
             "prefill_tokens": prefill,
             "padding_tokens": (
                 getattr(eng, "num_prefill_padding_tokens", 0) - pad0

@@ -370,6 +370,25 @@ class OpenAIServer:
                 "helix_moe_dropped_tokens_total",
                 getattr(eng, "moe_dropped_tokens", 0), lbl,
             )
+            if getattr(eng.model_cfg, "num_experts", 0):
+                # the routing load, from the small array each MoE step
+                # returns with its tokens: (token, choice) assignments
+                # routed; of the last step read, the busiest expert's
+                # tokens over the mean and the distinct experts touched
+                # (mean over the MoE layers: it decides a decode step's
+                # weight bytes)
+                c.counter(
+                    "helix_moe_routed_tokens_total",
+                    getattr(eng, "moe_routed_tokens", 0), lbl,
+                )
+                c.gauge(
+                    "helix_moe_expert_load_max_ratio",
+                    getattr(eng, "moe_expert_load_max_ratio", 0.0), lbl,
+                )
+                c.gauge(
+                    "helix_moe_experts_touched",
+                    getattr(eng, "moe_experts_touched", 0.0), lbl,
+                )
             # speculative decoding (ISSUE 5): host-drafted tokens, the
             # subset the verify pass accepted, lifetime acceptance, and
             # slots the per-request EMA currently benches

@@ -189,3 +189,103 @@ def test_one_kv_head_per_chip_is_a_typed_error():
     check_geometry(14, 2, 128, 2)          # tp=2
     with pytest.raises(UnsupportedKernelGeometry, match="sublane pack"):
         check_geometry(7, 1, 128, 2)       # tp=4
+
+
+# ---- DeepSeek-V2-Lite: the latent kernel and the expert step ---------------
+
+MLA_SHAPES = {  # tokens, rows, a row's most fresh tokens
+    "decode": (64, 64, 1),
+    "chunk_with_history": (512, 1, 512),
+    "packed_cold": (512, 64, 512),
+    "verify": (256, 64, 4),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(MLA_SHAPES))
+def test_latent_kernel_compiles_at_the_published_geometry(one_chip, shape):
+    from helix_tpu.ops.paged import mla_ragged_paged_attention
+
+    T, R, mq = MLA_SHAPES[shape]
+    H, lat, rope, L, pages, max_pages = 16, 512, 64, 17, 10240, 160
+
+    def S(shp, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    args = (S((T, H, lat + rope)), S((T, lat)), S((T, rope)),
+            S((L, pages, PAGE, lat)), S((L, pages, PAGE, 128)),
+            S((), jnp.int32), S((R,), jnp.int32), S((R,), jnp.int32),
+            S((R,), jnp.int32), S((R, max_pages), jnp.int32))
+    compiled = jax.jit(
+        lambda *a: mla_ragged_paged_attention(
+            *a, backend="pallas", max_q_len=mq)
+    ).lower(*args).compile()
+    # the name a trace finds the kernel by (benchmark/metrics/*.mla*.json)
+    assert "mla_ragged_paged_attention_tpu" in compiled.as_text()
+
+
+def test_latent_geometry_mosaic_refuses_is_a_typed_error():
+    from helix_tpu.ops.mla_kernel import check_mla_geometry
+    from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
+
+    with pytest.raises(UnsupportedKernelGeometry, match="128 lanes"):
+        check_mla_geometry(16, 576, 64)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_expert_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of DeepSeek-V2-Lite (the dense layer and two
+    expert layers of the seventeen, int8 weights, 64 slots) for the
+    described chip: the latent kernel, the grouped expert product with
+    int8 weights as stored, the row scatter into the latent pool."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import DEEPSEEK_V2_LITE
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(DEEPSEEK_V2_LITE, num_layers=3)
+    B, max_pages, pages = 64, 160, 10240
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    ks, vs = CacheConfig(num_pages=pages).page_shapes(cfg)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "mla_ragged_paged_attention_tpu" in text
+    assert "ragged-dot" in text or "ragged_dot" in text
+    # the pool is updated in place: no pool-sized temporary
+    pool_bytes = (ks[0] * pages * 16 * (512 + 128)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
