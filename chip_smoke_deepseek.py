@@ -9,7 +9,11 @@ One process holds the chip.  Two phases:
 1. kernel: ``mla_ragged_paged_attention(backend="pallas")`` against its
    ``jax.numpy`` reference at the published geometry (16 heads over a
    latent of 512 + rope 64, pages of 16, bf16): 64 decode rows over ragged
-   histories, a 512-token chunk with history, and packed cold rows.
+   histories, a 512-token chunk with history, and packed cold rows; then
+   the grouped expert product kernel (``ops/grouped_matmul.py``: 64
+   experts of 2048 x 1408, int8 weights, the second layer of a stack of
+   two) against ``lax.ragged_dot`` + scale at 384 decode rows and a
+   3,072-row chunk, gate and up in one call and down after it.
 2. engine: the published widths cut to ``--layers`` (17: what one chip
    serves), int8 weights from ``--seed``, a bf16 latent pool.  A 600-token
    prompt is prefilled in two chunks (the second attends the first through
@@ -58,8 +62,9 @@ from chip_smoke import TOL_BF16, _device_or_die, fail, say  # noqa: E402
 # the reference 0.006; a dropped expert 0.034-0.039; an 8-bit activation
 # path 0.059-0.065.  The median's limit lies between the engine and both
 # faults, the worst step's between the engine's worst and the 8-bit path.
-# A second seed (3000002904) was cut by its call's time limit after one
-# step: 0.015; bf16 alone 0.006; 8-bit 0.073.
+# With the grouped product kernel (PR 29; seeds 3000002981, 3000002982, both
+# to the end): median 0.0158 / 0.0162, worst step 0.027 / 0.035; bf16 alone
+# 0.005-0.010; a dropped expert 0.035-0.038; 8-bit 0.057-0.062.
 TOL_LOGITS = 0.025
 TOL_LOGITS_WORST = 0.045
 
@@ -69,6 +74,8 @@ def phase_kernel(seed, rehearse):
     import jax.numpy as jnp
     import numpy as np
 
+    from helix_tpu.models.moe import experts_pallas, experts_xla
+    from helix_tpu.ops.grouped_matmul import row_tile, visit_plan
     from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
     from helix_tpu.ops.paged import (
         mla_ragged_paged_attention,
@@ -124,6 +131,37 @@ def phase_kernel(seed, rehearse):
             tol=TOL_BF16, ok=good)
     if not ok:
         fail("the latent kernel disagrees with its reference")
+    ok = True
+    X, E, F = (8, 256, 128) if rehearse else (64, 2048, 1408)
+    stack = {
+        name: {"weight": jnp.asarray(rng.integers(
+                   -127, 128, (2, X, k, n), dtype=np.int8)),
+               "scale": jnp.asarray(
+                   rng.random((2, X, 1, n)) * 4e-4 + 1e-4, jnp.float32)}
+        for name, (k, n) in (("w_gate", (E, F)), ("w_up", (E, F)),
+                             ("w_down", (F, E)))}
+    for name, rows, skew in (("decode", 6 * B, 8.0), ("chunk", 6 * S, 1.2)):
+        sizes = rng.multinomial(rows - 7, rng.dirichlet(np.full(X, skew)))
+        xs = jax.random.normal(ks[2], (rows, E)).astype(jnp.bfloat16)
+        tm = row_tile(rows, X)
+        gs = jnp.asarray(sizes, jnp.int32)
+        got = experts_pallas(xs, visit_plan(gs, rows, tm), tm, stack, 1,
+                             jax.nn.silu, rehearse)
+        # each sorted row's group; the last 7 rows belong to none
+        e_row = np.concatenate(
+            [np.repeat(np.arange(X), sizes), np.full(7, X - 1)])
+        want = experts_xla(
+            xs, gs, jnp.asarray(e_row), stack, 1, jax.nn.silu)
+        got, want = (np.asarray(x, np.float32)[:rows - 7]
+                     for x in (got, want))
+        err = float(np.abs(got - want).max() / want.std())
+        good = bool(np.isfinite(got).all() and err <= TOL_BF16)
+        ok &= good
+        say(phase="kernel", op="grouped_matmul", geometry=[X, E, F],
+            shape=name, rows=rows, row_tile=tm, busiest=int(sizes.max()),
+            max_abs_err_over_std=err, tol=TOL_BF16, ok=good)
+    if not ok:
+        fail("the grouped expert product kernel disagrees with ragged_dot")
 
 
 def phase_engine(seed, layers, steps, rehearse):
@@ -170,7 +208,8 @@ def phase_engine(seed, layers, steps, rehearse):
     jax.block_until_ready(params)
     eng = Engine(cfg, params, ecfg)
     say(phase="engine", layers=cfg.num_layers, weights_s=round(
-        time.monotonic() - t, 1), backend=eng._backend)
+        time.monotonic() - t, 1), backend=eng._backend,
+        grouped_backend=eng.grouped_backend)
     prompt = np.random.default_rng(seed).integers(
         1, cfg.vocab_size, size=n_prompt).tolist()
     req = Request(id="smoke", prompt_tokens=prompt,
@@ -273,6 +312,7 @@ def phase_engine(seed, layers, steps, rehearse):
         tol_median=TOL_LOGITS, tol_worst=TOL_LOGITS_WORST, moe_dropped=eng.moe_dropped_tokens,
         moe_routed=eng.moe_routed_tokens, moe_routed_expected=want_routed,
         experts_touched=eng.moe_experts_touched,
+        tile_fill=eng.moe_tile_fill_ratio,
         load_max_ratio=eng.moe_expert_load_max_ratio, ok=ok)
     if not ok and not rehearse:
         fail("the engine and the reference part by more than the "

@@ -834,6 +834,7 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
             # inactive slots never consume expert capacity: outputs
             # are independent of batch-mates (decode is dropless too)
             moe_token_mask=(active > 0)[:, None],
+            moe_backend=backend,
             adapter_ids=(
                 state.adapter_slots[:, None] if use_adapters else None
             ),
@@ -1030,6 +1031,7 @@ def _build_ragged_step_fn(
                     attn_fn=p_attn,
                     carry_caches=(cache.carry(), kacc0, vacc0),
                     moe_token_mask=p_seg > 0,
+                    moe_backend=backend,
                     return_moe_stats=is_moe,
                     adapter_ids=p_aids,
                 )
@@ -1098,6 +1100,7 @@ def _build_ragged_step_fn(
                     attn_fn=s_attn,
                     carry_caches=carry0,
                     moe_token_mask=live,
+                    moe_backend=backend,
                     return_moe_stats=is_moe,
                     adapter_ids=(
                         jnp.broadcast_to(
@@ -1554,6 +1557,17 @@ class Engine:
         self.moe_routed_tokens = 0
         self.moe_expert_load_max_ratio = 0.0
         self.moe_experts_touched = 0.0
+        # of the same step, the dropless grouped product's rows routed
+        # over rows walked (mean over the MoE layers), and which product
+        # runs: "pallas" (ops/grouped_matmul.py) or "xla" (lax.ragged_dot)
+        self.moe_tile_fill_ratio = 0.0
+        self.grouped_backend = None
+        if model_cfg.num_experts and model_cfg.expert_capacity_factor <= 0:
+            from helix_tpu.models.moe import grouped_backend
+
+            E, F = model_cfg.hidden_size, model_cfg.expert_width
+            self.grouped_backend = grouped_backend(
+                [(E, F), (F, E)], self._backend)
         # the step in progress, by named phase (obs.trace.phase): the
         # engine loop clears it at the top of a pass and files it in the
         # flight record; standalone step() callers never read it
@@ -2780,7 +2794,7 @@ class Engine:
 
     def _drain_moe_drops(self) -> None:
         """Fold the queued MoE step stats (``[dropped, routed, load max
-        ratio, experts touched]`` a step, queued un-fetched by
+        ratio, experts touched, tile fill]`` a step, queued un-fetched by
         ``_ragged_step``) into the host counters.  Only arrays the device
         has already produced are read, so a step still in flight is never
         waited for; called after each step's own fetch, which is when its
@@ -2792,12 +2806,13 @@ class Engine:
         self._moe_drop_handles = [
             h for h in self._moe_drop_handles if not h.is_ready()
         ]
-        stats = np.asarray(jax.device_get(ready), np.float64)   # [n, 4]
+        stats = np.asarray(jax.device_get(ready), np.float64)   # [n, 5]
         self.moe_routed_tokens += int(stats[:, 1].sum())
         routed = stats[stats[:, 1] > 0]
         if len(routed):
             self.moe_expert_load_max_ratio = float(routed[-1, 2])
             self.moe_experts_touched = float(routed[-1, 3])
+            self.moe_tile_fill_ratio = float(routed[-1, 4])
         n = int(stats[:, 0].sum())
         if n <= 0:
             return
@@ -4493,6 +4508,8 @@ class Engine:
             padding_tokens=rung - used,
             **({"experts_touched": round(self.moe_experts_touched, 1)}
                if self.model_cfg.num_experts else {}),
+            **({"grouped_backend": self.grouped_backend}
+               if self.grouped_backend else {}),
         ):
             (self.cache, self._dstate, p_first, sampled, emit, extra,
              drops) = fn(

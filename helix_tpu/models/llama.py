@@ -379,6 +379,7 @@ def _layer(
     moe_token_mask=None,
     adapter_ids=None,
     stacked_experts=None,
+    moe_backend=None,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -445,6 +446,7 @@ def _layer(
             token_mask=moe_token_mask,
             return_stats=True,
             stacked_experts=stacked_experts,
+            backend=moe_backend,
         )
         if "shared" in p:
             with jax.named_scope("moe.shared"):
@@ -453,7 +455,7 @@ def _layer(
     else:
         h = h + _swiglu(x, p, act, adapter_ids, scoped=True)
     if moe_stats is None:
-        moe_stats = jnp.zeros((4,), jnp.float32)
+        moe_stats = jnp.zeros((5,), jnp.float32)
     return h, (k, v), new_cache, moe_stats
 
 
@@ -544,6 +546,8 @@ def forward(
                           # and the step's routing load
     adapter_ids=None,     # [B, S] i32: per-token multi-LoRA pool slot
                           # (0 = identity); None = no batched adapters
+    moe_backend=None,     # the dropless experts' grouped product, as the
+                          # attention dispatchers take it (models/moe.py)
 ):
     """Run the decoder.
 
@@ -572,11 +576,12 @@ def forward(
     for stack, n in layer_stacks(params, cfg):
         whole = None
         if "experts" in stack and cfg.expert_capacity_factor <= 0:
-            # the grouped product is a Mosaic kernel: it reads its weights
-            # from a whole buffer, and a scan's per-layer slice of the
-            # stacked experts would be copied out for it (184 MB a
-            # projection a layer).  So the experts stay out of the scan's
-            # slices and the layer is picked by its groups (models/moe.py).
+            # the grouped product is a Pallas kernel (ops/grouped_matmul.py):
+            # it reads its weights from a whole buffer, and a scan's
+            # per-layer slice of the stacked experts would be copied out
+            # for it (184 MB a projection a layer).  So the experts stay
+            # out of the scan's slices and the kernel's block index picks
+            # the layer (a scalar-prefetch operand; models/moe.py).
             whole = stack["experts"]
             stack = {k: v for k, v in stack.items() if k != "experts"}
 
@@ -586,6 +591,7 @@ def forward(
                 attn_fn, moe_token_mask=moe_token_mask,
                 adapter_ids=adapter_ids,
                 stacked_experts=None if whole is None else (whole, i),
+                moe_backend=moe_backend,
             )
 
         h, kv, st = scan_decoder_blocks(
@@ -632,11 +638,13 @@ def forward(
         return logits, kv, {
             "dropped": jnp.sum(stats[:, 0]).astype(jnp.int32),
             # [dropped, routed, busiest expert over the mean (max over
-            # layers), distinct experts touched (mean over MoE layers)]
+            # layers), distinct experts touched and the grouped product's
+            # tile fill (means over MoE layers)]
             "vector": jnp.stack([
                 jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]),
                 jnp.max(stats[:, 2]),
                 jnp.sum(stats[:, 3]) / max(cfg.num_moe_layers, 1),
+                jnp.sum(stats[:, 4]) / max(cfg.num_moe_layers, 1),
             ]),
         }
     return logits, kv

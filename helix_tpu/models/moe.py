@@ -7,8 +7,14 @@
 - **Dropless grouped dispatch** (``expert_capacity_factor == 0``, what
   DeepSeek configs get): the ``T * k`` (token, choice) assignments are
   sorted by expert and each projection is ONE grouped matrix product over
-  the experts' stacked weights (``jax.lax.ragged_dot``: on a TPU a Mosaic
-  kernel that walks the groups, with int8 weights fed to it as stored).
+  the experts' stacked weights, int8 weights read as stored.  On a TPU it
+  is this repo's Pallas kernel (``ops/grouped_matmul.py``: a grid step is
+  one (row tile, expert) visit against the expert's whole ``[K, N]``
+  weight, the layer picked from the whole stack by the block index, the
+  per-channel scale in the kernel's last step, gate and up in one call
+  that writes ``act(gate) * up``); on a CPU, under ``backend="reference"``
+  or for widths the kernel refuses it is ``jax.lax.ragged_dot``, the
+  oracle the tests hold the kernel to (``grouped_backend``).
   Compute follows the tokens routed, at every shape: prefill, chunk,
   decode, mixed.  Nothing is ever dropped.  Padding rows and idle slots
   (``token_mask`` False) are sorted past the last group and multiply
@@ -25,8 +31,19 @@ dense MLP the layer adds beside it (``models/llama.py``).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+
+from helix_tpu.ops.attention import resolve_backend
+from helix_tpu.ops.grouped_matmul import (
+    check_grouped_geometry,
+    grouped_matmul_tpu,
+    row_tile,
+    visit_plan,
+)
+from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
 
 
 def _expert_dense(h_in, wp, spec):
@@ -58,9 +75,11 @@ def route(xf, router_p, cfg):
     return top_w * cfg.routed_scaling_factor, top_idx
 
 
-def expert_load_stats(top_idx, valid, X, dropped=0):
+def expert_load_stats(top_idx, valid, X, dropped=0, tile_fill=0.0):
     """``[dropped, routed, busiest expert's tokens over the mean, distinct
-    experts touched]`` of one layer's routing, f32."""
+    experts touched, tile fill]`` of one layer's routing, f32.
+    ``tile_fill`` is the dropless path's (``_grouped_experts``); the
+    capacity path walks no row tiles and reports 0."""
     load = jnp.sum(
         jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
         * valid[:, None, None].astype(jnp.float32), axis=(0, 1)
@@ -70,18 +89,35 @@ def expert_load_stats(top_idx, valid, X, dropped=0):
     return jnp.stack([
         jnp.asarray(dropped, jnp.float32), routed, ratio,
         jnp.sum((load > 0).astype(jnp.float32)),
+        jnp.asarray(tile_fill, jnp.float32),
     ])
 
 
+def grouped_backend(widths, backend: Optional[str] = None) -> str:
+    """Which grouped product the dropless path runs for expert weights of
+    ``widths`` (``(K, N)`` pairs): ``"pallas"`` (``ops/grouped_matmul.py``)
+    where the dispatcher the attention kernels use says so and the kernel
+    takes every width, else ``"xla"`` (``lax.ragged_dot``)."""
+    if resolve_backend(backend) != "pallas":
+        return "xla"
+    try:
+        for K, N in widths:
+            check_grouped_geometry(K, N)
+    except UnsupportedKernelGeometry:
+        return "xla"
+    return "pallas"
+
+
 def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
-                     layer=None):
-    """Dropless: sort the assignments by expert, three grouped products,
-    unsort, weighted sum over each token's k choices.
+                     layer=None, backend=None, interpret=False):
+    """Dropless: sort the assignments by expert, the grouped products,
+    unsort, weighted sum over each token's k choices.  Also returns the
+    products' tile fill: rows routed over rows walked, ``routed / (visits
+    * tm)``, of the kernel's visit plan (whichever product ran).
 
     ``layer`` (a traced index): ``experts_p`` is then a whole STACK of
-    layers' experts (``[n, X, ...]`` leaves) read as ``n * X`` groups of
-    which only this layer's X have rows: the kernel takes the stack's
-    buffer as it is, where a per-layer slice would be copied out for it."""
+    layers' experts (``[n, X, ...]`` leaves) of which this layer's are
+    read in place: a per-layer slice would be copied out for a kernel."""
     T, E = xf.shape
     k = top_idx.shape[1]
     with jax.named_scope("moe.dispatch"):
@@ -94,50 +130,78 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
             jax.nn.one_hot(flat_e, X, dtype=jnp.int32), axis=0
         )                                                           # [X]
         xs = xf[order // k]                                         # [T*k, E]
-        e_row = jnp.minimum(sorted_e, X - 1)
 
-    sizes = group_sizes
-    if layer is not None:
-        n_stack = experts_p["w_gate"]["weight"].shape[0]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_stack * X,), group_sizes.dtype), group_sizes,
-            (layer * X,))
-
-    def grouped(h, wp):
-        w = wp["weight"]
-        scale = wp.get("scale")
-        if layer is not None:
-            w = w.reshape((-1,) + w.shape[2:])        # [n * X, in, out]
-            scale = None if scale is None else scale[layer]
-        out = jax.lax.ragged_dot(
-            h, w, sizes,
-            preferred_element_type=jnp.float32,
-            # int8 weights meet the activations as stored (the kernel
-            # converts them in VMEM); there is no higher-precision product
-            # of that pair for a global matmul precision to ask for
-            precision=(jax.lax.Precision.DEFAULT
-                       if w.dtype == jnp.int8 else None),
-        )
-        if scale is not None:
-            # [X, 1, out] per-output-channel scales, one row per sorted
-            # assignment
-            out = out * scale[e_row, 0].astype(jnp.float32)
-        return out
+    names = ("w_gate", "w_up", "w_down")
+    if layer is None:
+        # one layer's experts are a stack of one
+        experts_p = {n: jax.tree.map(lambda a: a[None], experts_p[n])
+                     for n in names}
+        layer = 0
+    kernel = grouped_backend(
+        [experts_p[n]["weight"].shape[-2:] for n in names], backend
+    ) == "pallas"
+    tm = row_tile(T * k, X)
+    plan = visit_plan(group_sizes, T * k, tm)
+    visits = plan[-1][0]
+    fill = jnp.sum(group_sizes) / jnp.maximum(visits * tm, 1)
 
     with jax.named_scope("moe.experts"):
-        gate = grouped(xs, experts_p["w_gate"])
-        up = grouped(xs, experts_p["w_up"])
-        y = grouped((act(gate) * up).astype(xf.dtype), experts_p["w_down"])
+        if kernel:
+            y = experts_pallas(
+                xs, plan, tm, experts_p, layer, act, interpret)
+        else:
+            y = experts_xla(
+                xs, group_sizes, jnp.minimum(sorted_e, X - 1), experts_p,
+                layer, act)
     with jax.named_scope("moe.combine"):
-        # rows past the last group hold whatever the kernel left there
+        # rows past the last group hold whatever the product left there
         y = jnp.where((sorted_e < X)[:, None], y, 0.0)
         y = y[jnp.argsort(order)].reshape(T, k, E)
         w = top_w * valid[:, None].astype(top_w.dtype)
-        return jnp.einsum("tk,tke->te", w, y)
+        return jnp.einsum("tk,tke->te", w, y), fill
+
+
+def experts_pallas(xs, plan, tm, experts_p, layer, act, interpret):
+    """The three products through ``ops/grouped_matmul.py``: gate and up
+    in one call, down in a second, one visit plan for both."""
+    kw = dict(tm=tm, interpret=interpret)
+    gate, up, down = (experts_p[n] for n in ("w_gate", "w_up", "w_down"))
+    h = grouped_matmul_tpu(
+        xs, gate["weight"], plan, layer, scale=gate.get("scale"),
+        w2=up["weight"], scale2=up.get("scale"), act=act,
+        out_dtype=xs.dtype, **kw)
+    return grouped_matmul_tpu(
+        h, down["weight"], plan, layer, scale=down.get("scale"), **kw)
+
+
+def experts_xla(xs, group_sizes, e_row, experts_p, layer, act):
+    """The same three products as ``lax.ragged_dot`` over this layer's
+    slice of the stack: the oracle, and what a CPU runs."""
+    def grouped(h, wp):
+        w = wp["weight"][layer]
+        out = jax.lax.ragged_dot(
+            h, w, group_sizes,
+            preferred_element_type=jnp.float32,
+            # int8 weights meet the activations as stored; there is no
+            # higher-precision product of that pair for a global matmul
+            # precision to ask for
+            precision=(jax.lax.Precision.DEFAULT
+                       if w.dtype == jnp.int8 else None),
+        )
+        if "scale" in wp:
+            # [X, 1, out] per-output-channel scales, one row per sorted
+            # assignment
+            out = out * wp["scale"][layer][e_row, 0].astype(jnp.float32)
+        return out
+
+    gate = grouped(xs, experts_p["w_gate"])
+    up = grouped(xs, experts_p["w_up"])
+    return grouped((act(gate) * up).astype(xs.dtype), experts_p["w_down"])
 
 
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
-            return_dropped=False, return_stats=False, stacked_experts=None):
+            return_dropped=False, return_stats=False, stacked_experts=None,
+            backend=None, interpret=False):
     """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
     ``return_dropped`` also the int32 count of (token, choice) assignments
     this call dropped to capacity overflow (always 0 on the dropless
@@ -152,7 +216,11 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     never depend on garbage riding the same batch.
 
     ``stacked_experts = (experts of a whole layer stack, this layer's
-    index in it)`` instead of ``experts_p`` (dropless path only)."""
+    index in it)`` instead of ``experts_p`` (dropless path only).
+    ``backend``: as the attention dispatchers take it (``None``: what the
+    process' devices call for), for the dropless path's grouped product
+    (``grouped_backend``); ``interpret`` runs its kernel in interpret mode
+    (the CPU tests)."""
     B, S, E = x.shape
     X = cfg.num_experts
     T = B * S
@@ -164,6 +232,7 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     )
     with jax.named_scope("moe.router"):
         top_w, top_idx = route(xf, router_p, cfg)
+    fill = 0.0
     if cfg.expert_capacity_factor > 0:
         out, dropped = _capacity_experts(
             xf, top_w, top_idx, valid, experts_p, cfg, act, S
@@ -173,12 +242,13 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             experts_p, layer = stacked_experts
         else:
             layer = None
-        out = _grouped_experts(
-            xf, top_w, top_idx, valid, experts_p, X, act, layer)
+        out, fill = _grouped_experts(
+            xf, top_w, top_idx, valid, experts_p, X, act, layer, backend,
+            interpret)
         dropped = jnp.int32(0)
     out = out.reshape(B, S, E).astype(x.dtype)
     if return_stats:
-        return out, expert_load_stats(top_idx, valid, X, dropped)
+        return out, expert_load_stats(top_idx, valid, X, dropped, fill)
     if return_dropped:
         return out, dropped
     return out

@@ -1486,10 +1486,12 @@ class EngineLoop:
             "kv_pages_free": eng.allocator.free_pages,
             # of the last MoE step the host has read (0 for dense models):
             # the busiest expert's tokens over the mean, and the distinct
-            # experts a step touched (mean over the MoE layers)
+            # experts a step touched (mean over the MoE layers), the dropless
+            # grouped product's rows routed over rows walked
             "moe_expert_load_max_ratio": getattr(
                 eng, "moe_expert_load_max_ratio", 0.0),
             "moe_experts_touched": getattr(eng, "moe_experts_touched", 0.0),
+            "moe_tile_fill_ratio": getattr(eng, "moe_tile_fill_ratio", 0.0),
             "prefill_tokens": prefill,
             "padding_tokens": (
                 getattr(eng, "num_prefill_padding_tokens", 0) - pad0

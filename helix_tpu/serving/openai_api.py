@@ -389,6 +389,12 @@ class OpenAIServer:
                     "helix_moe_experts_touched",
                     getattr(eng, "moe_experts_touched", 0.0), lbl,
                 )
+                # the dropless grouped product's rows routed over rows
+                # walked by its (row tile, expert) visits, same step
+                c.gauge(
+                    "helix_moe_tile_fill_ratio",
+                    getattr(eng, "moe_tile_fill_ratio", 0.0), lbl,
+                )
             # speculative decoding (ISSUE 5): host-drafted tokens, the
             # subset the verify pass accepted, lifetime acceptance, and
             # slots the per-request EMA currently benches

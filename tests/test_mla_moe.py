@@ -350,8 +350,9 @@ def test_dropless_when_every_token_picks_the_same_experts():
                              return_stats=True)
         want = _loop_over_experts(x[0], router_w, experts, cfg)
     assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < 1e-5
-    dropped, routed, ratio, touched = np.asarray(stats)
+    dropped, routed, ratio, touched, fill = np.asarray(stats)
     assert (dropped, routed, touched) == (0, 64 * 3, 3)
+    assert fill == 0.5      # three groups of 64 rows: three visits of 128
     assert ratio == pytest.approx(8 / 3)      # busiest over the mean load
 
 
