@@ -10,7 +10,6 @@ The counterpart of the reference's cobra CLI (``api/cmd/helix/root.go:45-72``
   node stack (compose-manager + inference-proxy + heartbeat).
 - ``profile``    — validate / describe profile YAML (composeparse analogue).
 - ``chat``       — one-shot chat against a server (reference: ``helix chat``).
-- ``bench``      — run the standard benchmark.
 """
 
 from __future__ import annotations
@@ -480,13 +479,6 @@ def _cmd_chat(args) -> int:
     return 0
 
 
-def _cmd_bench(args) -> int:
-    import runpy
-
-    runpy.run_module("bench", run_name="__main__")
-    return 0
-
-
 def _cmd_sft(args) -> int:
     """LoRA SFT: the `fine-tune a model from a JSONL dataset` surface the
     reference exposed through fine-tune sessions (axolotl, deleted)."""
@@ -811,9 +803,6 @@ def main(argv=None) -> int:
         help="print every HELIX_* environment variable the runtime reads",
     )
     cr.set_defaults(fn=_cmd_config_reference)
-
-    b = sub.add_parser("bench", help="run the standard benchmark")
-    b.set_defaults(fn=_cmd_bench)
 
     t = sub.add_parser("sft", help="LoRA supervised fine-tune from JSONL")
     t.add_argument("--data", required=True, help="JSONL dataset path")

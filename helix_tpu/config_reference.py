@@ -35,12 +35,6 @@ ENV_REFERENCE: tuple = (
     ),
     # -- accelerator -----------------------------------------------------
     EnvVar(
-        "HELIX_BENCH_BATCH",
-        "Decode batch size for bench.py's TPU measurement (default 32; "
-        "the KV pool is provisioned for 64 at 256 tokens/request).",
-        section="accelerator",
-    ),
-    EnvVar(
         "HELIX_PEAK_FLOPS",
         "Peak accelerator FLOP/s used as the denominator of the runner's "
         "helix_mfu_estimate gauge. Unset: the v5e bf16 peak (197e12) on "
@@ -807,11 +801,6 @@ ENV_REFERENCE: tuple = (
         "JAX platform selection; the control plane and sandbox children "
         "pin 'cpu' (they never touch chips). Serving nodes inherit the "
         "deployment default (tpu).",
-        section="accelerator",
-    ),
-    EnvVar(
-        "HELIX_BENCH_CHILD",
-        "Internal: marks the CPU-fallback bench child process.",
         section="accelerator",
     ),
     # -- multi-host (DCN) serving (serving/multihost_serving.py) ---------

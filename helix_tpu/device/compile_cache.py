@@ -1,9 +1,9 @@
 """The one rule for JAX's persistent compilation cache.
 
 Every program of this repository that compiles for the accelerator
-(``serve-node``, ``bench.py``, ``chip_smoke.py``'s children, the
-``tools/`` probes) calls :func:`configure_compile_cache` before its first
-compile, and nothing else sets a cache directory:
+(``serve-node``, ``chip_smoke.py``'s children) calls
+:func:`configure_compile_cache` before its first compile, and nothing
+else sets a cache directory:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; do nothing.
 - otherwise: ``<checkout>/.jax_cache`` (git-ignored).  A fixed path — the

@@ -17,7 +17,6 @@ interchangeable.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 
@@ -154,17 +153,3 @@ def total_hbm_bytes(devices: Optional[list] = None) -> int:
     return sum(a.total_memory_bytes for a in detect_accelerators(devices))
 
 
-@functools.lru_cache(maxsize=1)
-def platform_name() -> str:
-    import jax
-
-    return jax.devices()[0].platform
-
-
-def live_hbm_bytes(device=None) -> int:
-    """Bytes currently held live on ``device`` (default: first device)."""
-    import jax
-
-    d = device if device is not None else jax.devices()[0]
-    _, used, _ = _memory_stats(d)
-    return used

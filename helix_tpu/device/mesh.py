@@ -72,10 +72,6 @@ class MeshSpec:
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
-    @classmethod
-    def tp_only(cls, n: int, device_offset: int = 0) -> "MeshSpec":
-        return cls(tp=n, device_offset=device_offset)
-
 
 def slice_devices(
     spec: MeshSpec, devices: Optional[Sequence] = None

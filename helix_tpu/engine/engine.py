@@ -533,14 +533,6 @@ class RequestSnapshot:
     def has_kv(self) -> bool:
         return self.position is not None and bool(self.pages)
 
-    def kv_bytes(self) -> int:
-        return sum(
-            int(a.nbytes)
-            for p in self.pages
-            for a in p.values()
-            if a is not None
-        )
-
 
 # Compiled step functions are cached at module level keyed by the static
 # configuration, NOT per Engine instance — two Engines serving the same
@@ -1524,13 +1516,14 @@ class Engine:
         # tokens-per-device-step figure the ragged unification moves
         self.num_device_calls = 0
         # KV tiering (ISSUE 6): swap-out/swap-in of running decoders and
-        # cumulative host->device restore time (bench's restore-latency
-        # numerator; page-level spill/restore pools live on host_pool)
+        # cumulative host->device restore time (the numerator of
+        # helix_kv_restore_seconds_total; page-level spill/restore
+        # pools live on host_pool)
         self.num_preemptions = 0
         self.num_resumes = 0
         self.restore_seconds = 0.0
         # portable request snapshots (ISSUE 11): export/import counters
-        # feed the helix_migrations_* series and the migration bench
+        # feed the helix_migrations_* series
         self.num_snapshots_exported = 0
         self.num_snapshots_imported = 0
         # disaggregated prefill/decode (ISSUE 14): snapshots exported at
@@ -2045,7 +2038,7 @@ class Engine:
     def generate(
         self, prompts: Sequence[Sequence[int]], sampling: SamplingParams
     ) -> list[list[int]]:
-        """Blocking convenience wrapper (tests, bench)."""
+        """Blocking convenience wrapper (tests)."""
         reqs = [
             Request(
                 id=f"gen-{i}",

@@ -110,17 +110,6 @@ def host_pool_budget_bytes(default: int = 0) -> int:
     return int(v) if v else default
 
 
-def host_tier_pages(model_cfg, cache_cfg, host_budget_bytes: int) -> int:
-    """How many spilled pages a host budget holds for this model — the
-    ``fit_hbm`` arithmetic applied to the host tier.  The ratio against
-    ``cache_cfg.num_pages`` is the effective prefix-cache
-    multiplication a system-prompt-heavy fleet gets (the 10-100x
-    figure): host RAM is typically 8-16x HBM and a page spills at its
-    stored size (int8 pages stay int8)."""
-    per_page = cache_cfg.page_bytes(model_cfg)
-    return int(host_budget_bytes // per_page) if per_page else 0
-
-
 def served_model_bytes(m: ServedModel, headroom: float = 0.10) -> int:
     """Footprint of a live ServedModel: weights + KV pages (+headroom)."""
     total = 0

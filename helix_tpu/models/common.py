@@ -65,10 +65,6 @@ class ModelConfig:
     name: str = "unnamed"
 
     @property
-    def q_per_kv(self) -> int:
-        return self.num_heads // self.num_kv_heads
-
-    @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
 

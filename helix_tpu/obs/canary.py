@@ -397,7 +397,7 @@ class CanaryProber:
         """Replay every minted probe once; compare token-level
         bit-identity against the golden record; advance the health
         rungs.  Returns ``{probes, mismatched, errors}`` for callers
-        that drive rounds directly (tests, bench, chaos)."""
+        that drive rounds directly (tests, chaos)."""
         with self._lock:
             probes = list(self._probes.values())
         by_model = {}
@@ -544,7 +544,7 @@ class CanaryProber:
         }
 
     def snapshot(self) -> dict:
-        """Operator introspection (bench + debug surfaces): summary plus
+        """Operator introspection (debug surfaces): summary plus
         per-probe golden/latest detail."""
         with self._lock:
             probes = [

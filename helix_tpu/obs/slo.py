@@ -798,47 +798,6 @@ class SLOObserver:
     def rollup(self) -> dict:
         return self.accounting.rollup()
 
-    def summary(self) -> dict:
-        """Aggregate + per-tenant latency/goodput snapshot (bench.py's
-        ``slo`` JSON block)."""
-        acc = self.accounting
-        rows = acc._snapshot_rows()
-        agg = acc._snapshot(None)
-        now = acc.clock()
-
-        def qs(samples, q):
-            return round(
-                _quantile(
-                    [v for ts, v in samples
-                     if now - ts <= acc.fast_window], q,
-                ), 6,
-            )
-
-        out = {
-            "ttft_p50_seconds": qs(agg.ttft, 0.50),
-            "ttft_p95_seconds": qs(agg.ttft, 0.95),
-            "queue_wait_p50_seconds": qs(agg.queue_wait, 0.50),
-            "queue_wait_p95_seconds": qs(agg.queue_wait, 0.95),
-            "goodput_tokens_per_second": round(
-                acc._goodput(agg, now), 2
-            ),
-            "tenants": {},
-        }
-        for snap in rows:
-            if snap.tenant == OTHER_TENANT and not snap.requests:
-                continue
-            out["tenants"][snap.tenant] = {
-                "requests": snap.requests,
-                "prompt_tokens": snap.prompt_tokens,
-                "generated_tokens": snap.generated_tokens,
-                "ttft_p50_seconds": qs(snap.ttft, 0.50),
-                "ttft_p95_seconds": qs(snap.ttft, 0.95),
-                "goodput_tokens_per_second": round(
-                    acc._goodput(snap, now), 2
-                ),
-            }
-        return out
-
     def stats(self) -> dict:
         return {
             **self.accounting.totals(),

@@ -214,8 +214,8 @@ def maybe_dequant_dense(x, p: dict, adapter_ids=None, compute_dtype=None):
     cdims = (((x.ndim - 1,), (0,)), ((), ()))
     # int8 weights feed the dot directly (mixed-precision dot_general):
     # XLA:TPU converts the int8 operand in VMEM after the (halved) HBM
-    # fetch, ~20% faster than an explicit astype which can materialise a
-    # converted copy outside the dot fusion.
+    # fetch, where an explicit astype can materialise a converted copy
+    # outside the dot fusion.
     out = jax.lax.dot_general(
         x, w, cdims, preferred_element_type=jnp.float32,
     )

@@ -1555,8 +1555,7 @@ class EngineLoop:
         if timing:
             # per-step time split (ISSUE 13): host build / device wait /
             # emit, plus the device-idle gap this step charged — the
-            # numerators of helix_device_idle_ratio and the bench's
-            # host_overlap block
+            # numerators of helix_device_idle_ratio
             rec.update(timing)
         if failed is not None:
             rec["anomaly"] = "step_failure"

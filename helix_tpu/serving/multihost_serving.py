@@ -120,8 +120,8 @@ def plan_trace_id(model: str) -> str:
     follower so plan publishes, follower applies, digest verifies,
     checkpoints and takeovers stitch into ONE federated timeline — a
     takeover blackout reads as a gap between the last leader publish
-    and the promoted host's first, not just ``takeover_blackout_ms``
-    in bench output."""
+    and the promoted host's first, not just ``takeover_ms`` in the
+    mesh's stats."""
     return ("mh-plan-" + _PLAN_TID_RE.sub("-", model or "default"))[:64]
 
 #: Follower health states in the leader's registry (ISSUE 17).  Minted
@@ -781,7 +781,7 @@ class PlanLeader:
         self._digest = _DIGEST_SEED
         self._digest_step: Optional[int] = None
         self._digest_reset_pending = False
-        # surfaced by bench.py and /admin stats
+        # surfaced by /admin stats
         self.plans_published = 0
         self.plan_bytes_total = 0
         self.plan_bytes_max = 0
@@ -2006,7 +2006,7 @@ class HTTPFeed:
 
 
 class LocalFeed:
-    """In-process feed (tests, bench, chaos): reads the leader's ring
+    """In-process feed (tests, chaos): reads the leader's ring
     directly AND registers the bound follower's health on every poll —
     the same contract HTTPFeed provides via query params over DCN, so
     the N-follower registry and lag ladder exercise without HTTP."""

@@ -263,6 +263,7 @@ class OpenAIServer:
         # ring), not a feature guard
         journal = getattr(served.loop.engine, "journal", None)
         if journal is None:
+            # multihost-ok: the word is in the client's error message
             return _error(
                 400, f"model '{model}' is not running as a multihost "
                 "leader"

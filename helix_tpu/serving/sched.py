@@ -426,12 +426,6 @@ class WFQScheduler(FifoScheduler):
             for t in [t for t, v in vs.items() if v <= floor]:
                 del vs[t]
 
-    def normalized_service(self, cls: str, tenant: str) -> float:
-        with self._lock:
-            return max(
-                self._vsrv[cls].get(tenant, 0.0), self._vfloor[cls]
-            )
-
     # -- ordering ------------------------------------------------------------
 
     def reorder(self, waiting: list) -> None:

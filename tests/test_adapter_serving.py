@@ -384,6 +384,27 @@ class TestBatchedAdapters:
             [[5, 6, 7]], SamplingParams(**GREEDY)
         )[0]
 
+    def test_pool_bytes_are_low_rank_factors_not_model_copies(
+        self, tiny_base, pool_rig
+    ):
+        """What the resident adapters cost in device memory: the pool's
+        A and B factors for every slot and target, a small share of ONE
+        copy of the base weights, where a merged model an adapter would
+        cost a whole copy each."""
+        cfg, params = tiny_base
+        eng, adapters = pool_rig
+        pool = eng.adapter_pool
+        base_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(params))
+        want = 0
+        for t, (d_in, d_out) in _target_dims(cfg).items():
+            if t in pool.targets:
+                want += (
+                    cfg.num_layers * POOL_ECFG["adapter_pool_slots"]
+                    * POOL_ECFG["adapter_rank"] * (d_in + d_out) * 4
+                )
+        assert pool.hbm_bytes() == want
+        assert pool.hbm_bytes() < base_bytes / 4 < len(adapters) * base_bytes
+
     def test_residency_summary_bounded(self, pool_rig):
         eng, _ = pool_rig
 

@@ -223,10 +223,15 @@ class TestProbeRounds:
 
     def test_clean_round(self, rig):
         served, prober = rig
+        steps0 = served.loop.flight.steps_recorded
         res = prober.probe_round()
         assert res["probes"] > 0
         assert res["mismatched"] == 0 and res["errors"] == 0
         assert prober.state == CANARY_OK
+        # what a round costs the engine: each probe is one short greedy
+        # request, so at most its token budget in engine steps
+        steps = served.loop.flight.steps_recorded - steps0
+        assert 0 < steps <= res["probes"] * prober.probe_tokens
 
     def test_corruption_detected_within_bounded_rounds(self, rig):
         served, prober = rig

@@ -796,6 +796,45 @@ class TestPackedPrefill:
         assert all(len(r.output_tokens) == 3 for r in reqs)
 
 
+    def test_full_batch_keeps_every_slot_decoding(self, tiny_model):
+        """The counts of a saturated batch: as many equal requests as
+        slots, admitted at once.  Every decode step carries every slot
+        (decode tokens = steps x slots: utilization 1.0), padding is
+        what the prefill buckets left over, and the pool's peak is each
+        sequence's pages and nothing more."""
+        cfg, params = tiny_model
+        slots, plen, gen, page = 4, 5, 8, 4
+        eng = Engine(
+            cfg, params,
+            EngineConfig(
+                max_decode_batch=slots, page_size=page, num_pages=128,
+                max_pages_per_seq=16, max_prefill_len=16,
+                attn_backend="reference", enable_prefix_cache=False,
+            ),
+        )
+        reqs = [
+            Request(id=f"r{i}",
+                    prompt_tokens=[(7 * i + j) % 250 + 1 for j in range(plen)],
+                    sampling=SamplingParams(temperature=0.0, max_tokens=gen))
+            for i in range(slots)
+        ]
+        for r in reqs:
+            eng.add_request(r)
+        while eng.has_work():
+            eng.step()
+        assert [len(r.output_tokens) for r in reqs] == [gen] * slots
+        # the first token of each comes from its prefill
+        assert eng.num_decode_tokens == slots * (gen - 1)
+        assert eng.num_decode_device_steps == gen - 1
+        # 20 prompt tokens over a 16-token bucket: a wave of three
+        # (15 -> 16) and a wave of one (5 -> 8)
+        assert eng.num_prefill_tokens == slots * plen
+        assert eng.num_prefill_padding_tokens == (16 - 15) + (8 - 5)
+        assert eng.num_device_calls == 2 + (gen - 1)
+        assert eng.allocator.peak_used == slots * -(-(plen + gen) // page)
+        assert eng.allocator.used_pages == 0
+
+
 class TestInt8KVCache:
     """Int8 KV page pools: per-(slot, head) f32 scales, quantize on write,
     dequantize in-register on read — numerical equivalence with the

@@ -151,6 +151,36 @@ class TestPlanBroadcast:
         assert follower.stats()["digest_checks"] >= steps - 1
         assert follower.stats()["digest_mismatches"] == 0
 
+    def test_plan_size_follows_the_admission_wave_not_the_history(
+        self, tiny
+    ):
+        """What crosses hosts a step: the plan that admits the wave
+        carries the requests; a steady decode plan carries no admits
+        and no drafts, so five times the generation is five times the
+        plans of the same small size, never a larger one."""
+
+        def publish(max_tokens):
+            leader = PlanLeader(_engine(tiny))
+            for i in range(2):
+                leader.add_request(Request(
+                    id=f"r{i}", prompt_tokens=[3 + i, 5, 8],
+                    sampling=SamplingParams(temperature=0.0,
+                                            max_tokens=max_tokens),
+                ))
+            steps = _drain(leader)
+            assert leader.plans_published == steps
+            return leader
+
+        short, long = publish(6), publish(30)
+        assert long.plans_published > 4 * short.plans_published
+        # the admitting plan is the largest, and the same in both runs
+        # up to the digits of max_tokens
+        assert abs(long.plan_bytes_max - short.plan_bytes_max) <= 4
+        steady = (long.plan_bytes_total - long.plan_bytes_max) / (
+            long.plans_published - 1
+        )
+        assert steady < long.plan_bytes_max / 3
+
     def test_greedy_bit_identity(self, tiny):
         leader = PlanLeader(_engine(tiny))
         fe = _engine(tiny)

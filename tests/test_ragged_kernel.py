@@ -345,6 +345,10 @@ class TestShapeCollapse:
             SamplingParams(temperature=0.0, max_tokens=2),
         )
         assert eng.num_prefill_padding_tokens == 16 - 10
+        # and ONE device call for the wave, one for the decode step:
+        # 12 tokens in 2 calls
+        assert eng.num_prefill_tokens + eng.num_decode_tokens == 10 + 2
+        assert eng.num_device_calls == 2
 
     @pytest.mark.slow
     def test_warmup_compiles_ladder_ahead_of_traffic(self, tiny_model):

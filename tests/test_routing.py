@@ -241,6 +241,61 @@ class TestPrefixAffinityRouting:
         assert r.pick_runner("m", affinity_key=key).id == "r2"
         assert r.route_affinity_hits >= 1
 
+    def test_affinity_turns_repeats_into_prefix_hits(self):
+        """Three shared prompt heads in turn over two runners, each a
+        real engine with its prefix cache.  Round robin alternates, so
+        an odd number of heads prefills every head on both runners (6
+        misses in 15 requests); affinity parks each head on one runner
+        and only its first arrival misses (3 in 15)."""
+        import jax
+
+        from helix_tpu.engine.engine import Engine, EngineConfig, Request
+        from helix_tpu.engine.sampling import SamplingParams
+        from helix_tpu.models.common import ModelConfig
+        from helix_tpu.models.llama import init_params
+
+        cfg = ModelConfig.tiny(dtype="float32")
+        params = init_params(cfg, jax.random.PRNGKey(7))
+        heads = [
+            [(40 * (p + 1) + j) % 250 + 1 for j in range(16)]
+            for p in range(3)
+        ]
+
+        def serve(policy):
+            engines = {
+                rid: Engine(cfg, params, EngineConfig(
+                    max_decode_batch=2, page_size=4, num_pages=128,
+                    max_pages_per_seq=16, max_prefill_len=32,
+                    attn_backend="reference", enable_prefix_cache=True,
+                ))
+                for rid in ("r1", "r2")
+            }
+            router = InferenceRouter(policy=policy)
+            for i in range(15):
+                for rid in engines:
+                    _hb(router, rid)
+                head = heads[i % 3]
+                picked = router.pick_runner(
+                    "m", affinity_key=prefix_digest("m", str(head))
+                )
+                eng = engines[picked.id]
+                eng.add_request(Request(
+                    id=f"q{i}", prompt_tokens=head + [200 + i],
+                    sampling=SamplingParams(temperature=0.0, max_tokens=2),
+                ))
+                while eng.has_work():
+                    eng.step()
+            return (
+                sum(e.prefix_cache_hits for e in engines.values()),
+                sum(e.prefix_cache_misses for e in engines.values()),
+                router.route_affinity_hits,
+            )
+
+        assert serve(RouterPolicy()) == (9, 6, 0)
+        assert serve(
+            RouterPolicy(policy="scored", affinity=True)
+        ) == (12, 3, 12)
+
     def test_affinity_entry_pruned_with_runner(self):
         r = self._router()
         _hb(r, "r1", saturation=_sat())

@@ -501,6 +501,51 @@ class TestLoopIntegration:
         # and nobody starved outright
         assert all(r.finished for r in flood + chat)
 
+    @pytest.mark.parametrize("policy", ["fifo", "wfq"])
+    def test_a_policy_changes_order_never_tokens(self, tiny_parts, policy):
+        """A flooding batch tenant and an interactive one through the
+        loop: every request's greedy output is what the bare engine
+        gives for it, and each tenant's account holds its own tokens."""
+        from helix_tpu.serving.engine_loop import EngineLoop
+
+        def traffic():
+            return [
+                _req(f"bulk{i}", [(393 + 17 * i + j) % 500 + 4
+                                  for j in range(12)],
+                     tenant="bulk", klass=BATCH, max_tokens=8)
+                for i in range(6)
+            ] + [
+                _req(f"chat{i}", [(1441 + 17 * i + j) % 500 + 4
+                                  for j in range(12)],
+                     tenant="chat", klass=INTERACTIVE, max_tokens=4)
+                for i in range(3)
+            ]
+
+        bare = _mk_engine(tiny_parts)
+        want = traffic()
+        for r in want:
+            bare.add_request(r)
+        while bare.has_work():
+            bare.step()
+        loop = EngineLoop(
+            _mk_engine(tiny_parts), name=f"identity-{policy}",
+            sched_config={"sched": {"policy": policy}},
+        ).start()
+        got = traffic()
+        assert _drain(loop, got) == []
+        loop.stop(join=True)
+        assert {r.id: r.output_tokens for r in got} == {
+            r.id: r.output_tokens for r in want
+        }
+        account = {t["tenant"]: t for t in loop.slo.rollup()["top"]}
+        for tenant in ("bulk", "chat"):
+            mine = [r for r in got if r.tenant == tenant]
+            assert account[tenant]["requests"] == len(mine)
+            assert account[tenant]["prompt_tokens"] == 12 * len(mine)
+            assert account[tenant]["generated_tokens"] == sum(
+                len(r.output_tokens) for r in mine
+            )
+
     def test_fifo_default_loop_unchanged(self, tiny_parts):
         from helix_tpu.serving.engine_loop import EngineLoop
 

@@ -535,6 +535,62 @@ class TestMultihostPlanCorrelation:
 
 
 # ---------------------------------------------------------------------------
+# what one request costs in spans at the engine plane
+# ---------------------------------------------------------------------------
+
+
+class TestSpansARequest:
+    """The engine plane records a fixed handful of spans a request,
+    whatever its length: the HTTP planes stack theirs on top."""
+
+    PROMPT = list(range(3, 27))
+    SAMPLING = SamplingParams(temperature=0.0, max_tokens=16)
+
+    @pytest.mark.parametrize("flow", ["plain", "migrated"])
+    def test_engine_plane_span_names(self, tiny, flow):
+        from helix_tpu.serving import migration
+
+        tid = f"spans-{flow}-0001"
+        loop = EngineLoop(_engine(tiny), name=f"spans-{flow}")
+        loop._trace = store = TraceStore()   # a host of its own
+        loop.start()
+        done = threading.Event()
+
+        def cb(e):
+            if e.finished:
+                done.set()
+
+        req = Request(id="one", prompt_tokens=list(self.PROMPT),
+                      sampling=self.SAMPLING, trace_id=tid)
+        want = ["queue", "prefill", "admit_to_token", "first_token_hold",
+                "decode"]
+        try:
+            if flow == "plain":
+                loop.submit(req, cb)
+            else:
+                # cut mid-decode on another engine, through the wire
+                # format, and finished here: the import span and then
+                # the same five
+                src = _engine(tiny)
+                src.add_request(req)
+                while len(req.output_tokens) < 4:
+                    src.step()
+                wire = migration.snapshot_to_wire(
+                    src.export_request("one"))
+                imported = threading.Event()
+                loop.submit_import(
+                    migration.wire_to_snapshot(wire), cb,
+                    on_result=lambda err, code: imported.set(),
+                )
+                assert imported.wait(60)
+                want = ["engine import admit"] + want
+            assert done.wait(60)
+        finally:
+            loop.stop(join=True)
+        assert [s["name"] for s in store.get(tid)["spans"]] == want
+
+
+# ---------------------------------------------------------------------------
 # the full HTTP spine: cp + two pool runners, three hosts on one trace
 # ---------------------------------------------------------------------------
 
