@@ -123,21 +123,23 @@ def phase_kernels(seed, rehearse):
     pools["int8"] = (kq8, vq8, pack_scale_pages(ks8), pack_scale_pages(vs8))
 
     def layout(shape):
-        """(T, t0, q_len, hist, tables): decode = B one-token rows over
-        ragged histories; prefill = one S-token row over a history that
-        ends mid-page."""
+        """(T, t0, q_len, hist, tables, a row's most fresh tokens): decode =
+        B one-token rows over ragged histories; prefill = one S-token row
+        over a history that ends mid-page.  The last is the static bound
+        the engine passes (``_ragged_attn_call``), which sizes the
+        kernel's query blocks."""
         pages = rng.permutation(np.arange(1, N))
         if shape == "decode":
             hist = rng.integers(1, maxP * P - 1, size=B)
             tables = np.resize(pages, (B, maxP))
-            return (B, np.arange(B), np.ones(B, int), hist, tables)
+            return (B, np.arange(B), np.ones(B, int), hist, tables, 1)
         hist = np.array([(maxP * P) // 2 - 5])
         return (S, np.zeros(1, int), np.array([S]), hist,
-                pages[:maxP][None])
+                pages[:maxP][None], S)
 
     ok = True
     for shape in ("decode", "prefill_with_history"):
-        T, t0, q_len, hist, tables = layout(shape)
+        T, t0, q_len, hist, tables, bound = layout(shape)
         q = jax.random.normal(kq, (T, H, D), jnp.float32).astype(jnp.bfloat16)
         k_new = jax.random.normal(kn, (T, KVH, D)).astype(jnp.bfloat16)
         v_new = (k_new * 0.5 + 0.25).astype(jnp.bfloat16)
@@ -146,10 +148,12 @@ def phase_kernels(seed, rehearse):
             args = (q, k_new, v_new, kp, vp, jnp.int32(1), *meta)
             if rehearse:
                 got = ragged_paged_attention_tpu(
-                    *args, interpret=True, k_scale=ks, v_scale=vs)
+                    *args, max_q_len=bound, interpret=True,
+                    k_scale=ks, v_scale=vs)
             else:
                 got = ragged_paged_attention(
-                    *args, backend="pallas", k_scale=ks, v_scale=vs)
+                    *args, backend="pallas", max_q_len=bound,
+                    k_scale=ks, v_scale=vs)
             with jax.default_matmul_precision("highest"):
                 want = ragged_paged_attention_reference(
                     *args, k_scale=ks, v_scale=vs)
@@ -159,8 +163,8 @@ def phase_kernels(seed, rehearse):
             good = bool(np.isfinite(got).all() and err <= tol)
             ok &= good
             say(phase="kernel", op="ragged_paged_attention", geometry=[H, KVH, D],
-                shape=shape, tokens=T, kv=kvname, max_abs_err=err, tol=tol,
-                ok=good)
+                shape=shape, tokens=T, max_q_len=bound, kv=kvname,
+                max_abs_err=err, tol=tol, ok=good)
 
     q = jax.random.normal(kq, (1, S, H, D), jnp.float32).astype(jnp.bfloat16)
     k = jax.random.normal(kn, (1, S, KVH, D), jnp.float32).astype(jnp.bfloat16)

@@ -701,7 +701,8 @@ def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
         k.reshape(Bq * Sq, KVH, D),
         v.reshape(Bq * Sq, KVH, D),
         kp, vp, lyr, t0, q_len, hist, tables,
-        backend=backend, mesh=mesh, k_scale=ks, v_scale=vs, **tkw,
+        backend=backend, mesh=mesh, max_q_len=Sq, k_scale=ks, v_scale=vs,
+        **tkw,
     )
     return out.reshape(Bq, Sq, H, D)
 
@@ -1561,6 +1562,12 @@ class Engine:
             E, F = model_cfg.hidden_size, model_cfg.expert_width
             self.grouped_backend = grouped_backend(
                 [(E, F), (F, E)], self._backend)
+        # the query block of the state segment's attention call, from the
+        # segment's static width as both ragged kernels read it: 1 token
+        # for plain decode, 8 under speculation
+        from helix_tpu.ops.paged_kernel import query_block
+
+        self.attn_q_block = query_block(self._spec_width())
         # the step in progress, by named phase (obs.trace.phase): the
         # engine loop clears it at the top of a pass and files it in the
         # flight record; standalone step() callers never read it
@@ -4503,6 +4510,7 @@ class Engine:
                if self.model_cfg.num_experts else {}),
             **({"grouped_backend": self.grouped_backend}
                if self.grouped_backend else {}),
+            attn_q_block=self.attn_q_block,
         ):
             (self.cache, self._dstate, p_first, sampled, emit, extra,
              drops) = fn(

@@ -40,7 +40,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
-from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
+from helix_tpu.ops.paged_kernel import (
+    UnsupportedKernelGeometry,
+    live_query_blocks,
+    query_block,
+)
 
 ROPE_LANES = 128   # the rope key's width in the pool and in flight
 
@@ -272,7 +276,7 @@ def mla_ragged_paged_attention_tpu(
         check_mla_geometry(H, R, dr, c_pages.dtype.itemsize)
     assert DQ == R + dr and r_pages.shape[-1] == ROPE_LANES
     max_q_len = T if max_q_len is None else min(max_q_len, T)
-    BQ = 1 if max_q_len == 1 else 8
+    BQ = query_block(max_q_len)
     KB = 16 if BQ == 1 else 128
     C = max(1, min(256 // P, maxP))
     pad = ROPE_LANES - dr
@@ -284,16 +288,8 @@ def mla_ragged_paged_attention_tpu(
     rn = jnp.pad(r_new.astype(r_pages.dtype), ((0, Tpad - T), (0, pad)))
     qp = qp.astype(c_pages.dtype)
 
-    # the live query blocks, in row order: block b -> (row, index in row)
-    NB = T if BQ == 1 else T // BQ + min(n_rows, T)
     q_len = q_len.astype(jnp.int32)
-    nblk = (q_len + BQ - 1) // BQ
-    ends = jnp.cumsum(nblk)
-    blk = jnp.arange(NB, dtype=jnp.int32)
-    brow = jnp.sum((blk[:, None] >= ends[None, :]).astype(jnp.int32), axis=1)
-    live = blk < ends[-1]
-    brow = jnp.where(live, jnp.minimum(brow, n_rows - 1), -1)
-    bidx = blk - (ends - nblk)[jnp.maximum(brow, 0)]
+    brow, bidx = live_query_blocks(q_len, BQ, T)
 
     kernel = functools.partial(
         _mla_kernel, scale=scale, page_size=P, pages_per_chunk=C, bq=BQ,
@@ -303,7 +299,7 @@ def mla_ragged_paged_attention_tpu(
     dt = c_pages.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(NB,),
+        grid=brow.shape,
         in_specs=[any_spec] * 5,
         out_specs=any_spec,
         scratch_shapes=[

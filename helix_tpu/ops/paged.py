@@ -28,8 +28,9 @@ shape per caller.
   ``max_pages`` per row).
 - ``ragged_paged_attention_tpu`` (``helix_tpu/ops/paged_kernel``) — the
   Pallas kernel: walks ONLY the pages each row actually uses (ragged over
-  rows), one whole-page ``[P, KVH, D]`` DMA per page, 8-token query
-  blocks, int8 dequantization in-register after the page fetch.
+  rows), one whole-page ``[P, KVH, D]`` DMA per page, query blocks of 1
+  token (plain decode: ``max_q_len`` 1) or 8, int8 dequantization
+  in-register after the page fetch.
 
 - ``paged_decode_attention_reference`` is kept as the decode-shaped
   numerics oracle for tests (one query token per sequence, no fresh-token
@@ -54,7 +55,7 @@ Semantics shared by both backends:
 
 Layout contract (both backends): ``t0`` is ascending and rows are
 disjoint; rows may start at any offset (the Pallas kernel pads the flat
-axis internally so its 8-token query blocks never DMA out of bounds).
+axis internally so its query and fresh-key blocks never DMA out of bounds).
 """
 
 from __future__ import annotations
@@ -338,6 +339,7 @@ def ragged_paged_attention(
     scale: Optional[float] = None,
     backend: Optional[str] = None,
     mesh=None,
+    max_q_len: Optional[int] = None,
     k_scale=None,  # [L, N, KVH*P] f32 — int8 pools' scale page rows
     v_scale=None,
     span_lo=None,  # [R] tiered rows: cold history span start (tokens)
@@ -357,6 +359,8 @@ def ragged_paged_attention(
     a TPU, the XLA gather oracle on a CPU or for ``backend="reference"``;
     on a TPU the kernel runs or the call raises.  ``mesh``: the mesh the
     heads are sharded over, so that the kernel runs per head shard.
+    ``max_q_len`` is a static bound on a row's fresh tokens (1 for plain
+    decode), which lets the kernel size its query blocks.
     One documented exception: tiered-residency metadata (``span_lo``/
     ``cold_*``) routes to the reference path on every backend — the
     Pallas kernel walks resident pages only and has no carried-stats
@@ -376,7 +380,7 @@ def ragged_paged_attention(
             ks, vs = scales if quantized else (None, None)
             return ragged_paged_attention_tpu(
                 q, k_new, v_new, k_pages, v_pages, *meta, scale=scale,
-                k_scale=ks, v_scale=vs,
+                max_q_len=max_q_len, k_scale=ks, v_scale=vs,
             )
 
         if head_shards(mesh) > 1:

@@ -1492,6 +1492,9 @@ class EngineLoop:
                 eng, "moe_expert_load_max_ratio", 0.0),
             "moe_experts_touched": getattr(eng, "moe_experts_touched", 0.0),
             "moe_tile_fill_ratio": getattr(eng, "moe_tile_fill_ratio", 0.0),
+            # tokens in a query block of the state segment's attention
+            # call: 1 for plain decode, 8 under speculation
+            "attn_q_block": getattr(eng, "attn_q_block", 0),
             "prefill_tokens": prefill,
             "padding_tokens": (
                 getattr(eng, "num_prefill_padding_tokens", 0) - pad0

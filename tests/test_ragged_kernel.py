@@ -121,37 +121,36 @@ def _random_layout(rng_np, R, maxP, P, N):
     return T, t0, q_lens.astype(np.int32), hist, tables
 
 
-def _op_case(rng, *, int8: bool, seed: int):
+def _pools(key, shape, int8: bool):
+    """(k_pages, v_pages, scales): f32 pools of ``shape``, or their int8
+    codes with the packed scale pools the kernel reads."""
     from helix_tpu.ops.quant import pack_scale_pages, quantize_kv
 
-    L, N, P, KVH, D, H, maxP, R = 2, 24, 4, 2, 16, 4, 4, 5
-    ks = jax.random.split(jax.random.fold_in(rng, seed), 4)
-    k_f = jax.random.normal(ks[0], (L, N, P, KVH, D), jnp.float32)
+    k_f = jax.random.normal(key, shape, jnp.float32)
     v_f = k_f * 0.5 - 0.25
-    k_scale = v_scale = None
-    if int8:
-        k_pages, k_scale = quantize_kv(k_f)
-        v_pages, v_scale = quantize_kv(v_f)
-        k_scale, v_scale = pack_scale_pages(k_scale), pack_scale_pages(v_scale)
-    else:
-        k_pages, v_pages = k_f, v_f
-    rng_np = np.random.default_rng(seed)
-    T, t0, q_len, hist, tables = _random_layout(rng_np, R, maxP, P, N)
-    q = jax.random.normal(ks[1], (T, H, D), jnp.float32)
-    k_new = jax.random.normal(ks[2], (T, KVH, D), jnp.float32)
-    v_new = jax.random.normal(ks[3], (T, KVH, D), jnp.float32)
+    if not int8:
+        return k_f, v_f, {}
+    k_pages, k_scale = quantize_kv(k_f)
+    v_pages, v_scale = quantize_kv(v_f)
+    return k_pages, v_pages, dict(
+        k_scale=pack_scale_pages(k_scale), v_scale=pack_scale_pages(v_scale))
+
+
+def _assert_kernel_matches_reference(keys, T, H, KVH, D, pools, layer,
+                                     t0, q_len, hist, tables, **kernel_kw):
+    """Interpret-mode kernel against the gather reference, row by row."""
+    k_pages, v_pages, scales = pools
+    q = jax.random.normal(keys[0], (T, H, D), jnp.float32)
+    k_new = jax.random.normal(keys[1], (T, KVH, D), jnp.float32)
+    v_new = jax.random.normal(keys[2], (T, KVH, D), jnp.float32)
     args = (
-        q, k_new, v_new, k_pages, v_pages, jnp.int32(seed % L),
-        jnp.asarray(t0), jnp.asarray(q_len), jnp.asarray(hist),
-        jnp.asarray(tables),
+        q, k_new, v_new, k_pages, v_pages, jnp.int32(layer),
+        *(jnp.asarray(x, jnp.int32) for x in (t0, q_len, hist, tables)),
     )
-    want = ragged_paged_attention_reference(
-        *args, k_scale=k_scale, v_scale=v_scale
-    )
+    want = ragged_paged_attention_reference(*args, **scales)
     got = ragged_paged_attention_tpu(
-        *args, interpret=True, k_scale=k_scale, v_scale=v_scale
-    )
-    for r in range(R):
+        *args, interpret=True, **scales, **kernel_kw)
+    for r in range(len(q_len)):
         s0, ql = int(t0[r]), int(q_len[r])
         if ql == 0:
             continue
@@ -162,7 +161,73 @@ def _op_case(rng, *, int8: bool, seed: int):
         )
 
 
+def _op_case(rng, *, int8: bool, seed: int):
+    L, N, P, KVH, D, H, maxP, R = 2, 24, 4, 2, 16, 4, 4, 5
+    ks = jax.random.split(jax.random.fold_in(rng, seed), 4)
+    pools = _pools(ks[0], (L, N, P, KVH, D), int8)
+    T, t0, q_len, hist, tables = _random_layout(
+        np.random.default_rng(seed), R, maxP, P, N)
+    _assert_kernel_matches_reference(
+        ks[1:], T, H, KVH, D, pools, seed % L, t0, q_len, hist, tables)
+
+
+# The decode layout (one flat position a slot, ``q_len`` 0 for a parked
+# slot) under the static one-token bound the engine passes, and one layout
+# that mixes one-token and longer rows under the 8-token block.  Pages of
+# 16 tokens: a chunk is 128 tokens, so histories cross chunk edges.
+_LAYOUTS = {  # name: (q_len a row, history a row, static bound)
+    "all_rows_one_token": ([1] * 6, [5, 130, 77, 300, 19, 250], 1),
+    "parked_rows_between_live": (
+        [1, 0, 1, 0, 0, 1], [40, 99, 200, 7, 0, 131], 1),
+    "rows_without_history": ([1] * 6, [0, 64, 0, 1, 129, 0], 1),
+    "history_ends_mid_page": ([1] * 6, [37, 1, 15, 17, 143, 305], 1),
+    "history_ends_on_a_chunk_edge": (
+        [1] * 6, [128, 256, 127, 129, 16, 255], 1),
+    "history_of_max_pages": ([1] * 6, [320, 3, 320, 0, 319, 320], 1),
+    "first_rows_parked": ([0, 0, 1, 1, 0, 1], [9, 9, 140, 0, 9, 260], 1),
+    "one_token_and_longer_rows": (
+        [1, 5, 1, 12, 0, 1], [200, 3, 0, 131, 50, 320], 12),
+    # fresh keys come 128 a step for the 8-token block: a row of several
+    "a_row_of_several_key_blocks": ([3, 150, 1], [40, 131, 0], 150),
+}
+
+
+def _layout_case(rng, name, *, int8: bool):
+    q_len, hist, bound = _LAYOUTS[name]
+    L, P, KVH, D, H, maxP = 2, 16, 2, 16, 4, 20
+    R = len(q_len)
+    N = R * maxP + 1
+    ks = jax.random.split(jax.random.fold_in(rng, len(name)), 4)
+    pools = _pools(ks[0], (L, N, P, KVH, D), int8)
+    if bound == 1:
+        t0 = np.arange(R)                 # a slot keeps its flat position
+        T = R
+    else:
+        t0 = np.cumsum([0] + q_len[:-1])
+        T = int(sum(q_len))
+    tables = np.random.default_rng(len(name)).permutation(
+        np.arange(1, N))[: R * maxP].reshape(R, maxP)
+    _assert_kernel_matches_reference(
+        ks[1:], T, H, KVH, D, pools, 1, t0, q_len, hist, tables,
+        max_q_len=bound)
+
+
 class TestRaggedOpParity:
+    @pytest.mark.parametrize("pool", ["float32", "int8"])
+    @pytest.mark.parametrize("name", sorted(_LAYOUTS))
+    def test_kernel_matches_reference_at_the_static_bound(
+            self, rng, name, pool):
+        """The block shape follows the static bound on a row's fresh
+        tokens: one-token blocks for the decode layout, 8-token blocks
+        where a row may be longer; one row contract, one answer."""
+        _layout_case(rng, name, int8=pool == "int8")
+
+    def test_query_block_follows_the_static_bound(self):
+        from helix_tpu.ops.paged_kernel import query_block
+
+        assert query_block(1) == 1
+        assert [query_block(n) for n in (2, 4, 8, 512)] == [8] * 4
+
     def test_kernel_matches_reference_random_layout(self, rng):
         """One randomized ragged layout through interpret-mode pallas
         vs the gather reference (fast lane; the sweep is slow)."""

@@ -216,6 +216,14 @@ def test_every_flight_record_carries_phases_that_add_up(stepped):
     assert set(LOOP_PHASES) <= kinds
 
 
+def test_every_flight_record_carries_the_state_segments_query_block(stepped):
+    """Plain decode: the state segment's rows are one-token query blocks
+    (``ops/paged_kernel.py::query_block`` of the segment's width)."""
+    recs = stepped.flight.snapshot(recent=512)["recent"]
+    assert recs and {rec["attn_q_block"] for rec in recs} == {1}
+    assert stepped.engine.attn_q_block == 1
+
+
 @pytest.mark.parametrize("name", LOOP_PHASES)
 def test_phase_histogram_counts_one_observation_a_step(stepped, name):
     hists = dict(stepped.obs.step_phases,
@@ -387,6 +395,11 @@ def test_capture_launch_span_names_the_program(capture):
     assert {ln["kind"] for ln in launches} <= {
         "admit", "chunk", "mixed", "spec", "decode"}
     assert any("step_num" in s for s in events["helix.loop.step"])
+
+
+def test_capture_launch_span_carries_the_query_block(capture):
+    _, _, events = capture
+    assert {ln["attn_q_block"] for ln in events["helix.loop.launch"]} == {1}
 
 
 def test_capture_is_stamped_with_the_monotonic_clock(capture):

@@ -64,7 +64,7 @@ def _ragged_args(geometry, kv, shape, sharding, heads=P(), pool=P(),
     """ShapeDtypeStructs of one ragged-op call.  ``sharding(spec)`` places
     an argument; the specs only matter under a mesh."""
     H, KVH, D, L = GEOMETRY[geometry]
-    T, R = (DECODE_BATCH, DECODE_BATCH) if shape == "decode" else (
+    T, R = (DECODE_BATCH, DECODE_BATCH) if shape.startswith("decode") else (
         PREFILL_LEN, 1)
 
     def S(shp, dt, spec=P()):
@@ -98,21 +98,38 @@ def _compile_ragged(args, **kw):
     return jax.jit(op).lower(*args).compile()
 
 
-@pytest.mark.parametrize("shape", ["decode", "prefill_with_history"])
+# the static bound on a row's fresh tokens, as the engine passes it: a
+# decode call's rows are one-token query blocks (``decode_one_token``),
+# a chunk's are 8-token blocks, and so are a caller's that gives no bound
+MAX_Q_LEN = {"decode": None, "decode_one_token": 1,
+             "prefill_with_history": PREFILL_LEN}
+
+
+@pytest.mark.parametrize("shape", sorted(MAX_Q_LEN))
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 @pytest.mark.parametrize("geometry", sorted(GEOMETRY))
 def test_ragged_kernel_compiles(one_chip, geometry, kv, shape):
     compiled = _compile_ragged(
-        _ragged_args(geometry, kv, shape, lambda spec: one_chip)
+        _ragged_args(geometry, kv, shape, lambda spec: one_chip),
+        max_q_len=MAX_Q_LEN[shape],
     )
     assert compiled.memory_analysis() is not None
 
 
 def test_decode_kernel_is_in_the_compiled_text(one_chip):
-    compiled = _compile_ragged(
-        _ragged_args("qwen2-7b", "bf16", "decode", lambda spec: one_chip)
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+    """One Pallas call, under the name the benchmark's trace readers count
+    model steps by (``benchmark/metrics/step.decode_ms.json``)."""
+    import re
+
+    text = _compile_ragged(
+        _ragged_args("qwen2-7b", "bf16", "decode_one_token",
+                     lambda spec: one_chip),
+        max_q_len=1,
+    ).as_text()
+    calls = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 1 and calls[0].startswith(
+        "ragged_paged_attention_tpu"), calls
 
 
 @pytest.mark.parametrize("geometry", sorted(GEOMETRY))
@@ -162,7 +179,7 @@ def test_ragged_kernel_compiles_over_a_tp_mesh(topo, kv):
 
     mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("dp", "tp"))
     args = _ragged_args(
-        "llama3-8b", kv, "decode",
+        "llama3-8b", kv, "decode_one_token",
         lambda spec: NamedSharding(mesh, spec),
         heads=P(None, "tp", None),
         pool=P(None, None, None, "tp", None),
@@ -170,9 +187,9 @@ def test_ragged_kernel_compiles_over_a_tp_mesh(topo, kv):
     )
     if kv == "int8":
         with pytest.raises(UnsupportedKernelGeometry, match="sublane pack"):
-            _compile_ragged(args, mesh=mesh)
+            _compile_ragged(args, mesh=mesh, max_q_len=1)
         return
-    text = _compile_ragged(args, mesh=mesh).as_text()
+    text = _compile_ragged(args, mesh=mesh, max_q_len=1).as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text   # nothing crosses chips: heads only
 
