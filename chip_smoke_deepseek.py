@@ -1,8 +1,17 @@
 #!/usr/bin/env python3
-"""DeepSeek-V2-Lite on the chip, against its references (``chip_smoke.py``'s
-sibling for the latent-attention, expert-layer path).
+"""An expert-layer configuration of the benchmark on the chip, against its
+references (``chip_smoke.py``'s sibling; the name is from the first
+configuration it took).
 
-    python3 chip_smoke_deepseek.py [--seed N] [--layers 17] [--steps 32]
+    python3 chip_smoke_deepseek.py [--config NAME] [--seed N] [--steps 32]
+
+``--config`` is a configuration of ``benchmark/configs/``: ``deepseek-v2-
+lite-int8`` (the default: latent attention, 64 + 2 experts; ``--layers 17``)
+or ``lfm2-8b-a1b-int8`` (gated short convolutions with a state pool, GQA
+heads of width 64 packed two to a lane tile, 32 experts behind a sigmoid-
+and-bias router; whole).  ``CONFIGS`` holds what differs: the reference, the
+kernel cases, the faults and the limits.  What follows describes DeepSeek-
+V2-Lite; the other configuration's table entry says what it changes.
 
 One process holds the chip.  Two phases:
 
@@ -69,30 +78,34 @@ TOL_LOGITS = 0.025
 TOL_LOGITS_WORST = 0.045
 
 
-def phase_kernel(seed, rehearse):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+# The same two limits for ``lfm2-8b-a1b-int8``, set from its own readings on
+# the chip (PERF.md section 6, PR 32; seeds 3000003201 | 3000003202, taken
+# with the final norm's gain at 1, logits of std 45; the gain is E ** -0.5
+# since, every reading here is relative, and the script has NOT run on the
+# chip with it: PERF.md section 7, 20).  This model is far
+# more sensitive to rounding than DeepSeek's 17 layers: its top-4 weights
+# are renormalised to sum to 1, so one near-tied expert choice that bf16
+# flips moves a layer's whole routed branch by a quarter, where DeepSeek's
+# unnormalised top-6 carry a tenth of the softmax's mass.  bf16 activations
+# ALONE in the reference read 0.088-0.224 | 0.015-0.173; the engine's median
+# is 0.079 | 0.101, its worst step 0.257 | 0.192 (steps ride between 0.03
+# and 0.19 as the bf16 reference's do), the prefix-hit request's worst
+# 0.142 | 0.191.  An 8-bit activation path reads 0.169-0.272 | 0.223-0.321,
+# a dropped expert (top-3) 0.397-0.405 | 0.391-0.415, the conv state zeroed
+# under the position that is read 1.11-1.12 | 1.08-1.09.  The median's
+# limit lies between the engine and every fault; a single step cannot be
+# told from an 8-bit activation path here (its limit lies under the dropped
+# expert and the zeroed state only).
+TOL_LFM2 = 0.13
+TOL_LFM2_WORST = 0.35
 
-    from helix_tpu.models.moe import experts_pallas, experts_xla
-    from helix_tpu.ops.grouped_matmul import row_tile, visit_plan
-    from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
-    from helix_tpu.ops.paged import (
-        mla_ragged_paged_attention,
-        mla_ragged_paged_attention_reference,
-    )
 
-    H, R, dr, P, L = 16, 512, 64, 16, 2
-    N, maxP, B, S = (64, 8, 4, 32) if rehearse else (2048, 160, 64, 512)
-    rng = np.random.default_rng(seed)
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    dt = jnp.float32 if rehearse else jnp.bfloat16
-    c_pages = jax.random.normal(ks[0], (L, N, P, R), jnp.float32).astype(dt)
-    r_pages = jnp.pad(
-        jax.random.normal(ks[1], (L, N, P, dr), jnp.float32).astype(dt),
-        ((0, 0),) * 3 + ((0, 128 - dr),))
+def _attention_cases(rng, B, S, maxP, P, N):
+    """The three shapes every paged kernel is held to: 64 decode rows over
+    ragged histories, a chunk row over history, packed cold rows.  Each
+    ``(tokens, t0, q_len, hist, tables, max_q_len)``."""
     pages = rng.permutation(np.arange(1, N))
-    shapes = {
+    return {
         "decode": (B, np.arange(B), np.ones(B, int),
                    rng.integers(1, maxP * P - 1, size=B),
                    np.resize(pages, (B, maxP)), 1),
@@ -103,8 +116,38 @@ def phase_kernel(seed, rehearse):
                         np.array([S // 4, 7, S // 2]), np.zeros(3, int),
                         np.zeros((3, maxP), int), S),
     }
+
+
+def _hold(op, geometry, name, T, t0, q_len, got, want):
+    """One kernel case against its reference, over the tokens in rows."""
+    in_row = np.zeros(T, bool)
+    for s0, n in zip(t0, q_len):
+        in_row[s0:s0 + n] = True
+    got, want = (np.asarray(x, np.float32)[in_row] for x in (got, want))
+    err = float(np.abs(got - want).max())
+    good = bool(np.isfinite(got).all() and err <= TOL_BF16)
+    say(phase="kernel", op=op, geometry=geometry, shape=name, tokens=T,
+        max_abs_err=err, tol=TOL_BF16, ok=good)
+    return good
+
+
+def kernel_mla(seed, rehearse, rng, ks):
+    from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
+    from helix_tpu.ops.paged import (
+        mla_ragged_paged_attention,
+        mla_ragged_paged_attention_reference,
+    )
+
+    H, R, dr, P, L = 16, 512, 64, 16, 2
+    N, maxP, B, S = (64, 8, 4, 32) if rehearse else (2048, 160, 64, 512)
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    c_pages = jax.random.normal(ks[0], (L, N, P, R), jnp.float32).astype(dt)
+    r_pages = jnp.pad(
+        jax.random.normal(ks[1], (L, N, P, dr), jnp.float32).astype(dt),
+        ((0, 0),) * 3 + ((0, 128 - dr),))
     ok = True
-    for name, (T, t0, q_len, hist, tables, mq) in shapes.items():
+    cases = _attention_cases(rng, B, S, maxP, P, N)
+    for name, (T, t0, q_len, hist, tables, mq) in cases.items():
         q = (jax.random.normal(ks[2], (T, H, R + dr)) * 0.1).astype(dt)
         c_new = jax.random.normal(ks[3], (T, R)).astype(dt)
         r_new = jax.random.normal(ks[4], (T, dr)).astype(dt)
@@ -119,28 +162,75 @@ def phase_kernel(seed, rehearse):
                 *args, backend="pallas", max_q_len=mq)
         with jax.default_matmul_precision("highest"):
             want = mla_ragged_paged_attention_reference(*args)
-        in_row = np.zeros(T, bool)
-        for s0, n in zip(t0, q_len):
-            in_row[s0:s0 + n] = True
-        got, want = (np.asarray(x, np.float32)[in_row] for x in (got, want))
-        err = float(np.abs(got - want).max())
-        good = bool(np.isfinite(got).all() and err <= TOL_BF16)
-        ok &= good
-        say(phase="kernel", op="mla_ragged_paged_attention",
-            geometry=[H, R, dr], shape=name, tokens=T, max_abs_err=err,
-            tol=TOL_BF16, ok=good)
+        ok &= _hold("mla_ragged_paged_attention", [H, R, dr], name, T, t0,
+                    q_len, got, want)
     if not ok:
         fail("the latent kernel disagrees with its reference")
+    return B, S
+
+
+def kernel_gqa_64(seed, rehearse, rng, ks):
+    """The dense ragged kernel at 32 query / 8 kv heads of width 64: the
+    pool holds two kv heads a 128-lane tile (``[P, 4, 128]``), and the
+    reference reads the same pool as ``[P, 8, 64]``, packing nothing."""
+    from helix_tpu.ops.paged import (
+        pack_heads, ragged_paged_attention,
+        ragged_paged_attention_reference, unpack_heads,
+    )
+    from helix_tpu.ops.paged_kernel import ragged_paged_attention_tpu
+
+    H, KVH, D, P, L = 32, 8, 64, 16, 2
+    N, maxP, B, S = (64, 8, 4, 32) if rehearse else (2048, 160, 64, 512)
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    k_pages = jax.random.normal(ks[0], (L, N, P, KVH, D)).astype(dt)
+    v_pages = jax.random.normal(ks[1], (L, N, P, KVH, D)).astype(dt)
+    packed = lambda a: a.reshape(L, N, P, KVH // 2, 2 * D)  # noqa: E731
     ok = True
-    X, E, F = (8, 256, 128) if rehearse else (64, 2048, 1408)
+    cases = _attention_cases(rng, B, S, maxP, P, N)
+    for name, (T, t0, q_len, hist, tables, mq) in cases.items():
+        q = jax.random.normal(ks[2], (T, H, D)).astype(dt)
+        k_new = jax.random.normal(ks[3], (T, KVH, D)).astype(dt)
+        v_new = jax.random.normal(ks[4], (T, KVH, D)).astype(dt)
+        meta = (jnp.int32(1), *(jnp.asarray(x, jnp.int32)
+                                for x in (t0, q_len, hist, tables)))
+        if rehearse:
+            qp, kp, vp = pack_heads(q, k_new, v_new, 2)
+            got = unpack_heads(ragged_paged_attention_tpu(
+                qp, kp, vp, packed(k_pages), packed(v_pages), *meta,
+                scale=D ** -0.5, max_q_len=mq, interpret=True), 2, KVH)
+        else:
+            got = ragged_paged_attention(
+                q, k_new, v_new, packed(k_pages), packed(v_pages), *meta,
+                backend="pallas", max_q_len=mq)
+        with jax.default_matmul_precision("highest"):
+            want = ragged_paged_attention_reference(
+                q, k_new, v_new, k_pages, v_pages, *meta)
+        ok &= _hold("ragged_paged_attention", [H, KVH, D], name, T, t0,
+                    q_len, got, want)
+    if not ok:
+        fail("the ragged kernel at head width 64 disagrees with its "
+             "reference")
+    return B, S
+
+
+def phase_kernel(spec, seed, rehearse):
+    from helix_tpu.models.moe import experts_pallas, experts_xla
+    from helix_tpu.ops.grouped_matmul import row_tile, visit_plan
+
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    B, S = spec["attention_kernel"](seed, rehearse, rng, ks)
+    ok = True
+    X, E, F, k = (8, 256, 128, spec["experts"][3]) if rehearse else (
+        spec["experts"])
     stack = {
         name: {"weight": jnp.asarray(rng.integers(
-                   -127, 128, (2, X, k, n), dtype=np.int8)),
+                   -127, 128, (2, X, kk, n), dtype=np.int8)),
                "scale": jnp.asarray(
                    rng.random((2, X, 1, n)) * 4e-4 + 1e-4, jnp.float32)}
-        for name, (k, n) in (("w_gate", (E, F)), ("w_up", (E, F)),
-                             ("w_down", (F, E)))}
-    for name, rows, skew in (("decode", 6 * B, 8.0), ("chunk", 6 * S, 1.2)):
+        for name, (kk, n) in (("w_gate", (E, F)), ("w_up", (E, F)),
+                              ("w_down", (F, E)))}
+    for name, rows, skew in (("decode", k * B, 8.0), ("chunk", k * S, 1.2)):
         sizes = rng.multinomial(rows - 7, rng.dirichlet(np.full(X, skew)))
         xs = jax.random.normal(ks[2], (rows, E)).astype(jnp.bfloat16)
         tm = row_tile(rows, X)
@@ -164,21 +254,12 @@ def phase_kernel(seed, rehearse):
         fail("the grouped expert product kernel disagrees with ragged_dot")
 
 
-def phase_engine(seed, layers, steps, rehearse):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+# ---- what differs between the configurations ------------------------------
 
-    from benchmark.lib import reference_mla_moe_decoder as reference
-    from helix_tpu.engine.engine import (
-        Engine, EngineConfig, Request, SamplingParams,
-    )
+
+def deepseek_model(hf, layers, rehearse):
     from helix_tpu.models.common import DEEPSEEK_V2_LITE, ModelConfig
-    from helix_tpu.models.llama import init_params
 
-    with open(os.path.join(HERE, "benchmark", "configs",
-                           "deepseek-v2-lite-int8.json")) as f:
-        hf = json.load(f)
     if rehearse:
         cfg = ModelConfig(
             vocab_size=300, hidden_size=64, num_layers=3, num_heads=4,
@@ -189,16 +270,102 @@ def phase_engine(seed, layers, steps, rehearse):
             moe_intermediate_size=32, num_shared_experts=2, first_k_dense=1,
             moe_renormalize=False, kv_lora_rank=32, qk_nope_head_dim=16,
             qk_rope_head_dim=8, v_head_dim=16, name="tiny-mla-moe")
-        hf = dict(hf, num_hidden_layers=3, num_attention_heads=4,
-                  kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
-                  v_head_dim=16, n_routed_experts=8, num_experts_per_tok=3)
+        return cfg, dict(
+            hf, num_hidden_layers=3, num_attention_heads=4,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, n_routed_experts=8, num_experts_per_tok=3)
+    return (dataclasses.replace(DEEPSEEK_V2_LITE, num_layers=layers),
+            dict(hf, num_hidden_layers=layers))
+
+
+def deepseek_reference(reference, hf, params, pos):
+    """``(layer(h, l, act, top_k, fault), head weight)`` of the reference a
+    layer at a time over the program's two stacks."""
+    inv_freq = jnp.asarray(reference.yarn_inv_freq(
+        hf["qk_rope_head_dim"], hf["rope_theta"], hf["rope_scaling"]))
+    n_dense = hf.get("first_k_dense_replace", 0)
+
+    def home(l):
+        return ("dense_layers", l) if l < n_dense else ("layers", l - n_dense)
+
+    def layer(h, stack, i, l, last, act, top_k, fault):
+        return reference._layer(h, stack, i, hf, pos, inv_freq, act, top_k)
+
+    return home, layer, lambda: reference._f32(params["lm_head"])
+
+
+def lfm2_model(hf, layers, rehearse):
+    from helix_tpu.models.common import ModelConfig
+
+    if rehearse:
+        hf = dict(
+            hf, vocab_size=256, hidden_size=64, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=64, intermediate_size=128,
+            moe_intermediate_size=32, num_hidden_layers=9,
+            layer_types=["conv", "conv", "full_attention"] * 3,
+            num_experts=8, num_experts_per_tok=2)
+    cfg = ModelConfig.from_hf_config(hf, name=hf["model"])
+    if rehearse:
+        cfg = dataclasses.replace(cfg, dtype="float32")
+    return cfg, hf
+
+
+def lfm2_reference(reference, hf, params, pos):
+    homes = reference.layer_homes(hf)
+    n_dense = hf.get("num_dense_layers", 0)
+
+    def layer(h, stack, i, l, last, act, top_k, fault):
+        return reference._layer(
+            h, stack, i, hf["layer_types"][l] == "conv", l >= n_dense, hf,
+            pos, act, top_k,
+            # the position that is read finds zeros where its sequence's
+            # state should be: a slot's state lost, or never restored (a
+            # state lost earlier, at a chunk boundary, reaches a later
+            # position's logits only through two tokens' keys and values)
+            last if fault == "zeroed_conv_state" else None, True, True)
+
+    return homes.__getitem__, layer, lambda: reference._f32(
+        params["embed"]).T
+
+
+CONFIGS = {
+    "deepseek-v2-lite-int8": dict(
+        reference="reference_mla_moe_decoder", model=deepseek_model,
+        layers=deepseek_reference, attention_kernel=kernel_mla,
+        experts=(64, 2048, 1408, 6), faults=("act_8bit", "dropped_expert"),
+        limits=(TOL_LOGITS, TOL_LOGITS_WORST), norm_eps="rms_norm_eps",
+        prefix_hit=False),
+    "lfm2-8b-a1b-int8": dict(
+        reference="reference_hybrid_conv_moe_decoder", model=lfm2_model,
+        layers=lfm2_reference, attention_kernel=kernel_gqa_64,
+        experts=(32, 2048, 1792, 4),
+        faults=("act_8bit", "dropped_expert", "zeroed_conv_state"),
+        limits=(TOL_LFM2, TOL_LFM2_WORST), norm_eps="norm_eps",
+        # a second request that shares the first chunk: the pages AND the
+        # conv state filed at their end are what it resumes from
+        prefix_hit=True),
+}
+
+
+def phase_engine(spec, name, seed, layers, steps, rehearse):
+    import importlib
+
+    from helix_tpu.engine.engine import (
+        Engine, EngineConfig, Request, SamplingParams,
+    )
+    from helix_tpu.models.llama import init_params
+
+    reference = importlib.import_module("benchmark.lib." + spec["reference"])
+    with open(os.path.join(HERE, "benchmark", "configs",
+                           name + ".json")) as f:
+        hf = json.load(f)
+    cfg, hf = spec["model"](hf, layers, rehearse)
+    if rehearse:
         ecfg = EngineConfig(max_decode_batch=2, page_size=8, num_pages=64,
                             max_pages_per_seq=16, max_prefill_len=16,
                             attn_backend="reference")
         n_prompt, steps = 24, 4
     else:
-        cfg = dataclasses.replace(DEEPSEEK_V2_LITE, num_layers=layers)
-        hf = dict(hf, num_hidden_layers=layers)
         ecfg = EngineConfig(max_decode_batch=8, page_size=16, num_pages=512,
                             max_pages_per_seq=160, max_prefill_len=512)
         n_prompt = 600
@@ -207,9 +374,11 @@ def phase_engine(seed, layers, steps, rehearse):
                          int8=not rehearse)
     jax.block_until_ready(params)
     eng = Engine(cfg, params, ecfg)
-    say(phase="engine", layers=cfg.num_layers, weights_s=round(
-        time.monotonic() - t, 1), backend=eng._backend,
-        grouped_backend=eng.grouped_backend)
+    say(phase="engine", config=name, layers=cfg.num_layers,
+        weights_s=round(time.monotonic() - t, 1), backend=eng._backend,
+        grouped_backend=eng.grouped_backend,
+        recurrent_state_bytes=eng.recurrent_state_bytes,
+        page_bytes=eng.cache_cfg.page_bytes(cfg))
     prompt = np.random.default_rng(seed).integers(
         1, cfg.vocab_size, size=n_prompt).tolist()
     req = Request(id="smoke", prompt_tokens=prompt,
@@ -217,14 +386,13 @@ def phase_engine(seed, layers, steps, rehearse):
                                           temperature=1.0, seed=seed))
     eng.add_request(req)
 
-    # The reference a layer at a time, jitted (eagerly its loop over 64
+    # The reference a layer at a time, jitted (eagerly its loop over the
     # experts takes a minute a forward): one program a layer kind, the
     # layer's index dynamic, the sequence padded to a fixed length (causal:
     # what follows a position does not reach it).
     s_pad = -(-(n_prompt + steps + 8) // 64) * 64
     pos = jnp.arange(s_pad)
-    inv_freq = jnp.asarray(reference.yarn_inv_freq(
-        hf["qk_rope_head_dim"], hf["rope_theta"], hf["rope_scaling"]))
+    home, layer_fn, head_w = spec["layers"](reference, hf, params, pos)
     faults_kw = {
         "none": {},
         "act_8bit": {"act": reference.round_to_8_bits},
@@ -232,14 +400,15 @@ def phase_engine(seed, layers, steps, rehearse):
         "act_bf16": {"act": lambda x: x.astype(jnp.bfloat16).astype(
             jnp.float32)},
         "dropped_expert": {"top_k": hf["num_experts_per_tok"] - 1},
+        "zeroed_conv_state": {},
     }
 
-    @functools.partial(jax.jit, static_argnames=("fault",))
-    def ref_layer(h, stack, i, fault):
+    @functools.partial(jax.jit, static_argnames=("layer", "fault"))
+    def ref_layer(h, stack, i, last, layer, fault):
         kw = {"act": lambda x: x, "top_k": None, **faults_kw[fault]}
         with jax.default_matmul_precision("highest"):
-            return reference._layer(h, stack, i, hf, pos, inv_freq,
-                                    kw["act"], kw["top_k"])
+            return layer_fn(h, stack, i, layer, last, kw["act"],
+                            kw["top_k"], fault)
 
     @functools.partial(jax.jit, static_argnames=("fault",))
     def ref_ends(tokens, h, last, fault):
@@ -249,8 +418,12 @@ def phase_engine(seed, layers, steps, rehearse):
                 return reference._f32(params["embed"])[tokens]
             x = act(reference.rms_norm(
                 h[last], params["final_norm"]["weight"].astype(jnp.float32),
-                hf["rms_norm_eps"]))
-            return x @ reference._f32(params["lm_head"])
+                hf[spec["norm_eps"]]))
+            return x @ head_w()
+
+    # layers of one kind share a program: ``layer`` is static only as far
+    # as the kind it stands for (the first layer of its stack)
+    first_of = {}
 
     def ref(seq, fault="none"):
         """The reference's logits at the last position of ``seq``."""
@@ -258,15 +431,25 @@ def phase_engine(seed, layers, steps, rehearse):
         toks = jnp.asarray(list(seq) + [0] * (s_pad - n), jnp.int32)
         h = ref_ends(toks, None, 0, fault)
         for layer in range(cfg.num_layers):
-            dense = layer < cfg.first_k_dense
-            h = ref_layer(
-                h, params["dense_layers" if dense else "layers"],
-                jnp.int32(layer if dense else layer - cfg.first_k_dense),
-                fault)
+            key, i = home(layer)
+            h = ref_layer(h, params[key], jnp.int32(i), jnp.int32(n - 1),
+                          first_of.setdefault(key, layer), fault)
         return np.asarray(ref_ends(toks, h, n - 1, fault), np.float32)
 
     def rel_rms(got, want):
         return float(np.sqrt(np.mean((got - want) ** 2)) / want.std())
+
+    def read(r, rows):
+        seq = r.prompt_tokens + r.output_tokens
+        got = np.asarray(eng.next_token_logits()[r.slot], np.float32)
+        want = ref(seq)
+        row = {"request": r.id, "step": len(rows), "tokens": len(seq),
+               "rel_rms_err": rel_rms(got, want),
+               "max_abs_err": float(np.abs(got - want).max()),
+               "logit_std": float(want.std())}
+        rows.append(row)
+        say(phase="engine", **row)
+        return seq, want
 
     rows, faults = [], []
     while eng.has_work() and len(rows) < steps:
@@ -274,68 +457,101 @@ def phase_engine(seed, layers, steps, rehearse):
         if not req.output_tokens or req.slot is None or (
                 eng.slots[req.slot] is not req):
             continue
-        seq = prompt + req.output_tokens
-        got = np.asarray(eng.next_token_logits()[req.slot], np.float32)
-        want = ref(seq)
-        row = {"step": len(rows), "tokens": len(seq),
-               "rel_rms_err": rel_rms(got, want),
-               "max_abs_err": float(np.abs(got - want).max()),
-               "logit_std": float(want.std())}
-        rows.append(row)
-        say(phase="engine", **row)
+        seq, want = read(req, rows)
         if len(rows) in (1, steps):
-            # what two faults would read, on the same tokens
-            for name in ("act_bf16", "act_8bit", "dropped_expert"):
-                bad = ref(seq, name)
-                faults.append({"step": row["step"], "fault": name,
+            # what the faults would read, on the same tokens
+            for fault in ("act_bf16",) + spec["faults"]:
+                bad = ref(seq, fault)
+                faults.append({"step": rows[-1]["step"], "fault": fault,
                                "rel_rms_err": rel_rms(bad, want),
                                "max_abs_err": float(
                                    np.abs(bad - want).max())})
                 say(phase="engine", **faults[-1])
+    hit_rows, hit_ok = [], True
+    if spec["prefix_hit"]:
+        share = ecfg.max_prefill_len if not rehearse else 16
+        second = Request(
+            id="hit", prompt_tokens=prompt[:share] + np.random.default_rng(
+                seed + 1).integers(1, cfg.vocab_size, size=60 if not
+                                   rehearse else 5).tolist(),
+            sampling=SamplingParams(max_tokens=8, temperature=1.0,
+                                    seed=seed + 1))
+        eng.add_request(second)
+        while eng.has_work() and len(hit_rows) < 4:
+            eng.step()
+            if second.output_tokens and second.slot is not None and (
+                    eng.slots[second.slot] is second):
+                read(second, hit_rows)
+        hit_ok = (second.cached_tokens == share
+                  and eng.num_state_restores >= 1 and len(hit_rows) >= 3)
+        say(phase="engine", request="hit", cached_tokens=second.cached_tokens,
+            state_restores=eng.num_state_restores,
+            state_snapshots=eng.num_state_snapshots,
+            shortened=eng.prefix_hits_shortened, ok=hit_ok)
+    tol_median, tol_worst = spec["limits"]
     errs = sorted(r["rel_rms_err"] for r in rows)
     worst, median = errs[-1], errs[len(errs) // 2]
+    hit_worst = max((r["rel_rms_err"] for r in hit_rows), default=0.0)
     least_fault = min(f["rel_rms_err"] for f in faults
                       if f["fault"] != "act_bf16")
     # every (token, choice) of every expert layer routed, none dropped:
-    # the prompt's tokens and one decode step a token after the first
+    # each prompt's fresh tokens and one decode step a token after the first
     forwards = n_prompt + len(req.output_tokens) - 1
+    if spec["prefix_hit"]:
+        forwards += (len(second.prompt_tokens) - second.cached_tokens
+                     + len(second.output_tokens) - 1)
     want_routed = forwards * cfg.num_experts_per_tok * cfg.num_moe_layers
     counted = (eng.moe_dropped_tokens == 0
                and eng.moe_routed_tokens == want_routed)
-    ok = (len(rows) >= steps and median <= TOL_LOGITS < least_fault
-          and worst <= TOL_LOGITS_WORST and counted)
+    ok = (len(rows) >= steps and median <= tol_median < least_fault
+          and worst <= tol_worst and hit_worst <= tol_worst and hit_ok
+          and counted)
     say(phase="engine", steps=len(rows), prompt_tokens=n_prompt,
         chunks=-(-n_prompt // ecfg.max_prefill_len),
         median_rel_rms_err=median, worst_rel_rms_err=worst,
+        prefix_hit_worst_rel_rms_err=hit_worst,
         worst_max_abs_err=max(
             r["max_abs_err"] for r in rows), faults=faults,
-        tol_median=TOL_LOGITS, tol_worst=TOL_LOGITS_WORST, moe_dropped=eng.moe_dropped_tokens,
+        tol_median=tol_median, tol_worst=tol_worst,
+        moe_dropped=eng.moe_dropped_tokens,
         moe_routed=eng.moe_routed_tokens, moe_routed_expected=want_routed,
         experts_touched=eng.moe_experts_touched,
         tile_fill=eng.moe_tile_fill_ratio,
         load_max_ratio=eng.moe_expert_load_max_ratio, ok=ok)
     if not ok and not rehearse:
         fail("the engine and the reference part by more than the "
-             "tolerance, the tolerance does not separate the two faults, "
-             "or the routing count is off")
+             "tolerance, the tolerance does not separate the faults, the "
+             "prefix hit did not resume from a filed state, or the routing "
+             "count is off")
 
 
 def main():
+    global jax, jnp, np
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default="deepseek-v2-lite-int8",
+                    choices=sorted(CONFIGS))
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--layers", type=int, default=17)
+    ap.add_argument("--layers", type=int, default=17,
+                    help="deepseek-v2-lite-int8 only: the depth it is cut to")
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     device = _device_or_die(args.rehearse, 1)
-    phase_kernel(args.seed, args.rehearse)
-    phase_engine(args.seed, args.layers, args.steps, args.rehearse)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    spec = CONFIGS[args.config]
+    phase_kernel(spec, args.seed, args.rehearse)
+    phase_engine(spec, args.config, args.seed, args.layers, args.steps,
+                 args.rehearse)
     if args.rehearse:
         say(ok=False, rehearsal=True, device=device)
         sys.exit(4)
-    print(json.dumps({"ok": True, "device": device}), flush=True)
+    print(json.dumps({"ok": True, "config": args.config, "device": device}),
+          flush=True)
 
 
 if __name__ == "__main__":

@@ -286,6 +286,7 @@ class EngineConfig:
             page_size=self.page_size,
             max_pages_per_seq=self.max_pages_per_seq,
             dtype=kv_dtype,
+            state_slots=self.max_decode_batch,
         )
 
 
@@ -660,6 +661,7 @@ def _pin_default_layout(cache):
         v_pages=pin(cache.v_pages),
         k_scale=None if cache.k_scale is None else pin(cache.k_scale),
         v_scale=None if cache.v_scale is None else pin(cache.v_scale),
+        state=cache.state,
     )
 
 
@@ -712,26 +714,67 @@ class UnsupportedForModel(ValueError):
     (raised when the engine is built, i.e. at profile apply)."""
 
 
-def _refuse_for_latent_attention(model_cfg, cfg, mesh) -> None:
-    """What a latent-attention (MLA) model is not served with: each is
-    refused here, by name, rather than run on a path that was never
-    written for a pool with no head axis."""
-    why = None
-    if mesh is not None and mesh.devices.size > 1:
-        why = (f"a mesh of {mesh.devices.size} devices (the latent pool "
-               "and its kernel are single-device: mesh {tp: 1})")
-    elif cfg.kv_cache_dtype == "int8":
-        why = "kv_cache_dtype int8 (the latent pool is bf16 or f32)"
-    elif cfg.adapter_pool_slots > 0:
-        why = "adapter_pool_slots > 0 (no LoRA targets on MLA projections)"
-    elif cfg.enable_spec_decode:
-        why = "enable_spec_decode (untested on the latent kernel)"
-    elif cfg.ctx_hot_pages > 0:
-        why = "ctx_hot_pages > 0 (tiered residency streams K/V chunks)"
-    if why:
+# What an architecture is not served with: (engine setting, what of the
+# model meets it, why).  Each row is refused by name when the engine is
+# built, rather than run on a path that was never written for a pool with
+# no head axis, or with a sequence's recurrent state left behind.
+_MULTI_DEVICE = ("a mesh of more than one device",
+                 lambda cfg, mesh: mesh is not None and mesh.devices.size > 1)
+_INT8_KV = ("kv_cache_dtype int8",
+            lambda cfg, mesh: cfg.kv_cache_dtype == "int8")
+_ADAPTERS = ("adapter_pool_slots > 0",
+             lambda cfg, mesh: cfg.adapter_pool_slots > 0)
+_SPEC = ("enable_spec_decode", lambda cfg, mesh: cfg.enable_spec_decode)
+_TIERED = ("ctx_hot_pages > 0", lambda cfg, mesh: cfg.ctx_hot_pages > 0)
+_HOST_TIER = ("host_pool_bytes > 0",
+              lambda cfg, mesh: cfg.host_pool_bytes > 0)
+_LATENT = ("latent attention (MLA)", lambda m: m.is_mla)
+_RECURRENT = ("recurrent state (gated short convolutions)",
+              lambda m: m.num_conv_layers > 0)
+_PACKED_HEADS = ("kv heads packed into one lane tile (head width under "
+                 "128)", lambda m: m.kv_head_pack > 1)
+_REFUSALS = (
+    (_MULTI_DEVICE, _LATENT,
+     "the latent pool and its kernel are single-device: mesh {tp: 1}"),
+    (_INT8_KV, _LATENT, "the latent pool is bf16 or f32"),
+    (_ADAPTERS, _LATENT, "no LoRA targets on MLA projections"),
+    (_SPEC, _LATENT, "untested on the latent kernel"),
+    (_TIERED, _LATENT, "tiered residency streams K/V chunks"),
+    (_MULTI_DEVICE, _RECURRENT,
+     "the state pool and the conv operator are single-device"),
+    (_INT8_KV, _RECURRENT, "the page pool beside a state pool is bf16 or "
+     "f32"),
+    (_ADAPTERS, _RECURRENT, "no LoRA targets on the conv projections"),
+    (_SPEC, _RECURRENT,
+     "a rejected draft would have to roll the conv state back"),
+    (_TIERED, _RECURRENT,
+     "a demoted cold middle is resumed without the state at its end"),
+    (_HOST_TIER, _RECURRENT,
+     "a spilled prefix or a preempted sequence's pages come back "
+     "without the state"),
+    (_INT8_KV, _PACKED_HEADS, "an int8 pool's scales are one a kv head"),
+)
+
+
+def refuse_unsupported(model_cfg, cfg, mesh) -> None:
+    """Raise :class:`UnsupportedForModel` for the first row of
+    ``_REFUSALS`` that the engine settings and the model both meet."""
+    for (setting, is_set), (prop, has), why in _REFUSALS:
+        if has(model_cfg) and is_set(cfg, mesh):
+            raise UnsupportedForModel(
+                f"{model_cfg.name}: {prop} is not served with {setting} "
+                f"({why})"
+            )
+
+
+def _refuse_call(model_cfg, what: str) -> None:
+    """Paths that move a sequence's pages and are asked for by a call, not
+    a setting: refused for a model whose sequences carry a state too."""
+    if model_cfg.num_conv_layers:
         raise UnsupportedForModel(
-            f"{model_cfg.name}: latent attention (MLA) is not served with "
-            + why
+            f"{model_cfg.name}: recurrent state (gated short "
+            f"convolutions) is not served with {what} (the sequence's "
+            "conv state has no place in what it moves)"
         )
 
 
@@ -741,9 +784,90 @@ def _fresh_kv_zeros(cfg: ModelConfig, B: int, S: int):
     for the one ``write_kv`` scatter after it."""
     kdt = jnp.dtype(cfg.dtype)
     return tuple(
-        jnp.zeros((cfg.num_layers, B, S) + shp, kdt)
+        jnp.zeros((cfg.num_attn_layers, B, S) + shp, kdt)
         for shp in cfg.kv_token_shapes()
     )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _restore_state_fn(pool, slot, state):
+    return pool.at[:, slot].set(state.astype(pool.dtype))
+
+
+def _cache_from(pc, cache: PagedKVCache) -> PagedKVCache:
+    """The cache back from a forward pass's pool carry, which ends in the
+    state pool iff ``cache`` has one."""
+    if cache.state is None:
+        return PagedKVCache.from_carry(pc)
+    return PagedKVCache.from_carry(pc[:-1], pc[-1])
+
+
+def _state_after(zf, S, t0, n):
+    """The conv state of each row after ``n [R]`` of its fresh tokens: the
+    last ``K - 1`` of (the state it came with, its first ``n`` tokens),
+    oldest first.  ``zf [T, E]`` flat, ``S [R, K - 1, E]``, ``t0 [R]``."""
+    K1 = S.shape[1]
+    T = zf.shape[0]
+    out = []
+    for i in range(K1):
+        at = n + i - K1                      # offset in the row, < 0: in S
+        fresh = zf[jnp.clip(t0 + at, 0, T - 1)]
+        old = S[:, 0]
+        for m in range(1, K1):
+            old = jnp.where((n + i == m)[:, None], S[:, m], old)
+        out.append(jnp.where((at >= 0)[:, None], fresh, old))
+    return jnp.stack(out, axis=1)
+
+
+def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
+    """The ``conv_fn`` of one segment of the step (``models/llama.py::
+    _conv_mixer``): its tokens lie row after row on one flat axis, row
+    ``r`` the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
+    ``slots[r]``, which has ``hist[r]`` tokens behind it.
+
+    A token's tap ``d`` back is its flat neighbour if that is in its own
+    row, else its row's state (zeros for a row that starts its sequence):
+    never the neighbour row's token.  The row's new state, the last ``K -
+    1`` of (state, the row's inputs), is written to its slot; a row with
+    no fresh token (an idle slot, padding) and a slot index past the pool
+    write nothing.  ``snap [R]``: also hand back each row's state after
+    that many of its tokens (what a prefix hit resumes from), stacked over
+    the conv layers in the carry's last element.
+
+    The carry it is called with is ``((page carry, kacc, vacc, state pool[,
+    snaps]), conv layer index)``."""
+    from helix_tpu.models.llama import short_conv
+
+    def conv_fn(z, taps, carry_cache):
+        (caches, kacc, vacc, pool, *snaps), lc = carry_cache
+        Bz, Sz, E = z.shape
+        T, K1 = Bz * Sz, taps.shape[-1] - 1
+        zf = z.reshape(T, E)
+        nslots = pool.shape[1]
+        S = pool[lc][jnp.clip(slots, 0, nslots - 1)]        # [R, K-1, E]
+        S = jnp.where((hist > 0)[:, None, None], S, 0).astype(z.dtype)
+        prevs = []
+        for d in range(1, K1 + 1):
+            if Sz == 1:
+                # one-token rows (a decode step): every tap is the state
+                prev = S[:, K1 - d]
+            else:
+                prev = jnp.pad(zf, ((d, 0), (0, 0)))[:T]
+                for j in range(d):
+                    # the row's token j reaches d back past its start
+                    at = jnp.where(qlen > j, t0 + j, T)
+                    prev = prev.at[at].set(S[:, K1 + j - d], mode="drop")
+            prevs.append(prev.reshape(z.shape))
+        y = short_conv(z, taps, prevs)
+        new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
+        dest = jnp.where(qlen > 0, slots, nslots)
+        pool = pool.at[lc, dest].set(new, mode="drop")
+        if snaps:
+            snaps = [snaps[0].at[lc].set(
+                _state_after(zf, S, t0, snap).astype(pool.dtype))]
+        return y, (caches, kacc, vacc, pool, *snaps)
+
+    return conv_fn
 
 
 def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
@@ -784,7 +908,8 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
                     use_adapters: bool = False, mesh=None):
     """One plain decode forward over every slot (each active slot a
     one-token row over its ragged paged history), nothing written:
-    returns ``(logits [B, 1, V], (pool carry, fresh K, fresh V))``."""
+    returns ``(logits [B, 1, V], (pool carry, fresh K, fresh V))``, the
+    pool carry ending in the state pool where the model has one."""
     B = state.last_token.shape[0]
     tokens = state.last_token[:, None]
     pos2d = state.positions[:, None]
@@ -795,14 +920,20 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     kacc0, vacc0 = _fresh_kv_zeros(cfg, B, 1)
 
     def attn_fn(q, k, v, carry_cache, pos):
-        (caches, kacc, vacc), lyr = carry_cache
+        (caches, kacc, vacc, *rest), lyr = carry_cache
         out = _ragged_attn_call(
             q, k, v, caches, lyr, t0, q_len, hist, state.page_tables,
             backend, mesh=mesh,
         )
-        return out, (caches, kacc.at[lyr].set(k), vacc.at[lyr].set(v))
+        return out, (caches, kacc.at[lyr].set(k), vacc.at[lyr].set(v),
+                     *rest)
 
     carry0 = (cache.carry(), kacc0, vacc0)
+    conv_fn = None
+    if cache.state is not None:
+        # the slots' conv states ride the carry beside the pages
+        carry0 += (cache.state,)
+        conv_fn = _conv_rows_fn(t0, q_len, hist, t0)
     if cfg.mrope_sections is not None:
         from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -820,7 +951,7 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
             seq_positions=pos2d,
         )
     else:
-        logits, (pc, kacc, vacc) = forward(
+        logits, (pc, kacc, vacc, *pool) = forward(
             params, cfg, tokens, pos2d,
             attn_fn=attn_fn,
             carry_caches=carry0,
@@ -831,7 +962,10 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
             adapter_ids=(
                 state.adapter_slots[:, None] if use_adapters else None
             ),
+            conv_fn=conv_fn,
         )
+        if pool:
+            pc = pc + (pool[0],)
     return logits, (pc, kacc, vacc)
 
 
@@ -849,7 +983,7 @@ def _tail_decode_step(params, cache, state: DecodeState, *, cfg, backend,
         params, cache, state, cfg=cfg, backend=backend,
         use_adapters=use_adapters, mesh=mesh,
     )
-    cache = PagedKVCache.from_carry(pc)
+    cache = _cache_from(pc, cache)
     pages, offsets = slot_to_page_offset(
         state.positions[:, None], state.page_tables, page_size
     )
@@ -938,6 +1072,9 @@ def _build_ragged_step_fn(
     # never retrace)
     use_adapters = adapter_slots > 0
     cfg = model_cfg
+    # conv layers: every row reads and writes its slot's state, and the
+    # prefill rows hand back the state at one page boundary each
+    has_state = cfg.num_conv_layers > 0
     is_moe = cfg.num_experts > 0
     is_mrope = cfg.mrope_sections is not None
     Cb = token_bucket
@@ -957,6 +1094,7 @@ def _build_ragged_step_fn(
                 draft_len, n_extra, cold=None):
         B = state.last_token.shape[0]
         drops = None
+        snaps = None
         # tiered KV residency (ISSUE 20): staged cold-middle chunks plus
         # the per-row demoted token spans — one slab shared by the
         # prefill segment (rows = plan rows, via c_prow) and the state
@@ -977,6 +1115,8 @@ def _build_ragged_step_fn(
         # ---- 1. prefill segment --------------------------------------
         if Cb > 0:
             with jax.named_scope("prefill"):
+                if has_state:
+                    *pargs, p_slots, p_snap = pargs
                 if use_adapters:
                     (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
                      p_qlen, p_hist, p_tables, p_ends, p_sampling, p_keys,
@@ -987,9 +1127,17 @@ def _build_ragged_step_fn(
                      p_keys) = pargs
                     p_aids = None
                 kacc0, vacc0 = _fresh_kv_zeros(cfg, 1, Cb)
+                p_carry = (cache.carry(), kacc0, vacc0)
+                p_conv = None
+                if has_state:
+                    p_carry += (cache.state, jnp.zeros(
+                        (cfg.num_conv_layers, prefill_rows)
+                        + cfg.conv_state_shape, cache.state.dtype))
+                    p_conv = _conv_rows_fn(
+                        p_t0, p_qlen, p_hist, p_slots, p_snap)
 
                 def p_attn(q, k, v, carry_cache, pos):
-                    (caches, kacc, vacc), lyr = carry_cache
+                    (caches, kacc, vacc, *rest), lyr = carry_cache
                     if use_ring:
                         out = _ring_chunk_attention(
                             q, k, v, caches, lyr, p_pos, p_seg, p_hist,
@@ -1017,25 +1165,30 @@ def _build_ragged_step_fn(
                             mesh=mesh,
                         )
                     return out, (caches, kacc.at[lyr].set(k),
-                                 vacc.at[lyr].set(v))
+                                 vacc.at[lyr].set(v), *rest)
 
                 res = forward(
                     params, cfg, p_tokens, p_pos,
                     attn_fn=p_attn,
-                    carry_caches=(cache.carry(), kacc0, vacc0),
+                    carry_caches=p_carry,
                     moe_token_mask=p_seg > 0,
                     moe_backend=backend,
                     return_moe_stats=is_moe,
                     adapter_ids=p_aids,
+                    conv_fn=p_conv,
                 )
                 if is_moe:
-                    logits_p, (pc, kacc, vacc), moe_stats = res
+                    logits_p, (pc, kacc, vacc, *rest), moe_stats = res
                     drops = moe_stats["vector"]
                 else:
-                    logits_p, (pc, kacc, vacc) = res
+                    logits_p, (pc, kacc, vacc, *rest) = res
+                if has_state:
+                    pool, snaps = rest
+                    cache = PagedKVCache.from_carry(pc, pool)
+                else:
+                    cache = PagedKVCache.from_carry(pc)
                 cache = write_kv(
-                    PagedKVCache.from_carry(pc), kacc, vacc, p_pages,
-                    p_offsets, p_seg > 0,
+                    cache, kacc, vacc, p_pages, p_offsets, p_seg > 0,
                 )
                 last = logits_p[0, p_ends]   # [R, V]: each row's last token
                 with jax.named_scope("sample"):
@@ -1065,15 +1218,23 @@ def _build_ragged_step_fn(
             kacc0s, vacc0s = _fresh_kv_zeros(cfg, B, W)
 
             def s_attn(q, k, v, carry_cache, pos):
-                (caches, kacc, vacc), lyr = carry_cache
+                (caches, kacc, vacc, *rest), lyr = carry_cache
                 out = _ragged_attn_call(
                     q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
                     state.page_tables, backend, cold=s_cold, mesh=mesh,
                 )
                 return out, (caches, kacc.at[lyr].set(k),
-                             vacc.at[lyr].set(v))
+                             vacc.at[lyr].set(v), *rest)
 
             carry0 = (cache.carry(), kacc0s, vacc0s)
+            s_conv = None
+            if has_state:
+                # W is 1 here (speculation is refused beside a state pool):
+                # a live slot is a one-token row over its own state
+                carry0 += (cache.state,)
+                s_conv = _conv_rows_fn(
+                    s_t0, live[:, 0].astype(jnp.int32), s_hist,
+                    jnp.arange(B, dtype=jnp.int32))
             if is_mrope:
                 from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -1101,8 +1262,11 @@ def _build_ragged_step_fn(
                         )
                         if use_adapters else None
                     ),
+                    conv_fn=s_conv,
                 )
-                logits_s, (pc2, kaccs, vaccs) = res[:2]
+                logits_s, (pc2, kaccs, vaccs, *rest) = res[:2]
+                if rest:
+                    pc2 = pc2 + (rest[0],)
                 if is_moe:
                     # the step's routing load is the decode rows' when any
                     # is live (what sets a decode step's bytes), else the
@@ -1115,7 +1279,7 @@ def _build_ragged_step_fn(
                             drops[:2] + sv[:2],
                             jnp.where(sv[1] > 0, sv[2:], drops[2:]),
                         ])
-            cache = PagedKVCache.from_carry(pc2)
+            cache = _cache_from(pc2, cache)
             pages_s, offs_s = slot_to_page_offset(
                 pos_s, state.page_tables, page_size
             )
@@ -1206,6 +1370,9 @@ def _build_ragged_step_fn(
                 )
         else:
             extra = jnp.zeros((0, B), jnp.int32)
+        if has_state:
+            return (cache, new_state, p_first, sampled, emit, extra, drops,
+                    snaps)
         return cache, new_state, p_first, sampled, emit, extra, drops
 
     step_fn.__name__ = step_fn.__qualname__ = ragged_meta.step_program_name(
@@ -1247,8 +1414,7 @@ class Engine:
                 f"unsupported kv_cache_dtype {cfg.kv_cache_dtype!r} "
                 "(expected auto | bfloat16 | float32 | int8)"
             )
-        if model_cfg.is_mla:
-            _refuse_for_latent_attention(model_cfg, cfg, mesh)
+        refuse_unsupported(model_cfg, cfg, mesh)
         # resolved ONCE, here: on a TPU the Pallas kernels, on a CPU the
         # XLA references; nothing downstream re-decides or falls back
         from helix_tpu.ops.attention import head_shards, resolve_backend
@@ -1303,9 +1469,22 @@ class Engine:
         self._chunking: Optional[dict] = None  # in-flight chunked prefill
         from helix_tpu.engine.kv_cache import PrefixCache
 
+        # a model with conv layers: a prefix is pages AND the conv state
+        # at its end, filed by the steps that pass a page boundary
         self.prefix_cache = (
-            PrefixCache() if cfg.enable_prefix_cache else None
+            PrefixCache(stateful=model_cfg.num_conv_layers > 0)
+            if cfg.enable_prefix_cache else None
         )
+        # page boundaries of a prompt in flight whose state a step has
+        # handed back: req id -> {pages: (the step's states, the row)},
+        # filed under the chain digests when the prompt's pages are adopted
+        self._boundary_states: dict[str, dict] = {}
+        self.num_state_snapshots = 0
+        self.num_state_restores = 0
+        # prefix hits cut back to a boundary with a state on file (or to
+        # nothing) for want of one at the pages' end
+        self.prefix_hits_shortened = 0
+        self._kv_filestore = None
         self._shared_pages: dict[str, list] = {}  # req id -> cache pages
         # host-RAM KV tier (ISSUE 6): spilled prefix pages + swapped-out
         # decoders, byte-budgeted; None = tier off (evictions free pages,
@@ -1536,7 +1715,6 @@ class Engine:
         # Wired post-construction (serving.kv_filestore.filestore_for_
         # engine) like on_admit; None = tier off.  filestore_restored_
         # pages counts pages adopted FROM it (cross-restart prefix hits).
-        self.kv_filestore = None
         self.filestore_restored_pages = 0
         # MoE routing assignments dropped to expert-capacity overflow
         # during prefill (those tokens silently rode the residual stream);
@@ -1576,6 +1754,22 @@ class Engine:
     # ------------------------------------------------------------------
     # public API
     # ------------------------------------------------------------------
+
+    @property
+    def kv_filestore(self):
+        return self._kv_filestore
+
+    @kv_filestore.setter
+    def kv_filestore(self, store) -> None:
+        # wired after construction (node_agent): refused there and then
+        if store is not None:
+            _refuse_call(self.model_cfg, "the persistent KV filestore")
+        self._kv_filestore = store
+
+    @property
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of the state pool (0 for a model without one)."""
+        return self.cache_cfg.state_bytes(self.model_cfg)
 
     @property
     def kv_pages_used(self) -> int:
@@ -2279,6 +2473,15 @@ class Engine:
         if use_cache and self.prefix_cache is not None:
             hashes = self._prompt_hashes(req)
             k = self.prefix_cache.match_len(hashes)
+        stateful = (self.prefix_cache is not None
+                    and self.prefix_cache.stateful)
+        shortened = False
+        if stateful:
+            # a hit cut back (maybe to nothing) to a boundary whose conv
+            # state is on file: pages alone never resume a sequence
+            chain = self._prompt_hashes(req)
+            shortened = self.prefix_cache.match_len(chain) < (
+                self.prefix_cache.match_len(chain, pages_only=True))
         # tiered KV residency (ISSUE 20): a sequence longer than hot tail
         # + one stream chunk admits with only its FIRST dispatch's pages;
         # _tiered_prep grows the table lazily each step and demotes pages
@@ -2317,7 +2520,15 @@ class Engine:
         if use_cache and self.prefix_cache is not None:
             if not self._ensure_pages(need_now - k):
                 return None   # blocked retry: no acquire, no stat churn
-            shared = self.prefix_cache.acquire(hashes)
+            shared = self.prefix_cache.acquire(hashes[:k])
+            if stateful:
+                # making room may have evicted the boundary's state
+                n = len(shared)
+                while n and self.prefix_cache.state_at(
+                        hashes[n - 1]) is None:
+                    n -= 1
+                self.prefix_cache.release(shared[n:])
+                shared = shared[:n]
         need_new = need_now - len(shared)
         if not self._ensure_pages(need_new):
             if shared:
@@ -2340,6 +2551,13 @@ class Engine:
                 req, hashes, len(shared) + restored, pages
             )
         req.cached_tokens = (len(shared) + restored) * self.cache_cfg.page_size
+        if shortened:
+            self.prefix_hits_shortened += 1
+        if stateful and shared:
+            # the sequence resumes from the state filed at the boundary its
+            # shared pages end on (a cold row reads zeros: its history is 0)
+            self._restore_state(
+                slot, self.prefix_cache.state_at(hashes[len(shared) - 1]))
         if self._plan_recorder is not None:
             # leader: this admission is final — broadcast the full
             # request identity plus the cached_tokens the prefix /
@@ -2408,6 +2626,34 @@ class Engine:
                 "table": table,
             }
         return table
+
+    def _restore_state(self, slot: int, state) -> None:
+        """Write a filed conv state ``[conv layers, K - 1, E]`` into a
+        slot's row of the state pool, ahead of the step that resumes the
+        sequence (dispatched in order on the device's stream)."""
+        with obs_trace.phase("helix.state.restore", into=self.step_phases):
+            self.cache = dataclasses.replace(
+                self.cache,
+                state=_restore_state_fn(
+                    self.cache.state, jnp.int32(slot), state),
+            )
+            self.num_state_restores += 1
+
+    def _snap_tokens(self, req: Request, start: int, rem: int) -> int:
+        """How many of a prefill row's ``rem`` tokens (from ``start``) lie
+        before the page boundary whose conv state the step hands back: the
+        row's end for a chunk that continues a prompt, else the last
+        boundary a later request with this prompt could share up to (the
+        page of the last prompt token is never shared).  0: none."""
+        if self.prefix_cache is None or not self.prefix_cache.stateful:
+            return 0
+        ps = self.cache_cfg.page_size
+        end, plen = start + rem, len(req.prompt_tokens)
+        if end < plen:
+            bound = end - end % ps
+        else:
+            bound = (plen - 1) // ps * ps
+        return max(bound - start, 0)
 
     def _restore_host_prefix(
         self, req: Request, hashes: list, shared: list, pages: list
@@ -2737,6 +2983,7 @@ class Engine:
                 req, table, start, rem,
                 req.prompt_tokens[start:plen], sub, req.sampling,
                 adapter=int(self._slot_adapters[req.slot]),
+                slot=req.slot, snap=self._snap_tokens(req, start, rem),
             )
             batch.append((req, table))
         if adapter_deferred:
@@ -2851,6 +3098,7 @@ class Engine:
             req, st["table"], start, rem,
             req.prompt_tokens[start:end], sub, req.sampling,
             adapter=int(self._slot_adapters[st["slot"]]),
+            slot=st["slot"], snap=self._snap_tokens(req, start, rem),
         )
         return plan, rem, end
 
@@ -3114,16 +3362,24 @@ class Engine:
             ],
             np.int32,
         )
+        # the mirrors go up as COPIES: on a CPU ``jnp.asarray`` shares a
+        # numpy array's memory, ``_rebuild_state`` hands these through as
+        # they are, and the step that follows is given the state to write
+        # in place: it would advance ``self._positions`` itself, under the
+        # host's own ``+= 1`` (a TPU copies on upload either way)
+        def up(mirror):
+            return jnp.asarray(mirror.copy())
+
         self._dstate = _rebuild_state(
             self._dstate,
-            jnp.asarray(self._last_token),
-            jnp.asarray(self._positions),
-            jnp.asarray(self._page_tables),
+            up(self._last_token),
+            up(self._positions),
+            up(self._page_tables),
             jnp.asarray(active),
-            jnp.asarray(self._mrope_delta),
-            jnp.asarray(self._slot_keys),
+            up(self._mrope_delta),
+            up(self._slot_keys),
             jnp.asarray(keep),
-            jnp.asarray(self._slot_adapters),
+            up(self._slot_adapters),
             sampling,
         )
         self._changed_slots.clear()
@@ -3729,6 +3985,7 @@ class Engine:
         runner — or a parked page that failed verification).  The caller
         owns the request's local teardown; export itself mutates
         nothing."""
+        _refuse_call(self.model_cfg, "export_request")
         req = self._requests.get(req_id)
         if req is None or req.finished:
             return None
@@ -3835,6 +4092,7 @@ class Engine:
         nothing; the caller tears the local request down only after the
         ship is CONFIRMED, so a failed transfer degrades to local
         decode — never a lost request."""
+        _refuse_call(self.model_cfg, "export_prefill")
         req = self._requests.get(req_id)
         if req is None or req.finished or not req.output_tokens:
             return None
@@ -3858,6 +4116,7 @@ class Engine:
         continuation is bit-identical); plain snapshots join the wait
         queue like any fresh request.  Raises ``SnapshotError`` (typed)
         without touching allocator or queue state on any failure."""
+        _refuse_call(self.model_cfg, "import_request")
         if snap.version != SNAPSHOT_VERSION:
             raise SnapshotError(
                 f"snapshot version {snap.version} != engine version "
@@ -4449,7 +4708,8 @@ class Engine:
             # under the async loop this step's metadata uploads overlap
             # the previous step's device execution (double-buffered
             # metadata — jax issues the transfers asynchronously)
-            a = plan.finalize_device(rung)
+            a = plan.finalize_device(
+                rung, with_state=self.cache.state is not None)
             sampling = SamplingState.from_params(
                 [r.sampling for r in plan.rows]
                 + [SamplingParams()] * (plan.max_rows - len(plan.rows))
@@ -4463,6 +4723,10 @@ class Engine:
                 # one more per-row metadata column: each token's
                 # adapter pool slot (0 = identity)
                 pargs = pargs + (a["aids"],)
+            if self.cache.state is not None:
+                # each row's slot in the state pool, and how many of its
+                # tokens lie before the boundary whose state comes back
+                pargs = pargs + (a["slots"], a["snaps"])
             rows = plan.max_rows
             has_hist = plan.has_hist
         else:
@@ -4491,10 +4755,19 @@ class Engine:
                 bound = self._finalize_cold(staged, plan, rows)
                 if bound is not None:
                     cold_arg, cold_chunks, cold_ct = bound
+        n_tail_max = self._n_tail_max
+        if self.model_cfg.loop_bodies > 2 and rung:
+            # only the decode-only program runs the fused tail (every
+            # caller that brings a plan passes n_extra 0); the tail holds
+            # every loop body again, a third of a prefill program's compile
+            # at five bodies (PERF.md section 7, 21: all models, one day)
+            if n_extra:
+                raise ValueError("a prefill step has no fused decode tail")
+            n_tail_max = 0
         fn = _build_ragged_step_fn(
             self.model_cfg, self.cache_cfg.page_size, self._backend,
             self.mesh, rung, has_hist, rows, self._spec_width(),
-            self._n_tail_max, ring_hist, pool_slots,
+            n_tail_max, ring_hist, pool_slots,
             cold_chunks, cold_ct,
         )
         self.num_device_calls += 1
@@ -4511,17 +4784,38 @@ class Engine:
             **({"grouped_backend": self.grouped_backend}
                if self.grouped_backend else {}),
             attn_q_block=self.attn_q_block,
+            **({"conv_layers": self.model_cfg.num_conv_layers,
+                "attn_layers": self.model_cfg.num_attn_layers}
+               if self.model_cfg.num_conv_layers else {}),
         ):
             (self.cache, self._dstate, p_first, sampled, emit, extra,
-             drops) = fn(
+             drops, *snaps) = fn(
                 self._graft_params(), self.cache, self._dstate, pargs,
                 jnp.asarray(drafts), jnp.asarray(draft_len),
                 jnp.int32(n_extra), cold_arg,
             )
+        if snaps and snaps[0] is not None:
+            self._note_boundary_states(plan, snaps[0])
         if drops is not None:
             # fetched when ready, after this step's own fetch
             self._moe_drop_handles.append(drops)
         return p_first, sampled, emit, extra
+
+    def _note_boundary_states(self, plan, snaps) -> None:
+        """Note, for each prefill row that passed a page boundary, where the
+        conv state the step handed back for it lies (``snaps [conv layers,
+        rows, K - 1, E]``, left on the device: nothing is fetched) until
+        the prompt's pages are adopted and it is filed under their digest."""
+        with obs_trace.phase("helix.state.snapshot", into=self.step_phases):
+            ps = self.cache_cfg.page_size
+            for j, row in enumerate(plan.rows):
+                if row.req is None or row.snap <= 0:
+                    continue
+                # (the row's slice is cut when it is filed, once a prompt:
+                # a device operation a row here is 2 ms a step of the host)
+                self._boundary_states.setdefault(row.req.id, {})[
+                    (row.start + row.snap) // ps] = (snaps, j)
+                self.num_state_snapshots += 1
 
     # ------------------------------------------------------------------
     # completion
@@ -4565,6 +4859,14 @@ class Engine:
             int(table[i]) for i in range(k_shared, len(hashes))
         ]
         adopted = self.prefix_cache.adopt(fresh_hashes, fresh_pages)
+        for pages_in, (snaps, row) in self._boundary_states.pop(
+                req.id, {}).items():
+            # the conv state at a boundary the prompt's steps passed: what
+            # lets a later request share the pages up to it
+            if pages_in <= len(hashes) and self.prefix_cache.state_at(
+                    hashes[pages_in - 1]) is None:
+                self.prefix_cache.file_state(
+                    hashes[pages_in - 1], snaps[:, row])
         if adopted:
             self.allocator.detach(req.id, adopted)
             # the request keeps USING them (refcount 1 held on its
@@ -4632,6 +4934,7 @@ class Engine:
         shared = self._shared_pages.pop(req.id, None)
         if shared and self.prefix_cache is not None:
             self.prefix_cache.release(shared)
+        self._boundary_states.pop(req.id, None)
         if self.spec is not None:
             self.spec.forget(req.id)
         self._release_adapter(req)

@@ -24,7 +24,19 @@ reference rides inside its CUDA containers — ``SURVEY.md`` §2.2).  Design:
 
 HBM cost per page = ``2 * L * page_size * KVH * D * itemsize`` — the unit the
 residency manager (``engine/residency.py``) budgets with, replacing the
-reference's GPU VRAM accounting.
+reference's GPU VRAM accounting.  ``L`` counts the layers that HAVE pages
+(``ModelConfig.num_attn_layers``); a head width that divides the 128 lanes
+is stored with ``128 / D`` kv heads side by side in one lane tile (``[KVH /
+pack, pack * D]``: the same bytes, and whole tiles for the kernel's DMAs).
+
+A second kind of state lives beside the pages: a gated short convolution's
+layer keeps, for each sequence, its last ``conv_kernel - 1`` inputs and
+nothing a token.  That is the STATE POOL, ``PagedKVCache.state [conv layers,
+slots, K - 1, E]``, one row a decode slot: created with the cache, donated
+and returned by the step with it, counted in ``CacheConfig.total_bytes`` and
+``fit_hbm``.  A prefix is then pages AND a state: ``PrefixCache`` files the
+state a step returns for a page boundary under that boundary's chain digest
+and matches only up to a boundary that has one.
 """
 
 from __future__ import annotations
@@ -54,10 +66,26 @@ class CacheConfig:
     # rows (Llama-3-8B at page 16: ~1.94x the pages) and to (D + 8) /
     # (2 * D) when it fills half of one (Qwen2-7B: ~1.88x).
     dtype: str = "bfloat16"
+    # rows of the state pool (the engine's decode slots); a model with no
+    # recurrent layers has no such pool whatever this says
+    state_slots: int = 0
 
     @property
     def quantized(self) -> bool:
         return self.dtype == "int8"
+
+    def state_shape(self, model: ModelConfig) -> Optional[tuple]:
+        """The state pool's shape, ``None`` for a model without one."""
+        if not model.num_conv_layers:
+            return None
+        return (model.num_conv_layers, self.state_slots,
+                ) + model.conv_state_shape
+
+    def state_bytes(self, model: ModelConfig) -> int:
+        shp = self.state_shape(model)
+        if shp is None:
+            return 0
+        return int(np.prod(shp)) * jnp.dtype(model.dtype).itemsize
 
     @property
     def max_seq_len(self) -> int:
@@ -75,11 +103,12 @@ class CacheConfig:
         ``gather_pages`` hands out and a snapshot carries): K and V
         ``[L, P, KVH, D]``, or for latent attention the latent ``[L, P,
         R]`` and the lane-padded rope key ``[L, P, 128]``."""
-        L, P = model.num_layers, self.page_size
+        L, P = model.num_attn_layers, self.page_size
         if model.is_mla:
             kw, vw = self.latent_widths(model)
             return (L, P, kw), (L, P, vw)
-        kv = (L, P, model.num_kv_heads, model.head_dim)
+        pack = model.kv_head_pack
+        kv = (L, P, model.num_kv_heads // pack, model.head_dim * pack)
         return kv, kv
 
     def page_bytes(self, model: ModelConfig) -> int:
@@ -90,7 +119,7 @@ class CacheConfig:
             ) * jnp.dtype(self.dtype).itemsize
         per_elem = (
             2
-            * model.num_layers
+            * model.num_attn_layers
             * self.page_size
             * model.num_kv_heads
         )
@@ -99,11 +128,12 @@ class CacheConfig:
             # f32 scale per (token slot, kv head), for K and V pools, in
             # page rows padded to whole 128-lane rows
             row = -(-self.page_size * model.num_kv_heads // 128) * 128
-            total += 2 * model.num_layers * row * 4
+            total += 2 * model.num_attn_layers * row * 4
         return total
 
     def total_bytes(self, model: ModelConfig) -> int:
-        return self.num_pages * self.page_bytes(model)
+        return self.num_pages * self.page_bytes(model) + self.state_bytes(
+            model)
 
     @classmethod
     def fit_hbm(
@@ -113,6 +143,7 @@ class CacheConfig:
         page_size: int = 16,
         max_pages_per_seq: int = 128,
         dtype: str = "bfloat16",
+        state_slots: int = 0,
     ) -> "CacheConfig":
         """Size the page pool to an HBM budget (what's left after weights) —
         the accounting the reference does per-GPU with
@@ -120,15 +151,14 @@ class CacheConfig:
         ``dtype="int8"`` budgets codes + lane-padded scale pools (see
         ``page_bytes``)."""
         probe = cls(num_pages=1, page_size=page_size,
-                    max_pages_per_seq=max_pages_per_seq, dtype=dtype)
+                    max_pages_per_seq=max_pages_per_seq, dtype=dtype,
+                    state_slots=state_slots)
         per_page = probe.page_bytes(model)
-        num_pages = max(hbm_budget_bytes // per_page, 0)
-        return cls(
-            num_pages=int(num_pages),
-            page_size=page_size,
-            max_pages_per_seq=max_pages_per_seq,
-            dtype=dtype,
-        )
+        # the state pool comes out of the budget first: its size follows
+        # the slots, not the pages
+        left = hbm_budget_bytes - probe.state_bytes(model)
+        num_pages = max(left // per_page, 0)
+        return dataclasses.replace(probe, num_pages=int(num_pages))
 
 
 @jax.tree_util.register_dataclass
@@ -148,6 +178,9 @@ class PagedKVCache:
     v_pages: jax.Array  # same shape; latent pools: rope key [L, N, P, 128]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
+    # the state pool [conv layers, slots, K - 1, E] of a model with gated
+    # short convolutions, in the model's dtype; None for every other model
+    state: Optional[jax.Array] = None
 
     @classmethod
     def create(
@@ -158,6 +191,8 @@ class PagedKVCache:
     ) -> "PagedKVCache":
         if model.is_mla:
             return cls._create_latent(model, cache, mesh)
+        if model.num_conv_layers:
+            return cls._create_with_state(model, cache, mesh)
         shape = (
             model.num_layers,
             cache.num_pages,
@@ -165,6 +200,16 @@ class PagedKVCache:
             model.num_kv_heads,
             model.head_dim,
         )
+        if model.kv_head_pack > 1:
+            kshape = cache.page_shapes(model)[0]
+            shape = (kshape[0], cache.num_pages) + kshape[1:]
+            if cache.quantized:
+                raise ValueError(
+                    f"a page pool of head width {model.head_dim} packs "
+                    f"{model.kv_head_pack} kv heads into a lane tile and "
+                    "has no int8 storage (its scales are one a head): set "
+                    "kv_cache_dtype to auto, bfloat16 or float32"
+                )
         sshape = (
             model.num_layers,
             cache.num_pages,
@@ -215,22 +260,39 @@ class PagedKVCache:
         """A latent pool: the two arrays keep their roles in every opaque
         pair path (host pool, snapshots, checksums, filestore), with a
         width each and no head axis."""
+        return cls._create_on_one_device(
+            model, cache, mesh, "a latent (MLA) page pool")
+
+    @classmethod
+    def _create_with_state(cls, model, cache, mesh) -> "PagedKVCache":
+        """Pages for the attention layers and a state pool for the conv
+        layers."""
+        return cls._create_on_one_device(
+            model, cache, mesh, "a page pool beside a state pool",
+            state=jnp.zeros(cache.state_shape(model),
+                            jnp.dtype(model.dtype)))
+
+    @classmethod
+    def _create_on_one_device(cls, model, cache, mesh, what,
+                              state=None) -> "PagedKVCache":
+        """The two pools at ``CacheConfig.page_shapes``, bf16 or f32, on
+        one device: what the pools without an int8 or a sharded form are."""
         if cache.quantized:
             raise ValueError(
-                "a latent (MLA) page pool has no int8 storage: set "
-                "kv_cache_dtype to auto, bfloat16 or float32"
+                f"{what} has no int8 storage: set kv_cache_dtype to auto, "
+                "bfloat16 or float32"
             )
         if mesh is not None and mesh.devices.size > 1:
             raise ValueError(
-                "a latent (MLA) page pool is held by one device: a "
-                f"mesh of {mesh.devices.size} devices is not supported"
+                f"{what} is held by one device: a mesh of "
+                f"{mesh.devices.size} devices is not supported"
             )
         dtype = jnp.dtype(cache.dtype)
         pools = [
             jnp.zeros((shp[0], cache.num_pages) + shp[1:], dtype)
             for shp in cache.page_shapes(model)
         ]
-        return cls(k_pages=pools[0], v_pages=pools[1])
+        return cls(k_pages=pools[0], v_pages=pools[1], state=state)
 
     @property
     def latent(self) -> bool:
@@ -256,12 +318,14 @@ class PagedKVCache:
         return (self.k_pages, self.v_pages, self.k_scale, self.v_scale)
 
     @classmethod
-    def from_carry(cls, carry) -> "PagedKVCache":
+    def from_carry(cls, carry, state=None) -> "PagedKVCache":
+        """The page pools back from a scan's carry, beside ``state`` (the
+        state pool is threaded on its own: it has another layer axis)."""
         if len(carry) == 2:
-            return cls(k_pages=carry[0], v_pages=carry[1])
+            return cls(k_pages=carry[0], v_pages=carry[1], state=state)
         return cls(
             k_pages=carry[0], v_pages=carry[1],
-            k_scale=carry[2], v_scale=carry[3],
+            k_scale=carry[2], v_scale=carry[3], state=state,
         )
 
 
@@ -301,8 +365,10 @@ def write_kv(
 
         k_new, k_sc = quantize_kv(k_new)   # int8 + [L, B, S, KVH] f32
         v_new, v_sc = quantize_kv(v_new)
-    kf = k_new.reshape(L, B * S, KVH, D).astype(cache.k_pages.dtype)
-    vf = v_new.reshape(L, B * S, KVH, D).astype(cache.v_pages.dtype)
+    # (a packed pool's minor pair is [KVH / pack, pack * D]: the same
+    # values in the same order)
+    kf = k_new.reshape(L, B * S, KVHp, Dp).astype(cache.k_pages.dtype)
+    vf = v_new.reshape(L, B * S, KVHp, Dp).astype(cache.v_pages.dtype)
     k_pages = (
         cache.k_pages.reshape(Lp, P * ps, KVHp, Dp)
         .at[:, flat_idx]
@@ -316,7 +382,8 @@ def write_kv(
         .reshape(Lp, P, ps, KVHp, Dp)
     )
     if not cache.quantized:
-        return PagedKVCache(k_pages=k_pages, v_pages=v_pages)
+        return PagedKVCache(k_pages=k_pages, v_pages=v_pages,
+                            state=cache.state)
     # scale pools are [L, N, KVH*ps] page rows, head-major in a page: a
     # token's KVH scales sit ps lanes apart, so the scatter indexes
     # (page, offset) on a [L, N, KVH, ps] view with the head axis a
@@ -501,9 +568,14 @@ class PrefixCache:
     stitched from the longest cached run.
     """
 
-    def __init__(self):
+    def __init__(self, stateful: bool = False):
         self._entries: dict[bytes, list] = {}   # digest -> [page, refs, tick]
         self._by_page: dict[int, bytes] = {}
+        # a model with recurrent state: a prefix is pages AND the state at
+        # its end.  digest -> the state at that page boundary (whatever the
+        # engine files: a device array); evicted with the boundary's page
+        self.stateful = stateful
+        self._states: dict[bytes, object] = {}
         self._tick = 0
         self.hits = 0          # pages served from cache
         self.misses = 0        # full pages prefilled fresh
@@ -527,14 +599,29 @@ class PrefixCache:
             out.append(prev)
         return out
 
-    def match_len(self, hashes: list) -> int:
-        """Longest cached prefix (pages), without acquiring."""
+    def match_len(self, hashes: list, pages_only: bool = False) -> int:
+        """Longest cached prefix (pages), without acquiring.  A stateful
+        cache walks back to the longest boundary whose state is on file
+        (0 if none is): a sequence is never resumed from pages alone.
+        ``pages_only`` gives the match before that walk."""
         n = 0
         for h in hashes:
             if h not in self._entries:
                 break
             n += 1
+        if self.stateful and not pages_only:
+            while n and hashes[n - 1] not in self._states:
+                n -= 1
         return n
+
+    def file_state(self, digest: bytes, state) -> None:
+        """File the recurrent state at the end of the page ``digest``
+        names (first filing wins: the state is a function of the chain)."""
+        if digest in self._entries:
+            self._states.setdefault(digest, state)
+
+    def state_at(self, digest: bytes):
+        return self._states.get(digest)
 
     def acquire(self, hashes: list) -> list:
         """Claim the longest cached prefix; returns its pages (refs++).
@@ -609,6 +696,7 @@ class PrefixCache:
             page = e[0]
             h = self._by_page.pop(page)
             del self._entries[h]
+            self._states.pop(h, None)
             freed.append((h, page))
         self.evicted_pages += len(freed)
         return freed
@@ -618,6 +706,7 @@ class PrefixCache:
         return {
             "entries": len(self._entries),
             "pages": len(self._by_page),
+            **({"states": len(self._states)} if self.stateful else {}),
             "hits": self.hits,
             "misses": self.misses,
             "evicted_pages": self.evicted_pages,
@@ -1021,4 +1110,4 @@ def restore_pages(
         v_sc = pack_scale_pages(stack("v_scale"))
     fn = _build_page_restore_fn(bucket, quantized)
     carry = fn(cache.carry(), jnp.asarray(idx), k_new, v_new, k_sc, v_sc)
-    return PagedKVCache.from_carry(carry)
+    return PagedKVCache.from_carry(carry, cache.state)

@@ -131,6 +131,10 @@ class PrefillRow:
     sampling: object            # SamplingParams
     t0: int = 0                 # assigned at finalize
     adapter: int = 0            # multi-LoRA pool slot (0 = identity)
+    slot: int = -1              # the row's decode slot (its row of a state
+                                # pool); -1: none (warmup)
+    snap: int = 0               # tokens of the row before the page boundary
+                                # whose recurrent state the step hands back
 
 
 class PrefillPlan:
@@ -153,11 +157,13 @@ class PrefillPlan:
         return len(self.rows) < self.max_rows and self.used + rem <= cap
 
     def add(self, req, table, start: int, rem: int, tokens, key,
-            sampling, adapter: int = 0) -> None:
+            sampling, adapter: int = 0, slot: int = -1,
+            snap: int = 0) -> None:
         row = PrefillRow(
             req=req, table=np.asarray(table), start=int(start),
             rem=int(rem), tokens=list(tokens), key=key, sampling=sampling,
-            t0=self.used, adapter=int(adapter),
+            t0=self.used, adapter=int(adapter), slot=int(slot),
+            snap=int(snap),
         )
         self.rows.append(row)
         self.used += row.rem
@@ -190,6 +196,9 @@ class PrefillPlan:
         ends = np.zeros((R,), np.int32)
         tables = np.zeros((R, self.max_pages), np.int32)
         keys = np.zeros((R, 2), np.uint32)
+        # a row with no slot points past any state pool: it writes nothing
+        slots = np.full((R,), np.iinfo(np.int32).max, np.int32)
+        snaps = np.zeros((R,), np.int32)
         for j, row in enumerate(self.rows):
             sl = slice(row.t0, row.t0 + row.rem)
             tokens[0, sl] = row.tokens
@@ -210,6 +219,9 @@ class PrefillPlan:
             ends[j] = row.t0 + row.rem - 1
             tables[j, : len(row.table)] = row.table
             keys[j] = row.key
+            if row.slot >= 0:
+                slots[j] = row.slot
+            snaps[j] = row.snap
         # unused rows park at the segment end (ascending-start contract)
         t0[len(self.rows):] = self.used
         return {
@@ -217,9 +229,10 @@ class PrefillPlan:
             "pages": pages, "offsets": offsets, "aids": aids,
             "t0": t0, "qlen": qlen, "hist": hist, "ends": ends,
             "tables": tables, "keys": keys,
+            "slots": slots, "snaps": snaps,
         }
 
-    def finalize_device(self, rung: int):
+    def finalize_device(self, rung: int, with_state: bool = False):
         """``finalize`` + the host->device upload, in one place.
 
         The engine calls this at DISPATCH time so the conversion (and
@@ -230,4 +243,6 @@ class PrefillPlan:
         here reads device values."""
         import jax.numpy as jnp
 
-        return {k: jnp.asarray(v) for k, v in self.finalize(rung).items()}
+        # ``slots`` and ``snaps`` go up only for a model with a state pool
+        return {k: jnp.asarray(v) for k, v in self.finalize(rung).items()
+                if with_state or k not in ("slots", "snaps")}

@@ -5,12 +5,40 @@ vLLM compose profiles (Llama-3, Phi-3, Qwen-2/3 — see
 ``design/sample-profiles/`` and BASELINE.md configs); family-specific
 behaviour is expressed as data (activation, norm offsets, qk-norm, soft
 caps), not subclasses, so one compiled forward function serves them all.
+
+A model's depth is a sequence of RUNS of one kind of layer
+(``ModelConfig.layer_runs``): a kind is a token mixer (attention, or a
+gated short convolution with a fixed per-sequence state) times an FFN
+(dense, or routed experts).  A dense decoder is one run; DeepSeek-V2 is
+two (the leading dense layers, then the expert layers); LFM2 interleaves
+three kinds in thirteen, of which the runs that repeat back to back (the
+period "attention, three convolutions" four times, "attention, two
+convolutions" twice) are executed as one group each: five loop bodies a
+forward pass, not thirteen.  Three routers (``models/moe.py::route``):
+top-k then softmax (Mixtral), softmax then top-k (DeepSeek), and sigmoid
+scores selected on score + a learned bias and weighted without it (LFM2).
 """
 
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerRun:
+    key: str        # the run's stack in the parameter tree
+    mixer: str      # "attn" | "conv"
+    moe: bool       # routed experts (else a dense FFN)
+    count: int      # layers a repetition
+    first: int      # its first layer among its mixer's layers, repetition 0
+    step: int       # layers of its mixer in one repetition of its group
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    reps: int       # times the runs are executed, one after the other
+    runs: tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +84,18 @@ class ModelConfig:
     # norm_topk_prob false), times routed_scaling_factor.
     moe_renormalize: bool = True
     routed_scaling_factor: float = 1.0
+    # "softmax" (above) or "sigmoid": per-expert sigmoid scores, the top-k
+    # chosen on score + ``expert_bias`` (a learned [experts] vector, when
+    # ``moe_expert_bias``), weighted by the unbiased scores, renormalised
+    # when ``moe_renormalize`` (LFM2)
+    moe_scoring: str = "softmax"
+    moe_expert_bias: bool = False
+    # --- interleaved token mixers (LFM2); None = attention at every layer ---
+    # one of "attn" | "conv" a layer.  A "conv" layer is a gated short
+    # convolution of ``conv_kernel`` taps whose whole state is the last
+    # ``conv_kernel - 1`` inputs of the sequence: it has no pages
+    layer_types: Optional[tuple] = None
+    conv_kernel: int = 0
     # --- multi-head latent attention (MLA); 0 = plain multi-head ---
     kv_lora_rank: int = 0               # compressed KV width, cached
     qk_nope_head_dim: int = 0
@@ -67,6 +107,100 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def mixers(self) -> tuple:
+        """The token mixer of every layer, ``"attn"`` or ``"conv"``."""
+        return self.layer_types or ("attn",) * self.num_layers
+
+    @property
+    def num_attn_layers(self) -> int:
+        """Layers with pages: what the page pool's layer axis counts."""
+        return self.mixers.count("attn")
+
+    @property
+    def num_conv_layers(self) -> int:
+        """Layers with a fixed per-sequence state: what the state pool's
+        layer axis counts."""
+        return self.mixers.count("conv")
+
+    @property
+    def conv_state_shape(self) -> tuple:
+        """One sequence's state in one conv layer: its last
+        ``conv_kernel - 1`` gated inputs, oldest first."""
+        return (self.conv_kernel - 1, self.hidden_size)
+
+    @property
+    def kv_head_pack(self) -> int:
+        """KV heads that share one 128-lane tile of the page pool: a head
+        width that divides 128 is stored ``[kv_heads / pack, pack * width]``
+        (the same bytes, whole lane tiles), wider heads as they are."""
+        d = self.head_dim
+        pack = 128 // d if d < 128 and 128 % d == 0 else 1
+        return pack if self.num_kv_heads % pack == 0 else 1
+
+    def layer_runs(self) -> tuple:
+        """The depth as GROUPS of runs of one kind of layer, in order: a
+        group is ``LayerGroup(reps, runs)``, its runs ``LayerRun(key,
+        mixer, moe, count, first, step)`` executed one after the other
+        ``reps`` times.  A plain run is a group of one run done once; where
+        a sequence of runs repeats back to back (a period of the layer
+        pattern) it is ONE group, so a forward pass holds one loop body a
+        run of the period, not one an occurrence.
+
+        ``key`` is the run's stack in the parameter tree: ``count * reps``
+        layers, the layers of repetition ``r`` at ``[r * count, (r + 1) *
+        count)``.  ``first`` is the index of its first layer AMONG THE
+        LAYERS OF ITS MIXER (the page pool's layer axis for an attention
+        run, the state pool's for a conv run) at repetition 0, ``step`` how
+        many layers of that mixer one repetition holds.  Models without
+        ``layer_types`` keep the two names they always had."""
+        ffn_moe = [self.num_experts > 0 and i >= self.first_k_dense
+                   for i in range(self.num_layers)]
+        kinds = list(zip(self.mixers, ffn_moe))
+        flat, i = [], 0                       # (mixer, moe, count)
+        while i < len(kinds):
+            j = i
+            while j < len(kinds) and kinds[j] == kinds[i]:
+                j += 1
+            flat.append(kinds[i] + (j - i,))
+            i = j
+
+        def key(at, moe):
+            if self.layer_types is not None:
+                return f"run{at:02d}"
+            return "layers" if (moe or not self.num_experts) else (
+                "dense_layers")
+
+        groups, seen, i = [], {"attn": 0, "conv": 0}, 0
+        while i < len(flat):
+            # the period starting here that repeats over the most runs
+            p, reps = 1, 1
+            for q in range(1, (len(flat) - i) // 2 + 1):
+                k = 1
+                while flat[i + k * q:i + (k + 1) * q] == flat[i:i + q]:
+                    k += 1
+                if k > 1 and k * q > p * reps:
+                    p, reps = q, k
+            period = flat[i:i + p]
+            step = {m: sum(c for mx, _, c in period if mx == m)
+                    for m in seen}
+            runs, at = [], dict(seen)
+            for j, (mixer, moe, count) in enumerate(period):
+                runs.append(LayerRun(key(i + j, moe), mixer, moe, count,
+                                     at[mixer], step[mixer]))
+                at[mixer] += count
+            groups.append(LayerGroup(reps, tuple(runs)))
+            for m in seen:
+                seen[m] += reps * step[m]
+            i += p * reps
+        return tuple(groups)
+
+    @property
+    def loop_bodies(self) -> int:
+        """Loop bodies a forward pass traces and compiles: one a run of a
+        group (1 for a dense stack, 2 for DeepSeek-V2-Lite, 5 for LFM2)."""
+        return sum(len(g.runs) for g in self.layer_runs())
 
     @property
     def expert_width(self) -> int:
@@ -132,6 +266,28 @@ class ModelConfig:
                 qk_rope_head_dim=hf["qk_rope_head_dim"],
                 v_head_dim=hf["v_head_dim"],
             )
+        if model_type == "lfm2_moe":
+            if hf.get("conv_bias"):
+                raise ValueError(
+                    "lfm2_moe with conv_bias true is not supported: the "
+                    "gated short convolution here has no bias term"
+                )
+            types = tuple(
+                {"conv": "conv", "full_attention": "attn"}[t]
+                for t in hf["layer_types"])
+            family = dict(
+                num_experts=hf["num_experts"],
+                moe_intermediate_size=hf["moe_intermediate_size"],
+                first_k_dense=hf.get("num_dense_layers", 0),
+                moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                moe_scoring="sigmoid",
+                moe_expert_bias=bool(hf.get("use_expert_bias", False)),
+                expert_capacity_factor=0.0,
+                layer_types=types,
+                conv_kernel=hf["conv_L_cache"],
+            )
         return cls(
             **family,
             mrope_sections=mrope,
@@ -147,13 +303,15 @@ class ModelConfig:
             intermediate_size=hf["intermediate_size"],
             rope_theta=hf.get("rope_theta", 10000.0),
             rope_scaling=rope_scaling,
-            rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
-            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            rms_norm_eps=hf.get("rms_norm_eps", hf.get("norm_eps", 1e-5)),
+            tie_word_embeddings=hf.get(
+                "tie_word_embeddings",
+                hf.get("tie_embedding", model_type == "lfm2_moe")),
             hidden_act=hf.get("hidden_act", "silu"),
             attention_bias=hf.get("attention_bias", False)
             or model_type == "qwen2",
             mlp_bias=hf.get("mlp_bias", False),
-            qk_norm=model_type == "qwen3",
+            qk_norm=model_type in ("qwen3", "lfm2_moe"),
             max_position_embeddings=hf.get("max_position_embeddings", 8192),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
@@ -273,8 +431,42 @@ DEEPSEEK_V2_LITE = ModelConfig(
     name="deepseek-ai/DeepSeek-V2-Lite",
 )
 
+# LFM2-8B-A1B (https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/
+# config.json): 18 gated short convolutions and 6 GQA layers of head width
+# 64 with q/k norms, two dense FFNs then 32 routed experts top-4 behind a
+# sigmoid router with a learned bias, tied embedding.  Its conv state lives
+# in a per-slot pool beside the pages; what moves a sequence's pages
+# without that state is refused at engine start (engine.py's table).
+LFM2_8B_A1B = ModelConfig(
+    vocab_size=65536,
+    hidden_size=2048,
+    num_layers=24,
+    num_heads=32,
+    num_kv_heads=8,
+    head_dim=64,
+    intermediate_size=7168,
+    rope_theta=1000000.0,
+    rms_norm_eps=1e-5,
+    tie_word_embeddings=True,
+    qk_norm=True,
+    max_position_embeddings=128000,
+    num_experts=32,
+    num_experts_per_tok=4,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=1792,
+    first_k_dense=2,
+    moe_renormalize=True,
+    routed_scaling_factor=1.0,
+    moe_scoring="sigmoid",
+    moe_expert_bias=True,
+    layer_types=tuple(
+        "attn" if c == "A" else "conv" for c in "ccAcccAcccAcccAcccAccAcc"),
+    conv_kernel=3,
+    name="LiquidAI/LFM2-8B-A1B",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
-              DEEPSEEK_V2_LITE)
+              DEEPSEEK_V2_LITE, LFM2_8B_A1B)
 }

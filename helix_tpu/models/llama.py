@@ -5,9 +5,17 @@ Covers Llama-3, Phi-3 (MHA, fused-free), Qwen-2 (attention bias), Qwen-3
 serves through vLLM containers (``design/sample-profiles/``), here as owned
 TPU-first code:
 
-- Layers are **stacked** (every weight has a leading ``num_layers`` dim) and
-  the forward pass is a single ``lax.scan`` — one layer gets traced/compiled
-  once regardless of depth, keeping XLA compile times flat.
+- Layers are **stacked** (every weight has a leading layer dim) in RUNS of
+  one kind (``ModelConfig.layer_runs``: a token mixer, attention or gated
+  short convolution, times an FFN, dense or routed experts), one stack in
+  the parameter tree and one ``lax.scan`` a run: a dense decoder is one
+  run, DeepSeek-V2 two (``dense_layers`` then ``layers``), LFM2 thirteen,
+  of which those that repeat back to back are one GROUP (a stack a run of
+  the period, one loop over the repetitions): ``run00``, four times
+  (``run01``, ``run02``), twice (``run09``, ``run10``).
+- A conv layer's look-back (``conv_fn``) is injected as attention is: the
+  engine reads a row's taps from its flat neighbours or its slot's state.
+- Three routers, by ``ModelConfig`` (``models/moe.py::route``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
   attention over the engine's KV cache) without re-tracing model code.
@@ -57,6 +65,19 @@ def _seeded_int8(key, shape, dtype, std, embed, shardings):
     in float: a stacked ``[L, ...]`` tensor is drawn and quantized one
     layer at a time inside one jit (Qwen2-7B's bf16 ``w_gate`` alone is
     3.8 GB, its f32 draw 7.6 GB, on a 16 GB chip)."""
+    # out_shardings is keyed like the output dict ({weight, scale});
+    # lax.map stacks each layer's [1, out] scale row into [L, 1, out],
+    # the shape quantize_tensor gives the stacked tensor
+    build = _seeded_int8_builder(tuple(shape), jnp.dtype(dtype), std, embed)
+    if shardings is None:
+        return build(key)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded_int8_builder(shape, dtype, std, embed):
+    """One compiled program a (shape, draw): a model of many runs of layers
+    draws tensors of the same few shapes again and again."""
     from helix_tpu.ops.quant import quantize_embedding, quantize_tensor
 
     quantize = quantize_embedding if embed else quantize_tensor
@@ -72,10 +93,7 @@ def _seeded_int8(key, shape, dtype, std, embed, shardings):
             lambda kk: draw(kk, shape[1:]), jax.random.split(k, shape[0])
         )
 
-    # out_shardings is keyed like the output dict ({weight, scale});
-    # lax.map stacks each layer's [1, out] scale row into [L, 1, out],
-    # the shape quantize_tensor gives the stacked tensor
-    return jax.jit(build, out_shardings=shardings)(key)
+    return jax.jit(build)
 
 
 def init_params(
@@ -131,7 +149,7 @@ def init_params(
         ("layers", "experts", "w_down"): kx[3],
     }
 
-    def stack(n, moe, *at):
+    def stack(n, moe, *at, mixer="attn"):
         """One stack of ``n`` layers of one kind, at ``at`` in the tree."""
 
         def w(shape, *name, std=0.02):
@@ -147,7 +165,18 @@ def init_params(
             "attn_norm": {"weight": jnp.ones((n, E), dtype)},
             "mlp_norm": {"weight": jnp.ones((n, E), dtype)},
         }
-        if cfg.is_mla:
+        if mixer == "conv":
+            # a three-tap depthwise filter at std 0.02 passes a signal
+            # thirty times smaller than the residual stream: the taps are
+            # drawn at 0.5, so a conv layer's branch is of the size of an
+            # attention layer's and a conv layer left out is seen
+            lp["in_proj"] = w((E, 3 * E), "in_proj")
+            lp["conv"] = {"taps": (jax.random.normal(
+                jax.random.fold_in(key, 2000 + zlib.crc32(
+                    "/".join(at).encode()) % 1000),
+                (n, E, cfg.conv_kernel), jnp.float32) * 0.5).astype(dtype)}
+            lp["out_proj"] = w((E, E), "out_proj")
+        elif cfg.is_mla:
             R, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
             lp["wq"] = w((E, H * (dn + dr)), "wq")
@@ -165,6 +194,14 @@ def init_params(
             # (models/moe.py); Mixtral's experts are as wide as the FFN
             X, Fx = cfg.num_experts, cfg.expert_width
             lp["router"] = w((E, X), "router")
+            if cfg.moe_expert_bias:
+                # nonzero: at 0.03 it changes the top-4 of about half the
+                # tokens and leaves every expert in play (at 0.1 it kept 3
+                # of 32 out and the busiest took 4-6x the mean: PERF.md 6)
+                lp["expert_bias"] = {"bias": jax.random.normal(
+                    jax.random.fold_in(key, 3000 + zlib.crc32(
+                        "/".join(at).encode()) % 1000),
+                    (n, X), jnp.float32) * 0.03}
             lp["experts"] = {
                 "w_gate": w((X, E, Fx), "experts", "w_gate"),
                 "w_up": w((X, E, Fx), "experts", "w_up"),
@@ -181,16 +218,15 @@ def init_params(
             lp["w_gate"] = w((E, F), "w_gate")
             lp["w_up"] = w((E, F), "w_up")
             lp["w_down"] = w((F, E), "w_down")
-        if cfg.attention_bias:
+        if cfg.attention_bias and mixer == "attn":
             for nm, width in (("wq", H * D), ("wk", KVH * D),
                               ("wv", KVH * D)):
                 lp[nm]["bias"] = jnp.zeros((n, width), dtype)
-        if cfg.qk_norm:
+        if cfg.qk_norm and mixer == "attn":
             lp["q_norm"] = {"weight": jnp.ones((n, D), dtype)}
             lp["k_norm"] = {"weight": jnp.ones((n, D), dtype)}
         return lp
 
-    n_dense = cfg.first_k_dense if cfg.num_experts > 0 else 0
     # A dropless expert model's embedding rows are drawn at unit RMS.  At
     # 0.02 a token's own vector is lost under what the first random layer
     # adds to the residual stream, every token's hidden state is one
@@ -198,20 +234,25 @@ def init_params(
     # chip: 47-49 of 64 experts touched, the busiest at 8.5x the mean, the
     # count and with it the step's time following the seed).  No trained
     # router does that.  At 1.0 the decode rows of a step touch every
-    # expert of every layer (PERF.md section 6, PR 28).  The head is
-    # untied, so the logits keep their scale.
+    # expert of every layer (PERF.md section 6, PR 28).  An untied head
+    # keeps the logits' scale; under a tied one (interleaved mixers) the
+    # final norm's gain is E ** -0.5: at 1 the logits' std is sqrt(E) and
+    # sampling at temperature 1 is the argmax, one token for ever
+    tied = cfg.tie_word_embeddings
     dropless_moe = (cfg.num_experts > 0 and cfg.expert_capacity_factor <= 0
-                    and not cfg.tie_word_embeddings)
+                    and (not tied or cfg.layer_types is not None))
     params = {
         "embed": weight(ks[0], (V, E), "embed",
                         std=1.0 if dropless_moe else 0.02),
-        "layers": stack(L - n_dense, cfg.num_experts > 0, "layers"),
-        "final_norm": {"weight": jnp.ones((E,), dtype)},
+        "final_norm": {"weight": jnp.full(
+            (E,), E ** -0.5 if dropless_moe and tied else 1.0, dtype)},
     }
-    if n_dense:
-        # the leading dense layers are a stack of their own: two kinds of
-        # layer cannot share one scan over stacked weights
-        params["dense_layers"] = stack(n_dense, False, "dense_layers")
+    # a run of one kind is a stack of its own: two kinds of layer cannot
+    # share one scan over stacked weights
+    for group in cfg.layer_runs():
+        for run in group.runs:
+            params[run.key] = stack(run.count * group.reps, run.moe,
+                                    run.key, mixer=run.mixer)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = weight(
             jax.random.fold_in(key, 99), (E, V), "lm_head")
@@ -230,14 +271,21 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
     and shards layer blocks across pipeline groups on ``mesh: {pp: N}``
     — a new stacked weight must use "layers" too or it silently
     replicates across the pipeline."""
-    def stack(moe):
+    def stack(moe, mixer="attn"):
         lax_ = {
             "attn_norm": {"weight": ("layers", None)},
             "mlp_norm": {"weight": ("layers", None)},
-            "wq": {"weight": ("layers", "embed", "heads")},
-            "wo": {"weight": ("layers", "heads", "embed")},
         }
-        if cfg.is_mla:
+        if mixer == "attn":
+            lax_["wq"] = {"weight": ("layers", "embed", "heads")}
+            lax_["wo"] = {"weight": ("layers", "heads", "embed")}
+        if mixer == "conv":
+            # the gated convolution is depthwise over the hidden axis: its
+            # projections are replicated (a mesh is refused for it)
+            lax_["in_proj"] = {"weight": ("layers", "embed", None)}
+            lax_["conv"] = {"taps": ("layers", None, None)}
+            lax_["out_proj"] = {"weight": ("layers", None, "embed")}
+        elif cfg.is_mla:
             # the latent projection is shared by every head: replicated
             lax_["wkv_a"] = {"weight": ("layers", "embed", None)}
             lax_["kv_norm"] = {"weight": ("layers", None)}
@@ -252,6 +300,8 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
         }
         if moe:
             lax_["router"] = {"weight": ("layers", "embed", None)}
+            if cfg.moe_expert_bias:
+                lax_["expert_bias"] = {"bias": ("layers", None)}
             lax_["experts"] = {
                 "w_gate": {"weight": ("layers", "expert", "embed", "mlp")},
                 "w_up": {"weight": ("layers", "expert", "embed", "mlp")},
@@ -261,22 +311,22 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
                 lax_["shared"] = mlp
         else:
             lax_.update(mlp)
-        if cfg.attention_bias:
+        if cfg.attention_bias and mixer == "attn":
             lax_["wq"]["bias"] = ("layers", "heads")
             lax_["wk"]["bias"] = ("layers", "kv_heads")
             lax_["wv"]["bias"] = ("layers", "kv_heads")
-        if cfg.qk_norm:
+        if cfg.qk_norm and mixer == "attn":
             lax_["q_norm"] = {"weight": ("layers", None)}
             lax_["k_norm"] = {"weight": ("layers", None)}
         return lax_
 
     axes = {
         "embed": {"weight": ("vocab", "embed")},
-        "layers": stack(cfg.num_experts > 0),
         "final_norm": {"weight": (None,)},
     }
-    if cfg.num_experts > 0 and cfg.first_k_dense:
-        axes["dense_layers"] = stack(False)
+    for group in cfg.layer_runs():
+        for run in group.runs:
+            axes[run.key] = stack(run.moe, run.mixer)
     if not cfg.tie_word_embeddings:
         axes["lm_head"] = {"weight": ("embed", "vocab")}
     return axes
@@ -368,6 +418,48 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
     return h, (c, k_pe), new_cache
 
 
+def short_conv(z, taps, prevs):
+    """``y_t = sum_i taps[:, i] * z_{t-(K-1)+i}`` in f32: ``z [..., E]`` is
+    the token's own gated input and ``prevs[d-1]`` the one ``d`` tokens
+    back IN ITS SEQUENCE (zeros before the sequence's start)."""
+    K = taps.shape[-1]
+    w = taps.astype(jnp.float32)
+    y = z.astype(jnp.float32) * w[:, K - 1]
+    for d in range(1, K):
+        y = y + prevs[d - 1].astype(jnp.float32) * w[:, K - 1 - d]
+    return y
+
+
+def whole_sequence_conv_fn(z, taps, layer_cache):
+    """The conv look-back of a forward pass with no cache: every row of
+    ``z [B, S, E]`` is one sequence from its start, so tap ``d`` is the
+    row shifted by ``d`` with zeros before it."""
+    S = z.shape[1]
+    prevs = [jnp.pad(z, ((0, 0), (d, 0), (0, 0)))[:, :S]
+             for d in range(1, taps.shape[-1])]
+    return short_conv(z, taps, prevs), None
+
+
+def _conv_mixer(h, p, layer_cache, cfg, conv_fn):
+    """Gated short convolution (LFM2): ``(B, C, x) = in_proj(u)``,
+    ``y = conv(B * x)`` depthwise and causal over the sequence, ``out_proj(C
+    * y)``.  ``conv_fn(z, taps, layer_cache) -> (y, new_cache)`` owns the
+    look-back: which earlier tokens are a token's own sequence, and the
+    state a sequence carries between calls."""
+    with jax.named_scope("conv.in_proj"):
+        x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                     cfg.norm_offset)
+        b, c, x = jnp.split(_dense(x, p["in_proj"]).astype(h.dtype), 3,
+                            axis=-1)
+        z = b * x
+    with jax.named_scope("conv.mix"):
+        y, new_cache = conv_fn(z, p["conv"]["taps"], layer_cache)
+        y = (c.astype(jnp.float32) * y).astype(h.dtype)
+    with jax.named_scope("conv.out_proj"):
+        h = h + _dense(y, p["out_proj"]).astype(h.dtype)
+    return h, new_cache
+
+
 def _layer(
     h,
     layer_params: Params,
@@ -380,6 +472,7 @@ def _layer(
     adapter_ids=None,
     stacked_experts=None,
     moe_backend=None,
+    conv_fn=None,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -399,7 +492,12 @@ def _layer(
     # --- attention ---
     # the named scopes are what a profiler trace calls these operations,
     # whatever number the compiler gives their fusions
-    if cfg.is_mla:
+    if "in_proj" in p:
+        # the layer's mixer, like its FFN, is what its weights are
+        k = v = None
+        h, new_cache = _conv_mixer(
+            h, p, layer_cache, cfg, conv_fn or whole_sequence_conv_fn)
+    elif cfg.is_mla:
         h, (k, v), new_cache = _mla_attention(
             h, p, layer_cache, cfg, positions, inv_freq, attn_fn)
     else:
@@ -447,6 +545,8 @@ def _layer(
             return_stats=True,
             stacked_experts=stacked_experts,
             backend=moe_backend,
+            expert_bias=(p["expert_bias"]["bias"]
+                         if "expert_bias" in p else None),
         )
         if "shared" in p:
             with jax.named_scope("moe.shared"):
@@ -518,15 +618,10 @@ def scan_decoder_blocks(
 
 
 def layer_stacks(params: Params, cfg: ModelConfig) -> list:
-    """``[(stacked layer weights, layer count)]`` in layer order: the
-    leading dense layers, if the model has them, then the rest."""
-    stacks = []
-    n_dense = 0
-    if "dense_layers" in params:
-        n_dense = cfg.first_k_dense
-        stacks.append((params["dense_layers"], n_dense))
-    stacks.append((params["layers"], cfg.num_layers - n_dense))
-    return stacks
+    """``[(reps, [(stacked layer weights, run)])]`` in layer order: one
+    entry a group of runs (``ModelConfig.layer_runs``), one stack a run."""
+    return [(group.reps, [(params[run.key], run) for run in group.runs])
+            for group in cfg.layer_runs()]
 
 
 def forward(
@@ -548,6 +643,8 @@ def forward(
                           # (0 = identity); None = no batched adapters
     moe_backend=None,     # the dropless experts' grouped product, as the
                           # attention dispatchers take it (models/moe.py)
+    conv_fn=None,         # a conv layer's look-back (``_conv_mixer``);
+                          # None: every row is a whole sequence
 ):
     """Run the decoder.
 
@@ -572,39 +669,90 @@ def forward(
     )
     h = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
 
-    kvs, stats, first = [], [], 0
-    for stack, n in layer_stacks(params, cfg):
+    def run_layers(h, carry, stack, run, rep):
+        """The ``run.count`` layers of one run at repetition ``rep`` of its
+        group (0 for a plain run; a traced index inside a group's loop):
+        ``stack`` holds them alone.  Returns ``(h, kv or carry, stats)``."""
         whole = None
-        if "experts" in stack and cfg.expert_capacity_factor <= 0:
+        if "experts" in params[run.key] and cfg.expert_capacity_factor <= 0:
             # the grouped product is a Pallas kernel (ops/grouped_matmul.py):
             # it reads its weights from a whole buffer, and a scan's
             # per-layer slice of the stacked experts would be copied out
             # for it (184 MB a projection a layer).  So the experts stay
             # out of the scan's slices and the kernel's block index picks
-            # the layer (a scalar-prefetch operand; models/moe.py).
-            whole = stack["experts"]
+            # the layer (a scalar-prefetch operand; models/moe.py), out of
+            # ALL the run's layers, every repetition's
+            whole = params[run.key]["experts"]
             stack = {k: v for k, v in stack.items() if k != "experts"}
 
-        def block(h, layer_params, layer_cache, i, whole=whole):
+        def block(h, layer_params, layer_cache, i):
             return _layer(
                 h, layer_params, layer_cache, cfg, positions, inv_freq,
                 attn_fn, moe_token_mask=moe_token_mask,
                 adapter_ids=adapter_ids,
-                stacked_experts=None if whole is None else (whole, i),
-                moe_backend=moe_backend,
+                stacked_experts=None if whole is None else (
+                    whole, rep * run.count + i),
+                moe_backend=moe_backend, conv_fn=conv_fn,
             )
 
-        h, kv, st = scan_decoder_blocks(
-            h, stack, n, block,
-            None if layer_caches is None else jax.tree.map(
-                lambda c: c[first:first + n], layer_caches),
-            carry_caches, first_layer=first, with_index=True,
+        # the cache's layer index counts the layers of the run's mixer:
+        # an attention run's is the page pool's, a conv run's the state
+        # pool's
+        first = run.first + rep * run.step
+        return scan_decoder_blocks(
+            h, stack, run.count, block,
+            None if layer_caches is None or run.mixer != "attn"
+            else jax.tree.map(
+                lambda c: c[run.first:run.first + run.count], layer_caches),
+            carry, first_layer=first, with_index=True,
         )
+
+    kvs, stats = [], []
+    for reps, runs in layer_stacks(params, cfg):
+        if reps == 1:
+            for stack, run in runs:
+                h, kv, st = run_layers(h, carry_caches, stack, run, 0)
+                if carry_caches is not None:
+                    carry_caches = kv
+                if carry_caches is not None or run.mixer == "attn":
+                    kvs.append(kv)
+                stats.append(st)
+            continue
+        if layer_caches is not None:
+            raise NotImplementedError(
+                "per-layer cache views (layer_caches) over a repeated "
+                "group of runs: use carry_caches")
+        # a period of the layer pattern, ``reps`` times: ONE loop whose body
+        # holds one inner loop a run, over the run's stack viewed
+        # [reps, count, ...] (the experts stay whole, see above)
+        xs = [jax.tree.map(
+                  lambda a, n=run.count: a.reshape((reps, n) + a.shape[1:]),
+                  {k: v for k, v in stack.items() if k != "experts"})
+              for stack, run in runs]
+
+        def period(carry, x):
+            h, caches = carry
+            rep, stacks = x
+            outs = []
+            for part, (_, run) in zip(stacks, runs):
+                h, kv, st = run_layers(h, caches, part, run, rep)
+                if caches is not None:
+                    caches = kv
+                outs.append((None if caches is not None else kv, st))
+            return (h, caches), outs
+
+        (h, carry_caches), outs = jax.lax.scan(
+            period, (h, carry_caches),
+            (jnp.arange(reps, dtype=jnp.int32), xs))
         if carry_caches is not None:
-            carry_caches = kv
-        kvs.append(kv)
-        stats.append(st)
-        first += n
+            kvs.append(carry_caches)
+        # layer order within the group: repetition-major
+        merged = [jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), o) for o in outs]
+        for (kv, st), (_, run) in zip(merged, runs):
+            if carry_caches is None and run.mixer == "attn":
+                kvs.append(kv)
+            stats.append(st)
     if carry_caches is not None or len(kvs) == 1:
         kv = kvs[-1]
     else:

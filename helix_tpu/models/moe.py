@@ -1,9 +1,12 @@
-"""Mixture-of-experts FFN: one router, two dispatches.
+"""Mixture-of-experts FFN: one router in three forms, two dispatches.
 
 - **Router** (``route``): per-token logits over the experts in fp32.
   Mixtral: top-k of the logits, softmax over the k (``moe_renormalize``).
   DeepSeek-V2: softmax over ALL experts, the top-k probabilities kept as
-  they are, times ``routed_scaling_factor``.
+  they are, times ``routed_scaling_factor``.  LFM2 (``moe_scoring``
+  "sigmoid"): a sigmoid score an expert, the top-k chosen on score + a
+  learned bias, weighted by the scores WITHOUT the bias, renormalised
+  over the k with the published ``1e-6`` in the divisor.
 - **Dropless grouped dispatch** (``expert_capacity_factor == 0``, what
   DeepSeek configs get): the ``T * k`` (token, choice) assignments are
   sorted by expert and each projection is ONE grouped matrix product over
@@ -61,12 +64,22 @@ def _expert_dense(h_in, wp, spec):
     return out
 
 
-def route(xf, router_p, cfg):
-    """Router: ``xf [T, E]`` -> ``(weights [T, k] f32, experts [T, k])``."""
+def route(xf, router_p, cfg, expert_bias=None):
+    """Router: ``xf [T, E]`` -> ``(weights [T, k] f32, experts [T, k])``.
+    ``expert_bias [X]``: the sigmoid router's learned selection bias."""
     k = cfg.num_experts_per_tok
     logits = jnp.dot(
         xf.astype(jnp.float32), router_p.astype(jnp.float32)
     )                                               # [T, X]
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen = scores if expert_bias is None else (
+            scores + expert_bias.astype(jnp.float32))
+        _, top_idx = jax.lax.top_k(chosen, k)
+        top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
+        if cfg.moe_renormalize:
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-6)
+        return top_w * cfg.routed_scaling_factor, top_idx
     if cfg.moe_renormalize:
         top_vals, top_idx = jax.lax.top_k(logits, k)
         return jax.nn.softmax(top_vals, axis=-1), top_idx
@@ -201,7 +214,7 @@ def experts_xla(xs, group_sizes, e_row, experts_p, layer, act):
 
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             return_dropped=False, return_stats=False, stacked_experts=None,
-            backend=None, interpret=False):
+            backend=None, interpret=False, expert_bias=None):
     """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
     ``return_dropped`` also the int32 count of (token, choice) assignments
     this call dropped to capacity overflow (always 0 on the dropless
@@ -231,7 +244,7 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
         else token_mask.reshape(T)
     )
     with jax.named_scope("moe.router"):
-        top_w, top_idx = route(xf, router_p, cfg)
+        top_w, top_idx = route(xf, router_p, cfg, expert_bias)
     fill = 0.0
     if cfg.expert_capacity_factor > 0:
         out, dropped = _capacity_experts(

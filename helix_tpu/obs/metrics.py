@@ -513,6 +513,23 @@ class EngineLoopObs:
                 buckets=FAST_BUCKETS,
             ),
         }
+        # the same for a model with recurrent (conv) state: nested in the
+        # phases above (a nested phase's seconds are taken out of the
+        # outer one's), 0 for every other model
+        self.state_phases = {
+            "helix.state.snapshot": Histogram(
+                "helix_state_snapshot_seconds",
+                "Keeping the recurrent states a step handed back for the "
+                "page boundaries its prefill rows passed, per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.state.restore": Histogram(
+                "helix_state_restore_seconds",
+                "Writing filed recurrent states into the slots of "
+                "admitted prefix hits, per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+        }
         # request stages (ISSUE 25), beside queue_wait: handler entry to
         # first SSE chunk in five consecutive pieces, each also a span in
         # the request's trace
@@ -543,7 +560,7 @@ class EngineLoopObs:
             self.queue_wait, self.ttft, self.inter_token,
             self.step_seconds, self.host_build, self.emit_seconds,
             self.emit_deliver, self.emit_queue_wait, self.emit_backpressure,
-            *self.step_phases.values(),
+            *self.step_phases.values(), *self.state_phases.values(),
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,
         ):

@@ -324,6 +324,39 @@ def ragged_paged_attention_reference(
     return out[0].astype(q.dtype)
 
 
+def pack_heads(q, k_new, v_new, pack: int):
+    """A call at head width ``D = 128 / pack`` as the same attention at
+    width 128 over ``KVH / pack`` kv heads: ``pack`` neighbouring kv heads
+    share a 128-lane tile (``[T, KVH, D]`` viewed ``[T, KVH / pack, 128]``:
+    the same values in the same order, which is how the pool stores them),
+    and each query head is zero-filled over the lanes of its kv head's
+    neighbours, so its scores see its own kv head alone.  The output then
+    holds, for each query head, every neighbour's values under its own
+    weights; ``unpack_heads`` keeps its own."""
+    T, H, D = q.shape
+    KVH = k_new.shape[1]
+    group = H // KVH
+    # query head h belongs to kv head h // group, lane block (h // group)
+    # % pack of packed kv head h // (group * pack)
+    block = (jnp.arange(H) // group) % pack                       # [H]
+    onehot = jax.nn.one_hot(block, pack, dtype=q.dtype)           # [H, pack]
+    qp = (q[:, :, None, :] * onehot[None, :, :, None]).reshape(
+        T, H, pack * D)
+    shape = (T, KVH // pack, pack * D)
+    return qp, k_new.reshape(shape), v_new.reshape(shape)
+
+
+def unpack_heads(out, pack: int, kv_heads: int):
+    """``[T, H, pack * D]`` of a packed call -> ``[T, H, D]``: each query
+    head's own lane block."""
+    T, H, W = out.shape
+    D = W // pack
+    block = (jnp.arange(H) // (H // kv_heads)) % pack
+    return jnp.take_along_axis(
+        out.reshape(T, H, pack, D), block[None, :, None, None], axis=2
+    )[:, :, 0]
+
+
 def ragged_paged_attention(
     q,            # [T, H, D] flat fresh queries across all rows
     k_new,        # [T, KVH, D] fresh K/V (attended raw; caller persists)
@@ -371,6 +404,19 @@ def ragged_paged_attention(
     tiered = cold_k is not None or span_lo is not None
     backend = resolve_backend(backend)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    pack = k_pages.shape[-1] // q.shape[-1]
+    if pack > 1:
+        # a pool of packed heads (head width under 128): the same call at
+        # the pool's geometry, on either backend
+        if tiered or k_scale is not None:
+            raise NotImplementedError(
+                "a page pool of packed kv heads has neither an int8 "
+                "storage nor tiered residency")
+        qp, kp, vp = pack_heads(q, k_new, v_new, pack)
+        out = ragged_paged_attention(
+            qp, kp, vp, k_pages, v_pages, layer, t0, q_len, hist, tables,
+            scale=scale, backend=backend, mesh=mesh, max_q_len=max_q_len)
+        return unpack_heads(out, pack, k_new.shape[1])
     if backend == "pallas" and not tiered:
         from helix_tpu.ops.paged_kernel import ragged_paged_attention_tpu
 

@@ -88,9 +88,14 @@ def check_geometry(num_heads: int, num_kv_heads: int, head_dim: int,
     refuses (per device: under a ``tp`` mesh pass the per-shard counts).
 
     - The kernel views a chunk as ``[tokens, KVH*D]`` with each head a
-      whole number of 128-lane tiles; a head width such as Phi-3-mini's
-      96 needs a relayout the compiler does not have ("unsupported shape
-      cast").
+      whole number of 128-lane tiles.  A width that divides the 128 lanes
+      (64: LFM2) is served PACKED: the pool stores ``128 / D`` kv heads
+      side by side in one tile (``[KVH / pack, 128]``, the same bytes) and
+      the dispatcher (``ops/paged.py::pack_heads``) hands the kernel that
+      geometry, each query zero-filled over its neighbours' lanes; asked
+      for ``[P, 8, 64]`` as it is, Mosaic refuses the chunk's reshape
+      ("unsupported shape cast").  A width such as Phi-3-mini's 96, which
+      neither divides nor is a multiple of 128, is refused.
     - A page is DMA'd as ``[P, KVH, D]``, and the pool's ``(KVH, D)``
       minor pair is tiled in 32-bit sublane packs: ``KVH`` kv heads of
       ``kv_itemsize`` bytes must fill one (2 heads in bf16, 4 in int8), or
@@ -99,8 +104,14 @@ def check_geometry(num_heads: int, num_kv_heads: int, head_dim: int,
       case.
     """
     why = None
+    pack = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    if pack > 1 and num_kv_heads % pack == 0:
+        # what the kernel is handed: ``pack`` kv heads a lane tile
+        num_kv_heads, head_dim = num_kv_heads // pack, head_dim * pack
     if head_dim % 128:
-        why = "the head width must be a multiple of the 128 lanes"
+        why = ("the head width must be a multiple of the 128 lanes, or "
+               "divide them with the kv heads a multiple of 128 / width "
+               "(served: 64 and multiples of 128; not served: 96)")
     elif num_heads % num_kv_heads:
         why = "the kv heads must divide the query heads"
     elif num_kv_heads * kv_itemsize < 4:

@@ -1423,7 +1423,8 @@ class EngineLoop:
         phase histogram (0 where the phase did not run), so the phase
         means add up to the step's."""
         self.obs.step_seconds.observe(seconds)
-        for name, hist in self.obs.step_phases.items():
+        for name, hist in (*self.obs.step_phases.items(),
+                           *self.obs.state_phases.items()):
             hist.observe(ph.get(name, 0.0))
 
     # -- flight recorder (host-side counter deltas only) --------------------
@@ -1495,6 +1496,12 @@ class EngineLoop:
             # tokens in a query block of the state segment's attention
             # call: 1 for plain decode, 8 under speculation
             "attn_q_block": getattr(eng, "attn_q_block", 0),
+            # layers whose state is a fixed tensor a slot (the state
+            # pool), and layers with pages
+            "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),
+            "attn_layers": getattr(
+                eng.model_cfg, "num_attn_layers",
+                getattr(eng.model_cfg, "num_layers", 0)),
             "prefill_tokens": prefill,
             "padding_tokens": (
                 getattr(eng, "num_prefill_padding_tokens", 0) - pad0

@@ -396,6 +396,26 @@ class OpenAIServer:
                     "helix_moe_tile_fill_ratio",
                     getattr(eng, "moe_tile_fill_ratio", 0.0), lbl,
                 )
+            if getattr(eng.model_cfg, "num_conv_layers", 0):
+                # a second kind of state beside the pages: boundary states
+                # kept for the prefix cache, states written into admitted
+                # hits' slots, hits cut back for want of a state
+                c.counter(
+                    "helix_state_snapshots_total",
+                    getattr(eng, "num_state_snapshots", 0), lbl,
+                )
+                c.counter(
+                    "helix_state_restores_total",
+                    getattr(eng, "num_state_restores", 0), lbl,
+                )
+                c.counter(
+                    "helix_prefix_hits_shortened_total",
+                    getattr(eng, "prefix_hits_shortened", 0), lbl,
+                )
+                c.gauge(
+                    "helix_recurrent_state_bytes",
+                    getattr(eng, "recurrent_state_bytes", 0), lbl,
+                )
             # speculative decoding (ISSUE 5): host-drafted tokens, the
             # subset the verify pass accepted, lifetime acceptance, and
             # slots the per-request EMA currently benches

@@ -27,6 +27,10 @@ GEOMETRY = {  # (query heads, kv heads, head width, layers)
     "qwen2-7b": (28, 4, 128, 28),
     "llama3-8b": (32, 8, 128, 32),
 }
+# head width 64, two kv heads to a 128-lane tile of the pool; its six layers
+# of pages (not in GEOMETRY: that is parametrised over an int8 pool too,
+# which packed heads do not have)
+LFM2_8B = (32, 8, 64, 6)
 PAGE, PAGES, MAX_PAGES = 16, 2048, 64
 DECODE_BATCH, PREFILL_LEN = 32, 512   # profiles/v5e1-qwen2-7b.yaml
 
@@ -66,6 +70,8 @@ def _ragged_args(geometry, kv, shape, sharding, heads=P(), pool=P(),
     H, KVH, D, L = GEOMETRY[geometry]
     T, R = (DECODE_BATCH, DECODE_BATCH) if shape.startswith("decode") else (
         PREFILL_LEN, 1)
+    pack = 128 // D if D < 128 and 128 % D == 0 else 1
+    PKVH, PD = KVH // pack, D * pack    # the pool's minor pair
 
     def S(shp, dt, spec=P()):
         return jax.ShapeDtypeStruct(shp, dt, sharding=sharding(spec))
@@ -75,8 +81,8 @@ def _ragged_args(geometry, kv, shape, sharding, heads=P(), pool=P(),
         S((T, H, D), jnp.bfloat16, heads),
         S((T, KVH, D), jnp.bfloat16, heads),
         S((T, KVH, D), jnp.bfloat16, heads),
-        S((L, PAGES, PAGE, KVH, D), pool_dt, pool),
-        S((L, PAGES, PAGE, KVH, D), pool_dt, pool),
+        S((L, PAGES, PAGE, PKVH, PD), pool_dt, pool),
+        S((L, PAGES, PAGE, PKVH, PD), pool_dt, pool),
         S((), jnp.int32),
         S((R,), jnp.int32), S((R,), jnp.int32), S((R,), jnp.int32),
         S((R, MAX_PAGES), jnp.int32),
@@ -114,6 +120,49 @@ def test_ragged_kernel_compiles(one_chip, geometry, kv, shape):
         max_q_len=MAX_Q_LEN[shape],
     )
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("shape", sorted(MAX_Q_LEN))
+def test_ragged_kernel_compiles_at_head_width_64(one_chip, shape):
+    """LFM2-8B-A1B's attention (32 / 8 heads of 64) in both block shapes:
+    the dispatcher hands the kernel two kv heads a lane tile.  Asked for the
+    pool as ``[P, 8, 64]`` Mosaic refuses the chunk's reshape ("unsupported
+    shape cast"); ``check_geometry`` admits the width because the kv heads
+    pack evenly."""
+    from helix_tpu.ops.paged_kernel import check_geometry
+
+    check_geometry(32, 8, 64)
+    GEOMETRY["lfm2-8b-a1b"] = LFM2_8B
+    try:
+        args = _ragged_args("lfm2-8b-a1b", "bf16", shape,
+                            lambda spec: one_chip)
+    finally:
+        del GEOMETRY["lfm2-8b-a1b"]
+    assert args[3].shape == (6, PAGES, PAGE, 4, 128)
+    compiled = _compile_ragged(args, max_q_len=MAX_Q_LEN[shape])
+    assert "ragged_paged_attention_tpu" in compiled.as_text()
+
+
+def test_flash_attention_compiles_at_head_width_64(one_chip):
+    from helix_tpu.ops.attention import attention
+
+    H, KVH, D, _ = LFM2_8B
+
+    def S(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    q = S((1, PREFILL_LEN, H, D), jnp.bfloat16)
+    kv = S((1, PREFILL_LEN, KVH, D), jnp.bfloat16)
+    ids = S((1, PREFILL_LEN), jnp.int32)
+
+    def op(q, k, v, pos, seg):
+        return attention(
+            q, k, v, causal=True, q_positions=pos, kv_positions=pos,
+            q_segment_ids=seg, kv_segment_ids=seg, backend="pallas",
+        )
+
+    compiled = jax.jit(op).lower(q, kv, kv, ids, ids).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_decode_kernel_is_in_the_compiled_text(one_chip):
@@ -342,3 +391,71 @@ def test_expert_step_compiles_at_published_widths(one_chip, program):
     # the pool is updated in place: no pool-sized temporary
     pool_bytes = (ks[0] * pages * 16 * (512 + 128)) * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_hybrid_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of LFM2-8B-A1B cut to seven layers that hold all
+    three kinds (conv+dense, then attention+experts and two conv+experts
+    twice: a repeated group; int8 weights, 64 slots) for the described chip: the conv operator over the flat
+    ragged axis with the state pool in the carry, the paged kernel at head
+    width 64, the grouped expert product, both pools updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import LFM2_8B_A1B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        LFM2_8B_A1B, num_layers=7, first_k_dense=1,
+        layer_types=("conv",) + ("attn", "conv", "conv") * 2)
+    assert [g.reps for g in cfg.layer_runs()] == [1, 2]
+    B, max_pages, pages = 64, 160, 10240
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == (2, 16, 4, 128) and cc.state_shape(cfg) == (5, B, 2, 2048)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=S(cc.state_shape(cfg), jnp.bfloat16))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "ragged_paged_attention_tpu" in text
+    assert "grouped_matmul_tpu" in text
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # both pools are updated in place: no pool-sized temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        pages * cc.page_bytes(cfg)) // 2
