@@ -54,21 +54,6 @@ ENV_REFERENCE: tuple = (
         section="accelerator",
     ),
     EnvVar(
-        "HELIX_ASYNC_LOOP",
-        "Pipelined dispatch override for every engine this node "
-        "serves: truthy dispatches device step N+1 against predicted "
-        "post-step state while step N executes (greedy and seeded "
-        "temp>0 outputs stay bit-identical to the synchronous "
-        "dispatch); 0/false forces the synchronous dispatch even where "
-        "a profile sets engine.enable_async_loop. It governs the "
-        "dispatch only: every started engine loop delivers tokens on "
-        "its bounded off-thread emission stage either way. Watch "
-        "helix_device_idle_ratio, helix_pipelined_steps_total and the "
-        "helix_step_host_build_seconds histogram for the effect. "
-        "Unset: the profile setting applies (default off).",
-        section="accelerator",
-    ),
-    EnvVar(
         "HELIX_TOKEN_BUCKETS",
         "Comma-separated token-bucket ladder for the unified ragged "
         "device step's prefill segment (e.g. '64,192,512,2048'). Each "

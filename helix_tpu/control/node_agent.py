@@ -308,15 +308,6 @@ def _build_served_model(pm: ProfileModel, mesh=None) -> ServedModel:
         # the batched adapter path on, 0 forces it off even where a
         # profile enables it
         ekw["adapter_pool_slots"] = adapter_slots
-    async_env = _os_env.environ.get("HELIX_ASYNC_LOOP", "")
-    if async_env:
-        # operator-level async-engine-loop override for EVERY engine
-        # this node serves (same operator-beats-profile contract as
-        # HELIX_SPEC_TOKENS): truthy enables the pipelined loop, 0/false
-        # forces the synchronous baseline even where a profile enables it
-        ekw["enable_async_loop"] = async_env.strip().lower() not in (
-            "0", "false", "no", "off"
-        )
     mpps_env = _os_env.environ.get("HELIX_MAX_PAGES_PER_SEQ", "")
     if mpps_env:
         # operator-level per-sequence page-table cap for EVERY engine

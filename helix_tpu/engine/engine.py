@@ -202,25 +202,6 @@ class EngineConfig:
     # routing vs plain decode) — the engine logs and disables there.
     enable_spec_decode: bool = False
     spec_tokens: int = 4
-    # Asynchronous pipelined engine loop (serving/engine_loop.py): while
-    # device step N executes, the loop dispatches step N+1 against
-    # PREDICTED post-step state (positions/budgets advanced at dispatch
-    # — the device advances every active row by the full window whether
-    # or not the host later discards an overrun, so the prediction is
-    # exact for everything but EOS, whose overrun tokens are discarded
-    # exactly like fused-window overruns always were).  (Step N-1's
-    # tokens go out through the bounded off-thread emission stage in
-    # every started loop, with this knob on or off.)  The
-    # pipeline engages only for plain fused-decode steps in steady state
-    # (no admissions, no chunked prefill, no parked preemptions, state
-    # clean) and degrades to the synchronous loop everywhere else —
-    # including for the WHOLE engine when speculative decoding is
-    # enabled (a drafter conditioning on host-lagged sequences would
-    # gut acceptance; spec already amortizes host syncs via its fused
-    # verify+tail) — so greedy AND seeded temp>0 outputs are
-    # bit-identical with the knob on or off.  Node-level override:
-    # HELIX_ASYNC_LOOP (operator-beats-profile, 0 forces off).
-    enable_async_loop: bool = False
     # Continuous multi-LoRA serving (engine/adapters.py): >= 2 turns on
     # the batched adapter path — a fixed-capacity stacked HBM pool of
     # LoRA factors (slot 0 reserved for the zero identity adapter) is
@@ -336,7 +317,9 @@ class DecodeState:
     are advanced inside the fused step.  The host re-syncs the state only
     when the slot set changes (admission / completion) via one jitted
     merge (``_rebuild_state``) that preserves the device-evolving
-    pieces (keys, histograms) of surviving slots.
+    pieces (last tokens, positions, keys, histograms) of surviving slots:
+    the host supplies them for changed slots only, so a rebuild is valid
+    while a step is still in flight (its tokens are not on the host yet).
     """
 
     last_token: jax.Array    # [B] i32
@@ -364,8 +347,8 @@ def _rebuild_state(
         ((keep == 0) & (active > 0)).astype(fresh.dtype)
     )
     return DecodeState(
-        last_token=last_token,
-        positions=positions,
+        last_token=jnp.where(keep > 0, old.last_token, last_token),
+        positions=jnp.where(keep > 0, old.positions, positions),
         page_tables=page_tables,
         active=active,
         mrope_delta=mrope_delta,
@@ -389,18 +372,25 @@ def _override_token_counts(state: DecodeState, slot, counts) -> DecodeState:
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _patch_first_token(state: DecodeState, slot, tok) -> DecodeState:
-    """Seed ONE fresh slot's device-resident last_token + histogram from
-    a still-on-device first-token handle (deferred chunk-final fetch):
-    ``_rebuild_state`` seeded the slot from the host mirror's placeholder
-    0, so move that histogram count to the real token and set last_token
-    — the decode step that follows in the same engine step then conditions
-    on the true first token without the host ever fetching it alone."""
-    counts = state.token_counts.at[slot, 0].add(-1)
-    counts = counts.at[slot, tok].add(1)
+def _patch_first_tokens(state: DecodeState, src, toks) -> DecodeState:
+    """Seed fresh slots' device-resident last_token + histogram from a
+    still-on-device first-token handle ``toks [R]`` (an admission wave's,
+    or a final chunk's): slot ``b`` takes ``toks[src[b]]``, ``src[b]`` -1
+    leaves it alone.  ``_rebuild_state`` seeded the slot from the host
+    mirror's placeholder 0, so move that histogram count to the real
+    token and set last_token: the decode step that follows then
+    conditions on the true first token without the host having fetched
+    it."""
+    has = src >= 0
+    tok = toks[jnp.clip(src, 0)]
+    rows = jnp.arange(src.shape[0])
+    # a slot launched inactive was given no placeholder count to move
+    d = (has & (state.active > 0)).astype(state.token_counts.dtype)
+    counts = state.token_counts.at[rows, 0].add(-d)
+    counts = counts.at[rows, tok].add(d)
     return dataclasses.replace(
         state,
-        last_token=state.last_token.at[slot].set(tok),
+        last_token=jnp.where(has, tok, state.last_token),
         token_counts=counts,
     )
 
@@ -425,11 +415,10 @@ class PendingStep:
     n: int = 1                  # fused window size (decode)
     n_extra: int = 0            # fused tail length (spec)
     draft_len: Optional[np.ndarray] = None   # [B] (spec)
-    # deferred chunk-final first tokens: [(Request, [R] device handle)],
-    # fetched inside this step's one device_get instead of their own
+    # deferred first tokens (an admission wave's, a final chunk's):
+    # [(Request, [R] device handle, row)], fetched inside this step's one
+    # device_get instead of their own
     pending_first: list = dataclasses.field(default_factory=list)
-    st: Optional[dict] = None   # mixed: the in-flight chunking record
-    final: bool = False         # mixed: this chunk completes the prompt
 
 
 @dataclasses.dataclass
@@ -1555,17 +1544,26 @@ class Engine:
         self._plan_recorder = None
         self._plan_drive = None
         self._slot_count_overrides: dict[int, np.ndarray] = {}
-        # deferred chunk-final first tokens (ISSUE 13): the final chunk's
-        # sampled token stays on device — _sync_state patches the slot's
-        # DecodeState from the handle and the emit joins the decode
-        # step's single device_get (one host round trip per step, not
-        # two).  _inflight_out counts dispatched-not-yet-reconciled
-        # tokens per request so the async loop's predicted dispatch
-        # computes budgets/headroom against post-step state.
-        self._pending_first: list = []           # [(req, [R] dev handle)]
+        # deferred first tokens (ISSUE 13 for a final chunk, ISSUE 33 for an
+        # admission wave): the prefill's sampled tokens stay on device:
+        # _sync_state patches the slots' DecodeState from the handle and
+        # the emit joins the decode step's single device_get (one host
+        # round trip per step, not two, and no fetch between a prefill's
+        # launch and the decode step's).  _inflight_out counts a request's
+        # dispatched-not-yet-reconciled tokens, a deferred first token
+        # among them, so a dispatch on predicted state computes budgets and
+        # headroom against post-step state.
+        self._pending_first: list = []    # [(req, [R] dev handle, row)]
         self._pending_first_ids: set = set()
-        self._pending_token_patches: dict[int, object] = {}
+        self._pending_token_patches: dict[int, tuple] = {}  # slot -> (h, row)
         self._inflight_out: dict[str, int] = {}
+        # the ``active`` column the device state holds (a row whose budget
+        # or page room the tokens in flight exhaust is launched inactive)
+        self._active_sent = np.zeros((B,), np.int32)
+        # host clock at the first device launch of the last
+        # ``step_dispatch`` (None: it launched nothing); the engine loop
+        # reads how long the device waited for the host from it
+        self.first_launch_time: Optional[float] = None
         self._prefetched: set = set()   # digests with in-flight device puts
         self._key_base = _splitmix64(0x8E1_1C9 ^ (rng_seed & _M64))
         self._key_nonce = 0
@@ -1949,6 +1947,12 @@ class Engine:
             # every entry point)
             drive(self.cfg.max_prefill_len, False, 1)
             drive(self.cfg.max_prefill_len, True, 1)
+            # a final chunk's one-row token handle seeds its slot through
+            # the same patch the request above compiled for a wave's
+            self._dstate = _patch_first_tokens(
+                self._dstate, jnp.full((B,), -1, jnp.int32),
+                jnp.zeros((1,), jnp.int32),
+            )
 
     def step(self) -> list[tuple[Request, int]]:
         """Admit + prefill waiting requests, then one decode step.
@@ -1962,8 +1966,10 @@ class Engine:
         Returns [(request, new_token_id), ...] for tokens produced this step.
 
         ``step()`` is exactly ``step_complete(step_dispatch())`` — the
-        async engine loop (ISSUE 13) calls the halves itself so the host
-        phase of step N+1 overlaps the device phase of step N.
+        engine loop calls the halves itself so that, while admission is
+        blocked, the host phase of step N+1 overlaps the device phase of
+        step N (ISSUE 33); with nothing in flight the two orders are the
+        same code.
         """
         emitted, pend = self.step_dispatch()
         if pend is not None:
@@ -1988,10 +1994,13 @@ class Engine:
         """The HOST phase of one engine step: admission, plan building,
         metadata upload and the (async) device dispatch.  Returns
         ``(emitted_so_far, pending)`` — ``pending`` carries the device
-        handles; nothing here blocks on the device except the admission
-        wave's batched first-token fetch (conservative fallback: steps
-        with admissions reconcile synchronously)."""
+        handles; nothing here blocks on the device (an admission wave's
+        first tokens stay there and ride ``pending``'s fetch) except the
+        VL single-shot prefill and an admission with no decodable row
+        behind it.  Valid while an earlier step is still in flight: see
+        ``pipeline_ready``."""
         emitted: list[tuple[Request, int]] = []
+        self.first_launch_time = None
         if self.host_pool is not None:
             # release the HBM gather buffers of spills from EARLIER
             # steps (their async D2H copies have landed by now) —
@@ -2017,13 +2026,11 @@ class Engine:
         speculative verify, or the fused decode window."""
         if self._chunking is not None and self._chunking["req"].finished:
             self._chunking = None    # aborted mid-prefill
-        decode_ready = any(
-            self._slot_active(i) for i in range(len(self.slots))
-        )
+        slots = range(len(self.slots))
         if (
             self._chunking is not None
-            and decode_ready
             and self.cfg.enable_mixed_step
+            and any(self._row_runs(i) for i in slots)
         ):
             return self._mixed_dispatch()
         if self._chunking is not None:
@@ -2031,7 +2038,7 @@ class Engine:
         # re-check: a chunk that just completed activates its slot and
         # decodes its second token this same step (pre-mixed behaviour);
         # its deferred first token rides that step's single device_get
-        if any(self._slot_active(i) for i in range(len(self.slots))):
+        if any(self._row_runs(i) for i in slots):
             # speculate when the drafter has something to verify; any
             # step it doesn't (no n-gram hit, EMA-disabled slots, no
             # headroom) falls straight through to the plain fused window
@@ -2041,9 +2048,9 @@ class Engine:
             if pend is None:
                 pend = self._decode_dispatch()
             return pend
-        # nothing decodable (admission-only step, or a chunk whose
-        # request aborted between activation and decode): any deferred
-        # first token must still land — conservative synchronous flush
+        # nothing decodable (every admitted row ends on its first token, or
+        # a chunk whose request aborted between activation and decode): any
+        # deferred first token must still land — synchronous flush
         self._flush_pending_first(emitted)
         return None
 
@@ -2073,45 +2080,55 @@ class Engine:
         return out
 
     def pipeline_ready(self) -> bool:
-        """True when the NEXT dispatch can safely run against predicted
-        post-step state while a step is still in flight: plain
-        fused-decode steady state only.  Admission waves, chunked
-        prefill, speculation (its per-slot advance depends on acceptance
-        counts the host has not seen), parked preemptions and any dirty
-        slot state (the rebuild uploads host mirrors that are only
-        accurate at reconcile points) all force the loop back to the
-        synchronous dispatch->complete ordering."""
-        if (
+        """True when the NEXT dispatch may run against predicted post-step
+        state while a step is still in flight (and may itself stay in
+        flight).  What makes that safe, for a finish, an admission wave
+        and a mixed step alike:
+
+        - the device stream runs programs in launch order, so a page (or a
+          slot of the state pool) freed at a reconcile and claimed by the
+          next wave is written by the old owner's overrun first and by
+          its new owner after;
+        - ``_rebuild_state`` keeps the device's last tokens and positions
+          for surviving rows, so no rebuild needs tokens still in flight;
+        - ``_pending_out`` charges every dispatched-not-reconciled token
+          against a row's ``max_tokens`` and page room: a row the tokens
+          in flight exhaust is launched inactive (``_row_runs``), and a
+          row that ends on a stop token is found one step late, its
+          overrun inside the page room the ``room`` check reserved and
+          discarded at the reconcile (``slots[i] is not r or r.finished``).
+
+        What still reconciles first: speculation (a row's advance depends
+        on acceptance counts the host has not seen, and the drafter reads
+        the host's sequence), parked preemptions (a resume uploads the
+        mirrors of a row the host must have reconciled) and tiered rows
+        (their demotion gathers order against a reconciled cache handle).
+        The engine loop adds the conditions it owns: aborts, imports,
+        drain, hand-off, checkpoints, preemption for pressure."""
+        return not (self.preempted or self.spec is not None or self._tiered)
+
+    def steady_decode(self) -> bool:
+        """Plain fused-decode steady state: nothing queued, no chunked
+        prefill, clean slot state and headroom in every running row.  The
+        multi-host plan leader looks ahead only here (ISSUE 13's rule): a
+        follower replays each plan with nothing in flight, so it sees a
+        finish one step before the leader does and would claim another
+        slot for the same admission."""
+        return not (
             self._state_dirty
-            or self._dstate is None
             or self.waiting
             or self._chunking is not None
-            or self.preempted
-            or self.spec is not None
             or self._pending_first
-            # tiered slots gather pages for demotion between steps — the
-            # gathers must order against a RECONCILED cache handle, so
-            # tiering keeps the loop on the synchronous path
-            or self._tiered
-        ):
-            return False
-        # every active slot must have headroom for at least one more
-        # predicted token: a slot whose in-flight window exhausts its
-        # budget or page allocation is about to FINISH at the reconcile,
-        # and dispatching past that point would trip the headroom
-        # invariant (or waste a whole discarded step) — reconcile first
-        for i, req in enumerate(self.slots):
-            if req is None or not self._slot_active(i):
-                continue
-            pend = self._pending_out(req)
-            if (
-                req.sampling.max_tokens - len(req.output_tokens) - pend
-                <= 0
-                or (req.max_len or self.cache_cfg.max_seq_len)
-                - req.num_tokens - pend <= 0
-            ):
-                return False
-        return True
+        ) and all(
+            self._row_runs(i) for i in range(len(self.slots))
+            if self._slot_active(i)
+        )
+
+    def admission_blocked(self) -> bool:
+        """Nothing more could be admitted before the step that was just
+        launched ends: requests still queue after this pass's admission
+        (for a slot or for pages), or no slot is free for an arrival."""
+        return bool(self.waiting) or all(s is not None for s in self.slots)
 
     def discard_pending(self, pend: PendingStep) -> None:
         """Forget an in-flight dispatch whose completion failed or will
@@ -2119,7 +2136,7 @@ class Engine:
         slot is marked changed so the next ``_sync_state`` re-uploads
         the mirrors rather than trusting device state the failed step
         may have left behind."""
-        if pend.kind == "decode":
+        if pend.kind in ("decode", "mixed"):
             # roll back the predicted-position advance: the mirror's
             # last_token is still the last RECONCILED token (position
             # p-1), so the retry must re-decode from p — leaving the
@@ -2129,46 +2146,91 @@ class Engine:
             for i, r in pend.rows:
                 if self.slots[i] is r:
                     self._positions[i] -= pend.n
-        for _i, r in pend.rows:
-            self._inflight_out.pop(r.id, None)
-        self._pending_token_patches.clear()
-        self._pending_first = []
-        self._pending_first_ids.clear()
-        for req, tok in pend.pending_first:
-            if req.finished or req.slot is None:
+                self._uncharge(r, pend.n)
+        for req, tok, row in pend.pending_first:
+            self._uncharge(req, 1)
+            if (
+                req.finished or req.slot is None
+                or req.id in self._pending_first_ids
+            ):
                 continue
-            # the chunk call that sampled this deferred first token
+            # the prefill that sampled this deferred first token
             # SUCCEEDED — only the decode completion failed.  Put it
             # back so the retry re-seeds the slot from the handle and
             # still emits token #1; dropping it would condition the
             # retried stream on the placeholder mirror (0) and silently
             # lose the prompt's first sampled token.
-            self._pending_first.append((req, tok))
-            self._pending_first_ids.add(req.id)
-            self._pending_token_patches[req.slot] = tok[0]
+            self._defer_first_token(req, tok, row)
         self._state_dirty = True
         self._changed_slots.update(range(len(self.slots)))
 
     def _pending_out(self, req: Request) -> int:
         """Tokens this request has in flight (dispatched, not yet
-        reconciled) plus a deferred chunk-final first token — the
-        correction every budget/headroom read applies so a predicted
-        dispatch can never overrun max_tokens or the allocated pages."""
-        return self._inflight_out.get(req.id, 0) + (
-            1 if req.id in self._pending_first_ids else 0
-        )
+        reconciled), a deferred first token among them — the correction
+        every budget/headroom read applies so a predicted dispatch can
+        never overrun max_tokens or the allocated pages."""
+        return self._inflight_out.get(req.id, 0)
+
+    def _charge(self, req: Request, n: int) -> None:
+        """``n`` more of this request's tokens are dispatched and not yet
+        reconciled (or sit on the device as a deferred first token)."""
+        self._inflight_out[req.id] = self._inflight_out.get(req.id, 0) + n
+
+    def _uncharge(self, req: Request, n: int) -> None:
+        """``n`` of them reached the host (they are charged by
+        ``len(output_tokens)`` from now on), or their step was discarded:
+        the request's account is closed when nothing is left in flight."""
+        left = self._inflight_out.get(req.id, 0) - n
+        if left > 0:
+            self._inflight_out[req.id] = left
+        else:
+            self._inflight_out.pop(req.id, None)
+
+    def _row_runs(self, i: int) -> bool:
+        """Slot ``i`` is launched live: decodable, with headroom left
+        (``_headroom``).  A row the tokens in flight exhaust finishes at
+        their reconcile; until then it sits inactive on the device
+        instead of making the loop wait for that reconcile."""
+        return self._slot_active(i) and self._headroom(self.slots[i]) > 0
+
+    def _defer_first_token(self, req: Request, tok, row: int) -> None:
+        """Leave a prefill's sampled first token on the device: a
+        placeholder in the mirror, a device-side patch at the next
+        ``_sync_state``, the emit at the next step's batched fetch."""
+        self._last_token[req.slot] = 0
+        self._pending_token_patches[req.slot] = (tok, row)
+        self._pending_first.append((req, tok, row))
+        self._pending_first_ids.add(req.id)
+        self._charge(req, 1)
 
     def _take_pending_first(self) -> list:
         pf, self._pending_first = self._pending_first, []
         self._pending_first_ids.clear()
         return pf
 
+    def _fetch_with_firsts(self, handles: tuple, pending_first: list,
+                           emitted) -> tuple:
+        """A step's one fetch, the deferred first tokens it carries
+        included (each handle once); they are emitted ahead of the step's
+        own tokens.  Returns the fetched ``handles``."""
+        uniq: dict = {}
+        for _req, tok, _row in pending_first:
+            uniq.setdefault(id(tok), tok)
+        fetched = self._fetch(handles + tuple(uniq.values()))
+        firsts = dict(zip(uniq, fetched[len(handles):]))
+        for req, tok, row in pending_first:
+            self._finish_first_emit(
+                req, int(firsts[id(tok)][row]), emitted)
+        return fetched[:len(handles)]
+
     def _finish_first_emit(self, req: Request, first_token: int,
                            emitted) -> None:
-        """Deferred chunk-final emit, after its handle was fetched as
+        """Deferred first-token emit, after its handle was fetched as
         part of the step's batched device_get."""
+        self._uncharge(req, 1)
         if req.finished:
             return   # aborted after activation: the token is moot
+        req.first_token_time = time.monotonic()
         if req.slot is not None:
             self._last_token[req.slot] = first_token
             # a patch not yet consumed by _sync_state is superseded by
@@ -2178,14 +2240,17 @@ class Engine:
         self._emit(req, first_token, emitted)
 
     def _flush_pending_first(self, emitted) -> None:
-        """Conservative fallback when no same-step decode fetch will
-        carry the deferred first token: fetch it alone (today's
-        behaviour)."""
+        """Fallback when no same-step decode fetch will carry the
+        deferred first tokens: fetch them alone."""
         pf = self._take_pending_first()
         if not pf:
             return
-        for req, tok in pf:
-            self._finish_first_emit(req, int(self._fetch(tok)[0]), emitted)
+        with obs_trace.phase(
+            "helix.loop.prefill_sync", into=self.step_phases
+        ), self.device_wait:
+            toks = jax.device_get([tok for _req, tok, _row in pf])
+        for (req, _tok, row), t_np in zip(pf, toks):
+            self._finish_first_emit(req, int(t_np[row]), emitted)
         self._drain_moe_drops()   # the fetch above synced the device
 
     def _request_key(self, req: Request) -> np.ndarray:
@@ -2806,7 +2871,7 @@ class Engine:
             self._admit_inner(emitted, deferred, pending)
         finally:
             if pending:
-                self._finish_packed_admissions(pending, emitted)
+                self._finish_packed_admissions(pending)
             if deferred:
                 self.waiting[:0] = deferred
         if self.preempted:
@@ -2856,9 +2921,8 @@ class Engine:
                 # into ONE ragged prefill segment (a hit row's remainder
                 # attends the shared pages via its per-row history
                 # length; pre-unification each hit paid its own padded
-                # chunk call).  First tokens stay on device until the
-                # whole wave is admitted (one fetch per wave, not per
-                # call — each fetch is a full host round trip).
+                # chunk call).  First tokens stay on the device and ride
+                # the next decode step's fetch.
                 if not self._admit_wave(pending):
                     # resource wait: overlap it with the host->device
                     # uploads the eventual claim will consume
@@ -2908,9 +2972,8 @@ class Engine:
         one-request chunk calls.  Returns requests admitted (0 =
         blocked on resources).
 
-        First tokens are NOT fetched here: the device handle is appended
-        to ``pending`` and ``_finish_packed_admissions`` fetches the whole
-        admission wave in one host round trip."""
+        First tokens are NOT fetched: the device handle is appended to
+        ``pending`` for ``_finish_packed_admissions``."""
         C_cap = self.cfg.max_prefill_len
         ps = self.cache_cfg.page_size
         maxP = self.cache_cfg.max_pages_per_seq
@@ -3001,34 +3064,16 @@ class Engine:
             admitted += len(wave_batch)
         return admitted
 
-    def _finish_packed_admissions(self, pending: list, emitted) -> None:
-        """Fetch every admission wave's first tokens in ONE host round
-        trip and complete the per-request bookkeeping."""
-        with obs_trace.phase(
-            "helix.loop.prefill_sync", into=self.step_phases
-        ), self.device_wait:
-            if len(pending) == 1:
-                batch0, tok0 = pending[0]
-                flat = np.asarray(tok0)[: len(batch0)]
-            else:
-                flat = np.asarray(
-                    jnp.concatenate(
-                        [t[: len(b)] for b, t in pending], axis=0
-                    )
-                )
-        # the token fetch above synced the device: draining is free here
-        self._drain_moe_drops()
-        now = time.monotonic()
-        i = 0
-        for batch, _ in pending:
-            for req, _table in batch:
-                first_token = int(flat[i])
-                i += 1
+    def _finish_packed_admissions(self, pending: list) -> None:
+        """Per-request bookkeeping of the admission waves just launched.
+        Their first tokens are NOT fetched: they stay on the device, seed
+        the new rows there (``_defer_first_token``) and reach the host
+        with the tokens of the decode step launched behind the waves."""
+        for batch, first_tokens in pending:
+            for row, (req, _table) in enumerate(batch):
                 slot = req.slot
-                req.first_token_time = now
                 self._positions[slot] = len(req.prompt_tokens)
                 self._mrope_delta[slot] = 0
-                self._last_token[slot] = first_token
                 self._state_dirty = True
                 self._changed_slots.add(slot)
                 self.num_prefill_tokens += (
@@ -3037,7 +3082,7 @@ class Engine:
                 self._adopt_prompt_pages(
                     req, self._page_tables[slot]
                 )
-                self._emit(req, first_token, emitted)
+                self._defer_first_token(req, first_tokens, row)
 
     def _drain_moe_drops(self) -> None:
         """Fold the queued MoE step stats (``[dropped, routed, load max
@@ -3102,39 +3147,23 @@ class Engine:
         )
         return plan, rem, end
 
-    def _finish_chunk(self, st, first_token, emitted) -> None:
-        """Prompt fully cached: activate the slot with the first sampled
-        token (shared by the standalone chunk step and the mixed step).
-
-        ``first_token`` is either a host int (mixed step — its fetch was
-        folded into the step's one device_get) or the chunk step's [R]
-        DEVICE handle, in which case the fetch DEFERS: _sync_state seeds
-        the slot's device state from the handle and the emit joins the
-        same-step decode fetch, so a long-prompt chunk cascade costs one
+    def _finish_chunk(self, st, first_token) -> None:
+        """Prompt fully cached: activate the slot (shared by the
+        standalone chunk step and the mixed step).  ``first_token`` is the
+        final chunk's [R] DEVICE handle and its fetch DEFERS: _sync_state
+        seeds the slot's device state from the handle and the emit joins
+        a decode step's fetch, so a long-prompt chunk cascade costs one
         host round trip per step, not two."""
         req: Request = st["req"]
         self._adopt_prompt_pages(req, st["table"])
         slot = st["slot"]
         self._chunking = None
-        req.first_token_time = time.monotonic()
         self._positions[slot] = len(req.prompt_tokens)
         self._mrope_delta[slot] = req.mrope_delta
         self._slot_keys[slot] = _host_split(st["key"])[0]
         self._state_dirty = True
         self._changed_slots.add(slot)
-        if isinstance(first_token, (int, np.integer)):
-            self._last_token[slot] = first_token
-            # the caller fetched the first token already: device is
-            # synced, so folding the queued chunk drop counts is free
-            self._drain_moe_drops()
-            self._emit(req, int(first_token), emitted)
-            return
-        # deferred: placeholder mirror, device-side patch at the next
-        # _sync_state, emit at the next batched fetch
-        self._last_token[slot] = 0
-        self._pending_token_patches[slot] = first_token[0]
-        self._pending_first.append((req, first_token))
-        self._pending_first_ids.add(req.id)
+        self._defer_first_token(req, first_token, 0)
 
     # per-request cap on prefill_chunk spans: a 128k prompt would
     # otherwise flood its own trace's span budget and evict the decode/
@@ -3177,32 +3206,32 @@ class Engine:
             )
         if end < len(req.prompt_tokens):
             return
-        self._finish_chunk(st, token, None)
+        self._finish_chunk(st, token)
 
     def _mixed_dispatch(self) -> Optional[PendingStep]:
-        """Ragged mixed step: ONE device call advances every active decode
+        """Ragged mixed step: ONE device call advances every running decode
         slot one token AND the in-flight long prefill one chunk — decode
         never stalls (and never pays a second dispatch) while a long
-        prompt is being admitted."""
+        prompt is being admitted.  Like the decode window it is dispatched
+        on predicted state: positions advance here, the chunk's progress
+        is the host's own, and a final chunk's token stays on the device
+        (``_finish_chunk``) and rides this step's fetch."""
         st = self._chunking
         req: Request = st["req"]
-        if self._state_dirty or self._dstate is None:
-            self._sync_state()
+        rows = [
+            (i, r) for i, r in enumerate(self.slots) if self._row_runs(i)
+        ]
         # same headroom invariant as the decode step, for the fused step
         table_cap = (
             self.cache_cfg.max_pages_per_seq * self.cache_cfg.page_size
         )
-        for i in range(len(self.slots)):
-            if self._slot_active(i) and self._positions[i] + 1 > table_cap:
+        for i, _r in rows:
+            if self._positions[i] + 1 > table_cap:
                 raise RuntimeError(
                     f"decode step overruns page-table capacity: slot {i} "
                     f"at position {self._positions[i]} — headroom "
                     f"invariant violated"
                 )
-        rows = [
-            (i, r) for i, r in enumerate(self.slots)
-            if r is not None and self._slot_active(i)
-        ]
         t0 = time.monotonic()
         plan, rem, end = self._chunk_plan(st)
         token, sampled, _, _ = self._ragged_step(
@@ -3212,50 +3241,36 @@ class Engine:
         self.num_decode_device_steps += 1
         self.num_prefill_tokens += rem
         st["next"] = end
+        for i, r in rows:
+            self._positions[i] += 1
+            self._charge(r, 1)
         if req.trace_id and self._should_trace_chunk(st, req, end):
             obs_trace.default_store().record(
                 req.trace_id, "prefill_chunk", t0, time.monotonic(),
                 plane="engine", request_id=req.id,
                 chunk_end=end, tokens=rem, mixed=True,
             )
+        if end >= len(req.prompt_tokens):
+            self._finish_chunk(st, token)
         return PendingStep(
-            kind="mixed", rows=rows, handles=(sampled, token), st=st,
-            final=end >= len(req.prompt_tokens),
-            # a deferred chunk-final first token re-queued by a failed
-            # step can cross into a mixed retry (a NEW prompt started
-            # chunking): it must ride THIS step's fetch or its request
-            # would emit token #2 before token #1
+            kind="mixed", rows=rows, handles=(sampled,),
+            # the final chunk's own token, and any deferred first token
+            # re-queued by a failed step: each must ride THIS step's fetch
+            # or its request would emit token #2 before token #1
             pending_first=self._take_pending_first(),
         )
 
     def _mixed_complete(self, p: PendingStep, emitted) -> None:
-        sampled, token = p.handles
-        firsts = tuple(tok for _r, tok in p.pending_first)
-        if p.final:
-            # chunk-final token folded into the step's ONE device_get
-            # (previously its own np.asarray fetch — a second host
-            # round trip on every long-prompt completion step)
-            fetched = self._fetch((sampled, token) + firsts)
-            next_np, tok_np = fetched[0], fetched[1]
-            first_np = fetched[2:]
-        else:
-            fetched = self._fetch((sampled,) + firsts)
-            next_np, tok_np = fetched[0], None
-            first_np = fetched[1:]
-        if p.pending_first:
-            for (req, _h), t_np in zip(p.pending_first, first_np):
-                self._finish_first_emit(req, int(t_np[0]), emitted)
-            self._drain_moe_drops()   # the fetch above synced the device
-        # decode emissions first (the chunking slot is still parked here)
+        (next_np,) = self._fetch_with_firsts(
+            p.handles, p.pending_first, emitted)
+        for _i, r in p.rows:
+            self._uncharge(r, 1)
         for i, r in p.rows:
             if self.slots[i] is not r or r.finished:
-                continue
-            self._positions[i] += 1
+                continue  # finished/evicted mid-flight: discard the overrun
             self._last_token[i] = next_np[i, 0]
             self.num_decode_tokens += 1
             self._emit(r, int(next_np[i, 0]), emitted)
-        if p.final:
-            self._finish_chunk(p.st, int(tok_np[0]), emitted)
 
     def _prefill(
         self, req: Request, page_table: np.ndarray, slot: Optional[int] = None
@@ -3326,17 +3341,21 @@ class Engine:
     # decode
     # ------------------------------------------------------------------
 
+    def _running_mask(self) -> np.ndarray:
+        return np.array(
+            [1 if self._row_runs(i) else 0 for i in range(len(self.slots))],
+            np.int32,
+        )
+
     def _sync_state(self) -> None:
-        """One jitted merge uploads the host mirrors after the slot set
-        changed; device-evolving pieces (RNG keys, penalty histograms) of
-        surviving slots are preserved on device."""
+        """One jitted merge uploads the host mirrors of the slots that
+        changed; the device-evolving pieces (last tokens, positions, RNG
+        keys, penalty histograms) of surviving slots are preserved on
+        device, so the merge is valid while a step is in flight."""
         B = self.cfg.max_decode_batch
         V = self.model_cfg.vocab_size
         P = self.cache_cfg.max_pages_per_seq
-        active = np.array(
-            [1 if self._slot_active(i) else 0 for i in range(len(self.slots))],
-            np.int32,
-        )
+        active = self._active_sent = self._running_mask()
         sampling = SamplingState.from_params(
             [
                 (s.sampling if s is not None else SamplingParams())
@@ -3393,12 +3412,17 @@ class Engine:
                 )
             self._slot_count_overrides.clear()
         if self._pending_token_patches:
-            # deferred chunk-final first tokens: seed the fresh slot's
-            # last_token + histogram from the still-on-device handle —
-            # the rebuild above used the placeholder mirror (0)
-            for slot, tok in sorted(self._pending_token_patches.items()):
-                self._dstate = _patch_first_token(
-                    self._dstate, jnp.int32(slot), tok
+            # deferred first tokens: seed the fresh slots' last_token +
+            # histogram from the still-on-device handles — the rebuild
+            # above used the placeholder mirror (0); one call a handle
+            by_handle: dict = {}
+            for slot, (tok, row) in self._pending_token_patches.items():
+                src = by_handle.setdefault(
+                    id(tok), (tok, np.full((B,), -1, np.int32)))[1]
+                src[slot] = row
+            for tok, src in by_handle.values():
+                self._dstate = _patch_first_tokens(
+                    self._dstate, jnp.asarray(src), tok
                 )
             self._pending_token_patches.clear()
 
@@ -3448,26 +3472,26 @@ class Engine:
             # steps instead of n_max.
             cap = min(cap, 4)
         for i, req in enumerate(self.slots):
-            if req is None or not self._slot_active(i):
+            if not self._row_runs(i):
                 continue
-            # in-flight tokens (async pipeline / deferred chunk-final)
-            # count against budget and page room: the predicted dispatch
-            # must never overrun what the reconcile will reveal
-            pend = self._pending_out(req)
-            budget = (
-                req.sampling.max_tokens - len(req.output_tokens) - pend
-            )
-            room = (
-                (req.max_len or self.cache_cfg.max_seq_len)
-                - req.num_tokens - pend
-            )
-            cap = min(cap, budget, room)
+            # in-flight tokens (a step not reconciled yet, a deferred first
+            # token) count against budget and page room: the predicted
+            # dispatch must never overrun what the reconcile will reveal
+            cap = min(cap, self._headroom(req))
         if cap <= 1:
             return 1
         n = 1
         while n * 2 <= cap:
             n *= 2
         return n
+
+    def _headroom(self, req: Request) -> int:
+        """Tokens left of a row's ``max_tokens`` budget and of its page
+        room once the tokens in flight have landed."""
+        pend = self._pending_out(req)
+        budget = req.sampling.max_tokens - len(req.output_tokens)
+        room = (req.max_len or self.cache_cfg.max_seq_len) - req.num_tokens
+        return min(budget, room) - pend
 
     # ------------------------------------------------------------------
     # tiered KV residency: streamed cold-middle attention (ISSUE 20)
@@ -4472,7 +4496,7 @@ class Engine:
             if req is None or not self._slot_active(i):
                 continue
             if req.id in self._pending_first_ids:
-                # deferred chunk-final first token: the host-visible
+                # deferred first token: the host-visible
                 # sequence lags the device by one token, so a draft
                 # would condition on the wrong suffix — sit this call
                 # out (the verify would just reject it anyway)
@@ -4545,14 +4569,8 @@ class Engine:
         )
 
     def _spec_complete(self, p: PendingStep, emitted) -> None:
-        sampled, emit, extra = p.handles
-        firsts = tuple(tok for _r, tok in p.pending_first)
-        fetched = self._fetch((sampled, emit, extra) + firsts)
-        sampled_np, emit_np, extra_np = fetched[0], fetched[1], fetched[2]
-        if p.pending_first:
-            for (req, _h), tok_np in zip(p.pending_first, fetched[3:]):
-                self._finish_first_emit(req, int(tok_np[0]), emitted)
-            self._drain_moe_drops()   # the fetch above synced the device
+        sampled_np, emit_np, extra_np = self._fetch_with_firsts(
+            p.handles, p.pending_first, emitted)
         draft_len = p.draft_len
         for i, req in p.rows:
             if self.slots[i] is not req:
@@ -4590,17 +4608,16 @@ class Engine:
         # its last page instead of failing (ADVICE r3).  The window logic
         # above must make this impossible; verify it.
         table_cap = self.cache_cfg.max_pages_per_seq * self.cache_cfg.page_size
-        for i in range(len(self.slots)):
-            if self._slot_active(i) and self._positions[i] + n > table_cap:
+        rows = [
+            (i, r) for i, r in enumerate(self.slots) if self._row_runs(i)
+        ]
+        for i, _r in rows:
+            if self._positions[i] + n > table_cap:
                 raise RuntimeError(
                     f"decode window overruns page-table capacity: slot {i} "
                     f"at position {self._positions[i]} + {n} steps > "
                     f"{table_cap} — headroom invariant violated"
                 )
-        rows = [
-            (i, r) for i, r in enumerate(self.slots)
-            if r is not None and self._slot_active(i)
-        ]
         # plain decode IS the unified step with zero drafts: position 0
         # of each active row samples this step's token, and the fused
         # tail advances the remaining n-1 window steps in the same jit
@@ -4611,34 +4628,24 @@ class Engine:
         # Predicted-state advance: the DEVICE moves every dispatched row
         # forward by the full window whether or not the host later
         # discards an overrun, so the position mirror advances at
-        # dispatch — this is what lets the async loop build step N+1's
+        # dispatch — this is what lets the loop build step N+1's
         # metadata before step N's tokens are on host.  Completion only
         # fetches, emits and applies stop conditions.
         for i, r in rows:
             self._positions[i] += n
-            self._inflight_out[r.id] = self._inflight_out.get(r.id, 0) + n
+            self._charge(r, n)
         return PendingStep(
             kind="decode", rows=rows, handles=(sampled, extra), n=n,
             pending_first=self._take_pending_first(),
         )
 
     def _decode_complete(self, p: PendingStep, emitted) -> None:
-        sampled, extra = p.handles
-        firsts = tuple(tok for _r, tok in p.pending_first)
-        fetched = self._fetch((sampled, extra) + firsts)
-        sampled_np, extra_np = fetched[0], fetched[1]
-        if p.pending_first:
-            # deferred chunk-final first tokens land in the SAME host
-            # round trip as the decode window (ISSUE 13 satellite)
-            for (req, _h), tok_np in zip(p.pending_first, fetched[2:]):
-                self._finish_first_emit(req, int(tok_np[0]), emitted)
-            self._drain_moe_drops()   # the fetch above synced the device
+        # deferred first tokens land in the SAME host round trip as the
+        # decode window (ISSUE 13 satellite)
+        sampled_np, extra_np = self._fetch_with_firsts(
+            p.handles, p.pending_first, emitted)
         for _i, r in p.rows:
-            left = self._inflight_out.get(r.id, 0) - p.n
-            if left > 0:
-                self._inflight_out[r.id] = left
-            else:
-                self._inflight_out.pop(r.id, None)
+            self._uncharge(r, p.n)
         for i, r in p.rows:
             if self.slots[i] is not r or r.finished:
                 continue  # finished/evicted mid-flight: discard the overrun
@@ -4691,12 +4698,17 @@ class Engine:
             # np.asarray(table)), so mutations land in already-built
             # plans before finalize_device below reads them.
             self._tiered_prep(n_extra)
-        if self._state_dirty or self._dstate is None:
+        if draft_len is None:
+            draft_len = self._inert_rows
+        if (
+            self._state_dirty or self._dstate is None
+            # a row that the tokens in flight exhaust sits this step out
+            or (draft_len is not self._inert_rows and not np.array_equal(
+                self._running_mask(), self._active_sent))
+        ):
             self._sync_state()
         if drafts is None:
             drafts = self._zero_drafts
-        if draft_len is None:
-            draft_len = self._inert_rows
         pool_slots = (
             self.adapter_pool.slots if self.adapter_pool is not None
             else 0
@@ -4788,6 +4800,8 @@ class Engine:
                 "attn_layers": self.model_cfg.num_attn_layers}
                if self.model_cfg.num_conv_layers else {}),
         ):
+            if self.first_launch_time is None:
+                self.first_launch_time = time.monotonic()
             (self.cache, self._dstate, p_first, sampled, emit, extra,
              drops, *snaps) = fn(
                 self._graft_params(), self.cache, self._dstate, pargs,

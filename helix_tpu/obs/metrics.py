@@ -431,16 +431,24 @@ class EngineLoopObs:
             "Engine step wall time (host view, includes device sync)",
             buckets=FAST_BUCKETS,
         )
-        # async-loop time split (ISSUE 13): where each step's host
+        # the step's time split (ISSUE 13): where each step's host
         # milliseconds go — schedule/plan/dispatch vs token emission.
-        # Under the pipelined loop both phases overlap device execution;
-        # the flight recorder's idle_gap_s field (and the
-        # helix_device_idle_ratio gauge) shows whether they still leave
-        # the device waiting.
+        # While the loop runs a step ahead both overlap device execution;
+        # exposed_host below (the flight recorder's idle_gap_s, the
+        # helix_device_idle_ratio gauge) is what still leaves the device
+        # waiting.
         self.host_build = Histogram(
             "helix_step_host_build_seconds",
             "Host-side step build time (scheduling + plan packing + "
             "metadata upload + dispatch) per engine step",
+            buckets=FAST_BUCKETS,
+        )
+        self.exposed_host = Histogram(
+            "helix_step_exposed_host_seconds",
+            "Host time the device waited out per engine step: the last "
+            "completion's return to this step's first launch when nothing "
+            "was queued on the device, 0 for a step launched behind a "
+            "running one",
             buckets=FAST_BUCKETS,
         )
         self.emit_seconds = Histogram(
@@ -558,8 +566,8 @@ class EngineLoopObs:
     def collect(self, c: Collector, labels: Optional[dict] = None) -> None:
         for m in (
             self.queue_wait, self.ttft, self.inter_token,
-            self.step_seconds, self.host_build, self.emit_seconds,
-            self.emit_deliver, self.emit_queue_wait, self.emit_backpressure,
+            self.step_seconds, self.host_build, self.exposed_host,
+            self.emit_seconds, self.emit_deliver, self.emit_queue_wait, self.emit_backpressure,
             *self.step_phases.values(), *self.state_phases.values(),
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,

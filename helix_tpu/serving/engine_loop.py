@@ -293,20 +293,17 @@ class EngineLoop:
         # per-tenant inbox depth (admission lock); the per-tenant bound
         # adds the engine-side wait-queue count on demand
         self._pending_by_tenant: dict[str, int] = {}
-        # asynchronous pipelined loop (ISSUE 13): dispatch step N+1
-        # against predicted post-step state while step N executes (the
-        # bounded off-thread emission stage below is on in every started
-        # loop, pipelined or not).  Requires the
-        # dispatch/complete engine split.  Multihost leaders pipeline
-        # too: plan N+1 publishes at dispatch, so the broadcast rides
-        # the same overlap and followers apply it while device step N
-        # completes.
-        self.async_enabled = (
-            bool(getattr(
-                getattr(engine, "cfg", None), "enable_async_loop", False
-            ))
-            and hasattr(engine, "step_dispatch")
-        )
+        # the loop runs one step ahead (ISSUE 13, finished by ISSUE 33):
+        # while admission is blocked it dispatches step N+1 against
+        # predicted post-step state before it fetches step N, so the
+        # host's admit and dispatch run under the device's time.  Needs
+        # the engine's dispatch/complete split; the gate is what the loop
+        # observes each pass (``_run``), not a setting.  Multihost leaders
+        # look ahead too: plan N+1 publishes at dispatch, so the broadcast
+        # rides the same overlap.
+        self.async_enabled = hasattr(engine, "step_dispatch")
+        # the at-most-one dispatched-but-not-reconciled step
+        self._inflight = None
         self.pipelined_steps = 0    # steps dispatched while one was in flight
         self._emit_stage = _EmissionStage(self._deliver, self.obs)
         self._phases = obs_trace.Phases()   # the step in progress
@@ -992,11 +989,18 @@ class EngineLoop:
     # -- engine thread ------------------------------------------------------
 
     def _drain_inbox(self):
+        """Apply what arrived.  A plain submit only appends to the wait
+        queue, which no step in flight reads; an abort or an import
+        changes slots the in-flight prediction was built on, so the step
+        in flight is reconciled first (whether or not that succeeds,
+        nothing is in flight afterwards)."""
         while True:
             try:
                 item, on_event = self._inbox.get_nowait()
             except queue.Empty:
                 return
+            if isinstance(item, _ImportItem) or on_event is None:
+                self._reconcile_or_fail()
             if isinstance(item, _ImportItem):  # migrated-in snapshot
                 self._handle_import(item)
                 continue
@@ -1274,6 +1278,9 @@ class EngineLoop:
             and now - self._stall_since > self.preempt_stall_seconds
             and now - self._last_preempt_at > self.preempt_stall_seconds
         ):
+            # a swap-out reads the victim's sampler state and pages where
+            # its last step left them
+            self._reconcile_or_fail()
             victim = self.engine.preempt_for_pressure()
             if victim is not None:
                 self._last_preempt_at = now
@@ -1418,11 +1425,15 @@ class EngineLoop:
             self._emit_stage.push(self._snapshot_events(emitted))
         return span.seconds
 
-    def _observe_step(self, seconds: float, ph: obs_trace.Phases) -> None:
+    def _observe_step(self, seconds: float, ph: obs_trace.Phases,
+                      exposed: float = 0.0) -> None:
         """One observation a step of the step histogram and of every
         phase histogram (0 where the phase did not run), so the phase
-        means add up to the step's."""
+        means add up to the step's; and of the host time the device
+        waited out before this step's launch (0 for a step launched
+        behind a running one)."""
         self.obs.step_seconds.observe(seconds)
+        self.obs.exposed_host.observe(exposed)
         for name, hist in (*self.obs.step_phases.items(),
                            *self.obs.state_phases.items()):
             hist.observe(ph.get(name, 0.0))
@@ -1576,279 +1587,65 @@ class EngineLoop:
         # so sparse external scrapes can't understate a recent burst
         self._tps.rate(getattr(eng, "num_generated_tokens", 0))
 
-    def _run(self):
-        # the at-most-one dispatched-but-not-reconciled step (async
-        # pipeline, ISSUE 13); always None under the synchronous loop
-        inflight = None
+    def _complete(self, pend):
+        """One step's reconcile: the fetch + every host-visible effect,
+        stamping the device-busy watermark the exposed-host accounting
+        reads."""
+        emitted = self.engine.step_complete(pend)
+        self._device_busy_until = time.monotonic()
+        return emitted
 
-        def complete(pend):
-            """One step's reconcile: the fetch + every host-visible
-            effect, stamping the device-busy watermark the idle-gap
-            accounting reads."""
-            emitted = self.engine.step_complete(pend)
-            self._device_busy_until = time.monotonic()
-            return emitted
-
-        def reconcile_or_fail() -> bool:
-            """Reconcile point outside the main step path (inbox
-            arrivals, drain, idle, preemption): complete the in-flight
-            step and drain the emission stage.  False = the completion
-            failed and the failure ladder ran — restart the loop pass."""
-            nonlocal inflight
-            if inflight is None:
-                self._emit_stage.flush()
-                return True
-            pend, inflight = inflight, None
-            pre = self._flight_pre()
-            ph = self._new_phases()
-            t0 = time.monotonic()
-            try:
-                emitted = complete(pend)
-            except Exception as e:  # noqa: BLE001 — fail requests, not the loop
-                self.engine.discard_pending(pend)
-                self._handle_step_failure(e, time.monotonic() - t0, pre)
-                return False
-            dt_wait = time.monotonic() - t0
-            dt_emit = self._push_emit(emitted, ph)
-            # the step was dispatched by an earlier pass that skipped
-            # its record ("numbers land with its completion"): record
-            # it here or the burst's last step vanishes from the flight
-            # window (tokens, device wait, the idle-ratio denominator)
-            self._flight_record(
-                dt_wait, pre, generated=len(emitted),
-                timing={
-                    "host_build_s": 0.0,
-                    "device_wait_s": round(dt_wait, 6),
-                    "emit_s": round(dt_emit, 6),
-                    "idle_gap_s": 0.0,
-                    "wall_s": round(time.monotonic() - t0, 6),
-                    "pipelined": 1,
-                    "phases": _rounded(ph),
-                },
-            )
+    def _reconcile_or_fail(self) -> bool:
+        """Reconcile point outside the main step path (aborts, imports,
+        drain, idle, preemption): complete the in-flight step and drain
+        the emission stage.  False = the completion failed and the
+        failure ladder ran — restart the loop pass."""
+        if self._inflight is None:
             self._emit_stage.flush()
             return True
+        pend, self._inflight = self._inflight, None
+        pre = self._flight_pre()
+        ph = self._new_phases()
+        t0 = time.monotonic()
+        try:
+            emitted = self._complete(pend)
+        except Exception as e:  # noqa: BLE001 — fail requests, not the loop
+            self.engine.discard_pending(pend)
+            self._handle_step_failure(e, time.monotonic() - t0, pre)
+            return False
+        dt_wait = time.monotonic() - t0
+        dt_emit = self._push_emit(emitted, ph)
+        # the step's own pass recorded its dispatch only: its tokens and
+        # its device wait land here, or the burst's last step vanishes
+        # from the flight window
+        self._flight_record(
+            dt_wait, pre, generated=len(emitted),
+            timing={
+                "host_build_s": 0.0,
+                "device_wait_s": round(dt_wait, 6),
+                "emit_s": round(dt_emit, 6),
+                "idle_gap_s": 0.0,
+                "wall_s": round(time.monotonic() - t0, 6),
+                "pipelined": 1,
+                "phases": _rounded(ph),
+            },
+        )
+        self._emit_stage.flush()
+        return True
 
-        while not self._stop.is_set():
-            if inflight is not None and not self._inbox.empty():
-                # inbox items (submit/abort/import) mutate state the
-                # in-flight prediction did not see — reconcile first
-                if not reconcile_or_fail():
-                    continue
-            self._drain_inbox()
-            if self._draining:
-                if not reconcile_or_fail():
-                    continue
-                if not self.engine.has_work():
-                    break
-                if time.monotonic() > self._drain_deadline:
-                    # migrate instead of shed (ISSUE 11): with an
-                    # exporter wired, the drain ladder is
-                    # finish -> snapshot+ship -> shed — _fail_all only
-                    # sees what could not be exported
-                    shipped = self._export_survivors()
-                    if shipped:
-                        log.info(
-                            "engine '%s' exported %d request(s) at the "
-                            "drain deadline", self.name, shipped,
-                        )
-                    self._fail_all("drain deadline exceeded at shutdown")
-                    break
-            if time.monotonic() - self._last_reap > 10.0:
-                self._last_reap = time.monotonic()
-                reaped = self.engine.reap_stuck(self.max_queue_seconds)
-                if reaped:
-                    self._emit_stage.flush()
-                for req in reaped:
-                    cb = self._subscribers.pop(req.id, None)
-                    if cb:
-                        cb(
-                            TokenEvent(
-                                request_id=req.id, token_id=-1,
-                                finished=True, finish_reason="error",
-                                error="request timed out in queue",
-                            )
-                        )
-            self._memory_pressure_tick()
-            if self._handoff_work():
-                # disaggregated prefill export (ISSUE 14): the export
-                # gathers pages + syncs device sampler state, so the
-                # in-flight pipelined step (if any) reconciles first
-                if not reconcile_or_fail():
-                    continue
-                self._disagg_tick()
-            ctick = getattr(self.engine, "checkpoint_tick", None)
-            if ctick is not None and self.engine.checkpoint_due():
-                # leader-state checkpoint (ISSUE 17): capture is a pure
-                # host-side read of queue/digest bookkeeping (the blob
-                # write happens off-thread), but the snapshot must not
-                # straddle an in-flight pipelined step
-                if not reconcile_or_fail():
-                    continue
-                ctick(sched=self.sched)
-            if not self.engine.has_work():
-                if not reconcile_or_fail():
-                    continue
-                if self.engine.has_work():
-                    continue   # the reconcile freed/advanced work
-                with obs_trace.phase("helix.loop.idle"):
-                    self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            sched_ph = obs_trace.Phases()
-            if self._sched_active:
-                # scheduler pass (engine thread — the wait queue's
-                # owner): rewrite the queue into dispatch order (strict
-                # classes + per-tenant DRR) and refresh the per-step
-                # prefill-admission budget from the live TTFT burn.
-                # With a step in flight this still only touches the wait
-                # queue and burn-rate reads (the sched.reorder contract)
-                # — and a non-empty queue forces the reconcile below
-                # before the dispatch acts on the new order anyway.
-                with obs_trace.phase("helix.sched.reorder", into=sched_ph):
-                    self.sched.reorder(self.engine.waiting)
-                    self.engine.prefill_budget = (
-                        self.sched.prefill_budget(self.slo)
-                    )
-            # pipeline gate, decided BEFORE the dispatch: plain
-            # fused-decode steady state only — anything else (admission
-            # waves, chunked prefill, speculation, parked preemptions,
-            # dirty slot state, draining) reconciles first and runs the
-            # synchronous dispatch -> complete ordering
-            can_pipe = (
-                self.async_enabled
-                and not self._draining
-                and self.engine.pipeline_ready()
-            )
-            if inflight is not None and not can_pipe:
-                if not reconcile_or_fail():
-                    continue
-            ph = self._new_phases()
-            ph.update(sched_ph)
-            with obs_trace.phase("helix.loop.step", step_num=self.steps):
-                t_step = time.monotonic()
-                flight_pre = self._flight_pre()
-                overlapped = inflight is not None
-                try:
-                    emitted, pend = self._dispatch_once()
-                except Exception as e:  # noqa: BLE001 — fail requests, not the loop
-                    # the in-flight step is healthy already-dispatched work:
-                    # reconcile it first so its tokens are not lost — and
-                    # flight-record it (its fill pass skipped the record on
-                    # the promise the completion would land it)
-                    if inflight is not None:
-                        prev, inflight = inflight, None
-                        pre_prev = self._flight_pre()
-                        t0_prev = time.monotonic()
-                        try:
-                            prev_emitted = complete(prev)
-                        except Exception:  # noqa: BLE001 — poisoned chain
-                            self.engine.discard_pending(prev)
-                        else:
-                            self._emit_stage.push(
-                                self._snapshot_events(prev_emitted)
-                            )
-                            dt_prev = time.monotonic() - t0_prev
-                            self._flight_record(
-                                dt_prev, pre_prev,
-                                generated=len(prev_emitted),
-                                timing={
-                                    "host_build_s": 0.0,
-                                    "device_wait_s": round(dt_prev, 6),
-                                    "emit_s": 0.0,
-                                    "idle_gap_s": 0.0,
-                                    "wall_s": round(dt_prev, 6),
-                                    "pipelined": 1,
-                                },
-                            )
-                    self._handle_step_failure(
-                        e, time.monotonic() - t_step, flight_pre
-                    )
-                    continue
-                t_build_end = time.monotonic()
-                dt_build = t_build_end - t_step
-                idle_gap = 0.0
-                if not overlapped and self._device_busy_until:
-                    # nothing was in flight while this step's metadata was
-                    # built: the device sat idle from the last completion's
-                    # return until this dispatch landed
-                    idle_gap = max(
-                        0.0, t_build_end - self._device_busy_until
-                    )
-                prev, inflight = inflight, None
-                dt_wait = 0.0
-                try:
-                    if prev is not None:
-                        # step N+1 is now queued on the device: fetch step
-                        # N's results — the block covers only the device
-                        # time the host build did not already overlap
-                        t_w = time.monotonic()
-                        prev_emitted = complete(prev)
-                        prev = None
-                        dt_wait += time.monotonic() - t_w
-                        emitted = prev_emitted + emitted
-                    if pend is not None and can_pipe and pend.kind == "decode":
-                        inflight, pend = pend, None
-                        self.pipelined_steps += 1
-                    elif pend is not None:
-                        t_w = time.monotonic()
-                        if hasattr(self.engine, "prefetch_cold"):
-                            # stage the NEXT step's cold-middle KV chunks
-                            # while the dispatched step still runs on the
-                            # device — the gathers queue behind the step on
-                            # the device stream, so this is free overlap
-                            self.engine.prefetch_cold()
-                        self.engine.step_complete(pend, emitted)
-                        pend = None
-                        dt_wait += time.monotonic() - t_w
-                        self._device_busy_until = time.monotonic()
-                except Exception as e:  # noqa: BLE001 — fail requests, not the loop
-                    for p in (prev, pend):
-                        if p is not None:
-                            self.engine.discard_pending(p)
-                    inflight = None
-                    self._handle_step_failure(
-                        e, time.monotonic() - t_step, flight_pre
-                    )
-                    continue
-                dt_step = time.monotonic() - t_step
-                self.obs.host_build.observe(dt_build)
-                self._consec_failures = 0
-                self._barren_rounds = 0
-                self.steps += 1
-                if inflight is not None and not emitted:
-                    # pipeline-fill pass: dispatched with nothing reconciled
-                    # yet — no flight record (a dispatch-only pass would read
-                    # as zero_progress to the watchdog); the step's numbers
-                    # land with its completion next pass
-                    self._observe_step(dt_step, ph)
-                    continue
-                dt_emit = self._push_emit(emitted, ph)
-                self._deliver_resume_failures()
-                wall = time.monotonic() - t_step
-                self._observe_step(wall, ph)
-                self._flight_record(
-                    dt_step, flight_pre, generated=len(emitted),
-                    timing={
-                        "host_build_s": round(dt_build, 6),
-                        "device_wait_s": round(dt_wait, 6),
-                        "emit_s": round(dt_emit, 6),
-                        "idle_gap_s": round(idle_gap, 6),
-                        "wall_s": round(wall, 6),
-                        "pipelined": 1 if overlapped else 0,
-                        "phases": _rounded(ph),
-                    },
-                )
+    def _run(self):
+        while not self._stop.is_set() and self._pass():
+            pass
         # a step still in flight at shutdown: reconcile so its tokens
         # reach subscribers before the terminal sweep
-        if inflight is not None:
+        if self._inflight is not None:
+            pend, self._inflight = self._inflight, None
             try:
                 self._emit_stage.push(
-                    self._snapshot_events(complete(inflight))
+                    self._snapshot_events(self._complete(pend))
                 )
             except Exception:  # noqa: BLE001 — best-effort at shutdown
-                self.engine.discard_pending(inflight)
-            inflight = None
+                self.engine.discard_pending(pend)
         self._emit_stage.stop()
         log.info(
             "engine '%s' emission stage stopped: %d batch(es) delivered "
@@ -1872,6 +1669,237 @@ class EngineLoop:
                               "stopped",
                     )
                 )
+
+    def _pass(self) -> bool:
+        """One pass of the engine thread: apply the inbox, walk the
+        ladders that need a reconciled engine (drain, hand-off,
+        checkpoint, idle), then one engine step.  False: the drain is
+        over and the thread leaves."""
+        self._drain_inbox()
+        if self._draining:
+            if not self._reconcile_or_fail():
+                return True
+            if not self.engine.has_work():
+                return False
+            if time.monotonic() > self._drain_deadline:
+                # migrate instead of shed (ISSUE 11): with an
+                # exporter wired, the drain ladder is
+                # finish -> snapshot+ship -> shed — _fail_all only
+                # sees what could not be exported
+                shipped = self._export_survivors()
+                if shipped:
+                    log.info(
+                        "engine '%s' exported %d request(s) at the "
+                        "drain deadline", self.name, shipped,
+                    )
+                self._fail_all("drain deadline exceeded at shutdown")
+                return False
+        if time.monotonic() - self._last_reap > 10.0:
+            self._last_reap = time.monotonic()
+            reaped = self.engine.reap_stuck(self.max_queue_seconds)
+            if reaped:
+                self._emit_stage.flush()
+            for req in reaped:
+                cb = self._subscribers.pop(req.id, None)
+                if cb:
+                    cb(
+                        TokenEvent(
+                            request_id=req.id, token_id=-1,
+                            finished=True, finish_reason="error",
+                            error="request timed out in queue",
+                        )
+                    )
+        self._memory_pressure_tick()
+        if self._handoff_work():
+            # disaggregated prefill export (ISSUE 14): the export
+            # gathers pages + syncs device sampler state, so the
+            # step in flight (if any) reconciles first
+            if not self._reconcile_or_fail():
+                return True
+            self._disagg_tick()
+        ctick = getattr(self.engine, "checkpoint_tick", None)
+        if ctick is not None and self.engine.checkpoint_due():
+            # leader-state checkpoint (ISSUE 17): capture is a pure
+            # host-side read of queue/digest bookkeeping (the blob
+            # write happens off-thread), but the snapshot must not
+            # straddle a step in flight
+            if not self._reconcile_or_fail():
+                return True
+            ctick(sched=self.sched)
+        if not self.engine.has_work():
+            if not self._reconcile_or_fail():
+                return True
+            if self.engine.has_work():
+                return True  # the reconcile freed/advanced work
+            with obs_trace.phase("helix.loop.idle"):
+                self._wake.wait(timeout=0.05)
+            self._wake.clear()
+            return True
+        sched_ph = obs_trace.Phases()
+        if self._sched_active:
+            # scheduler pass (engine thread — the wait queue's
+            # owner): rewrite the queue into dispatch order (strict
+            # classes + per-tenant DRR) and refresh the per-step
+            # prefill-admission budget from the live TTFT burn.
+            # With a step in flight this only touches the wait
+            # queue and burn-rate reads (the sched.reorder contract).
+            with obs_trace.phase("helix.sched.reorder", into=sched_ph):
+                self.sched.reorder(self.engine.waiting)
+                self.engine.prefill_budget = (
+                    self.sched.prefill_budget(self.slo)
+                )
+        # may this pass's dispatch run on predicted state, behind a
+        # step still in flight?  The engine names what reconciles
+        # first (speculation, parked preemptions, tiered rows; for a
+        # plan leader anything but steady decode); draining does too
+        lookahead = (
+            self.async_enabled
+            and not self._draining
+            and self.engine.pipeline_ready()
+        )
+        if self._inflight is not None and not lookahead:
+            if not self._reconcile_or_fail():
+                return True
+        ph = self._new_phases()
+        ph.update(sched_ph)
+        with obs_trace.phase("helix.loop.step", step_num=self.steps):
+            self._step_pass(ph, lookahead)
+        return True
+
+    def _step_pass(self, ph: obs_trace.Phases, lookahead: bool) -> None:
+        """One engine step: admit and launch step N+1 (behind step N if
+        one is in flight), then fetch and reconcile step N, then either
+        leave N+1 in flight or complete it too.
+
+        N+1 stays in flight when ``lookahead`` holds and nothing more
+        could be admitted before it ends (``Engine.admission_blocked``:
+        requests still queue after this pass's admission, or no slot is
+        free): the next pass's host work then runs under its device time.
+        With the queue empty and a slot free it is completed at once — a
+        window queued ahead would stand between a new arrival and its
+        prefill — which is the synchronous order: the same code with
+        nothing in flight."""
+        eng = self.engine
+        t_step = time.monotonic()
+        flight_pre = self._flight_pre()
+        prev = self._inflight
+        overlapped = prev is not None
+        try:
+            emitted, pend = self._dispatch_once()
+        except Exception as e:  # noqa: BLE001 — fail requests, not the loop
+            self._inflight = None
+            # the in-flight step is healthy already-dispatched work:
+            # reconcile it first so its tokens are not lost — and
+            # flight-record it (its own pass recorded the dispatch only)
+            if prev is not None:
+                pre_prev = self._flight_pre()
+                t0_prev = time.monotonic()
+                try:
+                    prev_emitted = self._complete(prev)
+                except Exception:  # noqa: BLE001 — poisoned chain
+                    eng.discard_pending(prev)
+                else:
+                    self._emit_stage.push(
+                        self._snapshot_events(prev_emitted)
+                    )
+                    dt_prev = time.monotonic() - t0_prev
+                    self._flight_record(
+                        dt_prev, pre_prev,
+                        generated=len(prev_emitted),
+                        timing={
+                            "host_build_s": 0.0,
+                            "device_wait_s": round(dt_prev, 6),
+                            "emit_s": 0.0,
+                            "idle_gap_s": 0.0,
+                            "wall_s": round(dt_prev, 6),
+                            "pipelined": 1,
+                        },
+                    )
+            self._handle_step_failure(
+                e, time.monotonic() - t_step, flight_pre
+            )
+            return
+        self._inflight = None
+        t_build_end = time.monotonic()
+        dt_build = t_build_end - t_step
+        idle_gap = 0.0
+        if not overlapped and self._device_busy_until:
+            # nothing was queued on the device while this step was
+            # built: it sat idle from the last completion's return until
+            # this step's first launch (an admission wave's, or the step
+            # program's own)
+            launched = getattr(eng, "first_launch_time", None)
+            idle_gap = max(
+                0.0, (launched or t_build_end) - self._device_busy_until
+            )
+        dt_wait = 0.0
+        try:
+            if prev is not None:
+                # step N+1 is now queued on the device: fetch step N's
+                # results — the block covers only the device time the
+                # host build did not already overlap
+                t_w = time.monotonic()
+                prev_emitted = self._complete(prev)
+                prev = None
+                dt_wait += time.monotonic() - t_w
+                emitted = prev_emitted + emitted
+            if (
+                pend is not None
+                and lookahead
+                and pend.kind in ("decode", "mixed")
+                and eng.admission_blocked()
+            ):
+                self._inflight, pend = pend, None
+                self.pipelined_steps += 1
+            elif pend is not None:
+                t_w = time.monotonic()
+                if hasattr(eng, "prefetch_cold"):
+                    # stage the NEXT step's cold-middle KV chunks while
+                    # the dispatched step still runs on the device — the
+                    # gathers queue behind the step on the device
+                    # stream, so this is free overlap
+                    eng.prefetch_cold()
+                eng.step_complete(pend, emitted)
+                pend = None
+                dt_wait += time.monotonic() - t_w
+                self._device_busy_until = time.monotonic()
+        except Exception as e:  # noqa: BLE001 — fail requests, not the loop
+            for p in (prev, pend):
+                if p is not None:
+                    eng.discard_pending(p)
+            self._inflight = None
+            self._handle_step_failure(
+                e, time.monotonic() - t_step, flight_pre
+            )
+            return
+        dt_step = time.monotonic() - t_step
+        self.obs.host_build.observe(dt_build)
+        self._consec_failures = 0
+        self._barren_rounds = 0
+        self.steps += 1
+        dt_emit = self._push_emit(emitted, ph)
+        if self._inflight is not None and not emitted:
+            # fill pass: dispatched with nothing reconciled yet — no
+            # flight record (a dispatch-only pass would read as
+            # zero_progress to the watchdog); the step's numbers land
+            # with its completion next pass
+            self._observe_step(dt_step, ph, idle_gap)
+            return
+        self._deliver_resume_failures()
+        wall = time.monotonic() - t_step
+        self._observe_step(wall, ph, idle_gap)
+        self._flight_record(
+            dt_step, flight_pre, generated=len(emitted),
+            timing={
+                "host_build_s": round(dt_build, 6),
+                "device_wait_s": round(dt_wait, 6),
+                "emit_s": round(dt_emit, 6),
+                "idle_gap_s": round(idle_gap, 6),
+                "wall_s": round(wall, 6),
+                "pipelined": 1 if overlapped else 0,
+                "phases": _rounded(ph),
+            },
+        )
 
     # -- poisoned-request quarantine ----------------------------------------
 

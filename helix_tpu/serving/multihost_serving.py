@@ -1239,6 +1239,12 @@ class PlanLeader:
                 self._plan_content[step_idx] = (admits, resumes)
             return emitted, pend
 
+    def pipeline_ready(self) -> bool:
+        """A plan leader looks ahead in plain decode steady state only
+        (``Engine.steady_decode`` says why): every other pass reconciles
+        the step in flight before it dispatches."""
+        return self.engine.pipeline_ready() and self.engine.steady_decode()
+
     def step_complete(self, pend, emitted=None):
         base = len(emitted) if emitted is not None else 0
         out = self.engine.step_complete(pend, emitted)

@@ -1,6 +1,6 @@
 """Token delivery off the engine thread in every started loop (ISSUE 26).
 
-A started ``EngineLoop`` — synchronous, ``enable_async_loop`` false —
+A started ``EngineLoop`` —
 hands each step's tokens to the emission worker and never runs
 ``_deliver`` between a completion and the next launch.  (a) the stage
 alone: the worker delivers only while the engine thread is parked, a
@@ -54,7 +54,7 @@ def make_engine(tiny_parts, **extra):
     kw = dict(
         max_decode_batch=4, page_size=4, num_pages=128,
         max_pages_per_seq=32, max_prefill_len=8,
-        attn_backend="reference", enable_async_loop=False,
+        attn_backend="reference",
     )
     kw.update(extra)
     return Engine(cfg, params, EngineConfig(**kw))
@@ -411,7 +411,6 @@ def test_streams_bit_identical_to_the_inline_path(tiny_parts, kind):
         reqs_i, reqs_s = workload(kind), workload(kind)
         _, want = run_inline(tiny_parts, reqs_i)
         loop, got, *_ = run_started(tiny_parts, reqs_s)
-        assert not loop.async_enabled
         for reqs, cols in ((reqs_i, want), (reqs_s, got)):
             for req in reqs:
                 assert cols[req.id].tokens == req.output_tokens, req.id

@@ -396,7 +396,7 @@ class TestAllFeaturesLockstep:
             return Engine(cfg, params, EngineConfig(
                 max_decode_batch=2, page_size=4, num_pages=64,
                 max_pages_per_seq=16, max_prefill_len=16,
-                attn_backend="reference", enable_async_loop=True,
+                attn_backend="reference",
             ))
 
         leader = PlanLeader(make())
@@ -682,7 +682,6 @@ class TestSampleProfiles:
         # and the pair actually exercises the plan-broadcast features
         assert leader.engine.get("enable_spec_decode") is True
         assert leader.engine.get("adapter_pool_slots", 0) >= 2
-        assert leader.engine.get("enable_async_loop") is True
         assert leader.engine.get("host_pool_bytes", 0) > 0
 
 

@@ -177,12 +177,17 @@ def test_histogram_metric_finds_its_series(spine, spec):
 
 @pytest.fixture(scope="module")
 def stepped():
-    """Some twenty steps of a tiny engine under the synchronous loop:
-    three requests, one with a prompt of several chunks."""
+    """Some twenty steps of a tiny engine under a started loop: three
+    requests, one with a prompt of several chunks, then one that ends on
+    its first token (no decode step to carry that token: the one case
+    left where the loop waits for a prefill alone,
+    ``helix.loop.prefill_sync``)."""
     loop = EngineLoop(tiny_engine(), "phases").start()
     done = []
     try:
-        for i, n in enumerate((6, 40, 9)):
+        for i, n in enumerate((6, 40, 9, 5)):
+            if i == 3:
+                assert all(ev.wait(120) for ev in done)
             ev = threading.Event()
             done.append(ev)
 
@@ -192,7 +197,8 @@ def stepped():
 
             loop.submit(Request(
                 id=f"p{i}", prompt_tokens=list(range(4, 4 + n)),
-                sampling=SamplingParams(max_tokens=10, temperature=0.0),
+                sampling=SamplingParams(
+                    max_tokens=1 if i == 3 else 10, temperature=0.0),
             ), on_event)
         for ev in done:
             assert ev.wait(120)

@@ -1,0 +1,85 @@
+#!/usr/bin/env python3
+"""Run benchmark cells from two trees, one after another, in one chip
+call (a chip has one owner; a cache entry is found again by the next run
+of the same tree, so a side's second run starts warm).
+
+    chiprun --timeout 3600 -- python3 tools/bench_pairs.py --tag A \
+        change:qwen2-7b.saturated:3000003301:1 \
+        parent:qwen2-7b.saturated:3000003302:0 \
+        change:qwen2-7b.saturated:3000003302:0 ...
+
+Each run is ``<side>:<cell>:<seed>:<trace>``: ``benchmark/run.py`` from
+``.chip_parent/`` (``git archive <parent>`` with ``BENCHMARK.json``,
+``benchmark/`` and ``tests/benchmark/`` laid over it) or from
+``.chip_tree/final/`` (``git archive $(git write-tree)``: the files git
+would commit); both are git-ignored.  Every run's JSON lines go to
+``chiprun_out/pairs/<tag>.jsonl``, its server log and trace summary beside
+it, and one line a run (side, cell, seed, the metrics) to stdout.  A run is
+not started when ``--per-run`` seconds more would pass ``--budget``.
+"""
+import argparse
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TREES = {"parent": os.path.join(ROOT, ".chip_parent"),
+         "change": os.path.join(ROOT, ".chip_tree", "final")}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tag", required=True)
+    ap.add_argument("--budget", type=float, default=3300)
+    ap.add_argument("--per-run", type=float, default=600,
+                    help="seconds a run is expected to need at most")
+    ap.add_argument("runs", nargs="+")
+    a = ap.parse_args()
+    t0 = time.monotonic()
+    out_dir = os.path.join(ROOT, "chiprun_out", "pairs")
+    os.makedirs(out_dir, exist_ok=True)
+    out = os.path.join(out_dir, f"{a.tag}.jsonl")
+    for i, spec in enumerate(a.runs):
+        side, cell, seed, trace = spec.split(":")
+        if time.monotonic() - t0 + a.per_run > a.budget:
+            print(f"SKIPPED {spec}: budget", flush=True)
+            continue
+        tree = TREES[side]
+        t1 = time.monotonic()
+        r = subprocess.run(
+            [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",
+             seed, "--seconds", "45", "--trace", trace],
+            cwd=tree, capture_output=True, text=True)
+        wall = time.monotonic() - t1
+        lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
+        rec = {"side": side, "cell": cell, "seed": int(seed), "trace": int(trace),
+               "exit": r.returncode, "wall_s": round(wall, 1),
+               "lines": [json.loads(ln) for ln in lines],
+               "stderr": r.stderr[-2000:] if r.returncode else ""}
+        with open(out, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+        bo = os.path.join(tree, ".bench_out")
+        keep = os.path.join(out_dir, f"{a.tag}_{i}_{side}_{cell}")
+        os.makedirs(keep, exist_ok=True)
+        for name in os.listdir(bo) if os.path.isdir(bo) else []:
+            p = os.path.join(bo, name)
+            if os.path.isfile(p) and os.path.getsize(p) < 8 << 20 and (
+                    name.endswith(".log") or name.endswith(".json")):
+                shutil.copy(p, keep)
+        last = rec["lines"][-1] if rec["lines"] else {}
+        print(json.dumps({"side": side, "cell": cell, "seed": seed,
+                          "trace": trace, "exit": r.returncode,
+                          "wall_s": round(wall, 1),
+                          "correct": last.get("correct"),
+                          "failed": last.get("failed"),
+                          "metrics": {k: (v.get("value") if isinstance(v, dict) else v)
+                                      for k, v in (last.get("metrics") or {}).items()}}),
+              flush=True)
+    print("elapsed", round(time.monotonic() - t0, 1), flush=True)
+
+
+if __name__ == "__main__":
+    main()
