@@ -722,6 +722,10 @@ _RECURRENT = ("recurrent state (gated short convolutions)",
               lambda m: m.num_conv_layers > 0)
 _PACKED_HEADS = ("kv heads packed into one lane tile (head width under "
                  "128)", lambda m: m.kv_head_pack > 1)
+_PREFIX_CACHE = ("enable_prefix_cache",
+                 lambda cfg, mesh: cfg.enable_prefix_cache)
+_MATRIX_STATE = ("a matrix state (power retention)",
+                 lambda m: m.num_retention_layers > 0)
 _REFUSALS = (
     (_MULTI_DEVICE, _LATENT,
      "the latent pool and its kernel are single-device: mesh {tp: 1}"),
@@ -742,6 +746,20 @@ _REFUSALS = (
      "a spilled prefix or a preempted sequence's pages come back "
      "without the state"),
     (_INT8_KV, _PACKED_HEADS, "an int8 pool's scales are one a kv head"),
+    (_MULTI_DEVICE, _MATRIX_STATE,
+     "the state pool and the retention kernel are single-device"),
+    (_INT8_KV, _MATRIX_STATE,
+     "no page holds bytes, and the state is a float32 running sum"),
+    (_ADAPTERS, _MATRIX_STATE, "no LoRA targets on the retention "
+     "projections"),
+    (_SPEC, _MATRIX_STATE,
+     "a rejected draft would have to roll the matrix state back"),
+    (_TIERED, _MATRIX_STATE, "there is no page of KV to demote"),
+    (_HOST_TIER, _MATRIX_STATE,
+     "a preempted sequence's state (tens of MB a layer) has no host tier"),
+    (_PREFIX_CACHE, _MATRIX_STATE,
+     "a filed state is tens of MB a layer: a snapshot budget and an "
+     "eviction of its own; set enable_prefix_cache: false"),
 )
 
 
@@ -764,6 +782,12 @@ def _refuse_call(model_cfg, what: str) -> None:
             f"{model_cfg.name}: recurrent state (gated short "
             f"convolutions) is not served with {what} (the sequence's "
             "conv state has no place in what it moves)"
+        )
+    if model_cfg.num_retention_layers:
+        raise UnsupportedForModel(
+            f"{model_cfg.name}: a matrix state (power retention) is not "
+            f"served with {what} (the sequence's state has no place in "
+            "what it moves, and it has no page of KV)"
         )
 
 
@@ -859,6 +883,51 @@ def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
     return conv_fn
 
 
+def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
+    """The ``retention_fn`` of one segment of the step (``models/llama.py::
+    _retention_mixer``), under ``_conv_rows_fn``'s contract for a state
+    thousands of times the size: row ``r`` is the ``qlen[r]`` tokens from
+    ``t0[r]`` of the sequence in slot ``slots[r]`` with ``hist[r]`` tokens
+    behind it; a row that starts its sequence starts from zeros; a row with
+    no fresh token (an idle slot, padding) and a row without a slot write
+    nothing.  The pools ride the carry and are updated IN PLACE.
+
+    ``decode``: the segment's rows are one token each and row ``b`` is slot
+    ``b`` (the recurrence applied once: on a TPU one pass of the decode
+    kernel over the live slots).  Else the rows are runs of fresh tokens on
+    one flat axis (the chunked form, a row at a time).
+
+    The carry it is called with is ``((page carry, kacc, vacc, (S pool, Z
+    pool)), retention layer index)``."""
+    from helix_tpu.ops.retention import retention_decode, retention_rows
+
+    def retention_fn(q, k, v, log_g, carry_cache):
+        (caches, kacc, vacc, (s_pool, z_pool)), lc = carry_cache
+        Bq, Sq, H, D = q.shape
+        if decode:
+            y, s_pool, z_pool = retention_decode(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], s_pool, z_pool, lc,
+                qlen > 0, backend=backend)
+        else:
+            y, s_pool, z_pool = retention_rows(
+                q.reshape(Bq * Sq, H, D), k.reshape(Bq * Sq, -1, D),
+                v.reshape(Bq * Sq, -1, D), log_g.reshape(Bq * Sq, -1),
+                t0, qlen, hist, slots, s_pool, z_pool, lc)
+        return y.reshape(Bq, Sq, H, D), (caches, kacc, vacc,
+                                         (s_pool, z_pool))
+
+    return retention_fn
+
+
+def _state_rows_fns(cfg, t0, qlen, hist, slots, backend, decode,
+                    snap=None) -> dict:
+    """``forward``'s look-back argument for the model's recurrent mixer."""
+    if cfg.state_mixer == "retention":
+        return {"retention_fn": _retention_rows_fn(
+            t0, qlen, hist, slots, backend, decode)}
+    return {"conv_fn": _conv_rows_fn(t0, qlen, hist, slots, snap)}
+
+
 def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
                           p_tables, mesh, page_size, hist_pages):
     """Sequence-parallel chunk-vs-history attention over the ICI ring
@@ -918,11 +987,11 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
                      *rest)
 
     carry0 = (cache.carry(), kacc0, vacc0)
-    conv_fn = None
+    state_fns = {}
     if cache.state is not None:
-        # the slots' conv states ride the carry beside the pages
+        # the slots' recurrent states ride the carry beside the pages
         carry0 += (cache.state,)
-        conv_fn = _conv_rows_fn(t0, q_len, hist, t0)
+        state_fns = _state_rows_fns(cfg, t0, q_len, hist, t0, backend, True)
     if cfg.mrope_sections is not None:
         from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -951,7 +1020,7 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
             adapter_ids=(
                 state.adapter_slots[:, None] if use_adapters else None
             ),
-            conv_fn=conv_fn,
+            **state_fns,
         )
         if pool:
             pc = pc + (pool[0],)
@@ -1061,9 +1130,12 @@ def _build_ragged_step_fn(
     # never retrace)
     use_adapters = adapter_slots > 0
     cfg = model_cfg
-    # conv layers: every row reads and writes its slot's state, and the
-    # prefill rows hand back the state at one page boundary each
-    has_state = cfg.num_conv_layers > 0
+    # recurrent layers: every row reads and writes its slot's state; a
+    # conv model's prefill rows also hand back the state at one page
+    # boundary each (what a prefix hit resumes from; a matrix state is not
+    # filed: the prefix cache is refused for it)
+    has_state = cfg.state_mixer is not None
+    has_snaps = cfg.num_conv_layers > 0
     is_moe = cfg.num_experts > 0
     is_mrope = cfg.mrope_sections is not None
     Cb = token_bucket
@@ -1117,13 +1189,17 @@ def _build_ragged_step_fn(
                     p_aids = None
                 kacc0, vacc0 = _fresh_kv_zeros(cfg, 1, Cb)
                 p_carry = (cache.carry(), kacc0, vacc0)
-                p_conv = None
+                p_state_fns = {}
                 if has_state:
-                    p_carry += (cache.state, jnp.zeros(
-                        (cfg.num_conv_layers, prefill_rows)
-                        + cfg.conv_state_shape, cache.state.dtype))
-                    p_conv = _conv_rows_fn(
-                        p_t0, p_qlen, p_hist, p_slots, p_snap)
+                    p_carry += (cache.state,)
+                    if has_snaps:
+                        (shp, _), = cfg.state_arrays()
+                        p_carry += (jnp.zeros(
+                            (cfg.num_conv_layers, prefill_rows) + shp,
+                            cache.state.dtype),)
+                    p_state_fns = _state_rows_fns(
+                        cfg, p_t0, p_qlen, p_hist, p_slots, backend, False,
+                        p_snap)
 
                 def p_attn(q, k, v, carry_cache, pos):
                     (caches, kacc, vacc, *rest), lyr = carry_cache
@@ -1164,7 +1240,7 @@ def _build_ragged_step_fn(
                     moe_backend=backend,
                     return_moe_stats=is_moe,
                     adapter_ids=p_aids,
-                    conv_fn=p_conv,
+                    **p_state_fns,
                 )
                 if is_moe:
                     logits_p, (pc, kacc, vacc, *rest), moe_stats = res
@@ -1172,7 +1248,8 @@ def _build_ragged_step_fn(
                 else:
                     logits_p, (pc, kacc, vacc, *rest) = res
                 if has_state:
-                    pool, snaps = rest
+                    pool, *snap_out = rest
+                    snaps = snap_out[0] if snap_out else None
                     cache = PagedKVCache.from_carry(pc, pool)
                 else:
                     cache = PagedKVCache.from_carry(pc)
@@ -1216,14 +1293,14 @@ def _build_ragged_step_fn(
                              vacc.at[lyr].set(v), *rest)
 
             carry0 = (cache.carry(), kacc0s, vacc0s)
-            s_conv = None
+            s_state_fns = {}
             if has_state:
                 # W is 1 here (speculation is refused beside a state pool):
                 # a live slot is a one-token row over its own state
                 carry0 += (cache.state,)
-                s_conv = _conv_rows_fn(
-                    s_t0, live[:, 0].astype(jnp.int32), s_hist,
-                    jnp.arange(B, dtype=jnp.int32))
+                s_state_fns = _state_rows_fns(
+                    cfg, s_t0, live[:, 0].astype(jnp.int32), s_hist,
+                    jnp.arange(B, dtype=jnp.int32), backend, True)
             if is_mrope:
                 from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -1251,7 +1328,7 @@ def _build_ragged_step_fn(
                         )
                         if use_adapters else None
                     ),
-                    conv_fn=s_conv,
+                    **s_state_fns,
                 )
                 logits_s, (pc2, kaccs, vaccs, *rest) = res[:2]
                 if rest:
@@ -1420,6 +1497,15 @@ class Engine:
                 model_cfg.qk_rope_head_dim,
                 jnp.dtype(self.cache_cfg.dtype).itemsize,
             )
+        elif self._backend == "pallas" and not model_cfg.num_attn_layers:
+            if model_cfg.num_retention_layers:
+                from helix_tpu.ops.retention_kernel import (
+                    check_retention_geometry,
+                )
+
+                check_retention_geometry(
+                    model_cfg.num_heads, model_cfg.num_kv_heads,
+                    model_cfg.head_dim)
         elif self._backend == "pallas":
             from helix_tpu.ops.paged_kernel import check_geometry
 
@@ -1470,6 +1556,11 @@ class Engine:
         self._boundary_states: dict[str, dict] = {}
         self.num_state_snapshots = 0
         self.num_state_restores = 0
+        # a matrix state (power retention): rows of the state pool the
+        # steps read and wrote, by the form that ran them, and their bytes
+        # (what a roofline reckoned from a trace divides by)
+        self.num_retention_rows = {"decode": 0, "chunk": 0}
+        self.state_bytes_touched = 0
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -1763,6 +1854,20 @@ class Engine:
         if store is not None:
             _refuse_call(self.model_cfg, "the persistent KV filestore")
         self._kv_filestore = store
+
+    def _note_retention_rows(self, plan, draft_len, n_extra) -> None:
+        """Count the rows of the state pool this step reads and writes: a
+        live decode row once a fused step, a prefill row with a slot once;
+        each row is every retention layer's state of one slot, read once and
+        written once."""
+        live = (np.asarray(draft_len) >= 0) & (
+            np.asarray(self._active_sent) > 0)
+        dec = int(np.count_nonzero(live)) * (1 + int(n_extra))
+        chunk = sum(1 for r in plan.rows if r.slot >= 0) if plan else 0
+        self.num_retention_rows["decode"] += dec
+        self.num_retention_rows["chunk"] += chunk
+        self.state_bytes_touched += 2 * (dec + chunk) * (
+            self.recurrent_state_bytes // self.cfg.max_decode_batch)
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -4784,6 +4889,9 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
+        if self.model_cfg.num_retention_layers:
+            self._note_retention_rows(
+                plan if rows else None, draft_len, n_extra)
         used = plan.used if rows else 0
         with obs_trace.phase(
             "helix.loop.launch", kind=kind, token_bucket=rung,
@@ -4799,6 +4907,9 @@ class Engine:
             **({"conv_layers": self.model_cfg.num_conv_layers,
                 "attn_layers": self.model_cfg.num_attn_layers}
                if self.model_cfg.num_conv_layers else {}),
+            **({"retention_layers": self.model_cfg.num_retention_layers,
+                "attn_layers": self.model_cfg.num_attn_layers}
+               if self.model_cfg.num_retention_layers else {}),
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

@@ -37,6 +37,17 @@ and returned by the step with it, counted in ``CacheConfig.total_bytes`` and
 ``fit_hbm``.  A prefix is then pages AND a state: ``PrefixCache`` files the
 state a step returns for a page boundary under that boundary's chain digest
 and matches only up to a boundary that has one.
+
+The pool's shape and dtype follow the KIND of the recurrent mixer
+(``ModelConfig.state_arrays``).  A power-retention layer keeps a float32
+matrix a kv head, ``S [layers, slots, kv heads, D_held, head_dim]`` and its
+normaliser ``Z [layers, slots, kv heads, head_dim, head_dim]``:
+``PagedKVCache.state`` is then the pair (34 MB a layer and a slot at width
+128, thousands of times a conv state: updated in place, never copied).  A
+model with NO attention layer has a page pool of no bytes: pages are then
+only the bookkeeping of tokens a sequence (admission against ``num_pages``,
+``max_pages_per_seq``, ``kv_pages_used``), and ``fit_hbm`` sizes SLOTS
+against the budget, not pages.
 """
 
 from __future__ import annotations
@@ -74,18 +85,22 @@ class CacheConfig:
     def quantized(self) -> bool:
         return self.dtype == "int8"
 
+    def state_shapes(self, model: ModelConfig) -> tuple:
+        """``((shape, dtype), ...)`` of the state pool's arrays, by the kind
+        of the model's recurrent mixer; empty for a model without one."""
+        return tuple(
+            ((model.num_state_layers, self.state_slots) + tuple(shp), dt)
+            for shp, dt in model.state_arrays())
+
     def state_shape(self, model: ModelConfig) -> Optional[tuple]:
-        """The state pool's shape, ``None`` for a model without one."""
-        if not model.num_conv_layers:
-            return None
-        return (model.num_conv_layers, self.state_slots,
-                ) + model.conv_state_shape
+        """The state pool's (first array's) shape, ``None`` for a model
+        without one."""
+        shapes = self.state_shapes(model)
+        return shapes[0][0] if shapes else None
 
     def state_bytes(self, model: ModelConfig) -> int:
-        shp = self.state_shape(model)
-        if shp is None:
-            return 0
-        return int(np.prod(shp)) * jnp.dtype(model.dtype).itemsize
+        return sum(int(np.prod(shp)) * jnp.dtype(dt).itemsize
+                   for shp, dt in self.state_shapes(model))
 
     @property
     def max_seq_len(self) -> int:
@@ -154,6 +169,15 @@ class CacheConfig:
                     max_pages_per_seq=max_pages_per_seq, dtype=dtype,
                     state_slots=state_slots)
         per_page = probe.page_bytes(model)
+        if per_page == 0:
+            # no layer has pages: the budget buys SLOTS of state, and the
+            # page table is bookkeeping (room for every slot's longest
+            # sequence, and the garbage page)
+            per_slot = probe.state_bytes(model) // max(state_slots, 1)
+            slots = min(state_slots, hbm_budget_bytes // max(per_slot, 1))
+            return dataclasses.replace(
+                probe, state_slots=int(slots),
+                num_pages=int(slots) * max_pages_per_seq + 1)
         # the state pool comes out of the budget first: its size follows
         # the slots, not the pages
         left = hbm_budget_bytes - probe.state_bytes(model)
@@ -178,9 +202,11 @@ class PagedKVCache:
     v_pages: jax.Array  # same shape; latent pools: rope key [L, N, P, 128]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
-    # the state pool [conv layers, slots, K - 1, E] of a model with gated
-    # short convolutions, in the model's dtype; None for every other model
-    state: Optional[jax.Array] = None
+    # the state pool: ``[conv layers, slots, K - 1, E]`` in the model's
+    # dtype for gated short convolutions; the pair ``(S, Z)`` in float32 for
+    # power retention (``CacheConfig.state_shapes``); None for a model whose
+    # memory is pages alone
+    state: Optional[object] = None
 
     @classmethod
     def create(
@@ -191,7 +217,7 @@ class PagedKVCache:
     ) -> "PagedKVCache":
         if model.is_mla:
             return cls._create_latent(model, cache, mesh)
-        if model.num_conv_layers:
+        if model.state_mixer:
             return cls._create_with_state(model, cache, mesh)
         shape = (
             model.num_layers,
@@ -265,12 +291,13 @@ class PagedKVCache:
 
     @classmethod
     def _create_with_state(cls, model, cache, mesh) -> "PagedKVCache":
-        """Pages for the attention layers and a state pool for the conv
-        layers."""
+        """Pages for the attention layers (no bytes where there are none)
+        and a state pool for the recurrent ones."""
+        arrays = tuple(jnp.zeros(shp, jnp.dtype(dt))
+                       for shp, dt in cache.state_shapes(model))
         return cls._create_on_one_device(
             model, cache, mesh, "a page pool beside a state pool",
-            state=jnp.zeros(cache.state_shape(model),
-                            jnp.dtype(model.dtype)))
+            state=arrays[0] if len(arrays) == 1 else arrays)
 
     @classmethod
     def _create_on_one_device(cls, model, cache, mesh, what,

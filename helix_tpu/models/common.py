@@ -7,9 +7,10 @@ behaviour is expressed as data (activation, norm offsets, qk-norm, soft
 caps), not subclasses, so one compiled forward function serves them all.
 
 A model's depth is a sequence of RUNS of one kind of layer
-(``ModelConfig.layer_runs``): a kind is a token mixer (attention, or a
-gated short convolution with a fixed per-sequence state) times an FFN
-(dense, or routed experts).  A dense decoder is one run; DeepSeek-V2 is
+(``ModelConfig.layer_runs``): a kind is a token mixer (attention, a gated
+short convolution with a fixed per-sequence state, or power retention,
+whose per-sequence state is a matrix a kv head) times an FFN (dense, or
+routed experts).  A dense decoder is one run; DeepSeek-V2 is
 two (the leading dense layers, then the expert layers); LFM2 interleaves
 three kinds in thirteen, of which the runs that repeat back to back (the
 period "attention, three convolutions" four times, "attention, two
@@ -28,7 +29,7 @@ from typing import Any, Optional
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
     key: str        # the run's stack in the parameter tree
-    mixer: str      # "attn" | "conv"
+    mixer: str      # "attn" | "conv" | "retention"
     moe: bool       # routed experts (else a dense FFN)
     count: int      # layers a repetition
     first: int      # its first layer among its mixer's layers, repetition 0
@@ -91,11 +92,15 @@ class ModelConfig:
     moe_scoring: str = "softmax"
     moe_expert_bias: bool = False
     # --- interleaved token mixers (LFM2); None = attention at every layer ---
-    # one of "attn" | "conv" a layer.  A "conv" layer is a gated short
-    # convolution of ``conv_kernel`` taps whose whole state is the last
-    # ``conv_kernel - 1`` inputs of the sequence: it has no pages
+    # one of "attn" | "conv" | "retention" a layer.  A "conv" layer is a
+    # gated short convolution of ``conv_kernel`` taps whose whole state is
+    # the last ``conv_kernel - 1`` inputs of the sequence: it has no pages
     layer_types: Optional[tuple] = None
     conv_kernel: int = 0
+    # a "retention" layer is power retention of this degree over GQA-shaped
+    # q/k/v with a gate a kv head (``ops/retention.py``): its whole state is
+    # a matrix a kv head, whatever the sequence's length: no pages either
+    retention_degree: int = 2
     # --- multi-head latent attention (MLA); 0 = plain multi-head ---
     kv_lora_rank: int = 0               # compressed KV width, cached
     qk_nope_head_dim: int = 0
@@ -110,7 +115,8 @@ class ModelConfig:
 
     @property
     def mixers(self) -> tuple:
-        """The token mixer of every layer, ``"attn"`` or ``"conv"``."""
+        """The token mixer of every layer: ``"attn"``, ``"conv"`` or
+        ``"retention"``."""
         return self.layer_types or ("attn",) * self.num_layers
 
     @property
@@ -120,15 +126,52 @@ class ModelConfig:
 
     @property
     def num_conv_layers(self) -> int:
-        """Layers with a fixed per-sequence state: what the state pool's
-        layer axis counts."""
         return self.mixers.count("conv")
 
     @property
-    def conv_state_shape(self) -> tuple:
-        """One sequence's state in one conv layer: its last
-        ``conv_kernel - 1`` gated inputs, oldest first."""
-        return (self.conv_kernel - 1, self.hidden_size)
+    def num_retention_layers(self) -> int:
+        return self.mixers.count("retention")
+
+    @property
+    def state_mixer(self) -> Optional[str]:
+        """The mixer that keeps a fixed per-sequence state, ``"conv"`` or
+        ``"retention"`` (one kind a model: the state pool has one shape),
+        ``None`` for a model whose memory is pages alone."""
+        kinds = [m for m in ("conv", "retention") if m in self.mixers]
+        if len(kinds) > 1:
+            raise ValueError(
+                f"{self.name}: layers of {kinds} in one model: the state "
+                "pool holds one kind of recurrent state")
+        return kinds[0] if kinds else None
+
+    @property
+    def num_state_layers(self) -> int:
+        """Layers with a fixed per-sequence state: what the state pool's
+        layer axis counts."""
+        kind = self.state_mixer
+        return self.mixers.count(kind) if kind else 0
+
+    def state_arrays(self) -> tuple:
+        """``((shape, dtype), ...)``: one sequence's state in one layer, by
+        the mixer's kind.  A conv layer: its last ``conv_kernel - 1`` gated
+        inputs, oldest first, in the model's dtype.  A retention layer: the
+        matrix ``S [kv heads, D_held, head_dim]`` and the normaliser ``Z
+        [kv heads, head_dim, head_dim]``, float32 (running sums over the
+        whole context)."""
+        kind = self.state_mixer
+        if kind == "conv":
+            return (((self.conv_kernel - 1, self.hidden_size), self.dtype),)
+        if kind == "retention":
+            from helix_tpu.ops.retention import held_rows
+
+            if self.retention_degree != 2:
+                raise ValueError(
+                    f"{self.name}: power retention of degree "
+                    f"{self.retention_degree} is not supported: only 2")
+            d, kvh = self.head_dim, self.num_kv_heads
+            return (((kvh, held_rows(d), d), "float32"),
+                    ((kvh, d, d), "float32"))
+        return ()
 
     @property
     def kv_head_pack(self) -> int:
@@ -172,7 +215,7 @@ class ModelConfig:
             return "layers" if (moe or not self.num_experts) else (
                 "dense_layers")
 
-        groups, seen, i = [], {"attn": 0, "conv": 0}, 0
+        groups, seen, i = [], {"attn": 0, "conv": 0, "retention": 0}, 0
         while i < len(flat):
             # the period starting here that repeats over the most runs
             p, reps = 1, 1
@@ -266,6 +309,11 @@ class ModelConfig:
                 qk_rope_head_dim=hf["qk_rope_head_dim"],
                 v_head_dim=hf["v_head_dim"],
             )
+        if model_type == "brumby":
+            # every layer is power retention over the Qwen3 block's q/k/v
+            # (per-head q/k norms, rope); the config carries no key of the
+            # mixer, so the degree is the release's
+            family["layer_types"] = ("retention",) * hf["num_hidden_layers"]
         if model_type == "lfm2_moe":
             if hf.get("conv_bias"):
                 raise ValueError(
@@ -311,7 +359,7 @@ class ModelConfig:
             attention_bias=hf.get("attention_bias", False)
             or model_type == "qwen2",
             mlp_bias=hf.get("mlp_bias", False),
-            qk_norm=model_type in ("qwen3", "lfm2_moe"),
+            qk_norm=model_type in ("qwen3", "lfm2_moe", "brumby"),
             max_position_embeddings=hf.get("max_position_embeddings", 8192),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
@@ -465,8 +513,30 @@ LFM2_8B_A1B = ModelConfig(
     name="LiquidAI/LFM2-8B-A1B",
 )
 
+# Brumby-14B-Base (https://huggingface.co/manifestai/Brumby-14B-Base/blob/
+# main/config.json): the Qwen3-14B block with every attention layer replaced
+# by power retention of degree 2: no page of KV anywhere, a float32 matrix
+# state a kv head, layer and slot in the state pool.  What would move or
+# share that state is refused at engine start (engine.py's table).
+BRUMBY_14B = ModelConfig(
+    vocab_size=151936,
+    hidden_size=5120,
+    num_layers=40,
+    num_heads=40,
+    num_kv_heads=8,
+    head_dim=128,
+    intermediate_size=17408,
+    rope_theta=1000000.0,
+    rms_norm_eps=1e-6,
+    qk_norm=True,
+    max_position_embeddings=32768,
+    layer_types=("retention",) * 40,
+    retention_degree=2,
+    name="manifestai/Brumby-14B-Base",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
-              DEEPSEEK_V2_LITE, LFM2_8B_A1B)
+              DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B)
 }

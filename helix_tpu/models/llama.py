@@ -6,8 +6,9 @@ serves through vLLM containers (``design/sample-profiles/``), here as owned
 TPU-first code:
 
 - Layers are **stacked** (every weight has a leading layer dim) in RUNS of
-  one kind (``ModelConfig.layer_runs``: a token mixer, attention or gated
-  short convolution, times an FFN, dense or routed experts), one stack in
+  one kind (``ModelConfig.layer_runs``: a token mixer, attention, gated
+  short convolution or power retention, times an FFN, dense or routed
+  experts), one stack in
   the parameter tree and one ``lax.scan`` a run: a dense decoder is one
   run, DeepSeek-V2 two (``dense_layers`` then ``layers``), LFM2 thirteen,
   of which those that repeat back to back are one GROUP (a stack a run of
@@ -15,6 +16,8 @@ TPU-first code:
   (``run01``, ``run02``), twice (``run09``, ``run10``).
 - A conv layer's look-back (``conv_fn``) is injected as attention is: the
   engine reads a row's taps from its flat neighbours or its slot's state.
+  So is a retention layer's (``retention_fn``): the engine runs a row's
+  fresh tokens against its slot's matrix state and advances it.
 - Three routers, by ``ModelConfig`` (``models/moe.py::route``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
@@ -189,6 +192,16 @@ def init_params(
             lp["wk"] = w((E, KVH * D), "wk")
             lp["wv"] = w((E, KVH * D), "wv")
             lp["wo"] = w((H * D, E), "wo")
+        if mixer == "retention":
+            # a gate a kv head, ``sigmoid(W_g n + b_g)``.  The bias is drawn
+            # uniform in [3, 7]: gates of 0.95-0.999, a state that remembers
+            # hundreds to thousands of tokens.  At a bias of 0 it forgets in
+            # two, and no parity check would see a state carried wrongly
+            lp["g_proj"] = w((E, KVH), "g_proj")
+            lp["g_bias"] = {"bias": jax.random.uniform(
+                jax.random.fold_in(key, 4000 + zlib.crc32(
+                    "/".join(at).encode()) % 1000),
+                (n, KVH), jnp.float32, 3.0, 7.0)}
         if moe:
             # router + expert-stacked SwiGLU replaces the dense FFN
             # (models/moe.py); Mixtral's experts are as wide as the FFN
@@ -222,7 +235,7 @@ def init_params(
             for nm, width in (("wq", H * D), ("wk", KVH * D),
                               ("wv", KVH * D)):
                 lp[nm]["bias"] = jnp.zeros((n, width), dtype)
-        if cfg.qk_norm and mixer == "attn":
+        if cfg.qk_norm and mixer in ("attn", "retention"):
             lp["q_norm"] = {"weight": jnp.ones((n, D), dtype)}
             lp["k_norm"] = {"weight": jnp.ones((n, D), dtype)}
         return lp
@@ -276,9 +289,13 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             "attn_norm": {"weight": ("layers", None)},
             "mlp_norm": {"weight": ("layers", None)},
         }
-        if mixer == "attn":
+        if mixer in ("attn", "retention"):
             lax_["wq"] = {"weight": ("layers", "embed", "heads")}
             lax_["wo"] = {"weight": ("layers", "heads", "embed")}
+        if mixer == "retention":
+            # (a mesh is refused for it: the state pool is one device's)
+            lax_["g_proj"] = {"weight": ("layers", "embed", None)}
+            lax_["g_bias"] = {"bias": ("layers", None)}
         if mixer == "conv":
             # the gated convolution is depthwise over the hidden axis: its
             # projections are replicated (a mesh is refused for it)
@@ -315,7 +332,7 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             lax_["wq"]["bias"] = ("layers", "heads")
             lax_["wk"]["bias"] = ("layers", "kv_heads")
             lax_["wv"]["bias"] = ("layers", "kv_heads")
-        if cfg.qk_norm and mixer == "attn":
+        if cfg.qk_norm and mixer in ("attn", "retention"):
             lax_["q_norm"] = {"weight": ("layers", None)}
             lax_["k_norm"] = {"weight": ("layers", None)}
         return lax_
@@ -460,6 +477,54 @@ def _conv_mixer(h, p, layer_cache, cfg, conv_fn):
     return h, new_cache
 
 
+def whole_sequence_retention_fn(q, k, v, log_g, layer_cache):
+    """The retention of a forward pass with no cache: every row of the
+    batch is one sequence from its start, so the definition's quadratic
+    form runs as it is."""
+    from helix_tpu.ops.retention import retention_quadratic
+
+    return retention_quadratic(q, k, v, log_g), None
+
+
+def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
+                     retention_fn):
+    """Power retention (Brumby): GQA-shaped ``q, k, v`` with per-head norms
+    and rope as in the Qwen3 block, a gate a kv head ``log g = logsigmoid(
+    W_g n + b_g)``, and ``y_t = sum_s a_ts v_s / (sum_s a_ts + eps)`` with
+    ``a_ts = prod_{r in (s, t]} g_r * (q_t . k_s / sqrt d) ** 2``.
+    ``retention_fn(q, k, v, log_g, layer_cache) -> (y, new_cache)`` owns the
+    sum: which earlier tokens are a token's own sequence, and the matrix
+    state a sequence carries between calls (``ops/retention.py``).  ``q``
+    arrives in float32 times ``head_dim ** -0.5``."""
+    B, S, E = h.shape
+    H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    with jax.named_scope("retention.qkvg"):
+        x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                     cfg.norm_offset)
+        q = _dense(x, p["wq"]).astype(h.dtype).reshape(B, S, H, D)
+        k = _dense(x, p["wk"]).astype(h.dtype).reshape(B, S, KVH, D)
+        v = _dense(x, p["wv"]).astype(h.dtype).reshape(B, S, KVH, D)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        from helix_tpu.ops.quant import maybe_dequant_dense
+
+        # the gate's logit stays float32: a context of thousands of tokens
+        # multiplies thousands of gates
+        log_g = jax.nn.log_sigmoid(
+            maybe_dequant_dense(x, p["g_proj"], compute_dtype=jnp.float32)
+            + p["g_bias"]["bias"].astype(jnp.float32))
+    with jax.named_scope("retention.mix"):
+        y, new_cache = retention_fn(
+            q.astype(jnp.float32) * D ** -0.5, k, v, log_g, layer_cache)
+    with jax.named_scope("retention.out_proj"):
+        h = h + _dense(
+            y.astype(h.dtype).reshape(B, S, H * D), p["wo"]).astype(h.dtype)
+    return h, new_cache
+
+
 def _layer(
     h,
     layer_params: Params,
@@ -473,6 +538,7 @@ def _layer(
     stacked_experts=None,
     moe_backend=None,
     conv_fn=None,
+    retention_fn=None,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -497,6 +563,11 @@ def _layer(
         k = v = None
         h, new_cache = _conv_mixer(
             h, p, layer_cache, cfg, conv_fn or whole_sequence_conv_fn)
+    elif "g_proj" in p:
+        k = v = None
+        h, new_cache = _retention_mixer(
+            h, p, layer_cache, cfg, positions, inv_freq,
+            retention_fn or whole_sequence_retention_fn)
     elif cfg.is_mla:
         h, (k, v), new_cache = _mla_attention(
             h, p, layer_cache, cfg, positions, inv_freq, attn_fn)
@@ -645,6 +716,8 @@ def forward(
                           # attention dispatchers take it (models/moe.py)
     conv_fn=None,         # a conv layer's look-back (``_conv_mixer``);
                           # None: every row is a whole sequence
+    retention_fn=None,    # a retention layer's sum over its sequence
+                          # (``_retention_mixer``); None: the same
 ):
     """Run the decoder.
 
@@ -693,6 +766,7 @@ def forward(
                 stacked_experts=None if whole is None else (
                     whole, rep * run.count + i),
                 moe_backend=moe_backend, conv_fn=conv_fn,
+                retention_fn=retention_fn,
             )
 
         # the cache's layer index counts the layers of the run's mixer:
@@ -753,7 +827,9 @@ def forward(
             if carry_caches is None and run.mixer == "attn":
                 kvs.append(kv)
             stats.append(st)
-    if carry_caches is not None or len(kvs) == 1:
+    if not kvs:
+        kv = None          # no layer with pages and no cache: nothing fresh
+    elif carry_caches is not None or len(kvs) == 1:
         kv = kvs[-1]
     else:
         kv = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *kvs)

@@ -1,0 +1,261 @@
+"""Power retention of degree 2: a linear-attention mixer whose memory is one
+matrix a kv head, whatever the sequence's length.
+
+For query head ``h`` of kv group ``j``, gates ``g_t`` in (0, 1)::
+
+    a_ts   = exp(sum_{r=s+1..t} log g_r[j]) * (q_t[h] . k_s[j]) ** 2     s <= t
+    y_t[h] = sum_s a_ts v_s[j] / (sum_s a_ts + eps)
+
+(``q`` arrives times ``head_dim ** -0.5``, so the square carries the
+``1 / head_dim``).  ``(q . k) ** 2 = phi(q) . phi(k)`` with ``phi(u)`` the
+symmetric second power of ``u``, so the same function is a recurrence::
+
+    S_t = g_t S_{t-1} + phi(k_t) v_t^T        Z_t = g_t Z_{t-1} + k_t k_t^T
+    y_t[h] = phi(q_t[h])^T S_t / (q_t[h]^T Z_t q_t[h] + eps)
+
+``S [D_held, d]`` is the state; the normaliser ``phi(q) . z`` is held as the
+full symmetric matrix ``Z [d, d]`` (the same numbers as ``z``: its ``d (d +
+1) / 2`` distinct entries are ``z``'s), 1.5% of the state's bytes, because a
+``[d, d]`` outer product needs no packing.
+
+**The packing that is held.**  ``phi(u)`` has ``d (d + 1) / 2`` distinct
+products.  They are held in 8 x 8 BLOCKS of the symmetric matrix ``u u^T``:
+the blocks ``(a, b)`` with ``a <= b``, a diagonal block whole (weight 1:
+both ``u_i u_j`` and ``u_j u_i``), an off-diagonal block times ``sqrt 2`` (it
+stands for its mirror too).  ``D_held = d * d / 2 + 4 * d`` (8,704 at width
+128, against 8,256 packed exactly: 5% more bytes for rows that are whole
+sublane tiles).  Block row ``a`` has ``d/8 - a`` blocks, so rows ``p`` and
+``d/8 - 1 - p`` together have ``d/8 + 1``: that pair is one TILE of
+``(d/8 + 1) * 64`` rows (1,088), ``d/16`` tiles a head, all the same shape:
+what the decode kernel's grid walks.  Inside a tile the row index is ``(r,
+s, c)``: ``r`` the row of ``u`` in its block (8), ``s`` the block's place in
+the tile (``d/8 + 1``), ``c`` the column in the block (8).
+
+Three forms of the same function live here, all ``jax.numpy`` in float32 at
+the highest matmul precision: the quadratic form over whole sequences (a
+forward pass with no cache), the recurrence one token at a time (the decode
+kernel's oracle and the CPU path), and the CHUNKED form (a row of fresh
+tokens that continues from a state).  The decode step on a TPU is
+``ops/retention_kernel.py``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+BLOCK = 8
+_HI = jax.lax.Precision.HIGHEST
+EPS = 1e-6
+
+
+def held_rows(d: int) -> int:
+    """``D_held``: rows of the state a kv head, in the packing above."""
+    if d % (2 * BLOCK):
+        raise ValueError(
+            f"power retention holds phi in 8 x 8 blocks paired into tiles: "
+            f"the head width must be a multiple of 16, not {d}")
+    return d * d // 2 + 4 * d
+
+
+def tile_rows(d: int) -> int:
+    """Rows of one tile (a pair of block rows)."""
+    return (d // BLOCK + 1) * BLOCK * BLOCK
+
+
+def phi(u):
+    """``[..., d] -> [..., D_held]`` float32: the held symmetric second power
+    (``phi(q) . phi(k) == (q . k) ** 2``), built from slices and outer
+    products alone (no gather)."""
+    d = u.shape[-1]
+    nb = d // BLOCK
+    held_rows(d)
+    u = u.astype(jnp.float32)
+    lead = u.shape[:-1]
+    tiles = []
+    for p in range(nb // 2):
+        parts = []
+        for a in (p, nb - 1 - p):
+            lo = BLOCK * a
+            w = jnp.concatenate([
+                jnp.ones((BLOCK,), jnp.float32),
+                jnp.full((d - lo - BLOCK,), math.sqrt(2.0), jnp.float32)])
+            parts.append(
+                u[..., lo:lo + BLOCK, None] * (u[..., None, lo:] * w))
+        tiles.append(jnp.concatenate(parts, axis=-1).reshape(
+            lead + (tile_rows(d),)))
+    return jnp.concatenate(tiles, axis=-1)
+
+
+def _grouped(q, kvh):
+    """``[..., H, d] -> [..., KVH, G, d]``."""
+    *lead, H, d = q.shape
+    return q.reshape(*lead, kvh, H // kvh, d)
+
+
+def retention_quadratic(q, k, v, log_g, eps: float = EPS):
+    """The definition over whole sequences: ``q [B, S, H, d]`` (scaled),
+    ``k, v [B, S, KVH, d]``, ``log_g [B, S, KVH]``; every row of the batch
+    one sequence from its start.  Returns ``y [B, S, H, d]`` float32."""
+    B, S, H, d = q.shape
+    KVH = k.shape[2]
+    qg = _grouped(q.astype(jnp.float32), KVH)
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+    G = jnp.cumsum(log_g.astype(jnp.float32), axis=1)          # [B, S, KVH]
+    sc = jnp.einsum("btkgd,bskd->bkgts", qg, k, precision=_HI) ** 2
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    dec = jnp.exp(jnp.where(
+        causal, G.transpose(0, 2, 1)[..., :, None]
+        - G.transpose(0, 2, 1)[..., None, :], -jnp.inf))       # [B, KVH, t, s]
+    a = sc * dec[:, :, None]
+    num = jnp.einsum("bkgts,bskd->btkgd", a, v, precision=_HI)
+    den = jnp.sum(a, axis=-1).transpose(0, 3, 1, 2)            # [B, t, KVH, G]
+    return (num / (den[..., None] + eps)).reshape(B, S, H, d)
+
+
+def retention_step(q, k, v, log_g, S, Z, eps: float = EPS):
+    """The recurrence, one token a row: ``q [B, H, d]`` (scaled), ``k, v
+    [B, KVH, d]``, ``log_g [B, KVH]``, ``S [B, KVH, D_held, d]``, ``Z [B,
+    KVH, d, d]``.  Returns ``(y [B, H, d], S, Z)``, all float32."""
+    B, H, d = q.shape
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+    g = jnp.exp(log_g.astype(jnp.float32))[..., None, None]
+    S = g * S + phi(k)[..., :, None] * v[..., None, :]
+    num = jnp.einsum(
+        "bkgf,bkfc->bkgc", phi(_grouped(q, k.shape[1])), S, precision=_HI)
+    den, Z = normaliser_step(q, k, log_g, Z)
+    return (num / (den[..., None] + eps)).reshape(B, H, d), S, Z
+
+
+def normaliser_step(q, k, log_g, Z):
+    """The normaliser's half of ``retention_step``: ``(den [B, KVH, G], Z)``
+    (what runs beside the decode kernel, which owns ``S``)."""
+    KVH = k.shape[1]
+    qg = _grouped(q.astype(jnp.float32), KVH)
+    k = k.astype(jnp.float32)
+    Z = (jnp.exp(log_g.astype(jnp.float32))[..., None, None] * Z
+         + k[..., :, None] * k[..., None, :])
+    return jnp.einsum("bkgi,bkij,bkgj->bkg", qg, Z, qg, precision=_HI), Z
+
+
+def retention_chunk(q, k, v, log_g, mask, S0, Z0, eps: float = EPS):
+    """The chunked form for ONE row of a flat token axis: ``mask [T]`` marks
+    the row's (contiguous) tokens, ``S0 [KVH, D_held, d]`` / ``Z0 [KVH, d,
+    d]`` the state it continues from (zeros for a row that starts its
+    sequence).  ``q [T, H, d]`` (scaled), ``k, v [T, KVH, d]``, ``log_g [T,
+    KVH]``.  Inside the chunk the quadratic form with cumulative log-gates;
+    the state for everything before it; the new state the decayed old one
+    plus the chunk's decayed outer products.  Returns ``(y [T, H, d], zeros
+    off the row; S1; Z1)``, float32.
+
+    One kv head at a time (``lax.map``): ``phi(Q)`` is ``[T, G, D_held]``
+    float32 a head (89 MB at 512 tokens, 5 heads, width 128) and is never
+    held for all heads at once."""
+    T, H, d = q.shape
+    KVH = k.shape[1]
+    qg = _grouped(q.astype(jnp.float32), KVH).transpose(1, 0, 2, 3)
+    k = k.astype(jnp.float32).transpose(1, 0, 2)               # [KVH, T, d]
+    v = v.astype(jnp.float32).transpose(1, 0, 2)
+    lg = jnp.where(mask[:, None], log_g.astype(jnp.float32), 0.0).T
+    pair = jnp.tril(jnp.ones((T, T), bool)) & mask[:, None] & mask[None, :]
+
+    def head(x):
+        qh, kh, vh, lgh, S, Z = x        # [T, G, d] [T, d] [T, d] [T] ...
+        # tokens off the row have log-gate 0: the sum runs from the row's
+        # first token, and its last value is the row's whole decay
+        Gc = jnp.cumsum(lgh)
+        sc = jnp.einsum("tgd,sd->gts", qh, kh, precision=_HI) ** 2
+        a = sc * jnp.exp(jnp.where(
+            pair, Gc[:, None] - Gc[None, :], -jnp.inf))[None]
+        into = jnp.exp(Gc)                                     # [T]
+        num = jnp.einsum("gts,sd->tgd", a, vh, precision=_HI) + into[
+            :, None, None] * jnp.einsum(
+                "tgf,fc->tgc", phi(qh), S, precision=_HI)
+        den = jnp.sum(a, axis=-1).T + into[:, None] * jnp.einsum(
+            "tgi,ij,tgj->tg", qh, Z, qh, precision=_HI)
+        y = jnp.where(mask[:, None, None], num / (den[..., None] + eps), 0.0)
+        out = jnp.where(mask, jnp.exp(Gc[-1] - Gc), 0.0)        # [T]
+        S1 = jnp.exp(Gc[-1]) * S + jnp.einsum(
+            "tf,tc->fc", phi(kh) * out[:, None], vh, precision=_HI)
+        Z1 = jnp.exp(Gc[-1]) * Z + jnp.einsum(
+            "t,ti,tj->ij", out, kh, kh, precision=_HI)
+        return y, S1, Z1
+
+    y, S1, Z1 = jax.lax.map(head, (qg, k, v, lg, S0, Z0))
+    return y.transpose(1, 0, 2, 3).reshape(T, H, d), S1, Z1
+
+
+def retention_decode(q, k, v, log_g, S_pool, Z_pool, layer, live, *,
+                     backend=None, interpret: bool = False,
+                     eps: float = EPS):
+    """One decode step of every slot: row ``b`` is slot ``b``'s one fresh
+    token (``live [B]`` bool: idle slots and rows that sit the step out
+    write nothing and read zeros).  ``q [B, H, d]`` (scaled), ``k, v [B,
+    KVH, d]``, ``log_g [B, KVH]``; pools ``S [L, N, KVH, D_held, d]`` and
+    ``Z [L, N, KVH, d, d]`` with ``N >= B``, updated IN PLACE at ``layer``.
+    Returns ``(y [B, H, d] float32, S_pool, Z_pool)``.
+
+    On a TPU the state's update and its query are one pass of
+    ``retention_decode_tpu`` over the live slots (``interpret``: the same
+    kernel in interpret mode, for tests on a CPU with ``backend="pallas"``);
+    on a CPU, or for ``backend="reference"``, the plain recurrence."""
+    from helix_tpu.ops.attention import resolve_backend
+
+    B, H, d = q.shape
+    KVH = k.shape[1]
+    N = S_pool.shape[1]
+    rows = jnp.arange(B, dtype=jnp.int32)
+    dest = jnp.where(live, rows, N)               # past the pool: dropped
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    if resolve_backend(backend) == "pallas":
+        from helix_tpu.ops.retention_kernel import retention_decode_tpu
+
+        den, Z = normaliser_step(q, k, log_g, Z_pool[layer, :B])
+        Z_pool = Z_pool.at[layer, dest].set(Z, mode="drop")
+        order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+        num, S_pool = retention_decode_tpu(
+            q.reshape(B, KVH, H // KVH, d), k[:, :, None], v[:, :, None],
+            jnp.broadcast_to(jnp.exp(log_g.astype(jnp.float32))[
+                ..., None, None], (B, KVH, 1, d)),
+            S_pool, layer, order, jnp.sum(live).astype(jnp.int32),
+            interpret=interpret)
+        y = (num / (den[..., None] + eps)).reshape(B, H, d)
+        return jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool
+    y, S, Z = retention_step(
+        q, k, v, log_g, S_pool[layer, :B], Z_pool[layer, :B], eps)
+    S_pool = S_pool.at[layer, dest].set(S, mode="drop")
+    Z_pool = Z_pool.at[layer, dest].set(Z, mode="drop")
+    return jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool
+
+
+def retention_rows(q, k, v, log_g, t0, qlen, hist, slots, S_pool, Z_pool,
+                   layer, eps: float = EPS):
+    """Rows of fresh tokens on one flat axis (a prefill segment): row ``r``
+    is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
+    ``slots[r]``, with ``hist[r]`` tokens behind it (0: it starts from
+    zeros).  Live rows come first (``PrefillPlan``'s order).  A row with no
+    token is not visited; a row whose slot lies past the pool (no slot)
+    writes back what it read.  The pools are read and written a row at a
+    time, in place.  Returns ``(y [T, H, d] float32, S_pool, Z_pool)``."""
+    T, H, d = q.shape
+    N = S_pool.shape[1]
+    at = jnp.arange(T, dtype=jnp.int32)
+    n_rows = jnp.sum(qlen > 0).astype(jnp.int32)
+
+    def row(r, carry):
+        y, S_pool, Z_pool = carry
+        slot = jnp.clip(slots[r], 0, N - 1)
+        held = slots[r] < N
+        mask = (at >= t0[r]) & (at < t0[r] + qlen[r])
+        keep = (hist[r] > 0).astype(jnp.float32)
+        S_old, Z_old = S_pool[layer, slot], Z_pool[layer, slot]
+        yr, S1, Z1 = retention_chunk(
+            q, k, v, log_g, mask, S_old * keep, Z_old * keep, eps)
+        S_pool = S_pool.at[layer, slot].set(jnp.where(held, S1, S_old))
+        Z_pool = Z_pool.at[layer, slot].set(jnp.where(held, Z1, Z_old))
+        return y + yr, S_pool, Z_pool
+
+    y0 = jnp.zeros((T, H, d), jnp.float32)
+    return jax.lax.fori_loop(0, n_rows, row, (y0, S_pool, Z_pool))

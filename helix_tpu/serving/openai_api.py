@@ -416,6 +416,22 @@ class OpenAIServer:
                     "helix_recurrent_state_bytes",
                     getattr(eng, "recurrent_state_bytes", 0), lbl,
                 )
+            if getattr(eng.model_cfg, "num_retention_layers", 0):
+                # a matrix state a slot and no page of KV: the pool's
+                # bytes, the rows of it the steps advanced (one token at a
+                # time, or a chunk of a prompt), and the bytes they moved
+                c.gauge(
+                    "helix_recurrent_state_bytes",
+                    getattr(eng, "recurrent_state_bytes", 0), lbl,
+                )
+                for kind, n in sorted(getattr(
+                        eng, "num_retention_rows", {}).items()):
+                    c.counter("helix_retention_rows_total", n,
+                              {**lbl, "kind": kind})
+                c.counter(
+                    "helix_state_bytes_touched_total",
+                    getattr(eng, "state_bytes_touched", 0), lbl,
+                )
             # speculative decoding (ISSUE 5): host-drafted tokens, the
             # subset the verify pass accepted, lifetime acceptance, and
             # slots the per-request EMA currently benches

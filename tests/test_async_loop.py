@@ -61,9 +61,25 @@ def conv_parts():
     return cfg, params
 
 
-@pytest.fixture(params=["dense", "conv"])
-def parts(request, tiny_parts, conv_parts):
-    return tiny_parts if request.param == "dense" else conv_parts
+@pytest.fixture(scope="module")
+def retention_parts():
+    """A model whose every mixer is power retention: no page holds a byte,
+    every row carries a matrix state a kv head in its slot of the pool."""
+    from helix_tpu.models.common import ModelConfig
+    from helix_tpu.models.llama import init_params
+
+    cfg = ModelConfig.tiny(
+        vocab_size=512, dtype="float32", qk_norm=True,
+        layer_types=("retention",) * 2,
+    )
+    params = init_params(cfg, jax.random.PRNGKey(7))
+    return cfg, params
+
+
+@pytest.fixture(params=["dense", "conv", "retention"])
+def parts(request, tiny_parts, conv_parts, retention_parts):
+    return {"dense": tiny_parts, "conv": conv_parts,
+            "retention": retention_parts}[request.param]
 
 
 def _make_engine(parts, **extra):
@@ -75,6 +91,9 @@ def _make_engine(parts, **extra):
         max_pages_per_seq=32, max_prefill_len=8,
         attn_backend="reference",
     )
+    if cfg.num_retention_layers:
+        # a matrix state is not filed: the prefix cache is refused for it
+        kw["enable_prefix_cache"] = False
     kw.update(extra)
     return Engine(cfg, params, EngineConfig(**kw))
 

@@ -459,3 +459,95 @@ def test_hybrid_step_compiles_at_published_widths(one_chip, program):
     # both pools are updated in place: no pool-sized temporary
     assert compiled.memory_analysis().temp_size_in_bytes < (
         pages * cc.page_bytes(cfg)) // 2
+
+
+def test_retention_decode_kernel_compiles_at_the_published_geometry(one_chip):
+    """Brumby-14B's decode kernel: 24 rows, 8 kv heads of 5 query heads,
+    width 128, a state of 8,704 x 128 float32 a head in a pool of ten layers
+    (8.6 GB), donated: aliased to its output, no copy."""
+    from helix_tpu.ops.retention import held_rows
+    from helix_tpu.ops.retention_kernel import retention_decode_tpu
+
+    B, KVH, G, d, L = 24, 8, 5, 128, 10
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    pool = S((L, B, KVH, held_rows(d), d))
+    compiled = jax.jit(retention_decode_tpu, donate_argnums=(4,)).lower(
+        S((B, KVH, G, d)), S((B, KVH, 1, d)), S((B, KVH, 1, d)),
+        S((B, KVH, 1, d)), pool, S((), jnp.int32), S((B,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert "retention_decode_tpu" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = L * B * KVH * held_rows(d) * d * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_retention_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of Brumby-14B cut to two layers (int8 weights, 24
+    slots) for the described chip: the retention decode kernel over the
+    state pool in the carry, the chunked form a row at a time, a page pool
+    of no bytes, and both state arrays updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import BRUMBY_14B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        BRUMBY_14B, num_layers=2, layer_types=("retention",) * 2)
+    B, max_pages, pages = 24, 160, 4096
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks[0] == 0 and cc.page_bytes(cfg) == 0
+    assert cc.state_shape(cfg) == (2, B, 8, 8704, 128)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "retention_decode_tpu" in text
+    # the state pool is updated in place: aliased whole, and no temporary
+    # of a tenth of its size
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
