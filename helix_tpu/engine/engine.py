@@ -68,7 +68,7 @@ from helix_tpu.engine.sampling import (
     split_keys,
 )
 from helix_tpu.models.common import ModelConfig
-from helix_tpu.models.llama import forward
+from helix_tpu.models.llama import forward, lm_head
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.slo import ANON_TENANT
 from helix_tpu.ops.attention import attention as full_attention
@@ -845,7 +845,8 @@ def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
     no fresh token (an idle slot, padding) and a slot index past the pool
     write nothing.  ``snap [R]``: also hand back each row's state after
     that many of its tokens (what a prefix hit resumes from), stacked over
-    the conv layers in the carry's last element.
+    the conv layers in the carry's last element (a segment without
+    ``snap`` passes that element on).
 
     The carry it is called with is ``((page carry, kacc, vacc, state pool[,
     snaps]), conv layer index)``."""
@@ -875,7 +876,7 @@ def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
         new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
         dest = jnp.where(qlen > 0, slots, nslots)
         pool = pool.at[lc, dest].set(new, mode="drop")
-        if snaps:
+        if snap is not None:
             snaps = [snaps[0].at[lc].set(
                 _state_after(zf, S, t0, snap).astype(pool.dtype))]
         return y, (caches, kacc, vacc, pool, *snaps)
@@ -919,13 +920,44 @@ def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
     return retention_fn
 
 
-def _state_rows_fns(cfg, t0, qlen, hist, slots, backend, decode,
-                    snap=None) -> dict:
-    """``forward``'s look-back argument for the model's recurrent mixer."""
+def _segments_fn(fn_p, fn_s, n_tok: int, split, join):
+    """A mixer's look-back over the step's whole token axis, from one
+    function a segment: the first ``n_tok`` arguments are token arrays and
+    ``split`` into (prefill tokens, state rows); the prefill rows run first
+    and the state rows behind them ON THE SAME CARRY (their slots are
+    disjoint: a prompt in flight does not decode), and the outputs
+    ``join`` back onto the axis.  ``fn_p`` None: the axis is the state
+    rows alone and ``fn_s`` is the whole of it."""
+    if fn_p is None:
+        return fn_s
+
+    def fn(*args):
+        *xs, (carry, lc) = args
+        parts = [split(x) for x in xs[:n_tok]]
+        with jax.named_scope("prefill"):
+            y_p, carry = fn_p(
+                *(p for p, _ in parts), *xs[n_tok:], (carry, lc))
+        with jax.named_scope("state"):
+            y_s, carry = fn_s(
+                *(r for _, r in parts), *xs[n_tok:], (carry, lc))
+        return join(y_p, y_s), carry
+
+    return fn
+
+
+def _state_rows_fns(cfg, rows_s, backend, rows_p=None, split=None,
+                    join=None) -> dict:
+    """``forward``'s look-back argument for the model's recurrent mixer:
+    ``rows_s = (t0, qlen, hist, slots)`` the state rows (one token each,
+    row ``b`` slot ``b``), ``rows_p = (t0, qlen, hist, slots, snap)`` the
+    prefill rows before them on the axis, if the program has any."""
     if cfg.state_mixer == "retention":
-        return {"retention_fn": _retention_rows_fn(
-            t0, qlen, hist, slots, backend, decode)}
-    return {"conv_fn": _conv_rows_fn(t0, qlen, hist, slots, snap)}
+        return {"retention_fn": _segments_fn(
+            rows_p and _retention_rows_fn(*rows_p[:4], backend, False),
+            _retention_rows_fn(*rows_s, backend, True), 4, split, join)}
+    return {"conv_fn": _segments_fn(
+        rows_p and _conv_rows_fn(*rows_p), _conv_rows_fn(*rows_s), 1,
+        split, join)}
 
 
 def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
@@ -991,7 +1023,7 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     if cache.state is not None:
         # the slots' recurrent states ride the carry beside the pages
         carry0 += (cache.state,)
-        state_fns = _state_rows_fns(cfg, t0, q_len, hist, t0, backend, True)
+        state_fns = _state_rows_fns(cfg, (t0, q_len, hist, t0), backend)
     if cfg.mrope_sections is not None:
         from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -1080,22 +1112,33 @@ def _build_ragged_step_fn(
     """THE unified device step: ONE compiled entry point serves every
     caller, keyed at runtime only on the prefill token-bucket.
 
-    One call runs, in one jit:
+    One call runs, in one jit, ONE pass over the layers (one ``forward``,
+    one carry): the prefill tokens and the state rows lie behind one
+    another on one flat token axis ``[1, token_bucket + B * state_width]``,
+    so every weight (norms, projections, MLP or experts, the head) is
+    streamed once a program.  Only the token mixers are a segment's own:
+    ``attn_fn`` and the recurrent mixers (``_state_rows_fns``) split the
+    axis at ``token_bucket`` (static), run the prefill rows and then the
+    state rows on the same pool carry (their pages and slots are disjoint:
+    a prompt in flight does not decode), and join the outputs.  The pass
+    ends in hidden rows, and the head reads only the rows that sample.
 
-    1. **Prefill segment** (``token_bucket`` > 0): a flat token axis of
-       up to ``prefill_rows`` ragged rows — cold packed prompts,
-       prefix-cache hits (their remainder attends the shared pages via
-       ``hist``) and the in-flight long-prompt chunk all share it.  One
-       forward, one ``write_kv`` scatter, one batched first-token
-       sample.  ``has_hist`` statically selects between pure packed
+    1. **Prefill rows** (``token_bucket`` > 0): up to ``prefill_rows``
+       ragged rows — cold packed prompts, prefix-cache hits (their
+       remainder attends the shared pages via ``hist``) and the in-flight
+       long-prompt chunk all share the flat axis.  One ``write_kv``
+       scatter, one batched first-token sample over each row's last
+       token.  ``has_hist`` statically selects between pure packed
        self-attention (no pool reads — the cold common case) and the
        ragged paged op; an ``sp`` mesh routes single-row history chunks
        through ring attention instead.
-    2. **State segment**: every decode slot is a ``state_width``-token
+    2. **State rows**: every decode slot is a ``state_width``-token
        row — its last sampled token plus up to ``state_width - 1``
        host-drafted speculative tokens (``draft_len[b]`` of them; 0 = a
        plain decode step, -1 = the slot sits this call out, e.g. during
-       an admission wave).  Verification is in-call: every live position
+       an admission wave: it stays on the axis, a row in the pass's
+       products, masked out of routing, attention and every write).
+       Verification is in-call: every live position
        samples from the slot's OWN SamplingParams with the penalty
        histogram evolved along the drafted prefix ("sample from target
        and compare" IS rejection sampling for a point-mass draft, so the
@@ -1105,7 +1148,9 @@ def _build_ragged_step_fn(
        Rejected drafts' KV lands only in the slot's private page tail
        and is overwritten by the next step.  Key splits are consumed
        only at live positions, so a plain step costs exactly one split —
-       the same key stream plain decode always had.
+       the same key stream plain decode always had.  With no prefill rows
+       (``token_bucket`` 0) the axis is the state rows' ``[B,
+       state_width]`` alone.
     3. **Fused tail**: ``n_extra`` (DYNAMIC — no shape per window size)
        plain decode steps scanned onto the rolled-back state inside the
        same jit, so one host sync still yields a full
@@ -1173,183 +1218,196 @@ def _build_ragged_step_fn(
             p_cold = None
             s_cold = None
 
-        # ---- 1. prefill segment --------------------------------------
+        # ---- the state rows (decode / verify) ------------------------
+        tokens_s = jnp.concatenate(
+            [state.last_token[:, None], drafts], axis=1
+        )                                                        # [B, W]
+        pos_s = state.positions[:, None] + jnp.arange(W)[None]
+        act = state.active > 0
+        live = (
+            (jnp.arange(W)[None] <= draft_len[:, None]) & act[:, None]
+        )
+        s_t0 = jnp.arange(B, dtype=jnp.int32) * W
+        # rows sitting this call out (draft_len -1: admission waves,
+        # standalone chunk steps) get q_len 0 so the kernel skips their
+        # page-pool sweep entirely; they stay on the token axis (a static
+        # shape) and cost rows in the pass's products, not a pass
+        s_qlen = jnp.where(
+            act & (draft_len >= 0), W, 0
+        ).astype(jnp.int32)
+        s_hist = state.positions * state.active
+        # a live slot beside a state pool is a one-token row over its own
+        # state (W is 1 there: speculation is refused)
+        rows_s = (s_t0, live[:, 0].astype(jnp.int32), s_hist,
+                  jnp.arange(B, dtype=jnp.int32))
+
+        # ---- the prefill rows, and the one token axis -----------------
         if Cb > 0:
-            with jax.named_scope("prefill"):
-                if has_state:
-                    *pargs, p_slots, p_snap = pargs
-                if use_adapters:
-                    (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
-                     p_qlen, p_hist, p_tables, p_ends, p_sampling, p_keys,
-                     p_aids) = pargs
-                else:
-                    (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
-                     p_qlen, p_hist, p_tables, p_ends, p_sampling,
-                     p_keys) = pargs
-                    p_aids = None
-                kacc0, vacc0 = _fresh_kv_zeros(cfg, 1, Cb)
-                p_carry = (cache.carry(), kacc0, vacc0)
-                p_state_fns = {}
-                if has_state:
-                    p_carry += (cache.state,)
-                    if has_snaps:
-                        (shp, _), = cfg.state_arrays()
-                        p_carry += (jnp.zeros(
-                            (cfg.num_conv_layers, prefill_rows) + shp,
-                            cache.state.dtype),)
-                    p_state_fns = _state_rows_fns(
-                        cfg, p_t0, p_qlen, p_hist, p_slots, backend, False,
-                        p_snap)
-
-                def p_attn(q, k, v, carry_cache, pos):
-                    (caches, kacc, vacc, *rest), lyr = carry_cache
-                    if use_ring:
-                        out = _ring_chunk_attention(
-                            q, k, v, caches, lyr, p_pos, p_seg, p_hist,
-                            p_tables, mesh, page_size, ring_hist_pages,
-                        )
-                    elif has_hist or cfg.is_mla:
-                        # latent attention has one kernel: a cold row is
-                        # a row with no history
-                        out = _ragged_attn_call(
-                            q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
-                            p_tables, backend, cold=p_cold, mesh=mesh,
-                        )
-                    else:
-                        # cold rows only: packed self-attention, no pool
-                        # reads — bit-compatible with the pre-unification
-                        # packed-prefill path
-                        out = full_attention(
-                            q, k, v,
-                            causal=True,
-                            q_positions=p_pos,
-                            kv_positions=p_pos,
-                            q_segment_ids=p_seg,
-                            kv_segment_ids=p_seg,
-                            backend=backend,
-                            mesh=mesh,
-                        )
-                    return out, (caches, kacc.at[lyr].set(k),
-                                 vacc.at[lyr].set(v), *rest)
-
-                res = forward(
-                    params, cfg, p_tokens, p_pos,
-                    attn_fn=p_attn,
-                    carry_caches=p_carry,
-                    moe_token_mask=p_seg > 0,
-                    moe_backend=backend,
-                    return_moe_stats=is_moe,
-                    adapter_ids=p_aids,
-                    **p_state_fns,
-                )
-                if is_moe:
-                    logits_p, (pc, kacc, vacc, *rest), moe_stats = res
-                    drops = moe_stats["vector"]
-                else:
-                    logits_p, (pc, kacc, vacc, *rest) = res
-                if has_state:
-                    pool, *snap_out = rest
-                    snaps = snap_out[0] if snap_out else None
-                    cache = PagedKVCache.from_carry(pc, pool)
-                else:
-                    cache = PagedKVCache.from_carry(pc)
-                cache = write_kv(
-                    cache, kacc, vacc, p_pages, p_offsets, p_seg > 0,
-                )
-                last = logits_p[0, p_ends]   # [R, V]: each row's last token
-                with jax.named_scope("sample"):
-                    p_first = sample(last, p_sampling, p_keys)
-        else:
-            p_first = jnp.zeros((0,), jnp.int32)
-
-        # ---- 2. state segment (decode / verify rows) -----------------
-        with jax.named_scope("state"):
-            tokens_s = jnp.concatenate(
-                [state.last_token[:, None], drafts], axis=1
-            )                                                    # [B, W]
-            pos_s = state.positions[:, None] + jnp.arange(W)[None]
-            act = state.active > 0
-            live = (
-                (jnp.arange(W)[None] <= draft_len[:, None]) & act[:, None]
-            )
-            s_t0 = jnp.arange(B, dtype=jnp.int32) * W
-            # rows sitting this call out (draft_len -1: admission waves,
-            # standalone chunk steps) get q_len 0 so the kernel skips their
-            # page-pool sweep entirely — an admission wave must not cost a
-            # wasted decode step per active slot
-            s_qlen = jnp.where(
-                act & (draft_len >= 0), W, 0
-            ).astype(jnp.int32)
-            s_hist = state.positions * state.active
-            kacc0s, vacc0s = _fresh_kv_zeros(cfg, B, W)
-
-            def s_attn(q, k, v, carry_cache, pos):
-                (caches, kacc, vacc, *rest), lyr = carry_cache
-                out = _ragged_attn_call(
-                    q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
-                    state.page_tables, backend, cold=s_cold, mesh=mesh,
-                )
-                return out, (caches, kacc.at[lyr].set(k),
-                             vacc.at[lyr].set(v), *rest)
-
-            carry0 = (cache.carry(), kacc0s, vacc0s)
-            s_state_fns = {}
             if has_state:
-                # W is 1 here (speculation is refused beside a state pool):
-                # a live slot is a one-token row over its own state
+                *pargs, p_slots, p_snap = pargs
+            if use_adapters:
+                (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
+                 p_qlen, p_hist, p_tables, p_ends, p_sampling, p_keys,
+                 p_aids) = pargs
+            else:
+                (p_tokens, p_pos, p_seg, p_pages, p_offsets, p_t0,
+                 p_qlen, p_hist, p_tables, p_ends, p_sampling,
+                 p_keys) = pargs
+                p_aids = None
+
+            def split(x):
+                """``[1, Cb + B * W, ...]`` -> the prefill tokens ``[1, Cb,
+                ...]`` and the state rows ``[B, W, ...]``."""
+                return x[:, :Cb], x[:, Cb:].reshape((B, W) + x.shape[2:])
+
+            def join(xp, xs):
+                return jnp.concatenate(
+                    [xp, xs.reshape((1, B * W) + xs.shape[2:])], axis=1)
+
+            rows_p = ((p_t0, p_qlen, p_hist, p_slots, p_snap)
+                      if has_state else None)
+            tokens, pos = join(p_tokens, tokens_s), join(p_pos, pos_s)
+            moe_mask = join(p_seg > 0, live)
+        else:
+            split = join = rows_p = None
+            tokens, pos, moe_mask = tokens_s, pos_s, live
+        aids = None
+        if use_adapters:
+            aids = jnp.broadcast_to(state.adapter_slots[:, None], (B, W))
+            if Cb > 0:
+                aids = join(p_aids, aids)
+
+        # the token mixer is a segment's own: the prefill tokens take their
+        # branch, the state rows the ragged call with the one-token query
+        # block, both over the same pools; each segment files its fresh K/V
+        # for its own scatter (the accumulators are (prefill's, state's))
+        def p_attn(q, k, v, carry_cache):
+            (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
+            if use_ring:
+                out = _ring_chunk_attention(
+                    q, k, v, caches, lyr, p_pos, p_seg, p_hist,
+                    p_tables, mesh, page_size, ring_hist_pages,
+                )
+            elif has_hist or cfg.is_mla:
+                # latent attention has one kernel: a cold row is a row
+                # with no history
+                out = _ragged_attn_call(
+                    q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
+                    p_tables, backend, cold=p_cold, mesh=mesh,
+                )
+            else:
+                # cold rows only: packed self-attention, no pool reads —
+                # bit-compatible with the pre-unification packed-prefill
+                # path
+                out = full_attention(
+                    q, k, v,
+                    causal=True,
+                    q_positions=p_pos,
+                    kv_positions=p_pos,
+                    q_segment_ids=p_seg,
+                    kv_segment_ids=p_seg,
+                    backend=backend,
+                    mesh=mesh,
+                )
+            return out, (caches, (kp.at[lyr].set(k), ks),
+                         (vp.at[lyr].set(v), vs), *rest)
+
+        def s_attn(q, k, v, carry_cache):
+            (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
+            out = _ragged_attn_call(
+                q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
+                state.page_tables, backend, cold=s_cold, mesh=mesh,
+            )
+            return out, (caches, (kp, ks.at[lyr].set(k)),
+                         (vp, vs.at[lyr].set(v)), *rest)
+
+        attend = _segments_fn(
+            p_attn if Cb > 0 else None, s_attn, 3, split, join)
+
+        def attn_fn(q, k, v, carry_cache, pos):
+            return attend(q, k, v, carry_cache)
+
+        # ---- ONE pass over every layer --------------------------------
+        with jax.named_scope("pass" if Cb > 0 else "state"):
+            kacc_p, vacc_p = (_fresh_kv_zeros(cfg, 1, Cb) if Cb > 0
+                              else (None, None))
+            kacc_s, vacc_s = _fresh_kv_zeros(cfg, B, W)
+            carry0 = (cache.carry(), (kacc_p, kacc_s), (vacc_p, vacc_s))
+            state_fns = {}
+            if has_state:
                 carry0 += (cache.state,)
-                s_state_fns = _state_rows_fns(
-                    cfg, s_t0, live[:, 0].astype(jnp.int32), s_hist,
-                    jnp.arange(B, dtype=jnp.int32), backend, True)
+                if has_snaps and Cb > 0:
+                    (shp, _), = cfg.state_arrays()
+                    carry0 += (jnp.zeros(
+                        (cfg.num_conv_layers, prefill_rows) + shp,
+                        cache.state.dtype),)
+                state_fns = _state_rows_fns(
+                    cfg, rows_s, backend, rows_p, split, join)
             if is_mrope:
                 from helix_tpu.models.qwen2_vl import text_forward_mrope
 
                 pos3 = jnp.broadcast_to(
                     (pos_s + state.mrope_delta[:, None])[None], (3, B, W)
                 )
-                logits_s, (pc2, kaccs, vaccs) = text_forward_mrope(
+                logits_s, (pc, kacc, vacc) = text_forward_mrope(
                     params, cfg, tokens_s, pos3,
-                    attn_fn=s_attn,
+                    attn_fn=attn_fn,
                     carry_caches=carry0,
                     mrope_sections=cfg.mrope_sections,
                     seq_positions=pos_s,
                 )
+                rest = ()
             else:
                 res = forward(
-                    params, cfg, tokens_s, pos_s,
-                    attn_fn=s_attn,
+                    params, cfg, tokens, pos,
+                    attn_fn=attn_fn,
                     carry_caches=carry0,
-                    moe_token_mask=live,
+                    return_hidden=True,
+                    moe_token_mask=moe_mask,
                     moe_backend=backend,
                     return_moe_stats=is_moe,
-                    adapter_ids=(
-                        jnp.broadcast_to(
-                            state.adapter_slots[:, None], (B, W)
-                        )
-                        if use_adapters else None
-                    ),
-                    **s_state_fns,
+                    adapter_ids=aids,
+                    moe_decode_rows=B * W if Cb > 0 else 0,
+                    **state_fns,
                 )
-                logits_s, (pc2, kaccs, vaccs, *rest) = res[:2]
-                if rest:
-                    pc2 = pc2 + (rest[0],)
+                hidden, (pc, kacc, vacc, *rest) = res[:2]
                 if is_moe:
-                    # the step's routing load is the decode rows' when any
-                    # is live (what sets a decode step's bytes), else the
-                    # prefill segment's; drops and routed tokens add up
-                    sv = res[2]["vector"]
-                    if drops is None:
-                        drops = sv
-                    else:
-                        drops = jnp.concatenate([
-                            drops[:2] + sv[:2],
-                            jnp.where(sv[1] > 0, sv[2:], drops[2:]),
-                        ])
-            cache = _cache_from(pc2, cache)
+                    # one product, one report: routed and dropped are the
+                    # program's; load ratio, experts touched and tile fill
+                    # are of its prefill tokens and decode rows together
+                    drops = res[2]["vector"]
+                # the head reads the rows that sample: each prefill row's
+                # last token and the state rows
+                if Cb > 0:
+                    h_p, h_s = split(hidden)
+                    logits = lm_head(
+                        params, cfg, join(h_p[:, p_ends], h_s))[0]
+                    logits_p = logits[:prefill_rows]          # [R, V]
+                    logits_s = logits[prefill_rows:].reshape(B, W, -1)
+                else:
+                    logits_s = lm_head(params, cfg, hidden)
+            if has_state:
+                pool, *snap_out = rest
+                snaps = snap_out[0] if snap_out else None
+                cache = PagedKVCache.from_carry(pc, pool)
+            else:
+                cache = PagedKVCache.from_carry(pc)
+
+        if Cb > 0:
+            with jax.named_scope("prefill"):
+                cache = write_kv(
+                    cache, kacc[0], vacc[0], p_pages, p_offsets, p_seg > 0,
+                )
+                with jax.named_scope("sample"):
+                    p_first = sample(logits_p, p_sampling, p_keys)
+        else:
+            p_first = jnp.zeros((0,), jnp.int32)
+
+        with jax.named_scope("state"):
             pages_s, offs_s = slot_to_page_offset(
                 pos_s, state.page_tables, page_size
             )
-            cache = write_kv(cache, kaccs, vaccs, pages_s, offs_s, live)
+            cache = write_kv(cache, kacc[1], vacc[1], pages_s, offs_s, live)
 
             # position-by-position penalised sampling (cheap [B, V] ops):
             # the histogram carries the drafted prefix forward so position
@@ -1677,6 +1735,11 @@ class Engine:
         self.prefix_cache_misses = 0
         # ragged mixed steps taken (chunk prefill + decode in ONE call)
         self.num_mixed_steps = 0
+        # programs whose prefill rows and state rows shared one pass over
+        # the layers (every program with a prefill segment), and the state
+        # rows that rode those passes sitting out (draft_len -1)
+        self.num_joint_pass_steps = 0
+        self.num_joint_pass_inert_rows = 0
         # --- speculative decoding (engine/spec.py) ---
         # host-side prompt-lookup drafter + per-request acceptance EMA;
         # None = speculation off (config, or an unsupported model family)
@@ -4893,10 +4956,16 @@ class Engine:
             self._note_retention_rows(
                 plan if rows else None, draft_len, n_extra)
         used = plan.used if rows else 0
+        live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
+        joint_pass = int(rows > 0)
+        inert_rows = (len(draft_len) - live_rows) * joint_pass
+        self.num_joint_pass_steps += joint_pass
+        self.num_joint_pass_inert_rows += inert_rows
         with obs_trace.phase(
             "helix.loop.launch", kind=kind, token_bucket=rung,
             prefill_rows=rows, has_hist=has_hist,
-            live_rows=int(np.count_nonzero(np.asarray(draft_len) >= 0)),
+            live_rows=live_rows, joint_pass=joint_pass,
+            inert_rows=inert_rows,
             n_extra=int(n_extra), prefill_tokens=used,
             padding_tokens=rung - used,
             **({"experts_touched": round(self.moe_experts_touched, 1)}

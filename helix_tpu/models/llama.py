@@ -539,6 +539,7 @@ def _layer(
     moe_backend=None,
     conv_fn=None,
     retention_fn=None,
+    moe_decode_rows: int = 0,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -618,6 +619,7 @@ def _layer(
             backend=moe_backend,
             expert_bias=(p["expert_bias"]["bias"]
                          if "expert_bias" in p else None),
+            decode_rows=moe_decode_rows,
         )
         if "shared" in p:
             with jax.named_scope("moe.shared"):
@@ -708,7 +710,7 @@ def forward(
     moe_token_mask=None,  # [B, S] bool: MoE routing validity (padding /
                           # inactive decode slots never consume capacity)
     return_moe_stats: bool = False,  # also return {"dropped": int32,
-                          # "vector": f32[4]}: drops summed over layers,
+                          # "vector": f32[5]}: drops summed over layers,
                           # and the step's routing load
     adapter_ids=None,     # [B, S] i32: per-token multi-LoRA pool slot
                           # (0 = identity); None = no batched adapters
@@ -718,6 +720,9 @@ def forward(
                           # None: every row is a whole sequence
     retention_fn=None,    # a retention layer's sum over its sequence
                           # (``_retention_mixer``); None: the same
+    moe_decode_rows: int = 0,  # the last n tokens of the axis are decode
+                          # rows riding a prefill's pass: capacity dispatch
+                          # leaves them dropless (``models/moe.py``)
 ):
     """Run the decoder.
 
@@ -766,7 +771,7 @@ def forward(
                 stacked_experts=None if whole is None else (
                     whole, rep * run.count + i),
                 moe_backend=moe_backend, conv_fn=conv_fn,
-                retention_fn=retention_fn,
+                retention_fn=retention_fn, moe_decode_rows=moe_decode_rows,
             )
 
         # the cache's layer index counts the layers of the run's mixer:
@@ -835,8 +840,27 @@ def forward(
         kv = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *kvs)
     stats = jnp.concatenate(stats, axis=0)                   # [L, 4]
     h = rms_norm(h, params["final_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
-    if return_hidden:
-        return h, kv
+    out = h if return_hidden else lm_head(params, cfg, h)
+    if return_moe_stats:
+        return out, kv, {
+            "dropped": jnp.sum(stats[:, 0]).astype(jnp.int32),
+            # [dropped, routed, busiest expert over the mean (max over
+            # layers), distinct experts touched and the grouped product's
+            # tile fill (means over MoE layers)]
+            "vector": jnp.stack([
+                jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]),
+                jnp.max(stats[:, 2]),
+                jnp.sum(stats[:, 3]) / max(cfg.num_moe_layers, 1),
+                jnp.sum(stats[:, 4]) / max(cfg.num_moe_layers, 1),
+            ]),
+        }
+    return out, kv
+
+
+def lm_head(params: Params, cfg: ModelConfig, h):
+    """Logits ``[..., V]`` (float32) of normed hidden rows ``h [..., E]``:
+    what ``forward`` ends with; a caller that took ``return_hidden`` calls
+    it on the rows it samples only."""
     if cfg.tie_word_embeddings:
         w_out = params["embed"]["weight"].T
         out_scale = params["embed"].get("embed_scale")  # [V, 1] if quantized
@@ -849,29 +873,16 @@ def forward(
         if w_out.dtype == jnp.int8:
             w_out = w_out.astype(h.dtype)
         logits = jax.lax.dot_general(
-            h, w_out, (((2,), (0,)), ((), ())),
+            h, w_out, (((h.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if out_scale is not None:
-            logits = logits * out_scale[None, None, :]
+            logits = logits * out_scale
         if cfg.logits_soft_cap:
             logits = cfg.logits_soft_cap * jnp.tanh(
                 logits / cfg.logits_soft_cap
             )
-    if return_moe_stats:
-        return logits, kv, {
-            "dropped": jnp.sum(stats[:, 0]).astype(jnp.int32),
-            # [dropped, routed, busiest expert over the mean (max over
-            # layers), distinct experts touched and the grouped product's
-            # tile fill (means over MoE layers)]
-            "vector": jnp.stack([
-                jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]),
-                jnp.max(stats[:, 2]),
-                jnp.sum(stats[:, 3]) / max(cfg.num_moe_layers, 1),
-                jnp.sum(stats[:, 4]) / max(cfg.num_moe_layers, 1),
-            ]),
-        }
-    return logits, kv
+    return logits
 
 
 def prefill_attn_fn(q, k, v, layer_cache, positions, *, segment_ids=None,

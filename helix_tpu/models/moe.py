@@ -214,7 +214,8 @@ def experts_xla(xs, group_sizes, e_row, experts_p, layer, act):
 
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             return_dropped=False, return_stats=False, stacked_experts=None,
-            backend=None, interpret=False, expert_bias=None):
+            backend=None, interpret=False, expert_bias=None,
+            decode_rows: int = 0):
     """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
     ``return_dropped`` also the int32 count of (token, choice) assignments
     this call dropped to capacity overflow (always 0 on the dropless
@@ -233,7 +234,8 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     ``backend``: as the attention dispatchers take it (``None``: what the
     process' devices call for), for the dropless path's grouped product
     (``grouped_backend``); ``interpret`` runs its kernel in interpret mode
-    (the CPU tests)."""
+    (the CPU tests).  ``decode_rows``: the last that many tokens are decode
+    rows on a prefill's axis (``_capacity_experts``)."""
     B, S, E = x.shape
     X = cfg.num_experts
     T = B * S
@@ -248,7 +250,7 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     fill = 0.0
     if cfg.expert_capacity_factor > 0:
         out, dropped = _capacity_experts(
-            xf, top_w, top_idx, valid, experts_p, cfg, act, S
+            xf, top_w, top_idx, valid, experts_p, cfg, act, S, decode_rows
         )
     else:
         if stacked_experts is not None:
@@ -267,17 +269,24 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     return out
 
 
-def _capacity_experts(xf, top_w, top_idx, valid, experts_p, cfg, act, S):
+def _capacity_experts(xf, top_w, top_idx, valid, experts_p, cfg, act, S,
+                      decode_rows: int = 0):
     """GShard capacity dispatch.  C = factor * T * k / X for prefill
-    shapes; decode (S == 1) runs DROPLESS (C = T)."""
+    shapes; decode (S == 1) runs DROPLESS (C = T).  With ``decode_rows``
+    the axis is a prefill's tokens and then that many decode rows: the
+    prefill tokens meet the capacity they would meet alone, and the decode
+    rows get slots of their own behind it, one a row and expert: they
+    neither drop nor push a prefill token out."""
     T, E = xf.shape
     X = cfg.num_experts
     k = cfg.num_experts_per_tok
+    n_pre = T - decode_rows
     # --- capacity + position of each (token, choice) in its expert ---
     if S == 1:
-        C = T                                        # dropless decode
+        C_pre = T                                    # dropless decode
     else:
-        C = max(int(cfg.expert_capacity_factor * T * k / X), 1)
+        C_pre = max(int(cfg.expert_capacity_factor * n_pre * k / X), 1)
+    C = C_pre + decode_rows
     # choice-major flattening ranks first choices ahead of second
     # choices across the batch, so capacity overflow drops the weaker
     # assignments first; invalid tokens are routed to a sentinel so they
@@ -286,11 +295,25 @@ def _capacity_experts(xf, top_w, top_idx, valid, experts_p, cfg, act, S):
         jnp.tile(valid, k), top_idx.T.reshape(-1), X
     )                                               # [k*T] expert ids
     onehot = jax.nn.one_hot(flat_idx, X, dtype=jnp.int32)   # [kT, X]
-    pos_in_expert = (
-        jnp.cumsum(onehot, axis=0) - onehot
-    )                                               # [kT, X]
+    limit = C_pre
+    if decode_rows:
+        # positions count within the prefill tokens and within the decode
+        # rows apart, the decode rows' from C_pre on
+        tail = jnp.tile(jnp.arange(T) >= n_pre, k)  # [kT]
+        tail_hot = onehot * tail[:, None]
+        pre_hot = onehot - tail_hot
+        pos_in_expert = (
+            jnp.cumsum(pre_hot, axis=0) - pre_hot
+        ) * pre_hot + (
+            C_pre + jnp.cumsum(tail_hot, axis=0) - tail_hot
+        ) * tail_hot
+        limit = jnp.where(tail, C, C_pre)
+    else:
+        pos_in_expert = (
+            jnp.cumsum(onehot, axis=0) - onehot
+        )                                           # [kT, X]
     pos = jnp.sum(pos_in_expert * onehot, axis=-1)  # [kT]
-    keep = (pos < C) & (flat_idx < X)
+    keep = (pos < limit) & (flat_idx < X)
     # capacity-overflow accounting: a valid assignment (real token, real
     # expert) whose position overflowed C — exactly the work that falls
     # back to the residual stream

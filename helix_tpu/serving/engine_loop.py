@@ -1458,6 +1458,8 @@ class EngineLoop:
             getattr(eng, "num_preemptions", 0),
             getattr(eng, "num_resumes", 0),
             getattr(eng, "num_ctx_stream_chunks", 0),
+            getattr(eng, "num_joint_pass_steps", 0),
+            getattr(eng, "num_joint_pass_inert_rows", 0),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1469,7 +1471,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0) = pre
+         cs0, jp0, ji0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1507,6 +1509,12 @@ class EngineLoop:
             # tokens in a query block of the state segment's attention
             # call: 1 for plain decode, 8 under speculation
             "attn_q_block": getattr(eng, "attn_q_block", 0),
+            # this step's programs in which prefill rows and state rows
+            # shared one pass over the layers (1 for a wave, a chunk or a
+            # mixed step; a step of several waves counts each), and the
+            # state rows that rode those passes sitting out
+            "joint_pass": getattr(eng, "num_joint_pass_steps", 0) - jp0,
+            "inert_rows": getattr(eng, "num_joint_pass_inert_rows", 0) - ji0,
             # layers whose state is a fixed tensor a slot (the state
             # pool), and layers with pages
             "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),

@@ -365,6 +365,12 @@ class OpenAIServer:
                 "helix_mixed_steps_total",
                 getattr(eng, "num_mixed_steps", 0), lbl,
             )
+            # programs whose prefill rows and decode rows went through
+            # the layers in one pass (each weight streamed once)
+            c.counter(
+                "helix_joint_pass_steps_total",
+                getattr(eng, "num_joint_pass_steps", 0), lbl,
+            )
             # MoE prefill routing assignments dropped to expert-capacity
             # overflow (rode the residual stream instead)
             c.counter(
@@ -377,7 +383,10 @@ class OpenAIServer:
                 # routed; of the last step read, the busiest expert's
                 # tokens over the mean and the distinct experts touched
                 # (mean over the MoE layers: it decides a decode step's
-                # weight bytes)
+                # weight bytes).  A program with a prefill segment runs
+                # ONE product over its prefill tokens and decode rows, so
+                # its ratio, experts touched and tile fill are of both
+                # together
                 c.counter(
                     "helix_moe_routed_tokens_total",
                     getattr(eng, "moe_routed_tokens", 0), lbl,

@@ -1,0 +1,145 @@
+"""What every model family's tests ask of a step program with a prefill
+segment (``engine/engine.py::_build_ragged_step_fn``): its prefill tokens
+and its state rows go through the layers in ONE pass.  Helpers only; the
+cases live in each family's test file, at that family's tiny size."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from helix_tpu.engine.engine import _build_ragged_step_fn, _host_key
+from helix_tpu.engine.ragged import PrefillPlan
+from helix_tpu.engine.sampling import SamplingParams, SamplingState
+
+
+def dummy_plan(eng, rung: int, rows: int, with_hist: bool = False):
+    """One row filling the rung against the garbage page, as ``warmup()``
+    builds it: nothing real advances."""
+    ps, maxP = eng.cache_cfg.page_size, eng.cache_cfg.max_pages_per_seq
+    plan = PrefillPlan(ps, maxP, rows)
+    plan.add(None, np.zeros((maxP,), np.int32), ps if with_hist else 0,
+             rung, [0] * rung, _host_key(0), SamplingParams())
+    return plan
+
+
+def step_program(eng, rung: int = 0, rows: int = 0, with_hist: bool = False):
+    """``(jitted step, its arguments)`` for a program of ``rows`` prefill
+    rows in the bucket ``rung`` (0: the decode-only program) beside the
+    engine's decode rows."""
+    eng._sync_state()
+    pargs = ()
+    if rung:
+        a = dummy_plan(eng, rung, rows, with_hist).finalize_device(
+            rung, with_state=eng.cache.state is not None)
+        pargs = (a["tokens"], a["pos"], a["seg"], a["pages"], a["offsets"],
+                 a["t0"], a["qlen"], a["hist"], a["tables"], a["ends"],
+                 SamplingState.from_params([SamplingParams()] * rows),
+                 a["keys"])
+        if eng.cache.state is not None:
+            pargs += (a["slots"], a["snaps"])
+    n_tail = eng._n_tail_max
+    if eng.model_cfg.loop_bodies > 2 and rung:
+        n_tail = 0
+    fn = _build_ragged_step_fn(
+        eng.model_cfg, eng.cache_cfg.page_size, eng._backend, eng.mesh,
+        rung, with_hist, rows, eng._spec_width(), n_tail, 0, 0, 0, 0)
+    args = (eng._graft_params(), eng.cache, eng._dstate, pargs,
+            jnp.asarray(eng._zero_drafts), jnp.asarray(eng._zero_rows),
+            jnp.int32(0), None)
+    return fn, args
+
+
+def products_outside_the_tail(fn, args, primitive: str, scope: str) -> int:
+    """Equations of ``primitive`` under the named scope ``scope`` in the
+    step's jaxpr, the fused tail's own forward left out: how many times
+    the program's layer bodies hold that product."""
+    def walk(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            stack = str(eqn.source_info.name_stack)
+            if (eqn.primitive.name == primitive and scope in stack
+                    and "tail" not in stack.split("/")):
+                n += 1
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n += walk(sub)
+        return n
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def assert_one_forward(eng, rung: int, rows: int, primitive: str,
+                       scope: str) -> None:
+    """A program with a prefill segment holds the products of ONE forward:
+    as many as the decode-only program (the two-call form held twice
+    that)."""
+    alone = products_outside_the_tail(
+        *step_program(eng), primitive, scope)
+    both = products_outside_the_tail(
+        *step_program(eng, rung, rows), primitive, scope)
+    assert alone > 0 and both == alone, (alone, both)
+
+
+def decode_state_of(eng) -> dict:
+    st = eng._dstate
+    return {k: np.asarray(getattr(st, k)) for k in
+            ("positions", "last_token", "keys", "token_counts")}
+
+
+def assert_inert_wave_keeps_decode_state(eng, rung: int) -> None:
+    """A program whose every state row sits out (an admission wave, a chunk
+    without live rows) leaves ``DecodeState`` bit for bit: no key split, no
+    count, no position."""
+    eng._sync_state()
+    before = decode_state_of(eng)
+    assert before["positions"].any(), "no slot is decoding"
+    n = eng.num_joint_pass_steps
+    eng._ragged_step("admit", plan=dummy_plan(eng, rung, 1),
+                     draft_len=eng._inert_rows, n_extra=0)
+    after = decode_state_of(eng)
+    for k in before:
+        assert np.array_equal(before[k], after[k]), k
+    assert eng.num_joint_pass_steps == n + 1
+    assert eng.num_joint_pass_inert_rows >= len(before["positions"])
+
+
+def run_both_ways(make_engine, make_reqs, watch_id: str):
+    """The same requests with the mixed step on (a chunk and the live decode
+    rows share one program: one pass) and off (the chunk's program with every
+    state row inert, then the decode step alone).  Returns ``{mixed: (tokens
+    by request, the watched request's logits by tokens out, engine)}``."""
+    out = {}
+    for mixed in (True, False):
+        eng = make_engine(enable_mixed_step=mixed)
+        reqs = make_reqs()
+        watch = next(r for r in reqs if r.id == watch_id)
+        for r in reqs:
+            eng.add_request(r)
+        logits = {}
+        while eng.has_work():
+            eng.step()
+            n = len(watch.output_tokens)
+            if (n and n not in logits and watch.slot is not None
+                    and eng.slots[watch.slot] is watch):
+                logits[n] = np.asarray(
+                    eng.next_token_logits()[watch.slot])
+        out[mixed] = ({r.id: list(r.output_tokens) for r in reqs},
+                      logits, eng)
+    return out
+
+
+def assert_mixed_is_chunk_then_decode(make_engine, make_reqs, watch_id: str,
+                                      tol: float) -> None:
+    """Greedy: a program with a chunk and live decode rows gives the tokens
+    of the chunk run alone followed by the decode step alone, and the
+    watched request's logits within ``tol``."""
+    out = run_both_ways(make_engine, make_reqs, watch_id)
+    (tok_m, log_m, eng_m), (tok_s, log_s, eng_s) = out[True], out[False]
+    assert eng_m.num_mixed_steps > 0 and eng_s.num_mixed_steps == 0
+    assert eng_m.num_joint_pass_steps > 0
+    assert tok_m == tok_s
+    both = sorted(set(log_m) & set(log_s))
+    assert len(both) >= 3, (sorted(log_m), sorted(log_s))
+    assert max(np.abs(log_m[n] - log_s[n]).max() for n in both) < tol

@@ -1063,6 +1063,56 @@ class TestMixedStep:
         assert dec_m == dec_s
         assert long_m == long_s
 
+    def _decoding(self, tiny_model, **over):
+        cfg, params = tiny_model
+        eng = Engine(cfg, params, self._cfg(**over))
+        eng.add_request(Request(
+            id="dec", prompt_tokens=[1, 2, 3],
+            sampling=SamplingParams(temperature=0.7, seed=5, max_tokens=64,
+                                    presence_penalty=0.5),
+        ))
+        eng.step()
+        eng.step()
+        return eng
+
+    def test_a_program_with_a_chunk_holds_one_forward(self, tiny_model):
+        """The prefill tokens and the state rows go through the layers in
+        ONE pass: the MLP's products are in the program once."""
+        import joint_pass
+
+        eng = self._decoding(tiny_model)
+        joint_pass.assert_one_forward(eng, 8, 1, "dot_general", "mlp.down")
+        joint_pass.assert_one_forward(eng, 8, 1, "dot_general", "lm_head")
+
+    def test_chunk_beside_decode_rows_is_the_chunk_then_the_decode_step(
+            self, tiny_model):
+        import joint_pass
+
+        cfg, params = tiny_model
+
+        def reqs():
+            return [
+                Request(id="dec", prompt_tokens=[5, 6, 7],
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=20)),
+                Request(id="long", prompt_tokens=list(range(2, 40)),
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=6)),
+            ]
+
+        joint_pass.assert_mixed_is_chunk_then_decode(
+            lambda **kw: Engine(cfg, params, self._cfg(
+                mixed=kw["enable_mixed_step"])), reqs, "long", 1e-4)
+
+    def test_a_wave_of_inert_rows_leaves_the_decode_state_bit_for_bit(
+            self, tiny_model):
+        """A sampled, penalised row beside an admission wave: its key,
+        histogram, position and last token are not touched."""
+        import joint_pass
+
+        joint_pass.assert_inert_wave_keeps_decode_state(
+            self._decoding(tiny_model), 8)
+
     @pytest.mark.slow  # ~43 s; mixed-step parity + int8-engine parity
     # siblings keep both axes covered in tier-1
     def test_mixed_step_with_int8_kv(self, tiny_model):

@@ -462,6 +462,58 @@ def test_mixed_step_rows_equal_each_row_alone():
     assert _worst(la, lc) < TOL
 
 
+def _decoding(cfg, params):
+    eng = _engine(cfg, params, slots=3)
+    eng.add_request(Request(
+        id="d", prompt_tokens=tokens_of(7, seed=3),
+        sampling=SamplingParams(max_tokens=40, temperature=0.8, seed=11,
+                                frequency_penalty=0.3)))
+    eng.step()
+    eng.step()
+    return eng
+
+
+def test_a_chunk_and_the_decode_rows_share_one_pass():
+    """A program with a prefill segment holds each loop body's products
+    once: the expert layers' grouped products, the conv layers' in_proj."""
+    import joint_pass
+
+    cfg = tiny()
+    eng = _decoding(cfg, init_params(cfg, jax.random.PRNGKey(4)))
+    joint_pass.assert_one_forward(eng, 16, 1, "ragged_dot_general", "moe.experts")
+    joint_pass.assert_one_forward(eng, 16, 1, "dot_general", "conv.in_proj")
+
+
+def test_a_chunk_beside_decode_rows_is_the_chunk_then_the_decode_step():
+    """Both pools: the chunk's rows and the decode rows read and write
+    their own slots' conv states in one loop body."""
+    import joint_pass
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(4))
+
+    def reqs():
+        return [_req("s", tokens_of(6, seed=5), n=14),
+                _req("x", tokens_of(44, seed=2), n=6)]
+
+    with jax.default_matmul_precision("highest"):
+        joint_pass.assert_mixed_is_chunk_then_decode(
+            lambda **kw: _engine(cfg, params, slots=3, **kw), reqs, "x", TOL)
+
+
+def test_a_wave_of_inert_rows_leaves_the_decode_state_and_the_states():
+    """Every state row sits the wave out: ``DecodeState`` bit for bit, and
+    no slot's conv state is written (the dummy row has no slot)."""
+    import joint_pass
+
+    cfg = tiny()
+    eng = _decoding(cfg, init_params(cfg, jax.random.PRNGKey(4)))
+    before = np.asarray(eng.cache.state)
+    joint_pass.assert_inert_wave_keeps_decode_state(eng, 16)
+    assert np.array_equal(before, np.asarray(eng.cache.state))
+    assert before.any()
+
+
 def test_a_reused_slot_starts_from_no_state():
     cfg = tiny()
     params = init_params(cfg, jax.random.PRNGKey(5))

@@ -149,6 +149,64 @@ def test_chunked_prefill_then_decode_through_the_latent_cache():
     assert eng.moe_routed_tokens > 0 and eng.moe_experts_touched > 0
 
 
+def _decoding(cfg, params, **kw):
+    """An engine with one sequence two decode steps in."""
+    eng = _engine(cfg, params, **kw)
+    eng.add_request(Request(
+        id="d", prompt_tokens=tokens_of(7, seed=3),
+        sampling=SamplingParams(max_tokens=40, temperature=0.8, seed=11,
+                                frequency_penalty=0.3)))
+    eng.step()
+    eng.step()
+    return eng
+
+
+def test_a_chunk_and_the_decode_rows_share_one_grouped_product():
+    """A program with a prefill segment holds ONE pass: the expert layers'
+    three grouped products once (the two-call form held them twice), and
+    the MoE step stats are that one product's."""
+    import joint_pass
+
+    cfg = tiny()
+    eng = _decoding(cfg, init_params(cfg, jax.random.PRNGKey(3)))
+    joint_pass.assert_one_forward(eng, 16, 1, "ragged_dot_general", "moe.experts")
+    joint_pass.assert_one_forward(eng, 16, 1, "dot_general", "moe.shared")
+    routed = eng.moe_routed_tokens
+    eng._ragged_step("mixed", plan=joint_pass.dummy_plan(eng, 16, 1),
+                     draft_len=eng._zero_rows, n_extra=0)
+    jax.block_until_ready(eng.cache.k_pages)
+    eng._drain_moe_drops()
+    # 16 prefill tokens and one live decode row, k choices each, a layer
+    k, layers = cfg.num_experts_per_tok, cfg.num_moe_layers
+    assert eng.moe_routed_tokens - routed == 17 * k * layers
+    assert eng.moe_dropped_tokens == 0
+
+
+def test_a_chunk_beside_decode_rows_is_the_chunk_then_the_decode_step():
+    import joint_pass
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(3))
+
+    def reqs():
+        return [Request(id=rid, prompt_tokens=tokens_of(n, seed=sd),
+                        sampling=SamplingParams(max_tokens=m,
+                                                temperature=0.0))
+                for rid, n, sd, m in (("s", 6, 5, 14), ("x", 41, 1, 6))]
+
+    with jax.default_matmul_precision("highest"):
+        joint_pass.assert_mixed_is_chunk_then_decode(
+            lambda **kw: _engine(cfg, params, **kw), reqs, "x", 2e-5)
+
+
+def test_a_wave_of_inert_rows_leaves_the_decode_state_bit_for_bit():
+    import joint_pass
+
+    cfg = tiny()
+    joint_pass.assert_inert_wave_keeps_decode_state(
+        _decoding(cfg, init_params(cfg, jax.random.PRNGKey(3))), 16)
+
+
 def test_padding_rows_and_idle_slots_change_no_live_rows_logits():
     cfg = tiny()
     params = init_params(cfg, jax.random.PRNGKey(4))

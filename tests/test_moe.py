@@ -310,6 +310,39 @@ class TestMoEDropCounter:
         )
         assert int(dropped_masked) == 0   # 1 token, 2 choices, both fit
 
+    @pytest.mark.parametrize("factor", [0.3, 2.0])
+    def test_decode_rows_on_a_prefills_axis_route_as_each_alone(self, factor):
+        """A step's one pass: ``decode_rows`` decode rows ride behind a
+        prefill's tokens.  The prefill tokens keep and drop what they keep
+        and drop alone (their own capacity), and the decode rows stay
+        dropless, as a decode call's are."""
+        cfg = tiny_moe_cfg(expert_capacity_factor=factor)
+        E, X, F = cfg.hidden_size, 4, cfg.intermediate_size
+        ks = jax.random.split(jax.random.PRNGKey(5), 5)
+        x = jax.random.normal(ks[0], (1, 19, E), jnp.float32)
+        # decode rows that all want the experts the prefill overflows
+        x = x.at[0, 12:].set(x[0, 3] + 0.01 * x[0, 12:])
+        router_w = jax.random.normal(ks[1], (E, X), jnp.float32)
+        mats = {
+            n: {"weight": 0.1 * jax.random.normal(k, shp, jnp.float32)}
+            for n, k, shp in (("w_gate", ks[2], (X, E, F)),
+                              ("w_up", ks[3], (X, E, F)),
+                              ("w_down", ks[4], (X, F, E)))}
+        mask = jnp.ones((1, 19), bool).at[0, 5].set(False).at[0, 15].set(
+            False)
+        both, d_both = moe_ffn(x, router_w, mats, cfg, jax.nn.silu,
+                               token_mask=mask, return_dropped=True,
+                               decode_rows=7)
+        pre, d_pre = moe_ffn(x[:, :12], router_w, mats, cfg, jax.nn.silu,
+                             token_mask=mask[:, :12], return_dropped=True)
+        dec, d_dec = moe_ffn(x[0, 12:, None], router_w, mats, cfg,
+                             jax.nn.silu, token_mask=mask[0, 12:, None],
+                             return_dropped=True)
+        assert int(d_dec) == 0 and int(d_both) == int(d_pre)
+        assert (int(d_pre) > 0) == (factor < 1)
+        np.testing.assert_allclose(both[0, :12], pre[0], atol=1e-6)
+        np.testing.assert_allclose(both[0, 12:], dec[:, 0], atol=1e-6)
+
     def test_engine_counts_prefill_drops(self):
         """The serving engine surfaces prefill capacity overflow in its
         per-engine counter instead of dropping silently (ADVICE r5)."""

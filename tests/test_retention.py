@@ -368,6 +368,50 @@ def test_a_mixed_step_gives_each_row_what_it_gets_alone(model):
         assert np.abs(both[n] - alone[n]).max() < TOL
 
 
+def _decoding(model):
+    cfg, params = model
+    eng = _engine(cfg, params)
+    eng.add_request(_req("d", tokens_of(7, 3), 40, seed=11))
+    eng.step()
+    eng.step()
+    return eng
+
+
+def test_a_chunk_and_the_decode_rows_share_one_pass(model):
+    """One pass a program: the projections and the MLP once, the chunked
+    form for the prefill rows and the recurrence for the state rows inside
+    the one mixer call."""
+    import joint_pass
+
+    eng = _decoding(model)
+    joint_pass.assert_one_forward(eng, 16, 1, "dot_general", "mlp.down")
+    joint_pass.assert_one_forward(
+        eng, 16, 1, "dot_general", "retention.out_proj")
+
+
+def test_a_chunk_beside_decode_rows_is_the_chunk_then_the_decode_step(model):
+    import joint_pass
+
+    cfg, params = model
+
+    def reqs():
+        return [_req("s", tokens_of(9, 5), 14), _req("x", tokens_of(40, 4))]
+
+    joint_pass.assert_mixed_is_chunk_then_decode(
+        lambda **kw: _engine(cfg, params, **kw), reqs, "x", TOL)
+
+
+def test_a_wave_of_inert_rows_leaves_the_decode_state_and_the_pool(model):
+    import joint_pass
+
+    eng = _decoding(model)
+    before = [np.asarray(a) for a in eng.cache.state]
+    joint_pass.assert_inert_wave_keeps_decode_state(eng, 16)
+    for a, b in zip(before, eng.cache.state):
+        assert np.array_equal(a, np.asarray(b))
+    assert before[0].any()
+
+
 def test_a_reused_slot_starts_from_zeros(model):
     """One slot, two requests one after the other: the second reads what it
     reads on a fresh engine, not the state the first left."""

@@ -188,6 +188,65 @@ class TestGreedyEquivalence:
         assert off.generate([REP], sp) == o1
 
 
+class TestVerifyBesideAChunk:
+    """The verify rows (W = 8 a slot) ride the same pass as a prefill
+    segment: they accept what they accept in a program of their own."""
+
+    def _ready(self, tiny_model):
+        cfg, params = tiny_model
+        eng = make_engine(cfg, params, True, spec_tokens=7,
+                          max_decode_batch=3, max_prefill_len=32)
+        for i, p in enumerate((REP, MIX, REP[2:])):
+            eng.add_request(Request(
+                id=f"r{i}", prompt_tokens=list(p),
+                sampling=SamplingParams(temperature=0.0, max_tokens=40)))
+        eng.step()
+        eng._sync_state()
+        return eng
+
+    def test_a_verify_step_beside_a_chunk_accepts_what_it_accepted(
+            self, tiny_model):
+        import joint_pass
+
+        cfg, params = tiny_model
+        # what each sequence goes on to say, from a plain engine
+        plain = make_engine(cfg, params, False, max_decode_batch=3)
+        outs = plain.generate(
+            [REP, MIX, REP[2:]],
+            SamplingParams(temperature=0.0, max_tokens=12))
+        got = {}
+        for beside in (False, True):
+            eng = self._ready(tiny_model)
+            assert eng._spec_width() == 8
+            drafts = np.zeros((3, 7), np.int32)
+            for i, req in enumerate(eng.slots):
+                n = len(req.output_tokens)
+                drafts[i] = outs[int(req.id[1:])][n:n + 7]
+            drafts[1, 3] += 1           # slot 1: the fourth draft is wrong
+            draft_len = np.asarray([7, 7, 0], np.int32)
+            plan = joint_pass.dummy_plan(eng, 16, 1) if beside else None
+            n_joint = eng.num_joint_pass_steps
+            _, sampled, emit, _ = eng._ragged_step(
+                "mixed" if beside else "spec", plan=plan, drafts=drafts,
+                draft_len=draft_len, n_extra=0)
+            got[beside] = (np.asarray(sampled), np.asarray(emit),
+                           joint_pass.decode_state_of(eng))
+            assert eng.num_joint_pass_steps == n_joint + int(beside)
+        (s0, e0, st0), (s1, e1, st1) = got[False], got[True]
+        assert e0.tolist() == [8, 4, 1] and e1.tolist() == e0.tolist()
+        for b in range(3):
+            assert s0[b, :e0[b]].tolist() == s1[b, :e1[b]].tolist()
+        for k in st0:
+            assert np.array_equal(st0[k], st1[k]), k
+
+    def test_the_verify_rows_and_the_chunk_share_one_forward(
+            self, tiny_model):
+        import joint_pass
+
+        eng = self._ready(tiny_model)
+        joint_pass.assert_one_forward(eng, 16, 1, "dot_general", "mlp.down")
+
+
 class TestDistributionPreservation:
     """Sampled (temperature > 0) outputs keep the target distribution."""
 

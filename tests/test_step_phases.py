@@ -172,6 +172,14 @@ def test_histogram_metric_finds_its_series(spine, spec):
     assert prom.mean_of_histogram_ms(zero, parsed, series) >= 0.0
 
 
+def test_metrics_count_the_joint_pass_programs(spine):
+    """``helix_joint_pass_steps_total``: the programs whose prefill rows and
+    decode rows shared one pass (every admission here launched one)."""
+    text = requests.get(f"{spine['url']}/metrics", timeout=10).text
+    parsed = prom.parse(text, MODEL)
+    assert parsed["helix_joint_pass_steps_total"] >= 4
+
+
 # ---- (b) flight phases and one observation a step -------------------------
 
 
@@ -228,6 +236,23 @@ def test_every_flight_record_carries_the_state_segments_query_block(stepped):
     recs = stepped.flight.snapshot(recent=512)["recent"]
     assert recs and {rec["attn_q_block"] for rec in recs} == {1}
     assert stepped.engine.attn_q_block == 1
+
+
+def test_flight_records_carry_the_joint_pass_and_its_inert_rows(stepped):
+    """A step that launched a program with a prefill segment (a wave, a
+    chunk, a mixed step) says so, and how many state rows rode that pass
+    sitting out; a plain decode step says 0 and 0; the counter adds up."""
+    recs = stepped.flight.snapshot(recent=512)["recent"]
+    eng = stepped.engine
+    joint = [rec for rec in recs if rec["joint_pass"]]
+    assert joint and all(rec["prefill_tokens"] for rec in joint)
+    assert any(rec["joint_pass"] == 0 and rec["inert_rows"] == 0
+               and rec["kind"] == "decode" for rec in recs)
+    # an admission wave's state rows all sit out
+    assert any(rec["inert_rows"] >= len(eng.slots) for rec in joint)
+    # (the ring leaves out the loop's first step, which launched some)
+    assert 0 < sum(rec["joint_pass"] for rec in recs) <= (
+        eng.num_joint_pass_steps)
 
 
 @pytest.mark.parametrize("name", LOOP_PHASES)
@@ -301,27 +326,11 @@ def test_jitted_step_carries_its_shapes_name(warmed):
 
 @pytest.fixture(scope="module")
 def lowered_text(warmed):
-    eng = warmed
-    plan_rung = eng._token_ladder[-1]
-    B = eng.cfg.max_decode_batch
-    # a step with a prefill segment: take the arguments warmup() builds
-    from helix_tpu.engine.engine import _host_key
-    from helix_tpu.engine.ragged import PrefillPlan
-    from helix_tpu.engine.sampling import SamplingState
-    import numpy as np
+    """A step with a prefill segment, on the arguments warmup() builds."""
+    import joint_pass
 
-    ps, maxP = eng.cache_cfg.page_size, eng.cache_cfg.max_pages_per_seq
-    plan = PrefillPlan(ps, maxP, B)
-    plan.add(None, np.zeros((maxP,), np.int32), 0, plan_rung,
-             [0] * plan_rung, _host_key(0), SamplingParams())
-    a = plan.finalize_device(plan_rung)
-    sampling = SamplingState.from_params([SamplingParams()] * B)
-    pargs = (a["tokens"], a["pos"], a["seg"], a["pages"], a["offsets"],
-             a["t0"], a["qlen"], a["hist"], a["tables"], a["ends"],
-             sampling, a["keys"])
-    args = list(decode_step_args(eng))
-    args[3] = pargs
-    fn = built(eng, plan_rung, False, B)
+    fn, args = joint_pass.step_program(
+        warmed, warmed._token_ladder[-1], warmed.cfg.max_decode_batch)
     return fn.lower(*args).as_text(debug_info=True)
 
 
@@ -401,6 +410,17 @@ def test_capture_launch_span_names_the_program(capture):
     assert {ln["kind"] for ln in launches} <= {
         "admit", "chunk", "mixed", "spec", "decode"}
     assert any("step_num" in s for s in events["helix.loop.step"])
+
+
+def test_capture_launch_span_says_whether_the_segments_shared_a_pass(capture):
+    _, _, events = capture
+    launches = events["helix.loop.launch"]
+    assert all({"joint_pass", "inert_rows"} <= set(ln) for ln in launches)
+    for ln in launches:
+        assert ln["joint_pass"] == int(ln["token_bucket"] > 0)
+        if not ln["joint_pass"]:
+            assert ln["inert_rows"] == 0
+    assert any(ln["joint_pass"] for ln in launches)
 
 
 def test_capture_launch_span_carries_the_query_block(capture):

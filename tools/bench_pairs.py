@@ -12,10 +12,12 @@ Each run is ``<side>:<cell>:<seed>:<trace>``: ``benchmark/run.py`` from
 ``.chip_parent/`` (``git archive <parent>`` with ``BENCHMARK.json``,
 ``benchmark/`` and ``tests/benchmark/`` laid over it) or from
 ``.chip_tree/final/`` (``git archive $(git write-tree)``: the files git
-would commit); both are git-ignored.  Every run's JSON lines go to
+would commit); both are git-ignored; ``--tree <side>=<dir>`` names another
+tree for a side.  Every run's JSON lines go to
 ``chiprun_out/pairs/<tag>.jsonl``, its server log and trace summary beside
-it, and one line a run (side, cell, seed, the metrics) to stdout.  A run is
-not started when ``--per-run`` seconds more would pass ``--budget``.
+it (after a traced run also ``programs.json``: ``tools/program_times.py`` over
+the capture), and one line a run (side, cell, seed, the metrics) to stdout.
+A run is not started when ``--per-run`` seconds more would pass ``--budget``.
 """
 import argparse
 import json
@@ -36,8 +38,13 @@ def main():
     ap.add_argument("--budget", type=float, default=3300)
     ap.add_argument("--per-run", type=float, default=600,
                     help="seconds a run is expected to need at most")
+    ap.add_argument("--tree", action="append", default=[],
+                    metavar="SIDE=DIR", help="a side's tree, from the root")
     ap.add_argument("runs", nargs="+")
     a = ap.parse_args()
+    trees = dict(TREES, **{
+        side: os.path.join(ROOT, path)
+        for side, path in (t.split("=", 1) for t in a.tree)})
     t0 = time.monotonic()
     out_dir = os.path.join(ROOT, "chiprun_out", "pairs")
     os.makedirs(out_dir, exist_ok=True)
@@ -47,7 +54,7 @@ def main():
         if time.monotonic() - t0 + a.per_run > a.budget:
             print(f"SKIPPED {spec}: budget", flush=True)
             continue
-        tree = TREES[side]
+        tree = trees[side]
         t1 = time.monotonic()
         r = subprocess.run(
             [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",
@@ -69,6 +76,14 @@ def main():
             if os.path.isfile(p) and os.path.getsize(p) < 8 << 20 and (
                     name.endswith(".log") or name.endswith(".json")):
                 shutil.copy(p, keep)
+        if int(trace) and os.path.isdir(os.path.join(bo, "profiles")):
+            # (a reader that fails or runs out of time costs its file only)
+            subprocess.run(
+                [sys.executable, os.path.join(ROOT, "tools", "program_times.py"),
+                 os.path.join(bo, "profiles"),
+                 "--out", os.path.join(keep, "programs.json")],
+                env=dict(os.environ, JAX_PLATFORMS="cpu",
+                         TPU_LOG_DIR="disabled"))
         last = rec["lines"][-1] if rec["lines"] else {}
         print(json.dumps({"side": side, "cell": cell, "seed": seed,
                           "trace": trace, "exit": r.returncode,
