@@ -538,6 +538,78 @@ class EngineLoopObs:
                 buckets=FAST_BUCKETS,
             ),
         }
+        # the host's account (ISSUE 37), each observed once an engine
+        # step like the phases above, 0 where nothing ran.  The PARTS of
+        # admit and dispatch: spans that write to Engine.step_parts (a
+        # sink beside step_phases, so the parents read what they read),
+        # self time among the parts, each the sum of both parents' calls
+        # (a wave's launch runs under admit, the step program's under
+        # dispatch); host_build less the four is slot scans, the decode
+        # window and the scheduler's pops
+        self.step_parts = {
+            "helix.loop.claim": Histogram(
+                "helix_step_claim_seconds",
+                "Claiming slots and pages per engine step: prefix lookup, "
+                "page allocation, state restore, and the bookkeeping and "
+                "page adoption of the prompts just launched",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.plan": Histogram(
+                "helix_step_plan_seconds",
+                "Building the ragged plan per engine step: the rows, the "
+                "plan's device arrays and the rows' sampling state",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.sync_state": Histogram(
+                "helix_step_sync_state_seconds",
+                "Uploading the slots' host mirrors and merging them into "
+                "the device's decode state per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "helix.loop.launch": Histogram(
+                "helix_step_launch_seconds",
+                "The jitted calls of an engine step: parameter graft, "
+                "argument flattening, donation and the runtime's enqueue",
+                buckets=FAST_BUCKETS,
+            ),
+        }
+        # CPU beside wall: the engine thread's CPU over the interval
+        # host_build times, and each host thread's CPU since the step
+        # before (the engine thread reads the three thread clocks); the
+        # three over the step's wall bound the one GIL's use from above
+        # (what a thread runs outside the GIL is CPU too)
+        self.host_build_cpu = Histogram(
+            "helix_step_host_build_cpu_seconds",
+            "CPU seconds of the engine thread inside the interval "
+            "helix_step_host_build_seconds times, per engine step",
+            buckets=FAST_BUCKETS,
+        )
+        self.threads_cpu = {
+            "engine": Histogram(
+                "helix_step_engine_cpu_seconds",
+                "CPU seconds of the engine thread since the step before, "
+                "per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "emit": Histogram(
+                "helix_step_emit_cpu_seconds",
+                "CPU seconds of the emission worker since the step "
+                "before, per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+            "http": Histogram(
+                "helix_step_http_cpu_seconds",
+                "CPU seconds of the thread that submits requests (the "
+                "HTTP event loop) since the step before, per engine step",
+                buckets=FAST_BUCKETS,
+            ),
+        }
+        self.gc_seconds = Histogram(
+            "helix_step_gc_seconds",
+            "Collector pauses (the span helix.gc, whichever thread "
+            "triggered them) since the step before, per engine step",
+            buckets=FAST_BUCKETS,
+        )
         # request stages (ISSUE 25), beside queue_wait: handler entry to
         # first SSE chunk in five consecutive pieces, each also a span in
         # the request's trace
@@ -569,6 +641,8 @@ class EngineLoopObs:
             self.step_seconds, self.host_build, self.exposed_host,
             self.emit_seconds, self.emit_deliver, self.emit_queue_wait, self.emit_backpressure,
             *self.step_phases.values(), *self.state_phases.values(),
+            *self.step_parts.values(), self.host_build_cpu,
+            *self.threads_cpu.values(), self.gc_seconds,
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,
         ):

@@ -20,6 +20,7 @@ in-flight work before the thread exits.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import logging
 import queue
 import threading
@@ -121,6 +122,9 @@ class _EmissionStage:
         self.started = False
         self.batches = 0
         self.parked = _Parked()
+        # the worker's CPU clock, for the engine thread to read
+        # (time.clock_gettime); taken by the worker itself as it starts
+        self.cpu_clock: Optional[int] = None
 
     def start(self, name: str = "emit") -> None:
         self._thread = threading.Thread(
@@ -169,6 +173,7 @@ class _EmissionStage:
         return self._q.qsize()
 
     def _run(self) -> None:
+        self.cpu_clock = time.pthread_getcpuclockid(threading.get_ident())
         while True:
             item = self._q.get()
             try:
@@ -307,6 +312,19 @@ class EngineLoop:
         self.pipelined_steps = 0    # steps dispatched while one was in flight
         self._emit_stage = _EmissionStage(self._deliver, self.obs)
         self._phases = obs_trace.Phases()   # the step in progress
+        self._parts = obs_trace.Phases()    # the parts of its admit and dispatch
+        # the host's account (ISSUE 37).  The CPU clock of the thread
+        # that submits (the HTTP event loop), taken by that thread in
+        # ``submit``; what the engine thread last read of each host
+        # thread's clock, ``{name: (clock, seconds)}``; collector pauses
+        # so far (added by ``_gc_hook`` on whichever thread collects) and
+        # as of the last step
+        self._http_ident: Optional[int] = None
+        self._http_clock: Optional[int] = None
+        self._cpu_seen: dict = {}
+        self._gc_total = 0.0
+        self._gc_seen = 0.0
+        self._gc_span: Optional[obs_trace.phase] = None
         # host-side device-busy watermark: the last completion's return
         # time.  A dispatch that happens with nothing in flight charges
         # the gap since this watermark as device idle (idle_gap_s).
@@ -481,6 +499,12 @@ class EngineLoop:
         # passes through, everything else gets the profile default
         if not getattr(req, "sched_class", ""):
             req.sched_class = self.sched.cfg.default_class
+        ident = threading.get_ident()
+        if ident != self._http_ident:
+            # the submitting thread's CPU clock, taken while it is
+            # certainly alive: by itself
+            self._http_clock = time.pthread_getcpuclockid(ident)
+            self._http_ident = ident
         # reject unservable requests on the caller's thread with a clean
         # event — the engine thread must never die on bad input
         err = self.engine.validate_request(req) or self.check_admission(
@@ -957,6 +981,7 @@ class EngineLoop:
         return {k: out[k] for k in SATURATION_KEYS}
 
     def start(self):
+        gc.callbacks.append(self._gc_hook)
         self._emit_stage.start(self.name)
         self._thread = threading.Thread(
             target=self._run, name=f"helix-engine-{self.name}", daemon=True
@@ -985,6 +1010,25 @@ class EngineLoop:
         self._wake.set()
         if join and self._thread is not None:
             self._thread.join(timeout=30)
+        self._unhook_gc()
+
+    def _gc_hook(self, event: str, info: dict) -> None:
+        """``gc.callbacks``: a collection as the span ``helix.gc``, on
+        whichever thread triggered it (a pause stalls them all: the
+        collector holds the GIL).  Collections do not nest, so one open
+        span is all there is."""
+        if event == "start":
+            self._gc_span = obs_trace.phase(
+                "helix.gc", generation=info["generation"])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            span, self._gc_span = self._gc_span, None
+            span.__exit__(None, None, None)
+            self._gc_total += span.seconds
+
+    def _unhook_gc(self) -> None:
+        if self._gc_hook in gc.callbacks:
+            gc.callbacks.remove(self._gc_hook)
 
     # -- engine thread ------------------------------------------------------
 
@@ -1411,6 +1455,10 @@ class EngineLoop:
             ph = obs_trace.Phases()
         ph.clear()
         self._phases = ph
+        parts = getattr(self.engine, "step_parts", None)
+        if parts is not None:
+            parts.clear()
+            self._parts = parts
         return ph
 
     def _push_emit(self, emitted, ph: obs_trace.Phases) -> float:
@@ -1426,17 +1474,60 @@ class EngineLoop:
         return span.seconds
 
     def _observe_step(self, seconds: float, ph: obs_trace.Phases,
-                      exposed: float = 0.0) -> None:
+                      exposed: float = 0.0, build_cpu: float = 0.0) -> dict:
         """One observation a step of the step histogram and of every
         phase histogram (0 where the phase did not run), so the phase
-        means add up to the step's; and of the host time the device
+        means add up to the step's; of the host time the device
         waited out before this step's launch (0 for a step launched
-        behind a running one)."""
-        self.obs.step_seconds.observe(seconds)
-        self.obs.exposed_host.observe(exposed)
-        for name, hist in (*self.obs.step_phases.items(),
-                           *self.obs.state_phases.items()):
+        behind a running one); and of the host's account: the parts of
+        admit and dispatch, the engine thread's CPU while it built the
+        step, the three host threads' CPU and the collector's pauses
+        since the step before.  Returns the account as the flight record
+        files it."""
+        obs = self.obs
+        obs.step_seconds.observe(seconds)
+        obs.exposed_host.observe(exposed)
+        for name, hist in (*obs.step_phases.items(),
+                           *obs.state_phases.items()):
             hist.observe(ph.get(name, 0.0))
+        parts = self._parts
+        for name, hist in obs.step_parts.items():
+            hist.observe(parts.get(name, 0.0))
+        obs.host_build_cpu.observe(build_cpu)
+        threads = self._threads_cpu()
+        for name, hist in obs.threads_cpu.items():
+            hist.observe(threads[name])
+        gc_total = self._gc_total
+        gc_s, self._gc_seen = gc_total - self._gc_seen, gc_total
+        obs.gc_seconds.observe(gc_s)
+        return {
+            "phases_cpu": _rounded(ph.cpu),
+            "parts": _rounded(parts),
+            "parts_cpu": _rounded(parts.cpu),
+            "threads_cpu": _rounded(threads),
+            "gc_s": round(gc_s, 6),
+        }
+
+    def _threads_cpu(self) -> dict:
+        """CPU seconds the engine thread (the caller), the emission
+        worker and the submitting thread used since the last call; 0 for
+        a thread first seen now, or gone."""
+        reads = {"engine": (None, obs_trace.thread_cpu())}
+        for name, clock in (("emit", self._emit_stage.cpu_clock),
+                            ("http", self._http_clock)):
+            if clock is None:
+                continue
+            try:
+                reads[name] = (clock, time.clock_gettime(clock))
+            except OSError:     # the thread has exited
+                pass
+        seen, self._cpu_seen = self._cpu_seen, reads
+        out = {}
+        for name in ("engine", "emit", "http"):
+            now, last = reads.get(name), seen.get(name)
+            same = now is not None and last is not None and now[0] == last[0]
+            out[name] = max(0.0, now[1] - last[1]) if same else 0.0
+        return out
 
     # -- flight recorder (host-side counter deltas only) --------------------
 
@@ -1636,6 +1727,7 @@ class EngineLoop:
                 "wall_s": round(time.monotonic() - t0, 6),
                 "pipelined": 1,
                 "phases": _rounded(ph),
+                "phases_cpu": _rounded(ph.cpu),
             },
         )
         self._emit_stage.flush()
@@ -1654,6 +1746,7 @@ class EngineLoop:
                 )
             except Exception:  # noqa: BLE001 — best-effort at shutdown
                 self.engine.discard_pending(pend)
+        self._unhook_gc()
         self._emit_stage.stop()
         log.info(
             "engine '%s' emission stage stopped: %d batch(es) delivered "
@@ -1769,7 +1862,7 @@ class EngineLoop:
             if not self._reconcile_or_fail():
                 return True
         ph = self._new_phases()
-        ph.update(sched_ph)
+        ph.merge(sched_ph)
         with obs_trace.phase("helix.loop.step", step_num=self.steps):
             self._step_pass(ph, lookahead)
         return True
@@ -1789,6 +1882,7 @@ class EngineLoop:
         nothing in flight."""
         eng = self.engine
         t_step = time.monotonic()
+        c_step = obs_trace.thread_cpu()
         flight_pre = self._flight_pre()
         prev = self._inflight
         overlapped = prev is not None
@@ -1828,6 +1922,7 @@ class EngineLoop:
             )
             return
         self._inflight = None
+        build_cpu = obs_trace.thread_cpu() - c_step
         t_build_end = time.monotonic()
         dt_build = t_build_end - t_step
         idle_gap = 0.0
@@ -1891,11 +1986,11 @@ class EngineLoop:
             # flight record (a dispatch-only pass would read as
             # zero_progress to the watchdog); the step's numbers land
             # with its completion next pass
-            self._observe_step(dt_step, ph, idle_gap)
+            self._observe_step(dt_step, ph, idle_gap, build_cpu)
             return
         self._deliver_resume_failures()
         wall = time.monotonic() - t_step
-        self._observe_step(wall, ph, idle_gap)
+        account = self._observe_step(wall, ph, idle_gap, build_cpu)
         self._flight_record(
             dt_step, flight_pre, generated=len(emitted),
             timing={
@@ -1906,6 +2001,7 @@ class EngineLoop:
                 "wall_s": round(wall, 6),
                 "pipelined": 1 if overlapped else 0,
                 "phases": _rounded(ph),
+                **account,
             },
         )
 

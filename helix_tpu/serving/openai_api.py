@@ -42,6 +42,7 @@ from helix_tpu.obs.trace import (
     clock_stamp,
     collect_trace_metrics,
     is_trace_id,
+    phase,
 )
 from helix_tpu.serving.engine_loop import (
     KV_EXHAUSTED,
@@ -318,9 +319,15 @@ class OpenAIServer:
         """Prometheus text surface, rendered by the shared obs registry.
         Runs in an executor: scrape-time collectors take live locks (the
         residency manager's stats() lock is held across whole model
-        builds) and must never block the event loop."""
+        builds) and must never block the event loop.  The render is the
+        span ``helix.http.scrape`` on the executor's thread: it holds the
+        GIL, beside the engine thread's."""
+        def render():
+            with phase("helix.http.scrape", path="/metrics"):
+                return self.obs.render()
+
         text = await asyncio.get_running_loop().run_in_executor(
-            None, self.obs.render
+            None, render
         )
         return web.Response(text=text)
 
@@ -766,13 +773,14 @@ class OpenAIServer:
             # runner blocks on the build-holding ResidencyManager lock
             # (same rule as the /metrics render above)
             snap = {}
-            for m in self.registry.list():
-                if m.loop is None or (want and m.name != want):
-                    continue
-                fl = getattr(m.loop, "flight", None)
-                if fl is None:
-                    continue
-                snap[m.name] = fl.snapshot(recent=recent)
+            with phase("helix.http.scrape", path="/v1/debug/flight"):
+                for m in self.registry.list():
+                    if m.loop is None or (want and m.name != want):
+                        continue
+                    fl = getattr(m.loop, "flight", None)
+                    if fl is None:
+                        continue
+                    snap[m.name] = fl.snapshot(recent=recent)
             return snap
 
         out = await asyncio.get_running_loop().run_in_executor(
@@ -782,7 +790,9 @@ class OpenAIServer:
             return _error(
                 404, f"model {want!r} has no engine flight recorder"
             )
-        return web.json_response({"models": out})
+        # the records' serialisation, on the event loop's thread
+        with phase("helix.http.scrape", path="/v1/debug/flight"):
+            return web.json_response({"models": out})
 
     async def debug_admissions(self, request):
         """The admission-decision audit trail: a bounded ring per model

@@ -11,7 +11,11 @@ named scopes; (d) a capture through ``POST /admin/profiler`` holds the
 ``helix.loop.*`` spans and both ``helix.clock`` stamps, with the Python
 tracer off by default; (e) a streamed request's five stages are ordered
 and sum to no more than the client's time to first token; (f)
-``device_idle_ratio()`` cannot exceed 1.
+``device_idle_ratio()`` cannot exceed 1; (g) the host's account (ISSUE
+37): ``Phases.cpu`` beside wall, the four parts of admit and dispatch in
+a sink of their own (``Engine.step_parts``), the three host threads' CPU
+and the collector's pauses a step, each a histogram observed once a step
+and a field of the flight record.
 """
 
 import asyncio
@@ -502,3 +506,356 @@ def ring(records):
 def test_device_idle_ratio_cannot_exceed_one(records, expect):
     ratio = ring(records).device_idle_ratio()
     assert expect[0] <= ratio <= expect[1] <= 1.0
+
+
+# ---- (g) the host's account (ISSUE 37) ------------------------------------
+
+import gc  # noqa: E402
+
+from helix_tpu.obs import trace as obs_trace  # noqa: E402
+from helix_tpu.obs.flight import FlightRecorder  # noqa: E402
+
+PARTS = ("helix.loop.claim", "helix.loop.plan", "helix.loop.sync_state",
+         "helix.loop.launch")
+ACCOUNT_FIELDS = ("phases_cpu", "parts", "parts_cpu", "threads_cpu", "gc_s")
+
+
+def burn(seconds):
+    t = time.monotonic()
+    while time.monotonic() - t < seconds:
+        pass
+
+
+def test_phases_cpu_of_a_busy_phase_is_its_wall():
+    # a busy thread can still be descheduled on a loaded machine: the
+    # best of a few tries is judged
+    best = 0.0
+    for _ in range(8):
+        ph = obs_trace.Phases()
+        with obs_trace.phase("busy", into=ph):
+            burn(0.03)
+        assert ph.cpu["busy"] <= ph["busy"] * 1.02
+        best = max(best, ph.cpu["busy"] / ph["busy"])
+        if best >= 0.8:
+            break
+    assert best >= 0.8
+
+
+def test_phases_cpu_of_a_sleeping_phase_is_under_a_tenth_of_its_wall():
+    ph = obs_trace.Phases()
+    with obs_trace.phase("asleep", into=ph):
+        time.sleep(0.05)
+    assert ph["asleep"] >= 0.05
+    assert ph.cpu["asleep"] < 0.1 * ph["asleep"]
+
+
+def test_a_nested_phase_is_taken_out_of_wall_and_cpu_alike():
+    ph = obs_trace.Phases()
+    with obs_trace.phase("outer", into=ph) as outer:
+        burn(0.02)
+        with obs_trace.phase("inner", into=ph) as inner:
+            time.sleep(0.04)
+    assert abs(ph["outer"] + ph["inner"] - outer.seconds) < 1e-9
+    assert abs(ph["outer"] - (outer.seconds - inner.seconds)) < 1e-9
+    # the sleep is the inner phase's, on both clocks
+    assert ph.cpu["inner"] < 0.1 * ph["inner"]
+    assert ph.cpu["outer"] <= ph["outer"] * 1.02
+    assert ph.cpu["outer"] + ph.cpu["inner"] <= outer.seconds
+    # a phase with no sink reads no clock of the thread's
+    assert set(ph) == set(ph.cpu) == {"outer", "inner"}
+    ph.clear()
+    assert not ph and not ph.cpu
+
+
+class _Sink:
+    def __init__(self):
+        self.tokens, self.done = [], threading.Event()
+
+    def __call__(self, e):
+        if e.token_id >= 0:
+            self.tokens.append(e.token_id)
+        if e.finished:
+            self.done.set()
+
+
+def manual_loop(eng=None, **extra):
+    """A loop that was never started: ``_pass()`` runs one pass on the
+    test's thread and tokens are delivered inline."""
+    return EngineLoop(eng if eng is not None else tiny_engine(**extra),
+                      "account")
+
+
+def submit(loop, rid, n_prompt, max_tokens=6):
+    sink = _Sink()
+    loop.submit(Request(
+        id=rid, prompt_tokens=list(range(4, 4 + n_prompt)),
+        sampling=SamplingParams(max_tokens=max_tokens, temperature=0.0),
+    ), sink)
+    return sink
+
+
+def run_passes(loop, sinks, each=None, passes=400):
+    for _ in range(passes):
+        if all(s.done.is_set() for s in sinks):
+            return
+        assert loop._pass()
+        if each is not None:
+            each()
+    raise AssertionError("requests never finished")
+
+
+def account_hists(obs):
+    return [*obs.step_parts.values(), obs.host_build_cpu,
+            *obs.threads_cpu.values(), obs.gc_seconds]
+
+
+@pytest.fixture(scope="module")
+def accounted():
+    """Three requests over two slots, one with a prompt of three chunks,
+    pass by pass: every kind of step, some left in flight."""
+    loop = manual_loop()
+    sinks = [submit(loop, "a0", 6, 12), submit(loop, "a1", 40, 8),
+             submit(loop, "a2", 9, 8)]
+    counts = []
+
+    def each():
+        counts.append([h.count for h in account_hists(loop.obs)]
+                      + [loop.obs.step_seconds.count])
+
+    run_passes(loop, sinks, each)
+    return loop, counts
+
+
+def test_each_new_histogram_gains_one_observation_a_step(accounted):
+    loop, counts = accounted
+    assert len(account_hists(loop.obs)) == 9
+    assert counts[-1][-1] >= 10
+    for row in counts:
+        assert set(row) == {row[-1]}, row
+    names = {h.name for h in account_hists(loop.obs)}
+    assert names == {
+        "helix_step_claim_seconds", "helix_step_plan_seconds",
+        "helix_step_sync_state_seconds", "helix_step_launch_seconds",
+        "helix_step_host_build_cpu_seconds",
+        "helix_step_engine_cpu_seconds", "helix_step_emit_cpu_seconds",
+        "helix_step_http_cpu_seconds", "helix_step_gc_seconds"}
+
+
+def test_the_parts_lie_inside_admit_and_dispatch(accounted):
+    loop, _ = accounted
+    recs = [r for r in loop.flight.snapshot(recent=512)["recent"]
+            if "parts" in r]
+    assert len(recs) >= 8
+    seen = set()
+    for rec in recs:
+        ph, parts = rec["phases"], rec["parts"]
+        assert set(parts) <= set(PARTS) and not set(ph) & set(PARTS)
+        seen |= set(parts)
+        parents = (ph.get("helix.loop.admit", 0.0)
+                   + ph.get("helix.loop.dispatch", 0.0))
+        assert sum(parts.values()) <= parents + 1e-5, rec
+        for name, sec in parts.items():
+            # (a boundary may share a reading up to 20 us old)
+            assert -2e-5 <= rec["parts_cpu"][name] <= sec * 1.02 + 4e-5, rec
+        for name, sec in ph.items():
+            assert -2e-5 <= rec["phases_cpu"][name] <= sec * 1.02 + 4e-5, rec
+    assert seen == set(PARTS)
+    obs = loop.obs
+    assert sum(h.sum for h in obs.step_parts.values()) <= (
+        obs.step_phases["helix.loop.admit"].sum
+        + obs.step_phases["helix.loop.dispatch"].sum)
+    assert obs.host_build_cpu.sum <= obs.host_build.sum * 1.02
+
+
+def structure(loop):
+    return [(r["kind"], sorted(r["phases"]), r["prefill_tokens"],
+             r["decode_tokens"])
+            for r in loop.flight.snapshot(recent=512)["recent"]]
+
+
+def test_the_parents_read_what_they_read_with_the_parts_discarded(accounted):
+    """The same traffic on an engine whose parts go to no sink: the same
+    steps with the same phases, and in both the parents still cover the
+    whole of the host's build (a part written to ``step_phases`` would be
+    taken out of them)."""
+    loop, _ = accounted
+    eng = tiny_engine()
+    eng._part = lambda name, **attrs: obs_trace.phase(name, **attrs)
+    bare = manual_loop(eng)
+    run_passes(bare, [submit(bare, "a0", 6, 12), submit(bare, "a1", 40, 8),
+                      submit(bare, "a2", 9, 8)])
+    assert structure(bare) == structure(loop)
+    for name in ("helix.loop.admit", "helix.loop.dispatch"):
+        assert (bare.obs.step_phases[name].count
+                == loop.obs.step_phases[name].count)
+    assert all(h.sum == 0.0 for h in bare.obs.step_parts.values())
+    assert all(h.sum > 0.0 for h in loop.obs.step_parts.values())
+    for lp in (loop, bare):
+        # (the median step: on a loaded machine a thread is descheduled
+        # between two phases now and then)
+        shares = sorted(
+            sum(rec["phases"].get(k, 0.0) for k in (
+                "helix.loop.admit", "helix.loop.prefill_sync",
+                "helix.loop.dispatch")) / rec["host_build_s"]
+            for rec in lp.flight.snapshot(recent=512)["recent"]
+            if rec.get("host_build_s"))
+        assert 0.9 <= shares[len(shares) // 2] <= 1.0, shares
+
+
+def test_every_launch_is_under_the_parts_once():
+    """A wave's program and the step program behind it: two launches; a
+    mixed step: one; a wave admitted while a prompt is chunking: the
+    wave's and the mixed step's.  ``num_device_calls`` counts the same."""
+    eng = tiny_engine(max_decode_batch=3)
+    loop = manual_loop(eng)
+    kinds, per_pass = [], []
+    orig = eng._part
+
+    def part(name, **attrs):
+        if name == "helix.loop.launch":
+            kinds.append(attrs["kind"])
+        return orig(name, **attrs)
+
+    eng._part = part
+
+    def one_pass():
+        calls, n = eng.num_device_calls, len(kinds)
+        assert loop._pass()
+        assert eng.num_device_calls - calls == len(kinds) - n
+        launched = eng.step_parts.get("helix.loop.launch", 0.0)
+        assert (launched > 0.0) == (len(kinds) > n)
+        per_pass.append(tuple(kinds[n:]))
+
+    sinks = [submit(loop, "w0", 6, 30)]
+    one_pass()
+    sinks.append(submit(loop, "w1", 40, 4))      # three chunks
+    one_pass()
+    sinks.append(submit(loop, "w2", 7, 4))       # a wave beside a chunk
+    one_pass()
+    for _ in range(200):
+        if all(s.done.is_set() for s in sinks):
+            break
+        one_pass()
+    assert per_pass[0] == ("admit", "decode")
+    assert per_pass[1] == ("mixed",)
+    assert per_pass[2] == ("admit", "mixed")
+    assert ("decode",) in per_pass
+
+
+def test_a_forced_collection_lands_in_that_steps_gc_s():
+    loop = manual_loop()
+    sink = submit(loop, "g0", 6, 12)
+    gc.callbacks.append(loop._gc_hook)      # what start() does
+    try:
+        for _ in range(3):
+            assert loop._pass()
+        before = loop.obs.gc_seconds.sum
+        gc.collect()
+        assert loop._pass()
+        rec = loop.flight.snapshot(recent=1)["recent"][-1]
+        assert rec["gc_s"] > 0.0
+        assert loop.obs.gc_seconds.sum - before >= rec["gc_s"] - 1e-6
+        assert loop._gc_span is None
+        run_passes(loop, [sink])
+    finally:
+        loop._unhook_gc()
+    assert loop._gc_hook not in gc.callbacks
+
+
+def test_start_installs_the_collector_hook_and_stop_removes_it():
+    before = list(gc.callbacks)
+    loop = manual_loop().start()
+    try:
+        assert loop._gc_hook in gc.callbacks
+        assert len(gc.callbacks) == len(before) + 1
+    finally:
+        loop.stop(join=True)
+    assert gc.callbacks == before
+    assert not loop._thread.is_alive()
+
+
+@pytest.mark.parametrize("which,work,shows", (
+    ("emit", "burn", True), ("emit", "sleep", False), ("http", "burn", True),
+))
+def test_threads_cpu_reads_the_other_threads_clocks(which, work, shows):
+    loop = manual_loop()
+    ready, go, done, leave = (threading.Event() for _ in range(4))
+
+    def worker():
+        if which == "http":
+            sink = submit(loop, "t0", 6, 2)     # submit learns the thread
+            assert not sink.done.is_set()
+        else:
+            loop._emit_stage.cpu_clock = time.pthread_getcpuclockid(
+                threading.get_ident())
+        ready.set()
+        go.wait(30)
+        if work == "burn":      # 50 ms of CPU, however long that takes
+            c0 = time.thread_time()
+            while time.thread_time() - c0 < 0.05:
+                pass
+        else:
+            time.sleep(0.05)
+        done.set()
+        leave.wait(30)
+
+    t = threading.Thread(target=worker)
+    t.start()
+    try:
+        assert ready.wait(30)
+        first = loop._threads_cpu()
+        assert first[which] == 0.0      # first seen now
+        go.set()
+        assert done.wait(30)
+        cpu = loop._threads_cpu()
+    finally:
+        leave.set()
+        t.join(30)
+    assert not t.is_alive()
+    assert set(cpu) == {"engine", "emit", "http"}
+    if shows:
+        assert 0.04 <= cpu[which] < 0.2
+    else:
+        assert cpu[which] < 0.01
+    other = "http" if which == "emit" else "emit"
+    assert cpu[other] == 0.0
+    # a thread that has left reads 0 or its last CPU; it never raises
+    assert loop._threads_cpu()[which] >= 0.0
+
+
+def test_flight_records_carry_the_account_and_a_frozen_tail_keeps_it():
+    eng = tiny_engine()
+    loop = manual_loop(eng)
+    loop.flight = FlightRecorder(min_samples=4)
+    sink = submit(loop, "f0", 6, 40)
+    for _ in range(3):
+        assert loop._pass()              # the compiles
+    loop.flight.reset_baseline()
+    for _ in range(8):
+        assert loop._pass()
+    orig = eng.step_dispatch
+
+    def slow():
+        time.sleep(1.5)
+        return orig()
+
+    eng.step_dispatch = slow
+    assert loop._pass()
+    eng.step_dispatch = orig
+    snap = loop.flight.snapshot(recent=64)
+    frozen = [a for a in snap["anomalies"] if a["reason"] == "slow_step"]
+    assert frozen, [a["reason"] for a in snap["anomalies"]]
+    tail = frozen[-1]
+    assert len(tail["steps"]) >= 9
+    for rec in (*snap["recent"], tail["record"], *tail["steps"]):
+        assert set(ACCOUNT_FIELDS) <= set(rec), sorted(rec)
+        assert set(rec["threads_cpu"]) == {"engine", "emit", "http"}
+        assert set(rec["phases_cpu"]) == set(rec["phases"])
+        assert set(rec["parts_cpu"]) == set(rec["parts"])
+    # the sleep is wall the thread did not run
+    slow_rec = tail["record"]
+    ph = slow_rec["phases"]["helix.loop.admit"] + slow_rec["phases"][
+        "helix.loop.dispatch"]
+    # the parents time the engine's own halves, not what ran around them
+    assert slow_rec["host_build_s"] - ph >= 1.4
+    run_passes(loop, [sink])

@@ -16,8 +16,12 @@ would commit); both are git-ignored; ``--tree <side>=<dir>`` names another
 tree for a side.  Every run's JSON lines go to
 ``chiprun_out/pairs/<tag>.jsonl``, its server log and trace summary beside
 it (after a traced run also ``programs.json``: ``tools/program_times.py`` over
-the capture), and one line a run (side, cell, seed, the metrics) to stdout.
+the capture, and ``gaps.json``: ``tools/gap_spans.py``, its long idle gaps by
+the program's spans), and one line a run (side, cell, seed, the metrics) to stdout.
 A run is not started when ``--per-run`` seconds more would pass ``--budget``.
+``--account`` runs each through the tree's ``tools/host_account.py --run``,
+which keeps the window's two ``/metrics`` scrapes and its flight records
+beside the rest and prints the host's account of the window.
 """
 import argparse
 import json
@@ -40,6 +44,7 @@ def main():
                     help="seconds a run is expected to need at most")
     ap.add_argument("--tree", action="append", default=[],
                     metavar="SIDE=DIR", help="a side's tree, from the root")
+    ap.add_argument("--account", action="store_true")
     ap.add_argument("runs", nargs="+")
     a = ap.parse_args()
     trees = dict(TREES, **{
@@ -55,9 +60,12 @@ def main():
             print(f"SKIPPED {spec}: budget", flush=True)
             continue
         tree = trees[side]
+        keep = os.path.join(out_dir, f"{a.tag}_{i}_{side}_{cell}")
         t1 = time.monotonic()
         r = subprocess.run(
-            [sys.executable, "benchmark/run.py", "--workload", cell, "--seed",
+            [sys.executable, *(["tools/host_account.py", "--run", keep]
+                               if a.account else ["benchmark/run.py"]),
+             "--workload", cell, "--seed",
              seed, "--seconds", "45", "--trace", trace],
             cwd=tree, capture_output=True, text=True)
         wall = time.monotonic() - t1
@@ -65,11 +73,11 @@ def main():
         rec = {"side": side, "cell": cell, "seed": int(seed), "trace": int(trace),
                "exit": r.returncode, "wall_s": round(wall, 1),
                "lines": [json.loads(ln) for ln in lines],
-               "stderr": r.stderr[-2000:] if r.returncode else ""}
+               "stderr": r.stderr[-6000:] if r.returncode or a.account
+               else ""}
         with open(out, "a") as f:
             f.write(json.dumps(rec) + "\n")
         bo = os.path.join(tree, ".bench_out")
-        keep = os.path.join(out_dir, f"{a.tag}_{i}_{side}_{cell}")
         os.makedirs(keep, exist_ok=True)
         for name in os.listdir(bo) if os.path.isdir(bo) else []:
             p = os.path.join(bo, name)
@@ -78,12 +86,14 @@ def main():
                 shutil.copy(p, keep)
         if int(trace) and os.path.isdir(os.path.join(bo, "profiles")):
             # (a reader that fails or runs out of time costs its file only)
-            subprocess.run(
-                [sys.executable, os.path.join(ROOT, "tools", "program_times.py"),
-                 os.path.join(bo, "profiles"),
-                 "--out", os.path.join(keep, "programs.json")],
-                env=dict(os.environ, JAX_PLATFORMS="cpu",
-                         TPU_LOG_DIR="disabled"))
+            for tool, name in (("program_times.py", "programs.json"),
+                               ("gap_spans.py", "gaps.json")):
+                subprocess.run(
+                    [sys.executable, os.path.join(ROOT, "tools", tool),
+                     os.path.join(bo, "profiles"),
+                     "--out", os.path.join(keep, name)],
+                    env=dict(os.environ, JAX_PLATFORMS="cpu",
+                             TPU_LOG_DIR="disabled"))
         last = rec["lines"][-1] if rec["lines"] else {}
         print(json.dumps({"side": side, "cell": cell, "seed": seed,
                           "trace": trace, "exit": r.returncode,
