@@ -419,6 +419,11 @@ class PendingStep:
     # [(Request, [R] device handle, row)], fetched inside this step's one
     # device_get instead of their own
     pending_first: list = dataclasses.field(default_factory=list)
+    # the tokens of the rows that decoded inside the admission waves
+    # launched ahead of this step: [(rows at the wave's launch, [B, W]
+    # device handle)] in launch order, fetched in the same device_get and
+    # emitted after the first tokens and ahead of the step's own
+    waves: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -1706,6 +1711,16 @@ class Engine:
         self._pending_first_ids: set = set()
         self._pending_token_patches: dict[int, tuple] = {}  # slot -> (h, row)
         self._inflight_out: dict[str, int] = {}
+        # an admission wave launches the running rows live (one decode
+        # step inside the wave's pass); their tokens stay on the device as
+        # the first tokens do and ride the next PendingStep:
+        # [([(slot, Request)], [B, W] dev handle)] in launch order
+        self._pending_waves: list = []
+        # slots claimed by the admission pass under way: in ``slots``, but
+        # their position and last token are seeded only when the pass ends
+        # (``_finish_packed_admissions`` + ``_sync_state``), so they sit
+        # out this pass's waves as a chunking request does
+        self._admitting: set = set()
         # the ``active`` column the device state holds (a row whose budget
         # or page room the tokens in flight exhaust is launched inactive)
         self._active_sent = np.zeros((B,), np.int32)
@@ -1740,6 +1755,9 @@ class Engine:
         # rows that rode those passes sitting out (draft_len -1)
         self.num_joint_pass_steps = 0
         self.num_joint_pass_inert_rows = 0
+        # tokens decoded by running rows inside admission waves (a model
+        # step a wave that once produced nothing)
+        self.num_wave_decode_tokens = 0
         # --- speculative decoding (engine/spec.py) ---
         # host-side prompt-lookup drafter + per-request acceptance EMA;
         # None = speculation off (config, or an unsupported model family)
@@ -2172,9 +2190,11 @@ class Engine:
         metadata upload and the (async) device dispatch.  Returns
         ``(emitted_so_far, pending)`` — ``pending`` carries the device
         handles; nothing here blocks on the device (an admission wave's
-        first tokens stay there and ride ``pending``'s fetch) except the
-        VL single-shot prefill and an admission with no decodable row
-        behind it.  Valid while an earlier step is still in flight: see
+        first tokens, and the tokens its running rows decoded in it, stay
+        there and ride ``pending``'s fetch) except the
+        VL single-shot prefill, an admission with no decodable row
+        behind it and, under speculation, a wave in which rows decoded.
+        Valid while an earlier step is still in flight: see
         ``pipeline_ready``."""
         emitted: list[tuple[Request, int]] = []
         self.first_launch_time = None
@@ -2212,6 +2232,13 @@ class Engine:
             return self._mixed_dispatch()
         if self._chunking is not None:
             self._chunk_dispatch()
+        if self.spec is not None and self._pending_waves:
+            # the drafter reads the host's sequence, which a row that
+            # decoded in a wave lags by that token: it could not draft, and
+            # the step would lose its verify.  Speculation reconciles
+            # before it dispatches as it is (``pipeline_ready``), so the
+            # wave's tokens are fetched here, ahead of the drafting
+            self._flush_pending_first(emitted)
         # re-check: a chunk that just completed activates its slot and
         # decodes its second token this same step (pre-mixed behaviour);
         # its deferred first token rides that step's single device_get
@@ -2225,9 +2252,10 @@ class Engine:
             if pend is None:
                 pend = self._decode_dispatch()
             return pend
-        # nothing decodable (every admitted row ends on its first token, or
-        # a chunk whose request aborted between activation and decode): any
-        # deferred first token must still land — synchronous flush
+        # nothing decodable (every admitted row ends on its first token and
+        # every running row on its wave token, or a chunk whose request
+        # aborted between activation and decode): any deferred token must
+        # still land — synchronous flush
         self._flush_pending_first(emitted)
         return None
 
@@ -2320,10 +2348,13 @@ class Engine:
             # dispatch-time p+n in place would re-sync a (position,
             # last_token) pair that never existed and silently skip n
             # tokens from the client's stream
-            for i, r in pend.rows:
-                if self.slots[i] is r:
-                    self._positions[i] -= pend.n
-                self._uncharge(r, pend.n)
+            self._roll_back(pend.rows, pend.n)
+        # a wave's rows advanced one token at its launch: the step that
+        # was to fetch them is gone (and so is the device state behind
+        # it), so they roll back as a step's do, those of a wave no step
+        # has taken yet among them
+        for rows, _sampled in pend.waves + self._take_pending_waves():
+            self._roll_back(rows, 1)
         for req, tok, row in pend.pending_first:
             self._uncharge(req, 1)
             if (
@@ -2340,6 +2371,43 @@ class Engine:
             self._defer_first_token(req, tok, row)
         self._state_dirty = True
         self._changed_slots.update(range(len(self.slots)))
+
+    def _check_table_room(self, rows: list, n: int) -> None:
+        """Headroom invariant, checked loudly on host: the KV write clamps
+        its page-table index, so a row whose position can reach table
+        capacity inside the ``n`` steps about to launch would silently
+        corrupt offset 0 of its last page instead of failing (ADVICE r3).
+        ``_row_runs`` and ``_decode_window`` must make this impossible;
+        verify it."""
+        table_cap = (
+            self.cache_cfg.max_pages_per_seq * self.cache_cfg.page_size
+        )
+        for i, _r in rows:
+            if self._positions[i] + n > table_cap:
+                raise RuntimeError(
+                    f"decode step overruns page-table capacity: slot {i} "
+                    f"at position {self._positions[i]} + {n} steps > "
+                    f"{table_cap} — headroom invariant violated"
+                )
+
+    def _advance(self, rows: list, n: int) -> None:
+        """Predicted-state advance: the DEVICE moves every launched row
+        forward ``n`` tokens whether or not the host later discards an
+        overrun, so the position mirror advances at the launch — this is
+        what lets the loop build step N+1's metadata before step N's
+        tokens are on host.  The reconcile only fetches, emits and applies
+        stop conditions."""
+        for i, r in rows:
+            self._positions[i] += n
+            self._charge(r, n)
+
+    def _roll_back(self, rows: list, n: int) -> None:
+        """Undo ``_advance`` for a launch whose tokens will never be
+        read."""
+        for i, r in rows:
+            if self.slots[i] is r:
+                self._positions[i] -= n
+            self._uncharge(r, n)
 
     def _pending_out(self, req: Request) -> int:
         """Tokens this request has in flight (dispatched, not yet
@@ -2370,6 +2438,13 @@ class Engine:
         instead of making the loop wait for that reconcile."""
         return self._slot_active(i) and self._headroom(self.slots[i]) > 0
 
+    def _running_rows(self) -> list:
+        """``[(slot, Request)]`` of the rows a launch runs live, snapshotted
+        at the launch: what its reconcile (or its roll-back) walks."""
+        return [
+            (i, r) for i, r in enumerate(self.slots) if self._row_runs(i)
+        ]
+
     def _defer_first_token(self, req: Request, tok, row: int) -> None:
         """Leave a prefill's sampled first token on the device: a
         placeholder in the mirror, a device-side patch at the next
@@ -2385,20 +2460,59 @@ class Engine:
         self._pending_first_ids.clear()
         return pf
 
-    def _fetch_with_firsts(self, handles: tuple, pending_first: list,
-                           emitted) -> tuple:
-        """A step's one fetch, the deferred first tokens it carries
-        included (each handle once); they are emitted ahead of the step's
-        own tokens.  Returns the fetched ``handles``."""
+    def _take_pending_waves(self) -> list:
+        waves, self._pending_waves = self._pending_waves, []
+        return waves
+
+    def _take_deferred(self) -> dict:
+        """What the step being launched carries for the launches ahead of
+        it, as ``PendingStep`` fields: the deferred first tokens (an
+        admission wave's, a final chunk's, any re-queued by a failed
+        step) and the tokens of the rows that decoded inside the waves.
+        Each must ride THIS step's fetch, or its request would emit a
+        later token before it."""
+        return dict(pending_first=self._take_pending_first(),
+                    waves=self._take_pending_waves())
+
+    def _fetch_deferred(self, p: PendingStep, emitted) -> tuple:
+        """A step's one fetch, the deferred tokens it carries included
+        (each handle once).  They are emitted ahead of the step's own
+        tokens, in the order the device produced them: the first tokens,
+        then wave by wave the tokens of the rows that decoded there.
+        Returns the fetched ``p.handles``."""
         uniq: dict = {}
-        for _req, tok, _row in pending_first:
+        for _req, tok, _row in p.pending_first:
             uniq.setdefault(id(tok), tok)
-        fetched = self._fetch(handles + tuple(uniq.values()))
-        firsts = dict(zip(uniq, fetched[len(handles):]))
-        for req, tok, row in pending_first:
+        n, m = len(p.handles), len(p.handles) + len(uniq)
+        fetched = self._fetch(
+            p.handles + tuple(uniq.values())
+            + tuple(sampled for _rows, sampled in p.waves))
+        firsts = dict(zip(uniq, fetched[n:m]))
+        for req, tok, row in p.pending_first:
             self._finish_first_emit(
                 req, int(firsts[id(tok)][row]), emitted)
-        return fetched[:len(handles)]
+        for (rows, _sampled), sampled_np in zip(p.waves, fetched[m:]):
+            self._finish_wave_emit(rows, sampled_np, emitted)
+        return fetched[:n]
+
+    def _finish_wave_emit(self, rows: list, sampled_np, emitted) -> None:
+        """The tokens a wave's running rows decoded in it, after their
+        handle was fetched with a later step's."""
+        for _i, r in rows:
+            self._uncharge(r, 1)
+        self._emit_row_tokens(rows, sampled_np[:, 0], emitted)
+
+    def _emit_row_tokens(self, rows: list, toks, emitted) -> None:
+        """One launched token a row (``toks [B]``) reaches the host: a row
+        whose slot no longer holds the request, or whose request finished
+        on an earlier token, discards it (the fused-window overrun
+        contract)."""
+        for i, r in rows:
+            if self.slots[i] is not r or r.finished:
+                continue  # finished/evicted mid-flight: discard the overrun
+            self._last_token[i] = toks[i]
+            self.num_decode_tokens += 1
+            self._emit(r, int(toks[i]), emitted)
 
     def _finish_first_emit(self, req: Request, first_token: int,
                            emitted) -> None:
@@ -2418,16 +2532,23 @@ class Engine:
 
     def _flush_pending_first(self, emitted) -> None:
         """Fallback when no same-step decode fetch will carry the
-        deferred first tokens: fetch them alone."""
-        pf = self._take_pending_first()
-        if not pf:
+        deferred first tokens (and the tokens of the rows that decoded in
+        the waves), or when the drafter must see them first: fetch them
+        alone."""
+        pf, waves = self._take_pending_first(), self._take_pending_waves()
+        if not pf and not waves:
             return
         with obs_trace.phase(
             "helix.loop.prefill_sync", into=self.step_phases
         ), self.device_wait:
-            toks = jax.device_get([tok for _req, tok, _row in pf])
+            toks, wave_toks = jax.device_get((
+                [tok for _req, tok, _row in pf],
+                [sampled for _rows, sampled in waves],
+            ))
         for (req, _tok, row), t_np in zip(pf, toks):
             self._finish_first_emit(req, int(t_np[row]), emitted)
+        for (rows, _sampled), sampled_np in zip(waves, wave_toks):
+            self._finish_wave_emit(rows, sampled_np, emitted)
         self._drain_moe_drops()   # the fetch above synced the device
 
     def _request_key(self, req: Request) -> np.ndarray:
@@ -2449,9 +2570,10 @@ class Engine:
         return _host_key(self._key_base ^ self._key_nonce)
 
     def _slot_active(self, i: int) -> bool:
-        """Occupied and decodable (not mid-chunked-prefill)."""
+        """Occupied and decodable (not mid-chunked-prefill, not claimed by
+        the admission pass under way)."""
         s = self.slots[i]
-        if s is None:
+        if s is None or i in self._admitting:
             return False
         return self._chunking is None or s is not self._chunking["req"]
 
@@ -3050,6 +3172,7 @@ class Engine:
             if pending:
                 with self._part("helix.loop.claim"):
                     self._finish_packed_admissions(pending)
+            self._admitting.clear()
             if deferred:
                 self.waiting[:0] = deferred
         if self.preempted:
@@ -3215,6 +3338,7 @@ class Engine:
             if table is None:
                 break
             self.waiting.pop(0)
+            self._admitting.add(req.slot)
             admitted_any = True
             start = req.cached_tokens   # 0 unless prefix-cache hit
             rem = plen - start
@@ -3237,13 +3361,61 @@ class Engine:
         flush()
         admitted = 0
         for wave_plan, wave_batch in waves:
-            first_tokens, _, _, _ = self._ragged_step(
-                "admit", plan=wave_plan, draft_len=self._inert_rows,
-                n_extra=0,
+            # the running rows decode one token in the wave's pass (they
+            # are on its token axis either way): re-read per wave, so that
+            # headroom and ``max_tokens`` hold across a pass of several
+            rows = self._wave_rows()
+            draft_len = self._inert_rows
+            if rows:
+                self._check_table_room(rows, 1)
+                draft_len = self._inert_rows.copy()
+                draft_len[[i for i, _r in rows]] = 0
+            first_tokens, sampled, _, _ = self._ragged_step(
+                "admit", plan=wave_plan, draft_len=draft_len, n_extra=0,
             )
             pending.append((wave_batch, first_tokens))
             admitted += len(wave_batch)
+            if rows:
+                self.num_decode_device_steps += 1
+                self.num_wave_decode_tokens += len(rows)
+                self._advance(rows, 1)
+                self._pending_waves.append((rows, sampled))
         return admitted
+
+    def _wave_rows(self) -> list:
+        """``[(slot, Request)]`` an admission wave launches live: every
+        row that runs (``_row_runs``: decodable, headroom left after the
+        tokens in flight; a slot this pass claimed is neither yet), but
+        one whose LAST token this would be while earlier tokens of it are
+        in flight (an earlier wave's among them): were that so of every
+        row, no step would follow the wave, and ``_flush_pending_first``
+        would hand the host the wave's token ahead of the unreconciled
+        step's.  Such a row decodes in the step behind the wave instead,
+        which reconciles in order.  A plan
+        follower replays the leader's sets and never derives its own (with
+        nothing in flight it would see another); a wave the plan has no
+        set for prefills admissions carried over from a plan the leader
+        discarded, whose rows it rolled back: every row sits it out."""
+        if self._plan_drive is not None:
+            if not self._plan_drive.wave_rows:
+                return []
+            rows = [(i, self.slots[i])
+                    for i in self._plan_drive.wave_rows.pop(0)]
+            if not all(self._row_runs(i) for i, _r in rows):
+                raise RuntimeError(
+                    "plan-follow divergence: the leader's wave decoded "
+                    f"slots {[i for i, _r in rows]}, of which "
+                    f"{[i for i, _r in rows if not self._row_runs(i)]} "
+                    "do not run on this replica"
+                )
+            return rows
+        rows = [
+            (i, r) for i, r in self._running_rows()
+            if self._headroom(r) > 1 or not self._pending_out(r)
+        ]
+        if self._plan_recorder is not None:
+            self._plan_recorder.wave_rows.append([i for i, _r in rows])
+        return rows
 
     def _finish_packed_admissions(self, pending: list) -> None:
         """Per-request bookkeeping of the admission waves just launched.
@@ -3401,20 +3573,8 @@ class Engine:
         (``_finish_chunk``) and rides this step's fetch."""
         st = self._chunking
         req: Request = st["req"]
-        rows = [
-            (i, r) for i, r in enumerate(self.slots) if self._row_runs(i)
-        ]
-        # same headroom invariant as the decode step, for the fused step
-        table_cap = (
-            self.cache_cfg.max_pages_per_seq * self.cache_cfg.page_size
-        )
-        for i, _r in rows:
-            if self._positions[i] + 1 > table_cap:
-                raise RuntimeError(
-                    f"decode step overruns page-table capacity: slot {i} "
-                    f"at position {self._positions[i]} — headroom "
-                    f"invariant violated"
-                )
+        rows = self._running_rows()
+        self._check_table_room(rows, 1)
         t0 = time.monotonic()
         with self._part("helix.loop.plan"):
             plan, rem, end = self._chunk_plan(st)
@@ -3425,9 +3585,7 @@ class Engine:
         self.num_decode_device_steps += 1
         self.num_prefill_tokens += rem
         st["next"] = end
-        for i, r in rows:
-            self._positions[i] += 1
-            self._charge(r, 1)
+        self._advance(rows, 1)
         if req.trace_id and self._should_trace_chunk(st, req, end):
             obs_trace.default_store().record(
                 req.trace_id, "prefill_chunk", t0, time.monotonic(),
@@ -3438,23 +3596,15 @@ class Engine:
             self._finish_chunk(st, token)
         return PendingStep(
             kind="mixed", rows=rows, handles=(sampled,),
-            # the final chunk's own token, and any deferred first token
-            # re-queued by a failed step: each must ride THIS step's fetch
-            # or its request would emit token #2 before token #1
-            pending_first=self._take_pending_first(),
+            # the final chunk's own token among them
+            **self._take_deferred(),
         )
 
     def _mixed_complete(self, p: PendingStep, emitted) -> None:
-        (next_np,) = self._fetch_with_firsts(
-            p.handles, p.pending_first, emitted)
+        (next_np,) = self._fetch_deferred(p, emitted)
         for _i, r in p.rows:
             self._uncharge(r, 1)
-        for i, r in p.rows:
-            if self.slots[i] is not r or r.finished:
-                continue  # finished/evicted mid-flight: discard the overrun
-            self._last_token[i] = next_np[i, 0]
-            self.num_decode_tokens += 1
-            self._emit(r, int(next_np[i, 0]), emitted)
+        self._emit_row_tokens(p.rows, next_np[:, 0], emitted)
 
     def _prefill(
         self, req: Request, page_table: np.ndarray, slot: Optional[int] = None
@@ -4757,12 +4907,11 @@ class Engine:
         return PendingStep(
             kind="spec", rows=rows, handles=(sampled, emit, extra),
             n_extra=n_extra, draft_len=draft_len,
-            pending_first=self._take_pending_first(),
+            **self._take_deferred(),
         )
 
     def _spec_complete(self, p: PendingStep, emitted) -> None:
-        sampled_np, emit_np, extra_np = self._fetch_with_firsts(
-            p.handles, p.pending_first, emitted)
+        sampled_np, emit_np, extra_np = self._fetch_deferred(p, emitted)
         draft_len = p.draft_len
         for i, req in p.rows:
             if self.slots[i] is not req:
@@ -4794,22 +4943,8 @@ class Engine:
 
     def _decode_dispatch(self) -> PendingStep:
         n = self._decode_window()
-        # Headroom invariant, checked loudly on host: the KV write clamps
-        # its page-table index, so a slot whose position can reach table
-        # capacity inside this window would silently corrupt offset 0 of
-        # its last page instead of failing (ADVICE r3).  The window logic
-        # above must make this impossible; verify it.
-        table_cap = self.cache_cfg.max_pages_per_seq * self.cache_cfg.page_size
-        rows = [
-            (i, r) for i, r in enumerate(self.slots) if self._row_runs(i)
-        ]
-        for i, _r in rows:
-            if self._positions[i] + n > table_cap:
-                raise RuntimeError(
-                    f"decode window overruns page-table capacity: slot {i} "
-                    f"at position {self._positions[i]} + {n} steps > "
-                    f"{table_cap} — headroom invariant violated"
-                )
+        rows = self._running_rows()
+        self._check_table_room(rows, n)
         # plain decode IS the unified step with zero drafts: position 0
         # of each active row samples this step's token, and the fused
         # tail advances the remaining n-1 window steps in the same jit
@@ -4817,40 +4952,21 @@ class Engine:
             "decode", draft_len=self._zero_rows, n_extra=n - 1,
         )
         self.num_decode_device_steps += n
-        # Predicted-state advance: the DEVICE moves every dispatched row
-        # forward by the full window whether or not the host later
-        # discards an overrun, so the position mirror advances at
-        # dispatch — this is what lets the loop build step N+1's
-        # metadata before step N's tokens are on host.  Completion only
-        # fetches, emits and applies stop conditions.
-        for i, r in rows:
-            self._positions[i] += n
-            self._charge(r, n)
+        self._advance(rows, n)
         return PendingStep(
             kind="decode", rows=rows, handles=(sampled, extra), n=n,
-            pending_first=self._take_pending_first(),
+            **self._take_deferred(),
         )
 
     def _decode_complete(self, p: PendingStep, emitted) -> None:
-        # deferred first tokens land in the SAME host round trip as the
-        # decode window (ISSUE 13 satellite)
-        sampled_np, extra_np = self._fetch_with_firsts(
-            p.handles, p.pending_first, emitted)
+        # deferred tokens land in the SAME host round trip as the decode
+        # window (ISSUE 13 satellite)
+        sampled_np, extra_np = self._fetch_deferred(p, emitted)
         for _i, r in p.rows:
             self._uncharge(r, p.n)
-        for i, r in p.rows:
-            if self.slots[i] is not r or r.finished:
-                continue  # finished/evicted mid-flight: discard the overrun
-            self._last_token[i] = sampled_np[i, 0]
-            self.num_decode_tokens += 1
-            self._emit(r, int(sampled_np[i, 0]), emitted)
+        self._emit_row_tokens(p.rows, sampled_np[:, 0], emitted)
         for s in range(p.n - 1):
-            for i, r in p.rows:
-                if self.slots[i] is not r or r.finished:
-                    continue
-                self._last_token[i] = extra_np[s, i]
-                self.num_decode_tokens += 1
-                self._emit(r, int(extra_np[s, i]), emitted)
+            self._emit_row_tokens(p.rows, extra_np[s], emitted)
 
     # ------------------------------------------------------------------
     # the unified ragged device step (ISSUE 10)

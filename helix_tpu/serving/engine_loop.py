@@ -1551,6 +1551,7 @@ class EngineLoop:
             getattr(eng, "num_ctx_stream_chunks", 0),
             getattr(eng, "num_joint_pass_steps", 0),
             getattr(eng, "num_joint_pass_inert_rows", 0),
+            getattr(eng, "num_wave_decode_tokens", 0),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1562,7 +1563,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0) = pre
+         cs0, jp0, ji0, wr0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1603,9 +1604,11 @@ class EngineLoop:
             # this step's programs in which prefill rows and state rows
             # shared one pass over the layers (1 for a wave, a chunk or a
             # mixed step; a step of several waves counts each), and the
-            # state rows that rode those passes sitting out
+            # state rows that rode those passes sitting out; the state
+            # rows that decoded a token inside the step's admission waves
             "joint_pass": getattr(eng, "num_joint_pass_steps", 0) - jp0,
             "inert_rows": getattr(eng, "num_joint_pass_inert_rows", 0) - ji0,
+            "wave_rows": getattr(eng, "num_wave_decode_tokens", 0) - wr0,
             # layers whose state is a fixed tensor a slot (the state
             # pool), and layers with pages
             "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),

@@ -647,10 +647,13 @@ class PlanRecorder:
     ``step_dispatch`` by :class:`PlanLeader`): admission claims call
     ``note_admit`` after ``cached_tokens`` is final, resumes append the
     resumed request id, spec drafting stores the drafted tokens per
-    slot, and the dispatch prologue stores the prefill budget and the
-    queue-pressure bit that pins the decode window."""
+    slot, the dispatch prologue stores the prefill budget and the
+    queue-pressure bit that pins the decode window, and each admission
+    wave stores the slots it launched live (the running rows decode a
+    token inside the wave)."""
 
-    __slots__ = ("admits", "resumes", "drafts", "budget", "queue_blocked")
+    __slots__ = ("admits", "resumes", "drafts", "budget", "queue_blocked",
+                 "wave_rows")
 
     def __init__(self):
         self.admits: list = []
@@ -658,6 +661,7 @@ class PlanRecorder:
         self.drafts: list = []
         self.budget = None
         self.queue_blocked = False
+        self.wave_rows: list = []   # one list of slots a wave, launch order
 
     def note_admit(self, req: Request) -> None:
         doc = request_to_wire(req)
@@ -672,21 +676,23 @@ class PlanDrive:
     ``step()`` by :class:`FollowerLoop`): the prefill budget and the
     queue-pressure bit are overridden, spec drafting consumes the
     plan's draft tokens verbatim instead of running the host drafter,
+    each admission wave launches live the slots the leader's did,
     resumes happen exactly in plan order, and each admission claim
     verifies its locally-restored ``cached_tokens`` against the
     leader's value (a mismatch means the prefix/filestore rungs drifted
     between hosts and the device steps would desync)."""
 
     __slots__ = ("budget", "queue_blocked", "drafts", "resumes",
-                 "cached_tokens")
+                 "cached_tokens", "wave_rows")
 
     def __init__(self, budget, queue_blocked, drafts, resumes,
-                 cached_tokens):
+                 cached_tokens, wave_rows=()):
         self.budget = budget
         self.queue_blocked = bool(queue_blocked)
         self.drafts = drafts
         self.resumes = resumes
         self.cached_tokens = cached_tokens
+        self.wave_rows = [list(slots) for slots in wave_rows]
 
 
 def _fold_digest(prev: bytes, step_idx: int, emissions, excluded) -> bytes:
@@ -1207,6 +1213,7 @@ class PlanLeader:
                 "resumes": resumes,
                 "budget": rec.budget,
                 "queue_blocked": rec.queue_blocked,
+                "wave_rows": rec.wave_rows,
                 "drafts": rec.drafts,
                 "digest_step": self._digest_step,
                 "digest": (self._digest.hex()
@@ -1505,6 +1512,8 @@ class FollowerLoop:
                     for s, toks in record.get("drafts", [])],
             resumes=list(record.get("resumes", [])),
             cached_tokens=cached,
+            wave_rows=[[int(i) for i in slots]
+                       for slots in record.get("wave_rows", [])],
         )
         eng._plan_drive = drive
         try:
@@ -1520,6 +1529,12 @@ class FollowerLoop:
         if drive.resumes:
             raise DivergenceError(
                 f"plan {step_idx}: resumes not applied: {drive.resumes}"
+            )
+        if drive.wave_rows:
+            raise DivergenceError(
+                f"plan {step_idx}: {len(drive.wave_rows)} of the leader's "
+                "admission waves not launched, rows "
+                f"{drive.wave_rows} did not decode in them"
             )
         self._prev = (step_idx, [(r.id, int(t)) for r, t in emitted])
         self._applied_step = step_idx

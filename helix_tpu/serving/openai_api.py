@@ -378,6 +378,11 @@ class OpenAIServer:
                 "helix_joint_pass_steps_total",
                 getattr(eng, "num_joint_pass_steps", 0), lbl,
             )
+            # tokens the running rows decoded inside admission waves
+            c.counter(
+                "helix_wave_decode_tokens_total",
+                getattr(eng, "num_wave_decode_tokens", 0), lbl,
+            )
             # MoE prefill routing assignments dropped to expert-capacity
             # overflow (rode the residual stream instead)
             c.counter(

@@ -3,10 +3,13 @@ segment (``engine/engine.py::_build_ragged_step_fn``): its prefill tokens
 and its state rows go through the layers in ONE pass.  Helpers only; the
 cases live in each family's test file, at that family's tiny size."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from helix_tpu.engine import engine as engine_mod
 from helix_tpu.engine.engine import _build_ragged_step_fn, _host_key
 from helix_tpu.engine.ragged import PrefillPlan
 from helix_tpu.engine.sampling import SamplingParams, SamplingState
@@ -143,3 +146,134 @@ def assert_mixed_is_chunk_then_decode(make_engine, make_reqs, watch_id: str,
     both = sorted(set(log_m) & set(log_s))
     assert len(both) >= 3, (sorted(log_m), sorted(log_s))
     assert max(np.abs(log_m[n] - log_s[n]).max() for n in both) < tol
+
+
+# -- an admission wave beside running rows -------------------------------
+
+@contextlib.contextmanager
+def watched_programs(seen: list):
+    """Every step program launched inside records ``{rung, draft_len,
+    before, after}``: the ``DecodeState`` and the state pool it was given
+    (after the launch's own state sync) and those it handed back."""
+    real = engine_mod._build_ragged_step_fn
+
+    def snapshot(state, cache):
+        return ({k: np.asarray(getattr(state, k)) for k in
+                 ("positions", "last_token", "keys", "token_counts")},
+                jax.tree.map(np.asarray, cache.state))
+
+    def build(*a, **kw):
+        fn = real(*a, **kw)
+
+        def run(params, cache, state, pargs, drafts, draft_len, *rest):
+            before = snapshot(state, cache)     # both are donated below
+            out = fn(params, cache, state, pargs, drafts, draft_len, *rest)
+            seen.append(dict(rung=a[4], draft_len=np.asarray(draft_len),
+                             before=before, after=snapshot(out[1], out[0])))
+            return out
+        return run
+
+    engine_mod._build_ragged_step_fn = build
+    try:
+        yield
+    finally:
+        engine_mod._build_ragged_step_fn = real
+
+
+def _same_row(rec, slot: int, pool: bool) -> None:
+    """Slot ``slot`` left the program as it entered it, bit for bit: its
+    ``DecodeState`` row and (``pool``) its row of every state pool."""
+    (st0, pool0), (st1, pool1) = rec["before"], rec["after"]
+    for k in st0:
+        assert np.array_equal(st0[k][slot], st1[k][slot]), (k, slot)
+    if pool:
+        for p0, p1 in zip(jax.tree.leaves(pool0), jax.tree.leaves(pool1)):
+            assert np.array_equal(p0[:, slot], p1[:, slot]), slot
+
+
+def run_wave_beside_rows(eng, running, short, late, watch):
+    """``running`` requests and ``short`` (two tokens to give) are admitted
+    and a decode step is launched and left in flight: it exhausts
+    ``short``.  Then ``late`` arrives and the next dispatch admits it in a
+    wave beside them.  Returns ``(the wave's record with the requests'
+    ``slots`` at its launch, tokens by request, {request: {tokens out:
+    next-token logits}} for ``watch``)``."""
+    assert short.sampling.max_tokens == 2
+    reqs = running + [short, late]
+    for r in running + [short]:
+        eng.add_request(r)
+    em1, p1 = eng.step_dispatch()
+    assert eng._headroom(short) == 0 and eng.slots[short.slot] is short
+    eng.add_request(late)
+    seen: list = []
+    assert eng.pipeline_ready()
+    with watched_programs(seen):
+        em2, p2 = eng.step_dispatch()
+    waves = [rec for rec in seen if rec["rung"]]
+    assert len(waves) == 1 and late.slot is not None, len(waves)
+    waves[0]["slots"] = {r.id: r.slot for r in reqs}
+    eng.step_complete(p1, em1)
+    assert short.finished and len(short.output_tokens) == 2
+    eng.step_complete(p2, em2)
+    logits = {r.id: {} for r in watch}
+    while eng.has_work():
+        eng.step()
+        for r in watch:
+            n = len(r.output_tokens)
+            if (n not in logits[r.id] and r.slot is not None
+                    and eng.slots[r.slot] is r):
+                logits[r.id][n] = np.asarray(
+                    eng.next_token_logits()[r.slot])
+    return (waves[0], {r.id: list(r.output_tokens) for r in reqs}, logits)
+
+
+def assert_wave_is_wave_then_decode(make_engine, make_reqs, tol: float):
+    """An admission wave beside running rows is the wave alone followed by
+    the decode step alone.  ``make_reqs() -> (running, short, late)``.
+
+    The engine as it is, against one whose waves launch every state row
+    sitting out (what a wave was before its rows decoded in it): each
+    admitted request gets the same first token and every running row the
+    same stream (ids equal, sampled rows included: a row's key stream is
+    the one it has without the wave), next-token logits within ``tol``.
+    In the wave itself the rows that run advance one token; the slot being
+    admitted and the row out of headroom keep ``DecodeState`` bit for bit,
+    the latter its rows of the state pools too."""
+    out = {}
+    for live in (True, False):
+        eng = make_engine()
+        if not live:
+            eng._wave_rows = lambda: []
+        running, short, late = make_reqs()
+        out[live] = run_wave_beside_rows(
+            eng, running, short, late, running[:1] + [late]) + (
+                eng, running, short, late)
+    (wave, toks, logits, eng, running, short, late) = out[True]
+    (wave_ref, toks_ref, logits_ref, eng_ref, *_rest) = out[False]
+    # what the wave launched, and what it left alone
+    slots = wave["slots"]
+    ran = sorted(slots[r.id] for r in running)
+    assert list(np.flatnonzero(wave["draft_len"] >= 0)) == ran
+    assert (wave_ref["draft_len"] == -1).all()
+    assert eng.num_wave_decode_tokens == len(ran)
+    assert eng_ref.num_wave_decode_tokens == 0
+    (st0, _), (st1, _) = wave["before"], wave["after"]
+    for i in ran:
+        assert st1["positions"][i] == st0["positions"][i] + 1
+        assert not np.array_equal(st0["keys"][i], st1["keys"][i])
+        assert st1["token_counts"][i].sum() == st0["token_counts"][i].sum() + 1
+    _same_row(wave, slots[short.id], pool=True)
+    _same_row(wave, slots[late.id], pool=False)
+    for i in range(len(eng_ref.slots)):
+        if i != wave_ref["slots"][late.id]:
+            _same_row(wave_ref, i, pool=True)
+    assert toks == toks_ref, (toks, toks_ref)
+    assert all(len(t) == r.sampling.max_tokens
+               for r in running + [short, late]
+               for t in [toks[r.id]]), toks
+    for rid in logits:
+        both = sorted(set(logits[rid]) & set(logits_ref[rid]))
+        assert len(both) >= 3, (rid, sorted(logits[rid]),
+                                sorted(logits_ref[rid]))
+        assert max(np.abs(logits[rid][n] - logits_ref[rid][n]).max()
+                   for n in both) < tol, rid

@@ -371,7 +371,62 @@ def _case_mixed_steps_in_flight(parts):
     assert len(out["lng"]) == 8
 
 
+def _inert_waves(parts, reqs, engine_extra=None):
+    """``_reference`` on an engine whose admission waves launch every
+    state row sitting out: what a wave was before its running rows
+    decoded in it.  Returns {rid: tokens}."""
+    eng = _make_engine(parts, **(engine_extra or {}))
+    eng._wave_rows = lambda: []
+    rs = reqs()
+    for r in rs:
+        eng.add_request(r)
+    while eng.has_work():
+        eng.step()
+    assert eng.num_wave_decode_tokens == 0
+    return {r.id: list(r.output_tokens) for r in rs}
+
+
+def _case_waves_beside_live_rows(parts):
+    """Three times as many requests as slots, sampled and penalised rows
+    among them: every wave after the first is launched beside running
+    rows, which decode a token in it, most of them behind a step still in
+    flight.  The streams are what ``Engine.step()`` gives, and what an
+    engine whose waves run alone gives: a wave token reaches the client
+    after every earlier token of its request and before the tokens of the
+    step that carried it."""
+
+    def reqs():
+        return [_req(f"v{j}", _prompt(j, 4 + j % 5), max_tokens=7 + 3 * j,
+                     temperature=0.8 if j % 3 == 1 else 0.0, seed=11 + j,
+                     presence=0.3 if j % 2 else 0.0, stop=())
+                for j in range(12)]
+
+    launched = []
+
+    def watch(eng):
+        orig = eng._wave_rows
+
+        def rows():
+            out = orig()
+            launched.append((len(out), bool(eng._inflight_out)))
+            return out
+
+        eng._wave_rows = rows
+
+    want, stats, eng, loop = _assert_parity(parts, reqs, FUSED, watch=watch)
+    assert want == _inert_waves(parts, reqs, FUSED)
+    assert stats["async_loop"]["pipelined_steps"] > 0
+    assert any(n and behind for n, behind in launched), launched
+    assert eng.num_wave_decode_tokens == sum(n for n, _b in launched) > 0
+    assert sum(r["wave_rows"] for r in _flight(loop)) == (
+        eng.num_wave_decode_tokens)
+    assert not eng._inflight_out and not eng._pending_waves
+    for j in range(12):
+        assert len(want[f"v{j}"]) == 7 + 3 * j
+
+
 CASES = {
+    "waves_beside_live_rows": _case_waves_beside_live_rows,
     "greedy_prefix_hit": _case_greedy_prefix_hit,
     "seeded_penalties": _case_seeded_penalties,
     "chunked_deferred_first_token": _case_chunked_deferred_first_token,
@@ -828,6 +883,152 @@ class TestPipelineMechanics:
             assert r.output_tokens == want[r.id]
             assert [t for q, t in emitted_all if q is r] == want[r.id]
         assert not eng._inflight_out
+
+    @pytest.mark.parametrize("family,what", [
+        (family, what)
+        for family in ("dense", "conv", "retention")
+        for what in ("discard", "abort", "finish", "max_tokens")
+        # a discarded launch has already updated a matrix state in place:
+        # ``discard_pending`` rolls back the host's mirrors, not the pools
+        if (family, what) != ("retention", "discard")])
+    def test_between_a_wave_and_its_fetch(
+            self, tiny_parts, conv_parts, retention_parts, family, what):
+        """Three rows decode, a step is in flight, a fourth request
+        arrives: the next dispatch admits it in a wave in which the three
+        decode a token, and launches a step behind it.  Before the wave's
+        tokens are fetched, ``m1`` meets a failed completion (``discard``:
+        the wave's advance is rolled back with the step's), an ``abort``,
+        a ``finish`` (the step in flight held its stop token) or its
+        ``max_tokens`` (one token left with another in flight: it sits
+        the WAVE out, so that its last token cannot reach the host first,
+        and decodes it in the step behind).  Positions, the in-flight
+        account and every stream come out as a run without the event
+        gives them."""
+        parts = {"dense": tiny_parts, "conv": conv_parts,
+                 "retention": retention_parts}[family]
+        plens = {"m0": 6, "m1": 6, "m2": 6, "late": 5}
+
+        def reqs(m1_tokens=30, m1_stop=()):
+            return [
+                _req("m0", _prompt(0, 6), max_tokens=30, stop=()),
+                _req("m1", _prompt(1, 6), max_tokens=m1_tokens,
+                     stop=m1_stop),
+                _req("m2", _prompt(2, 6), max_tokens=30, stop=()),
+                _req("late", _prompt(9, 5), max_tokens=12, stop=()),
+            ]
+
+        want, _ = _reference(parts, reqs)
+        # m1's tokens: first + one decoded (p1), the third in p2, the
+        # fourth in the wave (in p3 where it is the last), the fifth in p3
+        kw = {}
+        if what == "finish":
+            assert want["m1"][2] not in want["m1"][:2]
+            kw = dict(m1_stop=(want["m1"][2],))
+        elif what == "max_tokens":
+            kw = dict(m1_tokens=4)
+        eng = _make_engine(parts)
+        rs = {r.id: r for r in reqs(**kw)}
+        for rid in ("m0", "m1", "m2"):
+            eng.add_request(rs[rid])
+        eng.step()
+        em2, p2 = eng.step_dispatch()          # a step in flight
+        eng.add_request(rs["late"])
+        em3, p3 = eng.step_dispatch()          # the wave, then a step
+        (rows, _sampled), = p3.waves
+        live = [rid for rid in ("m0", "m1", "m2")
+                if (rid, what) != ("m1", "max_tokens")]
+        assert [r.id for _i, r in rows] == live
+        assert [r.id for _i, r in p3.rows] == ["m0", "m1", "m2", "late"]
+        assert eng._inflight_out == {
+            "m0": 3, "m1": 2 if what == "max_tokens" else 3, "m2": 3,
+            "late": 2}
+
+        def settled():
+            """Nothing in flight: every row's position is its tokens'."""
+            assert not eng._inflight_out and not eng._pending_waves
+            for i, r in enumerate(eng.slots):
+                if r is not None:
+                    assert eng._positions[i] == (
+                        plens[r.id] + len(r.output_tokens) - 1), r.id
+
+        if what == "abort":
+            eng.abort("m1")
+        eng.step_complete(p2, em2)
+        if what == "discard":
+            eng.discard_pending(p3)
+            # the first token is put back; the wave's advance is not
+            assert eng._inflight_out == {"late": 1}
+            assert [eng._positions[i] for i, _r in rows] == [
+                6 + 3 - 1] * 3
+        else:
+            eng.step_complete(p3, em3)
+            settled()
+        while eng.has_work():
+            eng.step()
+        settled()
+        got = {rid: list(r.output_tokens) for rid, r in rs.items()}
+        cut = {"abort": 2, "finish": 3, "max_tokens": 4}.get(what)
+        if cut:
+            assert got.pop("m1") == want.pop("m1")[:cut]
+            assert rs["m1"].finish_reason.value == {
+                "abort": "abort", "finish": "stop",
+                "max_tokens": "length"}[what]
+        assert got == want
+        assert eng.num_wave_decode_tokens == len(live)
+
+    @pytest.mark.parametrize("ends", ["length", "stop"])
+    @pytest.mark.parametrize("in_flight", [True, False])
+    def test_a_wave_holds_no_rows_last_token_ahead_of_a_step_in_flight(
+            self, tiny_parts, ends, in_flight):
+        """Every running row has ONE token left and the arrival ends on
+        its first (``max_tokens=1``: scoring traffic), so no row runs
+        behind the wave and no step follows it: what the wave decoded is
+        fetched at once (``_flush_pending_first``).  With a step in flight
+        that would hand the host each row's last token AHEAD of the step's
+        (and, where the last is a stop token, finish the request there and
+        drop the step's): the rows sit the wave out and decode in a step
+        behind it, which reconciles in order.  With nothing in flight
+        they decode in the wave and the flush is in order as it is."""
+        names = ("m0", "m1", "m2")
+
+        def reqs(stops=None):
+            return [
+                _req(rid, _prompt(j, 6), max_tokens=4,
+                     stop=(stops[rid],) if stops else ())
+                for j, rid in enumerate(names)
+            ] + [_req("late", _prompt(9, 5), max_tokens=1, stop=())]
+
+        want, _ = _reference(tiny_parts, reqs)
+        stops = None
+        if ends == "stop":
+            stops = {rid: want[rid][3] for rid in names}
+            assert all(stops[rid] not in want[rid][:3] for rid in names)
+        eng = _make_engine(tiny_parts)
+        rs = {r.id: r for r in reqs(stops)}
+        for rid in names:
+            eng.add_request(rs[rid])
+        seen = eng.step()                      # two tokens a row
+        em2, p2 = eng.step_dispatch()          # the third, in flight
+        assert [eng._headroom(rs[rid]) for rid in names] == [1, 1, 1]
+        if not in_flight:
+            seen += eng.step_complete(p2, em2)
+        eng.add_request(rs["late"])
+        em3, p3 = eng.step_dispatch()
+        if in_flight:
+            assert not p3.waves and em3 == []
+            assert [r.id for _i, r in p3.rows] == list(names)
+            assert eng.num_wave_decode_tokens == 0
+            seen += eng.step_complete(p2, em2)
+            seen += eng.step_complete(p3, em3)
+        else:
+            assert p3 is None and eng.num_wave_decode_tokens == 3
+            seen += em3
+        assert not eng.has_work() and not eng._inflight_out
+        for rid, r in rs.items():
+            assert [t for q, t in seen if q is r] == want[rid], rid
+            assert list(r.output_tokens) == want[rid]
+            assert r.finish_reason.value == (
+                "stop" if stops and rid in stops else "length")
 
     def test_step_rolls_back_on_completion_failure(
         self, tiny_parts, monkeypatch

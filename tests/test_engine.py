@@ -1113,6 +1113,108 @@ class TestMixedStep:
         joint_pass.assert_inert_wave_keeps_decode_state(
             self._decoding(tiny_model), 8)
 
+    def test_a_wave_beside_running_rows_is_the_wave_then_the_decode_step(
+            self, tiny_model):
+        """The running rows decode a token inside the wave: a seeded,
+        sampled, penalised row and a greedy one get the streams they have
+        when the wave runs alone (the key stream is the row's own)."""
+        import joint_pass
+
+        cfg, params = tiny_model
+
+        def reqs():
+            return (
+                [Request(id="a", prompt_tokens=[1, 2, 3],
+                         sampling=SamplingParams(
+                             temperature=0.7, seed=5, max_tokens=14,
+                             presence_penalty=0.5, frequency_penalty=0.2)),
+                 Request(id="g", prompt_tokens=[4, 5],
+                         sampling=SamplingParams(temperature=0.0,
+                                                 max_tokens=9))],
+                Request(id="short", prompt_tokens=[7, 8],
+                        sampling=SamplingParams(temperature=0.9, seed=3,
+                                                max_tokens=2)),
+                Request(id="late", prompt_tokens=[9, 8, 7, 6, 5],
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=8)),
+            )
+
+        joint_pass.assert_wave_is_wave_then_decode(
+            lambda: Engine(cfg, params, self._cfg(max_decode_batch=4)),
+            reqs, 1e-4)
+
+    @pytest.mark.parametrize("behind_a_step", [False, True])
+    def test_every_wave_of_a_pass_carries_the_rows_that_still_run(
+            self, tiny_model, behind_a_step):
+        """Three arrivals that do not fit one prefill segment are admitted
+        in three waves of ONE pass: the running rows decode a token in
+        each, re-read per wave (``a`` has four tokens to give and drops
+        out ahead of its last while others are in flight: that one is the
+        step's behind the waves), the slots the pass claimed sit all
+        three out, and every stream is what an engine whose waves run
+        alone gives."""
+        cfg, params = tiny_model
+        out = {}
+        for live in (True, False):
+            eng = Engine(cfg, params, self._cfg(max_decode_batch=6))
+            if not live:
+                eng._wave_rows = lambda: []
+            launched = []
+            rule = eng._wave_rows
+
+            def rows(rule=rule, launched=launched):
+                got = rule()
+                launched.append([r.id for _i, r in got])
+                return got
+
+            eng._wave_rows = rows
+            reqs = [
+                Request(id="a", prompt_tokens=[1, 2, 3],
+                        sampling=SamplingParams(
+                            temperature=0.7, seed=5, max_tokens=4,
+                            presence_penalty=0.5)),
+                Request(id="b", prompt_tokens=[4, 5],
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=12)),
+            ]
+            late = [
+                Request(id=f"l{j}",
+                        prompt_tokens=[9 - j, 8, 7, 6, 5, 4][:5 + j % 2],
+                        sampling=SamplingParams(temperature=0.0,
+                                                max_tokens=6))
+                for j in range(3)
+            ]
+            for r in reqs:
+                eng.add_request(r)
+            eng.step()
+            flying = eng.step_dispatch() if behind_a_step else None
+            for r in late:
+                eng.add_request(r)
+            del launched[:]
+            emitted, pend = eng.step_dispatch()
+            in_the_pass = list(launched)
+            assert len(pend.waves) == (3 if live else 0)
+            if flying:
+                eng.step_complete(flying[1], flying[0])
+            eng.step_complete(pend, emitted)
+            while eng.has_work():
+                eng.step()
+            assert not eng._inflight_out and not eng._pending_waves
+            out[live] = (in_the_pass,
+                         {r.id: list(r.output_tokens) for r in reqs + late},
+                         eng.num_wave_decode_tokens)
+        waves, toks, n = out[True]
+        # ``a`` holds two of its four tokens when the pass starts, and a
+        # third is in the step in flight: two / one left, of which the
+        # last is not a wave's to take while another is in flight
+        want = ([["a", "b"], ["b"], ["b"]] if not behind_a_step
+                else [["b"], ["b"], ["b"]])
+        assert waves == want and n == sum(map(len, want))
+        assert out[False][0] == [[], [], []] and out[False][2] == 0
+        assert toks == out[False][1]
+        assert [len(toks[k]) for k in ("a", "b", "l0", "l1", "l2")] == [
+            4, 12, 6, 6, 6]
+
     @pytest.mark.slow  # ~43 s; mixed-step parity + int8-engine parity
     # siblings keep both axes covered in tier-1
     def test_mixed_step_with_int8_kv(self, tiny_model):

@@ -514,6 +514,26 @@ def test_a_wave_of_inert_rows_leaves_the_decode_state_and_the_states():
     assert before.any()
 
 
+def test_a_wave_beside_running_rows_is_the_wave_then_the_decode_step():
+    """Both pools: the running rows read and write their own slots' conv
+    states inside the wave's pass, the slot being admitted is written by
+    its prompt alone, and the row out of headroom keeps its state."""
+    import joint_pass
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(4))
+
+    def reqs():
+        return ([_req("a", tokens_of(7, seed=3), n=12),
+                 _req("g", tokens_of(5, seed=4), n=9)],
+                _req("short", tokens_of(6, seed=5), n=2),
+                _req("late", tokens_of(11, seed=6), n=8))
+
+    with jax.default_matmul_precision("highest"):
+        joint_pass.assert_wave_is_wave_then_decode(
+            lambda: _engine(cfg, params, slots=4), reqs, TOL)
+
+
 def test_a_reused_slot_starts_from_no_state():
     cfg = tiny()
     params = init_params(cfg, jax.random.PRNGKey(5))

@@ -207,6 +207,30 @@ def test_a_wave_of_inert_rows_leaves_the_decode_state_bit_for_bit():
         _decoding(cfg, init_params(cfg, jax.random.PRNGKey(3))), 16)
 
 
+def test_a_wave_beside_running_rows_is_the_wave_then_the_decode_step():
+    """The running rows' one-token rows go through the latent kernel and
+    the grouped product beside the wave's prompt: the streams of the wave
+    alone followed by the decode step alone."""
+    import joint_pass
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(3))
+
+    def reqs():
+        def req(rid, n, sd, m, **kw):
+            return Request(id=rid, prompt_tokens=tokens_of(n, seed=sd),
+                           sampling=SamplingParams(max_tokens=m, **kw))
+        return ([req("a", 7, 3, 12, temperature=0.8, seed=11,
+                     frequency_penalty=0.3),
+                 req("g", 5, 4, 9, temperature=0.0)],
+                req("short", 6, 5, 2, temperature=0.0),
+                req("late", 11, 6, 8, temperature=0.0))
+
+    with jax.default_matmul_precision("highest"):
+        joint_pass.assert_wave_is_wave_then_decode(
+            lambda: _engine(cfg, params, slots=4), reqs, 2e-5)
+
+
 def test_padding_rows_and_idle_slots_change_no_live_rows_logits():
     cfg = tiny()
     params = init_params(cfg, jax.random.PRNGKey(4))

@@ -181,6 +181,66 @@ class TestPlanBroadcast:
         )
         assert steady < long.plan_bytes_max / 3
 
+    def test_a_follower_replays_a_wave_with_the_leaders_live_rows(
+            self, tiny):
+        """A wave's running rows decode a token in it: which rows is a
+        host decision, so the plan carries each wave's live slots and the
+        follower launches those; a plan naming a slot that does not run
+        on the replica is a divergence, not a guess."""
+        from helix_tpu.serving.multihost_serving import PlanDrive
+
+        leader = PlanLeader(_engine(tiny))
+        fe = _engine(tiny)
+        follower = FollowerLoop(fe, leader.journal)
+        reqs = [
+            Request(id=f"r{i}", prompt_tokens=[3 + i, 5, 8],
+                    sampling=SamplingParams(temperature=0.8, top_k=20,
+                                            max_tokens=5 + 4 * i))
+            for i in range(4)
+        ]
+        for r in reqs:
+            leader.add_request(r)
+        _drain(leader)
+        plans = [rec for rec in leader.journal._records
+                 if rec.get("kind") == "plan"]
+        waves = [rows for rec in plans for rows in rec["wave_rows"]]
+        # two slots: the first wave admits into an idle engine, the later
+        # ones beside the one row still running
+        assert waves[0] == [] and any(waves[1:]), waves
+        assert leader.engine.num_wave_decode_tokens == sum(map(len, waves))
+        _replay(follower)
+        assert fe.num_wave_decode_tokens == (
+            leader.engine.num_wave_decode_tokens)
+        for r in reqs:
+            assert fe._requests[r.id].output_tokens == r.output_tokens
+        assert follower.stats()["digest_mismatches"] == 0
+        # the leader's set is replayed, never re-derived: a slot that
+        # does not run here is a divergence, and beside a row that runs a
+        # wave the plan has no set for (admissions carried over from a
+        # discarded plan) launches it sitting out
+        for wave_rows, decoded in (([[1]], None), ([], 0), ([[0]], 1)):
+            other = _engine(tiny)
+            for rid in ("x", "y"):
+                other.add_request(Request(
+                    id=rid, prompt_tokens=[4, 5, 6],
+                    sampling=SamplingParams(temperature=0.0, max_tokens=4)))
+                other._plan_drive = PlanDrive(
+                    budget=None, queue_blocked=False, drafts=[], resumes=[],
+                    cached_tokens={},
+                    wave_rows=wave_rows if rid == "y" else [])
+                if rid == "y" and decoded is None:
+                    with pytest.raises(
+                            RuntimeError, match="plan-follow divergence"):
+                        other.step()
+                else:
+                    other.step()
+            assert other.num_wave_decode_tokens == (decoded or 0)
+        # a set of the leader's that no wave here took is one too
+        lost = dict(plans[0], wave_rows=[[], [0]], seq=0, step=0,
+                    digest=None, digest_step=None)
+        with pytest.raises(Exception, match="admission waves not launched"):
+            FollowerLoop(_engine(tiny), leader.journal).apply(lost)
+
     def test_greedy_bit_identity(self, tiny):
         leader = PlanLeader(_engine(tiny))
         fe = _engine(tiny)

@@ -412,6 +412,23 @@ def test_a_wave_of_inert_rows_leaves_the_decode_state_and_the_pool(model):
     assert before[0].any()
 
 
+def test_a_wave_beside_running_rows_is_the_wave_then_the_decode_step(model):
+    """The running rows' matrix states take one recurrence step inside the
+    wave's pass; the row out of headroom keeps its state bit for bit."""
+    import joint_pass
+
+    cfg, params = model
+
+    def reqs():
+        return ([_req("a", tokens_of(7, 3), 12, seed=11),
+                 _req("g", tokens_of(5, 4), 9)],
+                _req("short", tokens_of(6, 5), 2),
+                _req("late", tokens_of(11, 6), 8))
+
+    joint_pass.assert_wave_is_wave_then_decode(
+        lambda: _engine(cfg, params, max_decode_batch=4), reqs, TOL)
+
+
 def test_a_reused_slot_starts_from_zeros(model):
     """One slot, two requests one after the other: the second reads what it
     reads on a fresh engine, not the state the first left."""

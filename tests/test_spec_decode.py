@@ -239,6 +239,61 @@ class TestVerifyBesideAChunk:
         for k in st0:
             assert np.array_equal(st0[k], st1[k]), k
 
+    def test_a_row_under_speculation_decodes_one_plain_token_in_a_wave(
+            self, tiny_model):
+        """An admission wave beside two rows under speculation: each
+        decodes ONE plain token in it (its eight-wide row has one live
+        position), that token is fetched ahead of the drafting (a spec
+        engine reconciles before it dispatches), so the step behind the
+        wave verifies drafts as it did, and the rows go on being accepted
+        as before: the streams are a plain engine's, with the wave's rows
+        live or sitting out."""
+        import joint_pass
+
+        cfg, params = tiny_model
+        prompts = (REP, MIX, REP[2:])
+        sp = SamplingParams(temperature=0.0, max_tokens=24)
+        plain = make_engine(cfg, params, False, max_decode_batch=3)
+        outs = plain.generate(list(prompts), sp)
+        after = {}
+        for live in (True, False):
+            eng = make_engine(cfg, params, True, spec_tokens=7,
+                              max_decode_batch=3, max_prefill_len=32)
+            if not live:
+                eng._wave_rows = lambda: []
+            reqs = [Request(id=f"r{i}", prompt_tokens=list(p), sampling=sp)
+                    for i, p in enumerate(prompts)]
+            eng.add_request(reqs[0])
+            eng.add_request(reqs[1])
+            eng.step()
+            eng.step()
+            had = [len(r.output_tokens) for r in reqs[:2]]
+            eng.add_request(reqs[2])
+            seen: list = []
+            with joint_pass.watched_programs(seen):
+                eng.step()
+            wave, step = seen
+            assert wave["rung"] and not step["rung"]
+            want = [0, 0, -1] if live else [-1, -1, -1]
+            assert wave["draft_len"].tolist() == want
+            moved = (wave["after"][0]["positions"]
+                     - wave["before"][0]["positions"])
+            assert moved.tolist() == [int(live), int(live), 0]
+            # the step behind the wave keeps its verify: the wave's
+            # tokens are on the host before the drafter reads the rows
+            assert not eng._pending_waves
+            assert (step["draft_len"][:2] > 0).any(), step["draft_len"]
+            got = [len(r.output_tokens) - n for r, n in zip(reqs, had)]
+            assert all(g >= 1 + int(live) for g in got[:2]), got
+            accepted = eng.num_spec_accepted_tokens
+            while eng.has_work():
+                eng.step()
+            assert [r.output_tokens for r in reqs] == outs
+            after[live] = eng.num_spec_accepted_tokens - accepted
+            assert eng.num_wave_decode_tokens == 2 * int(live)
+        # the drafter's hits on the repeated suffix go on after the wave
+        assert after[True] > 0 and after[False] > 0
+
     def test_the_verify_rows_and_the_chunk_share_one_forward(
             self, tiny_model):
         import joint_pass

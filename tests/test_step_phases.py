@@ -184,6 +184,81 @@ def test_metrics_count_the_joint_pass_programs(spine):
     assert parsed["helix_joint_pass_steps_total"] >= 4
 
 
+@pytest.fixture(scope="module")
+def wave_beside_a_row(spine):
+    """A request streams forty tokens; once its first is out two short
+    completions arrive (a chat template alone is longer than this engine's
+    prefill segment: a chat prompt is admitted in chunks, a bare completion
+    in a wave).  The first takes the free slot, the second the slot the
+    first leaves: each is admitted in a wave in which the streaming row
+    decodes a token, the second behind a step in flight.  Returns the
+    parsed ``/metrics`` before and after."""
+    url = spine["url"]
+    before = prom.parse(
+        requests.get(f"{url}/metrics", timeout=10).text, MODEL)
+    first = threading.Event()
+
+    def long_one():
+        r = requests.post(
+            f"{url}/v1/chat/completions",
+            json={"model": MODEL, "max_tokens": 40, "temperature": 0.9,
+                  "seed": 3, "stream": True,
+                  "messages": [{"role": "user", "content": "keep going"}]},
+            stream=True, timeout=120,
+        )
+        for line in r.iter_lines():
+            if line:
+                first.set()
+
+    def short_one(text):
+        r = requests.post(
+            f"{url}/v1/completions",
+            json={"model": MODEL, "prompt": text, "max_tokens": 6,
+                  "temperature": 0}, timeout=120)
+        assert r.status_code == 200, r.text
+
+    threads = [threading.Thread(target=long_one)]
+    threads[0].start()
+    assert first.wait(120)
+    for text in ("beside it", "and behind"):
+        threads.append(threading.Thread(target=short_one, args=(text,)))
+        threads[-1].start()
+    for t in threads:
+        t.join(120)
+    after = prom.parse(
+        requests.get(f"{url}/metrics", timeout=10).text, MODEL)
+    return before, after
+
+
+def test_metrics_count_the_tokens_decoded_inside_waves(wave_beside_a_row):
+    """``helix_wave_decode_tokens_total``: a wave's running rows decode a
+    token in its pass; requests served one at a time never move it."""
+    before, after = wave_beside_a_row
+    assert before["helix_wave_decode_tokens_total"] == 0
+    assert after["helix_wave_decode_tokens_total"] >= 1
+    assert after["helix_wave_decode_tokens_total"] < (
+        after["helix_decode_tokens_total"])
+
+
+def test_debug_flight_says_how_many_rows_decoded_in_a_steps_waves(
+        spine, wave_beside_a_row):
+    body = requests.get(
+        f"{spine['url']}/v1/debug/flight?model={MODEL}&recent=512",
+        timeout=10).json()
+    recs = body["models"][MODEL]["recent"]
+    assert all("wave_rows" in rec for rec in recs)
+    beside = [rec for rec in recs if rec["wave_rows"]]
+    assert beside and all(
+        rec["joint_pass"] and rec["admissions"]
+        and rec["inert_rows"] < rec["joint_pass"] * rec["slots_total"]
+        for rec in beside)
+    # (a pass that only fills the pipeline leaves no record: the wave
+    # that took the free slot is on the counter alone)
+    _, after = wave_beside_a_row
+    assert sum(rec["wave_rows"] for rec in recs) <= (
+        after["helix_wave_decode_tokens_total"])
+
+
 # ---- (b) flight phases and one observation a step -------------------------
 
 
@@ -252,8 +327,18 @@ def test_flight_records_carry_the_joint_pass_and_its_inert_rows(stepped):
     assert joint and all(rec["prefill_tokens"] for rec in joint)
     assert any(rec["joint_pass"] == 0 and rec["inert_rows"] == 0
                and rec["kind"] == "decode" for rec in recs)
-    # an admission wave's state rows all sit out
-    assert any(rec["inert_rows"] >= len(eng.slots) for rec in joint)
+    # a wave into an engine with nothing running: every state row sits out
+    assert any(rec["inert_rows"] >= len(eng.slots) and not rec["wave_rows"]
+               for rec in joint)
+    # a wave beside a running row: that row decodes a token in it
+    beside = [rec for rec in joint if rec["wave_rows"]]
+    assert beside and all(
+        rec["inert_rows"] < rec["joint_pass"] * len(eng.slots)
+        for rec in beside)
+    assert 0 < sum(rec["wave_rows"] for rec in recs) <= (
+        eng.num_wave_decode_tokens)
+    assert all(rec["wave_rows"] == 0 for rec in recs
+               if not rec["joint_pass"])
     # (the ring leaves out the loop's first step, which launched some)
     assert 0 < sum(rec["joint_pass"] for rec in recs) <= (
         eng.num_joint_pass_steps)
@@ -430,6 +515,32 @@ def test_capture_launch_span_says_whether_the_segments_shared_a_pass(capture):
 def test_capture_launch_span_carries_the_query_block(capture):
     _, _, events = capture
     assert {ln["attn_q_block"] for ln in events["helix.loop.launch"]} == {1}
+
+
+def test_program_times_lists_the_launches_beside_the_programs(capture):
+    """``tools/program_times.py`` answers with two keys: the device's
+    programs by name, and the host's launches by kind (each span's
+    ``live_rows`` / ``inert_rows``), which are not programs."""
+    import subprocess
+
+    body, _, events = capture
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tools", "program_times.py"),
+         body["log_dir"]],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    answer = json.loads(out.stdout)
+    assert set(answer) == {"programs", "launches"}
+    assert not any(name.startswith("helix.") for name in answer["programs"])
+    want: dict = {}
+    for ln in events["helix.loop.launch"]:
+        want.setdefault(ln["kind"], []).append(
+            (ln["live_rows"], ln["inert_rows"]))
+    got = {kind: list(zip(rows["live_rows"], rows["inert_rows"]))
+           for kind, rows in answer["launches"].items()}
+    assert {k: sorted(v) for k, v in got.items()} == {
+        k: sorted(v) for k, v in want.items()}
 
 
 def test_capture_is_stamped_with_the_monotonic_clock(capture):

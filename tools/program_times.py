@@ -13,8 +13,11 @@ the shape's rows say whose rows a call multiplied).  A capture's events
 carry no ``op_name`` (their stats are three timings; PERF.md section 7 item
 8), so a named scope cannot be summed from it: a program against the same
 program of another tree, and a kernel's calls by shape, are what it gives.
-``benchmark/lib/xplane.py`` has the arithmetic; ``tools/bench_pairs.py``
-calls this after a traced run.
+The answer has two keys: ``programs`` (by program name) and ``launches``
+(each ``helix.loop.launch`` span's ``live_rows`` and ``inert_rows`` by
+``kind``, as the host counted them: an admission wave's running rows decode
+in it, so ``admit`` reads ~31 / ~1 of 32).  ``benchmark/lib/xplane.py`` has
+the arithmetic; ``tools/bench_pairs.py`` calls this after a traced run.
 """
 import argparse
 import json
@@ -80,7 +83,23 @@ def main():
         prog["min_ms"], prog["max_ms"] = durs[0] / 1e6, durs[-1] / 1e6
         prog["op_ms"] = {
             k: v / n / 1e6 for k, v in sorted(prog.pop("op").items())}
-    text = json.dumps(programs, indent=1, sort_keys=True)
+    # the launches as the host counted them (``helix.loop.launch``'s stats),
+    # by kind: the state rows each launched live and those that sat out
+    launches = {}
+    for plane in data.planes:
+        if xplane.DEVICE_PLANE.match(plane.name):
+            continue
+        for ln in plane.lines:
+            for ev in ln.events:
+                if ev.name != "helix.loop.launch":
+                    continue
+                stats = dict(ev.stats)
+                rows = launches.setdefault(
+                    str(stats.get("kind")), {"live_rows": [], "inert_rows": []})
+                for key, seen in rows.items():
+                    seen.append(int(stats.get(key, 0)))
+    text = json.dumps({"programs": programs, "launches": launches},
+                      indent=1, sort_keys=True)
     if a.out:
         with open(a.out, "w") as f:
             f.write(text)
