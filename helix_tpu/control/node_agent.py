@@ -189,9 +189,9 @@ def _build_served_model(pm: ProfileModel, mesh=None) -> ServedModel:
             # a profile writes it as a mapping; the config keeps it hashable
             pm.model_overrides["rope_scaling"] = tuple(
                 sorted(pm.model_overrides["rope_scaling"].items()))
-        if isinstance(pm.model_overrides.get("layer_types"), list):
-            pm.model_overrides["layer_types"] = tuple(
-                pm.model_overrides["layer_types"])
+        for key in ("layer_types", "held_experts"):
+            if isinstance(pm.model_overrides.get(key), list):
+                pm.model_overrides[key] = tuple(pm.model_overrides[key])
         if model_cfg is not None and pm.model_overrides:
             # overrides apply to catalog configs too (shrink a catalog
             # architecture for a dev mesh) — silently ignoring them

@@ -731,6 +731,10 @@ _PREFIX_CACHE = ("enable_prefix_cache",
                  lambda cfg, mesh: cfg.enable_prefix_cache)
 _MATRIX_STATE = ("a matrix state (power retention)",
                  lambda m: m.num_retention_layers > 0)
+_DELTA_STATE = ("a matrix state and a conv tail (gated delta rule)",
+                lambda m: m.num_deltanet_layers > 0)
+_HELD_EXPERTS = ("held experts (one expert-parallel rank of the routed "
+                 "experts)", lambda m: m.held_experts is not None)
 _REFUSALS = (
     (_MULTI_DEVICE, _LATENT,
      "the latent pool and its kernel are single-device: mesh {tp: 1}"),
@@ -765,6 +769,25 @@ _REFUSALS = (
     (_PREFIX_CACHE, _MATRIX_STATE,
      "a filed state is tens of MB a layer: a snapshot budget and an "
      "eviction of its own; set enable_prefix_cache: false"),
+    (_MULTI_DEVICE, _DELTA_STATE,
+     "the state pool and the delta-rule kernel are single-device"),
+    (_INT8_KV, _DELTA_STATE,
+     "the pool beside a state pool is bf16 or f32, and the state is a "
+     "float32 matrix"),
+    (_ADAPTERS, _DELTA_STATE, "no LoRA targets on the delta-rule "
+     "projections"),
+    (_SPEC, _DELTA_STATE,
+     "a rejected draft would have to roll the matrix state back"),
+    (_TIERED, _DELTA_STATE,
+     "a demoted cold middle is resumed without the state at its end"),
+    (_HOST_TIER, _DELTA_STATE,
+     "a preempted sequence's state (megabytes a layer) has no host tier"),
+    (_PREFIX_CACHE, _DELTA_STATE,
+     "a filed state is megabytes a layer: a snapshot budget and an "
+     "eviction of its own; set enable_prefix_cache: false"),
+    (_MULTI_DEVICE, _HELD_EXPERTS,
+     "the other ranks' experts and the exchange with them are not run: "
+     "one chip computes its own experts' part of the sum"),
 )
 
 
@@ -793,6 +816,12 @@ def _refuse_call(model_cfg, what: str) -> None:
             f"{model_cfg.name}: a matrix state (power retention) is not "
             f"served with {what} (the sequence's state has no place in "
             "what it moves, and it has no page of KV)"
+        )
+    if model_cfg.num_deltanet_layers:
+        raise UnsupportedForModel(
+            f"{model_cfg.name}: a matrix state and a conv tail (gated "
+            f"delta rule) is not served with {what} (the sequence's state "
+            "has no place in what it moves)"
         )
 
 
@@ -837,56 +866,109 @@ def _state_after(zf, S, t0, n):
     return jnp.stack(out, axis=1)
 
 
-def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
-    """The ``conv_fn`` of one segment of the step (``models/llama.py::
-    _conv_mixer``): its tokens lie row after row on one flat axis, row
-    ``r`` the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
-    ``slots[r]``, which has ``hist[r]`` tokens behind it.
+def _conv_rows(z, taps, pool, lc, t0, qlen, hist, slots, snap=None):
+    """A causal depthwise convolution over one segment of the step, its
+    tokens row after row on one flat axis: row ``r`` the ``qlen[r]`` tokens
+    from ``t0[r]`` of the sequence in slot ``slots[r]``, which has
+    ``hist[r]`` tokens behind it.  ``z [B, S, E]``, ``taps [E, K]``, ``pool
+    [layers, slots, K - 1, E]`` read and written at layer ``lc``.
 
     A token's tap ``d`` back is its flat neighbour if that is in its own
     row, else its row's state (zeros for a row that starts its sequence):
     never the neighbour row's token.  The row's new state, the last ``K -
     1`` of (state, the row's inputs), is written to its slot; a row with
     no fresh token (an idle slot, padding) and a slot index past the pool
-    write nothing.  ``snap [R]``: also hand back each row's state after
-    that many of its tokens (what a prefix hit resumes from), stacked over
-    the conv layers in the carry's last element (a segment without
-    ``snap`` passes that element on).
+    write nothing.  Returns ``(y float32, pool, each row's state after
+    snap[r] of its tokens or None)``."""
+    from helix_tpu.models.llama import short_conv
+
+    Bz, Sz, E = z.shape
+    T, K1 = Bz * Sz, taps.shape[-1] - 1
+    zf = z.reshape(T, E)
+    nslots = pool.shape[1]
+    S = pool[lc][jnp.clip(slots, 0, nslots - 1)]            # [R, K-1, E]
+    S = jnp.where((hist > 0)[:, None, None], S, 0).astype(z.dtype)
+    prevs = []
+    for d in range(1, K1 + 1):
+        if Sz == 1:
+            # one-token rows (a decode step): every tap is the state
+            prev = S[:, K1 - d]
+        else:
+            prev = jnp.pad(zf, ((d, 0), (0, 0)))[:T]
+            for j in range(d):
+                # the row's token j reaches d back past its start
+                at = jnp.where(qlen > j, t0 + j, T)
+                prev = prev.at[at].set(S[:, K1 + j - d], mode="drop")
+        prevs.append(prev.reshape(z.shape))
+    y = short_conv(z, taps, prevs)
+    new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
+    dest = jnp.where(qlen > 0, slots, nslots)
+    pool = pool.at[lc, dest].set(new, mode="drop")
+    return y, pool, None if snap is None else _state_after(
+        zf, S, t0, snap).astype(pool.dtype)
+
+
+def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
+    """The ``conv_fn`` of one segment of the step (``models/llama.py::
+    _conv_mixer``): ``_conv_rows`` on the state pool in the carry.  ``snap
+    [R]``: also hand back each row's state after that many of its tokens
+    (what a prefix hit resumes from), stacked over the conv layers in the
+    carry's last element (a segment without ``snap`` passes that element
+    on).
 
     The carry it is called with is ``((page carry, kacc, vacc, state pool[,
     snaps]), conv layer index)``."""
-    from helix_tpu.models.llama import short_conv
 
     def conv_fn(z, taps, carry_cache):
         (caches, kacc, vacc, pool, *snaps), lc = carry_cache
-        Bz, Sz, E = z.shape
-        T, K1 = Bz * Sz, taps.shape[-1] - 1
-        zf = z.reshape(T, E)
-        nslots = pool.shape[1]
-        S = pool[lc][jnp.clip(slots, 0, nslots - 1)]        # [R, K-1, E]
-        S = jnp.where((hist > 0)[:, None, None], S, 0).astype(z.dtype)
-        prevs = []
-        for d in range(1, K1 + 1):
-            if Sz == 1:
-                # one-token rows (a decode step): every tap is the state
-                prev = S[:, K1 - d]
-            else:
-                prev = jnp.pad(zf, ((d, 0), (0, 0)))[:T]
-                for j in range(d):
-                    # the row's token j reaches d back past its start
-                    at = jnp.where(qlen > j, t0 + j, T)
-                    prev = prev.at[at].set(S[:, K1 + j - d], mode="drop")
-            prevs.append(prev.reshape(z.shape))
-        y = short_conv(z, taps, prevs)
-        new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
-        dest = jnp.where(qlen > 0, slots, nslots)
-        pool = pool.at[lc, dest].set(new, mode="drop")
-        if snap is not None:
-            snaps = [snaps[0].at[lc].set(
-                _state_after(zf, S, t0, snap).astype(pool.dtype))]
+        y, pool, after = _conv_rows(
+            z, taps, pool, lc, t0, qlen, hist, slots, snap)
+        if after is not None:
+            snaps = [snaps[0].at[lc].set(after)]
         return y, (caches, kacc, vacc, pool, *snaps)
 
     return conv_fn
+
+
+def _deltanet_rows_fn(cfg, t0, qlen, hist, slots, backend, decode: bool):
+    """The ``deltanet_fn`` of one segment of the step (``models/llama.py::
+    _deltanet_mixer``), under ``_conv_rows_fn``'s contract for TWO states a
+    slot: the convolution's tail (``_conv_rows``, the same look-back as a
+    gated short convolution's at 4 taps over the q | k | v channels) and the
+    float32 matrix a value head, both read and written IN PLACE in the
+    carry's pair of pools.
+
+    ``decode``: the segment's rows are one token each and row ``b`` is slot
+    ``b`` (the rule applied once: on a TPU one pass of the decode kernel
+    over the live slots).  Else the rows are runs of fresh tokens on one
+    flat axis (the chunked form, a row at a time, 64 tokens at a time).
+
+    Called ``(x W_qkv, g, beta, taps, carry)``, the carry ``((page carry,
+    kacc, vacc, (conv pool, S pool)), delta layer index)``."""
+    from helix_tpu.models.llama import deltanet_heads
+    from helix_tpu.ops.deltanet import delta_decode, delta_rows
+
+    def deltanet_fn(x, g, beta, taps, carry_cache):
+        (caches, kacc, vacc, (c_pool, s_pool)), lc = carry_cache
+        Bx, Sx, _ = x.shape
+        with jax.named_scope("deltanet.conv"):
+            y, c_pool, _ = _conv_rows(
+                x, taps, c_pool, lc, t0, qlen, hist, slots)
+            q, k, v = deltanet_heads(y, cfg)
+        with jax.named_scope("deltanet.mix"):
+            if decode:
+                o, s_pool = delta_decode(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s_pool,
+                    lc, qlen > 0, backend=backend)
+            else:
+                flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
+                o, s_pool = delta_rows(
+                    flat(q), flat(k), flat(v), flat(g), flat(beta), t0,
+                    qlen, hist, slots, s_pool, lc)
+        return o.reshape((Bx, Sx) + o.shape[1:]), (
+            caches, kacc, vacc, (c_pool, s_pool))
+
+    return deltanet_fn
 
 
 def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
@@ -960,6 +1042,10 @@ def _state_rows_fns(cfg, rows_s, backend, rows_p=None, split=None,
         return {"retention_fn": _segments_fn(
             rows_p and _retention_rows_fn(*rows_p[:4], backend, False),
             _retention_rows_fn(*rows_s, backend, True), 4, split, join)}
+    if cfg.state_mixer == "deltanet":
+        return {"deltanet_fn": _segments_fn(
+            rows_p and _deltanet_rows_fn(cfg, *rows_p[:4], backend, False),
+            _deltanet_rows_fn(cfg, *rows_s, backend, True), 3, split, join)}
     return {"conv_fn": _segments_fn(
         rows_p and _conv_rows_fn(*rows_p), _conv_rows_fn(*rows_s), 1,
         split, join)}
@@ -1560,6 +1646,14 @@ class Engine:
                 model_cfg.qk_rope_head_dim,
                 jnp.dtype(self.cache_cfg.dtype).itemsize,
             )
+            if model_cfg.num_deltanet_layers:
+                from helix_tpu.ops.deltanet_kernel import (
+                    check_deltanet_geometry,
+                )
+
+                check_deltanet_geometry(
+                    model_cfg.linear_key_heads, model_cfg.linear_value_heads,
+                    model_cfg.linear_key_dim, model_cfg.linear_value_dim)
         elif self._backend == "pallas" and not model_cfg.num_attn_layers:
             if model_cfg.num_retention_layers:
                 from helix_tpu.ops.retention_kernel import (
@@ -1623,6 +1717,7 @@ class Engine:
         # steps read and wrote, by the form that ran them, and their bytes
         # (what a roofline reckoned from a trace divides by)
         self.num_retention_rows = {"decode": 0, "chunk": 0}
+        self.num_deltanet_rows = {"decode": 0, "chunk": 0}
         self.state_bytes_touched = 0
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
@@ -1897,6 +1992,10 @@ class Engine:
         # any: the busiest expert's tokens over the mean, and the distinct
         # experts touched (mean over the MoE layers)
         self.moe_routed_tokens = 0
+        # held experts: assignments to experts on other ranks, which this
+        # chip does not compute (``moe_routed_tokens`` then counts the
+        # assignments to the experts held here)
+        self.moe_away_tokens = 0
         self.moe_expert_load_max_ratio = 0.0
         self.moe_experts_touched = 0.0
         # of the same step, the dropless grouped product's rows routed
@@ -1945,17 +2044,20 @@ class Engine:
             _refuse_call(self.model_cfg, "the persistent KV filestore")
         self._kv_filestore = store
 
-    def _note_retention_rows(self, plan, draft_len, n_extra) -> None:
-        """Count the rows of the state pool this step reads and writes: a
-        live decode row once a fused step, a prefill row with a slot once;
-        each row is every retention layer's state of one slot, read once and
-        written once."""
+    def _note_state_rows(self, plan, draft_len, n_extra) -> None:
+        """Count the rows of a matrix state's pool this step reads and
+        writes: a live decode row once a fused step, a prefill row with a
+        slot once; each row is every recurrent layer's state of one slot,
+        read once and written once."""
         live = (np.asarray(draft_len) >= 0) & (
             np.asarray(self._active_sent) > 0)
         dec = int(np.count_nonzero(live)) * (1 + int(n_extra))
         chunk = sum(1 for r in plan.rows if r.slot >= 0) if plan else 0
-        self.num_retention_rows["decode"] += dec
-        self.num_retention_rows["chunk"] += chunk
+        rows = (self.num_deltanet_rows
+                if self.model_cfg.num_deltanet_layers
+                else self.num_retention_rows)
+        rows["decode"] += dec
+        rows["chunk"] += chunk
         self.state_bytes_touched += 2 * (dec + chunk) * (
             self.recurrent_state_bytes // self.cfg.max_decode_batch)
 
@@ -3439,7 +3541,7 @@ class Engine:
 
     def _drain_moe_drops(self) -> None:
         """Fold the queued MoE step stats (``[dropped, routed, load max
-        ratio, experts touched, tile fill]`` a step, queued un-fetched by
+        ratio, experts touched, tile fill, away]`` a step, queued un-fetched by
         ``_ragged_step``) into the host counters.  Only arrays the device
         has already produced are read, so a step still in flight is never
         waited for; called after each step's own fetch, which is when its
@@ -3451,8 +3553,9 @@ class Engine:
         self._moe_drop_handles = [
             h for h in self._moe_drop_handles if not h.is_ready()
         ]
-        stats = np.asarray(jax.device_get(ready), np.float64)   # [n, 5]
+        stats = np.asarray(jax.device_get(ready), np.float64)   # [n, 6]
         self.moe_routed_tokens += int(stats[:, 1].sum())
+        self.moe_away_tokens += int(stats[:, 5].sum())
         routed = stats[stats[:, 1] > 0]
         if len(routed):
             self.moe_expert_load_max_ratio = float(routed[-1, 2])
@@ -5093,8 +5196,8 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
-        if self.model_cfg.num_retention_layers:
-            self._note_retention_rows(
+        if self.model_cfg.state_mixer in ("retention", "deltanet"):
+            self._note_state_rows(
                 plan if rows else None, draft_len, n_extra)
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
@@ -5120,6 +5223,11 @@ class Engine:
             **({"retention_layers": self.model_cfg.num_retention_layers,
                 "attn_layers": self.model_cfg.num_attn_layers}
                if self.model_cfg.num_retention_layers else {}),
+            **({"deltanet_layers": self.model_cfg.num_deltanet_layers,
+                "attn_layers": self.model_cfg.num_attn_layers}
+               if self.model_cfg.num_deltanet_layers else {}),
+            **({"held_experts": self.model_cfg.num_held_experts}
+               if self.model_cfg.held_experts else {}),
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

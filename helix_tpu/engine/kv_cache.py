@@ -47,7 +47,11 @@ normaliser ``Z [layers, slots, kv heads, head_dim, head_dim]``:
 model with NO attention layer has a page pool of no bytes: pages are then
 only the bookkeeping of tokens a sequence (admission against ``num_pages``,
 ``max_pages_per_seq``, ``kv_pages_used``), and ``fit_hbm`` sizes SLOTS
-against the budget, not pages.
+against the budget, not pages.  A delta-rule layer keeps a PAIR of unlike
+arrays: its convolution's tail ``[layers, slots, K - 1, channels]`` in the
+model's dtype and the float32 matrix ``S [layers, slots, value heads, dk,
+dv]``; its model's attention layers are latent, so the state pool stands
+beside a LATENT page pool whose layer axis counts those layers alone.
 """
 
 from __future__ import annotations
@@ -204,8 +208,9 @@ class PagedKVCache:
     v_scale: Optional[jax.Array] = None
     # the state pool: ``[conv layers, slots, K - 1, E]`` in the model's
     # dtype for gated short convolutions; the pair ``(S, Z)`` in float32 for
-    # power retention (``CacheConfig.state_shapes``); None for a model whose
-    # memory is pages alone
+    # power retention, ``(conv tail, S)`` for the gated delta rule
+    # (``CacheConfig.state_shapes``); None for a model whose memory is
+    # pages alone
     state: Optional[object] = None
 
     @classmethod
@@ -215,10 +220,10 @@ class PagedKVCache:
         cache: CacheConfig,
         mesh=None,
     ) -> "PagedKVCache":
-        if model.is_mla:
-            return cls._create_latent(model, cache, mesh)
         if model.state_mixer:
             return cls._create_with_state(model, cache, mesh)
+        if model.is_mla:
+            return cls._create_latent(model, cache, mesh)
         shape = (
             model.num_layers,
             cache.num_pages,
@@ -291,8 +296,8 @@ class PagedKVCache:
 
     @classmethod
     def _create_with_state(cls, model, cache, mesh) -> "PagedKVCache":
-        """Pages for the attention layers (no bytes where there are none)
-        and a state pool for the recurrent ones."""
+        """Pages for the attention layers (K/V or latent; no bytes where
+        there are none) and a state pool for the recurrent ones."""
         arrays = tuple(jnp.zeros(shp, jnp.dtype(dt))
                        for shp, dt in cache.state_shapes(model))
         return cls._create_on_one_device(
@@ -461,6 +466,7 @@ def _write_latent(cache, c_new, r_new, pages, offsets, valid):
     return PagedKVCache(
         k_pages=scatter(cache.k_pages, c_new),
         v_pages=scatter(cache.v_pages, r_new),
+        state=cache.state,
     )
 
 

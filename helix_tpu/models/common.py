@@ -8,8 +8,9 @@ caps), not subclasses, so one compiled forward function serves them all.
 
 A model's depth is a sequence of RUNS of one kind of layer
 (``ModelConfig.layer_runs``): a kind is a token mixer (attention, a gated
-short convolution with a fixed per-sequence state, or power retention,
-whose per-sequence state is a matrix a kv head) times an FFN (dense, or
+short convolution with a fixed per-sequence state, power retention, whose
+per-sequence state is a matrix a kv head, or the gated delta rule, whose
+state is a matrix a value head and a conv tail) times an FFN (dense, or
 routed experts).  A dense decoder is one run; DeepSeek-V2 is
 two (the leading dense layers, then the expert layers); LFM2 interleaves
 three kinds in thirteen, of which the runs that repeat back to back (the
@@ -29,7 +30,7 @@ from typing import Any, Optional
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
     key: str        # the run's stack in the parameter tree
-    mixer: str      # "attn" | "conv" | "retention"
+    mixer: str      # "attn" | "conv" | "retention" | "deltanet"
     moe: bool       # routed experts (else a dense FFN)
     count: int      # layers a repetition
     first: int      # its first layer among its mixer's layers, repetition 0
@@ -91,21 +92,51 @@ class ModelConfig:
     # when ``moe_renormalize`` (LFM2)
     moe_scoring: str = "softmax"
     moe_expert_bias: bool = False
+    # ``(lo, hi)``: this chip is one expert-parallel rank and holds experts
+    # ``[lo, hi)`` of ``num_experts``.  The router scores all of them at the
+    # published top-k; the layer computes the part of the sum its own
+    # experts give (``models/moe.py``).  None: every expert is here
+    held_experts: Optional[tuple] = None
+    # SwiGLU clamp, dense FFN and every expert: ``silu(min(gate, limit)) *
+    # clip(up, -limit, limit)``; 0 = none
+    swiglu_limit: float = 0.0
+    # a second pair of norms a layer, on each branch's OUTPUT:
+    # ``x + n_b(Mixer(n_a(x)))``, ``x + n_d(FFN(n_c(x)))``
+    post_norms: bool = False
     # --- interleaved token mixers (LFM2); None = attention at every layer ---
-    # one of "attn" | "conv" | "retention" a layer.  A "conv" layer is a
-    # gated short convolution of ``conv_kernel`` taps whose whole state is
-    # the last ``conv_kernel - 1`` inputs of the sequence: it has no pages
+    # one of "attn" | "conv" | "retention" | "deltanet" a layer.  A "conv"
+    # layer is a gated short convolution of ``conv_kernel`` taps whose whole
+    # state is the last ``conv_kernel - 1`` inputs of the sequence: no pages
     layer_types: Optional[tuple] = None
     conv_kernel: int = 0
     # a "retention" layer is power retention of this degree over GQA-shaped
     # q/k/v with a gate a kv head (``ops/retention.py``): its whole state is
     # a matrix a kv head, whatever the sequence's length: no pages either
     retention_degree: int = 2
+    # a "deltanet" layer is the gated delta rule (``ops/deltanet.py``):
+    # ``linear_key_heads`` q/k heads of ``linear_key_dim`` serve
+    # ``linear_value_heads`` value heads of ``linear_value_dim``, behind a
+    # causal depthwise convolution of ``conv_kernel`` taps over q|k|v.  Its
+    # state is the conv's tail and a float32 matrix a value head: no pages.
+    # The output gate is ``linear_gate_scale * sigmoid(z)`` on a
+    # zero-centred RMSNorm a head
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_gate_scale: float = 1.0
+    linear_norm_eps: float = 1e-6
     # --- multi-head latent attention (MLA); 0 = plain multi-head ---
     kv_lora_rank: int = 0               # compressed KV width, cached
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0           # shared rope key width, cached
     v_head_dim: int = 0
+    # compressed query: ``q = n(h W_qa) W_qb`` through this width; 0 = the
+    # direct projection (DeepSeek-V2-Lite)
+    q_lora_rank: int = 0
+    # ``o <- o * sigmoid(h W_g)``, a gate a head and value channel from the
+    # layer's input, before the output projection
+    attn_gate: bool = False
     # --- non-architectural serving metadata ---
     name: str = "unnamed"
 
@@ -115,8 +146,8 @@ class ModelConfig:
 
     @property
     def mixers(self) -> tuple:
-        """The token mixer of every layer: ``"attn"``, ``"conv"`` or
-        ``"retention"``."""
+        """The token mixer of every layer: ``"attn"``, ``"conv"``,
+        ``"retention"`` or ``"deltanet"``."""
         return self.layer_types or ("attn",) * self.num_layers
 
     @property
@@ -133,11 +164,29 @@ class ModelConfig:
         return self.mixers.count("retention")
 
     @property
+    def num_deltanet_layers(self) -> int:
+        return self.mixers.count("deltanet")
+
+    @property
+    def deltanet_channels(self) -> int:
+        """Channels of the delta layer's convolution: q | k | v."""
+        return (2 * self.linear_key_heads * self.linear_key_dim
+                + self.linear_value_heads * self.linear_value_dim)
+
+    @property
+    def num_held_experts(self) -> int:
+        """Routed experts whose weights are on this chip."""
+        if self.held_experts is None:
+            return self.num_experts
+        return self.held_experts[1] - self.held_experts[0]
+
+    @property
     def state_mixer(self) -> Optional[str]:
-        """The mixer that keeps a fixed per-sequence state, ``"conv"`` or
-        ``"retention"`` (one kind a model: the state pool has one shape),
-        ``None`` for a model whose memory is pages alone."""
-        kinds = [m for m in ("conv", "retention") if m in self.mixers]
+        """The mixer that keeps a fixed per-sequence state, ``"conv"``,
+        ``"retention"`` or ``"deltanet"`` (one kind a model: the state pool
+        has one shape), ``None`` for a model whose memory is pages alone."""
+        kinds = [m for m in ("conv", "retention", "deltanet")
+                 if m in self.mixers]
         if len(kinds) > 1:
             raise ValueError(
                 f"{self.name}: layers of {kinds} in one model: the state "
@@ -157,7 +206,9 @@ class ModelConfig:
         inputs, oldest first, in the model's dtype.  A retention layer: the
         matrix ``S [kv heads, D_held, head_dim]`` and the normaliser ``Z
         [kv heads, head_dim, head_dim]``, float32 (running sums over the
-        whole context)."""
+        whole context).  A delta-rule layer: the conv's tail, the last
+        ``conv_kernel - 1`` rows of its q|k|v channels in the model's dtype,
+        and the matrix ``S [value heads, key dim, value dim]`` float32."""
         kind = self.state_mixer
         if kind == "conv":
             return (((self.conv_kernel - 1, self.hidden_size), self.dtype),)
@@ -171,6 +222,11 @@ class ModelConfig:
             d, kvh = self.head_dim, self.num_kv_heads
             return (((kvh, held_rows(d), d), "float32"),
                     ((kvh, d, d), "float32"))
+        if kind == "deltanet":
+            return (((self.conv_kernel - 1, self.deltanet_channels),
+                     self.dtype),
+                    ((self.linear_value_heads, self.linear_key_dim,
+                      self.linear_value_dim), "float32"))
         return ()
 
     @property
@@ -215,7 +271,8 @@ class ModelConfig:
             return "layers" if (moe or not self.num_experts) else (
                 "dense_layers")
 
-        groups, seen, i = [], {"attn": 0, "conv": 0, "retention": 0}, 0
+        groups, seen, i = [], {"attn": 0, "conv": 0, "retention": 0,
+                               "deltanet": 0}, 0
         while i < len(flat):
             # the period starting here that repeats over the most runs
             p, reps = 1, 1
@@ -280,21 +337,26 @@ class ModelConfig:
         if rs and mrope is None:
             rope_scaling = tuple(sorted(rs.items()))
         family = {"num_experts": hf.get("num_local_experts", 0)}
-        if model_type == "deepseek_v2":
-            if hf.get("q_lora_rank"):
-                raise ValueError(
-                    "deepseek_v2 with a compressed query (q_lora_rank "
-                    f"{hf['q_lora_rank']}) is not supported: only the "
-                    "direct query projection of DeepSeek-V2-Lite is"
-                )
+        if model_type in ("deepseek_v2", "gigachat3_5"):
             if (hf.get("topk_method", "greedy") != "greedy"
-                    or hf.get("scoring_func", "softmax") != "softmax"
+                    or (hf.get("n_group") or 1) > 1
                     or hf.get("moe_layer_freq", 1) != 1):
                 raise ValueError(
-                    "deepseek_v2: only the greedy softmax router with an "
-                    "expert layer at every layer after the dense ones is "
-                    "supported"
+                    f"{model_type}: only the greedy router over all the "
+                    "experts at once (no grouped top-k: topk_method greedy, "
+                    "n_group 1) with an expert layer at every layer after "
+                    "the dense ones is supported"
                 )
+            # softmax scores kept as they are, or sigmoid scores selected
+            # on score + a learned bias (DeepSeek-V3's; what a config of
+            # this form with no scoring_func key means in gigachat3_5)
+            scoring = hf.get("scoring_func", {
+                "deepseek_v2": "softmax", "gigachat3_5": "sigmoid",
+            }[model_type])
+            if scoring not in ("softmax", "sigmoid"):
+                raise ValueError(
+                    f"{model_type}: scoring_func {scoring!r} is not "
+                    "supported: softmax or sigmoid")
             family = dict(
                 num_experts=hf["n_routed_experts"],
                 moe_intermediate_size=hf["moe_intermediate_size"],
@@ -303,12 +365,55 @@ class ModelConfig:
                 moe_renormalize=bool(hf.get("norm_topk_prob", False)),
                 routed_scaling_factor=float(
                     hf.get("routed_scaling_factor", 1.0)),
+                moe_scoring=scoring,
+                moe_expert_bias=scoring == "sigmoid",
                 expert_capacity_factor=0.0,
                 kv_lora_rank=hf["kv_lora_rank"],
+                q_lora_rank=hf.get("q_lora_rank") or 0,
                 qk_nope_head_dim=hf["qk_nope_head_dim"],
                 qk_rope_head_dim=hf["qk_rope_head_dim"],
                 v_head_dim=hf["v_head_dim"],
             )
+        if model_type == "gigachat3_5":
+            # the hybrid: latent attention at ``full_attention_layers``, the
+            # gated delta rule everywhere else; sandwich norms whose gain
+            # is stored as an offset from 1; a clamped SwiGLU; a gate on the
+            # attention's output.  The multi-token-prediction modules are
+            # not layers of the served stack
+            if hf.get("layernorm_type", "pre_post") != "pre_post":
+                raise ValueError(
+                    "gigachat3_5: only layernorm_type pre_post is supported")
+            full = set(hf["full_attention_layers"])
+            family.update(
+                layer_types=tuple(
+                    "attn" if i in full else "deltanet"
+                    for i in range(hf["num_hidden_layers"])),
+                conv_kernel=hf["linear_conv_kernel_dim"],
+                linear_key_heads=hf["linear_num_key_heads"],
+                linear_value_heads=hf["linear_num_value_heads"],
+                linear_key_dim=hf["linear_key_head_dim"],
+                linear_value_dim=hf["linear_value_head_dim"],
+                linear_gate_scale=float(
+                    hf.get("linear_sigmoid_gate_scale", 1.0)),
+                linear_norm_eps=float(
+                    hf.get("linear_attn_o_norm_eps", 1e-6)),
+                attn_gate=bool(hf.get("gated_attention", False)),
+                post_norms=True,
+                swiglu_limit=float(hf.get("swiglu_limit") or 0.0),
+                norm_offset=1.0,
+            )
+            if hf.get("held_experts"):
+                # one expert-parallel rank: ``n_routed_experts`` is what is
+                # loaded, ``published_n_routed_experts`` what the router
+                # scores
+                lo, hi = hf["held_experts"]
+                if hi - lo != hf["n_routed_experts"]:
+                    raise ValueError(
+                        f"gigachat3_5: held_experts {[lo, hi]} are not the "
+                        f"{hf['n_routed_experts']} of n_routed_experts")
+                family.update(
+                    held_experts=(lo, hi),
+                    num_experts=hf["published_n_routed_experts"])
         if model_type == "brumby":
             # every layer is power retention over the Qwen3 block's q/k/v
             # (per-head q/k norms, rope); the config carries no key of the
@@ -535,8 +640,65 @@ BRUMBY_14B = ModelConfig(
     name="manifestai/Brumby-14B-Base",
 )
 
+# GigaChat3.5-432B-A28B (https://huggingface.co/ai-sage/GigaChat3.5-432B-A28B/
+# blob/main/config.json): 30 gated delta-rule layers (32 key / 64 value
+# heads of 128 behind a 4-tap convolution; a float32 matrix a value head and
+# a conv tail in the state pool) beside 10 latent-attention layers with a
+# compressed, gated query (a latent page pool beside the state pool), sandwich
+# norms stored zero-centred, a clamped SwiGLU, three dense layers then 256
+# routed experts top-8 + 1 shared behind a sigmoid router with a selection
+# bias.  One chip holds a cut of it as ONE expert-parallel rank
+# (``held_experts``, set by the profile); what would move or share the state
+# is refused at engine start (engine.py's table).
+GIGACHAT35_432B = ModelConfig(
+    vocab_size=128256,
+    hidden_size=7168,
+    num_layers=40,
+    num_heads=64,
+    num_kv_heads=64,
+    head_dim=192,
+    intermediate_size=18432,
+    rope_theta=100000.0,
+    rope_scaling=tuple(sorted({
+        "beta_fast": 32, "beta_slow": 1, "factor": 8, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 32768,
+        "type": "yarn",
+    }.items())),
+    rms_norm_eps=1e-6,
+    norm_offset=1.0,
+    post_norms=True,
+    swiglu_limit=10.0,
+    max_position_embeddings=262144,
+    num_experts=256,
+    num_experts_per_tok=8,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=2048,
+    num_shared_experts=1,
+    first_k_dense=3,
+    moe_renormalize=True,
+    routed_scaling_factor=2.5,
+    moe_scoring="sigmoid",
+    moe_expert_bias=True,
+    kv_lora_rank=512,
+    q_lora_rank=1536,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    attn_gate=True,
+    layer_types=tuple(
+        "attn" if i % 4 == 3 else "deltanet" for i in range(40)),
+    conv_kernel=4,
+    linear_key_heads=32,
+    linear_value_heads=64,
+    linear_key_dim=128,
+    linear_value_dim=128,
+    linear_gate_scale=2.0,
+    linear_norm_eps=1e-6,
+    name="ai-sage/GigaChat3.5-432B-A28B",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
-              DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B)
+              DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B)
 }

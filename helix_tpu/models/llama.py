@@ -7,8 +7,8 @@ TPU-first code:
 
 - Layers are **stacked** (every weight has a leading layer dim) in RUNS of
   one kind (``ModelConfig.layer_runs``: a token mixer, attention, gated
-  short convolution or power retention, times an FFN, dense or routed
-  experts), one stack in
+  short convolution, power retention or the gated delta rule, times an FFN,
+  dense or routed experts), one stack in
   the parameter tree and one ``lax.scan`` a run: a dense decoder is one
   run, DeepSeek-V2 two (``dense_layers`` then ``layers``), LFM2 thirteen,
   of which those that repeat back to back are one GROUP (a stack a run of
@@ -17,7 +17,9 @@ TPU-first code:
 - A conv layer's look-back (``conv_fn``) is injected as attention is: the
   engine reads a row's taps from its flat neighbours or its slot's state.
   So is a retention layer's (``retention_fn``): the engine runs a row's
-  fresh tokens against its slot's matrix state and advances it.
+  fresh tokens against its slot's matrix state and advances it.  So is a
+  delta-rule layer's (``deltanet_fn``): its convolution's tail and its
+  matrix state, both a slot's.
 - Three routers, by ``ModelConfig`` (``models/moe.py::route``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
@@ -164,11 +166,46 @@ def init_params(
                     zlib.crc32("/".join(path).encode()) & 0x7FFFFFFF)
             return weight(k, (n,) + shape, *path, std=std)
 
-        lp = {
-            "attn_norm": {"weight": jnp.ones((n, E), dtype)},
-            "mlp_norm": {"weight": jnp.ones((n, E), dtype)},
-        }
-        if mixer == "conv":
+        def drawn(tag, name):
+            """The key of a tensor that is drawn here, not by ``weight``."""
+            return jax.random.fold_in(key, tag + zlib.crc32(
+                "/".join(at + (name,)).encode()) % 1000)
+
+        # a norm's gain is stored as an offset from ``cfg.norm_offset``
+        unit = 1.0 - cfg.norm_offset
+        norm = lambda width: {"weight": jnp.full((n, width), unit, dtype)}
+        lp = {"attn_norm": norm(E), "mlp_norm": norm(E)}
+        if cfg.post_norms:
+            lp["attn_post_norm"] = norm(E)
+            lp["mlp_post_norm"] = norm(E)
+        if mixer == "deltanet":
+            nv, dv = cfg.linear_value_heads, cfg.linear_value_dim
+            C = cfg.deltanet_channels
+            lp["in_qkv"] = w((E, C), "in_qkv")
+            lp["in_z"] = w((E, nv * dv), "in_z")
+            lp["in_a"] = w((E, nv), "in_a")
+            lp["in_b"] = w((E, nv), "in_b")
+            # taps at 0.5, as the gated short convolution's: the branch is
+            # of the size of its input
+            lp["conv"] = {"taps": (jax.random.normal(
+                drawn(2000, "conv"), (n, C, cfg.conv_kernel), jnp.float32)
+                * 0.5).astype(dtype)}
+            # ``g = -exp(A_log) * softplus(a + dt_bias)``: the rate ``A``
+            # uniform in [0.5, 4] and the step uniform in log over [0.001,
+            # 0.1] (its bias the inverse softplus): decays of 0.7 to 0.9995
+            # a token, heads that forget in three tokens beside heads that
+            # remember two thousand.  At the modeling file's ones and
+            # uniform(0, 16) every head forgets in one, and no parity check
+            # would see a state carried wrongly
+            ka, kd = jax.random.split(drawn(5000, "A_log"))
+            lp["A_log"] = {"bias": jnp.log(jax.random.uniform(
+                ka, (n, nv), jnp.float32, 0.5, 4.0))}
+            dt = jnp.exp(jax.random.uniform(
+                kd, (n, nv), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+            lp["dt_bias"] = {"bias": dt + jnp.log(-jnp.expm1(-dt))}
+            lp["o_norm"] = norm(dv)
+            lp["out_proj"] = w((nv * dv, E), "out_proj")
+        elif mixer == "conv":
             # a three-tap depthwise filter at std 0.02 passes a signal
             # thirty times smaller than the residual stream: the taps are
             # drawn at 0.5, so a conv layer's branch is of the size of an
@@ -182,9 +219,16 @@ def init_params(
         elif cfg.is_mla:
             R, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
-            lp["wq"] = w((E, H * (dn + dr)), "wq")
+            if cfg.q_lora_rank:
+                lp["wq_a"] = w((E, cfg.q_lora_rank), "wq_a")
+                lp["q_a_norm"] = norm(cfg.q_lora_rank)
+                lp["wq_b"] = w((cfg.q_lora_rank, H * (dn + dr)), "wq_b")
+            else:
+                lp["wq"] = w((E, H * (dn + dr)), "wq")
+            if cfg.attn_gate:
+                lp["attn_gate"] = w((E, H * dv), "attn_gate")
             lp["wkv_a"] = w((E, R + dr), "wkv_a")
-            lp["kv_norm"] = {"weight": jnp.ones((n, R), dtype)}
+            lp["kv_norm"] = norm(R)
             lp["wkv_b"] = w((R, H * (dn + dv)), "wkv_b")
             lp["wo"] = w((H * dv, E), "wo")
         else:
@@ -207,6 +251,9 @@ def init_params(
             # (models/moe.py); Mixtral's experts are as wide as the FFN
             X, Fx = cfg.num_experts, cfg.expert_width
             lp["router"] = w((E, X), "router")
+            # the router scores every expert; the weights here are those
+            # of the experts this chip holds
+            X_all, X = X, cfg.num_held_experts
             if cfg.moe_expert_bias:
                 # nonzero: at 0.03 it changes the top-4 of about half the
                 # tokens and leaves every expert in play (at 0.1 it kept 3
@@ -214,7 +261,7 @@ def init_params(
                 lp["expert_bias"] = {"bias": jax.random.normal(
                     jax.random.fold_in(key, 3000 + zlib.crc32(
                         "/".join(at).encode()) % 1000),
-                    (n, X), jnp.float32) * 0.03}
+                    (n, X_all), jnp.float32) * 0.03}
             lp["experts"] = {
                 "w_gate": w((X, E, Fx), "experts", "w_gate"),
                 "w_up": w((X, E, Fx), "experts", "w_up"),
@@ -258,7 +305,8 @@ def init_params(
         "embed": weight(ks[0], (V, E), "embed",
                         std=1.0 if dropless_moe else 0.02),
         "final_norm": {"weight": jnp.full(
-            (E,), E ** -0.5 if dropless_moe and tied else 1.0, dtype)},
+            (E,), (E ** -0.5 if dropless_moe and tied else 1.0)
+            - cfg.norm_offset, dtype)},
     }
     # a run of one kind is a stack of its own: two kinds of layer cannot
     # share one scan over stacked weights
@@ -296,13 +344,32 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             # (a mesh is refused for it: the state pool is one device's)
             lax_["g_proj"] = {"weight": ("layers", "embed", None)}
             lax_["g_bias"] = {"bias": ("layers", None)}
-        if mixer == "conv":
+        if cfg.post_norms:
+            lax_["attn_post_norm"] = {"weight": ("layers", None)}
+            lax_["mlp_post_norm"] = {"weight": ("layers", None)}
+        if mixer == "deltanet":
+            # (a mesh is refused for it: the state pool is one device's)
+            for nm in ("in_qkv", "in_z", "in_a", "in_b"):
+                lax_[nm] = {"weight": ("layers", "embed", None)}
+            lax_["conv"] = {"taps": ("layers", None, None)}
+            lax_["A_log"] = {"bias": ("layers", None)}
+            lax_["dt_bias"] = {"bias": ("layers", None)}
+            lax_["o_norm"] = {"weight": ("layers", None)}
+            lax_["out_proj"] = {"weight": ("layers", None, "embed")}
+        elif mixer == "conv":
             # the gated convolution is depthwise over the hidden axis: its
             # projections are replicated (a mesh is refused for it)
             lax_["in_proj"] = {"weight": ("layers", "embed", None)}
             lax_["conv"] = {"taps": ("layers", None, None)}
             lax_["out_proj"] = {"weight": ("layers", None, "embed")}
         elif cfg.is_mla:
+            if cfg.q_lora_rank:
+                del lax_["wq"]
+                lax_["wq_a"] = {"weight": ("layers", "embed", None)}
+                lax_["q_a_norm"] = {"weight": ("layers", None)}
+                lax_["wq_b"] = {"weight": ("layers", None, "heads")}
+            if cfg.attn_gate:
+                lax_["attn_gate"] = {"weight": ("layers", "embed", "heads")}
             # the latent projection is shared by every head: replicated
             lax_["wkv_a"] = {"weight": ("layers", "embed", None)}
             lax_["kv_norm"] = {"weight": ("layers", None)}
@@ -349,15 +416,19 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
     return axes
 
 
-def _swiglu(x, p, act, adapter_ids=None, scoped=False):
-    """``(act(x W_g) * (x W_u)) W_d`` over one dict of three weights."""
+def _swiglu(x, p, act, adapter_ids=None, scoped=False, limit: float = 0.0):
+    """``(act(x W_g) * (x W_u)) W_d`` over one dict of three weights
+    (``limit``: the clamped form, ``ops.grouped_matmul.glu``)."""
+    from helix_tpu.ops.grouped_matmul import glu
+
     scope = jax.named_scope if scoped else (
         lambda _: contextlib.nullcontext())
     with scope("mlp.gate_up"):
         gate = _dense(x, p["w_gate"], adapter_ids)
         up = _dense(x, p["w_up"], adapter_ids)
     with scope("mlp.down"):
-        return _dense(act(gate) * up, p["w_down"], adapter_ids)
+        return _dense(glu(gate, up, act, limit).astype(x.dtype),
+                      p["w_down"], adapter_ids)
 
 
 def mla_softmax_scale(cfg: ModelConfig) -> float:
@@ -380,7 +451,8 @@ def mla_absorbed_weights(wkv_b: dict, cfg: ModelConfig, dtype):
     return w[..., :dn], w[..., dn:]
 
 
-def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
+def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
+                   post=None):
     """Multi-head latent attention (DeepSeek-V2) in the ABSORBED form, at
     every shape: each head's no-rope query is carried into the latent
     space (``q W_UK^T``), all heads attend ONE cached vector a token (the
@@ -394,7 +466,14 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
 
     and returns the attended latent ``[B, S, H, R]``.  Rope pairs are
     rotated as published, ``(2i, 2i+1)``, and kept de-interleaved
-    (``ops.rope.apply_rope_interleaved``)."""
+    (``ops.rope.apply_rope_interleaved``).
+
+    A COMPRESSED query (``q_lora_rank``): ``q = n(x W_qa) W_qb``, two
+    products and a norm where the direct form has one product.  A GATE
+    (``attn_gate``): the heads' outputs times ``sigmoid(x W_g)``, a gate a
+    head and value channel from the layer's input, before ``W_o``.
+    ``post``: what the branch passes through before it joins the residual
+    stream (a sandwich norm)."""
     from helix_tpu.ops.rope import apply_rope_interleaved, yarn_attention_scales
 
     B, S, E = h.shape
@@ -404,8 +483,17 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
     w_uk, w_uv = mla_absorbed_weights(p["wkv_b"], cfg, h.dtype)
     x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
                  cfg.norm_offset)
+    q = None
+    if "wq_a" in p:
+        with jax.named_scope("attn.q_a"):
+            c_q = rms_norm(
+                _dense(x, p["wq_a"]).astype(h.dtype),
+                p["q_a_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
+        with jax.named_scope("attn.q_b"):
+            q = _dense(c_q, p["wq_b"])
     with jax.named_scope("attn.q_proj"):
-        q = _dense(x, p["wq"]).astype(h.dtype).reshape(B, S, H, dn + dr)
+        q = _dense(x, p["wq"]) if q is None else q
+        q = q.astype(h.dtype).reshape(B, S, H, dn + dr)
         q_pe = apply_rope_interleaved(q[..., dn:], positions, inv_freq, rot)
         q_abs = jnp.einsum(
             "bshd,rhd->bshr", q[..., :dn], w_uk,
@@ -417,7 +505,8 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
         ).astype(h.dtype)
     with jax.named_scope("attn.kv_latent"):
         ckv = _dense(x, p["wkv_a"]).astype(h.dtype)
-        c = rms_norm(ckv[..., :R], p["kv_norm"]["weight"], cfg.rms_norm_eps)
+        c = rms_norm(ckv[..., :R], p["kv_norm"]["weight"], cfg.rms_norm_eps,
+                     cfg.norm_offset)
         k_pe = apply_rope_interleaved(ckv[..., R:], positions, inv_freq, rot)
     with jax.named_scope("attn.kernel"):
         res = attn_fn(q_lat, c, k_pe, layer_cache, positions)
@@ -430,8 +519,14 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn):
         a = jnp.einsum(
             "bshr,rhd->bshd", o_lat, w_uv,
             preferred_element_type=jnp.float32,
-        ).astype(h.dtype)
-        h = h + _dense(a.reshape(B, S, H * dv), p["wo"])
+        ).astype(h.dtype).reshape(B, S, H * dv)
+    if "attn_gate" in p:
+        with jax.named_scope("attn.gate"):
+            a = (a.astype(jnp.float32) * jax.nn.sigmoid(
+                _dense(x, p["attn_gate"]))).astype(h.dtype)
+    with jax.named_scope("attn.out"):
+        branch = _dense(a, p["wo"])
+        h = h + (post(branch) if post else branch).astype(h.dtype)
     return h, (c, k_pe), new_cache
 
 
@@ -525,6 +620,69 @@ def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
     return h, new_cache
 
 
+def whole_sequence_deltanet_fn(x, g, beta, taps, layer_cache, cfg):
+    """The delta-rule layer of a forward pass with no cache: every row of
+    the batch is one sequence from its start, so the convolution looks back
+    into zeros and the rule runs from a zero state."""
+    from helix_tpu.ops.deltanet import delta_sequence
+
+    with jax.named_scope("deltanet.conv"):
+        q, k, v = deltanet_heads(
+            whole_sequence_conv_fn(x, taps, None)[0], cfg)
+    with jax.named_scope("deltanet.mix"):
+        S0 = jnp.zeros(v.shape[2:3] + (q.shape[-1], v.shape[-1]), jnp.float32)
+        return jax.vmap(
+            lambda *a: delta_sequence(*a, S0)[0])(q, k, v, g, beta), None
+
+
+def deltanet_heads(y, cfg):
+    """The convolution's output ``y [..., channels]`` (float32) through its
+    SiLU, as the rule's ``q, k, v`` (``ops.deltanet.split_heads``)."""
+    from helix_tpu.ops.deltanet import split_heads
+
+    return split_heads(
+        jax.nn.silu(y), cfg.linear_key_heads, cfg.linear_value_heads,
+        cfg.linear_key_dim, cfg.linear_value_dim)
+
+
+def _deltanet_mixer(h, p, layer_cache, cfg, deltanet_fn, post=None):
+    """The gated delta rule (``ops/deltanet.py``): ``q | k | v = silu(conv(x
+    W_qkv))`` through a causal depthwise convolution, a write strength
+    ``beta = sigmoid(x W_b)`` and a log decay ``g = -exp(A_log) * softplus(x
+    W_a + dt_bias)`` a value head, the rule, then ``n_h(o) * (scale *
+    sigmoid(x W_z))`` with ``n_h`` an RMSNorm over a head's channels, and
+    ``W_o``.  ``deltanet_fn(x W_qkv, g, beta, taps, layer_cache) -> (o [B, S,
+    heads, dv] float32, new_cache)`` owns the look-back: the convolution's
+    tail and the matrix state a sequence carries between calls."""
+    from helix_tpu.ops.quant import maybe_dequant_dense
+
+    B, S, E = h.shape
+    nv, dv = cfg.linear_value_heads, cfg.linear_value_dim
+    with jax.named_scope("deltanet.in_proj"):
+        x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                     cfg.norm_offset)
+        qkv = _dense(x, p["in_qkv"]).astype(h.dtype)
+        z = _dense(x, p["in_z"])
+        # the decay's logit stays float32: a context of thousands of tokens
+        # multiplies thousands of decays
+        f32 = dict(compute_dtype=jnp.float32)
+        beta = jax.nn.sigmoid(maybe_dequant_dense(x, p["in_b"], **f32))
+        g = -jnp.exp(p["A_log"]["bias"].astype(jnp.float32)) * (
+            jax.nn.softplus(maybe_dequant_dense(x, p["in_a"], **f32)
+                            + p["dt_bias"]["bias"].astype(jnp.float32)))
+    o, new_cache = deltanet_fn(
+        qkv, g, beta, p["conv"]["taps"], layer_cache)
+    with jax.named_scope("deltanet.out_proj"):
+        y = rms_norm(o, p["o_norm"]["weight"], cfg.linear_norm_eps,
+                     cfg.norm_offset)
+        y = y * (cfg.linear_gate_scale * jax.nn.sigmoid(
+            z.astype(jnp.float32).reshape(B, S, nv, dv)))
+        branch = _dense(y.astype(h.dtype).reshape(B, S, nv * dv),
+                        p["out_proj"])
+        h = h + (post(branch) if post else branch).astype(h.dtype)
+    return h, new_cache
+
+
 def _layer(
     h,
     layer_params: Params,
@@ -540,6 +698,7 @@ def _layer(
     conv_fn=None,
     retention_fn=None,
     moe_decode_rows: int = 0,
+    deltanet_fn=None,
 ):
     """One decoder block. h: [B, S, E].
 
@@ -556,6 +715,13 @@ def _layer(
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
 
+    def post_norm(name):
+        """The sandwich norm on a branch's output, if the block has one."""
+        if name not in p:
+            return None
+        return lambda y: rms_norm(
+            y, p[name]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
+
     # --- attention ---
     # the named scopes are what a profiler trace calls these operations,
     # whatever number the compiler gives their fusions
@@ -569,9 +735,17 @@ def _layer(
         h, new_cache = _retention_mixer(
             h, p, layer_cache, cfg, positions, inv_freq,
             retention_fn or whole_sequence_retention_fn)
+    elif "in_qkv" in p:
+        k = v = None
+        h, new_cache = _deltanet_mixer(
+            h, p, layer_cache, cfg,
+            deltanet_fn or functools.partial(
+                whole_sequence_deltanet_fn, cfg=cfg),
+            post_norm("attn_post_norm"))
     elif cfg.is_mla:
         h, (k, v), new_cache = _mla_attention(
-            h, p, layer_cache, cfg, positions, inv_freq, attn_fn)
+            h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
+            post_norm("attn_post_norm"))
     else:
         with jax.named_scope("attn.qkv"):
             x = rms_norm(
@@ -623,12 +797,18 @@ def _layer(
         )
         if "shared" in p:
             with jax.named_scope("moe.shared"):
-                moe_out = moe_out + _swiglu(x, p["shared"], act)
-        h = h + moe_out
+                moe_out = moe_out + _swiglu(
+                    x, p["shared"], act, limit=cfg.swiglu_limit)
+        ffn = moe_out
     else:
-        h = h + _swiglu(x, p, act, adapter_ids, scoped=True)
+        ffn = _swiglu(x, p, act, adapter_ids, scoped=True,
+                      limit=cfg.swiglu_limit)
+    post = post_norm("mlp_post_norm")
+    h = h + (post(ffn) if post else ffn).astype(h.dtype)
     if moe_stats is None:
-        moe_stats = jnp.zeros((5,), jnp.float32)
+        from helix_tpu.models.moe import STATS
+
+        moe_stats = jnp.zeros((STATS,), jnp.float32)
     return h, (k, v), new_cache, moe_stats
 
 
@@ -723,6 +903,8 @@ def forward(
     moe_decode_rows: int = 0,  # the last n tokens of the axis are decode
                           # rows riding a prefill's pass: capacity dispatch
                           # leaves them dropless (``models/moe.py``)
+    deltanet_fn=None,     # a delta-rule layer's convolution and rule over
+                          # its sequence (``_deltanet_mixer``); None: the same
 ):
     """Run the decoder.
 
@@ -772,6 +954,7 @@ def forward(
                     whole, rep * run.count + i),
                 moe_backend=moe_backend, conv_fn=conv_fn,
                 retention_fn=retention_fn, moe_decode_rows=moe_decode_rows,
+                deltanet_fn=deltanet_fn,
             )
 
         # the cache's layer index counts the layers of the run's mixer:
@@ -846,12 +1029,14 @@ def forward(
             "dropped": jnp.sum(stats[:, 0]).astype(jnp.int32),
             # [dropped, routed, busiest expert over the mean (max over
             # layers), distinct experts touched and the grouped product's
-            # tile fill (means over MoE layers)]
+            # tile fill (means over MoE layers), assignments to experts
+            # held elsewhere]
             "vector": jnp.stack([
                 jnp.sum(stats[:, 0]), jnp.sum(stats[:, 1]),
                 jnp.max(stats[:, 2]),
                 jnp.sum(stats[:, 3]) / max(cfg.num_moe_layers, 1),
                 jnp.sum(stats[:, 4]) / max(cfg.num_moe_layers, 1),
+                jnp.sum(stats[:, 5]),
             ]),
         }
     return out, kv

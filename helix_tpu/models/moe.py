@@ -28,6 +28,13 @@
   processes at most ``C = factor * T * k / X`` tokens a call; overflow is
   dropped from that expert and counted; decode (S == 1) runs with C = T.
 
+- **Held experts** (``ModelConfig.held_experts = (lo, hi)``): the chip is
+  one expert-parallel rank.  The router scores all ``num_experts`` at the
+  published top-k; the dropless dispatch sorts, visits and combines the
+  assignments to experts ``[lo, hi)`` alone, and ``moe_ffn`` returns the
+  part of the layer's sum they give.  No code stands in for the other
+  ranks or their exchange.
+
 ``moe_ffn`` returns the routed experts' sum only; a shared expert is a
 dense MLP the layer adds beside it (``models/llama.py``).
 """
@@ -42,6 +49,7 @@ import jax.numpy as jnp
 from helix_tpu.ops.attention import resolve_backend
 from helix_tpu.ops.grouped_matmul import (
     check_grouped_geometry,
+    glu,
     grouped_matmul_tpu,
     row_tile,
     visit_plan,
@@ -88,21 +96,32 @@ def route(xf, router_p, cfg, expert_bias=None):
     return top_w * cfg.routed_scaling_factor, top_idx
 
 
-def expert_load_stats(top_idx, valid, X, dropped=0, tile_fill=0.0):
+STATS = 6
+
+
+def expert_load_stats(top_idx, valid, X, dropped=0, tile_fill=0.0,
+                      held=None):
     """``[dropped, routed, busiest expert's tokens over the mean, distinct
-    experts touched, tile fill]`` of one layer's routing, f32.
+    experts touched, tile fill, away]`` of one layer's routing, f32.
     ``tile_fill`` is the dropless path's (``_grouped_experts``); the
-    capacity path walks no row tiles and reports 0."""
+    capacity path walks no row tiles and reports 0.  With ``held = (lo,
+    hi)`` the load is that of the experts held here (``routed`` counts the
+    assignments to them) and ``away`` the assignments to experts elsewhere,
+    which this chip does not compute."""
     load = jnp.sum(
         jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
         * valid[:, None, None].astype(jnp.float32), axis=(0, 1)
     )                                               # [X]
+    away = jnp.float32(0)
+    if held is not None:
+        away = jnp.sum(load) - jnp.sum(load[held[0]:held[1]])
+        load = load[held[0]:held[1]]
     routed = jnp.sum(load)
-    ratio = jnp.max(load) * X / jnp.maximum(routed, 1.0)
+    ratio = jnp.max(load) * load.shape[0] / jnp.maximum(routed, 1.0)
     return jnp.stack([
         jnp.asarray(dropped, jnp.float32), routed, ratio,
         jnp.sum((load > 0).astype(jnp.float32)),
-        jnp.asarray(tile_fill, jnp.float32),
+        jnp.asarray(tile_fill, jnp.float32), away,
     ])
 
 
@@ -122,11 +141,18 @@ def grouped_backend(widths, backend: Optional[str] = None) -> str:
 
 
 def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
-                     layer=None, backend=None, interpret=False):
+                     layer=None, backend=None, interpret=False, held=None,
+                     limit: float = 0.0):
     """Dropless: sort the assignments by expert, the grouped products,
     unsort, weighted sum over each token's k choices.  Also returns the
     products' tile fill: rows routed over rows walked, ``routed / (visits
     * tm)``, of the kernel's visit plan (whichever product ran).
+
+    ``held = (lo, hi)``: ``experts_p`` holds experts ``[lo, hi)`` of the
+    ``X`` the router scored (one expert-parallel rank's).  An assignment to
+    an expert elsewhere is an invalid token's: out of the sort, the visit
+    plan and the combine.  What comes back is the part of the layer's sum
+    these experts give.
 
     ``layer`` (a traced index): ``experts_p`` is then a whole STACK of
     layers' experts (``[n, X, ...]`` leaves) of which this layer's are
@@ -136,7 +162,17 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
     with jax.named_scope("moe.dispatch"):
         # invalid tokens go to a sentinel past the last expert: they sort
         # to the end, belong to no group and are multiplied by nothing
-        flat_e = jnp.where(valid[:, None], top_idx, X).reshape(-1)  # [T*k]
+        here = valid[:, None]
+        if held is not None:
+            lo, hi = held
+            here = here & (top_idx >= lo) & (top_idx < hi)
+            # the rows an expert gets on average stay what they were; the
+            # groups are the held experts alone
+            rows_mean = T * k * (hi - lo) // X
+            top_idx, X = top_idx - lo, hi - lo
+        else:
+            rows_mean = T * k
+        flat_e = jnp.where(here, top_idx, X).reshape(-1)            # [T*k]
         order = jnp.argsort(flat_e, stable=True)
         sorted_e = flat_e[order]
         group_sizes = jnp.sum(
@@ -153,7 +189,7 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
     kernel = grouped_backend(
         [experts_p[n]["weight"].shape[-2:] for n in names], backend
     ) == "pallas"
-    tm = row_tile(T * k, X)
+    tm = row_tile(rows_mean, X)
     plan = visit_plan(group_sizes, T * k, tm)
     visits = plan[-1][0]
     fill = jnp.sum(group_sizes) / jnp.maximum(visits * tm, 1)
@@ -161,33 +197,35 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
     with jax.named_scope("moe.experts"):
         if kernel:
             y = experts_pallas(
-                xs, plan, tm, experts_p, layer, act, interpret)
+                xs, plan, tm, experts_p, layer, act, interpret, limit)
         else:
             y = experts_xla(
                 xs, group_sizes, jnp.minimum(sorted_e, X - 1), experts_p,
-                layer, act)
+                layer, act, limit)
     with jax.named_scope("moe.combine"):
         # rows past the last group hold whatever the product left there
         y = jnp.where((sorted_e < X)[:, None], y, 0.0)
         y = y[jnp.argsort(order)].reshape(T, k, E)
-        w = top_w * valid[:, None].astype(top_w.dtype)
+        w = top_w * here.astype(top_w.dtype)
         return jnp.einsum("tk,tke->te", w, y), fill
 
 
-def experts_pallas(xs, plan, tm, experts_p, layer, act, interpret):
+def experts_pallas(xs, plan, tm, experts_p, layer, act, interpret,
+                   limit: float = 0.0):
     """The three products through ``ops/grouped_matmul.py``: gate and up
     in one call, down in a second, one visit plan for both."""
     kw = dict(tm=tm, interpret=interpret)
     gate, up, down = (experts_p[n] for n in ("w_gate", "w_up", "w_down"))
     h = grouped_matmul_tpu(
         xs, gate["weight"], plan, layer, scale=gate.get("scale"),
-        w2=up["weight"], scale2=up.get("scale"), act=act,
+        w2=up["weight"], scale2=up.get("scale"), act=act, limit=limit,
         out_dtype=xs.dtype, **kw)
     return grouped_matmul_tpu(
         h, down["weight"], plan, layer, scale=down.get("scale"), **kw)
 
 
-def experts_xla(xs, group_sizes, e_row, experts_p, layer, act):
+def experts_xla(xs, group_sizes, e_row, experts_p, layer, act,
+                limit: float = 0.0):
     """The same three products as ``lax.ragged_dot`` over this layer's
     slice of the stack: the oracle, and what a CPU runs."""
     def grouped(h, wp):
@@ -209,7 +247,8 @@ def experts_xla(xs, group_sizes, e_row, experts_p, layer, act):
 
     gate = grouped(xs, experts_p["w_gate"])
     up = grouped(xs, experts_p["w_up"])
-    return grouped((act(gate) * up).astype(xs.dtype), experts_p["w_down"])
+    return grouped(glu(gate, up, act, limit).astype(xs.dtype),
+                   experts_p["w_down"])
 
 
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
@@ -248,7 +287,12 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     with jax.named_scope("moe.router"):
         top_w, top_idx = route(xf, router_p, cfg, expert_bias)
     fill = 0.0
+    held = cfg.held_experts
     if cfg.expert_capacity_factor > 0:
+        if held is not None or cfg.swiglu_limit:
+            raise ValueError(
+                "held experts and a clamped SwiGLU are the dropless "
+                "dispatch's: expert_capacity_factor must be 0")
         out, dropped = _capacity_experts(
             xf, top_w, top_idx, valid, experts_p, cfg, act, S, decode_rows
         )
@@ -259,11 +303,12 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             layer = None
         out, fill = _grouped_experts(
             xf, top_w, top_idx, valid, experts_p, X, act, layer, backend,
-            interpret)
+            interpret, held, cfg.swiglu_limit)
         dropped = jnp.int32(0)
     out = out.reshape(B, S, E).astype(x.dtype)
     if return_stats:
-        return out, expert_load_stats(top_idx, valid, X, dropped, fill)
+        return out, expert_load_stats(top_idx, valid, X, dropped, fill,
+                                      held)
     if return_dropped:
         return out, dropped
     return out

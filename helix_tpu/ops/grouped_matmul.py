@@ -60,6 +60,15 @@ def check_grouped_geometry(K: int, N: int):
         )
 
 
+def glu(gate, up, act, limit: float = 0.0):
+    """``act(gate) * up``; with ``limit`` the clamped form ``act(min(gate,
+    limit)) * clip(up, -limit, limit)``."""
+    if limit:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return act(gate) * up
+
+
 def row_tile(rows: int, groups: int) -> int:
     """The row tile for ``rows`` sorted rows over ``groups`` groups: the
     smallest of ``ROW_TILES`` that holds eight times a group's mean rows.
@@ -104,7 +113,7 @@ def _slab(K: int) -> int:
 
 
 def _kernel(layer_ref, offs_ref, group_ref, tile_ref, count_ref, x_ref,
-            *refs, tm: int, n_w: int, scaled: bool, act):
+            *refs, tm: int, n_w: int, scaled: bool, act, limit: float):
     del layer_ref   # read by the index maps
     w_refs = refs[:n_w]
     s_refs = refs[n_w:2 * n_w] if scaled else (None,) * n_w
@@ -136,7 +145,7 @@ def _kernel(layer_ref, offs_ref, group_ref, tile_ref, count_ref, x_ref,
 
         y = product(w_refs[0], s_refs[0])
         if n_w == 2:
-            y = act(y) * product(w_refs[1], s_refs[1])
+            y = glu(y, product(w_refs[1], s_refs[1]), act, limit)
         g = group_ref[v]
         row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, 1), 0)
@@ -145,7 +154,8 @@ def _kernel(layer_ref, offs_ref, group_ref, tile_ref, count_ref, x_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tm", "act", "out_dtype", "interpret"))
+    jax.jit,
+    static_argnames=("tm", "act", "limit", "out_dtype", "interpret"))
 def grouped_matmul_tpu(
     x,              # [rows, K] sorted by group; rows past the last group
                     # belong to no group
@@ -155,8 +165,9 @@ def grouped_matmul_tpu(
     *,
     scale=None,     # [n, X, 1, N] f32 per-output-channel scales of w
     w2=None,        # a second weight like w: the call computes
-    scale2=None,    # act(x @ w) * (x @ w2)
+    scale2=None,    # glu(x @ w, x @ w2, act, limit)
     act: Optional[Callable] = None,
+    limit: float = 0.0,
     tm: int,
     out_dtype=jnp.float32,
     interpret: bool = False,
@@ -201,7 +212,8 @@ def grouped_matmul_tpu(
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel, tm=tm, n_w=len(ws), scaled=bool(ss), act=act),
+            _kernel, tm=tm, n_w=len(ws), scaled=bool(ss), act=act,
+            limit=limit),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((padded, N), out_dtype),
         interpret=interpret,

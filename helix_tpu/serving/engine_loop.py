@@ -1612,6 +1612,13 @@ class EngineLoop:
             # layers whose state is a fixed tensor a slot (the state
             # pool), and layers with pages
             "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),
+            "deltanet_layers": getattr(
+                eng.model_cfg, "num_deltanet_layers", 0),
+            # experts of the routed set whose weights are on this chip (0:
+            # dense, or every expert is here)
+            "held_experts": (
+                getattr(eng.model_cfg, "num_held_experts", 0)
+                if getattr(eng.model_cfg, "held_experts", None) else 0),
             "attn_layers": getattr(
                 eng.model_cfg, "num_attn_layers",
                 getattr(eng.model_cfg, "num_layers", 0)),

@@ -437,18 +437,33 @@ class OpenAIServer:
                     "helix_recurrent_state_bytes",
                     getattr(eng, "recurrent_state_bytes", 0), lbl,
                 )
-            if getattr(eng.model_cfg, "num_retention_layers", 0):
-                # a matrix state a slot and no page of KV: the pool's
-                # bytes, the rows of it the steps advanced (one token at a
-                # time, or a chunk of a prompt), and the bytes they moved
+            if getattr(eng.model_cfg, "held_experts", None):
+                # one expert-parallel rank: assignments to the experts held
+                # here (computed) and to experts elsewhere (not); their
+                # ratio is how near this rank's share is to its 1 / ranks
+                c.counter(
+                    "helix_moe_held_tokens_total",
+                    getattr(eng, "moe_routed_tokens", 0), lbl,
+                )
+                c.counter(
+                    "helix_moe_away_tokens_total",
+                    getattr(eng, "moe_away_tokens", 0), lbl,
+                )
+            for mixer, rows_series in (
+                    ("retention", "helix_retention_rows_total"),
+                    ("deltanet", "helix_deltanet_rows_total")):
+                if not getattr(eng.model_cfg, f"num_{mixer}_layers", 0):
+                    continue
+                # a matrix state a slot: the pool's bytes, the rows of it
+                # the steps advanced (one token at a time, or a chunk of a
+                # prompt), and the bytes they moved
                 c.gauge(
                     "helix_recurrent_state_bytes",
                     getattr(eng, "recurrent_state_bytes", 0), lbl,
                 )
                 for kind, n in sorted(getattr(
-                        eng, "num_retention_rows", {}).items()):
-                    c.counter("helix_retention_rows_total", n,
-                              {**lbl, "kind": kind})
+                        eng, f"num_{mixer}_rows", {}).items()):
+                    c.counter(rows_series, n, {**lbl, "kind": kind})
                 c.counter(
                     "helix_state_bytes_touched_total",
                     getattr(eng, "state_bytes_touched", 0), lbl,

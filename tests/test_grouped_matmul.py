@@ -226,7 +226,7 @@ def test_tile_fill_ratio_against_a_count_by_hand():
     mask = jnp.asarray([[True] * 3 + [False] * 61])
     _, stats = moe_ffn(x, router_w, None, cfg, jax.nn.silu, token_mask=mask,
                        return_stats=True, stacked_experts=(stack, 0))
-    dropped, routed, _, touched, fill = np.asarray(stats)
+    dropped, routed, _, touched, fill, _ = np.asarray(stats)
     assert (dropped, routed, touched) == (0, 9, 3)
     # rows 0-2, 3-5, 6-8 all lie in the first tile: three visits to it
     assert fill == pytest.approx(9 / (3 * 128))

@@ -432,7 +432,8 @@ def test_dropless_when_every_token_picks_the_same_experts():
                              return_stats=True)
         want = _loop_over_experts(x[0], router_w, experts, cfg)
     assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < 1e-5
-    dropped, routed, ratio, touched, fill = np.asarray(stats)
+    dropped, routed, ratio, touched, fill, away = np.asarray(stats)
+    assert away == 0                          # every expert is here
     assert (dropped, routed, touched) == (0, 64 * 3, 3)
     assert fill == 0.5      # three groups of 64 rows: three visits of 128
     assert ratio == pytest.approx(8 / 3)      # busiest over the mean load
@@ -483,11 +484,75 @@ def test_catalog_entry_is_the_published_config():
             c.vocab_size, c.num_layers) == (64, 1408, 6, 2, 1, 10944,
                                             102400, 27)
     assert not c.moe_renormalize and c.expert_capacity_factor == 0
-    assert c.num_moe_layers == 26 and c.is_mla
-    with pytest.raises(ValueError, match="q_lora_rank"):
-        ModelConfig.from_hf_config({
-            "model_type": "deepseek_v2", "q_lora_rank": 1536,
-            "hidden_size": 8, "num_attention_heads": 2})
+    assert c.num_moe_layers == 26 and c.is_mla and c.q_lora_rank == 0
+
+
+HF_V2 = {
+    "model_type": "deepseek_v2", "vocab_size": 300, "hidden_size": 64,
+    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "intermediate_size": 96, "moe_intermediate_size": 32,
+    "n_routed_experts": 8, "n_shared_experts": 2, "num_experts_per_tok": 3,
+    "first_k_dense_replace": 1, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000.0, "rope_scaling": dict(YARN),
+}
+
+
+def test_a_compressed_query_loads_and_is_the_unabsorbed_form():
+    """``q_lora_rank`` in a DeepSeek-form config: ``q = n(x W_qa) W_qb``.  The
+    layer's absorbed attention against explicit per-head K and V built from
+    the latent, one layer, float32: another order of the same sums, 1e-5."""
+    from helix_tpu.models import llama
+    from helix_tpu.ops.norms import rms_norm
+
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(
+        dict(HF_V2, q_lora_rank=24), name="tiny-q-lora"), dtype="float32")
+    assert cfg.q_lora_rank == 24 and cfg.moe_scoring == "softmax"
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    assert lp["wq_a"]["weight"].shape == (64, 24) and "wq" not in lp
+    assert lp["wq_b"]["weight"].shape == (24, 4 * 24)
+    S, H, R, dn, dr, dv = 20, 4, 32, 16, 8, 16
+    h = jax.random.normal(jax.random.PRNGKey(1), (1, S, 64))
+    pos = jnp.arange(S)[None]
+    inv = jnp.asarray(rope_ops.rope_frequencies(
+        dr, cfg.rope_theta, cfg.rope_scaling))
+    with jax.default_matmul_precision("highest"):
+        got, _, _ = llama._mla_attention(
+            h, lp, None, cfg, pos, inv, prefill_attn_fn)
+        x = rms_norm(h[0], lp["attn_norm"]["weight"], 1e-6)
+        c_q = rms_norm(x @ lp["wq_a"]["weight"], lp["q_a_norm"]["weight"],
+                       1e-6)
+        q = (c_q @ lp["wq_b"]["weight"]).reshape(S, H, dn + dr)
+        ck = x @ lp["wkv_a"]["weight"]
+        c = rms_norm(ck[:, :R], lp["kv_norm"]["weight"], 1e-6)
+        kv = (c @ lp["wkv_b"]["weight"]).reshape(S, H, dn + dv)
+        inv_ref = jnp.asarray(reference.yarn_inv_freq(dr, 10000.0, YARN))
+        q_pe = reference.rope_pairs(q[..., dn:], pos[0], inv_ref, 1.0)
+        k_pe = reference.rope_pairs(ck[:, R:], pos[0], inv_ref, 1.0)
+        s = (jnp.einsum("qhd,khd->hqk", q[..., :dn], kv[..., :dn])
+             + jnp.einsum("qhd,kd->hqk", q_pe, k_pe)) * mla_softmax_scale(cfg)
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -jnp.inf)
+        a = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), kv[..., dn:])
+        want = h[0] + a.reshape(S, H * dv) @ lp["wo"]["weight"]
+    assert float(jnp.abs(got[0] - want).max()) < 1e-5
+    assert float(jnp.abs(want - h[0]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(topk_method="group_limited_greedy", n_group=8), "grouped top-k"),
+    (dict(n_group=4, topk_group=2), "grouped top-k"),
+    (dict(topk_method="noaux_tc"), "grouped top-k"),
+    (dict(moe_layer_freq=2), "every layer after"),
+    (dict(scoring_func="tanh"), "scoring_func")])
+def test_routers_that_are_not_served_are_still_refused(bad, match):
+    with pytest.raises(ValueError, match=match):
+        ModelConfig.from_hf_config(dict(HF_V2, **bad))
+
+
+def test_sigmoid_scores_in_a_deepseek_form_config_load():
+    cfg = ModelConfig.from_hf_config(dict(HF_V2, scoring_func="sigmoid"))
+    assert cfg.moe_scoring == "sigmoid" and cfg.moe_expert_bias
 
 
 @pytest.mark.parametrize("seed", [0, 1])

@@ -551,3 +551,135 @@ def test_retention_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+
+
+def test_deltanet_decode_kernel_compiles_at_the_published_geometry(one_chip):
+    """GigaChat3.5's decode kernel: 64 rows, 64 value heads, a state of 128 x
+    128 float32 a head in a pool of seven layers (1.88 GB), donated: aliased
+    to its output, no copy."""
+    from helix_tpu.ops.deltanet_kernel import deltanet_decode_tpu
+
+    B, H, d, L = 64, 64, 128, 7
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    compiled = jax.jit(deltanet_decode_tpu, donate_argnums=(5,)).lower(
+        S((B, H, d)), S((B, H, d)), S((B, H, d)), S((B, H)), S((B, H)),
+        S((L, B, H, d, d)), S((), jnp.int32), S((B,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert "deltanet_decode_tpu" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = L * B * H * d * d * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+@pytest.mark.parametrize("rows", [512, 4608], ids=["decode", "chunk"])
+def test_grouped_product_compiles_at_7168_by_4096(one_chip, rows):
+    """16 held experts of 7168 x 2048, int8, the layer picked from a stack of
+    eight: gate and up in one call (a group's two whole ``[7168, 2048]``
+    blocks: 29 MB a buffer, 59 MB double-buffered, under the kernel's own
+    VMEM limit with the slabs' converts), the clamp inside it, then down; at
+    the row tile the rows an expert gets on average give (64 x 8 and 576 x 8
+    assignments of which a sixteenth are held)."""
+    from helix_tpu.ops.grouped_matmul import (
+        grouped_matmul_tpu, row_tile, visit_plan)
+
+    n, X, E, F = 8, 16, 7168, 2048
+    tm = row_tile(rows * X // 256, X)
+    assert tm == (32 if rows == 512 else 128)
+
+    def S(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    def op(x, wg, sg, wu, su, wd, sd, sizes, layer):
+        plan = visit_plan(sizes, rows, tm)
+        h = grouped_matmul_tpu(
+            x, wg, plan, layer, scale=sg, w2=wu, scale2=su,
+            act=jax.nn.silu, limit=10.0, tm=tm, out_dtype=x.dtype)
+        return grouped_matmul_tpu(h, wd, plan, layer, scale=sd, tm=tm)
+
+    up = (S((n, X, E, F), jnp.int8), S((n, X, 1, F), jnp.float32))
+    down = (S((n, X, F, E), jnp.int8), S((n, X, 1, E), jnp.float32))
+    compiled = jax.jit(op).lower(
+        S((rows, E), jnp.bfloat16), *up, *up, *down, S((X,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert compiled.as_text().count("grouped_matmul_tpu") >= 2
+    # no slice of the stack is copied out for the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < E * F
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_deltanet_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of GigaChat3.5 cut to three layers (delta + dense,
+    latent + held experts, delta + held experts; int8 weights, 64 slots) for
+    the described chip: the delta decode kernel over the state pool in the
+    carry, the chunked form a row at a time, the latent kernel at 64 heads
+    over a latent pool of ONE layer, the grouped product over 16 of 256
+    experts, and both state arrays updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import GIGACHAT35_432B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        GIGACHAT35_432B, num_layers=3, first_k_dense=1, held_experts=(0, 16),
+        layer_types=("deltanet", "attn", "deltanet"))
+    B, max_pages, pages = 64, 160, 2048
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == (1, 16, 512) and vs == (1, 16, 128)
+    assert cc.state_shapes(cfg) == (
+        ((2, B, 3, 16384), "bfloat16"), ((2, B, 64, 128, 128), "float32"))
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("deltanet_decode_tpu", "grouped_matmul_tpu",
+                   "mla_ragged_paged_attention"):
+        assert kernel in text, kernel
+    # the state pool is updated in place: aliased whole, and no temporary
+    # of half its size
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
