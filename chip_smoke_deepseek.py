@@ -147,9 +147,9 @@ def kernel_mla(seed, rehearse, rng, ks, H=16):
     R, dr, P, L = 512, 64, 16, 2
     N, maxP, B, S = (64, 8, 4, 32) if rehearse else (2048, 160, 64, 512)
     dt = jnp.float32 if rehearse else jnp.bfloat16
-    c_pages = jax.random.normal(ks[0], (L, N, P, R), jnp.float32).astype(dt)
-    r_pages = jnp.pad(
-        jax.random.normal(ks[1], (L, N, P, dr), jnp.float32).astype(dt),
+    # the pool's one array: a token's latent, its rope key, zeros to 128
+    kv_pages = jnp.pad(
+        jax.random.normal(ks[0], (L, N, P, R + dr), jnp.float32).astype(dt),
         ((0, 0),) * 3 + ((0, 128 - dr),))
     ok = True
     cases = _attention_cases(rng, B, S, maxP, P, N)
@@ -157,7 +157,7 @@ def kernel_mla(seed, rehearse, rng, ks, H=16):
         q = (jax.random.normal(ks[2], (T, H, R + dr)) * 0.1).astype(dt)
         c_new = jax.random.normal(ks[3], (T, R)).astype(dt)
         r_new = jax.random.normal(ks[4], (T, dr)).astype(dt)
-        args = (q, c_new, r_new, c_pages, r_pages, jnp.int32(1),
+        args = (q, c_new, r_new, kv_pages, jnp.int32(1),
                 *(jnp.asarray(x, jnp.int32)
                   for x in (t0, q_len, hist, tables)))
         if rehearse:

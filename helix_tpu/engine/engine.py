@@ -652,7 +652,7 @@ def _pin_default_layout(cache):
 
     return PagedKVCache(
         k_pages=pin(cache.k_pages),
-        v_pages=pin(cache.v_pages),
+        v_pages=None if cache.v_pages is None else pin(cache.v_pages),
         k_scale=None if cache.k_scale is None else pin(cache.k_scale),
         v_scale=None if cache.v_scale is None else pin(cache.v_scale),
         state=cache.state,
@@ -672,14 +672,15 @@ def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
     vs = caches[3] if len(caches) == 4 else None
     Bq, Sq, H, D = q.shape
     if k.ndim == 3:
-        # latent attention: k is the latent, v the rope key, no head axis;
+        # latent attention: k is the latent, v the rope key, no head axis,
+        # and the pool one array of their joined rows (``vp`` is None);
         # the layer has scaled q already.  A row holds at most Sq fresh
         # tokens (a decode slot's width, or the whole prefill bucket).
         out = mla_ragged_paged_attention(
             q.reshape(Bq * Sq, H, D),
             k.reshape(Bq * Sq, k.shape[-1]),
             v.reshape(Bq * Sq, v.shape[-1]),
-            kp, vp, lyr, t0, q_len, hist, tables,
+            kp, lyr, t0, q_len, hist, tables,
             backend=backend, max_q_len=Sq,
         )
         return out.reshape(Bq, Sq, H, out.shape[-1])
@@ -1719,6 +1720,8 @@ class Engine:
         self.num_retention_rows = {"decode": 0, "chunk": 0}
         self.num_deltanet_rows = {"decode": 0, "chunk": 0}
         self.state_bytes_touched = 0
+        # history pages the latent kernel walked (``_mla_page_fetches``)
+        self.num_mla_page_fetches = 0
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -2060,6 +2063,31 @@ class Engine:
         rows["chunk"] += chunk
         self.state_bytes_touched += 2 * (dec + chunk) * (
             self.recurrent_state_bytes // self.cfg.max_decode_batch)
+
+    def _mla_page_fetches(self, plan, rung, draft_len, n_extra) -> int:
+        """History pages the latent kernel walks in this launch, from the
+        host's mirrors: over the live rows, the pages of a row's history
+        (``ceil(hist / page)``: one DMA each) times the row's query blocks
+        (each block walks the whole history again), times the latent
+        layers.  A prefill row is ``ceil(rem / block)`` blocks over its
+        ``start`` tokens; a live state row is one one-token block (latent
+        attention is refused with speculation) over its position, a page
+        longer every ``page`` steps of the fused tail.  Kernel time over
+        this count is the cost of a page fetched (PERF.md section 5)."""
+        from helix_tpu.ops.paged_kernel import query_block
+
+        P = self.cache_cfg.page_size
+        pages = 0
+        if plan is not None and plan.rows:
+            bq = query_block(rung)
+            pages += sum(-(-r.start // P) * -(-r.rem // bq)
+                         for r in plan.rows)
+        live = (np.asarray(draft_len) >= 0) & (
+            np.asarray(self._active_sent) > 0)
+        pos = self._positions[live].astype(np.int64)
+        for k in range(1 + int(n_extra)):
+            pages += int((-(-(pos + k) // P)).sum())
+        return pages * self.model_cfg.num_attn_layers
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -4420,19 +4448,18 @@ class Engine:
             "page_size": self.cache_cfg.page_size,
             "num_layers": self.model_cfg.num_layers,
             # a latent pool has no head axis: "kv_heads" 0 tells it from
-            # a K/V pool, "head_dim" is then the latent width
+            # a K/V pool, "head_dim" is then the width of its one array's
+            # rows (latent + lane-padded rope key)
             "kv_heads": self._snapshot_geometry()[0],
             "head_dim": self._snapshot_geometry()[1],
             "kv_dtype": self.cache_cfg.dtype,
         }
 
     def _snapshot_geometry(self) -> tuple:
-        """``(kv_heads, head_dim)`` as a snapshot states them: ``(0,
-        kv_lora_rank)`` for a latent pool, which no K/V pool can match."""
-        m = self.model_cfg
-        if m.is_mla:
-            return 0, m.kv_lora_rank
-        return m.num_kv_heads, m.head_dim
+        """``(kv_heads, head_dim)`` as a snapshot states them
+        (``CacheConfig.geometry``: a latent pool's says its one array's
+        row width, so a snapshot of another layout is refused by name)."""
+        return self.cache_cfg.geometry(self.model_cfg)
 
     def export_request(self, req_id: str) -> Optional[RequestSnapshot]:
         """Build a portable snapshot of one live request (engine thread).
@@ -4674,7 +4701,9 @@ class Engine:
                 f: arrays.get(f)
                 for f in ("k", "v", "k_scale", "v_scale")
             }
-            if entry["k"] is None or entry["v"] is None:
+            # a latent pool's page is its one array: "v" travels as None
+            if entry["k"] is None or (
+                    (entry["v"] is None) != (self.cache.v_pages is None)):
                 raise SnapshotError(
                     "page missing k/v buffers", code="snapshot_corrupt"
                 )
@@ -5199,6 +5228,11 @@ class Engine:
         if self.model_cfg.state_mixer in ("retention", "deltanet"):
             self._note_state_rows(
                 plan if rows else None, draft_len, n_extra)
+        page_fetches = None
+        if self.model_cfg.is_mla:
+            page_fetches = self._mla_page_fetches(
+                plan, rung, draft_len, n_extra)
+            self.num_mla_page_fetches += page_fetches
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
         joint_pass = int(rows > 0)
@@ -5228,6 +5262,8 @@ class Engine:
                if self.model_cfg.num_deltanet_layers else {}),
             **({"held_experts": self.model_cfg.num_held_experts}
                if self.model_cfg.held_experts else {}),
+            **({"mla_page_fetches": page_fetches}
+               if page_fetches is not None else {}),
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

@@ -52,6 +52,17 @@ arrays: its convolution's tail ``[layers, slots, K - 1, channels]`` in the
 model's dtype and the float32 matrix ``S [layers, slots, value heads, dk,
 dv]``; its model's attention layers are latent, so the state pool stands
 beside a LATENT page pool whose layer axis counts those layers alone.
+
+A LATENT (MLA) page pool is ONE array, ``k_pages [L, N, P, R + 128]``: a
+token caches one row a layer, its normed latent in lanes ``0..R`` and its
+rope key behind it, zero-padded to whole 128-lane tiles
+(``CacheConfig.latent_widths``).  There is no second array (``v_pages`` is
+``None``: the values are the latent lanes of the same row), so a ``(layer,
+page)`` slice is one contiguous block that the latent kernel fetches with
+ONE DMA (two arrays cost it two starts and two waits a page, and issuing
+them paced it: PERF.md section 6, PR 40).  Every path that moves pages as
+opaque buffers (host pool, snapshots, checksums, filestore, prefix cache)
+carries ``"v": None`` for such a page.
 """
 
 from __future__ import annotations
@@ -112,23 +123,34 @@ class CacheConfig:
 
     @staticmethod
     def latent_widths(model: ModelConfig) -> tuple:
-        """Lane widths of a latent (MLA) pool's two arrays: the latent as
-        it is, the rope key padded to whole 128-lane tiles (what the pool
-        allocates and the kernel DMAs)."""
+        """Lane widths of the two parts of a latent (MLA) pool's ROW: the
+        latent as it is (lanes ``0..R``), then the rope key padded with
+        zeros to whole 128-lane tiles.  Their sum is the minor axis of the
+        pool's one array (what is allocated and what the kernel DMAs)."""
         return model.kv_lora_rank, -(-model.qk_rope_head_dim // 128) * 128
 
     def page_shapes(self, model: ModelConfig) -> tuple:
-        """Shapes of ONE page, all layers, in the pool's two arrays (what
-        ``gather_pages`` hands out and a snapshot carries): K and V
-        ``[L, P, KVH, D]``, or for latent attention the latent ``[L, P,
-        R]`` and the lane-padded rope key ``[L, P, 128]``."""
+        """Shapes of ONE page, all layers, in each of the pool's arrays
+        (what ``gather_pages`` hands out and a snapshot carries): K and V
+        ``[L, P, KVH, D]``, or for latent attention the ONE array's ``[L,
+        P, R + 128]`` (``latent_widths``)."""
         L, P = model.num_attn_layers, self.page_size
         if model.is_mla:
-            kw, vw = self.latent_widths(model)
-            return (L, P, kw), (L, P, vw)
+            return ((L, P, sum(self.latent_widths(model))),)
         pack = model.kv_head_pack
         kv = (L, P, model.num_kv_heads // pack, model.head_dim * pack)
         return kv, kv
+
+    def geometry(self, model: ModelConfig) -> tuple:
+        """``(kv_heads, head_dim)`` as a snapshot and the filestore's
+        namespace state a pool: ``(0, R + 128)`` for a latent pool, the
+        width of a row of its one array, which no K/V pool can match; nor
+        can what the two-array layout this pool had before PR 40 stated,
+        ``(0, R)``: its pages are refused by that field's name instead of
+        being misread."""
+        if model.is_mla:
+            return 0, sum(self.latent_widths(model))
+        return model.num_kv_heads, model.head_dim
 
     def page_bytes(self, model: ModelConfig) -> int:
         if model.is_mla:
@@ -202,8 +224,11 @@ class PagedKVCache:
     needed).
     """
 
-    k_pages: jax.Array  # [L, N, P, KVH, D]; latent pools: c [L, N, P, R]
-    v_pages: jax.Array  # same shape; latent pools: rope key [L, N, P, 128]
+    # [L, N, P, KVH, D]; a latent pool: [L, N, P, R + 128], a token's
+    # latent in lanes 0..R and its lane-padded rope key behind it
+    k_pages: jax.Array
+    # K's shape; None for a latent pool, which has no second array
+    v_pages: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
     # the state pool: ``[conv layers, slots, K - 1, E]`` in the model's
@@ -288,9 +313,9 @@ class PagedKVCache:
 
     @classmethod
     def _create_latent(cls, model, cache, mesh) -> "PagedKVCache":
-        """A latent pool: the two arrays keep their roles in every opaque
-        pair path (host pool, snapshots, checksums, filestore), with a
-        width each and no head axis."""
+        """A latent pool: one array with no head axis; where a K/V pool
+        has its second, ``None`` (every opaque page path carries it as
+        such: host pool, snapshots, checksums, filestore)."""
         return cls._create_on_one_device(
             model, cache, mesh, "a latent (MLA) page pool")
 
@@ -307,8 +332,9 @@ class PagedKVCache:
     @classmethod
     def _create_on_one_device(cls, model, cache, mesh, what,
                               state=None) -> "PagedKVCache":
-        """The two pools at ``CacheConfig.page_shapes``, bf16 or f32, on
-        one device: what the pools without an int8 or a sharded form are."""
+        """The pool's arrays at ``CacheConfig.page_shapes`` (two, or a
+        latent pool's one), bf16 or f32, on one device: what the pools
+        without an int8 or a sharded form are."""
         if cache.quantized:
             raise ValueError(
                 f"{what} has no int8 storage: set kv_cache_dtype to auto, "
@@ -324,7 +350,8 @@ class PagedKVCache:
             jnp.zeros((shp[0], cache.num_pages) + shp[1:], dtype)
             for shp in cache.page_shapes(model)
         ]
-        return cls(k_pages=pools[0], v_pages=pools[1], state=state)
+        return cls(k_pages=pools[0],
+                   v_pages=pools[1] if len(pools) > 1 else None, state=state)
 
     @property
     def latent(self) -> bool:
@@ -339,12 +366,10 @@ class PagedKVCache:
     def quantized(self) -> bool:
         return self.k_scale is not None
 
-    def layer_view(self, layer: int):
-        return self.k_pages[layer], self.v_pages[layer]
-
     def carry(self):
         """The pytree threaded through decode scans / prefill xs: pools
-        plus scale pools when quantized (leaves all carry a leading L)."""
+        plus scale pools when quantized (leaves all carry a leading L; a
+        latent pool's second entry is ``None``, no leaf)."""
         if self.k_scale is None:
             return (self.k_pages, self.v_pages)
         return (self.k_pages, self.v_pages, self.k_scale, self.v_scale)
@@ -440,34 +465,30 @@ def write_kv(
 
 
 def _write_latent(cache, c_new, r_new, pages, offsets, valid):
-    """``write_kv`` for a latent pool: ``c_new [L, B, S, R]``, ``r_new [L,
-    B, S, dr]`` (zero-padded to the pool's lane width).  One ROW scatter
-    over the pool viewed ``[L * N * ps, W]``, each (layer, token) its own
-    row index: a pool with no head axis has only the lane axis minor, and
-    a scatter that kept the layer axis as a window made layout assignment
-    move it next to the lanes and copy the whole pool (3.75 GB of
-    temporaries on a 16 GB chip, compiled for the described chip)."""
-    ps = cache.k_pages.shape[2]
+    """``write_kv`` for a latent pool: ``c_new [L, B, S, R]`` and ``r_new
+    [L, B, S, dr]`` joined into the pool's rows ``[c | r | zeros]``.  ONE
+    row scatter over the pool viewed ``[L * N * ps, R + 128]``, each
+    (layer, token) its own row index: a pool with no head axis has only
+    the lane axis minor, and a scatter that kept the layer axis as a
+    window made layout assignment move it next to the lanes and copy the
+    whole pool (3.75 GB of temporaries on a 16 GB chip, compiled for the
+    described chip)."""
+    pool = cache.k_pages
+    L, N, ps, W = pool.shape
     tok = jnp.where(valid, pages * ps + offsets, 0).reshape(-1)     # [T]
-
-    def scatter(pool, new):
-        L, N, _, W = pool.shape
-        rows = (jnp.arange(L, dtype=tok.dtype)[:, None] * (N * ps)
-                + tok[None, :]).reshape(-1)                          # [L*T]
-        new = new.reshape(-1, new.shape[-1]).astype(pool.dtype)
-        new = jnp.pad(new, ((0, 0), (0, W - new.shape[-1])))
-        return (
-            pool.reshape(L * N * ps, W)
-            .at[rows]
-            .set(new, mode="drop", unique_indices=False)
-            .reshape(L, N, ps, W)
-        )
-
-    return PagedKVCache(
-        k_pages=scatter(cache.k_pages, c_new),
-        v_pages=scatter(cache.v_pages, r_new),
-        state=cache.state,
+    rows = (jnp.arange(L, dtype=tok.dtype)[:, None] * (N * ps)
+            + tok[None, :]).reshape(-1)                              # [L*T]
+    new = jnp.concatenate(
+        [c_new.reshape(-1, c_new.shape[-1]),
+         r_new.reshape(-1, r_new.shape[-1])], axis=-1).astype(pool.dtype)
+    new = jnp.pad(new, ((0, 0), (0, W - new.shape[-1])))
+    k_pages = (
+        pool.reshape(L * N * ps, W)
+        .at[rows]
+        .set(new, mode="drop", unique_indices=False)
+        .reshape(L, N, ps, W)
     )
+    return PagedKVCache(k_pages=k_pages, v_pages=None, state=cache.state)
 
 
 class PageAllocator:
@@ -1059,12 +1080,13 @@ class HostPagePool:
 def gather_pages(cache: PagedKVCache, page_ids: list) -> list:
     """Slice ``page_ids`` out of the device pool as per-page array dicts
     (``[L, page_size, KVH, D]`` each, scale rows ``[L, page_size, KVH]``
-    when quantized).  One fused gather per field, then cheap per-page
+    when quantized; a latent pool's page is ``"k" [L, page_size, R +
+    128]`` and ``"v"`` None).  One fused gather per field, then cheap per-page
     slices — the result arrays are fresh buffers, safe to hand to
     ``HostPagePool.put`` while later steps donate the pool."""
     idx = jnp.asarray(np.asarray(page_ids, np.int32))
     k = cache.k_pages[:, idx]
-    v = cache.v_pages[:, idx]
+    v = None if cache.v_pages is None else cache.v_pages[:, idx]
     ks = vs = None
     if cache.k_scale is not None:
         from helix_tpu.ops.quant import unpack_scale_pages
@@ -1077,7 +1099,7 @@ def gather_pages(cache: PagedKVCache, page_ids: list) -> list:
         out.append(
             {
                 "k": k[:, i],
-                "v": v[:, i],
+                "v": None if v is None else v[:, i],
                 "k_scale": None if ks is None else ks[:, i],
                 "v_scale": None if vs is None else vs[:, i],
             }
@@ -1095,7 +1117,9 @@ def _build_page_restore_fn(n: int, quantized: bool):
     @functools.partial(jax.jit, donate_argnums=(0,))
     def fn(carry, idx, k_new, v_new, k_sc, v_sc):
         k_pages = carry[0].at[:, idx].set(k_new)
-        v_pages = carry[1].at[:, idx].set(v_new)
+        # a latent pool has no second array
+        v_pages = None if carry[1] is None else carry[1].at[:, idx].set(
+            v_new)
         if not quantized:
             return (k_pages, v_pages)
         return (
@@ -1134,7 +1158,7 @@ def restore_pages(
         return jnp.stack(parts, axis=1)   # [L, bucket, ...]
 
     k_new = stack("k")
-    v_new = stack("v")
+    v_new = None if cache.v_pages is None else stack("v")
     k_sc = v_sc = None
     if quantized:
         from helix_tpu.ops.quant import pack_scale_pages

@@ -5,23 +5,34 @@ rows by ``t0``/``q_len``/``hist``/``tables``; decode, chunk with history,
 mixed, verify and cold rows are metadata), for multi-head latent attention
 in its absorbed form:
 
-- a token caches ONE vector, shared by every head: the normed latent ``c``
-  (``[L, N, P, R]``, R = 512) and the rope key (``[L, N, P, 128]``, the
-  published 64 values lane-padded).  No head axis (a ``(1, R)`` minor pair
-  would be padded 2x in HBM), no V: the values are ``c`` itself;
+- a token caches ONE row, shared by every head, in ONE array ``[L, N, P,
+  R + 128]``: the normed latent ``c`` in lanes ``0..R`` (R = 512) and the
+  rope key behind it (the published 64 values lane-padded to 128 with
+  zeros).  No head axis (a ``(1, R)`` minor pair would be padded 2x in
+  HBM), no V: the values are lanes ``0..R`` of the same row.  A ``(layer,
+  page)`` slice is ``P * (R + 128)`` contiguous values (20 KB at page 16):
+  ONE DMA;
 - the H query heads of a block of ``BQ`` tokens are the rows of ONE
-  ``[BQ * H, R + 128]`` operand: scores are ``q_c . c^T + q_r . r^T`` (two
-  MXU dots against the same chunk), the output ``p . c``;
+  ``[BQ * H, R + 128]`` operand laid out as the pool's rows are (absorbed
+  query | rope query | zeros): scores are one MXU product against the
+  chunk's rows, ``q_c . c^T + q_r . r^T`` in one contraction, the output
+  ``p . c``;
 - MXU operands stay in the pool's dtype (bf16) with f32 accumulation;
   softmax statistics in f32;
 - the grid walks a list of LIVE query blocks built by the wrapper from
   ``q_len`` (scalar-prefetched ``(row, block)`` pairs), not ``rows x
   blocks``: a decode step of 64 one-token rows is 64 programs of ``BQ`` 1
   (16 query rows each), a 512-token chunk 64 programs of ``BQ`` 8;
-- history streams HBM -> VMEM one page per DMA, two DMAs a page (latent,
-  rope key), double-buffered in chunks of ``C`` pages; fresh tokens come
-  from the flat ``c_new``/``r_new`` in blocks of ``KB`` keys, attended raw
-  (persisting them is the caller's ``write_kv``).
+- history streams HBM -> VMEM one DMA a page, double-buffered in chunks of
+  ``C`` pages.  The pages of a chunk signal ONE semaphore a buffer slot
+  and are awaited by count: one wait for each bit of the chunk's live page
+  count (one for a full chunk), whichever pages came.  Issuing is what
+  paces the walk: the scalar core that starts and awaits the copies also
+  drives the vector work, and a page's bytes take less time than the two
+  starts and two waits a two-array pool cost (PERF.md section 6, PR 40);
+- fresh tokens come from the flat ``[c_new | r_new]`` rows (joined and
+  lane-padded by the wrapper) in blocks of ``KB`` keys, one DMA a block,
+  attended raw (persisting them is the caller's ``write_kv``).
 
 Layout contract: as the dense kernel's.  Rows are disjoint and ascending;
 a row's last partial block spills garbage into the following flat
@@ -72,6 +83,8 @@ def check_mla_geometry(num_heads: int, latent: int, rope: int,
         )
 
 
+
+
 def _mla_kernel(
     # scalar prefetch
     brow_ref,    # SMEM [NB] int32 row of each query block (-1 = none)
@@ -81,12 +94,12 @@ def _mla_kernel(
     hist_ref,    # SMEM [R] int32 pages-resident history tokens per row
     pt_ref,      # SMEM [R, maxP] int32 page tables
     layer_ref,   # SMEM [1] int32 layer index
-    # inputs (HBM)
-    qf, cnf, rnf, c_hbm, r_hbm,
+    # inputs (HBM): queries, fresh rows, the pool: all [.., R + 128] wide
+    qf, knf, kv_hbm,
     # output (HBM)
     o_hbm,
     # scratch
-    qbuf, cbuf, rbuf, cnbuf, rnbuf, obuf, sems, fsems, qsem, osem,
+    qbuf, kvbuf, knbuf, obuf, sems, fsem, qsem, osem,
     *,
     scale: float,
     page_size: int,
@@ -111,30 +124,31 @@ def _mla_kernel(
         npages = jax.lax.div(hist_r + P - 1, P)
         nchunks = jax.lax.div(npages + C - 1, C)
 
-        def chunk_copies(ci, slot, c):
-            page = pt_ref[r, ci * C + c]
-            return (
-                pltpu.make_async_copy(
-                    c_hbm.at[lyr, page], cbuf.at[slot, c],
-                    sems.at[slot, c, 0]),
-                pltpu.make_async_copy(
-                    r_hbm.at[lyr, page], rbuf.at[slot, c],
-                    sems.at[slot, c, 1]),
-            )
-
         def start_chunk(ci, slot):
+            # one DMA a live page; a slot's pages all signal its semaphore
+            live = npages - ci * C
             for c in range(C):  # static unroll over a chunk's pages
-                @pl.when(ci * C + c < npages)
+                @pl.when(c < live)
                 def _():
-                    for cp in chunk_copies(ci, slot, c):
-                        cp.start()
+                    pltpu.make_async_copy(
+                        kv_hbm.at[lyr, pt_ref[r, ci * C + c]],
+                        kvbuf.at[slot, c], sems.at[slot],
+                    ).start()
 
         def wait_chunk(ci, slot):
-            for c in range(C):
-                @pl.when(ci * C + c < npages)
+            # by count, whichever pages came: a wait for each bit of the
+            # live page count takes that many pages' bytes off the slot's
+            # semaphore (a full chunk is one wait)
+            live = jnp.minimum(npages - ci * C, C)
+            bit = 1 << (C.bit_length() - 1)
+            while bit:
+                @pl.when((live & bit) != 0)
                 def _():
-                    for cp in chunk_copies(ci, slot, c):
-                        cp.wait()
+                    pltpu.make_async_copy(
+                        kv_hbm.at[lyr, pl.ds(0, bit)],
+                        kvbuf.at[slot, pl.ds(0, bit)], sems.at[slot],
+                    ).wait()
+                bit >>= 1
 
         @pl.when(nchunks > 0)
         def _():
@@ -142,10 +156,10 @@ def _mla_kernel(
 
         qcp.wait()
         H = qbuf.shape[1]
-        R = cbuf.shape[-1]
+        W = kvbuf.shape[-1]
+        R = W - ROPE_LANES
         RQ = BQ * H
-        q2 = qbuf[...].reshape(RQ, qbuf.shape[-1])    # token-major rows
-        q_c, q_r = q2[:, :R], q2[:, R:]
+        q2 = qbuf[...].reshape(RQ, W)                 # token-major rows
         # query offset in the row of each q2 row
         q_off = i * BQ + jax.lax.broadcasted_iota(
             jnp.int32, (RQ, 1), 0) // H
@@ -153,31 +167,27 @@ def _mla_kernel(
         # operands go to the MXU as they are stored (bf16), whatever
         # jax_default_matmul_precision says: Mosaic has no fp32-precision
         # product of bf16 operands
-        def online(carry, s, ok, vals):
-            """One online-softmax step over a block of keys."""
+        def online(carry, rows, ok):
+            """One online-softmax step over a block of cached rows ``[keys,
+            R + 128]``: scores against the whole row (latent | rope key),
+            values its latent lanes."""
             m_prev, l_prev, acc_prev = carry
+            s = jax.lax.dot_general(
+                q2, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT,
+            )
             s = jnp.where(ok, s * scale, DEFAULT_MASK_VALUE)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
             acc = acc_prev * alpha + jax.lax.dot_general(
-                p.astype(vals.dtype), vals, (((1,), (0,)), ((), ())),
+                p.astype(rows.dtype), rows[:, :R], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=jax.lax.Precision.DEFAULT,
             )
             return m_new, l_new, acc
-
-        def scores(c_flat, r_flat):
-            return jax.lax.dot_general(
-                q_c, c_flat, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.DEFAULT,
-            ) + jax.lax.dot_general(
-                q_r, r_flat, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.DEFAULT,
-            )
 
         # ---- history pages: the ragged page walk ----------------------
         def hist_body(ci, carry):
@@ -188,18 +198,16 @@ def _mla_kernel(
                 start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
 
             wait_chunk(ci, slot)
-            c_flat = cbuf[slot].reshape(C * P, R)
-            r_flat = rbuf[slot].reshape(C * P, ROPE_LANES)
             left = hist_r - ci * C * P
             # pages past the row's history were never fetched: the buffer
             # there holds whatever it held.  Their softmax weight is
             # exactly 0, but 0 * NaN poisons the PV product: zero them.
-            c_flat = jnp.where(
+            rows = jnp.where(
                 jax.lax.broadcasted_iota(jnp.int32, (C * P, 1), 0) < left,
-                c_flat, 0)
+                kvbuf[slot].reshape(C * P, W), 0)
             ok = jax.lax.broadcasted_iota(
                 jnp.int32, (1, C * P), 1) < left
-            return online(carry, scores(c_flat, r_flat), ok, c_flat)
+            return online(carry, rows, ok)
 
         carry = (
             jnp.full((RQ, 1), -jnp.inf, jnp.float32),
@@ -209,7 +217,7 @@ def _mla_kernel(
         carry = jax.lax.fori_loop(0, nchunks, hist_body, carry)
 
         # ---- the row's fresh tokens, KB keys a block (causal) ----------
-        # The flat arrays are tiled in rows: a DMA may only start on a tile
+        # The flat array is tiled in rows: a DMA may only start on a tile
         # (16 rows of bf16).  So key blocks start at the tile below the
         # row's start, and what lies before the row is masked like what
         # lies after it.
@@ -219,25 +227,19 @@ def _mla_kernel(
 
         def fresh_body(j, carry):
             src = pl.multiple_of(start + j * KB, align)
-            ccp = pltpu.make_async_copy(
-                cnf.at[pl.ds(src, KB)], cnbuf, fsems.at[0])
-            rcp = pltpu.make_async_copy(
-                rnf.at[pl.ds(src, KB)], rnbuf, fsems.at[1])
-            ccp.start()
-            rcp.start()
-            ccp.wait()
-            rcp.wait()
+            cp = pltpu.make_async_copy(knf.at[pl.ds(src, KB)], knbuf, fsem)
+            cp.start()
+            cp.wait()
             # offset in the row of each key of the block (negative before
             # the row); a block's ends read the neighbouring rows' tokens
             # or the flat padding: masked, and zeroed for the PV product
             k_off = j * KB - shift + jax.lax.broadcasted_iota(
                 jnp.int32, (KB, 1), 0)
-            c_flat = jnp.where((k_off >= 0) & (k_off < qlen_r),
-                               cnbuf[...], 0)
+            rows = jnp.where((k_off >= 0) & (k_off < qlen_r), knbuf[...], 0)
             kv_off = j * KB - shift + jax.lax.broadcasted_iota(
                 jnp.int32, (1, KB), 1)
             ok = (kv_off >= 0) & (kv_off < qlen_r) & (kv_off <= q_off)
-            return online(carry, scores(c_flat, rnbuf[...]), ok, c_flat)
+            return online(carry, rows, ok)
 
         last_q = jnp.minimum(i * BQ + BQ, qlen_r)     # keys 0..last_q-1
         m, l, acc = jax.lax.fori_loop(
@@ -257,8 +259,7 @@ def mla_ragged_paged_attention_tpu(
     q,            # [T, H, R + dr] flat queries: absorbed | rope
     c_new,        # [T, R] fresh latents, attended raw
     r_new,        # [T, dr] fresh rope keys
-    c_pages,      # [L, N, P, R] full latent pool (read-only here)
-    r_pages,      # [L, N, P, 128] full rope-key pool
+    kv_pages,     # [L, N, P, R + 128] full latent pool (read-only here)
     layer, t0, q_len, hist, tables,
     *,
     scale: float = 1.0,
@@ -269,24 +270,28 @@ def mla_ragged_paged_attention_tpu(
     bound on any row's fresh tokens (default: T), picks the query block:
     1 token for plain decode, else 8."""
     T, H, DQ = q.shape
-    L, N, P, R = c_pages.shape
+    L, N, P, W = kv_pages.shape
+    R = W - ROPE_LANES
     dr = r_new.shape[-1]
     n_rows, maxP = tables.shape
+    dt = kv_pages.dtype
     if not interpret:
-        check_mla_geometry(H, R, dr, c_pages.dtype.itemsize)
-    assert DQ == R + dr and r_pages.shape[-1] == ROPE_LANES
+        check_mla_geometry(H, R, dr, dt.itemsize)
+    assert DQ == R + dr and c_new.shape[-1] == R
     max_q_len = T if max_q_len is None else min(max_q_len, T)
     BQ = query_block(max_q_len)
     KB = 16 if BQ == 1 else 128
     C = max(1, min(256 // P, maxP))
     pad = ROPE_LANES - dr
     # the flat axis grows by a key block and a query block, so neither
-    # the last row's fresh-key DMA nor its partial query block leaves it
+    # the last row's fresh-key DMA nor its partial query block leaves it;
+    # queries and fresh rows take the pool's row layout (zeros behind the
+    # rope lanes score nothing)
     Tpad = -(-(T + KB + BQ) // 16) * 16
-    qp = jnp.pad(q, ((0, Tpad - T), (0, 0), (0, pad)))
-    cn = jnp.pad(c_new.astype(c_pages.dtype), ((0, Tpad - T), (0, 0)))
-    rn = jnp.pad(r_new.astype(r_pages.dtype), ((0, Tpad - T), (0, pad)))
-    qp = qp.astype(c_pages.dtype)
+    qp = jnp.pad(q, ((0, Tpad - T), (0, 0), (0, pad))).astype(dt)
+    kn = jnp.pad(
+        jnp.concatenate([c_new.astype(dt), r_new.astype(dt)], axis=-1),
+        ((0, Tpad - T), (0, pad)))
 
     q_len = q_len.astype(jnp.int32)
     brow, bidx = live_query_blocks(q_len, BQ, T)
@@ -296,21 +301,18 @@ def mla_ragged_paged_attention_tpu(
         kb=KB,
     )
     any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
-    dt = c_pages.dtype
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=brow.shape,
-        in_specs=[any_spec] * 5,
+        in_specs=[any_spec] * 3,
         out_specs=any_spec,
         scratch_shapes=[
-            pltpu.VMEM((BQ, H, R + ROPE_LANES), dt),          # qbuf
-            pltpu.VMEM((2, C, P, R), dt),                     # cbuf
-            pltpu.VMEM((2, C, P, ROPE_LANES), r_pages.dtype),  # rbuf
-            pltpu.VMEM((KB, R), dt),                          # cnbuf
-            pltpu.VMEM((KB, ROPE_LANES), r_pages.dtype),      # rnbuf
+            pltpu.VMEM((BQ, H, W), dt),                       # qbuf
+            pltpu.VMEM((2, C, P, W), dt),                     # kvbuf
+            pltpu.VMEM((KB, W), dt),                          # knbuf
             pltpu.VMEM((BQ, H, R), q.dtype),                  # obuf
-            pltpu.SemaphoreType.DMA((2, C, 2)),               # sems
-            pltpu.SemaphoreType.DMA((2,)),                    # fsems
+            pltpu.SemaphoreType.DMA((2,)),                    # sems
+            pltpu.SemaphoreType.DMA(()),                      # fsem
             pltpu.SemaphoreType.DMA(()),                      # qsem
             pltpu.SemaphoreType.DMA(()),                      # osem
         ],
@@ -328,6 +330,6 @@ def mla_ragged_paged_attention_tpu(
         brow, bidx,
         t0.astype(jnp.int32), q_len, hist.astype(jnp.int32),
         tables.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        qp, cn, rn, c_pages, r_pages,
+        qp, kn, kv_pages,
     )
     return out[:T]

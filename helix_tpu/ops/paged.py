@@ -486,20 +486,21 @@ def mla_attention_reference(q, c, k_pe, *, q_positions, kv_positions,
 
 
 def mla_ragged_paged_attention_reference(
-    q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist, tables, *,
+    q, c_new, r_new, kv_pages, layer, t0, q_len, hist, tables, *,
     scale: float = 1.0,
 ):
     """XLA gather oracle of ``mla_ragged_paged_attention``: gathers each
-    row's pages, masks beyond its history, one plain softmax."""
+    row's pages, splits a cached row into its latent and its rope key,
+    masks beyond the row's history, one plain softmax."""
     T = q.shape[0]
     R, maxP = tables.shape
-    P = c_pages.shape[2]
-    dr = r_new.shape[-1]
+    P = kv_pages.shape[2]
+    lat, dr = c_new.shape[-1], r_new.shape[-1]
     Hs = maxP * P
     row, q_off = _row_of_tokens(t0, q_len, T)
     q_pos = jnp.where(row >= 0, hist[jnp.clip(row, 0)] + q_off, 0)
-    ch = c_pages[layer][tables].reshape(R * Hs, -1)
-    rh = r_pages[layer][tables].reshape(R * Hs, -1)[:, :dr]
+    kvh = kv_pages[layer][tables].reshape(R * Hs, -1)
+    ch, rh = kvh[:, :lat], kvh[:, lat:lat + dr]
     hist_tok = jnp.arange(Hs)
     seg_h = jnp.where(
         hist_tok[None, :] < hist[:, None], jnp.arange(R)[:, None] + 1, 0
@@ -522,8 +523,7 @@ def mla_ragged_paged_attention(
     q,            # [T, H, R + dr] absorbed | rope queries, flat over rows
     c_new,        # [T, R] fresh normed latents (attended raw)
     r_new,        # [T, dr] fresh rope keys
-    c_pages,      # [L, N, P, R] latent pool (``PagedKVCache.k_pages``)
-    r_pages,      # [L, N, P, >= dr] rope-key pool, lane-padded (``v_pages``)
+    kv_pages,     # [L, N, P, R + 128] latent pool (``PagedKVCache.k_pages``)
     layer, t0, q_len, hist, tables,
     *,
     scale: float = 1.0,
@@ -533,7 +533,12 @@ def mla_ragged_paged_attention(
     """Ragged paged attention over a LATENT pool, under the row contract of
     ``ragged_paged_attention`` (decode, chunk with history, mixed, verify
     and cold rows are metadata): H query heads against one cached
-    ``[c | k_pe]`` a token, the values a view of the keys (``c``).
+    ``[c | k_pe]`` a token, the values a view of the keys (``c``).  The
+    pool is ONE array: a token's row holds its normed latent in lanes
+    ``0..R`` and its rope key behind it, zero-padded to 128 lanes
+    (``CacheConfig.latent_widths``), so a page of a layer is one contiguous
+    block and one DMA.  The fresh tokens come as the model makes them, the
+    latent and the rope key apart (``write_kv`` joins them into rows).
     Returns the attended latents ``[T, H, R]``.  ``max_q_len`` is a static
     bound on a row's fresh tokens (1 for plain decode), which lets the
     kernel size its query blocks.  The Pallas kernel on a TPU, the gather
@@ -542,10 +547,10 @@ def mla_ragged_paged_attention(
         from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
 
         return mla_ragged_paged_attention_tpu(
-            q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist,
-            tables, scale=scale, max_q_len=max_q_len,
+            q, c_new, r_new, kv_pages, layer, t0, q_len, hist, tables,
+            scale=scale, max_q_len=max_q_len,
         )
     return mla_ragged_paged_attention_reference(
-        q, c_new, r_new, c_pages, r_pages, layer, t0, q_len, hist, tables,
+        q, c_new, r_new, kv_pages, layer, t0, q_len, hist, tables,
         scale=scale,
     )

@@ -220,8 +220,10 @@ class KVFilestore:
                 for f in _PAGE_FIELDS
             }
             claimed = str(doc.get("checksum", ""))
-            if entry["k"] is None or entry["v"] is None:
-                raise ValueError("page missing k/v buffers")
+            # "v" is None in a latent pool's page (one array); the
+            # namespace keeps the two kinds of pool apart
+            if entry["k"] is None:
+                raise ValueError("page missing its k buffer")
             if page_checksum(entry).hex() != claimed:
                 raise ValueError("page checksum mismatch")
         except Exception as e:  # noqa: BLE001 — corrupt blob = typed miss
@@ -362,9 +364,10 @@ def filestore_for_engine(root: str, model_cfg, cache_cfg,
                          quota_bytes: Optional[int] = None) -> KVFilestore:
     """Bind a store to one engine's KV geometry (the namespace that
     makes content addressing safe across mixed fleets)."""
+    # as a snapshot states the pool: a latent pool's pages are one array
     ns = KVFilestore.namespace_for(
         model_cfg.name, cache_cfg.page_size, model_cfg.num_layers,
-        model_cfg.num_kv_heads, model_cfg.head_dim, cache_cfg.dtype,
+        *cache_cfg.geometry(model_cfg), cache_cfg.dtype,
     )
     return KVFilestore(root, ns, quota_bytes=quota_bytes)
 

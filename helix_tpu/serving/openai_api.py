@@ -449,6 +449,15 @@ class OpenAIServer:
                     "helix_moe_away_tokens_total",
                     getattr(eng, "moe_away_tokens", 0), lbl,
                 )
+            if getattr(eng.model_cfg, "is_mla", False):
+                # latent attention: the history pages its kernel walked,
+                # one DMA each (live rows' pages x query blocks x latent
+                # layers, from the host's mirrors); the kernel's time over
+                # this is the cost of a page fetched
+                c.counter(
+                    "helix_mla_page_fetches_total",
+                    getattr(eng, "num_mla_page_fetches", 0), lbl,
+                )
             for mixer, rows_series in (
                     ("retention", "helix_retention_rows_total"),
                     ("deltanet", "helix_deltanet_rows_total")):

@@ -342,12 +342,13 @@ def test_a_state_pool_stands_beside_a_latent_page_pool(model):
     cfg, _ = model
     cc = CacheConfig(num_pages=32, page_size=8, max_pages_per_seq=8,
                      dtype="float32", state_slots=3)
-    ks, vs = cc.page_shapes(cfg)
-    assert ks == (2, 8, 32) and vs == (2, 8, 128)       # two latent layers
+    ks, = cc.page_shapes(cfg)
+    assert ks == (2, 8, 32 + 128)       # two latent layers, one array
     assert cc.state_shapes(cfg) == (((7, 3, 3, 128), "float32"),
                                     ((7, 3, 4, 16, 16), "float32"))
     cache = PagedKVCache.create(cfg, cc)
-    assert cache.latent and cache.k_pages.shape == (2, 32, 8, 32)
+    assert cache.latent and cache.k_pages.shape == (2, 32, 8, 160)
+    assert cache.v_pages is None
     conv, S = cache.state
     assert conv.shape == (7, 3, 3, 128) and S.dtype == jnp.float32
     assert cc.total_bytes(cfg) == 32 * (2 * 8 * 160 * 4) + (

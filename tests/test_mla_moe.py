@@ -293,39 +293,82 @@ def test_absorbed_attention_is_the_decompressed_attention():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
 
 
-ROWS = {  # q_len, hist, t0, T, max_q_len
+ROWS = {  # q_len, hist, t0, T, max_q_len[, pages a row's table holds]
     "decode": ([1, 1, 0, 1], [5, 33, 0, 160], [0, 1, 2, 3], 4, 1),
     "verify": ([3, 3, 0, 3], [5, 33, 0, 100], [0, 3, 6, 9], 12, 3),
     "chunk": ([40], [130], [0], 48, None),
     "cold": ([20, 9, 150], [0, 0, 0], [0, 20, 29], 192, None),
     "mixed": ([37, 1, 1, 1], [70, 9, 0, 191], [0, 37, 38, 39], 64, None),
+    # chunks of 16 pages, awaited by count: the first row fills both buffer
+    # slots (33 pages: 16 + 16 + 1), the rows behind it fetch 6 (bits 4 + 2)
+    # and 11 (8 + 2 + 1) pages of their last chunk, one page, and none, so
+    # what they leave unfetched holds the rows before
+    "decode_by_count": ([1, 1, 1, 1, 1], [520, 350, 171, 3, 0],
+                        [0, 1, 2, 3, 4], 5, 1, 40),
+    "chunk_by_count": ([19, 1], [600, 271], [0, 19], 24, None, 40),
 }
+
+
+def _latent_kernel_case(kind):
+    """``(args, t0, q_len, T, max_q_len)`` of one ROWS case: float32, a pool
+    of ONE array ``[L, N, P, R + 128]`` (latent | rope key | zeros)."""
+    q_len, hist, t0, T, mq, *rest = ROWS[kind]
+    maxP = rest[0] if rest else 12
+    rng = np.random.default_rng(5)
+    L, N, P, R, dr, H = 2, 40, 16, 128, 64, 16
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa
+    kv_pages = jnp.pad(f(L, N, P, R + dr), ((0, 0),) * 3 + ((0, 128 - dr),))
+    tables = jnp.asarray(rng.integers(1, N, (len(q_len), maxP)), jnp.int32)
+    args = (f(T, H, R + dr) * 0.3, f(T, R), f(T, dr), kv_pages,
+            jnp.int32(1), jnp.asarray(t0, jnp.int32),
+            jnp.asarray(q_len, jnp.int32), jnp.asarray(hist, jnp.int32),
+            tables)
+    return args, t0, q_len, T, mq
+
+
+def _rows_of(x, t0, q_len, T):
+    in_row = np.zeros(T, bool)
+    for s, n in zip(t0, q_len):
+        in_row[s:s + n] = True
+    return np.asarray(x)[in_row]
 
 
 @pytest.mark.parametrize("kind", list(ROWS))
 def test_pallas_kernel_in_interpret_mode_against_the_reference(kind):
     from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
 
-    q_len, hist, t0, T, mq = ROWS[kind]
-    rng = np.random.default_rng(5)
-    L, N, P, R, dr, H, maxP = 2, 40, 16, 128, 64, 16, 12
-    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa
-    c_pages = f(L, N, P, R)
-    r_pages = jnp.pad(f(L, N, P, dr), ((0, 0),) * 3 + ((0, 128 - dr),))
-    tables = jnp.asarray(rng.integers(1, N, (len(q_len), maxP)), jnp.int32)
-    args = (f(T, H, R + dr) * 0.3, f(T, R), f(T, dr), c_pages, r_pages,
-            jnp.int32(1), jnp.asarray(t0, jnp.int32),
-            jnp.asarray(q_len, jnp.int32), jnp.asarray(hist, jnp.int32),
-            tables)
+    args, t0, q_len, T, mq = _latent_kernel_case(kind)
     want = mla_ragged_paged_attention_reference(*args, scale=0.7)
     got = mla_ragged_paged_attention_tpu(
         *args, scale=0.7, max_q_len=mq, interpret=True)
-    in_row = np.zeros(T, bool)
-    for s, n in zip(t0, q_len):
-        in_row[s:s + n] = True
     # float32 both sides; the kernel's online softmax against one softmax
-    err = np.abs(np.asarray(got) - np.asarray(want))[in_row].max()
+    err = np.abs(_rows_of(got, t0, q_len, T)
+                 - _rows_of(want, t0, q_len, T)).max()
     assert err < 1e-5, err
+
+
+def test_pages_a_row_never_fetched_hold_nan_and_change_nothing():
+    """The interpreter hands a kernel its scratch as NaN.  A row whose last
+    chunk is partly fetched (5 tokens: one page of twelve) computes over a
+    buffer whose other pages are still NaN: their weight is exactly 0 and
+    they are zeroed ahead of the PV product, so the output is finite and
+    the reference's.  With every page of the pool NaN but the row's own,
+    nothing a row does not own is read into a live product either."""
+    from helix_tpu.ops.mla_kernel import mla_ragged_paged_attention_tpu
+
+    args, t0, q_len, T, mq = _latent_kernel_case("decode")
+    q, c_new, r_new, kv_pages, layer, t0a, qla, hist, tables = args
+    own = np.zeros(kv_pages.shape[1], bool)
+    for row, h in enumerate(np.asarray(hist)):
+        own[np.asarray(tables)[row, :-(-int(h) // 16)]] = True
+    poisoned = jnp.where(own[None, :, None, None], kv_pages, jnp.nan)
+    want = mla_ragged_paged_attention_reference(*args, scale=0.7)
+    got = mla_ragged_paged_attention_tpu(
+        q, c_new, r_new, poisoned, layer, t0a, qla, hist, tables,
+        scale=0.7, max_q_len=mq, interpret=True)
+    got = _rows_of(got, t0, q_len, T)
+    assert np.isfinite(got).all()
+    assert np.abs(got - _rows_of(want, t0, q_len, T)).max() < 1e-5
 
 
 def test_kernel_geometry_it_cannot_lower_is_refused_by_name():
@@ -608,31 +651,171 @@ def test_int8_tree_and_logical_axes_cover_every_new_tensor():
 def test_latent_pool_counts_what_it_allocates_and_moves_by_page():
     cfg = tiny()
     cc = CacheConfig(num_pages=10, page_size=8, dtype="float32")
-    assert cc.page_shapes(cfg) == ((3, 8, 32), (3, 8, 128))
+    # ONE array: a token's latent, then its rope key padded to 128 lanes
+    assert cc.latent_widths(cfg) == (32, 128)
+    assert cc.page_shapes(cfg) == ((3, 8, 32 + 128),)
     assert cc.page_bytes(cfg) == 3 * 8 * (32 + 128) * 4
     full = CacheConfig(num_pages=1, page_size=16, dtype="bfloat16")
-    # 512 + 64 values a token a layer, the rope key padded to 128 lanes
+    # 512 + 64 values a token a layer, the rope key padded to 128 lanes:
+    # the bytes the two-array pool had (1,280 a token and layer)
+    assert full.page_shapes(DEEPSEEK_V2_LITE) == ((27, 16, 640),)
     assert full.page_bytes(DEEPSEEK_V2_LITE) == 27 * 16 * 640 * 2
     assert CacheConfig.fit_hbm(cfg, 10 * cc.page_bytes(cfg) + 5, page_size=8,
                                dtype="float32").num_pages == 10
     cache = PagedKVCache.create(cfg, cc)
-    assert cache.latent and cache.k_pages.shape == (3, 10, 8, 32)
+    assert cache.latent and cache.k_pages.shape == (3, 10, 8, 160)
+    assert cache.v_pages is None and cache.carry()[1] is None
+    assert jax.tree.leaves(cache) == [cache.k_pages]
     c = jnp.arange(3 * 1 * 4 * 32, dtype=jnp.float32).reshape(3, 1, 4, 32)
     r = jnp.ones((3, 1, 4, 8), jnp.float32)
     pages = jnp.asarray([[2, 2, 5, 0]])
     offs = jnp.asarray([[6, 7, 0, 0]])
     cache = write_kv(cache, c, r, pages, offs,
                      jnp.asarray([[True, True, True, False]]))
-    np.testing.assert_array_equal(cache.k_pages[:, 2, 6], c[:, 0, 0])
-    np.testing.assert_array_equal(cache.k_pages[:, 5, 0], c[:, 0, 2])
-    assert float(cache.v_pages[0, 2, 7, :8].sum()) == 8
-    assert float(cache.v_pages[..., 8:].sum()) == 0       # the lane padding
+    assert cache.v_pages is None
+    np.testing.assert_array_equal(cache.k_pages[:, 2, 6, :32], c[:, 0, 0])
+    np.testing.assert_array_equal(cache.k_pages[:, 5, 0, :32], c[:, 0, 2])
+    assert float(cache.k_pages[0, 2, 7, 32:40].sum()) == 8   # the rope key
+    assert float(cache.k_pages[..., 40:].sum()) == 0      # the lane padding
     held = gather_pages(cache, [2, 5])
-    assert held[0]["k"].shape == (3, 8, 32) and held[0]["v"].shape == (
-        3, 8, 128)
+    assert held[0]["k"].shape == (3, 8, 160) and held[0]["v"] is None
     moved = restore_pages(PagedKVCache.create(cfg, cc), [7, 3], held)
+    assert moved.v_pages is None
     np.testing.assert_array_equal(moved.k_pages[:, 7], cache.k_pages[:, 2])
-    np.testing.assert_array_equal(moved.v_pages[:, 3], cache.v_pages[:, 5])
+    np.testing.assert_array_equal(moved.k_pages[:, 3], cache.k_pages[:, 5])
+
+
+def test_write_latent_is_a_plain_loop_over_layers_and_tokens():
+    """``write_kv`` on a latent pool against the loop it stands for: each
+    valid token's row of each layer is ``[c | r | zeros]`` at its (page,
+    offset), rows of padding tokens land on the garbage page 0 alone, and
+    every other row of the pool keeps what it held."""
+    cfg = tiny()
+    cc = CacheConfig(num_pages=12, page_size=8, dtype="float32")
+    rng = np.random.default_rng(3)
+    L, B, S, R, dr = 3, 2, 5, 32, 8
+    before = jnp.asarray(
+        rng.standard_normal((L, 12, 8, R + 128)), jnp.float32)
+    cache = PagedKVCache(k_pages=before, v_pages=None)
+    c = jnp.asarray(rng.standard_normal((L, B, S, R)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((L, B, S, dr)), jnp.float32)
+    pages = rng.integers(1, 12, (B, S))
+    offs = np.stack([rng.permutation(8)[:S] for _ in range(B)])
+    pages[1] = pages[0] + 1 - 11 * (pages[0] == 11)  # no (page, offset) twice
+    valid = np.asarray([[True] * 5, [True, True, True, False, False]])
+    got = write_kv(cache, c, r, jnp.asarray(pages), jnp.asarray(offs),
+                   jnp.asarray(valid))
+    want = np.array(before)
+    for lyr in range(L):
+        for b in range(B):
+            for t in range(S):
+                if valid[b, t]:
+                    row = np.zeros(R + 128, np.float32)
+                    row[:R], row[R:R + dr] = c[lyr, b, t], r[lyr, b, t]
+                    want[lyr, pages[b, t], offs[b, t]] = row
+    np.testing.assert_array_equal(
+        np.asarray(got.k_pages)[:, 1:], want[:, 1:])
+    # the garbage page took the padding tokens' rows at offset 0 and
+    # nothing else
+    np.testing.assert_array_equal(
+        np.asarray(got.k_pages)[:, 0, 1:], want[:, 0, 1:])
+    assert got.v_pages is None and got.k_pages.shape == before.shape
+
+
+def _mid_decode(eng, rid, prompt, cut):
+    req = Request(id=rid, prompt_tokens=list(prompt),
+                  sampling=SamplingParams(max_tokens=12, temperature=0.0))
+    eng.add_request(req)
+    while len(req.output_tokens) < cut and eng.has_work():
+        eng.step()
+    return req
+
+
+def test_a_request_moves_between_latent_pools_by_snapshot():
+    """Export mid-generation, through the wire format, import into a second
+    engine, continue: the uninterrupted run's tokens.  A page travels as
+    its one array (``"v"`` None) under the digest the exporter stamped."""
+    from helix_tpu.serving import migration
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    prompt = tokens_of(21, seed=8)
+    ref = _engine(cfg, params).generate(
+        [prompt], SamplingParams(max_tokens=12, temperature=0.0))[0]
+    a, b = _engine(cfg, params), _engine(cfg, params)
+    req_a = _mid_decode(a, "m", prompt, 5)
+    snap = a.export_request("m")
+    assert snap is not None and snap.has_kv
+    assert (snap.kv_heads, snap.head_dim) == (0, 32 + 128)
+    assert all(p["v"] is None and p["k"].shape == (3, 8, 160)
+               for p in snap.pages)
+    head = list(req_a.output_tokens[:5])
+    a.abort("m")
+    snap = migration.wire_to_snapshot(migration.snapshot_to_wire(snap))
+    req_b = b.import_request(snap)
+    while not req_b.finished:
+        b.step()
+    assert head + list(req_b.output_tokens[5:]) == list(ref)
+
+
+@pytest.mark.parametrize("lie,field,code", [
+    # the two-array layout this pool had: its snapshots stated the latent
+    # width alone
+    (dict(head_dim=32), "head_dim", "snapshot_incompatible"),
+    (dict(kv_heads=4, head_dim=24), "kv_heads", "snapshot_incompatible"),
+], ids=["the_old_two_array_layout", "a_kv_pool"])
+def test_a_snapshot_of_another_pool_geometry_is_refused_by_name(
+        lie, field, code):
+    from helix_tpu.engine.engine import SnapshotError
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    a, b = _engine(cfg, params), _engine(cfg, params)
+    _mid_decode(a, "g", tokens_of(21, seed=8), 3)
+    snap = dataclasses.replace(a.export_request("g"), **lie)
+    free = b.allocator.free_pages
+    with pytest.raises(SnapshotError, match=field) as ei:
+        b.import_request(snap)
+    assert ei.value.code == code
+    assert b.allocator.free_pages == free       # refused before any page
+
+
+def test_a_latent_page_with_a_second_array_is_refused():
+    from helix_tpu.engine.engine import SnapshotError
+
+    cfg = tiny()
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    a, b = _engine(cfg, params), _engine(cfg, params)
+    _mid_decode(a, "h", tokens_of(21, seed=8), 3)
+    snap = a.export_request("h")
+    snap.pages[0] = dict(snap.pages[0], v=np.zeros((3, 8, 128), np.float32))
+    with pytest.raises(SnapshotError, match="k/v buffers"):
+        b.import_request(snap)
+
+
+def test_latent_pages_spill_to_the_host_tier_and_come_back():
+    """The host pool keeps a latent page as its one array: put, checksum,
+    restore into other pages of another pool."""
+    from helix_tpu.engine.kv_cache import HostPagePool, page_checksum
+
+    cfg = tiny()
+    cc = CacheConfig(num_pages=10, page_size=8, dtype="float32")
+    cache = PagedKVCache.create(cfg, cc)
+    cache = dataclasses.replace(cache, k_pages=jax.random.normal(
+        jax.random.PRNGKey(0), cache.k_pages.shape))
+    pool = HostPagePool(budget_bytes=1 << 20)
+    held = gather_pages(cache, [4, 9])
+    for i, page in enumerate(held):
+        assert pool.put(("seq", "r", i), page, pinned=True)
+    pool.drain_pending()
+    assert pool.used_bytes == 2 * cc.page_bytes(cfg)
+    back = [pool.take_restored(("seq", "r", i)) for i in range(2)]
+    assert back[0]["v"] is None
+    assert page_checksum(back[1]) == page_checksum(
+        {k: None if v is None else np.asarray(v)
+         for k, v in held[1].items()})
+    moved = restore_pages(PagedKVCache.create(cfg, cc), [1, 2], back)
+    np.testing.assert_array_equal(moved.k_pages[:, 2], cache.k_pages[:, 9])
 
 
 REFUSED = {
@@ -715,3 +898,50 @@ def test_loader_reads_deepseek_v2_tensor_names(tmp_path):
     got_cfg, got = load_params(str(tmp_path), dtype="float32")
     assert dataclasses.replace(got_cfg, name=cfg.name) == cfg
     jax.tree.map(np.testing.assert_array_equal, got, params)
+
+
+def test_page_fetches_are_counted_from_the_hosts_mirrors():
+    """``helix_mla_page_fetches_total``: a launch's history pages (live rows'
+    ``ceil(hist / page)`` x query blocks x latent layers) on the launch's
+    span and, summed, on ``/metrics``; by hand for a 40-token prompt in
+    chunks of 16 at page 8 and three decode steps."""
+    from helix_tpu.obs import trace as obs_trace
+    from helix_tpu.serving.engine_loop import EngineLoop
+    from helix_tpu.serving.openai_api import OpenAIServer
+    from helix_tpu.serving.registry import ModelRegistry, ServedModel
+    from helix_tpu.serving.tokenizer import ByteTokenizer
+
+    cfg = tiny()
+    eng = _engine(cfg, init_params(cfg, jax.random.PRNGKey(3)),
+                  decode_steps_per_sync=1)
+    seen = []
+    orig = obs_trace.phase
+
+    def phase(name, *a, **kw):
+        if name == "helix.loop.launch":
+            seen.append(kw["mla_page_fetches"])
+        return orig(name, *a, **kw)
+
+    obs_trace.phase = phase
+    try:
+        req = Request(id="p", prompt_tokens=tokens_of(40, seed=1),
+                      sampling=SamplingParams(max_tokens=4, temperature=0.0))
+        eng.add_request(req)
+        while eng.has_work():
+            eng.step()
+    finally:
+        obs_trace.phase = orig
+    L = cfg.num_attn_layers
+    # chunks at 0, 16, 32 tokens of history: 0, 2 and 4 pages under two
+    # 8-token blocks each, then one-token rows over 40, 41, 42 tokens
+    by_hand = [0, 2 * 2 * L, 4 * 1 * L, 5 * L, 6 * L, 6 * L]
+    assert seen == by_hand, seen
+    assert eng.num_mla_page_fetches == sum(by_hand)
+    registry = ModelRegistry()
+    registry.register(ServedModel(
+        name="tiny-mla", loop=EngineLoop(eng, "tiny-mla"),
+        tokenizer=ByteTokenizer(), context_length=128))
+    text = OpenAIServer(registry).obs.render()
+    line = next(ln for ln in text.splitlines()
+                if ln.startswith("helix_mla_page_fetches_total{"))
+    assert float(line.rsplit(" ", 1)[1]) == sum(by_hand)

@@ -267,18 +267,25 @@ MLA_SHAPES = {  # tokens, rows, a row's most fresh tokens
 }
 
 
+@pytest.mark.parametrize("heads", [16, 64], ids=["deepseek", "gigachat"])
 @pytest.mark.parametrize("shape", sorted(MLA_SHAPES))
-def test_latent_kernel_compiles_at_the_published_geometry(one_chip, shape):
+def test_latent_kernel_compiles_at_the_published_geometry(one_chip, shape,
+                                                          heads):
+    """The latent kernel over the ONE-array pool (a token's latent and its
+    lane-padded rope key in one row of 640 lanes: one DMA a page) at both
+    served head counts: DeepSeek-V2-Lite's 16 over its 17 layers' pool,
+    GigaChat3.5's 64 over its two latent layers'."""
     from helix_tpu.ops.paged import mla_ragged_paged_attention
 
     T, R, mq = MLA_SHAPES[shape]
-    H, lat, rope, L, pages, max_pages = 16, 512, 64, 17, 10240, 160
+    lat, rope, max_pages = 512, 64, 160
+    H, L, pages = {16: (16, 17, 10240), 64: (64, 2, 2048)}[heads]
 
     def S(shp, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
 
     args = (S((T, H, lat + rope)), S((T, lat)), S((T, rope)),
-            S((L, pages, PAGE, lat)), S((L, pages, PAGE, 128)),
+            S((L, pages, PAGE, lat + 128)),
             S((), jnp.int32), S((R,), jnp.int32), S((R,), jnp.int32),
             S((R,), jnp.int32), S((R, max_pages), jnp.int32))
     compiled = jax.jit(
@@ -354,10 +361,10 @@ def test_expert_step_compiles_at_published_widths(one_chip, program):
         lambda a: S(a.shape, a.dtype),
         jax.eval_shape(
             lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    ks, vs = CacheConfig(num_pages=pages).page_shapes(cfg)
+    ks, = CacheConfig(num_pages=pages).page_shapes(cfg)
+    assert ks == (3, 16, 512 + 128)
     cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
-        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16))
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None)
 
     def sampling(n):
         f32 = jnp.float32
@@ -641,13 +648,12 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
             lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
     cc = CacheConfig(num_pages=pages, state_slots=B,
                      max_pages_per_seq=max_pages)
-    ks, vs = cc.page_shapes(cfg)
-    assert ks == (1, 16, 512) and vs == (1, 16, 128)
+    ks, = cc.page_shapes(cfg)
+    assert ks == (1, 16, 512 + 128)
     assert cc.state_shapes(cfg) == (
         ((2, B, 3, 16384), "bfloat16"), ((2, B, 64, 128, 128), "float32"))
     cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
-        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None,
         state=tuple(S(shp, jnp.dtype(dt))
                     for shp, dt in cc.state_shapes(cfg)))
 
