@@ -14,7 +14,10 @@ layer: no page of KV, a float32 matrix state a slot; ten layers; its two
 phases are its own, ``phase_kernel_retention`` and ``phase_engine_retention``
 below) or ``gigachat3.5-432b-a28b-int8`` (gated delta-rule layers beside
 latent layers with a compressed gated query, 16 held experts of 256; nine
-layers; ``phase_kernel_deltanet`` and ``phase_engine_deltanet``).
+layers; ``phase_kernel_deltanet`` and ``phase_engine_deltanet``) or
+``laguna-xs2-int8`` (sliding-window layers over rings of K/V beside full
+layers over pages, 48 and 64 query heads, a gate a head, 32 held experts of
+256; all 40 layers; ``kernel_window`` and ``phase_engine_window``).
 ``CONFIGS`` holds what differs: the reference, the
 kernel cases, the faults and the limits.  What follows describes DeepSeek-
 V2-Lite; the other configuration's table entry says what it changes.
@@ -216,6 +219,114 @@ def kernel_gqa_64(seed, rehearse, rng, ks):
     if not ok:
         fail("the ragged kernel at head width 64 disagrees with its "
              "reference")
+    return B, S
+
+
+def kernel_window(seed, rehearse, rng, ks):
+    """Laguna's two attention calls at the published geometry.  The dense
+    ragged kernel at 48 query / 8 kv heads of 128 (a query group of 6, padded
+    to a sublane tile of 8) over pages, at ``_attention_cases``.  The window
+    kernel (``ops/window_kernel.py``) at 64 / 8 / 128 over rings of 512 in a
+    pool of 48 slots whose every row holds finite values of some other
+    sequence: 48 decode rows at histories from 0 to far past the window (0,
+    3, 511, 512 and 513 among them), a 512-token chunk over a wrapped ring,
+    one that crosses the window inside the chunk, and rows of both sides on
+    one axis.  Then a CONTROL the window kernel must fail: the ring row that
+    holds the token exactly ``W`` back gets a key along its query and a
+    value of 50; the kernel has to agree with the reference at ``W`` and
+    part from the reference at ``W + 1`` by more than the tolerance."""
+    from helix_tpu.ops.paged import (
+        ragged_paged_attention, ragged_paged_attention_reference,
+    )
+    from helix_tpu.ops.paged_kernel import ragged_paged_attention_tpu
+    from helix_tpu.ops.window import (
+        window_attention, window_attention_reference,
+    )
+    from helix_tpu.ops.window_kernel import window_attention_tpu
+
+    KVH, D, P, L = 8, 128, 16, 2
+    N, maxP, B, S, W = (64, 8, 4, 32, 8) if rehearse else (
+        2048, 160, 48, 512, 512)
+    dt = jnp.float32 if rehearse else jnp.bfloat16
+    draw = lambda k, shp: jax.random.normal(k, shp).astype(dt)  # noqa: E731
+    ok = True
+    H = 48
+    k_pages, v_pages = draw(ks[0], (L, N, P, KVH, D)), draw(
+        ks[1], (L, N, P, KVH, D))
+    for name, (T, t0, q_len, hist, tables, mq) in _attention_cases(
+            rng, B, S, maxP, P, N).items():
+        args = (draw(ks[2], (T, H, D)), draw(ks[3], (T, KVH, D)),
+                draw(ks[4], (T, KVH, D)), k_pages, v_pages, jnp.int32(1),
+                *(jnp.asarray(x, jnp.int32)
+                  for x in (t0, q_len, hist, tables)))
+        if rehearse:
+            got = ragged_paged_attention_tpu(
+                *args, max_q_len=mq, interpret=True)
+        else:
+            got = ragged_paged_attention(*args, backend="pallas",
+                                         max_q_len=mq)
+        with jax.default_matmul_precision("highest"):
+            want = ragged_paged_attention_reference(*args)
+        ok &= _hold("ragged_paged_attention", [H, KVH, D], name, T, t0,
+                    q_len, got, want)
+    H = 64
+    k_ring, v_ring = draw(ks[0], (L, B, W, KVH, D)), draw(
+        ks[1], (L, B, W, KVH, D))
+    edge = np.array([0, 3, W - 1, W, W + 1])[:B]
+    hist = np.concatenate([edge, rng.integers(1, 5 * W, size=B - len(edge))])
+    slots = rng.permutation(B)
+    cases = {
+        "decode": (B, np.arange(B), np.ones(B, int), hist, slots, 1),
+        "chunk_over_a_wrapped_ring": (
+            S, np.zeros(1, int), np.array([S]), np.array([W + W // 3]),
+            slots[:1], S),
+        "chunk_that_crosses_the_window": (
+            S, np.zeros(1, int), np.array([S]), np.array([W // 3]),
+            slots[1:2], S),
+        "rows_of_both_sides": (
+            S, np.array([0, S // 4, S // 4 + 7]),
+            np.array([S // 4, 7, S // 2]), np.array([0, 3 * W, W - 2]),
+            slots[:3], S),
+    }
+
+    def call(args, mq):
+        if rehearse:
+            return window_attention_tpu(*args, max_q_len=mq, interpret=True)
+        return window_attention(*args, backend="pallas", max_q_len=mq)
+
+    for name, (T, t0, q_len, hst, slt, mq) in cases.items():
+        args = (draw(ks[2], (T, H, D)), draw(ks[3], (T, KVH, D)),
+                draw(ks[4], (T, KVH, D)), k_ring, v_ring, jnp.int32(1),
+                *(jnp.asarray(x, jnp.int32) for x in (t0, q_len, hst, slt)))
+        with jax.default_matmul_precision("highest"):
+            want = window_attention_reference(*args)
+        ok &= _hold("window_attention", [H, KVH, D, W], name, T, t0, q_len,
+                    call(args, mq), want)
+    # the control: decode rows past the window, the row W back spiked
+    T, t0, q_len, hst, slt, mq = cases["decode"]
+    q = draw(ks[2], (T, H, D))
+    past = np.flatnonzero(hst >= W)
+    kr, vr = (np.array(a, np.float32) for a in (k_ring, v_ring))
+    lead = np.asarray(q, np.float32)[:, ::H // KVH]          # [T, KVH, D]
+    for r in past:
+        kr[1, slt[r], hst[r] % W] = 4.0 * lead[r]
+        vr[1, slt[r], hst[r] % W] = 50.0
+    args = (q, draw(ks[3], (T, KVH, D)), draw(ks[4], (T, KVH, D)),
+            jnp.asarray(kr, dt), jnp.asarray(vr, dt), jnp.int32(1),
+            *(jnp.asarray(x, jnp.int32) for x in (t0, q_len, hst, slt)))
+    got = np.asarray(call(args, mq), np.float32)[past]
+    with jax.default_matmul_precision("highest"):
+        right, wrong = (
+            np.asarray(window_attention_reference(*args, window=w),
+                       np.float32)[past] for w in (W, W + 1))
+    err, off = (float(np.abs(got - x).max()) for x in (right, wrong))
+    held = bool(err <= TOL_BF16 < off)
+    say(phase="kernel", op="window_attention", control="window_off_by_one",
+        rows=len(past), max_abs_err=err, max_abs_err_at_w_plus_1=off,
+        tol=TOL_BF16, ok=held)
+    if not (ok and held):
+        fail("a Laguna attention kernel disagrees with its reference, or "
+             "agrees with a window of one key more")
     return B, S
 
 
@@ -849,8 +960,252 @@ def phase_engine_deltanet(spec, name, seed, layers, steps, rehearse):
         fail("the engine and the reference part by more than the limits, or "
              "a fault lies under them at some compared step")
 
+# ``laguna-xs2-int8`` (PERF.md section 6, PR 41).  Limits on the relative RMS
+# error of the logits a step, its median over the steps and its worst step,
+# as GigaChat3.5's: both sides read the same int8 weights; what is left is
+# the program's bf16 over 40 layers, its rings and pages, and a near-tied
+# expert choice of the eight that bf16 flips.  Readings on the chip (PR 41,
+# seed 4100000101, 32 steps of a 1,400-token request | of a 300-token one
+# in the same engine; logits of std 0.90): the engine's median 0.0247 |
+# 0.0221, its worst step 0.0595 | 0.0871.  At EVERY step: no window
+# 0.568-0.614, the full layers rotated over all 128 dims 0.204-0.250 |
+# 0.237-0.269, the sliding layers under the full layers' table 0.223-0.265,
+# no gate 0.930-0.984 | 0.782-0.823.  The rank's 32 experts all dropped
+# 0.145-0.192 (median 0.170): over the median's limit, and over the worst
+# step's by a hair.  NOT separated by logits: one key more in the window
+# 0.0057-0.047 (ONE key in 512 at scores of std 0.8: the kernel phase's
+# control separates it, 0.002 against 50.3) and one dropped expert of the
+# 32 held 0.015-0.086.  The median's limit is 2.4 times the engine's and a
+# third of the least fault that must fail; the worst step's is 1.6 times
+# the engine's worst and 0.7 of that fault's least step.  A second seed
+# (4100000102): median 0.0304 | 0.0265, worst step 0.0746 | 0.0649, the four
+# faults 0.224 and over at every step, the 32 experts dropped 0.150-0.200.
+TOL_LAGUNA = 0.06
+TOL_LAGUNA_WORST = 0.14
+
+
+def phase_engine_window(spec, name, seed, layers, steps, rehearse):
+    """The engine at the published widths and all 40 layers, int8 weights
+    from the seed (one expert-parallel rank's 32 of 256 experts), against the
+    plain reference's full forward by logits at EVERY decode step: a
+    1,400-token prompt in three chunks (the ring wraps twice in prefill; the
+    second and third chunks read the ring and the full layers' pages the ones
+    before left) beside a SECOND request of 300 tokens, shorter than the
+    window, in the same engine, then ``steps`` decode steps of both through
+    rings and pages.  The reference is causal and has no cache: one forward
+    a request gives every compared step's logits, and one more a fault that
+    fault's reading at every step."""
+    import importlib
+
+    from helix_tpu.engine.engine import (
+        Engine, EngineConfig, Request, SamplingParams,
+    )
+    from helix_tpu.models.common import ModelConfig
+    from helix_tpu.models.llama import init_params
+
+    reference = importlib.import_module("benchmark.lib." + spec["reference"])
+    with open(os.path.join(HERE, "benchmark", "configs",
+                           name + ".json")) as f:
+        hf = json.load(f)
+    if rehearse:
+        L = 12
+        rope = {k: dict(v) for k, v in hf["rope_parameters"].items()
+                if isinstance(v, dict)}
+        rope["full_attention"].update(
+            original_max_position_embeddings=16, beta_fast=4)
+        hf = dict(
+            hf, vocab_size=256, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            num_attention_heads=6, num_key_value_heads=2, head_dim=16,
+            num_experts_per_tok=4, num_experts=4, published_num_experts=16,
+            held_experts=[0, 4], sliding_window=8, num_hidden_layers=L,
+            layer_types=hf["layer_types"][:L],
+            mlp_layer_types=hf["mlp_layer_types"][:L],
+            num_attention_heads_per_layer=[
+                {48: 6, 64: 8}[h]
+                for h in hf["num_attention_heads_per_layer"][:L]],
+            rope_parameters=rope)
+        ecfg = EngineConfig(max_decode_batch=2, page_size=8, num_pages=64,
+                            max_pages_per_seq=24, max_prefill_len=16,
+                            attn_backend="reference",
+                            enable_prefix_cache=False)
+        n_prompt, n_short, steps, block = 40, 5, 4, 64
+    else:
+        ecfg = EngineConfig(max_decode_batch=2, page_size=16, num_pages=256,
+                            max_pages_per_seq=128, max_prefill_len=512,
+                            enable_prefix_cache=False)
+        n_prompt, n_short, block = 1400, 300, 256
+    cfg = ModelConfig.from_hf_config(hf, name=hf["model"])
+    if rehearse:
+        cfg = dataclasses.replace(cfg, dtype="float32")
+    t = time.monotonic()
+    params = init_params(cfg, jax.random.PRNGKey(seed), int8=not rehearse)
+    jax.block_until_ready(params)
+    eng = Engine(cfg, params, ecfg)
+    say(phase="engine", config=name, layers=cfg.num_layers,
+        window_layers=cfg.num_window_layers, attn_layers=cfg.num_attn_layers,
+        sliding_window=cfg.sliding_window,
+        held_experts=list(cfg.held_experts), routed_experts=cfg.num_experts,
+        weights_s=round(time.monotonic() - t, 1), backend=eng._backend,
+        recurrent_state_bytes=eng.recurrent_state_bytes,
+        page_bytes=eng.cache_cfg.page_bytes(cfg))
+    view = reference.kinds(hf)
+    homes = reference.layer_homes(view)
+    faults = {"no_window": {"no_window": True},
+              "window_off_by_one": {"window_off_by_one": True},
+              "full_rotary": {"full_rotary": True},
+              "one_rope": {"one_rope": True},
+              "drop_gate": {"drop_gate": True},
+              "dropped_expert": {"drop_expert": 0},
+              "dropped_share": {"drop_expert": "all"}}
+
+    # (the weights are arguments: closed over, a jit holds them as constants
+    # of the program; the index in the stack is traced: one compile a stack
+    # and fault, not one a layer)
+    @functools.partial(jax.jit, static_argnames=("kind", "dense", "fault"))
+    def ref_layer(h, stack, i, kind, dense, fault):
+        with jax.default_matmul_precision("highest"):
+            return reference.layer(
+                h, stack, i, hf, jnp.arange(h.shape[0]), kind, dense,
+                faults.get(fault, {}), block)
+
+    @jax.jit
+    def ref_head(h, at, norm, head):
+        with jax.default_matmul_precision("highest"):
+            x = reference.norm(h[at], norm["weight"].astype(jnp.float32),
+                               hf["rms_norm_eps"])
+            return x @ (head["weight"].astype(jnp.float32)
+                        * head.get("scale", 1.0))
+
+    @jax.jit
+    def ref_embed(tokens, table):
+        rows = table["weight"][tokens].astype(jnp.float32)
+        if "embed_scale" in table:
+            rows = rows * table["embed_scale"][tokens]
+        return rows
+
+    def applies(fault, kind, dense):
+        """A fault of another kind of layer is no fault here: the layer
+        runs the program that is compiled already."""
+        if fault in ("no_window", "window_off_by_one", "one_rope"):
+            return kind == "sliding_attention"
+        if fault == "full_rotary":
+            return kind == "full_attention"
+        if fault in ("dropped_expert", "dropped_share"):
+            return not dense
+        return True
+
+    def ref(seq, at, fault):
+        """The reference's logits at the positions ``at`` of ``seq``."""
+        h = ref_embed(jnp.asarray(list(seq), jnp.int32), params["embed"])
+        for l, (key, i) in enumerate(homes):
+            kind, dense = hf["layer_types"][l], l < view["num_dense_layers"]
+            h = ref_layer(
+                h, params[key], jnp.int32(i), kind, dense,
+                fault if applies(fault, kind, dense) else "none")
+        return np.asarray(ref_head(
+            h, jnp.asarray(at), params["final_norm"], params["lm_head"]),
+            np.float32)
+
+    def rel_rms(got, want):
+        return np.sqrt(np.mean((got - want) ** 2, axis=-1)) / want.std(
+            axis=-1)
+
+    tol_median, tol_worst = spec["limits"]
+    rng = np.random.default_rng(seed + n_prompt)
+    reqs = {
+        rid: Request(
+            id=rid, prompt_tokens=rng.integers(
+                1, cfg.vocab_size, size=n).tolist(),
+            sampling=SamplingParams(max_tokens=steps + 2, temperature=1.0,
+                                    seed=seed + n))
+        for rid, n in (("cell", n_prompt), ("short", n_short))}
+    for r in reqs.values():
+        eng.add_request(r)
+    got = {rid: {} for rid in reqs}
+    t = time.monotonic()
+    while eng.has_work() and min(len(g) for g in got.values()) < steps:
+        eng.step()
+        for rid, r in reqs.items():
+            n = len(r.output_tokens)
+            if n and n not in got[rid] and r.slot is not None and (
+                    eng.slots[r.slot] is r):
+                got[rid][n] = np.asarray(
+                    eng.next_token_logits()[r.slot], np.float32)
+    while eng.has_work():
+        eng.step()
+    eng._drain_moe_drops()
+    say(phase="engine", prompt_tokens=[n_prompt, n_short],
+        chunks=-(-n_prompt // ecfg.max_prefill_len),
+        steps={rid: len(g) for rid, g in got.items()},
+        engine_s=round(time.monotonic() - t, 1),
+        window_rows=dict(eng.num_window_rows),
+        window_ring_bytes_read=eng.window_ring_bytes_read,
+        state_bytes_touched=eng.state_bytes_touched,
+        mixed_steps=eng.num_mixed_steps,
+        moe_held_tokens=eng.moe_routed_tokens,
+        moe_away_tokens=eng.moe_away_tokens)
+    ok = all(len(g) >= steps for g in got.values())
+    counted = eng.moe_routed_tokens + eng.moe_away_tokens
+    for rid, r in reqs.items():
+        n_p = len(r.prompt_tokens)
+        seq = r.prompt_tokens + r.output_tokens
+        ns = sorted(got[rid])
+        at = [n_p + n - 1 for n in ns]
+        mine = np.stack([got[rid][n] for n in ns])
+        t = time.monotonic()
+        want = ref(seq, at, "none")
+        err = rel_rms(mine, want)
+        readings = {"engine": err}
+        # (the window's faults are no faults under the window: the short
+        # request is held to the engine's limits and the others' readings)
+        for fault in spec["faults"] if rid == "cell" else (
+                "full_rotary", "drop_gate"):
+            readings[fault] = rel_rms(ref(seq, at, fault), want)
+        median, worst = float(np.median(err)), float(err.max())
+        good = (median <= tol_median and worst <= tol_worst and all(
+            float(readings[f].min()) > tol_worst
+            for f in spec["over_at_every_step"] if f in readings) and all(
+            float(np.median(readings[f])) > tol_median
+            for f in spec["over_in_the_median"] if f in readings))
+        ok &= good
+        say(phase="engine", request=rid, tokens=len(seq), steps=len(ns),
+            reference_s=round(time.monotonic() - t, 1),
+            logit_std=float(want.std()), median_rel_rms_err=median,
+            worst_rel_rms_err=worst,
+            max_abs_err=float(np.abs(mine - want).max()),
+            faults={f: {"least": float(x.min()),
+                        "median": float(np.median(x)),
+                        "most": float(x.max())}
+                    for f, x in readings.items()},
+            tol_median=tol_median, tol_worst=tol_worst, ok=bool(good))
+    # every assignment is counted, here or away: top-k a token and layer
+    tokens = sum(len(r.prompt_tokens) + len(r.output_tokens) - 1
+                 for r in reqs.values())
+    routed = counted == tokens * cfg.num_experts_per_tok * cfg.num_moe_layers
+    say(phase="engine", assignments_counted=counted,
+        held_share=eng.moe_routed_tokens / max(counted, 1), ok=bool(routed))
+    if not (ok and routed) and not rehearse:
+        fail("the engine and the reference part by more than the limits, or "
+             "a fault that must fail lies under them")
+
 
 CONFIGS = {
+    "laguna-xs2-int8": dict(
+        reference="reference_window_moe_decoder",
+        engine_phase=phase_engine_window,
+        attention_kernel=kernel_window,
+        experts=(32, 2048, 512, 8),
+        faults=("no_window", "window_off_by_one", "full_rotary", "one_rope",
+                "drop_gate", "dropped_expert", "dropped_share"),
+        # by logits; window_off_by_one is ONE key in 512 at scores of std
+        # 0.8: under bf16's noise by logits, it fails the kernel phase's
+        # control instead (``kernel_window``); it and one dropped expert of
+        # the 32 held are read at every step and reported
+        over_at_every_step=("no_window", "full_rotary", "one_rope",
+                            "drop_gate"),
+        over_in_the_median=("dropped_share",),
+        limits=(TOL_LAGUNA, TOL_LAGUNA_WORST)),
     "gigachat3.5-432b-a28b-int8": dict(
         reference="reference_deltanet_mla_moe_decoder",
         kernel_phase=phase_kernel_deltanet,

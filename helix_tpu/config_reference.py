@@ -49,8 +49,9 @@ ENV_REFERENCE: tuple = (
         "where a profile enables it. Unset: the profile's "
         "enable_spec_decode/spec_tokens settings apply. A latent-"
         "attention model (DeepSeek-V2-Lite) is not served with "
-        "speculation: enabling it is refused at profile apply "
-        "(UnsupportedForModel).",
+        "speculation, nor is one whose sequences carry a state or a "
+        "ring of K/V a slot (sliding-window layers): enabling it is "
+        "refused at profile apply (UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -209,8 +210,9 @@ ENV_REFERENCE: tuple = (
         "multi-host meshes the pool runs on every host (adapter ids "
         "ride the step plan and followers stage residency before the "
         "step), so publish adapters to the leader and followers as a "
-        "pair. A latent-attention model (DeepSeek-V2-Lite) refuses a "
-        "pool at profile apply (UnsupportedForModel).",
+        "pair. A latent-attention model (DeepSeek-V2-Lite) and a model "
+        "with sliding-window layers refuse a pool at profile apply "
+        "(UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -287,8 +289,9 @@ ENV_REFERENCE: tuple = (
         "this node serves (operator-beats-profile); 0 forces fully-"
         "resident even where a profile enables tiering. Unset: the "
         "profile's engine block (default 0 = off). A latent-attention "
-        "model (DeepSeek-V2-Lite) refuses tiering at profile apply "
-        "(UnsupportedForModel).",
+        "model (DeepSeek-V2-Lite) and a model with sliding-window "
+        "layers (a demoted middle would come back without the ring) "
+        "refuse tiering at profile apply (UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(

@@ -185,10 +185,12 @@ def _build_served_model(pm: ProfileModel, mesh=None) -> ServedModel:
         model_cfg = dataclasses.replace(model_cfg, name=pm.name)
     else:
         model_cfg = CATALOG.get(pm.name)
-        if isinstance(pm.model_overrides.get("rope_scaling"), dict):
-            # a profile writes it as a mapping; the config keeps it hashable
-            pm.model_overrides["rope_scaling"] = tuple(
-                sorted(pm.model_overrides["rope_scaling"].items()))
+        for key in ("rope_scaling", "window_rope_scaling"):
+            if isinstance(pm.model_overrides.get(key), dict):
+                # a profile writes it as a mapping; the config keeps it
+                # hashable
+                pm.model_overrides[key] = tuple(
+                    sorted(pm.model_overrides[key].items()))
         for key in ("layer_types", "held_experts"):
             if isinstance(pm.model_overrides.get(key), list):
                 pm.model_overrides[key] = tuple(pm.model_overrides[key])

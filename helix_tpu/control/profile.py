@@ -63,7 +63,21 @@ class ProfileModel:
     context_length: Optional[int] = None
     # architecture overrides for random-init dev models (no checkpoint):
     # forwarded to ModelConfig.tiny — e.g. {num_experts: 4} builds a toy
-    # MoE for ep-mesh dev profiles
+    # MoE for ep-mesh dev profiles.  Any field of ``models/common.py::
+    # ModelConfig``; among them, by architecture:
+    #   layer_types: one of attn | conv | retention | deltanet | window a
+    #     layer ("window": sliding-window attention, its K/V a ring a slot)
+    #   sliding_window: tokens a window layer's query sees, its own among
+    #     them (the ring's length)
+    #   num_heads / window_num_heads: query heads of a full / a window layer
+    #   rope_theta, rope_scaling, rotary_dim: the full layers' rope (a
+    #     mapping for rope_scaling; rotary_dim < head_dim rotates a head's
+    #     first dims only); window_rope_theta, window_rope_scaling,
+    #     window_rotary_dim: the window layers'
+    #   attn_gate: a sigmoid gate on the attention's output (ONE value a
+    #     head on GQA layers, a value a channel on latent ones)
+    #   held_experts: [lo, hi), the routed experts this chip holds as one
+    #     expert-parallel rank
     model_overrides: dict = dataclasses.field(default_factory=dict)
     # PRNG seed of a random-init model's weights (no checkpoint)
     seed: int = 0

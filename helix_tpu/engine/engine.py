@@ -736,6 +736,8 @@ _DELTA_STATE = ("a matrix state and a conv tail (gated delta rule)",
                 lambda m: m.num_deltanet_layers > 0)
 _HELD_EXPERTS = ("held experts (one expert-parallel rank of the routed "
                  "experts)", lambda m: m.held_experts is not None)
+_WINDOW_RING = ("a ring of K/V a slot (sliding-window attention)",
+                lambda m: m.num_window_layers > 0)
 _REFUSALS = (
     (_MULTI_DEVICE, _LATENT,
      "the latent pool and its kernel are single-device: mesh {tp: 1}"),
@@ -789,6 +791,24 @@ _REFUSALS = (
     (_MULTI_DEVICE, _HELD_EXPERTS,
      "the other ranks' experts and the exchange with them are not run: "
      "one chip computes its own experts' part of the sum"),
+    (_MULTI_DEVICE, _WINDOW_RING,
+     "the rings and the window kernel are single-device"),
+    (_INT8_KV, _WINDOW_RING,
+     "the rings and the pages beside them are bf16 or f32: a ring row has "
+     "no scale"),
+    (_ADAPTERS, _WINDOW_RING, "no LoRA targets on the window layers' "
+     "projections"),
+    (_SPEC, _WINDOW_RING,
+     "a rejected draft's K/V would have overwritten ring rows the window "
+     "still needs"),
+    (_TIERED, _WINDOW_RING,
+     "a demoted cold middle is resumed without the ring at its end"),
+    (_HOST_TIER, _WINDOW_RING,
+     "a spilled prefix or a preempted sequence's pages come back without "
+     "the ring"),
+    (_PREFIX_CACHE, _WINDOW_RING,
+     "a hit would need the ring as it stood at the prefix's boundary: no "
+     "step files it; set enable_prefix_cache: false"),
 )
 
 
@@ -823,6 +843,12 @@ def _refuse_call(model_cfg, what: str) -> None:
             f"{model_cfg.name}: a matrix state and a conv tail (gated "
             f"delta rule) is not served with {what} (the sequence's state "
             "has no place in what it moves)"
+        )
+    if model_cfg.num_window_layers:
+        raise UnsupportedForModel(
+            f"{model_cfg.name}: a ring of K/V a slot (sliding-window "
+            f"attention) is not served with {what} (the sequence's rings "
+            "have no place in what it moves)"
         )
 
 
@@ -972,6 +998,51 @@ def _deltanet_rows_fn(cfg, t0, qlen, hist, slots, backend, decode: bool):
     return deltanet_fn
 
 
+def _window_rows_fn(t0, qlen, hist, slots, backend, packed=None):
+    """The ``window_fn`` of one segment of the step (``models/llama.py::
+    _layer``, a window layer), under ``_conv_rows_fn``'s contract: row ``r``
+    is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
+    ``slots[r]`` with ``hist[r]`` tokens behind it, of which its slot's
+    rings hold the last ``W``.  The row's queries read the rings AS THEY
+    STAND (``ops.window.window_attention``: a decode step's one-token rows,
+    a chunk that continues a prompt), its fresh K/V beside them; then its
+    fresh K/V land in the rings (``write_ring``: a chunk of ``W`` replaces
+    the ring, a shorter one rotates into it).  A ring is not cleared for a
+    new sequence: a row with no history reads none of it and the mask by
+    position hides what it has not written.  A row with no fresh token (an
+    idle slot, padding) and a row without a slot write nothing.
+
+    ``packed = (positions, segment ids, mesh)``: no row of the segment has
+    history (a cold packed wave, a first chunk), so its attention is the
+    packed self-attention under the window beside the segment mask, with no
+    pool read.
+
+    Called ``(q, k, v, carry)``, the carry ``((page carry, kacc, vacc, (K
+    rings, V rings)), window layer index)``."""
+    from helix_tpu.ops.window import window_attention, write_ring
+
+    def window_fn(q, k, v, carry_cache):
+        (caches, kacc, vacc, (k_ring, v_ring)), lc = carry_cache
+        Bq, Sq, H, D = q.shape
+        flat = lambda a: a.reshape((Bq * Sq,) + a.shape[2:])
+        if packed is not None:
+            pos, seg, mesh = packed
+            out = full_attention(
+                q, k, v, causal=True, q_positions=pos, kv_positions=pos,
+                q_segment_ids=seg, kv_segment_ids=seg, backend=backend,
+                mesh=mesh, window=k_ring.shape[2])
+        else:
+            out = window_attention(
+                flat(q), flat(k), flat(v), k_ring, v_ring, lc, t0, qlen,
+                hist, slots, backend=backend, max_q_len=Sq,
+            ).reshape(q.shape)
+        k_ring, v_ring = write_ring(
+            k_ring, v_ring, lc, flat(k), flat(v), t0, qlen, hist, slots)
+        return out, (caches, kacc, vacc, (k_ring, v_ring))
+
+    return window_fn
+
+
 def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
     """The ``retention_fn`` of one segment of the step (``models/llama.py::
     _retention_mixer``), under ``_conv_rows_fn``'s contract for a state
@@ -1034,11 +1105,18 @@ def _segments_fn(fn_p, fn_s, n_tok: int, split, join):
 
 
 def _state_rows_fns(cfg, rows_s, backend, rows_p=None, split=None,
-                    join=None) -> dict:
+                    join=None, packed=None) -> dict:
     """``forward``'s look-back argument for the model's recurrent mixer:
     ``rows_s = (t0, qlen, hist, slots)`` the state rows (one token each,
     row ``b`` slot ``b``), ``rows_p = (t0, qlen, hist, slots, snap)`` the
-    prefill rows before them on the axis, if the program has any."""
+    prefill rows before them on the axis, if the program has any.
+    ``packed``: the prefill rows have no history (``_window_rows_fn``)."""
+    if cfg.state_mixer == "window":
+        attend = _segments_fn(
+            rows_p and _window_rows_fn(*rows_p[:4], backend, packed),
+            _window_rows_fn(*rows_s, backend), 3, split, join)
+        return {"window_fn": lambda q, k, v, carry, pos: attend(
+            q, k, v, carry)}
     if cfg.state_mixer == "retention":
         return {"retention_fn": _segments_fn(
             rows_p and _retention_rows_fn(*rows_p[:4], backend, False),
@@ -1434,7 +1512,9 @@ def _build_ragged_step_fn(
                         (cfg.num_conv_layers, prefill_rows) + shp,
                         cache.state.dtype),)
                 state_fns = _state_rows_fns(
-                    cfg, rows_s, backend, rows_p, split, join)
+                    cfg, rows_s, backend, rows_p, split, join,
+                    packed=((p_pos, p_seg, mesh)
+                            if Cb > 0 and not has_hist else None))
             if is_mrope:
                 from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -1668,12 +1748,15 @@ class Engine:
             from helix_tpu.ops.paged_kernel import check_geometry
 
             tp = head_shards(mesh)
-            check_geometry(
-                model_cfg.num_heads // tp,
-                max(model_cfg.num_kv_heads // tp, 1),
-                model_cfg.head_dim,
-                jnp.dtype(self.cache_cfg.dtype).itemsize,
-            )
+            # each kind of GQA layer at its own count of query heads (the
+            # window kernel takes what the ragged kernel takes)
+            for mixer in sorted({"attn", "window"} & set(model_cfg.mixers)):
+                check_geometry(
+                    model_cfg.heads_of(mixer) // tp,
+                    max(model_cfg.num_kv_heads // tp, 1),
+                    model_cfg.head_dim,
+                    jnp.dtype(self.cache_cfg.dtype).itemsize,
+                )
         logging.getLogger(__name__).info(
             "engine %s: attention backend %s on platform %s, device_kind "
             "%s, %d device(s)",
@@ -1719,6 +1802,12 @@ class Engine:
         # (what a roofline reckoned from a trace divides by)
         self.num_retention_rows = {"decode": 0, "chunk": 0}
         self.num_deltanet_rows = {"decode": 0, "chunk": 0}
+        # rings of K/V (sliding-window layers): rows that read their slot's
+        # rings, and the bytes of live ring rows they read (what a roofline
+        # reckoned from a trace divides by); ``state_bytes_touched`` counts
+        # the ring rows written
+        self.num_window_rows = {"decode": 0, "chunk": 0}
+        self.window_ring_bytes_read = 0
         self.state_bytes_touched = 0
         # history pages the latent kernel walked (``_mla_page_fetches``)
         self.num_mla_page_fetches = 0
@@ -2064,6 +2153,30 @@ class Engine:
         self.state_bytes_touched += 2 * (dec + chunk) * (
             self.recurrent_state_bytes // self.cfg.max_decode_batch)
 
+    def _note_window_rows(self, plan, draft_len, n_extra) -> None:
+        """Count the rows of this launch that read and write their slot's
+        rings, from the host's mirrors.  A row
+        reads ``min(tokens behind it, W)`` ring rows of K and of V in every
+        window layer and writes its fresh tokens' (at most ``W``); a live
+        decode row does so once a fused step, a token further on each."""
+        m = self.model_cfg
+        W = m.sliding_window
+        per_tok = (2 * m.num_kv_heads * m.head_dim * jnp.dtype(
+            self.cache_cfg.dtype).itemsize * m.num_window_layers)
+        live = (np.asarray(draft_len) >= 0) & (
+            np.asarray(self._active_sent) > 0)
+        pos = self._positions[live].astype(np.int64)
+        steps = 1 + int(n_extra)
+        read = sum(int(np.minimum(pos + k, W).sum()) for k in range(steps))
+        wrote = len(pos) * steps
+        rows = plan.rows if plan else ()
+        read += sum(min(r.start, W) for r in rows)
+        wrote += sum(min(r.rem, W) for r in rows if r.slot >= 0)
+        self.num_window_rows["decode"] += len(pos) * steps
+        self.num_window_rows["chunk"] += len(rows)
+        self.window_ring_bytes_read += read * per_tok
+        self.state_bytes_touched += wrote * per_tok
+
     def _mla_page_fetches(self, plan, rung, draft_len, n_extra) -> int:
         """History pages the latent kernel walks in this launch, from the
         host's mirrors: over the live rows, the pages of a row's history
@@ -2088,6 +2201,16 @@ class Engine:
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
         return pages * self.model_cfg.num_attn_layers
+
+    @property
+    def window_rows_wrapped(self) -> int:
+        """Running rows whose sequence has passed the window: their ring
+        has wrapped (0 for a model without window layers)."""
+        W = self.model_cfg.sliding_window
+        if not self.model_cfg.num_window_layers:
+            return 0
+        return int(np.count_nonzero(
+            self._positions[np.asarray(self._active_sent) > 0] >= W))
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -5228,6 +5351,9 @@ class Engine:
         if self.model_cfg.state_mixer in ("retention", "deltanet"):
             self._note_state_rows(
                 plan if rows else None, draft_len, n_extra)
+        if self.model_cfg.num_window_layers:
+            self._note_window_rows(
+                plan if rows else None, draft_len, n_extra)
         page_fetches = None
         if self.model_cfg.is_mla:
             page_fetches = self._mla_page_fetches(
@@ -5260,6 +5386,10 @@ class Engine:
             **({"deltanet_layers": self.model_cfg.num_deltanet_layers,
                 "attn_layers": self.model_cfg.num_attn_layers}
                if self.model_cfg.num_deltanet_layers else {}),
+            **({"window_layers": self.model_cfg.num_window_layers,
+                "attn_layers": self.model_cfg.num_attn_layers,
+                "window_rows_wrapped": self.window_rows_wrapped}
+               if self.model_cfg.num_window_layers else {}),
             **({"held_experts": self.model_cfg.num_held_experts}
                if self.model_cfg.held_experts else {}),
             **({"mla_page_fetches": page_fetches}

@@ -53,6 +53,16 @@ model's dtype and the float32 matrix ``S [layers, slots, value heads, dk,
 dv]``; its model's attention layers are latent, so the state pool stands
 beside a LATENT page pool whose layer axis counts those layers alone.
 
+A SLIDING-WINDOW layer's query sees its sequence's last ``W`` tokens and no
+more, so its K/V are not pages either: the state pool is then the pair of
+RINGS ``[window layers, slots, W, kv heads, head_dim]`` in the pool's dtype
+(a token at position ``p`` in ring row ``p mod W``; ``ops/window.py``), ``2 *
+W * kv heads * head_dim`` values a layer and slot whatever the sequence's
+length, and the page pool's layer axis counts the FULL-attention layers
+alone: admission, ``max_pages_per_seq`` and ``kv_pages_used`` count their
+pages.  A ring is not cleared when its slot is claimed: what it holds is
+masked by position.
+
 A LATENT (MLA) page pool is ONE array, ``k_pages [L, N, P, R + 128]``: a
 token caches one row a layer, its normed latent in lanes ``0..R`` and its
 rope key behind it, zero-padded to whole 128-lane tiles
@@ -102,9 +112,13 @@ class CacheConfig:
 
     def state_shapes(self, model: ModelConfig) -> tuple:
         """``((shape, dtype), ...)`` of the state pool's arrays, by the kind
-        of the model's recurrent mixer; empty for a model without one."""
+        of the model's recurrent mixer; empty for a model without one.  A
+        window layer's rings are K and V like the pages beside them: in the
+        POOL's dtype."""
+        ring = model.state_mixer == "window"
         return tuple(
-            ((model.num_state_layers, self.state_slots) + tuple(shp), dt)
+            ((model.num_state_layers, self.state_slots) + tuple(shp),
+             self.dtype if ring else dt)
             for shp, dt in model.state_arrays())
 
     def state_shape(self, model: ModelConfig) -> Optional[tuple]:
@@ -233,7 +247,8 @@ class PagedKVCache:
     v_scale: Optional[jax.Array] = None
     # the state pool: ``[conv layers, slots, K - 1, E]`` in the model's
     # dtype for gated short convolutions; the pair ``(S, Z)`` in float32 for
-    # power retention, ``(conv tail, S)`` for the gated delta rule
+    # power retention, ``(conv tail, S)`` for the gated delta rule, ``(K
+    # ring, V ring)`` in the pool's dtype for sliding-window layers
     # (``CacheConfig.state_shapes``); None for a model whose memory is
     # pages alone
     state: Optional[object] = None

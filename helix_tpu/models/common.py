@@ -9,9 +9,10 @@ caps), not subclasses, so one compiled forward function serves them all.
 A model's depth is a sequence of RUNS of one kind of layer
 (``ModelConfig.layer_runs``): a kind is a token mixer (attention, a gated
 short convolution with a fixed per-sequence state, power retention, whose
-per-sequence state is a matrix a kv head, or the gated delta rule, whose
-state is a matrix a value head and a conv tail) times an FFN (dense, or
-routed experts).  A dense decoder is one run; DeepSeek-V2 is
+per-sequence state is a matrix a kv head, the gated delta rule, whose
+state is a matrix a value head and a conv tail, or sliding-window
+attention, whose state is a ring of its last tokens' K/V) times an FFN
+(dense, or routed experts).  A dense decoder is one run; DeepSeek-V2 is
 two (the leading dense layers, then the expert layers); LFM2 interleaves
 three kinds in thirteen, of which the runs that repeat back to back (the
 period "attention, three convolutions" four times, "attention, two
@@ -27,10 +28,14 @@ import dataclasses
 from typing import Any, Optional
 
 
+# the token mixers that keep a fixed per-sequence state in the state pool
+STATE_MIXERS = ("conv", "retention", "deltanet", "window")
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
     key: str        # the run's stack in the parameter tree
-    mixer: str      # "attn" | "conv" | "retention" | "deltanet"
+    mixer: str      # "attn" | "conv" | "retention" | "deltanet" | "window"
     moe: bool       # routed experts (else a dense FFN)
     count: int      # layers a repetition
     first: int      # its first layer among its mixer's layers, repetition 0
@@ -137,6 +142,24 @@ class ModelConfig:
     # ``o <- o * sigmoid(h W_g)``, a gate a head and value channel from the
     # layer's input, before the output projection
     attn_gate: bool = False
+    # --- sliding-window attention layers beside full ones (Laguna) ---
+    # a "window" layer is GQA attention whose query at position i sees keys
+    # i - sliding_window < j <= i (the query's own among them): its whole
+    # cache is the last ``sliding_window`` tokens' K and V, a fixed RING a
+    # sequence in the state pool (a token at position p in ring row p mod
+    # sliding_window): no pages.  On the GQA path ``attn_gate`` is ONE value
+    # a head, ``o_h <- sigmoid(x W_g)_h o_h``, on both kinds of layer
+    sliding_window: int = 0
+    # a window layer's query heads (0: ``num_heads``) and its rope: theta
+    # (0: ``rope_theta``), scaling (the full layers' ``rope_scaling`` does
+    # NOT carry over) and rotary width
+    window_num_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_rope_scaling: Optional[tuple] = None
+    window_rotary_dim: int = 0
+    # dims of a head that rope rotates on the full ("attn") layers, the rest
+    # pass through; 0: all of ``head_dim``
+    rotary_dim: int = 0
     # --- non-architectural serving metadata ---
     name: str = "unnamed"
 
@@ -147,7 +170,7 @@ class ModelConfig:
     @property
     def mixers(self) -> tuple:
         """The token mixer of every layer: ``"attn"``, ``"conv"``,
-        ``"retention"`` or ``"deltanet"``."""
+        ``"retention"``, ``"deltanet"`` or ``"window"``."""
         return self.layer_types or ("attn",) * self.num_layers
 
     @property
@@ -168,6 +191,28 @@ class ModelConfig:
         return self.mixers.count("deltanet")
 
     @property
+    def num_window_layers(self) -> int:
+        return self.mixers.count("window")
+
+    def heads_of(self, mixer: str) -> int:
+        """Query heads of a layer of this kind."""
+        if mixer == "window" and self.window_num_heads:
+            return self.window_num_heads
+        return self.num_heads
+
+    def rope_of(self, mixer: str) -> tuple:
+        """``(rotary width, theta, scaling)`` of a layer of this kind: what
+        ``ops.rope.rope_frequencies`` takes."""
+        if self.is_mla:
+            return self.qk_rope_head_dim, self.rope_theta, self.rope_scaling
+        if mixer == "window":
+            return (self.window_rotary_dim or self.head_dim,
+                    self.window_rope_theta or self.rope_theta,
+                    self.window_rope_scaling)
+        return (self.rotary_dim or self.head_dim, self.rope_theta,
+                self.rope_scaling)
+
+    @property
     def deltanet_channels(self) -> int:
         """Channels of the delta layer's convolution: q | k | v."""
         return (2 * self.linear_key_heads * self.linear_key_dim
@@ -183,10 +228,10 @@ class ModelConfig:
     @property
     def state_mixer(self) -> Optional[str]:
         """The mixer that keeps a fixed per-sequence state, ``"conv"``,
-        ``"retention"`` or ``"deltanet"`` (one kind a model: the state pool
-        has one shape), ``None`` for a model whose memory is pages alone."""
-        kinds = [m for m in ("conv", "retention", "deltanet")
-                 if m in self.mixers]
+        ``"retention"``, ``"deltanet"`` or ``"window"`` (one kind a model:
+        the state pool has one shape), ``None`` for a model whose memory is
+        pages alone."""
+        kinds = [m for m in STATE_MIXERS if m in self.mixers]
         if len(kinds) > 1:
             raise ValueError(
                 f"{self.name}: layers of {kinds} in one model: the state "
@@ -208,8 +253,16 @@ class ModelConfig:
         [kv heads, head_dim, head_dim]``, float32 (running sums over the
         whole context).  A delta-rule layer: the conv's tail, the last
         ``conv_kernel - 1`` rows of its q|k|v channels in the model's dtype,
-        and the matrix ``S [value heads, key dim, value dim]`` float32."""
+        and the matrix ``S [value heads, key dim, value dim]`` float32.  A
+        window layer: the K ring and the V ring ``[sliding_window, kv heads,
+        head_dim]`` in the pool's dtype (``CacheConfig.state_shapes``)."""
         kind = self.state_mixer
+        if kind == "window":
+            if self.sliding_window <= 0:
+                raise ValueError(
+                    f"{self.name}: window layers need sliding_window > 0")
+            ring = (self.sliding_window, self.num_kv_heads, self.head_dim)
+            return ((ring, self.dtype), (ring, self.dtype))
         if kind == "conv":
             return (((self.conv_kernel - 1, self.hidden_size), self.dtype),)
         if kind == "retention":
@@ -271,8 +324,7 @@ class ModelConfig:
             return "layers" if (moe or not self.num_experts) else (
                 "dense_layers")
 
-        groups, seen, i = [], {"attn": 0, "conv": 0, "retention": 0,
-                               "deltanet": 0}, 0
+        groups, seen, i = [], dict.fromkeys(("attn",) + STATE_MIXERS, 0), 0
         while i < len(flat):
             # the period starting here that repeats over the most runs
             p, reps = 1, 1
@@ -441,6 +493,12 @@ class ModelConfig:
                 layer_types=types,
                 conv_kernel=hf["conv_L_cache"],
             )
+        if model_type == "laguna":
+            family = cls._laguna_family(hf)
+            heads = family.pop("num_heads")
+        rope_theta = family.pop("rope_theta", None) or hf.get(
+            "rope_theta", 10000.0)
+        rope_scaling = family.pop("rope_scaling", rope_scaling)
         return cls(
             **family,
             mrope_sections=mrope,
@@ -454,7 +512,7 @@ class ModelConfig:
                 if "kv_lora_rank" in family
                 else hf.get("head_dim") or hidden // heads),
             intermediate_size=hf["intermediate_size"],
-            rope_theta=hf.get("rope_theta", 10000.0),
+            rope_theta=rope_theta,
             rope_scaling=rope_scaling,
             rms_norm_eps=hf.get("rms_norm_eps", hf.get("norm_eps", 1e-5)),
             tie_word_embeddings=hf.get(
@@ -469,6 +527,97 @@ class ModelConfig:
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
         )
+
+    @staticmethod
+    def _laguna_family(hf: dict) -> dict:
+        """The fields a ``model_type: laguna`` config sets: sliding-window
+        layers beside full ones (``layer_types``), each kind with its own
+        query head count (``num_attention_heads_per_layer``) and rope
+        (``rope_parameters``: YaRN over half a head on the full layers,
+        plain rope over the whole head on the sliding ones), a sigmoid gate
+        a head on the attention's output (``gating``), leading dense layers
+        then routed experts behind a sigmoid router renormalised over the
+        chosen and scaled, and one shared expert."""
+        L = hf["num_hidden_layers"]
+        kinds = {"full_attention": "attn", "sliding_attention": "window"}
+        types = tuple(kinds[t] for t in hf["layer_types"])
+        mlp = list(hf.get("mlp_layer_types") or ["sparse"] * L)
+        dense = 0
+        while dense < L and mlp[dense] == "dense":
+            dense += 1
+        if any(t != "sparse" for t in mlp[dense:]):
+            raise ValueError(
+                "laguna: only leading dense layers then expert layers "
+                "(mlp_layer_types) are supported")
+        if hf.get("moe_apply_router_weight_on_input"):
+            raise ValueError(
+                "laguna: moe_apply_router_weight_on_input true is not "
+                "supported: the router's weight multiplies an expert's output")
+        gating = hf.get("gating", False)
+        if gating not in (True, False, None, "per-head"):
+            raise ValueError(
+                f"laguna: gating {gating!r} is not supported: one sigmoid "
+                "gate a head (true or \"per-head\"), or none")
+        per_layer = list(hf.get("num_attention_heads_per_layer")
+                         or [hf["num_attention_heads"]] * L)
+        by_kind = {k: sorted({h for h, t in zip(per_layer, types) if t == k})
+                   for k in ("attn", "window")}
+        if any(len(v) > 1 for v in by_kind.values()):
+            raise ValueError(
+                f"laguna: one query head count a kind of layer is "
+                f"supported, not {by_kind}")
+        head_dim = hf.get("head_dim") or (
+            hf["hidden_size"] // hf["num_attention_heads"])
+        rope = hf.get("rope_parameters") or {}
+
+        def rope_of(kind):
+            r = dict(rope.get(kind) or {})
+            theta = float(r.pop("rope_theta", hf.get("rope_theta", 10000.0)))
+            width = int(head_dim * r.pop(
+                "partial_rotary_factor", hf.get("partial_rotary_factor", 1)))
+            plain = r.get("rope_type", "default") in ("default", None)
+            return theta, (None if plain else tuple(sorted(r.items()))), (
+                0 if width == head_dim else width)
+
+        theta, scaling, width = rope_of("full_attention")
+        w_theta, w_scaling, w_width = rope_of("sliding_attention")
+        fx = hf["moe_intermediate_size"]
+        shared = hf.get("shared_expert_intermediate_size") or 0
+        if shared % fx:
+            raise ValueError(
+                f"laguna: a shared expert of {shared} is not a whole number "
+                f"of routed experts' widths ({fx})")
+        family = dict(
+            num_heads=(by_kind["attn"] or [hf["num_attention_heads"]])[0],
+            window_num_heads=(by_kind["window"] or [0])[0],
+            layer_types=types,
+            sliding_window=hf.get("sliding_window") or 0,
+            rope_theta=theta, rope_scaling=scaling, rotary_dim=width,
+            window_rope_theta=w_theta, window_rope_scaling=w_scaling,
+            window_rotary_dim=w_width,
+            attn_gate=bool(gating),
+            num_experts=hf["num_experts"],
+            moe_intermediate_size=fx,
+            num_shared_experts=shared // fx,
+            first_k_dense=dense,
+            moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(
+                hf.get("moe_routed_scaling_factor", 1.0)),
+            moe_scoring="sigmoid",
+            moe_expert_bias=False,
+            expert_capacity_factor=0.0,
+        )
+        if hf.get("held_experts"):
+            # one expert-parallel rank: ``num_experts`` is what is loaded,
+            # ``published_num_experts`` what the router scores
+            lo, hi = hf["held_experts"]
+            if hi - lo != hf["num_experts"]:
+                raise ValueError(
+                    f"laguna: held_experts {[lo, hi]} are not the "
+                    f"{hf['num_experts']} of num_experts")
+            family.update(held_experts=(lo, hi),
+                          num_experts=hf["published_num_experts"])
+        return family
 
     @classmethod
     def tiny(cls, **overrides) -> "ModelConfig":
@@ -697,8 +846,53 @@ GIGACHAT35_432B = ModelConfig(
     name="ai-sage/GigaChat3.5-432B-A28B",
 )
 
+# Laguna-XS.2 (https://huggingface.co/poolside/Laguna-XS.2/blob/main/
+# config.json): ten periods of one full-attention layer (48 query heads, YaRN
+# over the first 64 of a head's 128 dims, pages) and three sliding-window
+# layers (64 query heads, plain rope, the last 512 tokens' K/V in a ring a
+# slot in the state pool: no pages), 8 kv heads of 128 everywhere, a sigmoid
+# gate a head on the attention's output, one dense layer then 256 routed
+# experts of width 512 top-8 + 1 shared behind a sigmoid router renormalised
+# and scaled by 2.5.  One chip holds it as ONE expert-parallel rank
+# (``held_experts``, set by the profile); what would move or share a ring is
+# refused at engine start (engine.py's table).
+LAGUNA_XS2 = ModelConfig(
+    vocab_size=100352,
+    hidden_size=2048,
+    num_layers=40,
+    num_heads=48,
+    num_kv_heads=8,
+    head_dim=128,
+    intermediate_size=8192,
+    rope_theta=500000.0,
+    rope_scaling=tuple(sorted({
+        "rope_type": "yarn", "factor": 64, "beta_fast": 64, "beta_slow": 1,
+        "original_max_position_embeddings": 4096,
+        "attention_factor": 1.4158883083359672,
+    }.items())),
+    rotary_dim=64,
+    rms_norm_eps=1e-6,
+    max_position_embeddings=262144,
+    num_experts=256,
+    num_experts_per_tok=8,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=512,
+    num_shared_experts=1,
+    first_k_dense=1,
+    moe_renormalize=True,
+    routed_scaling_factor=2.5,
+    moe_scoring="sigmoid",
+    attn_gate=True,
+    layer_types=tuple("attn" if i % 4 == 0 else "window" for i in range(40)),
+    sliding_window=512,
+    window_num_heads=64,
+    window_rope_theta=10000.0,
+    name="poolside/Laguna-XS.2",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
-              DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B)
+              DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B,
+              LAGUNA_XS2)
 }

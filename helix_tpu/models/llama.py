@@ -19,7 +19,10 @@ TPU-first code:
   So is a retention layer's (``retention_fn``): the engine runs a row's
   fresh tokens against its slot's matrix state and advances it.  So is a
   delta-rule layer's (``deltanet_fn``): its convolution's tail and its
-  matrix state, both a slot's.
+  matrix state, both a slot's.  So is a sliding-window layer's
+  (``window_fn``, ``attn_fn``'s signature): the engine attends a row's
+  fresh tokens over its slot's ring of K/V and writes them into it; such a
+  layer may have its own count of query heads and its own rope.
 - Three routers, by ``ModelConfig`` (``models/moe.py::route``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
@@ -232,10 +235,16 @@ def init_params(
             lp["wkv_b"] = w((R, H * (dn + dv)), "wkv_b")
             lp["wo"] = w((H * dv, E), "wo")
         else:
-            lp["wq"] = w((E, H * D), "wq")
+            # a window layer may have its own count of query heads
+            Ht = cfg.heads_of(mixer)
+            lp["wq"] = w((E, Ht * D), "wq")
             lp["wk"] = w((E, KVH * D), "wk")
             lp["wv"] = w((E, KVH * D), "wv")
-            lp["wo"] = w((H * D, E), "wo")
+            lp["wo"] = w((Ht * D, E), "wo")
+            if cfg.attn_gate and mixer in ("attn", "window"):
+                # ONE value a head: logits of std ~0.9 from a normed input,
+                # gates of 0.3-0.7, so a gate left out is seen
+                lp["attn_gate"] = w((E, Ht), "attn_gate")
         if mixer == "retention":
             # a gate a kv head, ``sigmoid(W_g n + b_g)``.  The bias is drawn
             # uniform in [3, 7]: gates of 0.95-0.999, a state that remembers
@@ -278,11 +287,11 @@ def init_params(
             lp["w_gate"] = w((E, F), "w_gate")
             lp["w_up"] = w((E, F), "w_up")
             lp["w_down"] = w((F, E), "w_down")
-        if cfg.attention_bias and mixer == "attn":
-            for nm, width in (("wq", H * D), ("wk", KVH * D),
-                              ("wv", KVH * D)):
+        if cfg.attention_bias and mixer in ("attn", "window"):
+            for nm, width in (("wq", cfg.heads_of(mixer) * D),
+                              ("wk", KVH * D), ("wv", KVH * D)):
                 lp[nm]["bias"] = jnp.zeros((n, width), dtype)
-        if cfg.qk_norm and mixer in ("attn", "retention"):
+        if cfg.qk_norm and mixer in ("attn", "retention", "window"):
             lp["q_norm"] = {"weight": jnp.ones((n, D), dtype)}
             lp["k_norm"] = {"weight": jnp.ones((n, D), dtype)}
         return lp
@@ -337,9 +346,12 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             "attn_norm": {"weight": ("layers", None)},
             "mlp_norm": {"weight": ("layers", None)},
         }
-        if mixer in ("attn", "retention"):
+        if mixer in ("attn", "retention", "window"):
             lax_["wq"] = {"weight": ("layers", "embed", "heads")}
             lax_["wo"] = {"weight": ("layers", "heads", "embed")}
+        if (cfg.attn_gate and not cfg.is_mla
+                and mixer in ("attn", "window")):
+            lax_["attn_gate"] = {"weight": ("layers", "embed", "heads")}
         if mixer == "retention":
             # (a mesh is refused for it: the state pool is one device's)
             lax_["g_proj"] = {"weight": ("layers", "embed", None)}
@@ -395,11 +407,11 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
                 lax_["shared"] = mlp
         else:
             lax_.update(mlp)
-        if cfg.attention_bias and mixer == "attn":
+        if cfg.attention_bias and mixer in ("attn", "window"):
             lax_["wq"]["bias"] = ("layers", "heads")
             lax_["wk"]["bias"] = ("layers", "kv_heads")
             lax_["wv"]["bias"] = ("layers", "kv_heads")
-        if cfg.qk_norm and mixer in ("attn", "retention"):
+        if cfg.qk_norm and mixer in ("attn", "retention", "window"):
             lax_["q_norm"] = {"weight": ("layers", None)}
             lax_["k_norm"] = {"weight": ("layers", None)}
         return lax_
@@ -683,6 +695,18 @@ def _deltanet_mixer(h, p, layer_cache, cfg, deltanet_fn, post=None):
     return h, new_cache
 
 
+def whole_sequence_window_fn(q, k, v, layer_cache, positions, *, window):
+    """The window layer of a forward pass with no cache: every row of the
+    batch is one sequence from its start, so the definition's masks run as
+    they are (causal, and key ``j`` hidden from query ``i`` where ``i - j >=
+    window``)."""
+    from helix_tpu.ops.attention import attention
+
+    return attention(
+        q, k, v, causal=True, q_positions=positions, kv_positions=positions,
+        window=window), None
+
+
 def _layer(
     h,
     layer_params: Params,
@@ -699,8 +723,13 @@ def _layer(
     retention_fn=None,
     moe_decode_rows: int = 0,
     deltanet_fn=None,
+    mixer: str = "attn",
+    window_fn=None,
 ):
-    """One decoder block. h: [B, S, E].
+    """One decoder block. h: [B, S, E].  ``mixer``: the kind of a GQA layer,
+    ``"attn"`` or ``"window"`` (its query heads, its rope through
+    ``inv_freq`` and its look-back differ; the other mixers are told by
+    their weights).
 
     When ``attn_fn`` returns ``(out, new_cache)`` (the carry-cache decode
     protocol — the paged pool threads through the layer scan and the
@@ -747,7 +776,15 @@ def _layer(
             h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
             post_norm("attn_post_norm"))
     else:
-        with jax.named_scope("attn.qkv"):
+        # the scopes are the kind's: ``attn.*`` on a full layer, ``window.*``
+        # on a sliding one
+        sc = "window" if mixer == "window" else "attn"
+        H = cfg.heads_of(mixer)
+        # YaRN's stated ``attention_factor`` multiplies cos and sin: it acts
+        # on the rotated dims only
+        rot = float(dict(cfg.rope_of(mixer)[2] or ()).get(
+            "attention_factor", 1.0))
+        with jax.named_scope(f"{sc}.qkv"):
             x = rms_norm(
                 h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
                 cfg.norm_offset,
@@ -758,18 +795,32 @@ def _layer(
             if cfg.qk_norm:
                 q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
                 k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
-        with jax.named_scope("attn.kernel"):
-            res = attn_fn(q, k, v, layer_cache, positions)
+            q = apply_rope(q, positions, inv_freq, rot)
+            k = apply_rope(k, positions, inv_freq, rot)
+        with jax.named_scope(f"{sc}.kernel"):
+            if mixer == "window":
+                res = (window_fn or functools.partial(
+                    whole_sequence_window_fn, window=cfg.sliding_window))(
+                        q, k, v, layer_cache, positions)
+            else:
+                res = attn_fn(q, k, v, layer_cache, positions)
         new_cache = None
         if isinstance(res, tuple):
             attn_out, new_cache = res
         else:
             attn_out = res
-        with jax.named_scope("attn.out"):
+        if "attn_gate" in p:
+            # one sigmoid gate a head, from the branch's normed input
+            with jax.named_scope(f"{sc}.gate"):
+                attn_out = (attn_out.astype(jnp.float32) * jax.nn.sigmoid(
+                    _dense(x, p["attn_gate"]).astype(jnp.float32)
+                )[..., None]).astype(h.dtype)
+        with jax.named_scope(f"{sc}.out"):
             h = h + _dense(
                 attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
+        if mixer == "window":
+            # its K/V live in its slot's ring, not in the pass's pages
+            k = v = None
 
     # --- mlp: the layer's kind is what its weights are ---
     x = rms_norm(h, p["mlp_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -905,6 +956,9 @@ def forward(
                           # leaves them dropless (``models/moe.py``)
     deltanet_fn=None,     # a delta-rule layer's convolution and rule over
                           # its sequence (``_deltanet_mixer``); None: the same
+    window_fn=None,       # a window layer's attention over its sequence's
+                          # last ``sliding_window`` tokens, ``attn_fn``'s
+                          # signature; None: the same
 ):
     """Run the decoder.
 
@@ -922,11 +976,12 @@ def forward(
     """
     from helix_tpu.ops.quant import embed_lookup
 
-    inv_freq = jnp.asarray(
-        rope_frequencies(
-            cfg.qk_rope_head_dim if cfg.is_mla else cfg.head_dim,
-            cfg.rope_theta, cfg.rope_scaling)
-    )
+    # one table a KIND of layer: a window layer may rotate another width at
+    # another base than a full one
+    inv_freqs = {
+        m: jnp.asarray(rope_frequencies(*cfg.rope_of(m)))
+        for m in ("attn",) + (("window",) if cfg.num_window_layers else ())
+    }
     h = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
 
     def run_layers(h, carry, stack, run, rep):
@@ -947,14 +1002,16 @@ def forward(
 
         def block(h, layer_params, layer_cache, i):
             return _layer(
-                h, layer_params, layer_cache, cfg, positions, inv_freq,
+                h, layer_params, layer_cache, cfg, positions,
+                inv_freqs["window" if run.mixer == "window" else "attn"],
                 attn_fn, moe_token_mask=moe_token_mask,
                 adapter_ids=adapter_ids,
                 stacked_experts=None if whole is None else (
                     whole, rep * run.count + i),
                 moe_backend=moe_backend, conv_fn=conv_fn,
                 retention_fn=retention_fn, moe_decode_rows=moe_decode_rows,
-                deltanet_fn=deltanet_fn,
+                deltanet_fn=deltanet_fn, mixer=run.mixer,
+                window_fn=window_fn,
             )
 
         # the cache's layer index counts the layers of the run's mixer:

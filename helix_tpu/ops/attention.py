@@ -50,12 +50,15 @@ def mha_reference(
     kv_segment_ids=None,
     logits_soft_cap: Optional[float] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ):
     """Numerics oracle. q: [B, Sq, H, D]; k/v: [B, Skv, KVH, D].
 
     ``q_positions``/``kv_positions`` make causal masking correct for ragged
     prefill where query block i sits at an arbitrary absolute position.
     ``segment_ids`` mask cross-sequence attention in packed batches.
+    ``window``: a query at position i sees keys ``i - window < j <= i`` (the
+    query's own among them); it needs ``causal``.
     """
     B, Sq, H, D = q.shape
     Skv = k.shape[1]
@@ -80,6 +83,11 @@ def mha_reference(
             else jnp.broadcast_to(jnp.arange(Skv)[None], (B, Skv))
         )
         mask = mask & (qp[:, None, :, None] >= kp[:, None, None, :])
+        if window is not None:
+            mask = mask & (
+                qp[:, None, :, None] - kp[:, None, None, :] < window)
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     if q_segment_ids is not None and kv_segment_ids is not None:
         mask = mask & (
             q_segment_ids[:, None, :, None] == kv_segment_ids[:, None, None, :]
@@ -114,6 +122,7 @@ def _flash_kernel(
     block_q: int,
     block_kv: int,
     soft_cap: Optional[float],
+    window: Optional[int],
 ):
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -135,6 +144,9 @@ def _flash_kernel(
         # default arange positions it degenerates to the classic
         # lower-triangle grid walk (~2x fewer MXU FLOPs at long S).
         run = jnp.max(qp) >= jnp.min(kp)
+        if window is not None:
+            # ... and so does one wholly behind every query's window
+            run = run & (jnp.min(qp) - jnp.max(kp) < window)
     else:
         run = True
 
@@ -156,6 +168,8 @@ def _flash_kernel(
         mask = jnp.ones((block_q, block_kv), dtype=bool)
         if causal:
             mask = mask & (qp[:, None] >= kp[None, :])
+            if window is not None:
+                mask = mask & (qp[:, None] - kp[None, :] < window)
         if use_segments:
             mask = mask & (
                 qseg_ref[0, 0, :][:, None] == kseg_ref[0, 0, :][None, :]
@@ -195,6 +209,7 @@ def _flash_kernel(
         "block_q",
         "block_kv",
         "interpret",
+        "window",
     ),
 )
 def flash_attention(
@@ -212,8 +227,12 @@ def flash_attention(
     block_q: int = 256,
     block_kv: int = 256,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
     """Flash attention for prefill. q: [B, Sq, H, D]; k/v: [B, Skv, KVH, D].
+    ``window``: sliding-window attention beside the causal and segment
+    masks (``mha_reference``); kv blocks wholly behind a query block's
+    window are skipped like those wholly ahead of it.
 
     GQA is handled in the grid index map (each q head reads its kv group's
     block — no materialised ``repeat``).  Sequences shorter than the block
@@ -224,6 +243,8 @@ def flash_attention(
     Skv, KVH = k.shape[1], k.shape[2]
     group = H // KVH
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     block_q = min(block_q, Sq)
     block_kv = min(block_kv, Skv)
     if Sq % block_q or Skv % block_kv:
@@ -261,6 +282,7 @@ def flash_attention(
         block_q=block_q,
         block_kv=block_kv,
         soft_cap=logits_soft_cap,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,

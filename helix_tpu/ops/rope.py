@@ -78,10 +78,15 @@ def yarn_attention_scales(scaling) -> tuple:
     """``(cos/sin scale, softmax scale factor)`` of a YaRN ``rope_scaling``:
     the rotation is scaled by ``mscale(f, mscale) / mscale(f,
     mscale_all_dim)`` and the attention scores by ``mscale(f,
-    mscale_all_dim) ** 2`` (DeepSeek-V2).  ``(1, 1)`` without YaRN."""
+    mscale_all_dim) ** 2`` (DeepSeek-V2).  A config that states the
+    rotation's scale itself (``attention_factor``, Hugging Face's key) gets
+    that on cos and sin and nothing on the scores.  ``(1, 1)`` without
+    YaRN."""
     scaling = dict(scaling or ())
     if scaling.get("rope_type", scaling.get("type")) != "yarn":
         return 1.0, 1.0
+    if scaling.get("attention_factor"):
+        return float(scaling["attention_factor"]), 1.0
     f = scaling["factor"]
     all_dim = yarn_mscale(f, scaling.get("mscale_all_dim", 0))
     rot = yarn_mscale(f, scaling.get("mscale", 1)) / all_dim
@@ -106,16 +111,28 @@ def apply_rope_interleaved(x, positions, inv_freq, scale: float = 1.0):
     return out.astype(x.dtype)
 
 
-def apply_rope(x, positions, inv_freq):
+def apply_rope(x, positions, inv_freq, scale: float = 1.0):
     """Rotate q or k.
 
     x:         [..., seq, heads, head_dim]
     positions: broadcastable to [..., seq] (int32)
-    inv_freq:  [head_dim // 2]
+    inv_freq:  [rotary width // 2]: a table narrower than the head rotates
+               the head's FIRST ``2 * len(inv_freq)`` dims (paired across
+               that width's halves) and passes the rest through
+    scale:     on cos and sin (YaRN's attention factor): it acts on the
+               rotated dims only
     """
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., seq, hd/2]
-    cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, hd/2]
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., seq, w/2]
+    cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, w/2]
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    width = 2 * inv_freq.shape[-1]
+    xf = x.astype(jnp.float32)
+    rest = []
+    if width < x.shape[-1]:
+        xf, rest = xf[..., :width], [xf[..., width:]]
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin] + rest, axis=-1)
     return out.astype(x.dtype)

@@ -1614,6 +1614,10 @@ class EngineLoop:
             "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),
             "deltanet_layers": getattr(
                 eng.model_cfg, "num_deltanet_layers", 0),
+            # sliding-window layers (a ring of K/V a slot), and the live
+            # rows whose sequence has passed the window
+            "window_layers": getattr(eng.model_cfg, "num_window_layers", 0),
+            "window_rows_wrapped": getattr(eng, "window_rows_wrapped", 0),
             # experts of the routed set whose weights are on this chip (0:
             # dense, or every expert is here)
             "held_experts": (

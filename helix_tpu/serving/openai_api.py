@@ -458,9 +458,18 @@ class OpenAIServer:
                     "helix_mla_page_fetches_total",
                     getattr(eng, "num_mla_page_fetches", 0), lbl,
                 )
+            if getattr(eng.model_cfg, "num_window_layers", 0):
+                # rings of K/V a slot: the bytes of live ring rows the
+                # window calls read (rows x min(length, W) x a token's K and
+                # V x window layers: what a roofline by hand divides by)
+                c.counter(
+                    "helix_window_ring_bytes_read_total",
+                    getattr(eng, "window_ring_bytes_read", 0), lbl,
+                )
             for mixer, rows_series in (
                     ("retention", "helix_retention_rows_total"),
-                    ("deltanet", "helix_deltanet_rows_total")):
+                    ("deltanet", "helix_deltanet_rows_total"),
+                    ("window", "helix_window_rows_total")):
                 if not getattr(eng.model_cfg, f"num_{mixer}_layers", 0):
                     continue
                 # a matrix state a slot: the pool's bytes, the rows of it

@@ -689,3 +689,158 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
+
+
+# ---- Laguna-XS.2: window layers beside full ones (ISSUE 41) -------------------
+
+LAGUNA_SLOTS, LAGUNA_WINDOW = 48, 512
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk_with_history",
+                                   "rows_on_one_axis"])
+def test_window_kernel_compiles_at_the_published_geometry(one_chip, shape):
+    """The window call at 64 query / 8 kv heads of 128 over rings of 512 in
+    a pool of 30 layers and 48 slots: 48 one-token rows, a 512-token chunk
+    row (8-token blocks that share the ring their first block fetched), and
+    eight rows on one axis."""
+    from helix_tpu.ops.window import window_attention
+
+    H, KVH, D, L = 64, 8, 128, 30
+    T, R, mq = {"decode": (LAGUNA_SLOTS, LAGUNA_SLOTS, 1),
+                "chunk_with_history": (PREFILL_LEN, 1, PREFILL_LEN),
+                "rows_on_one_axis": (PREFILL_LEN + 16, 8, PREFILL_LEN)}[shape]
+
+    def S(shp, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    ring = S((L, LAGUNA_SLOTS, LAGUNA_WINDOW, KVH, D), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: window_attention(
+        *a, backend="pallas", max_q_len=mq)).lower(
+        S((T, H, D), jnp.bfloat16), S((T, KVH, D), jnp.bfloat16),
+        S((T, KVH, D), jnp.bfloat16), ring, ring, S(()), S((R,)), S((R,)),
+        S((R,)), S((R,))).compile()
+    assert "window_attention_tpu" in compiled.as_text()
+    # the rings are read where they lie: no copy of a pool (1.5 GB each)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+
+
+@pytest.mark.parametrize("shape", sorted(MAX_Q_LEN))
+def test_ragged_kernel_compiles_at_a_query_group_of_6(one_chip, shape):
+    """Laguna's full layers: 48 query heads over 8 kv heads of 128, the
+    group of 6 padded to a sublane tile of 8 in both block shapes."""
+    GEOMETRY["laguna-full"] = (48, 8, 128, 10)
+    try:
+        compiled = _compile_ragged(
+            _ragged_args("laguna-full", "bf16", shape, lambda spec: one_chip),
+            max_q_len=MAX_Q_LEN[shape])
+    finally:
+        del GEOMETRY["laguna-full"]
+    assert "ragged_paged_attention_tpu" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [384, 4480], ids=["decode", "chunk"])
+def test_grouped_product_compiles_at_2048_by_512(one_chip, rows):
+    """32 held experts of 2048 x 512, int8, the layer picked from a stack of
+    27: a decode step's 48 x 8 assignments (1.5 rows a held expert of the
+    256 routed) and a chunk program's (512 + 48) x 8."""
+    from helix_tpu.ops.grouped_matmul import (
+        grouped_matmul_tpu, row_tile, visit_plan)
+
+    n, X, E, F = 27, 32, 2048, 512
+    tm = row_tile(rows * X // 256, X)
+
+    def S(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    def op(x, wg, sg, wu, su, wd, sd, sizes, layer):
+        plan = visit_plan(sizes, rows, tm)
+        h = grouped_matmul_tpu(
+            x, wg, plan, layer, scale=sg, w2=wu, scale2=su,
+            act=jax.nn.silu, tm=tm, out_dtype=x.dtype)
+        return grouped_matmul_tpu(h, wd, plan, layer, scale=sd, tm=tm)
+
+    up = (S((n, X, E, F), jnp.int8), S((n, X, 1, F), jnp.float32))
+    down = (S((n, X, F, E), jnp.int8), S((n, X, 1, E), jnp.float32))
+    compiled = jax.jit(op).lower(
+        S((rows, E), jnp.bfloat16), *up, *up, *down, S((X,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert compiled.as_text().count("grouped_matmul_tpu") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history",
+                                     "packed_wave"])
+def test_window_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of Laguna-XS.2 cut to ONE period of four layers
+    (full + dense, three sliding + held experts; int8 weights, 48 slots) for
+    the described chip: the window kernel over the rings in the carry, the
+    dense ragged kernel at a group of 6 over a page pool of ONE layer, the
+    windowed flash attention of a cold wave, the grouped product over 32 of
+    256 experts, and both rings updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import LAGUNA_XS2
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        LAGUNA_XS2, num_layers=4, held_experts=(0, 32),
+        layer_types=LAGUNA_XS2.layer_types[:4])
+    B, max_pages, pages = LAGUNA_SLOTS, 160, 2048
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == vs == (1, 16, 8, 128)
+    assert cc.state_shapes(cfg) == (
+        ((3, B, LAGUNA_WINDOW, 8, 128), "bfloat16"),) * 2
+    cache = PagedKVCache(
+        k_pages=S((1, pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((1, pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows, hist = {"decode": (0, 0, False),
+                          "chunk_with_history": (512, 1, True),
+                          "packed_wave": (512, 32, False)}[program]
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, hist, rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("window_attention_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention_tpu"):
+        assert kernel in text, kernel
+    # the rings are updated in place: aliased whole, and no temporary of the
+    # size of ONE of the two
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
