@@ -769,19 +769,42 @@ def phase_kernel_deltanet(spec, seed, rehearse):
     with jax.default_matmul_precision("highest"):
         want, S_end = jax.jit(D.delta_recurrence)(
             *args, jnp.zeros((H, d, d)))
-    pool = jnp.zeros((L, 2, H, d, d))
-    rows = jax.jit(D.delta_rows)
-    one = jnp.ones((1,), jnp.int32)
-    first, pool = rows(*(a[:T] for a in args), 0 * one, T * one, 0 * one,
-                       one, pool, 1)
-    second, pool = rows(*(a[T:] for a in args), 0 * one, T * one, T * one,
-                        one, pool, 1)
+    pool = jnp.zeros((L, 4, H, d, d))
+    rows = jax.jit(D.delta_rows, donate_argnums=(9,))
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)
+    first, pool = rows(*(a[:T] for a in args), i32(0), i32(T), i32(0),
+                       i32(1), pool, 1)
+    second, pool = rows(*(a[T:] for a in args), i32(0), i32(T), i32(T),
+                        i32(1), pool, 1)
     errs = {"from_zeros": rel(first, want[:T]),
             "from_a_state": rel(second, want[T:]),
             "state_after": rel(pool[1, 1], S_end)}
     good = all(e <= TOL_DELTANET_F32 for e in errs.values())
+
+    def ms_a_layer(plan, tokens, pool, reps=20):
+        """Wall time of one layer's call, the pool donated from call to
+        call: on the chip the device's time, here the CPU's (rehearsal)."""
+        held = tuple(a[:tokens] for a in args) + plan
+        for _ in range(2):
+            o, pool = rows(*held, pool, 1)
+        jax.block_until_ready(o)
+        t = time.perf_counter()
+        for _ in range(reps):
+            o, pool = rows(*held, pool, 1)
+        jax.block_until_ready(o)
+        return round((time.perf_counter() - t) / reps * 1e3, 4), pool
+
+    # one row of T tokens that continues from its slot's state, and four
+    # rows of unlike lengths at unaligned starts on an axis of 2 T tokens
+    row_ms, pool = ms_a_layer((i32(0), i32(T), i32(T), i32(1)), T, pool)
+    q4 = [T // 2 + 44, T // 4 + 9, T - 100, T // 4 + 30]
+    t4 = [0, q4[0], q4[0] + q4[1], 2 * T - q4[3]]
+    wave_ms, pool = ms_a_layer(
+        (i32(*t4), i32(*q4), i32(0, 0, 7, 0), i32(0, 1, 2, 3)), 2 * T, pool)
     say(phase="kernel", op="delta_rows (chunked form)", tokens=T,
-        geometry=[H, d, d], **errs, tol=TOL_DELTANET_F32, ok=bool(good))
+        geometry=[H, d, d], **errs, tol=TOL_DELTANET_F32,
+        row_ms_a_layer=row_ms, wave_of_four_rows_ms_a_layer=wave_ms,
+        wave_tokens=sum(q4), timed_on=jax.default_backend(), ok=bool(good))
     if not (ok and good):
         fail("the delta-rule kernel or the chunked form disagrees with the "
              "recurrence")

@@ -968,7 +968,9 @@ def _deltanet_rows_fn(cfg, t0, qlen, hist, slots, backend, decode: bool):
     ``decode``: the segment's rows are one token each and row ``b`` is slot
     ``b`` (the rule applied once: on a TPU one pass of the decode kernel
     over the live slots).  Else the rows are runs of fresh tokens on one
-    flat axis (the chunked form, a row at a time, 64 tokens at a time).
+    flat axis (the chunked form, 64 tokens at a time: what does not read
+    the state for all the rows' chunks at once, then the chunks in order,
+    on a TPU in the chunk kernel).
 
     Called ``(x W_qkv, g, beta, taps, carry)``, the carry ``((page carry,
     kacc, vacc, (conv pool, S pool)), delta layer index)``."""
@@ -991,7 +993,7 @@ def _deltanet_rows_fn(cfg, t0, qlen, hist, slots, backend, decode: bool):
                 flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
                 o, s_pool = delta_rows(
                     flat(q), flat(k), flat(v), flat(g), flat(beta), t0,
-                    qlen, hist, slots, s_pool, lc)
+                    qlen, hist, slots, s_pool, lc, backend=backend)
         return o.reshape((Bx, Sx) + o.shape[1:]), (
             caches, kacc, vacc, (c_pool, s_pool))
 
@@ -1811,6 +1813,8 @@ class Engine:
         self.state_bytes_touched = 0
         # history pages the latent kernel walked (``_mla_page_fetches``)
         self.num_mla_page_fetches = 0
+        # 64-token chunks the chunked delta rule ran (``_deltanet_chunks``)
+        self.num_deltanet_chunks = 0
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -2201,6 +2205,19 @@ class Engine:
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
         return pages * self.model_cfg.num_attn_layers
+
+    def _deltanet_chunks(self, plan) -> int:
+        """The 64-token chunks the chunked delta rule runs in this launch,
+        from the host's mirrors: a prefill row's ``ceil(rem / 64)`` in every
+        delta layer (``ops/deltanet.py::chunk_table``'s live entries).
+        Device time under ``deltanet.mix`` in the programs that carry a
+        chunk, over this count, is the cost of a chunk (PERF.md section
+        5)."""
+        from helix_tpu.ops.deltanet import CHUNK
+
+        rows = plan.rows if plan is not None else ()
+        return sum(-(-r.rem // CHUNK) for r in rows) * (
+            self.model_cfg.num_deltanet_layers)
 
     @property
     def window_rows_wrapped(self) -> int:
@@ -5359,6 +5376,10 @@ class Engine:
             page_fetches = self._mla_page_fetches(
                 plan, rung, draft_len, n_extra)
             self.num_mla_page_fetches += page_fetches
+        delta_chunks = None
+        if self.model_cfg.num_deltanet_layers:
+            delta_chunks = self._deltanet_chunks(plan if rows else None)
+            self.num_deltanet_chunks += delta_chunks
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
         joint_pass = int(rows > 0)
@@ -5394,6 +5415,8 @@ class Engine:
                if self.model_cfg.held_experts else {}),
             **({"mla_page_fetches": page_fetches}
                if page_fetches is not None else {}),
+            **({"deltanet_chunks": delta_chunks}
+               if delta_chunks is not None else {}),
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

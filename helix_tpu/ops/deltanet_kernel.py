@@ -34,9 +34,8 @@ from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
 HEAD_BLOCK = 16     # value heads a grid step: 1 MB of state at width 128
 
 
-def head_block(heads: int) -> int:
-    return next(b for b in range(min(HEAD_BLOCK, heads), 0, -1)
-                if heads % b == 0)
+def head_block(heads: int, most: int = HEAD_BLOCK) -> int:
+    return next(b for b in range(min(most, heads), 0, -1) if heads % b == 0)
 
 
 def check_deltanet_geometry(key_heads: int, value_heads: int, dk: int,
@@ -156,3 +155,146 @@ def deltanet_decode_tpu(
         q, k, v, across(decay), across(beta), s_pool,
     )
     return o, s_pool
+
+
+CHUNK_HEAD_BLOCK = 8    # value heads a grid step of the chunk kernel: 1.4 MB in
+# what an entry of the chunk kernel's table is to its row and its state block
+FIRST, WRITE, FROM_STATE, OPEN = 1, 2, 4, 8
+
+
+def _chunk_kernel(layer_ref, slot_ref, flag_ref, count_ref, sv_ref, sk_ref,
+                  qg_ref, qk_ref, kd_ref, el_ref, s_ref, c_ref, o_ref, so_ref,
+                  co_ref, s_scr, *, hb: int):
+    del layer_ref, slot_ref                  # read by the index maps
+    e = pl.program_id(1)
+    flags = flag_ref[e]
+    live = e < count_ref[0]
+    on = lambda flag: flags & flag != 0
+    dot = functools.partial(
+        jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    nn = (((1,), (0,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
+
+    @pl.when(e == 0)
+    def _the_row_under_way():
+        s_scr[...] = c_ref[...]
+
+    @pl.when(on(OPEN))
+    def _a_state_block_opens():
+        # written back whatever follows: unchanged, unless its row ends here
+        so_ref[...] = s_ref[...]
+
+    @pl.when(jnp.logical_and(on(FIRST), on(FROM_STATE)))
+    def _a_row_continues_from_its_slot():
+        s_scr[...] = s_ref[...]
+
+    @pl.when(jnp.logical_and(on(FIRST), jnp.logical_not(on(FROM_STATE))))
+    def _a_row_starts():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    @pl.when(live)
+    def _a_chunk():
+        for h in range(hb):                                  # static unroll
+            s = s_scr[h]
+            new = sv_ref[h] - dot(sk_ref[h], s, nn)          # the writes
+            o_ref[h] = dot(qg_ref[h], s, nn) + dot(qk_ref[h], new, nn)
+            s_scr[h] = el_ref[pl.ds(h, 1), :] * s + dot(kd_ref[h], new, tn)
+
+    @pl.when(on(WRITE))
+    def _a_row_ends():
+        so_ref[...] = s_scr[...]
+
+    @pl.when(e == pl.num_programs(1) - 1)
+    def _hand_on():
+        co_ref[...] = s_scr[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def deltanet_chunk_tpu(
+    sol_v,      # [n, H, C, dv] f32   } ops/deltanet.py::state_free of the
+    sol_k,      # [n, H, C, dk] f32   } chunks of ``table``, in its order
+    qg,         # [n, H, C, dk] f32
+    qk,         # [n, H, C, C] f32
+    kd,         # [n, H, C, dk] f32
+    elast,      # [n, H] f32
+    s_pool,     # [L, N, H, dk, dv] f32
+    s_row,      # [H, dk, dv] f32: the state of the row under way at entry 0
+    layer,      # which of the L layers (a traced index)
+    table,      # n entries of ops/deltanet.py::chunk_table
+    count,      # how many of them are some row's: they come first
+    *,
+    interpret: bool = False,
+):
+    """The half of the chunked delta rule that reads the state: the table's
+    entries in order, three products a head against ``S``, which stays in
+    VMEM from a row's first chunk to its last: read from ``s_pool[layer,
+    slot]`` at the first (zeros for a row that starts there), written at the
+    last, in place; a row whose first chunk lay before this table continues
+    from ``s_row``, and the state of a row whose last lies behind it is
+    handed on.  Returns ``(o [n, H, C, dv] f32, s_pool, s_row)``; entries
+    past the rows' ends hold whatever was there.
+
+    Grid ``(head blocks, entries)``, sequential.  An entry of a row with no
+    slot, and entries past the rows' ends, name the state block of the last
+    row before them that has one (nothing is fetched or written back for
+    them: a row without a slot starts from zeros and its state goes
+    nowhere).  A block is copied through as it opens, so one whose row does
+    not end here, or that no row owns, goes back as it came."""
+    n, H, C, dv = sol_v.shape
+    dk = sol_k.shape[-1]
+    assert s_pool.shape[2:] == (H, dk, dv) == s_row.shape
+    if not interpret:
+        check_deltanet_geometry(H, H, dk, dv)
+    hb = head_block(H, CHUNK_HEAD_BLOCK)
+    at = jnp.arange(n, dtype=jnp.int32)
+    count = jnp.asarray(count, jnp.int32)
+    slotted = jnp.sum(table["has_slot"]).astype(jnp.int32)
+    named = table["slot"][jnp.minimum(at, jnp.maximum(slotted - 1, 0))]
+    flags = (FIRST * table["first"] + WRITE * table["write"]
+             + FROM_STATE * table["from_state"]
+             + OPEN * ((at == 0) | (table["first"] & table["has_slot"]))
+             ).astype(jnp.int32)
+
+    def entry(j, e, layer, slot, flags, count):
+        return jnp.minimum(e, jnp.maximum(count[0] - 1, 0)), j
+
+    def mat_map(j, e, *pre):
+        return entry(j, e, *pre) + (0, 0)
+
+    def vec_map(j, e, *pre):
+        return entry(j, e, *pre) + (0,)
+
+    def state_map(j, e, layer, slot, flags, count):
+        return layer[0], slot[e], j, 0, 0
+
+    mat = lambda w: pl.BlockSpec((None, hb, C, w), mat_map)
+    state = pl.BlockSpec((None, None, hb, dk, dv), state_map)
+    row = pl.BlockSpec((hb, dk, dv), lambda j, e, *pre: (j, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(H // hb, n),
+        in_specs=[mat(dv), mat(dk), mat(dk), mat(C), mat(dk),
+                  pl.BlockSpec((None, hb, dv), vec_map), state, row],
+        out_specs=[mat(dv), state, row],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, hb=hb),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n, H, C, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype),
+                   jax.ShapeDtypeStruct(s_row.shape, s_row.dtype)],
+        # operand 10 (after the four prefetched scalars): the pool
+        input_output_aliases={10: 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        name="deltanet_chunk_tpu",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), named, flags,
+        count.reshape(1),
+        sol_v, sol_k, qg, qk, kd,
+        jnp.broadcast_to(elast[..., None], (n, H, dv)), s_pool, s_row,
+    )

@@ -458,6 +458,15 @@ class OpenAIServer:
                     "helix_mla_page_fetches_total",
                     getattr(eng, "num_mla_page_fetches", 0), lbl,
                 )
+            if getattr(eng.model_cfg, "num_deltanet_layers", 0):
+                # the chunked delta rule: the 64-token chunks it ran (a
+                # prefill row's ceil(tokens / 64) x delta layers, from the
+                # host's mirrors); device time under deltanet.mix in the
+                # programs that carry a chunk, over this, is a chunk's cost
+                c.counter(
+                    "helix_deltanet_chunks_total",
+                    getattr(eng, "num_deltanet_chunks", 0), lbl,
+                )
             if getattr(eng.model_cfg, "num_window_layers", 0):
                 # rings of K/V a slot: the bytes of live ring rows the
                 # window calls read (rows x min(length, W) x a token's K and

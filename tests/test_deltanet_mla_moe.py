@@ -35,7 +35,9 @@ from helix_tpu.models.llama import (  # noqa: E402
 )
 from helix_tpu.models.moe import moe_ffn  # noqa: E402
 from helix_tpu.ops import deltanet as D  # noqa: E402
-from helix_tpu.ops.deltanet_kernel import check_deltanet_geometry  # noqa: E402
+from helix_tpu.ops.deltanet_kernel import (  # noqa: E402
+    check_deltanet_geometry, deltanet_chunk_tpu,
+)
 from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry  # noqa: E402
 
 HF = dict(
@@ -140,13 +142,13 @@ def _draw(n, H=4, d=16, seed=0):
 # ---- the delta rule: recurrence, chunked form, kernel ----------------------
 
 
-@pytest.mark.parametrize("T", [1, 63, 64, 65, 150])
+@pytest.mark.parametrize("T", [1, 63, 64, 65, 150, 512, 1400])
 @pytest.mark.parametrize("from_state", [False, True])
 def test_chunked_form_is_the_recurrence(T, from_state):
-    """Across a chunk boundary (65, 150), at it (64), inside it (1, 63), from
-    zeros and from a state that is not zero: outputs and the state left.
-    float32 at the highest precision both sides: 1e-5 of outputs of size 0.5
-    (measured 2e-7)."""
+    """Across a chunk boundary (65, 150, 1400), at it (64, 512), inside it
+    (1, 63), from zeros and from a state that is not zero: outputs and the
+    state left.  float32 at the highest precision both sides: 1e-5 of
+    outputs of size 0.5 (measured 2e-6 at 1,400 tokens)."""
     args = _draw(T, seed=T)
     S0 = jnp.zeros((4, 16, 16))
     if from_state:
@@ -176,6 +178,123 @@ def test_a_bf16_state_fails_the_chunked_forms_tolerance():
     assert float(jnp.abs(got - want).max()) > 1e-4
 
 
+@pytest.mark.parametrize("C", [1, 2, 8, 64])
+def test_the_block_recursion_inverts_a_unit_lower_triangle(C):
+    """``unit_lower_inverse`` against ``numpy.linalg.inv`` over a batch, and
+    on the system that breaks a Neumann series in float32: every entry under
+    the diagonal 1 (identical keys written at full strength), whose inverse
+    is the diagonal's ones over a subdiagonal of -1."""
+    M = np.tril(np.random.RandomState(C).randn(3, 5, C, C), -1).astype(
+        np.float32)
+    M[0, 0] = np.tril(np.ones((C, C), np.float32), -1)
+    got = np.asarray(D.unit_lower_inverse(jnp.asarray(M)))
+    want = np.linalg.inv(M.astype(np.float64) + np.eye(C))
+    assert np.abs(got - want).max() < 1e-4 * max(1.0, np.abs(want).max())
+    assert np.array_equal(got[0, 0], np.eye(C) - np.eye(C, k=-1))
+
+
+def test_chunk_table_by_hand():
+    """Rows of 70, 0, 130 and 1 tokens, the third with no slot: the slotted
+    rows' chunks first and in order, then the other's, then inert entries."""
+    big = np.iinfo(np.int32).max
+    tab, count = D.chunk_table(
+        jnp.array([0, 70, 70, 200]), jnp.array([70, 0, 130, 1]),
+        jnp.array([5, 9, 0, 0]), jnp.array([2, 1, big, 0]), 8, 4)
+    tab = {k: np.asarray(v).tolist() for k, v in tab.items()}
+    assert int(count) == 6
+    assert tab["start"][:6] == [0, 64, 200, 70, 134, 198]
+    assert tab["left"] == [70, 6, 1, 130, 66, 2, 0, 0]
+    assert tab["first"] == [1, 0, 1, 1, 0, 0, 0, 0]
+    assert tab["write"] == [0, 1, 1, 0, 0, 0, 0, 0]
+    assert tab["slot"][:6] == [2, 2, 0, 3, 3, 3]
+    assert tab["has_slot"] == [1, 1, 1, 0, 0, 0, 0, 0]
+    assert tab["from_state"][:6] == [1, 1, 0, 0, 0, 0]
+
+
+_BIG = np.iinfo(np.int32).max
+# (tokens on the axis, t0, qlen, hist, slots): rows in PrefillPlan's order
+_ROWS = {
+    # unaligned starts, a row of no tokens, a slot that held something else
+    "unaligned": (150, [0, 70, 0], [70, 80, 0], [5, 0, 0], [2, 0, 4]),
+    # more chunks than a pass holds: rows that straddle passes, one of them
+    # without a slot (its state rides from pass to pass and goes nowhere)
+    "passes": (1300, [1, 700, 1290], [699, 590, 10], [0, 0, 1],
+               [_BIG, 2, 4]),
+    "no_slot_first": (300, [0, 150, 299, 0], [150, 149, 1, 0], [3, 0, 2, 0],
+                      [3, _BIG, 0, 1]),
+    "none_has_a_slot": (200, [0, 70], [70, 130], [0, 0], [_BIG, _BIG]),
+    "nothing": (200, [0, 70], [0, 0], [4, 0], [1, 2]),
+}
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+@pytest.mark.parametrize("rows", sorted(_ROWS))
+def test_rows_on_one_axis_are_each_the_recurrence(rows, backend):
+    """``delta_rows`` in both forms of its second half (the scan, and the
+    chunk kernel in interpret mode) against the recurrence a row: a row
+    continues from its slot's state or starts from zeros, a row without a
+    slot starts from zeros and writes nothing, tokens no row owns read
+    zeros; every slot no row ends in and the other layer bit for bit."""
+    T, t0, ql, hist, slots = _ROWS[rows]
+    args = _draw(T, seed=T)
+    pool = jax.random.normal(jax.random.PRNGKey(5), (2, 5, 4, 16, 16))
+    fn = jax.jit(D.delta_rows, static_argnames=("backend", "interpret"))
+    with jax.default_matmul_precision("highest"):
+        o, new = fn(*args, *(jnp.asarray(a, jnp.int32)
+                             for a in (t0, ql, hist, slots)), pool, 1,
+                    backend=backend, interpret=True)
+        want_o = np.zeros(o.shape, np.float32)
+        want_pool = np.asarray(pool).copy()
+        for a, n, h, slot in zip(t0, ql, hist, slots):
+            if not n:
+                continue
+            held = slot < 5
+            S0 = pool[1, slot] if held and h else jnp.zeros((4, 16, 16))
+            ro, S = D.delta_recurrence(*(x[a:a + n] for x in args), S0)
+            want_o[a:a + n] = np.asarray(ro)
+            if held:
+                want_pool[1, slot] = np.asarray(S)
+    assert np.abs(np.asarray(o) - want_o).max() < 1e-5
+    assert np.abs(np.asarray(new) - want_pool).max() < 2e-5
+    assert np.array_equal(np.asarray(new[0]), np.asarray(pool[0]))
+    ends = {s for s, n in zip(slots, ql) if n and s < 5}
+    for slot in set(range(5)) - ends:
+        assert np.array_equal(np.asarray(new[1, slot]),
+                              np.asarray(pool[1, slot])), slot
+
+
+@pytest.mark.parametrize("rows", ["unaligned", "passes", "no_slot_first"])
+def test_chunk_kernel_in_interpret_mode_is_the_scan(rows):
+    """The two forms of the second half run the same products in the same
+    order: the kernel's outputs and pool are the scan's to a rounding."""
+    T, *plan = _ROWS[rows]
+    args = _draw(T, seed=3)
+    pool = jax.random.normal(jax.random.PRNGKey(6), (2, 5, 4, 16, 16))
+    fn = jax.jit(D.delta_rows, static_argnames=("backend", "interpret"))
+    plan = [jnp.asarray(a, jnp.int32) for a in plan]
+    with jax.default_matmul_precision("highest"):
+        o0, p0 = fn(*args, *plan, pool, 1, backend="reference")
+        o1, p1 = fn(*args, *plan, pool, 1, backend="pallas", interpret=True)
+    assert float(jnp.abs(o1 - o0).max()) < 1e-6
+    assert float(jnp.abs(p1 - p0).max()) < 1e-6
+    assert float(jnp.abs(p0 - pool).max()) > 1e-2
+
+
+@pytest.mark.parametrize("widths,why", [
+    ((128, 96), "128 lanes"), ((64, 128), "equal")])
+def test_chunk_kernel_refuses_by_name_what_mosaic_refuses(widths, why):
+    dk, dv = widths
+    z = lambda *shp: jnp.zeros(shp, jnp.float32)
+    tab, count = D.chunk_table(
+        jnp.zeros((1,), jnp.int32), jnp.full((1,), 64, jnp.int32),
+        jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), 1, 2)
+    with pytest.raises(UnsupportedKernelGeometry, match=why):
+        deltanet_chunk_tpu(
+            z(1, 8, 64, dv), z(1, 8, 64, dk), z(1, 8, 64, dk),
+            z(1, 8, 64, 64), z(1, 8, 64, dk), z(1, 8), z(1, 2, 8, dk, dv),
+            z(8, dk, dv), 0, tab, count)
+
+
 def test_rows_share_a_flat_axis_and_each_writes_its_own_slot_alone():
     """Two rows and a row without tokens on one axis: a row that continues
     from its slot's state, a row that starts from zeros in a slot that held
@@ -202,7 +321,9 @@ def test_rows_share_a_flat_axis_and_each_writes_its_own_slot_alone():
     assert np.array_equal(np.asarray(new[1, 3]), np.asarray(pool[1, 3]))
 
 
-def test_what_lies_behind_a_rows_end_is_selected_out_not_multiplied_out():
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_what_lies_behind_a_rows_end_is_selected_out_not_multiplied_out(
+        backend):
     """The tokens behind a row's last one in its last 64-token chunk are
     padding whose values nothing vouches for (found on the chip: a kernel
     leaves the rows it skips unwritten, and a NaN times a zero weight is a
@@ -213,8 +334,10 @@ def test_what_lies_behind_a_rows_end_is_selected_out_not_multiplied_out():
     pool = jnp.full((1, 2, 4, 16, 16), jnp.nan)
     one = jnp.ones((1,), jnp.int32)
     with jax.default_matmul_precision("highest"):
-        o, new = jax.jit(D.delta_rows)(
-            *bad, 0 * one, 70 * one, 0 * one, one, pool, 0)
+        o, new = jax.jit(
+            D.delta_rows, static_argnames=("backend", "interpret"))(
+            *bad, 0 * one, 70 * one, 0 * one, one, pool, 0,
+            backend=backend, interpret=True)
         want, S = D.delta_recurrence(
             q[:70], k[:70], v[:70], g[:70], b[:70], jnp.zeros((4, 16, 16)))
     assert float(jnp.abs(o[:70] - want).max()) < 1e-5
@@ -744,6 +867,34 @@ def test_lowered_step_carries_the_named_scope(lowered_text, scope):
     assert re.search(rf"[/\"]{re.escape(scope)}[/\"]", lowered_text), scope
 
 
+def test_chunks_of_the_delta_rule_are_counted_from_the_hosts_mirrors(model):
+    """``helix_deltanet_chunks_total``: a launch's prefill rows' ``ceil(tokens
+    / 64)`` x delta layers on the launch's span and, summed, on the engine;
+    by hand for a 150-token prompt in chunks of 128 and three decode steps."""
+    from helix_tpu.obs import trace as obs_trace
+
+    cfg, params = model
+    eng = _engine(cfg, params, max_prefill_len=128, max_pages_per_seq=24)
+    seen = []
+    orig = obs_trace.phase
+
+    def phase(name, *a, **kw):
+        if name == "helix.loop.launch":
+            seen.append(kw["deltanet_chunks"])
+        return orig(name, *a, **kw)
+
+    obs_trace.phase = phase
+    try:
+        eng.add_request(_req("c", tokens_of(150, 4), 4))
+        while eng.has_work():
+            eng.step()
+    finally:
+        obs_trace.phase = orig
+    # 128 tokens are two chunks, the 22 left one, in each of 7 delta layers
+    assert seen[:2] == [2 * 7, 1 * 7] and not any(seen[2:]), seen
+    assert eng.num_deltanet_chunks == 3 * 7
+
+
 def test_flight_records_and_metrics_carry_the_new_series(model):
     """Through the serving loop and the HTTP surface's collector: the step's
     flight record says how many delta layers and held experts the model has,
@@ -785,6 +936,9 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
         return float(line.rsplit(" ", 1)[1])
 
     assert value("helix_deltanet_rows_total{", 'kind="chunk"') == 2
+    # a 21-token prompt in chunks of 16: two rows of one 64-token chunk each
+    assert value("helix_deltanet_chunks_total{") == 2 * 7
+    assert sum(r["deltanet_chunks"] for r in records) == 2 * 7
     assert value("helix_deltanet_rows_total{", 'kind="decode"') >= 4
     assert value("helix_recurrent_state_bytes{") == eng.recurrent_state_bytes
     assert value("helix_state_bytes_touched_total{") == (

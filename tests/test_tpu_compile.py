@@ -582,6 +582,40 @@ def test_deltanet_decode_kernel_compiles_at_the_published_geometry(one_chip):
     assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
+@pytest.mark.parametrize("tokens,rows", [(512, 1), (512, 64), (16, 64)],
+                         ids=["one_row", "wave", "short_wave"])
+def test_deltanet_chunked_form_compiles_at_the_published_geometry(
+        one_chip, tokens, rows):
+    """GigaChat3.5's chunked delta rule over a prefill segment (``ops/
+    deltanet.py::delta_rows``): the state-free half in XLA and the chunk
+    kernel, 64 value heads of 128 x 128 in a pool of seven layers, donated:
+    aliased to its output; a pass of eight chunks at a time, so a wave of 64
+    rows holds no more temporaries than one row does."""
+    import functools
+
+    from helix_tpu.ops.deltanet import delta_rows
+
+    H, d, L, N = 64, 128, 7, 64
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    vec = S((rows,), jnp.int32)
+    compiled = jax.jit(
+        functools.partial(delta_rows, backend="pallas"),
+        donate_argnums=(9,)).lower(
+        S((tokens, H, d)), S((tokens, H, d)), S((tokens, H, d)),
+        S((tokens, H)), S((tokens, H)), vec, vec, vec, vec,
+        S((L, N, H, d, d)), S((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "deltanet_chunk_tpu" in text
+    # no triangular solve is left: the inverse is products
+    assert "TriangularSolve" not in text and "InvertDiagBlocks" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * N * H * d * d * 4
+    assert mem.temp_size_in_bytes < 128 * 2 ** 20
+
+
 @pytest.mark.parametrize("rows", [512, 4608], ids=["decode", "chunk"])
 def test_grouped_product_compiles_at_7168_by_4096(one_chip, rows):
     """16 held experts of 7168 x 2048, int8, the layer picked from a stack of
@@ -622,7 +656,7 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
     """A whole engine step of GigaChat3.5 cut to three layers (delta + dense,
     latent + held experts, delta + held experts; int8 weights, 64 slots) for
     the described chip: the delta decode kernel over the state pool in the
-    carry, the chunked form a row at a time, the latent kernel at 64 heads
+    carry, the chunked form in its chunk kernel, the latent kernel at 64 heads
     over a latent pool of ONE layer, the grouped product over 16 of 256
     experts, and both state arrays updated in place."""
     import dataclasses
@@ -682,7 +716,8 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
     ).compile()
     text = compiled.as_text()
     for kernel in ("deltanet_decode_tpu", "grouped_matmul_tpu",
-                   "mla_ragged_paged_attention"):
+                   "mla_ragged_paged_attention") + (
+                       ("deltanet_chunk_tpu",) if bucket else ()):
         assert kernel in text, kernel
     # the state pool is updated in place: aliased whole, and no temporary
     # of half its size
