@@ -469,8 +469,9 @@ TOL_RETENTION_F32 = 1e-4
 # float32 reference it reads 0.018-0.024 | 0.022-0.031, under or at the
 # engine's own bf16 activations.  It is read and reported at every step, and does not decide
 # ``ok``; what holds the state's precision on the chip is the kernel phase
-# (the state the kernel and the chunked form leave, against the float32
-# recurrence, to 1e-4: a bf16 state reads 2e-3 there).  PERF.md section 7.
+# (the state the decode kernel leaves against the float32 recurrence's, and
+# the state the chunked form leaves against the definition's sum at once, to
+# 1e-4: a bf16 state reads 2e-3 there).  PERF.md section 7.
 TOL_BRUMBY = 0.04
 TOL_BRUMBY_WORST = 0.05
 
@@ -479,9 +480,11 @@ def phase_kernel_retention(spec, seed, rehearse):
     """``retention_decode_tpu`` against the ``jax.numpy`` recurrence at 24
     rows (17 live), 8 kv heads of 5 query heads, width 128, the second layer
     of a pool of two: the states the live slots are left with, the outputs,
-    and every other slot and layer bit for bit.  Then the chunked form at 512
-    tokens against the definition's quadratic form: a row from zeros, and the
-    row that continues it from the state the first left."""
+    and every other slot and layer bit for bit.  Then what the ENGINE runs
+    for a prefill segment (``retention_rows``: on the chip the chunk kernel's
+    path) at 512 tokens: a row from zeros and the row that continues it from
+    the state the first left, by their outputs and by the state they leave;
+    and its time a layer for one row of each kind and for a wave of four."""
     from helix_tpu.ops import retention as R
 
     B, KVH, G, d, T = (5, 2, 3, 16, 24) if rehearse else (24, 8, 5, 128, 512)
@@ -518,22 +521,79 @@ def phase_kernel_retention(spec, seed, rehearse):
         tol=TOL_RETENTION_F32, ok=bool(ok))
 
     q, k, v, lg = draw(2 * T)
+    zeros = lambda: (jnp.zeros((L, 4, KVH, F, d)),
+                     jnp.zeros((L, 4, KVH, d, d)))
+
+    def state_of_the_recurrence(k, v, lg):
+        def step(state, x):
+            kt, vt, lt = (a[None] for a in x)
+            g = jnp.exp(lt)[..., None, None]
+            return g * state + R.phi(kt)[..., :, None] * vt[..., None, :], ()
+
+        return jax.lax.scan(
+            step, jnp.zeros((1, KVH, F, d)), (k, v, lg))[0][0]
+
+    def state_at_once(k, v, lg):
+        G = jnp.cumsum(lg, axis=0)
+        return jnp.einsum("tk,tkf,tkc->kfc", jnp.exp(G[-1] - G), R.phi(k), v)
+
+    # the outputs against the definition (the recurrence's own first token
+    # is ill-conditioned where (q . k) ** 2 is near eps); the state the rows
+    # leave against the definition's sum over all their tokens at once, and,
+    # reported only, against the token-by-token recurrence (whose product of
+    # a thousand rounded gates is what drifts: PERF.md section 6, PR 43)
     with jax.default_matmul_precision("highest"):
         want = R.retention_quadratic(q[None], k[None], v[None], lg[None])[0]
-    pools = (jnp.zeros((L, 2, KVH, F, d)), jnp.zeros((L, 2, KVH, d, d)))
-    rows = jax.jit(R.retention_rows)
-    one = jnp.ones((1,), jnp.int32)
-    first, *pools = rows(
-        q[:T], k[:T], v[:T], lg[:T], 0 * one, T * one, 0 * one, one,
-        *pools, 1)
-    second, *pools = rows(
-        q[T:], k[T:], v[T:], lg[T:], 0 * one, T * one, T * one, one,
-        *pools, 1)
+        S_end = jax.jit(state_at_once)(k, v, lg)
+        S_rec = jax.jit(state_of_the_recurrence)(k, v, lg)
+    # what the engine runs: on the chip the chunk kernel, here (a rehearsal)
+    # the same kernel in interpret mode
+    rows = jax.jit(functools.partial(R.retention_rows, **(
+        dict(backend="pallas", interpret=True) if rehearse else {})),
+        donate_argnums=(8, 9))
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)
+    first, *pools = rows(q[:T], k[:T], v[:T], lg[:T], i32(0), i32(T), i32(0),
+                         i32(1), *zeros(), 1)
+    second, *pools = rows(q[T:], k[T:], v[T:], lg[T:], i32(0), i32(T),
+                          i32(T), i32(1), *pools, 1)
     errs = {"from_zeros": rel(first, want[:T]),
-            "from_a_state": rel(second, want[T:])}
+            "from_a_state": rel(second, want[T:]),
+            "state_after": rel(pools[0][1, 1], S_end)}
     good = all(e <= TOL_RETENTION_F32 for e in errs.values())
+    errs["state_after_by_the_recurrence"] = rel(pools[0][1, 1], S_rec)
+    errs["recurrence_by_the_sum_at_once"] = rel(S_rec, S_end)
+
+    def ms_a_layer(plan, tokens, pools, reps=20):
+        """Wall time of one layer's call, the pools donated from call to
+        call: on the chip the device's time, here the CPU's (rehearsal)."""
+        held = tuple(a[:tokens] for a in (q, k, v, lg)) + plan
+        for _ in range(2):
+            y, *pools = rows(*held, *pools, 1)
+        jax.block_until_ready(y)
+        t = time.perf_counter()
+        for _ in range(reps):
+            y, *pools = rows(*held, *pools, 1)
+        jax.block_until_ready(y)
+        return round((time.perf_counter() - t) / reps * 1e3, 4), pools
+
+    # one row of T tokens that continues from its slot's state, the same
+    # row from zeros, and four rows of unlike lengths at unaligned starts
+    # (the third continues from its slot) on an axis of 2 T tokens
+    reps = 2 if rehearse else 20
+    row_ms, pools = ms_a_layer(
+        (i32(0), i32(T), i32(T), i32(1)), T, pools, reps)
+    zero_ms, pools = ms_a_layer(
+        (i32(0), i32(T), i32(0), i32(1)), T, pools, reps)
+    q4 = [T // 2 + 44, T // 4 + 9, T - 100, T // 4 + 30]
+    t4 = [0, q4[0], q4[0] + q4[1], 2 * T - q4[3]]
+    wave_ms, pools = ms_a_layer(
+        (i32(*t4), i32(*q4), i32(0, 0, 7, 0), i32(0, 1, 2, 3)), 2 * T, pools,
+        reps)
     say(phase="kernel", op="retention_rows (chunked form)", tokens=T,
-        geometry=[H, KVH, d], **errs, tol=TOL_RETENTION_F32, ok=bool(good))
+        geometry=[H, KVH, d], **errs, tol=TOL_RETENTION_F32,
+        row_ms_a_layer={"from_a_state": row_ms, "from_zeros": zero_ms},
+        wave_of_four_rows_ms_a_layer=wave_ms, wave_tokens=sum(q4),
+        timed_on=jax.default_backend(), ok=bool(good))
     if not (ok and good):
         fail("the retention kernel or the chunked form disagrees with its "
              "reference")

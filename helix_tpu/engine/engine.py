@@ -1057,7 +1057,9 @@ def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
     ``decode``: the segment's rows are one token each and row ``b`` is slot
     ``b`` (the recurrence applied once: on a TPU one pass of the decode
     kernel over the live slots).  Else the rows are runs of fresh tokens on
-    one flat axis (the chunked form, a row at a time).
+    one flat axis (the chunked form: on a TPU what reads no state once for
+    the axis, then the chunk kernel a row, which reads a row's state once,
+    or not at all where the row starts its sequence, and writes it once).
 
     The carry it is called with is ``((page carry, kacc, vacc, (S pool, Z
     pool)), retention layer index)``."""
@@ -1074,7 +1076,7 @@ def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
             y, s_pool, z_pool = retention_rows(
                 q.reshape(Bq * Sq, H, D), k.reshape(Bq * Sq, -1, D),
                 v.reshape(Bq * Sq, -1, D), log_g.reshape(Bq * Sq, -1),
-                t0, qlen, hist, slots, s_pool, z_pool, lc)
+                t0, qlen, hist, slots, s_pool, z_pool, lc, backend=backend)
         return y.reshape(Bq, Sq, H, D), (caches, kacc, vacc,
                                          (s_pool, z_pool))
 
@@ -1803,6 +1805,9 @@ class Engine:
         # steps read and wrote, by the form that ran them, and their bytes
         # (what a roofline reckoned from a trace divides by)
         self.num_retention_rows = {"decode": 0, "chunk": 0}
+        # ... and of the chunk rows, those that started their sequence: the
+        # chunk kernel skips the state's read and its query for them
+        self.num_retention_chunk_rows_from_zeros = 0
         self.num_deltanet_rows = {"decode": 0, "chunk": 0}
         # rings of K/V (sliding-window layers): rows that read their slot's
         # rings, and the bytes of live ring rows they read (what a roofline
@@ -2140,22 +2145,28 @@ class Engine:
             _refuse_call(self.model_cfg, "the persistent KV filestore")
         self._kv_filestore = store
 
-    def _note_state_rows(self, plan, draft_len, n_extra) -> None:
+    def _note_state_rows(self, plan, draft_len, n_extra) -> tuple:
         """Count the rows of a matrix state's pool this step reads and
         writes: a live decode row once a fused step, a prefill row with a
         slot once; each row is every recurrent layer's state of one slot,
-        read once and written once."""
+        read once and written once.  Returns the launch's ``(chunk rows,
+        those of them that start their sequence)``."""
         live = (np.asarray(draft_len) >= 0) & (
             np.asarray(self._active_sent) > 0)
         dec = int(np.count_nonzero(live)) * (1 + int(n_extra))
-        chunk = sum(1 for r in plan.rows if r.slot >= 0) if plan else 0
+        held = [r for r in plan.rows if r.slot >= 0] if plan else []
+        chunk = len(held)
+        from_zeros = sum(1 for r in held if r.start == 0)
         rows = (self.num_deltanet_rows
                 if self.model_cfg.num_deltanet_layers
                 else self.num_retention_rows)
         rows["decode"] += dec
         rows["chunk"] += chunk
+        if self.model_cfg.num_retention_layers:
+            self.num_retention_chunk_rows_from_zeros += from_zeros
         self.state_bytes_touched += 2 * (dec + chunk) * (
             self.recurrent_state_bytes // self.cfg.max_decode_batch)
+        return chunk, from_zeros
 
     def _note_window_rows(self, plan, draft_len, n_extra) -> None:
         """Count the rows of this launch that read and write their slot's
@@ -5365,8 +5376,9 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
+        chunk_rows = None
         if self.model_cfg.state_mixer in ("retention", "deltanet"):
-            self._note_state_rows(
+            chunk_rows = self._note_state_rows(
                 plan if rows else None, draft_len, n_extra)
         if self.model_cfg.num_window_layers:
             self._note_window_rows(
@@ -5402,7 +5414,9 @@ class Engine:
                 "attn_layers": self.model_cfg.num_attn_layers}
                if self.model_cfg.num_conv_layers else {}),
             **({"retention_layers": self.model_cfg.num_retention_layers,
-                "attn_layers": self.model_cfg.num_attn_layers}
+                "attn_layers": self.model_cfg.num_attn_layers,
+                "retention_chunk_rows": chunk_rows[0],
+                "retention_chunk_rows_from_zeros": chunk_rows[1]}
                if self.model_cfg.num_retention_layers else {}),
             **({"deltanet_layers": self.model_cfg.num_deltanet_layers,
                 "attn_layers": self.model_cfg.num_attn_layers}

@@ -31,12 +31,15 @@ what the decode kernel's grid walks.  Inside a tile the row index is ``(r,
 s, c)``: ``r`` the row of ``u`` in its block (8), ``s`` the block's place in
 the tile (``d/8 + 1``), ``c`` the column in the block (8).
 
-Three forms of the same function live here, all ``jax.numpy`` in float32 at
-the highest matmul precision: the quadratic form over whole sequences (a
-forward pass with no cache), the recurrence one token at a time (the decode
-kernel's oracle and the CPU path), and the CHUNKED form (a row of fresh
-tokens that continues from a state).  The decode step on a TPU is
-``ops/retention_kernel.py``.
+Three forms of the same function live here in ``jax.numpy``, float32 at the
+highest matmul precision: the quadratic form over whole sequences (a forward
+pass with no cache), the recurrence one token at a time (the decode kernel's
+oracle and the CPU path), and the CHUNKED form (a row of fresh tokens that
+continues from a state: ``retention_chunk``, the chunk kernel's oracle and
+the CPU path).  On a TPU the decode step and the half of the chunked form
+that touches the state are ``ops/retention_kernel.py``'s two kernels
+(``retention_decode``, ``retention_rows``: chosen by the backend the engine
+resolves); the half that reads no state is ``rows_state_free`` here.
 """
 
 from __future__ import annotations
@@ -231,14 +234,27 @@ def retention_decode(q, k, v, log_g, S_pool, Z_pool, layer, live, *,
 
 
 def retention_rows(q, k, v, log_g, t0, qlen, hist, slots, S_pool, Z_pool,
-                   layer, eps: float = EPS):
+                   layer, eps: float = EPS, *, backend=None,
+                   interpret: bool = False):
     """Rows of fresh tokens on one flat axis (a prefill segment): row ``r``
     is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
     ``slots[r]``, with ``hist[r]`` tokens behind it (0: it starts from
-    zeros).  Live rows come first (``PrefillPlan``'s order).  A row with no
-    token is not visited; a row whose slot lies past the pool (no slot)
-    writes back what it read.  The pools are read and written a row at a
-    time, in place.  Returns ``(y [T, H, d] float32, S_pool, Z_pool)``."""
+    zeros, whatever its slot holds).  A row with no token is not visited; a
+    row whose slot lies past the pool (no slot) starts from zeros and writes
+    nothing.  The pools are read and written a row at a time, in place.
+    Returns ``(y [T, H, d] float32, S_pool, Z_pool)``.
+
+    On a TPU the form is in two halves (``_rows_on_the_kernel``: what reads
+    no state once for the whole axis, then ``retention_chunk_tpu`` a row;
+    ``interpret``: the same kernel in interpret mode, for tests on a CPU
+    with ``backend="pallas"``); on a CPU, or for ``backend="reference"``,
+    ``retention_chunk`` a row over the whole axis under the row's mask."""
+    from helix_tpu.ops.attention import resolve_backend
+
+    if resolve_backend(backend) == "pallas":
+        return _rows_on_the_kernel(
+            q, k, v, log_g, t0, qlen, hist, slots, S_pool, Z_pool, layer,
+            eps, interpret)
     T, H, d = q.shape
     N = S_pool.shape[1]
     at = jnp.arange(T, dtype=jnp.int32)
@@ -247,15 +263,127 @@ def retention_rows(q, k, v, log_g, t0, qlen, hist, slots, S_pool, Z_pool,
     def row(r, carry):
         y, S_pool, Z_pool = carry
         slot = jnp.clip(slots[r], 0, N - 1)
-        held = slots[r] < N
+        dest = jnp.where(slots[r] < N, slot, N)   # past the pool: dropped
         mask = (at >= t0[r]) & (at < t0[r] + qlen[r])
-        keep = (hist[r] > 0).astype(jnp.float32)
-        S_old, Z_old = S_pool[layer, slot], Z_pool[layer, slot]
+        keep = (hist[r] > 0) & (slots[r] < N)
         yr, S1, Z1 = retention_chunk(
-            q, k, v, log_g, mask, S_old * keep, Z_old * keep, eps)
-        S_pool = S_pool.at[layer, slot].set(jnp.where(held, S1, S_old))
-        Z_pool = Z_pool.at[layer, slot].set(jnp.where(held, Z1, Z_old))
-        return y + yr, S_pool, Z_pool
+            q, k, v, log_g, mask,
+            jnp.where(keep, S_pool[layer, slot], 0.0),
+            jnp.where(keep, Z_pool[layer, slot], 0.0), eps)
+        return (y + yr, S_pool.at[layer, dest].set(S1, mode="drop"),
+                Z_pool.at[layer, dest].set(Z1, mode="drop"))
 
     y0 = jnp.zeros((T, H, d), jnp.float32)
     return jax.lax.fori_loop(0, n_rows, row, (y0, S_pool, Z_pool))
+
+
+def rows_state_free(q, k, v, log_g, member):
+    """What the chunked form does not read a state for, ONCE for a whole
+    flat axis: ``member [R, T]`` bool marks each row's tokens (a token is
+    one row's at most).  Scores, their decay and the sums run under a
+    same-row-and-causal mask, the cumulative log-gates restarted at each
+    row's first token.  ``q [T, H, d]`` (scaled), ``k, v [T, KVH, d]``,
+    ``log_g [T, KVH]``.  Returns ``(num [T, H, d], den [T, H], into [T,
+    KVH], out [T, KVH], whole [R, KVH])``: the inside-the-row numerator and
+    normaliser, the decay from a row's start to each token (what the state
+    it continues from is seen through), from each token to its row's last
+    (what the token's outer product enters the new state with; 0 off the
+    rows), and a row's whole decay."""
+    T, H, d = q.shape
+    KVH = k.shape[1]
+    inside = member.astype(jnp.float32)
+    owned = jnp.any(member, axis=0)
+    same = jnp.einsum("rt,rs->ts", inside, inside) > 0
+    pair = same & jnp.tril(jnp.ones((T, T), bool))
+    lg = jnp.where(owned[:, None], log_g.astype(jnp.float32), 0.0)
+    Gc = jnp.einsum("ts,sk->tk", pair.astype(jnp.float32), lg, precision=_HI)
+    whole = jnp.einsum("rt,tk->rk", inside, lg, precision=_HI)
+    last = jnp.einsum("rt,rk->tk", inside, whole, precision=_HI)
+    out = jnp.where(owned[:, None], jnp.exp(last - Gc), 0.0)
+
+    def head(x):
+        qh, kh, vh, Gh = x                       # [T, G, d] [T, d] [T, d] [T]
+        sc = jnp.einsum("tgd,sd->gts", qh, kh, precision=_HI) ** 2
+        a = sc * jnp.exp(jnp.where(
+            pair, Gh[:, None] - Gh[None, :], -jnp.inf))[None]
+        return (jnp.einsum("gts,sd->tgd", a, vh, precision=_HI),
+                jnp.sum(a, axis=-1).T)
+
+    num, den = jax.lax.map(head, (
+        _grouped(q.astype(jnp.float32), KVH).transpose(1, 0, 2, 3),
+        k.astype(jnp.float32).transpose(1, 0, 2),
+        v.astype(jnp.float32).transpose(1, 0, 2), Gc.T))
+    return (num.transpose(1, 0, 2, 3).reshape(T, H, d),
+            den.transpose(1, 0, 2).reshape(T, H), jnp.exp(Gc), out,
+            jnp.exp(whole))
+
+
+def _rows_on_the_kernel(q, k, v, log_g, t0, qlen, hist, slots, S_pool,
+                        Z_pool, layer, eps, interpret):
+    """``retention_rows`` in two halves.  ``rows_state_free`` for the whole
+    axis in XLA; then the rows that have a slot, those that continue from a
+    state first: ``S`` in ``retention_chunk_tpu`` (read once and written
+    once a row, not read for a row from zeros), ``Z`` (1.5% of the state's
+    bytes) in a loop of ``[d, d]`` products here."""
+    from helix_tpu.ops.retention_kernel import TOKENS, retention_chunk_tpu
+
+    T, H, d = q.shape
+    KVH, N, R = k.shape[1], S_pool.shape[1], t0.shape[0]
+    G = H // KVH
+    at = jnp.arange(T, dtype=jnp.int32)
+    member = (at >= t0[:, None]) & (at < (t0 + qlen)[:, None])     # [R, T]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    num, den, into, out, whole = rows_state_free(q, k, v, log_g, member)
+    owned = jnp.any(member, axis=0)
+
+    live = (qlen > 0) & (slots < N)
+    cont = live & (hist > 0)
+    order = jnp.argsort(
+        jnp.where(cont, 0, jnp.where(live, 1, 2)), stable=True)
+    n_hist, n_live = (jnp.sum(x).astype(jnp.int32) for x in (cont, live))
+    slot = jnp.clip(slots, 0, N - 1).astype(jnp.int32)[order]
+
+    # the token axis in blocks of 128, q and k with channels down the
+    # sublanes and tokens across the lanes
+    NB = -(-T // TOKENS)
+    blocks = lambda a: jnp.pad(
+        a, ((0, NB * TOKENS - T),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (NB, TOKENS) + a.shape[1:])
+    held, S_pool = retention_chunk_tpu(
+        blocks(q).reshape(NB, TOKENS, KVH, G, d).transpose(2, 0, 3, 4, 1),
+        blocks(k).transpose(2, 0, 3, 1),
+        blocks(v * out[..., None]).transpose(2, 0, 1, 3),
+        jnp.broadcast_to(whole[order][..., None, None], (R, KVH, 1, d)),
+        S_pool, layer, slot, t0[order], qlen[order], n_hist, n_live,
+        interpret=interpret)
+    held = held.transpose(1, 3, 0, 2, 4).reshape(NB * TOKENS, H, d)[:T]
+
+    qg = _grouped(q, KVH)
+
+    def normaliser(i, carry):
+        seen, Z_pool = carry
+        r = order[i]
+        mine = member[r]
+        fresh = jnp.einsum(
+            "tk,tki,tkj->kij", jnp.where(mine[:, None], out, 0.0), k, k,
+            precision=_HI)
+
+        def continues(seen, Z0):
+            return seen + jnp.where(mine[:, None, None], jnp.einsum(
+                "tkgi,kij,tkgj->tkg", qg, Z0, qg, precision=_HI), 0.0), (
+                    whole[r][:, None, None] * Z0 + fresh)
+
+        # (the slot's matrix is read outside the branch: a branch that
+        # closed over the pool would copy it)
+        seen, Z1 = jax.lax.cond(
+            i < n_hist, continues, lambda seen, Z0: (seen, fresh), seen,
+            Z_pool[layer, slot[i]])
+        return seen, Z_pool.at[layer, slot[i]].set(Z1)
+
+    seen, Z_pool = jax.lax.fori_loop(
+        0, n_live, normaliser,
+        (jnp.zeros((T, KVH, G), jnp.float32), Z_pool))
+    through = jnp.repeat(into, G, axis=1)                          # [T, H]
+    y = (num + through[..., None] * held) / (
+        den + through * seen.reshape(T, H) + eps)[..., None]
+    return jnp.where(owned[:, None, None], y, 0.0), S_pool, Z_pool

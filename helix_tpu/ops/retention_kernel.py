@@ -1,6 +1,9 @@
-"""Pallas TPU kernel: one decode step of power retention, the state's update
-and its query in ONE pass.
+"""Pallas TPU kernels for power retention: one decode step (the state's
+update and its query in ONE pass), and the half of the chunked form that
+touches the state (``retention_chunk_tpu``, further down: a row of fresh
+tokens against its slot's state, read once and written once).
 
+**The decode step** (``retention_decode_tpu``).
 XLA's form of ``ops/retention.py::retention_step`` walks the state three
 times (read for the update, write, read again for the query), and the state
 is four fifths of a decode step's bytes.  Here a tile of ``S`` is read once,
@@ -23,6 +26,13 @@ Grid ``(rows, kv heads, tiles)``, sequential.  Visits past the live rows
 repeat the last live block (nothing is fetched or written for them) and are
 skipped; with no live row at all the one block they all name is copied
 through unchanged.
+
+**A row of fresh tokens** (``retention_chunk_tpu``).  There the two
+products are matrix products and ``phi`` IS built, but only in VMEM: a tile
+of ``phi(Q)^T`` or ``phi(K)^T`` for 128 tokens at a time, features down the
+sublanes and tokens across the lanes, where an 8-row slab is the
+sublane-aligned slice ``u^T[8b:8b+8, :]`` times the one row ``u^T[8a + r,
+:]`` times the block's weight: whole vregs, one multiply each.
 """
 
 from __future__ import annotations
@@ -53,7 +63,7 @@ def check_retention_geometry(num_heads: int, num_kv_heads: int,
         why = "the kv heads must divide the query heads"
     if why:
         raise UnsupportedKernelGeometry(
-            "retention decode kernel: no TPU lowering for "
+            "retention kernels: no TPU lowering for "
             f"{num_heads} query / {num_kv_heads} kv heads of width "
             f"{head_dim}: {why}.  Serve this geometry with "
             "attn_backend='reference' explicitly, or extend the kernel.")
@@ -190,3 +200,193 @@ def retention_decode_tpu(
         q, q, k, k, v, gate, s_pool,
     )
     return num, s_pool
+
+
+TOKENS = 128        # tokens a block of the chunk kernel: the lanes of a vreg
+
+
+def _chunk_kernel(layer_ref, slot_ref, t0_ref, qlen_ref, hist_ref, live_ref,
+                  q_ref, k_ref, vo_ref, decay_ref, s_ref, num_ref, o_ref,
+                  phiq_ref, phik_ref, *, d: int, group: int):
+    del layer_ref, slot_ref                  # read by the index maps
+    nb = d // BLOCK
+    width = (nb + 1) * BLOCK                 # a row of the tile, in rows of S
+    r, p = pl.program_id(1), pl.program_id(2)
+    n_hist, n_live = hist_ref[0], live_ref[0]
+    lo = t0_ref[r]
+    hi = lo + qlen_ref[r]
+    dot = functools.partial(
+        jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    nn = (((1,), (0,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
+
+    def phi_t(u_ref, at, out_ref, cols):
+        """``phi(u)^T`` of this tile for the 128 tokens of ``u_ref[at]``
+        (``[d, 128]``: channels down the sublanes), into ``out_ref[:,
+        cols]``: slab ``(i, s)`` is the 8 channels of block ``b`` times
+        channel ``8 a + i``, times the block's weight."""
+        def across(a):
+            rows = u_ref[at + (pl.ds(pl.multiple_of(a * BLOCK, BLOCK),
+                                     BLOCK), slice(None))]
+            return [jnp.broadcast_to(rows[i:i + 1], rows.shape)
+                    for i in range(BLOCK)]
+
+        # the tile pairs block row p (its nb - p blocks first) with block
+        # row nb - 1 - p
+        upper, lower = across(p), across(nb - 1 - p)
+        for s in range(nb + 1):                              # static unroll
+            first = s < nb - p
+            a = jnp.where(first, p, nb - 1 - p)
+            b = jnp.where(first, p + s, s - 1)
+            w = jnp.where(a == b, 1.0, _SQRT2).astype(jnp.float32)
+            ub = u_ref[at + (pl.ds(pl.multiple_of(b * BLOCK, BLOCK), BLOCK),
+                             slice(None))] * w
+            for i in range(BLOCK):
+                out_ref[pl.ds(i * width + s * BLOCK, BLOCK), cols] = (
+                    ub * jnp.where(first, upper[i], lower[i]))
+
+    @pl.when(jnp.logical_and(r == 0, p == 0))
+    def _a_kv_head_opens():
+        num_ref[...] = jnp.zeros_like(num_ref)
+
+    @pl.when(r < n_live)
+    def _a_row():
+        from_state = r < n_hist
+
+        @pl.when(from_state)
+        def _decayed():
+            o_ref[...] = decay_ref[...] * s_ref[...]
+
+        @pl.when(jnp.logical_not(from_state))
+        def _from_zeros():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        def block(tb, carry):
+            token = tb * TOKENS + jax.lax.broadcasted_iota(
+                jnp.int32, (TOKENS, 1), 0)
+            mine = jnp.logical_and(token >= lo, token < hi)  # [128, 1]
+
+            @pl.when(from_state)
+            def _what_the_state_holds():
+                for g in range(group):
+                    phi_t(q_ref, (tb, g), phiq_ref,
+                          pl.ds(g * TOKENS, TOKENS))
+                held = dot(phiq_ref[...], s_ref[...], tn)    # [G * 128, d]
+                for g in range(group):
+                    num_ref[tb, g] += jnp.where(
+                        mine, held[g * TOKENS:(g + 1) * TOKENS], 0.0)
+
+            phi_t(k_ref, (tb,), phik_ref, slice(None))
+            o_ref[...] += dot(
+                phik_ref[...], jnp.where(mine, vo_ref[tb], 0.0), nn)
+            return carry
+
+        jax.lax.fori_loop(lo // TOKENS, (hi - 1) // TOKENS + 1, block, 0)
+
+    @pl.when(jnp.logical_and(n_live == 0, jnp.logical_and(
+        pl.program_id(0) == 0, jnp.logical_and(r == 0, p == 0))))
+    def _nothing_live():
+        o_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def retention_chunk_tpu(
+    q,          # [KVH, NB, G, d, 128] f32: q^T (times head_dim ** -0.5), 128
+                #   tokens of the flat axis a block
+    k,          # [KVH, NB, d, 128] f32: k^T
+    vo,         # [KVH, NB, 128, d] f32: v times the decay from its token to
+                #   its row's last
+    decay,      # [R, KVH, 1, d] f32: a row's whole decay across the lanes
+    s_pool,     # [L, N, KVH, D_held, d] f32
+    layer,      # which of the L layers (a traced index)
+    slot,       # [R] int32, inside the pool  } rows that continue from
+    t0,         # [R] int32: first token      } their slot first, then rows
+    qlen,       # [R] int32: tokens           } from zeros, then the rest
+    n_hist,     # how many rows continue from their slot's state
+    n_live,     # how many rows are live (those, and the rows from zeros)
+    *,
+    interpret: bool = False,
+):
+    """The half of the chunked form that touches the state, a live row, kv
+    head and tile at a time: the tile of ``S`` is read once (not at all for
+    a row from zeros, whatever its slot holds), asked for ``phi(Q)^T S`` of
+    the row's tokens while in VMEM, scaled by the row's whole decay, given
+    ``phi(K)^T (decay * V)``, and written back in place.  ``phi`` is built a
+    tile and 128 tokens at a time in VMEM, transposed (features down the
+    sublanes, tokens across the lanes): an 8-row slab is 8 channels of ``u``
+    times one channel.  Token blocks outside a row are not visited; tokens
+    of a block that are another row's are selected out.
+
+    Returns ``(num [KVH, NB, G, 128, d] f32, s_pool)``: ``phi(q_t)^T S_0``
+    for every token of a row that continues from a state ``S_0`` (zeros
+    elsewhere), and the pool with the live rows' states advanced.
+
+    Grid ``(kv heads, rows, tiles)``, sequential.  Visits past the live rows
+    repeat the last live block (nothing is fetched or written for them);
+    visits of rows from zeros name, as their INPUT, the last block a row
+    with history read (nothing is fetched); with no live row at all the one
+    block they all name is copied through unchanged."""
+    KVH, NB, G, d, _ = q.shape
+    L, N, _, F, _ = s_pool.shape
+    R = slot.shape[0]
+    assert F == held_rows(d) and q.shape[-1] == TOKENS
+    if not interpret:
+        check_retention_geometry(KVH * G, KVH, d)
+    tiles, rows = d // (2 * BLOCK), tile_rows(d)
+
+    def state_in(j, r, p, layer, slot, t0, qlen, n_hist, n_live):
+        some = n_hist[0] > 0
+        past = r >= n_hist[0]
+        row = slot[jnp.clip(jnp.minimum(r, n_hist[0] - 1), 0, R - 1)]
+        return (layer[0], jnp.where(some, row, 0), jnp.where(some, j, 0),
+                jnp.where(some, jnp.where(past, tiles - 1, p), 0), 0)
+
+    def state_out(j, r, p, layer, slot, t0, qlen, n_hist, n_live):
+        some = n_live[0] > 0
+        past = r >= n_live[0]
+        row = slot[jnp.clip(jnp.minimum(r, n_live[0] - 1), 0, R - 1)]
+        return (layer[0], jnp.where(some, row, 0), jnp.where(some, j, 0),
+                jnp.where(some, jnp.where(past, tiles - 1, p), 0), 0)
+
+    def decay_map(j, r, p, layer, slot, t0, qlen, n_hist, n_live):
+        return jnp.clip(jnp.minimum(r, n_live[0] - 1), 0, R - 1), j, 0, 0
+
+    head = lambda *shape: pl.BlockSpec(
+        (None,) + shape, lambda j, r, p, *pre: (j,) + (0,) * len(shape))
+    state = lambda index: pl.BlockSpec((None, None, None, rows, d), index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(KVH, R, tiles),
+        in_specs=[head(NB, G, d, TOKENS), head(NB, d, TOKENS),
+                  head(NB, TOKENS, d),
+                  pl.BlockSpec((None, None, 1, d), decay_map),
+                  state(state_in)],
+        out_specs=[head(NB, G, TOKENS, d), state(state_out)],
+        scratch_shapes=[
+            pltpu.VMEM((rows, G * TOKENS), jnp.float32),     # phi(Q)^T
+            pltpu.VMEM((rows, TOKENS), jnp.float32),         # phi(K)^T
+        ],
+    )
+    # both ends of the pipeline twice, the scratch, the product's temporaries
+    held = 4 * (2 * (2 * NB * G * d + 2 * NB * d + 2 * rows) * TOKENS
+                + 3 * rows * (G + 1) * TOKENS)
+    as_i32 = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, d=d, group=G),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((KVH, NB, G, TOKENS, d), jnp.float32),
+                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype)],
+        # operand 10 (after the six prefetched scalars): the pool
+        input_output_aliases={10: 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(max(16 << 20, held + (8 << 20)), 100 << 20),
+        ),
+        name="retention_chunk_tpu",
+    )(
+        as_i32(layer), as_i32(slot), as_i32(t0), as_i32(qlen),
+        as_i32(n_hist), as_i32(n_live),
+        q, k, vo, decay, s_pool,
+    )

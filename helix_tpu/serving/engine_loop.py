@@ -1553,6 +1553,8 @@ class EngineLoop:
             getattr(eng, "num_joint_pass_inert_rows", 0),
             getattr(eng, "num_wave_decode_tokens", 0),
             getattr(eng, "num_deltanet_chunks", 0),
+            getattr(eng, "num_retention_rows", {}).get("chunk", 0),
+            getattr(eng, "num_retention_chunk_rows_from_zeros", 0),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1564,7 +1566,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0, wr0, dc0) = pre
+         cs0, jp0, ji0, wr0, dc0, rc0, rz0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1618,6 +1620,13 @@ class EngineLoop:
             # 64-token chunks the chunked delta rule ran in this step's
             # programs (prefill rows' ceil(tokens / 64) x delta layers)
             "deltanet_chunks": getattr(eng, "num_deltanet_chunks", 0) - dc0,
+            # power retention: this step's prefill rows with a slot (the
+            # chunked form), and those of them that started their sequence
+            # (no state read, no product against it)
+            "retention_chunk_rows": getattr(
+                eng, "num_retention_rows", {}).get("chunk", 0) - rc0,
+            "retention_chunk_rows_from_zeros": getattr(
+                eng, "num_retention_chunk_rows_from_zeros", 0) - rz0,
             # sliding-window layers (a ring of K/V a slot), and the live
             # rows whose sequence has passed the window
             "window_layers": getattr(eng.model_cfg, "num_window_layers", 0),

@@ -491,6 +491,17 @@ class OpenAIServer:
                 for kind, n in sorted(getattr(
                         eng, f"num_{mixer}_rows", {}).items()):
                     c.counter(rows_series, n, {**lbl, "kind": kind})
+                if mixer == "retention":
+                    # chunk rows that started their sequence: the chunk
+                    # kernel read no state for them; over kind="chunk"
+                    # above, the share of rows the state's query was
+                    # skipped for
+                    c.counter(
+                        "helix_retention_chunk_rows_from_zeros_total",
+                        getattr(
+                            eng, "num_retention_chunk_rows_from_zeros", 0),
+                        lbl,
+                    )
                 c.counter(
                     "helix_state_bytes_touched_total",
                     getattr(eng, "state_bytes_touched", 0), lbl,

@@ -230,12 +230,105 @@ def test_decode_kernel_in_interpret_mode_against_the_recurrence(live):
     np.testing.assert_allclose(y1, y0, rtol=1e-4, atol=1e-4)
 
 
+def _recurrence(q, k, v, lg, S0, Z0):
+    """The token-by-token recurrence over one row from ``(S0, Z0)``."""
+    def step(state, x):
+        y, S, Z = R.retention_step(*(a[None] for a in x), *state)
+        return (S, Z), y[0]
+
+    (S, Z), y = jax.lax.scan(step, (S0[None], Z0[None]), (q, k, v, lg))
+    return y, S[0], Z[0]
+
+
+NO_SLOT = 2 ** 31 - 1
+# (tokens on the axis, t0, qlen, hist, slots) over a pool of 5 slots
+CHUNK_PLANS = {
+    "a_row_from_zeros": (128, (0,), (128,), (0,), (3,)),
+    "a_row_from_a_state": (128, (0,), (128,), (77,), (3,)),
+    # uneven rows back to back, two of them in one block of 128 tokens, one
+    # across a block's edge, history on some; then a row with no token
+    "a_wave": (256, (0, 12, 150, 180), (12, 138, 30, 0), (5, 0, 7, 0),
+               (2, 0, 4, 3)),
+    "a_row_without_a_slot": (32, (0,), (20,), (0,), (NO_SLOT,)),
+    "padding_and_idle_rows": (64, (0, 9, 9), (9, 0, 0), (4, 0, 3),
+                              (1, 0, NO_SLOT)),
+    "a_row_from_zeros_in_a_slot_of_nan": (
+        200, (0, 150), (150, 50), (0, 9), (3, 1)),
+    "tokens_that_are_no_multiple_of_the_block": (
+        200, (0, 131), (131, 69), (40, 0), (4, 2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_PLANS))
+def test_chunk_kernel_in_interpret_mode_against_both_references(case):
+    """``retention_rows`` on the kernel path (``retention_chunk_tpu`` in
+    interpret mode) against ``retention_chunk`` a row and against the
+    token-by-token recurrence: outputs, the state each row's slot is left
+    with, and every other slot and layer bit for bit."""
+    T, t0, qlen, hist, slots = CHUNK_PLANS[case]
+    d, KVH, N = 16, 2, 5
+    q, k, v, lg = _draw(T, seed=len(case))
+    # a token's own score is kept away from 0: where a row's first scores
+    # are near eps the RECURRENCE is ill-conditioned (its numerator goes
+    # through phi, its normaliser does not), and no form agrees with it
+    kq = jnp.repeat(k, q.shape[1] // KVH, axis=1)
+    q = q + kq / jnp.linalg.norm(kq, axis=-1, keepdims=True)
+    S = jax.random.normal(
+        jax.random.PRNGKey(9), (2, N, KVH, R.held_rows(d), d))
+    Z = jax.random.normal(jax.random.PRNGKey(10), (2, N, KVH, d, d))
+    if "nan" in case:
+        S, Z = S.at[:, 3].set(jnp.nan), Z.at[:, 3].set(jnp.nan)
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    y, S1, Z1 = jax.jit(
+        R.retention_rows, static_argnames=("backend", "interpret"))(
+        q, k, v, lg, i32(t0), i32(qlen), i32(hist), i32(slots), S, Z, 1,
+        backend="pallas", interpret=True)
+    at = jnp.arange(T)
+    written, owned = set(), np.zeros(T, bool)
+    for lo, n, h, slot in zip(t0, qlen, hist, slots):
+        if not n:
+            continue
+        held = slot < N
+        keep = held and h > 0
+        S0 = S[1, slot] if keep else jnp.zeros_like(S[1, 0])
+        Z0 = Z[1, slot] if keep else jnp.zeros_like(Z[1, 0])
+        row = slice(lo, lo + n)
+        owned[row] = True
+        yc, Sc, Zc = R.retention_chunk(
+            q, k, v, lg, (at >= lo) & (at < lo + n), S0, Z0)
+        yr, Sr, Zr = _recurrence(q[row], k[row], v[row], lg[row], S0, Z0)
+        assert np.isfinite(np.asarray(y[row])).all()
+        np.testing.assert_allclose(y[row], yc[row], rtol=5e-5, atol=2e-5)
+        np.testing.assert_allclose(y[row], yr, rtol=5e-5, atol=2e-5)
+        if held:
+            written.add(slot)
+            for got, chunked, stepped in ((S1, Sc, Sr), (Z1, Zc, Zr)):
+                np.testing.assert_allclose(
+                    got[1, slot], chunked, rtol=5e-5, atol=2e-5)
+                np.testing.assert_allclose(
+                    got[1, slot], stepped, rtol=5e-5, atol=2e-5)
+    assert not np.any(np.asarray(y)[~owned])
+    for pool, was in ((S1, S), (Z1, Z)):
+        pool, was = np.asarray(pool), np.asarray(was)
+        assert np.array_equal(pool[0], was[0], equal_nan=True)
+        for slot in set(range(N)) - written:
+            assert np.array_equal(pool[1, slot], was[1, slot], equal_nan=True)
+
+
 def test_kernel_geometry_mosaic_refuses_is_refused_by_name():
     check_retention_geometry(40, 8, 128)
     with pytest.raises(UnsupportedKernelGeometry, match="128 lanes"):
         check_retention_geometry(4, 2, 16)
     with pytest.raises(UnsupportedKernelGeometry, match="divide"):
         check_retention_geometry(40, 7, 128)
+    # the chunked form's kernel path refuses what the decode kernel does
+    q, k, v, lg = _draw(16)
+    one = jnp.ones((1,), jnp.int32)
+    with pytest.raises(UnsupportedKernelGeometry, match="128 lanes"):
+        R.retention_rows(
+            q, k, v, lg, 0 * one, 16 * one, 0 * one, one,
+            jnp.zeros((1, 2, 2, R.held_rows(16), 16)),
+            jnp.zeros((1, 2, 2, 16, 16)), 0, backend="pallas")
 
 
 # ---- the model --------------------------------------------------------------
@@ -321,27 +414,35 @@ def test_page_pool_holds_no_bytes_and_the_state_pool_follows_the_kind():
 # ---- the engine -------------------------------------------------------------
 
 
-def test_chunked_prefill_then_decode_through_the_state_is_the_reference(model):
+def _chunked_prefill_then_decode(eng, params):
     """A 37-token prompt in three chunks beside a second request (mixed
-    steps), then decode steps: next-token logits against the reference's
-    full forward at every step, and each fault over the limit at every
-    step."""
-    cfg, params = model
-    eng = _engine(cfg, params)
+    steps), then decode steps.  Returns the watched request's next-token
+    logits at every step, the reference's, the sequence and the rows."""
     prompt = tokens_of(37, 0)
     req, other = _req("a", prompt, 7), _req("b", tokens_of(11, 1), 9)
     got = _run(eng, [req, other], req)
     assert len(got) >= 6 and eng.num_mixed_steps >= 1
     assert eng.num_retention_rows["chunk"] >= 4
-    assert eng.num_retention_rows["decode"] >= 12
-    per_slot = eng.recurrent_state_bytes // 3
-    assert eng.state_bytes_touched == 2 * per_slot * sum(
-        eng.num_retention_rows.values())
+    assert 2 <= eng.num_retention_chunk_rows_from_zeros < (
+        eng.num_retention_rows["chunk"])
     seq = jnp.asarray(prompt + req.output_tokens)
     at = [len(prompt) + n - 1 for n in sorted(got)]
     mine = np.stack([got[n] for n in sorted(got)])
     want = np.asarray(reference.forward(params, HF, seq, rows=at, block=16))
     assert np.abs(mine - want).max() < TOL
+    return mine, want, seq, at
+
+
+def test_chunked_prefill_then_decode_through_the_state_is_the_reference(model):
+    """Next-token logits against the reference's full forward at every
+    step, and each fault over the limit at every step."""
+    cfg, params = model
+    eng = _engine(cfg, params)
+    mine, want, seq, at = _chunked_prefill_then_decode(eng, params)
+    assert eng.num_retention_rows["decode"] >= 12
+    per_slot = eng.recurrent_state_bytes // 3
+    assert eng.state_bytes_touched == 2 * per_slot * sum(
+        eng.num_retention_rows.values())
     for kw in (dict(state_bf16=True), dict(gate=False),
                dict(normaliser=False), dict(cross_sqrt2=False),
                dict(zero_state_at=32)):
@@ -350,6 +451,26 @@ def test_chunked_prefill_then_decode_through_the_state_is_the_reference(model):
         least = min(_rel(b, w) for b, w in zip(bad, want))
         assert least > FAULT_LIMIT, (kw, least)
         assert max(_rel(m, w) for m, w in zip(mine, want)) < least / 100
+
+
+def test_chunked_prefill_then_decode_on_the_kernel_path(model, monkeypatch):
+    """The same prompt in three chunks beside a second request, then decode
+    steps, with the engine on ``backend="pallas"``: the chunk kernel and the
+    decode kernel in interpret mode (the test steers that, and lets width 16
+    past the geometry only Mosaic refuses), against the reference's full
+    forward at every step."""
+    import functools
+
+    from helix_tpu.ops import retention_kernel
+
+    cfg, params = model
+    monkeypatch.setattr(
+        retention_kernel, "check_retention_geometry", lambda *a: None)
+    for name in ("retention_rows", "retention_decode"):
+        monkeypatch.setattr(
+            R, name, functools.partial(getattr(R, name), interpret=True))
+    _chunked_prefill_then_decode(
+        _engine(cfg, params, attn_backend="pallas"), params)
 
 
 def test_a_mixed_step_gives_each_row_what_it_gets_alone(model):
@@ -549,3 +670,53 @@ def test_launch_record_and_metrics_carry_the_retention_layers(model):
         kw["retention_layers"] == 3 and kw["attn_layers"] == 0
         and "conv_layers" not in kw for kw in seen)
     assert eng.recurrent_state_bytes == 3 * 3 * 2 * (192 + 16) * 16 * 4
+    # the one prompt is one chunk row, and it starts its sequence
+    assert sum(kw["retention_chunk_rows"] for kw in seen) == 1
+    assert sum(kw["retention_chunk_rows_from_zeros"] for kw in seen) == 1
+    assert eng.num_retention_chunk_rows_from_zeros == 1
+    # a 40-token prompt in three chunks: one row of three starts it
+    long = _req("b", tokens_of(40, 2), 2)
+    _run(eng, [long], long)
+    assert eng.num_retention_rows["chunk"] == 4
+    assert eng.num_retention_chunk_rows_from_zeros == 2
+
+
+def test_metrics_and_flight_records_carry_the_rows_from_zeros(model):
+    """``helix_retention_chunk_rows_from_zeros_total`` beside
+    ``helix_retention_rows_total{kind="chunk"}`` on ``/metrics``, and both
+    as a step's deltas in its flight record."""
+    import threading
+
+    from helix_tpu.serving.engine_loop import EngineLoop
+    from helix_tpu.serving.openai_api import OpenAIServer
+    from helix_tpu.serving.registry import ModelRegistry, ServedModel
+    from helix_tpu.serving.tokenizer import ByteTokenizer
+
+    cfg, params = model
+    eng = _engine(cfg, params)
+    loop = EngineLoop(eng, "tiny-brumby")        # never started: inline
+    done = threading.Event()
+    loop.submit(_req("m", tokens_of(37, 3), 4),
+                lambda e: done.set() if e.finished else None)
+    for _ in range(200):
+        if done.is_set():
+            break
+        assert loop._pass()
+    assert done.is_set()
+    records = loop.flight.snapshot()["recent"]
+    # a 37-token prompt in chunks of 16: three rows, the first from zeros
+    assert sum(r["retention_chunk_rows"] for r in records) == 3
+    assert sum(r["retention_chunk_rows_from_zeros"] for r in records) == 1
+    registry = ModelRegistry()
+    registry.register(ServedModel(
+        name="tiny-brumby", loop=loop, tokenizer=ByteTokenizer(),
+        context_length=128))
+    text = OpenAIServer(registry).obs.render()
+
+    def value(series, label=""):
+        line = next(ln for ln in text.splitlines()
+                    if ln.startswith(series) and label in ln)
+        return float(line.rsplit(" ", 1)[1])
+
+    assert value("helix_retention_rows_total{", 'kind="chunk"') == 3
+    assert value("helix_retention_chunk_rows_from_zeros_total{") == 1

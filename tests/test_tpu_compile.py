@@ -492,6 +492,38 @@ def test_retention_decode_kernel_compiles_at_the_published_geometry(one_chip):
     assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
+@pytest.mark.parametrize("tokens,rows", [(512, 1), (512, 24), (16, 24)],
+                         ids=["one_row", "wave", "short_wave"])
+def test_retention_chunked_form_compiles_at_the_published_geometry(
+        one_chip, tokens, rows):
+    """Brumby's chunked form over a prefill segment (``ops/retention.py::
+    retention_rows``): the state-free half in XLA and the chunk kernel, 8
+    kv heads of 5 query heads, a state of 8,704 x 128 a head in a pool of
+    ten layers and 24 slots, donated: aliased to its output, and no
+    ``phi(Q)`` (89 MB a kv head) among the temporaries."""
+    import functools
+
+    from helix_tpu.ops.retention import held_rows, retention_rows
+
+    H, KVH, d, L, N = 40, 8, 128, 10, 24
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    vec = S((rows,), jnp.int32)
+    compiled = jax.jit(
+        functools.partial(retention_rows, backend="pallas"),
+        donate_argnums=(8, 9)).lower(
+        S((tokens, H, d)), S((tokens, KVH, d)), S((tokens, KVH, d)),
+        S((tokens, KVH)), vec, vec, vec, vec,
+        S((L, N, KVH, held_rows(d), d)), S((L, N, KVH, d, d)),
+        S((), jnp.int32)).compile()
+    assert "retention_chunk_tpu" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * N * KVH * (held_rows(d) + d) * d * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
 def test_retention_step_compiles_at_published_widths(one_chip, program):
     """A whole engine step of Brumby-14B cut to two layers (int8 weights, 24
