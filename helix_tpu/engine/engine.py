@@ -68,6 +68,7 @@ from helix_tpu.engine.sampling import (
     split_keys,
 )
 from helix_tpu.models.common import ModelConfig
+from helix_tpu.models.mixers import STATE_MIXERS
 from helix_tpu.models.llama import forward, lm_head
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.slo import ANON_TENANT
@@ -712,110 +713,65 @@ class UnsupportedForModel(ValueError):
 # What an architecture is not served with: (engine setting, what of the
 # model meets it, why).  Each row is refused by name when the engine is
 # built, rather than run on a path that was never written for a pool with
-# no head axis, or with a sequence's recurrent state left behind.
-_MULTI_DEVICE = ("a mesh of more than one device",
-                 lambda cfg, mesh: mesh is not None and mesh.devices.size > 1)
-_INT8_KV = ("kv_cache_dtype int8",
-            lambda cfg, mesh: cfg.kv_cache_dtype == "int8")
-_ADAPTERS = ("adapter_pool_slots > 0",
-             lambda cfg, mesh: cfg.adapter_pool_slots > 0)
-_SPEC = ("enable_spec_decode", lambda cfg, mesh: cfg.enable_spec_decode)
-_TIERED = ("ctx_hot_pages > 0", lambda cfg, mesh: cfg.ctx_hot_pages > 0)
-_HOST_TIER = ("host_pool_bytes > 0",
-              lambda cfg, mesh: cfg.host_pool_bytes > 0)
+# no head axis, or with a sequence's recurrent state left behind.  A kind of
+# layer with a per-sequence state brings its rows in its record
+# (``models/mixers.py``: ``refused_as``, ``refusals`` by these keys).
+_SETTINGS = {
+    "multi_device": (
+        "a mesh of more than one device",
+        lambda cfg, mesh: mesh is not None and mesh.devices.size > 1),
+    "int8_kv": ("kv_cache_dtype int8",
+                lambda cfg, mesh: cfg.kv_cache_dtype == "int8"),
+    "adapters": ("adapter_pool_slots > 0",
+                 lambda cfg, mesh: cfg.adapter_pool_slots > 0),
+    "spec_decode": ("enable_spec_decode",
+                    lambda cfg, mesh: cfg.enable_spec_decode),
+    "tiered": ("ctx_hot_pages > 0", lambda cfg, mesh: cfg.ctx_hot_pages > 0),
+    "host_tier": ("host_pool_bytes > 0",
+                  lambda cfg, mesh: cfg.host_pool_bytes > 0),
+    "prefix_cache": ("enable_prefix_cache",
+                     lambda cfg, mesh: cfg.enable_prefix_cache),
+}
 _LATENT = ("latent attention (MLA)", lambda m: m.is_mla)
-_RECURRENT = ("recurrent state (gated short convolutions)",
-              lambda m: m.num_conv_layers > 0)
 _PACKED_HEADS = ("kv heads packed into one lane tile (head width under "
                  "128)", lambda m: m.kv_head_pack > 1)
-_PREFIX_CACHE = ("enable_prefix_cache",
-                 lambda cfg, mesh: cfg.enable_prefix_cache)
-_MATRIX_STATE = ("a matrix state (power retention)",
-                 lambda m: m.num_retention_layers > 0)
-_DELTA_STATE = ("a matrix state and a conv tail (gated delta rule)",
-                lambda m: m.num_deltanet_layers > 0)
 _HELD_EXPERTS = ("held experts (one expert-parallel rank of the routed "
                  "experts)", lambda m: m.held_experts is not None)
-_WINDOW_RING = ("a ring of K/V a slot (sliding-window attention)",
-                lambda m: m.num_window_layers > 0)
+
+
+def _state_rows(kind) -> tuple:
+    """A state kind's rows of ``_REFUSALS``, from its record."""
+    prop = (kind.refused_as, lambda m: m.state_kind is kind)
+    return tuple((setting, prop, why) for setting, why in kind.refusals)
+
+
+# the first row met is the one raised, so the rows stand in the order they
+# were written: the state kinds' in ``STATE_MIXERS``' order, the packed
+# heads' row behind the first kind's and the held experts' behind the third's
+_KIND_ROWS = [_state_rows(kind) for kind in STATE_MIXERS.values()]
 _REFUSALS = (
-    (_MULTI_DEVICE, _LATENT,
+    ("multi_device", _LATENT,
      "the latent pool and its kernel are single-device: mesh {tp: 1}"),
-    (_INT8_KV, _LATENT, "the latent pool is bf16 or f32"),
-    (_ADAPTERS, _LATENT, "no LoRA targets on MLA projections"),
-    (_SPEC, _LATENT, "untested on the latent kernel"),
-    (_TIERED, _LATENT, "tiered residency streams K/V chunks"),
-    (_MULTI_DEVICE, _RECURRENT,
-     "the state pool and the conv operator are single-device"),
-    (_INT8_KV, _RECURRENT, "the page pool beside a state pool is bf16 or "
-     "f32"),
-    (_ADAPTERS, _RECURRENT, "no LoRA targets on the conv projections"),
-    (_SPEC, _RECURRENT,
-     "a rejected draft would have to roll the conv state back"),
-    (_TIERED, _RECURRENT,
-     "a demoted cold middle is resumed without the state at its end"),
-    (_HOST_TIER, _RECURRENT,
-     "a spilled prefix or a preempted sequence's pages come back "
-     "without the state"),
-    (_INT8_KV, _PACKED_HEADS, "an int8 pool's scales are one a kv head"),
-    (_MULTI_DEVICE, _MATRIX_STATE,
-     "the state pool and the retention kernel are single-device"),
-    (_INT8_KV, _MATRIX_STATE,
-     "no page holds bytes, and the state is a float32 running sum"),
-    (_ADAPTERS, _MATRIX_STATE, "no LoRA targets on the retention "
-     "projections"),
-    (_SPEC, _MATRIX_STATE,
-     "a rejected draft would have to roll the matrix state back"),
-    (_TIERED, _MATRIX_STATE, "there is no page of KV to demote"),
-    (_HOST_TIER, _MATRIX_STATE,
-     "a preempted sequence's state (tens of MB a layer) has no host tier"),
-    (_PREFIX_CACHE, _MATRIX_STATE,
-     "a filed state is tens of MB a layer: a snapshot budget and an "
-     "eviction of its own; set enable_prefix_cache: false"),
-    (_MULTI_DEVICE, _DELTA_STATE,
-     "the state pool and the delta-rule kernel are single-device"),
-    (_INT8_KV, _DELTA_STATE,
-     "the pool beside a state pool is bf16 or f32, and the state is a "
-     "float32 matrix"),
-    (_ADAPTERS, _DELTA_STATE, "no LoRA targets on the delta-rule "
-     "projections"),
-    (_SPEC, _DELTA_STATE,
-     "a rejected draft would have to roll the matrix state back"),
-    (_TIERED, _DELTA_STATE,
-     "a demoted cold middle is resumed without the state at its end"),
-    (_HOST_TIER, _DELTA_STATE,
-     "a preempted sequence's state (megabytes a layer) has no host tier"),
-    (_PREFIX_CACHE, _DELTA_STATE,
-     "a filed state is megabytes a layer: a snapshot budget and an "
-     "eviction of its own; set enable_prefix_cache: false"),
-    (_MULTI_DEVICE, _HELD_EXPERTS,
+    ("int8_kv", _LATENT, "the latent pool is bf16 or f32"),
+    ("adapters", _LATENT, "no LoRA targets on MLA projections"),
+    ("spec_decode", _LATENT, "untested on the latent kernel"),
+    ("tiered", _LATENT, "tiered residency streams K/V chunks"),
+    *_KIND_ROWS[0],
+    ("int8_kv", _PACKED_HEADS, "an int8 pool's scales are one a kv head"),
+    *_KIND_ROWS[1],
+    *_KIND_ROWS[2],
+    ("multi_device", _HELD_EXPERTS,
      "the other ranks' experts and the exchange with them are not run: "
      "one chip computes its own experts' part of the sum"),
-    (_MULTI_DEVICE, _WINDOW_RING,
-     "the rings and the window kernel are single-device"),
-    (_INT8_KV, _WINDOW_RING,
-     "the rings and the pages beside them are bf16 or f32: a ring row has "
-     "no scale"),
-    (_ADAPTERS, _WINDOW_RING, "no LoRA targets on the window layers' "
-     "projections"),
-    (_SPEC, _WINDOW_RING,
-     "a rejected draft's K/V would have overwritten ring rows the window "
-     "still needs"),
-    (_TIERED, _WINDOW_RING,
-     "a demoted cold middle is resumed without the ring at its end"),
-    (_HOST_TIER, _WINDOW_RING,
-     "a spilled prefix or a preempted sequence's pages come back without "
-     "the ring"),
-    (_PREFIX_CACHE, _WINDOW_RING,
-     "a hit would need the ring as it stood at the prefix's boundary: no "
-     "step files it; set enable_prefix_cache: false"),
+    *(row for rows in _KIND_ROWS[3:] for row in rows),
 )
 
 
 def refuse_unsupported(model_cfg, cfg, mesh) -> None:
     """Raise :class:`UnsupportedForModel` for the first row of
     ``_REFUSALS`` that the engine settings and the model both meet."""
-    for (setting, is_set), (prop, has), why in _REFUSALS:
+    for key, (prop, has), why in _REFUSALS:
+        setting, is_set = _SETTINGS[key]
         if has(model_cfg) and is_set(cfg, mesh):
             raise UnsupportedForModel(
                 f"{model_cfg.name}: {prop} is not served with {setting} "
@@ -826,29 +782,11 @@ def refuse_unsupported(model_cfg, cfg, mesh) -> None:
 def _refuse_call(model_cfg, what: str) -> None:
     """Paths that move a sequence's pages and are asked for by a call, not
     a setting: refused for a model whose sequences carry a state too."""
-    if model_cfg.num_conv_layers:
+    kind = model_cfg.state_kind
+    if kind is not None:
         raise UnsupportedForModel(
-            f"{model_cfg.name}: recurrent state (gated short "
-            f"convolutions) is not served with {what} (the sequence's "
-            "conv state has no place in what it moves)"
-        )
-    if model_cfg.num_retention_layers:
-        raise UnsupportedForModel(
-            f"{model_cfg.name}: a matrix state (power retention) is not "
-            f"served with {what} (the sequence's state has no place in "
-            "what it moves, and it has no page of KV)"
-        )
-    if model_cfg.num_deltanet_layers:
-        raise UnsupportedForModel(
-            f"{model_cfg.name}: a matrix state and a conv tail (gated "
-            f"delta rule) is not served with {what} (the sequence's state "
-            "has no place in what it moves)"
-        )
-    if model_cfg.num_window_layers:
-        raise UnsupportedForModel(
-            f"{model_cfg.name}: a ring of K/V a slot (sliding-window "
-            f"attention) is not served with {what} (the sequence's rings "
-            "have no place in what it moves)"
+            f"{model_cfg.name}: {kind.refused_as} is not served with "
+            f"{what} ({kind.call_refusal})"
         )
 
 
@@ -876,213 +814,6 @@ def _cache_from(pc, cache: PagedKVCache) -> PagedKVCache:
     return PagedKVCache.from_carry(pc[:-1], pc[-1])
 
 
-def _state_after(zf, S, t0, n):
-    """The conv state of each row after ``n [R]`` of its fresh tokens: the
-    last ``K - 1`` of (the state it came with, its first ``n`` tokens),
-    oldest first.  ``zf [T, E]`` flat, ``S [R, K - 1, E]``, ``t0 [R]``."""
-    K1 = S.shape[1]
-    T = zf.shape[0]
-    out = []
-    for i in range(K1):
-        at = n + i - K1                      # offset in the row, < 0: in S
-        fresh = zf[jnp.clip(t0 + at, 0, T - 1)]
-        old = S[:, 0]
-        for m in range(1, K1):
-            old = jnp.where((n + i == m)[:, None], S[:, m], old)
-        out.append(jnp.where((at >= 0)[:, None], fresh, old))
-    return jnp.stack(out, axis=1)
-
-
-def _conv_rows(z, taps, pool, lc, t0, qlen, hist, slots, snap=None):
-    """A causal depthwise convolution over one segment of the step, its
-    tokens row after row on one flat axis: row ``r`` the ``qlen[r]`` tokens
-    from ``t0[r]`` of the sequence in slot ``slots[r]``, which has
-    ``hist[r]`` tokens behind it.  ``z [B, S, E]``, ``taps [E, K]``, ``pool
-    [layers, slots, K - 1, E]`` read and written at layer ``lc``.
-
-    A token's tap ``d`` back is its flat neighbour if that is in its own
-    row, else its row's state (zeros for a row that starts its sequence):
-    never the neighbour row's token.  The row's new state, the last ``K -
-    1`` of (state, the row's inputs), is written to its slot; a row with
-    no fresh token (an idle slot, padding) and a slot index past the pool
-    write nothing.  Returns ``(y float32, pool, each row's state after
-    snap[r] of its tokens or None)``."""
-    from helix_tpu.models.llama import short_conv
-
-    Bz, Sz, E = z.shape
-    T, K1 = Bz * Sz, taps.shape[-1] - 1
-    zf = z.reshape(T, E)
-    nslots = pool.shape[1]
-    S = pool[lc][jnp.clip(slots, 0, nslots - 1)]            # [R, K-1, E]
-    S = jnp.where((hist > 0)[:, None, None], S, 0).astype(z.dtype)
-    prevs = []
-    for d in range(1, K1 + 1):
-        if Sz == 1:
-            # one-token rows (a decode step): every tap is the state
-            prev = S[:, K1 - d]
-        else:
-            prev = jnp.pad(zf, ((d, 0), (0, 0)))[:T]
-            for j in range(d):
-                # the row's token j reaches d back past its start
-                at = jnp.where(qlen > j, t0 + j, T)
-                prev = prev.at[at].set(S[:, K1 + j - d], mode="drop")
-        prevs.append(prev.reshape(z.shape))
-    y = short_conv(z, taps, prevs)
-    new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
-    dest = jnp.where(qlen > 0, slots, nslots)
-    pool = pool.at[lc, dest].set(new, mode="drop")
-    return y, pool, None if snap is None else _state_after(
-        zf, S, t0, snap).astype(pool.dtype)
-
-
-def _conv_rows_fn(t0, qlen, hist, slots, snap=None):
-    """The ``conv_fn`` of one segment of the step (``models/llama.py::
-    _conv_mixer``): ``_conv_rows`` on the state pool in the carry.  ``snap
-    [R]``: also hand back each row's state after that many of its tokens
-    (what a prefix hit resumes from), stacked over the conv layers in the
-    carry's last element (a segment without ``snap`` passes that element
-    on).
-
-    The carry it is called with is ``((page carry, kacc, vacc, state pool[,
-    snaps]), conv layer index)``."""
-
-    def conv_fn(z, taps, carry_cache):
-        (caches, kacc, vacc, pool, *snaps), lc = carry_cache
-        y, pool, after = _conv_rows(
-            z, taps, pool, lc, t0, qlen, hist, slots, snap)
-        if after is not None:
-            snaps = [snaps[0].at[lc].set(after)]
-        return y, (caches, kacc, vacc, pool, *snaps)
-
-    return conv_fn
-
-
-def _deltanet_rows_fn(cfg, t0, qlen, hist, slots, backend, decode: bool):
-    """The ``deltanet_fn`` of one segment of the step (``models/llama.py::
-    _deltanet_mixer``), under ``_conv_rows_fn``'s contract for TWO states a
-    slot: the convolution's tail (``_conv_rows``, the same look-back as a
-    gated short convolution's at 4 taps over the q | k | v channels) and the
-    float32 matrix a value head, both read and written IN PLACE in the
-    carry's pair of pools.
-
-    ``decode``: the segment's rows are one token each and row ``b`` is slot
-    ``b`` (the rule applied once: on a TPU one pass of the decode kernel
-    over the live slots).  Else the rows are runs of fresh tokens on one
-    flat axis (the chunked form, 64 tokens at a time: what does not read
-    the state for all the rows' chunks at once, then the chunks in order,
-    on a TPU in the chunk kernel).
-
-    Called ``(x W_qkv, g, beta, taps, carry)``, the carry ``((page carry,
-    kacc, vacc, (conv pool, S pool)), delta layer index)``."""
-    from helix_tpu.models.llama import deltanet_heads
-    from helix_tpu.ops.deltanet import delta_decode, delta_rows
-
-    def deltanet_fn(x, g, beta, taps, carry_cache):
-        (caches, kacc, vacc, (c_pool, s_pool)), lc = carry_cache
-        Bx, Sx, _ = x.shape
-        with jax.named_scope("deltanet.conv"):
-            y, c_pool, _ = _conv_rows(
-                x, taps, c_pool, lc, t0, qlen, hist, slots)
-            q, k, v = deltanet_heads(y, cfg)
-        with jax.named_scope("deltanet.mix"):
-            if decode:
-                o, s_pool = delta_decode(
-                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s_pool,
-                    lc, qlen > 0, backend=backend)
-            else:
-                flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
-                o, s_pool = delta_rows(
-                    flat(q), flat(k), flat(v), flat(g), flat(beta), t0,
-                    qlen, hist, slots, s_pool, lc, backend=backend)
-        return o.reshape((Bx, Sx) + o.shape[1:]), (
-            caches, kacc, vacc, (c_pool, s_pool))
-
-    return deltanet_fn
-
-
-def _window_rows_fn(t0, qlen, hist, slots, backend, packed=None):
-    """The ``window_fn`` of one segment of the step (``models/llama.py::
-    _layer``, a window layer), under ``_conv_rows_fn``'s contract: row ``r``
-    is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
-    ``slots[r]`` with ``hist[r]`` tokens behind it, of which its slot's
-    rings hold the last ``W``.  The row's queries read the rings AS THEY
-    STAND (``ops.window.window_attention``: a decode step's one-token rows,
-    a chunk that continues a prompt), its fresh K/V beside them; then its
-    fresh K/V land in the rings (``write_ring``: a chunk of ``W`` replaces
-    the ring, a shorter one rotates into it).  A ring is not cleared for a
-    new sequence: a row with no history reads none of it and the mask by
-    position hides what it has not written.  A row with no fresh token (an
-    idle slot, padding) and a row without a slot write nothing.
-
-    ``packed = (positions, segment ids, mesh)``: no row of the segment has
-    history (a cold packed wave, a first chunk), so its attention is the
-    packed self-attention under the window beside the segment mask, with no
-    pool read.
-
-    Called ``(q, k, v, carry)``, the carry ``((page carry, kacc, vacc, (K
-    rings, V rings)), window layer index)``."""
-    from helix_tpu.ops.window import window_attention, write_ring
-
-    def window_fn(q, k, v, carry_cache):
-        (caches, kacc, vacc, (k_ring, v_ring)), lc = carry_cache
-        Bq, Sq, H, D = q.shape
-        flat = lambda a: a.reshape((Bq * Sq,) + a.shape[2:])
-        if packed is not None:
-            pos, seg, mesh = packed
-            out = full_attention(
-                q, k, v, causal=True, q_positions=pos, kv_positions=pos,
-                q_segment_ids=seg, kv_segment_ids=seg, backend=backend,
-                mesh=mesh, window=k_ring.shape[2])
-        else:
-            out = window_attention(
-                flat(q), flat(k), flat(v), k_ring, v_ring, lc, t0, qlen,
-                hist, slots, backend=backend, max_q_len=Sq,
-            ).reshape(q.shape)
-        k_ring, v_ring = write_ring(
-            k_ring, v_ring, lc, flat(k), flat(v), t0, qlen, hist, slots)
-        return out, (caches, kacc, vacc, (k_ring, v_ring))
-
-    return window_fn
-
-
-def _retention_rows_fn(t0, qlen, hist, slots, backend, decode: bool):
-    """The ``retention_fn`` of one segment of the step (``models/llama.py::
-    _retention_mixer``), under ``_conv_rows_fn``'s contract for a state
-    thousands of times the size: row ``r`` is the ``qlen[r]`` tokens from
-    ``t0[r]`` of the sequence in slot ``slots[r]`` with ``hist[r]`` tokens
-    behind it; a row that starts its sequence starts from zeros; a row with
-    no fresh token (an idle slot, padding) and a row without a slot write
-    nothing.  The pools ride the carry and are updated IN PLACE.
-
-    ``decode``: the segment's rows are one token each and row ``b`` is slot
-    ``b`` (the recurrence applied once: on a TPU one pass of the decode
-    kernel over the live slots).  Else the rows are runs of fresh tokens on
-    one flat axis (the chunked form: on a TPU what reads no state once for
-    the axis, then the chunk kernel a row, which reads a row's state once,
-    or not at all where the row starts its sequence, and writes it once).
-
-    The carry it is called with is ``((page carry, kacc, vacc, (S pool, Z
-    pool)), retention layer index)``."""
-    from helix_tpu.ops.retention import retention_decode, retention_rows
-
-    def retention_fn(q, k, v, log_g, carry_cache):
-        (caches, kacc, vacc, (s_pool, z_pool)), lc = carry_cache
-        Bq, Sq, H, D = q.shape
-        if decode:
-            y, s_pool, z_pool = retention_decode(
-                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], s_pool, z_pool, lc,
-                qlen > 0, backend=backend)
-        else:
-            y, s_pool, z_pool = retention_rows(
-                q.reshape(Bq * Sq, H, D), k.reshape(Bq * Sq, -1, D),
-                v.reshape(Bq * Sq, -1, D), log_g.reshape(Bq * Sq, -1),
-                t0, qlen, hist, slots, s_pool, z_pool, lc, backend=backend)
-        return y.reshape(Bq, Sq, H, D), (caches, kacc, vacc,
-                                         (s_pool, z_pool))
-
-    return retention_fn
-
-
 def _segments_fn(fn_p, fn_s, n_tok: int, split, join):
     """A mixer's look-back over the step's whole token axis, from one
     function a segment: the first ``n_tok`` arguments are token arrays and
@@ -1108,30 +839,21 @@ def _segments_fn(fn_p, fn_s, n_tok: int, split, join):
     return fn
 
 
-def _state_rows_fns(cfg, rows_s, backend, rows_p=None, split=None,
-                    join=None, packed=None) -> dict:
-    """``forward``'s look-back argument for the model's recurrent mixer:
-    ``rows_s = (t0, qlen, hist, slots)`` the state rows (one token each,
-    row ``b`` slot ``b``), ``rows_p = (t0, qlen, hist, slots, snap)`` the
-    prefill rows before them on the axis, if the program has any.
-    ``packed``: the prefill rows have no history (``_window_rows_fn``)."""
-    if cfg.state_mixer == "window":
-        attend = _segments_fn(
-            rows_p and _window_rows_fn(*rows_p[:4], backend, packed),
-            _window_rows_fn(*rows_s, backend), 3, split, join)
-        return {"window_fn": lambda q, k, v, carry, pos: attend(
-            q, k, v, carry)}
-    if cfg.state_mixer == "retention":
-        return {"retention_fn": _segments_fn(
-            rows_p and _retention_rows_fn(*rows_p[:4], backend, False),
-            _retention_rows_fn(*rows_s, backend, True), 4, split, join)}
-    if cfg.state_mixer == "deltanet":
-        return {"deltanet_fn": _segments_fn(
-            rows_p and _deltanet_rows_fn(cfg, *rows_p[:4], backend, False),
-            _deltanet_rows_fn(cfg, *rows_s, backend, True), 3, split, join)}
-    return {"conv_fn": _segments_fn(
-        rows_p and _conv_rows_fn(*rows_p), _conv_rows_fn(*rows_s), 1,
-        split, join)}
+def _state_rows_fn(cfg, rows_s, backend, rows_p=None, split=None,
+                   join=None, packed=None):
+    """``forward``'s ``state_fn`` for the model's layers with a
+    per-sequence state, from their kind's record: ``rows_s = (t0, qlen,
+    hist, slots)`` the state rows (one token each, row ``b`` slot ``b``),
+    ``rows_p = (t0, qlen, hist, slots, snap)`` the prefill rows before them
+    on the axis, if the program has any.  ``packed``: the prefill rows have
+    no history (``models/mixers.py::_window_rows_fn``)."""
+    kind = cfg.state_kind
+    return _segments_fn(
+        rows_p and kind.rows_fn(
+            rows_p[:4], backend, cfg=cfg, decode=False, snap=rows_p[4],
+            packed=packed),
+        kind.rows_fn(rows_s, backend, cfg=cfg, decode=True),
+        kind.token_args, split, join)
 
 
 def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
@@ -1193,11 +915,11 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
                      *rest)
 
     carry0 = (cache.carry(), kacc0, vacc0)
-    state_fns = {}
+    state_fn = None
     if cache.state is not None:
         # the slots' recurrent states ride the carry beside the pages
         carry0 += (cache.state,)
-        state_fns = _state_rows_fns(cfg, (t0, q_len, hist, t0), backend)
+        state_fn = _state_rows_fn(cfg, (t0, q_len, hist, t0), backend)
     if cfg.mrope_sections is not None:
         from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -1226,7 +948,7 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
             adapter_ids=(
                 state.adapter_slots[:, None] if use_adapters else None
             ),
-            **state_fns,
+            state_fn=state_fn,
         )
         if pool:
             pc = pc + (pool[0],)
@@ -1291,7 +1013,7 @@ def _build_ragged_step_fn(
     another on one flat token axis ``[1, token_bucket + B * state_width]``,
     so every weight (norms, projections, MLP or experts, the head) is
     streamed once a program.  Only the token mixers are a segment's own:
-    ``attn_fn`` and the recurrent mixers (``_state_rows_fns``) split the
+    ``attn_fn`` and the state kinds' look-back (``_state_rows_fn``) split the
     axis at ``token_bucket`` (static), run the prefill rows and then the
     state rows on the same pool carry (their pages and slots are disjoint:
     a prompt in flight does not decode), and join the outputs.  The pass
@@ -1349,12 +1071,11 @@ def _build_ragged_step_fn(
     # never retrace)
     use_adapters = adapter_slots > 0
     cfg = model_cfg
-    # recurrent layers: every row reads and writes its slot's state; a
-    # conv model's prefill rows also hand back the state at one page
-    # boundary each (what a prefix hit resumes from; a matrix state is not
-    # filed: the prefix cache is refused for it)
-    has_state = cfg.state_mixer is not None
-    has_snaps = cfg.num_conv_layers > 0
+    # layers with a per-sequence state: every row reads and writes its
+    # slot's; where the kind files snapshots, the prefill rows also hand back
+    # the state at one page boundary each (what a prefix hit resumes from)
+    has_state = cfg.state_kind is not None
+    has_snaps = has_state and cfg.state_kind.snapshots
     is_moe = cfg.num_experts > 0
     is_mrope = cfg.mrope_sections is not None
     Cb = token_bucket
@@ -1507,15 +1228,15 @@ def _build_ragged_step_fn(
                               else (None, None))
             kacc_s, vacc_s = _fresh_kv_zeros(cfg, B, W)
             carry0 = (cache.carry(), (kacc_p, kacc_s), (vacc_p, vacc_s))
-            state_fns = {}
+            state_fn = None
             if has_state:
                 carry0 += (cache.state,)
                 if has_snaps and Cb > 0:
                     (shp, _), = cfg.state_arrays()
                     carry0 += (jnp.zeros(
-                        (cfg.num_conv_layers, prefill_rows) + shp,
+                        (cfg.num_state_layers, prefill_rows) + shp,
                         cache.state.dtype),)
-                state_fns = _state_rows_fns(
+                state_fn = _state_rows_fn(
                     cfg, rows_s, backend, rows_p, split, join,
                     packed=((p_pos, p_seg, mesh)
                             if Cb > 0 and not has_hist else None))
@@ -1544,7 +1265,7 @@ def _build_ragged_step_fn(
                     return_moe_stats=is_moe,
                     adapter_ids=aids,
                     moe_decode_rows=B * W if Cb > 0 else 0,
-                    **state_fns,
+                    state_fn=state_fn,
                 )
                 hidden, (pc, kacc, vacc, *rest) = res[:2]
                 if is_moe:
@@ -1694,6 +1415,9 @@ class Engine:
         rng_seed: int = 0,
     ):
         self.model_cfg = model_cfg
+        # the record of the model's kind of layer with a per-sequence state
+        # (``models/mixers.py``), None where its memory is pages alone
+        self.mixer = model_cfg.state_kind
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
@@ -1721,46 +1445,31 @@ class Engine:
 
         self._backend = resolve_backend(cfg.attn_backend)
         self.cache_cfg = cfg.cache_config(dtype=model_cfg.dtype)
+        # bytes of the state pool (0 for a model without one)
+        self.recurrent_state_bytes = self.cache_cfg.state_bytes(model_cfg)
         dev = (mesh.devices.flat[0] if mesh is not None
                else jax.devices()[0])
-        if self._backend == "pallas" and model_cfg.is_mla:
-            from helix_tpu.ops.mla_kernel import check_mla_geometry
-
-            check_mla_geometry(
-                model_cfg.num_heads, model_cfg.kv_lora_rank,
-                model_cfg.qk_rope_head_dim,
-                jnp.dtype(self.cache_cfg.dtype).itemsize,
-            )
-            if model_cfg.num_deltanet_layers:
-                from helix_tpu.ops.deltanet_kernel import (
-                    check_deltanet_geometry,
-                )
-
-                check_deltanet_geometry(
-                    model_cfg.linear_key_heads, model_cfg.linear_value_heads,
-                    model_cfg.linear_key_dim, model_cfg.linear_value_dim)
-        elif self._backend == "pallas" and not model_cfg.num_attn_layers:
-            if model_cfg.num_retention_layers:
-                from helix_tpu.ops.retention_kernel import (
-                    check_retention_geometry,
-                )
-
-                check_retention_geometry(
-                    model_cfg.num_heads, model_cfg.num_kv_heads,
-                    model_cfg.head_dim)
-        elif self._backend == "pallas":
-            from helix_tpu.ops.paged_kernel import check_geometry
-
+        if self._backend == "pallas":
             tp = head_shards(mesh)
-            # each kind of GQA layer at its own count of query heads (the
-            # window kernel takes what the ragged kernel takes)
-            for mixer in sorted({"attn", "window"} & set(model_cfg.mixers)):
-                check_geometry(
-                    model_cfg.heads_of(mixer) // tp,
-                    max(model_cfg.num_kv_heads // tp, 1),
-                    model_cfg.head_dim,
-                    jnp.dtype(self.cache_cfg.dtype).itemsize,
+            itemsize = jnp.dtype(self.cache_cfg.dtype).itemsize
+            if model_cfg.is_mla:
+                from helix_tpu.ops.mla_kernel import check_mla_geometry
+
+                check_mla_geometry(
+                    model_cfg.num_heads, model_cfg.kv_lora_rank,
+                    model_cfg.qk_rope_head_dim, itemsize,
                 )
+            elif model_cfg.num_attn_layers:
+                from helix_tpu.ops.paged_kernel import check_geometry
+
+                check_geometry(
+                    model_cfg.heads_of("attn") // tp,
+                    max(model_cfg.num_kv_heads // tp, 1),
+                    model_cfg.head_dim, itemsize,
+                )
+            if self.mixer is not None and self.mixer.check_geometry:
+                # each kind of layer at its own kernel's geometry
+                self.mixer.check_geometry(model_cfg, tp, itemsize)
         logging.getLogger(__name__).info(
             "engine %s: attention backend %s on platform %s, device_kind "
             "%s, %d device(s)",
@@ -1789,10 +1498,10 @@ class Engine:
         self._chunking: Optional[dict] = None  # in-flight chunked prefill
         from helix_tpu.engine.kv_cache import PrefixCache
 
-        # a model with conv layers: a prefix is pages AND the conv state
-        # at its end, filed by the steps that pass a page boundary
+        # a kind whose steps hand back boundary states: a prefix is pages
+        # AND the state at its end, filed by the steps that pass a boundary
         self.prefix_cache = (
-            PrefixCache(stateful=model_cfg.num_conv_layers > 0)
+            PrefixCache(stateful=bool(self.mixer and self.mixer.snapshots))
             if cfg.enable_prefix_cache else None
         )
         # page boundaries of a prompt in flight whose state a step has
@@ -1801,25 +1510,16 @@ class Engine:
         self._boundary_states: dict[str, dict] = {}
         self.num_state_snapshots = 0
         self.num_state_restores = 0
-        # a matrix state (power retention): rows of the state pool the
-        # steps read and wrote, by the form that ran them, and their bytes
-        # (what a roofline reckoned from a trace divides by)
-        self.num_retention_rows = {"decode": 0, "chunk": 0}
-        # ... and of the chunk rows, those that started their sequence: the
-        # chunk kernel skips the state's read and its query for them
-        self.num_retention_chunk_rows_from_zeros = 0
-        self.num_deltanet_rows = {"decode": 0, "chunk": 0}
-        # rings of K/V (sliding-window layers): rows that read their slot's
-        # rings, and the bytes of live ring rows they read (what a roofline
-        # reckoned from a trace divides by); ``state_bytes_touched`` counts
-        # the ring rows written
-        self.num_window_rows = {"decode": 0, "chunk": 0}
-        self.window_ring_bytes_read = 0
-        self.state_bytes_touched = 0
+        # the host's account of what the steps did to the state pool, by
+        # the keys of the kind's record (``models/mixers.py``: rows by the
+        # form that ran them, bytes moved, chunks); a launch adds its
+        # ``mixer.account`` to it, and an empty launch's is every key at 0
+        account = self.mixer.account if self.mixer else None
+        self.mixer_counts = account(
+            model_cfg, self.cache_cfg, (), np.zeros(0, np.int64), 0,
+        ) if account else {}
         # history pages the latent kernel walked (``_mla_page_fetches``)
         self.num_mla_page_fetches = 0
-        # 64-token chunks the chunked delta rule ran (``_deltanet_chunks``)
-        self.num_deltanet_chunks = 0
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -2145,52 +1845,45 @@ class Engine:
             _refuse_call(self.model_cfg, "the persistent KV filestore")
         self._kv_filestore = store
 
-    def _note_state_rows(self, plan, draft_len, n_extra) -> tuple:
-        """Count the rows of a matrix state's pool this step reads and
-        writes: a live decode row once a fused step, a prefill row with a
-        slot once; each row is every recurrent layer's state of one slot,
-        read once and written once.  Returns the launch's ``(chunk rows,
-        those of them that start their sequence)``."""
-        live = (np.asarray(draft_len) >= 0) & (
-            np.asarray(self._active_sent) > 0)
-        dec = int(np.count_nonzero(live)) * (1 + int(n_extra))
-        held = [r for r in plan.rows if r.slot >= 0] if plan else []
-        chunk = len(held)
-        from_zeros = sum(1 for r in held if r.start == 0)
-        rows = (self.num_deltanet_rows
-                if self.model_cfg.num_deltanet_layers
-                else self.num_retention_rows)
-        rows["decode"] += dec
-        rows["chunk"] += chunk
-        if self.model_cfg.num_retention_layers:
-            self.num_retention_chunk_rows_from_zeros += from_zeros
-        self.state_bytes_touched += 2 * (dec + chunk) * (
-            self.recurrent_state_bytes // self.cfg.max_decode_batch)
-        return chunk, from_zeros
+    def _live_positions(self, draft_len=None):
+        """The positions of the running rows (of a launch: those its
+        ``draft_len`` does not sit out), from the host's mirrors."""
+        live = np.asarray(self._active_sent) > 0
+        if draft_len is not None:
+            live &= np.asarray(draft_len) >= 0
+        return self._positions[live].astype(np.int64)
 
-    def _note_window_rows(self, plan, draft_len, n_extra) -> None:
-        """Count the rows of this launch that read and write their slot's
-        rings, from the host's mirrors.  A row
-        reads ``min(tokens behind it, W)`` ring rows of K and of V in every
-        window layer and writes its fresh tokens' (at most ``W``); a live
-        decode row does so once a fused step, a token further on each."""
-        m = self.model_cfg
-        W = m.sliding_window
-        per_tok = (2 * m.num_kv_heads * m.head_dim * jnp.dtype(
-            self.cache_cfg.dtype).itemsize * m.num_window_layers)
-        live = (np.asarray(draft_len) >= 0) & (
-            np.asarray(self._active_sent) > 0)
-        pos = self._positions[live].astype(np.int64)
-        steps = 1 + int(n_extra)
-        read = sum(int(np.minimum(pos + k, W).sum()) for k in range(steps))
-        wrote = len(pos) * steps
-        rows = plan.rows if plan else ()
-        read += sum(min(r.start, W) for r in rows)
-        wrote += sum(min(r.rem, W) for r in rows if r.slot >= 0)
-        self.num_window_rows["decode"] += len(pos) * steps
-        self.num_window_rows["chunk"] += len(rows)
-        self.window_ring_bytes_read += read * per_tok
-        self.state_bytes_touched += wrote * per_tok
+    def _note_mixer(self, plan, draft_len, n_extra) -> dict:
+        """Add the launch's account to ``mixer_counts``; returns what the
+        launch's span shows of it (the record's ``launch`` attributes): the
+        launch's own increments and the levels."""
+        m, inc = self.mixer, {}
+        if m.account is not None:
+            inc = m.account(
+                self.model_cfg, self.cache_cfg, plan.rows if plan else (),
+                self._live_positions(draft_len), n_extra)
+            for key, n in inc.items():
+                self.mixer_counts[key] += n
+        shown = {**inc, "layers": self.model_cfg.num_state_layers,
+                 **self.mixer_gauges()}
+        return {attr: shown[key] for attr, key in m.launch}
+
+    def mixer_values(self) -> dict:
+        """What the kind's series read, by the record's keys: the counts as
+        they stand, its layers and the pool's bytes (no mirror is read: any
+        thread may ask); empty for a model without a state kind."""
+        if self.mixer is None:
+            return {}
+        return {**self.mixer_counts,
+                "layers": self.model_cfg.num_state_layers,
+                "pool_bytes": self.recurrent_state_bytes}
+
+    def mixer_gauges(self) -> dict:
+        """The kind's levels from the host's mirrors, which the engine's
+        thread alone reads: for a launch's span and the flight record."""
+        if self.mixer is None or self.mixer.gauges is None:
+            return {}
+        return self.mixer.gauges(self.model_cfg, self._live_positions())
 
     def _mla_page_fetches(self, plan, rung, draft_len, n_extra) -> int:
         """History pages the latent kernel walks in this launch, from the
@@ -2210,40 +1903,10 @@ class Engine:
             bq = query_block(rung)
             pages += sum(-(-r.start // P) * -(-r.rem // bq)
                          for r in plan.rows)
-        live = (np.asarray(draft_len) >= 0) & (
-            np.asarray(self._active_sent) > 0)
-        pos = self._positions[live].astype(np.int64)
+        pos = self._live_positions(draft_len)
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
         return pages * self.model_cfg.num_attn_layers
-
-    def _deltanet_chunks(self, plan) -> int:
-        """The 64-token chunks the chunked delta rule runs in this launch,
-        from the host's mirrors: a prefill row's ``ceil(rem / 64)`` in every
-        delta layer (``ops/deltanet.py::chunk_table``'s live entries).
-        Device time under ``deltanet.mix`` in the programs that carry a
-        chunk, over this count, is the cost of a chunk (PERF.md section
-        5)."""
-        from helix_tpu.ops.deltanet import CHUNK
-
-        rows = plan.rows if plan is not None else ()
-        return sum(-(-r.rem // CHUNK) for r in rows) * (
-            self.model_cfg.num_deltanet_layers)
-
-    @property
-    def window_rows_wrapped(self) -> int:
-        """Running rows whose sequence has passed the window: their ring
-        has wrapped (0 for a model without window layers)."""
-        W = self.model_cfg.sliding_window
-        if not self.model_cfg.num_window_layers:
-            return 0
-        return int(np.count_nonzero(
-            self._positions[np.asarray(self._active_sent) > 0] >= W))
-
-    @property
-    def recurrent_state_bytes(self) -> int:
-        """Bytes of the state pool (0 for a model without one)."""
-        return self.cache_cfg.state_bytes(self.model_cfg)
 
     @property
     def kv_pages_used(self) -> int:
@@ -5376,22 +5039,16 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
-        chunk_rows = None
-        if self.model_cfg.state_mixer in ("retention", "deltanet"):
-            chunk_rows = self._note_state_rows(
-                plan if rows else None, draft_len, n_extra)
-        if self.model_cfg.num_window_layers:
-            self._note_window_rows(
-                plan if rows else None, draft_len, n_extra)
+        mixer_attrs = {}
+        if self.mixer is not None:
+            mixer_attrs = {
+                **self._note_mixer(plan if rows else None, draft_len, n_extra),
+                "attn_layers": self.model_cfg.num_attn_layers}
         page_fetches = None
         if self.model_cfg.is_mla:
             page_fetches = self._mla_page_fetches(
                 plan, rung, draft_len, n_extra)
             self.num_mla_page_fetches += page_fetches
-        delta_chunks = None
-        if self.model_cfg.num_deltanet_layers:
-            delta_chunks = self._deltanet_chunks(plan if rows else None)
-            self.num_deltanet_chunks += delta_chunks
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
         joint_pass = int(rows > 0)
@@ -5410,27 +5067,11 @@ class Engine:
             **({"grouped_backend": self.grouped_backend}
                if self.grouped_backend else {}),
             attn_q_block=self.attn_q_block,
-            **({"conv_layers": self.model_cfg.num_conv_layers,
-                "attn_layers": self.model_cfg.num_attn_layers}
-               if self.model_cfg.num_conv_layers else {}),
-            **({"retention_layers": self.model_cfg.num_retention_layers,
-                "attn_layers": self.model_cfg.num_attn_layers,
-                "retention_chunk_rows": chunk_rows[0],
-                "retention_chunk_rows_from_zeros": chunk_rows[1]}
-               if self.model_cfg.num_retention_layers else {}),
-            **({"deltanet_layers": self.model_cfg.num_deltanet_layers,
-                "attn_layers": self.model_cfg.num_attn_layers}
-               if self.model_cfg.num_deltanet_layers else {}),
-            **({"window_layers": self.model_cfg.num_window_layers,
-                "attn_layers": self.model_cfg.num_attn_layers,
-                "window_rows_wrapped": self.window_rows_wrapped}
-               if self.model_cfg.num_window_layers else {}),
+            **mixer_attrs,
             **({"held_experts": self.model_cfg.num_held_experts}
                if self.model_cfg.held_experts else {}),
             **({"mla_page_fetches": page_fetches}
                if page_fetches is not None else {}),
-            **({"deltanet_chunks": delta_chunks}
-               if delta_chunks is not None else {}),
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

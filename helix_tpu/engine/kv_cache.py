@@ -29,39 +29,29 @@ reference's GPU VRAM accounting.  ``L`` counts the layers that HAVE pages
 is stored with ``128 / D`` kv heads side by side in one lane tile (``[KVH /
 pack, pack * D]``: the same bytes, and whole tiles for the kernel's DMAs).
 
-A second kind of state lives beside the pages: a gated short convolution's
-layer keeps, for each sequence, its last ``conv_kernel - 1`` inputs and
-nothing a token.  That is the STATE POOL, ``PagedKVCache.state [conv layers,
-slots, K - 1, E]``, one row a decode slot: created with the cache, donated
-and returned by the step with it, counted in ``CacheConfig.total_bytes`` and
-``fit_hbm``.  A prefix is then pages AND a state: ``PrefixCache`` files the
-state a step returns for a page boundary under that boundary's chain digest
-and matches only up to a boundary that has one.
+A second kind of state lives beside the pages: a layer whose memory is not
+a token's K/V keeps, for each sequence, arrays of one size whatever the
+sequence's length.  That is the STATE POOL, ``PagedKVCache.state``, one row a
+decode slot: created with the cache, donated and returned by the step with
+it, counted in ``CacheConfig.total_bytes`` and ``fit_hbm``.  WHICH arrays a
+layer and slot, in which dtype, is the kind's record's
+(``models/mixers.py::STATE_MIXERS``: ``arrays``, ``pool_dtype``, through
+``ModelConfig.state_arrays``); this module gives them the axes ``[layers of
+the kind, slots, ...]`` and holds one array as it is, several as a tuple
+(updated in place, never copied: a matrix state is tens of MB a layer and
+slot).  Where the kind's steps hand back boundary states (``snapshots``) a
+prefix is pages AND a state: ``PrefixCache`` files the state a step returns
+for a page boundary under that boundary's chain digest and matches only up
+to a boundary that has one.
 
-The pool's shape and dtype follow the KIND of the recurrent mixer
-(``ModelConfig.state_arrays``).  A power-retention layer keeps a float32
-matrix a kv head, ``S [layers, slots, kv heads, D_held, head_dim]`` and its
-normaliser ``Z [layers, slots, kv heads, head_dim, head_dim]``:
-``PagedKVCache.state`` is then the pair (34 MB a layer and a slot at width
-128, thousands of times a conv state: updated in place, never copied).  A
-model with NO attention layer has a page pool of no bytes: pages are then
-only the bookkeeping of tokens a sequence (admission against ``num_pages``,
-``max_pages_per_seq``, ``kv_pages_used``), and ``fit_hbm`` sizes SLOTS
-against the budget, not pages.  A delta-rule layer keeps a PAIR of unlike
-arrays: its convolution's tail ``[layers, slots, K - 1, channels]`` in the
-model's dtype and the float32 matrix ``S [layers, slots, value heads, dk,
-dv]``; its model's attention layers are latent, so the state pool stands
-beside a LATENT page pool whose layer axis counts those layers alone.
-
-A SLIDING-WINDOW layer's query sees its sequence's last ``W`` tokens and no
-more, so its K/V are not pages either: the state pool is then the pair of
-RINGS ``[window layers, slots, W, kv heads, head_dim]`` in the pool's dtype
-(a token at position ``p`` in ring row ``p mod W``; ``ops/window.py``), ``2 *
-W * kv heads * head_dim`` values a layer and slot whatever the sequence's
-length, and the page pool's layer axis counts the FULL-attention layers
-alone: admission, ``max_pages_per_seq`` and ``kv_pages_used`` count their
-pages.  A ring is not cleared when its slot is claimed: what it holds is
-masked by position.
+The page pool's layer axis counts the layers WITH pages alone (latent ones
+where the model's attention is latent): admission, ``max_pages_per_seq`` and
+``kv_pages_used`` count their pages.  A model with NO such layer has a page
+pool of no bytes: pages are then only the bookkeeping of tokens a sequence
+(admission against ``num_pages``, ``max_pages_per_seq``, ``kv_pages_used``),
+and ``fit_hbm`` sizes SLOTS against the budget, not pages.  A slot's state is
+not cleared when the slot is claimed: a row that starts its sequence reads
+none of it.
 
 A LATENT (MLA) page pool is ONE array, ``k_pages [L, N, P, R + 128]``: a
 token caches one row a layer, its normed latent in lanes ``0..R`` and its
@@ -112,13 +102,13 @@ class CacheConfig:
 
     def state_shapes(self, model: ModelConfig) -> tuple:
         """``((shape, dtype), ...)`` of the state pool's arrays, by the kind
-        of the model's recurrent mixer; empty for a model without one.  A
-        window layer's rings are K and V like the pages beside them: in the
-        POOL's dtype."""
-        ring = model.state_mixer == "window"
+        of the model's layers with a per-sequence state; empty for a model
+        without one.  A kind whose arrays are K and V like the pages beside
+        them (``StateMixer.pool_dtype``) has them in the POOL's dtype."""
+        kind = model.state_kind
         return tuple(
             ((model.num_state_layers, self.state_slots) + tuple(shp),
-             self.dtype if ring else dt)
+             self.dtype if kind.pool_dtype else dt)
             for shp, dt in model.state_arrays())
 
     def state_shape(self, model: ModelConfig) -> Optional[tuple]:
@@ -245,12 +235,9 @@ class PagedKVCache:
     v_pages: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
-    # the state pool: ``[conv layers, slots, K - 1, E]`` in the model's
-    # dtype for gated short convolutions; the pair ``(S, Z)`` in float32 for
-    # power retention, ``(conv tail, S)`` for the gated delta rule, ``(K
-    # ring, V ring)`` in the pool's dtype for sliding-window layers
-    # (``CacheConfig.state_shapes``); None for a model whose memory is
-    # pages alone
+    # the state pool: the arrays of the model's state kind
+    # (``CacheConfig.state_shapes``), one as it is, several as a tuple; None
+    # for a model whose memory is pages alone
     state: Optional[object] = None
 
     @classmethod

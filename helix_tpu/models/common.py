@@ -12,7 +12,10 @@ short convolution with a fixed per-sequence state, power retention, whose
 per-sequence state is a matrix a kv head, the gated delta rule, whose
 state is a matrix a value head and a conv tail, or sliding-window
 attention, whose state is a ring of its last tokens' K/V) times an FFN
-(dense, or routed experts).  A dense decoder is one run; DeepSeek-V2 is
+(dense, or routed experts).  What a kind with a state IS to the engine, the
+cache and the serving layer (its arrays, its look-back, its refusals, its
+counters) is its record in ``models/mixers.py::STATE_MIXERS``; this module
+knows the kinds by name only.  A dense decoder is one run; DeepSeek-V2 is
 two (the leading dense layers, then the expert layers); LFM2 interleaves
 three kinds in thirteen, of which the runs that repeat back to back (the
 period "attention, three convolutions" four times, "attention, two
@@ -27,9 +30,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Optional
 
-
-# the token mixers that keep a fixed per-sequence state in the state pool
-STATE_MIXERS = ("conv", "retention", "deltanet", "window")
+from helix_tpu.models.mixers import STATE_MIXERS, StateMixer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,42 +246,18 @@ class ModelConfig:
         kind = self.state_mixer
         return self.mixers.count(kind) if kind else 0
 
+    @property
+    def state_kind(self) -> Optional[StateMixer]:
+        """``state_mixer``'s record (``models/mixers.py``), ``None`` for a
+        model whose memory is pages alone."""
+        return STATE_MIXERS.get(self.state_mixer)
+
     def state_arrays(self) -> tuple:
         """``((shape, dtype), ...)``: one sequence's state in one layer, by
-        the mixer's kind.  A conv layer: its last ``conv_kernel - 1`` gated
-        inputs, oldest first, in the model's dtype.  A retention layer: the
-        matrix ``S [kv heads, D_held, head_dim]`` and the normaliser ``Z
-        [kv heads, head_dim, head_dim]``, float32 (running sums over the
-        whole context).  A delta-rule layer: the conv's tail, the last
-        ``conv_kernel - 1`` rows of its q|k|v channels in the model's dtype,
-        and the matrix ``S [value heads, key dim, value dim]`` float32.  A
-        window layer: the K ring and the V ring ``[sliding_window, kv heads,
-        head_dim]`` in the pool's dtype (``CacheConfig.state_shapes``)."""
-        kind = self.state_mixer
-        if kind == "window":
-            if self.sliding_window <= 0:
-                raise ValueError(
-                    f"{self.name}: window layers need sliding_window > 0")
-            ring = (self.sliding_window, self.num_kv_heads, self.head_dim)
-            return ((ring, self.dtype), (ring, self.dtype))
-        if kind == "conv":
-            return (((self.conv_kernel - 1, self.hidden_size), self.dtype),)
-        if kind == "retention":
-            from helix_tpu.ops.retention import held_rows
-
-            if self.retention_degree != 2:
-                raise ValueError(
-                    f"{self.name}: power retention of degree "
-                    f"{self.retention_degree} is not supported: only 2")
-            d, kvh = self.head_dim, self.num_kv_heads
-            return (((kvh, held_rows(d), d), "float32"),
-                    ((kvh, d, d), "float32"))
-        if kind == "deltanet":
-            return (((self.conv_kernel - 1, self.deltanet_channels),
-                     self.dtype),
-                    ((self.linear_value_heads, self.linear_key_dim,
-                      self.linear_value_dim), "float32"))
-        return ()
+        the mixer's kind (its record's ``arrays``); empty for a model whose
+        memory is pages alone."""
+        kind = self.state_kind
+        return kind.arrays(self) if kind else ()
 
     @property
     def kv_head_pack(self) -> int:
@@ -324,7 +301,7 @@ class ModelConfig:
             return "layers" if (moe or not self.num_experts) else (
                 "dense_layers")
 
-        groups, seen, i = [], dict.fromkeys(("attn",) + STATE_MIXERS, 0), 0
+        groups, seen, i = [], dict.fromkeys(("attn", *STATE_MIXERS), 0), 0
         while i < len(flat):
             # the period starting here that repeats over the most runs
             p, reps = 1, 1

@@ -14,15 +14,15 @@ TPU-first code:
   of which those that repeat back to back are one GROUP (a stack a run of
   the period, one loop over the repetitions): ``run00``, four times
   (``run01``, ``run02``), twice (``run09``, ``run10``).
-- A conv layer's look-back (``conv_fn``) is injected as attention is: the
-  engine reads a row's taps from its flat neighbours or its slot's state.
-  So is a retention layer's (``retention_fn``): the engine runs a row's
-  fresh tokens against its slot's matrix state and advances it.  So is a
-  delta-rule layer's (``deltanet_fn``): its convolution's tail and its
-  matrix state, both a slot's.  So is a sliding-window layer's
-  (``window_fn``, ``attn_fn``'s signature): the engine attends a row's
-  fresh tokens over its slot's ring of K/V and writes them into it; such a
-  layer may have its own count of query heads and its own rope.
+- A layer that keeps a fixed per-sequence state (a conv layer's tail, a
+  retention or delta-rule layer's matrix, a sliding-window layer's ring of
+  K/V) has its look-back injected as attention is, through ONE argument
+  (``state_fn``): the engine builds it from the kind's record
+  (``models/mixers.py::STATE_MIXERS``: which earlier tokens are a token's
+  own sequence, and the state a sequence carries between calls); without
+  one the record's ``oracle`` runs, every row a whole sequence.  The
+  COMPUTE around it (projections, gates, norms, rope; a window layer may
+  have its own count of query heads and its own rope) is this module's.
 - Three routers, by ``ModelConfig`` (``models/moe.py::route``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from helix_tpu.models.common import ModelConfig
+from helix_tpu.models.mixers import STATE_MIXERS
 from helix_tpu.ops.norms import rms_norm
 from helix_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -542,32 +543,10 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
     return h, (c, k_pe), new_cache
 
 
-def short_conv(z, taps, prevs):
-    """``y_t = sum_i taps[:, i] * z_{t-(K-1)+i}`` in f32: ``z [..., E]`` is
-    the token's own gated input and ``prevs[d-1]`` the one ``d`` tokens
-    back IN ITS SEQUENCE (zeros before the sequence's start)."""
-    K = taps.shape[-1]
-    w = taps.astype(jnp.float32)
-    y = z.astype(jnp.float32) * w[:, K - 1]
-    for d in range(1, K):
-        y = y + prevs[d - 1].astype(jnp.float32) * w[:, K - 1 - d]
-    return y
-
-
-def whole_sequence_conv_fn(z, taps, layer_cache):
-    """The conv look-back of a forward pass with no cache: every row of
-    ``z [B, S, E]`` is one sequence from its start, so tap ``d`` is the
-    row shifted by ``d`` with zeros before it."""
-    S = z.shape[1]
-    prevs = [jnp.pad(z, ((0, 0), (d, 0), (0, 0)))[:, :S]
-             for d in range(1, taps.shape[-1])]
-    return short_conv(z, taps, prevs), None
-
-
-def _conv_mixer(h, p, layer_cache, cfg, conv_fn):
+def _conv_mixer(h, p, layer_cache, cfg, state_fn):
     """Gated short convolution (LFM2): ``(B, C, x) = in_proj(u)``,
     ``y = conv(B * x)`` depthwise and causal over the sequence, ``out_proj(C
-    * y)``.  ``conv_fn(z, taps, layer_cache) -> (y, new_cache)`` owns the
+    * y)``.  ``state_fn(z, taps, layer_cache) -> (y, new_cache)`` owns the
     look-back: which earlier tokens are a token's own sequence, and the
     state a sequence carries between calls."""
     with jax.named_scope("conv.in_proj"):
@@ -577,29 +556,20 @@ def _conv_mixer(h, p, layer_cache, cfg, conv_fn):
                             axis=-1)
         z = b * x
     with jax.named_scope("conv.mix"):
-        y, new_cache = conv_fn(z, p["conv"]["taps"], layer_cache)
+        y, new_cache = state_fn(z, p["conv"]["taps"], layer_cache)
         y = (c.astype(jnp.float32) * y).astype(h.dtype)
     with jax.named_scope("conv.out_proj"):
         h = h + _dense(y, p["out_proj"]).astype(h.dtype)
     return h, new_cache
 
 
-def whole_sequence_retention_fn(q, k, v, log_g, layer_cache):
-    """The retention of a forward pass with no cache: every row of the
-    batch is one sequence from its start, so the definition's quadratic
-    form runs as it is."""
-    from helix_tpu.ops.retention import retention_quadratic
-
-    return retention_quadratic(q, k, v, log_g), None
-
-
 def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
-                     retention_fn):
+                     state_fn):
     """Power retention (Brumby): GQA-shaped ``q, k, v`` with per-head norms
     and rope as in the Qwen3 block, a gate a kv head ``log g = logsigmoid(
     W_g n + b_g)``, and ``y_t = sum_s a_ts v_s / (sum_s a_ts + eps)`` with
     ``a_ts = prod_{r in (s, t]} g_r * (q_t . k_s / sqrt d) ** 2``.
-    ``retention_fn(q, k, v, log_g, layer_cache) -> (y, new_cache)`` owns the
+    ``state_fn(q, k, v, log_g, layer_cache) -> (y, new_cache)`` owns the
     sum: which earlier tokens are a token's own sequence, and the matrix
     state a sequence carries between calls (``ops/retention.py``).  ``q``
     arrives in float32 times ``head_dim ** -0.5``."""
@@ -624,7 +594,7 @@ def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
             maybe_dequant_dense(x, p["g_proj"], compute_dtype=jnp.float32)
             + p["g_bias"]["bias"].astype(jnp.float32))
     with jax.named_scope("retention.mix"):
-        y, new_cache = retention_fn(
+        y, new_cache = state_fn(
             q.astype(jnp.float32) * D ** -0.5, k, v, log_g, layer_cache)
     with jax.named_scope("retention.out_proj"):
         h = h + _dense(
@@ -632,38 +602,13 @@ def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
     return h, new_cache
 
 
-def whole_sequence_deltanet_fn(x, g, beta, taps, layer_cache, cfg):
-    """The delta-rule layer of a forward pass with no cache: every row of
-    the batch is one sequence from its start, so the convolution looks back
-    into zeros and the rule runs from a zero state."""
-    from helix_tpu.ops.deltanet import delta_sequence
-
-    with jax.named_scope("deltanet.conv"):
-        q, k, v = deltanet_heads(
-            whole_sequence_conv_fn(x, taps, None)[0], cfg)
-    with jax.named_scope("deltanet.mix"):
-        S0 = jnp.zeros(v.shape[2:3] + (q.shape[-1], v.shape[-1]), jnp.float32)
-        return jax.vmap(
-            lambda *a: delta_sequence(*a, S0)[0])(q, k, v, g, beta), None
-
-
-def deltanet_heads(y, cfg):
-    """The convolution's output ``y [..., channels]`` (float32) through its
-    SiLU, as the rule's ``q, k, v`` (``ops.deltanet.split_heads``)."""
-    from helix_tpu.ops.deltanet import split_heads
-
-    return split_heads(
-        jax.nn.silu(y), cfg.linear_key_heads, cfg.linear_value_heads,
-        cfg.linear_key_dim, cfg.linear_value_dim)
-
-
-def _deltanet_mixer(h, p, layer_cache, cfg, deltanet_fn, post=None):
+def _deltanet_mixer(h, p, layer_cache, cfg, state_fn, post=None):
     """The gated delta rule (``ops/deltanet.py``): ``q | k | v = silu(conv(x
     W_qkv))`` through a causal depthwise convolution, a write strength
     ``beta = sigmoid(x W_b)`` and a log decay ``g = -exp(A_log) * softplus(x
     W_a + dt_bias)`` a value head, the rule, then ``n_h(o) * (scale *
     sigmoid(x W_z))`` with ``n_h`` an RMSNorm over a head's channels, and
-    ``W_o``.  ``deltanet_fn(x W_qkv, g, beta, taps, layer_cache) -> (o [B, S,
+    ``W_o``.  ``state_fn(x W_qkv, g, beta, taps, layer_cache) -> (o [B, S,
     heads, dv] float32, new_cache)`` owns the look-back: the convolution's
     tail and the matrix state a sequence carries between calls."""
     from helix_tpu.ops.quant import maybe_dequant_dense
@@ -682,7 +627,7 @@ def _deltanet_mixer(h, p, layer_cache, cfg, deltanet_fn, post=None):
         g = -jnp.exp(p["A_log"]["bias"].astype(jnp.float32)) * (
             jax.nn.softplus(maybe_dequant_dense(x, p["in_a"], **f32)
                             + p["dt_bias"]["bias"].astype(jnp.float32)))
-    o, new_cache = deltanet_fn(
+    o, new_cache = state_fn(
         qkv, g, beta, p["conv"]["taps"], layer_cache)
     with jax.named_scope("deltanet.out_proj"):
         y = rms_norm(o, p["o_norm"]["weight"], cfg.linear_norm_eps,
@@ -693,18 +638,6 @@ def _deltanet_mixer(h, p, layer_cache, cfg, deltanet_fn, post=None):
                         p["out_proj"])
         h = h + (post(branch) if post else branch).astype(h.dtype)
     return h, new_cache
-
-
-def whole_sequence_window_fn(q, k, v, layer_cache, positions, *, window):
-    """The window layer of a forward pass with no cache: every row of the
-    batch is one sequence from its start, so the definition's masks run as
-    they are (causal, and key ``j`` hidden from query ``i`` where ``i - j >=
-    window``)."""
-    from helix_tpu.ops.attention import attention
-
-    return attention(
-        q, k, v, causal=True, q_positions=positions, kv_positions=positions,
-        window=window), None
 
 
 def _layer(
@@ -719,17 +652,16 @@ def _layer(
     adapter_ids=None,
     stacked_experts=None,
     moe_backend=None,
-    conv_fn=None,
-    retention_fn=None,
+    state_fn=None,
     moe_decode_rows: int = 0,
-    deltanet_fn=None,
     mixer: str = "attn",
-    window_fn=None,
 ):
-    """One decoder block. h: [B, S, E].  ``mixer``: the kind of a GQA layer,
-    ``"attn"`` or ``"window"`` (its query heads, its rope through
-    ``inv_freq`` and its look-back differ; the other mixers are told by
-    their weights).
+    """One decoder block. h: [B, S, E].  ``mixer``: the layer's kind (a GQA
+    layer's, ``"attn"`` or ``"window"``, decides its query heads, its rope
+    through ``inv_freq`` and its look-back; the other mixers are told by
+    their weights).  ``state_fn``: the look-back of a kind with a
+    per-sequence state (``STATE_MIXERS``); None: its record's ``oracle``,
+    every row a whole sequence.
 
     When ``attn_fn`` returns ``(out, new_cache)`` (the carry-cache decode
     protocol — the paged pool threads through the layer scan and the
@@ -743,6 +675,8 @@ def _layer(
     B, S, E = h.shape
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     p = layer_params
+    if state_fn is None and mixer in STATE_MIXERS:
+        state_fn = STATE_MIXERS[mixer].oracle(cfg, positions)
 
     def post_norm(name):
         """The sandwich norm on a branch's output, if the block has one."""
@@ -757,20 +691,15 @@ def _layer(
     if "in_proj" in p:
         # the layer's mixer, like its FFN, is what its weights are
         k = v = None
-        h, new_cache = _conv_mixer(
-            h, p, layer_cache, cfg, conv_fn or whole_sequence_conv_fn)
+        h, new_cache = _conv_mixer(h, p, layer_cache, cfg, state_fn)
     elif "g_proj" in p:
         k = v = None
         h, new_cache = _retention_mixer(
-            h, p, layer_cache, cfg, positions, inv_freq,
-            retention_fn or whole_sequence_retention_fn)
+            h, p, layer_cache, cfg, positions, inv_freq, state_fn)
     elif "in_qkv" in p:
         k = v = None
         h, new_cache = _deltanet_mixer(
-            h, p, layer_cache, cfg,
-            deltanet_fn or functools.partial(
-                whole_sequence_deltanet_fn, cfg=cfg),
-            post_norm("attn_post_norm"))
+            h, p, layer_cache, cfg, state_fn, post_norm("attn_post_norm"))
     elif cfg.is_mla:
         h, (k, v), new_cache = _mla_attention(
             h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
@@ -799,9 +728,7 @@ def _layer(
             k = apply_rope(k, positions, inv_freq, rot)
         with jax.named_scope(f"{sc}.kernel"):
             if mixer == "window":
-                res = (window_fn or functools.partial(
-                    whole_sequence_window_fn, window=cfg.sliding_window))(
-                        q, k, v, layer_cache, positions)
+                res = state_fn(q, k, v, layer_cache)
             else:
                 res = attn_fn(q, k, v, layer_cache, positions)
         new_cache = None
@@ -947,18 +874,13 @@ def forward(
                           # (0 = identity); None = no batched adapters
     moe_backend=None,     # the dropless experts' grouped product, as the
                           # attention dispatchers take it (models/moe.py)
-    conv_fn=None,         # a conv layer's look-back (``_conv_mixer``);
-                          # None: every row is a whole sequence
-    retention_fn=None,    # a retention layer's sum over its sequence
-                          # (``_retention_mixer``); None: the same
+    state_fn=None,        # the look-back of the model's layers with a
+                          # per-sequence state, as its kind's mixer calls
+                          # it (``models/mixers.py``); None: every row is
+                          # a whole sequence
     moe_decode_rows: int = 0,  # the last n tokens of the axis are decode
                           # rows riding a prefill's pass: capacity dispatch
                           # leaves them dropless (``models/moe.py``)
-    deltanet_fn=None,     # a delta-rule layer's convolution and rule over
-                          # its sequence (``_deltanet_mixer``); None: the same
-    window_fn=None,       # a window layer's attention over its sequence's
-                          # last ``sliding_window`` tokens, ``attn_fn``'s
-                          # signature; None: the same
 ):
     """Run the decoder.
 
@@ -1008,10 +930,8 @@ def forward(
                 adapter_ids=adapter_ids,
                 stacked_experts=None if whole is None else (
                     whole, rep * run.count + i),
-                moe_backend=moe_backend, conv_fn=conv_fn,
-                retention_fn=retention_fn, moe_decode_rows=moe_decode_rows,
-                deltanet_fn=deltanet_fn, mixer=run.mixer,
-                window_fn=window_fn,
+                moe_backend=moe_backend, state_fn=state_fn,
+                moe_decode_rows=moe_decode_rows, mixer=run.mixer,
             )
 
         # the cache's layer index counts the layers of the run's mixer:

@@ -1,0 +1,685 @@
+"""The token mixers that keep a fixed per-sequence state: one record a kind.
+
+A layer whose memory is not pages (a gated short convolution's tail, power
+retention's matrix, the gated delta rule's matrix and conv tail, a
+sliding-window layer's ring of K/V) keeps, for each sequence, a state of one
+size whatever the sequence's length: a row of the STATE POOL
+(``engine/kv_cache.py``).  ``STATE_MIXERS`` maps the kind's name (what
+``ModelConfig.layer_types`` calls it) to a :class:`StateMixer`, which holds
+everything the modules ABOVE ``models/`` ask of a kind:
+
+- what it is called when an engine setting or a call is refused for it
+  (``engine/engine.py::refuse_unsupported``, ``_refuse_call``);
+- the arrays of one sequence's state in one layer, whether they take the page
+  pool's dtype, whether a step hands back boundary states for the prefix
+  cache, and the geometry its Pallas kernel takes;
+- the look-back of one segment of a step (``rows_fn``: the function
+  ``models/llama.py::_layer`` calls as ``state_fn``, built for the segment's
+  rows) and of a forward pass with no engine (``oracle``);
+- the host's account of a launch (``account``: increments of the engine's
+  ``mixer_counts``), its levels from the host's mirrors (``gauges``), and the
+  names under which ``/metrics``, ``helix.loop.launch`` and the flight record
+  show them.
+
+A layer's COMPUTE (projections, gates, norms, rope) is ``models/llama.py``'s,
+its operator ``ops/``'s.  A fifth kind is a record here, its compute there,
+its ``ModelConfig`` keys and its tests: nothing under ``engine/``,
+``serving/`` or ``obs/`` spells a kind's name.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Callable
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+@dataclasses.dataclass(frozen=True)
+class Series:
+    """One series of ``/metrics``: ``value`` is the key of the engine's
+    ``mixer_values()`` it shows, ``labels`` its own beside the model's."""
+    name: str
+    kind: str                    # "counter" | "gauge"
+    value: str
+    labels: tuple = ()           # ((label, value), ...)
+
+
+@dataclasses.dataclass(frozen=True)
+class StateMixer:
+    # -- refused by name ---------------------------------------------------
+    refused_as: str              # the property text of a refusal
+    refusals: tuple              # ((setting, why), ...) by the keys of
+                                 # ``engine/engine.py::_SETTINGS``; a setting
+                                 # that is not here the kind is served with
+    call_refusal: str            # why a call that moves pages is refused
+    # -- its state -----------------------------------------------------------
+    arrays: Callable             # cfg -> ((shape, dtype), ...) a layer and slot
+    # -- its look-back -------------------------------------------------------
+    rows_fn: Callable            # (rows, backend, *, cfg, decode, snap, packed)
+                                 # -> one segment's ``state_fn``
+    token_args: int              # leading arguments of ``state_fn`` that are
+                                 # token arrays (``_segments_fn`` splits them)
+    oracle: Callable             # (cfg, positions) -> the ``state_fn`` of a
+                                 # forward pass with no engine
+    # -- what shows it -------------------------------------------------------
+    series: tuple                # of Series, in the order rendered
+    launch: tuple                # ((attribute of helix.loop.launch, key), ...)
+    flight: tuple = None         # ((field of the flight record, key), ...):
+                                 # ``launch``'s unless given
+    # -- what only some kinds have -------------------------------------------
+    pool_dtype: bool = False     # the arrays take the PAGE POOL's dtype
+    snapshots: bool = False      # a step hands back boundary states, which
+                                 # the prefix cache files (``stateful``)
+    check_geometry: Callable = None  # (cfg, tp, kv itemsize): the Pallas
+                                 # kernel's; None: ``jax.numpy`` everywhere
+    # the host's account of a launch, (cfg, cache_cfg, rows, pos, n_extra) ->
+    # {count: increment}, every count at every launch; None: nothing counted
+    account: Callable = None
+    gauges: Callable = None      # (cfg, pos) -> {level: value} from the live
+                                 # positions; None: no level
+
+    def __post_init__(self):
+        if self.flight is None:
+            object.__setattr__(self, "flight", self.launch)
+
+
+# ---- the no-cache forms (a forward pass with no engine) -------------------
+
+
+def short_conv(z, taps, prevs):
+    """``y_t = sum_i taps[:, i] * z_{t-(K-1)+i}`` in f32: ``z [..., E]`` is
+    the token's own gated input and ``prevs[d-1]`` the one ``d`` tokens
+    back IN ITS SEQUENCE (zeros before the sequence's start)."""
+    K = taps.shape[-1]
+    w = taps.astype(jnp.float32)
+    y = z.astype(jnp.float32) * w[:, K - 1]
+    for d in range(1, K):
+        y = y + prevs[d - 1].astype(jnp.float32) * w[:, K - 1 - d]
+    return y
+
+
+def whole_sequence_conv_fn(z, taps, layer_cache):
+    """The conv look-back of a forward pass with no cache: every row of
+    ``z [B, S, E]`` is one sequence from its start, so tap ``d`` is the
+    row shifted by ``d`` with zeros before it."""
+    S = z.shape[1]
+    prevs = [jnp.pad(z, ((0, 0), (d, 0), (0, 0)))[:, :S]
+             for d in range(1, taps.shape[-1])]
+    return short_conv(z, taps, prevs), None
+
+
+def whole_sequence_retention_fn(q, k, v, log_g, layer_cache):
+    """The retention of a forward pass with no cache: every row of the
+    batch is one sequence from its start, so the definition's quadratic
+    form runs as it is."""
+    from helix_tpu.ops.retention import retention_quadratic
+
+    return retention_quadratic(q, k, v, log_g), None
+
+
+def deltanet_heads(y, cfg):
+    """The convolution's output ``y [..., channels]`` (float32) through its
+    SiLU, as the rule's ``q, k, v`` (``ops.deltanet.split_heads``)."""
+    from helix_tpu.ops.deltanet import split_heads
+
+    return split_heads(
+        jax.nn.silu(y), cfg.linear_key_heads, cfg.linear_value_heads,
+        cfg.linear_key_dim, cfg.linear_value_dim)
+
+
+def whole_sequence_deltanet_fn(x, g, beta, taps, layer_cache, cfg):
+    """The delta-rule layer of a forward pass with no cache: every row of
+    the batch is one sequence from its start, so the convolution looks back
+    into zeros and the rule runs from a zero state."""
+    from helix_tpu.ops.deltanet import delta_sequence
+
+    with jax.named_scope("deltanet.conv"):
+        q, k, v = deltanet_heads(
+            whole_sequence_conv_fn(x, taps, None)[0], cfg)
+    with jax.named_scope("deltanet.mix"):
+        S0 = jnp.zeros(v.shape[2:3] + (q.shape[-1], v.shape[-1]), jnp.float32)
+        return jax.vmap(
+            lambda *a: delta_sequence(*a, S0)[0])(q, k, v, g, beta), None
+
+
+def whole_sequence_window_fn(q, k, v, layer_cache, *, positions, window):
+    """The window layer of a forward pass with no cache: every row of the
+    batch is one sequence from its start, so the definition's masks run as
+    they are (causal, and key ``j`` hidden from query ``i`` where ``i - j >=
+    window``)."""
+    from helix_tpu.ops.attention import attention
+
+    return attention(
+        q, k, v, causal=True, q_positions=positions, kv_positions=positions,
+        window=window), None
+
+
+# ---- one segment of a step -------------------------------------------------
+#
+# ``rows = (t0, qlen, hist, slots)``: row ``r`` of the segment is the
+# ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot ``slots[r]``,
+# which has ``hist[r]`` tokens behind it.  A row that starts its sequence
+# starts from zeros; a row with no fresh token (an idle slot, padding) and a
+# row without a slot write nothing.  The function a ``rows_fn`` returns is
+# called with the mixer's arrays and ``((page carry, kacc, vacc, state
+# pool[, snaps]), the layer's index among its kind's)``: the pools ride the
+# carry and are updated IN PLACE.  ``decode``: the rows are one token each
+# and row ``b`` is slot ``b``.
+
+
+def _state_after(zf, S, t0, n):
+    """The conv state of each row after ``n [R]`` of its fresh tokens: the
+    last ``K - 1`` of (the state it came with, its first ``n`` tokens),
+    oldest first.  ``zf [T, E]`` flat, ``S [R, K - 1, E]``, ``t0 [R]``."""
+    K1 = S.shape[1]
+    T = zf.shape[0]
+    out = []
+    for i in range(K1):
+        at = n + i - K1                      # offset in the row, < 0: in S
+        fresh = zf[jnp.clip(t0 + at, 0, T - 1)]
+        old = S[:, 0]
+        for m in range(1, K1):
+            old = jnp.where((n + i == m)[:, None], S[:, m], old)
+        out.append(jnp.where((at >= 0)[:, None], fresh, old))
+    return jnp.stack(out, axis=1)
+
+
+def _conv_rows(z, taps, pool, lc, t0, qlen, hist, slots, snap=None):
+    """A causal depthwise convolution over one segment of the step, its
+    tokens row after row on one flat axis.  ``z [B, S, E]``, ``taps [E,
+    K]``, ``pool [layers, slots, K - 1, E]`` read and written at layer
+    ``lc``.
+
+    A token's tap ``d`` back is its flat neighbour if that is in its own
+    row, else its row's state (zeros for a row that starts its sequence):
+    never the neighbour row's token.  The row's new state, the last ``K -
+    1`` of (state, the row's inputs), is written to its slot; a slot index
+    past the pool writes nothing.  Returns ``(y float32, pool, each row's
+    state after snap[r] of its tokens or None)``."""
+    Bz, Sz, E = z.shape
+    T, K1 = Bz * Sz, taps.shape[-1] - 1
+    zf = z.reshape(T, E)
+    nslots = pool.shape[1]
+    S = pool[lc][jnp.clip(slots, 0, nslots - 1)]            # [R, K-1, E]
+    S = jnp.where((hist > 0)[:, None, None], S, 0).astype(z.dtype)
+    prevs = []
+    for d in range(1, K1 + 1):
+        if Sz == 1:
+            # one-token rows (a decode step): every tap is the state
+            prev = S[:, K1 - d]
+        else:
+            prev = jnp.pad(zf, ((d, 0), (0, 0)))[:T]
+            for j in range(d):
+                # the row's token j reaches d back past its start
+                at = jnp.where(qlen > j, t0 + j, T)
+                prev = prev.at[at].set(S[:, K1 + j - d], mode="drop")
+        prevs.append(prev.reshape(z.shape))
+    y = short_conv(z, taps, prevs)
+    new = _state_after(zf, S, t0, qlen).astype(pool.dtype)
+    dest = jnp.where(qlen > 0, slots, nslots)
+    pool = pool.at[lc, dest].set(new, mode="drop")
+    return y, pool, None if snap is None else _state_after(
+        zf, S, t0, snap).astype(pool.dtype)
+
+
+def _conv_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+    """A conv layer's look-back (``models/llama.py::_conv_mixer``):
+    ``_conv_rows`` on the state pool in the carry.  ``snap [R]``: also hand
+    back each row's state after that many of its tokens (what a prefix hit
+    resumes from), stacked over the conv layers in the carry's last element
+    (a segment without ``snap`` passes that element on).  Called ``(z, taps,
+    carry)``."""
+
+    def conv_fn(z, taps, carry_cache):
+        (caches, kacc, vacc, pool, *snaps), lc = carry_cache
+        y, pool, after = _conv_rows(z, taps, pool, lc, *rows, snap)
+        if after is not None:
+            snaps = [snaps[0].at[lc].set(after)]
+        return y, (caches, kacc, vacc, pool, *snaps)
+
+    return conv_fn
+
+
+def _retention_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+    """A retention layer's sum (``models/llama.py::_retention_mixer``) over
+    a state thousands of times a conv state's size, the pair ``(S pool, Z
+    pool)``.  ``decode``: the recurrence applied once (on a TPU one pass of
+    the decode kernel over the live slots).  Else the rows are runs of fresh
+    tokens on one flat axis (the chunked form: on a TPU what reads no state
+    once for the axis, then the chunk kernel a row, which reads a row's
+    state once, or not at all where the row starts its sequence, and writes
+    it once).  Called ``(q, k, v, log_g, carry)``."""
+    from helix_tpu.ops.retention import retention_decode, retention_rows
+
+    t0, qlen, hist, slots = rows
+
+    def retention_fn(q, k, v, log_g, carry_cache):
+        (caches, kacc, vacc, (s_pool, z_pool)), lc = carry_cache
+        Bq, Sq, H, D = q.shape
+        if decode:
+            y, s_pool, z_pool = retention_decode(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], s_pool, z_pool, lc,
+                qlen > 0, backend=backend)
+        else:
+            y, s_pool, z_pool = retention_rows(
+                q.reshape(Bq * Sq, H, D), k.reshape(Bq * Sq, -1, D),
+                v.reshape(Bq * Sq, -1, D), log_g.reshape(Bq * Sq, -1),
+                t0, qlen, hist, slots, s_pool, z_pool, lc, backend=backend)
+        return y.reshape(Bq, Sq, H, D), (caches, kacc, vacc,
+                                         (s_pool, z_pool))
+
+    return retention_fn
+
+
+def _deltanet_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+    """A delta-rule layer's look-back (``models/llama.py::_deltanet_mixer``)
+    over TWO states a slot: the convolution's tail (``_conv_rows``, the same
+    look-back as a gated short convolution's at 4 taps over the q | k | v
+    channels) and the float32 matrix a value head, the pair ``(conv pool, S
+    pool)``.  ``decode``: the rule applied once (on a TPU one pass of the
+    decode kernel over the live slots).  Else the chunked form, 64 tokens at
+    a time: what does not read the state for all the rows' chunks at once,
+    then the chunks in order, on a TPU in the chunk kernel.  Called ``(x
+    W_qkv, g, beta, taps, carry)``."""
+    from helix_tpu.ops.deltanet import delta_decode, delta_rows
+
+    t0, qlen, hist, slots = rows
+
+    def deltanet_fn(x, g, beta, taps, carry_cache):
+        (caches, kacc, vacc, (c_pool, s_pool)), lc = carry_cache
+        Bx, Sx, _ = x.shape
+        with jax.named_scope("deltanet.conv"):
+            y, c_pool, _ = _conv_rows(
+                x, taps, c_pool, lc, t0, qlen, hist, slots)
+            q, k, v = deltanet_heads(y, cfg)
+        with jax.named_scope("deltanet.mix"):
+            if decode:
+                o, s_pool = delta_decode(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s_pool,
+                    lc, qlen > 0, backend=backend)
+            else:
+                flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
+                o, s_pool = delta_rows(
+                    flat(q), flat(k), flat(v), flat(g), flat(beta), t0,
+                    qlen, hist, slots, s_pool, lc, backend=backend)
+        return o.reshape((Bx, Sx) + o.shape[1:]), (
+            caches, kacc, vacc, (c_pool, s_pool))
+
+    return deltanet_fn
+
+
+def _window_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+    """A window layer's attention (``models/llama.py::_layer``): of a row's
+    ``hist[r]`` tokens its slot's rings ``(K rings, V rings)`` hold the last
+    ``W``.  The row's queries read the rings AS THEY STAND
+    (``ops.window.window_attention``: a decode step's one-token rows, a
+    chunk that continues a prompt), its fresh K/V beside them; then its
+    fresh K/V land in the rings (``write_ring``: a chunk of ``W`` replaces
+    the ring, a shorter one rotates into it).  A ring is not cleared for a
+    new sequence: a row with no history reads none of it and the mask by
+    position hides what it has not written.
+
+    ``packed = (positions, segment ids, mesh)``: no row of the segment has
+    history (a cold packed wave, a first chunk), so its attention is the
+    packed self-attention under the window beside the segment mask, with no
+    pool read.  Called ``(q, k, v, carry)``."""
+    from helix_tpu.ops.attention import attention as full_attention
+    from helix_tpu.ops.window import window_attention, write_ring
+
+    t0, qlen, hist, slots = rows
+
+    def window_fn(q, k, v, carry_cache):
+        (caches, kacc, vacc, (k_ring, v_ring)), lc = carry_cache
+        Bq, Sq, H, D = q.shape
+        flat = lambda a: a.reshape((Bq * Sq,) + a.shape[2:])
+        if packed is not None:
+            pos, seg, mesh = packed
+            out = full_attention(
+                q, k, v, causal=True, q_positions=pos, kv_positions=pos,
+                q_segment_ids=seg, kv_segment_ids=seg, backend=backend,
+                mesh=mesh, window=k_ring.shape[2])
+        else:
+            out = window_attention(
+                flat(q), flat(k), flat(v), k_ring, v_ring, lc, t0, qlen,
+                hist, slots, backend=backend, max_q_len=Sq,
+            ).reshape(q.shape)
+        k_ring, v_ring = write_ring(
+            k_ring, v_ring, lc, flat(k), flat(v), t0, qlen, hist, slots)
+        return out, (caches, kacc, vacc, (k_ring, v_ring))
+
+    return window_fn
+
+
+# ---- the arrays of one sequence's state in one layer ----------------------
+
+
+def _conv_arrays(cfg) -> tuple:
+    """Its last ``conv_kernel - 1`` gated inputs, oldest first, in the
+    model's dtype."""
+    return (((cfg.conv_kernel - 1, cfg.hidden_size), cfg.dtype),)
+
+
+def _retention_arrays(cfg) -> tuple:
+    """The matrix ``S [kv heads, D_held, head_dim]`` and the normaliser ``Z
+    [kv heads, head_dim, head_dim]``, float32 (running sums over the whole
+    context)."""
+    from helix_tpu.ops.retention import held_rows
+
+    if cfg.retention_degree != 2:
+        raise ValueError(
+            f"{cfg.name}: power retention of degree "
+            f"{cfg.retention_degree} is not supported: only 2")
+    d, kvh = cfg.head_dim, cfg.num_kv_heads
+    return (((kvh, held_rows(d), d), "float32"),
+            ((kvh, d, d), "float32"))
+
+
+def _deltanet_arrays(cfg) -> tuple:
+    """The conv's tail, the last ``conv_kernel - 1`` rows of its q|k|v
+    channels in the model's dtype, and the matrix ``S [value heads, key dim,
+    value dim]`` float32."""
+    return (((cfg.conv_kernel - 1, cfg.deltanet_channels), cfg.dtype),
+            ((cfg.linear_value_heads, cfg.linear_key_dim,
+              cfg.linear_value_dim), "float32"))
+
+
+def _window_arrays(cfg) -> tuple:
+    """The K ring and the V ring ``[sliding_window, kv heads, head_dim]``
+    (in the pool's dtype: ``pool_dtype``)."""
+    if cfg.sliding_window <= 0:
+        raise ValueError(
+            f"{cfg.name}: window layers need sliding_window > 0")
+    ring = (cfg.sliding_window, cfg.num_kv_heads, cfg.head_dim)
+    return ((ring, cfg.dtype), (ring, cfg.dtype))
+
+
+# ---- the Pallas kernels' geometry ------------------------------------------
+
+
+def _check_retention(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.retention_kernel import check_retention_geometry
+
+    check_retention_geometry(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
+
+
+def _check_deltanet(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.deltanet_kernel import check_deltanet_geometry
+
+    check_deltanet_geometry(
+        cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_dim,
+        cfg.linear_value_dim)
+
+
+def _check_window(cfg, tp, itemsize) -> None:
+    """The window kernel takes what the ragged kernel takes, at the window
+    layers' own count of query heads."""
+    from helix_tpu.ops.paged_kernel import check_geometry
+
+    check_geometry(
+        cfg.heads_of("window") // tp, max(cfg.num_kv_heads // tp, 1),
+        cfg.head_dim, itemsize)
+
+
+# ---- the host's account of a launch ----------------------------------------
+#
+# ``rows``: the plan's prefill rows (``engine/ragged.py``: ``start`` tokens
+# behind a row, ``rem`` fresh, ``slot`` or -1), ``pos`` the positions of the
+# live decode rows, each of which runs once a fused step (``1 + n_extra``
+# steps a launch), a token further on each.
+
+
+def _matrix_rows(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+    """The rows of a matrix state's pool a launch reads and writes: a live
+    decode row once a fused step, a prefill row with a slot once; each row
+    is every layer's state of one slot, read once and written once."""
+    dec = len(pos) * (1 + int(n_extra))
+    held = [r for r in rows if r.slot >= 0]
+    return {
+        "decode_rows": dec, "chunk_rows": len(held),
+        "state_bytes_touched": 2 * (dec + len(held)) * (
+            cache_cfg.state_bytes(cfg) // cache_cfg.state_slots),
+    }
+
+
+def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+    """... and of the chunk rows, those that start their sequence: the chunk
+    kernel skips the state's read and its query for them."""
+    return {
+        **_matrix_rows(cfg, cache_cfg, rows, pos, n_extra),
+        "chunk_rows_from_zeros": sum(
+            1 for r in rows if r.slot >= 0 and r.start == 0),
+    }
+
+
+def _deltanet_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+    """... and the 64-token chunks the chunked delta rule runs: a prefill
+    row's ``ceil(rem / 64)`` in every delta layer
+    (``ops/deltanet.py::chunk_table``'s live entries).  Device time under
+    ``deltanet.mix`` in the programs that carry a chunk, over this count,
+    is the cost of a chunk (PERF.md section 5)."""
+    from helix_tpu.ops.deltanet import CHUNK
+
+    return {
+        **_matrix_rows(cfg, cache_cfg, rows, pos, n_extra),
+        "chunks": sum(-(-r.rem // CHUNK) for r in rows) * (
+            cfg.num_state_layers),
+    }
+
+
+def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+    """The rows of a launch that read and write their slot's rings.  A row
+    reads ``min(tokens behind it, W)`` ring rows of K and of V in every
+    window layer and writes its fresh tokens' (at most ``W``); the bytes of
+    live ring rows read are what a roofline reckoned from a trace divides
+    by, ``state_bytes_touched`` counts the ring rows written."""
+    W = cfg.sliding_window
+    per_tok = (2 * cfg.num_kv_heads * cfg.head_dim * jnp.dtype(
+        cache_cfg.dtype).itemsize * cfg.num_state_layers)
+    steps = 1 + int(n_extra)
+    read = sum(int(np.minimum(pos + k, W).sum()) for k in range(steps))
+    wrote = len(pos) * steps
+    read += sum(min(r.start, W) for r in rows)
+    wrote += sum(min(r.rem, W) for r in rows if r.slot >= 0)
+    return {
+        "decode_rows": len(pos) * steps, "chunk_rows": len(rows),
+        "ring_bytes_read": read * per_tok,
+        "state_bytes_touched": wrote * per_tok,
+    }
+
+
+def _window_gauges(cfg, pos) -> dict:
+    """Running rows whose sequence has passed the window: their ring has
+    wrapped."""
+    return {"rows_wrapped": int(np.count_nonzero(pos >= cfg.sliding_window))}
+
+
+def _rows_series(name: str) -> tuple:
+    """The rows of the pool the steps advanced (one token at a time, or a
+    chunk of a prompt), by the form that ran them."""
+    return (Series(name, "counter", "chunk_rows", (("kind", "chunk"),)),
+            Series(name, "counter", "decode_rows", (("kind", "decode"),)))
+
+
+# the pool's bytes, and the bytes its rows moved
+_POOL_BYTES = Series("helix_recurrent_state_bytes", "gauge", "pool_bytes")
+_BYTES_TOUCHED = Series(
+    "helix_state_bytes_touched_total", "counter", "state_bytes_touched")
+
+
+STATE_MIXERS = {
+    "conv": StateMixer(
+        refused_as="recurrent state (gated short convolutions)",
+        refusals=(
+            ("multi_device",
+             "the state pool and the conv operator are single-device"),
+            ("int8_kv", "the page pool beside a state pool is bf16 or "
+             "f32"),
+            ("adapters", "no LoRA targets on the conv projections"),
+            ("spec_decode",
+             "a rejected draft would have to roll the conv state back"),
+            ("tiered",
+             "a demoted cold middle is resumed without the state at its end"),
+            ("host_tier",
+             "a spilled prefix or a preempted sequence's pages come back "
+             "without the state"),
+        ),
+        # served with the prefix cache: a prefix is pages AND the conv state
+        # at its end, filed by the steps that pass a page boundary
+        call_refusal="the sequence's conv state has no place in what it "
+                     "moves",
+        arrays=_conv_arrays,
+        snapshots=True,
+        rows_fn=_conv_rows_fn,
+        token_args=1,
+        oracle=lambda cfg, positions: whole_sequence_conv_fn,
+        series=(_POOL_BYTES,),
+        launch=(("conv_layers", "layers"),),
+    ),
+    "retention": StateMixer(
+        refused_as="a matrix state (power retention)",
+        refusals=(
+            ("multi_device",
+             "the state pool and the retention kernel are single-device"),
+            ("int8_kv",
+             "no page holds bytes, and the state is a float32 running sum"),
+            ("adapters", "no LoRA targets on the retention "
+             "projections"),
+            ("spec_decode",
+             "a rejected draft would have to roll the matrix state back"),
+            ("tiered", "there is no page of KV to demote"),
+            ("host_tier",
+             "a preempted sequence's state (tens of MB a layer) has no host "
+             "tier"),
+            ("prefix_cache",
+             "a filed state is tens of MB a layer: a snapshot budget and an "
+             "eviction of its own; set enable_prefix_cache: false"),
+        ),
+        call_refusal="the sequence's state has no place in what it moves, "
+                     "and it has no page of KV",
+        arrays=_retention_arrays,
+        check_geometry=_check_retention,
+        rows_fn=_retention_rows_fn,
+        token_args=4,
+        oracle=lambda cfg, positions: whole_sequence_retention_fn,
+        account=_retention_account,
+        series=(
+            _POOL_BYTES,
+            *_rows_series("helix_retention_rows_total"),
+            # over kind="chunk" above, the share of rows the state's query
+            # was skipped for
+            Series("helix_retention_chunk_rows_from_zeros_total", "counter",
+                   "chunk_rows_from_zeros"),
+            _BYTES_TOUCHED,
+        ),
+        launch=(("retention_layers", "layers"),
+                ("retention_chunk_rows", "chunk_rows"),
+                ("retention_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
+        flight=(("retention_chunk_rows", "chunk_rows"),
+                ("retention_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
+    ),
+    "deltanet": StateMixer(
+        refused_as="a matrix state and a conv tail (gated delta rule)",
+        refusals=(
+            ("multi_device",
+             "the state pool and the delta-rule kernel are single-device"),
+            ("int8_kv",
+             "the pool beside a state pool is bf16 or f32, and the state is "
+             "a float32 matrix"),
+            ("adapters", "no LoRA targets on the delta-rule "
+             "projections"),
+            ("spec_decode",
+             "a rejected draft would have to roll the matrix state back"),
+            ("tiered",
+             "a demoted cold middle is resumed without the state at its end"),
+            ("host_tier",
+             "a preempted sequence's state (megabytes a layer) has no host "
+             "tier"),
+            ("prefix_cache",
+             "a filed state is megabytes a layer: a snapshot budget and an "
+             "eviction of its own; set enable_prefix_cache: false"),
+        ),
+        call_refusal="the sequence's state has no place in what it moves",
+        arrays=_deltanet_arrays,
+        check_geometry=_check_deltanet,
+        rows_fn=_deltanet_rows_fn,
+        token_args=3,
+        oracle=lambda cfg, positions: functools.partial(
+            whole_sequence_deltanet_fn, cfg=cfg),
+        account=_deltanet_account,
+        series=(
+            # device time under deltanet.mix in the programs that carry a
+            # chunk, over this, is a chunk's cost
+            Series("helix_deltanet_chunks_total", "counter", "chunks"),
+            _POOL_BYTES,
+            *_rows_series("helix_deltanet_rows_total"),
+            _BYTES_TOUCHED,
+        ),
+        launch=(("deltanet_layers", "layers"), ("deltanet_chunks", "chunks")),
+    ),
+    "window": StateMixer(
+        refused_as="a ring of K/V a slot (sliding-window attention)",
+        refusals=(
+            ("multi_device",
+             "the rings and the window kernel are single-device"),
+            ("int8_kv",
+             "the rings and the pages beside them are bf16 or f32: a ring "
+             "row has no scale"),
+            ("adapters", "no LoRA targets on the window layers' "
+             "projections"),
+            ("spec_decode",
+             "a rejected draft's K/V would have overwritten ring rows the "
+             "window still needs"),
+            ("tiered",
+             "a demoted cold middle is resumed without the ring at its end"),
+            ("host_tier",
+             "a spilled prefix or a preempted sequence's pages come back "
+             "without the ring"),
+            ("prefix_cache",
+             "a hit would need the ring as it stood at the prefix's "
+             "boundary: no step files it; set enable_prefix_cache: false"),
+        ),
+        call_refusal="the sequence's rings have no place in what it moves",
+        arrays=_window_arrays,
+        pool_dtype=True,
+        check_geometry=_check_window,
+        rows_fn=_window_rows_fn,
+        token_args=3,
+        oracle=lambda cfg, positions: functools.partial(
+            whole_sequence_window_fn, positions=positions,
+            window=cfg.sliding_window),
+        account=_window_account,
+        gauges=_window_gauges,
+        series=(
+            # rows x min(length, W) x a token's K and V x window layers: what
+            # a roofline by hand divides by
+            Series("helix_window_ring_bytes_read_total", "counter",
+                   "ring_bytes_read"),
+            _POOL_BYTES,
+            *_rows_series("helix_window_rows_total"),
+            _BYTES_TOUCHED,
+        ),
+        launch=(("window_layers", "layers"),
+                ("window_rows_wrapped", "rows_wrapped")),
+    ),
+}
+
+
+def flight_fields(kind, values: dict, since: dict) -> dict:
+    """The flight record's fields of EVERY kind for a step of an engine
+    whose kind is ``kind`` (a record or None): ``values`` the engine's
+    ``mixer_values()`` and ``mixer_gauges()`` after the step, ``since`` its
+    ``mixer_counts`` before it.  A count reads what the step added, a level
+    as it stands, a field of a kind the model has not 0."""
+    out = {}
+    for m in STATE_MIXERS.values():
+        for field, key in m.flight:
+            if m is not kind:
+                out[field] = 0
+            elif key in since:
+                out[field] = values[key] - since[key]
+            else:
+                out[field] = values[key]
+    return out

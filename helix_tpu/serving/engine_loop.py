@@ -33,6 +33,7 @@ from helix_tpu.engine.engine import (
     Request,
     SnapshotError,
 )
+from helix_tpu.models.mixers import flight_fields
 from helix_tpu.obs import EngineLoopObs, FlightRecorder, RateTracker
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.flight import SATURATION_KEYS
@@ -1552,9 +1553,7 @@ class EngineLoop:
             getattr(eng, "num_joint_pass_steps", 0),
             getattr(eng, "num_joint_pass_inert_rows", 0),
             getattr(eng, "num_wave_decode_tokens", 0),
-            getattr(eng, "num_deltanet_chunks", 0),
-            getattr(eng, "num_retention_rows", {}).get("chunk", 0),
-            getattr(eng, "num_retention_chunk_rows_from_zeros", 0),
+            dict(getattr(eng, "mixer_counts", {})),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1566,7 +1565,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0, wr0, dc0, rc0, rz0) = pre
+         cs0, jp0, ji0, wr0, mixer0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1612,25 +1611,14 @@ class EngineLoop:
             "joint_pass": getattr(eng, "num_joint_pass_steps", 0) - jp0,
             "inert_rows": getattr(eng, "num_joint_pass_inert_rows", 0) - ji0,
             "wave_rows": getattr(eng, "num_wave_decode_tokens", 0) - wr0,
-            # layers whose state is a fixed tensor a slot (the state
-            # pool), and layers with pages
-            "conv_layers": getattr(eng.model_cfg, "num_conv_layers", 0),
-            "deltanet_layers": getattr(
-                eng.model_cfg, "num_deltanet_layers", 0),
-            # 64-token chunks the chunked delta rule ran in this step's
-            # programs (prefill rows' ceil(tokens / 64) x delta layers)
-            "deltanet_chunks": getattr(eng, "num_deltanet_chunks", 0) - dc0,
-            # power retention: this step's prefill rows with a slot (the
-            # chunked form), and those of them that started their sequence
-            # (no state read, no product against it)
-            "retention_chunk_rows": getattr(
-                eng, "num_retention_rows", {}).get("chunk", 0) - rc0,
-            "retention_chunk_rows_from_zeros": getattr(
-                eng, "num_retention_chunk_rows_from_zeros", 0) - rz0,
-            # sliding-window layers (a ring of K/V a slot), and the live
-            # rows whose sequence has passed the window
-            "window_layers": getattr(eng.model_cfg, "num_window_layers", 0),
-            "window_rows_wrapped": getattr(eng, "window_rows_wrapped", 0),
+            # layers whose state is fixed arrays a slot (the state pool), by
+            # kind, and what each kind's record shows of this step's
+            # programs (``models/mixers.py``: a count since the step began,
+            # a level as it stands; 0 for a kind the model has not)
+            **flight_fields(
+                getattr(eng, "mixer", None),
+                {**getattr(eng, "mixer_values", dict)(),
+                 **getattr(eng, "mixer_gauges", dict)()}, mixer0),
             # experts of the routed set whose weights are on this chip (0:
             # dense, or every expert is here)
             "held_experts": (

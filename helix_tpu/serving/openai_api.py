@@ -417,26 +417,6 @@ class OpenAIServer:
                     "helix_moe_tile_fill_ratio",
                     getattr(eng, "moe_tile_fill_ratio", 0.0), lbl,
                 )
-            if getattr(eng.model_cfg, "num_conv_layers", 0):
-                # a second kind of state beside the pages: boundary states
-                # kept for the prefix cache, states written into admitted
-                # hits' slots, hits cut back for want of a state
-                c.counter(
-                    "helix_state_snapshots_total",
-                    getattr(eng, "num_state_snapshots", 0), lbl,
-                )
-                c.counter(
-                    "helix_state_restores_total",
-                    getattr(eng, "num_state_restores", 0), lbl,
-                )
-                c.counter(
-                    "helix_prefix_hits_shortened_total",
-                    getattr(eng, "prefix_hits_shortened", 0), lbl,
-                )
-                c.gauge(
-                    "helix_recurrent_state_bytes",
-                    getattr(eng, "recurrent_state_bytes", 0), lbl,
-                )
             if getattr(eng.model_cfg, "held_experts", None):
                 # one expert-parallel rank: assignments to the experts held
                 # here (computed) and to experts elsewhere (not); their
@@ -458,54 +438,31 @@ class OpenAIServer:
                     "helix_mla_page_fetches_total",
                     getattr(eng, "num_mla_page_fetches", 0), lbl,
                 )
-            if getattr(eng.model_cfg, "num_deltanet_layers", 0):
-                # the chunked delta rule: the 64-token chunks it ran (a
-                # prefill row's ceil(tokens / 64) x delta layers, from the
-                # host's mirrors); device time under deltanet.mix in the
-                # programs that carry a chunk, over this, is a chunk's cost
-                c.counter(
-                    "helix_deltanet_chunks_total",
-                    getattr(eng, "num_deltanet_chunks", 0), lbl,
-                )
-            if getattr(eng.model_cfg, "num_window_layers", 0):
-                # rings of K/V a slot: the bytes of live ring rows the
-                # window calls read (rows x min(length, W) x a token's K and
-                # V x window layers: what a roofline by hand divides by)
-                c.counter(
-                    "helix_window_ring_bytes_read_total",
-                    getattr(eng, "window_ring_bytes_read", 0), lbl,
-                )
-            for mixer, rows_series in (
-                    ("retention", "helix_retention_rows_total"),
-                    ("deltanet", "helix_deltanet_rows_total"),
-                    ("window", "helix_window_rows_total")):
-                if not getattr(eng.model_cfg, f"num_{mixer}_layers", 0):
-                    continue
-                # a matrix state a slot: the pool's bytes, the rows of it
-                # the steps advanced (one token at a time, or a chunk of a
-                # prompt), and the bytes they moved
-                c.gauge(
-                    "helix_recurrent_state_bytes",
-                    getattr(eng, "recurrent_state_bytes", 0), lbl,
-                )
-                for kind, n in sorted(getattr(
-                        eng, f"num_{mixer}_rows", {}).items()):
-                    c.counter(rows_series, n, {**lbl, "kind": kind})
-                if mixer == "retention":
-                    # chunk rows that started their sequence: the chunk
-                    # kernel read no state for them; over kind="chunk"
-                    # above, the share of rows the state's query was
-                    # skipped for
+            mixer = getattr(eng, "mixer", None)
+            if mixer is not None:
+                if mixer.snapshots:
+                    # a second kind of state beside the pages: boundary
+                    # states kept for the prefix cache, states written into
+                    # admitted hits' slots, hits cut back for want of a state
                     c.counter(
-                        "helix_retention_chunk_rows_from_zeros_total",
-                        getattr(
-                            eng, "num_retention_chunk_rows_from_zeros", 0),
-                        lbl,
+                        "helix_state_snapshots_total",
+                        getattr(eng, "num_state_snapshots", 0), lbl,
                     )
-                c.counter(
-                    "helix_state_bytes_touched_total",
-                    getattr(eng, "state_bytes_touched", 0), lbl,
-                )
+                    c.counter(
+                        "helix_state_restores_total",
+                        getattr(eng, "num_state_restores", 0), lbl,
+                    )
+                    c.counter(
+                        "helix_prefix_hits_shortened_total",
+                        getattr(eng, "prefix_hits_shortened", 0), lbl,
+                    )
+                # the engine's mixer series: what the record of the model's
+                # state kind (``models/mixers.py``) shows of the state pool,
+                # from the host's account of the launches
+                values = eng.mixer_values()
+                for sr in mixer.series:
+                    getattr(c, sr.kind)(
+                        sr.name, values[sr.value], {**lbl, **dict(sr.labels)})
             # speculative decoding (ISSUE 5): host-drafted tokens, the
             # subset the verify pass accepted, lifetime acceptance, and
             # slots the per-request EMA currently benches

@@ -11,8 +11,8 @@ import os
 
 # Must be set before jax initialises its backends.  FORCE cpu: tests never
 # touch an accelerator (a chip belongs to one process at a time, and the
-# suite runs in several).  The one file that compiles for a described TPU,
-# tests/test_tpu_compile.py, does so from fixtures and still on this backend.
+# suite runs in several).  The files that compile for a described TPU,
+# tests/test_tpu_compile*.py, do so from fixtures and still on this backend.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -94,6 +94,39 @@ def pytest_runtest_protocol(item, nextitem):
     yield
     if timer is not None:
         timer.cancel()
+
+
+# Under ``--dist loadfile`` a file is one worker's, and xdist hands the files
+# out by their NUMBER of tests, most first: a file of few, long tests would
+# start last and the run would wait on it with the other workers idle (two
+# such files side by side in that tail, each compile 2.7 cores wide, cost
+# PR 44's first hand-in its run).  These are handed out FIRST: whole steps
+# compiled for the described chip (eight minutes in one process) and the
+# vision towers; the rest follow in xdist's own order.  With them
+# ``test_observability.py``, which needs a YOUNG worker: behind the delta-rule
+# family file and six more in one process its first request to an in-process
+# runner gets no answer in its 30 s (alone: 3 s; cause not found, ROADMAP
+# Queue 3, 13).
+_FEW_AND_LONG = (
+    "test_tpu_compile_steps.py", "test_qwen2_vl.py", "test_engine_vl.py",
+    "test_observability.py",
+)
+
+
+def pytest_configure(config):
+    # the scheduler would sort the files by their number of tests again
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    import collections
+
+    rank = {name: i for i, name in enumerate(_FEW_AND_LONG)}
+    size = collections.Counter(item.path for item in items)
+    # (stable: a file's tests stay together and in their order)
+    items.sort(key=lambda item: (
+        rank.get(item.path.name, len(rank)), -size[item.path]))
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -639,12 +639,12 @@ def test_chunked_prefill_then_decode_through_both_pools_is_the_reference(
     req, other = _req("a", prompt, 7), _req("b", tokens_of(11, 1), 9)
     got = _run(eng, [req, other], req)
     assert len(got) >= 6 and eng.num_mixed_steps >= 1
-    assert eng.num_deltanet_rows["chunk"] >= 4
-    assert eng.num_deltanet_rows["decode"] >= 12
+    assert eng.mixer_counts["chunk_rows"] >= 4
+    assert eng.mixer_counts["decode_rows"] >= 12
     per_slot = eng.recurrent_state_bytes // 3
     assert per_slot == 7 * (3 * 128 + 4 * 16 * 16) * 4
-    assert eng.state_bytes_touched == 2 * per_slot * sum(
-        eng.num_deltanet_rows.values())
+    assert eng.mixer_counts["state_bytes_touched"] == 2 * per_slot * (
+        eng.mixer_counts["decode_rows"] + eng.mixer_counts["chunk_rows"])
     # every (token, choice) of every expert layer is counted, here or away
     eng._drain_moe_drops()
     assert eng.moe_routed_tokens > 0 and eng.moe_away_tokens > 0
@@ -892,7 +892,7 @@ def test_chunks_of_the_delta_rule_are_counted_from_the_hosts_mirrors(model):
         obs_trace.phase = orig
     # 128 tokens are two chunks, the 22 left one, in each of 7 delta layers
     assert seen[:2] == [2 * 7, 1 * 7] and not any(seen[2:]), seen
-    assert eng.num_deltanet_chunks == 3 * 7
+    assert eng.mixer_counts["chunks"] == 3 * 7
 
 
 def test_flight_records_and_metrics_carry_the_new_series(model):
@@ -942,7 +942,7 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
     assert value("helix_deltanet_rows_total{", 'kind="decode"') >= 4
     assert value("helix_recurrent_state_bytes{") == eng.recurrent_state_bytes
     assert value("helix_state_bytes_touched_total{") == (
-        eng.state_bytes_touched) > 0
+        eng.mixer_counts["state_bytes_touched"]) > 0
     held = value("helix_moe_held_tokens_total{")
     away = value("helix_moe_away_tokens_total{")
     assert held == eng.moe_routed_tokens > 0 and away > held

@@ -422,9 +422,9 @@ def _chunked_prefill_then_decode(eng, params):
     req, other = _req("a", prompt, 7), _req("b", tokens_of(11, 1), 9)
     got = _run(eng, [req, other], req)
     assert len(got) >= 6 and eng.num_mixed_steps >= 1
-    assert eng.num_retention_rows["chunk"] >= 4
-    assert 2 <= eng.num_retention_chunk_rows_from_zeros < (
-        eng.num_retention_rows["chunk"])
+    assert eng.mixer_counts["chunk_rows"] >= 4
+    assert 2 <= eng.mixer_counts["chunk_rows_from_zeros"] < (
+        eng.mixer_counts["chunk_rows"])
     seq = jnp.asarray(prompt + req.output_tokens)
     at = [len(prompt) + n - 1 for n in sorted(got)]
     mine = np.stack([got[n] for n in sorted(got)])
@@ -439,10 +439,10 @@ def test_chunked_prefill_then_decode_through_the_state_is_the_reference(model):
     cfg, params = model
     eng = _engine(cfg, params)
     mine, want, seq, at = _chunked_prefill_then_decode(eng, params)
-    assert eng.num_retention_rows["decode"] >= 12
+    assert eng.mixer_counts["decode_rows"] >= 12
     per_slot = eng.recurrent_state_bytes // 3
-    assert eng.state_bytes_touched == 2 * per_slot * sum(
-        eng.num_retention_rows.values())
+    assert eng.mixer_counts["state_bytes_touched"] == 2 * per_slot * (
+        eng.mixer_counts["decode_rows"] + eng.mixer_counts["chunk_rows"])
     for kw in (dict(state_bf16=True), dict(gate=False),
                dict(normaliser=False), dict(cross_sqrt2=False),
                dict(zero_state_at=32)):
@@ -673,12 +673,12 @@ def test_launch_record_and_metrics_carry_the_retention_layers(model):
     # the one prompt is one chunk row, and it starts its sequence
     assert sum(kw["retention_chunk_rows"] for kw in seen) == 1
     assert sum(kw["retention_chunk_rows_from_zeros"] for kw in seen) == 1
-    assert eng.num_retention_chunk_rows_from_zeros == 1
+    assert eng.mixer_counts["chunk_rows_from_zeros"] == 1
     # a 40-token prompt in three chunks: one row of three starts it
     long = _req("b", tokens_of(40, 2), 2)
     _run(eng, [long], long)
-    assert eng.num_retention_rows["chunk"] == 4
-    assert eng.num_retention_chunk_rows_from_zeros == 2
+    assert eng.mixer_counts["chunk_rows"] == 4
+    assert eng.mixer_counts["chunk_rows_from_zeros"] == 2
 
 
 def test_metrics_and_flight_records_carry_the_rows_from_zeros(model):

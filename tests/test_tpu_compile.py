@@ -1,27 +1,32 @@
 """Compile the main path's kernels for a DESCRIBED TPU v5e, without the chip.
 
-The only place tests describe the chip.  The TPU's compiler is installed in
+With its two sibling files, the only place tests describe the chip.  The TPU's compiler is installed in
 the sandbox and compiles for a topology that is described, not attached
 (``on-chip-measurement`` guide, section 2): what Mosaic refuses here it
 refuses on the chip, which interpret-mode parity tests cannot see (tiling,
 slices, VMEM, partitioning).  Nothing runs, so these say nothing about
 results or times; ``chip_smoke.py`` does that on the chip.
 
-Rules this file keeps (or the whole suite counts 0 under xdist): the
+Rules these files keep (or the whole suite counts 0 under xdist): the
 topology is described inside a fixture, never while a module is imported,
-never in a ``skipif`` or a ``parametrize`` argument; the fixtures live here
-and are not ``autouse``; every compile happens in the test's own process;
-all such tests stay in this one file.
+never in a ``skipif`` or a ``parametrize`` argument; the fixtures
+(``tests/tpu_topology.py``) are not ``autouse``; every compile happens in
+the test's own process.  This file holds the KERNELS at published widths;
+whole engine steps are in ``test_tpu_compile_steps.py``, apart so that no one
+xdist worker (``--dist loadfile``: a file is one worker's) holds them all.  A
+worker's process keeps the TPU library once it has described the chip, so
+the two files pass side by side only where several processes may load it (the driver's test
+command sets ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``; without it, run them in one
+process, or a file at a time).
 """
-
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.sharding import SingleDeviceSharding
+
+from tpu_topology import one_chip, topo  # noqa: F401
 
 GEOMETRY = {  # (query heads, kv heads, head width, layers)
     "qwen2-7b": (28, 4, 128, 28),
@@ -33,34 +38,6 @@ GEOMETRY = {  # (query heads, kv heads, head width, layers)
 LFM2_8B = (32, 8, 64, 6)
 PAGE, PAGES, MAX_PAGES = 16, 2048, 64
 DECODE_BATCH, PREFILL_LEN = 32, 512   # profiles/v5e1-qwen2-7b.yaml
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    # a compile for a described chip can be written to a persistent cache
-    # but never read back without the chip: keep the cache off around them
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        desc = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:
-        jax.config.update("jax_enable_compilation_cache", was)
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
 
 
 def _ragged_args(geometry, kv, shape, sharding, heads=P(), pool=P(),
@@ -336,138 +313,6 @@ def test_grouped_product_compiles_at_the_published_widths(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
-def test_expert_step_compiles_at_published_widths(one_chip, program):
-    """A whole engine step of DeepSeek-V2-Lite (the dense layer and two
-    expert layers of the seventeen, int8 weights, 64 slots) for the
-    described chip: the latent kernel, the grouped expert product kernel
-    with int8 weights as stored, the row scatter into the latent pool."""
-    import dataclasses
-
-    from helix_tpu.engine import engine as E
-    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
-    from helix_tpu.engine.sampling import SamplingState
-    from helix_tpu.models.common import DEEPSEEK_V2_LITE
-    from helix_tpu.models.llama import init_params
-
-    cfg = dataclasses.replace(DEEPSEEK_V2_LITE, num_layers=3)
-    B, max_pages, pages = 64, 160, 10240
-    i32 = jnp.int32
-
-    def S(shp, dt=i32):
-        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(
-            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    ks, = CacheConfig(num_pages=pages).page_shapes(cfg)
-    assert ks == (3, 16, 512 + 128)
-    cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None)
-
-    def sampling(n):
-        f32 = jnp.float32
-        return SamplingState(
-            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
-            presence=S((n,), f32), frequency=S((n,), f32))
-
-    state = E.DecodeState(
-        last_token=S((B,)), positions=S((B,)),
-        page_tables=S((B, max_pages)), active=S((B,)),
-        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
-        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
-        sampling=sampling(B))
-    bucket, rows = (0, 0) if program == "decode" else (512, 1)
-    pargs = () if not bucket else (
-        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
-        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
-        S((rows, 2), jnp.uint32))
-    fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
-    compiled = fn.lower(
-        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
-    ).compile()
-    text = compiled.as_text()
-    assert "mla_ragged_paged_attention_tpu" in text
-    # the grouped expert product is this repo's kernel, by the name a trace
-    # finds it by (benchmark/metrics/kernel.grouped_mm_share.json), and
-    # XLA's ragged_dot kernel is gone from the step
-    assert "grouped_matmul_tpu" in text
-    assert "ragged-dot" not in text and "ragged_dot" not in text
-    # the pool is updated in place: no pool-sized temporary
-    pool_bytes = (ks[0] * pages * 16 * (512 + 128)) * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
-
-
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
-def test_hybrid_step_compiles_at_published_widths(one_chip, program):
-    """A whole engine step of LFM2-8B-A1B cut to seven layers that hold all
-    three kinds (conv+dense, then attention+experts and two conv+experts
-    twice: a repeated group; int8 weights, 64 slots) for the described chip: the conv operator over the flat
-    ragged axis with the state pool in the carry, the paged kernel at head
-    width 64, the grouped expert product, both pools updated in place."""
-    import dataclasses
-
-    from helix_tpu.engine import engine as E
-    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
-    from helix_tpu.engine.sampling import SamplingState
-    from helix_tpu.models.common import LFM2_8B_A1B
-    from helix_tpu.models.llama import init_params
-
-    cfg = dataclasses.replace(
-        LFM2_8B_A1B, num_layers=7, first_k_dense=1,
-        layer_types=("conv",) + ("attn", "conv", "conv") * 2)
-    assert [g.reps for g in cfg.layer_runs()] == [1, 2]
-    B, max_pages, pages = 64, 160, 10240
-    i32 = jnp.int32
-
-    def S(shp, dt=i32):
-        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(
-            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    cc = CacheConfig(num_pages=pages, state_slots=B)
-    ks, vs = cc.page_shapes(cfg)
-    assert ks == (2, 16, 4, 128) and cc.state_shape(cfg) == (5, B, 2, 2048)
-    cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
-        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
-        state=S(cc.state_shape(cfg), jnp.bfloat16))
-
-    def sampling(n):
-        f32 = jnp.float32
-        return SamplingState(
-            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
-            presence=S((n,), f32), frequency=S((n,), f32))
-
-    state = E.DecodeState(
-        last_token=S((B,)), positions=S((B,)),
-        page_tables=S((B, max_pages)), active=S((B,)),
-        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
-        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
-        sampling=sampling(B))
-    bucket, rows = (0, 0) if program == "decode" else (512, 1)
-    pargs = () if not bucket else (
-        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
-        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
-        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
-    fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
-    compiled = fn.lower(
-        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
-    ).compile()
-    text = compiled.as_text()
-    assert "ragged_paged_attention_tpu" in text
-    assert "grouped_matmul_tpu" in text
-    assert "ragged-dot" not in text and "ragged_dot" not in text
-    # both pools are updated in place: no pool-sized temporary
-    assert compiled.memory_analysis().temp_size_in_bytes < (
-        pages * cc.page_bytes(cfg)) // 2
-
-
 def test_retention_decode_kernel_compiles_at_the_published_geometry(one_chip):
     """Brumby-14B's decode kernel: 24 rows, 8 kv heads of 5 query heads,
     width 128, a state of 8,704 x 128 float32 a head in a pool of ten layers
@@ -522,74 +367,6 @@ def test_retention_chunked_form_compiles_at_the_published_geometry(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= L * N * KVH * (held_rows(d) + d) * d * 4
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
-
-
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
-def test_retention_step_compiles_at_published_widths(one_chip, program):
-    """A whole engine step of Brumby-14B cut to two layers (int8 weights, 24
-    slots) for the described chip: the retention decode kernel over the
-    state pool in the carry, the chunked form a row at a time, a page pool
-    of no bytes, and both state arrays updated in place."""
-    import dataclasses
-
-    from helix_tpu.engine import engine as E
-    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
-    from helix_tpu.engine.sampling import SamplingState
-    from helix_tpu.models.common import BRUMBY_14B
-    from helix_tpu.models.llama import init_params
-
-    cfg = dataclasses.replace(
-        BRUMBY_14B, num_layers=2, layer_types=("retention",) * 2)
-    B, max_pages, pages = 24, 160, 4096
-    i32 = jnp.int32
-
-    def S(shp, dt=i32):
-        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(
-            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    cc = CacheConfig(num_pages=pages, state_slots=B,
-                     max_pages_per_seq=max_pages)
-    ks, vs = cc.page_shapes(cfg)
-    assert ks[0] == 0 and cc.page_bytes(cfg) == 0
-    assert cc.state_shape(cfg) == (2, B, 8, 8704, 128)
-    cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
-        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
-        state=tuple(S(shp, jnp.dtype(dt))
-                    for shp, dt in cc.state_shapes(cfg)))
-
-    def sampling(n):
-        f32 = jnp.float32
-        return SamplingState(
-            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
-            presence=S((n,), f32), frequency=S((n,), f32))
-
-    state = E.DecodeState(
-        last_token=S((B,)), positions=S((B,)),
-        page_tables=S((B, max_pages)), active=S((B,)),
-        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
-        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
-        sampling=sampling(B))
-    bucket, rows = (0, 0) if program == "decode" else (512, 1)
-    pargs = () if not bucket else (
-        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
-        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
-        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
-    fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
-    compiled = fn.lower(
-        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
-    ).compile()
-    text = compiled.as_text()
-    assert "retention_decode_tpu" in text
-    # the state pool is updated in place: aliased whole, and no temporary
-    # of a tenth of its size
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
-    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
 
 
 def test_deltanet_decode_kernel_compiles_at_the_published_geometry(one_chip):
@@ -683,81 +460,6 @@ def test_grouped_product_compiles_at_7168_by_4096(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < E * F
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
-def test_deltanet_step_compiles_at_published_widths(one_chip, program):
-    """A whole engine step of GigaChat3.5 cut to three layers (delta + dense,
-    latent + held experts, delta + held experts; int8 weights, 64 slots) for
-    the described chip: the delta decode kernel over the state pool in the
-    carry, the chunked form in its chunk kernel, the latent kernel at 64 heads
-    over a latent pool of ONE layer, the grouped product over 16 of 256
-    experts, and both state arrays updated in place."""
-    import dataclasses
-
-    from helix_tpu.engine import engine as E
-    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
-    from helix_tpu.engine.sampling import SamplingState
-    from helix_tpu.models.common import GIGACHAT35_432B
-    from helix_tpu.models.llama import init_params
-
-    cfg = dataclasses.replace(
-        GIGACHAT35_432B, num_layers=3, first_k_dense=1, held_experts=(0, 16),
-        layer_types=("deltanet", "attn", "deltanet"))
-    B, max_pages, pages = 64, 160, 2048
-    i32 = jnp.int32
-
-    def S(shp, dt=i32):
-        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(
-            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    cc = CacheConfig(num_pages=pages, state_slots=B,
-                     max_pages_per_seq=max_pages)
-    ks, = cc.page_shapes(cfg)
-    assert ks == (1, 16, 512 + 128)
-    assert cc.state_shapes(cfg) == (
-        ((2, B, 3, 16384), "bfloat16"), ((2, B, 64, 128, 128), "float32"))
-    cache = PagedKVCache(
-        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None,
-        state=tuple(S(shp, jnp.dtype(dt))
-                    for shp, dt in cc.state_shapes(cfg)))
-
-    def sampling(n):
-        f32 = jnp.float32
-        return SamplingState(
-            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
-            presence=S((n,), f32), frequency=S((n,), f32))
-
-    state = E.DecodeState(
-        last_token=S((B,)), positions=S((B,)),
-        page_tables=S((B, max_pages)), active=S((B,)),
-        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
-        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
-        sampling=sampling(B))
-    bucket, rows = (0, 0) if program == "decode" else (512, 1)
-    pargs = () if not bucket else (
-        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
-        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
-        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
-    fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1,
-        0 if bucket else 7)
-    compiled = fn.lower(
-        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
-    ).compile()
-    text = compiled.as_text()
-    for kernel in ("deltanet_decode_tpu", "grouped_matmul_tpu",
-                   "mla_ragged_paged_attention") + (
-                       ("deltanet_chunk_tpu",) if bucket else ()):
-        assert kernel in text, kernel
-    # the state pool is updated in place: aliased whole, and no temporary
-    # of half its size
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
-    assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
-
-
 # ---- Laguna-XS.2: window layers beside full ones (ISSUE 41) -------------------
 
 LAGUNA_SLOTS, LAGUNA_WINDOW = 48, 512
@@ -833,81 +535,3 @@ def test_grouped_product_compiles_at_2048_by_512(one_chip, rows):
         S((), jnp.int32)).compile()
     assert compiled.as_text().count("grouped_matmul_tpu") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
-
-
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history",
-                                     "packed_wave"])
-def test_window_step_compiles_at_published_widths(one_chip, program):
-    """A whole engine step of Laguna-XS.2 cut to ONE period of four layers
-    (full + dense, three sliding + held experts; int8 weights, 48 slots) for
-    the described chip: the window kernel over the rings in the carry, the
-    dense ragged kernel at a group of 6 over a page pool of ONE layer, the
-    windowed flash attention of a cold wave, the grouped product over 32 of
-    256 experts, and both rings updated in place."""
-    import dataclasses
-
-    from helix_tpu.engine import engine as E
-    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
-    from helix_tpu.engine.sampling import SamplingState
-    from helix_tpu.models.common import LAGUNA_XS2
-    from helix_tpu.models.llama import init_params
-
-    cfg = dataclasses.replace(
-        LAGUNA_XS2, num_layers=4, held_experts=(0, 32),
-        layer_types=LAGUNA_XS2.layer_types[:4])
-    B, max_pages, pages = LAGUNA_SLOTS, 160, 2048
-    i32 = jnp.int32
-
-    def S(shp, dt=i32):
-        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(
-            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
-    cc = CacheConfig(num_pages=pages, state_slots=B,
-                     max_pages_per_seq=max_pages)
-    ks, vs = cc.page_shapes(cfg)
-    assert ks == vs == (1, 16, 8, 128)
-    assert cc.state_shapes(cfg) == (
-        ((3, B, LAGUNA_WINDOW, 8, 128), "bfloat16"),) * 2
-    cache = PagedKVCache(
-        k_pages=S((1, pages) + ks[1:], jnp.bfloat16),
-        v_pages=S((1, pages) + vs[1:], jnp.bfloat16),
-        state=tuple(S(shp, jnp.dtype(dt))
-                    for shp, dt in cc.state_shapes(cfg)))
-
-    def sampling(n):
-        f32 = jnp.float32
-        return SamplingState(
-            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
-            presence=S((n,), f32), frequency=S((n,), f32))
-
-    state = E.DecodeState(
-        last_token=S((B,)), positions=S((B,)),
-        page_tables=S((B, max_pages)), active=S((B,)),
-        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
-        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
-        sampling=sampling(B))
-    bucket, rows, hist = {"decode": (0, 0, False),
-                          "chunk_with_history": (512, 1, True),
-                          "packed_wave": (512, 32, False)}[program]
-    pargs = () if not bucket else (
-        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
-        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
-        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
-    fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, hist, rows, 1,
-        0 if bucket else 7)
-    compiled = fn.lower(
-        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
-    ).compile()
-    text = compiled.as_text()
-    for kernel in ("window_attention_tpu", "grouped_matmul_tpu",
-                   "ragged_paged_attention_tpu"):
-        assert kernel in text, kernel
-    # the rings are updated in place: aliased whole, and no temporary of the
-    # size of ONE of the two
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
-    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2

@@ -1,0 +1,378 @@
+"""Compile whole engine steps at published widths for a DESCRIBED TPU v5e,
+without the chip: DeepSeek-V2-Lite (latent attention, experts), LFM2-8B-A1B
+(conv layers), Brumby-14B (power retention), GigaChat3.5 (the gated delta
+rule beside latent attention) and Laguna-XS.2 (sliding-window layers).
+
+The rules of ``test_tpu_compile.py`` hold here (its docstring); the fixtures
+are ``tests/tpu_topology.py``'s.  Nothing runs, so these say nothing about
+results or times; ``chip_smoke_deepseek.py`` does that on the chip.
+
+ONE file, so one process compiles whole steps at a time (a compile is 2.7
+cores wide for half a minute to a minute), and ``tests/conftest.py`` hands it
+out first: by its few tests xdist would start it last.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpu_topology import one_chip, topo  # noqa: F401
+
+PAGE = 16
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_expert_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of DeepSeek-V2-Lite (the dense layer and two
+    expert layers of the seventeen, int8 weights, 64 slots) for the
+    described chip: the latent kernel, the grouped expert product kernel
+    with int8 weights as stored, the row scatter into the latent pool."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import DEEPSEEK_V2_LITE
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(DEEPSEEK_V2_LITE, num_layers=3)
+    B, max_pages, pages = 64, 160, 10240
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    ks, = CacheConfig(num_pages=pages).page_shapes(cfg)
+    assert ks == (3, 16, 512 + 128)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None)
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "mla_ragged_paged_attention_tpu" in text
+    # the grouped expert product is this repo's kernel, by the name a trace
+    # finds it by (benchmark/metrics/kernel.grouped_mm_share.json), and
+    # XLA's ragged_dot kernel is gone from the step
+    assert "grouped_matmul_tpu" in text
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # the pool is updated in place: no pool-sized temporary
+    pool_bytes = (ks[0] * pages * 16 * (512 + 128)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_hybrid_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of LFM2-8B-A1B cut to seven layers that hold all
+    three kinds (conv+dense, then attention+experts and two conv+experts
+    twice: a repeated group; int8 weights, 64 slots) for the described chip: the conv operator over the flat
+    ragged axis with the state pool in the carry, the paged kernel at head
+    width 64, the grouped expert product, both pools updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import LFM2_8B_A1B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        LFM2_8B_A1B, num_layers=7, first_k_dense=1,
+        layer_types=("conv",) + ("attn", "conv", "conv") * 2)
+    assert [g.reps for g in cfg.layer_runs()] == [1, 2]
+    B, max_pages, pages = 64, 160, 10240
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == (2, 16, 4, 128) and cc.state_shape(cfg) == (5, B, 2, 2048)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=S(cc.state_shape(cfg), jnp.bfloat16))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "ragged_paged_attention_tpu" in text
+    assert "grouped_matmul_tpu" in text
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # both pools are updated in place: no pool-sized temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        pages * cc.page_bytes(cfg)) // 2
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_retention_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of Brumby-14B cut to two layers (int8 weights, 24
+    slots) for the described chip: the retention decode kernel over the
+    state pool in the carry, the chunked form a row at a time, a page pool
+    of no bytes, and both state arrays updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import BRUMBY_14B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        BRUMBY_14B, num_layers=2, layer_types=("retention",) * 2)
+    B, max_pages, pages = 24, 160, 4096
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks[0] == 0 and cc.page_bytes(cfg) == 0
+    assert cc.state_shape(cfg) == (2, B, 8, 8704, 128)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    assert "retention_decode_tpu" in text
+    # the state pool is updated in place: aliased whole, and no temporary
+    # of a tenth of its size
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+
+
+# benchmark/configs/laguna-xs2-int8.profile.yaml
+LAGUNA_SLOTS, LAGUNA_WINDOW = 48, 512
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_deltanet_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of GigaChat3.5 cut to three layers (delta + dense,
+    latent + held experts, delta + held experts; int8 weights, 64 slots) for
+    the described chip: the delta decode kernel over the state pool in the
+    carry, the chunked form in its chunk kernel, the latent kernel at 64 heads
+    over a latent pool of ONE layer, the grouped product over 16 of 256
+    experts, and both state arrays updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import GIGACHAT35_432B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        GIGACHAT35_432B, num_layers=3, first_k_dense=1, held_experts=(0, 16),
+        layer_types=("deltanet", "attn", "deltanet"))
+    B, max_pages, pages = 64, 160, 2048
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, = cc.page_shapes(cfg)
+    assert ks == (1, 16, 512 + 128)
+    assert cc.state_shapes(cfg) == (
+        ((2, B, 3, 16384), "bfloat16"), ((2, B, 64, 128, 128), "float32"))
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16), v_pages=None,
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("deltanet_decode_tpu", "grouped_matmul_tpu",
+                   "mla_ragged_paged_attention") + (
+                       ("deltanet_chunk_tpu",) if bucket else ()):
+        assert kernel in text, kernel
+    # the state pool is updated in place: aliased whole, and no temporary
+    # of half its size
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history",
+                                     "packed_wave"])
+def test_window_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of Laguna-XS.2 cut to ONE period of four layers
+    (full + dense, three sliding + held experts; int8 weights, 48 slots) for
+    the described chip: the window kernel over the rings in the carry, the
+    dense ragged kernel at a group of 6 over a page pool of ONE layer, the
+    windowed flash attention of a cold wave, the grouped product over 32 of
+    256 experts, and both rings updated in place."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import LAGUNA_XS2
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        LAGUNA_XS2, num_layers=4, held_experts=(0, 32),
+        layer_types=LAGUNA_XS2.layer_types[:4])
+    B, max_pages, pages = LAGUNA_SLOTS, 160, 2048
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == vs == (1, 16, 8, 128)
+    assert cc.state_shapes(cfg) == (
+        ((3, B, LAGUNA_WINDOW, 8, 128), "bfloat16"),) * 2
+    cache = PagedKVCache(
+        k_pages=S((1, pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((1, pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows, hist = {"decode": (0, 0, False),
+                          "chunk_with_history": (512, 1, True),
+                          "packed_wave": (512, 32, False)}[program]
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, hist, rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("window_attention_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention_tpu"):
+        assert kernel in text, kernel
+    # the rings are updated in place: aliased whole, and no temporary of the
+    # size of ONE of the two
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2

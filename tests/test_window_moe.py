@@ -409,19 +409,20 @@ def test_prefill_chunks_then_decode_through_ring_and_pages_is_the_reference(
     req, other = _req("a", prompt, 12), _req("b", tokens_of(5, 1), 20)
     got = _run(eng, [req, other], req)
     assert len(got) >= 10 and eng.num_mixed_steps >= 1
-    assert eng.num_window_rows["chunk"] >= 4
-    assert eng.num_window_rows["decode"] >= 20
+    assert eng.mixer_counts["chunk_rows"] >= 4
+    assert eng.mixer_counts["decode_rows"] >= 20
     assert len(other.prompt_tokens) < W < len(other.prompt_tokens) + len(
         other.output_tokens)
     # a sliding layer holds no page and a slot's bytes do not grow
     per_slot = eng.recurrent_state_bytes // 3
     assert per_slot == 9 * 2 * W * 2 * 16 * 4
-    assert eng.window_ring_bytes_read > 0 and eng.state_bytes_touched > 0
+    counts = eng.mixer_counts
+    assert counts["ring_bytes_read"] > 0 and counts["state_bytes_touched"] > 0
     tok_bytes = 9 * 2 * 2 * 16 * 4
-    assert eng.window_ring_bytes_read % tok_bytes == 0
+    assert counts["ring_bytes_read"] % tok_bytes == 0
     # every fresh token of both sequences was written once (the prompts'
     # rows of at most 16 tokens land their last 8)
-    assert eng.state_bytes_touched % tok_bytes == 0
+    assert counts["state_bytes_touched"] % tok_bytes == 0
     eng._drain_moe_drops()
     assert eng.moe_routed_tokens > 0 and eng.moe_away_tokens > 0
     seq = jnp.asarray(prompt + req.output_tokens)
@@ -706,8 +707,8 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
     assert value("helix_window_rows_total{", 'kind="decode"') >= 4
     assert value("helix_recurrent_state_bytes{") == eng.recurrent_state_bytes
     assert value("helix_window_ring_bytes_read_total{") == (
-        eng.window_ring_bytes_read) > 0
+        eng.mixer_counts["ring_bytes_read"]) > 0
     assert value("helix_state_bytes_touched_total{") == (
-        eng.state_bytes_touched) > 0
+        eng.mixer_counts["state_bytes_touched"]) > 0
     assert value("helix_moe_held_tokens_total{") == eng.moe_routed_tokens > 0
     assert "helix_deltanet_rows_total" not in text
