@@ -49,9 +49,10 @@ ENV_REFERENCE: tuple = (
         "where a profile enables it. Unset: the profile's "
         "enable_spec_decode/spec_tokens settings apply. A latent-"
         "attention model (DeepSeek-V2-Lite) is not served with "
-        "speculation, nor is one whose sequences carry a state or a "
-        "ring of K/V a slot (sliding-window layers): enabling it is "
-        "refused at profile apply (UnsupportedForModel).",
+        "speculation, nor is one whose sequences carry a state (Mamba-2 "
+        "layers among them) or a ring of K/V a slot (sliding-window "
+        "layers): enabling it is refused at profile apply "
+        "(UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -211,8 +212,8 @@ ENV_REFERENCE: tuple = (
         "ride the step plan and followers stage residency before the "
         "step), so publish adapters to the leader and followers as a "
         "pair. A latent-attention model (DeepSeek-V2-Lite) and a model "
-        "with sliding-window layers refuse a pool at profile apply "
-        "(UnsupportedForModel).",
+        "with sliding-window or Mamba-2 layers refuse a pool at profile "
+        "apply (UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(
@@ -289,9 +290,10 @@ ENV_REFERENCE: tuple = (
         "this node serves (operator-beats-profile); 0 forces fully-"
         "resident even where a profile enables tiering. Unset: the "
         "profile's engine block (default 0 = off). A latent-attention "
-        "model (DeepSeek-V2-Lite) and a model with sliding-window "
-        "layers (a demoted middle would come back without the ring) "
-        "refuse tiering at profile apply (UnsupportedForModel).",
+        "model (DeepSeek-V2-Lite) and a model with sliding-window or "
+        "Mamba-2 layers (a demoted middle would come back without the "
+        "ring or the state) refuse tiering at profile apply "
+        "(UnsupportedForModel).",
         section="accelerator",
     ),
     EnvVar(

@@ -65,8 +65,17 @@ class ProfileModel:
     # forwarded to ModelConfig.tiny — e.g. {num_experts: 4} builds a toy
     # MoE for ep-mesh dev profiles.  Any field of ``models/common.py::
     # ModelConfig``; among them, by architecture:
-    #   layer_types: one of attn | conv | retention | deltanet | window a
-    #     layer ("window": sliding-window attention, its K/V a ring a slot)
+    #   layer_types: one of attn | conv | retention | deltanet | window |
+    #     mamba2 a layer ("window": sliding-window attention, its K/V a
+    #     ring a slot)
+    #   hybrid_pattern: INSTEAD of layer_types, layers of ONE branch each, a
+    #     character a layer (M Mamba-2, * attention, E experts, - an MLP) with
+    #     num_layers their count; mamba_heads, mamba_head_dim, mamba_groups,
+    #     mamba_state_size, mamba_chunk, conv_kernel: the Mamba-2 layers'
+    #   mlp_gated: false for MLPs and experts with no gate matrix (hidden_act
+    #     relu2: squared ReLU); moe_latent_size: the routed experts' own
+    #     width, between two projections; attn_rope: false for attention
+    #     that rotates nothing
     #   sliding_window: tokens a window layer's query sees, its own among
     #     them (the ring's length)
     #   num_heads / window_num_heads: query heads of a full / a window layer

@@ -10,9 +10,11 @@ A model's depth is a sequence of RUNS of one kind of layer
 (``ModelConfig.layer_runs``): a kind is a token mixer (attention, a gated
 short convolution with a fixed per-sequence state, power retention, whose
 per-sequence state is a matrix a kv head, the gated delta rule, whose
-state is a matrix a value head and a conv tail, or sliding-window
-attention, whose state is a ring of its last tokens' K/V) times an FFN
-(dense, or routed experts).  What a kind with a state IS to the engine, the
+state is a matrix a value head and a conv tail, sliding-window
+attention, whose state is a ring of its last tokens' K/V, or Mamba-2,
+whose state is a float32 array a head and a conv tail) times an FFN
+(dense, routed experts, or none: ``hybrid_pattern``'s layers of one branch
+run as blocks of a mixer and the feed-forward behind it, if one is).  What a kind with a state IS to the engine, the
 cache and the serving layer (its arrays, its look-back, its refusals, its
 counters) is its record in ``models/mixers.py::STATE_MIXERS``; this module
 knows the kinds by name only.  A dense decoder is one run; DeepSeek-V2 is
@@ -28,19 +30,45 @@ scores selected on score + a learned bias and weighted without it (LFM2).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 from helix_tpu.models.mixers import STATE_MIXERS, StateMixer
 
 
+@functools.lru_cache(maxsize=None)
+def _pattern_blocks(pattern: str) -> tuple:
+    """A pattern of one-branch layers (``ModelConfig.hybrid_pattern``) as
+    blocks ``((mixer, ffn), ...)``; read at every launch through
+    ``ModelConfig.mixers``, so parsed once a pattern."""
+    blocks = []
+    for i, ch in enumerate(pattern):
+        if ch in "M*":
+            blocks.append(["mamba2" if ch == "M" else "attn", "none"])
+        elif ch in "E-":
+            if not blocks or blocks[-1][1] != "none":
+                raise ValueError(
+                    f"hybrid_pattern layer {i} ({ch!r}) follows no mixer "
+                    "layer: a feed-forward layer is served as the second "
+                    "branch of the mixer before it")
+            blocks[-1][1] = "moe" if ch == "E" else "dense"
+        else:
+            raise ValueError(
+                f"hybrid_pattern layer {i} is {ch!r}: one of M (Mamba-2), "
+                "* (attention), E (experts), - (MLP)")
+    return tuple(map(tuple, blocks))
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
     key: str        # the run's stack in the parameter tree
-    mixer: str      # "attn" | "conv" | "retention" | "deltanet" | "window"
+    mixer: str      # "attn" or a kind of ``STATE_MIXERS``
     moe: bool       # routed experts (else a dense FFN)
     count: int      # layers a repetition
     first: int      # its first layer among its mixer's layers, repetition 0
     step: int       # layers of its mixer in one repetition of its group
+    ffn: bool = True    # False: the block is its mixer alone (no feed-forward,
+                        # no norm and no weight of one)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,7 +138,8 @@ class ModelConfig:
     # ``x + n_b(Mixer(n_a(x)))``, ``x + n_d(FFN(n_c(x)))``
     post_norms: bool = False
     # --- interleaved token mixers (LFM2); None = attention at every layer ---
-    # one of "attn" | "conv" | "retention" | "deltanet" a layer.  A "conv"
+    # one of "attn" | "conv" | "retention" | "deltanet" | "window" | "mamba2"
+    # a layer.  A "conv"
     # layer is a gated short convolution of ``conv_kernel`` taps whose whole
     # state is the last ``conv_kernel - 1`` inputs of the sequence: no pages
     layer_types: Optional[tuple] = None
@@ -161,18 +190,90 @@ class ModelConfig:
     # dims of a head that rope rotates on the full ("attn") layers, the rest
     # pass through; 0: all of ``head_dim``
     rotary_dim: int = 0
+    # --- layers of ONE branch (nemotron_h) ---
+    # the published pattern, a character a layer, each layer one branch
+    # behind one norm and one residual: "M" Mamba-2, "*" attention, "E"
+    # routed experts, "-" a dense MLP.  ``num_layers`` counts these.  The
+    # program runs BLOCKS: a mixer and the feed-forward that follows it, if
+    # one does (``mixers``, ``ffns``: as mathematics ``M`` then ``E`` is the
+    # pre-norm block of the other families); a mixer that no feed-forward
+    # follows is a block without one
+    hybrid_pattern: Optional[str] = None
+    # False: an MLP (dense, expert, shared expert) is ``W_down act(W_up x)``,
+    # no gate matrix; ``hidden_act`` "relu2" is ``relu(x) ** 2``
+    mlp_gated: bool = True
+    # > 0: the routed experts live in a latent of this width: ``W_fc1`` before
+    # the dispatch, ``W_fc2`` behind the combine; the router and the shared
+    # expert read the un-projected input
+    moe_latent_size: int = 0
+    # False: a GQA layer rotates nothing (the Mamba-2 layers carry position)
+    attn_rope: bool = True
+    # a "mamba2" layer is a selective state space (``ops/ssd.py``):
+    # ``mamba_heads`` heads of ``mamba_head_dim`` channels over a state of
+    # ``mamba_state_size`` a channel, ``B`` and ``C`` shared by the heads of
+    # one of ``mamba_groups`` groups, behind a causal depthwise convolution
+    # of ``conv_kernel`` taps with a bias.  Its state is the conv's tail and
+    # a float32 array ``h`` a head: no pages.  ``mamba_chunk``: the block of
+    # the chunked form (not of the mathematics)
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_groups: int = 0
+    mamba_state_size: int = 0
+    mamba_chunk: int = 128
     # --- non-architectural serving metadata ---
     name: str = "unnamed"
+
+    def __post_init__(self):
+        if self.hybrid_pattern is not None:
+            self._blocks()          # refuses what is not served, by name
+            if self.layer_types is not None:
+                raise ValueError(
+                    f"{self.name}: hybrid_pattern and layer_types both "
+                    "name the layers' kinds: give one")
 
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
 
+    def _blocks(self) -> tuple:
+        """``hybrid_pattern`` as the blocks the program runs: ``((mixer,
+        ffn), ...)``, ``ffn`` one of ``"moe"``, ``"dense"``, ``"none"``."""
+        pat = self.hybrid_pattern
+        try:
+            blocks = _pattern_blocks(pat)
+        except ValueError as e:
+            raise ValueError(f"{self.name}: {e}") from None
+        if len(pat) != self.num_layers:
+            raise ValueError(
+                f"{self.name}: hybrid_pattern names {len(pat)} layers, "
+                f"num_layers {self.num_layers}")
+        if "M" in pat and (
+                self.mamba_groups <= 0
+                or self.mamba_heads % self.mamba_groups):
+            raise ValueError(
+                f"{self.name}: mamba_groups {self.mamba_groups} does not "
+                f"divide mamba_heads {self.mamba_heads}: B and C are shared "
+                "by the heads of a group")
+        return blocks
+
     @property
     def mixers(self) -> tuple:
-        """The token mixer of every layer: ``"attn"``, ``"conv"``,
-        ``"retention"``, ``"deltanet"`` or ``"window"``."""
+        """The token mixer of every layer (of every BLOCK under
+        ``hybrid_pattern``): ``"attn"``, ``"conv"``, ``"retention"``,
+        ``"deltanet"``, ``"window"`` or ``"mamba2"``."""
+        if self.hybrid_pattern is not None:
+            return tuple(m for m, _ in self._blocks())
         return self.layer_types or ("attn",) * self.num_layers
+
+    @property
+    def ffns(self) -> tuple:
+        """The feed-forward of every layer of ``mixers``: ``"moe"``,
+        ``"dense"`` or ``"none"`` (the layer is its mixer alone)."""
+        if self.hybrid_pattern is not None:
+            return tuple(f for _, f in self._blocks())
+        return tuple(
+            "moe" if self.num_experts > 0 and i >= self.first_k_dense
+            else "dense" for i in range(self.num_layers))
 
     @property
     def num_attn_layers(self) -> int:
@@ -214,6 +315,16 @@ class ModelConfig:
                 self.rope_scaling)
 
     @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba-2 layer's ``x`` (and of its gate ``z``)."""
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_channels(self) -> int:
+        """Channels of the Mamba-2 layer's convolution: x | B | C."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.mamba_state_size
+
+    @property
     def deltanet_channels(self) -> int:
         """Channels of the delta layer's convolution: q | k | v."""
         return (2 * self.linear_key_heads * self.linear_key_dim
@@ -228,8 +339,8 @@ class ModelConfig:
 
     @property
     def state_mixer(self) -> Optional[str]:
-        """The mixer that keeps a fixed per-sequence state, ``"conv"``,
-        ``"retention"``, ``"deltanet"`` or ``"window"`` (one kind a model:
+        """The mixer that keeps a fixed per-sequence state, a key of
+        ``STATE_MIXERS`` (one kind a model:
         the state pool has one shape), ``None`` for a model whose memory is
         pages alone."""
         kinds = [m for m in STATE_MIXERS if m in self.mixers]
@@ -284,10 +395,8 @@ class ModelConfig:
         run, the state pool's for a conv run) at repetition 0, ``step`` how
         many layers of that mixer one repetition holds.  Models without
         ``layer_types`` keep the two names they always had."""
-        ffn_moe = [self.num_experts > 0 and i >= self.first_k_dense
-                   for i in range(self.num_layers)]
-        kinds = list(zip(self.mixers, ffn_moe))
-        flat, i = [], 0                       # (mixer, moe, count)
+        kinds = list(zip(self.mixers, self.ffns))
+        flat, i = [], 0                       # (mixer, ffn, count)
         while i < len(kinds):
             j = i
             while j < len(kinds) and kinds[j] == kinds[i]:
@@ -296,7 +405,7 @@ class ModelConfig:
             i = j
 
         def key(at, moe):
-            if self.layer_types is not None:
+            if (self.layer_types or self.hybrid_pattern) is not None:
                 return f"run{at:02d}"
             return "layers" if (moe or not self.num_experts) else (
                 "dense_layers")
@@ -315,9 +424,10 @@ class ModelConfig:
             step = {m: sum(c for mx, _, c in period if mx == m)
                     for m in seen}
             runs, at = [], dict(seen)
-            for j, (mixer, moe, count) in enumerate(period):
+            for j, (mixer, ffn, count) in enumerate(period):
+                moe = ffn == "moe"
                 runs.append(LayerRun(key(i + j, moe), mixer, moe, count,
-                                     at[mixer], step[mixer]))
+                                     at[mixer], step[mixer], ffn != "none"))
                 at[mixer] += count
             groups.append(LayerGroup(reps, tuple(runs)))
             for m in seen:
@@ -337,7 +447,7 @@ class ModelConfig:
 
     @property
     def num_moe_layers(self) -> int:
-        return self.num_layers - self.first_k_dense if self.num_experts else 0
+        return self.ffns.count("moe")
 
     def kv_token_shapes(self) -> tuple:
         """Per-token shapes of the two cached arrays, as the model hands
@@ -473,9 +583,13 @@ class ModelConfig:
         if model_type == "laguna":
             family = cls._laguna_family(hf)
             heads = family.pop("num_heads")
+        if model_type == "nemotron_h":
+            family = cls._nemotron_h_family(hf)
         rope_theta = family.pop("rope_theta", None) or hf.get(
             "rope_theta", 10000.0)
         rope_scaling = family.pop("rope_scaling", rope_scaling)
+        hidden_act = family.pop("hidden_act", None) or hf.get(
+            "hidden_act", "silu")
         return cls(
             **family,
             mrope_sections=mrope,
@@ -495,7 +609,7 @@ class ModelConfig:
             tie_word_embeddings=hf.get(
                 "tie_word_embeddings",
                 hf.get("tie_embedding", model_type == "lfm2_moe")),
-            hidden_act=hf.get("hidden_act", "silu"),
+            hidden_act=hidden_act,
             attention_bias=hf.get("attention_bias", False)
             or model_type == "qwen2",
             mlp_bias=hf.get("mlp_bias", False),
@@ -594,6 +708,102 @@ class ModelConfig:
                     f"{hf['num_experts']} of num_experts")
             family.update(held_experts=(lo, hi),
                           num_experts=hf["published_num_experts"])
+        return family
+
+    # keys of a ``model_type: nemotron_h`` config that no layer of the served
+    # stack reads, each with why
+    NEMOTRON_H_UNREAD = {
+        "rope_theta": "the family's attention applies no rotary embedding",
+        "partial_rotary_factor": "no rotary embedding",
+        "layer_norm_epsilon": "norm_eps is the RMSNorms' (the same value)",
+        "time_step_min": "the initialiser's draw of dt_bias",
+        "time_step_max": "the initialiser's draw of dt_bias",
+        "time_step_floor": "the initialiser's draw of dt_bias",
+        "rescale_prenorm_residual": "the initialiser's scale of out-proj",
+        "use_mamba_kernels": "which CUDA kernels the modeling file calls",
+        "moe_shared_expert_overlap": "a stream overlap of the training code",
+        "num_logits_to_keep": "a generate() argument",
+        "mtp_hybrid_override_pattern": "multi-token prediction is not loaded",
+        "num_nextn_predict_layers": "multi-token prediction is not loaded",
+        "model_type": "chose this branch",
+    }
+
+    @staticmethod
+    def _nemotron_h_family(hf: dict) -> dict:
+        """The fields a ``model_type: nemotron_h`` config sets: layers of ONE
+        branch by ``hybrid_override_pattern`` (Mamba-2, attention with no
+        rotary embedding, routed experts), ungated MLPs under ``relu2``,
+        experts in a latent of ``moe_latent_size`` behind a sigmoid router
+        with a selection bias, renormalised and scaled.  What is not served
+        is refused by name."""
+        def refuse(why):
+            raise ValueError(f"nemotron_h: {why}")
+
+        if (hf.get("n_group") or 1) > 1 or (hf.get("topk_group") or 1) > 1:
+            refuse("only the router over all the experts at once is "
+                   "supported (n_group 1, topk_group 1: no grouped top-k)")
+        if hf.get("mamba_proj_bias") or hf.get("use_bias") or hf.get(
+                "mlp_bias"):
+            refuse("mamba_proj_bias, use_bias and mlp_bias true are not "
+                   "supported: no projection here has a bias")
+        if not hf.get("use_conv_bias", True):
+            refuse("use_conv_bias false is not supported: the Mamba-2 "
+                   "convolution here has a bias")
+        if hf.get("mamba_hidden_act", "silu") != "silu":
+            refuse(f"mamba_hidden_act {hf['mamba_hidden_act']!r} is not "
+                   "supported: silu")
+        if hf.get("residual_in_fp32"):
+            refuse("residual_in_fp32 true is not supported: the residual "
+                   "stream is in the model's dtype")
+        if hf.get("sliding_window"):
+            refuse("sliding_window is not supported with hybrid_override_"
+                   "pattern: its attention layers are full")
+        inner = hf["mamba_num_heads"] * hf["mamba_head_dim"]
+        if "expand" in hf and hf["expand"] * hf["hidden_size"] != inner:
+            refuse(f"expand {hf['expand']} x hidden_size is not "
+                   f"mamba_num_heads x mamba_head_dim ({inner})")
+        if hf["mamba_num_heads"] % hf["n_groups"]:
+            refuse(f"n_groups {hf['n_groups']} does not divide "
+                   f"mamba_num_heads {hf['mamba_num_heads']}")
+        if len(hf["hybrid_override_pattern"]) != hf["num_hidden_layers"]:
+            refuse("hybrid_override_pattern does not name num_hidden_layers "
+                   "layers")
+        fx = hf.get("moe_intermediate_size") or hf["intermediate_size"]
+        shared = (hf.get("n_shared_experts") or 0) * (
+            hf.get("moe_shared_expert_intermediate_size") or fx)
+        if shared % fx:
+            refuse(f"a shared expert of {shared} is not a whole number of "
+                   f"routed experts' widths ({fx})")
+        family = dict(
+            hybrid_pattern=hf["hybrid_override_pattern"],
+            hidden_act=hf.get("mlp_hidden_act", "relu2"),
+            mlp_gated=False,
+            attn_rope=False,
+            conv_kernel=hf["conv_kernel"],
+            mamba_heads=hf["mamba_num_heads"],
+            mamba_head_dim=hf["mamba_head_dim"],
+            mamba_groups=hf["n_groups"],
+            mamba_state_size=hf["ssm_state_size"],
+            mamba_chunk=hf.get("chunk_size", 128),
+            num_experts=hf.get("n_routed_experts") or 0,
+            moe_intermediate_size=fx,
+            moe_latent_size=hf.get("moe_latent_size") or 0,
+            num_shared_experts=shared // fx,
+            moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            moe_scoring="sigmoid",
+            moe_expert_bias=True,
+            expert_capacity_factor=0.0,
+        )
+        if hf.get("held_experts"):
+            # one expert-parallel rank: ``n_routed_experts`` is what is
+            # loaded, ``published_n_routed_experts`` what the router scores
+            lo, hi = hf["held_experts"]
+            if hi - lo != hf["n_routed_experts"]:
+                refuse(f"held_experts {[lo, hi]} are not the "
+                       f"{hf['n_routed_experts']} of n_routed_experts")
+            family.update(held_experts=(lo, hi),
+                          num_experts=hf["published_n_routed_experts"])
         return family
 
     @classmethod
@@ -867,9 +1077,57 @@ LAGUNA_XS2 = ModelConfig(
     name="poolside/Laguna-XS.2",
 )
 
+# NVIDIA-Nemotron-3-Super-120B-A12B (https://huggingface.co/nvidia/
+# NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json): 88 layers of
+# ONE branch each: 40 Mamba-2 mixers (128 heads of 64 over a state of 128, B
+# and C shared by the 16 heads of one of 8 groups, a 4-tap convolution with a
+# bias: a float32 ``h`` of 4 MB and a conv tail a layer and slot in the state
+# pool), 8 attention layers of 32 query over 2 kv heads with NO rotary
+# embedding (pages), 40 expert layers: 512 ungated relu2 experts of width
+# 2,688 in a latent of 1,024 at top-22 + one shared expert of 5,376 behind a
+# sigmoid router with a selection bias, renormalised and scaled by 5.  One
+# chip holds a cut of it as ONE expert-parallel rank (``held_experts``, set by
+# the profile); what would move or share the state is refused at engine start
+# (the kind's record, ``models/mixers.py``).
+NEMOTRON3_SUPER_120B = ModelConfig(
+    vocab_size=131072,
+    hidden_size=4096,
+    num_layers=88,
+    num_heads=32,
+    num_kv_heads=2,
+    head_dim=128,
+    intermediate_size=2688,
+    rope_theta=10000.0,         # published, and read by no layer (attn_rope)
+    rms_norm_eps=1e-5,
+    hidden_act="relu2",
+    mlp_gated=False,
+    attn_rope=False,
+    max_position_embeddings=262144,
+    hybrid_pattern=(
+        "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+        "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+    conv_kernel=4,
+    mamba_heads=128,
+    mamba_head_dim=64,
+    mamba_groups=8,
+    mamba_state_size=128,
+    mamba_chunk=128,
+    num_experts=512,
+    num_experts_per_tok=22,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=2688,
+    moe_latent_size=1024,
+    num_shared_experts=2,
+    moe_renormalize=True,
+    routed_scaling_factor=5.0,
+    moe_scoring="sigmoid",
+    moe_expert_bias=True,
+    name="nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
               DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B,
-              LAGUNA_XS2)
+              LAGUNA_XS2, NEMOTRON3_SUPER_120B)
 }

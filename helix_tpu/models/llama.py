@@ -7,8 +7,9 @@ TPU-first code:
 
 - Layers are **stacked** (every weight has a leading layer dim) in RUNS of
   one kind (``ModelConfig.layer_runs``: a token mixer, attention, gated
-  short convolution, power retention or the gated delta rule, times an FFN,
-  dense or routed experts), one stack in
+  short convolution, power retention, the gated delta rule or Mamba-2,
+  times an FFN, dense or routed experts, or NONE: a block that is its
+  mixer alone, under ``ModelConfig.hybrid_pattern``), one stack in
   the parameter tree and one ``lax.scan`` a run: a dense decoder is one
   run, DeepSeek-V2 two (``dense_layers`` then ``layers``), LFM2 thirteen,
   of which those that repeat back to back are one GROUP (a stack a run of
@@ -16,14 +17,17 @@ TPU-first code:
   (``run01``, ``run02``), twice (``run09``, ``run10``).
 - A layer that keeps a fixed per-sequence state (a conv layer's tail, a
   retention or delta-rule layer's matrix, a sliding-window layer's ring of
-  K/V) has its look-back injected as attention is, through ONE argument
+  K/V, a Mamba-2 layer's state and conv tail) has its look-back injected as
+  attention is, through ONE argument
   (``state_fn``): the engine builds it from the kind's record
   (``models/mixers.py::STATE_MIXERS``: which earlier tokens are a token's
   own sequence, and the state a sequence carries between calls); without
   one the record's ``oracle`` runs, every row a whole sequence.  The
   COMPUTE around it (projections, gates, norms, rope; a window layer may
   have its own count of query heads and its own rope) is this module's.
-- Three routers, by ``ModelConfig`` (``models/moe.py::route``).
+- Three routers, by ``ModelConfig`` (``models/moe.py::route``); an MLP
+  (dense, expert, shared expert) gated or not (``mlp_gated``), the routed
+  experts in a latent or not (``moe_latent_size``).
 - Attention is injected (``attn_fn``) so the same forward serves training
   (flash attention), prefill (flash + segment masks) and decode (paged
   attention over the engine's KV cache) without re-tracing model code.
@@ -66,6 +70,10 @@ def _act(name: str):
         return jax.nn.silu
     if name in ("gelu", "gelu_new", "gelu_pytorch_tanh", "gelu_tanh"):
         return functools.partial(jax.nn.gelu, approximate=True)
+    if name == "relu2":
+        from helix_tpu.ops.grouped_matmul import relu2
+
+        return relu2
     raise ValueError(f"unknown activation {name}")
 
 
@@ -158,8 +166,9 @@ def init_params(
         ("layers", "experts", "w_down"): kx[3],
     }
 
-    def stack(n, moe, *at, mixer="attn"):
-        """One stack of ``n`` layers of one kind, at ``at`` in the tree."""
+    def stack(n, moe, *at, mixer="attn", ffn=True):
+        """One stack of ``n`` layers of one kind, at ``at`` in the tree
+        (``ffn`` False: blocks of a mixer alone)."""
 
         def w(shape, *name, std=0.02):
             path = at + name
@@ -178,7 +187,9 @@ def init_params(
         # a norm's gain is stored as an offset from ``cfg.norm_offset``
         unit = 1.0 - cfg.norm_offset
         norm = lambda width: {"weight": jnp.full((n, width), unit, dtype)}
-        lp = {"attn_norm": norm(E), "mlp_norm": norm(E)}
+        lp = {"attn_norm": norm(E)}
+        if ffn:
+            lp["mlp_norm"] = norm(E)
         if cfg.post_norms:
             lp["attn_post_norm"] = norm(E)
             lp["mlp_post_norm"] = norm(E)
@@ -209,6 +220,38 @@ def init_params(
             lp["dt_bias"] = {"bias": dt + jnp.log(-jnp.expm1(-dt))}
             lp["o_norm"] = norm(dv)
             lp["out_proj"] = w((nv * dv, E), "out_proj")
+        elif mixer == "mamba2":
+            Hm, C = cfg.mamba_heads, cfg.mamba_channels
+            # ``[z | xBC | dt] = u W_in`` as three weights (the same bytes):
+            # the step's logit is a product of its own, in float32
+            lp["in_z"] = w((E, cfg.mamba_inner), "in_z")
+            lp["in_xbc"] = w((E, C), "in_xbc")
+            lp["in_dt"] = w((E, Hm), "in_dt")
+            # taps at 0.5 (the branch is of the size of its input), a bias
+            # at 0.1
+            kt, kb = jax.random.split(drawn(2000, "conv"))
+            lp["conv"] = {
+                "taps": (jax.random.normal(
+                    kt, (n, C, cfg.conv_kernel), jnp.float32)
+                    * 0.5).astype(dtype),
+                "bias": (jax.random.normal(kb, (n, C), jnp.float32)
+                         * 0.1).astype(dtype)}
+            # ``a = exp(-exp(A_log) * softplus(dt + dt_bias))``: the rate
+            # uniform in [0.5, 4] and the step uniform in log over [0.001,
+            # 0.1] (its bias the inverse softplus), as the delta rule's
+            # above and for its reason: decays of 0.7 to 0.9995 a token.
+            # The skip ``D`` uniform in [0.5, 1.5] (the initialiser's 1
+            # would hide a ``D`` read from the wrong head)
+            ka, kd, ks = jax.random.split(drawn(5000, "A_log"), 3)
+            lp["A_log"] = {"bias": jnp.log(jax.random.uniform(
+                ka, (n, Hm), jnp.float32, 0.5, 4.0))}
+            dt = jnp.exp(jax.random.uniform(
+                kd, (n, Hm), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
+            lp["dt_bias"] = {"bias": dt + jnp.log(-jnp.expm1(-dt))}
+            lp["D"] = {"bias": jax.random.uniform(
+                ks, (n, Hm), jnp.float32, 0.5, 1.5)}
+            lp["o_norm"] = norm(cfg.mamba_inner)
+            lp["out_proj"] = w((cfg.mamba_inner, E), "out_proj")
         elif mixer == "conv":
             # a three-tap depthwise filter at std 0.02 passes a signal
             # thirty times smaller than the residual stream: the taps are
@@ -256,7 +299,15 @@ def init_params(
                 jax.random.fold_in(key, 4000 + zlib.crc32(
                     "/".join(at).encode()) % 1000),
                 (n, KVH), jnp.float32, 3.0, 7.0)}
-        if moe:
+        # an MLP's weights: gate, up, down, or up and down alone
+        mlp_names = ("w_gate", "w_up", "w_down")[0 if cfg.mlp_gated else 1:]
+
+        def mlp(lead, d_in, width, *name):
+            return {nm: w(lead + ((width, d_in) if nm == "w_down"
+                                  else (d_in, width)), *name, nm)
+                    for nm in mlp_names}
+
+        if ffn and moe:
             # router + expert-stacked SwiGLU replaces the dense FFN
             # (models/moe.py); Mixtral's experts are as wide as the FFN
             X, Fx = cfg.num_experts, cfg.expert_width
@@ -272,22 +323,17 @@ def init_params(
                     jax.random.fold_in(key, 3000 + zlib.crc32(
                         "/".join(at).encode()) % 1000),
                     (n, X_all), jnp.float32) * 0.03}
-            lp["experts"] = {
-                "w_gate": w((X, E, Fx), "experts", "w_gate"),
-                "w_up": w((X, E, Fx), "experts", "w_up"),
-                "w_down": w((X, Fx, E), "experts", "w_down"),
-            }
+            # the experts' own width: the latent's, where they live in one
+            Ex = cfg.moe_latent_size or E
+            if cfg.moe_latent_size:
+                lp["fc1"] = w((E, Ex), "fc1")
+                lp["fc2"] = w((Ex, E), "fc2")
+            lp["experts"] = mlp((X,), Ex, Fx, "experts")
             if cfg.num_shared_experts:
-                Fs = cfg.num_shared_experts * Fx
-                lp["shared"] = {
-                    "w_gate": w((E, Fs), "shared", "w_gate"),
-                    "w_up": w((E, Fs), "shared", "w_up"),
-                    "w_down": w((Fs, E), "shared", "w_down"),
-                }
-        else:
-            lp["w_gate"] = w((E, F), "w_gate")
-            lp["w_up"] = w((E, F), "w_up")
-            lp["w_down"] = w((F, E), "w_down")
+                lp["shared"] = mlp(
+                    (), E, cfg.num_shared_experts * Fx, "shared")
+        elif ffn:
+            lp.update(mlp((), E, F))
         if cfg.attention_bias and mixer in ("attn", "window"):
             for nm, width in (("wq", cfg.heads_of(mixer) * D),
                               ("wk", KVH * D), ("wv", KVH * D)):
@@ -323,7 +369,7 @@ def init_params(
     for group in cfg.layer_runs():
         for run in group.runs:
             params[run.key] = stack(run.count * group.reps, run.moe,
-                                    run.key, mixer=run.mixer)
+                                    run.key, mixer=run.mixer, ffn=run.ffn)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = weight(
             jax.random.fold_in(key, 99), (E, V), "lm_head")
@@ -342,11 +388,10 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
     and shards layer blocks across pipeline groups on ``mesh: {pp: N}``
     — a new stacked weight must use "layers" too or it silently
     replicates across the pipeline."""
-    def stack(moe, mixer="attn"):
-        lax_ = {
-            "attn_norm": {"weight": ("layers", None)},
-            "mlp_norm": {"weight": ("layers", None)},
-        }
+    def stack(moe, mixer="attn", ffn=True):
+        lax_ = {"attn_norm": {"weight": ("layers", None)}}
+        if ffn:
+            lax_["mlp_norm"] = {"weight": ("layers", None)}
         if mixer in ("attn", "retention", "window"):
             lax_["wq"] = {"weight": ("layers", "embed", "heads")}
             lax_["wo"] = {"weight": ("layers", "heads", "embed")}
@@ -367,6 +412,16 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             lax_["conv"] = {"taps": ("layers", None, None)}
             lax_["A_log"] = {"bias": ("layers", None)}
             lax_["dt_bias"] = {"bias": ("layers", None)}
+            lax_["o_norm"] = {"weight": ("layers", None)}
+            lax_["out_proj"] = {"weight": ("layers", None, "embed")}
+        elif mixer == "mamba2":
+            # (a mesh is refused for it: the state pool is one device's)
+            for nm in ("in_z", "in_xbc", "in_dt"):
+                lax_[nm] = {"weight": ("layers", "embed", None)}
+            lax_["conv"] = {"taps": ("layers", None, None),
+                            "bias": ("layers", None)}
+            for nm in ("A_log", "dt_bias", "D"):
+                lax_[nm] = {"bias": ("layers", None)}
             lax_["o_norm"] = {"weight": ("layers", None)}
             lax_["out_proj"] = {"weight": ("layers", None, "embed")}
         elif mixer == "conv":
@@ -395,18 +450,24 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             "w_up": {"weight": ("layers", "embed", "mlp")},
             "w_down": {"weight": ("layers", "mlp", "embed")},
         }
-        if moe:
+        experts = {
+            "w_gate": {"weight": ("layers", "expert", "embed", "mlp")},
+            "w_up": {"weight": ("layers", "expert", "embed", "mlp")},
+            "w_down": {"weight": ("layers", "expert", "mlp", "embed")},
+        }
+        if not cfg.mlp_gated:
+            del mlp["w_gate"], experts["w_gate"]
+        if ffn and moe:
             lax_["router"] = {"weight": ("layers", "embed", None)}
             if cfg.moe_expert_bias:
                 lax_["expert_bias"] = {"bias": ("layers", None)}
-            lax_["experts"] = {
-                "w_gate": {"weight": ("layers", "expert", "embed", "mlp")},
-                "w_up": {"weight": ("layers", "expert", "embed", "mlp")},
-                "w_down": {"weight": ("layers", "expert", "mlp", "embed")},
-            }
+            if cfg.moe_latent_size:
+                lax_["fc1"] = {"weight": ("layers", "embed", None)}
+                lax_["fc2"] = {"weight": ("layers", None, "embed")}
+            lax_["experts"] = experts
             if cfg.num_shared_experts:
                 lax_["shared"] = mlp
-        else:
+        elif ffn:
             lax_.update(mlp)
         if cfg.attention_bias and mixer in ("attn", "window"):
             lax_["wq"]["bias"] = ("layers", "heads")
@@ -423,7 +484,7 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
     }
     for group in cfg.layer_runs():
         for run in group.runs:
-            axes[run.key] = stack(run.moe, run.mixer)
+            axes[run.key] = stack(run.moe, run.mixer, run.ffn)
     if not cfg.tie_word_embeddings:
         axes["lm_head"] = {"weight": ("embed", "vocab")}
     return axes
@@ -431,17 +492,18 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
 
 def _swiglu(x, p, act, adapter_ids=None, scoped=False, limit: float = 0.0):
     """``(act(x W_g) * (x W_u)) W_d`` over one dict of three weights
-    (``limit``: the clamped form, ``ops.grouped_matmul.glu``)."""
+    (``limit``: the clamped form, ``ops.grouped_matmul.glu``); over a dict
+    with no ``w_gate`` the ungated ``act(x W_u) W_d``."""
     from helix_tpu.ops.grouped_matmul import glu
 
     scope = jax.named_scope if scoped else (
         lambda _: contextlib.nullcontext())
     with scope("mlp.gate_up"):
-        gate = _dense(x, p["w_gate"], adapter_ids)
         up = _dense(x, p["w_up"], adapter_ids)
+        mid = act(up) if "w_gate" not in p else glu(
+            _dense(x, p["w_gate"], adapter_ids), up, act, limit)
     with scope("mlp.down"):
-        return _dense(glu(gate, up, act, limit).astype(x.dtype),
-                      p["w_down"], adapter_ids)
+        return _dense(mid.astype(x.dtype), p["w_down"], adapter_ids)
 
 
 def mla_softmax_scale(cfg: ModelConfig) -> float:
@@ -640,6 +702,46 @@ def _deltanet_mixer(h, p, layer_cache, cfg, state_fn, post=None):
     return h, new_cache
 
 
+def _mamba2_mixer(h, p, layer_cache, cfg, state_fn):
+    """Mamba-2 (``ops/ssd.py``): ``[z | xBC | dt] = u W_in``, ``x | B | C =
+    silu(conv(xBC) + b)`` through a causal depthwise convolution, a step ``dt
+    = softplus(dt + dt_bias)`` and a log decay ``dt * A`` with ``A =
+    -exp(A_log)`` a head, the state space with its skip ``D x``, then ``g *
+    GroupRMS(y * silu(z))`` (the gate BEFORE the norm, the norm over each
+    group's channels) and ``W_out``.  ``state_fn(xBC, dt, dt * A, taps, bias,
+    D, layer_cache) -> (y [B, S, heads, head dim] float32, new_cache)`` owns
+    the look-back: the convolution's tail and the state ``h`` a sequence
+    carries between calls."""
+    from helix_tpu.ops.quant import maybe_dequant_dense
+
+    B, S, E = h.shape
+    G = cfg.mamba_groups
+    with jax.named_scope("ssd.in_proj"):
+        u = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
+                     cfg.norm_offset)
+        z = _dense(u, p["in_z"])
+        xbc = _dense(u, p["in_xbc"]).astype(h.dtype)
+        # the step's logit stays float32: a context of thousands of tokens
+        # multiplies thousands of decays
+        dt = jax.nn.softplus(
+            maybe_dequant_dense(u, p["in_dt"], compute_dtype=jnp.float32)
+            + p["dt_bias"]["bias"].astype(jnp.float32))
+        la = -jnp.exp(p["A_log"]["bias"].astype(jnp.float32)) * dt
+    y, new_cache = state_fn(
+        xbc, dt, la, p["conv"]["taps"], p["conv"]["bias"], p["D"]["bias"],
+        layer_cache)
+    with jax.named_scope("ssd.norm"):
+        y = (y.reshape(B, S, -1) * jax.nn.silu(z.astype(jnp.float32))
+             ).reshape(B, S, G, -1)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+        y = y.reshape(B, S, -1) * (
+            cfg.norm_offset + p["o_norm"]["weight"].astype(jnp.float32))
+    with jax.named_scope("ssd.out"):
+        h = h + _dense(y.astype(h.dtype), p["out_proj"]).astype(h.dtype)
+    return h, new_cache
+
+
 def _layer(
     h,
     layer_params: Params,
@@ -700,6 +802,9 @@ def _layer(
         k = v = None
         h, new_cache = _deltanet_mixer(
             h, p, layer_cache, cfg, state_fn, post_norm("attn_post_norm"))
+    elif "in_xbc" in p:
+        k = v = None
+        h, new_cache = _mamba2_mixer(h, p, layer_cache, cfg, state_fn)
     elif cfg.is_mla:
         h, (k, v), new_cache = _mla_attention(
             h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
@@ -724,8 +829,9 @@ def _layer(
             if cfg.qk_norm:
                 q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
                 k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
-            q = apply_rope(q, positions, inv_freq, rot)
-            k = apply_rope(k, positions, inv_freq, rot)
+            if cfg.attn_rope:
+                q = apply_rope(q, positions, inv_freq, rot)
+                k = apply_rope(k, positions, inv_freq, rot)
         with jax.named_scope(f"{sc}.kernel"):
             if mixer == "window":
                 res = state_fn(q, k, v, layer_cache)
@@ -750,12 +856,15 @@ def _layer(
             k = v = None
 
     # --- mlp: the layer's kind is what its weights are ---
+    from helix_tpu.models.moe import STATS, moe_ffn
+
+    if "mlp_norm" not in p:
+        # the block is its mixer alone
+        return h, (k, v), new_cache, jnp.zeros((STATS,), jnp.float32)
     x = rms_norm(h, p["mlp_norm"]["weight"], cfg.rms_norm_eps, cfg.norm_offset)
     act = _act(cfg.hidden_act)
     moe_stats = None
     if "router" in p:
-        from helix_tpu.models.moe import moe_ffn
-
         router_w = p["router"]["weight"]
         if router_w.dtype == jnp.int8:
             # dequantise in fp32: the router's softmax runs in fp32, and
@@ -763,8 +872,15 @@ def _layer(
             router_w = router_w.astype(jnp.float32) * p["router"][
                 "scale"
             ].astype(jnp.float32)
+        xr = x
+        if "fc1" in p:
+            # the routed experts live in a latent; the router and the shared
+            # expert read the un-projected input
+            with jax.named_scope("moe.latent_in"):
+                xr = _dense(x, p["fc1"]).astype(h.dtype)
         moe_out, moe_stats = moe_ffn(
-            x, router_w, p.get("experts"), cfg, act,
+            xr, router_w, p.get("experts"), cfg, act,
+            router_x=x if "fc1" in p else None,
             token_mask=moe_token_mask,
             return_stats=True,
             stacked_experts=stacked_experts,
@@ -773,6 +889,9 @@ def _layer(
                          if "expert_bias" in p else None),
             decode_rows=moe_decode_rows,
         )
+        if "fc2" in p:
+            with jax.named_scope("moe.latent_out"):
+                moe_out = _dense(moe_out, p["fc2"]).astype(h.dtype)
         if "shared" in p:
             with jax.named_scope("moe.shared"):
                 moe_out = moe_out + _swiglu(
@@ -784,8 +903,6 @@ def _layer(
     post = post_norm("mlp_post_norm")
     h = h + (post(ffn) if post else ffn).astype(h.dtype)
     if moe_stats is None:
-        from helix_tpu.models.moe import STATS
-
         moe_stats = jnp.zeros((STATS,), jnp.float32)
     return h, (k, v), new_cache, moe_stats
 

@@ -2,7 +2,8 @@
 
 A layer whose memory is not pages (a gated short convolution's tail, power
 retention's matrix, the gated delta rule's matrix and conv tail, a
-sliding-window layer's ring of K/V) keeps, for each sequence, a state of one
+sliding-window layer's ring of K/V, a Mamba-2 layer's state and conv tail)
+keeps, for each sequence, a state of one
 size whatever the sequence's length: a row of the STATE POOL
 (``engine/kv_cache.py``).  ``STATE_MIXERS`` maps the kind's name (what
 ``ModelConfig.layer_types`` calls it) to a :class:`StateMixer`, which holds
@@ -22,9 +23,10 @@ everything the modules ABOVE ``models/`` ask of a kind:
   show them.
 
 A layer's COMPUTE (projections, gates, norms, rope) is ``models/llama.py``'s,
-its operator ``ops/``'s.  A fifth kind is a record here, its compute there,
-its ``ModelConfig`` keys and its tests: nothing under ``engine/``,
-``serving/`` or ``obs/`` spells a kind's name.
+its operator ``ops/``'s.  A further kind is a record here, its compute
+there, its ``ModelConfig`` keys and its tests (the fifth, ``mamba2``, came
+so): nothing under ``engine/``, ``serving/`` or ``obs/`` spells a kind's
+name.
 """
 
 from __future__ import annotations
@@ -143,6 +145,34 @@ def whole_sequence_deltanet_fn(x, g, beta, taps, layer_cache, cfg):
         S0 = jnp.zeros(v.shape[2:3] + (q.shape[-1], v.shape[-1]), jnp.float32)
         return jax.vmap(
             lambda *a: delta_sequence(*a, S0)[0])(q, k, v, g, beta), None
+
+
+def mamba2_heads(y, bias, cfg):
+    """The convolution's output ``y [..., channels]`` (float32) plus its bias
+    through SiLU, as the state space's ``x [..., heads, head dim]`` and ``B, C
+    [..., groups, state]``."""
+    y = jax.nn.silu(y + bias.astype(jnp.float32))
+    lead, G, N = y.shape[:-1], cfg.mamba_groups, cfg.mamba_state_size
+    x, Bm, Cm = jnp.split(
+        y, [cfg.mamba_inner, cfg.mamba_inner + G * N], axis=-1)
+    return (x.reshape(lead + (cfg.mamba_heads, cfg.mamba_head_dim)),
+            Bm.reshape(lead + (G, N)), Cm.reshape(lead + (G, N)))
+
+
+def whole_sequence_mamba2_fn(xbc, dt, la, taps, bias, D, layer_cache, cfg):
+    """The Mamba-2 layer of a forward pass with no cache: every row of the
+    batch is one sequence from its start, so the convolution looks back into
+    zeros and the state space runs from a zero state."""
+    from helix_tpu.ops.ssd import ssd_sequence
+
+    with jax.named_scope("ssd.conv"):
+        x, Bm, Cm = mamba2_heads(
+            whole_sequence_conv_fn(xbc, taps, None)[0], bias, cfg)
+    with jax.named_scope("ssd.kernel"):
+        h0 = jnp.zeros(x.shape[2:] + (Bm.shape[-1],), jnp.float32)
+        y = jax.vmap(lambda *a: ssd_sequence(
+            *a, h0, chunk=cfg.mamba_chunk)[0])(x, dt, la, Bm, Cm)
+        return y + D.astype(jnp.float32)[:, None] * x, None
 
 
 def whole_sequence_window_fn(q, k, v, layer_cache, *, positions, window):
@@ -311,6 +341,42 @@ def _deltanet_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     return deltanet_fn
 
 
+def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+    """A Mamba-2 layer's look-back (``models/llama.py::_mamba2_mixer``) over
+    TWO states a slot: the convolution's tail (``_conv_rows`` at 4 taps over
+    the x | B | C channels) and the float32 array ``h`` a head, the pair
+    ``(conv pool, h pool)``.  ``decode``: the recurrence applied once (on a
+    TPU one pass of the decode kernel over the live slots).  Else the chunked
+    form at the published block, a row's state read at its first block and
+    written at its last.  Called ``(x W_xBC, dt, dt * A, taps, bias, D,
+    carry)``."""
+    from helix_tpu.ops.ssd import ssd_decode, ssd_rows
+
+    t0, qlen, hist, slots = rows
+
+    def mamba2_fn(xbc, dt, la, taps, bias, D, carry_cache):
+        (caches, kacc, vacc, (c_pool, h_pool)), lc = carry_cache
+        Bx, Sx, _ = xbc.shape
+        with jax.named_scope("ssd.conv"):
+            y, c_pool, _ = _conv_rows(
+                xbc, taps, c_pool, lc, t0, qlen, hist, slots)
+            x, Bm, Cm = mamba2_heads(y, bias, cfg)
+        with jax.named_scope("ssd.kernel"):
+            if decode:
+                o, h_pool = ssd_decode(
+                    x[:, 0], dt[:, 0], la[:, 0], Bm[:, 0], Cm[:, 0], h_pool,
+                    lc, qlen > 0, backend=backend)
+            else:
+                flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
+                o, h_pool = ssd_rows(
+                    flat(x), flat(dt), flat(la), flat(Bm), flat(Cm), t0,
+                    qlen, hist, slots, h_pool, lc, chunk=cfg.mamba_chunk)
+            o = o.reshape(x.shape) + D.astype(jnp.float32)[:, None] * x
+        return o, (caches, kacc, vacc, (c_pool, h_pool))
+
+    return mamba2_fn
+
+
 def _window_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     """A window layer's attention (``models/llama.py::_layer``): of a row's
     ``hist[r]`` tokens its slot's rings ``(K rings, V rings)`` hold the last
@@ -386,6 +452,25 @@ def _deltanet_arrays(cfg) -> tuple:
               cfg.linear_value_dim), "float32"))
 
 
+def _mamba2_arrays(cfg) -> tuple:
+    """The conv's tail, the last ``conv_kernel - 1`` rows of its x|B|C
+    channels in the model's dtype, and the state ``h`` float32 as the pool
+    holds it (``ops/ssd.py``: ``pack`` heads to a lane tile, ``[heads /
+    pack, state, pack * head dim]``: the bytes of ``[heads, head dim,
+    state]``)."""
+    from helix_tpu.ops.ssd import head_pack
+
+    k = head_pack(cfg.mamba_head_dim)
+    if cfg.mamba_heads % (k * cfg.mamba_groups):
+        raise ValueError(
+            f"{cfg.name}: {cfg.mamba_heads} Mamba-2 heads of "
+            f"{cfg.mamba_head_dim} in {cfg.mamba_groups} groups: a group's "
+            f"heads must fill whole rows of {k} heads in the state pool")
+    return (((cfg.conv_kernel - 1, cfg.mamba_channels), cfg.dtype),
+            ((cfg.mamba_heads // k, cfg.mamba_state_size,
+              k * cfg.mamba_head_dim), "float32"))
+
+
 def _window_arrays(cfg) -> tuple:
     """The K ring and the V ring ``[sliding_window, kv heads, head_dim]``
     (in the pool's dtype: ``pool_dtype``)."""
@@ -411,6 +496,13 @@ def _check_deltanet(cfg, tp, itemsize) -> None:
     check_deltanet_geometry(
         cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_dim,
         cfg.linear_value_dim)
+
+
+def _check_mamba2(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.ssd_kernel import check_ssd_geometry
+
+    check_ssd_geometry(cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
+                       cfg.mamba_state_size)
 
 
 def _check_window(cfg, tp, itemsize) -> None:
@@ -454,19 +546,32 @@ def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     }
 
 
-def _deltanet_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
-    """... and the 64-token chunks the chunked delta rule runs: a prefill
-    row's ``ceil(rem / 64)`` in every delta layer
-    (``ops/deltanet.py::chunk_table``'s live entries).  Device time under
-    ``deltanet.mix`` in the programs that carry a chunk, over this count,
+def _chunked_rows(chunk_of) -> Callable:
+    """... and the chunks the chunked form runs: a prefill row's ``ceil(rem /
+    chunk_of(cfg))`` in every layer of the kind (for the delta rule
+    ``ops/deltanet.py::chunk_table``'s live entries).  Device time under the
+    kind's kernel scope in the programs that carry a chunk, over this count,
     is the cost of a chunk (PERF.md section 5)."""
+
+    def account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+        return {
+            **_matrix_rows(cfg, cache_cfg, rows, pos, n_extra),
+            "chunks": sum(-(-r.rem // chunk_of(cfg)) for r in rows) * (
+                cfg.num_state_layers),
+        }
+
+    return account
+
+
+def _delta_chunk(cfg) -> int:
     from helix_tpu.ops.deltanet import CHUNK
 
-    return {
-        **_matrix_rows(cfg, cache_cfg, rows, pos, n_extra),
-        "chunks": sum(-(-r.rem // CHUNK) for r in rows) * (
-            cfg.num_state_layers),
-    }
+    return CHUNK
+
+
+_deltanet_account = _chunked_rows(_delta_chunk)
+# the published block of the state space's chunked form
+_mamba2_account = _chunked_rows(lambda cfg: cfg.mamba_chunk)
 
 
 def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
@@ -663,6 +768,44 @@ STATE_MIXERS = {
         ),
         launch=(("window_layers", "layers"),
                 ("window_rows_wrapped", "rows_wrapped")),
+    ),
+    "mamba2": StateMixer(
+        refused_as="a state-space state and a conv tail (Mamba-2)",
+        refusals=(
+            ("multi_device",
+             "the state pool and the state-space kernel are single-device"),
+            ("int8_kv",
+             "the pool beside a state pool is bf16 or f32, and the state is "
+             "a float32 array"),
+            ("adapters", "no LoRA targets on the Mamba-2 projections"),
+            ("spec_decode",
+             "a rejected draft would have to roll the state back"),
+            ("tiered",
+             "a demoted cold middle is resumed without the state at its end"),
+            ("host_tier",
+             "a preempted sequence's state (megabytes a layer) has no host "
+             "tier"),
+            ("prefix_cache",
+             "a filed state is megabytes a layer: a snapshot budget and an "
+             "eviction of its own; set enable_prefix_cache: false"),
+        ),
+        call_refusal="the sequence's state has no place in what it moves",
+        arrays=_mamba2_arrays,
+        check_geometry=_check_mamba2,
+        rows_fn=_mamba2_rows_fn,
+        token_args=3,
+        oracle=lambda cfg, positions: functools.partial(
+            whole_sequence_mamba2_fn, cfg=cfg),
+        account=_mamba2_account,
+        series=(
+            # device time under ssd.kernel in the programs that carry a
+            # chunk, over this, is a block's cost
+            Series("helix_ssd_chunks_total", "counter", "chunks"),
+            _POOL_BYTES,
+            *_rows_series("helix_ssd_rows_total"),
+            _BYTES_TOUCHED,
+        ),
+        launch=(("ssd_layers", "layers"), ("ssd_chunks", "chunks")),
     ),
 }
 

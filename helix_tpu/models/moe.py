@@ -180,7 +180,8 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
         )                                                           # [X]
         xs = xf[order // k]                                         # [T*k, E]
 
-    names = ("w_gate", "w_up", "w_down")
+    # an ungated expert has no ``w_gate``
+    names = tuple(n for n in ("w_gate", "w_up", "w_down") if n in experts_p)
     if layer is None:
         # one layer's experts are a stack of one
         experts_p = {n: jax.tree.map(lambda a: a[None], experts_p[n])
@@ -213,13 +214,20 @@ def _grouped_experts(xf, top_w, top_idx, valid, experts_p, X, act,
 def experts_pallas(xs, plan, tm, experts_p, layer, act, interpret,
                    limit: float = 0.0):
     """The three products through ``ops/grouped_matmul.py``: gate and up
-    in one call, down in a second, one visit plan for both."""
+    in one call, down in a second, one visit plan for both.  An ungated
+    expert (no ``w_gate``): ``act(x W_up)`` in the first call."""
     kw = dict(tm=tm, interpret=interpret)
-    gate, up, down = (experts_p[n] for n in ("w_gate", "w_up", "w_down"))
-    h = grouped_matmul_tpu(
-        xs, gate["weight"], plan, layer, scale=gate.get("scale"),
-        w2=up["weight"], scale2=up.get("scale"), act=act, limit=limit,
-        out_dtype=xs.dtype, **kw)
+    up, down = experts_p["w_up"], experts_p["w_down"]
+    if "w_gate" in experts_p:
+        gate = experts_p["w_gate"]
+        h = grouped_matmul_tpu(
+            xs, gate["weight"], plan, layer, scale=gate.get("scale"),
+            w2=up["weight"], scale2=up.get("scale"), act=act, limit=limit,
+            out_dtype=xs.dtype, **kw)
+    else:
+        h = grouped_matmul_tpu(
+            xs, up["weight"], plan, layer, scale=up.get("scale"), act=act,
+            out_dtype=xs.dtype, **kw)
     return grouped_matmul_tpu(
         h, down["weight"], plan, layer, scale=down.get("scale"), **kw)
 
@@ -245,16 +253,16 @@ def experts_xla(xs, group_sizes, e_row, experts_p, layer, act,
             out = out * wp["scale"][layer][e_row, 0].astype(jnp.float32)
         return out
 
-    gate = grouped(xs, experts_p["w_gate"])
     up = grouped(xs, experts_p["w_up"])
-    return grouped(glu(gate, up, act, limit).astype(xs.dtype),
-                   experts_p["w_down"])
+    mid = act(up) if "w_gate" not in experts_p else glu(
+        grouped(xs, experts_p["w_gate"]), up, act, limit)
+    return grouped(mid.astype(xs.dtype), experts_p["w_down"])
 
 
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             return_dropped=False, return_stats=False, stacked_experts=None,
             backend=None, interpret=False, expert_bias=None,
-            decode_rows: int = 0):
+            decode_rows: int = 0, router_x=None):
     """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
     ``return_dropped`` also the int32 count of (token, choice) assignments
     this call dropped to capacity overflow (always 0 on the dropless
@@ -262,7 +270,8 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
 
     router_p: [E, X] (dequantised); experts_p: {"w_gate"/"w_up":
     {"weight": [X, E, F][, "scale"]}, "w_down": {...}}: int8 weight-only
-    trees pass through unchanged.
+    trees pass through unchanged; without ``w_gate`` the experts are ungated,
+    ``W_down act(W_up x)`` (dropless path only).
 
     token_mask [B, S] (optional): False tokens (padding, inactive decode
     slots) are EXCLUDED from routing entirely, so a request's outputs
@@ -274,7 +283,9 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     process' devices call for), for the dropless path's grouped product
     (``grouped_backend``); ``interpret`` runs its kernel in interpret mode
     (the CPU tests).  ``decode_rows``: the last that many tokens are decode
-    rows on a prefill's axis (``_capacity_experts``)."""
+    rows on a prefill's axis (``_capacity_experts``).  ``router_x [B, S,
+    E']``: what the router scores where that is not the experts' input (experts
+    in a latent: ``x`` is the projected input, ``router_x`` the un-projected)."""
     B, S, E = x.shape
     X = cfg.num_experts
     T = B * S
@@ -285,14 +296,18 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
         else token_mask.reshape(T)
     )
     with jax.named_scope("moe.router"):
-        top_w, top_idx = route(xf, router_p, cfg, expert_bias)
+        top_w, top_idx = route(
+            xf if router_x is None else router_x.reshape(T, -1), router_p,
+            cfg, expert_bias)
     fill = 0.0
     held = cfg.held_experts
     if cfg.expert_capacity_factor > 0:
-        if held is not None or cfg.swiglu_limit:
+        if (held is not None or cfg.swiglu_limit or not cfg.mlp_gated
+                or router_x is not None):
             raise ValueError(
-                "held experts and a clamped SwiGLU are the dropless "
-                "dispatch's: expert_capacity_factor must be 0")
+                "held experts, a clamped SwiGLU, ungated experts and experts "
+                "in a latent are the dropless dispatch's: "
+                "expert_capacity_factor must be 0")
         out, dropped = _capacity_experts(
             xf, top_w, top_idx, valid, experts_p, cfg, act, S, decode_rows
         )

@@ -181,9 +181,11 @@ def delta_sequence(q, k, v, g, beta, S0):
 SLAB = 8    # chunks a pass of the two halves: one 512-token row
 
 
-def chunk_table(t0, qlen, hist, slots, n: int, pool_slots: int):
+def chunk_table(t0, qlen, hist, slots, n: int, pool_slots: int,
+                chunk: int = CHUNK):
     """The chunks of a segment's rows, in the order they run: chunk ``c`` of
-    row ``r`` starts at ``t0[r] + 64 c``.  Rows with tokens and a slot come
+    row ``r`` starts at ``t0[r] + 64 c`` (``chunk``: another block than this
+    module's 64, ``ops/ssd.py``'s).  Rows with tokens and a slot come
     first, then rows with tokens and no slot (a row's chunks stay together
     and in order; rows do not depend on each other), then entries past the
     rows' ends, which are inert.  ``n >= sum ceil(qlen / 64)`` entries,
@@ -200,15 +202,15 @@ def chunk_table(t0, qlen, hist, slots, n: int, pool_slots: int):
         jnp.where(qlen > 0, jnp.where(has_slot, 0, 1), 2), stable=True)
     t0, qlen, hist, slots, has_slot = (
         a[order] for a in (t0, qlen, hist, slots, has_slot))
-    counts = ((qlen + CHUNK - 1) // CHUNK).astype(jnp.int32)
+    counts = ((qlen + chunk - 1) // chunk).astype(jnp.int32)
     ends = jnp.cumsum(counts)
     e = jnp.arange(n, dtype=jnp.int32)
     r = jnp.minimum(jnp.sum(ends[None, :] <= e[:, None], axis=1), R - 1)
     live = e < ends[-1]
     c = e - (ends[r] - counts[r])
     return {
-        "start": t0[r] + c * CHUNK,
-        "left": jnp.where(live, qlen[r] - c * CHUNK, 0),
+        "start": t0[r] + c * chunk,
+        "left": jnp.where(live, qlen[r] - c * chunk, 0),
         "first": live & (c == 0),
         "write": live & has_slot[r] & (c == counts[r] - 1),
         "slot": jnp.clip(slots[r], 0, pool_slots - 1).astype(jnp.int32),

@@ -61,32 +61,91 @@ def check_deltanet_geometry(key_heads: int, value_heads: int, dk: int,
             "attn_backend='reference' explicitly, or extend the kernel.")
 
 
-def _kernel(layer_ref, order_ref, count_ref, q_ref, k_ref, v_ref, a_ref,
-            b_ref, s_ref, o_ref, so_ref, *, hb: int, d: int):
-    del layer_ref, order_ref                 # read by the index maps
-    n = pl.program_id(0)
-    count = count_ref[0]
+def state_decode_call(live, vecs, pool, layer, order, count, *, hb: int,
+                      name: str, interpret: bool = False):
+    """The frame of a one-pass decode kernel over a pool of matrix states
+    (this module's and ``ops/ssd_kernel.py``'s): ``vecs`` are ``[B, H, d]``
+    float32 operands a row and head, ``pool [L, N, H, dk, d]`` float32 with
+    ``N >= B`` (row ``b`` is slot ``b``).  Grid ``(rows, blocks of hb
+    heads)``, sequential; ``layer``, ``order`` (the live rows first) and
+    ``count`` go in by scalar prefetch, and a visit names its own (row, head
+    block) while the row is live, the last live one after (nothing is fetched
+    or written for it).  ``live(*vec refs, state ref, out ref, state out
+    ref)`` is the body of a live visit, over blocks ``[hb, d]`` and ``[hb,
+    dk, d]``.  The pool is aliased in and out: one read and one write of a
+    live slot's state.  Returns ``(o [B, H, d] float32, pool)``."""
+    B, H, d = vecs[0].shape
+    blocks = H // hb
 
-    @pl.when(n < count)
-    def _live():
-        def column(row):
-            # [j, c] = u[j]: a vector down the sublanes, across every lane
-            return jnp.broadcast_to(row, (d, d)).T
+    def kernel(layer_ref, order_ref, count_ref, *refs):
+        del layer_ref, order_ref             # read by the index maps
+        s_ref, o_ref, so_ref = refs[-3:]
+        n = pl.program_id(0)
+        count = count_ref[0]
 
-        for h in range(hb):                                  # static unroll
-            at = pl.ds(h, 1)
-            kc = column(k_ref[at, :])
-            s = s_ref[h] * a_ref[at, :]                      # the decay
-            held = jnp.sum(kc * s, axis=0, keepdims=True)    # [1, dv]
-            s = s + kc * (b_ref[at, :] * (v_ref[at, :] - held))
-            so_ref[h] = s
-            o_ref[at, :] = jnp.sum(
-                column(q_ref[at, :]) * s, axis=0, keepdims=True)
+        @pl.when(n < count)
+        def _live():
+            live(*refs)
 
-    @pl.when(jnp.logical_and(count == 0, jnp.logical_and(
-        n == 0, pl.program_id(1) == 0)))
-    def _nothing_live():
-        so_ref[...] = s_ref[...]
+        @pl.when(jnp.logical_and(count == 0, jnp.logical_and(
+            n == 0, pl.program_id(1) == 0)))
+        def _nothing_live():
+            so_ref[...] = s_ref[...]
+
+    def visit(n, j, layer, order, count):
+        """The (row, head block) a visit names: its own while the row is
+        live, the last live one after."""
+        dead = n >= count[0]
+        row = order[jnp.clip(jnp.minimum(n, count[0] - 1), 0, B - 1)]
+        return row, jnp.where(dead, blocks - 1, j)
+
+    def vec_map(n, j, *pre):
+        return visit(n, j, *pre) + (0,)
+
+    def state_map(n, j, layer, order, count):
+        return (layer[0],) + visit(n, j, layer, order, count) + (0, 0)
+
+    vec = pl.BlockSpec((None, hb, d), vec_map)
+    state = pl.BlockSpec((None, None, hb) + pool.shape[3:], state_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, blocks),
+        in_specs=[vec] * len(vecs) + [state],
+        out_specs=[vec, state],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, d), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # the pool, behind the three prefetched scalars and the vectors
+        input_output_aliases={3 + len(vecs): 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        name=name,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
+        jnp.asarray(count, jnp.int32).reshape(1), *vecs, pool,
+    )
+
+
+def _live(q_ref, k_ref, v_ref, a_ref, b_ref, s_ref, o_ref, so_ref, *,
+          hb: int, d: int):
+    def column(row):
+        # [j, c] = u[j]: a vector down the sublanes, across every lane
+        return jnp.broadcast_to(row, (d, d)).T
+
+    for h in range(hb):                                      # static unroll
+        at = pl.ds(h, 1)
+        kc = column(k_ref[at, :])
+        s = s_ref[h] * a_ref[at, :]                          # the decay
+        held = jnp.sum(kc * s, axis=0, keepdims=True)        # [1, dv]
+        s = s + kc * (b_ref[at, :] * (v_ref[at, :] - held))
+        so_ref[h] = s
+        o_ref[at, :] = jnp.sum(
+            column(q_ref[at, :]) * s, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -112,49 +171,12 @@ def deltanet_decode_tpu(
     if not interpret:
         check_deltanet_geometry(H, H, dk, dv)
     hb = head_block(H)
-    blocks = H // hb
-
-    def visit(n, j, layer, order, count):
-        """The (row, head block) a visit names: its own while the row is
-        live, the last live one after."""
-        dead = n >= count[0]
-        row = order[jnp.clip(jnp.minimum(n, count[0] - 1), 0, B - 1)]
-        return row, jnp.where(dead, blocks - 1, j)
-
-    def vec_map(n, j, *pre):
-        return visit(n, j, *pre) + (0,)
-
-    def state_map(n, j, layer, order, count):
-        return (layer[0],) + visit(n, j, layer, order, count) + (0, 0)
-
-    vec = pl.BlockSpec((None, hb, dv), vec_map)
-    state = pl.BlockSpec((None, None, hb, dk, dv), state_map)
     across = lambda a: jnp.broadcast_to(
         a.astype(jnp.float32)[..., None], (B, H, dv))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, blocks),
-        in_specs=[vec, vec, vec, vec, vec, state],
-        out_specs=[vec, state],
-    )
-    o, s_pool = pl.pallas_call(
-        functools.partial(_kernel, hb=hb, d=dk),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
-                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype)],
-        # operand 8 (after the three prefetched scalars): the pool
-        input_output_aliases={8: 1},
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
-        name="deltanet_decode_tpu",
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
-        jnp.asarray(count, jnp.int32).reshape(1),
-        q, k, v, across(decay), across(beta), s_pool,
-    )
-    return o, s_pool
+    return state_decode_call(
+        functools.partial(_live, hb=hb, d=dk),
+        (q, k, v, across(decay), across(beta)), s_pool, layer, order, count,
+        hb=hb, name="deltanet_decode_tpu", interpret=interpret)
 
 
 CHUNK_HEAD_BLOCK = 8    # value heads a grid step of the chunk kernel: 1.4 MB in

@@ -19,7 +19,8 @@ layers ``[n, X, K, N]`` and read as stored.
   dtype skip the convert.
 - **Gate and up in one call** (``w2``/``act``): both read the same rows, and
   ``act(x @ W1) * (x @ W2)`` is written in the activations' dtype, so the
-  two f32 intermediates never reach HBM.
+  two f32 intermediates never reach HBM.  ``act`` without ``w2`` is the
+  ungated expert's ``act(x @ W1)``, the same epilogue with one operand.
 - A row tile shared by several groups is visited by each in turn; a visit
   stores only its own group's rows (the output tile stays in VMEM between
   consecutive visits).  Rows past the last group belong to nobody and hold
@@ -67,6 +68,11 @@ def glu(gate, up, act, limit: float = 0.0):
         gate = jnp.minimum(gate, limit)
         up = jnp.clip(up, -limit, limit)
     return act(gate) * up
+
+
+def relu2(x):
+    """Squared ReLU, an ungated expert's activation."""
+    return jnp.square(jax.nn.relu(x))
 
 
 def row_tile(rows: int, groups: int) -> int:
@@ -146,6 +152,8 @@ def _kernel(layer_ref, offs_ref, group_ref, tile_ref, count_ref, x_ref,
         y = product(w_refs[0], s_refs[0])
         if n_w == 2:
             y = glu(y, product(w_refs[1], s_refs[1]), act, limit)
+        elif act is not None:
+            y = act(y)          # an ungated expert: no second operand
         g = group_ref[v]
         row = tile_ref[v] * tm + jax.lax.broadcasted_iota(
             jnp.int32, (tm, 1), 0)
@@ -166,7 +174,7 @@ def grouped_matmul_tpu(
     scale=None,     # [n, X, 1, N] f32 per-output-channel scales of w
     w2=None,        # a second weight like w: the call computes
     scale2=None,    # glu(x @ w, x @ w2, act, limit)
-    act: Optional[Callable] = None,
+    act: Optional[Callable] = None,     # without w2: act(x @ w)
     limit: float = 0.0,
     tm: int,
     out_dtype=jnp.float32,
@@ -175,7 +183,7 @@ def grouped_matmul_tpu(
     """Returns ``[rows, N]`` in ``out_dtype``."""
     rows, K = x.shape
     n, X, Kw, N = w.shape
-    assert Kw == K and (w2 is None) == (act is None)
+    assert Kw == K and (w2 is None or act is not None)
     if not interpret:
         check_grouped_geometry(K, N)
     ws = tuple(a for a in (w, w2) if a is not None)

@@ -1,0 +1,237 @@
+"""The selective state space of Mamba-2 (state space duality,
+arXiv:2405.21060): a mixer whose memory is one array a head, whatever the
+sequence's length.
+
+A head of ``P`` channels keeps ``h [P, N]``.  With a step ``dt_t > 0``, a log
+decay ``la_t = dt_t * A <= 0`` (one scalar a head and token), and ``B_t, C_t
+[N]`` shared by the heads of a group::
+
+    h_t = exp(la_t) h_{t-1} + (dt_t x_t) B_t^T
+    y_t = h_t C_t
+
+(the skip ``D x_t`` and everything around it are the layer's:
+``models/llama.py``).  Two forms of the same function live here, ``jax.numpy``
+in float32 at the highest matmul precision: the recurrence (``ssd_step`` one
+token a row, ``ssd_recurrence`` scanned over a sequence: the definition, what
+the tests hold the rest to) and the CHUNKED form at a block of ``chunk``
+tokens (``ssd_chunk``: within a block ``Y = ((C B^T) * L) (dt x) + exp(G) C
+h_0`` and ``h_end = exp(G_last) h_0 + sum_s exp(G_last - G_s) (dt_s x_s)
+B_s^T``, ``G`` the running sum of ``la`` from the block's start), which the
+rows of fresh tokens of a step run from their slots' states (``ssd_rows``),
+reading a state once and writing it once a row and chunk.  On a TPU the
+decode step is ``ops/ssd_kernel.py``.
+
+THE POOL'S LAYOUT.  A state of head width under 128 is stored with ``pack =
+128 / P`` heads to a 128-lane tile and the state axis down the sublanes:
+``[H / pack, N, pack * P]`` (``pack_state``; the same bytes as ``[H, P,
+N]``).  The decode kernel then decays, writes and reads a tile with ``B`` and
+``C`` down the sublanes and everything a head has across the lanes, and a
+layer's ``x`` and ``y`` ``[H * P]`` are rows of it as they stand.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def head_pack(P: int) -> int:
+    """Heads that share a 128-lane tile of the pool."""
+    return 128 // P if P < 128 and 128 % P == 0 else 1
+
+
+def pack_state(h):
+    """``h [..., H, P, N]`` as the pool holds it, ``[..., H / pack, N, pack *
+    P]``."""
+    *lead, H, P, N = h.shape
+    k = head_pack(P)
+    h = h.reshape(*lead, H // k, k, P, N)
+    return jnp.moveaxis(h, -1, -3).reshape(*lead, H // k, N, k * P)
+
+
+def unpack_state(hp, P: int):
+    """``pack_state``'s inverse."""
+    *lead, I, N, W = hp.shape
+    k = W // P
+    h = jnp.moveaxis(hp.reshape(*lead, I, N, k, P), -3, -1)
+    return h.reshape(*lead, I * k, P, N)
+
+
+def rows_of(v, I: int):
+    """``v [..., G, N]`` (a group's ``B`` or ``C``) for each of the ``I``
+    packed rows of heads: row ``i`` holds heads of ONE group."""
+    return jnp.repeat(v, I // v.shape[-2], axis=-2)
+
+
+def lanes(v, P: int, I: int):
+    """``v [..., H]`` (a scalar a head) across its head's ``P`` lanes of the
+    packed rows: ``[..., I, pack * P]``."""
+    return jnp.repeat(v, P, axis=-1).reshape(v.shape[:-1] + (I, -1))
+
+
+def ssd_step(x, dt, la, Bm, Cm, h):
+    """The recurrence, one token a row: ``x [B, H, P]``, ``dt, la [B, H]``,
+    ``Bm, Cm [B, G, N]``, ``h [B, H, P, N]``.  Returns ``(y [B, H, P], h)``,
+    float32."""
+    H, G = x.shape[1], Bm.shape[1]
+    Bh, Ch = (jnp.repeat(a, H // G, axis=1) for a in (Bm, Cm))
+    h = jnp.exp(la)[..., None, None] * h + (
+        (dt[..., None] * x)[..., :, None] * Bh[..., None, :])
+    return jnp.einsum("bhpn,bhn->bhp", h, Ch, precision=_HI), h
+
+
+def ssd_recurrence(x, dt, la, Bm, Cm, h0):
+    """The definition over one sequence, token by token: ``x [T, H, P]``,
+    ``dt, la [T, H]``, ``Bm, Cm [T, G, N]``, from ``h0 [H, P, N]``.  Returns
+    ``(y [T, H, P], h_T)``."""
+
+    def token(h, a):
+        y, h = ssd_step(*(v[None] for v in a), h[None])
+        return h[0], y[0]
+
+    h, y = jax.lax.scan(token, h0, (x, dt, la, Bm, Cm))
+    return y, h
+
+
+def ssd_step_packed(x, dt, la, Bm, Cm, hp):
+    """``ssd_step`` on states as the pool holds them, ``hp [B, I, N, W]``:
+    what a CPU runs for a decode step (the kernel's function, elementwise)."""
+    B, H, P = x.shape
+    I = hp.shape[1]
+    hp = lanes(jnp.exp(la), P, I)[:, :, None, :] * hp + (
+        rows_of(Bm, I)[..., None]
+        * (dt[..., None] * x).reshape(B, I, 1, -1))
+    y = jnp.einsum("binw,bin->biw", hp, rows_of(Cm, I), precision=_HI)
+    return y.reshape(B, H, P), hp
+
+
+def ssd_chunk(x, dt, la, Bm, Cm, hp):
+    """One block of one sequence against the state it meets: ``x [C, H,
+    P]``, ``dt, la [C, H]``, ``Bm, Cm [C, G, N]``, ``hp [I, N, W]`` (packed).
+    A token of zeros with ``dt 0`` and ``la 0`` (padding behind a row's last
+    token) writes nothing and decays nothing.  Returns ``(y [C, H, P], hp
+    after the block)``; everything that exponentiates is float32."""
+    C, H, P = x.shape
+    G, I = Bm.shape[1], hp.shape[0]
+    Gs = jnp.cumsum(la, axis=0)                                  # [C, H]
+    xdt = dt[..., None] * x
+    # within the block: (C B^T) * L, a group's product under a head's decay
+    cb = jnp.einsum("tgn,sgn->gts", Cm, Bm, precision=_HI)
+    low = jnp.tril(jnp.ones((C, C), bool))
+    L = jnp.exp(jnp.where(low, Gs.T[:, :, None] - Gs.T[:, None, :], -jnp.inf))
+    y = jnp.einsum("hts,shp->thp", jnp.repeat(cb, H // G, axis=0) * L, xdt,
+                   precision=_HI)
+    # against the state, as the pool holds it
+    y = y + (lanes(jnp.exp(Gs), P, I) * jnp.einsum(
+        "tin,inw->tiw", rows_of(Cm, I), hp, precision=_HI)
+             ).reshape(C, H, P)
+    last = Gs[-1]
+    xw = (xdt * jnp.exp(last - Gs)[..., None]).reshape(C, I, -1)
+    hp = lanes(jnp.exp(last), P, I)[:, None, :] * hp + jnp.einsum(
+        "sin,siw->inw", rows_of(Bm, I), xw, precision=_HI)
+    return y, hp
+
+
+def ssd_sequence(x, dt, la, Bm, Cm, h0, chunk: int = 128):
+    """``ssd_recurrence``'s function in the chunked form: one sequence of any
+    length from ``h0 [H, P, N]``."""
+    T, P = x.shape[0], x.shape[-1]
+    n = -(-T // chunk)
+
+    def chunks(a):
+        a = jnp.pad(a, ((0, n * chunk - T),) + ((0, 0),) * (a.ndim - 1))
+        return a.reshape((n, chunk) + a.shape[1:])
+
+    def block(hp, a):
+        y, hp = ssd_chunk(*a, hp)
+        return hp, y
+
+    hp, y = jax.lax.scan(
+        block, pack_state(h0), tuple(chunks(a) for a in (x, dt, la, Bm, Cm)))
+    return y.reshape((n * chunk,) + x.shape[1:])[:T], unpack_state(hp, P)
+
+
+def ssd_rows(x, dt, la, Bm, Cm, t0, qlen, hist, slots, h_pool, layer, *,
+             chunk: int = 128):
+    """Rows of fresh tokens on one flat axis (a prefill segment): row ``r``
+    is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
+    ``slots[r]``, with ``hist[r]`` tokens behind it (0: it starts from
+    zeros).  A row runs ``ceil(qlen / chunk)`` blocks against its slot's
+    state, read once at its first and written once at its last; a row with no
+    token is not visited; a row whose slot lies past the pool (no slot)
+    starts from zeros and writes nothing.  ``x [T, H, P]``, ``dt, la [T,
+    H]``, ``Bm, Cm [T, G, N]``, ``h_pool [L, slots, I, N, W]``.  Returns ``(y
+    [T, H, P] float32, h_pool)``.  Plain ``jax.numpy`` on every backend: the
+    chunk kernel is a later change's."""
+    from helix_tpu.ops.deltanet import chunk_table
+
+    T, R = x.shape[0], t0.shape[0]
+    N = h_pool.shape[1]
+    # a row's first block may hold one token, every further one a whole block
+    n = min(R, T) + (T - min(R, T)) // chunk
+    table, count = chunk_table(t0, qlen, hist, slots, n, N, chunk)
+    at = jnp.arange(chunk, dtype=jnp.int32)
+    # the loop carries the pool with a slot's rows of heads and its state axis
+    # as ONE axis: a block's products then cannot lend the WHOLE pool their
+    # layout (a 128-token chunk's program transposed 2.7 GB twice a layer:
+    # PERF.md section 6, PR 45); a slot's own 4 MB take what layout they like
+    slot_shape = h_pool.shape[2:]
+    pool = h_pool.reshape(h_pool.shape[:2] + (-1, slot_shape[-1]))
+
+    def entry(e, carry):
+        y, hp, pool = carry
+        mine = at < table["left"][e]
+        where = table["start"][e] + at
+        # what lies behind a row's last token in its last block is a
+        # neighbour's, or padding whose values nothing vouches for: selected
+        # out, never multiplied out
+        own = lambda a: jnp.where(
+            mine.reshape((chunk,) + (1,) * (a.ndim - 1)),
+            a[jnp.clip(where, 0, T - 1)], 0.0)
+        slot = table["slot"][e]
+        hp = jnp.where(table["first"][e], jnp.where(
+            table["from_state"][e], pool[layer, slot].reshape(slot_shape),
+            0.0), hp)
+        yc, hp = ssd_chunk(*(own(a) for a in (x, dt, la, Bm, Cm)), hp)
+        pool = pool.at[layer, jnp.where(table["write"][e], slot, N)].set(
+            hp.reshape(pool.shape[2:]), mode="drop")
+        return y.at[jnp.where(mine, where, T)].set(yc, mode="drop"), hp, pool
+
+    # what no row owns reads zeros; entries past the rows' ends are not run
+    y, _, pool = jax.lax.fori_loop(0, count, entry, (
+        jnp.zeros(x.shape, jnp.float32),
+        jnp.zeros(slot_shape, h_pool.dtype), pool))
+    return y, pool.reshape(h_pool.shape)
+
+
+def ssd_decode(x, dt, la, Bm, Cm, h_pool, layer, live, *, backend=None,
+               interpret: bool = False):
+    """One decode step of every slot: row ``b`` is slot ``b``'s one fresh
+    token (``live [B]`` bool: idle slots and rows that sit the step out write
+    nothing and read zeros).  ``x [B, H, P]``, ``dt, la [B, H]``, ``Bm, Cm
+    [B, G, N]``; the pool ``h [L, slots, I, N, W]`` with ``slots >= B``,
+    updated IN PLACE at ``layer``.  Returns ``(y [B, H, P] float32,
+    h_pool)``.
+
+    On a TPU it is one pass of ``ssd_decode_tpu`` over the live slots
+    (``interpret``: the same kernel in interpret mode, for tests on a CPU
+    with ``backend="pallas"``); on a CPU, or for ``backend="reference"``, the
+    plain recurrence."""
+    from helix_tpu.ops.attention import resolve_backend
+
+    B = x.shape[0]
+    N = h_pool.shape[1]
+    if resolve_backend(backend) == "pallas":
+        from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
+
+        order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+        y, h_pool = ssd_decode_tpu(
+            dt[..., None] * x, jnp.exp(la), Bm, Cm, h_pool, layer, order,
+            jnp.sum(live).astype(jnp.int32), interpret=interpret)
+    else:
+        y, hp = ssd_step_packed(x, dt, la, Bm, Cm, h_pool[layer, :B])
+        dest = jnp.where(live, jnp.arange(B, dtype=jnp.int32), N)
+        h_pool = h_pool.at[layer, dest].set(hp, mode="drop")
+    return jnp.where(live[:, None, None], y, 0.0), h_pool

@@ -74,6 +74,19 @@ def test_no_module_above_models_names_a_state_kind():
     assert names_of_a_kind(ROOT, tuple(STATE_MIXERS)) == []
 
 
+def test_the_fifth_kind_is_spelt_nowhere_above_models():
+    """PR 45's kind (Mamba-2) came as a record, its compute and its operator:
+    no module under ``engine/``, ``serving/`` or ``obs/`` names it, by its
+    kind's name, its operator's (``ssd``: the series, the launch attributes,
+    the flight fields) or its family's."""
+    assert "mamba2" in STATE_MIXERS
+    assert names_of_a_kind(ROOT, ("mamba2", "mamba", "ssd")) == []
+    for rel in ABOVE_MODELS:
+        with open(os.path.join(ROOT, rel)) as f:
+            text = f.read().lower()
+        assert "mamba" not in text and "ssd_" not in text, rel
+
+
 def test_the_search_finds_the_names_it_is_for(tmp_path):
     """The forms are found where they stand: the search is not blind."""
     for rel in ABOVE_MODELS:
@@ -148,7 +161,7 @@ def test_refusal_rows_name_the_seven_settings_or_say_which_it_serves(kind):
 
 def test_the_refusal_rows_stand_in_the_order_they_were_written():
     """The first row met is the one raised, and the engine splices the
-    kinds' rows among its own by the records' ORDER: the 34 rows as
+    kinds' rows among its own by the records' ORDER: the 41 rows as
     (setting, what of the model meets it), in the order they have had since
     each was written."""
     from helix_tpu.engine.engine import _REFUSALS, _SETTINGS
@@ -168,9 +181,11 @@ def test_the_refusal_rows_stand_in_the_order_they_were_written():
         + [("multi_device", "held experts (one expert-parallel rank of the "
             "routed experts)")]
         + [(s, "a ring of K/V a slot (sliding-window attention)")
+           for s in seven]
+        + [(s, "a state-space state and a conv tail (Mamba-2)")
            for s in seven])
     assert [(key, prop) for key, (prop, _), _ in _REFUSALS] == want
-    assert len(want) == 34
+    assert len(want) == 41
 
 
 def _cfgs(kind):
@@ -181,7 +196,8 @@ def _cfgs(kind):
 
     cfg = ModelConfig.tiny(
         vocab_size=64, dtype="float32", num_layers=2,
-        layer_types=(kind, "attn"), sliding_window=8)
+        layer_types=(kind, "attn"), sliding_window=8, conv_kernel=4,
+        mamba_heads=4, mamba_head_dim=32, mamba_groups=1, mamba_state_size=8)
     return cfg, EngineConfig(max_decode_batch=2).cache_config("float32")
 
 

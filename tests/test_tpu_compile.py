@@ -535,3 +535,88 @@ def test_grouped_product_compiles_at_2048_by_512(one_chip, rows):
         S((), jnp.int32)).compile()
     assert compiled.as_text().count("grouped_matmul_tpu") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
+
+
+# ---- Nemotron-3-Super: Mamba-2 layers, experts in a latent (ISSUE 45) -------
+
+
+def test_ssd_decode_kernel_compiles_at_the_published_geometry(one_chip):
+    """Nemotron-3-Super's decode kernel: 64 rows, 128 heads of 64 over a state
+    of 128 in groups of 16 heads, float32, two heads to a lane tile, in a pool
+    of ten layers (2.68 GB), donated: aliased to its output, no copy."""
+    from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
+
+    B, H, P, G, N, L = 64, 128, 64, 8, 128, 10
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    compiled = jax.jit(ssd_decode_tpu, donate_argnums=(4,)).lower(
+        S((B, H, P)), S((B, H)), S((B, G, N)), S((B, G, N)),
+        S((L, B, H // 2, N, 128)), S((), jnp.int32), S((B,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert "ssd_decode_tpu" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = L * B * H * P * N * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+@pytest.mark.parametrize("tokens,rows", [(512, 1), (512, 64)],
+                         ids=["one_row", "wave"])
+def test_ssd_chunked_form_compiles_at_the_published_geometry(
+        one_chip, tokens, rows):
+    """The chunked form over a prefill segment (``ops/ssd.py::ssd_rows``) at
+    the published block of 128: plain ``jax.numpy``, the pool donated and
+    aliased to its output, a block's tokens gathered a block at a time so a
+    wave of 64 rows holds no more temporaries than one row does."""
+    import functools
+
+    from helix_tpu.ops.ssd import ssd_rows
+
+    H, P, G, N, L, B = 128, 64, 8, 128, 10, 64
+
+    def S(shp, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    vec = S((rows,), jnp.int32)
+    compiled = jax.jit(
+        functools.partial(ssd_rows, chunk=128), donate_argnums=(9,)).lower(
+        S((tokens, H, P)), S((tokens, H)), S((tokens, H)),
+        S((tokens, G, N)), S((tokens, G, N)), vec, vec, vec, vec,
+        S((L, B, H // 2, N, 128)), S((), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * B * H * P * N * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [1408, 12672], ids=["decode", "chunk"])
+def test_ungated_grouped_product_compiles_at_1024_by_2688(one_chip, rows):
+    """128 held experts of 1024 x 2688 in the latent, int8, the layer picked
+    from a stack of eight, ONE operand and relu2 in the first call (no gate
+    matrix), then down; at the row tile that 64 x 22 and 576 x 22 assignments
+    of which a quarter are held give."""
+    from helix_tpu.ops.grouped_matmul import (
+        grouped_matmul_tpu, relu2, row_tile, visit_plan)
+
+    n, X, E, F = 8, 128, 1024, 2688
+    tm = row_tile(rows * X // 512, X)
+    assert tm == (32 if rows == 1408 else 128)
+
+    def S(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    def op(x, wu, su, wd, sd, sizes, layer):
+        plan = visit_plan(sizes, rows, tm)
+        h = grouped_matmul_tpu(
+            x, wu, plan, layer, scale=su, act=relu2, tm=tm,
+            out_dtype=x.dtype)
+        return grouped_matmul_tpu(h, wd, plan, layer, scale=sd, tm=tm)
+
+    up = (S((n, X, E, F), jnp.int8), S((n, X, 1, F), jnp.float32))
+    down = (S((n, X, F, E), jnp.int8), S((n, X, 1, E), jnp.float32))
+    compiled = jax.jit(op).lower(
+        S((rows, E), jnp.bfloat16), *up, *down, S((X,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert compiled.as_text().count("grouped_matmul_tpu") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < X * E * F

@@ -1,7 +1,8 @@
 """Compile whole engine steps at published widths for a DESCRIBED TPU v5e,
 without the chip: DeepSeek-V2-Lite (latent attention, experts), LFM2-8B-A1B
 (conv layers), Brumby-14B (power retention), GigaChat3.5 (the gated delta
-rule beside latent attention) and Laguna-XS.2 (sliding-window layers).
+rule beside latent attention), Laguna-XS.2 (sliding-window layers) and
+Nemotron-3-Super (Mamba-2 layers, experts in a latent).
 
 The rules of ``test_tpu_compile.py`` hold here (its docstring); the fixtures
 are ``tests/tpu_topology.py``'s.  Nothing runs, so these say nothing about
@@ -376,3 +377,86 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+
+
+def test_ssd_step_compiles_at_published_widths(one_chip):
+    """A whole engine step of Nemotron-3-Super cut to three published layers
+    in two blocks (attention + held latent experts, a Mamba-2 layer alone;
+    int8 weights, 64 slots) for the described chip, the program that carries
+    a 512-token chunk with history beside the decode rows: the state-space
+    decode kernel over the state pool in the carry, the chunked form, the
+    paged kernel at 32 query over 2 kv heads, the one-operand grouped product
+    over 128 of 512 experts in the latent, and both state arrays updated in
+    place.  ONE program: the decode-only one holds nothing this does not.
+    The chunk is 128 tokens, ONE block of the chunked form: the program that
+    PR 45's first chip run found transposing the whole state pool twice a
+    layer (``ops/ssd.py::ssd_rows`` says how that is prevented)."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import NEMOTRON3_SUPER_120B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        NEMOTRON3_SUPER_120B, num_layers=3, hybrid_pattern="*EM",
+        held_experts=(0, 128))
+    assert cfg.ffns == ("moe", "none")
+    B, max_pages, pages = 64, 160, 2048
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == vs == (1, 16, 2, 128)
+    assert cc.state_shapes(cfg) == (
+        ((1, B, 3, 10240), "bfloat16"), ((1, B, 64, 128, 128), "float32"))
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = 128, 1
+    pargs = (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, True, rows, 1, 0)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("ssd_decode_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention"):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # the state pool is updated in place: aliased whole, no temporary of its
+    # size and no copy of it in another layout
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
+    import re
+
+    assert not re.search(r"= f32\[1,64,64,128,128\]\S* copy\(", text)
