@@ -1137,8 +1137,11 @@ def phase_kernel_ssd(spec, seed, rehearse):
     of two: the states the live slots are left with, the outputs, and every
     other slot and layer bit for bit; and what a bfloat16 pool would read
     (the CONTROL: it must lie over the limit).  Then the chunked form at 512
-    tokens against the token-by-token recurrence: a row from zeros, and the
-    row that continues it from the state the first left."""
+    tokens, in the two halves a TPU runs (``ops/ssd.py::state_free`` and
+    ``ssd_chunk_tpu``), against the token-by-token recurrence run on the
+    host's CPU: a row from zeros, and the row that continues it from the
+    state the first left; and the device's time a layer for the whole and
+    for each half (``_ssd_form_times``)."""
     from helix_tpu.ops import ssd
 
     phase_kernel(spec, seed, rehearse)
@@ -1198,7 +1201,10 @@ def phase_kernel_ssd(spec, seed, rehearse):
             jax.device_put(jnp.zeros((H, P, N)), cpu))
     want, h_end = np.asarray(want), np.asarray(h_end)
     pool = jnp.zeros((L, 4) + pool.shape[2:])
-    rows = jax.jit(ssd.ssd_rows, donate_argnums=(9,))
+    # the two halves, as a TPU runs them (here the kernel in interpret mode)
+    rows = jax.jit(functools.partial(
+        ssd.ssd_rows, backend="pallas", interpret=rehearse),
+        donate_argnums=(9,))
     i32 = lambda *a: jnp.asarray(a, jnp.int32)
     first, pool = rows(*(a[:T] for a in args), i32(0), i32(T), i32(0),
                        i32(1), pool, 1)
@@ -1207,11 +1213,21 @@ def phase_kernel_ssd(spec, seed, rehearse):
     errs = {"from_zeros": rel(first, want[:T]),
             "from_a_state": rel(second, want[T:]),
             "state_after": rel(ssd.unpack_state(pool[1, 1], P), h_end)}
-    good = all(e <= TOL_SSD_F32 for e in errs.values())
+    untouched = bool(jnp.all(pool[0] == 0) and jnp.all(pool[1, 0] == 0)
+                     and jnp.all(pool[1, 2:] == 0))
+    good = untouched and all(e <= TOL_SSD_F32 for e in errs.values())
+    say(phase="kernel", op="ssd_rows (chunked form)", tokens=T,
+        geometry=[H, P, G, N], **errs, tol=TOL_SSD_F32,
+        other_slots_and_layers_untouched=untouched,
+        **_ssd_form_times(spec, ssd, tuple(a[:T] for a in args), pool,
+                          rehearse),
+        ok=bool(good))
 
     def ms_a_layer(fn, held, pool, reps=20):
         """Wall time of one layer's call, the pool donated from call to
-        call: on the chip the device's time, here the CPU's (rehearsal)."""
+        call: on the chip the device's time where a call outlasts its
+        dispatch (0.6 ms on the one-chip machine: PERF.md section 6, PR 46),
+        here the CPU's (rehearsal)."""
         for _ in range(2):
             o, pool = fn(*held, pool)
         jax.block_until_ready(o)
@@ -1221,13 +1237,6 @@ def phase_kernel_ssd(spec, seed, rehearse):
         jax.block_until_ready(o)
         return round((time.perf_counter() - t) / reps * 1e3, 4), pool
 
-    row_ms, pool = ms_a_layer(
-        lambda *a: rows(*a[:-1], i32(0), i32(T), i32(T), i32(1), a[-1], 1),
-        tuple(a[:T] for a in args), pool)
-    say(phase="kernel", op="ssd_rows (chunked form)", tokens=T,
-        geometry=[H, P, G, N], **errs, tol=TOL_SSD_F32,
-        row_ms_a_layer=row_ms, timed_on=jax.default_backend(),
-        ok=bool(good))
     # the decode kernel timed over a whole pool of live slots: its bytes are
     # benchmark/lib/model_bytes_ssd_latent_moe.py::ssd_decode_bytes
     dec = jax.jit(functools.partial(
@@ -1243,6 +1252,93 @@ def phase_kernel_ssd(spec, seed, rehearse):
     if not (ok and good):
         fail("the state-space kernel or the chunked form disagrees with the "
              "recurrence, or a bfloat16 pool would pass")
+
+
+def _ssd_form_times(spec, ssd, held, pool, rehearse):
+    """DEVICE time a layer of the chunked form for one row of the held
+    tokens, whole (from a state, and from zeros) and a half at a time, from a
+    capture of five calls each (``tools/program_times.py`` over it: a call's
+    wall time is its dispatch's, not the device's, under a millisecond), and
+    the whole's share of the bf16 peak at six passes a float32 product by
+    ``benchmark/lib/model_bytes_ssd_latent_moe.py::ssd_chunk_call``.  On a
+    CPU there is no device to time: the calls are walked and nothing is
+    reported."""
+    import shutil
+    import subprocess
+    import tempfile
+
+    from helix_tpu.ops.deltanet import chunk_table
+    from helix_tpu.ops.ssd_kernel import CHUNK, ssd_chunk_tpu
+
+    T = held[0].shape[0]
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)
+    slot, blocks = i32(1), -(-T // CHUNK)
+    form = functools.partial(ssd.ssd_rows, backend="pallas",
+                             interpret=rehearse)
+
+    def row_from_a_state(*a):
+        return form(*a[:-1], i32(0), i32(T), i32(T), slot, a[-1], 1)
+
+    def row_from_zeros(*a):
+        return form(*a[:-1], i32(0), i32(T), i32(0), slot, a[-1], 1)
+
+    table, count = chunk_table(i32(0), i32(T), i32(T), slot, blocks,
+                               pool.shape[1], CHUNK)
+
+    def state_free_half(x, *a):
+        return ssd.state_free(
+            x.reshape(T, -1), *a, table["start"], table["left"])
+
+    h_row = jnp.zeros(pool.shape[2:])
+
+    def chunk_kernel_half(*a):
+        y, pool, _ = ssd_chunk_tpu(
+            *a, h_row, 1, table, count, interpret=rehearse)
+        return y, pool
+
+    whole = {f.__name__: jax.jit(f, donate_argnums=(len(held),))
+             for f in (row_from_a_state, row_from_zeros)}
+    free = jax.jit(state_free_half)
+    kernel = jax.jit(chunk_kernel_half, donate_argnums=(7,))
+    out = tempfile.mkdtemp(prefix="ssd_form_")
+    try:
+        with jax.profiler.trace(out):
+            for _ in range(5):
+                for fn in whole.values():
+                    o, pool = fn(*held, pool)
+                o, pool = kernel(*free(*held), pool)
+            jax.block_until_ready(o)
+        if rehearse:
+            return {"timed_on": jax.default_backend()}
+        times = os.path.join(out, "programs.json")
+        subprocess.run(
+            [sys.executable, os.path.join(HERE, "tools", "program_times.py"),
+             out, "--op", "^ssd_chunk_tpu", "--out", times],
+            env=dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled"),
+            check=True)
+        with open(times) as f:
+            programs = json.load(f)["programs"]
+    finally:
+        shutil.rmtree(out, ignore_errors=True)
+    ms = {name: round(programs["jit_" + name]["mean_ms"], 4)
+          for name in (*whole, "state_free_half", "chunk_kernel_half")}
+    from benchmark.lib.model_bytes_ssd_latent_moe import ssd_chunk_call
+    from benchmark.lib.peaks import chip_peaks
+
+    name = next(k for k, v in CONFIGS.items() if v is spec)
+    with open(os.path.join(HERE, "benchmark", "configs",
+                           name + ".json")) as f:
+        ops, _ = ssd_chunk_call(json.load(f), T)
+    peak = chip_peaks(jax.devices()[0].device_kind)["bf16_flops"]
+    return {
+        "device_ms_a_layer": ms,
+        "kernel_alone_ms": round(sum(
+            programs["jit_chunk_kernel_half"]["op_ms"].values()), 4),
+        "gflop_a_row": ops / 1e9,
+        "share_of_the_six_pass_peak": {
+            name: round(6 * ops / peak / (ms[name] * 1e-3), 4)
+            for name in whole},
+        "timed_on": jax.default_backend()}
 
 
 def phase_engine_ssd(spec, name, seed, layers, steps, rehearse):

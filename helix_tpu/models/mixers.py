@@ -348,8 +348,10 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     ``(conv pool, h pool)``.  ``decode``: the recurrence applied once (on a
     TPU one pass of the decode kernel over the live slots).  Else the chunked
     form at the published block, a row's state read at its first block and
-    written at its last.  Called ``(x W_xBC, dt, dt * A, taps, bias, D,
-    carry)``."""
+    written at its last: on a TPU what does not read the state for the rows'
+    blocks at once, then the blocks in order in the chunk kernel
+    (``ops/ssd.py::ssd_rows``); on a CPU a loop over the blocks.  Called ``(x
+    W_xBC, dt, dt * A, taps, bias, D, carry)``."""
     from helix_tpu.ops.ssd import ssd_decode, ssd_rows
 
     t0, qlen, hist, slots = rows
@@ -370,7 +372,8 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
                 flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
                 o, h_pool = ssd_rows(
                     flat(x), flat(dt), flat(la), flat(Bm), flat(Cm), t0,
-                    qlen, hist, slots, h_pool, lc, chunk=cfg.mamba_chunk)
+                    qlen, hist, slots, h_pool, lc, chunk=cfg.mamba_chunk,
+                    backend=backend)
             o = o.reshape(x.shape) + D.astype(jnp.float32)[:, None] * x
         return o, (caches, kacc, vacc, (c_pool, h_pool))
 
@@ -502,7 +505,7 @@ def _check_mamba2(cfg, tp, itemsize) -> None:
     from helix_tpu.ops.ssd_kernel import check_ssd_geometry
 
     check_ssd_geometry(cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
-                       cfg.mamba_state_size)
+                       cfg.mamba_state_size, cfg.mamba_chunk)
 
 
 def _check_window(cfg, tp, itemsize) -> None:
@@ -536,7 +539,7 @@ def _matrix_rows(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     }
 
 
-def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+def _rows_from_zeros(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     """... and of the chunk rows, those that start their sequence: the chunk
     kernel skips the state's read and its query for them."""
     return {
@@ -546,16 +549,16 @@ def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     }
 
 
-def _chunked_rows(chunk_of) -> Callable:
-    """... and the chunks the chunked form runs: a prefill row's ``ceil(rem /
-    chunk_of(cfg))`` in every layer of the kind (for the delta rule
-    ``ops/deltanet.py::chunk_table``'s live entries).  Device time under the
-    kind's kernel scope in the programs that carry a chunk, over this count,
-    is the cost of a chunk (PERF.md section 5)."""
+def _chunked_rows(chunk_of, rows_of=_matrix_rows) -> Callable:
+    """... (``rows_of``) and the chunks the chunked form runs: a prefill row's
+    ``ceil(rem / chunk_of(cfg))`` in every layer of the kind (for the delta
+    rule ``ops/deltanet.py::chunk_table``'s live entries).  Device time under
+    the kind's kernel scope in the programs that carry a chunk, over this
+    count, is the cost of a chunk (PERF.md section 5)."""
 
     def account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
         return {
-            **_matrix_rows(cfg, cache_cfg, rows, pos, n_extra),
+            **rows_of(cfg, cache_cfg, rows, pos, n_extra),
             "chunks": sum(-(-r.rem // chunk_of(cfg)) for r in rows) * (
                 cfg.num_state_layers),
         }
@@ -571,7 +574,10 @@ def _delta_chunk(cfg) -> int:
 
 _deltanet_account = _chunked_rows(_delta_chunk)
 # the published block of the state space's chunked form
-_mamba2_account = _chunked_rows(lambda cfg: cfg.mamba_chunk)
+# ... with the rows that start their sequence: the chunk kernel skips the
+# state's read and its product for their first block
+_mamba2_account = _chunked_rows(
+    lambda cfg: cfg.mamba_chunk, _rows_from_zeros)
 
 
 def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
@@ -669,7 +675,7 @@ STATE_MIXERS = {
         rows_fn=_retention_rows_fn,
         token_args=4,
         oracle=lambda cfg, positions: whole_sequence_retention_fn,
-        account=_retention_account,
+        account=_rows_from_zeros,
         series=(
             _POOL_BYTES,
             *_rows_series("helix_retention_rows_total"),
@@ -803,9 +809,15 @@ STATE_MIXERS = {
             Series("helix_ssd_chunks_total", "counter", "chunks"),
             _POOL_BYTES,
             *_rows_series("helix_ssd_rows_total"),
+            # over kind="chunk" above, the share of rows whose first block
+            # skipped the state's read and its product
+            Series("helix_ssd_chunk_rows_from_zeros_total", "counter",
+                   "chunk_rows_from_zeros"),
             _BYTES_TOUCHED,
         ),
-        launch=(("ssd_layers", "layers"), ("ssd_chunks", "chunks")),
+        launch=(("ssd_layers", "layers"), ("ssd_chunks", "chunks"),
+                ("ssd_chunk_rows", "chunk_rows"),
+                ("ssd_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
     ),
 }
 

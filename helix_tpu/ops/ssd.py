@@ -18,8 +18,14 @@ tokens (``ssd_chunk``: within a block ``Y = ((C B^T) * L) (dt x) + exp(G) C
 h_0`` and ``h_end = exp(G_last) h_0 + sum_s exp(G_last - G_s) (dt_s x_s)
 B_s^T``, ``G`` the running sum of ``la`` from the block's start), which the
 rows of fresh tokens of a step run from their slots' states (``ssd_rows``),
-reading a state once and writing it once a row and chunk.  On a TPU the
-decode step is ``ops/ssd_kernel.py``.
+reading a state once and writing it once a row.  On a CPU that is a loop of
+``ssd_chunk`` over the rows' blocks.  On a TPU both forms are kernels of
+``ops/ssd_kernel.py``: the decode step in one pass over the live slots, and
+the chunked form in TWO HALVES, as the delta rule's and power retention's
+are: what does not read the state for a pass's blocks at once in
+``jax.numpy`` (``state_free``), then the blocks in order in
+``ssd_chunk_tpu``, which builds each head's decay in VMEM and keeps ``h``
+there from a row's first block to its last.
 
 THE POOL'S LAYOUT.  A state of head width under 128 is stored with ``pack =
 128 / P`` heads to a 128-lane tile and the state axis down the sublanes:
@@ -134,6 +140,36 @@ def ssd_chunk(x, dt, la, Bm, Cm, hp):
     return y, hp
 
 
+def state_free(x, dt, la, Bm, Cm, start, left, chunk: int = 128):
+    """The half of the chunked form that does not read the state, for the
+    ``m`` blocks of a pass at once: block ``e`` is the ``min(left[e],
+    chunk)`` tokens from ``start[e]`` of the flat axis (``x [T, H * P]``, a
+    token's heads side by side as the layer holds them, ``dt, la [T, H]``,
+    ``Bm, Cm [T, G, N]``; ``ops/deltanet.py::chunk_table``).  What lies
+    behind a row's last token in its last block is a neighbour's, or padding
+    whose values nothing vouches for (a kernel leaves the rows it skips
+    unwritten: NaN is possible): selected out, never multiplied out, so it
+    reads as a token of zeros with ``dt 0`` and ``la 0``.  Returns the
+    blocks' ``x [m, C, H * P]`` AS GATHERED with ``mine [m, C]`` (the kernel
+    selects: one pass over ``x`` less), their own ``dt [m, C, H]``, a
+    GROUP's ``C B^T [m, G, C, C]`` (the heads of a group share it: their
+    decays are the kernel's), ``B`` and ``C`` a group ``[m, G, C, N]`` and
+    the running sum ``Gs [m, C, H]`` of ``la`` from each block's start, all a
+    block needs beside the state it meets (``ops/ssd_kernel.py::
+    ssd_chunk_tpu``, which spreads ``dt`` and what it exponentiates of ``Gs``
+    across a head's lanes itself)."""
+    T = x.shape[0]
+    at = jnp.arange(chunk, dtype=jnp.int32)
+    mine = at < left[:, None]                                  # [m, C]
+    where = jnp.clip(start[:, None] + at, 0, T - 1)
+    own = lambda a: jnp.where(
+        mine.reshape(mine.shape + (1,) * (a.ndim - 1)), a[where], 0.0)
+    dt, la, Bm, Cm = (own(a) for a in (dt, la, Bm, Cm))
+    Bg, Cg = (a.transpose(0, 2, 1, 3) for a in (Bm, Cm))
+    cb = jnp.einsum("mgtn,mgsn->mgts", Cg, Bg, precision=_HI)
+    return x[where], mine, dt, cb, Bg, Cg, jnp.cumsum(la, axis=1)
+
+
 def ssd_sequence(x, dt, la, Bm, Cm, h0, chunk: int = 128):
     """``ssd_recurrence``'s function in the chunked form: one sequence of any
     length from ``h0 [H, P, N]``."""
@@ -153,8 +189,64 @@ def ssd_sequence(x, dt, la, Bm, Cm, h0, chunk: int = 128):
     return y.reshape((n * chunk,) + x.shape[1:])[:T], unpack_state(hp, P)
 
 
+# blocks a pass of the two halves.  Two, not a 512-token row's four: with 16 MB
+# of a pass's x and y beside a layer's activations the TPU's compiler chose to
+# compute the layer's in-projection three times over (PERF.md section 6, PR 46:
+# +4.2 ms a 512-token program).  NO TEST HOLDS THIS: only the cell's whole
+# 22-layer program shows it, as ``.remat`` in its ``compiled.as_text()`` (the
+# three-layer cut of tests/test_tpu_compile_steps.py does not); compile that
+# program for the described chip before changing the number.
+SLAB = 2
+
+
+def _rows_in_two_halves(x, dt, la, Bm, Cm, rows, n: int, h_pool, layer,
+                        chunk: int, interpret: bool):
+    """``ssd_rows`` on a TPU: ``rows = (t0, qlen, hist, slots)`` in a table
+    of ``n`` blocks or more, ``SLAB`` of them a pass."""
+    from helix_tpu.ops.deltanet import chunk_table
+    from helix_tpu.ops.ssd_kernel import ssd_chunk_tpu
+
+    T = x.shape[0]
+    m = min(n, SLAB)
+    table, count = chunk_table(
+        *rows, -(-n // m) * m, h_pool.shape[1], chunk)
+    # a token's heads side by side, as the layer holds them: [T, H * P]
+    flat = x.reshape(T, -1)
+
+    def slab(i, carry):
+        y, h_pool, h = carry
+        tab = {key: jax.lax.dynamic_slice_in_dim(a, i * m, m)
+               for key, a in table.items()}
+        free = state_free(
+            flat, dt, la, Bm, Cm, tab["start"], tab["left"], chunk)
+        mine = free[1]
+        yc, h_pool, h = ssd_chunk_tpu(
+            *free, h_pool, h, layer, tab, jnp.clip(count - i * m, 0, m),
+            interpret=interpret)
+        # back onto the flat axis a block at a time, a block's own tokens
+        # over what lies there: a block is one window of the axis, and a
+        # window moves at the memory's speed where a scatter or a gather of
+        # rows pays for every row (PERF.md section 6, PR 46)
+        for e in range(m):
+            at = tab["start"][e]
+            y = jax.lax.dynamic_update_slice_in_dim(y, jnp.where(
+                mine[e][:, None], yc[e],
+                jax.lax.dynamic_slice_in_dim(y, at, chunk)), at, 0)
+        return y, h_pool, h
+
+    # what no row owns reads zeros; a block of room behind the axis, so that
+    # a row's last block is a whole window wherever the row ends
+    carry = (jnp.zeros((T + chunk, flat.shape[1]), jnp.float32), h_pool,
+             jnp.zeros(h_pool.shape[2:], h_pool.dtype))
+    if n == m:
+        y, h_pool, _ = slab(0, carry)
+    else:
+        y, h_pool, _ = jax.lax.fori_loop(0, (count + m - 1) // m, slab, carry)
+    return y[:T].reshape(x.shape), h_pool
+
+
 def ssd_rows(x, dt, la, Bm, Cm, t0, qlen, hist, slots, h_pool, layer, *,
-             chunk: int = 128):
+             chunk: int = 128, backend=None, interpret: bool = False):
     """Rows of fresh tokens on one flat axis (a prefill segment): row ``r``
     is the ``qlen[r]`` tokens from ``t0[r]`` of the sequence in slot
     ``slots[r]``, with ``hist[r]`` tokens behind it (0: it starts from
@@ -163,14 +255,29 @@ def ssd_rows(x, dt, la, Bm, Cm, t0, qlen, hist, slots, h_pool, layer, *,
     token is not visited; a row whose slot lies past the pool (no slot)
     starts from zeros and writes nothing.  ``x [T, H, P]``, ``dt, la [T,
     H]``, ``Bm, Cm [T, G, N]``, ``h_pool [L, slots, I, N, W]``.  Returns ``(y
-    [T, H, P] float32, h_pool)``.  Plain ``jax.numpy`` on every backend: the
-    chunk kernel is a later change's."""
+    [T, H, P] float32, h_pool)``.
+
+    On a TPU in two halves, ``SLAB`` blocks of the rows' table a pass and as
+    many passes as the rows have blocks for (one, where the table is no
+    longer): ``state_free`` for the pass's blocks at once, then those blocks
+    in order in ``ssd_chunk_tpu``, which keeps ``h`` on the chip from a row's
+    first block to its last (handed from pass to pass where a row has more
+    blocks than a pass) and skips the state's read and its product for a
+    row that starts its sequence (``interpret``: the same kernel in interpret
+    mode, for tests on a CPU with ``backend="pallas"``).  On a CPU, or for
+    ``backend="reference"``, a loop of ``ssd_chunk`` over the rows' live
+    blocks: the form the tests hold the kernel to."""
+    from helix_tpu.ops.attention import resolve_backend
     from helix_tpu.ops.deltanet import chunk_table
 
     T, R = x.shape[0], t0.shape[0]
     N = h_pool.shape[1]
     # a row's first block may hold one token, every further one a whole block
     n = min(R, T) + (T - min(R, T)) // chunk
+    if resolve_backend(backend) == "pallas":
+        return _rows_in_two_halves(
+            x, dt, la, Bm, Cm, (t0, qlen, hist, slots), n, h_pool, layer,
+            chunk, interpret)
     table, count = chunk_table(t0, qlen, hist, slots, n, N, chunk)
     at = jnp.arange(chunk, dtype=jnp.int32)
     # the loop carries the pool with a slot's rows of heads and its state axis

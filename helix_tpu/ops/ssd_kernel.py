@@ -1,5 +1,7 @@
-"""Pallas TPU kernel: one decode step of a Mamba-2 layer, the state's decay,
-its write and its read-out in ONE pass.
+"""Pallas TPU kernels of a Mamba-2 layer: one decode step (the state's decay,
+its write and its read-out in ONE pass: ``ssd_decode_tpu``), and the half of
+the chunked form that reads the state (``ssd_chunk_tpu``, below its own
+heading at the end).
 
 XLA's form of ``ops/ssd.py::ssd_step_packed`` walks the state twice (the
 update, then the product with ``C``), and the state is over a third of a
@@ -25,24 +27,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from helix_tpu.ops.deltanet_kernel import head_block, state_decode_call
+from helix_tpu.ops.deltanet_kernel import (
+    FIRST, FROM_STATE, OPEN, WRITE, head_block, state_decode_call,
+)
 from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
 from helix_tpu.ops.ssd import head_pack, lanes, rows_of
 
 ROW_BLOCK = 16      # packed rows a grid step: 1 MB of state at 128 x 128
+CHUNK = 128         # tokens a block of the chunk kernel: the published block
 
 
-def check_ssd_geometry(heads: int, head_dim: int, groups: int,
-                       state: int) -> None:
+def check_ssd_geometry(heads: int, head_dim: int, groups: int, state: int,
+                       chunk: int = CHUNK) -> None:
     """Raise :class:`UnsupportedKernelGeometry` for what Mosaic refuses, or
-    the kernel does not do: a packed row is a square ``[128, 128]`` tile
+    the kernels do not do: a packed row is a square ``[128, 128]`` tile
     (``B`` and ``C`` go down the sublanes by a square transpose), a row holds
-    heads of one group, and the rows come in blocks of 8 (a sublane tile of
-    the vectors)."""
+    heads of one group, the rows come in blocks of 8 (a sublane tile of
+    the vectors), and the chunked form's block is the 128 tokens of a
+    ``[128, 128]`` decay."""
     k = head_pack(head_dim)
     why = None
-    if k * head_dim != 128 or state != 128:
+    if chunk != CHUNK:
+        why = (f"the chunked form's block must be {CHUNK} tokens "
+               f"(mamba_chunk is {chunk})")
+    elif k * head_dim != 128 or state != 128:
         why = ("the state size and the packed head width must both be the "
                "128 lanes")
     elif heads % groups or (heads // groups) % k:
@@ -51,7 +61,7 @@ def check_ssd_geometry(heads: int, head_dim: int, groups: int,
         why = "the packed rows must come in blocks of 8 (a sublane tile)"
     if why:
         raise UnsupportedKernelGeometry(
-            "ssd decode kernel: no TPU lowering for "
+            "ssd kernels: no TPU lowering for "
             f"{heads} heads of {head_dim} in {groups} groups over a state "
             f"of {state}: {why}.  Serve this geometry with "
             "attn_backend='reference' explicitly, or extend the kernel.")
@@ -107,3 +117,228 @@ def ssd_decode_tpu(
         h_pool, layer, order, count, hb=hb, name="ssd_decode_tpu",
         interpret=interpret)
     return y.reshape(B, H, P), h_pool
+
+
+# ---- the chunked form: the half that reads the state -----------------------
+
+CHUNK_ROW_BLOCK = 8     # packed rows a grid step of the chunk kernel
+
+
+def _chunk_kernel(layer_ref, slot_ref, flag_ref, count_ref, x_ref, cb_ref,
+                  c_ref, b_ref, gs_ref, col_ref, last_ref, s_ref, r_ref,
+                  o_ref, so_ref, ro_ref, h_scr, *, hb: int, pack: int):
+    del layer_ref, slot_ref                  # read by the index maps
+    e = pl.program_id(1)
+    flags = flag_ref[e]
+    live = e < count_ref[0]
+    on = lambda flag: flags & flag != 0
+    # a row that starts its sequence meets a state of zeros: no read of its
+    # slot, and no product against what it holds
+    starts = jnp.logical_and(on(FIRST), jnp.logical_not(on(FROM_STATE)))
+
+    @pl.when(e == 0)
+    def _the_row_under_way():
+        h_scr[...] = r_ref[...]
+
+    @pl.when(on(OPEN))
+    def _a_state_block_opens():
+        # written back whatever follows: unchanged, unless its row ends here
+        so_ref[...] = s_ref[...]
+
+    @pl.when(jnp.logical_and(on(FIRST), on(FROM_STATE)))
+    def _a_row_continues_from_its_slot():
+        h_scr[...] = s_ref[...]
+
+    @pl.when(starts)
+    def _a_row_starts():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    @pl.when(live)
+    def _a_block():
+        _block(starts, x_ref, cb_ref, c_ref, b_ref, gs_ref, col_ref,
+               last_ref, o_ref, h_scr, hb=hb, pack=pack)
+
+    @pl.when(on(WRITE))
+    def _a_row_ends():
+        so_ref[...] = h_scr[...]
+
+    @pl.when(e == pl.num_programs(1) - 1)
+    def _hand_on():
+        ro_ref[...] = h_scr[...]
+
+
+def _block(starts, x_ref, cb_ref, c_ref, b_ref, gs_ref, col_ref, last_ref,
+           o_ref, h_scr, *, hb: int, pack: int):
+    C, W = cb_ref.shape[0], h_scr.shape[-1]
+    P = W // pack
+    dot = functools.partial(
+        jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    nn = (((1,), (0,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
+    low = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+           >= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+    head_of = jax.lax.broadcasted_iota(jnp.int32, (C, W), 1) // P
+    cols = lambda i: pl.ds(i * W, W)
+    # a head's running sums, then its steps, a token down the sublanes; and
+    # whether the token is the block's own
+    sums_of = lambda h: col_ref[:, pl.ds(h, 1)]
+    step_of = lambda h: col_ref[:, pl.ds(hb * pack + h, 1)]
+    mine = col_ref[:, pl.ds(2 * hb * pack, 1)] > 0
+
+    def across(of, i):
+        """``[C, W]`` from one ``[C, W]`` (or ``[C, 1]``) a head of packed
+        row ``i``: each head's own lanes."""
+        out = jnp.broadcast_to(of(i * pack), (C, W))
+        for k in range(1, pack):
+            out = jnp.where(head_of == k, of(i * pack + k), out)
+        return out
+
+    @pl.when(starts)
+    def _nothing_held():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(jnp.logical_not(starts))
+    def _what_the_state_holds():
+        for i in range(hb):                                  # static unroll
+            o_ref[:, cols(i)] = jnp.exp(across(sums_of, i)) * dot(
+                c_ref[...], h_scr[i], nn)
+
+    cb = cb_ref[...]
+    for i in range(hb):                                      # static unroll
+        # what lies behind a row's last token is selected out, not
+        # multiplied out: nothing vouches for its values
+        x = jnp.where(mine, x_ref[:, cols(i)], 0.0) * across(step_of, i)
+
+        def within(h):
+            # the decay from token s to token t, built here a head: the exp
+            # of a DIFFERENCE of running sums under the causal mask
+            decay = jnp.exp(jnp.where(
+                low, sums_of(h) - gs_ref[pl.ds(h, 1), :], -jnp.inf))
+            return dot(cb * decay, x, nn)
+
+        o_ref[:, cols(i)] += across(within, i)
+        last = last_ref[pl.ds(i, 1), :]                      # [1, W]
+        h_scr[i] = jnp.exp(last) * h_scr[i] + dot(
+            b_ref[...], x * jnp.exp(last - across(sums_of, i)), tn)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_chunk_tpu(
+    x,          # [n, C, H * P] f32           } ops/ssd.py::state_free of the
+    mine,       # [n, C] bool: a block's own  } blocks of ``table``, in its
+    dt,         # [n, C, H] f32               } order
+    cb,         # [n, G, C, C] f32: C B^T
+    Bm,         # [n, G, C, N] f32
+    Cm,         # [n, G, C, N] f32
+    Gs,         # [n, C, H] f32: the running sum of dt * A from a block's start
+    h_pool,     # [L, slots, H / pack, N, pack * P] f32
+    h_row,      # [H / pack, N, pack * P] f32: the row under way at entry 0
+    layer,      # which of the L layers (a traced index)
+    table,      # n entries of ops/deltanet.py::chunk_table at a block of C
+    count,      # how many of them are some row's: they come first
+    *,
+    interpret: bool = False,
+):
+    """The half of the chunked state-space form that reads the state: the
+    table's entries in order, a block of ``C = 128`` tokens each, ``h`` in
+    VMEM from a row's first block to its last: read from ``h_pool[layer,
+    slot]`` at the first block only if the row continues from it (zeros
+    without a read for a row that starts there), written at the last, in
+    place; a row whose first block lay before this table continues from
+    ``h_row``, and the state of a row whose last lies behind it is handed
+    on.  At an entry, for each packed row ``i`` of heads (``pack`` heads of
+    one group across the 128 lanes): the decay ``L = exp(Gs_t - Gs_s)``
+    under the causal mask a head, in VMEM; ``(C B^T * L) (dt x)`` a head;
+    ``exp(Gs) * (C h[i])``, skipped with the read for the first block of a
+    row that starts its sequence; and ``h[i] = exp(last) h[i] + B^T (dt x
+    exp(last - Gs))``.  What is one scalar a token and head (``dt``,
+    ``exp(Gs)``, ``exp(last - Gs)``, ``exp(last)``) is spread across its
+    head's lanes HERE, from a column of ``dt`` and one of ``Gs`` a head:
+    laid out for the lanes in HBM each would be as large as ``x``, and ``x``
+    stays the ``[tokens, H * P]`` rows the layer holds, its tokens that are
+    not the block's own (``mine``) selected out here too.  Every product
+    float32 at the highest precision.
+
+    Returns ``(y [n, C, H * P] f32, h_pool, h_row)``; entries past the rows'
+    ends hold whatever was there.
+
+    Grid ``(blocks of packed rows, entries)``, sequential; the table's flags
+    and the slot an entry names are ``ops/deltanet_kernel.py::
+    deltanet_chunk_tpu``'s, which see: an entry of a row with no slot, and
+    entries past the rows' ends, name the state block of the last row before
+    them that has one, and a block is copied through as it opens, so one
+    whose row does not end here, or that no row owns, goes back as it came.
+    (The two frames are alike and kept apart: folding them into one changes
+    the program another model's cell runs.)"""
+    n, C, H = dt.shape
+    G, N = Bm.shape[1], Bm.shape[-1]
+    I, W = h_pool.shape[2], h_pool.shape[4]
+    pack = H // I
+    assert h_pool.shape[2:] == (I, N, W) == h_row.shape
+    assert x.shape == (n, C, I * W)
+    if not interpret:
+        check_ssd_geometry(H, W // pack, G, N, C)
+    per_group = I // G
+    hb = head_block(per_group, CHUNK_ROW_BLOCK)
+    at = jnp.arange(n, dtype=jnp.int32)
+    count = jnp.asarray(count, jnp.int32)
+    slotted = jnp.sum(table["has_slot"]).astype(jnp.int32)
+    named = table["slot"][jnp.minimum(at, jnp.maximum(slotted - 1, 0))]
+    flags = (FIRST * table["first"] + WRITE * table["write"]
+             + FROM_STATE * table["from_state"]
+             + OPEN * ((at == 0) | (table["first"] & table["has_slot"]))
+             ).astype(jnp.int32)
+
+    def per_entry(block, index):
+        def index_map(j, e, layer, slot, flags, count):
+            return (jnp.minimum(e, jnp.maximum(count[0] - 1, 0)),) + index(j)
+
+        return pl.BlockSpec((None,) + block, index_map)
+
+    def state_map(j, e, layer, slot, flags, count):
+        return layer[0], slot[e], j, 0, 0
+
+    group = lambda w: per_entry(
+        (None, C, w), lambda j: (j * hb // per_group, 0, 0))
+    wide = per_entry((C, hb * W), lambda j: (0, j))
+    state = pl.BlockSpec((None, None, hb, N, W), state_map)
+    row = pl.BlockSpec((hb, N, W), lambda j, e, *pre: (j, 0, 0))
+    # a block of packed rows' heads side by side: [n, I / hb, C, hb * pack]
+    down = lambda a: a.reshape(n, C, I // hb, hb * pack).transpose(0, 2, 1, 3)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(I // hb, n),
+        in_specs=[wide, group(C), group(N), group(N),
+                  per_entry((hb * pack, C), lambda j: (j, 0)),
+                  per_entry((None, C, 2 * hb * pack + 1),
+                            lambda j: (j, 0, 0)),
+                  per_entry((hb, W), lambda j: (j, 0)), state, row],
+        out_specs=[wide, state, row],
+        scratch_shapes=[pltpu.VMEM((hb, N, W), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_chunk_kernel, hb=hb, pack=pack),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n, C, I * W), jnp.float32),
+                   jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype),
+                   jax.ShapeDtypeStruct(h_row.shape, h_row.dtype)],
+        # operand 11 (after the four prefetched scalars): the pool
+        input_output_aliases={11: 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        name="ssd_chunk_tpu",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), named, flags,
+        count.reshape(1),
+        x, cb, Cm, Bm,
+        # a head's running sums across the lanes (token s); and down the
+        # sublanes (token t) beside its steps and the token's ``mine``
+        Gs.transpose(0, 2, 1),
+        jnp.concatenate([down(Gs), down(dt), jnp.broadcast_to(
+            mine.astype(jnp.float32)[:, None, :, None],
+            (n, I // hb, C, 1))], axis=-1),
+        lanes(Gs[:, -1], W // pack, I), h_pool, h_row,
+    )

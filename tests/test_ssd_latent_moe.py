@@ -363,6 +363,73 @@ def test_rows_that_start_mid_batch_and_a_row_shorter_than_a_block():
     assert bool(jnp.all(new[1, 3] == pool[1, 3]))
 
 
+# the chunk kernel's cases: rows on one flat axis at a geometry it takes (packed
+# rows of 128 lanes over a state of 128, the block of 128), (t0, tokens,
+# tokens behind it, slot or None) a row, in a pool of two layers of four slots
+_KERNEL_ROWS = {
+    "three_blocks_from_a_state_with_a_short_last":
+        dict(T=300, rows=[(0, 300, 40, 2)]),
+    "from_zeros_mid_axis_and_shorter_than_a_block":
+        dict(T=160, rows=[(0, 128, 7, 1), (128, 5, 0, 3)]),
+    "a_row_with_no_slot": dict(T=140, rows=[(0, 140, 5, None)]),
+    "a_row_with_no_token":
+        dict(T=40, rows=[(0, 30, 9, 0), (30, 0, 7, 1)]),
+    # seven entries in passes of two (``ssd.SLAB``): each row's blocks lie
+    # either side of a pass's end, its state handed on
+    "a_row_that_straddles_two_passes":
+        dict(T=640, rows=[(0, 300, 0, 1), (300, 200, 6, 0), (500, 140, 0,
+                                                            None)]),
+    "nan_behind_a_rows_last_token":
+        dict(T=200, rows=[(0, 70, 3, 2)], nan_from=70),
+    "slots_out_of_order_and_one_left_idle":
+        dict(T=150, rows=[(0, 20, 0, 3), (20, 130, 11, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_ROWS))
+def test_the_chunk_kernel_is_the_recurrence(case):
+    """``ssd_rows`` in its two halves with the chunk kernel in interpret
+    mode: each row is the recurrence on its own tokens from its own state
+    (zeros where it starts its sequence or has no slot), the pool written at
+    ``layer`` where a row has a slot and tokens, and nowhere else; what no row
+    owns reads zeros, whatever lies there."""
+    H, P, G, N, S = 8, 64, 2, 128, 4
+    spec = _KERNEL_ROWS[case]
+    T, rows = spec["T"], spec["rows"]
+    args = _draw(T, H, P, G, N, seed=11)
+    if "nan_from" in spec:
+        args = tuple(a.at[spec["nan_from"]:].set(jnp.nan) for a in args)
+    pool = ssd.pack_state(jax.random.normal(
+        jax.random.PRNGKey(12), (2, S, H, P, N)))
+    col = lambda i, none=0: jnp.asarray(
+        [none if r[i] is None else r[i] for r in rows], jnp.int32)
+    y, new = ssd.ssd_rows(
+        *args, col(0), col(1), col(2), col(3, S), pool, 1,
+        backend="pallas", interpret=True)
+    h_of = lambda p, s: ssd.unpack_state(p[1, s], P)
+    written = set()
+    for a, n, hist, slot in rows:
+        if n == 0:
+            continue
+        h0 = (h_of(pool, slot) if slot is not None and hist > 0
+              else jnp.zeros((H, P, N)))
+        want, h = ssd.ssd_recurrence(*(v[a:a + n] for v in args), h0)
+        # float32 both sides, sums in another order
+        assert float(jnp.max(jnp.abs(y[a:a + n] - want))) < 1e-4 * float(
+            jnp.std(want)), (a, n)
+        if slot is not None:
+            written.add(slot)
+            assert float(jnp.max(jnp.abs(h_of(new, slot) - h))) < 1e-4 * (
+                float(jnp.std(h))), (a, n)
+    owned = np.zeros(T, bool)
+    for a, n, _, _ in rows:
+        owned[a:a + n] = True
+    assert not bool(jnp.any(y[~owned]))
+    assert bool(jnp.all(new[0] == pool[0]))
+    for s in set(range(S)) - written:
+        assert bool(jnp.all(new[1, s] == pool[1, s])), s
+
+
 @pytest.mark.parametrize("backend", ["reference", "pallas"])
 def test_the_decode_step_is_the_recurrence(backend):
     """The CPU's packed step and the kernel (interpret mode, at a geometry
@@ -391,8 +458,10 @@ def test_the_kernel_refuses_what_it_cannot_tile():
     from helix_tpu.ops.ssd_kernel import check_ssd_geometry
 
     check_ssd_geometry(128, 64, 8, 128)              # the published
+    check_ssd_geometry(128, 64, 8, 128, 128)         # ... and its block
     for bad in ((128, 64, 8, 64), (128, 48, 8, 128), (8, 64, 8, 128),
-                (8, 64, 2, 128)):
+                (8, 64, 2, 128), (128, 64, 8, 128, 256),
+                (128, 64, 8, 128, 64)):
         with pytest.raises(UnsupportedKernelGeometry):
             check_ssd_geometry(*bad)
 
@@ -556,7 +625,10 @@ def test_a_mesh_and_the_calls_that_move_pages_are_refused_by_name(model):
     assert [s.name for s in kind.series] == [
         "helix_ssd_chunks_total", "helix_recurrent_state_bytes",
         "helix_ssd_rows_total", "helix_ssd_rows_total",
+        "helix_ssd_chunk_rows_from_zeros_total",
         "helix_state_bytes_touched_total"]
-    assert dict(kind.launch) == {"ssd_layers": "layers",
-                                 "ssd_chunks": "chunks"}
+    assert dict(kind.launch) == {
+        "ssd_layers": "layers", "ssd_chunks": "chunks",
+        "ssd_chunk_rows": "chunk_rows",
+        "ssd_chunk_rows_from_zeros": "chunk_rows_from_zeros"}
     assert kind.flight == kind.launch

@@ -562,14 +562,19 @@ def test_ssd_decode_kernel_compiles_at_the_published_geometry(one_chip):
     assert mem.temp_size_in_bytes < pool_bytes // 100
 
 
-@pytest.mark.parametrize("tokens,rows", [(512, 1), (512, 64)],
-                         ids=["one_row", "wave"])
+@pytest.mark.parametrize("backend", ["pallas", None],
+                         ids=["two_halves", "loop"])
+@pytest.mark.parametrize("tokens,rows", [(512, 1), (512, 64), (16, 64)],
+                         ids=["one_row", "wave", "short_wave"])
 def test_ssd_chunked_form_compiles_at_the_published_geometry(
-        one_chip, tokens, rows):
+        one_chip, tokens, rows, backend):
     """The chunked form over a prefill segment (``ops/ssd.py::ssd_rows``) at
-    the published block of 128: plain ``jax.numpy``, the pool donated and
-    aliased to its output, a block's tokens gathered a block at a time so a
-    wave of 64 rows holds no more temporaries than one row does."""
+    the published block of 128, the pool donated and aliased to its output.
+    ``two_halves``: what a TPU runs, the state-free half in XLA and
+    ``ssd_chunk_tpu``, two blocks a pass (``ops/ssd.py::SLAB``), so a wave of
+    64 rows holds no more temporaries than one row does.  ``loop``: what this host's backend
+    resolves to, the plain ``jax.numpy`` loop a block at a time, as it
+    lowered before the kernel was written."""
     import functools
 
     from helix_tpu.ops.ssd import ssd_rows
@@ -581,10 +586,12 @@ def test_ssd_chunked_form_compiles_at_the_published_geometry(
 
     vec = S((rows,), jnp.int32)
     compiled = jax.jit(
-        functools.partial(ssd_rows, chunk=128), donate_argnums=(9,)).lower(
+        functools.partial(ssd_rows, chunk=128, backend=backend),
+        donate_argnums=(9,)).lower(
         S((tokens, H, P)), S((tokens, H)), S((tokens, H)),
         S((tokens, G, N)), S((tokens, G, N)), vec, vec, vec, vec,
         S((L, B, H // 2, N, 128)), S((), jnp.int32)).compile()
+    assert ("ssd_chunk_tpu" in compiled.as_text()) == (backend == "pallas")
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= L * B * H * P * N * 4
     assert mem.temp_size_in_bytes < 64 * 2 ** 20

@@ -379,18 +379,23 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
 
 
-def test_ssd_step_compiles_at_published_widths(one_chip):
+@pytest.mark.parametrize("bucket", [128, 512])
+def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
     """A whole engine step of Nemotron-3-Super cut to three published layers
     in two blocks (attention + held latent experts, a Mamba-2 layer alone;
     int8 weights, 64 slots) for the described chip, the program that carries
-    a 512-token chunk with history beside the decode rows: the state-space
-    decode kernel over the state pool in the carry, the chunked form, the
-    paged kernel at 32 query over 2 kv heads, the one-operand grouped product
-    over 128 of 512 experts in the latent, and both state arrays updated in
-    place.  ONE program: the decode-only one holds nothing this does not.
-    The chunk is 128 tokens, ONE block of the chunked form: the program that
-    PR 45's first chip run found transposing the whole state pool twice a
-    layer (``ops/ssd.py::ssd_rows`` says how that is prevented)."""
+    a chunk with history beside the decode rows: the state-space decode
+    kernel over the state pool in the carry, the chunked form in its two
+    halves (``ssd_chunk_tpu`` behind ``ops/ssd.py::state_free``; no loop of
+    ``ssd_chunk`` is left), the paged kernel at 32 query over 2 kv heads, the
+    one-operand grouped product over 128 of 512 experts in the latent, and
+    both state arrays updated in place.  The decode-only program holds
+    nothing these do not.  A chunk of 128 tokens is ONE block of the chunked
+    form: the program that PR 45's first chip run found transposing the
+    whole state pool twice a layer; 512 tokens are four blocks in two passes
+    of two (``ops/ssd.py::SLAB``), the program the cell's prompts run.  (What
+    ``SLAB`` guards against, a layer's in-projection computed again, shows
+    only in the whole 22-layer program: nothing here can assert it.)"""
     import dataclasses
 
     from helix_tpu.engine import engine as E
@@ -437,7 +442,7 @@ def test_ssd_step_compiles_at_published_widths(one_chip):
         mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
         token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
         sampling=sampling(B))
-    bucket, rows = 128, 1
+    rows = 1
     pargs = (
         *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
         S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
@@ -448,7 +453,7 @@ def test_ssd_step_compiles_at_published_widths(one_chip):
         params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
     ).compile()
     text = compiled.as_text()
-    for kernel in ("ssd_decode_tpu", "grouped_matmul_tpu",
+    for kernel in ("ssd_decode_tpu", "ssd_chunk_tpu", "grouped_matmul_tpu",
                    "ragged_paged_attention"):
         assert kernel in text, kernel
     assert "ragged-dot" not in text and "ragged_dot" not in text

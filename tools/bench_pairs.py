@@ -16,7 +16,7 @@ would commit); both are git-ignored; ``--tree <side>=<dir>`` names another
 tree for a side.  Every run's JSON lines go to
 ``chiprun_out/pairs/<tag>.jsonl``, its server log and trace summary beside
 it (after a traced run also ``programs.json``: ``tools/program_times.py`` over
-the capture, and ``gaps.json``: ``tools/gap_spans.py``, its long idle gaps by
+the capture, with ``--op`` if given, and ``gaps.json``: ``tools/gap_spans.py``, its long idle gaps by
 the program's spans), and one line a run (side, cell, seed, the metrics) to stdout.
 A run is not started when ``--per-run`` seconds more would pass ``--budget``.
 ``--account`` runs each through the tree's ``tools/host_account.py --run``,
@@ -45,6 +45,8 @@ def main():
     ap.add_argument("--tree", action="append", default=[],
                     metavar="SIDE=DIR", help="a side's tree, from the root")
     ap.add_argument("--account", action="store_true")
+    ap.add_argument("--op", help="tools/program_times.py's --op for the "
+                    "programs.json of a traced run ('.': every operation)")
     ap.add_argument("runs", nargs="+")
     a = ap.parse_args()
     trees = dict(TREES, **{
@@ -91,7 +93,9 @@ def main():
                 subprocess.run(
                     [sys.executable, os.path.join(ROOT, "tools", tool),
                      os.path.join(bo, "profiles"),
-                     "--out", os.path.join(keep, name)],
+                     "--out", os.path.join(keep, name),
+                     *(["--op", a.op] if a.op and name == "programs.json"
+                       else [])],
                     env=dict(os.environ, JAX_PLATFORMS="cpu",
                              TPU_LOG_DIR="disabled"))
         last = rec["lines"][-1] if rec["lines"] else {}
