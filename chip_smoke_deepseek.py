@@ -533,6 +533,89 @@ def _rows_by_form(eng) -> dict:
             for form in ("decode", "chunk")}
 
 
+def _device_programs(run, op, rehearse):
+    """``tools/program_times.py``'s ``programs`` (by ``jit_<name>``: mean
+    device time, the self time of the operations ``op`` matches) over a
+    profiler capture of ``run()``, which returns what to wait for.  A call's
+    wall time is its dispatch's, not the device's.  On a CPU (a rehearsal)
+    the calls are walked and None comes back: there is no device to time."""
+    import shutil
+    import subprocess
+    import tempfile
+
+    out = tempfile.mkdtemp(prefix="device_programs_")
+    try:
+        with jax.profiler.trace(out):
+            jax.block_until_ready(run())
+        if rehearse:
+            return None
+        times = os.path.join(out, "programs.json")
+        subprocess.run(
+            [sys.executable, os.path.join(HERE, "tools", "program_times.py"),
+             out, "--op", op, "--out", times],
+            env=dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled"),
+            check=True)
+        with open(times) as f:
+            return json.load(f)["programs"]
+    finally:
+        shutil.rmtree(out, ignore_errors=True)
+
+
+def _retention_decode_times(B, KVH, G, d, rehearse):
+    """Device time of one layer's ``retention_decode_tpu`` by the form of the
+    call, every row live, in a pool of ten layers (the cell's 8.6 GB, so that
+    no call finds its tiles anywhere but in HBM): a window of one (a step
+    that stands alone), a step that reads and writes nothing, and the commit
+    of four tokens where the window holds four and where it holds eight.
+    From a profiler capture (``tools/program_times.py``), not the host's
+    clock."""
+    from helix_tpu.ops import retention as R
+    from helix_tpu.ops.retention_kernel import retention_decode_tpu
+
+    L = 2 if rehearse else 10
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (B, KVH, G, d)) * d ** -0.5
+    order = jnp.arange(B, dtype=jnp.int32)
+
+    def form(name, terms, held, commit):
+        pad = ((0, 0), (0, 0), (0, held - terms), (0, 0))
+        k, v = (jnp.pad(jax.random.normal(key, (B, KVH, terms, d)), pad)
+                for key in ks[1:])
+        gate = jnp.full((B, KVH, 1, d), 0.97, jnp.float32)
+
+        def call(pool, layer):
+            return retention_decode_tpu(
+                q, k, v, gate, pool, layer, order, B, commit,
+                interpret=rehearse)
+
+        call.__name__ = name
+        return jax.jit(call, donate_argnums=(0,))
+
+    forms = [form("a_window_of_one", 1, 1, True),
+             form("reads_and_writes_nothing", 0, 8, False),
+             form("commits_four_of_four", 4, 4, True),
+             form("commits_four_of_eight", 4, 8, True)]
+
+    def run(reps, pool):
+        for i in range(reps):
+            for fn in forms:
+                num, pool = fn(pool, i % L)
+        return num, pool
+
+    # compiled outside the capture
+    _, pool = run(1, jnp.zeros((L, B, KVH, R.held_rows(d), d), jnp.float32))
+    programs = _device_programs(
+        lambda: run(2 if rehearse else 10, pool)[0], "^retention_decode_tpu",
+        rehearse)
+    if programs is None:
+        return {"timed_on": jax.default_backend()}
+    return {"device_ms_a_layer": {
+                fn.__name__: round(programs["jit_" + fn.__name__]["mean_ms"], 4)
+                for fn in forms},
+            "state_bytes_a_layer": B * KVH * R.held_rows(d) * d * 4,
+            "timed_on": jax.default_backend()}
+
+
 def phase_kernel_retention(spec, seed, rehearse):
     """``retention_decode_tpu`` against the ``jax.numpy`` recurrence at 24
     rows (17 live), 8 kv heads of 5 query heads, width 128, the second layer
@@ -576,6 +659,64 @@ def phase_kernel_retention(spec, seed, rehearse):
         rows=B, live=int(jnp.sum(live)), held_rows=F, **errs,
         idle_slots_and_other_layers_untouched=untouched,
         tol=TOL_RETENTION_F32, ok=bool(ok))
+
+    # a fused window: the steps before the last read the state and write
+    # nothing, the last commits the window's tokens at once.  Some rows sit
+    # some steps out, one sits them all out; against the recurrence a step
+    # at a time
+    # (the recurrence on the HOST: the product of a window's gates, one
+    # ``exp`` a step, is what the chip's ``exp`` moves at 1e-4; the kernel
+    # takes one ``exp`` of their sum)
+    cpu = jax.devices("cpu")[0]
+    on_cpu = lambda *a: jax.device_put(a, cpu)
+
+    # (a normaliser that is a sum of outer products, as a served one is:
+    # under a random matrix q^T Z q passes through zero and no two devices
+    # agree on the quotient)
+    Zw = jnp.einsum("lbkij,lbkmj->lbkim", Z, Z) / d
+
+    def a_window(steps):
+        (S_ref, Z_ref), S_win, Z_win = on_cpu(S, Zw), S, Zw
+        pending, worst, unwritten = R.window_zeros(B, KVH, d, 8), 0.0, True
+        for i in range(steps):
+            kk = jax.random.split(jax.random.fold_in(ks[6], 10 * steps + i), 4)
+            qi, ki, vi = (jax.random.normal(kk[0], (B, H, d)) * d ** -0.5,
+                          jax.random.normal(kk[1], (B, KVH, d)),
+                          jax.random.normal(kk[2], (B, KVH, d)))
+            lgi = -jax.random.uniform(kk[3], (B, KVH), minval=1e-3,
+                                      maxval=5e-2)
+            here = live & ((jnp.arange(B) + i) % 4 != 0)
+            with jax.default_device(cpu):
+                yr, S_ref, Z_ref, _ = R.retention_window_step(
+                    *on_cpu(qi, ki, vi, lgi), S_ref, Z_ref, None, 1,
+                    *on_cpu(here), 0, True, backend="reference")
+            yw, S_win, Z_win, pending = R.retention_window_step(
+                qi, ki, vi, lgi, S_win, Z_win, pending, 1, here,
+                jnp.int32(i), jnp.asarray(i == steps - 1), backend="pallas",
+                interpret=rehearse)
+            worst = max(worst, rel(yw, np.asarray(yr)))
+            if i < steps - 1:
+                unwritten = unwritten and bool(jnp.all(S_win == S))
+        return {"state": rel(S_win[1], np.asarray(S_ref[1])), "output": worst,
+                "normaliser": rel(Z_win[1], np.asarray(Z_ref[1]))}, bool(
+            unwritten and jnp.all(S_win[0] == S[0])
+            and jnp.all(S_win[1][idle] == S[1][idle])
+            and not any(bool(jnp.any(a)) for a in pending))
+
+    windows = {steps: a_window(steps) for steps in (4, 8)}
+    win_ok = all(exact and all(e <= TOL_RETENTION_F32 for e in errs.values())
+                 for errs, exact in windows.values())
+    say(phase="kernel", op="retention_decode_tpu (a fused window)",
+        geometry=[H, KVH, d], rows=B,
+        **{f"window_of_{n}": errs for n, (errs, _) in windows.items()},
+        pool_exact_until_the_commit_and_pending_empty_after=all(
+            exact for _, exact in windows.values()),
+        tol=TOL_RETENTION_F32, ok=bool(win_ok))
+    ok = ok and win_ok
+    del S, Z, Zw, S0, Z0, S1, Z1
+    say(phase="kernel", op="retention_decode_tpu (device time a layer)",
+        geometry=[H, KVH, d], rows=B,
+        **_retention_decode_times(B, KVH, G, d, rehearse))
 
     q, k, v, lg = draw(2 * T)
     zeros = lambda: (jnp.zeros((L, 4, KVH, F, d)),
@@ -1263,10 +1404,6 @@ def _ssd_form_times(spec, ssd, held, pool, rehearse):
     ``benchmark/lib/model_bytes_ssd_latent_moe.py::ssd_chunk_call``.  On a
     CPU there is no device to time: the calls are walked and nothing is
     reported."""
-    import shutil
-    import subprocess
-    import tempfile
-
     from helix_tpu.ops.deltanet import chunk_table
     from helix_tpu.ops.ssd_kernel import CHUNK, ssd_chunk_tpu
 
@@ -1300,26 +1437,17 @@ def _ssd_form_times(spec, ssd, held, pool, rehearse):
              for f in (row_from_a_state, row_from_zeros)}
     free = jax.jit(state_free_half)
     kernel = jax.jit(chunk_kernel_half, donate_argnums=(7,))
-    out = tempfile.mkdtemp(prefix="ssd_form_")
-    try:
-        with jax.profiler.trace(out):
-            for _ in range(5):
-                for fn in whole.values():
-                    o, pool = fn(*held, pool)
-                o, pool = kernel(*free(*held), pool)
-            jax.block_until_ready(o)
-        if rehearse:
-            return {"timed_on": jax.default_backend()}
-        times = os.path.join(out, "programs.json")
-        subprocess.run(
-            [sys.executable, os.path.join(HERE, "tools", "program_times.py"),
-             out, "--op", "^ssd_chunk_tpu", "--out", times],
-            env=dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled"),
-            check=True)
-        with open(times) as f:
-            programs = json.load(f)["programs"]
-    finally:
-        shutil.rmtree(out, ignore_errors=True)
+
+    def run(pool=pool):
+        for _ in range(5):
+            for fn in whole.values():
+                o, pool = fn(*held, pool)
+            o, pool = kernel(*free(*held), pool)
+        return o
+
+    programs = _device_programs(run, "^ssd_chunk_tpu", rehearse)
+    if programs is None:
+        return {"timed_on": jax.default_backend()}
     ms = {name: round(programs["jit_" + name]["mean_ms"], 4)
           for name in (*whole, "state_free_half", "chunk_kernel_half")}
     from benchmark.lib.model_bytes_ssd_latent_moe import ssd_chunk_call
@@ -2049,6 +2177,8 @@ def main():
                     help="deepseek-v2-lite-int8 only: the depth it is cut to")
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--phase", choices=("all", "kernels"), default="all",
+                    help="kernels: the kernel phase alone (no engine)")
     args = ap.parse_args()
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -2060,6 +2190,11 @@ def main():
     spec = CONFIGS[args.config]
     seed = spec.get("seed", 0) if args.seed is None else args.seed
     spec.get("kernel_phase", phase_kernel)(spec, seed, args.rehearse)
+    if args.phase == "kernels":
+        print(json.dumps({"ok": not args.rehearse, "phase": "kernels",
+                          "config": args.config, "device": device}),
+              flush=True)
+        sys.exit(4 if args.rehearse else 0)
     spec.get("engine_phase", phase_engine)(
         spec, args.config, seed, args.layers, args.steps, args.rehearse)
     if args.rehearse:

@@ -839,20 +839,22 @@ def _segments_fn(fn_p, fn_s, n_tok: int, split, join):
     return fn
 
 
-def _state_rows_fn(cfg, rows_s, backend, rows_p=None, split=None,
+def _state_rows_fn(cfg, rows_s, backend, window, rows_p=None, split=None,
                    join=None, packed=None):
     """``forward``'s ``state_fn`` for the model's layers with a
     per-sequence state, from their kind's record: ``rows_s = (t0, qlen,
     hist, slots)`` the state rows (one token each, row ``b`` slot ``b``),
-    ``rows_p = (t0, qlen, hist, slots, snap)`` the prefill rows before them
-    on the axis, if the program has any.  ``packed``: the prefill rows have
-    no history (``models/mixers.py::_window_rows_fn``)."""
+    ``window = (step, n_extra)`` which decode step of the program's fused
+    window of ``1 + n_extra`` they are, ``rows_p = (t0, qlen, hist, slots,
+    snap)`` the prefill rows before them on the axis, if the program has
+    any.  ``packed``: the prefill rows have no history
+    (``models/mixers.py::_window_rows_fn``)."""
     kind = cfg.state_kind
     return _segments_fn(
         rows_p and kind.rows_fn(
             rows_p[:4], backend, cfg=cfg, decode=False, snap=rows_p[4],
             packed=packed),
-        kind.rows_fn(rows_s, backend, cfg=cfg, decode=True),
+        kind.rows_fn(rows_s, backend, cfg=cfg, decode=True, window=window),
         kind.token_args, split, join)
 
 
@@ -891,11 +893,12 @@ def _ring_chunk_attention(q, k, v, caches, lyr, p_pos, p_seg, p_hist,
 
 
 def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
-                    use_adapters: bool = False, mesh=None):
+                    window, use_adapters: bool = False, mesh=None):
     """One plain decode forward over every slot (each active slot a
     one-token row over its ragged paged history), nothing written:
     returns ``(logits [B, 1, V], (pool carry, fresh K, fresh V))``, the
-    pool carry ending in the state pool where the model has one."""
+    pool carry ending in the state pool where the model has one
+    (``window``: ``_state_rows_fn``'s)."""
     B = state.last_token.shape[0]
     tokens = state.last_token[:, None]
     pos2d = state.positions[:, None]
@@ -919,7 +922,8 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     if cache.state is not None:
         # the slots' recurrent states ride the carry beside the pages
         carry0 += (cache.state,)
-        state_fn = _state_rows_fn(cfg, (t0, q_len, hist, t0), backend)
+        state_fn = _state_rows_fn(
+            cfg, (t0, q_len, hist, t0), backend, window)
     if cfg.mrope_sections is not None:
         from helix_tpu.models.qwen2_vl import text_forward_mrope
 
@@ -956,7 +960,8 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
 
 
 def _tail_decode_step(params, cache, state: DecodeState, *, cfg, backend,
-                      page_size, use_adapters: bool = False, mesh=None):
+                      page_size, window, use_adapters: bool = False,
+                      mesh=None):
     """Traced body of ONE plain decode step over every slot.  This is the
     fused-window TAIL of the unified step (scanned ``n_extra`` times
     inside the same jit so a multi-token window still costs one host
@@ -966,7 +971,7 @@ def _tail_decode_step(params, cache, state: DecodeState, *, cfg, backend,
     B = state.last_token.shape[0]
     active = state.active
     logits, (pc, kacc, vacc) = _decode_forward(
-        params, cache, state, cfg=cfg, backend=backend,
+        params, cache, state, cfg=cfg, backend=backend, window=window,
         use_adapters=use_adapters, mesh=mesh,
     )
     cache = _cache_from(pc, cache)
@@ -1076,6 +1081,7 @@ def _build_ragged_step_fn(
     # the state at one page boundary each (what a prefix hit resumes from)
     has_state = cfg.state_kind is not None
     has_snaps = has_state and cfg.state_kind.snapshots
+    has_window = has_state and cfg.state_kind.window is not None
     is_moe = cfg.num_experts > 0
     is_mrope = cfg.mrope_sections is not None
     Cb = token_bucket
@@ -1230,14 +1236,19 @@ def _build_ragged_step_fn(
             carry0 = (cache.carry(), (kacc_p, kacc_s), (vacc_p, vacc_s))
             state_fn = None
             if has_state:
-                carry0 += (cache.state,)
+                # beside the pool, for this program alone, what its kind's
+                # fused window keeps from step to step (empty: a program
+                # starts from an exact pool and leaves one)
+                carry0 += (cache.state if not has_window else (
+                    *cache.state,
+                    cfg.state_kind.window(cfg, B, n_tail_max + 1)),)
                 if has_snaps and Cb > 0:
                     (shp, _), = cfg.state_arrays()
                     carry0 += (jnp.zeros(
                         (cfg.num_state_layers, prefill_rows) + shp,
                         cache.state.dtype),)
                 state_fn = _state_rows_fn(
-                    cfg, rows_s, backend, rows_p, split, join,
+                    cfg, rows_s, backend, (0, n_extra), rows_p, split, join,
                     packed=((p_pos, p_seg, mesh)
                             if Cb > 0 and not has_hist else None))
             if is_mrope:
@@ -1380,8 +1391,9 @@ def _build_ragged_step_fn(
                     c, st, buf = carry
                     c, st, tok = _tail_decode_step(
                         params, c, st, cfg=cfg, backend=backend,
-                        page_size=page_size, use_adapters=use_adapters,
-                        mesh=mesh,
+                        page_size=page_size,
+                        window=(t + 1, n_extra) if has_window else None,
+                        use_adapters=use_adapters, mesh=mesh,
                     )
                     return _pin_default_layout(c), st, buf.at[t].set(tok)
 
@@ -1391,6 +1403,9 @@ def _build_ragged_step_fn(
                 )
         else:
             extra = jnp.zeros((0, B), jnp.int32)
+        if has_window:
+            # the window's last step left it empty
+            cache = dataclasses.replace(cache, state=cache.state[:-1])
         if has_state:
             return (cache, new_state, p_first, sampled, emit, extra, drops,
                     snaps)
@@ -2535,7 +2550,7 @@ class Engine:
             def peek(params, cache, state: DecodeState):
                 logits, _ = _decode_forward(
                     params, cache, state, cfg=self.model_cfg,
-                    backend=self._backend,
+                    backend=self._backend, window=None,
                     use_adapters=self.adapter_pool is not None,
                     mesh=self.mesh,
                 )

@@ -60,8 +60,8 @@ class StateMixer:
     # -- its state -----------------------------------------------------------
     arrays: Callable             # cfg -> ((shape, dtype), ...) a layer and slot
     # -- its look-back -------------------------------------------------------
-    rows_fn: Callable            # (rows, backend, *, cfg, decode, snap, packed)
-                                 # -> one segment's ``state_fn``
+    rows_fn: Callable            # (rows, backend, *, cfg, decode, snap, packed,
+                                 # window) -> one segment's ``state_fn``
     token_args: int              # leading arguments of ``state_fn`` that are
                                  # token arrays (``_segments_fn`` splits them)
     oracle: Callable             # (cfg, positions) -> the ``state_fn`` of a
@@ -80,6 +80,11 @@ class StateMixer:
     # the host's account of a launch, (cfg, cache_cfg, rows, pos, n_extra) ->
     # {count: increment}, every count at every launch; None: nothing counted
     account: Callable = None
+    # (cfg, slots, steps) -> what the decode steps of one fused window hand
+    # one another BESIDE the pool, empty (the last array of the state in the
+    # carry, made by the program and dropped by it: a program starts from an
+    # exact pool and leaves one); None: every step leaves the pool exact
+    window: Callable = None
     gauges: Callable = None      # (cfg, pos) -> {level: value} from the live
                                  # positions; None: no level
 
@@ -197,7 +202,9 @@ def whole_sequence_window_fn(q, k, v, layer_cache, *, positions, window):
 # called with the mixer's arrays and ``((page carry, kacc, vacc, state
 # pool[, snaps]), the layer's index among its kind's)``: the pools ride the
 # carry and are updated IN PLACE.  ``decode``: the rows are one token each
-# and row ``b`` is slot ``b``.
+# and row ``b`` is slot ``b``; ``window = (step, n_extra)`` then says which
+# step of a fused window of ``1 + n_extra`` decode steps this is (both may be
+# traced: the stage is step 0, the tail's step ``t`` is ``t + 1``).
 
 
 def _state_after(zf, S, t0, n):
@@ -255,7 +262,8 @@ def _conv_rows(z, taps, pool, lc, t0, qlen, hist, slots, snap=None):
         zf, S, t0, snap).astype(pool.dtype)
 
 
-def _conv_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+def _conv_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
+                  window=None):
     """A conv layer's look-back (``models/llama.py::_conv_mixer``):
     ``_conv_rows`` on the state pool in the carry.  ``snap [R]``: also hand
     back each row's state after that many of its tokens (what a prefix hit
@@ -273,38 +281,51 @@ def _conv_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     return conv_fn
 
 
-def _retention_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+def _retention_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
+                       window=None):
     """A retention layer's sum (``models/llama.py::_retention_mixer``) over
     a state thousands of times a conv state's size, the pair ``(S pool, Z
-    pool)``.  ``decode``: the recurrence applied once (on a TPU one pass of
-    the decode kernel over the live slots).  Else the rows are runs of fresh
-    tokens on one flat axis (the chunked form: on a TPU what reads no state
-    once for the axis, then the chunk kernel a row, which reads a row's
-    state once, or not at all where the row starts its sequence, and writes
-    it once).  Called ``(q, k, v, log_g, carry)``."""
-    from helix_tpu.ops.retention import retention_decode, retention_rows
+    pool)``.  ``decode``: the recurrence applied once; on a TPU one pass of
+    the decode kernel over the live slots, which READS ``S`` at every step
+    of a fused window and writes it at the window's last
+    (``ops/retention.py::retention_window_step``: the window's tokens ride
+    the carry behind the pools, ``_retention_window``).  Else the rows are
+    runs of fresh tokens on one flat axis (the chunked form: on a TPU what
+    reads no state once for the axis, then the chunk kernel a row, which
+    reads a row's state once, or not at all where the row starts its
+    sequence, and writes it once).  Called ``(q, k, v, log_g, carry)``."""
+    from helix_tpu.ops.retention import retention_rows, retention_window_step
 
     t0, qlen, hist, slots = rows
 
     def retention_fn(q, k, v, log_g, carry_cache):
-        (caches, kacc, vacc, (s_pool, z_pool)), lc = carry_cache
+        (caches, kacc, vacc, (s_pool, z_pool, *pending)), lc = carry_cache
         Bq, Sq, H, D = q.shape
         if decode:
-            y, s_pool, z_pool = retention_decode(
-                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], s_pool, z_pool, lc,
-                qlen > 0, backend=backend)
+            # a window's tokens have a layer axis like the pools'; without
+            # them every step is a window of one
+            step, n_extra = window if pending else (0, 0)
+            y, s_pool, z_pool, mine = retention_window_step(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], s_pool, z_pool,
+                jax.tree.map(lambda a: a[lc], pending[0]) if pending
+                else None, lc, qlen > 0, step, step == n_extra,
+                backend=backend)
+            if pending:
+                pending = [jax.tree.map(
+                    lambda a, m: a.at[lc].set(m), pending[0], mine)]
         else:
             y, s_pool, z_pool = retention_rows(
                 q.reshape(Bq * Sq, H, D), k.reshape(Bq * Sq, -1, D),
                 v.reshape(Bq * Sq, -1, D), log_g.reshape(Bq * Sq, -1),
                 t0, qlen, hist, slots, s_pool, z_pool, lc, backend=backend)
         return y.reshape(Bq, Sq, H, D), (caches, kacc, vacc,
-                                         (s_pool, z_pool))
+                                         (s_pool, z_pool, *pending))
 
     return retention_fn
 
 
-def _deltanet_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+def _deltanet_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
+                      window=None):
     """A delta-rule layer's look-back (``models/llama.py::_deltanet_mixer``)
     over TWO states a slot: the convolution's tail (``_conv_rows``, the same
     look-back as a gated short convolution's at 4 taps over the q | k | v
@@ -341,7 +362,8 @@ def _deltanet_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     return deltanet_fn
 
 
-def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
+                    window=None):
     """A Mamba-2 layer's look-back (``models/llama.py::_mamba2_mixer``) over
     TWO states a slot: the convolution's tail (``_conv_rows`` at 4 taps over
     the x | B | C channels) and the float32 array ``h`` a head, the pair
@@ -380,7 +402,8 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
     return mamba2_fn
 
 
-def _window_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None):
+def _window_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
+                    window=None):
     """A window layer's attention (``models/llama.py::_layer``): of a row's
     ``hist[r]`` tokens its slot's rings ``(K rings, V rings)`` hold the last
     ``W``.  The row's queries read the rings AS THEY STAND
@@ -444,6 +467,16 @@ def _retention_arrays(cfg) -> tuple:
     d, kvh = cfg.head_dim, cfg.num_kv_heads
     return (((kvh, held_rows(d), d), "float32"),
             ((kvh, d, d), "float32"))
+
+
+def _retention_window(cfg, slots: int, steps: int) -> tuple:
+    """A fused window's tokens (``ops/retention.py::window_zeros``) a
+    retention layer: ~2 MB a layer at 24 slots and 8 steps, float32."""
+    from helix_tpu.ops.retention import window_zeros
+
+    return jax.tree.map(
+        lambda a: jnp.broadcast_to(a, (cfg.num_state_layers,) + a.shape),
+        window_zeros(slots, cfg.num_kv_heads, cfg.head_dim, steps))
 
 
 def _deltanet_arrays(cfg) -> tuple:
@@ -547,6 +580,22 @@ def _rows_from_zeros(cfg, cache_cfg, rows, pos, n_extra) -> dict:
         "chunk_rows_from_zeros": sum(
             1 for r in rows if r.slot >= 0 and r.start == 0),
     }
+
+
+def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+    """... and of the decode row-steps, those that WROTE the state: a fused
+    window of ``1 + n_extra`` steps reads a live row's ``S`` at every step
+    and writes it at its last (``Z``, 1.5% of the bytes, at every step), so
+    writes over ``decode_rows`` is the share of steps that paid for a write
+    and the bytes touched follow what moved.  As the kernel's path runs it:
+    the CPU's recurrence, its oracle, writes at every step."""
+    out = _rows_from_zeros(cfg, cache_cfg, rows, pos, n_extra)
+    (s, sdt), _ = cfg.state_arrays()
+    unwritten = out["decode_rows"] - len(pos)
+    out["state_writes"] = len(pos)
+    out["state_bytes_touched"] -= unwritten * cfg.num_state_layers * int(
+        np.prod(s)) * jnp.dtype(sdt).itemsize
+    return out
 
 
 def _chunked_rows(chunk_of, rows_of=_matrix_rows) -> Callable:
@@ -675,10 +724,15 @@ STATE_MIXERS = {
         rows_fn=_retention_rows_fn,
         token_args=4,
         oracle=lambda cfg, positions: whole_sequence_retention_fn,
-        account=_rows_from_zeros,
+        account=_retention_account,
+        window=_retention_window,
         series=(
             _POOL_BYTES,
             *_rows_series("helix_retention_rows_total"),
+            # over kind="decode" above, the share of decode steps that wrote
+            # the state (1 where no window is fused)
+            Series("helix_retention_state_writes_total", "counter",
+                   "state_writes"),
             # over kind="chunk" above, the share of rows the state's query
             # was skipped for
             Series("helix_retention_chunk_rows_from_zeros_total", "counter",
@@ -687,7 +741,8 @@ STATE_MIXERS = {
         ),
         launch=(("retention_layers", "layers"),
                 ("retention_chunk_rows", "chunk_rows"),
-                ("retention_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
+                ("retention_chunk_rows_from_zeros", "chunk_rows_from_zeros"),
+                ("retention_state_writes", "state_writes")),
         flight=(("retention_chunk_rows", "chunk_rows"),
                 ("retention_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
     ),

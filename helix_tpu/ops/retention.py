@@ -39,7 +39,11 @@ continues from a state: ``retention_chunk``, the chunk kernel's oracle and
 the CPU path).  On a TPU the decode step and the half of the chunked form
 that touches the state are ``ops/retention_kernel.py``'s two kernels
 (``retention_decode``, ``retention_rows``: chosen by the backend the engine
-resolves); the half that reads no state is ``rows_state_free`` here.
+resolves); the half that reads no state is ``rows_state_free`` here.  A
+fused window of decode steps reads ``S`` at every step and writes it at its
+last (``retention_window_step``): the recurrence is linear, so for token
+``i`` of a window that began at ``S_0``, ``phi(q_i)^T S_i = G_{1..i} phi(q_i)^T
+S_0 + sum_{j<=i} G_{j+1..i} (q_i . k_j)^2 v_j``.
 """
 
 from __future__ import annotations
@@ -190,20 +194,54 @@ def retention_chunk(q, k, v, log_g, mask, S0, Z0, eps: float = EPS):
     return y.transpose(1, 0, 2, 3).reshape(T, H, d), S1, Z1
 
 
+def window_zeros(slots: int, kv_heads: int, d: int, steps: int) -> tuple:
+    """What the steps of one fused window of ``steps`` decode steps hand one
+    another beside the pool, empty: the window's tokens a slot and kv head,
+    ``(k [slots, KVH, steps, d], v alike, log-gates [slots, KVH, steps],
+    which slots were live at some step [slots])``.  A term of zero ``k`` and
+    log-gate 0 is no term."""
+    vec = jnp.zeros((slots, kv_heads, steps, d), jnp.float32)
+    return (vec, vec, jnp.zeros((slots, kv_heads, steps), jnp.float32),
+            jnp.zeros((slots,), bool))
+
+
 def retention_decode(q, k, v, log_g, S_pool, Z_pool, layer, live, *,
                      backend=None, interpret: bool = False,
                      eps: float = EPS):
-    """One decode step of every slot: row ``b`` is slot ``b``'s one fresh
-    token (``live [B]`` bool: idle slots and rows that sit the step out
-    write nothing and read zeros).  ``q [B, H, d]`` (scaled), ``k, v [B,
-    KVH, d]``, ``log_g [B, KVH]``; pools ``S [L, N, KVH, D_held, d]`` and
-    ``Z [L, N, KVH, d, d]`` with ``N >= B``, updated IN PLACE at ``layer``.
-    Returns ``(y [B, H, d] float32, S_pool, Z_pool)``.
+    """One decode step of every slot that stands alone (a window of one):
+    ``retention_window_step`` without its pending tokens.  Returns ``(y [B,
+    H, d] float32, S_pool, Z_pool)``."""
+    return retention_window_step(
+        q, k, v, log_g, S_pool, Z_pool, None, layer, live, 0, True,
+        backend=backend, interpret=interpret, eps=eps)[:3]
 
-    On a TPU the state's update and its query are one pass of
-    ``retention_decode_tpu`` over the live slots (``interpret``: the same
-    kernel in interpret mode, for tests on a CPU with ``backend="pallas"``);
-    on a CPU, or for ``backend="reference"``, the plain recurrence."""
+
+def retention_window_step(q, k, v, log_g, S_pool, Z_pool, pending, layer,
+                          live, step, last, *, backend=None,
+                          interpret: bool = False, eps: float = EPS):
+    """Step ``step`` (from 0) of a fused window of decode steps, for every
+    slot: row ``b`` is slot ``b``'s one fresh token (``live [B]`` bool: idle
+    slots and rows that sit the step out write nothing and read zeros).
+    ``q [B, H, d]`` (scaled), ``k, v [B, KVH, d]``, ``log_g [B, KVH]``;
+    pools ``S [L, N, KVH, D_held, d]`` and ``Z [L, N, KVH, d, d]`` with ``N
+    >= B``, updated IN PLACE at ``layer``.  ``pending``: ``window_zeros`` at
+    the window's first step, what the step before returned after (None: a
+    window of one).  Returns ``(y [B, H, d] float32, S_pool, Z_pool,
+    pending)``.
+
+    On a TPU the recurrence is linear, so a step that is not the window's
+    ``last`` only READS ``S`` (``retention_decode_tpu`` with ``commit``
+    false: ``phi(q)^T S_0``, seen through the gates since the window began)
+    and adds the window's own tokens by their scores, ``sum_j G_{j+1..i} (q
+    . k_j)^2 v_j``, from ``pending``, where its own ``k, v, log g`` join
+    them; the last step writes ``S`` once with all the window's outer
+    products and queries what it wrote, for every slot that was live at some
+    step, and hands back ``pending`` empty.  ``Z`` (1.5% of the bytes) steps
+    every time.  ``step`` and ``last`` are data: one program whatever the
+    window's length (``interpret``: the same kernel in interpret mode, for
+    tests on a CPU with ``backend="pallas"``).  On a CPU, or for
+    ``backend="reference"``, the plain recurrence at every step, and
+    ``pending`` as it came."""
     from helix_tpu.ops.attention import resolve_backend
 
     B, H, d = q.shape
@@ -217,20 +255,45 @@ def retention_decode(q, k, v, log_g, S_pool, Z_pool, layer, live, *,
 
         den, Z = normaliser_step(q, k, log_g, Z_pool[layer, :B])
         Z_pool = Z_pool.at[layer, dest].set(Z, mode="drop")
-        order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+        # a row that sits the step out is a term of nothing
+        here = live[:, None]
+        lg = jnp.where(here, log_g.astype(jnp.float32), 0.0)
+        k, v = (jnp.where(here[..., None], x, 0.0) for x in (k, v))
+        if pending is None:
+            ks, vs, lgs, seen = k[:, :, None], v[:, :, None], lg[..., None], live
+        else:
+            ks, vs, lgs, seen = pending
+            ks, vs = ks.at[:, :, step].set(k), vs.at[:, :, step].set(v)
+            lgs, seen = lgs.at[:, :, step].set(lg), seen | live
+        # the gates after each token (tokens the window has not reached are
+        # zeros: no gate), and the window's whole gate
+        after = jnp.exp(jnp.cumsum(lgs[..., ::-1], axis=-1)[..., ::-1] - lgs)
+        whole = jnp.exp(jnp.sum(lgs, axis=-1))                 # [B, KVH]
+        # the last step visits every slot the window touched
+        visit = jnp.where(last, seen, live)
+        qg = q.reshape(B, KVH, H // KVH, d)
         num, S_pool = retention_decode_tpu(
-            q.reshape(B, KVH, H // KVH, d), k[:, :, None], v[:, :, None],
-            jnp.broadcast_to(jnp.exp(log_g.astype(jnp.float32))[
-                ..., None, None], (B, KVH, 1, d)),
-            S_pool, layer, order, jnp.sum(live).astype(jnp.int32),
-            interpret=interpret)
+            qg, ks, vs * after[..., None],
+            jnp.broadcast_to(whole[..., None, None], (B, KVH, 1, d)),
+            S_pool, layer, jnp.argsort(~visit, stable=True).astype(jnp.int32),
+            jnp.sum(visit).astype(jnp.int32), last, interpret=interpret)
+        if pending is not None:
+            sc = jnp.einsum("bkgd,bkmd->bkgm", qg, ks, precision=_HI) ** 2
+            fresh = jnp.einsum(
+                "bkgm,bkmd->bkgd", sc * after[:, :, None], vs, precision=_HI)
+            num = jnp.where(
+                last, num, whole[..., None, None] * num + fresh)
+            pending = jax.tree.map(
+                lambda a: jnp.where(last, jnp.zeros_like(a), a),
+                (ks, vs, lgs, seen))
         y = (num / (den[..., None] + eps)).reshape(B, H, d)
-        return jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool
+        return (jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool,
+                pending)
     y, S, Z = retention_step(
         q, k, v, log_g, S_pool[layer, :B], Z_pool[layer, :B], eps)
     S_pool = S_pool.at[layer, dest].set(S, mode="drop")
     Z_pool = Z_pool.at[layer, dest].set(Z, mode="drop")
-    return jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool
+    return jnp.where(live[:, None, None], y, 0.0), S_pool, Z_pool, pending
 
 
 def retention_rows(q, k, v, log_g, t0, qlen, hist, slots, S_pool, Z_pool,

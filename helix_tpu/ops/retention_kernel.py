@@ -1,7 +1,7 @@
 """Pallas TPU kernels for power retention: one decode step (the state's
-update and its query in ONE pass), and the half of the chunked form that
-touches the state (``retention_chunk_tpu``, further down: a row of fresh
-tokens against its slot's state, read once and written once).
+update and its query in ONE pass, or its query alone), and the half of the
+chunked form that touches the state (``retention_chunk_tpu``, further down: a
+row of fresh tokens against its slot's state, read once and written once).
 
 **The decode step** (``retention_decode_tpu``).
 XLA's form of ``ops/retention.py::retention_step`` walks the state three
@@ -12,15 +12,27 @@ the group's query heads' ``phi(q)`` rows while it is still in VMEM, and
 written back through ``input_output_aliases``: one read and one write of the
 state a row, a layer and a step, over the live slots only.
 
+**A fused window writes once.**  The recurrence is linear, so the steps of a
+window of decode steps that are not its last need ``phi(q)^T S_0`` alone
+(``ops/retention.py::retention_window_step`` adds the window's own tokens by
+their scores): with ``commit`` false (data, a prefetched scalar: one program)
+the kernel streams the same tiles, multiplies, and writes NOTHING: every
+visit names one output block, which goes back as it came.  The window's last
+step commits all its ``M`` tokens at once, ``S = G S + sum_j phi(k_j) (G_j
+v_j)^T``, and queries what it wrote; ``M = 1`` is the step that stands alone.
+
 ``phi`` is never built, in HBM or in VMEM.  In the held packing
 (``ops/retention.py``) a tile is ``d/8 + 1`` blocks ``(a, b)`` of the
 symmetric matrix ``k k^T``, and a vreg of the tile is row ``r`` of block
 ``a`` against the 8 columns of block ``b``, all ``d`` lanes of ``v``: its
-update is ``k[8a + r] * (k[8b:8b+8] v^T)``, a scalar times an ``[8, d]``
+update is ``k[8a + r] * (k[8b:8b+8] v^T)``, a number times an ``[8, d]``
 slab of the outer product ``k v^T`` (built once a slot and head, 16 vregs at
 width 128), and its query term is ``q[8a + r] * q[8b:8b+8]`` the same way.
-The scalars come from SMEM copies of ``k`` and ``q``; the sublane-oriented
-columns from one ``[d, d]`` transpose a vector.
+Both factors come from one ``[d, d]`` transpose a vector (the vector down
+the sublanes, across every lane): the slab is its rows ``8b..8b+8``, the
+number is its row ``8a + r`` spread over the sublanes.  (NOT a scalar from
+an SMEM copy of the vector at a traced index: that is 27 scalar operations a
+vreg of state, and the scalar unit, not the bytes, then sets the pass's time.)
 
 Grid ``(rows, kv heads, tiles)``, sequential.  Visits past the live rows
 repeat the last live block (nothing is fetched or written for them) and are
@@ -69,30 +81,50 @@ def check_retention_geometry(num_heads: int, num_kv_heads: int,
             "attn_backend='reference' explicitly, or extend the kernel.")
 
 
-def _kernel(layer_ref, order_ref, count_ref, q_ref, qs_ref, k_ref, ks_ref,
-            v_ref, g_ref, s_ref, num_ref, o_ref, kv_ref, qb_ref, acc_ref,
-            *, d: int, group: int):
+def _as_i32(a):
+    """A prefetched scalar or index vector: int32, one axis."""
+    return jnp.asarray(a, jnp.int32).reshape(-1)
+
+
+def _kernel(layer_ref, order_ref, count_ref, commit_ref, q_ref, k_ref, v_ref,
+            g_ref, s_ref, num_ref, o_ref, kb_ref, kv_ref, qb_ref, acc_ref,
+            *, d: int, group: int, terms: int):
     del layer_ref, order_ref                 # read by the index maps
     nb = d // BLOCK
     width = (nb + 1) * BLOCK                 # a row of the tile, in rows of S
     n, p = pl.program_id(0), pl.program_id(2)
     count = count_ref[0]
+    commits = commit_ref[0] > 0
+    live = n < count
 
-    @pl.when(n < count)
-    def _live():
+    def column(row):
+        # [j, c] = u[j]: a vector down the sublanes, across every lane
+        return jnp.broadcast_to(row, (d, d)).T
+
+    def across(rows, r):
+        """Row ``r`` of each ``[8, d]`` block of ``rows [n, 8, d]`` over all 8
+        sublanes: with a vector down the sublanes, ``u[8 a + r]`` across a
+        whole vreg."""
+        return jnp.broadcast_to(rows[:, r:r + 1], rows.shape)
+
+    def a_tile(write: bool):
+        """The tile against the group's queries; ``write``: scaled by the
+        row's gate and given the window's outer products first, and stored.
+        (The heads and the window's tokens are ONE array each: a program
+        holds this body twice, and a step program is lowered a bucket at a
+        time, so every traced operation is paid in set-up.)"""
         @pl.when(p == 0)
         def _first_tile():
-            # [j, c] = u[j]: a vector down the sublanes, across every lane
-            def column(row):
-                return jnp.broadcast_to(row, (d, d)).T
-
-            kv_ref[...] = column(k_ref[...]) * v_ref[...]
+            if write:
+                for j in range(terms):
+                    kb_ref[j] = column(k_ref[pl.ds(j, 1), :])
+                    kv_ref[j] = kb_ref[j] * v_ref[pl.ds(j, 1), :]
             for h in range(group):
                 qb_ref[h] = column(q_ref[pl.ds(h, 1), :])
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         gate = g_ref[...]                                    # [1, d]
-        accs = [jnp.zeros((BLOCK, d), jnp.float32)] * group
+        acc = None
         for s in range(nb + 1):                              # static unroll
             # the tile pairs block row p (its nb - p blocks first) with
             # block row nb - 1 - p
@@ -100,56 +132,76 @@ def _kernel(layer_ref, order_ref, count_ref, q_ref, qs_ref, k_ref, ks_ref,
             a = jnp.where(first, p, nb - 1 - p)
             b = jnp.where(first, p + s, s - 1)
             w = jnp.where(a == b, 1.0, _SQRT2).astype(jnp.float32)
-            at = pl.multiple_of(b * BLOCK, BLOCK)
-            kvb = kv_ref[pl.ds(at, BLOCK), :] * w            # [8, d]
-            part = [None] * group
+            up = pl.ds(pl.multiple_of(a * BLOCK, BLOCK), BLOCK)
+            at = pl.ds(pl.multiple_of(b * BLOCK, BLOCK), BLOCK)
+            qa = qb_ref[:, up, :]                            # [G, 8, d]
+            if write:
+                ka = kb_ref[:, up, :]                        # [M, 8, d]
+                kvb = kv_ref[:, at, :] * w
+            part = None
             for r in range(BLOCK):
                 rows = pl.ds(r * width + s * BLOCK, BLOCK)
-                new = gate * s_ref[rows, :] + ks_ref[0, a * BLOCK + r] * kvb
-                o_ref[rows, :] = new
-                for h in range(group):
-                    t = qs_ref[h, a * BLOCK + r] * new
-                    part[h] = t if part[h] is None else part[h] + t
-            accs = [
-                acc + (qb_ref[h, pl.ds(at, BLOCK), :] * w) * part[h]
-                for h, acc in enumerate(accs)]
-        for h in range(group):
-            acc_ref[h] += accs[h]
+                new = s_ref[rows, :]
+                if write:
+                    new = gate * new + jnp.sum(across(ka, r) * kvb, axis=0)
+                    o_ref[rows, :] = new
+                t = across(qa, r) * new
+                part = t if part is None else part + t
+            t = (qb_ref[:, at, :] * w) * part
+            acc = t if acc is None else acc + t
+        acc_ref[...] += acc
 
         @pl.when(p == nb // 2 - 1)
         def _last_tile():
             num_ref[...] = jnp.sum(acc_ref[...], axis=1)
 
-    @pl.when(jnp.logical_and(count == 0, jnp.logical_and(
-        n == 0, jnp.logical_and(pl.program_id(1) == 0, p == 0))))
-    def _nothing_live():
+    @pl.when(jnp.logical_and(live, commits))
+    def _commits():
+        a_tile(True)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(commits)))
+    def _reads():
+        a_tile(False)
+
+    # every visit of a step that writes nothing names ONE output block, the
+    # first visit's: copied through once, it goes back as it came
+    @pl.when(jnp.logical_and(
+        jnp.logical_or(count == 0, jnp.logical_not(commits)),
+        jnp.logical_and(n == 0, jnp.logical_and(
+            pl.program_id(1) == 0, p == 0))))
+    def _nothing_written():
         o_ref[...] = s_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def retention_decode_tpu(
     q,          # [B, KVH, G, d] f32, times head_dim ** -0.5
-    k,          # [B, KVH, 1, d] f32
-    v,          # [B, KVH, 1, d] f32
-    gate,       # [B, KVH, 1, d] f32: the row's gate across the lanes
+    k,          # [B, KVH, M, d] f32: the window's tokens (zeros: no term)
+    v,          # [B, KVH, M, d] f32, each times the gates after its token
+    gate,       # [B, KVH, 1, d] f32: the window's whole gate across the lanes
     s_pool,     # [L, N, KVH, D_held, d] f32, N >= B: row b is slot b
     layer,      # which of the L layers (a traced index)
     order,      # [B] int32: the live rows first
     count,      # how many of them are live
+    commit=True,  # (traced) False: the state is read and nothing is written
     *,
     interpret: bool = False,
 ):
-    """Returns ``(num [B, KVH, G, d] f32, s_pool)``: ``phi(q)^T S_t`` of
-    every live row (rows that are not live hold whatever was there), and the
-    pool with the live slots' states advanced one token, in place."""
+    """Returns ``(num [B, KVH, G, d] f32, s_pool)`` for every live row (rows
+    that are not live hold whatever was there).  ``commit``: the live slots'
+    states advanced by the window's ``M`` tokens in place, ``S = gate * S +
+    sum_j phi(k_j) v_j^T`` (``M = 1``: one decode step), and ``num = phi(q)^T
+    S``.  Not ``commit``: ``num = phi(q)^T S`` of the state as it stands, the
+    pool bit for bit what it was (one tile is written back as it came)."""
     B, KVH, G, d = q.shape
+    M = k.shape[2]
     L, N, _, F, _ = s_pool.shape
     assert F == held_rows(d) and N >= B
     if not interpret:
         check_retention_geometry(KVH * G, KVH, d)
     tiles, rows = d // (2 * BLOCK), tile_rows(d)
 
-    def visit(n, h, p, layer, order, count):
+    def visit(n, h, p, order, count):
         """The (row, kv head, tile) a visit names: its own while the row is
         live, the last live one after."""
         dead = n >= count[0]
@@ -157,47 +209,51 @@ def retention_decode_tpu(
         return (row, jnp.where(dead, KVH - 1, h),
                 jnp.where(dead, tiles - 1, p))
 
-    def vec_map(n, h, p, *pre):
-        row, h, _ = visit(n, h, p, *pre)
+    def vec_map(n, h, p, layer, order, count, commit):
+        row, h, _ = visit(n, h, p, order, count)
         return row, h, 0, 0
 
-    def state_map(n, h, p, layer, order, count):
-        row, h, p = visit(n, h, p, layer, order, count)
+    def state_in(n, h, p, layer, order, count, commit):
+        return (layer[0],) + visit(n, h, p, order, count) + (0,)
+
+    def state_out(n, h, p, layer, order, count, commit):
+        # a step that writes nothing names the first visit's block throughout
+        row, h, p = (
+            jnp.where(commit[0] > 0, here, first) for here, first in zip(
+                visit(n, h, p, order, count), visit(0, 0, 0, order, count)))
         return layer[0], row, h, p, 0
 
-    def vec(width, **kw):
-        return pl.BlockSpec((None, None, width, d), vec_map, **kw)
+    def vec(width):
+        return pl.BlockSpec((None, None, width, d), vec_map)
 
-    smem = dict(memory_space=pltpu.MemorySpace.SMEM)
-    state = pl.BlockSpec((None, None, None, rows, d), state_map)
+    state = lambda index: pl.BlockSpec((None, None, None, rows, d), index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, KVH, tiles),
-        in_specs=[vec(G), vec(G, **smem), vec(1), vec(1, **smem), vec(1),
-                  vec(1), state],
-        out_specs=[vec(G), state],
+        in_specs=[vec(G), vec(M), vec(M), vec(1), state(state_in)],
+        out_specs=[vec(G), state(state_out)],
         scratch_shapes=[
-            pltpu.VMEM((d, d), jnp.float32),           # k v^T
+            pltpu.VMEM((M, d, d), jnp.float32),        # k_j down the sublanes
+            pltpu.VMEM((M, d, d), jnp.float32),        # k_j v_j^T
             pltpu.VMEM((G, d, d), jnp.float32),        # q down the sublanes
             pltpu.VMEM((G, BLOCK, d), jnp.float32),    # the group's sums
         ],
     )
     num, s_pool = pl.pallas_call(
-        functools.partial(_kernel, d=d, group=G),
+        functools.partial(_kernel, d=d, group=G, terms=M),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KVH, G, d), jnp.float32),
                    jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype)],
-        # operand 9 (after the three prefetched scalars): the pool
-        input_output_aliases={9: 1},
+        # operand 8 (after the four prefetched scalars): the pool
+        input_output_aliases={8: 1},
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         name="retention_decode_tpu",
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
-        jnp.asarray(count, jnp.int32).reshape(1),
-        q, q, k, k, v, gate, s_pool,
+        _as_i32(layer), _as_i32(order), _as_i32(count), _as_i32(commit),
+        q, k, v, gate, s_pool,
     )
     return num, s_pool
 
@@ -371,7 +427,6 @@ def retention_chunk_tpu(
     # both ends of the pipeline twice, the scratch, the product's temporaries
     held = 4 * (2 * (2 * NB * G * d + 2 * NB * d + 2 * rows) * TOKENS
                 + 3 * rows * (G + 1) * TOKENS)
-    as_i32 = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
     return pl.pallas_call(
         functools.partial(_chunk_kernel, d=d, group=G),
         grid_spec=grid_spec,
@@ -386,7 +441,7 @@ def retention_chunk_tpu(
         ),
         name="retention_chunk_tpu",
     )(
-        as_i32(layer), as_i32(slot), as_i32(t0), as_i32(qlen),
-        as_i32(n_hist), as_i32(n_live),
+        _as_i32(layer), _as_i32(slot), _as_i32(t0), _as_i32(qlen),
+        _as_i32(n_hist), _as_i32(n_live),
         q, k, vo, decay, s_pool,
     )

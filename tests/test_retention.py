@@ -5,6 +5,7 @@ oracle is the benchmark's plain reference
 (``benchmark/lib/reference_retention_decoder.py``: the quadratic form
 straight from the definition); the engine is compared by LOGITS."""
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -89,6 +90,25 @@ def _run(eng, reqs, watch):
                 and eng.slots[watch.slot] is watch):
             logits[n] = np.asarray(eng.next_token_logits()[watch.slot])
     return logits
+
+
+@contextlib.contextmanager
+def _launch_spans():
+    """The attributes of every ``helix.loop.launch`` span opened inside."""
+    from helix_tpu.obs import trace as obs_trace
+
+    seen, orig = [], obs_trace.phase
+
+    def phase(name, *a, **kw):
+        if name == "helix.loop.launch":
+            seen.append(kw)
+        return orig(name, *a, **kw)
+
+    obs_trace.phase = phase
+    try:
+        yield seen
+    finally:
+        obs_trace.phase = orig
 
 
 def _rel(got, want):
@@ -228,6 +248,45 @@ def test_decode_kernel_in_interpret_mode_against_the_recurrence(live):
     assert np.array_equal(S1[1][:B][idle], S[1][:B][idle])
     assert np.array_equal(S1[1, B:], S[1, B:])
     np.testing.assert_allclose(y1, y0, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4, 8])
+def test_a_fused_window_on_the_kernel_is_the_recurrence_a_step_at_a_time(
+        steps):
+    """A window of ``steps`` decode steps on the kernel in interpret mode,
+    some rows live at only some steps and one at none, against the
+    token-by-token recurrence: every step's outputs; the pool untouched
+    until the last step; then the state and ``Z`` the recurrence leaves,
+    every other slot and layer bit for bit, and the pending tokens empty."""
+    B, KVH, G, d, L, N = 5, 2, 3, 16, 2, 6
+    S = jax.random.normal(jax.random.PRNGKey(1), (L, N, KVH, R.held_rows(d), d))
+    Z = jax.random.normal(jax.random.PRNGKey(2), (L, N, KVH, d, d))
+    lives = np.random.default_rng(steps).random((steps, B)) < 0.6
+    lives[:, 4] = False
+    lives[-1, 0], lives[:, 1] = False, True     # one leaves early, one stays
+    want, got = (S, Z), (S, Z)
+    pending = R.window_zeros(B, KVH, d, 8)
+    for i in range(steps):
+        q, k, v, lg = _draw(B, KVH=KVH, G=G, seed=100 * steps + i)
+        live = jnp.asarray(lives[i])
+        y0, *want, _ = R.retention_window_step(
+            q, k, v, lg, *want, None, 1, live, 0, True, backend="reference")
+        y1, *got, pending = R.retention_window_step(
+            q, k, v, lg, *got, pending, 1, live, jnp.int32(i),
+            jnp.asarray(i == steps - 1), backend="pallas", interpret=True)
+        np.testing.assert_allclose(y1, y0, rtol=1e-4, atol=1e-4)
+        assert not np.any(np.asarray(y1)[~lives[i]])
+        if i < steps - 1:
+            assert np.array_equal(got[0], S)
+            assert np.array_equal(pending[3], lives[:i + 1].any(axis=0))
+    np.testing.assert_allclose(got[0], want[0], atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-5)
+    idle = ~lives.any(axis=0)
+    for pool, was in ((got[0], S), (got[1], Z)):
+        assert np.array_equal(pool[0], was[0])
+        assert np.array_equal(pool[1][:B][idle], was[1][:B][idle])
+        assert np.array_equal(pool[1, B:], was[1, B:])
+    assert not any(np.any(np.asarray(a)) for a in pending)
 
 
 def _recurrence(q, k, v, lg, S0, Z0):
@@ -466,11 +525,90 @@ def test_chunked_prefill_then_decode_on_the_kernel_path(model, monkeypatch):
     cfg, params = model
     monkeypatch.setattr(
         retention_kernel, "check_retention_geometry", lambda *a: None)
-    for name in ("retention_rows", "retention_decode"):
+    for name in ("retention_rows", "retention_window_step"):
         monkeypatch.setattr(
             R, name, functools.partial(getattr(R, name), interpret=True))
     _chunked_prefill_then_decode(
         _engine(cfg, params, attn_backend="pallas"), params)
+
+
+def _windows_a_chunked_prompt_and_a_reused_slot(eng):
+    """Two rows decode in fused windows; a 37-token prompt arrives and its
+    three chunks run beside their decode rows (each a window of one, on the
+    state the windows committed); it decodes in windows with them; then a
+    fourth request reuses the slot of the first to finish.  Returns every
+    request's tokens."""
+    reqs = [_req("a", tokens_of(9, 1), 18), _req("b", tokens_of(7, 2), 6)]
+    late = [_req("chunked", tokens_of(37, 3), 9),
+            _req("reuses", tokens_of(6, 4), 8)]
+    for r in reqs:
+        eng.add_request(r)
+    steps = 0
+    while eng.has_work() or late:
+        eng.step()
+        steps += 1
+        if late and (steps == 3 if len(late) == 2 else reqs[1].finished):
+            reqs.append(late.pop(0))
+            eng.add_request(reqs[-1])
+        assert steps < 200
+    return {r.id: list(r.output_tokens) for r in reqs}
+
+
+def test_fused_windows_on_the_kernel_path_give_the_references_tokens(
+        model, monkeypatch):
+    """Through the engine with windows of up to 4 fused decode steps on
+    ``backend="pallas"`` (the kernels in interpret mode): steps that read the
+    state and write nothing, commits, chunk rows and decode rows that
+    continue from what a window committed, and a reused slot, against the
+    plain recurrence a step at a time (``backend="reference"``, no window)."""
+    import functools
+
+    from helix_tpu.ops import retention_kernel
+
+    cfg, params = model
+    want = _windows_a_chunked_prompt_and_a_reused_slot(_engine(cfg, params))
+    monkeypatch.setattr(
+        retention_kernel, "check_retention_geometry", lambda *a: None)
+    for name in ("retention_rows", "retention_window_step"):
+        monkeypatch.setattr(
+            R, name, functools.partial(getattr(R, name), interpret=True))
+    eng = _engine(cfg, params, attn_backend="pallas", decode_steps_per_sync=4,
+                  adaptive_sync_max_streams=0)
+    got = _windows_a_chunked_prompt_and_a_reused_slot(eng)
+    assert got == want and all(got.values())
+    counts = eng.mixer_counts
+    # windows were fused: a good part of the decode row-steps wrote nothing
+    assert counts["state_writes"] < 0.75 * counts["decode_rows"]
+    assert counts["chunk_rows"] >= 5 and eng.num_mixed_steps >= 1
+
+
+def test_a_window_of_four_over_three_rows_writes_the_state_three_times(model):
+    """The host's account of one fused launch: 12 decode row-steps, 3 writes
+    of the state (``helix_retention_state_writes_total``, and
+    ``retention_state_writes`` on the launch's span), and the bytes that
+    moved: ``S`` read at every step and written once a row, ``Z`` read and
+    written at every step."""
+    cfg, params = model
+    eng = _engine(cfg, params, decode_steps_per_sync=4)
+    for i in range(3):
+        eng.add_request(_req(str(i), tokens_of(5 + i, i), 12))
+    while eng.waiting or eng._decode_window() != 4:
+        eng.step()
+    before = dict(eng.mixer_counts)
+    with _launch_spans() as seen:
+        eng.step()
+    added = {k: n - before[k] for k, n in eng.mixer_counts.items()}
+    assert added["decode_rows"] == 12 and added["state_writes"] == 3
+    assert [kw["retention_state_writes"] for kw in seen] == [3]
+    (s, _), (z, _) = cfg.state_arrays()
+    s, z = (cfg.num_state_layers * int(np.prod(a)) * 4 for a in (s, z))
+    assert added["state_bytes_touched"] == 12 * (s + z) + 12 * z + 3 * s
+    # a step that stands alone writes what it reads
+    eng2 = _engine(cfg, params)
+    one = _req("x", tokens_of(5), 4)
+    _run(eng2, [one], one)
+    assert eng2.mixer_counts["state_writes"] == (
+        eng2.mixer_counts["decode_rows"])
 
 
 def test_a_mixed_step_gives_each_row_what_it_gets_alone(model):
@@ -648,24 +786,11 @@ def test_conv_models_keep_their_prefix_cache(model):
 
 
 def test_launch_record_and_metrics_carry_the_retention_layers(model):
-    from helix_tpu.obs import trace as obs_trace
-
     cfg, params = model
     eng = _engine(cfg, params)
-    seen = []
-    orig = obs_trace.phase
-
-    def phase(name, *a, **kw):
-        if name == "helix.loop.launch":
-            seen.append(kw)
-        return orig(name, *a, **kw)
-
-    obs_trace.phase = phase
-    try:
+    with _launch_spans() as seen:
         req = _req("a", tokens_of(9, 9), 3)
         _run(eng, [req], req)
-    finally:
-        obs_trace.phase = orig
     assert seen and all(
         kw["retention_layers"] == 3 and kw["attn_layers"] == 0
         and "conv_layers" not in kw for kw in seen)

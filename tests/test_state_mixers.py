@@ -127,7 +127,7 @@ def _gauges(m, cfg) -> dict:
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_record_is_complete(kind):
     m = STATE_MIXERS[kind]
-    optional = ("check_geometry", "account", "gauges")
+    optional = ("check_geometry", "account", "gauges", "window")
     for f in dataclasses.fields(m):
         assert f.name in optional or getattr(m, f.name) is not None, f.name
     assert m.refused_as and m.call_refusal and m.token_args >= 1
@@ -239,3 +239,61 @@ def test_series_names_pass_the_linters_naming_contract(kind):
         assert not s.name.endswith(lint._BAD_SUFFIXES), s.name
         assert not s.name.endswith(lint._RESERVED_SUFFIXES), s.name
         assert s.name.endswith("_total") == (s.kind == "counter"), s.name
+
+
+# ---- a kind's fused window is nothing to a model without one ---------------
+#
+# sha256 of ``fn.lower(*args).as_text()`` (it carries no locations; taken
+# under this suite's ``conftest.py``, whose matmul precision is in the text)
+# of a tiny dense model's three programs with a fused tail, AT THE COMMIT
+# BEFORE a state kind could keep a window's tokens beside its pool (d23911d,
+# PR 46).  The same digests came out of that tree and of this one for a tiny
+# model of every other kind too (latent, conv, deltanet, window, mamba2: 18
+# programs; PERF.md section 6, PR 47).  A PR that MEANS to change the step
+# every model runs takes new digests from its own tree and says so.
+_DENSE_PROGRAMS = {
+    "decode": ((0, 0, False), "c978b3b4185b112051c29602ca1e7907"
+                              "98c748c70c21d5caf53006376487e687"),
+    "wave": ((16, 2, False), "52d7205379a8feb72fa06534ee9eb445"
+                             "57a2bd62597222c7b145432d318a2904"),
+    "chunk_with_history": ((16, 1, True), "62ee93659baf2fcc4ed0898d3824b769"
+                                          "dec03bd61430ac26b183406178fda9c8"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_DENSE_PROGRAMS))
+def test_a_model_without_a_state_kind_lowers_to_the_text_it_had(program):
+    import hashlib
+
+    import jax
+
+    import joint_pass
+    from helix_tpu.engine.engine import Engine, EngineConfig
+    from helix_tpu.models.common import ModelConfig
+    from helix_tpu.models.llama import init_params
+
+    cfg = ModelConfig.tiny(vocab_size=512, dtype="float32")
+    assert cfg.state_kind is None
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(3)), EngineConfig(
+        max_decode_batch=3, page_size=8, num_pages=96, max_pages_per_seq=16,
+        max_prefill_len=16, attn_backend="reference",
+        decode_steps_per_sync=4))
+    shape, digest = _DENSE_PROGRAMS[program]
+    fn, args = joint_pass.step_program(eng, *shape)
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def test_only_a_kind_with_a_window_is_told_the_step_of_the_tail():
+    """A kind that keeps nothing beside its pool (``window`` None) gets no
+    pending tokens in its carry; the one that does starts them empty."""
+    from helix_tpu.models.common import BRUMBY_14B
+
+    kinds = {name for name, m in STATE_MIXERS.items() if m.window is not None}
+    assert kinds == {"retention"}
+    k, v, lg, seen = STATE_MIXERS["retention"].window(
+        dataclasses.replace(BRUMBY_14B, num_layers=2,
+                            layer_types=("retention",) * 2), 3, 8)
+    assert k.shape == v.shape == (2, 3, 8, 8, 128) and k.dtype == np.float32
+    assert lg.shape == (2, 3, 8, 8) and seen.shape == (2, 3)
+    assert not any(np.any(np.asarray(a)) for a in (k, v, lg, seen))

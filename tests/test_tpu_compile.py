@@ -313,10 +313,14 @@ def test_grouped_product_compiles_at_the_published_widths(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
 
 
-def test_retention_decode_kernel_compiles_at_the_published_geometry(one_chip):
+@pytest.mark.parametrize("terms", [1, 4, 8])
+def test_retention_decode_kernel_compiles_at_the_published_geometry(
+        one_chip, terms):
     """Brumby-14B's decode kernel: 24 rows, 8 kv heads of 5 query heads,
     width 128, a state of 8,704 x 128 float32 a head in a pool of ten layers
-    (8.6 GB), donated: aliased to its output, no copy."""
+    (8.6 GB), donated: aliased to its output, no copy.  ``terms``: the
+    tokens a fused window commits at once (1: a step that stands alone);
+    whether a call commits or only reads is data."""
     from helix_tpu.ops.retention import held_rows
     from helix_tpu.ops.retention_kernel import retention_decode_tpu
 
@@ -327,9 +331,9 @@ def test_retention_decode_kernel_compiles_at_the_published_geometry(one_chip):
 
     pool = S((L, B, KVH, held_rows(d), d))
     compiled = jax.jit(retention_decode_tpu, donate_argnums=(4,)).lower(
-        S((B, KVH, G, d)), S((B, KVH, 1, d)), S((B, KVH, 1, d)),
+        S((B, KVH, G, d)), S((B, KVH, terms, d)), S((B, KVH, terms, d)),
         S((B, KVH, 1, d)), pool, S((), jnp.int32), S((B,), jnp.int32),
-        S((), jnp.int32)).compile()
+        S((), jnp.int32), S((), jnp.bool_)).compile()
     assert "retention_decode_tpu" in compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = L * B * KVH * held_rows(d) * d * 4

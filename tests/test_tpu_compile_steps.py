@@ -220,6 +220,14 @@ def test_retention_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+    # ... through the fused tail of seven steps too, where a step that is not
+    # the window's last reads the pool and writes nothing, and which step
+    # that is is data: no copy of the pool (in any layout) around the kernel
+    # or the loop, and nothing computed again
+    import re
+
+    assert not re.search(r"= f32\[2,24,8,8704,128\]\S* copy\(", text)
+    assert ".remat" not in text
 
 
 # benchmark/configs/laguna-xs2-int8.profile.yaml
