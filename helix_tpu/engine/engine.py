@@ -1533,8 +1533,13 @@ class Engine:
         self.mixer_counts = account(
             model_cfg, self.cache_cfg, (), np.zeros(0, np.int64), 0,
         ) if account else {}
-        # history pages the latent kernel walked (``_mla_page_fetches``)
+        # history pages the latent kernel walked (``_history_pages``), the
+        # K/V bytes of the pages the dense paged kernel walked, and the live
+        # tokens the last launch's rows attended over
         self.num_mla_page_fetches = 0
+        self.attn_page_bytes_read = 0
+        self.step_context_tokens = 0
+        self._page_bytes = self.cache_cfg.page_bytes(model_cfg)
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -1900,16 +1905,18 @@ class Engine:
             return {}
         return self.mixer.gauges(self.model_cfg, self._live_positions())
 
-    def _mla_page_fetches(self, plan, rung, draft_len, n_extra) -> int:
-        """History pages the latent kernel walks in this launch, from the
-        host's mirrors: over the live rows, the pages of a row's history
+    def _history_pages(self, plan, rung, pos, n_extra) -> int:
+        """History pages ONE paged layer's kernel walks in this launch, from
+        the host's mirrors: over the live rows, the pages of a row's history
         (``ceil(hist / page)``: one DMA each) times the row's query blocks
-        (each block walks the whole history again), times the latent
-        layers.  A prefill row is ``ceil(rem / block)`` blocks over its
-        ``start`` tokens; a live state row is one one-token block (latent
-        attention is refused with speculation) over its position, a page
-        longer every ``page`` steps of the fused tail.  Kernel time over
-        this count is the cost of a page fetched (PERF.md section 5)."""
+        (each block walks the whole history again).  A prefill row is
+        ``ceil(rem / block)`` blocks over its ``start`` tokens; a live state
+        row (``pos`` its position) is one one-token block over its position,
+        a page longer every ``page`` steps of the fused tail.  Times the
+        latent layers it is ``helix_mla_page_fetches_total`` (the latent
+        kernel's time over that count is the cost of a page fetched, PERF.md
+        section 5); times a page's K and V over the full layers,
+        ``helix_attn_page_bytes_read_total``."""
         from helix_tpu.ops.paged_kernel import query_block
 
         P = self.cache_cfg.page_size
@@ -1918,10 +1925,9 @@ class Engine:
             bq = query_block(rung)
             pages += sum(-(-r.start // P) * -(-r.rem // bq)
                          for r in plan.rows)
-        pos = self._live_positions(draft_len)
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
-        return pages * self.model_cfg.num_attn_layers
+        return pages
 
     @property
     def kv_pages_used(self) -> int:
@@ -5059,11 +5065,24 @@ class Engine:
             mixer_attrs = {
                 **self._note_mixer(plan if rows else None, draft_len, n_extra),
                 "attn_layers": self.model_cfg.num_attn_layers}
-        page_fetches = None
-        if self.model_cfg.is_mla:
-            page_fetches = self._mla_page_fetches(
-                plan, rung, draft_len, n_extra)
-            self.num_mla_page_fetches += page_fetches
+        walked = {}
+        if self.model_cfg.num_attn_layers:
+            pos = self._live_positions(draft_len)
+            pages = self._history_pages(
+                plan if rows else None, rung, pos, n_extra)
+            context = int(pos.sum()) + sum(
+                r.start + r.rem for r in (plan.rows if rows else ()))
+            if kind != "warmup":
+                self.step_context_tokens = context
+            if self.model_cfg.is_mla:
+                fetches = pages * self.model_cfg.num_attn_layers
+                self.num_mla_page_fetches += fetches
+                walked = {"mla_page_fetches": fetches}
+            else:
+                page_bytes = pages * self._page_bytes
+                self.attn_page_bytes_read += page_bytes
+                walked = {"attn_page_bytes": page_bytes}
+            walked["context_tokens"] = context
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
         joint_pass = int(rows > 0)
@@ -5085,8 +5104,7 @@ class Engine:
             **mixer_attrs,
             **({"held_experts": self.model_cfg.num_held_experts}
                if self.model_cfg.held_experts else {}),
-            **({"mla_page_fetches": page_fetches}
-               if page_fetches is not None else {}),
+            **walked,
         ):
             if self.first_launch_time is None:
                 self.first_launch_time = time.monotonic()

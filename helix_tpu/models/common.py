@@ -172,7 +172,7 @@ class ModelConfig:
     # ``o <- o * sigmoid(h W_g)``, a gate a head and value channel from the
     # layer's input, before the output projection
     attn_gate: bool = False
-    # --- sliding-window attention layers beside full ones (Laguna) ---
+    # --- sliding-window attention layers beside full ones (Laguna, Mellum) ---
     # a "window" layer is GQA attention whose query at position i sees keys
     # i - sliding_window < j <= i (the query's own among them): its whole
     # cache is the last ``sliding_window`` tokens' K and V, a fixed RING a
@@ -585,6 +585,8 @@ class ModelConfig:
             heads = family.pop("num_heads")
         if model_type == "nemotron_h":
             family = cls._nemotron_h_family(hf)
+        if model_type == "mellum":
+            family = cls._mellum_family(hf)
         rope_theta = family.pop("rope_theta", None) or hf.get(
             "rope_theta", 10000.0)
         rope_scaling = family.pop("rope_scaling", rope_scaling)
@@ -619,6 +621,36 @@ class ModelConfig:
             name=name,
         )
 
+    # a ``layer_types`` entry, as the kind of layer that serves it
+    ATTENTION_KINDS = {"full_attention": "attn", "sliding_attention": "window"}
+
+    @staticmethod
+    def _leading_dense(hf: dict, family: str) -> int:
+        """How many leading layers ``mlp_layer_types`` calls dense; every
+        layer after them has to be sparse."""
+        L = hf["num_hidden_layers"]
+        mlp = list(hf.get("mlp_layer_types") or ["sparse"] * L)
+        dense = 0
+        while dense < L and mlp[dense] == "dense":
+            dense += 1
+        if any(t != "sparse" for t in mlp[dense:]):
+            raise ValueError(
+                f"{family}: only leading dense layers then expert layers "
+                "(mlp_layer_types) are supported")
+        return dense
+
+    @staticmethod
+    def _rope_of_kind(hf: dict, kind: str, head_dim: int) -> tuple:
+        """``(theta, scaling, rotary width)`` of ``rope_parameters[kind]``:
+        scaling None for plain rope, the width 0 for the whole head."""
+        r = dict((hf.get("rope_parameters") or {}).get(kind) or {})
+        theta = float(r.pop("rope_theta", hf.get("rope_theta", 10000.0)))
+        width = int(head_dim * r.pop(
+            "partial_rotary_factor", hf.get("partial_rotary_factor", 1)))
+        plain = r.get("rope_type", "default") in ("default", None)
+        return theta, (None if plain else tuple(sorted(r.items()))), (
+            0 if width == head_dim else width)
+
     @staticmethod
     def _laguna_family(hf: dict) -> dict:
         """The fields a ``model_type: laguna`` config sets: sliding-window
@@ -630,16 +662,9 @@ class ModelConfig:
         then routed experts behind a sigmoid router renormalised over the
         chosen and scaled, and one shared expert."""
         L = hf["num_hidden_layers"]
-        kinds = {"full_attention": "attn", "sliding_attention": "window"}
+        kinds = ModelConfig.ATTENTION_KINDS
         types = tuple(kinds[t] for t in hf["layer_types"])
-        mlp = list(hf.get("mlp_layer_types") or ["sparse"] * L)
-        dense = 0
-        while dense < L and mlp[dense] == "dense":
-            dense += 1
-        if any(t != "sparse" for t in mlp[dense:]):
-            raise ValueError(
-                "laguna: only leading dense layers then expert layers "
-                "(mlp_layer_types) are supported")
+        dense = ModelConfig._leading_dense(hf, "laguna")
         if hf.get("moe_apply_router_weight_on_input"):
             raise ValueError(
                 "laguna: moe_apply_router_weight_on_input true is not "
@@ -659,17 +684,8 @@ class ModelConfig:
                 f"supported, not {by_kind}")
         head_dim = hf.get("head_dim") or (
             hf["hidden_size"] // hf["num_attention_heads"])
-        rope = hf.get("rope_parameters") or {}
-
-        def rope_of(kind):
-            r = dict(rope.get(kind) or {})
-            theta = float(r.pop("rope_theta", hf.get("rope_theta", 10000.0)))
-            width = int(head_dim * r.pop(
-                "partial_rotary_factor", hf.get("partial_rotary_factor", 1)))
-            plain = r.get("rope_type", "default") in ("default", None)
-            return theta, (None if plain else tuple(sorted(r.items()))), (
-                0 if width == head_dim else width)
-
+        rope_of = functools.partial(
+            ModelConfig._rope_of_kind, hf, head_dim=head_dim)
         theta, scaling, width = rope_of("full_attention")
         w_theta, w_scaling, w_width = rope_of("sliding_attention")
         fx = hf["moe_intermediate_size"]
@@ -709,6 +725,63 @@ class ModelConfig:
             family.update(held_experts=(lo, hi),
                           num_experts=hf["published_num_experts"])
         return family
+
+    @staticmethod
+    def _mellum_family(hf: dict) -> dict:
+        """The fields a ``model_type: mellum`` config sets: sliding-window
+        layers beside full ones (``layer_types``: the period may start on
+        either), ONE count of query heads for both kinds, rope over the
+        whole head in both at ``rope_parameters``' theta and scaling by kind
+        (YaRN on the full layers, plain on the sliding ones), every layer
+        after the leading dense ones sparse: a softmax router over all the
+        experts, the top-k by probability, renormalised over the chosen
+        (``norm_topk_prob``) with no scale, no shared expert.  No key names
+        a q/k norm, so there is none (``qk_norm`` is one field away);
+        ``max_window_layers`` is a key of the lineage that ``layer_types``
+        overrides.  What is not served is refused by name."""
+        def refuse(why):
+            raise ValueError(f"mellum: {why}")
+
+        kinds = ModelConfig.ATTENTION_KINDS
+        unknown = sorted(set(hf["layer_types"]) - set(kinds))
+        if unknown:
+            refuse(f"layer_types {unknown} are not supported: "
+                   f"{sorted(kinds)}")
+        types = tuple(kinds[t] for t in hf["layer_types"])
+        if len(types) != hf["num_hidden_layers"]:
+            refuse("layer_types does not name num_hidden_layers layers")
+        if "window" in types and not hf.get("use_sliding_window", True):
+            refuse("use_sliding_window false with sliding_attention layers "
+                   "in layer_types is not supported: which of the two holds "
+                   "is not stated")
+        if "window" in types and not hf.get("sliding_window"):
+            refuse("sliding_attention layers need sliding_window")
+        # a dict under rope_parameters is a kind of layer's rope
+        rope = hf.get("rope_parameters") or {}
+        stray = sorted(k for k, v in rope.items()
+                       if isinstance(v, dict) and k not in kinds)
+        if stray:
+            refuse(f"rope_parameters {stray} name a kind of layer that is "
+                   f"not served: {sorted(kinds)}")
+        head_dim = hf.get("head_dim") or (
+            hf["hidden_size"] // hf["num_attention_heads"])
+        theta, scaling, width = ModelConfig._rope_of_kind(
+            hf, "full_attention", head_dim)
+        w_theta, w_scaling, w_width = ModelConfig._rope_of_kind(
+            hf, "sliding_attention", head_dim)
+        return dict(
+            layer_types=types,
+            sliding_window=hf.get("sliding_window") or 0,
+            rope_theta=theta, rope_scaling=scaling, rotary_dim=width,
+            window_rope_theta=w_theta, window_rope_scaling=w_scaling,
+            window_rotary_dim=w_width,
+            num_experts=hf["num_experts"],
+            moe_intermediate_size=hf["moe_intermediate_size"],
+            first_k_dense=ModelConfig._leading_dense(hf, "mellum"),
+            moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+            moe_scoring="softmax",
+            expert_capacity_factor=0.0,
+        )
 
     # keys of a ``model_type: nemotron_h`` config that no layer of the served
     # stack reads, each with why
@@ -1125,9 +1198,48 @@ NEMOTRON3_SUPER_120B = ModelConfig(
     name="nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16",
 )
 
+# Mellum2-12B-A2.5B-Instruct (https://huggingface.co/JetBrains/
+# Mellum2-12B-A2.5B-Instruct/blob/main/config.json): seven periods of three
+# sliding-window layers (the last 1,024 tokens' K/V in a ring a slot in the
+# state pool: no pages) and one full-attention layer (pages), 32 query heads
+# over 4 kv heads of 128 in both kinds, the whole head rotated at theta
+# 500,000 in both, YaRN on the full layers alone; every layer 64 routed
+# experts of width 896 top-8 behind a softmax router renormalised over the
+# chosen, no shared expert, no dense layer (``intermediate_size`` is
+# published and read by no layer).  One chip holds it whole at int8; what
+# would move or share a ring is refused at engine start (the kind's record,
+# ``models/mixers.py``).
+MELLUM2_12B = ModelConfig(
+    vocab_size=98304,
+    hidden_size=2304,
+    num_layers=28,
+    num_heads=32,
+    num_kv_heads=4,
+    head_dim=128,
+    intermediate_size=7168,
+    rope_theta=500000.0,
+    rope_scaling=tuple(sorted({
+        "rope_type": "yarn", "factor": 16, "beta_fast": 32, "beta_slow": 1,
+        "original_max_position_embeddings": 8192,
+        "attention_factor": 1.2772588722239782,
+    }.items())),
+    rms_norm_eps=1e-6,
+    max_position_embeddings=131072,
+    num_experts=64,
+    num_experts_per_tok=8,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=896,
+    moe_renormalize=True,
+    moe_scoring="softmax",
+    layer_types=tuple("attn" if i % 4 == 3 else "window" for i in range(28)),
+    sliding_window=1024,
+    window_rope_theta=500000.0,
+    name="JetBrains/Mellum2-12B-A2.5B-Instruct",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
               DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B,
-              LAGUNA_XS2, NEMOTRON3_SUPER_120B)
+              LAGUNA_XS2, NEMOTRON3_SUPER_120B, MELLUM2_12B)
 }

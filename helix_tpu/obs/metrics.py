@@ -43,6 +43,8 @@ FAST_BUCKETS = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 )
+# tokens: a step of a few short rows to one of 64 rows at 8k each
+CONTEXT_BUCKETS = tuple(float(2 ** n) for n in range(8, 20))
 
 
 def validate_metric_name(name: str) -> str:
@@ -629,6 +631,15 @@ class EngineLoopObs:
             "decode window it travels through)",
             buckets=FAST_BUCKETS,
         )
+        # live tokens the rows of a step's last launch attended over (the
+        # decode rows' positions and a chunk's history and fresh tokens, from
+        # the host's mirrors): what the attention kernels' time follows
+        self.step_context_tokens = Histogram(
+            "helix_step_context_tokens",
+            "Live tokens a step's rows attend over (decode rows' positions "
+            "plus prefill rows' history and fresh tokens) per engine step",
+            buckets=CONTEXT_BUCKETS,
+        )
         self.http_first_write = Histogram(
             "helix_http_first_write_seconds",
             "First token's emission to the first SSE chunk written",
@@ -645,5 +656,6 @@ class EngineLoopObs:
             *self.threads_cpu.values(), self.gc_seconds,
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,
+            self.step_context_tokens,
         ):
             c.metric(m, labels)

@@ -1488,6 +1488,8 @@ class EngineLoop:
         obs = self.obs
         obs.step_seconds.observe(seconds)
         obs.exposed_host.observe(exposed)
+        obs.step_context_tokens.observe(
+            getattr(self.engine, "step_context_tokens", 0))
         for name, hist in (*obs.step_phases.items(),
                            *obs.state_phases.items()):
             hist.observe(ph.get(name, 0.0))
@@ -1554,6 +1556,7 @@ class EngineLoop:
             getattr(eng, "num_joint_pass_inert_rows", 0),
             getattr(eng, "num_wave_decode_tokens", 0),
             dict(getattr(eng, "mixer_counts", {})),
+            getattr(eng, "attn_page_bytes_read", 0),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1565,7 +1568,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0, wr0, mixer0) = pre
+         cs0, jp0, ji0, wr0, mixer0, pb0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1627,6 +1630,12 @@ class EngineLoop:
             "attn_layers": getattr(
                 eng.model_cfg, "num_attn_layers",
                 getattr(eng.model_cfg, "num_layers", 0)),
+            # K/V bytes of the pages the dense paged kernel walked in this
+            # step's programs, and the live tokens the rows of its last
+            # launch attended over (from the host's mirrors)
+            "attn_page_bytes_read": (
+                getattr(eng, "attn_page_bytes_read", 0) - pb0),
+            "context_tokens": getattr(eng, "step_context_tokens", 0),
             "prefill_tokens": prefill,
             "padding_tokens": (
                 getattr(eng, "num_prefill_padding_tokens", 0) - pad0

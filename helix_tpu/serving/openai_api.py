@@ -438,6 +438,15 @@ class OpenAIServer:
                     "helix_mla_page_fetches_total",
                     getattr(eng, "num_mla_page_fetches", 0), lbl,
                 )
+            elif getattr(eng.model_cfg, "num_attn_layers", 0):
+                # K/V bytes of the pages the dense paged kernel walked (live
+                # rows' pages x query blocks x a page over the full layers,
+                # from the host's mirrors): what a roofline by hand divides
+                # the kernel's time by
+                c.counter(
+                    "helix_attn_page_bytes_read_total",
+                    getattr(eng, "attn_page_bytes_read", 0), lbl,
+                )
             mixer = getattr(eng, "mixer", None)
             if mixer is not None:
                 if mixer.snapshots:

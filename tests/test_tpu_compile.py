@@ -631,3 +631,102 @@ def test_ungated_grouped_product_compiles_at_1024_by_2688(one_chip, rows):
         S((), jnp.int32)).compile()
     assert compiled.as_text().count("grouped_matmul_tpu") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
+
+
+# ---- Mellum2-12B-A2.5B: rings of 1,024 over 4 kv heads (ISSUE 48) -----------
+
+MELLUM_SLOTS, MELLUM_WINDOW, MELLUM_TABLE = 12, 1024, 544
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk_with_history",
+                                   "rows_on_one_axis"])
+def test_window_kernel_compiles_at_a_ring_of_1024_over_4_kv_heads(
+        one_chip, shape):
+    """The window call at 32 query / 4 kv heads of 128 (a query group of 8)
+    over rings of 1,024 in a pool of 21 layers and 12 slots: Laguna's bytes a
+    ring in another tiling (twice the rows, half the kv heads).  The pool's
+    ``(4, 128)`` bf16 minor pair is tiled ``T(4,128)(2,1)`` in HBM: the
+    counted bytes, no padded tile."""
+    import re
+
+    from helix_tpu.ops.window import window_attention
+
+    H, KVH, D, L = 32, 4, 128, 21
+    T, R, mq = {"decode": (MELLUM_SLOTS, MELLUM_SLOTS, 1),
+                "chunk_with_history": (PREFILL_LEN, 1, PREFILL_LEN),
+                "rows_on_one_axis": (PREFILL_LEN + 16, 8, PREFILL_LEN)}[shape]
+
+    def S(shp, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    ring = S((L, MELLUM_SLOTS, MELLUM_WINDOW, KVH, D), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: window_attention(
+        *a, backend="pallas", max_q_len=mq)).lower(
+        S((T, H, D), jnp.bfloat16), S((T, KVH, D), jnp.bfloat16),
+        S((T, KVH, D), jnp.bfloat16), ring, ring, S(()), S((R,)), S((R,)),
+        S((R,)), S((R,))).compile()
+    text = compiled.as_text()
+    assert "window_attention_tpu" in text
+    layouts = set(re.findall(
+        r"bf16\[21,12,1024,4,128\]\{[^}]*T\(([^}]*)\}", text))
+    assert layouts == {"4,128)(2,1)"}, layouts
+    mem = compiled.memory_analysis()
+    # the rings are read where they lie, at their counted bytes: two pools of
+    # 21 x 12 x 1,024 x 4 x 128 x 2 B among the arguments and no copy of one
+    pool = L * MELLUM_SLOTS * MELLUM_WINDOW * KVH * D * 2
+    assert 2 * pool <= mem.argument_size_in_bytes < 2 * pool + (1 << 23)
+    assert mem.temp_size_in_bytes < 1 << 24
+
+
+@pytest.mark.parametrize("shape", sorted(MAX_Q_LEN))
+def test_ragged_kernel_compiles_at_a_page_table_544_wide(one_chip, shape):
+    """Mellum's full layers: 32 query over 4 kv heads of 128, seven layers of
+    pages ``[16, 4, 128]`` and a page table 544 wide in SMEM (8,704 tokens a
+    row; 64 to 160 elsewhere), decode rows and a 512-token chunk row."""
+    from helix_tpu.ops.paged import ragged_paged_attention
+
+    H, KVH, D, L, pages = 32, 4, 128, 7, MELLUM_SLOTS * MELLUM_TABLE + 1
+    T, R = (MELLUM_SLOTS, MELLUM_SLOTS) if shape.startswith("decode") else (
+        PREFILL_LEN, 1)
+
+    def S(shp, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    pool = S((L, pages, PAGE, KVH, D), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: ragged_paged_attention(
+        *a, backend="pallas", max_q_len=MAX_Q_LEN[shape])).lower(
+        S((T, H, D), jnp.bfloat16), S((T, KVH, D), jnp.bfloat16),
+        S((T, KVH, D), jnp.bfloat16), pool, pool, S(()), S((R,)), S((R,)),
+        S((R,)), S((R, MELLUM_TABLE))).compile()
+    assert "ragged_paged_attention_tpu" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+
+
+@pytest.mark.parametrize("rows", [96, 4192], ids=["decode", "chunk"])
+def test_grouped_product_compiles_at_2304_by_896(one_chip, rows):
+    """All 64 experts of 2304 x 896, int8, the layer picked from a stack of
+    21: a decode step's 12 x 8 assignments (1.5 rows an expert) and a chunk
+    program's (512 + 12) x 8 (65 rows an expert)."""
+    from helix_tpu.ops.grouped_matmul import (
+        grouped_matmul_tpu, row_tile, visit_plan)
+
+    n, X, E, F = 21, 64, 2304, 896
+    tm = row_tile(rows, X)
+
+    def S(shp, dt):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    def op(x, wg, sg, wu, su, wd, sd, sizes, layer):
+        plan = visit_plan(sizes, rows, tm)
+        h = grouped_matmul_tpu(
+            x, wg, plan, layer, scale=sg, w2=wu, scale2=su,
+            act=jax.nn.silu, tm=tm, out_dtype=x.dtype)
+        return grouped_matmul_tpu(h, wd, plan, layer, scale=sd, tm=tm)
+
+    up = (S((n, X, E, F), jnp.int8), S((n, X, 1, F), jnp.float32))
+    down = (S((n, X, F, E), jnp.int8), S((n, X, 1, E), jnp.float32))
+    compiled = jax.jit(op).lower(
+        S((rows, E), jnp.bfloat16), *up, *up, *down, S((X,), jnp.int32),
+        S((), jnp.int32)).compile()
+    assert compiled.as_text().count("grouped_matmul_tpu") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < X * E * F

@@ -1,8 +1,9 @@
 """Compile whole engine steps at published widths for a DESCRIBED TPU v5e,
 without the chip: DeepSeek-V2-Lite (latent attention, experts), LFM2-8B-A1B
 (conv layers), Brumby-14B (power retention), GigaChat3.5 (the gated delta
-rule beside latent attention), Laguna-XS.2 (sliding-window layers) and
-Nemotron-3-Super (Mamba-2 layers, experts in a latent).
+rule beside latent attention), Laguna-XS.2 (sliding-window layers),
+Nemotron-3-Super (Mamba-2 layers, experts in a latent) and Mellum2-12B-A2.5B
+(rings of 1,024 over 4 kv heads, a page table 544 wide, 64 experts held).
 
 The rules of ``test_tpu_compile.py`` hold here (its docstring); the fixtures
 are ``tests/tpu_topology.py``'s.  Nothing runs, so these say nothing about
@@ -15,6 +16,7 @@ out first: by its few tests xdist would start it last.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from tpu_topology import one_chip, topo  # noqa: F401
@@ -473,3 +475,99 @@ def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
     import re
 
     assert not re.search(r"= f32\[1,64,64,128,128\]\S* copy\(", text)
+
+
+# benchmark/configs/mellum2-12b-a2.5b-int8.profile.yaml
+MELLUM_SLOTS, MELLUM_WINDOW, MELLUM_TABLE = 12, 1024, 544
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of Mellum2-12B-A2.5B cut to ONE period of four
+    layers (three sliding + experts, one full + experts; int8 weights, 12
+    slots, every size of the cell's profile: a page table 544 wide over 6,529
+    pages, 98,304 rows) for the described chip: the window kernel over rings
+    of ``[1024, 4, 128]`` in the carry, the dense ragged kernel at a query
+    group of 8 over a page pool of ONE layer, the grouped product over all 64
+    experts, both rings updated in place and laid out at their counted bytes
+    (``T(4,128)(2,1)``: no padded tile)."""
+    import dataclasses
+    import re
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import MELLUM2_12B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        MELLUM2_12B, num_layers=4, layer_types=MELLUM2_12B.layer_types[:4])
+    assert cfg.held_experts is None and cfg.num_held_experts == 64
+    B, max_pages = MELLUM_SLOTS, MELLUM_TABLE
+    pages = B * max_pages + 1
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == vs == (1, 16, 4, 128)
+    assert cc.state_shapes(cfg) == (
+        ((3, B, MELLUM_WINDOW, 4, 128), "bfloat16"),) * 2
+    cache = PagedKVCache(
+        k_pages=S((1, pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((1, pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows, hist = {"decode": (0, 0, False),
+                          "chunk_with_history": (512, 1, True)}[program]
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, hist, rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("window_attention_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention_tpu"):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    # a ring of 4 kv heads takes its counted bytes, in HBM as in the carry
+    tiles = set(re.findall(
+        r"bf16\[3,12,1024,4,128\]\{[^}]*T\(([0-9,]*)\)", text))
+    assert tiles == {"4,128"}, tiles
+    # the rings are updated in place: aliased whole, and no temporary of the
+    # size of ONE of the two
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+    weights = sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for a in jax.tree.leaves(params))
+    held = weights + cc.state_bytes(cfg) + pages * cc.page_bytes(cfg)
+    # the arguments are the weights, the rings, the pages and the decode
+    # state (its 12 x 98,304 token counts the most of it): nothing is padded
+    assert held <= mem.argument_size_in_bytes < held + (8 << 20)
