@@ -391,7 +391,55 @@ def kernel_window(seed, rehearse, rng, ks, heads=(48, 64), KVH=8,
     if not (ok and held):
         fail("an attention kernel of the window-and-full model disagrees "
              "with its reference, or agrees with a window of one key more")
+    _window_chunk_times(
+        (draw(ks[2], (S, H, D)), draw(ks[3], (S, KVH, D)),
+         draw(ks[4], (S, KVH, D)), k_ring, v_ring),
+        (W + W // 3, *((100, 700) if W < 1024 else ())), call, rehearse)
     return B, S
+
+
+def _window_chunk_times(held, histories, call, rehearse):
+    """DEVICE time a layer of the window kernel's chunk call alone: ONE row
+    of the held tokens over a ring with each of ``histories`` behind it, from
+    a capture of five calls a history (``tools/program_times.py`` over it: a
+    call's wall time is its dispatch's), with the products a query needs of
+    the keys IT sees (``min(history + its offset + 1, W)`` of them, scores and
+    values) and their share of the bf16 peak.  On a CPU there is no device to
+    time: the calls are walked and nothing is reported."""
+    S, H, D = held[0].shape
+    W = held[3].shape[2]
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)  # noqa: E731
+
+    def row(hist):
+        def fn(*a):
+            return call((*a, jnp.int32(1), i32(0), i32(S), i32(hist),
+                         i32(1)), S)
+        fn.__name__ = f"chunk_row_behind_{hist}"
+        return jax.jit(fn)
+
+    rows = {hist: row(hist) for hist in histories}
+
+    def run():
+        for _ in range(5):
+            out = [fn(*held) for fn in rows.values()]
+        return out
+
+    programs = _device_programs(run, "^window_attention_tpu", rehearse)
+    if programs is None:
+        return
+    from benchmark.lib.peaks import chip_peaks
+
+    peak = chip_peaks(jax.devices()[0].device_kind)["bf16_flops"]
+    for hist in histories:
+        p = programs[f"jit_chunk_row_behind_{hist}"]
+        ops = 4 * H * D * int(np.minimum(hist + np.arange(S) + 1, W).sum())
+        kernel_ms = sum(p["op_ms"].values())
+        say(phase="kernel", op="window_attention", timed="chunk_row",
+            heads=[H, held[1].shape[1], D, W], tokens=S, history=hist,
+            device_ms_a_layer=round(p["mean_ms"], 4),
+            kernel_alone_ms=round(kernel_ms, 4), useful_gflop=ops / 1e9,
+            share_of_the_bf16_peak=round(ops / peak / (kernel_ms * 1e-3), 4),
+            timed_on=jax.default_backend())
 
 
 def phase_kernel(spec, seed, rehearse):

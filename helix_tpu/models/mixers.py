@@ -634,8 +634,18 @@ def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     reads ``min(tokens behind it, W)`` ring rows of K and of V in every
     window layer and writes its fresh tokens' (at most ``W``); the bytes of
     live ring rows read are what a roofline reckoned from a trace divides
-    by, ``state_bytes_touched`` counts the ring rows written."""
+    by, ``state_bytes_touched`` counts the ring rows written.
+    ``query_blocks``: the programs the window kernel runs for the launch's
+    chunk rows, a row's ``ceil(tokens / block)`` in every window layer (the
+    block is the kernel's own, ``chunk_query_block``: 128 tokens at a query
+    group of 8, the same count under any bucket that holds the row); none
+    where no row has history: the packed flash kernel runs those."""
+    from helix_tpu.ops.window_kernel import chunk_query_block
+
     W = cfg.sliding_window
+    block = chunk_query_block(
+        -(-max((r.rem for r in rows), default=8) // 8) * 8,
+        cfg.heads_of("window") // cfg.num_kv_heads)
     per_tok = (2 * cfg.num_kv_heads * cfg.head_dim * jnp.dtype(
         cache_cfg.dtype).itemsize * cfg.num_state_layers)
     steps = 1 + int(n_extra)
@@ -645,6 +655,8 @@ def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     wrote += sum(min(r.rem, W) for r in rows if r.slot >= 0)
     return {
         "decode_rows": len(pos) * steps, "chunk_rows": len(rows),
+        "query_blocks": cfg.num_state_layers * any(
+            r.start > 0 for r in rows) * sum(-(-r.rem // block) for r in rows),
         "ring_bytes_read": read * per_tok,
         "state_bytes_touched": wrote * per_tok,
     }
@@ -825,6 +837,10 @@ STATE_MIXERS = {
                    "ring_bytes_read"),
             _POOL_BYTES,
             *_rows_series("helix_window_rows_total"),
+            # over kind="chunk" above x window layers: the window kernel's
+            # programs a chunk row (4 a 512-token row at a block of 128)
+            Series("helix_window_query_blocks_total", "counter",
+                   "query_blocks"),
             _BYTES_TOUCHED,
         ),
         launch=(("window_layers", "layers"),

@@ -25,7 +25,10 @@ whatever the sequence's length.
 - ``window_attention_tpu`` (``ops/window_kernel.py``) — the Pallas kernel:
   a row's ring comes in ONE contiguous DMA a pool (``[W, kv heads,
   head_dim]``: 1 MB at 512 x 8 x 128 in bf16), not page by page, and stays in
-  VMEM for every query block of its row.
+  VMEM for every query block of its row.  A decode row is a one-token block
+  that walks the ring; a chunk row is long blocks (128 tokens at a query
+  group of 8) that score one kv head at a time against the ``W + block`` keys
+  they can see, the row's keys laid out head-major and by position once.
 """
 
 from __future__ import annotations

@@ -6,26 +6,36 @@ history is not a table of 16-token pages but ONE ring ``[W, KVH, D]`` a pool
 
 - Row metadata (``t0`` / ``q_len`` / ``hist`` / ``slots``) and the layer index
   are scalar-prefetched; the grid walks the list of LIVE query blocks (a
-  decode row is one one-token block, a chunk row 8-token blocks), as the
-  ragged kernel's does.
+  decode row is one one-token block, a chunk row blocks of
+  ``chunk_query_block`` tokens: 128 at a query group of 8, what the bucket
+  holds under that), as the ragged kernel's does.
 - A row's ring comes HBM -> VMEM in ONE async DMA a pool (1 MB of K and 1 MB of
   V at 512 x 8 x 128 in bf16), into one of two slots: the ring of the NEXT
   block's row is started before this block computes (the grid is sequential,
   the scratch persists), and the blocks of one row share the ring their first
   block fetched: a 512-token chunk reads its ring once, not once a block.
-- A row with no history fetches nothing; a row shorter than the ring walks
-  only the ring's chunks it has written.
+- A row with no history fetches nothing.
 - The mask is by POSITION: ring row ``j`` of a sequence with ``hist`` tokens
   behind it holds position ``hist - 1 - ((hist - 1 - j) mod W)``; negative:
   not this sequence's (a ring is never cleared); more than ``W - 1`` behind
   the query: out of its window.  Fresh tokens are attended raw under the
   causal and the window mask; persisting them is the caller's
   (``ops.window.write_ring``, after the call).
-- Scores for all heads of a query block come from one dot of the
-  block-diagonal query ``[BQ * H, KVH * D]`` against the chunk viewed flat
-  ``[tokens, KVH * D]`` (the ragged kernel's layout), in the INPUTS' dtype
-  with float32 accumulation: bf16 products are exact in float32, and the
-  probabilities meet V in V's dtype as in ``ops/attention.py``'s flash kernel.
+- A DECODE call (one-token blocks; q, the fresh rows and the output resident
+  in VMEM) walks the ring's chunks a row has written, and scores all heads of
+  a block in one dot of the block-diagonal query ``[H, KVH * D]`` against the
+  chunk viewed flat ``[tokens, KVH * D]`` (the ragged kernel's layout) under
+  an online softmax: it is bound by the ring's bytes, not by its products.
+- A CHUNK call's block is long and scores ONE KV HEAD AT A TIME
+  (``_chunk_block``): the row's first block lays the ring and the row's fresh
+  keys out head-major and by position, once a row; every block then takes
+  the ``W + BQ`` keys its queries can see as one window of that layout, a
+  kv head's queries ``[BQ x group, D]`` against that head's keys ``[W + BQ,
+  D]`` in one dense product and a plain softmax.  No block-diagonal query is
+  built and no key outside the block's window is read.
+- Products run in the INPUTS' dtype with float32 accumulation: bf16 products
+  are exact in float32, and the probabilities meet V in V's dtype as in
+  ``ops/attention.py``'s flash kernel.
 """
 
 from __future__ import annotations
@@ -40,11 +50,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
-from helix_tpu.ops.paged_kernel import (
-    check_geometry,
-    live_query_blocks,
-    query_block,
-)
+from helix_tpu.ops.paged_kernel import check_geometry, live_query_blocks
+
+
+# query rows a kv head and product the chunk form aims at (``BQ x group``):
+# a block of keys is the MXU's stationary operand for that many rows
+CHUNK_QUERY_ROWS = 1024
+# rows a step when a row's ring and fresh keys are laid out head-major
+_RELAY = 128
+
+
+def chunk_query_block(max_q_len: int, group: int) -> int:
+    """Tokens in a chunk row's query block, from the static bound on a row's
+    fresh tokens (the bucket) and the query heads a kv head: about
+    ``CHUNK_QUERY_ROWS`` query rows a product (128 tokens at a group of 8),
+    never more than the bucket holds, a multiple of 8.  A row of ``n`` tokens
+    makes ``ceil(n / block)`` blocks, and as many under any bucket that holds
+    it (a bucket under the cap is one block)."""
+    cap = max(8, CHUNK_QUERY_ROWS // (-(-group // 8) * 8) // 8 * 8)
+    return max(8, min(cap, max_q_len // 8 * 8))
 
 
 def _window_kernel(
@@ -58,9 +82,9 @@ def _window_kernel(
     layer_ref,   # SMEM [1] int32 layer index
     # inputs / outputs / scratch, in this order:
     #   qf, knf, vnf, k_hbm, v_hbm | of | kbuf, vbuf, sems, cur
-    #   [, qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem]
+    #   [, qbuf, obuf, kst, vst, kall, vall, fsems, qsem, osem]
     # A one-token block (``bq`` 1: a decode call) finds qf/knf/vnf/of whole
-    # in VMEM; the 8-token block streams its own through the last group.
+    # in VMEM; a chunk call's block streams its own through the last group.
     *refs,
     scale: float,
     window: int,
@@ -75,19 +99,29 @@ def _window_kernel(
     qf, knf, vnf, k_hbm, v_hbm = refs[:5]
     of, kbuf, vbuf, sems, cur_ref, *rest = refs[5:]
     if not resident:
-        qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem = rest
+        qbuf, obuf, kst, vst, kall, vall, fsems, qsem, osem = rest
     b = pl.program_id(0)
     NB = brow_ref.shape[0]
     r = brow_ref[b]
     lyr = layer_ref[0]
 
     def ring_dma(row, slot, go: bool):
-        """Start (``go``) or wait for ``row``'s rings in ``slot``."""
+        """Start (``go``) or wait for ``row``'s rings in ``slot``: the ring
+        whole, and for a chunk call its first rows once more behind it, so
+        that a run of them from any row on is contiguous."""
         at = slots_ref[row]
         for j, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
-            cp = pltpu.make_async_copy(
-                pool.at[lyr, at], buf.at[slot], sems.at[slot, j])
-            cp.start() if go else cp.wait()
+            again = buf.shape[1] - W
+            cps = [pltpu.make_async_copy(
+                pool.at[lyr, at],
+                buf.at[slot, pl.ds(0, W)] if again else buf.at[slot],
+                sems.at[slot, j])]
+            if again:
+                cps.append(pltpu.make_async_copy(
+                    pool.at[lyr, at, pl.ds(0, again)],
+                    buf.at[slot, pl.ds(W, again)], sems.at[slot, j]))
+            for cp in cps:
+                cp.start() if go else cp.wait()
 
     # The ring of a row's first block is in flight before its program starts:
     # the block before it (of another row) issued it, into the slot that
@@ -95,6 +129,12 @@ def _window_kernel(
     @pl.when(b == 0)
     def _():
         cur_ref[0] = 0
+        if not resident:
+            # past a row's fresh keys a block's window finds zeros, for good
+            end = W + kst.shape[0]
+            for buf in (kall, vall):
+                buf[:, pl.ds(end, buf.shape[1] - end)] = jnp.zeros(
+                    (KVH, buf.shape[1] - end, buf.shape[2]), buf.dtype)
 
         @pl.when((r >= 0) & (hist_ref[jnp.maximum(r, 0)] > 0))
         def _():
@@ -107,20 +147,20 @@ def _window_kernel(
         hist_r = hist_ref[r]
         base = t0_ref[r] + i * BQ
 
-        def fresh_dma(j, go: bool):
-            # block j of the row's fresh keys and values, KB tokens
-            for n, (src, dst) in enumerate(((knf, knbuf), (vnf, vnbuf))):
-                cp = pltpu.make_async_copy(
-                    src.at[pl.ds(t0_ref[r] + j * KB, KB)], dst, fsems.at[n]
-                )
-                cp.start() if go else cp.wait()
-
         if not resident:
-            qcp = pltpu.make_async_copy(
-                qf.at[pl.ds(base, BQ)], qbuf, qsem
-            )
+            qcp = pltpu.make_async_copy(qf.at[pl.ds(base, BQ)], qbuf, qsem)
             qcp.start()
-            fresh_dma(0, True)      # lands behind the ring's walk
+            # the row's fresh keys and values, the whole bucket of them at
+            # its first block: they land behind the ring's laying out
+            fresh = [
+                pltpu.make_async_copy(
+                    src.at[pl.ds(t0_ref[r], dst.shape[0])], dst, fsems.at[n])
+                for n, (src, dst) in enumerate(((knf, kst), (vnf, vst)))]
+
+            @pl.when(i == 0)
+            def _():
+                for cp in fresh:
+                    cp.start()
 
         slot = cur_ref[0]
         nxt = brow_ref[jnp.minimum(b + 1, NB - 1)]
@@ -138,11 +178,17 @@ def _window_kernel(
         def _():
             ring_dma(r, slot, False)
 
-        if resident:
-            q = qf[pl.ds(base, BQ)]
-        else:
-            qcp.wait()
-            q = qbuf[...]
+        if not resident:
+            _chunk_block(
+                i, qlen_r, hist_r, kbuf.at[slot], vbuf.at[slot], qbuf, obuf,
+                kst, vst, kall, vall, scale=scale, window=W, kv_heads=KVH,
+                group=group, bq=BQ, fresh=fresh, q_copy=qcp)
+            ocp = pltpu.make_async_copy(obuf, of.at[pl.ds(base, BQ)], osem)
+            ocp.start()
+            ocp.wait()
+            return
+
+        q = qf[pl.ds(base, BQ)]
         dot_dtype = q.dtype
         # (sliced and reshaped in float32, whose (8, 128) tile a group of 8
         # fills; the dots run in the inputs' dtype)
@@ -221,18 +267,9 @@ def _window_kernel(
 
         # ---- fresh tokens of this row, KB keys a step -----------------
         def fresh_body(j, carry):
-            if resident:
-                src = pl.ds(t0_ref[r] + j * KB, KB)
-                kf, vf = knf[src], vnf[src]
-            else:
-                @pl.when(j > 0)
-                def _():
-                    fresh_dma(j, True)
-
-                fresh_dma(j, False)
-                kf, vf = knbuf[...], vnbuf[...]
-            kf = kf.reshape(KB, KVH * D)
-            vf = vf.reshape(KB, KVH * D)
+            src = pl.ds(t0_ref[r] + j * KB, KB)
+            kf = knf[src].reshape(KB, KVH * D)
+            vf = vnf[src].reshape(KB, KVH * D)
             kv_off = j * KB + jax.lax.broadcasted_iota(
                 jnp.int32, (1, KB), 1
             )                                   # [1, KB]
@@ -260,20 +297,119 @@ def _window_kernel(
         # length) have l == 0; guard the divide so garbage stays finite
         out = acc / jnp.where(l > 0, l, 1.0)    # [RQ, KVH*D]
         for k in range(KVH):                    # extract each head block
-            head = out[
+            of[pl.ds(base, BQ), k] = out[
                 k * BQ * group:(k + 1) * BQ * group,
                 k * D:(k + 1) * D,
             ].reshape(BQ, group, D).astype(of.dtype)
-            if resident:
-                of[pl.ds(base, BQ), k] = head
-            else:
-                obuf[:, k] = head
-        if not resident:
-            ocp = pltpu.make_async_copy(
-                obuf, of.at[pl.ds(base, BQ)], osem
-            )
-            ocp.start()
-            ocp.wait()
+
+
+def _chunk_block(
+    i, qlen_r, hist_r,
+    kring, vring,  # VMEM [W + again, KVH, D] the row's rings as the pool holds
+                   # them, their first rows once more behind them
+    qbuf,          # VMEM [BQ, KVH, G, D] the block's queries (in flight)
+    obuf,          # VMEM [BQ, KVH, G, D] the block's output
+    kst, vst,      # VMEM [F, KVH, D] the row's fresh K/V as they lie in HBM
+    kall, vall,    # VMEM [KVH, W + F + pad, D] the row's keys by POSITION
+    *, scale, window, kv_heads, group, bq, fresh, q_copy,
+):
+    """One long query block of a chunk row: each kv head's queries ``[BQ x
+    group, D]`` meet that head's keys alone, a head at a time, in ONE product
+    over the keys the block can see.
+
+    The row's FIRST block lays its keys out head-major and BY POSITION
+    (``[tokens, KVH, D] -> [KVH, tokens, D]``: the reshape every 8-token
+    block used to make of every chunk, once a row): ``kall[k, m]`` is the key
+    at position ``hist - W + m``, the ring's ``W`` rows from row ``hist mod
+    W`` on (the oldest first; what the sequence has not written lands on
+    negative positions) and the fresh keys behind them.  Query ``o`` of the
+    row sees ``o < m <= o + W``, so a block's queries see the ``W + BQ`` keys
+    from ``m = i BQ`` on: one window of static width at an offset, whatever
+    the ring holds.  Its scores are one product, the softmax is plain (no
+    running maximum to carry), and nothing outside the window is read."""
+    W, KVH, G, BQ = window, kv_heads, group, bq
+    D = qbuf.shape[-1]
+    F = kst.shape[0]
+    RQ = BQ * G
+    CW = kall.shape[1] - F          # the window's width: W + BQ and a pad
+    dot_dtype = qbuf.dtype
+    prec = (jax.lax.Precision.DEFAULT if dot_dtype == jnp.bfloat16
+            else None)
+
+    def lay_out(src, row0, dst, at0, rows: int, keep=None):
+        """``src[row0 : row0 + rows]`` ``[rows, KVH, D]`` to ``dst[:, at0 :
+        at0 + rows]``; of the rows at and past ``keep`` zeros."""
+        flat = src[pl.ds(row0, rows)].reshape(rows, KVH * D)
+        if keep is not None:
+            flat = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < keep,
+                flat, 0)
+        for k in range(KVH):
+            dst[k, pl.ds(at0, rows)] = flat[:, k * D:(k + 1) * D]
+
+    @pl.when(i == 0)
+    def _():
+        RL = kring.shape[0] - W     # (the rows that lie behind it again)
+
+        @pl.when(hist_r > 0)
+        def _():
+            first = jax.lax.rem(hist_r, W)
+            for n in range(W // RL):
+                row0 = jax.lax.rem(first + n * RL, W)
+                lay_out(kring, row0, kall, n * RL, RL)
+                lay_out(vring, row0, vall, n * RL, RL)
+
+        # no ring was fetched: whatever lies there is masked by position,
+        # and weighs 0 only while it is finite
+        @pl.when(hist_r == 0)
+        def _():
+            vall[:, pl.ds(0, W)] = jnp.zeros((KVH, W, D), vall.dtype)
+
+        for cp in fresh:
+            cp.wait()
+        FL = min(F, _RELAY)
+        for n in range(F // FL):
+            lay_out(kst, n * FL, kall, W + n * FL, FL)
+            # past the row lie the NEXT row's fresh tokens (or flat padding),
+            # which may be NaN: zero V out-of-row (the ragged kernel's guard)
+            lay_out(vst, n * FL, vall, W + n * FL, FL, keep=qlen_r - n * FL)
+
+    # key m of the block's window (``m0 + c``) against query ``o`` (``m0 +
+    # u``): inside the window by ``0 < c - u <= W``, a key at all by ``W -
+    # hist <= m < W + q_len``
+    m0 = pl.multiple_of(i * BQ, 8)
+    c = jax.lax.broadcasted_iota(jnp.int32, (1, CW), 1)
+    u = jax.lax.broadcasted_iota(jnp.int32, (RQ, 1), 0) // G
+    is_key = (m0 + c >= W - hist_r) & (m0 + c < W + qlen_r)      # [1, CW]
+    q_copy.wait()
+
+    def head(k, _):
+        # (reshaped in float32, whose (8, 128) tile a group of 8 fills; the
+        # dots run in the inputs' dtype)
+        q = qbuf[:, k].astype(jnp.float32).reshape(RQ, D).astype(dot_dtype)
+        s = jax.lax.dot_general(
+            q, kall[k, pl.ds(m0, CW)], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        ) * scale                                           # [RQ, CW]
+        ok = is_key & (c - u > 0) & (c - u <= W)
+        # a fully masked row's exp(0) weighs the values by 1: it is divided
+        # out only while they are finite, and a pool is zeros or a
+        # sequence's finite values
+        s = jnp.where(ok, s, DEFAULT_MASK_VALUE)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        v = vall[k, pl.ds(m0, CW)]
+        acc = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        )
+        # (l >= 1, the row's maximum counts 1: a row with no key at all,
+        # block-tail padding past the row, stays finite)
+        out = acc / l                                       # [RQ, D]
+        obuf[:, k] = out.reshape(BQ, G, D).astype(obuf.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, KVH, head, 0)
 
 
 @functools.partial(
@@ -296,23 +432,35 @@ def window_attention_tpu(
 ):
     """Returns ``out [T, H, D]``: ``ops.window.window_attention``'s contract
     (the window is the ring's length).  Rows may start at any offset; the
-    flat axis is padded internally.  ``max_q_len`` picks the query block as
-    in the ragged kernel: 1 token for a decode call, else 8."""
+    flat axis is padded internally.  ``max_q_len``, a static bound on any
+    row's fresh tokens (default: T), picks the query block: 1 token for a
+    decode call, else ``chunk_query_block``'s long one."""
     T, H, D = q.shape
     L, nslots, W, KVH, _ = k_ring.shape
     if not interpret:
         check_geometry(H, KVH, D, k_ring.dtype.itemsize)
     group = H // KVH
-    BQ = query_block(T if max_q_len is None else min(max_q_len, T))
-    G = group if BQ == 8 and group in (1, 2, 4) else -(-group // 8) * 8
+    MQ = T if max_q_len is None else min(max_q_len, T)
+    G = -(-group // 8) * 8
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    # ring rows a step of the walk: the ring is in VMEM whole, the step
-    # bounds the scores' width
-    CT = 256 if BQ == 1 else 128
-    if W <= CT or W % CT:
-        CT = W
-    KB = 8 if BQ == 1 else 128
-    Tpad = -(-(T + KB + BQ) // 8) * 8
+    if MQ == 1:
+        # a decode call: ring rows a step of the walk (the ring is in VMEM
+        # whole, the step bounds the scores' width), fresh keys a step
+        BQ, KB, F, again = 1, 8, 8, 0
+        CT = 256
+        if W <= CT or W % CT:
+            CT = W
+    else:
+        BQ, CT, KB = chunk_query_block(MQ, group), 0, 0   # (decode's steps)
+        # the fresh keys a row's first block brings in: the bucket's
+        F = -(-MQ // _RELAY) * _RELAY if MQ > _RELAY else -(-MQ // 8) * 8
+        # the keys a block's queries can see, to the lanes' tile
+        CW = -(-(W + BQ) // 128) * 128
+        # ring rows a step of the laying out, which lie behind the ring again
+        again = _RELAY if W % _RELAY == 0 else W
+    # a row's last block, and its fresh keys' copy, run on past its end onto
+    # the next rows' tokens (which their own blocks then write) or this pad
+    Tpad = -(-(T + F + BQ) // 8) * 8
     tail = ((0, Tpad - T), (0, 0), (0, 0))
     k_new, v_new = jnp.pad(k_new, tail), jnp.pad(v_new, tail)
     qg = jnp.pad(
@@ -336,32 +484,44 @@ def window_attention_tpu(
     def whole(shape):
         return pl.BlockSpec(shape, lambda b, *_: (0,) * len(shape))
 
-    # two slots of two rings, and the block's q, scores and accumulators
-    ring_bytes = 4 * W * KVH * max(D, 128) * k_ring.dtype.itemsize
-    if BQ == 1:
-        q_spec = out_spec = whole((Tpad, KVH, G, D))
-        new_spec = whole((Tpad, KVH, D))
-        held = 8 * Tpad * KVH * 16 * D * q.dtype.itemsize
-    else:
-        q_spec = out_spec = new_spec = any_spec
-        held = 6 * BQ * KVH * G * KVH * D * 4
-    vmem_limit = min(max(16 << 20, ring_bytes + held + (8 << 20)), 100 << 20)
+    held = []
+
+    def vmem(shape, dtype):
+        """A VMEM scratch buffer and its bytes there: the minor pair padded
+        to the dtype's tile (a ring of 4 kv heads in bf16 is a quarter of a
+        (16, 128) one)."""
+        size = jnp.dtype(dtype).itemsize
+        sub = 8 * (4 // size)
+        held.append(math.prod(shape[:-2]) * -(-shape[-2] // sub) * sub
+                    * -(-shape[-1] // 128) * 128 * size)
+        return pltpu.VMEM(shape, dtype)
+
     scratch = [
-        pltpu.VMEM((2, W, KVH, D), k_ring.dtype),           # kbuf
-        pltpu.VMEM((2, W, KVH, D), v_ring.dtype),           # vbuf
+        vmem((2, W + again, KVH, D), k_ring.dtype),         # kbuf
+        vmem((2, W + again, KVH, D), v_ring.dtype),         # vbuf
         pltpu.SemaphoreType.DMA((2, 2)),                    # sems
         pltpu.SMEM((1,), jnp.int32),                        # cur
     ]
-    if BQ != 1:
+    if BQ == 1:
+        q_spec = out_spec = whole((Tpad, KVH, G, D))
+        new_spec = whole((Tpad, KVH, D))
+        held.append(8 * Tpad * KVH * 16 * D * q.dtype.itemsize)
+    else:
+        q_spec = out_spec = new_spec = any_spec
         scratch += [
-            pltpu.VMEM((BQ, KVH, G, D), q.dtype),           # qbuf
-            pltpu.VMEM((KB, KVH, D), k_new.dtype),          # knbuf
-            pltpu.VMEM((KB, KVH, D), v_new.dtype),          # vnbuf
-            pltpu.VMEM((BQ, KVH, G, D), q.dtype),           # obuf
+            vmem((BQ, KVH, G, D), q.dtype),                 # qbuf
+            vmem((BQ, KVH, G, D), q.dtype),                 # obuf
+            vmem((F, KVH, D), k_new.dtype),                 # kst
+            vmem((F, KVH, D), v_new.dtype),                 # vst
+            vmem((KVH, F + CW, D), k_ring.dtype),           # kall
+            vmem((KVH, F + CW, D), v_ring.dtype),           # vall
             pltpu.SemaphoreType.DMA((2,)),                  # fsems
             pltpu.SemaphoreType.DMA(()),                    # qsem
             pltpu.SemaphoreType.DMA(()),                    # osem
         ]
+        # one head's q and output, its scores, mask and probabilities
+        held.append(BQ * G * (4 * CW + 4 * D) * 4)
+    vmem_limit = min(max(16 << 20, sum(held) + (8 << 20)), 100 << 20)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=brow.shape,

@@ -78,6 +78,8 @@ def _now() -> int:
     return int(time.time())
 
 
+# seconds a profiler capture's answer may stay silent before it is streamed
+_PROFILER_QUIET_S = 45.0
 _LONGPOLL_POOL = None
 
 
@@ -1192,16 +1194,43 @@ class OpenAIServer:
         except Exception:   # submission failed: the thread never runs
             self._profiler_lock.release()
             raise
+        # A long capture of a busy server takes minutes to WRITE (three
+        # seconds of Laguna-XS.2's steps: 110-130 s), and a client that
+        # reads nothing for two minutes gives the connection up.  Past
+        # ``_PROFILER_QUIET_S`` the answer is therefore streamed: the
+        # headers now, a space every few seconds (JSON allows it before
+        # the value), the same body at the end.  A capture that is done by
+        # then answers as it always has, its failure a 501.
+        stream = None
         try:
-            d, stamps = await fut
+            while True:
+                try:
+                    d, stamps = await asyncio.wait_for(
+                        asyncio.shield(fut),
+                        _PROFILER_QUIET_S if stream is None else 5.0)
+                    break
+                except asyncio.TimeoutError:
+                    if stream is None:
+                        stream = web.StreamResponse(
+                            headers={"Content-Type": "application/json"})
+                        await stream.prepare(request)
+                    await stream.write(b" ")
         except asyncio.CancelledError:
             raise   # capture thread finishes + releases on its own
         except Exception as e:  # noqa: BLE001 — profiler not available
-            return _error(501, f"jax profiler capture failed: {e}")
-        return web.json_response({
-            "log_dir": d, "seconds": seconds,
-            "python_tracer": python_tracer, "clock_ns": stamps,
-        })
+            if stream is None:
+                return _error(501, f"jax profiler capture failed: {e}")
+            await stream.write(json.dumps({"error": {
+                "message": f"jax profiler capture failed: {e}"}}).encode())
+            await stream.write_eof()
+            return stream
+        body = {"log_dir": d, "seconds": seconds,
+                "python_tracer": python_tracer, "clock_ns": stamps}
+        if stream is None:
+            return web.json_response(body)
+        await stream.write(json.dumps(body).encode())
+        await stream.write_eof()
+        return stream
 
     def _residency_manager(self):
         """The ResidencyManager behind the registry, if hot-swap is on."""

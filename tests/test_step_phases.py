@@ -566,6 +566,27 @@ def test_python_tracer_field_must_be_a_boolean(spine):
     assert r.status_code == 400
 
 
+def test_a_capture_that_is_long_in_the_writing_streams_its_answer(
+        spine, monkeypatch, tmp_path):
+    """Past ``_PROFILER_QUIET_S`` the answer's headers go out and a space
+    every few seconds keeps the connection from idling out (three seconds of
+    a busy 40-layer server take two minutes to write); the body is the same
+    JSON behind the spaces."""
+    from helix_tpu.serving import openai_api
+
+    monkeypatch.setattr(openai_api, "_PROFILER_QUIET_S", 0.05)
+    monkeypatch.setenv("HELIX_PROFILER_DIR", str(tmp_path))
+    r = requests.post(f"{spine['url']}/admin/profiler",
+                      json={"seconds": 0.3}, timeout=300)
+    assert r.status_code == 200, r.text
+    assert r.text.startswith(" ") and r.headers["Content-Type"].startswith(
+        "application/json")
+    body = r.json()
+    assert body["seconds"] == 0.3 and len(body["clock_ns"]) == 2
+    assert glob.glob(os.path.join(body["log_dir"], "**", "*.xplane.pb"),
+                     recursive=True)
+
+
 # ---- (e) request stages ---------------------------------------------------
 
 

@@ -474,8 +474,8 @@ LAGUNA_SLOTS, LAGUNA_WINDOW = 48, 512
 def test_window_kernel_compiles_at_the_published_geometry(one_chip, shape):
     """The window call at 64 query / 8 kv heads of 128 over rings of 512 in
     a pool of 30 layers and 48 slots: 48 one-token rows, a 512-token chunk
-    row (8-token blocks that share the ring their first block fetched), and
-    eight rows on one axis."""
+    row (four 128-token blocks that share the ring their first block fetched
+    and laid out a kv head at a time), and eight rows on one axis."""
     from helix_tpu.ops.window import window_attention
 
     H, KVH, D, L = 64, 8, 128, 30

@@ -312,6 +312,7 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk_with_history",
+                                     "chunk_of_64_with_history",
                                      "packed_wave"])
 def test_window_step_compiles_at_published_widths(one_chip, program):
     """A whole engine step of Laguna-XS.2 cut to ONE period of four layers
@@ -321,6 +322,8 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     windowed flash attention of a cold wave, the grouped product over 32 of
     256 experts, and both rings updated in place."""
     import dataclasses
+
+    import re
 
     from helix_tpu.engine import engine as E
     from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
@@ -365,8 +368,11 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
         mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
         token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
         sampling=sampling(B))
+    # (the window kernel's long block follows the bucket: 64 tokens a
+    # block in both chunk programs, one block a row in the smaller)
     bucket, rows, hist = {"decode": (0, 0, False),
                           "chunk_with_history": (512, 1, True),
+                          "chunk_of_64_with_history": (64, 1, True),
                           "packed_wave": (512, 32, False)}[program]
     pargs = () if not bucket else (
         *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
@@ -387,6 +393,10 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+    # ... no copy of a ring pool (in any layout) around the kernel or the
+    # loop, and nothing computed again
+    assert not re.search(r"= bf16\[3,48,512,8,128\]\S* copy\(", text)
+    assert ".remat" not in text
 
 
 @pytest.mark.parametrize("bucket", [128, 512])
@@ -481,7 +491,8 @@ def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
 MELLUM_SLOTS, MELLUM_WINDOW, MELLUM_TABLE = 12, 1024, 544
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history",
+                                     "chunk_of_64_with_history"])
 def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
     """A whole engine step of Mellum2-12B-A2.5B cut to ONE period of four
     layers (three sliding + experts, one full + experts; int8 weights, 12
@@ -539,7 +550,8 @@ def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
         token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
         sampling=sampling(B))
     bucket, rows, hist = {"decode": (0, 0, False),
-                          "chunk_with_history": (512, 1, True)}[program]
+                          "chunk_with_history": (512, 1, True),
+                          "chunk_of_64_with_history": (64, 1, True)}[program]
     pargs = () if not bucket else (
         *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
         S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
@@ -564,6 +576,10 @@ def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg) // 2
+    # ... no copy of a ring pool (in any layout) around the kernel or the
+    # loop, and nothing computed again
+    assert not re.search(r"= bf16\[3,12,1024,4,128\]\S* copy\(", text)
+    assert ".remat" not in text
     weights = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize
         for a in jax.tree.leaves(params))

@@ -37,6 +37,8 @@ from helix_tpu.ops.window import (  # noqa: E402
     ring_positions, window_attention_reference, write_ring,
 )
 from helix_tpu.ops.window_kernel import window_attention_tpu  # noqa: E402
+import window_cases  # noqa: E402
+from window_cases import ROWS  # noqa: E402
 
 FULL, SLIDE = "full_attention", "sliding_attention"
 W = 8
@@ -123,88 +125,40 @@ def _rel(got, want):
 
 # ---- the window call and the windowed flash form -----------------------------
 
-# rows of (fresh tokens, tokens behind, slot): from an empty ring, across a
-# chunk boundary, after the ring has wrapped, a row shorter than the window
-# beside one past it, a row longer than the window, an unused row
-ROWS = {
-    "decode_rows_on_both_sides_of_the_wrap": (
-        8, 8, [(1, 0, 0), (1, 3, 1), (1, 8, 2), (1, 29, 3)], 1),
-    "chunks_from_empty_across_a_boundary_and_wrapped": (
-        16, 16, [(5, 0, 0), (12, 3, 1), (16, 16, 2), (9, 40, 3), (0, 0, 4)],
-        None),
-    "a_row_longer_than_the_window_at_a_group_of_6": (
-        8, 12, [(20, 5, 0), (3, 0, 1)], None),
-}
-
-
-def _window_case(window, H, rows, seed=0, KVH=2, D=128, L=2, layer=1,
-                 nslots=5):
-    """Rings holding each row's last ``window`` tokens where it has them and
-    ANOTHER sequence's loud values everywhere else, and every row's whole
-    history for the plain oracle."""
-    rng = np.random.default_rng(seed)
-    T = sum(r[0] for r in rows) + 3
-    q, kn, vn = (jnp.asarray(rng.standard_normal((T, h, D)), jnp.float32)
-                 for h in (H, KVH, KVH))
-    hk, hv = ({s: rng.standard_normal((h, KVH, D)).astype(np.float32)
-               for _, h, s in rows} for _ in range(2))
-    kr, vr = (rng.standard_normal((L, nslots, window, KVH, D)).astype(
-        np.float32) * 5 for _ in range(2))
-    for _, h, s in rows:
-        for p in range(h):
-            kr[layer, s, p % window] = hk[s][p]
-            vr[layer, s, p % window] = hv[s][p]
-    t0 = np.cumsum([0] + [r[0] for r in rows[:-1]])
-    meta = [jnp.asarray(x, jnp.int32) for x in (
-        t0, [r[0] for r in rows], [r[1] for r in rows],
-        [r[2] for r in rows])]
-    return (q, kn, vn, jnp.asarray(kr), jnp.asarray(vr), layer, *meta), (
-        hk, hv, t0)
-
-
-def _plain(args, hist, rows, window):
-    """Whole-sequence attention under the explicit causal and window masks,
-    a row at a time: ``{row: out}``."""
-    q, kn, vn = args[:3]
-    hk, hv, t0 = hist
-    out = {}
-    for (n, h, s), a in zip(rows, t0):
-        if n:
-            out[a] = mha_reference(
-                q[a:a + n][None],
-                jnp.concatenate([jnp.asarray(hk[s]), kn[a:a + n]])[None],
-                jnp.concatenate([jnp.asarray(hv[s]), vn[a:a + n]])[None],
-                causal=True, q_positions=jnp.arange(h, h + n)[None],
-                kv_positions=jnp.arange(h + n)[None], window=window)[0]
-    return out
-
-
 @pytest.mark.parametrize("name", sorted(ROWS))
 @pytest.mark.parametrize("form", ["reference", "kernel_in_interpret_mode"])
 def test_window_call_against_the_masked_reference(name, form):
-    window, H, rows, mq = ROWS[name]
-    args, hist = _window_case(window, H, rows)
-    if form == "reference":
-        got = window_attention_reference(*args)
-    else:
-        got = window_attention_tpu(*args, interpret=True, max_q_len=mq)
-    for a, want in _plain(args, hist, rows, window).items():
-        # float32 both sides, another order of the softmax's sums
-        assert float(jnp.abs(got[a:a + len(want)] - want).max()) < 1e-5
+    def call(*args, max_q_len):
+        if form == "reference":
+            return window_attention_reference(*args)
+        return window_attention_tpu(
+            *args, interpret=True, max_q_len=max_q_len)
+
+    window_cases.held_to_the_plain_oracle(call, ROWS[name])
 
 
-def test_one_key_more_is_seen_by_the_window_call():
+@pytest.mark.parametrize("name,least", [
+    ("decode_rows_on_both_sides_of_the_wrap", 1e-2),
+    ("chunks_from_empty_across_a_boundary_and_wrapped", 1e-2),
+    ("a_chunk_longer_than_a_block_and_not_a_multiple_of_it", 1e-3)])
+def test_one_key_more_is_seen_by_the_window_call(name, least):
     """The control the chip's kernel phase runs: a window of ``W + 1`` lets
-    in the ring row that holds the token ``W`` back."""
-    window, H, rows, _ = ROWS["decode_rows_on_both_sides_of_the_wrap"]
-    args, _ = _window_case(window, H, rows)
+    in the ring row that holds the token ``W`` back.  The one-token block and
+    the chunk call's long one (one key in 256 there)."""
+    window, H, rows, mq, *more = ROWS[name]
+    args, _ = window_cases.window_case(window, H, rows, 0, *more)
     right = window_attention_reference(*args)
     wrong = window_attention_reference(*args, window=window + 1)
-    got = window_attention_tpu(*args, interpret=True, max_q_len=1)
-    past = [i for i, (_, h, _) in enumerate(rows) if h >= window]
-    n = len(rows)              # (tokens outside every row are unspecified)
-    assert past and float(jnp.abs(got[:n] - right[:n]).max()) < 1e-5
-    assert min(float(jnp.abs(got[i] - wrong[i]).max()) for i in past) > 1e-2
+    got = window_attention_tpu(*args, interpret=True, max_q_len=mq)
+    # the first token of each row past the window (tokens outside every row
+    # are unspecified)
+    t0 = np.asarray(args[6])
+    past = [int(t0[i]) for i, (n, h, _) in enumerate(rows)
+            if n and h >= window]
+    live = np.concatenate([np.arange(t0[i], t0[i] + n)
+                           for i, (n, _, _) in enumerate(rows)])
+    assert past and float(jnp.abs(got[live] - right[live]).max()) < 1e-5
+    assert min(float(jnp.abs(got[i] - wrong[i]).max()) for i in past) > least
 
 
 def test_ring_positions_and_the_write():
@@ -705,6 +659,9 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
 
     assert value("helix_window_rows_total{", 'kind="chunk"') == 2
     assert value("helix_window_rows_total{", 'kind="decode"') >= 4
+    # chunks of 16 and 5 tokens: the second is one block of the window
+    # kernel in each of the nine sliding layers
+    assert value("helix_window_query_blocks_total{") == 9
     assert value("helix_recurrent_state_bytes{") == eng.recurrent_state_bytes
     assert value("helix_window_ring_bytes_read_total{") == (
         eng.mixer_counts["ring_bytes_read"]) > 0

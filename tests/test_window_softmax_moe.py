@@ -11,6 +11,7 @@ by LOGITS.  The window is 8 and a page-table row 40 pages of 8: a 70-token
 prompt passes the window eight times, a 330-token one a whole table row."""
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -36,6 +37,10 @@ from helix_tpu.models.common import (  # noqa: E402
 from helix_tpu.models.llama import (  # noqa: E402
     forward, init_params, param_logical_axes, prefill_attn_fn,
 )
+from helix_tpu.ops.window_kernel import (  # noqa: E402
+    chunk_query_block, window_attention_tpu,
+)
+import window_cases  # noqa: E402
 
 FULL, SLIDE = "full_attention", "sliding_attention"
 W = 8
@@ -126,6 +131,30 @@ def _rel(got, want):
 
 
 # ---- the model -----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name", sorted(window_cases.ROWS_OF_4_KV_HEADS))
+def test_long_block_over_a_ring_of_1024_against_the_masked_reference(name):
+    """The window kernel's chunk call at this family's layout (a query group
+    of 8 over 4 kv heads, a ring of 1,024), in interpret mode against
+    whole-sequence attention under explicit masks."""
+    window_cases.held_to_the_plain_oracle(
+        functools.partial(window_attention_tpu, interpret=True),
+        window_cases.ROWS_OF_4_KV_HEADS[name])
+
+
+@pytest.mark.parametrize("bucket,group,block", [
+    (16, 8, 16), (64, 8, 64), (128, 8, 128), (512, 8, 128), (512, 6, 128),
+    (512, 16, 64), (512, 1, 128), (12, 8, 8), (5, 8, 8)])
+def test_the_block_follows_the_bucket_and_the_group(bucket, group, block):
+    """About 1,024 query rows a kv head and product, never more tokens than
+    the bucket holds, a multiple of 8; and a row makes as many blocks under
+    the smallest bucket (the engine's are multiples of 8) that holds it as
+    under the largest: what ``_window_account`` counts by."""
+    assert chunk_query_block(bucket, group) == block
+    for n in (1, 9, 64, 65, 129, 300, 512):
+        if n <= bucket and bucket % 8 == 0:
+            assert -(-n // block) == -(-n // chunk_query_block(512, group))
 
 
 def test_catalog_entry_is_the_published_config():
@@ -530,6 +559,9 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
         r["context_tokens"] for r in records)
     assert value("helix_window_rows_total{", 'kind="chunk"') == 3
     assert value("helix_window_rows_total{", 'kind="decode"') >= 4
+    # chunks of 16, 16 and 5 tokens: the two with history are one block of
+    # the window kernel each in the three sliding layers
+    assert value("helix_window_query_blocks_total{") == 2 * 3
     # the rings at this model's own bytes: three sliding layers of 2 kv heads
     tok_bytes = 3 * 2 * 2 * 16 * 4
     read = value("helix_window_ring_bytes_read_total{")
@@ -542,6 +574,30 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
         eng.moe_routed_tokens) > 0
     assert "helix_moe_held_tokens_total" not in text
     assert "helix_mla_page_fetches_total" not in text
+
+
+@pytest.mark.parametrize("rows,blocks", [
+    ([(512, 0)], 0),                # a first chunk: the packed flash kernel
+    ([(512, 8192)], 4),             # 64 under the 8-token block
+    ([(20, 512)], 1), ([(130, 1024)], 2),
+    ([(70, 0), (140, 200)], 1 + 2),  # one launch, one row of it with history
+    ([], 0)])
+def test_the_query_blocks_a_launch_counts_at_the_published_config(
+        rows, blocks):
+    """``helix_window_query_blocks_total`` by hand: the window kernel's
+    programs for a launch's chunk rows, in each of the 21 sliding layers."""
+    import types
+
+    from helix_tpu.models.mixers import STATE_MIXERS
+
+    cache_cfg = CacheConfig(dtype="bfloat16", num_pages=8, page_size=16,
+                            max_pages_per_seq=8, state_slots=2)
+    got = STATE_MIXERS["window"].account(
+        MELLUM2_12B, cache_cfg,
+        [types.SimpleNamespace(rem=n, start=h, slot=0) for n, h in rows],
+        np.zeros(0, np.int64), 0)
+    assert got["query_blocks"] == 21 * blocks
+    assert got["chunk_rows"] == len(rows)
 
 
 SCOPES = ("window.qkv", "window.kernel", "window.out", "attn.qkv",
