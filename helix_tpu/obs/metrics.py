@@ -612,6 +612,43 @@ class EngineLoopObs:
             "triggered them) since the step before, per engine step",
             buckets=FAST_BUCKETS,
         )
+        # stalls (ISSUE 51).  The two step series are observed once for
+        # every step the histograms above observe, 0 for a step that was
+        # not flagged (by the recorder's slow-step rule or by the stall
+        # watch): their means are per engine step like the rest, and mean
+        # x steps is the seconds a window lost.  The counter counts closed
+        # stall records of every kind (a step's, the emission worker's,
+        # the event loop's, one between passes): the operator's alert.
+        self.stall_seconds = Histogram(
+            "helix_step_stall_seconds",
+            "Wall time of an engine step that was flagged a stall (over "
+            "the slow-step rule, or caught overdue by the stall watch), "
+            "0 for every other step, per engine step",
+            buckets=LATENCY_BUCKETS,
+        )
+        self.stall_offcpu = Histogram(
+            "helix_step_stall_offcpu_seconds",
+            "Of a flagged step's wall, the part its engine thread was not "
+            "on a CPU (wall less the thread's CPU time), 0 for every "
+            "other step, per engine step",
+            buckets=LATENCY_BUCKETS,
+        )
+        self.stalls = Counter(
+            "helix_engine_stalls_total",
+            "Closed stall records: one a stall, each also one 'helix "
+            "stall' log line and one anomalies entry of the flight "
+            "recorder",
+        )
+        self.stalls.inc(0)   # exported from the first scrape
+        # what a token's call_soon_threadsafe, a handler and an SSE write
+        # wait for the serving event loop's thread: how late its
+        # heartbeat ran, ten observations a second, none on a token's path
+        self.http_loop_lag = Histogram(
+            "helix_http_loop_lag_seconds",
+            "How late the serving event loop ran its 0.1 s heartbeat "
+            "(the time anything scheduled on that thread waits for it)",
+            buckets=FAST_BUCKETS,
+        )
         # request stages (ISSUE 25), beside queue_wait: handler entry to
         # first SSE chunk in five consecutive pieces, each also a span in
         # the request's trace
@@ -654,6 +691,8 @@ class EngineLoopObs:
             *self.step_phases.values(), *self.state_phases.values(),
             *self.step_parts.values(), self.host_build_cpu,
             *self.threads_cpu.values(), self.gc_seconds,
+            self.stall_seconds, self.stall_offcpu, self.stalls,
+            self.http_loop_lag,
             self.http_pre_submit, self.admit_to_first_token,
             self.first_token_hold, self.http_first_write,
             self.step_context_tokens,

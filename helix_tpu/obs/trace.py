@@ -595,7 +595,34 @@ class Phases(dict):
 
 
 _annotations = None
-_last_cpu = threading.local()
+_local = threading.local()
+
+
+class Mark:
+    """Where one thread is, for the stall watch (``obs.flight.StallWatch``)
+    to read WHILE the thread hangs.  ``at`` is ``(pass number, step
+    number, span name, t_mono the span was entered)`` or None (nothing
+    open), written by the thread itself with one plain attribute store
+    and no lock: the spans a marked thread opens (:class:`phase`) write
+    it as they enter and put back what stood as they leave, so ``at`` is
+    always the innermost span still open.  The owner sets ``pass_no`` and
+    ``step`` (what the next spans stamp) and may write ``at`` for a place
+    with no span.  ``attrs`` keeps the attributes of the last span of each
+    name that carried any: ``helix.loop.launch``'s are the step's launch
+    record."""
+
+    __slots__ = ("at", "pass_no", "step", "attrs")
+
+    def __init__(self):
+        self.at = None
+        self.pass_no = self.step = 0
+        self.attrs = {}
+
+
+def mark_thread(mark: Optional[Mark]) -> None:
+    """The calling thread's spans write ``mark`` from now on (None: no
+    more)."""
+    _local.mark = mark
 
 
 def thread_cpu() -> float:
@@ -605,30 +632,32 @@ def thread_cpu() -> float:
     advances in 10 ms ticks), and phase boundaries come in pairs: one span
     closes and the next opens.  A span's cpu is exact to 20 us a
     boundary."""
-    last = getattr(_last_cpu, "read", None)
+    last = getattr(_local, "read", None)
     if last is not None and time.monotonic() - last[0] < 2e-5:
         return last[1]
     cpu = time.thread_time()
-    _last_cpu.read = (time.monotonic(), cpu)
+    _local.read = (time.monotonic(), cpu)
     return cpu
 
 
 class phase:
     """``with phase(name, hist=None, into=None, **attrs)``: one named
-    span with three sinks.  (a) A ``jax.profiler.TraceAnnotation`` (a
+    span with four sinks.  (a) A ``jax.profiler.TraceAnnotation`` (a
     ``StepTraceAnnotation`` when ``step_num`` is among ``attrs``), which
     records only while a profiler capture is active and is then on the
     profiler's clock by construction.  (b) ``into[name]`` gains the
     elapsed seconds less the phases nested inside this one (``into`` is
     the current step's :class:`Phases`, which lands in the flight record
     as ``phases``).  (c) ``hist`` observes the elapsed seconds, which
-    ``seconds`` holds once the block has ended.
+    ``seconds`` holds once the block has ended.  (d) On a thread with a
+    :class:`Mark` (``mark_thread``) the span is where the thread is,
+    for the stall watch to read while it runs.
 
     JAX is imported on first use: the control plane imports ``obs`` and
     never opens a phase."""
 
     __slots__ = ("name", "hist", "into", "seconds", "_ann", "_t0", "_c0",
-                 "_parent", "_child", "_child_cpu")
+                 "_parent", "_child", "_child_cpu", "_mark", "_was")
 
     def __init__(self, name: str, hist=None, into: Optional[Phases] = None,
                  **attrs):
@@ -642,6 +671,9 @@ class phase:
         self.into = into
         self._ann = _annotations["step_num" in attrs](name, **attrs)
         self._child = self._child_cpu = 0.0
+        mark = self._mark = getattr(_local, "mark", None)
+        if attrs and mark is not None:
+            mark.attrs[name] = attrs
 
     def __enter__(self):
         into = self.into
@@ -650,6 +682,10 @@ class phase:
             into.open = self
         self._ann.__enter__()
         self._t0 = time.monotonic()
+        mark = self._mark
+        if mark is not None:
+            self._was = mark.at
+            mark.at = (mark.pass_no, mark.step, self.name, self._t0)
         if into is not None:
             # inside the wall clock's pair: cpu passes wall by no more
             # than a shared reading's 20 us
@@ -661,6 +697,8 @@ class phase:
         if into is not None:
             dc = thread_cpu() - self._c0
         dt = self.seconds = time.monotonic() - self._t0
+        if self._mark is not None:
+            self._mark.at = self._was
         self._ann.__exit__(*exc)
         if into is not None:
             name, cpu = self.name, into.cpu

@@ -33,8 +33,10 @@ from helix_tpu.engine.engine import (
     Request,
     SnapshotError,
 )
+from helix_tpu.engine.ragged import step_program_name
 from helix_tpu.models.mixers import flight_fields
 from helix_tpu.obs import EngineLoopObs, FlightRecorder, RateTracker
+from helix_tpu.obs import flight as obs_flight
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.flight import SATURATION_KEYS
 from helix_tpu.obs.slo import ANON_TENANT, SLOObserver
@@ -115,9 +117,14 @@ class _EmissionStage:
     SLICE = 16
     HOLD_MAX = 0.05
 
-    def __init__(self, sink: Callable, obs: EngineLoopObs, depth: int = 8):
+    def __init__(self, sink: Callable, obs: EngineLoopObs, depth: int = 8,
+                 watched: Optional[obs_flight.Watched] = None):
         self._sink = sink
         self._obs = obs
+        # the worker says where it is ("delivering batch n since t") for
+        # the stall watch, and closes a stall of its own itself (a stage
+        # on its own, with no loop around it, is watched by nobody)
+        self._watched = watched
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._thread: Optional[threading.Thread] = None
         self.started = False
@@ -175,12 +182,18 @@ class _EmissionStage:
 
     def _run(self) -> None:
         self.cpu_clock = time.pthread_getcpuclockid(threading.get_ident())
+        watched = self._watched
+        mark = watched.marks["emit"] if watched else obs_trace.Mark()
+        obs_trace.mark_thread(mark)
         while True:
             item = self._q.get()
             try:
                 if item is None:
                     return
                 pushed_at, batch = item
+                mark.pass_no += 1
+                t0, c0 = time.monotonic(), time.thread_time()
+                mark.at = (mark.pass_no, 0, "helix.emit.batch", t0)
                 spent = 0.0
                 for i in range(0, len(batch), self.SLICE):
                     self.parked.wait(self.HOLD_MAX)
@@ -195,6 +208,12 @@ class _EmissionStage:
                             log.exception("emission stage sink failed")
                     spent += span.seconds
                 self._obs.emit_deliver.observe(spent)
+                mark.at = None
+                during = watched.take("emit") if watched else None
+                if during is not None:
+                    watched.end(
+                        "emit", during, time.monotonic() - t0,
+                        time.thread_time() - c0, batch=mark.pass_no)
             finally:
                 self._q.task_done()
 
@@ -275,6 +294,20 @@ class EngineLoop:
         # (host-side counter deltas only — nothing enters the jitted
         # path), served at GET /v1/debug/flight
         self.flight = FlightRecorder()
+        # what the process's stall watch reads of this loop (ISSUE 51):
+        # where the engine thread is (the spans it opens write the
+        # marker; ``_at`` for the places with no span), and the watcher's
+        # capture of a stall under way until the thread that ends it
+        # closes the record
+        self._watched = obs_flight.Watched(
+            name, lambda: self.flight, self.obs, self._probe,
+            obs_flight.WATCH)
+        self._mark = self._watched.marks["engine"]
+        self._engine_clock: Optional[int] = None
+        # as the step in progress began: seconds of XLA compilation so
+        # far, compiled step shapes
+        self._compiled0 = (0.0, 0)
+        self._stalled_pass = -1     # the pass whose step was last closed
         # goodput tokens/s over a trailing window (scraped by /metrics
         # and the heartbeat saturation summary)
         self._tps = RateTracker()
@@ -311,7 +344,8 @@ class EngineLoop:
         # the at-most-one dispatched-but-not-reconciled step
         self._inflight = None
         self.pipelined_steps = 0    # steps dispatched while one was in flight
-        self._emit_stage = _EmissionStage(self._deliver, self.obs)
+        self._emit_stage = _EmissionStage(
+            self._deliver, self.obs, watched=self._watched)
         self._phases = obs_trace.Phases()   # the step in progress
         self._parts = obs_trace.Phases()    # the parts of its admit and dispatch
         # the host's account (ISSUE 37).  The CPU clock of the thread
@@ -983,6 +1017,7 @@ class EngineLoop:
 
     def start(self):
         gc.callbacks.append(self._gc_hook)
+        obs_flight.WATCH.attach(self._watched)
         self._emit_stage.start(self.name)
         self._thread = threading.Thread(
             target=self._run, name=f"helix-engine-{self.name}", daemon=True
@@ -1012,6 +1047,7 @@ class EngineLoop:
         if join and self._thread is not None:
             self._thread.join(timeout=30)
         self._unhook_gc()
+        self._unwatch()
 
     def _gc_hook(self, event: str, info: dict) -> None:
         """``gc.callbacks``: a collection as the span ``helix.gc``, on
@@ -1030,6 +1066,121 @@ class EngineLoop:
     def _unhook_gc(self) -> None:
         if self._gc_hook in gc.callbacks:
             gc.callbacks.remove(self._gc_hook)
+
+    # -- the stall watch (obs.flight.StallWatch) ----------------------------
+
+    def _probe(self) -> dict:
+        """This loop's account of itself for a stall capture, read by the
+        WATCHER's thread: each host thread's ident, CPU clock and CPU
+        seconds as of the last step's end (``_threads_cpu``'s reading),
+        whether a collection is open, and what waits in the inbox and
+        before the emission worker."""
+        stage, seen = self._emit_stage, self._cpu_seen
+        threads = {}
+        for name, thread, clock in (
+            ("engine", self._thread, self._engine_clock),
+            ("emit", stage._thread, stage.cpu_clock),
+            ("http", self._http_ident, self._http_clock),
+        ):
+            ident = getattr(thread, "ident", thread)
+            if ident is not None and clock is not None:
+                threads[name] = (ident, clock, seen.get(name, (0, None))[1])
+        return {
+            "threads": threads,
+            "gc_open": self._gc_span is not None,
+            "inbox_depth": self._inbox.qsize(),
+            "emit_depth": stage.depth(),
+        }
+
+    def _at(self, name: Optional[str]) -> None:
+        """The engine thread is at ``name`` from now (a place with no
+        span; None: nowhere)."""
+        mark = self._mark
+        mark.at = name and (
+            mark.pass_no, mark.step, name, time.monotonic())
+
+    def _unwatch(self) -> None:
+        """Leave the stall watch; a stall still under way is closed as it
+        stands, marked ``unfinished``."""
+        obs_flight.WATCH.detach(self._watched)
+        now = time.monotonic()
+        for where in list(self._watched.during):
+            during = self._watched.take(where)
+            if during is not None:
+                self._watched.end(
+                    where if where != "engine" else "between", during,
+                    now - during["since"], span=during["span"],
+                    unfinished=True)
+
+    def _stall_of(self, duration: float, wall: float,
+                  cpu: Optional[float]) -> Optional[dict]:
+        """Whether the step that just ended is a stall, asked BEFORE its
+        observation is made: the watcher caught it overdue, or its
+        ``duration`` passes the recorder's slow-step rule (the record
+        that follows is then filed ``slow_step``).  ``wall``: the seconds
+        the stall is charged; ``cpu``: the engine thread's CPU seconds
+        inside them, where known."""
+        during = self._watched.take("engine")
+        if during is not None and during["pass"] != self._mark.pass_no:
+            # a capture that landed after its own pass had closed its step
+            # (a process that thaws ends the step before the watcher has
+            # read the stacks): that step is filed, this one did not hang
+            during = None
+        if during is None and not self.flight.slow(duration):
+            return None
+        self._stalled_pass = self._mark.pass_no
+        return {"wall": wall, "cpu": cpu, "during": during}
+
+    def _close_stall(self, stall: dict, rec: Optional[dict] = None) -> dict:
+        """Close a stalled step's record with what the engine loop alone
+        knows (the launch record, the step's own flight record, compile
+        seconds and shapes over the step), log and count it; returns
+        what the recorder files with the anomaly.  ``where`` is the span
+        the engine thread was caught in, or, for a step that ended
+        inside a tick of the watcher, the phase that took longest."""
+        during, rec = stall["during"], rec or {}
+        phases = rec.get("phases") or _rounded(self._phases)
+        where = during["span"] if during is not None else max(
+            phases, key=phases.get, default="helix.loop.step")
+        launch = self._mark.attrs.get("helix.loop.launch")
+        if launch is not None:
+            launch = {
+                # (rings' and cold chunks' suffixes are not among the
+                # span's attributes)
+                "program": "jit_" + step_program_name(
+                    launch["token_bucket"], launch["has_hist"],
+                    launch["prefill_rows"]),
+                **{k: launch[k] for k in (
+                    "kind", "token_bucket", "prefill_rows", "live_rows")},
+            }
+        compile0, shapes0 = self._compiled0
+        closed = self._watched.closed(
+            where, during, stall["wall"], stall["cpu"],
+            step=self.steps, launch=launch,
+            **{k: rec[k] for k in (
+                "kind", "phases", "phases_cpu", "parts", "threads_cpu",
+                "gc_s", "device_wait_s") if k in rec},
+            compiled_shapes=[shapes0, getattr(
+                self.engine, "compiled_step_shapes", 0)],
+            compile_s=round(
+                obs_flight.WATCH.compile_seconds - compile0, 6),
+        )
+        return self._watched.file(closed)
+
+    def _close_between(self) -> None:
+        """A stall of the engine thread outside any step (the inbox
+        drain, a reconcile point, a ladder between passes), closed as
+        soon as the pass is past it; its CPU is the thread's since the
+        last step's end."""
+        during = self._watched.take("engine")
+        if during is None or during["pass"] == self._stalled_pass:
+            # (a capture that landed as its step closed: filed already)
+            return
+        seen = self._cpu_seen.get("engine")
+        self._watched.end(
+            "between", during, time.monotonic() - during["since"],
+            time.thread_time() - seen[1] if seen else None,
+            span=during["span"])
 
     # -- engine thread ------------------------------------------------------
 
@@ -1418,9 +1569,11 @@ class EngineLoop:
         """The step-failure ladder (shared by the sync and async paths):
         record, retry once on the exact same state, then quarantine."""
         self._emit_stage.flush()
-        self._observe_step(dt_step, self._phases)
+        stall = self._stall_of(
+            dt_step, dt_step, sum(self._phases.cpu.values()))
+        self._observe_step(dt_step, self._phases, stall=stall)
         self._flight_record(
-            dt_step, flight_pre, generated=0, failed=str(e)
+            dt_step, flight_pre, generated=0, failed=str(e), stall=stall
         )
         self.step_failures += 1
         self._consec_failures += 1
@@ -1456,6 +1609,10 @@ class EngineLoop:
             ph = obs_trace.Phases()
         ph.clear()
         self._phases = ph
+        self._mark.attrs.clear()    # the launch record is this step's
+        self._compiled0 = (
+            obs_flight.WATCH.compile_seconds,
+            getattr(self.engine, "compiled_step_shapes", 0))
         parts = getattr(self.engine, "step_parts", None)
         if parts is not None:
             parts.clear()
@@ -1475,7 +1632,8 @@ class EngineLoop:
         return span.seconds
 
     def _observe_step(self, seconds: float, ph: obs_trace.Phases,
-                      exposed: float = 0.0, build_cpu: float = 0.0) -> dict:
+                      exposed: float = 0.0, build_cpu: float = 0.0,
+                      stall: Optional[dict] = None) -> dict:
         """One observation a step of the step histogram and of every
         phase histogram (0 where the phase did not run), so the phase
         means add up to the step's; of the host time the device
@@ -1483,10 +1641,14 @@ class EngineLoop:
         behind a running one); and of the host's account: the parts of
         admit and dispatch, the engine thread's CPU while it built the
         step, the three host threads' CPU and the collector's pauses
-        since the step before.  Returns the account as the flight record
-        files it."""
+        since the step before; and of the step's wall and of the part of
+        it off the CPU if the step is a ``stall`` (``_stall_of``), 0 if
+        not.  Returns the account as the flight record files it."""
         obs = self.obs
         obs.step_seconds.observe(seconds)
+        wall, cpu = (stall["wall"], stall["cpu"] or 0.0) if stall else (0, 0)
+        obs.stall_seconds.observe(wall)
+        obs.stall_offcpu.observe(max(0.0, wall - cpu))
         obs.exposed_host.observe(exposed)
         obs.step_context_tokens.observe(
             getattr(self.engine, "step_context_tokens", 0))
@@ -1565,6 +1727,7 @@ class EngineLoop:
     def _flight_record(
         self, duration: float, pre: tuple, generated: int,
         failed: Optional[str] = None, timing: Optional[dict] = None,
+        stall: Optional[dict] = None,
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
@@ -1704,7 +1867,12 @@ class EngineLoop:
         if failed is not None:
             rec["anomaly"] = "step_failure"
             rec["error"] = failed[:200]
-        self.flight.record_step(rec)
+        elif stall is not None:
+            rec["anomaly"] = "slow_step"
+        # a stall's record is closed (one log line, one count) and filed
+        # with the anomaly the recorder freezes for it
+        self.flight.record_step(
+            rec, **(self._close_stall(stall, rec) if stall else {}))
         # bank a goodput sample while the engine works (throttled inside
         # the tracker): keeps the rate anchor within ~one window of now,
         # so sparse external scrapes can't understate a recent burst
@@ -1723,6 +1891,14 @@ class EngineLoop:
         drain, idle, preemption): complete the in-flight step and drain
         the emission stage.  False = the completion failed and the
         failure ladder ran — restart the loop pass."""
+        was = self._mark.at
+        try:
+            self._at("helix.loop.park")
+            return self._park()
+        finally:
+            self._mark.at = was
+
+    def _park(self) -> bool:
         if self._inflight is None:
             self._emit_stage.flush()
             return True
@@ -1738,6 +1914,7 @@ class EngineLoop:
             return False
         dt_wait = time.monotonic() - t0
         dt_emit = self._push_emit(emitted, ph)
+        wall = time.monotonic() - t0
         # the step's own pass recorded its dispatch only: its tokens and
         # its device wait land here, or the burst's last step vanishes
         # from the flight window
@@ -1748,18 +1925,24 @@ class EngineLoop:
                 "device_wait_s": round(dt_wait, 6),
                 "emit_s": round(dt_emit, 6),
                 "idle_gap_s": 0.0,
-                "wall_s": round(time.monotonic() - t0, 6),
+                "wall_s": round(wall, 6),
                 "pipelined": 1,
                 "phases": _rounded(ph),
                 "phases_cpu": _rounded(ph.cpu),
             },
+            stall=self._stall_of(dt_wait, wall, sum(ph.cpu.values())),
         )
         self._emit_stage.flush()
         return True
 
     def _run(self):
+        self._engine_clock = time.pthread_getcpuclockid(
+            threading.get_ident())
+        obs_trace.mark_thread(self._mark)
         while not self._stop.is_set() and self._pass():
-            pass
+            if self._watched.during:
+                self._close_between()
+        self._at(None)
         # a step still in flight at shutdown: reconcile so its tokens
         # reach subscribers before the terminal sweep
         if self._inflight is not None:
@@ -1772,6 +1955,7 @@ class EngineLoop:
                 self.engine.discard_pending(pend)
         self._unhook_gc()
         self._emit_stage.stop()
+        self._unwatch()
         log.info(
             "engine '%s' emission stage stopped: %d batch(es) delivered "
             "off the engine thread, %d push(es) found the queue full",
@@ -1800,7 +1984,12 @@ class EngineLoop:
         ladders that need a reconciled engine (drain, hand-off,
         checkpoint, idle), then one engine step.  False: the drain is
         over and the thread leaves."""
+        mark = self._mark
+        mark.pass_no += 1
+        mark.step = self.steps
+        self._at("helix.loop.inbox")
         self._drain_inbox()
+        self._at("helix.loop.pass")
         if self._draining:
             if not self._reconcile_or_fail():
                 return True
@@ -1887,6 +2076,8 @@ class EngineLoop:
                 return True
         ph = self._new_phases()
         ph.merge(sched_ph)
+        if self._watched.during:
+            self._close_between()   # a stall before the step, not of it
         with obs_trace.phase("helix.loop.step", step_num=self.steps):
             self._step_pass(ph, lookahead)
         return True
@@ -1940,6 +2131,7 @@ class EngineLoop:
                             "wall_s": round(dt_prev, 6),
                             "pipelined": 1,
                         },
+                        stall=self._stall_of(dt_prev, dt_prev, None),
                     )
             self._handle_step_failure(
                 e, time.monotonic() - t_step, flight_pre
@@ -2010,11 +2202,17 @@ class EngineLoop:
             # flight record (a dispatch-only pass would read as
             # zero_progress to the watchdog); the step's numbers land
             # with its completion next pass
-            self._observe_step(dt_step, ph, idle_gap, build_cpu)
+            stall = self._stall_of(
+                dt_step, dt_step, time.thread_time() - c_step)
+            self._observe_step(dt_step, ph, idle_gap, build_cpu, stall)
+            if stall is not None:
+                self.flight.note_anomaly(
+                    "stall", self._close_stall(stall), step=self.steps)
             return
         self._deliver_resume_failures()
         wall = time.monotonic() - t_step
-        account = self._observe_step(wall, ph, idle_gap, build_cpu)
+        stall = self._stall_of(dt_step, wall, time.thread_time() - c_step)
+        account = self._observe_step(wall, ph, idle_gap, build_cpu, stall)
         self._flight_record(
             dt_step, flight_pre, generated=len(emitted),
             timing={
@@ -2027,6 +2225,7 @@ class EngineLoop:
                 "phases": _rounded(ph),
                 **account,
             },
+            stall=stall,
         )
 
     # -- poisoned-request quarantine ----------------------------------------

@@ -15,6 +15,7 @@ SSE framing follows OpenAI: ``data: {json}\n\n`` chunks, closing
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import threading
 import time
@@ -27,6 +28,7 @@ from helix_tpu import obs
 from helix_tpu.engine.engine import Request, SnapshotError
 from helix_tpu.engine.sampling import SamplingParams
 from helix_tpu.obs.canary import collect_canary_metrics, default_prober
+from helix_tpu.obs.flight import WATCH
 from helix_tpu.obs.slo import ANON_TENANT, TENANT_HEADER, sanitize_tenant
 from helix_tpu.engine.adapters import (
     ADAPTER_SEP,
@@ -80,6 +82,8 @@ def _now() -> int:
 
 # seconds a profiler capture's answer may stay silent before it is streamed
 _PROFILER_QUIET_S = 45.0
+# the serving event loop's heartbeat: ten observations of its lag a second
+_HEARTBEAT_S = 0.1
 _LONGPOLL_POOL = None
 
 
@@ -190,6 +194,11 @@ class OpenAIServer:
         self.traces = (trace_store if trace_store is not None
                        else obs.default_store())
         self._profiler_lock = threading.Lock()
+        # the /metrics and flight renders open now, by path (the stall
+        # watch asks at a capture), and the event loop's heartbeat
+        self._scrapes: list = []
+        self._beat: Optional[asyncio.TimerHandle] = None
+        self._beat_due = 0.0
         # migrated-in requests awaiting their resumed stream (ISSUE 11):
         # the peer engine may start generating before the control plane
         # attaches, so token events buffer here until /v1/migrate/resume
@@ -250,7 +259,51 @@ class OpenAIServer:
         # historical name — followers of either wire version find it,
         # and the version field inside each record does the rejecting.
         app.router.add_get("/multihost/commands", self.multihost_commands)
+        app.on_startup.append(self._start_heartbeat)
+        app.on_cleanup.append(self._stop_heartbeat)
         return app
+
+    # -- the event loop's heartbeat (ISSUE 51) ------------------------------
+
+    async def _start_heartbeat(self, app) -> None:
+        """Every ``_HEARTBEAT_S`` a timer on the serving event loop
+        observes how late it ran (``helix_http_loop_lag_seconds``: what a
+        token's ``call_soon_threadsafe``, a handler and an SSE write wait
+        for this thread) and stamps the marker the stall watch reads: a
+        loop silent past the stall rule is a stall ``where: http``."""
+        WATCH.http_state = self._http_state
+        loop = WATCH.http_loop = asyncio.get_running_loop()
+        self._beat_due = loop.time() + _HEARTBEAT_S
+        self._beat = loop.call_later(_HEARTBEAT_S, self._heartbeat, loop)
+
+    def _heartbeat(self, loop) -> None:
+        now = loop.time()
+        WATCH.beat(max(0.0, now - self._beat_due))
+        self._beat_due = now + _HEARTBEAT_S
+        self._beat = loop.call_later(_HEARTBEAT_S, self._heartbeat, loop)
+
+    async def _stop_heartbeat(self, app) -> None:
+        if self._beat is not None:
+            self._beat.cancel()
+            self._beat = None
+        WATCH.http.at = WATCH.http_loop = None
+        WATCH.http_state = dict
+
+    def _http_state(self) -> dict:
+        return {"profiler_capture": self._profiler_lock.locked(),
+                "scrapes_open": list(self._scrapes)}
+
+    @contextlib.contextmanager
+    def _scrape(self, path: str):
+        """A render of ``path`` as the span ``helix.http.scrape``, on the
+        thread that makes it: it holds the GIL, beside the engine
+        thread's."""
+        self._scrapes.append(path)
+        try:
+            with phase("helix.http.scrape", path=path):
+                yield
+        finally:
+            self._scrapes.remove(path)
 
     async def multihost_commands(self, request):
         """Leader-side plan feed for follower hosts."""
@@ -321,11 +374,9 @@ class OpenAIServer:
         """Prometheus text surface, rendered by the shared obs registry.
         Runs in an executor: scrape-time collectors take live locks (the
         residency manager's stats() lock is held across whole model
-        builds) and must never block the event loop.  The render is the
-        span ``helix.http.scrape`` on the executor's thread: it holds the
-        GIL, beside the engine thread's."""
+        builds) and must never block the event loop."""
         def render():
-            with phase("helix.http.scrape", path="/metrics"):
+            with self._scrape("/metrics"):
                 return self.obs.render()
 
         text = await asyncio.get_running_loop().run_in_executor(
@@ -797,28 +848,34 @@ class OpenAIServer:
         def collect():
             # off the event loop: registry.list() on a residency-backed
             # runner blocks on the build-holding ResidencyManager lock
-            # (same rule as the /metrics render above)
+            # (same rule as the /metrics render above), and the body is
+            # joined here too, from each record's JSON as it was made the
+            # first time it was served: what json_response would write,
+            # letter for letter
             snap = {}
-            with phase("helix.http.scrape", path="/v1/debug/flight"):
+            with self._scrape("/v1/debug/flight"):
                 for m in self.registry.list():
                     if m.loop is None or (want and m.name != want):
                         continue
                     fl = getattr(m.loop, "flight", None)
                     if fl is None:
                         continue
-                    snap[m.name] = fl.snapshot(recent=recent)
-            return snap
+                    snap[m.name] = fl.snapshot_json(recent=recent)
+                if want and not snap:
+                    return None
+                return ('{"models": {' + ", ".join(
+                    json.dumps(name) + ": " + body
+                    for name, body in snap.items()) + "}}").encode()
 
-        out = await asyncio.get_running_loop().run_in_executor(
+        body = await asyncio.get_running_loop().run_in_executor(
             None, collect
         )
-        if want and not out:
+        if body is None:
             return _error(
                 404, f"model {want!r} has no engine flight recorder"
             )
-        # the records' serialisation, on the event loop's thread
-        with phase("helix.http.scrape", path="/v1/debug/flight"):
-            return web.json_response({"models": out})
+        return web.Response(
+            body=body, content_type="application/json", charset="utf-8")
 
     async def debug_admissions(self, request):
         """The admission-decision audit trail: a bounded ring per model

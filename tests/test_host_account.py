@@ -99,3 +99,36 @@ def test_the_command_line_takes_two_texts_and_a_flight_answer(tmp_path):
     assert "part sync_state     4.000" in r.stdout
     assert "thread http" in r.stdout and "  7.000" in r.stdout
     assert "step 3 mixed wall 40.0 ms gc 1.00" in r.stdout
+
+
+def test_the_closed_stall_records_are_printed_and_no_other_anomaly():
+    mod = tool()
+    buf = io.StringIO()
+    mod.stalls([
+        {"reason": "quarantine", "ts": 1.0, "step": None,
+         "record": {"request_id": "r"}, "steps": []},
+        {"reason": "slow_step", "ts": 2.0, "step": 9, "steps": [],
+         "record": {"phases": {"helix.loop.fetch": 2.01}, "gc_s": 0.0},
+         "where": "helix.loop.fetch",
+         "stall": {"wall_s": 2.02, "offcpu_s": 2.0, "seen": True,
+                   "compile_s": 0.0, "compiled_shapes": [15, 15],
+                   "launch": {"program": "jit_step_fn_t0"},
+                   "rusage": {"nivcsw": 3, "majflt": 0}},
+         "during": {"span": "helix.loop.fetch", "stood_s": 1.1,
+                    "rusage": {}, "threads": {"engine": {
+                        "stack": ["engine/engine.py:2248:_fetch",
+                                  "engine/engine.py:4931:_decode_complete"],
+                        "cpu_since_step_s": 0.002}}}},
+        {"reason": "stall", "ts": 3.0, "step": None, "steps": [],
+         "record": {"where": "http"}, "where": "http",
+         "stall": {"wall_s": 1.4, "seen": True}, "during": None},
+    ], buf)
+    text = buf.getvalue()
+    assert text.startswith("stalls: 2 closed record(s)\n")
+    assert " slow_step step 9 where helix.loop.fetch wall 2.02 s " in text
+    assert "off-cpu 2.0 s" in text and "'nivcsw': 3" in text
+    assert "phases {'helix.loop.fetch': 2.01}" in text
+    assert ("engine cpu_since_step 0.002 s: engine/engine.py:2248:_fetch < "
+            "engine/engine.py:4931:_decode_complete") in text
+    assert " stall step None where http wall 1.4 s " in text
+    assert "quarantine" not in text

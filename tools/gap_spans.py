@@ -56,6 +56,11 @@ def main():
            "first_op_after_first_stamp_ms":
                (lo - stamps[0]) / 1e6 if stamps else None,
            "gaps": []}
+    # the stall watch's captures inside this capture (ISSUE 51)
+    out["stalls"] = [
+        {"at_ms": (e[1] - stamps[0]) / 1e6 if stamps else None,
+         "ms": e[2] / 1e6, **(e[4] or {})}
+        for e in host if e[0] == "helix.stall"]
     for g0, dur in xplane.gaps_of([(e[1], e[2]) for e in ops], (lo, hi)):
         if dur < a.min_ms * 1e6:
             break
