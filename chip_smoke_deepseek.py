@@ -277,7 +277,9 @@ def kernel_window(seed, rehearse, rng, ks, heads=(48, 64), KVH=8,
     """A window-and-full model's two attention calls at the published
     geometry; the defaults are Laguna's.  The dense ragged kernel at 48 query
     / 8 kv heads of 128 (a query group of 6, padded
-    to a sublane tile of 8) over pages, at ``_attention_cases``.  The window
+    to a sublane tile of 8) over pages, at ``_attention_cases`` (the chunk
+    row is the kernel's long block, one kv head at a time), then that call
+    TIMED alone by the device (``_paged_chunk_times``).  The window
     kernel (``ops/window_kernel.py``) at 64 / 8 / 128 over rings of 512 in a
     pool of 48 slots whose every row holds finite values of some other
     sequence: 48 decode rows at histories from 0 to far past the window (0,
@@ -315,8 +317,8 @@ def kernel_window(seed, rehearse, rng, ks, heads=(48, 64), KVH=8,
     H = heads[0]
     k_pages, v_pages = draw(ks[0], (L, N, P, KVH, D)), draw(
         ks[1], (L, N, P, KVH, D))
-    for name, (T, t0, q_len, hist, tables, mq) in _attention_cases(
-            rng, B, S, maxP, P, N, deep).items():
+    cases = _attention_cases(rng, B, S, maxP, P, N, deep)
+    for name, (T, t0, q_len, hist, tables, mq) in cases.items():
         args = (draw(ks[2], (T, H, D)), draw(ks[3], (T, KVH, D)),
                 draw(ks[4], (T, KVH, D)), k_pages, v_pages, jnp.int32(1),
                 *(jnp.asarray(x, jnp.int32)
@@ -331,6 +333,13 @@ def kernel_window(seed, rehearse, rng, ks, heads=(48, 64), KVH=8,
             want = ragged_paged_attention_reference(*args)
         ok &= _hold("ragged_paged_attention", [H, KVH, D], name, T, t0,
                     q_len, got, want)
+    # the chunk row's call alone (the tokens and the table of the case held
+    # above), at histories the cell's prompts reach
+    _paged_chunk_times(
+        (draw(ks[2], (S, H, D)), draw(ks[3], (S, KVH, D)),
+         draw(ks[4], (S, KVH, D)), k_pages, v_pages),
+        jnp.asarray(cases["chunk_with_history"][4], jnp.int32),
+        (700, 1500) if maxP * P < 8192 else (2048, 8128), rehearse)
     H = heads[1]
     k_ring, v_ring = draw(ks[0], (L, B, W, KVH, D)), draw(
         ks[1], (L, B, W, KVH, D))
@@ -398,6 +407,67 @@ def kernel_window(seed, rehearse, rng, ks, heads=(48, 64), KVH=8,
     return B, S
 
 
+def _chunk_row_programs(rows, held, op, rehearse):
+    """``_device_programs`` over a capture of five calls of each jitted
+    ``rows[history](*held)``: a chunk row's call a history, timed by the
+    device (a call's wall time is its dispatch's)."""
+    def run():
+        for _ in range(5):
+            out = [fn(*held) for fn in rows.values()]
+        return out
+
+    return _device_programs(run, op, rehearse)
+
+
+def _paged_chunk_times(held, table, histories, rehearse):
+    """DEVICE time a layer of the paged kernel's chunk call alone: ONE row of
+    the held tokens (``q``, fresh K/V and the pools of a case the kernel was
+    just held to the reference at) over each of ``histories`` tokens of
+    pages, from a capture of five calls a history (``_window_chunk_times``'s
+    method), with the products a query needs of the keys IT sees (its
+    history and the row's tokens up to itself, scores and values) and their
+    share of the bf16 peak.  On a CPU the calls are walked (histories cut to
+    the table) and nothing is reported."""
+    from helix_tpu.ops.paged import ragged_paged_attention
+    from helix_tpu.ops.paged_kernel import ragged_paged_attention_tpu
+
+    S, H, D = held[0].shape
+    P, KVH = held[3].shape[2], held[3].shape[3]
+    i32 = lambda *a: jnp.asarray(a, jnp.int32)  # noqa: E731
+    if rehearse:
+        histories = tuple(min(h, table.shape[1] * P - 1) for h in histories)
+
+    def row(hist):
+        def fn(*a):
+            meta = (jnp.int32(1), i32(0), i32(S), i32(hist), table)
+            if rehearse:
+                return ragged_paged_attention_tpu(
+                    *a, *meta, max_q_len=S, interpret=True)
+            return ragged_paged_attention(
+                *a, *meta, backend="pallas", max_q_len=S)
+        fn.__name__ = f"paged_chunk_row_over_{hist}"
+        return jax.jit(fn)
+
+    programs = _chunk_row_programs(
+        {hist: row(hist) for hist in histories}, held,
+        "^ragged_paged_attention_tpu", rehearse)
+    if programs is None:
+        return
+    from benchmark.lib.peaks import chip_peaks
+
+    peak = chip_peaks(jax.devices()[0].device_kind)["bf16_flops"]
+    for hist in histories:
+        p = programs[f"jit_paged_chunk_row_over_{hist}"]
+        ops = 4 * H * D * int((hist + np.arange(S) + 1).sum())
+        kernel_ms = sum(p["op_ms"].values())
+        say(phase="kernel", op="ragged_paged_attention", timed="chunk_row",
+            heads=[H, KVH, D], tokens=S, history=hist,
+            device_ms_a_layer=round(p["mean_ms"], 4),
+            kernel_alone_ms=round(kernel_ms, 4), useful_gflop=ops / 1e9,
+            share_of_the_bf16_peak=round(ops / peak / (kernel_ms * 1e-3), 4),
+            timed_on=jax.default_backend())
+
+
 def _window_chunk_times(held, histories, call, rehearse):
     """DEVICE time a layer of the window kernel's chunk call alone: ONE row
     of the held tokens over a ring with each of ``histories`` behind it, from
@@ -417,14 +487,9 @@ def _window_chunk_times(held, histories, call, rehearse):
         fn.__name__ = f"chunk_row_behind_{hist}"
         return jax.jit(fn)
 
-    rows = {hist: row(hist) for hist in histories}
-
-    def run():
-        for _ in range(5):
-            out = [fn(*held) for fn in rows.values()]
-        return out
-
-    programs = _device_programs(run, "^window_attention_tpu", rehearse)
+    programs = _chunk_row_programs(
+        {hist: row(hist) for hist in histories}, held,
+        "^window_attention_tpu", rehearse)
     if programs is None:
         return
     from benchmark.lib.peaks import chip_peaks

@@ -1836,6 +1836,11 @@ class Engine:
         from helix_tpu.ops.paged_kernel import query_block
 
         self.attn_q_block = query_block(self._spec_width())
+        # ... and of the PREFILL segment's paged call in the last launch that
+        # had one (``prefill_q_block``; 0 before any), with the programs the
+        # dense paged kernel ran for such launches' rows over the full layers
+        self.chunk_q_block = 0
+        self.attn_query_blocks = 0
         # the step in progress, by named phase (obs.trace.phase): the
         # engine loop clears it at the top of a pass and files it in the
         # flight record; standalone step() callers never read it
@@ -1905,25 +1910,39 @@ class Engine:
             return {}
         return self.mixer.gauges(self.model_cfg, self._live_positions())
 
-    def _history_pages(self, plan, rung, pos, n_extra) -> int:
+    def prefill_q_block(self, rung: int, rows: int) -> int:
+        """Tokens in a query block of the prefill segment's paged call, as
+        the kernel that runs it sizes it from what the call sees: the bucket
+        (``rung``: the segment's flat tokens and the bound on a row's) and
+        the rows it can hold.  The latent kernel's is 8 tokens
+        (``query_block``); the dense kernel's follows the query heads a kv
+        head too, as the POOL holds them (``paged_query_block``: 128 tokens
+        for a one-row 512-token chunk at a group of 8 or under)."""
+        from helix_tpu.ops.paged_kernel import paged_query_block, query_block
+
+        cfg = self.model_cfg
+        if cfg.is_mla:
+            return query_block(rung)
+        group = cfg.heads_of("attn") * cfg.kv_head_pack // cfg.num_kv_heads
+        return paged_query_block(rung, group, rows, rung)
+
+    def _history_pages(self, plan, block, pos, n_extra) -> int:
         """History pages ONE paged layer's kernel walks in this launch, from
         the host's mirrors: over the live rows, the pages of a row's history
         (``ceil(hist / page)``: one DMA each) times the row's query blocks
         (each block walks the whole history again).  A prefill row is
-        ``ceil(rem / block)`` blocks over its ``start`` tokens; a live state
+        ``ceil(rem / block)`` blocks over its ``start`` tokens (``block``:
+        ``prefill_q_block``, the kernel's own); a live state
         row (``pos`` its position) is one one-token block over its position,
         a page longer every ``page`` steps of the fused tail.  Times the
         latent layers it is ``helix_mla_page_fetches_total`` (the latent
         kernel's time over that count is the cost of a page fetched, PERF.md
         section 5); times a page's K and V over the full layers,
         ``helix_attn_page_bytes_read_total``."""
-        from helix_tpu.ops.paged_kernel import query_block
-
         P = self.cache_cfg.page_size
         pages = 0
         if plan is not None and plan.rows:
-            bq = query_block(rung)
-            pages += sum(-(-r.start // P) * -(-r.rem // bq)
+            pages += sum(-(-r.start // P) * -(-r.rem // block)
                          for r in plan.rows)
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
@@ -5068,12 +5087,14 @@ class Engine:
         walked = {}
         if self.model_cfg.num_attn_layers:
             pos = self._live_positions(draft_len)
+            block = self.prefill_q_block(rung, rows) if rows else 0
             pages = self._history_pages(
-                plan if rows else None, rung, pos, n_extra)
+                plan if rows else None, block, pos, n_extra)
             context = int(pos.sum()) + sum(
                 r.start + r.rem for r in (plan.rows if rows else ()))
             if kind != "warmup":
                 self.step_context_tokens = context
+                self.chunk_q_block = block or self.chunk_q_block
             if self.model_cfg.is_mla:
                 fetches = pages * self.model_cfg.num_attn_layers
                 self.num_mla_page_fetches += fetches
@@ -5081,7 +5102,15 @@ class Engine:
             else:
                 page_bytes = pages * self._page_bytes
                 self.attn_page_bytes_read += page_bytes
-                walked = {"attn_page_bytes": page_bytes}
+                # rows with no history anywhere in the launch go to the
+                # packed flash kernel: the paged kernel runs no program
+                blocks = self.model_cfg.num_attn_layers * has_hist * sum(
+                    -(-r.rem // block) for r in (plan.rows if rows else ()))
+                self.attn_query_blocks += blocks
+                walked = {"attn_page_bytes": page_bytes,
+                          "attn_query_blocks": blocks}
+            if rows:
+                walked["chunk_q_block"] = block
             walked["context_tokens"] = context
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))

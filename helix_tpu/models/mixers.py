@@ -640,7 +640,7 @@ def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     block is the kernel's own, ``chunk_query_block``: 128 tokens at a query
     group of 8, the same count under any bucket that holds the row); none
     where no row has history: the packed flash kernel runs those."""
-    from helix_tpu.ops.window_kernel import chunk_query_block
+    from helix_tpu.ops.paged_kernel import chunk_query_block
 
     W = cfg.sliding_window
     block = chunk_query_block(

@@ -29,8 +29,10 @@ shape per caller.
 - ``ragged_paged_attention_tpu`` (``helix_tpu/ops/paged_kernel``) — the
   Pallas kernel: walks ONLY the pages each row actually uses (ragged over
   rows), one whole-page ``[P, KVH, D]`` DMA per page, query blocks of 1
-  token (plain decode: ``max_q_len`` 1) or 8, int8 dequantization
-  in-register after the page fetch.
+  token (plain decode: ``max_q_len`` 1), 8 (rows that can only be short)
+  or a chunk row's long block scored one kv head at a time
+  (``paged_query_block``), int8 dequantization in-register after the page
+  fetch.
 
 - ``paged_decode_attention_reference`` is kept as the decode-shaped
   numerics oracle for tests (one query token per sequence, no fresh-token

@@ -20,12 +20,6 @@ instead of one trace family per caller.
   ragged: a decode row is 1 token, a verify row ``1+k`` tokens, a prefill
   row a whole chunk — the grid walks ONLY the blocks, pages and fresh
   keys each row actually uses.
-- The block shape follows a static bound on a row's fresh tokens
-  (``max_q_len``, the engine's ``Sq``).  A plain decode call (bound 1) is
-  one-token blocks: its q, fresh K/V and output are a few hundred KB and
-  stay in VMEM for the whole call, and its history comes in chunks of 256
-  tokens.  Anything longer is 8-token blocks, which stream their q, their
-  output and their fresh keys (128 a DMA) and walk chunks of 128.
 - A block's history streams page by page into one of two chunk slots
   while the chunk before it is computed, and the FIRST chunk of the next
   block is started before the last one of this block is computed (the
@@ -44,15 +38,40 @@ instead of one trace family per caller.
   slice it); the wrapper gathers each row's table of scale rows into a
   ``[R, KVH, tokens]`` slab and the kernel streams one lane-aligned window of
   it per chunk.  Dequantization is on the score side — K's scale
-  multiplies the scores, V's the probabilities — so the MXU still sees
-  fp32 operands and no scale ever crosses from lanes to sublanes.  Fresh
-  tokens are attended at full precision; the write path quantizes
-  through the shared codec.
-- Scores for ALL heads of a q block come from ONE 128-aligned MXU dot:
-  the block-diagonal q layout ``[BQ*H, KVH*D]`` (query head h occupies the
-  column block of its kv head) against the chunk buffer viewed flat
-  ``[T, KVH*D]`` — no per-head strided slices (the same trick the
-  decode-only predecessor kernel used, extended to 8-token q blocks).
+  multiplies the scores, V's the probabilities — so no scale ever crosses
+  from lanes to sublanes.  Fresh tokens are attended at full precision;
+  the write path quantizes through the shared codec.
+
+THREE FORMS of a program, chosen by ``paged_query_block`` from what a call
+can see before it runs (the static bound on a row's fresh tokens
+``max_q_len``, the engine's ``Sq``; the query heads a kv head; the rows the
+segment can hold and its flat tokens), never from a name or a switch:
+
+1. **One token** (a plain decode call, bound 1).  q, fresh K/V and the
+   output of the whole call are a few hundred KB and stay in VMEM; the
+   history comes in chunks of 256 tokens.  Scores for ALL heads of the token
+   come from ONE 128-aligned MXU dot: the block-diagonal q layout ``[H,
+   KVH*D]`` (query head h occupies the column block of its kv head) against
+   the chunk buffer viewed flat ``[T, KVH*D]`` — no per-head strided slices.
+   Bound by the history's bytes, not by its products.
+2. **8 tokens** (rows that can only be short: a verify row of ``1 + k``
+   tokens, a wave whose rows share a small bucket).  The same block-diagonal
+   dot at ``[8*H, KVH*D]``; the block streams its q, its output and its
+   fresh keys (128 a DMA) and walks chunks of 128 tokens.  ``KVH`` times the
+   MXU passes a head-by-head product needs, which a few short rows never
+   notice and a 512-token chunk row paid 64 times over its whole history.
+3. **Long** (a chunk row: ``chunk_query_block`` tokens, 128 at a group of 8
+   or under, 64 at 16; a wave's rows the power of two at or above their
+   share of the bucket).  ONE KV HEAD AT A TIME (``_long_block``): a kv
+   head's queries ``[BQ x group, D]`` against that head's keys of a history
+   step ``[1024, D]``, under a ``fori_loop`` over kv heads, the step's pages
+   laid out head-major once (a reshape and lane-aligned slices of the
+   chunk's ``[tokens, KVH*D]`` view).  No block-diagonal query, no zero
+   block multiplied; a 512-token row is 4 to 8 programs and walks its
+   history 4 to 8 times.  Operands in the queries' dtype with float32
+   accumulation (int8 codes widened exactly), statistics in float32.  The
+   group is padded to a sublane tile of 8 (Mistral's 4 too: 128 tokens, not
+   the 256 an unpadded group would take).
 
 Layout contract: rows are disjoint and ascending on the flat axis; rows
 may start at ANY offset.  A row's final partial query block writes
@@ -141,10 +160,13 @@ def _ragged_kernel(
     # inputs / outputs / scratch, in this order:
     #   qf, knf, vnf, k_hbm, v_hbm [, ks_hbm, vs_hbm row slabs] | of
     #   | kbuf, vbuf, sems, slot [, ksbuf, vsbuf, ssems]
-    #   [, qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem]
+    #   [, qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem
+    #    [, qhm, khm, vhm, m_ref, l_ref, acc_ref]]
     # A one-token block (``bq`` 1: a plain decode call) finds qf/knf/vnf/of
-    # whole in VMEM; the 8-token block streams its own through the last
-    # group of scratch.
+    # whole in VMEM; a longer block streams its own through the second
+    # group of scratch, and a LONG one (``bq`` over 8: a chunk row's) keeps
+    # its queries, a step's keys and values and the softmax's state a kv
+    # head in the last.
     *refs,
     scale: float,
     page_size: int,
@@ -164,7 +186,7 @@ def _ragged_kernel(
         ks_hbm, vs_hbm = scale_slabs
         ksbuf, vsbuf, ssems, *rest = rest
     if not resident:
-        qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem = rest
+        qbuf, knbuf, vnbuf, obuf, fsems, qsem, osem, *long_scratch = rest
     b = pl.program_id(0)
     NB = brow_ref.shape[0]
     r = brow_ref[b]
@@ -275,6 +297,22 @@ def _ragged_kernel(
         @pl.when(nchunks == 0)
         def _():
             start_next_block(slot0)
+
+        if BQ > 8:
+            _long_block(
+                i, qlen_r, hist_r, nchunks, slot0, kbuf, vbuf, qbuf, obuf,
+                knbuf, vnbuf, *long_scratch,
+                ksbuf=ksbuf if quantized else None,
+                vsbuf=vsbuf if quantized else None,
+                scale=scale, q_copy=qcp, fresh_dma=fresh_dma,
+                chunk_dma=functools.partial(chunk_dma, r, npages),
+                start_next_block=start_next_block)
+            ocp = pltpu.make_async_copy(
+                obuf, of.at[pl.ds(base, BQ)], osem
+            )
+            ocp.start()
+            ocp.wait()
+            return
 
         if resident:
             q = qf[pl.ds(base, BQ)]
@@ -449,10 +487,232 @@ def _ragged_kernel(
             ocp.wait()
 
 
+def _long_block(
+    i, qlen_r, hist_r, nchunks, slot0,
+    kbuf, vbuf,    # VMEM [2, C, P, KVH, D] a history step's pages, two slots
+    qbuf,          # VMEM [BQ, KVH, G, D] the block's queries (in flight)
+    obuf,          # VMEM [BQ, KVH, G, D] the block's output
+    knbuf, vnbuf,  # VMEM [KB, KVH, D] a step of the row's fresh K/V (step
+                   # 0 in flight)
+    qhm,           # VMEM [KVH, BQ * G, D] the queries, head-major
+    khm, vhm,      # VMEM [KVH, S, D] the step's keys and values, head-major
+    m_ref, l_ref,  # VMEM [KVH, BQ * G, 1] the running maximum and sum
+    acc_ref,       # VMEM [KVH, BQ * G, D] the unnormalised output
+    *, ksbuf, vsbuf, scale, q_copy, fresh_dma, chunk_dma, start_next_block,
+):
+    """One LONG query block of a chunk row: each kv head's queries ``[BQ x
+    group, D]`` meet that head's keys of a step alone, ``[S, D]``, a head at
+    a time under a ``fori_loop``: dense products, no block-diagonal query and
+    no zero block multiplied.
+
+    The history comes in steps of ``S = C x P`` tokens (one DMA a page into
+    one of two slots, the next step in flight while this one is computed,
+    the next block's first step started before this block's last: the
+    8-token block's walk with a wider step), then the row's fresh keys in
+    steps of ``KB``, causal, as far as some query of the block can see.  A
+    step's keys are laid out head-major once (``[S, KVH, D] -> [KVH, S,
+    D]``: a reshape and lane-aligned slices), which frees the fresh keys'
+    buffer for the next step's DMA while this one is computed.  The online
+    softmax's state is per kv head, in VMEM across the steps; a step pays
+    for its two reductions over the lanes, its ``alpha`` and the
+    accumulator's rescale whatever its width (14 us at 1,024 query rows and
+    4 kv heads, what 2,000 keys of scores cost: PERF.md section 6, PR 52),
+    and for its products by its width whatever it holds, so a step is 1,024
+    tokens and not what VMEM would hold.
+    Products run in the queries' dtype with float32 accumulation (int8 codes
+    widened exactly; their scales multiply the scores and the probabilities
+    as one ``[1, S]`` row a head), statistics in float32."""
+    KVH, RQ, D = qhm.shape
+    BQ, G = qbuf.shape[0], qbuf.shape[2]
+    S, KB = khm.shape[1], knbuf.shape[0]
+    dot_dtype = qhm.dtype
+    # one MXU mode for bf16 operands, whatever the process-wide matmul
+    # precision (the flash kernel's note)
+    prec = (jax.lax.Precision.DEFAULT if dot_dtype == jnp.bfloat16
+            else None)
+
+    def relay(flat, dst, keep=None):
+        """``flat [n, KVH * D]`` to ``dst[:, :n]`` (``[KVH, S, D]``); of the
+        rows at and past ``keep`` zeros."""
+        n = flat.shape[0]
+        if flat.dtype != dot_dtype:
+            flat = flat.astype(jnp.float32)
+        if keep is not None:
+            flat = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) < keep,
+                flat, 0)
+        for k in range(KVH):
+            dst[k, pl.ds(0, n)] = flat[:, k * D:(k + 1) * D].astype(
+                dot_dtype)
+
+    def heads(ok, n, ks=None, vs=None):
+        """One step of the online softmax, a kv head at a time, over the
+        first ``n`` keys and values of ``khm`` / ``vhm``; ``ok`` masks the
+        scores."""
+        def head(k, _):
+            s = jax.lax.dot_general(
+                qhm[k], khm[k, pl.ds(0, n)], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            ) * scale                                       # [RQ, n]
+            if ks is not None:
+                s = s * ks[pl.ds(k, 1)]
+            s = jnp.where(ok, s, DEFAULT_MASK_VALUE)
+            m_prev = m_ref[k]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[k] = m_new
+            l_ref[k] = alpha * l_ref[k] + jnp.sum(p, axis=-1, keepdims=True)
+            if vs is not None:
+                p = p * vs[pl.ds(k, 1)]
+            acc_ref[k] = acc_ref[k] * alpha + jax.lax.dot_general(
+                p.astype(dot_dtype), vhm[k, pl.ds(0, n)],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+            return 0
+
+        jax.lax.fori_loop(0, KVH, head, 0)
+
+    q_copy.wait()
+    # (sliced and reshaped in float32, whose (8, 128) tile a group of 8
+    # fills; the dots run in the queries' dtype)
+    q = qbuf[...].astype(jnp.float32)                       # [BQ, KVH, G, D]
+    for k in range(KVH):
+        qhm[k] = q[:, k].reshape(RQ, D).astype(dot_dtype)
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    # ---- history pages, S tokens a step --------------------------------
+    def body(ci, _):
+        slot = jax.lax.rem(slot0 + ci, 2)
+
+        @pl.when(ci + 1 < nchunks)
+        def _():
+            chunk_dma(ci + 1, 1 - slot, True)
+
+        @pl.when(ci + 1 == nchunks)
+        def _():
+            start_next_block(1 - slot)
+
+        chunk_dma(ci, slot, False)
+        token0 = ci * S
+        relay(kbuf[slot].reshape(S, KVH * D), khm)
+        # pages past the row's history were never fetched: their scores are
+        # masked, but 0 * NaN would still poison the values' product
+        relay(vbuf[slot].reshape(S, KVH * D), vhm, keep=hist_r - token0)
+        tok = token0 + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        heads(tok < hist_r, S,
+              None if ksbuf is None else ksbuf.at[slot],
+              None if vsbuf is None else vsbuf.at[slot])
+        return 0
+
+    jax.lax.fori_loop(0, nchunks, body, 0)
+
+    # ---- fresh tokens of this row, KB keys a step (causal) -------------
+    # keys 0 .. last_q - 1 are visible to some query of the block
+    last_q = jnp.minimum(i * BQ + BQ, qlen_r)
+    nfresh = jax.lax.div(last_q + KB - 1, KB)
+    # query rows are [token, group]-major
+    q_off_row = i * BQ + jax.lax.broadcasted_iota(
+        jnp.int32, (RQ, 1), 0) // G
+
+    def fresh_body(j, _):
+        fresh_dma(j, False)
+        relay(knbuf[...].reshape(KB, KVH * D), khm)
+        # past the row lie the NEXT row's fresh tokens (or flat padding),
+        # which may be NaN: zero V out-of-row
+        relay(vnbuf[...].reshape(KB, KVH * D), vhm, keep=qlen_r - j * KB)
+
+        @pl.when(j + 1 < nfresh)
+        def _():
+            fresh_dma(j + 1, True)      # lands behind this step's products
+
+        kv_off = j * KB + jax.lax.broadcasted_iota(jnp.int32, (1, KB), 1)
+        heads((kv_off < qlen_r) & (kv_off <= q_off_row), KB)  # [RQ, KB]
+        return 0
+
+    jax.lax.fori_loop(0, nfresh, fresh_body, 0)
+
+    def normalise(k, _):
+        # (l >= 1: a row's maximum counts 1, and block-tail padding past the
+        # row, which sees no key at all, stays finite)
+        out = acc_ref[k] / l_ref[k]                           # [RQ, D]
+        obuf[:, k] = out.reshape(BQ, G, D).astype(obuf.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, KVH, normalise, 0)
+
+
+def vmem_scratch(held: list, shape, dtype):
+    """A VMEM scratch buffer, its bytes there appended to ``held``: the minor
+    pair padded to the dtype's tile (4 kv heads in bf16 are a quarter of a
+    (16, 128) one)."""
+    size = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // size)
+    held.append(math.prod(shape[:-2]) * -(-shape[-2] // sub) * sub
+                * -(-shape[-1] // 128) * 128 * size)
+    return pltpu.VMEM(shape, dtype)
+
+
 def query_block(max_q_len: int) -> int:
-    """Tokens in a query block, from the static bound on a row's fresh
-    tokens: a plain decode call's rows are their own one-token blocks."""
+    """Tokens in a query block of the LATENT kernel (``ops/mla_kernel.py``),
+    and of this one where a row cannot be long, from the static bound on a
+    row's fresh tokens: a plain decode call's rows are their own one-token
+    blocks, anything else is 8-token blocks."""
     return 1 if max_q_len == 1 else 8
+
+
+# query rows a kv head and product the long block aims at (``BQ x group``):
+# a block of keys is the MXU's stationary operand for that many rows
+CHUNK_QUERY_ROWS = 1024
+# history tokens a step of the long block's walk, and fresh keys a step: a
+# step costs its statistics whatever its width and its products by its width
+# whatever it holds, so 512 lost to the first and 2,048 to the second at
+# histories under it (PERF.md section 6, PR 52)
+LONG_STEP_TOKENS = 1024
+LONG_FRESH_TOKENS = 512
+
+
+def chunk_query_block(max_q_len: int, group: int) -> int:
+    """Tokens in a chunk row's query block, from the static bound on a row's
+    fresh tokens (the bucket) and the query heads a kv head: about
+    ``CHUNK_QUERY_ROWS`` query rows a product (128 tokens at a group of 8),
+    never more than the bucket holds, a multiple of 8.  A row of ``n`` tokens
+    makes ``ceil(n / block)`` blocks, and as many under any bucket that holds
+    it (a bucket under the cap is one block)."""
+    cap = max(8, CHUNK_QUERY_ROWS // (-(-group // 8) * 8) // 8 * 8)
+    return max(8, min(cap, max_q_len // 8 * 8))
+
+
+def paged_query_block(max_q_len: int, group: int, rows: int,
+                      tokens: int) -> int:
+    """Tokens in a query block of ``ragged_paged_attention_tpu``, from what
+    a call can see before it runs: the static bound on a row's fresh tokens,
+    the query heads a kv head, the rows the segment can hold and its flat
+    tokens.
+
+    - 1 for a plain decode call (``max_q_len`` 1): the resident form.
+    - ``chunk_query_block`` for a segment of ONE row (a prompt's chunk): 128
+      tokens at a group of 8 or under (groups are padded to a sublane tile of
+      8), 64 at a group of 16, never more than the bucket; under 16 tokens (a
+      verify row of ``1 + k``) the 8-token block.
+    - a segment of SEVERAL rows shares its tokens between them, so its rows
+      cannot all be long: the power of two at or above ``tokens / rows``,
+      where that is under the one-row block (a wave of 32 rows in a 64-token
+      bucket keeps the 8-token block; 32 rows in a 512-token bucket take 16,
+      12 rows 64): such a segment walks at most ``2 x rows`` blocks however
+      its tokens are dealt, and a short row does not pay a 128-token block.
+
+    A block over 8 tokens runs the long form (``_long_block``)."""
+    if max_q_len == 1:
+        return 1
+    block = chunk_query_block(max_q_len, group)
+    if rows > 1:
+        share = -(-tokens // rows)
+        block = min(block, max(8, 1 << (share - 1).bit_length()))
+    return block
 
 
 def live_query_blocks(q_len, bq: int, tokens: int):
@@ -494,8 +754,11 @@ def ragged_paged_attention_tpu(
     """Returns ``out [T, H, D]``.  Rows may start at any offset; the
     flat axis is padded internally so partial query blocks never DMA out
     of bounds.  ``max_q_len``, a static bound on any row's fresh tokens
-    (default: T), picks the query block (``query_block``): 1 token for a
-    plain decode call, else 8.
+    (default: T), picks the query block with the group, the rows and the
+    tokens (``paged_query_block``): 1 token for a plain decode call, 8 for
+    rows that can only be short, else a chunk row's long block.  Int8 pools
+    and packed heads (``ops/paged.py::pack_heads``) take the long block by
+    the same form: Mosaic refused neither.
 
     Tiered-residency metadata (``span_lo``/``span_hi``/``cold_*`` from
     the streamed cold-middle path) is rejected here: this kernel walks
@@ -516,7 +779,9 @@ def ragged_paged_attention_tpu(
     if not interpret:
         check_geometry(H, KVH, D, k_pages.dtype.itemsize)
     group = H // KVH
-    BQ = query_block(T if max_q_len is None else min(max_q_len, T))
+    BQ = paged_query_block(
+        T if max_q_len is None else min(max_q_len, T), group, R, T)
+    long = BQ > 8
     # Mosaic tiles the (group, D) minor pair of the q/o blocks: a group
     # that is neither a whole sublane tile nor a power-of-two fraction of
     # one (Qwen2-7B: 28/4 = 7) is refused, so pad it with zero query heads
@@ -531,6 +796,12 @@ def ragged_paged_attention_tpu(
     # fresh keys a step: the one-token block's own token in a sublane
     # tile of its neighbours, the 8-token block's in chunks of 128
     KB = 8 if BQ == 1 else 128
+    if long:
+        # the long block's steps: history in ``LONG_STEP_TOKENS`` (no wider
+        # than a table's tokens to the lanes' tile), fresh keys in
+        # ``LONG_FRESH_TOKENS``
+        C = max(1, min(LONG_STEP_TOKENS, -(-maxP * P // 128) * 128) // P)
+        KB = min(C * P, LONG_FRESH_TOKENS)
     # pad so that neither the last row's final (possibly unaligned,
     # possibly partial) query block nor its last block of fresh keys
     # leaves the flat axis
@@ -561,6 +832,9 @@ def ragged_paged_attention_tpu(
         # written back after the last
         return pl.BlockSpec(shape, lambda b, *_: (0,) * len(shape))
 
+    held = []           # (a long block sets its VMEM limit from these)
+    vmem = functools.partial(vmem_scratch, held)
+
     vmem_limit = None
     if BQ == 1:
         q_spec = out_spec = whole((Tpad, KVH, G, D))
@@ -568,34 +842,48 @@ def ragged_paged_attention_tpu(
         # four such blocks, double-buffered, each token's minor pair padded
         # to a tile of at most 16 sublanes: a wide decode batch outgrows
         # the compiler's 16 MiB default
-        held = 8 * Tpad * KVH * 16 * D * q.dtype.itemsize
-        vmem_limit = min(max(16 << 20, held + (8 << 20)), 100 << 20)
+        resident = 8 * Tpad * KVH * 16 * D * q.dtype.itemsize
+        vmem_limit = min(max(16 << 20, resident + (8 << 20)), 100 << 20)
     else:
         q_spec = out_spec = new_spec = any_spec
     in_specs = [q_spec, new_spec, new_spec] + [any_spec] * (
         4 if quantized else 2)
     scratch = [
-        pltpu.VMEM((2, C, P, KVH, D), k_pages.dtype),       # kbuf
-        pltpu.VMEM((2, C, P, KVH, D), v_pages.dtype),       # vbuf
+        vmem((2, C, P, KVH, D), k_pages.dtype),             # kbuf
+        vmem((2, C, P, KVH, D), v_pages.dtype),             # vbuf
         pltpu.SemaphoreType.DMA((2, 2)),                    # sems
         pltpu.SMEM((1,), jnp.int32),                        # slot
     ]
     if quantized:
         scratch += [
-            pltpu.VMEM((2, KVH, C * P), jnp.float32),       # ksbuf
-            pltpu.VMEM((2, KVH, C * P), jnp.float32),       # vsbuf
+            vmem((2, KVH, C * P), jnp.float32),             # ksbuf
+            vmem((2, KVH, C * P), jnp.float32),             # vsbuf
             pltpu.SemaphoreType.DMA((2, 2)),                # ssems
         ]
     if BQ != 1:
         scratch += [
-            pltpu.VMEM((BQ, KVH, G, D), q.dtype),           # qbuf
-            pltpu.VMEM((KB, KVH, D), k_new.dtype),          # knbuf
-            pltpu.VMEM((KB, KVH, D), v_new.dtype),          # vnbuf
-            pltpu.VMEM((BQ, KVH, G, D), q.dtype),           # obuf
+            vmem((BQ, KVH, G, D), q.dtype),                 # qbuf
+            vmem((KB, KVH, D), k_new.dtype),                # knbuf
+            vmem((KB, KVH, D), v_new.dtype),                # vnbuf
+            vmem((BQ, KVH, G, D), q.dtype),                 # obuf
             pltpu.SemaphoreType.DMA((2,)),                  # fsems
             pltpu.SemaphoreType.DMA(()),                    # qsem
             pltpu.SemaphoreType.DMA(()),                    # osem
         ]
+    if long:
+        RQ = BQ * G
+        scratch += [
+            vmem((KVH, RQ, D), q.dtype),                    # qhm
+            vmem((KVH, C * P, D), q.dtype),                 # khm
+            vmem((KVH, C * P, D), q.dtype),                 # vhm
+            vmem((KVH, RQ, 1), jnp.float32),                # m
+            vmem((KVH, RQ, 1), jnp.float32),                # l
+            vmem((KVH, RQ, D), jnp.float32),                # acc
+        ]
+        # beside them a head's scores, mask and probabilities, and a step's
+        # pages on their way to the head-major layout
+        held.append(RQ * C * P * 4 * 4 + 2 * C * P * KVH * D * 4)
+        vmem_limit = min(max(16 << 20, sum(held) + (8 << 20)), 100 << 20)
     inputs = (qg, k_new, v_new, k_pages, v_pages)
     if quantized:
         # The scale pools are lane-dense ``[L, N, KVH*P]`` (head-major in

@@ -50,25 +50,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
-from helix_tpu.ops.paged_kernel import check_geometry, live_query_blocks
+from helix_tpu.ops.paged_kernel import (
+    check_geometry, chunk_query_block, live_query_blocks, vmem_scratch,
+)
 
 
-# query rows a kv head and product the chunk form aims at (``BQ x group``):
-# a block of keys is the MXU's stationary operand for that many rows
-CHUNK_QUERY_ROWS = 1024
 # rows a step when a row's ring and fresh keys are laid out head-major
 _RELAY = 128
-
-
-def chunk_query_block(max_q_len: int, group: int) -> int:
-    """Tokens in a chunk row's query block, from the static bound on a row's
-    fresh tokens (the bucket) and the query heads a kv head: about
-    ``CHUNK_QUERY_ROWS`` query rows a product (128 tokens at a group of 8),
-    never more than the bucket holds, a multiple of 8.  A row of ``n`` tokens
-    makes ``ceil(n / block)`` blocks, and as many under any bucket that holds
-    it (a bucket under the cap is one block)."""
-    cap = max(8, CHUNK_QUERY_ROWS // (-(-group // 8) * 8) // 8 * 8)
-    return max(8, min(cap, max_q_len // 8 * 8))
 
 
 def _window_kernel(
@@ -485,16 +473,7 @@ def window_attention_tpu(
         return pl.BlockSpec(shape, lambda b, *_: (0,) * len(shape))
 
     held = []
-
-    def vmem(shape, dtype):
-        """A VMEM scratch buffer and its bytes there: the minor pair padded
-        to the dtype's tile (a ring of 4 kv heads in bf16 is a quarter of a
-        (16, 128) one)."""
-        size = jnp.dtype(dtype).itemsize
-        sub = 8 * (4 // size)
-        held.append(math.prod(shape[:-2]) * -(-shape[-2] // sub) * sub
-                    * -(-shape[-1] // 128) * 128 * size)
-        return pltpu.VMEM(shape, dtype)
+    vmem = functools.partial(vmem_scratch, held)
 
     scratch = [
         vmem((2, W + again, KVH, D), k_ring.dtype),         # kbuf

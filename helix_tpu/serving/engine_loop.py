@@ -1769,6 +1769,9 @@ class EngineLoop:
             # tokens in a query block of the state segment's attention
             # call: 1 for plain decode, 8 under speculation
             "attn_q_block": getattr(eng, "attn_q_block", 0),
+            # ... and of the prefill segment's paged call in the last launch
+            # that had one (``Engine.prefill_q_block``; 0 before any)
+            "chunk_q_block": getattr(eng, "chunk_q_block", 0),
             # this step's programs in which prefill rows and state rows
             # shared one pass over the layers (1 for a wave, a chunk or a
             # mixed step; a step of several waves counts each), and the

@@ -500,6 +500,13 @@ class OpenAIServer:
                     "helix_attn_page_bytes_read_total",
                     getattr(eng, "attn_page_bytes_read", 0), lbl,
                 )
+                # the programs that kernel ran for launches' chunk rows over
+                # the full layers (a row's ``ceil(tokens / block)`` a layer,
+                # the block the kernel's own: 4 a 512-token row at 128)
+                c.counter(
+                    "helix_attn_query_blocks_total",
+                    getattr(eng, "attn_query_blocks", 0), lbl,
+                )
             mixer = getattr(eng, "mixer", None)
             if mixer is not None:
                 if mixer.snapshots:

@@ -937,6 +937,10 @@ def test_page_fetches_are_counted_from_the_hosts_mirrors():
     by_hand = [0, 2 * 2 * L, 4 * 1 * L, 5 * L, 6 * L, 6 * L]
     assert seen == by_hand, seen
     assert eng.num_mla_page_fetches == sum(by_hand)
+    # the latent kernel's block stays 8 tokens whatever the bucket (the dense
+    # kernel's long block is not its own), and the dense kernel ran nothing
+    assert [eng.prefill_q_block(rung, 1) for rung in (8, 16, 512)] == [8] * 3
+    assert eng.chunk_q_block == 8 and eng.attn_query_blocks == 0
     registry = ModelRegistry()
     registry.register(ServedModel(
         name="tiny-mla", loop=EngineLoop(eng, "tiny-mla"),
@@ -945,3 +949,4 @@ def test_page_fetches_are_counted_from_the_hosts_mirrors():
     line = next(ln for ln in text.splitlines()
                 if ln.startswith("helix_mla_page_fetches_total{"))
     assert float(line.rsplit(" ", 1)[1]) == sum(by_hand)
+    assert "helix_attn_query_blocks_total" not in text

@@ -137,19 +137,31 @@ def _pools(key, shape, int8: bool):
 
 
 def _assert_kernel_matches_reference(keys, T, H, KVH, D, pools, layer,
-                                     t0, q_len, hist, tables, **kernel_kw):
-    """Interpret-mode kernel against the gather reference, row by row."""
+                                     t0, q_len, hist, tables, pack=1,
+                                     kernel=ragged_paged_attention_tpu,
+                                     **kernel_kw):
+    """Interpret-mode kernel against the gather reference, row by row.
+    ``pack``: kv heads of width ``D`` a 128-lane tile of the pool the KERNEL
+    is handed (``ops/paged.py::pack_heads``); the reference reads the same
+    pool as ``[P, KVH, D]``, packing nothing."""
     k_pages, v_pages, scales = pools
     q = jax.random.normal(keys[0], (T, H, D), jnp.float32)
     k_new = jax.random.normal(keys[1], (T, KVH, D), jnp.float32)
     v_new = jax.random.normal(keys[2], (T, KVH, D), jnp.float32)
-    args = (
-        q, k_new, v_new, k_pages, v_pages, jnp.int32(layer),
-        *(jnp.asarray(x, jnp.int32) for x in (t0, q_len, hist, tables)),
-    )
+    meta = (jnp.int32(layer),
+            *(jnp.asarray(x, jnp.int32) for x in (t0, q_len, hist, tables)))
+    args = (q, k_new, v_new, k_pages, v_pages, *meta)
     want = ragged_paged_attention_reference(*args, **scales)
-    got = ragged_paged_attention_tpu(
-        *args, interpret=True, **scales, **kernel_kw)
+    if pack > 1:
+        from helix_tpu.ops.paged import pack_heads, unpack_heads
+
+        packed = k_pages.shape[:3] + (KVH // pack, pack * D)
+        got = unpack_heads(kernel(
+            *pack_heads(q, k_new, v_new, pack), k_pages.reshape(packed),
+            v_pages.reshape(packed), *meta, scale=D ** -0.5,
+            interpret=True, **kernel_kw), pack, KVH)
+    else:
+        got = kernel(*args, interpret=True, **scales, **kernel_kw)
     for r in range(len(q_len)):
         s0, ql = int(t0[r]), int(q_len[r])
         if ql == 0:
@@ -212,7 +224,166 @@ def _layout_case(rng, name, *, int8: bool):
         max_q_len=bound)
 
 
+# A chunk row's LONG block (``paged_query_block`` over 8 tokens:
+# ``_long_block``): (query heads, kv heads, head width, page, pages a table,
+# [(fresh tokens, history, flat positions skipped before the row)], static
+# bound, pool, kv heads a lane tile, history step | None for the module's).
+# Steps of 128 tokens keep the histories short: 256 ends on a step's edge,
+# 300 crosses two.
+_LONG_ROWS = {
+    # 4 + 2 + 2 + 1 blocks of 128 tokens, rows at unaligned offsets
+    "rows_of_512_256_136_and_9_tokens_at_a_group_of_8": (
+        16, 2, 64, 16, 20,
+        [(512, 300, 3), (256, 256, 5), (136, 0, 1), (9, 37, 6)],
+        512, "float32", 1, 128),
+    "a_group_of_7_padded_to_8": (
+        14, 2, 32, 16, 20, [(136, 200, 0), (9, 50, 3)], 136, "float32", 1,
+        128),
+    "a_group_of_6_padded_to_8": (
+        12, 2, 32, 16, 20, [(136, 256, 2), (9, 0, 0)], 136, "float32", 1,
+        128),
+    "a_group_of_4_padded_to_8": (
+        8, 2, 32, 16, 20, [(136, 129, 0), (9, 300, 1)], 136, "float32", 1,
+        128),
+    "a_group_of_16_in_blocks_of_64": (
+        32, 2, 32, 16, 20, [(136, 300, 0), (9, 128, 7)], 136, "float32", 1,
+        128),
+    "two_kv_heads_of_width_64_a_lane_tile": (
+        8, 4, 64, 16, 20, [(136, 270, 0), (40, 0, 3)], 136, "float32", 2,
+        128),
+    "an_int8_pool": (
+        16, 2, 32, 16, 20, [(200, 300, 0), (9, 256, 5)], 200, "int8", 1, 128),
+    # eight rows share a 128-token bucket: blocks of 16 tokens
+    "a_wave_of_short_rows_with_history": (
+        16, 2, 32, 16, 20,
+        [(5, 0, 0), (40, 130, 0), (1, 17, 0), (16, 128, 0), (9, 300, 0),
+         (3, 64, 0), (30, 5, 0), (8, 200, 0)], 128, "float32", 1, 128),
+    # the module's own step (1,024 tokens, fresh keys 512 a step) over pages
+    # of 128: two whole steps and a part of a third
+    "a_history_of_three_steps_at_the_modules_width": (
+        16, 2, 32, 128, 24, [(136, 2348, 0)], 136, "float32", 1, None),
+}
+
+
+def _long_rows_case(rng, name, monkeypatch):
+    from helix_tpu.ops import paged_kernel
+
+    H, KVH, D, P, maxP, rows, bound, pool, pack, step = _LONG_ROWS[name]
+    if step:
+        monkeypatch.setattr(paged_kernel, "LONG_STEP_TOKENS", step)
+        monkeypatch.setattr(paged_kernel, "LONG_FRESH_TOKENS", step)
+    L, R = 2, len(rows)
+    N = R * maxP + 1
+    q_len = [n for n, _, _ in rows]
+    t0 = np.cumsum([skip + (rows[r - 1][0] if r else 0)
+                    for r, (_, _, skip) in enumerate(rows)])
+    T = int(t0[-1] + q_len[-1])
+    if len(rows) > 4:
+        T = bound                      # a wave: the bucket's flat tokens
+    ks = jax.random.split(jax.random.fold_in(rng, len(name)), 4)
+    pools = _pools(ks[0], (L, N, P, KVH, D), pool == "int8")
+    tables = np.random.default_rng(len(name)).permutation(
+        np.arange(1, N))[: R * maxP].reshape(R, maxP)
+    assert paged_kernel.paged_query_block(
+        min(bound, T), H * pack // KVH, R, T) > 8
+    # (not the jitted entry: a module constant is read when a call is traced)
+    _assert_kernel_matches_reference(
+        ks[1:], T, H, KVH, D, pools, 1, t0, q_len, [h for _, h, _ in rows],
+        tables, pack=pack,
+        kernel=ragged_paged_attention_tpu.__wrapped__, max_q_len=bound)
+
+
+# the programs a call's grid walks (``live_query_blocks``: the most a
+# segment of these tokens and rows can make) under the engine's shapes:
+# (bound on a row's tokens, group, rows, flat tokens) -> (block, programs)
+_BLOCKS = {
+    "decode_rows": ((1, 7, 32, 32), (1, 32)),
+    "verify_rows_of_5": ((5, 7, 32, 160), (8, 20 + 32)),
+    "one_chunk_row_of_512": ((512, 8, 1, 512), (128, 4 + 1)),
+    "one_chunk_row_at_a_group_of_6": ((512, 6, 1, 512), (128, 4 + 1)),
+    "one_chunk_row_at_a_group_of_4": ((512, 4, 1, 512), (128, 4 + 1)),
+    "one_chunk_row_at_a_group_of_16": ((512, 16, 1, 512), (64, 8 + 1)),
+    "a_final_chunk_in_a_bucket_of_128": ((128, 8, 1, 128), (128, 1 + 1)),
+    "a_final_chunk_in_a_bucket_of_8": ((8, 8, 1, 8), (8, 1 + 1)),
+    # a wave's rows share its tokens: the power of two at or above
+    # tokens / rows, and never more than 2 x rows programs
+    "a_wave_of_32_rows_in_64_tokens": ((64, 8, 32, 64), (8, 8 + 32)),
+    "a_wave_of_32_rows_in_512_tokens": ((512, 8, 32, 512), (16, 32 + 32)),
+    "a_wave_of_12_rows_in_512_tokens": ((512, 8, 12, 512), (64, 8 + 12)),
+    "a_wave_of_2_rows_in_512_tokens": ((512, 8, 2, 512), (128, 4 + 2)),
+}
+
+# sha256 of ``ragged_paged_attention_tpu.lower(...).as_text()`` (interpret
+# mode, no locations, under ``jax.default_matmul_precision("highest")`` as
+# ``conftest.py`` sets it) at commit 637cd06, BEFORE the long block: (flat
+# tokens, rows, bound, pool) of a decode call, a verify call of 5-token
+# rows and a wave of 32 rows in a 64-token bucket
+_LOWERED_AT_THE_PARENT = {
+    (6, 6, 1, "bfloat16"): "5446ccb9529ea00d",
+    (6, 6, 1, "int8"): "0910d30b6d4daf93",
+    (30, 6, 5, "bfloat16"): "680db32a41f86f84",
+    (30, 6, 5, "int8"): "e644a144412c4449",
+    (64, 32, 64, "bfloat16"): "0f0aa2d68427590a",
+}
+
+
 class TestRaggedOpParity:
+    @pytest.mark.parametrize("name", sorted(_LONG_ROWS))
+    def test_long_block_matches_reference(self, rng, name, monkeypatch):
+        """A chunk row's long query block, one kv head at a time over wide
+        history steps: every group a cell sends, packed heads, an int8 pool,
+        rows at unaligned offsets, histories of 0, on a step's edge and
+        across several steps, a wave of short rows."""
+        _long_rows_case(rng, name, monkeypatch)
+
+    @pytest.mark.parametrize("name", sorted(_BLOCKS))
+    def test_block_and_programs_follow_what_a_call_sees(self, name):
+        """``paged_query_block`` from the static facts of a call, and the
+        programs its grid then walks: a 512-token chunk row is 4 to 8
+        programs (64 under the 8-token block), and a wave of short rows does
+        not pay a long block a row."""
+        from helix_tpu.ops.paged_kernel import (
+            chunk_query_block, live_query_blocks, paged_query_block,
+        )
+
+        (bound, group, rows, tokens), (block, programs) = _BLOCKS[name]
+        assert paged_query_block(bound, group, rows, tokens) == block
+        q_len = jnp.zeros((rows,), jnp.int32).at[0].set(min(bound, tokens))
+        brow, bidx = live_query_blocks(q_len, block, tokens)
+        assert brow.shape == (programs,)
+        live = -(-min(bound, tokens) // block)
+        assert int((brow >= 0).sum()) == live
+        assert list(np.asarray(bidx[:live])) == list(range(live))
+        if rows > 1 and block < chunk_query_block(bound, group):
+            assert programs <= 2 * rows
+
+    @pytest.mark.parametrize(
+        "case", sorted(_LOWERED_AT_THE_PARENT), ids=lambda c: "-".join(
+            str(x) for x in c))
+    def test_short_rows_lower_to_the_text_they_lowered_to(self, case):
+        """The one-token resident form and the 8-token form are untouched by
+        the long block: a decode call, a verify call and a wave of rows that
+        can only be short lower to the parent's text, byte for byte."""
+        import hashlib
+
+        T, R, bound, pool = case
+        H, KVH, D, L, N, P, maxP = 8, 2, 128, 2, 40, 16, 12
+        S = jax.ShapeDtypeStruct
+        pages = S((L, N, P, KVH, D), jnp.dtype(pool))
+        scales = {}
+        if pool == "int8":
+            scales = dict(k_scale=S((L, N, KVH * P), jnp.float32),
+                          v_scale=S((L, N, KVH * P), jnp.float32))
+        i32 = lambda *shape: S(shape, jnp.int32)  # noqa: E731
+        with jax.default_matmul_precision("highest"):
+            text = ragged_paged_attention_tpu.lower(
+                S((T, H, D), jnp.bfloat16), S((T, KVH, D), jnp.bfloat16),
+                S((T, KVH, D), jnp.bfloat16), pages, pages, i32(), i32(R),
+                i32(R), i32(R), i32(R, maxP), max_q_len=bound,
+                interpret=True, **scales).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+            _LOWERED_AT_THE_PARENT[case])
+
     @pytest.mark.parametrize("pool", ["float32", "int8"])
     @pytest.mark.parametrize("name", sorted(_LAYOUTS))
     def test_kernel_matches_reference_at_the_static_bound(
@@ -223,10 +394,14 @@ class TestRaggedOpParity:
         _layout_case(rng, name, int8=pool == "int8")
 
     def test_query_block_follows_the_static_bound(self):
-        from helix_tpu.ops.paged_kernel import query_block
+        from helix_tpu.ops.paged_kernel import paged_query_block, query_block
 
         assert query_block(1) == 1
         assert [query_block(n) for n in (2, 4, 8, 512)] == [8] * 4
+        # this kernel's own: rows that can only be short keep those two
+        assert paged_query_block(1, 7, 32, 32) == 1
+        assert [paged_query_block(n, 7, 32, 32 * n) for n in (2, 4, 8)] == [
+            8] * 3
 
     def test_kernel_matches_reference_random_layout(self, rng):
         """One randomized ragged layout through interpret-mode pallas

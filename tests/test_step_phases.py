@@ -317,6 +317,48 @@ def test_every_flight_record_carries_the_state_segments_query_block(stepped):
     assert stepped.engine.attn_q_block == 1
 
 
+def test_every_flight_record_carries_the_chunk_segments_query_block(stepped):
+    """... and the prefill segment's block in the last launch that had one
+    (``Engine.prefill_q_block``: the paged kernel's own, from the bucket,
+    the group and the rows): 0 before any, 16 for a one-row chunk in the
+    bucket of 16, 8 where two rows share it or the bucket is 8 or under."""
+    recs = stepped.flight.snapshot(recent=512)["recent"]
+    eng = stepped.engine
+    assert {rec["chunk_q_block"] for rec in recs} <= {0, 8, 16}
+    assert 16 in {rec["chunk_q_block"] for rec in recs}
+    assert recs[-1]["chunk_q_block"] == eng.chunk_q_block
+    assert [eng.prefill_q_block(rung, rows) for rung, rows in (
+        (16, 1), (16, 2), (8, 1), (4, 2))] == [16, 8, 8, 8]
+
+
+def test_the_paged_kernels_counters_follow_its_own_block(stepped):
+    """``helix_attn_query_blocks_total`` and
+    ``helix_attn_page_bytes_read_total`` count what the kernel's block
+    function gives: a launch whose rows have history adds, over the
+    attention layers, ``ceil(tokens / block)`` programs a row, and each
+    walks the row's pages of history once."""
+    import types
+
+    import numpy as np
+
+    from helix_tpu.engine.ragged import PrefillPlan
+
+    eng = stepped.engine
+    L, P = eng.model_cfg.num_attn_layers, eng.cache_cfg.page_size
+    none = np.zeros(0, np.int64)
+    for rung, rows, block in ((16, [(16, 32)], 16), (16, [(9, 5), (7, 8)], 8),
+                              (8, [(5, 64)], 8)):
+        plan = PrefillPlan(P, eng.cache_cfg.max_pages_per_seq, len(rows))
+        plan.rows = [types.SimpleNamespace(rem=n, start=h) for n, h in rows]
+        assert eng.prefill_q_block(rung, len(rows)) == block
+        assert eng._history_pages(plan, block, none, 0) == sum(
+            -(-h // P) * -(-n // block) for n, h in rows)
+    # a wave of two rows in the bucket of 16: 2 + 1 blocks of 8, and the
+    # one-row chunk of 16 over 32 tokens: 1 block over 8 pages
+    assert eng.attn_query_blocks % L == 0 and eng.attn_query_blocks > 0
+    assert eng.attn_page_bytes_read > 0
+
+
 def test_flight_records_carry_the_joint_pass_and_its_inert_rows(stepped):
     """A step that launched a program with a prefill segment (a wave, a
     chunk, a mixed step) says so, and how many state rows rode that pass
@@ -515,6 +557,23 @@ def test_capture_launch_span_says_whether_the_segments_shared_a_pass(capture):
 def test_capture_launch_span_carries_the_query_block(capture):
     _, _, events = capture
     assert {ln["attn_q_block"] for ln in events["helix.loop.launch"]} == {1}
+
+
+def test_capture_launch_span_carries_the_chunk_segments_block(capture):
+    """A launch with a prefill segment says its paged call's query block (a
+    one-row chunk in the bucket of 16: 16; two rows, or a bucket of 8 or
+    under: 8) and the programs the kernel ran for it; a decode launch has no
+    such segment."""
+    _, _, events = capture
+    launches = events["helix.loop.launch"]
+    with_rows = [ln for ln in launches if ln["prefill_rows"]]
+    assert with_rows
+    for ln in with_rows:
+        alone = ln["prefill_rows"] == 1 and ln["token_bucket"] == 16
+        assert ln["chunk_q_block"] == (16 if alone else 8), ln
+        assert (ln["attn_query_blocks"] > 0) == bool(ln["has_hist"]), ln
+    assert all("chunk_q_block" not in ln and ln["attn_query_blocks"] == 0
+               for ln in launches if not ln["prefill_rows"])
 
 
 def test_program_times_lists_the_launches_beside_the_programs(capture):

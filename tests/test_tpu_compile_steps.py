@@ -396,6 +396,8 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     # ... no copy of a ring pool (in any layout) around the kernel or the
     # loop, and nothing computed again
     assert not re.search(r"= bf16\[3,48,512,8,128\]\S* copy\(", text)
+    # ... nor of the page pool the paged kernel's long block walks
+    assert not re.search(r"= bf16\[1,2048,16,8,128\]\S* copy\(", text)
     assert ".remat" not in text
 
 
@@ -579,6 +581,8 @@ def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
     # ... no copy of a ring pool (in any layout) around the kernel or the
     # loop, and nothing computed again
     assert not re.search(r"= bf16\[3,12,1024,4,128\]\S* copy\(", text)
+    # ... nor of the page pool the paged kernel's long block walks
+    assert not re.search(r"= bf16\[1,6529,16,4,128\]\S* copy\(", text)
     assert ".remat" not in text
     weights = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize
@@ -587,3 +591,45 @@ def test_window_softmax_step_compiles_at_published_widths(one_chip, program):
     # the arguments are the weights, the rings, the pages and the decode
     # state (its 12 x 98,304 token counts the most of it): nothing is padded
     assert held <= mem.argument_size_in_bytes < held + (8 << 20)
+
+
+# (query heads, kv heads, head width, pages a table) of the configurations
+# whose cells send the paged kernel a chunk row and have no whole step above:
+# the groups 7 -> 8, 4 -> 8, 4 packed to 8 and 16
+CHUNK_ROW_GEOMETRY = {
+    "qwen2-7b": (28, 4, 128, 64),
+    "mistral-7b": (32, 8, 128, 64),
+    "lfm2-8b-a1b": (32, 8, 64, 160),
+    "nemotron-3-super": (32, 2, 128, 160),
+}
+
+
+@pytest.mark.parametrize("rows", [1, 32], ids=["one_row", "a_wave_of_32"])
+@pytest.mark.parametrize("geometry", sorted(CHUNK_ROW_GEOMETRY))
+def test_paged_kernel_compiles_a_chunk_row_at_every_cells_geometry(
+        one_chip, geometry, rows):
+    """The paged kernel ALONE over a 512-token bucket with history, as the
+    dispatcher hands it each configuration's heads (the long block: 128
+    tokens at a group of 8 or under, 64 at 16; 16 where 32 rows share the
+    bucket), for the described chip: Mosaic takes the form, the pools are
+    read where they lie."""
+    from helix_tpu.ops.paged import ragged_paged_attention
+    from helix_tpu.ops.paged_kernel import paged_query_block
+
+    H, KVH, D, max_pages = CHUNK_ROW_GEOMETRY[geometry]
+    pack = 128 // D
+    T, L, pages = 512, 2, 2048
+
+    def S(shp, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    assert paged_query_block(T, H * pack // KVH, rows, T) == (
+        16 if rows > 1 else 64 if H * pack // KVH > 8 else 128)
+    pool = S((L, pages, PAGE, KVH // pack, D * pack), jnp.bfloat16)
+    compiled = jax.jit(lambda *a: ragged_paged_attention(
+        *a, backend="pallas", max_q_len=T)).lower(
+        S((T, H, D), jnp.bfloat16), S((T, KVH, D), jnp.bfloat16),
+        S((T, KVH, D), jnp.bfloat16), pool, pool, S(()), S((rows,)),
+        S((rows,)), S((rows,)), S((rows, max_pages))).compile()
+    assert "ragged_paged_attention_tpu" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24

@@ -37,9 +37,8 @@ from helix_tpu.models.common import (  # noqa: E402
 from helix_tpu.models.llama import (  # noqa: E402
     forward, init_params, param_logical_axes, prefill_attn_fn,
 )
-from helix_tpu.ops.window_kernel import (  # noqa: E402
-    chunk_query_block, window_attention_tpu,
-)
+from helix_tpu.ops.paged_kernel import chunk_query_block  # noqa: E402
+from helix_tpu.ops.window_kernel import window_attention_tpu  # noqa: E402
 import window_cases  # noqa: E402
 
 FULL, SLIDE = "full_attention", "sliding_attention"
@@ -467,9 +466,11 @@ def test_calls_that_move_pages_are_refused_by_name(model, call):
 def test_the_page_bytes_and_the_context_a_launch_counts_by_hand(model):
     """``helix_attn_page_bytes_read_total``: a launch's history pages (a live
     decode row's ``ceil(position / page)``, a chunk row's ``ceil(start /
-    page)`` once a query block of 8) times a page's K and V over the FULL
+    page)`` once a query block of the paged kernel's own size:
+    ``Engine.prefill_q_block``) times a page's K and V over the FULL
     layers alone; ``context_tokens``: the decode rows' positions and a chunk
-    row's history and fresh tokens.  Both on the launch's span."""
+    row's history and fresh tokens; ``attn_query_blocks``: the programs the
+    paged kernel runs for the chunk rows.  All on the launch's span."""
     from helix_tpu.obs import trace as obs_trace
 
     cfg, params = model
@@ -491,15 +492,24 @@ def test_the_page_bytes_and_the_context_a_launch_counts_by_hand(model):
     finally:
         obs_trace.phase = orig
     launches = [kw for kw in seen if kw["kind"] != "warmup"]
-    # three chunks (16, 16, 5 tokens: 0, 2 and 4 pages of history, 2, 2 and
-    # 1 query blocks), then decode rows at positions 37..
+    # three chunks (16, 16, 5 tokens: 0, 2 and 4 pages of history; a bucket
+    # of 16 is ONE block of the long form, where the 8-token block made 2),
+    # then decode rows at positions 37..
     chunks = [kw for kw in launches if kw["prefill_rows"]]
+    assert [kw["chunk_q_block"] for kw in chunks] == [16, 16, 8]
+    assert [kw["chunk_q_block"] for kw in chunks] == [
+        eng.prefill_q_block(kw["token_bucket"], 1) for kw in chunks]
     assert [kw["attn_page_bytes"] for kw in chunks] == [
-        0, 2 * 2 * page, 4 * 1 * page]
+        0, 2 * 1 * page, 4 * 1 * page]
+    # (the first chunk has no history: the packed flash kernel runs it)
+    assert [kw["attn_query_blocks"] for kw in chunks] == [0, 1, 1]
+    assert eng.attn_query_blocks == 2 and eng.chunk_q_block == 8
     assert [kw["context_tokens"] for kw in chunks] == [16, 32, 37]
     decodes = [kw for kw in launches if not kw["prefill_rows"]]
     assert decodes and decodes[0]["context_tokens"] == 37
     assert decodes[0]["attn_page_bytes"] >= 5 * page
+    assert all(kw["attn_query_blocks"] == 0 and "chunk_q_block" not in kw
+               for kw in decodes)
     assert eng.attn_page_bytes_read == sum(
         kw["attn_page_bytes"] for kw in launches)
     assert all(kw["window_layers"] == 3 and kw["attn_layers"] == 1
@@ -554,6 +564,12 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
 
     assert value("helix_attn_page_bytes_read_total{") == (
         eng.attn_page_bytes_read)
+    # chunks of 16, 16 and 5 tokens: the two with history are one program
+    # of the paged kernel each in the one full layer
+    assert value("helix_attn_query_blocks_total{") == 2 == (
+        eng.attn_query_blocks)
+    assert {r["chunk_q_block"] for r in records} <= {0, 16, 8}
+    assert records[-1]["chunk_q_block"] == 8
     assert value("helix_step_context_tokens_count{") == len(records)
     assert value("helix_step_context_tokens_sum{") == sum(
         r["context_tokens"] for r in records)
