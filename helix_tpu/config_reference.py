@@ -51,8 +51,9 @@ ENV_REFERENCE: tuple = (
         "attention model (DeepSeek-V2-Lite) is not served with "
         "speculation, nor is one whose sequences carry a state (Mamba-2 "
         "layers among them) or a ring of K/V a slot (sliding-window "
-        "layers: the laguna and mellum families): enabling it is "
-        "refused at profile apply "
+        "layers: the laguna and mellum families), nor one with a sparse-"
+        "attention indexer (glm_moe_dsa: latent attention's refusal): "
+        "enabling it is refused at profile apply "
         "(UnsupportedForModel).",
         section="accelerator",
     ),
@@ -274,11 +275,16 @@ ENV_REFERENCE: tuple = (
         "max_model_len can exceed it — the demoted cold middle lives "
         "in the host pool; on a fully-resident engine it caps the "
         "whole sequence. Unset: the profile's engine block (default "
-        "128; the widest a benchmark profile sets is 544, 8,704 tokens a "
-        "sequence, for Mellum2-12B-A2.5B, of whose 28 layers only the 7 "
-        "full-attention ones keep pages: a page is 229,376 bytes there "
-        "and the 21 sliding-window layers keep a ring a slot, "
-        "ModelConfig.sliding_window 1,024 keys, whatever the length).",
+        "128; the widest a benchmark profile sets is 1,056, 16,896 tokens "
+        "a sequence, for GLM-5 (model_type glm_moe_dsa), where a page is a "
+        "page of TWO pools under one id, the latent pool and the index-key "
+        "pool of the sparse-attention indexer, ModelConfig.index_heads / "
+        "index_head_dim / index_topk: 196,608 bytes over its 8 layers; "
+        "then 544, 8,704 tokens, for Mellum2-12B-A2.5B, of whose 28 "
+        "layers only the 7 full-attention ones keep pages: a page is "
+        "229,376 bytes there and the 21 sliding-window layers keep a ring "
+        "a slot, ModelConfig.sliding_window 1,024 keys, whatever the "
+        "length).",
         section="accelerator",
     ),
     EnvVar(

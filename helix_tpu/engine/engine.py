@@ -73,6 +73,7 @@ from helix_tpu.models.llama import forward, lm_head
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.slo import ANON_TENANT
 from helix_tpu.ops.attention import attention as full_attention
+from helix_tpu.ops.dsa import dsa_ragged_paged_attention
 from helix_tpu.ops.paged import (
     mla_ragged_paged_attention,
     ragged_paged_attention,
@@ -661,7 +662,7 @@ def _pin_default_layout(cache):
 
 
 def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
-                      backend, cold=None, mesh=None):
+                      backend, cold=None, mesh=None, qi=None, dsa=None):
     """One ragged-op invocation from inside a forward pass: unpack the
     pool carry (with optional int8 scale pools) and flatten the token
     grid onto the op's flat row axis.  ``cold`` (tiered KV residency)
@@ -672,6 +673,20 @@ def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
     ks = caches[2] if len(caches) == 4 else None
     vs = caches[3] if len(caches) == 4 else None
     Bq, Sq, H, D = q.shape
+    if qi is not None:
+        # latent attention behind an indexer: v is [rope key | index key],
+        # ``vp`` the index-key pool under the latent pool's page ids, and
+        # each query attends the ``dsa = (index heads, topk)`` keys its
+        # index scores choose (``ops/dsa.py``)
+        out = dsa_ragged_paged_attention(
+            q.reshape(Bq * Sq, H, D),
+            k.reshape(Bq * Sq, k.shape[-1]),
+            v.reshape(Bq * Sq, v.shape[-1]),
+            qi.reshape(Bq * Sq, qi.shape[-1]),
+            kp, vp, lyr, t0, q_len, hist, tables,
+            index_heads=dsa[0], topk=dsa[1], backend=backend, max_q_len=Sq,
+        )
+        return out.reshape(Bq, Sq, H, out.shape[-1])
     if k.ndim == 3:
         # latent attention: k is the latent, v the rope key, no head axis,
         # and the pool one array of their joined rows (``vp`` is None);
@@ -733,6 +748,8 @@ _SETTINGS = {
                      lambda cfg, mesh: cfg.enable_prefix_cache),
 }
 _LATENT = ("latent attention (MLA)", lambda m: m.is_mla)
+_INDEX_POOL = ("a sparse-attention indexer (an index-key pool beside the "
+               "latent pool)", lambda m: m.is_dsa)
 _PACKED_HEADS = ("kv heads packed into one lane tile (head width under "
                  "128)", lambda m: m.kv_head_pack > 1)
 _HELD_EXPERTS = ("held experts (one expert-parallel rank of the routed "
@@ -747,7 +764,8 @@ def _state_rows(kind) -> tuple:
 
 # the first row met is the one raised, so the rows stand in the order they
 # were written: the state kinds' in ``STATE_MIXERS``' order, the packed
-# heads' row behind the first kind's and the held experts' behind the third's
+# heads' row behind the first kind's, the held experts' behind the third's,
+# the index-key pool's last
 _KIND_ROWS = [_state_rows(kind) for kind in STATE_MIXERS.values()]
 _REFUSALS = (
     ("multi_device", _LATENT,
@@ -764,6 +782,9 @@ _REFUSALS = (
      "the other ranks' experts and the exchange with them are not run: "
      "one chip computes its own experts' part of the sum"),
     *(row for rows in _KIND_ROWS[3:] for row in rows),
+    ("host_tier", _INDEX_POOL,
+     "the host tier moves pages of the latent pool; an index-key pool "
+     "beside the latent pool is not carried there"),
 )
 
 
@@ -781,13 +802,33 @@ def refuse_unsupported(model_cfg, cfg, mesh) -> None:
 
 def _refuse_call(model_cfg, what: str) -> None:
     """Paths that move a sequence's pages and are asked for by a call, not
-    a setting: refused for a model whose sequences carry a state too."""
+    a setting: refused for a model whose sequences carry a state too, or
+    whose pages are pages of two pools."""
     kind = model_cfg.state_kind
     if kind is not None:
         raise UnsupportedForModel(
             f"{model_cfg.name}: {kind.refused_as} is not served with "
             f"{what} ({kind.call_refusal})"
         )
+    if model_cfg.is_dsa:
+        raise UnsupportedForModel(
+            f"{model_cfg.name}: {_INDEX_POOL[0]} is not served with {what} "
+            "(a page's contents leave the device as the latent pool's "
+            "alone; an index-key pool beside the latent pool is not "
+            "carried there)"
+        )
+
+
+# the host's account of a model with a sparse-attention indexer
+# (``Engine._note_dsa``): its ``/metrics`` series and flight fields
+DSA_COUNTS = ("keys_scored", "keys_selected", "rows_decode_sparse",
+              "rows_decode_all", "rows_chunk_sparse", "rows_chunk_all",
+              "index_bytes_read", "latent_rows_fetched")
+
+
+def _dsa_of(cfg: ModelConfig):
+    """``(index heads, topk)`` of a model with a sparse-attention indexer."""
+    return (cfg.index_heads, cfg.index_topk) if cfg.is_dsa else None
 
 
 def _fresh_kv_zeros(cfg: ModelConfig, B: int, S: int):
@@ -908,11 +949,11 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     hist = state.positions * active
     kacc0, vacc0 = _fresh_kv_zeros(cfg, B, 1)
 
-    def attn_fn(q, k, v, carry_cache, pos):
+    def attn_fn(q, k, v, carry_cache, pos, qi=None):
         (caches, kacc, vacc, *rest), lyr = carry_cache
         out = _ragged_attn_call(
             q, k, v, caches, lyr, t0, q_len, hist, state.page_tables,
-            backend, mesh=mesh,
+            backend, mesh=mesh, qi=qi, dsa=_dsa_of(cfg),
         )
         return out, (caches, kacc.at[lyr].set(k), vacc.at[lyr].set(v),
                      *rest)
@@ -1182,9 +1223,19 @@ def _build_ragged_step_fn(
         # branch, the state rows the ragged call with the one-token query
         # block, both over the same pools; each segment files its fresh K/V
         # for its own scatter (the accumulators are (prefill's, state's))
-        def p_attn(q, k, v, carry_cache):
+        def p_attn(q, k, v, *qi_cache):
+            *qi, carry_cache = qi_cache
             (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
-            if use_ring:
+            if qi and (has_hist or Cb > cfg.index_topk):
+                # behind an indexer a row with history chooses its keys,
+                # and so does a cold row in a bucket past ``index_topk``; a
+                # cold row of no more tokens than that attends all it has,
+                # on the latent kernel, and only caches its index keys
+                out = _ragged_attn_call(
+                    q, k, v, caches, lyr, p_t0, p_qlen, p_hist, p_tables,
+                    backend, qi=qi[0], dsa=_dsa_of(cfg),
+                )
+            elif use_ring:
                 out = _ring_chunk_attention(
                     q, k, v, caches, lyr, p_pos, p_seg, p_hist,
                     p_tables, mesh, page_size, ring_hist_pages,
@@ -1193,7 +1244,8 @@ def _build_ragged_step_fn(
                 # latent attention has one kernel: a cold row is a row
                 # with no history
                 out = _ragged_attn_call(
-                    q, k, v, caches, lyr, p_t0, p_qlen, p_hist,
+                    q, k, v[..., :cfg.qk_rope_head_dim] if qi else v,
+                    caches, lyr, p_t0, p_qlen, p_hist,
                     p_tables, backend, cold=p_cold, mesh=mesh,
                 )
             else:
@@ -1213,20 +1265,22 @@ def _build_ragged_step_fn(
             return out, (caches, (kp.at[lyr].set(k), ks),
                          (vp.at[lyr].set(v), vs), *rest)
 
-        def s_attn(q, k, v, carry_cache):
+        def s_attn(q, k, v, *qi_cache):
+            *qi, carry_cache = qi_cache
             (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
             out = _ragged_attn_call(
                 q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
                 state.page_tables, backend, cold=s_cold, mesh=mesh,
+                qi=qi[0] if qi else None, dsa=_dsa_of(cfg),
             )
             return out, (caches, (kp, ks.at[lyr].set(k)),
                          (vp, vs.at[lyr].set(v)), *rest)
 
         attend = _segments_fn(
-            p_attn if Cb > 0 else None, s_attn, 3, split, join)
+            p_attn if Cb > 0 else None, s_attn, 3 + cfg.is_dsa, split, join)
 
-        def attn_fn(q, k, v, carry_cache, pos):
-            return attend(q, k, v, carry_cache)
+        def attn_fn(q, k, v, carry_cache, pos, *qi):
+            return attend(q, k, v, *qi, carry_cache)
 
         # ---- ONE pass over every layer --------------------------------
         with jax.named_scope("pass" if Cb > 0 else "state"):
@@ -1462,6 +1516,14 @@ class Engine:
         self.cache_cfg = cfg.cache_config(dtype=model_cfg.dtype)
         # bytes of the state pool (0 for a model without one)
         self.recurrent_state_bytes = self.cache_cfg.state_bytes(model_cfg)
+        # bytes of the index-key pool beside the latent pool (0 without a
+        # sparse-attention indexer)
+        self.index_pool_bytes = 0
+        if model_cfg.is_dsa:
+            shp = self.cache_cfg.page_shapes(model_cfg)[1]
+            self.index_pool_bytes = (
+                self.cache_cfg.num_pages * int(np.prod(shp))
+                * jnp.dtype(self.cache_cfg.dtype).itemsize)
         dev = (mesh.devices.flat[0] if mesh is not None
                else jax.devices()[0])
         if self._backend == "pallas":
@@ -1474,6 +1536,13 @@ class Engine:
                     model_cfg.num_heads, model_cfg.kv_lora_rank,
                     model_cfg.qk_rope_head_dim, itemsize,
                 )
+                if model_cfg.is_dsa:
+                    from helix_tpu.ops.dsa_kernel import check_dsa_geometry
+
+                    check_dsa_geometry(
+                        model_cfg.index_heads, model_cfg.index_head_dim,
+                        model_cfg.num_heads,
+                        sum(self.cache_cfg.latent_widths(model_cfg)))
             elif model_cfg.num_attn_layers:
                 from helix_tpu.ops.paged_kernel import check_geometry
 
@@ -1537,6 +1606,10 @@ class Engine:
         # K/V bytes of the pages the dense paged kernel walked, and the live
         # tokens the last launch's rows attended over
         self.num_mla_page_fetches = 0
+        # behind a sparse-attention indexer: the host's account of what the
+        # launches' rows scored, chose and fetched (``_note_dsa``)
+        self.dsa_counts = dict.fromkeys(DSA_COUNTS, 0) if (
+            model_cfg.is_dsa) else {}
         self.attn_page_bytes_read = 0
         self.step_context_tokens = 0
         self._page_bytes = self.cache_cfg.page_bytes(model_cfg)
@@ -1947,6 +2020,51 @@ class Engine:
         for k in range(1 + int(n_extra)):
             pages += int((-(-(pos + k) // P)).sum())
         return pages
+
+    def _note_dsa(self, plan, has_hist, rung, pos, n_extra) -> dict:
+        """The launch's account of a model with a sparse-attention indexer,
+        from the host's mirrors, added to ``dsa_counts``; returns the
+        launch's own increments (its span's attributes).  A query with ``n``
+        keys (its own position among them) scores ``n`` index keys a layer
+        and attends ``min(n, topk)``: ``sparse`` past ``topk``, else ``all``.
+        A decode row fetches the latent rows it chose; a chunk row with
+        history reads its history's latent rows ONCE (a dense copy for all
+        its queries, which mask what they dropped); a cold row in a bucket
+        of no more than ``topk`` tokens runs the latent kernel over its
+        fresh tokens and scores nothing.  ``index_bytes_read`` is what the
+        device's gather moves out of the index-key pool (``ops/dsa.py::
+        _gather_rows``): EVERY row of a segment's page table at the table's
+        whole width, whatever the row holds (the scoring kernel then skips
+        the key blocks past a row's history)."""
+        cfg = self.model_cfg
+        L, K = cfg.num_attn_layers, cfg.index_topk
+        slots, table = self._page_tables.shape
+        row_bytes = (table * self.cache_cfg.page_size * cfg.index_head_dim
+                     * jnp.dtype(self.cache_cfg.dtype).itemsize)
+        inc = dict.fromkeys(DSA_COUNTS, 0)
+        for k in range(1 + int(n_extra)):
+            n = pos + k + 1
+            inc["keys_scored"] += int(n.sum())
+            inc["keys_selected"] += int(np.minimum(n, K).sum())
+            inc["rows_decode_sparse"] += int((n > K).sum())
+            inc["rows_decode_all"] += int((n <= K).sum())
+            inc["index_bytes_read"] += slots * row_bytes
+            inc["latent_rows_fetched"] += int(np.minimum(n, K).sum())
+        chooses = has_hist or rung > K
+        if plan is not None and chooses:
+            inc["index_bytes_read"] += plan.max_rows * row_bytes
+        for r in (plan.rows if plan is not None else ()):
+            n = np.arange(r.start + 1, r.start + r.rem + 1)
+            mode = "sparse" if r.start + r.rem > K else "all"
+            inc[f"rows_chunk_{mode}"] += 1
+            if chooses:
+                inc["keys_scored"] += int(n.sum())
+                inc["keys_selected"] += int(np.minimum(n, K).sum())
+                inc["latent_rows_fetched"] += int(n[-1])
+        for key in inc:
+            inc[key] *= L if not key.startswith("rows_") else 1
+            self.dsa_counts[key] += inc[key]
+        return {"dsa_" + k: v for k, v in inc.items()}
 
     @property
     def kv_pages_used(self) -> int:
@@ -5095,7 +5213,10 @@ class Engine:
             if kind != "warmup":
                 self.step_context_tokens = context
                 self.chunk_q_block = block or self.chunk_q_block
-            if self.model_cfg.is_mla:
+            if self.model_cfg.is_dsa:
+                walked = self._note_dsa(
+                    plan if rows else None, has_hist, rung, pos, n_extra)
+            elif self.model_cfg.is_mla:
                 fetches = pages * self.model_cfg.num_attn_layers
                 self.num_mla_page_fetches += fetches
                 walked = {"mla_page_fetches": fetches}

@@ -56,13 +56,25 @@ none of it.
 A LATENT (MLA) page pool is ONE array, ``k_pages [L, N, P, R + 128]``: a
 token caches one row a layer, its normed latent in lanes ``0..R`` and its
 rope key behind it, zero-padded to whole 128-lane tiles
-(``CacheConfig.latent_widths``).  There is no second array (``v_pages`` is
-``None``: the values are the latent lanes of the same row), so a ``(layer,
-page)`` slice is one contiguous block that the latent kernel fetches with
-ONE DMA (two arrays cost it two starts and two waits a page, and issuing
-them paced it: PERF.md section 6, PR 40).  Every path that moves pages as
-opaque buffers (host pool, snapshots, checksums, filestore, prefix cache)
-carries ``"v": None`` for such a page.
+(``CacheConfig.latent_widths``).  The values are the latent lanes of the
+same row, so attention has no second array (``v_pages`` is ``None``) and a
+``(layer, page)`` slice is one contiguous block that the latent kernel
+fetches with ONE DMA (two arrays cost it two starts and two waits a page,
+and issuing them paced it: PERF.md section 6, PR 40).  Every path that
+moves pages as opaque buffers (host pool, snapshots, checksums, filestore,
+prefix cache) carries ``"v": None`` for such a page.
+
+Behind a sparse-attention INDEXER (``ModelConfig.is_dsa``) a token caches a
+second row a layer: its index key, ``index_head_dim`` wide.  That is the
+INDEX-KEY POOL, ``v_pages [L, N, P, Di]`` in the pool's dtype, addressed by
+the SAME page ids and page tables as the latent pool beside it (a page is a
+page of both: admission, ``kv_pages_used`` and the prefix cache, which
+shares page ids and never looks inside one, count and share them as one);
+``write_kv`` scatters both from the one fresh pair ``(c, [k_pe | k_idx])``;
+``page_bytes`` / ``total_bytes`` / ``fit_hbm`` count both.  The paths that
+move a page's CONTENTS off the device are refused for such a model by name
+(``engine/engine.py::_REFUSALS``: the host tier, tiered residency, request
+export / import, the filestore).
 """
 
 from __future__ import annotations
@@ -137,8 +149,13 @@ class CacheConfig:
         """Shapes of ONE page, all layers, in each of the pool's arrays
         (what ``gather_pages`` hands out and a snapshot carries): K and V
         ``[L, P, KVH, D]``, or for latent attention the ONE array's ``[L,
-        P, R + 128]`` (``latent_widths``)."""
+        P, R + 128]`` (``latent_widths``), behind an indexer the index-key
+        pool's ``[L, P, Di]`` too."""
         L, P = model.num_attn_layers, self.page_size
+        if model.is_dsa:
+            # the index-key pool's page behind the latent pool's
+            return ((L, P, sum(self.latent_widths(model))),
+                    (L, P, model.index_head_dim))
         if model.is_mla:
             return ((L, P, sum(self.latent_widths(model))),)
         pack = model.kv_head_pack
@@ -153,7 +170,9 @@ class CacheConfig:
         ``(0, R)``: its pages are refused by that field's name instead of
         being misread."""
         if model.is_mla:
-            return 0, sum(self.latent_widths(model))
+            # (an index-key pool beside it: its width too, so that a page
+            # of one pool is never read as a page of two)
+            return 0, sum(self.latent_widths(model)) + model.index_head_dim
         return model.num_kv_heads, model.head_dim
 
     def page_bytes(self, model: ModelConfig) -> int:
@@ -231,7 +250,8 @@ class PagedKVCache:
     # [L, N, P, KVH, D]; a latent pool: [L, N, P, R + 128], a token's
     # latent in lanes 0..R and its lane-padded rope key behind it
     k_pages: jax.Array
-    # K's shape; None for a latent pool, which has no second array
+    # K's shape; None for a latent pool, whose values are its own rows;
+    # behind an indexer the index-key pool [L, N, P, Di]
     v_pages: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
@@ -468,7 +488,9 @@ def write_kv(
 
 def _write_latent(cache, c_new, r_new, pages, offsets, valid):
     """``write_kv`` for a latent pool: ``c_new [L, B, S, R]`` and ``r_new
-    [L, B, S, dr]`` joined into the pool's rows ``[c | r | zeros]``.  ONE
+    [L, B, S, dr]`` joined into the pool's rows ``[c | r | zeros]``
+    (beside an index-key pool ``r_new`` is ``[r | k_idx]`` and its last
+    ``Di`` lanes are scattered into that pool at the same rows).  ONE
     row scatter over the pool viewed ``[L * N * ps, R + 128]``, each
     (layer, token) its own row index: a pool with no head axis has only
     the lane axis minor, and a scatter that kept the layer axis as a
@@ -480,6 +502,17 @@ def _write_latent(cache, c_new, r_new, pages, offsets, valid):
     tok = jnp.where(valid, pages * ps + offsets, 0).reshape(-1)     # [T]
     rows = (jnp.arange(L, dtype=tok.dtype)[:, None] * (N * ps)
             + tok[None, :]).reshape(-1)                              # [L*T]
+    idx_pool = cache.v_pages
+    if idx_pool is not None:
+        Di = idx_pool.shape[-1]
+        r_new, i_new = r_new[..., :-Di], r_new[..., -Di:]
+        idx_pool = (
+            idx_pool.reshape(L * N * ps, Di)
+            .at[rows]
+            .set(i_new.reshape(-1, Di).astype(idx_pool.dtype), mode="drop",
+                 unique_indices=False)
+            .reshape(L, N, ps, Di)
+        )
     new = jnp.concatenate(
         [c_new.reshape(-1, c_new.shape[-1]),
          r_new.reshape(-1, r_new.shape[-1])], axis=-1).astype(pool.dtype)
@@ -490,7 +523,8 @@ def _write_latent(cache, c_new, r_new, pages, offsets, valid):
         .set(new, mode="drop", unique_indices=False)
         .reshape(L, N, ps, W)
     )
-    return PagedKVCache(k_pages=k_pages, v_pages=None, state=cache.state)
+    return PagedKVCache(k_pages=k_pages, v_pages=idx_pool,
+                        state=cache.state)
 
 
 class PageAllocator:

@@ -172,6 +172,18 @@ class ModelConfig:
     # ``o <- o * sigmoid(h W_g)``, a gate a head and value channel from the
     # layer's input, before the output projection
     attn_gate: bool = False
+    # --- a learned sparse-attention indexer in front of MLA (DeepSeek Sparse
+    # Attention, ``glm_moe_dsa``); 0 heads = every query reads every key ---
+    # ``index_heads`` query heads of ``index_head_dim`` from the compressed
+    # query score ONE cached key a token (a LayerNorm'd projection of the
+    # layer's input, rope over its first ``qk_rope_head_dim`` dims):
+    # ``I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])``; a query attends the
+    # ``index_topk`` keys of largest ``I`` (all of them while it has no
+    # more).  The index key is cached in a pool of its own beside the latent
+    # pool, under the same page ids (``engine/kv_cache.py``)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # --- sliding-window attention layers beside full ones (Laguna, Mellum) ---
     # a "window" layer is GQA attention whose query at position i sees keys
     # i - sliding_window < j <= i (the query's own among them): its whole
@@ -234,6 +246,12 @@ class ModelConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_dsa(self) -> bool:
+        """Latent attention behind a learned indexer: a second cached array
+        a token (the index key) and a key set that differs by query."""
+        return self.index_heads > 0
 
     def _blocks(self) -> tuple:
         """``hybrid_pattern`` as the blocks the program runs: ``((mixer,
@@ -453,7 +471,13 @@ class ModelConfig:
         """Per-token shapes of the two cached arrays, as the model hands
         them to ``attn_fn``: K and V ``(kv_heads, head_dim)`` each, or for
         latent attention the compressed latent ``(kv_lora_rank,)`` and
-        the shared rope key ``(qk_rope_head_dim,)``: no head axis, no V."""
+        the shared rope key ``(qk_rope_head_dim,)``: no head axis, no V.
+        Behind an indexer the second is ``[rope key | index key]``."""
+        if self.is_dsa:
+            # the index key rides behind the rope key: one fresh row a
+            # token for each of the two pools (``write_kv`` parts them)
+            return (self.kv_lora_rank,), (
+                self.qk_rope_head_dim + self.index_head_dim,)
         if self.is_mla:
             return (self.kv_lora_rank,), (self.qk_rope_head_dim,)
         kv = (self.num_kv_heads, self.head_dim)
@@ -476,9 +500,13 @@ class ModelConfig:
         if rs and mrope is None:
             rope_scaling = tuple(sorted(rs.items()))
         family = {"num_experts": hf.get("num_local_experts", 0)}
-        if model_type in ("deepseek_v2", "gigachat3_5"):
-            if (hf.get("topk_method", "greedy") != "greedy"
+        if model_type in ("deepseek_v2", "gigachat3_5", "glm_moe_dsa"):
+            # ``noaux_tc`` at one group IS the sigmoid router with a
+            # selection bias over all the experts at once
+            greedy = ("greedy", "noaux_tc")[:1 + (model_type == "glm_moe_dsa")]
+            if (hf.get("topk_method", "greedy") not in greedy
                     or (hf.get("n_group") or 1) > 1
+                    or (hf.get("topk_group") or 1) > 1
                     or hf.get("moe_layer_freq", 1) != 1):
                 raise ValueError(
                     f"{model_type}: only the greedy router over all the "
@@ -491,6 +519,7 @@ class ModelConfig:
             # this form with no scoring_func key means in gigachat3_5)
             scoring = hf.get("scoring_func", {
                 "deepseek_v2": "softmax", "gigachat3_5": "sigmoid",
+                "glm_moe_dsa": "sigmoid",
             }[model_type])
             if scoring not in ("softmax", "sigmoid"):
                 raise ValueError(
@@ -541,18 +570,9 @@ class ModelConfig:
                 swiglu_limit=float(hf.get("swiglu_limit") or 0.0),
                 norm_offset=1.0,
             )
-            if hf.get("held_experts"):
-                # one expert-parallel rank: ``n_routed_experts`` is what is
-                # loaded, ``published_n_routed_experts`` what the router
-                # scores
-                lo, hi = hf["held_experts"]
-                if hi - lo != hf["n_routed_experts"]:
-                    raise ValueError(
-                        f"gigachat3_5: held_experts {[lo, hi]} are not the "
-                        f"{hf['n_routed_experts']} of n_routed_experts")
-                family.update(
-                    held_experts=(lo, hi),
-                    num_experts=hf["published_n_routed_experts"])
+            family.update(cls._held_experts(hf, "gigachat3_5"))
+        if model_type == "glm_moe_dsa":
+            family.update(cls._glm_moe_dsa_family(hf))
         if model_type == "brumby":
             # every layer is power retention over the Qwen3 block's q/k/v
             # (per-head q/k norms, rope); the config carries no key of the
@@ -620,6 +640,60 @@ class ModelConfig:
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
         )
+
+    @staticmethod
+    def _glm_moe_dsa_family(hf: dict) -> dict:
+        """What ``model_type: glm_moe_dsa`` adds to the latent-attention
+        expert family: the sparse-attention indexer in front of every
+        layer's attention (DeepSeek Sparse Attention) and rope under
+        ``rope_parameters``.  The multi-token-prediction module is not a
+        layer of the served stack."""
+        def refuse(why):
+            raise ValueError(f"glm_moe_dsa: {why}")
+
+        missing = [k for k in ("index_n_heads", "index_head_dim",
+                               "index_topk") if not hf.get(k)]
+        if missing:
+            refuse(f"the indexer's {missing} are not given: model_type "
+                   "glm_moe_dsa promises a sparse-attention indexer")
+        rope = hf.get("rope_parameters") or {}
+        kind = rope.get("rope_type", rope.get("type", "default"))
+        if kind != "default" or hf.get("rope_scaling"):
+            refuse(f"rope_type {kind!r} is not supported: default (no "
+                   "scaling)")
+        if not hf.get("rope_interleave", True):
+            refuse("only rope_interleave true (pairs (2i, 2i+1)) is "
+                   "supported on the latent attention's rope dims")
+        if not hf.get("indexer_rope_interleave", True):
+            refuse("only indexer_rope_interleave true (pairs (2i, 2i+1)) "
+                   "is supported on an index head's rope dims")
+        if hf["index_head_dim"] < hf["qk_rope_head_dim"]:
+            refuse(f"index_head_dim {hf['index_head_dim']} is under the "
+                   f"{hf['qk_rope_head_dim']} dims rope rotates")
+        return dict(
+            index_heads=hf["index_n_heads"],
+            index_head_dim=hf["index_head_dim"],
+            index_topk=hf["index_topk"],
+            rope_theta=float(rope.get("rope_theta")
+                             or hf.get("rope_theta", 10000.0)),
+            **ModelConfig._held_experts(hf, "glm_moe_dsa"),
+        )
+
+    @staticmethod
+    def _held_experts(hf: dict, family: str,
+                      key: str = "n_routed_experts") -> dict:
+        """The fields of ONE expert-parallel rank, where the config names
+        one (``held_experts: [lo, hi]``): ``hf[key]`` is what is loaded,
+        ``published_<key>`` what the router scores.  Empty without it."""
+        if not hf.get("held_experts"):
+            return {}
+        lo, hi = hf["held_experts"]
+        if hi - lo != hf[key]:
+            raise ValueError(
+                f"{family}: held_experts {[lo, hi]} are not the "
+                f"{hf[key]} of {key}")
+        return dict(held_experts=(lo, hi),
+                    num_experts=hf["published_" + key])
 
     # a ``layer_types`` entry, as the kind of layer that serves it
     ATTENTION_KINDS = {"full_attention": "attn", "sliding_attention": "window"}
@@ -714,16 +788,7 @@ class ModelConfig:
             moe_expert_bias=False,
             expert_capacity_factor=0.0,
         )
-        if hf.get("held_experts"):
-            # one expert-parallel rank: ``num_experts`` is what is loaded,
-            # ``published_num_experts`` what the router scores
-            lo, hi = hf["held_experts"]
-            if hi - lo != hf["num_experts"]:
-                raise ValueError(
-                    f"laguna: held_experts {[lo, hi]} are not the "
-                    f"{hf['num_experts']} of num_experts")
-            family.update(held_experts=(lo, hi),
-                          num_experts=hf["published_num_experts"])
+        family.update(ModelConfig._held_experts(hf, "laguna", "num_experts"))
         return family
 
     @staticmethod
@@ -868,15 +933,7 @@ class ModelConfig:
             moe_expert_bias=True,
             expert_capacity_factor=0.0,
         )
-        if hf.get("held_experts"):
-            # one expert-parallel rank: ``n_routed_experts`` is what is
-            # loaded, ``published_n_routed_experts`` what the router scores
-            lo, hi = hf["held_experts"]
-            if hi - lo != hf["n_routed_experts"]:
-                refuse(f"held_experts {[lo, hi]} are not the "
-                       f"{hf['n_routed_experts']} of n_routed_experts")
-            family.update(held_experts=(lo, hi),
-                          num_experts=hf["published_n_routed_experts"])
+        family.update(ModelConfig._held_experts(hf, "nemotron_h"))
         return family
 
     @classmethod
@@ -1237,9 +1294,61 @@ MELLUM2_12B = ModelConfig(
     name="JetBrains/Mellum2-12B-A2.5B-Instruct",
 )
 
+# GLM-5 (https://huggingface.co/zai-org/GLM-5/blob/main/config.json,
+# ``model_type: glm_moe_dsa``): 78 layers of latent attention with a
+# compressed query (64 heads, nope 192 / rope 64 / value 256 over a latent of
+# 512) behind a LEARNED SPARSE-ATTENTION INDEXER (``index_heads`` 32 of
+# ``index_head_dim`` 128 from the compressed query against ONE cached index key
+# a token; a query past ``index_topk`` 2,048 keys attends the 2,048 of largest
+# index score: ``ops/dsa.py``), the index key in a pool of its own beside the
+# latent pool (``engine/kv_cache.py``); three dense layers then 256 routed
+# experts top-8 + 1 shared behind the sigmoid router with a selection bias
+# (``noaux_tc`` at one group), renormalised, times 2.5; rope theta 1e6, pairs
+# (2i, 2i+1), no scaling.  One chip holds a cut of it as ONE expert-parallel
+# rank (``held_experts``, set by the profile).  Refused by name
+# (``from_hf_config``): grouped top-k (``n_group`` / ``topk_group`` > 1),
+# ``moe_layer_freq`` != 1, a ``rope_type`` other than default, an indexer key
+# missing, ``rope_interleave`` or ``indexer_rope_interleave`` false (the
+# pairs are (2i, 2i+1) on both ropes); (engine.py's table): a mesh, an int8 pool, adapters, speculation,
+# tiered residency, the host tier, and by call request export / import and the
+# KV filestore (the index-key pool is not carried there).  The multi-token-
+# prediction module is not loaded.  On the chip: ``chip_smoke_deepseek.py
+# --config glm-5-int8``.
+GLM5 = ModelConfig(
+    vocab_size=154880,
+    hidden_size=6144,
+    num_layers=78,
+    num_heads=64,
+    num_kv_heads=64,
+    head_dim=256,
+    intermediate_size=12288,
+    rope_theta=1000000.0,
+    rms_norm_eps=1e-5,
+    max_position_embeddings=202752,
+    num_experts=256,
+    num_experts_per_tok=8,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=2048,
+    num_shared_experts=1,
+    first_k_dense=3,
+    moe_renormalize=True,
+    routed_scaling_factor=2.5,
+    moe_scoring="sigmoid",
+    moe_expert_bias=True,
+    kv_lora_rank=512,
+    q_lora_rank=2048,
+    qk_nope_head_dim=192,
+    qk_rope_head_dim=64,
+    v_head_dim=256,
+    index_heads=32,
+    index_head_dim=128,
+    index_topk=2048,
+    name="zai-org/GLM-5",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
               DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B,
-              LAGUNA_XS2, NEMOTRON3_SUPER_120B, MELLUM2_12B)
+              LAGUNA_XS2, NEMOTRON3_SUPER_120B, MELLUM2_12B, GLM5)
 }

@@ -278,6 +278,22 @@ def init_params(
             lp["kv_norm"] = norm(R)
             lp["wkv_b"] = w((R, H * (dn + dv)), "wkv_b")
             lp["wo"] = w((H * dv, E), "wo")
+            if cfg.is_dsa:
+                # the indexer: queries from the compressed query, ONE key a
+                # token and a weight a head from the layer's input.  The
+                # key's LayerNorm is drawn (gain in [0.5, 1.5], bias at
+                # 0.1): at the initialiser's 1 and 0 a gain or a bias left
+                # out would not be seen
+                Hi, Di = cfg.index_heads, cfg.index_head_dim
+                lp["wq_idx"] = w((cfg.q_lora_rank or E, Hi * Di), "wq_idx")
+                lp["wk_idx"] = w((E, Di), "wk_idx")
+                lp["w_idx"] = w((E, Hi), "w_idx")
+                kg, kb = jax.random.split(drawn(6000, "k_idx_norm"))
+                lp["k_idx_norm"] = {
+                    "weight": jax.random.uniform(
+                        kg, (n, Di), jnp.float32, 0.5, 1.5).astype(dtype),
+                    "bias": (jax.random.normal(kb, (n, Di), jnp.float32)
+                             * 0.1).astype(dtype)}
         else:
             # a window layer may have its own count of query heads
             Ht = cfg.heads_of(mixer)
@@ -442,6 +458,13 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             lax_["wkv_a"] = {"weight": ("layers", "embed", None)}
             lax_["kv_norm"] = {"weight": ("layers", None)}
             lax_["wkv_b"] = {"weight": ("layers", None, "heads")}
+            if cfg.is_dsa:
+                # (a mesh is refused for it: the index pool is one device's)
+                lax_["wq_idx"] = {"weight": ("layers", None, None)}
+                lax_["wk_idx"] = {"weight": ("layers", "embed", None)}
+                lax_["w_idx"] = {"weight": ("layers", "embed", None)}
+                lax_["k_idx_norm"] = {"weight": ("layers", None),
+                                      "bias": ("layers", None)}
         else:
             lax_["wk"] = {"weight": ("layers", "embed", "kv_heads")}
             lax_["wv"] = {"weight": ("layers", "embed", "kv_heads")}
@@ -543,6 +566,13 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
     rotated as published, ``(2i, 2i+1)``, and kept de-interleaved
     (``ops.rope.apply_rope_interleaved``).
 
+    Behind an INDEXER (``cfg.is_dsa``, DeepSeek Sparse Attention): ``v`` is
+    ``[k_pe | k_idx]``, the rope key and behind it the token's index key
+    (both cached, each in its pool), and ``attn_fn`` gets a sixth argument
+    ``qi [B, S, Hi * Di + Hi]``: the index queries and behind them the
+    heads' weights times ``Hi ** -0.5 * Di ** -0.5`` (``index_queries``).
+    Which keys a query then attends is ``attn_fn``'s (``ops/dsa.py``).
+
     A COMPRESSED query (``q_lora_rank``): ``q = n(x W_qa) W_qb``, two
     products and a norm where the direct form has one product.  A GATE
     (``attn_gate``): the heads' outputs times ``sigmoid(x W_g)``, a gate a
@@ -559,6 +589,7 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
     x = rms_norm(h, p["attn_norm"]["weight"], cfg.rms_norm_eps,
                  cfg.norm_offset)
     q = None
+    c_q = x
     if "wq_a" in p:
         with jax.named_scope("attn.q_a"):
             c_q = rms_norm(
@@ -583,8 +614,14 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
         c = rms_norm(ckv[..., :R], p["kv_norm"]["weight"], cfg.rms_norm_eps,
                      cfg.norm_offset)
         k_pe = apply_rope_interleaved(ckv[..., R:], positions, inv_freq, rot)
-    with jax.named_scope("attn.kernel"):
-        res = attn_fn(q_lat, c, k_pe, layer_cache, positions)
+    if cfg.is_dsa:
+        qi, k_idx = _dsa_index(x, c_q, p, cfg, positions, inv_freq)
+        k_pe = jnp.concatenate([k_pe, k_idx], axis=-1)
+        with jax.named_scope("attn.sparse"):
+            res = attn_fn(q_lat, c, k_pe, layer_cache, positions, qi)
+    else:
+        with jax.named_scope("attn.kernel"):
+            res = attn_fn(q_lat, c, k_pe, layer_cache, positions)
     new_cache = None
     if isinstance(res, tuple):
         o_lat, new_cache = res
@@ -603,6 +640,47 @@ def _mla_attention(h, p, layer_cache, cfg, positions, inv_freq, attn_fn,
         branch = _dense(a, p["wo"])
         h = h + (post(branch) if post else branch).astype(h.dtype)
     return h, (c, k_pe), new_cache
+
+
+def _dsa_index(x, c_q, p, cfg, positions, inv_freq):
+    """The indexer's side of a layer (DeepSeek Sparse Attention): from the
+    normed input ``x`` and the compressed query ``c_q``,
+
+    - ``q^I = c_q W_qb^I``, ``index_heads`` heads of ``index_head_dim``;
+    - ``k^I = LayerNorm(x W_k^I)``, ONE key a token (gain and bias);
+    - rope over the FIRST ``qk_rope_head_dim`` dims of each, with the
+      attention's own frequencies and positions, the rest pass;
+    - ``w = x W_w^I`` a head, in float32, times ``Hi ** -0.5 * Di ** -0.5``.
+
+    Returns ``qi [B, S, Hi * Di + Hi]`` (queries | weights, the layer's
+    dtype) and ``k_idx [B, S, Di]``.  A rotated part is left de-interleaved
+    where the pairs are the published ``(2i, 2i+1)``: queries and keys share
+    the permutation, so ``q . k`` is what it was."""
+    from helix_tpu.ops.norms import layer_norm
+    from helix_tpu.ops.quant import maybe_dequant_dense
+    from helix_tpu.ops.rope import apply_rope_interleaved
+
+    B, S, _ = x.shape
+    Hi, Di, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def rope(t):
+        return jnp.concatenate(
+            [apply_rope_interleaved(t[..., :dr], positions, inv_freq),
+             t[..., dr:]], axis=-1)
+
+    with jax.named_scope("attn.index.q"):
+        q_idx = rope(_dense(c_q, p["wq_idx"]).astype(x.dtype).reshape(
+            B, S, Hi, Di))
+        w_idx = maybe_dequant_dense(
+            x, p["w_idx"], compute_dtype=jnp.float32) * (
+                Hi ** -0.5 * Di ** -0.5)
+        qi = jnp.concatenate(
+            [q_idx.reshape(B, S, Hi * Di), w_idx.astype(x.dtype)], axis=-1)
+    with jax.named_scope("attn.index.k"):
+        k_idx = rope(layer_norm(
+            _dense(x, p["wk_idx"]).astype(x.dtype),
+            p["k_idx_norm"]["weight"], p["k_idx_norm"]["bias"], 1e-6))
+    return qi, k_idx
 
 
 def _conv_mixer(h, p, layer_cache, cfg, state_fn):
@@ -1164,13 +1242,27 @@ def lm_head(params: Params, cfg: ModelConfig, h):
     return logits
 
 
-def prefill_attn_fn(q, k, v, layer_cache, positions, *, segment_ids=None,
-                    backend=None, soft_cap=None):
+def prefill_attn_fn(q, k, v, layer_cache, positions, qi=None, *,
+                    segment_ids=None, backend=None, soft_cap=None,
+                    cfg=None):
     """Self-attention over the freshly computed K/V (no history).  For a
     latent-attention model ``k``/``v`` are the latent and the rope key
     (``_mla_attention``) and the plain absorbed-form reference runs."""
     from helix_tpu.ops.attention import attention
 
+    if qi is not None:
+        # behind an indexer (``cfg`` says how many heads and keys): every
+        # query attends the keys its index scores choose
+        from helix_tpu.ops.dsa import dsa_dense_attention
+
+        seg = (jnp.ones_like(positions) if segment_ids is None
+               else segment_ids)
+        return jax.vmap(
+            lambda q1, c1, r1, i1, p1, s1: dsa_dense_attention(
+                q1, c1, r1, i1, positions=p1, segment_ids=s1,
+                index_heads=cfg.index_heads, topk=cfg.index_topk,
+                backend="reference")
+        )(q, k, v, qi, positions, seg)
     if k.ndim == 3:
         from helix_tpu.ops.paged import mla_attention_reference
 

@@ -34,6 +34,14 @@ in its absorbed form:
   lane-padded by the wrapper) in blocks of ``KB`` keys, one DMA a block,
   attended raw (persisting them is the caller's ``write_kv``).
 
+Behind a sparse-attention indexer (``ModelConfig.is_dsa``) this kernel serves
+the rows that attend ALL they have: a cold row of no more fresh tokens than
+``index_topk``, with the rope key's lanes of the fresh ``[k_pe | k_idx]`` cut
+off by the caller.  A row with history, or past ``index_topk`` keys, goes to
+``ops/dsa.py`` (scores over the index-key pool beside this one, a choice a
+query, ``ops/dsa_kernel.py``'s dense kernels over gathered rows); the page
+table such a model prefetches to SMEM here is up to 1,056 wide.
+
 Layout contract: as the dense kernel's.  Rows are disjoint and ascending;
 a row's last partial block spills garbage into the following flat
 positions, which a later row's own block overwrites (the grid is

@@ -556,3 +556,44 @@ def mla_ragged_paged_attention(
         q, c_new, r_new, kv_pages, layer, t0, q_len, hist, tables,
         scale=scale,
     )
+
+
+# ---------------------------------------------------------------------------
+# Sparse latent attention behind an indexer (DeepSeek Sparse Attention): the
+# plain form of each of ``ops/dsa.py``'s two dense passes
+# ---------------------------------------------------------------------------
+
+
+def dsa_index_scores_reference(q, w, keys):
+    """``I[r, t, s] = sum_j w[t, j] relu(q[t, j] . keys[r, s])`` in float32.
+
+    ``q [Rq, T, Hi, Di]`` the index queries, ``w [Rq, T, Hi]`` the heads'
+    weights (scales folded in), ``keys [R, S, Di]`` ONE index key a token; ``Rq``
+    is ``R`` (a row's own queries: decode) or 1 (every row scores the same
+    queries: a chunk's flat axis).  Returns ``[R, T, S]``."""
+    s = jnp.einsum("rthd,rsd->rths", q.astype(jnp.float32),
+                   keys.astype(jnp.float32))
+    return jnp.einsum("rths,rth->rts", jnp.maximum(s, 0.0),
+                      w.astype(jnp.float32))
+
+
+def mla_sparse_attention_reference(q, kv, bias, latent: int):
+    """Absorbed-form latent attention of each query over the keys its
+    ``bias`` leaves (0 keeps a key, ``DEFAULT_MASK_VALUE`` drops it), plain
+    softmax in float32.
+
+    ``q [Rq, T, H, W]`` in the pool's row layout (absorbed query | rope query
+    | zeros), ``kv [R, S, W]`` rows ``[c | k_pe | zeros]``, ``bias [R, T,
+    S]``; ``Rq`` is ``R`` or 1 (``dsa_index_scores_reference``).  The values
+    are lanes ``0..latent`` of the rows.  A query that keeps no key gets
+    zeros.  Returns ``[R, T, H, latent]``."""
+    kvf = kv.astype(jnp.float32)
+    s = jnp.einsum("rthw,rsw->rths", q.astype(jnp.float32), kvf)
+    keep = (bias > 0.5 * DEFAULT_MASK_VALUE)[:, :, None, :]
+    s = jnp.where(keep, s, DEFAULT_MASK_VALUE)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(keep, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("rths,rsw->rthw", p / jnp.where(l > 0, l, 1.0),
+                     kvf[..., :latent])
+    return out.astype(q.dtype)

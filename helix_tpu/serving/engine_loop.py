@@ -1719,6 +1719,7 @@ class EngineLoop:
             getattr(eng, "num_wave_decode_tokens", 0),
             dict(getattr(eng, "mixer_counts", {})),
             getattr(eng, "attn_page_bytes_read", 0),
+            dict(getattr(eng, "dsa_counts", {})),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1731,7 +1732,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0, wr0, mixer0, pb0) = pre
+         cs0, jp0, ji0, wr0, mixer0, pb0, dsa0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1801,6 +1802,10 @@ class EngineLoop:
             # launch attended over (from the host's mirrors)
             "attn_page_bytes_read": (
                 getattr(eng, "attn_page_bytes_read", 0) - pb0),
+            # behind a sparse-attention indexer: what this step's programs
+            # scored, chose and fetched (``Engine._note_dsa``)
+            **{"dsa_" + k: n - dsa0.get(k, 0)
+               for k, n in getattr(eng, "dsa_counts", {}).items()},
             "context_tokens": getattr(eng, "step_context_tokens", 0),
             "prefill_tokens": prefill,
             "padding_tokens": (

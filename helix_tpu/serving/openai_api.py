@@ -172,6 +172,16 @@ def _sse_error_frame(e: Exception, trace_id: str = "") -> dict:
     return {"error": err}
 
 
+# the host's account of a sparse-attention indexer (``Engine.dsa_counts``),
+# by series
+_DSA_SERIES = {
+    "keys_scored": "helix_dsa_keys_scored_total",
+    "keys_selected": "helix_dsa_keys_selected_total",
+    "index_bytes_read": "helix_dsa_index_bytes_read_total",
+    "latent_rows_fetched": "helix_dsa_latent_rows_fetched_total",
+}
+
+
 class OpenAIServer:
     def __init__(self, registry: ModelRegistry, metrics=None,
                  inter_token_timeout: Optional[float] = None,
@@ -482,6 +492,23 @@ class OpenAIServer:
                     "helix_moe_away_tokens_total",
                     getattr(eng, "moe_away_tokens", 0), lbl,
                 )
+            for key, n in getattr(eng, "dsa_counts", {}).items():
+                # a sparse-attention indexer in front of latent attention,
+                # from the host's account of the launches, times the latent
+                # layers: index keys scored and keys then attended (their
+                # ratio is the chosen share), rows by kind and by whether
+                # they were past ``index_topk`` keys, the bytes the gather
+                # moves out of the index-key pool (every table row at the
+                # table's whole width) and the latent rows fetched
+                if key.startswith("rows_"):
+                    _, kind, mode = key.split("_")
+                    c.counter("helix_dsa_rows_total", n,
+                              {**lbl, "kind": kind, "mode": mode})
+                else:
+                    c.counter(_DSA_SERIES[key], n, lbl)
+            if getattr(eng.model_cfg, "is_dsa", False):
+                c.gauge("helix_dsa_index_pool_bytes",
+                        eng.index_pool_bytes, lbl)
             if getattr(eng.model_cfg, "is_mla", False):
                 # latent attention: the history pages its kernel walked,
                 # one DMA each (live rows' pages x query blocks x latent

@@ -161,7 +161,7 @@ def test_refusal_rows_name_the_seven_settings_or_say_which_it_serves(kind):
 
 def test_the_refusal_rows_stand_in_the_order_they_were_written():
     """The first row met is the one raised, and the engine splices the
-    kinds' rows among its own by the records' ORDER: the 41 rows as
+    kinds' rows among its own by the records' ORDER: the 42 rows as
     (setting, what of the model meets it), in the order they have had since
     each was written."""
     from helix_tpu.engine.engine import _REFUSALS, _SETTINGS
@@ -183,9 +183,11 @@ def test_the_refusal_rows_stand_in_the_order_they_were_written():
         + [(s, "a ring of K/V a slot (sliding-window attention)")
            for s in seven]
         + [(s, "a state-space state and a conv tail (Mamba-2)")
-           for s in seven])
+           for s in seven]
+        + [("host_tier", "a sparse-attention indexer (an index-key pool "
+            "beside the latent pool)")])
     assert [(key, prop) for key, (prop, _), _ in _REFUSALS] == want
-    assert len(want) == 41
+    assert len(want) == 42
 
 
 def _cfgs(kind):

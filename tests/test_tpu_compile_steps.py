@@ -633,3 +633,99 @@ def test_paged_kernel_compiles_a_chunk_row_at_every_cells_geometry(
         S((rows,)), S((rows,)), S((rows, max_pages))).compile()
     assert "ragged_paged_attention_tpu" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_with_history"])
+def test_sparse_latent_step_compiles_at_published_widths(one_chip, program):
+    """A whole engine step of GLM-5 (the dense layer and two expert layers of
+    its cut of eight, int8 weights, 16 slots, a page table 1,056 wide over
+    16,897 pages) for the described chip: the scoring kernel over a row's
+    gathered index keys, the choice, the sparse attention kernel over the
+    chosen rows (decode) or under a per-query mask over a dense copy of the
+    history (a 512-token chunk), the row scatter into BOTH pools.  (A cold
+    chunk is the latent kernel's with the table 1,056 wide in SMEM: compiled
+    here at PR 53 and run in the cell; not kept, for the suite's time.)"""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import CATALOG
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        CATALOG["zai-org/GLM-5"], num_layers=3, first_k_dense=1,
+        held_experts=(0, 16))
+    B, max_pages, pages = 16, 1056, 16897
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    ks, vs = CacheConfig(num_pages=pages).page_shapes(cfg)
+    assert ks == (3, 16, 512 + 128) and vs == (3, 16, 128)
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, program == "chunk_with_history",
+        rows, 1, 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    # the kernels, by the names a trace finds them by
+    # (benchmark/metrics/kernel.dsa_index_share.json, .mla_sparse_share)
+    assert "dsa_index_scores_tpu" in text
+    assert "mla_sparse_attention_tpu" in text
+    assert "grouped_matmul_tpu" in text
+    # both pools are updated in place: no pool-sized temporary
+    pool_bytes = ks[0] * pages * 16 * (512 + 128) * 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes, mem.temp_size_in_bytes
+    if program == "decode":
+        # the two pools as the described chip lays them out, against the
+        # benchmark's count (benchmark/lib/model_bytes_mla_dsa_moe.py): no
+        # padding in either (640 and 128 lanes are whole tiles)
+        import json
+        import os
+
+        from benchmark.lib import model_bytes_mla_dsa_moe as mb
+
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmark", "configs",
+                "glm-5-int8.json")) as f:
+            hf = dict(json.load(f), num_hidden_layers=3)
+        pools = jax.jit(
+            lambda: (jnp.zeros(cache.k_pages.shape, jnp.bfloat16),
+                     jnp.zeros(cache.v_pages.shape, jnp.bfloat16)),
+            out_shardings=(one_chip, one_chip)).lower().compile()
+        lat, key = mb.token_bytes(hf)
+        counted = pages * 16 * (lat + key)
+        assert counted == pages * mb.page_bytes(hf, 16)
+        # (the compiler adds the result tuple's own 512 bytes)
+        assert 0 <= pools.memory_analysis().output_size_in_bytes - (
+            counted) <= 4096
+        assert key * pages * 16 == 3 * pages * 16 * 128 * 2
