@@ -2211,27 +2211,68 @@ def phase_kernel_dsa(spec, seed, rehearse):
             queries=T, keys=S, rows=Rk, max_err_over_rms=err)
         if err > 1e-2 and not rehearse:
             fail(f"dsa_index_scores {name}: {err}")
-    for name, (Rq, Rk, T, S) in {
-            "decode": (16, 16, 1, 2048),
-            "chunk": (1, 1, 512, 4096 + 512)}.items():
+    # a decode row's call: 64 heads over the 2,048 rows it gathered
+    Rk, T, S = (16, 1, 2048) if not rehearse else (16, 1, 32)
+    q = (jax.random.normal(ks[3], (Rk, T, H, W), jnp.float32)
+         * 0.06).astype(bf)
+    kv = jax.random.normal(ks[4], (Rk, S, W), bf)
+    bias = jnp.where(jax.random.uniform(ks[5], (Rk, T, S)) < 0.25, 0.0,
+                     DEFAULT_MASK_VALUE)
+    got = np.asarray(dsa.sparse_attention(q, kv, bias, R, backend),
+                     np.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(mla_sparse_attention_reference(
+            q.astype(jnp.float32), kv.astype(jnp.float32), bias, R))
+    err = float(np.abs(got - want).max())
+    say(phase="kernel", op="mla_sparse_attention", shape="decode",
+        queries=T, keys=S, rows=Rk, max_abs_err=err,
+        out_std=float(want.std()))
+    if err > TOL_BF16 * 2 and not rehearse:
+        fail(f"mla_sparse_attention decode: {err}")
+    # a chunk's choice: ``dsa_threshold_tpu``'s two numbers a query against
+    # ``topk_mask``'s sets (scores in 64ths: thresholds are tied) at the
+    # table's whole width behind a history that ends inside a key block;
+    # then ``mla_sparse_attention_tpu``'s chunk form, which makes its mask
+    # from the same scores, against the plain form under ``topk_mask``'s bias
+    from helix_tpu.ops.dsa_kernel import (
+        dsa_threshold_tpu, mla_sparse_chunk_attention_tpu,
+    )
+
+    T, k = (512, 2048) if not rehearse else (32, 32)
+    t0, q_len = jnp.zeros((1,), jnp.int32), jnp.full((1,), T, jnp.int32)
+    for name, S, live in (("table", 16896, 6341), ("attended", 4096, 2900)):
         if rehearse:
-            S, T = S // 64, max(T // 16, 1)
-        q = (jax.random.normal(ks[3], (Rq, T, H, W), jnp.float32)
-             * 0.06).astype(bf)
-        kv = jax.random.normal(ks[4], (Rk, S, W), bf)
-        bias = jnp.where(jax.random.uniform(ks[5], (Rk, T, S)) < 0.25, 0.0,
-                         DEFAULT_MASK_VALUE)
-        got = np.asarray(dsa.sparse_attention(q, kv, bias, R, backend),
-                         np.float32)
-        with jax.default_matmul_precision("highest"):
-            want = np.asarray(mla_sparse_attention_reference(
-                q.astype(jnp.float32), kv.astype(jnp.float32), bias, R))
-        err = float(np.abs(got - want).max())
-        say(phase="kernel", op="mla_sparse_attention", shape=name,
-            queries=T, keys=S, rows=Rk, max_abs_err=err,
-            out_std=float(want.std()))
-        if err > TOL_BF16 * 2 and not rehearse:
-            fail(f"mla_sparse_attention {name}: {err}")
+            S, live = S // 64, live // 64
+        sc_h = jnp.round(jax.random.normal(ks[6], (1, T, S)) * 64) / 64
+        sc_f = jnp.round(jax.random.normal(ks[7], (T, T)) * 64) / 64
+        lim = jnp.full((1,), live, jnp.int32)
+        thr, tie = dsa_threshold_tpu(sc_h, sc_f, t0, q_len, lim, topk=k,
+                                     interpret=rehearse)
+        scores, valid, onehot = dsa._chunk_dense(sc_h, sc_f, t0, q_len, lim)
+        chosen = dsa.topk_mask(scores, valid, k)
+        agree = bool((dsa.kept(scores, valid, thr[0], tie[0]) == chosen).all())
+        say(phase="kernel", op="dsa_threshold", shape=name, queries=T,
+            keys=S, live=live, k=k, agree=agree,
+            cut_by_position=int((tie[0] < 2 ** 31 - 1).sum()))
+        if not agree:
+            fail(f"dsa_threshold {name} parts from topk_mask")
+    q = (jax.random.normal(ks[3], (T, H, W), jnp.float32) * 0.06).astype(bf)
+    kv_h = jax.random.normal(ks[4], (1, S, W), bf)
+    kv_f = jax.random.normal(ks[5], (T, W), bf)
+    got = np.asarray(mla_sparse_chunk_attention_tpu(
+        q, kv_h, kv_f, sc_h, sc_f, thr, tie, t0, q_len, lim, latent=R,
+        interpret=rehearse)[0], np.float32)
+    bias = jnp.where(chosen, 0.0, DEFAULT_MASK_VALUE)[None]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(mla_sparse_attention_reference(
+            q.astype(jnp.float32)[None], jnp.concatenate(
+                [kv_h[0], kv_f]).astype(jnp.float32)[None], bias, R))[0]
+    err = float(np.abs(got - want).max())
+    say(phase="kernel", op="mla_sparse_attention", shape="chunk", queries=T,
+        keys=S + T, live=live, rows=1, max_abs_err=err,
+        out_std=float(want.std()))
+    if err > TOL_BF16 * 2 and not rehearse:
+        fail(f"mla_sparse_attention chunk: {err}")
     sc = jax.random.normal(ks[6], (64, 3000 if not rehearse else 200))
     sc = jnp.round(sc * 64) / 64                 # ties at the threshold
     k = 2048 if not rehearse else 32

@@ -823,7 +823,7 @@ def _refuse_call(model_cfg, what: str) -> None:
 # (``Engine._note_dsa``): its ``/metrics`` series and flight fields
 DSA_COUNTS = ("keys_scored", "keys_selected", "rows_decode_sparse",
               "rows_decode_all", "rows_chunk_sparse", "rows_chunk_all",
-              "index_bytes_read", "latent_rows_fetched")
+              "index_bytes_read", "latent_rows_fetched", "select_bytes")
 
 
 def _dsa_of(cfg: ModelConfig):
@@ -2035,7 +2035,12 @@ class Engine:
         device's gather moves out of the index-key pool (``ops/dsa.py::
         _gather_rows``): EVERY row of a segment's page table at the table's
         whole width, whatever the row holds (the scoring kernel then skips
-        the key blocks past a row's history)."""
+        the key blocks past a row's history).  ``select_bytes`` is what a
+        chunk row's CHOICE moves: the float32 scores of the flat axis'
+        queries over the row's live key blocks and the fresh tokens, written
+        once (the scoring kernel) and read twice (the threshold kernel, the
+        attention kernel's mask): they follow the history, not the table;
+        a decode row chooses by ``lax.top_k`` and moves none."""
         cfg = self.model_cfg
         L, K = cfg.num_attn_layers, cfg.index_topk
         slots, table = self._page_tables.shape
@@ -2052,7 +2057,13 @@ class Engine:
             inc["latent_rows_fetched"] += int(np.minimum(n, K).sum())
         chooses = has_hist or rung > K
         if plan is not None and chooses:
+            from helix_tpu.ops.dsa_kernel import SCORE_KEY_BLOCK
+
             inc["index_bytes_read"] += plan.max_rows * row_bytes
+            width = table * self.cache_cfg.page_size
+            block = min(SCORE_KEY_BLOCK, -(-width // 128) * 128)
+            inc["select_bytes"] = 3 * 4 * rung * (rung + sum(
+                -(-r.start // block) * block for r in plan.rows))
         for r in (plan.rows if plan is not None else ()):
             n = np.arange(r.start + 1, r.start + r.rem + 1)
             mode = "sparse" if r.start + r.rem > K else "all"

@@ -1260,8 +1260,7 @@ def prefill_attn_fn(q, k, v, layer_cache, positions, qi=None, *,
         return jax.vmap(
             lambda q1, c1, r1, i1, p1, s1: dsa_dense_attention(
                 q1, c1, r1, i1, positions=p1, segment_ids=s1,
-                index_heads=cfg.index_heads, topk=cfg.index_topk,
-                backend="reference")
+                index_heads=cfg.index_heads, topk=cfg.index_topk)
         )(q, k, v, qi, positions, seg)
     if k.ndim == 3:
         from helix_tpu.ops.paged import mla_attention_reference

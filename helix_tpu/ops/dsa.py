@@ -13,21 +13,30 @@ and attends the ``topk`` keys of largest ``I`` (ties to the smaller ``s``;
 all of them while ``t + 1 <= topk``): cached keys and the step's fresh ones
 compete together, the query's own position like any other.
 
-Three passes, each dense over operands that XLA gathers from the pools by the
-page table (``pool.reshape(rows)[layer * N + table]``: one gather from the
-whole pool, no layer slice is copied):
+Passes over operands that XLA gathers from the pools by the page table
+(``pool.reshape(rows)[layer * N + table]``: one gather from the whole pool, no
+layer slice is copied):
 
 - **scores** (``index_scores``; on a TPU the kernel ``dsa_index_scores_tpu``):
   a row's queries against its index keys, in blocks over queries and keys; the
   ``[heads, queries, keys]`` product never leaves VMEM, what is written is the
   weighted sum over heads ``[queries, keys]``;
 - **choice**: a decode row's ``topk`` positions by ``lax.top_k`` (stable: ties
-  to the smaller position); a chunk's per-query THRESHOLD, the ``topk``-th
-  largest score of each query by bisection over the float's bits (32 passes of
-  compare-and-count, no sort), then a mask ``score >= threshold`` (ties at the
-  threshold cut by position, only in a step that has one);
-- **attention** (``sparse_attention``; ``mla_sparse_attention_tpu``): online
-  softmax of a query block's H heads over key blocks under a per-query bias.
+  to the smaller position).  A chunk's is TWO NUMBERS A QUERY: the ordered
+  bits of its ``topk``-th largest score, by bisection over the float's bits
+  (32 passes of compare-and-count, no sort), and the position that cuts the
+  keys tied AT it (no cut in almost every step).  On a TPU
+  ``dsa_threshold_tpu`` finds both over the row's LIVE scores held in VMEM;
+  the ``jax.numpy`` path (``topk_mask``: the CPU's, and every test's oracle)
+  builds the mask ``[queries, keys]`` itself;
+- **attention**: a decode row's H heads over the rows it gathered under a
+  bias of its own (``sparse_attention``; ``mla_sparse_attention_tpu``); a
+  chunk's query blocks over the row's history and the fresh rows in key
+  blocks (``chunk_attention``; the same kernel name's chunk form), each grid
+  step making its ``[BQ, BS]`` mask from the scores and the two numbers:
+  between the scoring kernel and the attention kernel's output a chunk
+  writes to HBM nothing of the page table's width but the float32 scores,
+  and touches no key block past the row's history.
 
 A DECODE row (one fresh token: ``max_q_len`` 1) reads its index keys (``Di``
 values a token), chooses, and fetches ``topk`` latent rows: never its whole
@@ -35,7 +44,8 @@ latent history.  A CHUNK row's queries each keep their own ``topk`` of one
 dense copy of the row's history: what a query dropped is masked inside the
 attention kernel (per-query sets of 2,048 rows for 512 queries would be 1.3 GB
 a layer); the products are those of dense attention, the saving is the decode
-row's.
+row's.  The two differ in kind (a threshold over a dense copy, a count over
+gathered rows); what tells them apart is the input's shape.
 """
 
 from __future__ import annotations
@@ -60,7 +70,9 @@ from helix_tpu.ops.paged import (
 # [B], hist, q_len, scores [B, S], chosen positions [B, K], kept [B, K])`` or
 # ``PROBE("chunk", layer, first pages [R], t0, q_len, hist, scores [T, S + T],
 # chosen [T, S + T])``, the key axis a
-# row's history positions then the flat axis' tokens.  None: nothing is traced
+# row's history positions then the flat axis' tokens (on a TPU both are
+# rebuilt for the callback from the kernels' outputs: a served program builds
+# neither).  None: nothing is traced
 PROBE = None
 
 
@@ -89,17 +101,13 @@ def index_scores(q, w, keys, backend=None, lim=None):
     return dsa_index_scores_reference(q, w, keys)
 
 
-def sparse_attention(q, kv, bias, latent: int, backend=None, lim=None,
-                     lead: int = 0):
+def sparse_attention(q, kv, bias, latent: int, backend=None):
     """``[R, T, H, latent]`` (``mla_sparse_attention_reference``'s
-    contract).  ``lim [R]`` / ``lead``: of a row's first ``lead`` keys only
-    the first ``lim`` are kept by any query (the kernel skips the blocks
-    between)."""
+    contract): a decode row over the rows it gathered."""
     if resolve_backend(backend) == "pallas":
         from helix_tpu.ops.dsa_kernel import mla_sparse_attention_tpu
 
-        return mla_sparse_attention_tpu(q, kv, bias, lim, latent=latent,
-                                        lead=lead)
+        return mla_sparse_attention_tpu(q, kv, bias, latent=latent)
     return mla_sparse_attention_reference(q, kv, bias, latent)
 
 
@@ -135,6 +143,17 @@ def topk_mask(scores, valid, k: int):
 
     over = jnp.any(jnp.sum(at_least, axis=-1, dtype=jnp.int32) > k)
     return jax.lax.cond(over, cut_ties, lambda _: at_least, None)
+
+
+def kept(scores, valid, thr, tie):
+    """``topk_mask``'s mask again from the two numbers a query that
+    ``dsa_kernel.dsa_threshold_tpu`` writes (``thr``, ``tie [...]``): a valid
+    entry whose ordered bits are over ``thr``, or AT it at an index no
+    larger than ``tie``."""
+    bits = jnp.maximum(_ordered_bits(scores), 1)
+    thr, tie = thr[..., None], tie[..., None]
+    return valid & ((bits > thr) | (
+        (bits == thr) & (jnp.arange(scores.shape[-1]) <= tie)))
 
 
 def _gather_rows(pool, layer, tables):
@@ -182,9 +201,9 @@ def dsa_ragged_paged_attention(
     S = maxP * P
     tables = tables.astype(jnp.int32)
     hist = hist.astype(jnp.int32)
-    pos = jnp.arange(S, dtype=jnp.int32)
     if max_q_len == 1 and T == n_rows:
         # ---- decode rows: token b is row b ------------------------------
+        pos = jnp.arange(S, dtype=jnp.int32)
         live = q_len > 0
         with jax.named_scope("attn.index.score"):
             keys = _gather_rows(idx_pages, layer, tables)         # [B, S, Di]
@@ -212,46 +231,95 @@ def dsa_ragged_paged_attention(
         out = sparse_attention(qp[:, None], kv, bias, lat, backend)
         return out[:, 0].astype(q.dtype)
     # ---- rows of several fresh tokens (chunks, packed prompts) -----------
-    row, q_off = _row_of_tokens(t0, q_len, T)
-    in_row = row >= 0
-    rowc = jnp.clip(row, 0)
-    onehot = (row[None, :] == jnp.arange(n_rows)[:, None])        # [R, T]
+    lim = hist * (q_len > 0)
     with jax.named_scope("attn.index.score"):
         keys = _gather_rows(idx_pages, layer, tables)             # [R, S, Di]
-        sc_h = index_scores(q_idx[None], w_idx[None], keys, backend,
-                            hist * (q_len > 0))
+        sc_h = index_scores(q_idx[None], w_idx[None], keys, backend, lim)
         sc_f = index_scores(q_idx[None], w_idx[None], i_new[None],
                             backend)[0]                           # [T, T]
-        ok_h = onehot[:, :, None] & (pos[None, None] < hist[:, None, None])
-        tok = jnp.arange(T)
-        ok_f = (in_row[:, None] & (row[:, None] == row[None, :])
-                & (tok[None, :] <= tok[:, None]))
-        # a token's own row's history scores, then the fresh tokens'
-        sc_own = jnp.take_along_axis(sc_h, rowc[None, :, None], axis=0)[0]
-        scores = jnp.concatenate([sc_own, sc_f], axis=-1)         # [T, S+T]
-        valid = jnp.concatenate(
-            [jnp.take_along_axis(ok_h, rowc[None, :, None], axis=0)[0],
-             ok_f], axis=-1)
-    with jax.named_scope("attn.index.select"):
-        chosen = topk_mask(scores, valid, topk)                   # [T, S+T]
-        _probe("chunk", layer, tables[:, 0], t0, q_len, hist, scores, chosen)
     with jax.named_scope("attn.sparse.gather"):
+        kv_h = _gather_rows(kv_pages, layer, tables)              # [R, S, W]
+    return chunk_attention(
+        qp, kv_h, fresh, sc_h, sc_f, t0, q_len, lim, topk=topk, latent=lat,
+        backend=backend, probe=(layer, tables[:, 0], hist)).astype(q.dtype)
+
+
+def _chunk_dense(sc_h, sc_f, t0, q_len, lim):
+    """A chunk's scores as ONE array a query (the ``jax.numpy`` path, and
+    what ``PROBE`` is shown): ``scores [T, S + T]``, a token's own row's
+    history positions then the flat axis' tokens; ``valid``, the keys a
+    query counts (its row's first ``lim`` cached ones, its row's fresh ones
+    up to itself); ``onehot [R, T]``, a token's row."""
+    R, T, S = sc_h.shape
+    row, _ = _row_of_tokens(t0, q_len, T)
+    own = jnp.clip(row, 0)[None, :, None]
+    onehot = row[None, :] == jnp.arange(R)[:, None]
+    ok_h = onehot[:, :, None] & (
+        jnp.arange(S, dtype=jnp.int32)[None, None] < lim[:, None, None])
+    tok = jnp.arange(T)
+    ok_f = ((row >= 0)[:, None] & (row[:, None] == row[None, :])
+            & (tok[None, :] <= tok[:, None]))
+    scores = jnp.concatenate(
+        [jnp.take_along_axis(sc_h, own, axis=0)[0], sc_f], axis=-1)
+    valid = jnp.concatenate(
+        [jnp.take_along_axis(ok_h, own, axis=0)[0], ok_f], axis=-1)
+    return scores, valid, onehot
+
+
+def chunk_attention(q, kv_h, kv_f, sc_h, sc_f, t0, q_len, lim, *, topk: int,
+                    latent: int, backend=None, probe=None):
+    """Rows of several fresh tokens on one flat axis: ``q [T, H, W]``, row
+    ``r`` the tokens ``[t0[r], t0[r] + q_len[r])`` behind ``lim[r]`` cached
+    keys ``kv_h [R, S, W]``, the fresh rows ``kv_f [T, W]``; ``sc_h [R, T,
+    S]`` / ``sc_f [T, T]`` every query's index scores of both.  Each query
+    attends the ``topk`` keys of its row (cached and fresh together, up to
+    itself) that ``topk_mask`` would choose; ``[T, H, latent]``, zeros for a
+    token outside every row.
+
+    On a TPU the choice never leaves the kernels as a mask: ``dsa_threshold_
+    tpu`` reads the live scores once and writes two numbers a query, and the
+    attention kernel makes each block's mask from the same scores."""
+    T = q.shape[0]
+    if resolve_backend(backend) == "pallas":
+        from helix_tpu.ops.dsa_kernel import (
+            dsa_threshold_tpu, mla_sparse_chunk_attention_tpu,
+        )
+
+        with jax.named_scope("attn.index.select"):
+            thr, tie = dsa_threshold_tpu(sc_h, sc_f, t0, q_len, lim,
+                                         topk=topk)               # [R, T]
+        if PROBE is not None and probe is not None:
+            # (what the probe is shown is rebuilt from the kernels' outputs)
+            scores, valid, _ = _chunk_dense(sc_h, sc_f, t0, q_len, lim)
+            at = (jnp.clip(_row_of_tokens(t0, q_len, T)[0], 0), jnp.arange(T))
+            _probe("chunk", *probe[:2], t0, q_len, probe[2], scores,
+                   kept(scores, valid, thr[at], tie[at]))
+        out = mla_sparse_chunk_attention_tpu(
+            q, kv_h, kv_f, sc_h, sc_f, thr, tie, t0, q_len, lim,
+            latent=latent)                                        # [R,T,H,lat]
+    else:
+        scores, valid, onehot = _chunk_dense(sc_h, sc_f, t0, q_len, lim)
+        with jax.named_scope("attn.index.select"):
+            chosen = topk_mask(scores, valid, topk)               # [T, S+T]
+            if probe is not None:
+                _probe("chunk", *probe[:2], t0, q_len, probe[2], scores,
+                       chosen)
         kv = jnp.concatenate(
-            [_gather_rows(kv_pages, layer, tables),
-             jnp.broadcast_to(fresh[None], (n_rows, T, W))], axis=1)
-    bias = jnp.where(chosen[None] & onehot[:, :, None], 0.0,
-                     DEFAULT_MASK_VALUE)                          # [R, T, S+T]
-    out = sparse_attention(qp[None], kv, bias, lat, backend,
-                           hist * (q_len > 0), S)                 # [R,T,H,lat]
+            [kv_h, jnp.broadcast_to(kv_f[None], (kv_h.shape[0],)
+                                    + kv_f.shape)], axis=1)
+        bias = jnp.where(chosen[None] & onehot[:, :, None], 0.0,
+                         DEFAULT_MASK_VALUE)                      # [R, T, S+T]
+        out = mla_sparse_attention_reference(q[None], kv, bias, latent)
     # a token is kept by its own row alone: the others give it zeros
-    return jnp.sum(out, axis=0).astype(q.dtype)
+    return jnp.sum(out, axis=0)
 
 
 def dsa_dense_attention(q, c, r_new, qi, *, positions, segment_ids,
-                        index_heads: int, topk: int, backend=None):
-    """The same mathematics with no pool: one flat axis of fresh tokens,
-    token ``t`` sees the tokens of its segment at positions up to its own
-    (the model's plain forward pass).  ``r_new`` is ``[k_pe | k_idx]``."""
+                        index_heads: int, topk: int):
+    """The same mathematics with no pool, in ``jax.numpy``: one flat axis of
+    fresh tokens, token ``t`` sees the tokens of its segment at positions up
+    to its own (the model's plain forward pass).  ``r_new`` is ``[k_pe |
+    k_idx]``."""
     T, H, Dq = q.shape
     q_idx, w_idx = index_queries(qi, index_heads)
     Di = q_idx.shape[-1]
@@ -259,13 +327,13 @@ def dsa_dense_attention(q, c, r_new, qi, *, positions, segment_ids,
     W = c.shape[-1] + r_new.shape[-1]
     kv = _latent_rows(c, r_new, W, q.dtype)
     with jax.named_scope("attn.index.score"):
-        scores = index_scores(q_idx[None], w_idx[None], i_new[None],
-                              backend)[0]
+        scores = dsa_index_scores_reference(q_idx[None], w_idx[None],
+                                            i_new[None])[0]
         valid = ((segment_ids[:, None] == segment_ids[None, :])
                  & (segment_ids[None, :] > 0)
                  & (positions[None, :] <= positions[:, None]))
     with jax.named_scope("attn.index.select"):
         chosen = topk_mask(scores, valid, topk)
     bias = jnp.where(chosen, 0.0, DEFAULT_MASK_VALUE)[None]
-    return sparse_attention(q[None], kv[None], bias, c.shape[-1],
-                            backend)[0]
+    return mla_sparse_attention_reference(q[None], kv[None], bias,
+                                          c.shape[-1])[0]

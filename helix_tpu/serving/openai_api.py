@@ -179,6 +179,7 @@ _DSA_SERIES = {
     "keys_selected": "helix_dsa_keys_selected_total",
     "index_bytes_read": "helix_dsa_index_bytes_read_total",
     "latent_rows_fetched": "helix_dsa_latent_rows_fetched_total",
+    "select_bytes": "helix_dsa_select_bytes_total",
 }
 
 
@@ -499,7 +500,9 @@ class OpenAIServer:
                 # ratio is the chosen share), rows by kind and by whether
                 # they were past ``index_topk`` keys, the bytes the gather
                 # moves out of the index-key pool (every table row at the
-                # table's whole width) and the latent rows fetched
+                # table's whole width), the latent rows fetched, and the
+                # score bytes a chunk row's choice moves (its live key
+                # blocks and fresh tokens, written once and read twice)
                 if key.startswith("rows_"):
                     _, kind, mode = key.split("_")
                     c.counter("helix_dsa_rows_total", n,

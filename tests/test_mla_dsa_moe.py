@@ -308,11 +308,46 @@ def test_plain_forward_chooses_as_the_reference_does():
     assert np.abs(np.asarray(dense) - np.asarray(want)).max() > 1e-3
 
 
+def _chunk_case(R, T, S, t0, q_len, hist, seed=0, ties=True):
+    """A chunk's inputs: index scores (rounded to halves, so that a
+    threshold is tied) of ``T`` flat queries against ``R`` rows' ``S``
+    history positions and against each other, rows ``[t0, t0 + q_len)``
+    behind ``hist`` cached keys."""
+    rng = np.random.default_rng(seed)
+    sc_h = rng.normal(size=(R, T, S)).astype(np.float32)
+    sc_f = rng.normal(size=(T, T)).astype(np.float32)
+    if ties:
+        sc_h, sc_f = np.round(sc_h * 2) / 2, np.round(sc_f * 2) / 2
+    t0, q_len, hist = (jnp.asarray(x, jnp.int32) for x in (t0, q_len, hist))
+    return jnp.asarray(sc_h), jnp.asarray(sc_f), t0, q_len, hist * (q_len > 0)
+
+
+def _kept_by(thr, tie, scores, valid, t0, q_len):
+    """The sets ``dsa_threshold_tpu``'s two numbers a query stand for, on
+    ``_chunk_dense``'s axis (each query by its own row's pair)."""
+    from helix_tpu.ops.paged import _row_of_tokens
+
+    T = scores.shape[0]
+    at = (jnp.clip(_row_of_tokens(t0, q_len, T)[0], 0), jnp.arange(T))
+    return np.asarray(dsa.kept(scores, valid, thr[at], tie[at]))
+
+
+def _stable_top_k(scores, valid, k):
+    scores, valid = np.asarray(scores), np.asarray(valid)
+    want = np.zeros(scores.shape, bool)
+    for t in range(len(scores)):
+        key = np.where(valid[t], scores[t], -np.inf)
+        order = np.argsort(-key, kind="stable")[:min(k, valid[t].sum())]
+        want[t, order] = True
+    return want & valid
+
+
 @pytest.mark.parametrize("shape", ["decode", "chunk"])
 def test_pallas_kernels_in_interpret_mode_against_the_plain_forms(shape):
     from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
     from helix_tpu.ops.dsa_kernel import (
-        dsa_index_scores_tpu, mla_sparse_attention_tpu,
+        dsa_index_scores_tpu, dsa_threshold_tpu,
+        mla_sparse_attention_tpu, mla_sparse_chunk_attention_tpu,
     )
     from helix_tpu.ops.paged import (
         dsa_index_scores_reference, mla_sparse_attention_reference,
@@ -332,17 +367,40 @@ def test_pallas_kernels_in_interpret_mode_against_the_plain_forms(shape):
     assert float(jnp.abs(jnp.where(seen, got - want, 0.0)).max()) < 1e-3
     q = jax.random.normal(k[3], (Rq, T, 8, 256)) * 0.1
     kv = jax.random.normal(k[4], (R, S, 256))
-    bias = jnp.where(jax.random.uniform(k[5], (R, T, S)) < 0.3, 0.0,
+    if shape == "decode":
+        bias = jnp.where(jax.random.uniform(k[5], (R, T, S)) < 0.3, 0.0,
+                         DEFAULT_MASK_VALUE)
+        bias = bias.at[0, 0].set(DEFAULT_MASK_VALUE)   # a query keeps none
+        got = mla_sparse_attention_tpu(q, kv, bias, latent=128,
+                                       interpret=True)
+        want = mla_sparse_attention_reference(q, kv, bias, 128)
+        assert float(jnp.abs(got - want).max()) < 1e-5
+        assert float(jnp.abs(got[0, 0]).max()) == 0.0
+        return
+    # the chunk form is handed the scores and two numbers a query, and is
+    # held to the plain form under the bias ``topk_mask`` builds: rows of
+    # 22 and 15 tokens behind 130 and 0 cached keys, tokens 22-24 in no row
+    sc_h, sc_f, t0, q_len, lim = _chunk_case(
+        R, T, S, [0, 25], [22, 15], [130, 0])
+    thr, tie = dsa_threshold_tpu(sc_h, sc_f, t0, q_len, lim, topk=32,
+                                 interpret=True)
+    fresh = jax.random.normal(k[5], (T, 256))
+    got = mla_sparse_chunk_attention_tpu(
+        q[0], kv, fresh, sc_h, sc_f, thr, tie, t0, q_len, lim, latent=128,
+        interpret=True)
+    scores, valid, onehot = dsa._chunk_dense(sc_h, sc_f, t0, q_len, lim)
+    chosen = dsa.topk_mask(scores, valid, 32)
+    assert int(chosen.sum(-1).max()) == 32 and int(chosen[25].sum()) == 1
+    bias = jnp.where(chosen[None] & onehot[:, :, None], 0.0,
                      DEFAULT_MASK_VALUE)
-    bias = bias.at[0, 0].set(DEFAULT_MASK_VALUE)   # a query that keeps none
-    # a row's history is its first 128 keys, of which ``lim`` can be kept
-    bias = jnp.where((jnp.arange(S)[None, None] < lim[:, None, None])
-                     | (jnp.arange(S) >= 128), bias, DEFAULT_MASK_VALUE)
-    got = mla_sparse_attention_tpu(q, kv, bias, jnp.minimum(lim, 128),
-                                   latent=128, lead=128, interpret=True)
-    want = mla_sparse_attention_reference(q, kv, bias, 128)
+    want = mla_sparse_attention_reference(
+        q, jnp.concatenate(
+            [kv, jnp.broadcast_to(fresh[None], (R, T, 256))], axis=1),
+        bias, 128)
     assert float(jnp.abs(got - want).max()) < 1e-5
-    assert float(jnp.abs(got[0, 0]).max()) == 0.0
+    # a token outside every row keeps nothing: zeros, in both rows
+    assert float(jnp.abs(got[:, 22:25]).max()) == 0.0
+    assert float(jnp.abs(got[1, :22]).max()) == 0.0
 
 
 def test_the_threshold_by_bisection_is_the_stable_top_k():
@@ -359,12 +417,216 @@ def test_the_threshold_by_bisection_is_the_stable_top_k():
     valid[4, 20:] = False                      # fewer than k valid
     valid[5, ::3] = False
     got = np.asarray(dsa.topk_mask(jnp.asarray(sc), jnp.asarray(valid), 32))
-    for r in range(6):
-        key = np.where(valid[r], sc[r], -np.inf)
-        order = np.argsort(-key, kind="stable")[:min(32, valid[r].sum())]
-        want = np.zeros(90, bool)
-        want[order] = True
-        np.testing.assert_array_equal(got[r], want & valid[r], str(r))
+    np.testing.assert_array_equal(got, _stable_top_k(sc, valid, 32))
+
+
+# what the threshold kernel is held to: (rows' t0, q_len, hist) on a flat
+# axis of 24 queries behind tables 384 wide, and what the scores hold
+THRESHOLD_CASES = {
+    "ties_at_the_threshold": ([0], [24], [300], "halves"),
+    "all_tied": ([0], [24], [300], "zeros"),
+    "negative_zero": ([0], [24], [300], "negative_zero"),
+    "fewer_valid_than_k": ([0], [24], [5], "halves"),
+    "two_rows_in_one_flat_axis": ([0, 9], [9, 13], [140, 290], "halves"),
+    "a_row_with_no_history": ([0, 12], [10, 12], [0, 200], "halves"),
+    "no_two_scores_alike": ([0], [24], [300], "normal"),
+}
+
+
+@pytest.mark.parametrize("case", list(THRESHOLD_CASES))
+def test_the_threshold_kernel_chooses_what_the_stable_sort_chooses(
+        case, monkeypatch):
+    """``dsa_threshold_tpu`` in interpret mode: its two numbers a query
+    stand for ``topk_mask``'s set, which is the stable sort's; key blocks of
+    128, and NaN in every score block past a row's ``lim`` (a pass that read
+    them would poison the count)."""
+    from helix_tpu.ops import dsa_kernel
+
+    monkeypatch.setattr(dsa_kernel, "SCORE_KEY_BLOCK", 128)
+    t0, q_len, hist, fill = THRESHOLD_CASES[case]
+    R, T, S = len(t0), 24, 384
+    sc_h, sc_f, t0, q_len, lim = _chunk_case(
+        R, T, S, t0, q_len, hist, ties=fill != "normal")
+    if fill == "zeros":
+        sc_h, sc_f = jnp.zeros_like(sc_h), jnp.zeros_like(sc_f)
+    elif fill == "negative_zero":
+        sc_h, sc_f = sc_h.at[..., ::2].set(-0.0), sc_f.at[..., ::2].set(-0.0)
+    dead = jnp.arange(S)[None] >= (-(-lim // 128) * 128)[:, None]
+    thr, tie = dsa_kernel.dsa_threshold_tpu.__wrapped__(
+        jnp.where(dead[:, None], jnp.nan, sc_h), sc_f, t0, q_len, lim,
+        topk=32, interpret=True)
+    scores, valid, _ = dsa._chunk_dense(sc_h, sc_f, t0, q_len, lim)
+    got = _kept_by(thr, tie, scores, valid, t0, q_len)
+    np.testing.assert_array_equal(
+        got, np.asarray(dsa.topk_mask(scores, valid, 32)))
+    np.testing.assert_array_equal(got, _stable_top_k(scores, valid, 32))
+    # a query of no more than k keys keeps them all under threshold 1; a
+    # query outside a row keeps nothing of it
+    n = np.asarray(valid.sum(-1))
+    row = np.asarray(dsa._row_of_tokens(t0, q_len, T)[0])
+    for t in range(T):
+        if row[t] >= 0 and n[t] <= 32:
+            assert int(thr[row[t], t]) == 1 and got[t].sum() == n[t]
+        for r in range(R):
+            if r != row[t]:
+                assert int(thr[r, t]) == 0xFFFFFFFF and int(tie[r, t]) == -1
+
+
+def _interpreted(monkeypatch):
+    """``ops/dsa.py``'s Pallas path on the CPU: its kernels in interpret
+    mode."""
+    import functools
+
+    from helix_tpu.ops import dsa_kernel
+
+    for name in ("dsa_index_scores_tpu", "dsa_threshold_tpu",
+                 "mla_sparse_attention_tpu",
+                 "mla_sparse_chunk_attention_tpu"):
+        monkeypatch.setattr(dsa_kernel, name, functools.partial(
+            getattr(dsa_kernel, name), interpret=True))
+
+
+def _pool_case(T, hist, q_len, t0, pages, kinds=4, seed=0):
+    """``dsa_ragged_paged_attention``'s arguments for rows on one flat axis
+    of ``T`` tokens: 8 heads over a latent of 128 + 64 rope lanes, 8 index
+    heads of 128, pages of 16.  Index keys are drawn from ``kinds``
+    vectors, and they, the index queries and the heads' weights hold small
+    whole numbers: every score is exact in float32 whatever the order of
+    its sums, so both backends read the SAME scores and many are EXACTLY
+    tied."""
+    R = len(hist)
+    k = jax.random.split(jax.random.PRNGKey(seed), 8)
+    N = R * pages + 1
+    some = jax.random.randint(k[0], (kinds, 128), -2, 3).astype(jnp.float32)
+    idx_pages = some[jax.random.randint(k[1], (1, N, 16), 0, kinds)]
+    i_new = some[jax.random.randint(k[2], (T,), 0, kinds)]
+    kv_pages = jax.random.normal(k[3], (1, N, 16, 256))
+    tables = 1 + jnp.arange(R * pages, dtype=jnp.int32).reshape(R, pages)
+    return dict(
+        q=jax.random.normal(k[4], (T, 8, 192)) * 0.1,
+        c_new=jax.random.normal(k[5], (T, 128)),
+        r_new=jnp.concatenate(
+            [jax.random.normal(k[6], (T, 64)), i_new], axis=-1),
+        qi=jax.random.randint(k[7], (T, 8 * 128 + 8), -1, 3).astype(
+            jnp.float32),
+        kv_pages=kv_pages, idx_pages=idx_pages, layer=0,
+        t0=jnp.asarray(t0, jnp.int32), q_len=jnp.asarray(q_len, jnp.int32),
+        hist=jnp.asarray(hist, jnp.int32), tables=tables)
+
+
+def test_the_chunk_branch_on_the_kernels_is_the_reference_backends(
+        monkeypatch):
+    """``dsa_ragged_paged_attention``'s chunk branch, Pallas in interpret
+    mode against ``backend='reference'``: a row whose history is shorter
+    than ``topk`` and one whose history is longer, exact ties at the
+    threshold (four kinds of index key), and what the probe is shown is the
+    reference backend's scores and sets."""
+    _interpreted(monkeypatch)
+    a = _pool_case(T=40, hist=[20, 150], q_len=[14, 24], t0=[0, 16], pages=12)
+    seen = {}
+    outs = {}
+    for backend in ("pallas", "reference"):
+        monkeypatch.setattr(
+            dsa, "PROBE", lambda kind, *x, b=backend: seen.update({b: x}))
+        outs[backend] = dsa.dsa_ragged_paged_attention(
+            **a, index_heads=8, topk=32, backend=backend, max_q_len=40)
+        jax.effects_barrier()
+    assert float(jnp.abs(outs["pallas"] - outs["reference"]).max()) < 1e-5
+    assert float(jnp.abs(outs["pallas"][14:16]).max()) == 0.0
+    scores, chosen = seen["reference"][-2:]
+    ours, ours_chosen = seen["pallas"][-2:]
+    counted = np.asarray(dsa._chunk_dense(
+        jnp.zeros((2, 40, 192)), jnp.zeros((40, 40)), a["t0"], a["q_len"],
+        a["hist"])[1])
+    np.testing.assert_array_equal(ours_chosen, chosen)
+    np.testing.assert_array_equal(np.where(counted, ours, 0.0),
+                                  np.where(counted, scores, 0.0))
+    # the threshold was tied, and cut by position: a query of the long row
+    # has more keys AT its 32nd score than it keeps of them
+    t = 30
+    kth = np.sort(scores[t][counted[t]])[-32]
+    at = counted[t] & (scores[t] == kth)
+    assert chosen[t].sum() == 32 and at.sum() > (at & chosen[t]).sum() > 0
+
+
+def test_a_chunks_choice_writes_nothing_the_width_of_the_table_but_scores():
+    """THE MECHANISM, pinned: in the chunk branch's program on the Pallas
+    backend, at 512 queries behind a table 4,096 wide, no equation outside
+    the kernels writes an array of queries x table width (512 x 4,096
+    elements or more: the gathered rows, 4,096 x 256, are under it) but
+    the scoring kernel's float32 scores: no ``valid``, no ordered bits, no
+    ``chosen``, no bias."""
+    T, S = 512, 4096
+    a = _pool_case(T=T, hist=[3000], q_len=[T], t0=[0], pages=S // 16)
+    jaxpr = jax.make_jaxpr(lambda kw: dsa.dsa_ragged_paged_attention(
+        **kw, layer=0, index_heads=8, topk=2048, backend="pallas",
+        max_q_len=T))({k: v for k, v in a.items() if k != "layer"})
+    kernels, wide = [], []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels.append(eqn)
+                continue
+            if eqn.params.get("name") == "dsa_index_scores_tpu":
+                continue                        # the scores themselves
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+            wide.extend((eqn.primitive.name, v.aval.shape)
+                        for v in eqn.outvars
+                        if hasattr(v.aval, "shape")
+                        and np.prod(v.aval.shape) >= T * S)
+
+    walk(jaxpr.jaxpr)
+    assert len(kernels) == 2 and not wide, wide
+
+
+def test_the_kernels_skip_the_key_blocks_no_query_can_see(monkeypatch):
+    """Key blocks of 128 (patched down from 512 so that a small case has
+    several): a row's history is 384 gathered positions of which ``lim`` are
+    real, 24 fresh tokens behind them.  Dead blocks hold NaN: a block that
+    was fetched and multiplied would poison the output."""
+    from helix_tpu.ops import dsa_kernel
+    from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
+    from helix_tpu.ops.paged import (
+        dsa_index_scores_reference, mla_sparse_attention_reference,
+    )
+
+    monkeypatch.setattr(dsa_kernel, "ATTN_KEY_BLOCK", 128)
+    monkeypatch.setattr(dsa_kernel, "SCORE_KEY_BLOCK", 128)
+    R, T, S = 2, 24, 384
+    sc_h, sc_f, t0, q_len, lim = _chunk_case(
+        R, T, S, [0, 9], [9, 15], [130, 0])
+    k = jax.random.split(jax.random.PRNGKey(1), 6)
+    live = jnp.arange(S)[None] < (-(-lim // 128) * 128)[:, None]
+    q = jax.random.normal(k[0], (1, T, 8, 128))
+    w = jax.random.normal(k[1], (1, T, 8))
+    keys = jnp.where(live[..., None], jax.random.normal(k[2], (R, S, 128)),
+                     jnp.nan)
+    got = dsa_kernel.dsa_index_scores_tpu.__wrapped__(
+        q, w, keys, lim, interpret=True)
+    want = dsa_index_scores_reference(q, w, keys)
+    seen = jnp.arange(S)[None, None] < lim[:, None, None]
+    assert float(jnp.abs(jnp.where(seen, got - want, 0.0)).max()) < 1e-3
+    q = jax.random.normal(k[3], (T, 8, 256)) * 0.1
+    kv = jnp.where(live[..., None], jax.random.normal(k[4], (R, S, 256)),
+                   jnp.nan)
+    fresh = jax.random.normal(k[5], (T, 256))
+    dead_scores = jnp.where(live[:, None], sc_h, jnp.nan)
+    thr, tie = dsa_kernel.dsa_threshold_tpu.__wrapped__(
+        dead_scores, sc_f, t0, q_len, lim, topk=32, interpret=True)
+    got = dsa_kernel.mla_sparse_chunk_attention_tpu.__wrapped__(
+        q, kv, fresh, dead_scores, sc_f, thr, tie, t0, q_len, lim,
+        latent=128, interpret=True)
+    scores, valid, onehot = dsa._chunk_dense(sc_h, sc_f, t0, q_len, lim)
+    bias = jnp.where(dsa.topk_mask(scores, valid, 32)[None]
+                     & onehot[:, :, None], 0.0, DEFAULT_MASK_VALUE)
+    want = mla_sparse_attention_reference(
+        q[None], jnp.concatenate(
+            [jnp.nan_to_num(kv), jnp.broadcast_to(fresh[None], (R, T, 256))],
+            axis=1), bias, 128)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 1e-5
 
 
 # ---- held experts -----------------------------------------------------------
@@ -666,6 +928,14 @@ def test_the_hosts_account_is_exported_and_read():
     assert c["index_bytes_read"] == (6 * 2 + 2) * 16 * 16 * 16 * 4 * L
     assert seen[0]["dsa_keys_scored"] == 0
     assert seen[-1]["dsa_latent_rows_fetched"] == K * L
+    # what a chunk row's choice moves: the float32 scores of the bucket's 16
+    # queries over the row's live key blocks (one, of the table's 256
+    # positions) and the 16 fresh tokens, written once and read twice; none
+    # for the cold first chunk (nothing chosen) and none for a decode row
+    select = 3 * 4 * 16 * (256 + 16) * L
+    assert [s["dsa_select_bytes"] for s in seen] == [0, select, select,
+                                                     0, 0, 0]
+    assert c["select_bytes"] == 2 * select
     assert sum(s["dsa_keys_scored"] for s in seen) == c["keys_scored"]
     registry = ModelRegistry()
     loop = EngineLoop(eng, "tiny-dsa")
@@ -688,11 +958,13 @@ def test_the_hosts_account_is_exported_and_read():
     assert series("helix_dsa_latent_rows_fetched_total") == c[
         "latent_rows_fetched"]
     assert series("helix_dsa_index_bytes_read_total") == c["index_bytes_read"]
+    assert series("helix_dsa_select_bytes_total") == 2 * select
     assert series("helix_dsa_index_pool_bytes") == 64 * L * 16 * 16 * 4
     assert "helix_mla_page_fetches_total" in text
     loop._flight_record(0.0, loop._flight_pre(), 0)
     rec = loop.flight.snapshot()["recent"][-1]
     assert rec["dsa_keys_scored"] == 0 and "dsa_latent_rows_fetched" in rec
+    assert rec["dsa_select_bytes"] == 0
 
 
 def test_int8_tree_and_logical_axes_cover_the_indexers_tensors():
@@ -715,45 +987,3 @@ def test_int8_tree_and_logical_axes_cover_the_indexers_tensors():
     ) == jax.tree.structure(jax.tree.map(lambda a: 0, born))
     jax.tree.map(lambda ax, leaf: None if len(ax) == leaf.ndim else 1 / 0,
                  axes, born, is_leaf=is_axes)
-
-
-def test_the_kernels_skip_the_key_blocks_no_query_can_see(monkeypatch):
-    """Key blocks of 128 (patched down from 512 so that a small case has
-    several): a row's history is 384 gathered positions of which ``lim`` are
-    real, 128 fresh tokens behind them.  Dead blocks hold NaN: a block that
-    was fetched and multiplied would poison the output."""
-    from helix_tpu.ops import dsa_kernel
-    from helix_tpu.ops.attention import DEFAULT_MASK_VALUE
-    from helix_tpu.ops.paged import (
-        dsa_index_scores_reference, mla_sparse_attention_reference,
-    )
-
-    monkeypatch.setattr(dsa_kernel, "ATTN_KEY_BLOCK", 128)
-    monkeypatch.setattr(dsa_kernel, "SCORE_KEY_BLOCK", 128)
-    R, T, lead, S = 2, 24, 384, 512
-    lim = jnp.asarray([130, 0])
-    k = jax.random.split(jax.random.PRNGKey(1), 6)
-    live = (jnp.arange(S)[None] < (-(-lim // 128) * 128)[:, None]) | (
-        jnp.arange(S)[None] >= lead)
-    q = jax.random.normal(k[0], (1, T, 8, 128))
-    w = jax.random.normal(k[1], (1, T, 8))
-    keys = jnp.where(live[..., None], jax.random.normal(k[2], (R, S, 128)),
-                     jnp.nan)
-    got = dsa_kernel.dsa_index_scores_tpu.__wrapped__(
-        q, w, keys[:, :lead], lim, interpret=True)
-    want = dsa_index_scores_reference(q, w, keys[:, :lead])
-    seen = jnp.arange(lead)[None, None] < lim[:, None, None]
-    assert float(jnp.abs(jnp.where(seen, got - want, 0.0)).max()) < 1e-3
-    q = jax.random.normal(k[3], (1, T, 8, 256)) * 0.1
-    kv = jnp.where(live[..., None], jax.random.normal(k[4], (R, S, 256)),
-                   jnp.nan)
-    keep = (jax.random.uniform(k[5], (R, T, S)) < 0.4) & (
-        (jnp.arange(S)[None, None] < lim[:, None, None])
-        | (jnp.arange(S) >= lead))
-    bias = jnp.where(keep, 0.0, DEFAULT_MASK_VALUE)
-    got = dsa_kernel.mla_sparse_attention_tpu.__wrapped__(
-        q, kv, bias, lim, latent=128, lead=lead, interpret=True)
-    want = mla_sparse_attention_reference(
-        q, jnp.nan_to_num(kv), bias, 128)
-    assert bool(jnp.isfinite(got).all())
-    assert float(jnp.abs(got - want).max()) < 1e-5

@@ -730,3 +730,37 @@ def test_grouped_product_compiles_at_2304_by_896(one_chip, rows):
         S((), jnp.int32)).compile()
     assert compiled.as_text().count("grouped_matmul_tpu") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < X * E * F
+
+
+# ---- GLM-5: a chunk's choice behind the indexer -----------------------------
+
+@pytest.mark.parametrize("rows", [1, 4], ids=["one_row", "four_rows"])
+def test_chunk_choice_kernels_compile_at_the_published_geometry(one_chip,
+                                                                rows):
+    """``dsa_threshold_tpu`` and the chunk form of ``mla_sparse_attention_
+    tpu`` at GLM-5's sizes: 512 flat queries of 64 heads over rows of 640
+    lanes behind a page table 16,896 wide (33 key blocks of 512 and the
+    fresh block: the threshold's scratch is 34 x 64 x 512 float32)."""
+    from helix_tpu.ops.dsa_kernel import (
+        dsa_threshold_tpu, mla_sparse_chunk_attention_tpu)
+
+    T, S, H, W, lat = 512, 16896, 64, 640, 512
+
+    def A(shp, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+
+    scores = (A((rows, T, S), jnp.float32), A((T, T), jnp.float32))
+    extents = (A((rows,)), A((rows,)), A((rows,)))
+    compiled = jax.jit(lambda *a: dsa_threshold_tpu(*a, topk=2048)).lower(
+        *scores, *extents).compile()
+    assert "dsa_threshold_tpu" in compiled.as_text()
+    compiled = jax.jit(
+        lambda *a: mla_sparse_chunk_attention_tpu(*a, latent=lat)).lower(
+        A((T, H, W), jnp.bfloat16), A((rows, S, W), jnp.bfloat16),
+        A((T, W), jnp.bfloat16), *scores, A((rows, T), jnp.uint32),
+        A((rows, T)), *extents).compile()
+    # the name a trace finds the kernel by
+    # (benchmark/metrics/kernel.mla_sparse_share.json)
+    assert "mla_sparse_attention_tpu" in compiled.as_text()
+    # no mask, bias or second copy of the scores beside the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < T * S

@@ -640,9 +640,10 @@ def test_sparse_latent_step_compiles_at_published_widths(one_chip, program):
     """A whole engine step of GLM-5 (the dense layer and two expert layers of
     its cut of eight, int8 weights, 16 slots, a page table 1,056 wide over
     16,897 pages) for the described chip: the scoring kernel over a row's
-    gathered index keys, the choice, the sparse attention kernel over the
-    chosen rows (decode) or under a per-query mask over a dense copy of the
-    history (a 512-token chunk), the row scatter into BOTH pools.  (A cold
+    gathered index keys, the choice (a decode row's ``lax.top_k``; a chunk's
+    threshold kernel), the sparse attention kernel over the chosen rows
+    (decode) or over a dense copy of the history under the mask it makes from
+    the scores (a 512-token chunk), the row scatter into BOTH pools.  (A cold
     chunk is the latent kernel's with the table 1,056 wide in SMEM: compiled
     here at PR 53 and run in the cell; not kept, for the suite's time.)"""
     import dataclasses
@@ -701,6 +702,9 @@ def test_sparse_latent_step_compiles_at_published_widths(one_chip, program):
     assert "dsa_index_scores_tpu" in text
     assert "mla_sparse_attention_tpu" in text
     assert "grouped_matmul_tpu" in text
+    # a chunk's choice is the threshold kernel's (two numbers a query); a
+    # decode row's is ``lax.top_k``
+    assert ("dsa_threshold_tpu" in text) == (program != "decode")
     # both pools are updated in place: no pool-sized temporary
     pool_bytes = ks[0] * pages * 16 * (512 + 128) * 2
     mem = compiled.memory_analysis()
