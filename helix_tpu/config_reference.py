@@ -276,7 +276,14 @@ ENV_REFERENCE: tuple = (
         "in the host pool; on a fully-resident engine it caps the "
         "whole sequence. Unset: the profile's engine block (default "
         "128; the widest a benchmark profile sets is 1,056, 16,896 tokens "
-        "a sequence, for GLM-5 (model_type glm_moe_dsa), where a page is a "
+        "a sequence, for Qwen3-Next-80B-A3B (model_type qwen3_next; a page "
+        "is 98,304 bytes over its 3 attention layers of 2 kv heads of 256, "
+        "beside 19,316,736 bytes of delta-rule state a slot whatever the "
+        "length; its new ModelConfig fields are linear_gate, "
+        "attn_gate_channels and shared_expert_gate, its catalog entry "
+        "Qwen/Qwen3-Next-80B-A3B-Instruct, its cell "
+        "qwen3-next-80b-a3b.saturated-16k) and "
+        "for GLM-5 (model_type glm_moe_dsa), where a page is a "
         "page of TWO pools under one id, the latent pool and the index-key "
         "pool of the sparse-attention indexer, ModelConfig.index_heads / "
         "index_head_dim / index_topk: 196,608 bytes over its 8 layers; "

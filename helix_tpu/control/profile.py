@@ -84,7 +84,12 @@ class ProfileModel:
     #     first dims only); window_rope_theta, window_rope_scaling,
     #     window_rotary_dim: the window layers'
     #   attn_gate: a sigmoid gate on the attention's output (ONE value a
-    #     head on GQA layers, a value a channel on latent ones)
+    #     head on GQA layers, a value a channel on latent ones and, with
+    #     attn_gate_channels, on GQA layers too)
+    #   linear_gate: the delta layers' output gate, sigmoid (scaled, on a
+    #     norm that takes norm_offset) or silu (on a plain-gain norm)
+    #   shared_expert_gate: the shared expert times sigmoid(x w_sg), one
+    #     value a token
     #   held_experts: [lo, hi), the routed experts this chip holds as one
     #     expert-parallel rank
     model_overrides: dict = dataclasses.field(default_factory=dict)

@@ -114,6 +114,9 @@ class ModelConfig:
     # --- DeepSeek-V2 family: fine-grained MoE with shared experts ---
     moe_intermediate_size: int = 0      # routed expert width (0: the FFN's)
     num_shared_experts: int = 0         # one shared MLP of this many widths
+    # the shared expert's output times ``sigmoid(x w_sg)``, ONE value a
+    # token from the layer's normed input (``w_sg [E, 1]``; Qwen3-Next)
+    shared_expert_gate: bool = False
     first_k_dense: int = 0              # leading layers with a dense FFN
     # True: softmax over the top-k logits (Mixtral).  False: softmax over
     # all experts, the top-k probabilities kept as they are (DeepSeek's
@@ -153,12 +156,15 @@ class ModelConfig:
     # ``linear_value_heads`` value heads of ``linear_value_dim``, behind a
     # causal depthwise convolution of ``conv_kernel`` taps over q|k|v.  Its
     # state is the conv's tail and a float32 matrix a value head: no pages.
-    # The output gate is ``linear_gate_scale * sigmoid(z)`` on a
-    # zero-centred RMSNorm a head
+    # The output gate, ``linear_gate``: "sigmoid" is ``linear_gate_scale *
+    # sigmoid(z)`` on an RMSNorm a head whose gain takes ``norm_offset``
+    # (GigaChat3.5); "silu" is ``silu(z)`` on an RMSNorm a head with a PLAIN
+    # gain, whatever ``norm_offset`` is (Qwen3-Next)
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_dim: int = 0
     linear_value_dim: int = 0
+    linear_gate: str = "sigmoid"
     linear_gate_scale: float = 1.0
     linear_norm_eps: float = 1e-6
     # --- multi-head latent attention (MLA); 0 = plain multi-head ---
@@ -190,8 +196,11 @@ class ModelConfig:
     # cache is the last ``sliding_window`` tokens' K and V, a fixed RING a
     # sequence in the state pool (a token at position p in ring row p mod
     # sliding_window): no pages.  On the GQA path ``attn_gate`` is ONE value
-    # a head, ``o_h <- sigmoid(x W_g)_h o_h``, on both kinds of layer
+    # a head, ``o_h <- sigmoid(x W_g)_h o_h``, on both kinds of layer; with
+    # ``attn_gate_channels`` one a head AND channel (``W_g [E, heads *
+    # head_dim]``: the gate half of Qwen3-Next's doubled ``q_proj``)
     sliding_window: int = 0
+    attn_gate_channels: bool = False
     # a window layer's query heads (0: ``num_heads``) and its rope: theta
     # (0: ``rope_theta``), scaling (the full layers' ``rope_scaling`` does
     # NOT carry over) and rotary width
@@ -607,6 +616,8 @@ class ModelConfig:
             family = cls._nemotron_h_family(hf)
         if model_type == "mellum":
             family = cls._mellum_family(hf)
+        if model_type == "qwen3_next":
+            family = cls._qwen3_next_family(hf)
         rope_theta = family.pop("rope_theta", None) or hf.get(
             "rope_theta", 10000.0)
         rope_scaling = family.pop("rope_scaling", rope_scaling)
@@ -635,7 +646,8 @@ class ModelConfig:
             attention_bias=hf.get("attention_bias", False)
             or model_type == "qwen2",
             mlp_bias=hf.get("mlp_bias", False),
-            qk_norm=model_type in ("qwen3", "lfm2_moe", "brumby"),
+            qk_norm=model_type in ("qwen3", "lfm2_moe", "brumby",
+                                   "qwen3_next"),
             max_position_embeddings=hf.get("max_position_embeddings", 8192),
             num_experts_per_tok=hf.get("num_experts_per_tok", 2),
             name=name,
@@ -847,6 +859,86 @@ class ModelConfig:
             moe_scoring="softmax",
             expert_capacity_factor=0.0,
         )
+
+    @staticmethod
+    def _qwen3_next_family(hf: dict) -> dict:
+        """The fields a ``model_type: qwen3_next`` config sets: gated
+        delta-rule layers (the ``linear_*`` keys) with a full-attention layer
+        every ``full_attention_interval`` (layers 3, 7, ... at 4; a
+        ``layer_types`` list, where given, says it outright), whose GQA
+        heads rotate the first ``partial_rotary_factor`` of their dims, carry
+        zero-centred q/k norms and a sigmoid gate a head and channel from a
+        doubled ``q_proj``; every norm of the block zero-centred (``1 + w``)
+        but the delta layer's output norm, which is plain and gated by
+        ``silu(z)``; routed experts in EVERY layer behind a softmax router
+        (renormalised over the chosen under ``norm_topk_prob``) beside one
+        shared expert under a sigmoid gate of its own.  The multi-token-
+        prediction module is not a layer of the served stack.  What is not
+        served is refused by name."""
+        def refuse(why):
+            raise ValueError(f"qwen3_next: {why}")
+
+        L = hf["num_hidden_layers"]
+        if hf.get("decoder_sparse_step", 1) != 1:
+            refuse(f"decoder_sparse_step {hf['decoder_sparse_step']} is not "
+                   "supported: every layer's feed-forward is the experts (1)")
+        if hf.get("mlp_only_layers"):
+            refuse(f"mlp_only_layers {hf['mlp_only_layers']} is not "
+                   "supported: no layer has a dense MLP in place of experts")
+        if hf.get("use_sliding_window"):
+            refuse("use_sliding_window true is not supported: its attention "
+                   "layers are full")
+        if hf.get("rope_scaling"):
+            refuse(f"rope_scaling {hf['rope_scaling']} is not supported: "
+                   "plain rope (null)")
+        if hf.get("attention_bias"):
+            refuse("attention_bias true is not supported: the gate's half of "
+                   "q_proj is a matrix of its own here, with no bias")
+        kinds = {"linear_attention": "deltanet", "full_attention": "attn"}
+        if hf.get("layer_types"):
+            unknown = sorted(set(hf["layer_types"]) - set(kinds))
+            if unknown:
+                refuse(f"layer_types {unknown} are not supported: "
+                       f"{sorted(kinds)}")
+            types = tuple(kinds[t] for t in hf["layer_types"])
+            if len(types) != L:
+                refuse("layer_types does not name num_hidden_layers layers")
+        else:
+            every = hf.get("full_attention_interval", 4)
+            types = tuple("attn" if (i + 1) % every == 0 else "deltanet"
+                          for i in range(L))
+        head_dim = hf.get("head_dim") or (
+            hf["hidden_size"] // hf["num_attention_heads"])
+        width = int(head_dim * hf.get("partial_rotary_factor", 1.0))
+        fx = hf["moe_intermediate_size"]
+        shared = hf.get("shared_expert_intermediate_size") or 0
+        if shared % fx:
+            refuse(f"a shared expert of {shared} is not a whole number of "
+                   f"routed experts' widths ({fx})")
+        family = dict(
+            layer_types=types,
+            rotary_dim=0 if width == head_dim else width,
+            norm_offset=1.0,
+            attn_gate=True,
+            attn_gate_channels=True,
+            conv_kernel=hf["linear_conv_kernel_dim"],
+            linear_key_heads=hf["linear_num_key_heads"],
+            linear_value_heads=hf["linear_num_value_heads"],
+            linear_key_dim=hf["linear_key_head_dim"],
+            linear_value_dim=hf["linear_value_head_dim"],
+            linear_gate="silu",
+            linear_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            num_experts=hf["num_experts"],
+            moe_intermediate_size=fx,
+            num_shared_experts=shared // fx,
+            shared_expert_gate=shared > 0,
+            moe_renormalize=bool(hf.get("norm_topk_prob", True)),
+            moe_scoring="softmax",
+            expert_capacity_factor=0.0,
+        )
+        family.update(
+            ModelConfig._held_experts(hf, "qwen3_next", "num_experts"))
+        return family
 
     # keys of a ``model_type: nemotron_h`` config that no layer of the served
     # stack reads, each with why
@@ -1346,9 +1438,64 @@ GLM5 = ModelConfig(
     name="zai-org/GLM-5",
 )
 
+# Qwen3-Next-80B-A3B-Instruct (https://huggingface.co/Qwen/
+# Qwen3-Next-80B-A3B-Instruct/blob/main/config.json, ``model_type:
+# qwen3_next``): twelve periods of three gated delta-rule layers (16 key / 32
+# value heads of 128 behind a 4-tap convolution; a float32 matrix a value head
+# and a conv tail in the state pool; the output ``silu(z)`` times a PLAIN-gain
+# norm) and one gated attention layer (16 query heads over 2 kv heads of 256,
+# rope over the first 64 dims at theta 1e7, zero-centred q/k norms, a sigmoid
+# gate a head and channel: GQA pages beside the state pool); in EVERY layer 512
+# routed experts of width 512 top-10 behind a softmax router renormalised over
+# the chosen, plus one shared expert of 512 under a sigmoid gate a token;
+# zero-centred norms (``1 + w``).  ``intermediate_size`` is published and read
+# by no layer.  One chip holds a cut of it as ONE expert-parallel rank
+# (``held_experts``, set by the profile); what would move or share the state is
+# refused at engine start (the kind's record, ``models/mixers.py``).  Refused
+# by name (``from_hf_config``): ``decoder_sparse_step`` != 1, ``mlp_only_
+# layers``, ``use_sliding_window``, a ``rope_scaling``, ``attention_bias``.
+# The multi-token-prediction module is not loaded.  On the chip:
+# ``chip_smoke_deepseek.py --config qwen3-next-80b-a3b-int8``.
+QWEN3_NEXT_80B = ModelConfig(
+    vocab_size=151936,
+    hidden_size=2048,
+    num_layers=48,
+    num_heads=16,
+    num_kv_heads=2,
+    head_dim=256,
+    intermediate_size=5120,
+    rope_theta=10000000.0,
+    rotary_dim=64,
+    rms_norm_eps=1e-6,
+    norm_offset=1.0,
+    qk_norm=True,
+    max_position_embeddings=262144,
+    num_experts=512,
+    num_experts_per_tok=10,
+    expert_capacity_factor=0.0,
+    moe_intermediate_size=512,
+    num_shared_experts=1,
+    shared_expert_gate=True,
+    moe_renormalize=True,
+    moe_scoring="softmax",
+    attn_gate=True,
+    attn_gate_channels=True,
+    layer_types=tuple(
+        "attn" if i % 4 == 3 else "deltanet" for i in range(48)),
+    conv_kernel=4,
+    linear_key_heads=16,
+    linear_value_heads=32,
+    linear_key_dim=128,
+    linear_value_dim=128,
+    linear_gate="silu",
+    linear_norm_eps=1e-6,
+    name="Qwen/Qwen3-Next-80B-A3B-Instruct",
+)
+
 CATALOG = {
     m.name: m
     for m in (LLAMA3_8B, PHI3_MINI, QWEN2_7B, MIXTRAL_8X7B,
               DEEPSEEK_V2_LITE, LFM2_8B_A1B, BRUMBY_14B, GIGACHAT35_432B,
-              LAGUNA_XS2, NEMOTRON3_SUPER_120B, MELLUM2_12B, GLM5)
+              LAGUNA_XS2, NEMOTRON3_SUPER_120B, MELLUM2_12B, GLM5,
+              QWEN3_NEXT_80B)
 }

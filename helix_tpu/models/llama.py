@@ -218,7 +218,9 @@ def init_params(
             dt = jnp.exp(jax.random.uniform(
                 kd, (n, nv), jnp.float32, jnp.log(0.001), jnp.log(0.1)))
             lp["dt_bias"] = {"bias": dt + jnp.log(-jnp.expm1(-dt))}
-            lp["o_norm"] = norm(dv)
+            # under the silu gate the norm's gain is stored as it is
+            lp["o_norm"] = ({"weight": jnp.ones((n, dv), dtype)}
+                            if cfg.linear_gate == "silu" else norm(dv))
             lp["out_proj"] = w((nv * dv, E), "out_proj")
         elif mixer == "mamba2":
             Hm, C = cfg.mamba_heads, cfg.mamba_channels
@@ -302,9 +304,12 @@ def init_params(
             lp["wv"] = w((E, KVH * D), "wv")
             lp["wo"] = w((Ht * D, E), "wo")
             if cfg.attn_gate and mixer in ("attn", "window"):
-                # ONE value a head: logits of std ~0.9 from a normed input,
-                # gates of 0.3-0.7, so a gate left out is seen
-                lp["attn_gate"] = w((E, Ht), "attn_gate")
+                # ONE value a head (or a head and channel): logits of std
+                # ~0.9 from a normed input, gates of 0.3-0.7, so a gate left
+                # out is seen
+                lp["attn_gate"] = w(
+                    (E, Ht * D if cfg.attn_gate_channels else Ht),
+                    "attn_gate")
         if mixer == "retention":
             # a gate a kv head, ``sigmoid(W_g n + b_g)``.  The bias is drawn
             # uniform in [3, 7]: gates of 0.95-0.999, a state that remembers
@@ -348,6 +353,9 @@ def init_params(
             if cfg.num_shared_experts:
                 lp["shared"] = mlp(
                     (), E, cfg.num_shared_experts * Fx, "shared")
+                if cfg.shared_expert_gate:
+                    # one logit a token, of std ~0.9 as the attention gate's
+                    lp["shared_gate"] = w((E, 1), "shared_gate")
         elif ffn:
             lp.update(mlp((), E, F))
         if cfg.attention_bias and mixer in ("attn", "window"):
@@ -355,8 +363,8 @@ def init_params(
                               ("wk", KVH * D), ("wv", KVH * D)):
                 lp[nm]["bias"] = jnp.zeros((n, width), dtype)
         if cfg.qk_norm and mixer in ("attn", "retention", "window"):
-            lp["q_norm"] = {"weight": jnp.ones((n, D), dtype)}
-            lp["k_norm"] = {"weight": jnp.ones((n, D), dtype)}
+            lp["q_norm"] = norm(D)
+            lp["k_norm"] = norm(D)
         return lp
 
     # A dropless expert model's embedding rows are drawn at unit RMS.  At
@@ -490,6 +498,8 @@ def param_logical_axes(cfg: ModelConfig) -> Any:
             lax_["experts"] = experts
             if cfg.num_shared_experts:
                 lax_["shared"] = mlp
+                if cfg.shared_expert_gate:
+                    lax_["shared_gate"] = {"weight": ("layers", "embed", None)}
         elif ffn:
             lax_.update(mlp)
         if cfg.attention_bias and mixer in ("attn", "window"):
@@ -722,8 +732,10 @@ def _retention_mixer(h, p, layer_cache, cfg, positions, inv_freq,
         k = _dense(x, p["wk"]).astype(h.dtype).reshape(B, S, KVH, D)
         v = _dense(x, p["wv"]).astype(h.dtype).reshape(B, S, KVH, D)
         if cfg.qk_norm:
-            q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
-            k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
+            q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps,
+                         cfg.norm_offset)
+            k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps,
+                         cfg.norm_offset)
         q = apply_rope(q, positions, inv_freq)
         k = apply_rope(k, positions, inv_freq)
         from helix_tpu.ops.quant import maybe_dequant_dense
@@ -747,8 +759,9 @@ def _deltanet_mixer(h, p, layer_cache, cfg, state_fn, post=None):
     W_qkv))`` through a causal depthwise convolution, a write strength
     ``beta = sigmoid(x W_b)`` and a log decay ``g = -exp(A_log) * softplus(x
     W_a + dt_bias)`` a value head, the rule, then ``n_h(o) * (scale *
-    sigmoid(x W_z))`` with ``n_h`` an RMSNorm over a head's channels, and
-    ``W_o``.  ``state_fn(x W_qkv, g, beta, taps, layer_cache) -> (o [B, S,
+    sigmoid(x W_z))`` with ``n_h`` an RMSNorm over a head's channels (or,
+    ``cfg.linear_gate`` "silu", ``n_h(o) * silu(x W_z)`` with ``n_h``'s gain
+    PLAIN whatever ``cfg.norm_offset``), and ``W_o``.  ``state_fn(x W_qkv, g, beta, taps, layer_cache) -> (o [B, S,
     heads, dv] float32, new_cache)`` owns the look-back: the convolution's
     tail and the matrix state a sequence carries between calls."""
     from helix_tpu.ops.quant import maybe_dequant_dense
@@ -770,10 +783,15 @@ def _deltanet_mixer(h, p, layer_cache, cfg, state_fn, post=None):
     o, new_cache = state_fn(
         qkv, g, beta, p["conv"]["taps"], layer_cache)
     with jax.named_scope("deltanet.out_proj"):
-        y = rms_norm(o, p["o_norm"]["weight"], cfg.linear_norm_eps,
-                     cfg.norm_offset)
-        y = y * (cfg.linear_gate_scale * jax.nn.sigmoid(
-            z.astype(jnp.float32).reshape(B, S, nv, dv)))
+        if cfg.linear_gate == "silu":
+            y = rms_norm(o, p["o_norm"]["weight"], cfg.linear_norm_eps)
+            y = y * jax.nn.silu(
+                z.astype(jnp.float32).reshape(B, S, nv, dv))
+        else:
+            y = rms_norm(o, p["o_norm"]["weight"], cfg.linear_norm_eps,
+                         cfg.norm_offset)
+            y = y * (cfg.linear_gate_scale * jax.nn.sigmoid(
+                z.astype(jnp.float32).reshape(B, S, nv, dv)))
         branch = _dense(y.astype(h.dtype).reshape(B, S, nv * dv),
                         p["out_proj"])
         h = h + (post(branch) if post else branch).astype(h.dtype)
@@ -835,13 +853,15 @@ def _layer(
     state_fn=None,
     moe_decode_rows: int = 0,
     mixer: str = "attn",
+    moe_probe=None,
 ):
     """One decoder block. h: [B, S, E].  ``mixer``: the layer's kind (a GQA
     layer's, ``"attn"`` or ``"window"``, decides its query heads, its rope
     through ``inv_freq`` and its look-back; the other mixers are told by
     their weights).  ``state_fn``: the look-back of a kind with a
     per-sequence state (``STATE_MIXERS``); None: its record's ``oracle``,
-    every row a whole sequence.
+    every row a whole sequence.  ``moe_probe = (layer's index in the model,
+    the pass's input ids)``: for ``models.moe.PROBE``, where one is set.
 
     When ``attn_fn`` returns ``(out, new_cache)`` (the carry-cache decode
     protocol — the paged pool threads through the layer scan and the
@@ -905,8 +925,12 @@ def _layer(
             k = _dense(x, p["wk"], adapter_ids).reshape(B, S, KVH, D)
             v = _dense(x, p["wv"], adapter_ids).reshape(B, S, KVH, D)
             if cfg.qk_norm:
-                q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps)
-                k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps)
+                # a family whose norms are zero-centred stores these gains
+                # as offsets from 1 too
+                q = rms_norm(q, p["q_norm"]["weight"], cfg.rms_norm_eps,
+                             cfg.norm_offset)
+                k = rms_norm(k, p["k_norm"]["weight"], cfg.rms_norm_eps,
+                             cfg.norm_offset)
             if cfg.attn_rope:
                 q = apply_rope(q, positions, inv_freq, rot)
                 k = apply_rope(k, positions, inv_freq, rot)
@@ -921,11 +945,19 @@ def _layer(
         else:
             attn_out = res
         if "attn_gate" in p:
-            # one sigmoid gate a head, from the branch's normed input
+            # one sigmoid gate a head (``attn_gate_channels``: a head and
+            # channel), from the branch's normed input
             with jax.named_scope(f"{sc}.gate"):
-                attn_out = (attn_out.astype(jnp.float32) * jax.nn.sigmoid(
-                    _dense(x, p["attn_gate"]).astype(jnp.float32)
-                )[..., None]).astype(h.dtype)
+                # (one expression, the gate's spread inside it: a gate a
+                # head lowers to the text it lowered to before the channel
+                # form came)
+                spread = ((lambda g: g.reshape(B, S, H, D))
+                          if cfg.attn_gate_channels
+                          else (lambda g: g[..., None]))
+                attn_out = (attn_out.astype(jnp.float32) * spread(
+                    jax.nn.sigmoid(
+                        _dense(x, p["attn_gate"]).astype(jnp.float32)
+                    ))).astype(h.dtype)
         with jax.named_scope(f"{sc}.out"):
             h = h + _dense(
                 attn_out.reshape(B, S, H * D), p["wo"], adapter_ids)
@@ -966,14 +998,25 @@ def _layer(
             expert_bias=(p["expert_bias"]["bias"]
                          if "expert_bias" in p else None),
             decode_rows=moe_decode_rows,
+            probe=None if moe_probe is None else (*moe_probe, positions),
         )
         if "fc2" in p:
             with jax.named_scope("moe.latent_out"):
                 moe_out = _dense(moe_out, p["fc2"]).astype(h.dtype)
         if "shared" in p:
             with jax.named_scope("moe.shared"):
-                moe_out = moe_out + _swiglu(
-                    x, p["shared"], act, limit=cfg.swiglu_limit)
+                shared = _swiglu(x, p["shared"], act, limit=cfg.swiglu_limit)
+            if "shared_gate" in p:
+                # ONE sigmoid gate a token on the shared expert, its logit
+                # in float32
+                from helix_tpu.ops.quant import maybe_dequant_dense
+
+                with jax.named_scope("moe.shared_gate"):
+                    shared = (shared.astype(jnp.float32) * jax.nn.sigmoid(
+                        maybe_dequant_dense(
+                            x, p["shared_gate"], compute_dtype=jnp.float32))
+                              ).astype(h.dtype)
+            moe_out = moe_out + shared
         ffn = moe_out
     else:
         ffn = _swiglu(x, p, act, adapter_ids, scoped=True,
@@ -1101,10 +1144,13 @@ def forward(
     }
     h = embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
 
-    def run_layers(h, carry, stack, run, rep):
+    from helix_tpu.models import moe
+
+    def run_layers(h, carry, stack, run, rep, layer0=0):
         """The ``run.count`` layers of one run at repetition ``rep`` of its
         group (0 for a plain run; a traced index inside a group's loop):
-        ``stack`` holds them alone.  Returns ``(h, kv or carry, stats)``."""
+        ``stack`` holds them alone, the first of them layer ``layer0`` of
+        the model.  Returns ``(h, kv or carry, stats)``."""
         whole = None
         if "experts" in params[run.key] and cfg.expert_capacity_factor <= 0:
             # the grouped product is a Pallas kernel (ops/grouped_matmul.py):
@@ -1127,6 +1173,8 @@ def forward(
                     whole, rep * run.count + i),
                 moe_backend=moe_backend, state_fn=state_fn,
                 moe_decode_rows=moe_decode_rows, mixer=run.mixer,
+                moe_probe=(None if moe.PROBE is None
+                           else (layer0 + i, tokens)),
             )
 
         # the cache's layer index counts the layers of the run's mixer:
@@ -1142,10 +1190,14 @@ def forward(
         )
 
     kvs, stats = [], []
+    layer0 = 0              # layers before the group at hand
     for reps, runs in layer_stacks(params, cfg):
+        span = sum(run.count for _, run in runs)   # layers a repetition
         if reps == 1:
             for stack, run in runs:
-                h, kv, st = run_layers(h, carry_caches, stack, run, 0)
+                h, kv, st = run_layers(h, carry_caches, stack, run, 0,
+                                       layer0)
+                layer0 += run.count
                 if carry_caches is not None:
                     carry_caches = kv
                 if carry_caches is not None or run.mixer == "attn":
@@ -1168,8 +1220,10 @@ def forward(
             h, caches = carry
             rep, stacks = x
             outs = []
+            at = layer0 + rep * span
             for part, (_, run) in zip(stacks, runs):
-                h, kv, st = run_layers(h, caches, part, run, rep)
+                h, kv, st = run_layers(h, caches, part, run, rep, at)
+                at = at + run.count
                 if caches is not None:
                     caches = kv
                 outs.append((None if caches is not None else kv, st))
@@ -1178,6 +1232,7 @@ def forward(
         (h, carry_caches), outs = jax.lax.scan(
             period, (h, carry_caches),
             (jnp.arange(reps, dtype=jnp.int32), xs))
+        layer0 += reps * span
         if carry_caches is not None:
             kvs.append(carry_caches)
         # layer order within the group: repetition-major

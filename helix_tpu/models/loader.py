@@ -7,8 +7,11 @@ owned: safetensors are memory-mapped on the host, transposed into our
 scan-based forward, then device_put with the model's NamedShardings so each
 chip only materialises its shard (no full-model HBM spike on load).
 
-Supports Llama/Qwen2/Qwen3 per-projection layouts and Phi-3's fused
-``qkv_proj``/``gate_up_proj``.
+Supports Llama/Qwen2/Qwen3 per-projection layouts, Phi-3's fused
+``qkv_proj``/``gate_up_proj``, DeepSeek-V2's latent attention and
+Qwen3-Next's delta-rule / gated-attention / expert layers
+(``_qwen3_next_tree``: the published file's interleaved projections
+un-interleaved at load).
 """
 
 from __future__ import annotations
@@ -118,6 +121,9 @@ def load_params(
     pfx = "model.layers.{}."
     if cfg.is_mla:
         params = _deepseek_v2_tree(cfg, shards, get, linear_t)
+        return cfg, _place_tree(params, cfg, mesh, logical_axes, quantize)
+    if cfg.state_mixer == "deltanet":
+        params = _qwen3_next_tree(cfg, get, linear_t)
         return cfg, _place_tree(params, cfg, mesh, logical_axes, quantize)
     fused_qkv = f"{pfx.format(0)}self_attn.qkv_proj.weight" in shards
     fused_mlp = f"{pfx.format(0)}mlp.gate_up_proj.weight" in shards
@@ -313,4 +319,116 @@ def _deepseek_v2_tree(cfg, shards, get, linear_t):
         params["dense_layers"] = stack_of(range(n_dense), False)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"weight": linear_t("lm_head.weight")}
+    return params
+
+
+def _qwen3_next_tree(cfg, get, linear_t):
+    """Qwen3-Next's tensor names (``model_type: qwen3_next``) into the tree of
+    ``init_params``: a stack a run of ``cfg.layer_runs()`` (the delta layers
+    of every period under one key, the attention layers under another,
+    repetition-major).  Three of the published file's projections are
+    INTERLEAVED and are parted here:
+
+    - ``linear_attn.in_proj_qkvz`` ``[2 nk dk + 2 nv dv, E]``: a key-head
+      group's ``[q (dk) | k (dk) | v (r dv) | z (r dv)]``, ``r = nv / nk``
+      value heads a key head, group after group: into ``in_qkv`` (columns
+      ``q | k | v``, each heads-major: the order of the published ``conv1d``'s
+      channels) and ``in_z``;
+    - ``linear_attn.in_proj_ba`` ``[2 nv, E]``: a group's ``[b (r) | a (r)]``:
+      into ``in_b`` and ``in_a``;
+    - ``self_attn.q_proj`` ``[H * 2D, E]``: a head's ``[q (D) | gate (D)]``:
+      into ``wq`` and ``attn_gate``.
+
+    ``conv1d.weight [C, 1, K]`` is the taps ``[C, K]`` (the last tap the
+    token's own).  Experts ``[lo, hi)`` of ``cfg.held_experts`` alone are
+    read.  The multi-token-prediction module (``mtp.*``) is not loaded."""
+    pfx = "model.layers.{}."
+    nk, nv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    r = nv // nk
+    H, D, E = cfg.num_heads, cfg.head_dim, cfg.hidden_size
+    lo, hi = cfg.held_experts or (0, cfg.num_experts)
+
+    def delta(i):
+        at = pfx.format(i) + "linear_attn."
+        qkvz = linear_t(at + "in_proj_qkvz.weight").reshape(
+            E, nk, 2 * dk + 2 * r * dv)
+        q, k, v, z = np.split(
+            qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=-1)
+        ba = linear_t(at + "in_proj_ba.weight").reshape(E, nk, 2 * r)
+        flat = lambda t: np.ascontiguousarray(t.reshape(E, -1))
+        return {
+            "in_qkv": {"weight": np.concatenate(
+                [flat(q), flat(k), flat(v)], axis=-1)},
+            "in_z": {"weight": flat(z)},
+            "in_b": {"weight": flat(ba[..., :r])},
+            "in_a": {"weight": flat(ba[..., r:])},
+            "conv": {"taps": get(at + "conv1d.weight")[:, 0, :]},
+            "A_log": {"bias": get(at + "A_log").astype(np.float32)},
+            "dt_bias": {"bias": get(at + "dt_bias").astype(np.float32)},
+            "o_norm": {"weight": get(at + "norm.weight")},
+            "out_proj": {"weight": linear_t(at + "out_proj.weight")},
+        }
+
+    def attn(i):
+        at = pfx.format(i) + "self_attn."
+        qg = linear_t(at + "q_proj.weight").reshape(E, H, 2 * D)
+        return {
+            "wq": {"weight": np.ascontiguousarray(
+                qg[..., :D].reshape(E, H * D))},
+            "attn_gate": {"weight": np.ascontiguousarray(
+                qg[..., D:].reshape(E, H * D))},
+            "wk": {"weight": linear_t(at + "k_proj.weight")},
+            "wv": {"weight": linear_t(at + "v_proj.weight")},
+            "wo": {"weight": linear_t(at + "o_proj.weight")},
+            "q_norm": {"weight": get(at + "q_norm.weight")},
+            "k_norm": {"weight": get(at + "k_norm.weight")},
+        }
+
+    def experts(i):
+        at = pfx.format(i) + "mlp."
+        names = (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                 ("w_down", "down_proj"))
+        return {
+            "router": {"weight": linear_t(at + "gate.weight")},
+            "experts": {ours: {"weight": np.stack([
+                linear_t(at + f"experts.{e}.{theirs}.weight")
+                for e in range(lo, hi)])} for ours, theirs in names},
+            "shared": {ours: {"weight": linear_t(
+                at + f"shared_expert.{theirs}.weight")}
+                for ours, theirs in names},
+            "shared_gate": {"weight": linear_t(
+                at + "shared_expert_gate.weight")},
+        }
+
+    def layer(i, mixer):
+        return {
+            "attn_norm": {"weight": get(
+                pfx.format(i) + "input_layernorm.weight")},
+            "mlp_norm": {"weight": get(
+                pfx.format(i) + "post_attention_layernorm.weight")},
+            **(delta(i) if mixer == "deltanet" else attn(i)),
+            **experts(i),
+        }
+
+    def stacked(trees):
+        import jax
+
+        return jax.tree.map(lambda *a: np.stack(a), *trees)
+
+    params = {
+        "embed": {"weight": get("model.embed_tokens.weight")},
+        "final_norm": {"weight": get("model.norm.weight")},
+        "lm_head": {"weight": linear_t("lm_head.weight")},
+    }
+    at = 0
+    for group in cfg.layer_runs():
+        span = sum(run.count for run in group.runs)
+        off = 0
+        for run in group.runs:
+            ids = [at + rep * span + off + n
+                   for rep in range(group.reps) for n in range(run.count)]
+            params[run.key] = stacked([layer(i, run.mixer) for i in ids])
+            off += run.count
+        at += group.reps * span
     return params

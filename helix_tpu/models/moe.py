@@ -98,6 +98,15 @@ def route(xf, router_p, cfg, expert_bias=None):
 
 STATS = 6
 
+# A verification hook: a callable that, WHILE SET WHEN A PROGRAM IS TRACED,
+# receives every expert layer's choices through ``jax.debug.callback``:
+# ``PROBE(layer, tokens [B, S], positions [B, S], valid [T], experts [T,
+# k])``, ``layer`` the layer's index in the model and ``tokens`` the pass's
+# input ids (``helix_tpu/testing/moe_probe.py`` keeps them by layer, position
+# and token; ``tests/test_deltanet_gqa_moe.py`` and ``chip_smoke_deepseek.py``
+# run the plain reference on them).  None: nothing is traced
+PROBE = None
+
 
 def expert_load_stats(top_idx, valid, X, dropped=0, tile_fill=0.0,
                       held=None):
@@ -262,7 +271,7 @@ def experts_xla(xs, group_sizes, e_row, experts_p, layer, act,
 def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
             return_dropped=False, return_stats=False, stacked_experts=None,
             backend=None, interpret=False, expert_bias=None,
-            decode_rows: int = 0, router_x=None):
+            decode_rows: int = 0, router_x=None, probe=None):
     """x: [B, S, E] -> the routed experts' weighted sum [B, S, E].  With
     ``return_dropped`` also the int32 count of (token, choice) assignments
     this call dropped to capacity overflow (always 0 on the dropless
@@ -285,7 +294,9 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
     (the CPU tests).  ``decode_rows``: the last that many tokens are decode
     rows on a prefill's axis (``_capacity_experts``).  ``router_x [B, S,
     E']``: what the router scores where that is not the experts' input (experts
-    in a latent: ``x`` is the projected input, ``router_x`` the un-projected)."""
+    in a latent: ``x`` is the projected input, ``router_x`` the un-projected).
+    ``probe = (layer, tokens, positions)``: what ``PROBE`` is shown beside the
+    choices, where one is set."""
     B, S, E = x.shape
     X = cfg.num_experts
     T = B * S
@@ -299,6 +310,10 @@ def moe_ffn(x, router_p, experts_p, cfg, act, token_mask=None,
         top_w, top_idx = route(
             xf if router_x is None else router_x.reshape(T, -1), router_p,
             cfg, expert_bias)
+    if PROBE is not None and probe is not None:
+        jax.debug.callback(
+            lambda *a: PROBE(*(jax.device_get(x) for x in a)),
+            *probe, valid, top_idx)
     fill = 0.0
     held = cfg.held_experts
     if cfg.expert_capacity_factor > 0:

@@ -533,17 +533,28 @@ def _long_block(
 
     def relay(flat, dst, keep=None):
         """``flat [n, KVH * D]`` to ``dst[:, :n]`` (``[KVH, S, D]``); of the
-        rows at and past ``keep`` zeros."""
+        rows at and past ``keep`` zeros.  A head wider than a lane tile (256:
+        Qwen3-Next) is zeroed where it LANDS, and only in a step that holds
+        such rows: Mosaic refuses the row mask on the pages' flat view there
+        ("changeBitwidth when minor tiling is not 128")."""
         n = flat.shape[0]
         if flat.dtype != dot_dtype:
             flat = flat.astype(jnp.float32)
-        if keep is not None:
+        late = keep is not None and D > 128
+        if keep is not None and not late:
             flat = jnp.where(
                 jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) < keep,
                 flat, 0)
         for k in range(KVH):
             dst[k, pl.ds(0, n)] = flat[:, k * D:(k + 1) * D].astype(
                 dot_dtype)
+        if late:
+            @pl.when(keep < n)
+            def _():
+                rows = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) < keep
+                for k in range(KVH):
+                    dst[k, pl.ds(0, n)] = jnp.where(
+                        rows, dst[k, pl.ds(0, n)], 0).astype(dot_dtype)
 
     def heads(ok, n, ks=None, vs=None):
         """One step of the online softmax, a kv head at a time, over the

@@ -258,6 +258,16 @@ _LONG_ROWS = {
         16, 2, 32, 16, 20,
         [(5, 0, 0), (40, 130, 0), (1, 17, 0), (16, 128, 0), (9, 300, 0),
          (3, 64, 0), (30, 5, 0), (8, 200, 0)], 128, "float32", 1, 128),
+    # a head of two lane tiles (Qwen3-Next: 16 / 2 / 256): the rows past a
+    # step's end are zeroed where they land, in the steps that hold any
+    # (histories on a step's edge, inside one, none; an int8 pool widens
+    # its codes first)
+    "a_head_of_256_lanes_at_a_group_of_8": (
+        16, 2, 256, 16, 20, [(136, 300, 3), (130, 256, 5), (9, 0, 1)], 136,
+        "float32", 1, 128),
+    "a_head_of_256_lanes_over_an_int8_pool": (
+        16, 2, 256, 16, 20, [(136, 270, 0), (9, 128, 2)], 136, "int8", 1,
+        128),
     # the module's own step (1,024 tokens, fresh keys 512 a step) over pages
     # of 128: two whole steps and a part of a third
     "a_history_of_three_steps_at_the_modules_width": (

@@ -2,8 +2,10 @@
 without the chip: DeepSeek-V2-Lite (latent attention, experts), LFM2-8B-A1B
 (conv layers), Brumby-14B (power retention), GigaChat3.5 (the gated delta
 rule beside latent attention), Laguna-XS.2 (sliding-window layers),
-Nemotron-3-Super (Mamba-2 layers, experts in a latent) and Mellum2-12B-A2.5B
-(rings of 1,024 over 4 kv heads, a page table 544 wide, 64 experts held).
+Nemotron-3-Super (Mamba-2 layers, experts in a latent), Mellum2-12B-A2.5B
+(rings of 1,024 over 4 kv heads, a page table 544 wide, 64 experts held) and
+Qwen3-Next-80B-A3B (the WHOLE cut of twelve layers: the delta rule beside GQA
+pages at 256 lanes a head, a page table 1,056 wide, 256 experts held).
 
 The rules of ``test_tpu_compile.py`` hold here (its docstring); the fixtures
 are ``tests/tpu_topology.py``'s.  Nothing runs, so these say nothing about
@@ -601,6 +603,8 @@ CHUNK_ROW_GEOMETRY = {
     "mistral-7b": (32, 8, 128, 64),
     "lfm2-8b-a1b": (32, 8, 64, 160),
     "nemotron-3-super": (32, 2, 128, 160),
+    # a head of two lane tiles at a group of 8, a table 1,056 pages wide
+    "qwen3-next": (16, 2, 256, 1056),
 }
 
 
@@ -617,7 +621,7 @@ def test_paged_kernel_compiles_a_chunk_row_at_every_cells_geometry(
     from helix_tpu.ops.paged_kernel import paged_query_block
 
     H, KVH, D, max_pages = CHUNK_ROW_GEOMETRY[geometry]
-    pack = 128 // D
+    pack = max(1, 128 // D)
     T, L, pages = 512, 2, 2048
 
     def S(shp, dt=jnp.int32):
@@ -733,3 +737,104 @@ def test_sparse_latent_step_compiles_at_published_widths(one_chip, program):
         assert 0 <= pools.memory_analysis().output_size_in_bytes - (
             counted) <= 4096
         assert key * pages * 16 == 3 * pages * 16 * 128 * 2
+
+
+@pytest.mark.parametrize("program", ["decode", "t512_r1", "t512_r1_h"])
+def test_delta_gqa_step_compiles_at_published_widths(one_chip, program):
+    """The WHOLE cut of Qwen3-Next-80B-A3B (twelve layers: three periods of
+    three delta layers and a gated attention layer, run as ONE group of two
+    loop bodies; int8 weights, 16 slots, a page table 1,056 wide over 16,897
+    pages, 256 of 512 experts held) for the described chip: the delta decode
+    kernel over the state pool in the carry, the chunked form in its chunk
+    kernel at 16 / 32 heads, the paged kernel at 16 / 2 heads of 256 lanes
+    (decode rows; a 512-token chunk row's long blocks over the history: the
+    form Mosaic refused until the rows past a step's end were zeroed where
+    they land), the grouped product at 256 groups, both pools updated in
+    place, and weights, pools and temporaries inside the chip's memory."""
+    import dataclasses
+
+    from helix_tpu.engine import engine as E
+    from helix_tpu.engine.kv_cache import CacheConfig, PagedKVCache
+    from helix_tpu.engine.sampling import SamplingState
+    from helix_tpu.models.common import QWEN3_NEXT_80B
+    from helix_tpu.models.llama import init_params
+
+    cfg = dataclasses.replace(
+        QWEN3_NEXT_80B, num_layers=12,
+        layer_types=QWEN3_NEXT_80B.layer_types[:12], held_experts=(0, 256))
+    assert [g.reps for g in cfg.layer_runs()] == [3] and cfg.loop_bodies == 2
+    B, max_pages, pages = 16, 1056, 16897
+    i32 = jnp.int32
+
+    def S(shp, dt=i32):
+        return jax.ShapeDtypeStruct(tuple(shp), dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0), int8=True)))
+    cc = CacheConfig(num_pages=pages, state_slots=B,
+                     max_pages_per_seq=max_pages)
+    ks, vs = cc.page_shapes(cfg)
+    assert ks == vs == (3, 16, 2, 256)
+    assert cc.state_shapes(cfg) == (
+        ((9, B, 3, 8192), "bfloat16"), ((9, B, 32, 128, 128), "float32"))
+    cache = PagedKVCache(
+        k_pages=S((ks[0], pages) + ks[1:], jnp.bfloat16),
+        v_pages=S((vs[0], pages) + vs[1:], jnp.bfloat16),
+        state=tuple(S(shp, jnp.dtype(dt))
+                    for shp, dt in cc.state_shapes(cfg)))
+
+    def sampling(n):
+        f32 = jnp.float32
+        return SamplingState(
+            temperature=S((n,), f32), top_p=S((n,), f32), top_k=S((n,)),
+            presence=S((n,), f32), frequency=S((n,), f32))
+
+    state = E.DecodeState(
+        last_token=S((B,)), positions=S((B,)),
+        page_tables=S((B, max_pages)), active=S((B,)),
+        mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
+        token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
+        sampling=sampling(B))
+    bucket, rows = (0, 0) if program == "decode" else (512, 1)
+    pargs = () if not bucket else (
+        *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
+        S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
+        S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
+    fn = E._build_ragged_step_fn(
+        cfg, PAGE, "pallas", None, bucket, program.endswith("_h"), rows, 1,
+        0 if bucket else 7)
+    compiled = fn.lower(
+        params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
+    ).compile()
+    text = compiled.as_text()
+    for kernel in ("deltanet_decode_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention_tpu") + (
+                       ("deltanet_chunk_tpu",) if bucket else ()):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert ".remat" not in text
+    # both pools are updated in place: aliased whole, and no temporary of
+    # the state pool's size; the whole step fits the chip
+    mem = compiled.memory_analysis()
+    pools = cc.total_bytes(cfg)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    if program == "decode":
+        # weights and pools as the described chip lays them out, against the
+        # benchmark's count (benchmark/lib/model_bytes_deltanet_gqa_moe.py)
+        import json
+        import os
+
+        from benchmark.lib import model_bytes_deltanet_gqa_moe as mb
+
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmark", "configs",
+                "qwen3-next-80b-a3b-int8.json")) as f:
+            hf = json.load(f)
+        counted = (mb.weight_bytes(hf) + pages * mb.page_bytes(hf, 16)
+                   + B * mb.state_bytes_per_slot(hf))
+        # (beside them the decode state's arrays, 10 MB of token counts)
+        assert 0 <= mem.argument_size_in_bytes - counted < 16e6
