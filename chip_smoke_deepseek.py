@@ -1855,7 +1855,10 @@ def phase_kernel_ssd(spec, seed, rehearse):
     ``ssd_chunk_tpu``), against the token-by-token recurrence run on the
     host's CPU: a row from zeros, and the row that continues it from the
     state the first left; and the device's time a layer for the whole and
-    for each half (``_ssd_form_times``)."""
+    for each half (``_ssd_form_times``).  Between the two, a fused WINDOW of 4
+    and of 8 decode steps (steps that read the state and write nothing, then
+    the commit) against the recurrence run on the host's CPU, and the decode
+    kernel's time a layer by the form of the call (``_ssd_decode_times``)."""
     from helix_tpu.ops import ssd
 
     phase_kernel(spec, seed, rehearse)
@@ -1903,12 +1906,63 @@ def phase_kernel_ssd(spec, seed, rehearse):
         idle_slots_and_other_layers_untouched=untouched, tol=TOL_SSD_F32,
         ok=bool(ok))
 
+    # a fused window: the steps before the last read the state and write
+    # nothing, the last commits the window's tokens at once.  Some rows sit
+    # some steps out, one joins at the third; against the recurrence a step
+    # at a time ON THE HOST (the chip's recurrence multiplies one rounded
+    # ``exp`` a step; the window takes one ``exp`` of their sum)
+    cpu = jax.devices("cpu")[0]
+    on_cpu = lambda *a: jax.device_put(a, cpu)
+
+    def a_window(steps):
+        (ref,), win = on_cpu(pool), pool
+        pending = ssd.window_zeros(L, B, H, P, G, N, 8)
+        worst, unwritten = 0.0, True
+        for i in range(steps):
+            ki = jax.random.split(jax.random.fold_in(ks[6], 10 * steps + i), 5)
+            dt = jnp.exp(jax.random.uniform(
+                ki[1], (B, H), minval=jnp.log(1e-3), maxval=jnp.log(0.3)))
+            A = -jax.random.uniform(ks[2], (H,), minval=0.5, maxval=4.0)
+            a = (jax.random.normal(ki[0], (B, H, P)), dt, dt * A,
+                 jax.random.normal(ki[3], (B, G, N)),
+                 jax.random.normal(ki[4], (B, G, N)))
+            here = live & ((jnp.arange(B) + i) % 4 != 0) & (
+                (jnp.arange(B) != 0) | (i >= 2))
+            with jax.default_device(cpu):
+                yr, ref, _ = ssd.ssd_window_step(
+                    *on_cpu(*a), ref, None, 1, *on_cpu(here), 0, True,
+                    backend="reference")
+            yw, win, pending = ssd.ssd_window_step(
+                *a, win, pending, 1, here, jnp.int32(i),
+                jnp.asarray(i == steps - 1), backend="pallas",
+                interpret=rehearse)
+            worst = max(worst, rel(yw, np.asarray(yr)))
+            if i < steps - 1:
+                unwritten = unwritten and bool(jnp.all(win == pool))
+        return {"state": rel(win[1], np.asarray(ref[1])),
+                "output": worst}, bool(
+            unwritten and jnp.all(win[0] == pool[0])
+            and jnp.all(win[1][idle] == pool[1][idle]))
+
+    windows = {steps: a_window(steps) for steps in (4, 8)}
+    win_ok = all(exact and all(e <= TOL_SSD_F32 for e in errs.values())
+                 for errs, exact in windows.values())
+    say(phase="kernel", op="ssd_decode_tpu (a fused window)",
+        geometry=[H, P, G, N], rows=B,
+        **{f"window_of_{n}": errs for n, (errs, _) in windows.items()},
+        pool_exact_until_the_commit=all(e for _, e in windows.values()),
+        tol=TOL_SSD_F32, ok=bool(win_ok))
+    ok = ok and win_ok
+    del h1, pool1, h0, hb, rounded
+    say(phase="kernel", op="ssd_decode_tpu (device time a layer)",
+        geometry=[H, P, G, N], rows=B,
+        **_ssd_decode_times(spec, ssd, B, H, P, G, N, rehearse))
+
     args = draw(2 * T)
     # the recurrence on the HOST's CPU: the chip's exp is low by 8e-7 of its
     # value on average (PERF.md section 6, PR 45), which a product of a
     # thousand decays multiplies up to 3e-4 of the state; the chunked form
     # exponentiates sums and does not
-    cpu = jax.devices("cpu")[0]
     with jax.default_device(cpu):
         want, h_end = jax.jit(ssd.ssd_recurrence)(
             *jax.device_put(args, cpu),
@@ -1937,35 +1991,115 @@ def phase_kernel_ssd(spec, seed, rehearse):
                           rehearse),
         ok=bool(good))
 
-    def ms_a_layer(fn, held, pool, reps=20):
-        """Wall time of one layer's call, the pool donated from call to
-        call: on the chip the device's time where a call outlasts its
-        dispatch (0.6 ms on the one-chip machine: PERF.md section 6, PR 46),
-        here the CPU's (rehearsal)."""
-        for _ in range(2):
-            o, pool = fn(*held, pool)
-        jax.block_until_ready(o)
-        t = time.perf_counter()
-        for _ in range(reps):
-            o, pool = fn(*held, pool)
-        jax.block_until_ready(o)
-        return round((time.perf_counter() - t) / reps * 1e3, 4), pool
-
-    # the decode kernel timed over a whole pool of live slots: its bytes are
-    # benchmark/lib/model_bytes_ssd_latent_moe.py::ssd_decode_bytes
-    dec = jax.jit(functools.partial(
-        ssd.ssd_decode, backend="pallas", interpret=rehearse),
-        donate_argnums=(5,))
-    every = jnp.ones((B,), bool)
-    dec_ms, _ = ms_a_layer(
-        lambda *a: dec(*a[:-1], a[-1], 1, every),
-        tuple(a[:B] for a in args), ssd.pack_state(h))
-    say(phase="kernel", op="ssd_decode_tpu", rows=B, live=B,
-        ms_a_layer=dec_ms, state_bytes_read_and_written=2 * B * H * P * N * 4,
-        timed_on=jax.default_backend())
     if not (ok and good):
         fail("the state-space kernel or the chunked form disagrees with the "
              "recurrence, or a bfloat16 pool would pass")
+
+
+def _ssd_decode_times(spec, ssd, B, H, P, G, N, rehearse):
+    """Device time a layer of ``ssd_decode_tpu`` by the form of the call,
+    every row live, each form ONE program that walks a pool of ten layers
+    (the cell's 2.7 GB: no call finds its tiles anywhere but in HBM): the
+    step that stands alone, a pass that reads and writes nothing, the commit
+    of one token and of four in a kernel with room for eight; and
+    ``ssd_window_step`` whole (what the engine's layer calls: the kernel and
+    the window's own tokens in ``jax.numpy``) at a step that is not the
+    window's last and at its last.  Against the bytes each form moves (the
+    state read AND written with its vectors is ``benchmark/lib/
+    model_bytes_ssd_latent_moe.py::ssd_decode_call``'s; a pass that writes
+    nothing moves half).  From a profiler capture (``tools/
+    program_times.py``), not the host's clock."""
+    from benchmark.lib.model_bytes_ssd_latent_moe import ssd_decode_call
+    from benchmark.lib.peaks import chip_peaks
+    from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
+
+    L = 2 if rehearse else 10
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    order = jnp.arange(B, dtype=jnp.int32)
+    dt = jnp.full((B, H), 0.05, jnp.float32)
+    x, la = jax.random.normal(ks[0], (B, H, P)), -0.1 * dt
+    Cm, Bm = (jax.random.normal(k, (B, G, N)) for k in ks[1:3])
+    every = jnp.ones((B,), bool)
+
+    # (forms that differ in DATA alone are one program to the compile cache,
+    # which hands the second the first's executable under the first's name:
+    # each starts from a constant of its own)
+    forms = []
+    mark = lambda i: jnp.full((B, H, P), 1e-30 * i, jnp.float32)
+
+    def kernel(name, room, commit):
+        xw = jax.random.normal(ks[3], (B, room, H * P // 128, 128))
+        Bs = jax.random.normal(ks[4], (B, G, room, N))
+
+        def layer(l, carry):
+            pool, acc = carry
+            # (the last layer's output enters the next call: nothing hoisted)
+            y, pool = ssd_decode_tpu(
+                xw + acc.reshape(B, 1, -1, 128), jnp.exp(la), Bs, Cm, pool,
+                l, order, B, commit, interpret=rehearse)
+            return pool, 1e-3 * y
+
+        own = len(forms)
+
+        def call(pool, commit):
+            return jax.lax.fori_loop(0, L, layer, (pool, mark(own)))
+
+        call.__name__ = name
+        forms.append((jax.jit(call, donate_argnums=(0,)), jnp.int32(commit)))
+
+    def window(name, step, last):
+        own = len(forms)
+
+        def call(pool, step, last):
+            def layer(l, carry):
+                pool, pending, acc = carry
+                y, pool, pending = ssd.ssd_window_step(
+                    x + acc, dt, la, Bm, Cm, pool, pending, l, every, step,
+                    last, backend="pallas", interpret=rehearse)
+                return pool, pending, 1e-3 * y
+
+            return jax.lax.fori_loop(0, L, layer, (
+                pool, ssd.window_zeros(L, B, H, P, G, N, 8), mark(own)))[::2]
+
+        call.__name__ = name
+        forms.append((jax.jit(call, donate_argnums=(0,)), jnp.int32(step),
+                      jnp.asarray(last)))
+
+    kernel("stands_alone", 1, 1)
+    kernel("reads_and_writes_nothing", 8, 0)
+    kernel("commits_one_of_eight", 8, 1)
+    kernel("commits_four_of_eight", 8, 4)
+    window("window_step_not_last", 2, False)
+    window("window_step_last_of_four", 3, True)
+
+    def run(reps, pool):
+        for _ in range(reps):
+            for fn, *data in forms:
+                pool, y = fn(pool, *data)
+        return y, pool
+
+    # compiled outside the capture
+    _, pool = run(1, jnp.zeros((L, B, H * P // 128, N, 128), jnp.float32))
+    programs = _device_programs(
+        lambda: run(2 if rehearse else 5, pool)[0], "^ssd_decode_tpu",
+        rehearse)
+    if programs is None:
+        return {"timed_on": jax.default_backend()}
+    name = next(k for k, v in CONFIGS.items() if v is spec)
+    with open(os.path.join(HERE, "benchmark", "configs",
+                           name + ".json")) as f:
+        both = ssd_decode_call(json.load(f), B)[1]
+    peak = chip_peaks(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    ms = {fn.__name__: programs["jit_" + fn.__name__]["mean_ms"] / L
+          for fn, *_ in forms}
+    moved = {"stands_alone": both, "reads_and_writes_nothing": both / 2,
+             "commits_one_of_eight": both, "commits_four_of_eight": both}
+    return {"device_ms_a_layer": {k: round(v, 4) for k, v in ms.items()},
+            "share_of_hbm_roofline": {
+                k: round(moved[k] / (ms[k] * 1e-3) / peak, 3)
+                for k in moved},
+            "state_bytes_read_and_written": both,
+            "timed_on": jax.default_backend()}
 
 
 def _ssd_form_times(spec, ssd, held, pool, rehearse):

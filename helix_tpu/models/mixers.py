@@ -367,19 +367,22 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
     """A Mamba-2 layer's look-back (``models/llama.py::_mamba2_mixer``) over
     TWO states a slot: the convolution's tail (``_conv_rows`` at 4 taps over
     the x | B | C channels) and the float32 array ``h`` a head, the pair
-    ``(conv pool, h pool)``.  ``decode``: the recurrence applied once (on a
-    TPU one pass of the decode kernel over the live slots).  Else the chunked
-    form at the published block, a row's state read at its first block and
-    written at its last: on a TPU what does not read the state for the rows'
-    blocks at once, then the blocks in order in the chunk kernel
-    (``ops/ssd.py::ssd_rows``); on a CPU a loop over the blocks.  Called ``(x
-    W_xBC, dt, dt * A, taps, bias, D, carry)``."""
-    from helix_tpu.ops.ssd import ssd_decode, ssd_rows
+    ``(conv pool, h pool)``.  ``decode``: the recurrence applied once; on a
+    TPU one pass of the decode kernel over the live slots, which READS ``h``
+    at every step of a fused window and writes it at the window's last
+    (``ops/ssd.py::ssd_window_step``: the window's tokens ride the carry
+    behind the pools, ``_mamba2_window``; the conv tail steps every time).
+    Else the chunked form at the published block, a row's state read at its
+    first block and written at its last: on a TPU what does not read the
+    state for the rows' blocks at once, then the blocks in order in the
+    chunk kernel (``ops/ssd.py::ssd_rows``); on a CPU a loop over the blocks.
+    Called ``(x W_xBC, dt, dt * A, taps, bias, D, carry)``."""
+    from helix_tpu.ops.ssd import ssd_rows, ssd_window_step
 
     t0, qlen, hist, slots = rows
 
     def mamba2_fn(xbc, dt, la, taps, bias, D, carry_cache):
-        (caches, kacc, vacc, (c_pool, h_pool)), lc = carry_cache
+        (caches, kacc, vacc, (c_pool, h_pool, *pending)), lc = carry_cache
         Bx, Sx, _ = xbc.shape
         with jax.named_scope("ssd.conv"):
             y, c_pool, _ = _conv_rows(
@@ -387,9 +390,13 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
             x, Bm, Cm = mamba2_heads(y, bias, cfg)
         with jax.named_scope("ssd.kernel"):
             if decode:
-                o, h_pool = ssd_decode(
+                # without a window's tokens every step is a window of one
+                step, n_extra = window if pending else (0, 0)
+                o, h_pool, mine = ssd_window_step(
                     x[:, 0], dt[:, 0], la[:, 0], Bm[:, 0], Cm[:, 0], h_pool,
-                    lc, qlen > 0, backend=backend)
+                    pending[0] if pending else None, lc, qlen > 0, step,
+                    step == n_extra, backend=backend)
+                pending = [mine] if pending else []
             else:
                 flat = lambda a: a.reshape((Bx * Sx,) + a.shape[2:])
                 o, h_pool = ssd_rows(
@@ -397,7 +404,7 @@ def _mamba2_rows_fn(rows, backend, *, cfg, decode, snap=None, packed=None,
                     qlen, hist, slots, h_pool, lc, chunk=cfg.mamba_chunk,
                     backend=backend)
             o = o.reshape(x.shape) + D.astype(jnp.float32)[:, None] * x
-        return o, (caches, kacc, vacc, (c_pool, h_pool))
+        return o, (caches, kacc, vacc, (c_pool, h_pool, *pending))
 
     return mamba2_fn
 
@@ -507,6 +514,16 @@ def _mamba2_arrays(cfg) -> tuple:
               k * cfg.mamba_head_dim), "float32"))
 
 
+def _mamba2_window(cfg, slots: int, steps: int) -> tuple:
+    """A fused window's tokens (``ops/ssd.py::window_zeros``) of the Mamba-2
+    layers: 37 KB a layer, slot and step, float32."""
+    from helix_tpu.ops.ssd import window_zeros
+
+    return window_zeros(
+        cfg.num_state_layers, slots, cfg.mamba_heads, cfg.mamba_head_dim,
+        cfg.mamba_groups, cfg.mamba_state_size, steps)
+
+
 def _window_arrays(cfg) -> tuple:
     """The K ring and the V ring ``[sliding_window, kv heads, head_dim]``
     (in the pool's dtype: ``pool_dtype``)."""
@@ -582,20 +599,28 @@ def _rows_from_zeros(cfg, cache_cfg, rows, pos, n_extra) -> dict:
     }
 
 
-def _retention_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
-    """... and of the decode row-steps, those that WROTE the state: a fused
-    window of ``1 + n_extra`` steps reads a live row's ``S`` at every step
-    and writes it at its last (``Z``, 1.5% of the bytes, at every step), so
-    writes over ``decode_rows`` is the share of steps that paid for a write
-    and the bytes touched follow what moved.  As the kernel's path runs it:
-    the CPU's recurrence, its oracle, writes at every step."""
-    out = _rows_from_zeros(cfg, cache_cfg, rows, pos, n_extra)
-    (s, sdt), _ = cfg.state_arrays()
-    unwritten = out["decode_rows"] - len(pos)
-    out["state_writes"] = len(pos)
-    out["state_bytes_touched"] -= unwritten * cfg.num_state_layers * int(
-        np.prod(s)) * jnp.dtype(sdt).itemsize
-    return out
+def _written_once_a_window(which: int, rows_of) -> Callable:
+    """... (``rows_of``) and of the decode row-steps, those that WROTE the
+    state: a fused window of ``1 + n_extra`` steps reads a live row's matrix
+    (array ``which`` of the kind's) at every step and writes it at its last
+    (what the kind keeps beside it, a few percent of the bytes, at every
+    step), so writes over ``decode_rows`` is the share of steps that paid
+    for a write and the bytes touched follow what moved.  As the kernel's
+    path runs it: the CPU's recurrence, its oracle, writes at every step."""
+
+    def account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
+        out = rows_of(cfg, cache_cfg, rows, pos, n_extra)
+        s, sdt = cfg.state_arrays()[which]
+        unwritten = out["decode_rows"] - len(pos)
+        out["state_writes"] = len(pos)
+        out["state_bytes_touched"] -= unwritten * cfg.num_state_layers * int(
+            np.prod(s)) * jnp.dtype(sdt).itemsize
+        return out
+
+    return account
+
+
+_retention_account = _written_once_a_window(0, _rows_from_zeros)
 
 
 def _chunked_rows(chunk_of, rows_of=_matrix_rows) -> Callable:
@@ -625,8 +650,8 @@ _deltanet_account = _chunked_rows(_delta_chunk)
 # the published block of the state space's chunked form
 # ... with the rows that start their sequence: the chunk kernel skips the
 # state's read and its product for their first block
-_mamba2_account = _chunked_rows(
-    lambda cfg: cfg.mamba_chunk, _rows_from_zeros)
+_mamba2_account = _written_once_a_window(1, _chunked_rows(
+    lambda cfg: cfg.mamba_chunk, _rows_from_zeros))
 
 
 def _window_account(cfg, cache_cfg, rows, pos, n_extra) -> dict:
@@ -874,12 +899,16 @@ STATE_MIXERS = {
         oracle=lambda cfg, positions: functools.partial(
             whole_sequence_mamba2_fn, cfg=cfg),
         account=_mamba2_account,
+        window=_mamba2_window,
         series=(
             # device time under ssd.kernel in the programs that carry a
             # chunk, over this, is a block's cost
             Series("helix_ssd_chunks_total", "counter", "chunks"),
             _POOL_BYTES,
             *_rows_series("helix_ssd_rows_total"),
+            # over kind="decode" above, the share of decode steps that wrote
+            # the state (1 where no window is fused)
+            Series("helix_ssd_state_writes_total", "counter", "state_writes"),
             # over kind="chunk" above, the share of rows whose first block
             # skipped the state's read and its product
             Series("helix_ssd_chunk_rows_from_zeros_total", "counter",
@@ -888,7 +917,8 @@ STATE_MIXERS = {
         ),
         launch=(("ssd_layers", "layers"), ("ssd_chunks", "chunks"),
                 ("ssd_chunk_rows", "chunk_rows"),
-                ("ssd_chunk_rows_from_zeros", "chunk_rows_from_zeros")),
+                ("ssd_chunk_rows_from_zeros", "chunk_rows_from_zeros"),
+                ("ssd_state_writes", "state_writes")),
     ),
 }
 

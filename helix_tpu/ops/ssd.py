@@ -20,9 +20,11 @@ B_s^T``, ``G`` the running sum of ``la`` from the block's start), which the
 rows of fresh tokens of a step run from their slots' states (``ssd_rows``),
 reading a state once and writing it once a row.  On a CPU that is a loop of
 ``ssd_chunk`` over the rows' blocks.  On a TPU both forms are kernels of
-``ops/ssd_kernel.py``: the decode step in one pass over the live slots, and
-the chunked form in TWO HALVES, as the delta rule's and power retention's
-are: what does not read the state for a pass's blocks at once in
+``ops/ssd_kernel.py``: the decode step in one pass over the live slots (a
+fused window of them reads the state at every step and writes it at its
+last: ``ssd_window_step``), and the chunked form in TWO HALVES, as the delta
+rule's and power retention's are: what does not read the state for a pass's
+blocks at once in
 ``jax.numpy`` (``state_free``), then the blocks in order in
 ``ssd_chunk_tpu``, which builds each head's decay in VMEM and keeps ``h``
 there from a row's first block to its last.
@@ -73,8 +75,16 @@ def rows_of(v, I: int):
 
 def lanes(v, P: int, I: int):
     """``v [..., H]`` (a scalar a head) across its head's ``P`` lanes of the
-    packed rows: ``[..., I, pack * P]``."""
-    return jnp.repeat(v, P, axis=-1).reshape(v.shape[:-1] + (I, -1))
+    packed rows: ``[..., I, pack * P]``.  By selects over the lanes, which
+    XLA fuses into what consumes them (a repeat and a reshape that merges
+    two axes into the lanes it writes out: 16.8 MB twice a decode step over
+    a window's tokens, PERF.md section 6, PR 56)."""
+    v = v.reshape(v.shape[:-1] + (I, -1))
+    head_of = jnp.arange(v.shape[-1] * P) // P
+    out = v[..., :1]
+    for k in range(1, v.shape[-1]):
+        out = jnp.where(head_of == k, v[..., k:k + 1], out)
+    return jnp.broadcast_to(out, v.shape[:-1] + head_of.shape)
 
 
 def ssd_step(x, dt, la, Bm, Cm, h):
@@ -313,32 +323,97 @@ def ssd_rows(x, dt, la, Bm, Cm, t0, qlen, hist, slots, h_pool, layer, *,
     return y, pool.reshape(h_pool.shape)
 
 
+def window_zeros(layers: int, slots: int, heads: int, head_dim: int,
+                 groups: int, state: int, steps: int) -> tuple:
+    """What the steps of one fused window of ``steps`` decode steps hand one
+    another beside the pools: the window's tokens a layer and slot, ``(dt * x
+    [layers, slots, steps, H / pack, pack * P]`` in the pool's packed rows,
+    ``B [layers, slots, G, steps, N], dt * A [layers, slots, steps, H], which
+    slots were live at some step [layers, slots])``, float32.  A step reads
+    the tokens up to its own only, so what an earlier window left behind
+    them is no term."""
+    k = head_pack(head_dim)
+    f32 = lambda *shape: jnp.zeros((layers, slots) + shape, jnp.float32)
+    return (f32(steps, heads // k, k * head_dim), f32(groups, steps, state),
+            f32(steps, heads), jnp.zeros((layers, slots), bool))
+
+
 def ssd_decode(x, dt, la, Bm, Cm, h_pool, layer, live, *, backend=None,
                interpret: bool = False):
-    """One decode step of every slot: row ``b`` is slot ``b``'s one fresh
-    token (``live [B]`` bool: idle slots and rows that sit the step out write
-    nothing and read zeros).  ``x [B, H, P]``, ``dt, la [B, H]``, ``Bm, Cm
-    [B, G, N]``; the pool ``h [L, slots, I, N, W]`` with ``slots >= B``,
-    updated IN PLACE at ``layer``.  Returns ``(y [B, H, P] float32,
-    h_pool)``.
+    """One decode step of every slot that stands alone (a window of one):
+    ``ssd_window_step`` without its pending tokens.  Returns ``(y [B, H, P]
+    float32, h_pool)``."""
+    return ssd_window_step(
+        x, dt, la, Bm, Cm, h_pool, None, layer, live, 0, True,
+        backend=backend, interpret=interpret)[:2]
 
-    On a TPU it is one pass of ``ssd_decode_tpu`` over the live slots
-    (``interpret``: the same kernel in interpret mode, for tests on a CPU
-    with ``backend="pallas"``); on a CPU, or for ``backend="reference"``, the
-    plain recurrence."""
+
+def ssd_window_step(x, dt, la, Bm, Cm, h_pool, pending, layer, live, step,
+                    last, *, backend=None, interpret: bool = False):
+    """Step ``step`` (from 0) of a fused window of decode steps, for every
+    slot: row ``b`` is slot ``b``'s one fresh token (``live [B]`` bool: idle
+    slots and rows that sit the step out write nothing and read zeros).  ``x
+    [B, H, P]``, ``dt, la [B, H]``, ``Bm, Cm [B, G, N]``; the pool ``h [L,
+    slots, I, N, W]`` with ``slots >= B``, updated IN PLACE at ``layer``.
+    ``pending``: ``window_zeros`` (its tokens are written in place at
+    ``[layer, :, step]``; None, or room for one step: a window of one).
+    Returns ``(y [B, H, P] float32, h_pool, pending)``.
+
+    On a TPU the recurrence is linear with a scalar gate a head, so a step
+    that is not the window's ``last`` only READS ``h`` (``ssd_decode_tpu``
+    with ``commit`` 0: ``h_0 C``, seen through the decay since the window
+    began) and adds the window's own tokens by their scores, ``sum_i
+    exp(sum_{i<l<=j} la_l) (C_j . B_i) dt_i x_i``, from ``pending``, where
+    its own ``dt x, B, la`` join them; the last step writes ``h`` once with
+    all the window's outer products and reads out what it wrote, for every
+    slot that was live at some step.  Every decay is ONE ``exp`` of summed
+    ``la``, never a product of ``exp``s.  ``step`` and ``last`` are data: one
+    program whatever the window's length (``interpret``: the same kernel in
+    interpret mode, for tests on a CPU with ``backend="pallas"``).  On a CPU,
+    or for ``backend="reference"``, the plain recurrence at every step, and
+    ``pending`` as it came."""
     from helix_tpu.ops.attention import resolve_backend
 
-    B = x.shape[0]
-    N = h_pool.shape[1]
-    if resolve_backend(backend) == "pallas":
-        from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
-
-        order = jnp.argsort(~live, stable=True).astype(jnp.int32)
-        y, h_pool = ssd_decode_tpu(
-            dt[..., None] * x, jnp.exp(la), Bm, Cm, h_pool, layer, order,
-            jnp.sum(live).astype(jnp.int32), interpret=interpret)
-    else:
+    B, H, P = x.shape
+    _, N, I, _, W = h_pool.shape
+    if resolve_backend(backend) != "pallas":
         y, hp = ssd_step_packed(x, dt, la, Bm, Cm, h_pool[layer, :B])
         dest = jnp.where(live, jnp.arange(B, dtype=jnp.int32), N)
         h_pool = h_pool.at[layer, dest].set(hp, mode="drop")
-    return jnp.where(live[:, None, None], y, 0.0), h_pool
+        return jnp.where(live[:, None, None], y, 0.0), h_pool, pending
+    from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
+
+    run = lambda visit, commit, *a: ssd_decode_tpu(
+        *a, h_pool, layer, jnp.argsort(~visit, stable=True).astype(jnp.int32),
+        jnp.sum(visit).astype(jnp.int32), commit, interpret=interpret)
+    xdt = (dt[..., None] * x).reshape(B, I, W)
+    if pending is None or pending[0].shape[2] == 1:
+        y, h_pool = run(
+            live, 1, xdt[:, None], jnp.exp(la), Bm[:, :, None], Cm)
+        return jnp.where(live[:, None, None], y, 0.0), h_pool, pending
+    # a row that sits the step out is a term of nothing
+    own = lambda a: jnp.where(live.reshape((B,) + (1,) * (a.ndim - 1)), a, 0.0)
+    xs, Bs, las, seen = pending
+    xs = xs.at[layer, :, step].set(own(xdt))
+    Bs = Bs.at[layer, :, :, step].set(own(Bm))
+    las = las.at[layer, :, step].set(own(la))
+    seen = seen.at[layer].set(jnp.where(step == 0, live, seen[layer] | live))
+    pending = xs, Bs, las, seen
+    xs, Bs = xs[layer], Bs[layer]
+    reached = jnp.arange(xs.shape[1]) <= step                # [M]
+    lg = jnp.where(reached[:, None], las[layer], 0.0)        # [B, M, H]
+    # the decay after each token up to this step, and since the window began
+    after = jnp.exp(jnp.cumsum(lg[:, ::-1], axis=1)[:, ::-1] - lg)
+    whole = jnp.exp(jnp.sum(lg, axis=1))                     # [B, H]
+    # the last step visits every slot the window touched
+    y, h_pool = run(
+        jnp.where(last, seen[layer], live), jnp.where(last, step + 1, 0),
+        xs * lanes(after, P, I), whole, Bs, Cm)
+    sc = jnp.where(reached, jnp.einsum(
+        "bgn,bgmn->bgm", Cm, Bs, precision=_HI), 0.0)
+    # a token's score under its decay, a head: across the head's lanes
+    fresh = jnp.sum(xs * lanes(
+        jnp.repeat(sc, H // sc.shape[1], axis=1).transpose(0, 2, 1) * after,
+        P, I), axis=1).reshape(B, H, P)
+    y = jnp.where(last, y, whole[..., None] * y + fresh)
+    return jnp.where(live[:, None, None], y, 0.0), h_pool, pending

@@ -1,7 +1,7 @@
 """Pallas TPU kernels of a Mamba-2 layer: one decode step (the state's decay,
-its write and its read-out in ONE pass: ``ssd_decode_tpu``), and the half of
-the chunked form that reads the state (``ssd_chunk_tpu``, below its own
-heading at the end).
+its write and its read-out in ONE pass, or its read-out alone:
+``ssd_decode_tpu``), and the half of the chunked form that reads the state
+(``ssd_chunk_tpu``, below its own heading at the end).
 
 XLA's form of ``ops/ssd.py::ssd_step_packed`` walks the state twice (the
 update, then the product with ``C``), and the state is over a third of a
@@ -12,12 +12,22 @@ a row) is read once, decayed by its heads' scalars across the lanes, written
 back through ``input_output_aliases``: one read and one write of the state a
 row, a layer and a step, over the live slots only.
 
+**A fused window writes once.**  The recurrence is linear with a scalar gate
+a head, so the steps of a window of decode steps that are not its last need
+``h_0 C`` alone (``ops/ssd.py::ssd_window_step`` adds the window's own tokens
+by their scores): with ``commit`` 0 (data, a prefetched scalar: one program)
+the kernel streams the same tiles, reduces them under ``C``, and writes
+NOTHING: every visit names one output block, which goes back as it came.  The
+window's last step commits its ``commit`` tokens at once, ``h = decay h +
+sum_m B_m (dt x)_m^T``, and reads out what it wrote; one token is the step
+that stands alone.
+
 ``B`` and ``C`` are needed down the sublanes: one ``[128, 128]`` transpose a
 vector and GROUP (the heads of a group share them), as in
-``ops/deltanet_kernel.py``, whose frame this kernel runs in
-(``state_decode_call``: the grid over rows and head blocks, the live rows
-first by scalar prefetch, the pool aliased in and out).  The read-out is a
-sublane reduction; everything else is elementwise on ``[128, 128]`` tiles.
+``ops/deltanet_kernel.py``, whose frame this kernel's is (the grid over rows
+and head blocks, the live rows first by scalar prefetch, the pool aliased in
+and out).  The read-out is a sublane reduction; everything else is
+elementwise on ``[128, 128]`` tiles.
 """
 
 from __future__ import annotations
@@ -30,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from helix_tpu.ops.deltanet_kernel import (
-    FIRST, FROM_STATE, OPEN, WRITE, head_block, state_decode_call,
+    FIRST, FROM_STATE, OPEN, WRITE, head_block,
 )
 from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
 from helix_tpu.ops.ssd import head_pack, lanes, rows_of
@@ -67,55 +77,166 @@ def check_ssd_geometry(heads: int, head_dim: int, groups: int, state: int,
             "attn_backend='reference' explicitly, or extend the kernel.")
 
 
-def _live(x_ref, a_ref, b_ref, c_ref, s_ref, o_ref, so_ref, *, hb: int,
-          per_group: int, d: int):
+def _decode_kernel(layer_ref, order_ref, count_ref, commit_ref, c_ref, g_ref,
+                   x_ref, b_ref, s_ref, o_ref, so_ref, bc_ref, *, hb: int,
+                   per_group: int, d: int, terms: int):
+    del layer_ref, order_ref                 # read by the index maps
+    first = jnp.logical_and(pl.program_id(0) == 0, pl.program_id(1) == 0)
+    count, commit = count_ref[0], commit_ref[0]
+    live = pl.program_id(0) < count
+
     def column(row):
         # [j, c] = u[j]: a vector down the sublanes, across every lane
         return jnp.broadcast_to(row, (d, d)).T
 
-    for i in range(hb):                                      # static unroll
-        at = pl.ds(i, 1)
-        if i % per_group == 0:
-            # the rows of one group share B and C
-            bc, cc = column(b_ref[at, :]), column(c_ref[at, :])
-        s = s_ref[i] * a_ref[at, :] + bc * x_ref[at, :]
-        so_ref[i] = s
-        o_ref[at, :] = jnp.sum(cc * s, axis=0, keepdims=True)
+    def rows(write: bool):
+        """The block's packed rows under ``C``; ``write``: decayed by the
+        window's whole gate and given its tokens' outer products first, and
+        stored."""
+        for i in range(hb):                                  # static unroll
+            at = pl.ds(i, 1)
+            if i % per_group == 0:
+                # the rows of one group share B and C
+                group = i // per_group
+                cc = column(c_ref[at, :])
+                if write and terms == 1:
+                    bc = column(b_ref[group])
+                elif write:
+                    for m in range(terms):
+                        @pl.when(m < commit)
+                        def _a_token_of_the_window():
+                            bc_ref[m] = column(b_ref[group, pl.ds(m, 1), :])
+            s = s_ref[i]
+            if write:
+                s = s * g_ref[at, :]
+                if terms == 1:
+                    s = s + bc * x_ref[0, at, :]
+                else:
+                    # the tokens the window HAS, not the most it may hold
+                    s = jax.lax.fori_loop(
+                        0, commit,
+                        lambda m, s: s + bc_ref[m] * x_ref[m, at, :], s)
+                so_ref[i] = s
+            o_ref[at, :] = jnp.sum(cc * s, axis=0, keepdims=True)
+
+    @pl.when(jnp.logical_and(live, commit > 0))
+    def _commits():
+        rows(True)
+
+    @pl.when(jnp.logical_and(live, commit == 0))
+    def _reads():
+        rows(False)
+
+    # every visit of a pass that writes nothing names ONE output block of the
+    # state, the first visit's: copied through once, it goes back as it came
+    @pl.when(jnp.logical_and(
+        jnp.logical_or(count == 0, commit == 0), first))
+    def _nothing_written():
+        so_ref[...] = s_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_decode_tpu(
-    xdt,        # [B, H, P] f32: dt * x
-    decay,      # [B, H] f32: exp(dt * A)
-    Bm,         # [B, G, N] f32
-    Cm,         # [B, G, N] f32
+    xw,         # [B, M, H / pack, pack * P] f32: the window's tokens' dt * x
+                # in the pool's packed rows, each times the decay after its
+                # token
+    decay,      # [B, H] f32: the window's whole decay, exp(sum of dt * A)
+    Bm,         # [B, G, M, N] f32: the window's tokens' B
+    Cm,         # [B, G, N] f32: the step's C
     h_pool,     # [L, slots, H / pack, N, pack * P] f32, slots >= B
     layer,      # which of the L layers (a traced index)
     order,      # [B] int32: the live rows first
     count,      # how many of them are live
+    commit=1,   # (traced) how many of the M tokens the state is written
+                # with; 0: the state is read and nothing is written
     *,
     interpret: bool = False,
 ):
-    """Returns ``(y [B, H, P] f32, h_pool)``: ``h_t C_t`` of every live row
-    (rows that are not live hold whatever was there), and the pool with the
-    live slots' states advanced one token, in place."""
-    B, H, P = xdt.shape
-    G, N = Bm.shape[1:]
-    I, W = h_pool.shape[2], h_pool.shape[4]
-    assert h_pool.shape[2:] == (H * P // W, N, W) and h_pool.shape[1] >= B
+    """Returns ``(y [B, H, P] f32, h_pool)`` for every live row (rows that
+    are not live hold whatever was there).  ``commit > 0``: the live slots'
+    states advanced by the window's first ``commit`` tokens in place, ``h =
+    decay * h + sum_m B_m xw_m^T`` (``M = 1``: one decode step), and ``y = h
+    C``.  ``commit == 0``: ``y = h C`` of the state as it stands, the pool
+    bit for bit what it was (one block is written back as it came), and
+    what only a write needs is fetched once.
+
+    Grid ``(rows, blocks of packed rows)``, sequential; ``layer``, ``order``,
+    ``count`` and ``commit`` go in by scalar prefetch (one program whatever
+    the window's length).  Visits past the live rows repeat the last live
+    block (nothing is fetched or written for them) and are skipped; with no
+    live row at all the one block they all name is copied through
+    unchanged.  (The frame is ``ops/deltanet_kernel.py::state_decode_call``'s
+    with a pass that writes nothing; kept apart: folding them into one
+    changes the program two other models' cells run.)"""
+    B, M, I, W = xw.shape
+    H, (G, N) = decay.shape[1], Cm.shape[1:]
+    P = I * W // H
+    assert h_pool.shape[2:] == (I, N, W) and h_pool.shape[1] >= B
+    assert Bm.shape == (B, G, M, N)
     if not interpret:
         check_ssd_geometry(H, P, G, N)
     hb = head_block(I, ROW_BLOCK)
     per_group = I // G
     if hb % per_group and per_group % hb:
         hb = per_group
+    blocks, gb = I // hb, max(hb // per_group, 1)
     f32 = lambda v: v.astype(jnp.float32)
-    y, h_pool = state_decode_call(
-        functools.partial(_live, hb=hb, per_group=min(per_group, hb), d=N),
-        (f32(xdt).reshape(B, I, W), lanes(f32(decay), P, I),
-         rows_of(f32(Bm), I), rows_of(f32(Cm), I)),
-        h_pool, layer, order, count, hb=hb, name="ssd_decode_tpu",
-        interpret=interpret)
+
+    def visit(n, j, layer, order, count, commit):
+        """The (row, block of packed rows) a visit names: its own while the
+        row is live, the last live one after."""
+        dead = n >= count[0]
+        row = order[jnp.clip(jnp.minimum(n, count[0] - 1), 0, B - 1)]
+        return row, jnp.where(dead, blocks - 1, j)
+
+    def written(n, j, *pre):
+        """... for what only a pass that writes needs: the first visit's
+        throughout a pass that does not."""
+        return tuple(
+            jnp.where(pre[3][0] > 0, here, first)
+            for here, first in zip(visit(n, j, *pre), visit(0, 0, *pre)))
+
+    def spec(block, index, at=visit):
+        return pl.BlockSpec(block, lambda n, j, *pre: index(
+            pre[0][0], *at(n, j, *pre)))
+
+    vec = lambda l, n, j: (n, j, 0)
+    state = lambda l, n, j: (l, n, j, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, blocks),
+        in_specs=[
+            spec((None, hb, N), vec),
+            spec((None, hb, W), vec, written),
+            spec((None, M, hb, W), lambda l, n, j: (n, 0, j, 0), written),
+            spec((None, gb, M, N),
+                 lambda l, n, j: (n, j * hb // per_group // gb, 0, 0),
+                 written),
+            spec((None, None, hb, N, W), state)],
+        out_specs=[spec((None, hb, W), vec),
+                   spec((None, None, hb, N, W), state, written)],
+        # the window's B a token, down the sublanes
+        scratch_shapes=[pltpu.VMEM((M, N, W), jnp.float32)],
+    )
+    as_i32 = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
+    y, h_pool = pl.pallas_call(
+        functools.partial(_decode_kernel, hb=hb,
+                          per_group=min(per_group, hb), d=N, terms=M),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, I, W), jnp.float32),
+                   jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype)],
+        # operand 8 (after the four prefetched scalars): the pool
+        input_output_aliases={8: 1},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        name="ssd_decode_tpu",
+    )(
+        as_i32(layer), as_i32(order), as_i32(count), as_i32(commit),
+        rows_of(f32(Cm), I), lanes(f32(decay), P, I),
+        f32(xw), f32(Bm), h_pool,
+    )
     return y.reshape(B, H, P), h_pool
 
 

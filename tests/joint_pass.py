@@ -277,3 +277,46 @@ def assert_wave_is_wave_then_decode(make_engine, make_reqs, tol: float):
                                 sorted(logits_ref[rid]))
         assert max(np.abs(logits[rid][n] - logits_ref[rid][n]).max()
                    for n in both) < tol, rid
+
+
+@contextlib.contextmanager
+def launch_spans():
+    """The attributes of every ``helix.loop.launch`` span opened inside."""
+    from helix_tpu.obs import trace as obs_trace
+
+    seen, orig = [], obs_trace.phase
+
+    def phase(name, *a, **kw):
+        if name == "helix.loop.launch":
+            seen.append(kw)
+        return orig(name, *a, **kw)
+
+    obs_trace.phase = phase
+    try:
+        yield seen
+    finally:
+        obs_trace.phase = orig
+
+
+def windows_a_chunked_prompt_and_a_reused_slot(eng, req, tokens_of):
+    """A kind whose fused window keeps tokens beside its pool, through the
+    engine: two rows decode in fused windows; a 37-token prompt arrives and
+    its three chunks run beside their decode rows (each a window of one, on
+    the state the windows committed); it decodes in windows with them; then
+    a fourth request reuses the slot of the first to finish.  ``req(id,
+    prompt, tokens out)`` and ``tokens_of(n, seed)`` are the family's.
+    Returns every request's tokens."""
+    reqs = [req("a", tokens_of(9, 1), 18), req("b", tokens_of(7, 2), 6)]
+    late = [req("chunked", tokens_of(37, 3), 9),
+            req("reuses", tokens_of(6, 4), 8)]
+    for r in reqs:
+        eng.add_request(r)
+    steps = 0
+    while eng.has_work() or late:
+        eng.step()
+        steps += 1
+        if late and (steps == 3 if len(late) == 2 else reqs[1].finished):
+            reqs.append(late.pop(0))
+            eng.add_request(reqs[-1])
+        assert steps < 200
+    return {r.id: list(r.output_tokens) for r in reqs}

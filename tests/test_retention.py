@@ -5,7 +5,6 @@ oracle is the benchmark's plain reference
 (``benchmark/lib/reference_retention_decoder.py``: the quadratic form
 straight from the definition); the engine is compared by LOGITS."""
 
-import contextlib
 import dataclasses
 import os
 import sys
@@ -90,25 +89,6 @@ def _run(eng, reqs, watch):
                 and eng.slots[watch.slot] is watch):
             logits[n] = np.asarray(eng.next_token_logits()[watch.slot])
     return logits
-
-
-@contextlib.contextmanager
-def _launch_spans():
-    """The attributes of every ``helix.loop.launch`` span opened inside."""
-    from helix_tpu.obs import trace as obs_trace
-
-    seen, orig = [], obs_trace.phase
-
-    def phase(name, *a, **kw):
-        if name == "helix.loop.launch":
-            seen.append(kw)
-        return orig(name, *a, **kw)
-
-    obs_trace.phase = phase
-    try:
-        yield seen
-    finally:
-        obs_trace.phase = orig
 
 
 def _rel(got, want):
@@ -533,25 +513,10 @@ def test_chunked_prefill_then_decode_on_the_kernel_path(model, monkeypatch):
 
 
 def _windows_a_chunked_prompt_and_a_reused_slot(eng):
-    """Two rows decode in fused windows; a 37-token prompt arrives and its
-    three chunks run beside their decode rows (each a window of one, on the
-    state the windows committed); it decodes in windows with them; then a
-    fourth request reuses the slot of the first to finish.  Returns every
-    request's tokens."""
-    reqs = [_req("a", tokens_of(9, 1), 18), _req("b", tokens_of(7, 2), 6)]
-    late = [_req("chunked", tokens_of(37, 3), 9),
-            _req("reuses", tokens_of(6, 4), 8)]
-    for r in reqs:
-        eng.add_request(r)
-    steps = 0
-    while eng.has_work() or late:
-        eng.step()
-        steps += 1
-        if late and (steps == 3 if len(late) == 2 else reqs[1].finished):
-            reqs.append(late.pop(0))
-            eng.add_request(reqs[-1])
-        assert steps < 200
-    return {r.id: list(r.output_tokens) for r in reqs}
+    import joint_pass
+
+    return joint_pass.windows_a_chunked_prompt_and_a_reused_slot(
+        eng, _req, tokens_of)
 
 
 def test_fused_windows_on_the_kernel_path_give_the_references_tokens(
@@ -588,6 +553,8 @@ def test_a_window_of_four_over_three_rows_writes_the_state_three_times(model):
     ``retention_state_writes`` on the launch's span), and the bytes that
     moved: ``S`` read at every step and written once a row, ``Z`` read and
     written at every step."""
+    import joint_pass
+
     cfg, params = model
     eng = _engine(cfg, params, decode_steps_per_sync=4)
     for i in range(3):
@@ -595,7 +562,7 @@ def test_a_window_of_four_over_three_rows_writes_the_state_three_times(model):
     while eng.waiting or eng._decode_window() != 4:
         eng.step()
     before = dict(eng.mixer_counts)
-    with _launch_spans() as seen:
+    with joint_pass.launch_spans() as seen:
         eng.step()
     added = {k: n - before[k] for k, n in eng.mixer_counts.items()}
     assert added["decode_rows"] == 12 and added["state_writes"] == 3
@@ -786,9 +753,11 @@ def test_conv_models_keep_their_prefix_cache(model):
 
 
 def test_launch_record_and_metrics_carry_the_retention_layers(model):
+    import joint_pass
+
     cfg, params = model
     eng = _engine(cfg, params)
-    with _launch_spans() as seen:
+    with joint_pass.launch_spans() as seen:
         req = _req("a", tokens_of(9, 9), 3)
         _run(eng, [req], req)
     assert seen and all(

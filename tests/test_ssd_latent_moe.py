@@ -453,6 +453,150 @@ def test_the_decode_step_is_the_recurrence(backend):
     assert bool(jnp.all(new[0] == 0)) and bool(jnp.all(new[1, B:] == 0))
 
 
+@pytest.mark.parametrize("steps", [1, 2, 4, 8])
+def test_a_fused_window_on_the_kernel_is_the_recurrence_a_step_at_a_time(
+        steps):
+    """A window of ``steps`` decode steps on the kernel in interpret mode
+    against the token-by-token recurrence, with a row that goes idle
+    mid-window, a row that joins at step 2 and a row that is never live:
+    every step's outputs; the pool untouched until the last step; then the
+    state the recurrence leaves, every other slot and layer bit for bit."""
+    B, H, P, G, N, L, S = 5, 8, 64, 2, 128, 2, 6
+    pool = ssd.pack_state(jax.random.normal(
+        jax.random.PRNGKey(6), (L, S, H, P, N)))
+    lives = np.random.default_rng(steps).random((steps, B)) < 0.6
+    lives[:, 4] = False
+    lives[-1, 0], lives[:, 1] = False, True     # one leaves early, one stays
+    lives[:2, 2], lives[2:, 2] = False, True    # one joins at step 2
+    want, got = pool, pool
+    # a window's tokens from a window before lie behind this one's
+    pending = jax.tree.map(
+        lambda a: a + (3 if a.dtype == jnp.float32 else True),
+        ssd.window_zeros(L, B, H, P, G, N, 8))
+    for i in range(steps):
+        args = _draw(B, H, P, G, N, seed=100 * steps + i)
+        live = jnp.asarray(lives[i])
+        y0, want, _ = ssd.ssd_window_step(
+            *args, want, None, 1, live, 0, True, backend="reference")
+        y1, got, pending = ssd.ssd_window_step(
+            *args, got, pending, 1, live, jnp.int32(i),
+            jnp.asarray(i == steps - 1), backend="pallas", interpret=True)
+        assert float(jnp.max(jnp.abs(y1 - y0))) < 1e-4 * float(jnp.std(y0))
+        assert not np.any(np.asarray(y1)[~lives[i]])
+        if i < steps - 1:
+            assert np.array_equal(got, pool)
+            assert np.array_equal(pending[3][1], lives[:i + 1].any(axis=0))
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4 * float(jnp.std(want))
+    idle = ~lives.any(axis=0)
+    assert np.array_equal(got[0], pool[0])
+    assert np.array_equal(got[1][:B][idle], pool[1][:B][idle])
+    assert np.array_equal(got[1, B:], pool[1, B:])
+
+
+def test_a_window_of_one_is_the_step_that_stands_alone_bit_for_bit():
+    """On the kernel (interpret mode): a window with room for one token, and
+    the last step of a longer window's room that is also its first, against
+    ``ssd_decode``: the first the same call, the second the same sums."""
+    B, H, P, G, N, L = 4, 8, 64, 2, 128, 2
+    args = _draw(B, H, P, G, N, seed=5)
+    pool = ssd.pack_state(jax.random.normal(
+        jax.random.PRNGKey(6), (L, B + 2, H, P, N)))
+    live = jnp.asarray([True, False, True, True])
+    kw = dict(backend="pallas", interpret=True)
+    y0, new0 = ssd.ssd_decode(*args, pool, 1, live, **kw)
+    for room, exact in ((1, True), (8, False)):
+        y1, new1, _ = ssd.ssd_window_step(
+            *args, pool, ssd.window_zeros(L, B, H, P, G, N, room), 1, live,
+            jnp.int32(0), jnp.asarray(True), **kw)
+        if exact:
+            assert np.array_equal(y1, y0) and np.array_equal(new1, new0)
+        else:
+            np.testing.assert_allclose(y1, y0, rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(new1, new0, rtol=1e-6, atol=1e-6)
+
+
+# a model the kernels take in interpret mode: packed rows of 128 lanes over a
+# state of 128, the chunked form at its block of 128, and no layer whose
+# kernel has no interpret mode here (attention, the grouped expert product)
+KERNEL_HF = dict(
+    HF, num_hidden_layers=6, hybrid_override_pattern="M-M-M-",
+    ssm_state_size=128, chunk_size=128)
+
+
+@pytest.fixture(scope="module")
+def kernel_model():
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(
+        KERNEL_HF, name="tiny-nemotron-kernels"), dtype="float32")
+    return cfg, init_params(cfg, jax.random.PRNGKey(3))
+
+
+def test_fused_windows_on_the_kernel_path_give_the_references_tokens(
+        kernel_model, monkeypatch):
+    """Through the engine with windows of up to 4 fused decode steps on
+    ``backend="pallas"`` (the kernels in interpret mode): steps that read the
+    state and write nothing, commits, chunk rows and decode rows that
+    continue from what a window committed, and a reused slot, against the
+    plain recurrence a step at a time (``backend="reference"``, no window)."""
+    import functools
+
+    import joint_pass
+    from helix_tpu.ops import ssd_kernel
+
+    cfg, params = kernel_model
+    scenario = lambda eng: (
+        joint_pass.windows_a_chunked_prompt_and_a_reused_slot(
+            eng, _req, tokens_of))
+    want = scenario(_engine(cfg, params))
+    monkeypatch.setattr(ssd_kernel, "check_ssd_geometry", lambda *a: None)
+    for name in ("ssd_rows", "ssd_window_step"):
+        monkeypatch.setattr(
+            ssd, name, functools.partial(getattr(ssd, name), interpret=True))
+    eng = _engine(cfg, params, attn_backend="pallas", decode_steps_per_sync=4,
+                  adaptive_sync_max_streams=0)
+    got = scenario(eng)
+    assert got == want and all(got.values())
+    counts = eng.mixer_counts
+    # windows were fused: a good part of the decode row-steps wrote nothing
+    assert counts["state_writes"] < 0.75 * counts["decode_rows"]
+    assert counts["chunk_rows"] >= 5 and eng.num_mixed_steps >= 1
+
+
+def test_a_window_of_four_over_three_rows_writes_the_state_three_times(model):
+    """The host's account of one fused launch: 12 decode row-steps, 3 writes
+    of the state (``helix_ssd_state_writes_total``, and ``ssd_state_writes``
+    on the launch's span and the flight record), and the bytes that moved:
+    ``h`` read at every step and written once a row, the conv tail read and
+    written at every step."""
+    import joint_pass
+    from helix_tpu.models.mixers import flight_fields
+
+    cfg, params = model
+    eng = _engine(cfg, params, decode_steps_per_sync=4)
+    for i in range(3):
+        eng.add_request(_req(str(i), tokens_of(5 + i, i), 12))
+    while eng.waiting or eng._decode_window() != 4:
+        eng.step()
+    before = dict(eng.mixer_counts)
+    with joint_pass.launch_spans() as seen:
+        eng.step()
+    added = {k: n - before[k] for k, n in eng.mixer_counts.items()}
+    assert added["decode_rows"] == 12 and added["state_writes"] == 3
+    assert [kw["ssd_state_writes"] for kw in seen] == [3]
+    assert flight_fields(cfg.state_kind, {
+        **eng.mixer_values(), **eng.mixer_gauges()}, before)[
+            "ssd_state_writes"] == 3
+    (c, _), (h, _) = cfg.state_arrays()
+    c, h = (cfg.num_state_layers * int(np.prod(a)) * 4 for a in (c, h))
+    assert added["state_bytes_touched"] == 12 * (c + h) + 12 * c + 3 * h
+    # a step that stands alone writes what it reads
+    eng2 = _engine(cfg, params)
+    eng2.add_request(_req("x", tokens_of(5), 4))
+    while eng2.has_work():
+        eng2.step()
+    assert eng2.mixer_counts["state_writes"] == (
+        eng2.mixer_counts["decode_rows"]) > 0
+
+
 def test_the_kernel_refuses_what_it_cannot_tile():
     from helix_tpu.ops.paged_kernel import UnsupportedKernelGeometry
     from helix_tpu.ops.ssd_kernel import check_ssd_geometry
@@ -625,10 +769,12 @@ def test_a_mesh_and_the_calls_that_move_pages_are_refused_by_name(model):
     assert [s.name for s in kind.series] == [
         "helix_ssd_chunks_total", "helix_recurrent_state_bytes",
         "helix_ssd_rows_total", "helix_ssd_rows_total",
+        "helix_ssd_state_writes_total",
         "helix_ssd_chunk_rows_from_zeros_total",
         "helix_state_bytes_touched_total"]
     assert dict(kind.launch) == {
         "ssd_layers": "layers", "ssd_chunks": "chunks",
         "ssd_chunk_rows": "chunk_rows",
-        "ssd_chunk_rows_from_zeros": "chunk_rows_from_zeros"}
+        "ssd_chunk_rows_from_zeros": "chunk_rows_from_zeros",
+        "ssd_state_writes": "state_writes"}
     assert kind.flight == kind.launch

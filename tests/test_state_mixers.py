@@ -292,10 +292,16 @@ def test_only_a_kind_with_a_window_is_told_the_step_of_the_tail():
     from helix_tpu.models.common import BRUMBY_14B
 
     kinds = {name for name, m in STATE_MIXERS.items() if m.window is not None}
-    assert kinds == {"retention"}
+    assert kinds == {"retention", "mamba2"}
     k, v, lg, seen = STATE_MIXERS["retention"].window(
         dataclasses.replace(BRUMBY_14B, num_layers=2,
                             layer_types=("retention",) * 2), 3, 8)
     assert k.shape == v.shape == (2, 3, 8, 8, 128) and k.dtype == np.float32
     assert lg.shape == (2, 3, 8, 8) and seen.shape == (2, 3)
+    # Mamba-2's: ``dt x`` in the pool's packed rows, ``B`` a group, ``dt A``
+    cfg, _ = _cfgs("mamba2")
+    x, B, la, seen = STATE_MIXERS["mamba2"].window(cfg, 3, 8)
+    assert x.shape == (1, 3, 8, 1, 128) and B.shape == (1, 3, 1, 8, 8)
+    assert la.shape == (1, 3, 8, 4) and seen.shape == (1, 3)
+    assert {a.dtype for a in (x, B, la)} == {np.dtype("float32")}
     assert not any(np.any(np.asarray(a)) for a in (k, v, lg, seen))

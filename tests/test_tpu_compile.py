@@ -544,10 +544,13 @@ def test_grouped_product_compiles_at_2048_by_512(one_chip, rows):
 # ---- Nemotron-3-Super: Mamba-2 layers, experts in a latent (ISSUE 45) -------
 
 
-def test_ssd_decode_kernel_compiles_at_the_published_geometry(one_chip):
+@pytest.mark.parametrize("terms", [1, 8], ids=["alone", "window_of_8"])
+def test_ssd_decode_kernel_compiles_at_the_published_geometry(one_chip, terms):
     """Nemotron-3-Super's decode kernel: 64 rows, 128 heads of 64 over a state
     of 128 in groups of 16 heads, float32, two heads to a lane tile, in a pool
-    of ten layers (2.68 GB), donated: aliased to its output, no copy."""
+    of ten layers (2.68 GB), donated: aliased to its output, no copy.  With
+    room for one token (the step that stands alone) and for a fused window's
+    eight, how many it commits a traced scalar."""
     from helix_tpu.ops.ssd_kernel import ssd_decode_tpu
 
     B, H, P, G, N, L = 64, 128, 64, 8, 128, 10
@@ -556,9 +559,10 @@ def test_ssd_decode_kernel_compiles_at_the_published_geometry(one_chip):
         return jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
 
     compiled = jax.jit(ssd_decode_tpu, donate_argnums=(4,)).lower(
-        S((B, H, P)), S((B, H)), S((B, G, N)), S((B, G, N)),
+        S((B, terms, H // 2, 128)), S((B, H)), S((B, G, terms, N)),
+        S((B, G, N)),
         S((L, B, H // 2, N, 128)), S((), jnp.int32), S((B,), jnp.int32),
-        S((), jnp.int32)).compile()
+        S((), jnp.int32), S((), jnp.int32)).compile()
     assert "ssd_decode_tpu" in compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = L * B * H * P * N * 4

@@ -311,6 +311,13 @@ def test_deltanet_step_compiles_at_published_widths(one_chip, program):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cc.state_bytes(cfg)
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
+    # ... with no copy of the matrix states in any layout around the kernels
+    # or the fused tail's loop (the conv tail, 12.6 MB, is copied once or
+    # twice a program), and nothing computed again
+    import re
+
+    assert not re.search(r"= f32\[2,64,64,128,128\]\S* copy\(", text)
+    assert ".remat" not in text
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk_with_history",
@@ -403,7 +410,7 @@ def test_window_step_compiles_at_published_widths(one_chip, program):
     assert ".remat" not in text
 
 
-@pytest.mark.parametrize("bucket", [128, 512])
+@pytest.mark.parametrize("bucket", [0, 128, 512])
 def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
     """A whole engine step of Nemotron-3-Super cut to three published layers
     in two blocks (attention + held latent experts, a Mamba-2 layer alone;
@@ -413,8 +420,11 @@ def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
     halves (``ssd_chunk_tpu`` behind ``ops/ssd.py::state_free``; no loop of
     ``ssd_chunk`` is left), the paged kernel at 32 query over 2 kv heads, the
     one-operand grouped product over 128 of 512 experts in the latent, and
-    both state arrays updated in place.  The decode-only program holds
-    nothing these do not.  A chunk of 128 tokens is ONE block of the chunked
+    both state arrays updated in place.  Bucket 0 is the decode-only program
+    with its fused tail of seven steps, where a step that is not the
+    window's last reads the state and writes nothing and which step that is
+    is data: the window's tokens ride the tail's loop beside the pools.  A
+    chunk of 128 tokens is ONE block of the chunked
     form: the program that PR 45's first chip run found transposing the
     whole state pool twice a layer; 512 tokens are four blocks in two passes
     of two (``ops/ssd.py::SLAB``), the program the cell's prompts run.  (What
@@ -466,19 +476,21 @@ def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
         mrope_delta=S((B,)), keys=S((B, 2), jnp.uint32),
         token_counts=S((B, cfg.vocab_size)), adapter_slots=S((B,)),
         sampling=sampling(B))
-    rows = 1
-    pargs = (
+    rows = 1 if bucket else 0
+    pargs = () if not bucket else (
         *(S((1, bucket)) for _ in range(5)), S((rows,)), S((rows,)),
         S((rows,)), S((rows, max_pages)), S((rows,)), sampling(rows),
         S((rows, 2), jnp.uint32), S((rows,)), S((rows,)))
     fn = E._build_ragged_step_fn(
-        cfg, PAGE, "pallas", None, bucket, True, rows, 1, 0)
+        cfg, PAGE, "pallas", None, bucket, bool(bucket), rows, 1,
+        0 if bucket else 7)
     compiled = fn.lower(
         params, cache, state, pargs, S((B, 0)), S((B,)), S(()), None
     ).compile()
     text = compiled.as_text()
-    for kernel in ("ssd_decode_tpu", "ssd_chunk_tpu", "grouped_matmul_tpu",
-                   "ragged_paged_attention"):
+    for kernel in ("ssd_decode_tpu", "grouped_matmul_tpu",
+                   "ragged_paged_attention") + (
+                       ("ssd_chunk_tpu",) if bucket else ()):
         assert kernel in text, kernel
     assert "ragged-dot" not in text and "ragged_dot" not in text
     # the state pool is updated in place: aliased whole, no temporary of its
@@ -488,7 +500,18 @@ def test_ssd_step_compiles_at_published_widths(one_chip, bucket):
     assert mem.temp_size_in_bytes < cc.state_bytes(cfg)
     import re
 
+    # (of ``h``; the conv tail, 3.9 MB, is copied twice a program, as it was
+    # before a window was fused)
     assert not re.search(r"= f32\[1,64,64,128,128\]\S* copy\(", text)
+    assert ".remat" not in text
+    if not bucket:
+        # the decode kernel once a loop body (the stage's layer, the tail's),
+        # and the window's tokens in the tail's loop: eight steps' ``dt x``
+        # a slot and layer, in the pool's packed rows
+        assert sum("tpu_custom_call" in ln and "ssd_decode_tpu" in ln
+                   for ln in text.splitlines()) == 2
+        assert re.search(
+            r"%while\S* = \([^\n]*f32\[1,64,8,64,128\]", text)
 
 
 # benchmark/configs/mellum2-12b-a2.5b-int8.profile.yaml
