@@ -126,8 +126,8 @@ def phase_kernels(seed, rehearse):
         """(T, t0, q_len, hist, tables, a row's most fresh tokens): decode =
         B one-token rows over ragged histories; prefill = one S-token row
         over a history that ends mid-page.  The last is the static bound
-        the engine passes (``_ragged_attn_call``), which sizes the
-        kernel's query blocks."""
+        the engine passes (the page kind's ``attend``, ``models/mixers.py``),
+        which sizes the kernel's query blocks."""
         pages = rng.permutation(np.arange(1, N))
         if shape == "decode":
             hist = rng.integers(1, maxP * P - 1, size=B)

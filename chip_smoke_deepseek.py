@@ -1735,8 +1735,8 @@ def phase_engine_q3n(spec, name, seed, layers, steps, rehearse):
         engine_s=round(time.monotonic() - t, 1),
         deltanet_rows=_rows_by_form(eng), mixed_steps=eng.num_mixed_steps,
         state_bytes_touched=eng.mixer_counts["state_bytes_touched"],
-        attn_page_bytes_read=eng.attn_page_bytes_read,
-        attn_query_blocks=eng.attn_query_blocks,
+        attn_page_bytes_read=eng.mixer_counts["attn_page_bytes_read"],
+        attn_query_blocks=eng.mixer_counts["attn_query_blocks"],
         moe_held_tokens=eng.moe_routed_tokens,
         moe_away_tokens=eng.moe_away_tokens,
         probe_records={k: len(v) for k, v in probe.seen.items()},
@@ -2912,7 +2912,7 @@ def phase_engine_dsa(spec, name, seed, layers, steps, rehearse):
         held_experts=list(cfg.held_experts), routed_experts=cfg.num_experts,
         weights_s=round(time.monotonic() - t, 1), backend=eng._backend,
         page_bytes=eng.cache_cfg.page_bytes(cfg),
-        index_pool_bytes=eng.index_pool_bytes)
+        index_pool_bytes=eng.mixer_values()["index_keys_pool_bytes"])
     L, n_dense = cfg.num_layers, hf["first_k_dense_replace"]
     faults = {"none": {}, "index_no_rope": {"index_no_rope": True},
               "no_selection": {"no_selection": True},
@@ -2997,7 +2997,7 @@ def phase_engine_dsa(spec, name, seed, layers, steps, rehearse):
 
     t = time.monotonic()
     req, got, first = run(eng)
-    c = eng.dsa_counts
+    c = eng.mixer_counts
     say(phase="engine", request="cell", prompt_tokens=n_prompt,
         chunks=-(-n_prompt // ecfg.max_prefill_len), steps=len(got),
         engine_s=round(time.monotonic() - t, 1), dsa_counts=c,

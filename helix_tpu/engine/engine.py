@@ -68,16 +68,11 @@ from helix_tpu.engine.sampling import (
     split_keys,
 )
 from helix_tpu.models.common import ModelConfig
-from helix_tpu.models.mixers import STATE_MIXERS
+from helix_tpu.models.mixers import PAGE_KINDS, STATE_MIXERS
 from helix_tpu.models.llama import forward, lm_head
 from helix_tpu.obs import trace as obs_trace
 from helix_tpu.obs.slo import ANON_TENANT
 from helix_tpu.ops.attention import attention as full_attention
-from helix_tpu.ops.dsa import dsa_ragged_paged_attention
-from helix_tpu.ops.paged import (
-    mla_ragged_paged_attention,
-    ragged_paged_attention,
-)
 
 
 class FinishReason(str, enum.Enum):
@@ -652,72 +647,11 @@ def _pin_default_layout(cache):
             x, Layout(major_to_minor=tuple(range(x.ndim)))
         )
 
-    return PagedKVCache(
-        k_pages=pin(cache.k_pages),
-        v_pages=None if cache.v_pages is None else pin(cache.v_pages),
-        k_scale=None if cache.k_scale is None else pin(cache.k_scale),
-        v_scale=None if cache.v_scale is None else pin(cache.v_scale),
-        state=cache.state,
-    )
-
-
-def _ragged_attn_call(q, k, v, caches, lyr, t0, q_len, hist, tables,
-                      backend, cold=None, mesh=None, qi=None, dsa=None):
-    """One ragged-op invocation from inside a forward pass: unpack the
-    pool carry (with optional int8 scale pools) and flatten the token
-    grid onto the op's flat row axis.  ``cold`` (tiered KV residency)
-    carries the staged cold-middle chunks plus each row's demoted token
-    span — the op excludes the span from the hot gather and merges the
-    chunks' online-softmax stats instead."""
-    kp, vp = caches[0], caches[1]
-    ks = caches[2] if len(caches) == 4 else None
-    vs = caches[3] if len(caches) == 4 else None
-    Bq, Sq, H, D = q.shape
-    if qi is not None:
-        # latent attention behind an indexer: v is [rope key | index key],
-        # ``vp`` the index-key pool under the latent pool's page ids, and
-        # each query attends the ``dsa = (index heads, topk)`` keys its
-        # index scores choose (``ops/dsa.py``)
-        out = dsa_ragged_paged_attention(
-            q.reshape(Bq * Sq, H, D),
-            k.reshape(Bq * Sq, k.shape[-1]),
-            v.reshape(Bq * Sq, v.shape[-1]),
-            qi.reshape(Bq * Sq, qi.shape[-1]),
-            kp, vp, lyr, t0, q_len, hist, tables,
-            index_heads=dsa[0], topk=dsa[1], backend=backend, max_q_len=Sq,
-        )
-        return out.reshape(Bq, Sq, H, out.shape[-1])
-    if k.ndim == 3:
-        # latent attention: k is the latent, v the rope key, no head axis,
-        # and the pool one array of their joined rows (``vp`` is None);
-        # the layer has scaled q already.  A row holds at most Sq fresh
-        # tokens (a decode slot's width, or the whole prefill bucket).
-        out = mla_ragged_paged_attention(
-            q.reshape(Bq * Sq, H, D),
-            k.reshape(Bq * Sq, k.shape[-1]),
-            v.reshape(Bq * Sq, v.shape[-1]),
-            kp, lyr, t0, q_len, hist, tables,
-            backend=backend, max_q_len=Sq,
-        )
-        return out.reshape(Bq, Sq, H, out.shape[-1])
-    KVH = k.shape[-2]
-    tkw = {}
-    if cold is not None:
-        (c_k, c_v, c_ks, c_vs, c_row, c_len, lo, hi) = cold
-        tkw = dict(
-            span_lo=lo, span_hi=hi, cold_k=c_k, cold_v=c_v,
-            cold_row=c_row, cold_len=c_len,
-            cold_k_scale=c_ks, cold_v_scale=c_vs,
-        )
-    out = ragged_paged_attention(
-        q.reshape(Bq * Sq, H, D),
-        k.reshape(Bq * Sq, KVH, D),
-        v.reshape(Bq * Sq, KVH, D),
-        kp, vp, lyr, t0, q_len, hist, tables,
-        backend=backend, mesh=mesh, max_q_len=Sq, k_scale=ks, v_scale=vs,
-        **tkw,
-    )
-    return out.reshape(Bq, Sq, H, D)
+    # (the state pool is not the pages': it stays as it is)
+    return dataclasses.replace(cache, **{
+        f.name: pin(getattr(cache, f.name))
+        for f in dataclasses.fields(cache)
+        if f.name != "state" and getattr(cache, f.name) is not None})
 
 
 class UnsupportedForModel(ValueError):
@@ -729,8 +663,9 @@ class UnsupportedForModel(ValueError):
 # model meets it, why).  Each row is refused by name when the engine is
 # built, rather than run on a path that was never written for a pool with
 # no head axis, or with a sequence's recurrent state left behind.  A kind of
-# layer with a per-sequence state brings its rows in its record
-# (``models/mixers.py``: ``refused_as``, ``refusals`` by these keys).
+# page and a kind of layer with a per-sequence state bring their rows in
+# their records (``models/mixers.py``: ``refused_as``, ``refusals`` by these
+# keys).
 _SETTINGS = {
     "multi_device": (
         "a mesh of more than one device",
@@ -747,33 +682,32 @@ _SETTINGS = {
     "prefix_cache": ("enable_prefix_cache",
                      lambda cfg, mesh: cfg.enable_prefix_cache),
 }
-_LATENT = ("latent attention (MLA)", lambda m: m.is_mla)
-_INDEX_POOL = ("a sparse-attention indexer (an index-key pool beside the "
-               "latent pool)", lambda m: m.is_dsa)
 _PACKED_HEADS = ("kv heads packed into one lane tile (head width under "
                  "128)", lambda m: m.kv_head_pack > 1)
 _HELD_EXPERTS = ("held experts (one expert-parallel rank of the routed "
                  "experts)", lambda m: m.held_experts is not None)
 
 
-def _state_rows(kind) -> tuple:
-    """A state kind's rows of ``_REFUSALS``, from its record."""
-    prop = (kind.refused_as, lambda m: m.state_kind is kind)
+def _kind_rows(kind, has) -> tuple:
+    """A kind's rows of ``_REFUSALS``, from its record."""
+    prop = (kind.refused_as, functools.partial(has, kind))
     return tuple((setting, prop, why) for setting, why in kind.refusals)
 
 
 # the first row met is the one raised, so the rows stand in the order they
-# were written: the state kinds' in ``STATE_MIXERS``' order, the packed
-# heads' row behind the first kind's, the held experts' behind the third's,
-# the index-key pool's last
-_KIND_ROWS = [_state_rows(kind) for kind in STATE_MIXERS.values()]
+# were written: the page kinds' but the last's, the state kinds' in
+# ``STATE_MIXERS``' order, the packed heads' row behind the first kind's, the
+# held experts' behind the third's, the last page kind's (the index-key
+# pool's) last.  A page kind whose pools stand beside another's (``base``) is
+# refused what that one is.
+_PAGE_ROWS = [
+    _kind_rows(kind, lambda kind, m: kind in (
+        m.page_kind, m.page_kind.base))
+    for kind in PAGE_KINDS.values()]
+_KIND_ROWS = [_kind_rows(kind, lambda kind, m: m.state_kind is kind)
+              for kind in STATE_MIXERS.values()]
 _REFUSALS = (
-    ("multi_device", _LATENT,
-     "the latent pool and its kernel are single-device: mesh {tp: 1}"),
-    ("int8_kv", _LATENT, "the latent pool is bf16 or f32"),
-    ("adapters", _LATENT, "no LoRA targets on MLA projections"),
-    ("spec_decode", _LATENT, "untested on the latent kernel"),
-    ("tiered", _LATENT, "tiered residency streams K/V chunks"),
+    *(row for rows in _PAGE_ROWS[:-1] for row in rows),
     *_KIND_ROWS[0],
     ("int8_kv", _PACKED_HEADS, "an int8 pool's scales are one a kv head"),
     *_KIND_ROWS[1],
@@ -782,9 +716,7 @@ _REFUSALS = (
      "the other ranks' experts and the exchange with them are not run: "
      "one chip computes its own experts' part of the sum"),
     *(row for rows in _KIND_ROWS[3:] for row in rows),
-    ("host_tier", _INDEX_POOL,
-     "the host tier moves pages of the latent pool; an index-key pool "
-     "beside the latent pool is not carried there"),
+    *_PAGE_ROWS[-1],
 )
 
 
@@ -804,31 +736,12 @@ def _refuse_call(model_cfg, what: str) -> None:
     """Paths that move a sequence's pages and are asked for by a call, not
     a setting: refused for a model whose sequences carry a state too, or
     whose pages are pages of two pools."""
-    kind = model_cfg.state_kind
-    if kind is not None:
-        raise UnsupportedForModel(
-            f"{model_cfg.name}: {kind.refused_as} is not served with "
-            f"{what} ({kind.call_refusal})"
-        )
-    if model_cfg.is_dsa:
-        raise UnsupportedForModel(
-            f"{model_cfg.name}: {_INDEX_POOL[0]} is not served with {what} "
-            "(a page's contents leave the device as the latent pool's "
-            "alone; an index-key pool beside the latent pool is not "
-            "carried there)"
-        )
-
-
-# the host's account of a model with a sparse-attention indexer
-# (``Engine._note_dsa``): its ``/metrics`` series and flight fields
-DSA_COUNTS = ("keys_scored", "keys_selected", "rows_decode_sparse",
-              "rows_decode_all", "rows_chunk_sparse", "rows_chunk_all",
-              "index_bytes_read", "latent_rows_fetched", "select_bytes")
-
-
-def _dsa_of(cfg: ModelConfig):
-    """``(index heads, topk)`` of a model with a sparse-attention indexer."""
-    return (cfg.index_heads, cfg.index_topk) if cfg.is_dsa else None
+    for kind in (model_cfg.state_kind, model_cfg.page_kind):
+        if kind is not None and kind.call_refusal:
+            raise UnsupportedForModel(
+                f"{model_cfg.name}: {kind.refused_as} is not served with "
+                f"{what} ({kind.call_refusal})"
+            )
 
 
 def _fresh_kv_zeros(cfg: ModelConfig, B: int, S: int):
@@ -949,12 +862,12 @@ def _decode_forward(params, cache, state: DecodeState, *, cfg, backend,
     hist = state.positions * active
     kacc0, vacc0 = _fresh_kv_zeros(cfg, B, 1)
 
-    def attn_fn(q, k, v, carry_cache, pos, qi=None):
+    paged = cfg.page_kind.attend(
+        (t0, q_len, hist), state.page_tables, backend, cfg=cfg, mesh=mesh)
+
+    def attn_fn(q, k, v, carry_cache, pos, *more):
         (caches, kacc, vacc, *rest), lyr = carry_cache
-        out = _ragged_attn_call(
-            q, k, v, caches, lyr, t0, q_len, hist, state.page_tables,
-            backend, mesh=mesh, qi=qi, dsa=_dsa_of(cfg),
-        )
+        out = paged(q, k, v, *more, caches, lyr)
         return out, (caches, kacc.at[lyr].set(k), vacc.at[lyr].set(v),
                      *rest)
 
@@ -1223,31 +1136,27 @@ def _build_ragged_step_fn(
         # branch, the state rows the ragged call with the one-token query
         # block, both over the same pools; each segment files its fresh K/V
         # for its own scatter (the accumulators are (prefill's, state's))
-        def p_attn(q, k, v, *qi_cache):
-            *qi, carry_cache = qi_cache
+        # (the kind of the model's pages says how a segment attends over
+        # the pool: ``models/mixers.py::PAGE_KINDS``; None: prefill rows
+        # none of which has history read no page)
+        pages = cfg.page_kind
+        p_paged = pages.attend(
+            (p_t0, p_qlen, p_hist), p_tables, backend, cfg=cfg, bucket=Cb,
+            has_hist=has_hist, cold=p_cold, mesh=mesh) if Cb > 0 else None
+        s_paged = pages.attend(
+            (s_t0, s_qlen, s_hist), state.page_tables, backend, cfg=cfg,
+            cold=s_cold, mesh=mesh)
+
+        def p_attn(q, k, v, *more_cache):
+            *more, carry_cache = more_cache
             (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
-            if qi and (has_hist or Cb > cfg.index_topk):
-                # behind an indexer a row with history chooses its keys,
-                # and so does a cold row in a bucket past ``index_topk``; a
-                # cold row of no more tokens than that attends all it has,
-                # on the latent kernel, and only caches its index keys
-                out = _ragged_attn_call(
-                    q, k, v, caches, lyr, p_t0, p_qlen, p_hist, p_tables,
-                    backend, qi=qi[0], dsa=_dsa_of(cfg),
-                )
-            elif use_ring:
+            if use_ring:
                 out = _ring_chunk_attention(
                     q, k, v, caches, lyr, p_pos, p_seg, p_hist,
                     p_tables, mesh, page_size, ring_hist_pages,
                 )
-            elif has_hist or cfg.is_mla:
-                # latent attention has one kernel: a cold row is a row
-                # with no history
-                out = _ragged_attn_call(
-                    q, k, v[..., :cfg.qk_rope_head_dim] if qi else v,
-                    caches, lyr, p_t0, p_qlen, p_hist,
-                    p_tables, backend, cold=p_cold, mesh=mesh,
-                )
+            elif p_paged is not None:
+                out = p_paged(q, k, v, *more, caches, lyr)
             else:
                 # cold rows only: packed self-attention, no pool reads —
                 # bit-compatible with the pre-unification packed-prefill
@@ -1265,22 +1174,18 @@ def _build_ragged_step_fn(
             return out, (caches, (kp.at[lyr].set(k), ks),
                          (vp.at[lyr].set(v), vs), *rest)
 
-        def s_attn(q, k, v, *qi_cache):
-            *qi, carry_cache = qi_cache
+        def s_attn(q, k, v, *more_cache):
+            *more, carry_cache = more_cache
             (caches, (kp, ks), (vp, vs), *rest), lyr = carry_cache
-            out = _ragged_attn_call(
-                q, k, v, caches, lyr, s_t0, s_qlen, s_hist,
-                state.page_tables, backend, cold=s_cold, mesh=mesh,
-                qi=qi[0] if qi else None, dsa=_dsa_of(cfg),
-            )
+            out = s_paged(q, k, v, *more, caches, lyr)
             return out, (caches, (kp, ks.at[lyr].set(k)),
                          (vp, vs.at[lyr].set(v)), *rest)
 
         attend = _segments_fn(
-            p_attn if Cb > 0 else None, s_attn, 3 + cfg.is_dsa, split, join)
+            p_attn if Cb > 0 else None, s_attn, pages.token_args, split, join)
 
-        def attn_fn(q, k, v, carry_cache, pos, *qi):
-            return attend(q, k, v, *qi, carry_cache)
+        def attn_fn(q, k, v, carry_cache, pos, *more):
+            return attend(q, k, v, *more, carry_cache)
 
         # ---- ONE pass over every layer --------------------------------
         with jax.named_scope("pass" if Cb > 0 else "state"):
@@ -1485,8 +1390,14 @@ class Engine:
     ):
         self.model_cfg = model_cfg
         # the record of the model's kind of layer with a per-sequence state
-        # (``models/mixers.py``), None where its memory is pages alone
+        # (``models/mixers.py``), None where its memory is pages alone; and
+        # the records of the kinds it has layers of, the kind of its pages
+        # first: what is checked, counted and shown of a kind comes from here
         self.mixer = model_cfg.state_kind
+        self.kinds = tuple(
+            kind for kind, layers in (
+                (model_cfg.page_kind, model_cfg.num_attn_layers),
+                (self.mixer, model_cfg.num_state_layers)) if layers)
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
@@ -1516,44 +1427,19 @@ class Engine:
         self.cache_cfg = cfg.cache_config(dtype=model_cfg.dtype)
         # bytes of the state pool (0 for a model without one)
         self.recurrent_state_bytes = self.cache_cfg.state_bytes(model_cfg)
-        # bytes of the index-key pool beside the latent pool (0 without a
-        # sparse-attention indexer)
-        self.index_pool_bytes = 0
-        if model_cfg.is_dsa:
-            shp = self.cache_cfg.page_shapes(model_cfg)[1]
-            self.index_pool_bytes = (
-                self.cache_cfg.num_pages * int(np.prod(shp))
-                * jnp.dtype(self.cache_cfg.dtype).itemsize)
+        # ... and of each array of the page pool, by what it holds
+        self._pool_bytes = {
+            f"{holds}_pool_bytes": n
+            for holds, n in self.cache_cfg.pool_bytes(model_cfg).items()}
         dev = (mesh.devices.flat[0] if mesh is not None
                else jax.devices()[0])
         if self._backend == "pallas":
             tp = head_shards(mesh)
             itemsize = jnp.dtype(self.cache_cfg.dtype).itemsize
-            if model_cfg.is_mla:
-                from helix_tpu.ops.mla_kernel import check_mla_geometry
-
-                check_mla_geometry(
-                    model_cfg.num_heads, model_cfg.kv_lora_rank,
-                    model_cfg.qk_rope_head_dim, itemsize,
-                )
-                if model_cfg.is_dsa:
-                    from helix_tpu.ops.dsa_kernel import check_dsa_geometry
-
-                    check_dsa_geometry(
-                        model_cfg.index_heads, model_cfg.index_head_dim,
-                        model_cfg.num_heads,
-                        sum(self.cache_cfg.latent_widths(model_cfg)))
-            elif model_cfg.num_attn_layers:
-                from helix_tpu.ops.paged_kernel import check_geometry
-
-                check_geometry(
-                    model_cfg.heads_of("attn") // tp,
-                    max(model_cfg.num_kv_heads // tp, 1),
-                    model_cfg.head_dim, itemsize,
-                )
-            if self.mixer is not None and self.mixer.check_geometry:
+            for kind in self.kinds:
                 # each kind of layer at its own kernel's geometry
-                self.mixer.check_geometry(model_cfg, tp, itemsize)
+                if kind.check_geometry:
+                    kind.check_geometry(model_cfg, tp, itemsize)
         logging.getLogger(__name__).info(
             "engine %s: attention backend %s on platform %s, device_kind "
             "%s, %d device(s)",
@@ -1594,25 +1480,19 @@ class Engine:
         self._boundary_states: dict[str, dict] = {}
         self.num_state_snapshots = 0
         self.num_state_restores = 0
-        # the host's account of what the steps did to the state pool, by
-        # the keys of the kind's record (``models/mixers.py``: rows by the
-        # form that ran them, bytes moved, chunks); a launch adds its
-        # ``mixer.account`` to it, and an empty launch's is every key at 0
-        account = self.mixer.account if self.mixer else None
-        self.mixer_counts = account(
-            model_cfg, self.cache_cfg, (), np.zeros(0, np.int64), 0,
-        ) if account else {}
-        # history pages the latent kernel walked (``_history_pages``), the
-        # K/V bytes of the pages the dense paged kernel walked, and the live
-        # tokens the last launch's rows attended over
-        self.num_mla_page_fetches = 0
-        # behind a sparse-attention indexer: the host's account of what the
-        # launches' rows scored, chose and fetched (``_note_dsa``)
-        self.dsa_counts = dict.fromkeys(DSA_COUNTS, 0) if (
-            model_cfg.is_dsa) else {}
-        self.attn_page_bytes_read = 0
+        # the host's account of what the steps did to the page pool and the
+        # state pool, by the keys of the kinds' records (``models/mixers.py``:
+        # pages walked and bytes read, rows by the form that ran them, bytes
+        # moved, chunks); a launch adds each kind's ``account`` to it, and an
+        # empty launch's holds every key
+        self.mixer_counts = {}
+        for kind in self.kinds:
+            if kind.account:
+                self.mixer_counts.update(dict.fromkeys(kind.account(
+                    model_cfg, self.cache_cfg, (), np.zeros(0, np.int64), 0),
+                    0))
+        # the live tokens the last launch's rows attended over
         self.step_context_tokens = 0
-        self._page_bytes = self.cache_cfg.page_bytes(model_cfg)
         # prefix hits cut back to a boundary with a state on file (or to
         # nothing) for want of one at the pages' end
         self.prefix_hits_shortened = 0
@@ -1910,10 +1790,8 @@ class Engine:
 
         self.attn_q_block = query_block(self._spec_width())
         # ... and of the PREFILL segment's paged call in the last launch that
-        # had one (``prefill_q_block``; 0 before any), with the programs the
-        # dense paged kernel ran for such launches' rows over the full layers
+        # had one (the page kind's ``query_block``; 0 before any)
         self.chunk_q_block = 0
-        self.attn_query_blocks = 0
         # the step in progress, by named phase (obs.trace.phase): the
         # engine loop clears it at the top of a pass and files it in the
         # flight record; standalone step() callers never read it
@@ -1951,131 +1829,44 @@ class Engine:
             live &= np.asarray(draft_len) >= 0
         return self._positions[live].astype(np.int64)
 
-    def _note_mixer(self, plan, draft_len, n_extra) -> dict:
-        """Add the launch's account to ``mixer_counts``; returns what the
-        launch's span shows of it (the record's ``launch`` attributes): the
-        launch's own increments and the levels."""
-        m, inc = self.mixer, {}
-        if m.account is not None:
-            inc = m.account(
-                self.model_cfg, self.cache_cfg, plan.rows if plan else (),
-                self._live_positions(draft_len), n_extra)
-            for key, n in inc.items():
-                self.mixer_counts[key] += n
-        shown = {**inc, "layers": self.model_cfg.num_state_layers,
-                 **self.mixer_gauges()}
-        return {attr: shown[key] for attr, key in m.launch}
+    def _note_kinds(self, rows, pos, n_extra, rung, max_rows) -> dict:
+        """Add the launch's account to ``mixer_counts``, kind by kind (its
+        prefill ``rows``, laid in a bucket of ``rung`` tokens and ``max_rows``
+        rows, and the live decode rows at ``pos``); returns what the launch's
+        span shows of it (the records' ``launch`` attributes): the launch's
+        own increments and the levels."""
+        cfg, attrs, levels = self.model_cfg, {}, self.mixer_gauges()
+        for kind in self.kinds:
+            inc = {}
+            if kind.account is not None:
+                # (a page kind's kernels size their blocks from the bucket)
+                bucket = () if kind is self.mixer else (rung, max_rows)
+                inc = kind.account(
+                    cfg, self.cache_cfg, rows, pos, n_extra, *bucket)
+                for key, n in inc.items():
+                    self.mixer_counts[key] += n
+            shown = {**inc, "layers": cfg.num_state_layers, **levels}
+            attrs.update({attr: shown[key] for attr, key in kind.launch})
+        return attrs
 
     def mixer_values(self) -> dict:
-        """What the kind's series read, by the record's keys: the counts as
-        they stand, its layers and the pool's bytes (no mirror is read: any
-        thread may ask); empty for a model without a state kind."""
-        if self.mixer is None:
-            return {}
+        """What the kinds' series read, by the records' keys: the counts as
+        they stand, the state kind's layers and the pools' bytes (no mirror
+        is read: any thread may ask)."""
         return {**self.mixer_counts,
                 "layers": self.model_cfg.num_state_layers,
-                "pool_bytes": self.recurrent_state_bytes}
+                "pool_bytes": self.recurrent_state_bytes,
+                **self._pool_bytes}
 
     def mixer_gauges(self) -> dict:
-        """The kind's levels from the host's mirrors, which the engine's
+        """The kinds' levels from the host's mirrors, which the engine's
         thread alone reads: for a launch's span and the flight record."""
-        if self.mixer is None or self.mixer.gauges is None:
-            return {}
-        return self.mixer.gauges(self.model_cfg, self._live_positions())
-
-    def prefill_q_block(self, rung: int, rows: int) -> int:
-        """Tokens in a query block of the prefill segment's paged call, as
-        the kernel that runs it sizes it from what the call sees: the bucket
-        (``rung``: the segment's flat tokens and the bound on a row's) and
-        the rows it can hold.  The latent kernel's is 8 tokens
-        (``query_block``); the dense kernel's follows the query heads a kv
-        head too, as the POOL holds them (``paged_query_block``: 128 tokens
-        for a one-row 512-token chunk at a group of 8 or under)."""
-        from helix_tpu.ops.paged_kernel import paged_query_block, query_block
-
-        cfg = self.model_cfg
-        if cfg.is_mla:
-            return query_block(rung)
-        group = cfg.heads_of("attn") * cfg.kv_head_pack // cfg.num_kv_heads
-        return paged_query_block(rung, group, rows, rung)
-
-    def _history_pages(self, plan, block, pos, n_extra) -> int:
-        """History pages ONE paged layer's kernel walks in this launch, from
-        the host's mirrors: over the live rows, the pages of a row's history
-        (``ceil(hist / page)``: one DMA each) times the row's query blocks
-        (each block walks the whole history again).  A prefill row is
-        ``ceil(rem / block)`` blocks over its ``start`` tokens (``block``:
-        ``prefill_q_block``, the kernel's own); a live state
-        row (``pos`` its position) is one one-token block over its position,
-        a page longer every ``page`` steps of the fused tail.  Times the
-        latent layers it is ``helix_mla_page_fetches_total`` (the latent
-        kernel's time over that count is the cost of a page fetched, PERF.md
-        section 5); times a page's K and V over the full layers,
-        ``helix_attn_page_bytes_read_total``."""
-        P = self.cache_cfg.page_size
-        pages = 0
-        if plan is not None and plan.rows:
-            pages += sum(-(-r.start // P) * -(-r.rem // block)
-                         for r in plan.rows)
-        for k in range(1 + int(n_extra)):
-            pages += int((-(-(pos + k) // P)).sum())
-        return pages
-
-    def _note_dsa(self, plan, has_hist, rung, pos, n_extra) -> dict:
-        """The launch's account of a model with a sparse-attention indexer,
-        from the host's mirrors, added to ``dsa_counts``; returns the
-        launch's own increments (its span's attributes).  A query with ``n``
-        keys (its own position among them) scores ``n`` index keys a layer
-        and attends ``min(n, topk)``: ``sparse`` past ``topk``, else ``all``.
-        A decode row fetches the latent rows it chose; a chunk row with
-        history reads its history's latent rows ONCE (a dense copy for all
-        its queries, which mask what they dropped); a cold row in a bucket
-        of no more than ``topk`` tokens runs the latent kernel over its
-        fresh tokens and scores nothing.  ``index_bytes_read`` is what the
-        device's gather moves out of the index-key pool (``ops/dsa.py::
-        _gather_rows``): EVERY row of a segment's page table at the table's
-        whole width, whatever the row holds (the scoring kernel then skips
-        the key blocks past a row's history).  ``select_bytes`` is what a
-        chunk row's CHOICE moves: the float32 scores of the flat axis'
-        queries over the row's live key blocks and the fresh tokens, written
-        once (the scoring kernel) and read twice (the threshold kernel, the
-        attention kernel's mask): they follow the history, not the table;
-        a decode row chooses by ``lax.top_k`` and moves none."""
-        cfg = self.model_cfg
-        L, K = cfg.num_attn_layers, cfg.index_topk
-        slots, table = self._page_tables.shape
-        row_bytes = (table * self.cache_cfg.page_size * cfg.index_head_dim
-                     * jnp.dtype(self.cache_cfg.dtype).itemsize)
-        inc = dict.fromkeys(DSA_COUNTS, 0)
-        for k in range(1 + int(n_extra)):
-            n = pos + k + 1
-            inc["keys_scored"] += int(n.sum())
-            inc["keys_selected"] += int(np.minimum(n, K).sum())
-            inc["rows_decode_sparse"] += int((n > K).sum())
-            inc["rows_decode_all"] += int((n <= K).sum())
-            inc["index_bytes_read"] += slots * row_bytes
-            inc["latent_rows_fetched"] += int(np.minimum(n, K).sum())
-        chooses = has_hist or rung > K
-        if plan is not None and chooses:
-            from helix_tpu.ops.dsa_kernel import SCORE_KEY_BLOCK
-
-            inc["index_bytes_read"] += plan.max_rows * row_bytes
-            width = table * self.cache_cfg.page_size
-            block = min(SCORE_KEY_BLOCK, -(-width // 128) * 128)
-            inc["select_bytes"] = 3 * 4 * rung * (rung + sum(
-                -(-r.start // block) * block for r in plan.rows))
-        for r in (plan.rows if plan is not None else ()):
-            n = np.arange(r.start + 1, r.start + r.rem + 1)
-            mode = "sparse" if r.start + r.rem > K else "all"
-            inc[f"rows_chunk_{mode}"] += 1
-            if chooses:
-                inc["keys_scored"] += int(n.sum())
-                inc["keys_selected"] += int(np.minimum(n, K).sum())
-                inc["latent_rows_fetched"] += int(n[-1])
-        for key in inc:
-            inc[key] *= L if not key.startswith("rows_") else 1
-            self.dsa_counts[key] += inc[key]
-        return {"dsa_" + k: v for k, v in inc.items()}
+        out = {}
+        for kind in self.kinds:
+            if kind.gauges is not None:
+                out.update(kind.gauges(
+                    self.model_cfg, self._live_positions()))
+        return out
 
     @property
     def kv_pages_used(self) -> int:
@@ -5208,42 +4999,22 @@ class Engine:
         )
         self.num_device_calls += 1
         self._note_adapter_rows(plan, draft_len)
-        mixer_attrs = {}
+        pos = self._live_positions(draft_len)
+        walked = self._note_kinds(
+            plan.rows if rows else (), pos, n_extra, rung, rows)
         if self.mixer is not None:
-            mixer_attrs = {
-                **self._note_mixer(plan if rows else None, draft_len, n_extra),
-                "attn_layers": self.model_cfg.num_attn_layers}
-        walked = {}
+            walked["attn_layers"] = self.model_cfg.num_attn_layers
         if self.model_cfg.num_attn_layers:
-            pos = self._live_positions(draft_len)
-            block = self.prefill_q_block(rung, rows) if rows else 0
-            pages = self._history_pages(
-                plan if rows else None, block, pos, n_extra)
             context = int(pos.sum()) + sum(
                 r.start + r.rem for r in (plan.rows if rows else ()))
+            if rows:
+                walked["chunk_q_block"] = self.model_cfg.page_kind.query_block(
+                    self.model_cfg, rung, rows)
+            walked["context_tokens"] = context
             if kind != "warmup":
                 self.step_context_tokens = context
-                self.chunk_q_block = block or self.chunk_q_block
-            if self.model_cfg.is_dsa:
-                walked = self._note_dsa(
-                    plan if rows else None, has_hist, rung, pos, n_extra)
-            elif self.model_cfg.is_mla:
-                fetches = pages * self.model_cfg.num_attn_layers
-                self.num_mla_page_fetches += fetches
-                walked = {"mla_page_fetches": fetches}
-            else:
-                page_bytes = pages * self._page_bytes
-                self.attn_page_bytes_read += page_bytes
-                # rows with no history anywhere in the launch go to the
-                # packed flash kernel: the paged kernel runs no program
-                blocks = self.model_cfg.num_attn_layers * has_hist * sum(
-                    -(-r.rem // block) for r in (plan.rows if rows else ()))
-                self.attn_query_blocks += blocks
-                walked = {"attn_page_bytes": page_bytes,
-                          "attn_query_blocks": blocks}
-            if rows:
-                walked["chunk_q_block"] = block
-            walked["context_tokens"] = context
+                self.chunk_q_block = walked.get(
+                    "chunk_q_block", self.chunk_q_block)
         used = plan.used if rows else 0
         live_rows = int(np.count_nonzero(np.asarray(draft_len) >= 0))
         joint_pass = int(rows > 0)
@@ -5262,7 +5033,6 @@ class Engine:
             **({"grouped_backend": self.grouped_backend}
                if self.grouped_backend else {}),
             attn_q_block=self.attn_q_block,
-            **mixer_attrs,
             **({"held_experts": self.model_cfg.num_held_experts}
                if self.model_cfg.held_experts else {}),
             **walked,

@@ -53,28 +53,35 @@ and ``fit_hbm`` sizes SLOTS against the budget, not pages.  A slot's state is
 not cleared when the slot is claimed: a row that starts its sequence reads
 none of it.
 
-A LATENT (MLA) page pool is ONE array, ``k_pages [L, N, P, R + 128]``: a
-token caches one row a layer, its normed latent in lanes ``0..R`` and its
-rope key behind it, zero-padded to whole 128-lane tiles
-(``CacheConfig.latent_widths``).  The values are the latent lanes of the
-same row, so attention has no second array (``v_pages`` is ``None``) and a
-``(layer, page)`` slice is one contiguous block that the latent kernel
-fetches with ONE DMA (two arrays cost it two starts and two waits a page,
-and issuing them paced it: PERF.md section 6, PR 40).  Every path that
-moves pages as opaque buffers (host pool, snapshots, checksums, filestore,
-prefix cache) carries ``"v": None`` for such a page.
+WHICH arrays the page pool has, what each holds and one page's shape is the
+record's of the kind of the model's pages (``models/mixers.py::PAGE_KINDS``:
+``pools``, through ``CacheConfig.pools``); this module gives them the axes
+``[layers with pages, pages, *page]`` and holds the first as ``k_pages``, the
+second, where the kind has one, as ``v_pages``.  Three kinds stand there:
 
-Behind a sparse-attention INDEXER (``ModelConfig.is_dsa``) a token caches a
-second row a layer: its index key, ``index_head_dim`` wide.  That is the
-INDEX-KEY POOL, ``v_pages [L, N, P, Di]`` in the pool's dtype, addressed by
-the SAME page ids and page tables as the latent pool beside it (a page is a
-page of both: admission, ``kv_pages_used`` and the prefix cache, which
-shares page ids and never looks inside one, count and share them as one);
-``write_kv`` scatters both from the one fresh pair ``(c, [k_pe | k_idx])``;
-``page_bytes`` / ``total_bytes`` / ``fit_hbm`` count both.  The paths that
-move a page's CONTENTS off the device are refused for such a model by name
-(``engine/engine.py::_REFUSALS``: the host tier, tiered residency, request
-export / import, the filestore).
+- ``kv``: K and V ``[L, N, P, KVH, D]``, with int8 storage their scale pools
+  beside them.
+- ``latent`` (MLA): ONE array, ``k_pages [L, N, P, R + 128]``: a token caches
+  one row a layer, its normed latent in lanes ``0..R`` and its rope key
+  behind it, zero-padded to whole 128-lane tiles (``mixers.latent_widths``).
+  The values are the latent lanes of the same row, so attention has no second
+  array (``v_pages`` is ``None``) and a ``(layer, page)`` slice is one
+  contiguous block that the latent kernel fetches with ONE DMA (two arrays
+  cost it two starts and two waits a page, and issuing them paced it: PERF.md
+  section 6, PR 40).  Every path that moves pages as opaque buffers (host
+  pool, snapshots, checksums, filestore, prefix cache) carries ``"v": None``
+  for such a page.
+- ``latent_indexed`` (behind a sparse-attention INDEXER) a token caches a
+  second row a layer: its index key.  That is the INDEX-KEY POOL, ``v_pages
+  [L, N, P, Di]`` in the pool's dtype, addressed by the SAME page ids and
+  page tables as the latent pool beside it (a page is a page of both:
+  admission, ``kv_pages_used`` and the prefix cache, which shares page ids
+  and never looks inside one, count and share them as one); ``write_kv``
+  scatters both from the one fresh pair ``(c, [k_pe | k_idx])``;
+  ``page_bytes`` / ``total_bytes`` / ``fit_hbm`` count both.  The paths that
+  move a page's CONTENTS off the device are refused for such a model by name
+  (the record's ``refusals`` and ``call_refusal``: the host tier, tiered
+  residency, request export / import, the filestore).
 """
 
 from __future__ import annotations
@@ -137,62 +144,44 @@ class CacheConfig:
     def max_seq_len(self) -> int:
         return self.page_size * self.max_pages_per_seq
 
-    @staticmethod
-    def latent_widths(model: ModelConfig) -> tuple:
-        """Lane widths of the two parts of a latent (MLA) pool's ROW: the
-        latent as it is (lanes ``0..R``), then the rope key padded with
-        zeros to whole 128-lane tiles.  Their sum is the minor axis of the
-        pool's one array (what is allocated and what the kernel DMAs)."""
-        return model.kv_lora_rank, -(-model.qk_rope_head_dim // 128) * 128
+    def pools(self, model: ModelConfig) -> tuple:
+        """The arrays of the page pool, in the order ``PagedKVCache`` holds
+        them, by the kind of the model's pages (its record's ``pools``:
+        ``models/mixers.py::PAGE_KINDS``)."""
+        return model.page_kind.pools(model, self.page_size)
 
     def page_shapes(self, model: ModelConfig) -> tuple:
         """Shapes of ONE page, all layers, in each of the pool's arrays
         (what ``gather_pages`` hands out and a snapshot carries): K and V
         ``[L, P, KVH, D]``, or for latent attention the ONE array's ``[L,
-        P, R + 128]`` (``latent_widths``), behind an indexer the index-key
-        pool's ``[L, P, Di]`` too."""
-        L, P = model.num_attn_layers, self.page_size
-        if model.is_dsa:
-            # the index-key pool's page behind the latent pool's
-            return ((L, P, sum(self.latent_widths(model))),
-                    (L, P, model.index_head_dim))
-        if model.is_mla:
-            return ((L, P, sum(self.latent_widths(model))),)
-        pack = model.kv_head_pack
-        kv = (L, P, model.num_kv_heads // pack, model.head_dim * pack)
-        return kv, kv
+        P, R + 128]``, behind an indexer the index-key pool's ``[L, P, Di]``
+        too."""
+        L = model.num_attn_layers
+        return tuple((L,) + p.page for p in self.pools(model))
 
     def geometry(self, model: ModelConfig) -> tuple:
         """``(kv_heads, head_dim)`` as a snapshot and the filestore's
-        namespace state a pool: ``(0, R + 128)`` for a latent pool, the
-        width of a row of its one array, which no K/V pool can match; nor
-        can what the two-array layout this pool had before PR 40 stated,
-        ``(0, R)``: its pages are refused by that field's name instead of
-        being misread."""
-        if model.is_mla:
-            # (an index-key pool beside it: its width too, so that a page
-            # of one pool is never read as a page of two)
-            return 0, sum(self.latent_widths(model)) + model.index_head_dim
-        return model.num_kv_heads, model.head_dim
+        namespace state a pool (the record's ``geometry``)."""
+        return model.page_kind.geometry(model)
+
+    def pool_bytes(self, model: ModelConfig) -> dict:
+        """``{what an array of the page pool holds: its bytes}``, the scale
+        pools of an int8 pool left out."""
+        item = jnp.dtype(self.dtype).itemsize
+        return {p.holds: self.num_pages * int(np.prod(shp)) * item
+                for p, shp in zip(self.pools(model), self.page_shapes(model))}
 
     def page_bytes(self, model: ModelConfig) -> int:
-        if model.is_mla:
-            # what is allocated, lane padding included
-            return sum(
-                int(np.prod(shp)) for shp in self.page_shapes(model)
-            ) * jnp.dtype(self.dtype).itemsize
-        per_elem = (
-            2
-            * model.num_attn_layers
-            * self.page_size
-            * model.num_kv_heads
-        )
-        total = per_elem * model.head_dim * jnp.dtype(self.dtype).itemsize
+        # what is allocated, lane padding included
+        total = sum(
+            int(np.prod(shp)) for shp in self.page_shapes(model)
+        ) * jnp.dtype(self.dtype).itemsize
         if self.quantized:
-            # f32 scale per (token slot, kv head), for K and V pools, in
-            # page rows padded to whole 128-lane rows
+            # f32 scale per (token slot, kv head) of each array that has
+            # scales, in page rows padded to whole 128-lane rows
             row = -(-self.page_size * model.num_kv_heads // 128) * 128
-            total += 2 * model.num_attn_layers * row * 4
+            total += sum(p.scaled for p in self.pools(model)) * (
+                model.num_attn_layers * row * 4)
         return total
 
     def total_bytes(self, model: ModelConfig) -> int:
@@ -247,11 +236,10 @@ class PagedKVCache:
     needed).
     """
 
-    # [L, N, P, KVH, D]; a latent pool: [L, N, P, R + 128], a token's
-    # latent in lanes 0..R and its lane-padded rope key behind it
+    # the first and the second array of the model's page kind
+    # (``CacheConfig.pools``: the record says what each holds), ``[L, N,
+    # *page]``; None where the kind has no second
     k_pages: jax.Array
-    # K's shape; None for a latent pool, whose values are its own rows;
-    # behind an indexer the index-key pool [L, N, P, Di]
     v_pages: Optional[jax.Array]
     k_scale: Optional[jax.Array] = None  # [L, N, KVH*P] f32 (int8 pools)
     v_scale: Optional[jax.Array] = None
@@ -267,33 +255,35 @@ class PagedKVCache:
         cache: CacheConfig,
         mesh=None,
     ) -> "PagedKVCache":
+        """Pages for the layers that have them (by the kind of the model's
+        pages; no bytes where there are none) and a state pool for the ones
+        with a per-sequence state."""
+        pools = cache.pools(model)
+        shapes = [(shp[0], cache.num_pages) + shp[1:]
+                  for shp in cache.page_shapes(model)]
         if model.state_mixer:
-            return cls._create_with_state(model, cache, mesh)
-        if model.is_mla:
-            return cls._create_latent(model, cache, mesh)
-        shape = (
-            model.num_layers,
-            cache.num_pages,
-            cache.page_size,
-            model.num_kv_heads,
-            model.head_dim,
-        )
-        if model.kv_head_pack > 1:
-            kshape = cache.page_shapes(model)[0]
-            shape = (kshape[0], cache.num_pages) + kshape[1:]
-            if cache.quantized:
-                raise ValueError(
-                    f"a page pool of head width {model.head_dim} packs "
-                    f"{model.kv_head_pack} kv heads into a lane tile and "
-                    "has no int8 storage (its scales are one a head): set "
-                    "kv_cache_dtype to auto, bfloat16 or float32"
-                )
+            arrays = tuple(jnp.zeros(shp, jnp.dtype(dt))
+                           for shp, dt in cache.state_shapes(model))
+            return cls._on_one_device(
+                shapes, cache, mesh, "a page pool beside a state pool",
+                state=arrays[0] if len(arrays) == 1 else arrays)
+        if not all(p.scaled for p in pools):
+            return cls._on_one_device(
+                shapes, cache, mesh,
+                f"the page pool of {model.page_kind.refused_as}")
+        if model.kv_head_pack > 1 and cache.quantized:
+            raise ValueError(
+                f"a page pool of head width {model.head_dim} packs "
+                f"{model.kv_head_pack} kv heads into a lane tile and "
+                "has no int8 storage (its scales are one a head): set "
+                "kv_cache_dtype to auto, bfloat16 or float32"
+            )
+        shape, dtype = shapes[0], jnp.dtype(cache.dtype)
         sshape = (
             model.num_layers,
             cache.num_pages,
             model.num_kv_heads * cache.page_size,
         )
-        dtype = jnp.dtype(cache.dtype)
         if mesh is not None:
             from helix_tpu.parallel.sharding import logical_sharding
 
@@ -334,29 +324,12 @@ class PagedKVCache:
         return cls(k_pages=k, v_pages=v)
 
     @classmethod
-    def _create_latent(cls, model, cache, mesh) -> "PagedKVCache":
-        """A latent pool: one array with no head axis; where a K/V pool
-        has its second, ``None`` (every opaque page path carries it as
-        such: host pool, snapshots, checksums, filestore)."""
-        return cls._create_on_one_device(
-            model, cache, mesh, "a latent (MLA) page pool")
-
-    @classmethod
-    def _create_with_state(cls, model, cache, mesh) -> "PagedKVCache":
-        """Pages for the attention layers (K/V or latent; no bytes where
-        there are none) and a state pool for the recurrent ones."""
-        arrays = tuple(jnp.zeros(shp, jnp.dtype(dt))
-                       for shp, dt in cache.state_shapes(model))
-        return cls._create_on_one_device(
-            model, cache, mesh, "a page pool beside a state pool",
-            state=arrays[0] if len(arrays) == 1 else arrays)
-
-    @classmethod
-    def _create_on_one_device(cls, model, cache, mesh, what,
-                              state=None) -> "PagedKVCache":
-        """The pool's arrays at ``CacheConfig.page_shapes`` (two, or a
-        latent pool's one), bf16 or f32, on one device: what the pools
-        without an int8 or a sharded form are."""
+    def _on_one_device(cls, shapes, cache, mesh, what,
+                       state=None) -> "PagedKVCache":
+        """The pool's arrays (two, or one: where a K/V pool has its second,
+        ``None``, and every opaque page path carries it as such: host pool,
+        snapshots, checksums, filestore), bf16 or f32, on one device: what
+        the pools without an int8 or a sharded form are."""
         if cache.quantized:
             raise ValueError(
                 f"{what} has no int8 storage: set kv_cache_dtype to auto, "
@@ -367,11 +340,7 @@ class PagedKVCache:
                 f"{what} is held by one device: a mesh of "
                 f"{mesh.devices.size} devices is not supported"
             )
-        dtype = jnp.dtype(cache.dtype)
-        pools = [
-            jnp.zeros((shp[0], cache.num_pages) + shp[1:], dtype)
-            for shp in cache.page_shapes(model)
-        ]
+        pools = [jnp.zeros(shp, jnp.dtype(cache.dtype)) for shp in shapes]
         return cls(k_pages=pools[0],
                    v_pages=pools[1] if len(pools) > 1 else None, state=state)
 

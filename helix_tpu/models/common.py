@@ -33,7 +33,9 @@ import dataclasses
 import functools
 from typing import Any, Optional
 
-from helix_tpu.models.mixers import STATE_MIXERS, StateMixer
+from helix_tpu.models.mixers import (
+    PAGE_KINDS, STATE_MIXERS, PageKind, StateMixer,
+)
 
 
 @functools.lru_cache(maxsize=None)
@@ -390,6 +392,14 @@ class ModelConfig:
         model whose memory is pages alone."""
         return STATE_MIXERS.get(self.state_mixer)
 
+    @property
+    def page_kind(self) -> PageKind:
+        """The record of what the model's pages hold (``models/mixers.py::
+        PAGE_KINDS``), never ``None``: a model with no layer that has pages
+        is the K/V kind over zero layers, its page pool of no bytes."""
+        return PAGE_KINDS["latent_indexed" if self.is_dsa else
+                          "latent" if self.is_mla else "kv"]
+
     def state_arrays(self) -> tuple:
         """``((shape, dtype), ...)``: one sequence's state in one layer, by
         the mixer's kind (its record's ``arrays``); empty for a model whose
@@ -478,19 +488,11 @@ class ModelConfig:
 
     def kv_token_shapes(self) -> tuple:
         """Per-token shapes of the two cached arrays, as the model hands
-        them to ``attn_fn``: K and V ``(kv_heads, head_dim)`` each, or for
-        latent attention the compressed latent ``(kv_lora_rank,)`` and
-        the shared rope key ``(qk_rope_head_dim,)``: no head axis, no V.
-        Behind an indexer the second is ``[rope key | index key]``."""
-        if self.is_dsa:
-            # the index key rides behind the rope key: one fresh row a
-            # token for each of the two pools (``write_kv`` parts them)
-            return (self.kv_lora_rank,), (
-                self.qk_rope_head_dim + self.index_head_dim,)
-        if self.is_mla:
-            return (self.kv_lora_rank,), (self.qk_rope_head_dim,)
-        kv = (self.num_kv_heads, self.head_dim)
-        return kv, kv
+        them to ``attn_fn``, by the kind of its pages (the record's
+        ``token_arrays``): K and V ``(kv_heads, head_dim)`` each, or for
+        latent attention the latent and the rope key, behind an indexer
+        ``[rope key | index key]``."""
+        return self.page_kind.token_arrays(self)
 
     @classmethod
     def from_hf_config(cls, hf: dict, name: str = "unnamed") -> "ModelConfig":

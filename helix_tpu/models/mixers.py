@@ -1,4 +1,6 @@
-"""The token mixers that keep a fixed per-sequence state: one record a kind.
+"""A kind of layer memory is ONE record here: the token mixers that keep a
+fixed per-sequence state (``STATE_MIXERS``) and the attention kinds whose
+memory is pages (``PAGE_KINDS``).
 
 A layer whose memory is not pages (a gated short convolution's tail, power
 retention's matrix, the gated delta rule's matrix and conv tail, a
@@ -22,11 +24,27 @@ everything the modules ABOVE ``models/`` ask of a kind:
   names under which ``/metrics``, ``helix.loop.launch`` and the flight record
   show them.
 
+An ``attn`` layer's memory is PAGES, and what a page holds is a kind too:
+K and V a kv head (``kv``), one latent row a token (``latent``), or that row
+in one pool and the token's index key in a second under the same page ids
+(``latent_indexed``).  ``PAGE_KINDS`` maps those names to a
+:class:`PageKind` (``ModelConfig.page_kind`` resolves a model to its record,
+never ``None``: a model with no ``attn`` layer is the K/V kind over zero
+layers), which holds the same things under the same names (``refused_as``,
+``refusals``, ``call_refusal``, ``check_geometry``, ``account``, ``series``,
+``launch``, ``flight``) and, where a state kind says its arrays and its
+look-back, the ARRAYS OF THE PAGE POOL (``pools``: what
+``PagedKVCache.k_pages`` / ``v_pages`` hold for the kind, one page's shape,
+whether an int8 pool keeps scales beside it), the fresh arrays a layer hands
+on (``token_args``, ``token_arrays``), one segment's attention over the pool
+(``attend``) and the chunk's query block (``query_block``).
+
 A layer's COMPUTE (projections, gates, norms, rope) is ``models/llama.py``'s,
 its operator ``ops/``'s.  A further kind is a record here, its compute
-there, its ``ModelConfig`` keys and its tests (the fifth, ``mamba2``, came
-so): nothing under ``engine/``, ``serving/`` or ``obs/`` spells a kind's
-name.
+there, its ``ModelConfig`` keys and its tests (the fifth state kind,
+``mamba2``, came so): nothing under ``engine/``, ``serving/`` or ``obs/``
+spells a kind's name or reads the ``ModelConfig`` keys that tell one from
+another.
 """
 
 from __future__ import annotations
@@ -87,6 +105,59 @@ class StateMixer:
     window: Callable = None
     gauges: Callable = None      # (cfg, pos) -> {level: value} from the live
                                  # positions; None: no level
+
+    def __post_init__(self):
+        if self.flight is None:
+            object.__setattr__(self, "flight", self.launch)
+
+
+@dataclasses.dataclass(frozen=True)
+class Pool:
+    """One array of the page pool, ``[layers with pages, pages, *page]``."""
+    holds: str                   # "k" | "v" | "latent" | "index_keys"
+    page: tuple                  # one page's shape in one layer
+    scaled: bool = False         # it has a kv-head axis: an int8 pool keeps a
+                                 # scale a token and head in a scale pool
+                                 # beside it, and a mesh shards it by head;
+                                 # without one it is bf16 or f32 on one device
+
+
+@dataclasses.dataclass(frozen=True)
+class PageKind:
+    # -- refused by name (as a state kind's) ---------------------------------
+    refused_as: str
+    refusals: tuple
+    call_refusal: str            # None: a call that moves pages is served
+    # -- its pages -----------------------------------------------------------
+    # (cfg, page size) -> the Pools, in the order ``PagedKVCache`` holds them:
+    # the first is ``k_pages``, the second, where there is one, ``v_pages``
+    pools: Callable
+    geometry: Callable           # cfg -> ``(kv heads, head dim)`` as a snapshot
+                                 # and the filestore's namespace state a pool
+    token_args: int              # leading arguments of ``attn_fn`` that are
+                                 # token arrays (``_segments_fn`` splits them)
+    token_arrays: Callable       # cfg -> a token's shape in each of the two
+                                 # fresh arrays a layer hands on to be cached
+    # -- its attention over them ---------------------------------------------
+    # (rows, tables, backend, *, cfg, bucket, has_hist, cold, mesh) -> one
+    # segment's attention, called ``(q, k, v[, more], pool carry, layer)``;
+    # None: the segment reads no page (``bucket`` tokens of prefill rows, none
+    # with history: the packed kernel runs them)
+    attend: Callable
+    query_block: Callable        # (cfg, rung, rows) -> tokens in a query
+                                 # block of the prefill segment's paged call
+    check_geometry: Callable     # (cfg, tp, kv itemsize): the Pallas kernel's
+    # -- what shows it (as a state kind's) -----------------------------------
+    # the host's account of a launch, a state kind's arguments and the bucket
+    # the rows lie in (``rung`` tokens, ``max_rows`` rows): the kernels size
+    # their blocks from it
+    account: Callable
+    series: tuple
+    launch: tuple
+    flight: tuple = None
+    gauges: Callable = None
+    base: "PageKind" = None      # the kind whose pool this one's stand
+                                 # beside: refused what that one is
 
     def __post_init__(self):
         if self.flight is None:
@@ -923,16 +994,414 @@ STATE_MIXERS = {
 }
 
 
-def flight_fields(kind, values: dict, since: dict) -> dict:
-    """The flight record's fields of EVERY kind for a step of an engine
-    whose kind is ``kind`` (a record or None): ``values`` the engine's
-    ``mixer_values()`` and ``mixer_gauges()`` after the step, ``since`` its
-    ``mixer_counts`` before it.  A count reads what the step added, a level
-    as it stands, a field of a kind the model has not 0."""
+# ---- the kinds of page -------------------------------------------------------
+#
+# ``rows = (t0, q_len, hist)`` of a segment as a state kind's, ``tables`` its
+# rows' page tables.  The function an ``attend`` returns flattens the token
+# grid onto the op's flat row axis; ``caches`` is the pool carry
+# (``PagedKVCache.carry``) and ``lyr`` the layer's index among those with
+# pages.
+
+
+def _kv_pools(cfg, page_size: int) -> tuple:
+    """K and V ``[P, KVH, D]``; a head width that divides the 128 lanes is
+    stored ``[KVH / pack, pack * D]`` (``ModelConfig.kv_head_pack``)."""
+    pack = cfg.kv_head_pack
+    page = (page_size, cfg.num_kv_heads // pack, cfg.head_dim * pack)
+    return Pool("k", page, scaled=True), Pool("v", page, scaled=True)
+
+
+def latent_widths(cfg) -> tuple:
+    """Lane widths of the two parts of a latent (MLA) pool's ROW: the
+    latent as it is (lanes ``0..R``), then the rope key padded with
+    zeros to whole 128-lane tiles.  Their sum is the minor axis of the
+    pool's one array (what is allocated and what the kernel DMAs)."""
+    return cfg.kv_lora_rank, -(-cfg.qk_rope_head_dim // 128) * 128
+
+
+def _latent_pools(cfg, page_size: int) -> tuple:
+    """ONE array ``[P, R + 128]`` (``latent_widths``): the values are the
+    latent lanes of the same row, so there is no second array (``v_pages`` is
+    ``None``, and every path that moves pages as opaque buffers carries
+    ``"v": None``)."""
+    return (Pool("latent", (page_size, sum(latent_widths(cfg)))),)
+
+
+def _indexed_pools(cfg, page_size: int) -> tuple:
+    """The latent pool, and as ``v_pages`` the INDEX-KEY pool ``[P, Di]``
+    under the same page ids and page tables: a page is a page of both."""
+    return (*_latent_pools(cfg, page_size),
+            Pool("index_keys", (page_size, cfg.index_head_dim)))
+
+
+def _latent_geometry(cfg) -> tuple:
+    """``(0, the width of a row of every array)``, which no K/V pool can
+    match; nor can what the two-array layout the latent pool had before PR 40
+    stated, ``(0, R)``: its pages are refused by that field's name instead of
+    being misread, and a page of one pool is never read as a page of two."""
+    return 0, sum(latent_widths(cfg)) + cfg.index_head_dim
+
+
+def _latent_token_arrays(cfg) -> tuple:
+    """The compressed latent ``(kv_lora_rank,)`` and the shared rope key
+    ``(qk_rope_head_dim,)``: no head axis, no V."""
+    return (cfg.kv_lora_rank,), (cfg.qk_rope_head_dim,)
+
+
+def _indexed_token_arrays(cfg) -> tuple:
+    """The index key rides behind the rope key: one fresh row a token for
+    each of the two pools (``write_kv`` parts them)."""
+    return (cfg.kv_lora_rank,), (cfg.qk_rope_head_dim + cfg.index_head_dim,)
+
+
+def _kv_attend(rows, tables, backend, *, cfg, bucket=0, has_hist=True,
+               cold=None, mesh=None):
+    """The ragged paged op over the K and V pools (with int8 pools their
+    scale pools).  ``cold`` (tiered KV residency) carries the staged
+    cold-middle chunks plus each row's demoted token span: the op excludes
+    the span from the hot gather and merges the chunks' online-softmax stats
+    instead.  Prefill rows none of which has history read no page."""
+    from helix_tpu.ops.paged import ragged_paged_attention
+
+    if bucket and not has_hist:
+        return None
+    tkw = {}
+    if cold is not None:
+        (c_k, c_v, c_ks, c_vs, c_row, c_len, lo, hi) = cold
+        tkw = dict(
+            span_lo=lo, span_hi=hi, cold_k=c_k, cold_v=c_v,
+            cold_row=c_row, cold_len=c_len,
+            cold_k_scale=c_ks, cold_v_scale=c_vs,
+        )
+
+    def attend(q, k, v, caches, lyr):
+        kp, vp, *scales = caches
+        ks, vs = scales or (None, None)
+        Bq, Sq, H, D = q.shape
+        KVH = k.shape[-2]
+        out = ragged_paged_attention(
+            q.reshape(Bq * Sq, H, D),
+            k.reshape(Bq * Sq, KVH, D),
+            v.reshape(Bq * Sq, KVH, D),
+            kp, vp, lyr, *rows, tables,
+            backend=backend, mesh=mesh, max_q_len=Sq, k_scale=ks, v_scale=vs,
+            **tkw,
+        )
+        return out.reshape(Bq, Sq, H, D)
+
+    return attend
+
+
+def _latent_attend(rows, tables, backend, *, cfg, bucket=0, has_hist=True,
+                   cold=None, mesh=None):
+    """Latent attention: ``k`` is the latent, ``v`` the rope key, no head
+    axis, and the pool one array of their joined rows; the layer has scaled
+    ``q`` already.  A row holds at most ``Sq`` fresh tokens (a decode slot's
+    width, or the whole prefill bucket).  It has one kernel: a cold row is a
+    row with no history."""
+    from helix_tpu.ops.paged import mla_ragged_paged_attention
+
+    def attend(q, k, v, caches, lyr):
+        Bq, Sq, H, D = q.shape
+        out = mla_ragged_paged_attention(
+            q.reshape(Bq * Sq, H, D),
+            k.reshape(Bq * Sq, k.shape[-1]),
+            v.reshape(Bq * Sq, v.shape[-1]),
+            caches[0], lyr, *rows, tables,
+            backend=backend, max_q_len=Sq,
+        )
+        return out.reshape(Bq, Sq, H, out.shape[-1])
+
+    return attend
+
+
+def _indexed_attend(rows, tables, backend, *, cfg, bucket=0, has_hist=True,
+                    cold=None, mesh=None):
+    """Latent attention behind an indexer: ``v`` is ``[rope key | index
+    key]``, ``qi`` the index queries, and each query attends the
+    ``index_topk`` keys its index scores choose (``ops/dsa.py``).  A row with
+    history chooses its keys, and so does a cold row in a bucket past
+    ``index_topk``; a cold row of no more tokens than that attends all it has,
+    on the latent kernel, and only caches its index keys."""
+    from helix_tpu.ops.dsa import dsa_ragged_paged_attention
+
+    if bucket and not has_hist and bucket <= cfg.index_topk:
+        latent = _latent_attend(rows, tables, backend, cfg=cfg)
+        return lambda q, k, v, qi, caches, lyr: latent(
+            q, k, v[..., :cfg.qk_rope_head_dim], caches, lyr)
+
+    def attend(q, k, v, qi, caches, lyr):
+        Bq, Sq, H, D = q.shape
+        out = dsa_ragged_paged_attention(
+            q.reshape(Bq * Sq, H, D),
+            k.reshape(Bq * Sq, k.shape[-1]),
+            v.reshape(Bq * Sq, v.shape[-1]),
+            qi.reshape(Bq * Sq, qi.shape[-1]),
+            caches[0], caches[1], lyr, *rows, tables,
+            index_heads=cfg.index_heads, topk=cfg.index_topk,
+            backend=backend, max_q_len=Sq,
+        )
+        return out.reshape(Bq, Sq, H, out.shape[-1])
+
+    return attend
+
+
+def _kv_query_block(cfg, rung: int, rows: int) -> int:
+    """The dense kernel's, as it sizes it from what the call sees: the bucket
+    (``rung``: the segment's flat tokens and the bound on a row's), the rows
+    it can hold and the query heads a kv head as the POOL holds them
+    (``paged_query_block``: 128 tokens for a one-row 512-token chunk at a
+    group of 8 or under)."""
+    from helix_tpu.ops.paged_kernel import paged_query_block
+
+    group = cfg.heads_of("attn") * cfg.kv_head_pack // cfg.num_kv_heads
+    return paged_query_block(rung, group, rows, rung)
+
+
+def _latent_query_block(cfg, rung: int, rows: int) -> int:
+    """The latent kernel's: 8 tokens."""
+    from helix_tpu.ops.paged_kernel import query_block
+
+    return query_block(rung)
+
+
+def _check_kv(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.paged_kernel import check_geometry
+
+    check_geometry(
+        cfg.heads_of("attn") // tp, max(cfg.num_kv_heads // tp, 1),
+        cfg.head_dim, itemsize)
+
+
+def _check_latent(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.mla_kernel import check_mla_geometry
+
+    check_mla_geometry(
+        cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim, itemsize)
+
+
+def _check_indexed(cfg, tp, itemsize) -> None:
+    from helix_tpu.ops.dsa_kernel import check_dsa_geometry
+
+    _check_latent(cfg, tp, itemsize)
+    check_dsa_geometry(
+        cfg.index_heads, cfg.index_head_dim, cfg.num_heads,
+        sum(latent_widths(cfg)))
+
+
+def history_pages(rows, block: int, pos, n_extra, page_size: int) -> int:
+    """History pages ONE paged layer's kernel walks in a launch, from the
+    host's mirrors: over the live rows, the pages of a row's history
+    (``ceil(hist / page)``: one DMA each) times the row's query blocks
+    (each block walks the whole history again).  A prefill row is
+    ``ceil(rem / block)`` blocks over its ``start`` tokens (``block``: the
+    kind's ``query_block``, the kernel's own); a live state
+    row (``pos`` its position) is one one-token block over its position,
+    a page longer every ``page`` steps of the fused tail."""
+    P = page_size
+    pages = sum(-(-r.start // P) * -(-r.rem // block) for r in rows)
+    for k in range(1 + int(n_extra)):
+        pages += int((-(-(pos + k) // P)).sum())
+    return pages
+
+
+def _kv_account(cfg, cache_cfg, rows, pos, n_extra, rung=0,
+                max_rows=0) -> dict:
+    """K/V bytes of the pages the dense paged kernel walked (``history_pages``
+    times a page's K and V over the full layers: what a roofline by hand
+    divides the kernel's time by), and the programs that kernel ran for the
+    launch's chunk rows over the full layers (a row's ``ceil(tokens /
+    block)`` a layer: 4 a 512-token row at 128); rows with no history
+    anywhere in the launch go to the packed flash kernel: the paged kernel
+    runs no program."""
+    block = _kv_query_block(cfg, rung, max_rows) if max_rows else 0
+    pages = history_pages(rows, block, pos, n_extra, cache_cfg.page_size)
+    return {
+        "attn_page_bytes_read": pages * cache_cfg.page_bytes(cfg),
+        "attn_query_blocks": cfg.num_attn_layers * any(
+            r.start > 0 for r in rows) * sum(-(-r.rem // block) for r in rows),
+    }
+
+
+def _latent_account(cfg, cache_cfg, rows, pos, n_extra, rung=0,
+                    max_rows=0) -> dict:
+    """The history pages the latent kernel walked, one DMA each
+    (``history_pages`` times the latent layers): the kernel's time over this
+    is the cost of a page fetched (PERF.md section 5)."""
+    block = _latent_query_block(cfg, rung, max_rows) if max_rows else 0
+    return {"mla_page_fetches": cfg.num_attn_layers * history_pages(
+        rows, block, pos, n_extra, cache_cfg.page_size)}
+
+
+_INDEXED_COUNTS = ("keys_scored", "keys_selected", "rows_decode_sparse",
+                   "rows_decode_all", "rows_chunk_sparse", "rows_chunk_all",
+                   "index_bytes_read", "latent_rows_fetched", "select_bytes")
+
+
+def _indexed_account(cfg, cache_cfg, rows, pos, n_extra, rung=0,
+                     max_rows=0) -> dict:
+    """The launch's account of a model with a sparse-attention indexer.  A
+    query with ``n`` keys (its own position among them) scores ``n`` index
+    keys a layer and attends ``min(n, topk)``: ``sparse`` past ``topk``, else
+    ``all``.  A decode row fetches the latent rows it chose; a chunk row with
+    history reads its history's latent rows ONCE (a dense copy for all
+    its queries, which mask what they dropped); a cold row in a bucket
+    of no more than ``topk`` tokens runs the latent kernel over its
+    fresh tokens and scores nothing (and that kernel, which runs no row with
+    history here, fetches no page).  ``index_bytes_read`` is what the
+    device's gather moves out of the index-key pool (``ops/dsa.py::
+    _gather_rows``): EVERY row of a segment's page table at the table's
+    whole width, whatever the row holds (the scoring kernel then skips
+    the key blocks past a row's history).  ``select_bytes`` is what a
+    chunk row's CHOICE moves: the float32 scores of the flat axis'
+    queries over the row's live key blocks and the fresh tokens, written
+    once (the scoring kernel) and read twice (the threshold kernel, the
+    attention kernel's mask): they follow the history, not the table;
+    a decode row chooses by ``lax.top_k`` and moves none."""
+    L, K = cfg.num_attn_layers, cfg.index_topk
+    slots, table = cache_cfg.state_slots, cache_cfg.max_pages_per_seq
+    row_bytes = (table * cache_cfg.page_size * cfg.index_head_dim
+                 * jnp.dtype(cache_cfg.dtype).itemsize)
+    inc = dict.fromkeys(_INDEXED_COUNTS, 0)
+    for k in range(1 + int(n_extra)):
+        n = pos + k + 1
+        inc["keys_scored"] += int(n.sum())
+        inc["keys_selected"] += int(np.minimum(n, K).sum())
+        inc["rows_decode_sparse"] += int((n > K).sum())
+        inc["rows_decode_all"] += int((n <= K).sum())
+        inc["index_bytes_read"] += slots * row_bytes
+        inc["latent_rows_fetched"] += int(np.minimum(n, K).sum())
+    chooses = any(r.start > 0 for r in rows) or rung > K
+    if max_rows and chooses:
+        from helix_tpu.ops.dsa_kernel import SCORE_KEY_BLOCK
+
+        inc["index_bytes_read"] += max_rows * row_bytes
+        width = table * cache_cfg.page_size
+        block = min(SCORE_KEY_BLOCK, -(-width // 128) * 128)
+        inc["select_bytes"] = 3 * 4 * rung * (rung + sum(
+            -(-r.start // block) * block for r in rows))
+    for r in rows:
+        n = np.arange(r.start + 1, r.start + r.rem + 1)
+        mode = "sparse" if r.start + r.rem > K else "all"
+        inc[f"rows_chunk_{mode}"] += 1
+        if chooses:
+            inc["keys_scored"] += int(n.sum())
+            inc["keys_selected"] += int(np.minimum(n, K).sum())
+            inc["latent_rows_fetched"] += int(n[-1])
+    for key in inc:
+        inc[key] *= L if not key.startswith("rows_") else 1
+    return {**inc, "mla_page_fetches": 0}
+
+
+_PAGE_FETCHES = Series(
+    "helix_mla_page_fetches_total", "counter", "mla_page_fetches")
+_LATENT = PageKind(
+    refused_as="latent attention (MLA)",
+    refusals=(
+        ("multi_device",
+         "the latent pool and its kernel are single-device: mesh {tp: 1}"),
+        ("int8_kv", "the latent pool is bf16 or f32"),
+        ("adapters", "no LoRA targets on MLA projections"),
+        ("spec_decode", "untested on the latent kernel"),
+        ("tiered", "tiered residency streams K/V chunks"),
+    ),
+    call_refusal=None,
+    pools=_latent_pools,
+    geometry=_latent_geometry,
+    token_args=3,
+    token_arrays=_latent_token_arrays,
+    attend=_latent_attend,
+    query_block=_latent_query_block,
+    check_geometry=_check_latent,
+    account=_latent_account,
+    series=(_PAGE_FETCHES,),
+    launch=(("mla_page_fetches", "mla_page_fetches"),),
+    flight=(),
+)
+
+PAGE_KINDS = {
+    "kv": PageKind(
+        refused_as="K/V pages",
+        refusals=(),
+        call_refusal=None,
+        pools=_kv_pools,
+        geometry=lambda cfg: (cfg.num_kv_heads, cfg.head_dim),
+        token_args=3,
+        token_arrays=lambda cfg: ((cfg.num_kv_heads, cfg.head_dim),) * 2,
+        attend=_kv_attend,
+        query_block=_kv_query_block,
+        check_geometry=_check_kv,
+        account=_kv_account,
+        series=(
+            Series("helix_attn_page_bytes_read_total", "counter",
+                   "attn_page_bytes_read"),
+            Series("helix_attn_query_blocks_total", "counter",
+                   "attn_query_blocks"),
+        ),
+        launch=(("attn_page_bytes", "attn_page_bytes_read"),
+                ("attn_query_blocks", "attn_query_blocks")),
+        flight=(("attn_page_bytes_read", "attn_page_bytes_read"),),
+    ),
+    "latent": _LATENT,
+    "latent_indexed": PageKind(
+        refused_as="a sparse-attention indexer (an index-key pool beside the "
+                   "latent pool)",
+        refusals=(
+            ("host_tier",
+             "the host tier moves pages of the latent pool; an index-key "
+             "pool beside the latent pool is not carried there"),
+        ),
+        call_refusal="a page's contents leave the device as the latent "
+                     "pool's alone; an index-key pool beside the latent pool "
+                     "is not carried there",
+        base=_LATENT,
+        pools=_indexed_pools,
+        geometry=_latent_geometry,
+        token_args=4,
+        token_arrays=_indexed_token_arrays,
+        attend=_indexed_attend,
+        query_block=_latent_query_block,
+        check_geometry=_check_indexed,
+        account=_indexed_account,
+        series=(
+            # from the host's account of the launches, times the latent
+            # layers: index keys scored and keys then attended (their ratio
+            # is the chosen share), rows by kind and by whether they were
+            # past ``index_topk`` keys, the bytes the gather moves out of the
+            # index-key pool, the latent rows fetched, and the score bytes a
+            # chunk row's choice moves
+            Series("helix_dsa_keys_scored_total", "counter", "keys_scored"),
+            Series("helix_dsa_keys_selected_total", "counter",
+                   "keys_selected"),
+            *(Series("helix_dsa_rows_total", "counter", f"rows_{kind}_{mode}",
+                     (("kind", kind), ("mode", mode)))
+              for kind in ("decode", "chunk") for mode in ("sparse", "all")),
+            Series("helix_dsa_index_bytes_read_total", "counter",
+                   "index_bytes_read"),
+            Series("helix_dsa_latent_rows_fetched_total", "counter",
+                   "latent_rows_fetched"),
+            Series("helix_dsa_select_bytes_total", "counter", "select_bytes"),
+            # the index-key pool's bytes beside the latent pool's
+            Series("helix_dsa_index_pool_bytes", "gauge",
+                   "index_keys_pool_bytes"),
+            _PAGE_FETCHES,
+        ),
+        launch=tuple(("dsa_" + key, key) for key in _INDEXED_COUNTS),
+    ),
+}
+
+
+def flight_fields(kinds: tuple, values: dict, since: dict) -> dict:
+    """The flight record's fields of EVERY kind, of state and of page, for a
+    step of an engine whose layers are of ``kinds`` (the records,
+    ``Engine.kinds``): ``values`` the engine's ``mixer_values()`` and
+    ``mixer_gauges()`` after the step, ``since`` its ``mixer_counts`` before
+    it.  A count reads what the step added, a level as it stands, a field of
+    a kind the model has not 0."""
     out = {}
-    for m in STATE_MIXERS.values():
+    for m in (*STATE_MIXERS.values(), *PAGE_KINDS.values()):
         for field, key in m.flight:
-            if m is not kind:
+            if m not in kinds:
                 out[field] = 0
             elif key in since:
                 out[field] = values[key] - since[key]

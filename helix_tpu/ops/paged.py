@@ -538,7 +538,7 @@ def mla_ragged_paged_attention(
     ``[c | k_pe]`` a token, the values a view of the keys (``c``).  The
     pool is ONE array: a token's row holds its normed latent in lanes
     ``0..R`` and its rope key behind it, zero-padded to 128 lanes
-    (``CacheConfig.latent_widths``), so a page of a layer is one contiguous
+    (``mixers.py::latent_widths``), so a page of a layer is one contiguous
     block and one DMA.  The fresh tokens come as the model makes them, the
     latent and the rope key apart (``write_kv`` joins them into rows).
     Returns the attended latents ``[T, H, R]``.  ``max_q_len`` is a static

@@ -1718,8 +1718,6 @@ class EngineLoop:
             getattr(eng, "num_joint_pass_inert_rows", 0),
             getattr(eng, "num_wave_decode_tokens", 0),
             dict(getattr(eng, "mixer_counts", {})),
-            getattr(eng, "attn_page_bytes_read", 0),
-            dict(getattr(eng, "dsa_counts", {})),
         )
 
     def _resume_failures_pending(self) -> bool:
@@ -1732,7 +1730,7 @@ class EngineLoop:
     ) -> None:
         eng = self.engine
         (p0, pad0, d0, a0, q0, sd0, sa0, sp0, rs0, pe0, re0,
-         cs0, jp0, ji0, wr0, mixer0, pb0, dsa0) = pre
+         cs0, jp0, ji0, wr0, mixer0) = pre
         hp = getattr(eng, "host_pool", None)
         prefill = eng.num_prefill_tokens - p0
         decode = eng.num_decode_tokens - d0
@@ -1771,7 +1769,7 @@ class EngineLoop:
             # call: 1 for plain decode, 8 under speculation
             "attn_q_block": getattr(eng, "attn_q_block", 0),
             # ... and of the prefill segment's paged call in the last launch
-            # that had one (``Engine.prefill_q_block``; 0 before any)
+            # that had one (the page kind's ``query_block``; 0 before any)
             "chunk_q_block": getattr(eng, "chunk_q_block", 0),
             # this step's programs in which prefill rows and state rows
             # shared one pass over the layers (1 for a wave, a chunk or a
@@ -1781,12 +1779,15 @@ class EngineLoop:
             "joint_pass": getattr(eng, "num_joint_pass_steps", 0) - jp0,
             "inert_rows": getattr(eng, "num_joint_pass_inert_rows", 0) - ji0,
             "wave_rows": getattr(eng, "num_wave_decode_tokens", 0) - wr0,
-            # layers whose state is fixed arrays a slot (the state pool), by
-            # kind, and what each kind's record shows of this step's
-            # programs (``models/mixers.py``: a count since the step began,
-            # a level as it stands; 0 for a kind the model has not)
+            # what each kind's record, of state and of page, shows of this
+            # step's programs (``models/mixers.py``: a count since the step
+            # began, a level as it stands; 0 for a kind the model has not):
+            # layers whose state is fixed arrays a slot and what moved in the
+            # state pool, the K/V bytes of the pages the dense paged kernel
+            # walked, what a sparse-attention indexer scored, chose and
+            # fetched
             **flight_fields(
-                getattr(eng, "mixer", None),
+                getattr(eng, "kinds", ()),
                 {**getattr(eng, "mixer_values", dict)(),
                  **getattr(eng, "mixer_gauges", dict)()}, mixer0),
             # experts of the routed set whose weights are on this chip (0:
@@ -1797,15 +1798,8 @@ class EngineLoop:
             "attn_layers": getattr(
                 eng.model_cfg, "num_attn_layers",
                 getattr(eng.model_cfg, "num_layers", 0)),
-            # K/V bytes of the pages the dense paged kernel walked in this
-            # step's programs, and the live tokens the rows of its last
-            # launch attended over (from the host's mirrors)
-            "attn_page_bytes_read": (
-                getattr(eng, "attn_page_bytes_read", 0) - pb0),
-            # behind a sparse-attention indexer: what this step's programs
-            # scored, chose and fetched (``Engine._note_dsa``)
-            **{"dsa_" + k: n - dsa0.get(k, 0)
-               for k, n in getattr(eng, "dsa_counts", {}).items()},
+            # the live tokens the rows of the step's last launch attended
+            # over (from the host's mirrors)
             "context_tokens": getattr(eng, "step_context_tokens", 0),
             "prefill_tokens": prefill,
             "padding_tokens": (

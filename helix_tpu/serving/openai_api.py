@@ -172,17 +172,6 @@ def _sse_error_frame(e: Exception, trace_id: str = "") -> dict:
     return {"error": err}
 
 
-# the host's account of a sparse-attention indexer (``Engine.dsa_counts``),
-# by series
-_DSA_SERIES = {
-    "keys_scored": "helix_dsa_keys_scored_total",
-    "keys_selected": "helix_dsa_keys_selected_total",
-    "index_bytes_read": "helix_dsa_index_bytes_read_total",
-    "latent_rows_fetched": "helix_dsa_latent_rows_fetched_total",
-    "select_bytes": "helix_dsa_select_bytes_total",
-}
-
-
 class OpenAIServer:
     def __init__(self, registry: ModelRegistry, metrics=None,
                  inter_token_timeout: Optional[float] = None,
@@ -493,53 +482,14 @@ class OpenAIServer:
                     "helix_moe_away_tokens_total",
                     getattr(eng, "moe_away_tokens", 0), lbl,
                 )
-            for key, n in getattr(eng, "dsa_counts", {}).items():
-                # a sparse-attention indexer in front of latent attention,
-                # from the host's account of the launches, times the latent
-                # layers: index keys scored and keys then attended (their
-                # ratio is the chosen share), rows by kind and by whether
-                # they were past ``index_topk`` keys, the bytes the gather
-                # moves out of the index-key pool (every table row at the
-                # table's whole width), the latent rows fetched, and the
-                # score bytes a chunk row's choice moves (its live key
-                # blocks and fresh tokens, written once and read twice)
-                if key.startswith("rows_"):
-                    _, kind, mode = key.split("_")
-                    c.counter("helix_dsa_rows_total", n,
-                              {**lbl, "kind": kind, "mode": mode})
-                else:
-                    c.counter(_DSA_SERIES[key], n, lbl)
-            if getattr(eng.model_cfg, "is_dsa", False):
-                c.gauge("helix_dsa_index_pool_bytes",
-                        eng.index_pool_bytes, lbl)
-            if getattr(eng.model_cfg, "is_mla", False):
-                # latent attention: the history pages its kernel walked,
-                # one DMA each (live rows' pages x query blocks x latent
-                # layers, from the host's mirrors); the kernel's time over
-                # this is the cost of a page fetched
-                c.counter(
-                    "helix_mla_page_fetches_total",
-                    getattr(eng, "num_mla_page_fetches", 0), lbl,
-                )
-            elif getattr(eng.model_cfg, "num_attn_layers", 0):
-                # K/V bytes of the pages the dense paged kernel walked (live
-                # rows' pages x query blocks x a page over the full layers,
-                # from the host's mirrors): what a roofline by hand divides
-                # the kernel's time by
-                c.counter(
-                    "helix_attn_page_bytes_read_total",
-                    getattr(eng, "attn_page_bytes_read", 0), lbl,
-                )
-                # the programs that kernel ran for launches' chunk rows over
-                # the full layers (a row's ``ceil(tokens / block)`` a layer,
-                # the block the kernel's own: 4 a 512-token row at 128)
-                c.counter(
-                    "helix_attn_query_blocks_total",
-                    getattr(eng, "attn_query_blocks", 0), lbl,
-                )
-            mixer = getattr(eng, "mixer", None)
-            if mixer is not None:
-                if mixer.snapshots:
+            # the engine's series by kind: what the record of the kind of the
+            # model's pages and that of its state kind (``models/mixers.py``)
+            # show of the page pool and the state pool, from the host's
+            # account of the launches
+            kinds = getattr(eng, "kinds", ())
+            values = eng.mixer_values() if kinds else {}
+            for kind in kinds:
+                if kind is eng.mixer and kind.snapshots:
                     # a second kind of state beside the pages: boundary
                     # states kept for the prefix cache, states written into
                     # admitted hits' slots, hits cut back for want of a state
@@ -555,11 +505,7 @@ class OpenAIServer:
                         "helix_prefix_hits_shortened_total",
                         getattr(eng, "prefix_hits_shortened", 0), lbl,
                     )
-                # the engine's mixer series: what the record of the model's
-                # state kind (``models/mixers.py``) shows of the state pool,
-                # from the host's account of the launches
-                values = eng.mixer_values()
-                for sr in mixer.series:
+                for sr in kind.series:
                     getattr(c, sr.kind)(
                         sr.name, values[sr.value], {**lbl, **dict(sr.labels)})
             # speculative decoding (ISSUE 5): host-drafted tokens, the

@@ -96,13 +96,29 @@ def tokens_of(n, seed=0, vocab=300):
     return np.random.default_rng(seed).integers(1, vocab, size=n).tolist()
 
 
-@pytest.fixture
-def probe():
+# The engines below share TWO configurations, so that a step program is
+# traced and compiled once a shape for the module and not once a test
+# (``_build_ragged_step_fn`` is keyed by the configuration, its name too): one
+# whose programs are traced WITH ``ops.dsa.PROBE`` set, which only the tests
+# that take ``probe`` run (the callback looks the probe up when it is CALLED:
+# each test reads its own), and one traced without.  Weights are an engine's
+# own: they are no part of the key.
+PROBED = tiny(name="tiny-dsa-probed")
+UNPROBED = tiny(name="tiny-dsa")
+
+
+@pytest.fixture(scope="module")
+def model():
     engine_mod._build_ragged_step_fn.cache_clear()
+    yield PROBED, init_params(PROBED, jax.random.PRNGKey(7))
+    engine_mod._build_ragged_step_fn.cache_clear()
+
+
+@pytest.fixture
+def probe(model):
     dsa.PROBE = Probe()
     yield dsa.PROBE
     dsa.PROBE = None
-    engine_mod._build_ragged_step_fn.cache_clear()
 
 
 def _engine(cfg, params, **kw):
@@ -213,10 +229,10 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", list(CASES))
-def test_engine_through_both_pools_against_the_reference(case, probe):
+def test_engine_through_both_pools_against_the_reference(
+        case, model, probe):
     sizes, kw, kinds = CASES[case]
-    cfg = tiny(name=f"tiny-dsa-{case}")
-    params = init_params(cfg, jax.random.PRNGKey(7))
+    cfg, params = model
     eng = _engine(cfg, params, **kw)
     reqs = [_req(f"r{i}", n, out, seed=10 + i)
             for i, (n, out) in enumerate(sizes)]
@@ -231,21 +247,20 @@ def test_engine_through_both_pools_against_the_reference(case, probe):
             assert m["sparse"] == 0
     assert seen == kinds, seen
     # the host's account of the same launches
-    c = eng.dsa_counts
+    c = eng.mixer_counts
     assert c["keys_selected"] <= c["keys_scored"] or not kinds
     assert (c["rows_decode_sparse"] > 0) == ("decode" in kinds)
     assert (c["rows_chunk_sparse"] > 0) == ("chunk" in kinds)
 
 
-def test_each_tolerance_catches_its_fault(probe):
+def test_each_tolerance_catches_its_fault(model, probe):
     """What the three tolerances are FOR, on one request (100 tokens in
     chunks of 16, six steps): bfloat16 index queries and keys in a float32
     configuration fail (a); bfloat16 products in place of float32 ones fail
     (c); so does dropping the selection (attending everything); and the
     reference's OWN sets give the same logits here (float32 sides do not
     part on a near-tie), so (b) had nothing to forgive."""
-    cfg = tiny(name="tiny-dsa-faults")
-    params = init_params(cfg, jax.random.PRNGKey(7))
+    cfg, params = model
     eng = _engine(cfg, params)
     req = _req("f", 100, 6, seed=10)
     logits, first = _drive(eng, [req])
@@ -266,24 +281,23 @@ def test_each_tolerance_catches_its_fault(probe):
     assert np.abs(m["own"] - m["got"]).max() < LOGIT_TOL
 
 
-def test_the_program_without_the_probe_gives_the_probed_runs_logits(probe):
+def test_the_program_without_the_probe_gives_the_probed_runs_logits(
+        model, probe):
     """The comparisons above read a program traced WITH ``ops.dsa.PROBE``
     set (a ``jax.debug.callback`` a pass); what serves is traced without.
     The same request through both (100 tokens in chunks of 32, then six
     steps: chunk and decode rows past ``index_topk``): the same tokens, the
     same logits."""
-    cfg = tiny(name="tiny-dsa-unprobed")
-    params = init_params(cfg, jax.random.PRNGKey(7))
+    cfg, params = model
     runs = []
     for probed in (True, False):
         if not probed:
             dsa.PROBE = None
-            engine_mod._build_ragged_step_fn.cache_clear()
-        eng = _engine(cfg, params, chunk=32)
+        eng = _engine(cfg if probed else UNPROBED, params, chunk=32)
         req = _req("u", 100, 6, seed=10)
         logits, _ = _drive(eng, [req])
         runs.append((req.output_tokens, logits["u"],
-                     eng.dsa_counts["rows_decode_sparse"]))
+                     eng.mixer_counts["rows_decode_sparse"]))
     assert len(probe.scores) > 0 and runs[0][2] == runs[1][2] > 0
     assert runs[0][0] == runs[1][0]
     assert sorted(runs[0][1]) == sorted(runs[1][1])
@@ -825,7 +839,7 @@ REFUSED = {
 
 @pytest.mark.parametrize("what", list(REFUSED))
 def test_what_the_index_pool_is_not_served_with_is_refused(what):
-    cfg = tiny()
+    cfg = UNPROBED
     params = init_params(cfg, jax.random.PRNGKey(0))
     kw, match = REFUSED[what]
     with pytest.raises(UnsupportedForModel, match=match):
@@ -835,7 +849,7 @@ def test_what_the_index_pool_is_not_served_with_is_refused(what):
 @pytest.mark.parametrize("call", ["export_request", "export_prefill",
                                   "import_request", "filestore"])
 def test_paths_that_move_a_pages_contents_are_refused_by_call(call):
-    cfg = tiny()
+    cfg = UNPROBED
     eng = _engine(cfg, init_params(cfg, jax.random.PRNGKey(0)))
     with pytest.raises(UnsupportedForModel,
                        match="an index-key pool beside the latent pool"):
@@ -848,14 +862,13 @@ def test_paths_that_move_a_pages_contents_are_refused_by_call(call):
 
 
 def test_a_prefix_cache_hit_that_shares_pages_serves_the_index_keys_too(
-        probe):
+        model, probe):
     """The prefix cache shares page IDS and never looks inside a page: a page
     of the latent pool is the same page of the index-key pool.  The same 60-
     token prompt twice: the second run's remainder scores the FIRST run's
     cached index keys (its history is the shared pages), chooses past 32
     keys, and returns what the cold run returned."""
-    cfg = tiny(name="tiny-dsa-prefix")
-    params = init_params(cfg, jax.random.PRNGKey(5))
+    cfg, params = model[0], init_params(model[0], jax.random.PRNGKey(5))
     eng = _engine(cfg, params, enable_prefix_cache=True)
     prompt = tokens_of(60, seed=4)
     sp = SamplingParams(max_tokens=5, temperature=0.0)
@@ -886,7 +899,7 @@ def test_the_hosts_account_is_exported_and_read():
     from helix_tpu.serving.registry import ModelRegistry, ServedModel
     from helix_tpu.serving.tokenizer import ByteTokenizer
 
-    cfg = tiny(name="tiny-dsa-account")
+    cfg = UNPROBED
     eng = _engine(cfg, init_params(cfg, jax.random.PRNGKey(3)))
     seen = []
     orig = obs_trace.phase
@@ -914,7 +927,7 @@ def test_the_hosts_account_is_exported_and_read():
 
     scored = chunk(16, 16)[0] + chunk(32, 8)[0] + 41 + 42 + 43
     chosen = chunk(16, 16)[1] + chunk(32, 8)[1] + 3 * K
-    c = eng.dsa_counts
+    c = eng.mixer_counts
     assert c["keys_scored"] == scored * L and c["keys_selected"] == chosen * L
     assert (c["rows_chunk_all"], c["rows_chunk_sparse"]) == (2, 1)
     assert (c["rows_decode_all"], c["rows_decode_sparse"]) == (0, 3)
@@ -971,8 +984,11 @@ def test_int8_tree_and_logical_axes_cover_the_indexers_tensors():
     from helix_tpu.ops.quant import quantize_params, quantized_logical_axes
 
     cfg = tiny()
-    born = init_params(cfg, jax.random.PRNGKey(0), int8=True)
-    made = quantize_params(init_params(cfg, jax.random.PRNGKey(0)))
+    # (shapes, dtypes and structure are all that is asked: nothing is run)
+    born = jax.eval_shape(
+        lambda k: init_params(cfg, k, int8=True), jax.random.PRNGKey(0))
+    made = jax.eval_shape(
+        lambda k: quantize_params(init_params(cfg, k)), jax.random.PRNGKey(0))
     shapes = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)  # noqa
     assert shapes(born) == shapes(made)
     for stack in ("dense_layers", "layers"):

@@ -30,6 +30,7 @@ from helix_tpu.models.llama import (  # noqa: E402
     forward, init_params, mla_absorbed_weights, mla_softmax_scale,
     param_logical_axes, prefill_attn_fn,
 )
+from helix_tpu.models.mixers import latent_widths  # noqa: E402
 from helix_tpu.models.moe import moe_ffn, route  # noqa: E402
 from helix_tpu.ops import rope as rope_ops  # noqa: E402
 from helix_tpu.ops.paged import (  # noqa: E402
@@ -630,8 +631,11 @@ def test_int8_tree_and_logical_axes_cover_every_new_tensor():
     from helix_tpu.ops.quant import quantize_params, quantized_logical_axes
 
     cfg = tiny()
-    born = init_params(cfg, jax.random.PRNGKey(0), int8=True)
-    made = quantize_params(init_params(cfg, jax.random.PRNGKey(0)))
+    # (shapes, dtypes and structure are all that is asked: nothing is run)
+    born = jax.eval_shape(
+        lambda k: init_params(cfg, k, int8=True), jax.random.PRNGKey(0))
+    made = jax.eval_shape(
+        lambda k: quantize_params(init_params(cfg, k)), jax.random.PRNGKey(0))
     shapes = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)  # noqa
     assert shapes(born) == shapes(made)
     assert set(born) == {"embed", "layers", "dense_layers", "final_norm",
@@ -652,7 +656,7 @@ def test_latent_pool_counts_what_it_allocates_and_moves_by_page():
     cfg = tiny()
     cc = CacheConfig(num_pages=10, page_size=8, dtype="float32")
     # ONE array: a token's latent, then its rope key padded to 128 lanes
-    assert cc.latent_widths(cfg) == (32, 128)
+    assert latent_widths(cfg) == (32, 128)
     assert cc.page_shapes(cfg) == ((3, 8, 32 + 128),)
     assert cc.page_bytes(cfg) == 3 * 8 * (32 + 128) * 4
     full = CacheConfig(num_pages=1, page_size=16, dtype="bfloat16")
@@ -936,11 +940,13 @@ def test_page_fetches_are_counted_from_the_hosts_mirrors():
     # 8-token blocks each, then one-token rows over 40, 41, 42 tokens
     by_hand = [0, 2 * 2 * L, 4 * 1 * L, 5 * L, 6 * L, 6 * L]
     assert seen == by_hand, seen
-    assert eng.num_mla_page_fetches == sum(by_hand)
+    assert eng.mixer_counts["mla_page_fetches"] == sum(by_hand)
     # the latent kernel's block stays 8 tokens whatever the bucket (the dense
     # kernel's long block is not its own), and the dense kernel ran nothing
-    assert [eng.prefill_q_block(rung, 1) for rung in (8, 16, 512)] == [8] * 3
-    assert eng.chunk_q_block == 8 and eng.attn_query_blocks == 0
+    assert [cfg.page_kind.query_block(cfg, rung, 1)
+            for rung in (8, 16, 512)] == [8] * 3
+    assert eng.chunk_q_block == 8 and (
+        "attn_query_blocks" not in eng.mixer_counts)
     registry = ModelRegistry()
     registry.register(ServedModel(
         name="tiny-mla", loop=EngineLoop(eng, "tiny-mla"),

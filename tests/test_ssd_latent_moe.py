@@ -582,7 +582,7 @@ def test_a_window_of_four_over_three_rows_writes_the_state_three_times(model):
     added = {k: n - before[k] for k, n in eng.mixer_counts.items()}
     assert added["decode_rows"] == 12 and added["state_writes"] == 3
     assert [kw["ssd_state_writes"] for kw in seen] == [3]
-    assert flight_fields(cfg.state_kind, {
+    assert flight_fields(eng.kinds, {
         **eng.mixer_values(), **eng.mixer_gauges()}, before)[
             "ssd_state_writes"] == 3
     (c, _), (h, _) = cfg.state_arrays()

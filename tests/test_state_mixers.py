@@ -1,6 +1,7 @@
-"""A layer kind with a per-sequence state is ONE record in
-``models/mixers.py``: nothing above ``models/`` spells a kind's name, and
-every record holds all the modules above ask of a kind."""
+"""A layer kind with a per-sequence state, and a kind of page, is ONE record
+in ``models/mixers.py``: nothing above ``models/`` spells a kind's name or
+reads the ``ModelConfig`` keys that tell one from another, and every record
+holds all the modules above ask of a kind."""
 
 import dataclasses
 import importlib.util
@@ -11,7 +12,9 @@ import tokenize
 import numpy as np
 import pytest
 
-from helix_tpu.models.mixers import STATE_MIXERS, Series, flight_fields
+from helix_tpu.models.mixers import (
+    PAGE_KINDS, STATE_MIXERS, PageKind, Pool, Series, flight_fields,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ABOVE_MODELS = (
@@ -42,9 +45,17 @@ def _code_tokens(path):
     return out
 
 
-def names_of_a_kind(root: str, kinds) -> list:
-    """Every place a module above ``models/`` names a state kind, by the
-    forms the modules named one before a kind was a record."""
+# what the modules above ``models/`` told a kind of page by, before a kind of
+# page was a record: the operators' names (in series, attributes and counts)
+# and the ``ModelConfig`` keys that are one kind's alone
+PAGE_OPERATORS = ("mla", "dsa")
+PAGE_NAMES = ("is_mla", "is_dsa", "kv_lora_rank", "index_heads", "index_topk",
+              "index_head_dim", "_note_dsa", "dsa_counts", "latent_widths")
+
+
+def names_of_a_kind(root: str, kinds, names=()) -> list:
+    """Every place a module above ``models/`` names a kind, by the forms the
+    modules named one before a kind was a record (and by ``names``, whole)."""
     alt = "|".join(map(re.escape, kinds))
     ident = re.compile(
         rf"^(num_({alt})_\w*|\w*?_?({alt})_(fn|layers|chunks|rows\w*)"
@@ -53,7 +64,7 @@ def names_of_a_kind(root: str, kinds) -> list:
     for rel in ABOVE_MODELS:
         toks = _code_tokens(os.path.join(root, rel))
         for i, (typ, text, line) in enumerate(toks):
-            if typ == tokenize.NAME and ident.match(text):
+            if typ == tokenize.NAME and (ident.match(text) or text in names):
                 found.append(f"{rel}:{line}: {text}")
             if typ != tokenize.STRING:
                 continue
@@ -62,7 +73,7 @@ def names_of_a_kind(root: str, kinds) -> list:
             # (an attribute read by name, ``getattr(eng, "num_x_rows")``, is
             # an attribute)
             if (body in kinds or re.search(rf"helix_({alt})_", body)
-                    or ident.match(body)):
+                    or ident.match(body) or body in names):
                 found.append(f"{rel}:{line}: {text}")
             near = [s for _, s, _ in toks[max(i - 3, 0):i + 4]]
             if "state_mixer" in near and {"==", "!=", "in"} & set(near):
@@ -70,8 +81,11 @@ def names_of_a_kind(root: str, kinds) -> list:
     return found
 
 
-def test_no_module_above_models_names_a_state_kind():
-    assert names_of_a_kind(ROOT, tuple(STATE_MIXERS)) == []
+@pytest.mark.parametrize("kinds, names", [
+    (tuple(STATE_MIXERS), ()), (PAGE_OPERATORS, PAGE_NAMES)],
+    ids=["state", "page"])
+def test_no_module_above_models_names_a_state_kind(kinds, names):
+    assert names_of_a_kind(ROOT, kinds, names) == []
 
 
 def test_the_fifth_kind_is_spelt_nowhere_above_models():
@@ -103,16 +117,38 @@ def test_the_search_finds_the_names_it_is_for(tmp_path):
         'd = cfg.state_mixer == "window"\n'
         "e = eng.window_ring_bytes_read + m.sliding_window\n"
         "f = self._note_window_rows(plan)\n"
-        'g = getattr(eng, "num_deltanet_chunks", 0)\n')
+        'g = getattr(eng, "num_deltanet_chunks", 0)\n'
+        "h = 3 + cfg.is_dsa if cfg.is_mla else cfg.kv_lora_rank\n"
+        'i = ("helix_dsa_rows_total", eng.num_mla_page_fetches)\n'
+        'j = getattr(eng, "dsa_counts", {}) or self._note_dsa(plan)\n'
+        "k = a_latent_page + the_index_pool  # neither is a name\n")
     found = names_of_a_kind(str(tmp_path), tuple(STATE_MIXERS))
     assert [f.split(": ", 1)[1] for f in found] == [
         "num_conv_layers", "retention_fn", '"helix_deltanet_chunks_total"',
         '"window"', 'state_mixer against "window"', "window_ring_bytes_read",
         "sliding_window", "_note_window_rows", '"num_deltanet_chunks"']
+    # ... and the page kinds' names, beside the forms every search looks for
+    paged = names_of_a_kind(str(tmp_path), PAGE_OPERATORS, PAGE_NAMES)
+    assert [f.split(": ", 1)[1] for f in paged if f not in found] == [
+        "is_dsa", "is_mla", "kv_lora_rank", '"helix_dsa_rows_total"',
+        "num_mla_page_fetches", '"dsa_counts"', "_note_dsa"]
 
 
-KINDS = sorted(STATE_MIXERS)
+RECORDS = {**STATE_MIXERS, **PAGE_KINDS}
+KINDS = sorted(RECORDS)
 NO_POS = np.zeros((0,), np.int64)
+assert len(RECORDS) == len(STATE_MIXERS) + len(PAGE_KINDS)
+
+
+def _tool(name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, "tools", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+step_text = _tool("step_text")
 
 
 def _counts(m, cfg, cache_cfg) -> dict:
@@ -126,18 +162,33 @@ def _gauges(m, cfg) -> dict:
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_a_record_is_complete(kind):
-    m = STATE_MIXERS[kind]
-    optional = ("check_geometry", "account", "gauges", "window")
+    m = RECORDS[kind]
+    optional = ("check_geometry", "account", "gauges", "window", "base") + (
+        ("call_refusal",) if isinstance(m, PageKind) else ())
     for f in dataclasses.fields(m):
         assert f.name in optional or getattr(m, f.name) is not None, f.name
-    assert m.refused_as and m.call_refusal and m.token_args >= 1
-    for c in (m.arrays, m.rows_fn, m.oracle,
-              *filter(None, (getattr(m, name) for name in optional))):
-        assert callable(c)
-    # a series reads a count, the layers or the pool's bytes (any thread may
-    # render it); a launch attribute or a flight field also a level
     cfg, cache_cfg = _cfgs(kind)
     keys = set(_counts(m, cfg, cache_cfg)) | {"layers", "pool_bytes"}
+    if isinstance(m, PageKind):
+        assert m.refused_as and m.token_args >= 3
+        assert m.base is None or m.base in PAGE_KINDS.values()
+        called = (m.pools, m.geometry, m.token_arrays, m.attend,
+                  m.query_block, m.check_geometry, m.account)
+        # what ``k_pages`` and, where there is one, ``v_pages`` hold
+        pools = m.pools(cfg, cache_cfg.page_size)
+        assert 1 <= len(pools) <= 2 and all(
+            isinstance(p, Pool) and p.page[0] == cache_cfg.page_size
+            for p in pools)
+        assert len(m.token_arrays(cfg)) == 2 and len(m.geometry(cfg)) == 2
+        keys |= {p.holds + "_pool_bytes" for p in pools}
+    else:
+        assert m.refused_as and m.call_refusal and m.token_args >= 1
+        called = (m.arrays, m.rows_fn, m.oracle)
+    for c in (*called,
+              *filter(None, (getattr(m, name, None) for name in optional[:4]))):
+        assert callable(c)
+    # a series reads a count, the layers or a pool's bytes (any thread may
+    # render it); a launch attribute or a flight field also a level
     assert {s.value for s in m.series} <= keys
     assert {k for _, k in m.launch + m.flight} <= keys | set(_gauges(m, cfg))
     assert all(isinstance(s, Series) and s.kind in ("counter", "gauge")
@@ -146,7 +197,7 @@ def test_a_record_is_complete(kind):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_refusal_rows_name_the_seven_settings_or_say_which_it_serves(kind):
-    m = STATE_MIXERS[kind]
+    m = RECORDS[kind]
     from helix_tpu.engine.engine import _SETTINGS
 
     refused = [setting for setting, _ in m.refusals]
@@ -154,6 +205,12 @@ def test_refusal_rows_name_the_seven_settings_or_say_which_it_serves(kind):
     serves = set(_SETTINGS) - set(refused)
     assert set(refused) <= set(_SETTINGS)
     assert all(why and why[0].islower() for _, why in m.refusals)
+    if isinstance(m, PageKind):
+        # pages are shared by their ids: every kind of page is served with
+        # the prefix cache, and K/V pages with every setting
+        assert "prefix_cache" in serves
+        assert (serves == set(_SETTINGS)) == (kind == "kv")
+        return
     # the one setting a kind is served with: the prefix cache, by the kind
     # whose steps hand back boundary states for it
     assert serves == ({"prefix_cache"} if m.snapshots else set())
@@ -191,11 +248,18 @@ def test_the_refusal_rows_stand_in_the_order_they_were_written():
 
 
 def _cfgs(kind):
-    """A tiny model with one layer of the kind, and an engine's cache
+    """A tiny model with one layer of the state kind beside one with K/V
+    pages, or with two layers of the kind of page, and an engine's cache
     configuration for it."""
     from helix_tpu.engine.engine import EngineConfig
     from helix_tpu.models.common import ModelConfig
 
+    if kind in PAGE_KINDS:
+        # (the tiny models ``tools/step_text.py`` lowers: their names are the
+        # page kinds')
+        cfg = ModelConfig.tiny(
+            vocab_size=64, dtype="float32", **step_text.MODELS[kind])
+        return cfg, EngineConfig(max_decode_batch=2).cache_config("float32")
     cfg = ModelConfig.tiny(
         vocab_size=64, dtype="float32", num_layers=2,
         layer_types=(kind, "attn"), sliding_window=8, conv_kernel=4,
@@ -203,38 +267,48 @@ def _cfgs(kind):
     return cfg, EngineConfig(max_decode_batch=2).cache_config("float32")
 
 
+# the one count a launch adds to whatever its rows hold: the gather out of
+# the index-key pool moves EVERY slot's page table at its whole width
+# (``mixers._indexed_account``); the engine's mapping starts at zero all the
+# same
+_MOVED_WHATEVER_THE_ROWS = {"latent_indexed": {"index_bytes_read"}}
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_the_account_of_an_empty_launch_is_all_zeros(kind):
-    m = STATE_MIXERS[kind]
+    m = RECORDS[kind]
     cfg, cache_cfg = _cfgs(kind)
-    assert cfg.state_kind is m and cfg.num_state_layers == 1
+    if isinstance(m, PageKind):
+        assert cfg.page_kind is m and cfg.num_attn_layers == 2
+    else:
+        assert cfg.state_kind is m and cfg.num_state_layers == 1
+        assert cfg.page_kind is PAGE_KINDS["kv"]
     counts = _counts(m, cfg, cache_cfg)
     # every count at every launch, whatever it holds: the engine's mapping
     # starts as this
-    assert counts == dict.fromkeys(
-        m.account(cfg, cache_cfg, (), NO_POS, 3) if m.account else (), 0)
+    assert set(counts) == set(
+        m.account(cfg, cache_cfg, (), NO_POS, 3) if m.account else ())
+    moved = _MOVED_WHATEVER_THE_ROWS.get(kind, set())
+    assert {k for k, n in counts.items() if n} == moved
     assert all(isinstance(n, int) for n in counts.values())
     # and the flight record shows every kind's fields, this kind's alone
     # from the engine's values
     values = {**dict.fromkeys(counts, 5), "layers": 1, "pool_bytes": 9,
               **_gauges(m, cfg)}
-    fields = flight_fields(m, values, dict.fromkeys(counts, 2))
+    fields = flight_fields((m,), values, dict.fromkeys(counts, 2))
     assert set(fields) == {
-        f for k in STATE_MIXERS.values() for f, _ in k.flight}
+        f for k in RECORDS.values() for f, _ in k.flight}
     for field, key in m.flight:
         assert fields[field] == (3 if key in counts else values[key])
     assert all(v == 0 for f, v in fields.items()
                if f not in dict(m.flight))
-    assert set(flight_fields(None, {}, {}).values()) == {0}
+    assert set(flight_fields((), {}, {}).values()) == {0}
 
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_series_names_pass_the_linters_naming_contract(kind):
-    spec = importlib.util.spec_from_file_location(
-        "lint_metrics", os.path.join(ROOT, "tools", "lint_metrics.py"))
-    lint = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(lint)
-    m = STATE_MIXERS[kind]
+    lint = _tool("lint_metrics")
+    m = RECORDS[kind]
     assert m.series
     for s in m.series:
         assert lint.NAME_RE.fullmatch(s.name), s.name
@@ -243,47 +317,52 @@ def test_series_names_pass_the_linters_naming_contract(kind):
         assert s.name.endswith("_total") == (s.kind == "counter"), s.name
 
 
-# ---- a kind's fused window is nothing to a model without one ---------------
+# ---- code that moves leaves the step programs as they were ------------------
 #
 # sha256 of ``fn.lower(*args).as_text()`` (it carries no locations; taken
 # under this suite's ``conftest.py``, whose matmul precision is in the text)
-# of a tiny dense model's three programs with a fused tail, AT THE COMMIT
-# BEFORE a state kind could keep a window's tokens beside its pool (d23911d,
-# PR 46).  The same digests came out of that tree and of this one for a tiny
-# model of every other kind too (latent, conv, deltanet, window, mamba2: 18
-# programs; PERF.md section 6, PR 47).  A PR that MEANS to change the step
-# every model runs takes new digests from its own tree and says so.
-_DENSE_PROGRAMS = {
-    "decode": ((0, 0, False), "c978b3b4185b112051c29602ca1e7907"
-                              "98c748c70c21d5caf53006376487e687"),
-    "wave": ((16, 2, False), "52d7205379a8feb72fa06534ee9eb445"
-                             "57a2bd62597222c7b145432d318a2904"),
-    "chunk_with_history": ((16, 1, True), "62ee93659baf2fcc4ed0898d3824b769"
-                                          "dec03bd61430ac26b183406178fda9c8"),
+# of a tiny model's three programs with a fused tail, as
+# ``tools/step_text.py`` prints them (its ``MODELS``: a dense model, a latent
+# one, a latent one behind an indexer, delta-rule layers beside K/V pages).
+# The dense model's are of THE COMMIT BEFORE a state kind could keep a
+# window's tokens beside its pool (d23911d, PR 46), and came out of every tree
+# since; the others' of the commit before a kind of page was a record
+# (2b045e4, PR 56), with the same from that tree and from PR 57's for every
+# model the tool has (30 programs: PERF.md section 6, PRs 47 and 57).  A PR
+# that MEANS to change the step a model runs takes new digests from its own
+# tree and says so.
+_PROGRAMS = {
+    ("kv", "decode"): "c978b3b4185b112051c29602ca1e7907"
+                      "98c748c70c21d5caf53006376487e687",
+    ("kv", "wave"): "52d7205379a8feb72fa06534ee9eb445"
+                    "57a2bd62597222c7b145432d318a2904",
+    ("kv", "chunk_with_history"): "62ee93659baf2fcc4ed0898d3824b769"
+                                  "dec03bd61430ac26b183406178fda9c8",
+    ("latent", "decode"): "7175cc87e60fa381cd9cf3679a7f2b5c"
+                          "ab6d38d88a068bf217b72b2737a6fa60",
+    ("latent", "wave"): "2e315e12ddd1395296818096926fbf61"
+                        "9a579af31cef30dce8f286edc2575434",
+    ("latent", "chunk_with_history"): "e2b5747de313baa0182d5e59845c0173"
+                                      "fb8dcffca2c123375f61cb935ada6bb3",
+    ("latent_indexed", "decode"): "8ca80bb89fa3d63def47aad20c289462"
+                                  "7adf9bbe1d35750b1a33246aad44dd4d",
+    ("latent_indexed", "wave"): "2ab5a1239ac052686ce294efee59da6c"
+                                "550b040fb638917241c8792f8f94e409",
+    ("latent_indexed", "chunk_with_history"):
+        "6e1a1a7fee40052e231dad6a77454327305a6b76c0c71177ec34e2080aa91ee8",
+    ("deltanet", "decode"): "7dfedda505aa999a2a25d6a08c1e8d18"
+                            "49dfcf12d6bb4d6f30abd93009a9f370",
+    ("deltanet", "wave"): "917de55c4144dd37b58cf5cde74d09d3"
+                          "7a38efeffab4b0dcc6511815cb124f82",
+    ("deltanet", "chunk_with_history"): "fa9d5e71fc9621e9733d400e326ab6c3"
+                                        "96d4012108ee9c7e153bd327bf0d20a5",
 }
 
 
-@pytest.mark.parametrize("program", sorted(_DENSE_PROGRAMS))
-def test_a_model_without_a_state_kind_lowers_to_the_text_it_had(program):
-    import hashlib
-
-    import jax
-
-    import joint_pass
-    from helix_tpu.engine.engine import Engine, EngineConfig
-    from helix_tpu.models.common import ModelConfig
-    from helix_tpu.models.llama import init_params
-
-    cfg = ModelConfig.tiny(vocab_size=512, dtype="float32")
-    assert cfg.state_kind is None
-    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(3)), EngineConfig(
-        max_decode_batch=3, page_size=8, num_pages=96, max_pages_per_seq=16,
-        max_prefill_len=16, attn_backend="reference",
-        decode_steps_per_sync=4))
-    shape, digest = _DENSE_PROGRAMS[program]
-    fn, args = joint_pass.step_program(eng, *shape)
-    text = fn.lower(*args).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == digest
+@pytest.mark.parametrize("model, program", sorted(_PROGRAMS))
+def test_a_model_without_a_state_kind_lowers_to_the_text_it_had(
+        model, program):
+    assert step_text.digest(model, program) == _PROGRAMS[model, program]
 
 
 def test_only_a_kind_with_a_window_is_told_the_step_of_the_tail():

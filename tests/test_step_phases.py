@@ -319,15 +319,16 @@ def test_every_flight_record_carries_the_state_segments_query_block(stepped):
 
 def test_every_flight_record_carries_the_chunk_segments_query_block(stepped):
     """... and the prefill segment's block in the last launch that had one
-    (``Engine.prefill_q_block``: the paged kernel's own, from the bucket,
+    (the page kind's ``query_block``: the paged kernel's own, from the bucket,
     the group and the rows): 0 before any, 16 for a one-row chunk in the
     bucket of 16, 8 where two rows share it or the bucket is 8 or under."""
     recs = stepped.flight.snapshot(recent=512)["recent"]
     eng = stepped.engine
+    cfg = eng.model_cfg
     assert {rec["chunk_q_block"] for rec in recs} <= {0, 8, 16}
     assert 16 in {rec["chunk_q_block"] for rec in recs}
     assert recs[-1]["chunk_q_block"] == eng.chunk_q_block
-    assert [eng.prefill_q_block(rung, rows) for rung, rows in (
+    assert [cfg.page_kind.query_block(cfg, rung, rows) for rung, rows in (
         (16, 1), (16, 2), (8, 1), (4, 2))] == [16, 8, 8, 8]
 
 
@@ -342,21 +343,24 @@ def test_the_paged_kernels_counters_follow_its_own_block(stepped):
     import numpy as np
 
     from helix_tpu.engine.ragged import PrefillPlan
+    from helix_tpu.models.mixers import history_pages
 
     eng = stepped.engine
+    cfg = eng.model_cfg
     L, P = eng.model_cfg.num_attn_layers, eng.cache_cfg.page_size
     none = np.zeros(0, np.int64)
     for rung, rows, block in ((16, [(16, 32)], 16), (16, [(9, 5), (7, 8)], 8),
                               (8, [(5, 64)], 8)):
         plan = PrefillPlan(P, eng.cache_cfg.max_pages_per_seq, len(rows))
         plan.rows = [types.SimpleNamespace(rem=n, start=h) for n, h in rows]
-        assert eng.prefill_q_block(rung, len(rows)) == block
-        assert eng._history_pages(plan, block, none, 0) == sum(
+        assert cfg.page_kind.query_block(cfg, rung, len(rows)) == block
+        assert history_pages(plan.rows, block, none, 0, P) == sum(
             -(-h // P) * -(-n // block) for n, h in rows)
     # a wave of two rows in the bucket of 16: 2 + 1 blocks of 8, and the
     # one-row chunk of 16 over 32 tokens: 1 block over 8 pages
-    assert eng.attn_query_blocks % L == 0 and eng.attn_query_blocks > 0
-    assert eng.attn_page_bytes_read > 0
+    c = eng.mixer_counts
+    assert c["attn_query_blocks"] % L == 0 and c["attn_query_blocks"] > 0
+    assert c["attn_page_bytes_read"] > 0
 
 
 def test_flight_records_carry_the_joint_pass_and_its_inert_rows(stepped):

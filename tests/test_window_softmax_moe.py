@@ -467,7 +467,7 @@ def test_the_page_bytes_and_the_context_a_launch_counts_by_hand(model):
     """``helix_attn_page_bytes_read_total``: a launch's history pages (a live
     decode row's ``ceil(position / page)``, a chunk row's ``ceil(start /
     page)`` once a query block of the paged kernel's own size:
-    ``Engine.prefill_q_block``) times a page's K and V over the FULL
+    the page kind's ``query_block``) times a page's K and V over the FULL
     layers alone; ``context_tokens``: the decode rows' positions and a chunk
     row's history and fresh tokens; ``attn_query_blocks``: the programs the
     paged kernel runs for the chunk rows.  All on the launch's span."""
@@ -498,19 +498,21 @@ def test_the_page_bytes_and_the_context_a_launch_counts_by_hand(model):
     chunks = [kw for kw in launches if kw["prefill_rows"]]
     assert [kw["chunk_q_block"] for kw in chunks] == [16, 16, 8]
     assert [kw["chunk_q_block"] for kw in chunks] == [
-        eng.prefill_q_block(kw["token_bucket"], 1) for kw in chunks]
+        cfg.page_kind.query_block(cfg, kw["token_bucket"], 1)
+        for kw in chunks]
     assert [kw["attn_page_bytes"] for kw in chunks] == [
         0, 2 * 1 * page, 4 * 1 * page]
     # (the first chunk has no history: the packed flash kernel runs it)
     assert [kw["attn_query_blocks"] for kw in chunks] == [0, 1, 1]
-    assert eng.attn_query_blocks == 2 and eng.chunk_q_block == 8
+    assert eng.mixer_counts["attn_query_blocks"] == 2 and (
+        eng.chunk_q_block == 8)
     assert [kw["context_tokens"] for kw in chunks] == [16, 32, 37]
     decodes = [kw for kw in launches if not kw["prefill_rows"]]
     assert decodes and decodes[0]["context_tokens"] == 37
     assert decodes[0]["attn_page_bytes"] >= 5 * page
     assert all(kw["attn_query_blocks"] == 0 and "chunk_q_block" not in kw
                for kw in decodes)
-    assert eng.attn_page_bytes_read == sum(
+    assert eng.mixer_counts["attn_page_bytes_read"] == sum(
         kw["attn_page_bytes"] for kw in launches)
     assert all(kw["window_layers"] == 3 and kw["attn_layers"] == 1
                and "held_experts" not in kw and "mla_page_fetches" not in kw
@@ -548,7 +550,7 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
         r["window_layers"] == 3 and r["held_experts"] == 0
         and r["attn_layers"] == 1 for r in records)
     assert sum(r["attn_page_bytes_read"] for r in records) == (
-        eng.attn_page_bytes_read) > 0
+        eng.mixer_counts["attn_page_bytes_read"]) > 0
     assert max(r["context_tokens"] for r in records) >= 37
     assert max(r["window_rows_wrapped"] for r in records) == 1
     registry = ModelRegistry()
@@ -563,11 +565,11 @@ def test_flight_records_and_metrics_carry_the_new_series(model):
         return float(line.rsplit(" ", 1)[1])
 
     assert value("helix_attn_page_bytes_read_total{") == (
-        eng.attn_page_bytes_read)
+        eng.mixer_counts["attn_page_bytes_read"])
     # chunks of 16, 16 and 5 tokens: the two with history are one program
     # of the paged kernel each in the one full layer
     assert value("helix_attn_query_blocks_total{") == 2 == (
-        eng.attn_query_blocks)
+        eng.mixer_counts["attn_query_blocks"])
     assert {r["chunk_q_block"] for r in records} <= {0, 16, 8}
     assert records[-1]["chunk_q_block"] == 8
     assert value("helix_step_context_tokens_count{") == len(records)
